@@ -1,0 +1,578 @@
+#!/usr/bin/env python3
+"""The chip check: fit, serve and generate on the TPU, once, in one process.
+
+    python chip_smoke.py
+
+drives the system's main paths through the entry points a user calls, at
+the full width of models the repo supports (ResNet-50 at 224^2 / 1000
+classes, the 512-wide 4-layer TransformerLM), on weights and inputs made
+from a seed — no network, no files, no child process (a chip belongs to
+one process at a time).  Each phase checks what came out by the repo's
+own means and raises on the first thing that is wrong; nothing catches a
+failure and carries on.  Phases, each timed with XLA compile seconds
+apart from the rest:
+
+  fence     a chain of 8192^3 bf16 matmuls ended by block_until_ready:
+            the implied TFLOP/s must not exceed the chip's published peak
+            (a fence that returns early would "beat" it)
+  train     ResNet-50 NHWC bf16 through Module.fit: steps at one step per
+            dispatch (executor.fused_step), then blocks of K steps
+            through DeviceStagedIter (executor.fused_block)
+  serve     a ResNet-50 Predictor behind ModelServer, unbatched requests
+            from several threads, results against Predictor.forward
+  generate  TransformerLM via add_generative_tenant + submit_generate;
+            one session's prefill/decode logits against the
+            full-recompute score_symbol forward
+  kernel    ops/pallas_kernels.bn_stats under Mosaic at two ResNet-50
+            shapes against the jnp reduction
+  four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
+            Predictor bound to chip 3, in this same process
+
+It exits non-zero, before any work, when JAX's default backend is not a
+TPU, and prints as the last line of stdout one JSON object with
+"ok": true and the device as JAX reports it only when every phase ran
+and passed.  The phase functions take (sizes, ctx) so the tier-1 tests
+(tests/test_chip_smoke.py) call them tiny on mx.cpu(); main() has no
+switch that skips the device check.
+"""
+import json
+import sys
+import threading
+import time
+
+FULL = {
+    "fence": {"n": 8192, "chain": 8, "reps": 3},
+    "train": {"depth": 50, "image": 224, "classes": 1000, "batch": 256,
+              "k": 4, "blocks": 2, "seed": 0},
+    "serve": {"depth": 50, "image": 224, "classes": 1000,
+              "buckets": [8, 32], "requests": 64, "threads": 4,
+              "wait_ms": 5.0, "seed": 1},
+    "generate": {"vocab": 8192, "num_layers": 4, "num_heads": 8,
+                 "d_model": 512, "max_len": 320, "max_sessions": 4,
+                 "seq_buckets": [16, 64], "prompts": 8, "new_tokens": 32,
+                 "check_steps": 8, "seed": 2},
+    "kernel": {"shapes": [(512, 56, 56, 64), (512, 7, 7, 2048)], "seed": 3},
+    "four_chips": {"depth": 50, "image": 224, "classes": 1000,
+                   "batch": 256, "steps": 3, "seed": 4},
+}
+
+# Tolerances.  On the TPU an f32 matmul/conv runs at default precision
+# (bf16 passes), and two programs that compute the same thing at another
+# batch or sequence shape may round differently, so outputs are compared
+# by max |a - b| relative to max |reference| — never by argmax equality
+# on random weights.
+SERVE_RTOL = 2e-2      # served logits vs Predictor.forward at batch 1
+GENERATE_RTOL = 5e-2   # KV prefill/decode logits vs full recompute
+KERNEL_RTOL = 1e-2     # bn_stats (bf16 in, f32 accumulate) vs jnp
+
+
+def _rel_err(got, ref):
+    import numpy as np
+
+    return float(np.abs(np.asarray(got, np.float64) - ref).max()
+                 / max(float(np.abs(ref).max()), 1e-30))
+
+
+def _check(cond, msg):
+    # not `assert`: the checks must hold under python -O too
+    if not cond:
+        raise RuntimeError("chip_smoke check failed: " + msg)
+
+
+class CompileClock:
+    """Seconds XLA spent producing executables (a compile, or a
+    persistent-cache retrieval), summed from JAX's own monitoring events.
+    jit calls and the AOT wrapper's lower().compile() both report here."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        import jax
+
+        self._lock = threading.Lock()
+        self.seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **_kw):
+        if event == self.EVENT:
+            with self._lock:
+                self.seconds += duration
+
+
+# ----------------------------------------------------------------------
+# phases
+# ----------------------------------------------------------------------
+
+def phase_fence(sizes, ctx):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import telemetry
+
+    n, chain = sizes["n"], sizes["chain"]
+    dev = ctx.jax_device()
+    # ones @ (1/n) == ones exactly (1/n is a power of two, f32 accumulate),
+    # so the chain's value proves the matmuls ran
+    a = jax.device_put(jnp.ones((n, n), jnp.bfloat16), dev)
+    b = jax.device_put(jnp.full((n, n), 1.0 / n, jnp.bfloat16), dev)
+
+    @jax.jit
+    def run(a, b):
+        x = a
+        for _ in range(chain):
+            x = jnp.dot(x, b)
+        return x
+
+    run(a, b).block_until_ready()  # compile
+    best = float("inf")
+    for _ in range(sizes["reps"]):
+        t0 = time.perf_counter()
+        out = run(a, b)
+        out.block_until_ready()
+        best = min(best, time.perf_counter() - t0)
+    _check(float(out[0, 0]) == 1.0 and float(out[-1, -1]) == 1.0,
+           "matmul chain value %r != 1.0" % float(out[0, 0]))
+    tflops = chain * 2 * n ** 3 / best / 1e12
+    peak = telemetry.peak_flops(dev)
+    if dev.platform == "tpu":
+        _check(peak is not None,
+               "no published peak for device_kind %r in "
+               "mxnet_tpu.telemetry.PEAK_FLOPS" % dev.device_kind)
+    if peak is not None:
+        _check(tflops * 1e12 <= peak,
+               "fence implies %.1f TFLOP/s > the %.0f TFLOP/s peak of %s: "
+               "block_until_ready returned before the device finished"
+               % (tflops, peak / 1e12, dev.device_kind))
+    return {"tflops": round(tflops, 1),
+            "peak_tflops": None if peak is None else peak / 1e12,
+            "seconds_per_chain": round(best, 5)}
+
+
+def _resnet(sizes):
+    from mxnet_tpu.models.resnet import resnet
+
+    hw = sizes["image"]
+    return resnet(sizes["depth"], num_classes=sizes["classes"],
+                  image_shape=(3, hw, hw), layout="NHWC")
+
+
+def _xavier(mx):
+    return mx.init.Xavier(rnd_type="gaussian", factor_type="in", magnitude=2)
+
+
+def _images(sizes, n):
+    import numpy as np
+
+    rng = np.random.default_rng(sizes["seed"])
+    hw = sizes["image"]
+    X = rng.standard_normal((n, hw, hw, 3), dtype=np.float32)
+    y = rng.integers(0, sizes["classes"], n).astype("float32")
+    return X, y
+
+
+def _check_on_devices(exe, devices, what):
+    """Every parameter and optimizer-state leaf of `exe` lives on exactly
+    `devices` (one device; or the whole mesh, replicated)."""
+    from mxnet_tpu.optimizer import _state_leaves
+
+    diff_names = exe._fused_static[0]
+    for name in diff_names:
+        got = exe.arg_dict[name].data.devices()
+        _check(got == devices, "%s: parameter %s on %s, expected %s"
+               % (what, name, got, devices))
+        state = exe._fused_updater.states[exe._fused_index_of_name[name]]
+        for leaf in _state_leaves(state):
+            got = leaf.data.devices()
+            _check(got == devices, "%s: optimizer state of %s on %s, "
+                   "expected %s" % (what, name, got, devices))
+    return len(diff_names)
+
+
+def _fit(mod, it, metric, num_epoch, k):
+    mod.fit(it, eval_metric=metric, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.01, "momentum": 0.9},
+            num_epoch=num_epoch, steps_per_dispatch=k)
+
+
+def phase_train(sizes, ctx):
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import telemetry
+
+    B, K = sizes["batch"], sizes["k"]
+    X, y = _images(sizes, B * K)  # one epoch = K steps = one K-block
+    it = mx.io.NDArrayIter(X, y, batch_size=B)
+    mx.random.seed(sizes["seed"])
+    mod = mx.mod.Module(_resnet(sizes), context=ctx, compute_dtype="bfloat16")
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params(_xavier(mx))
+    exe = mod._exec_group.execs[0]
+    w0 = exe.arg_dict["fc1_weight"].asnumpy()
+    metric = mx.metric.CrossEntropy()
+
+    def dispatches():
+        return telemetry.counter_value("executor.train_dispatches")
+
+    d0 = dispatches()
+    _fit(mod, it, metric, num_epoch=1, k=1)
+    _check(dispatches() - d0 == K, "K=1: %d dispatches for %d steps"
+           % (dispatches() - d0, K))
+    loss_step = float(metric.get()[1])
+    _check(np.isfinite(loss_step), "K=1 loss %r" % loss_step)
+    w1 = exe.arg_dict["fc1_weight"].asnumpy()
+    _check(np.isfinite(w1).all() and np.abs(w1 - w0).max() > 0,
+           "K=1 fit did not move fc1_weight")
+
+    d0 = dispatches()
+    _fit(mod, it, metric, num_epoch=sizes["blocks"], k=K)
+    _check(dispatches() - d0 == sizes["blocks"],
+           "K=%d: %d dispatches for %d blocks"
+           % (K, dispatches() - d0, sizes["blocks"]))
+    loss_block = float(metric.get()[1])
+    _check(np.isfinite(loss_block), "K=%d loss %r" % (K, loss_block))
+    w2 = exe.arg_dict["fc1_weight"].asnumpy()
+    _check(np.isfinite(w2).all() and np.abs(w2 - w1).max() > 0,
+           "K=%d fit did not move fc1_weight" % K)
+    _check(exe._jit_step is not None and len(exe._jit_block) > 0,
+           "fused_step and fused_block did not both compile")
+    n = _check_on_devices(exe, {ctx.jax_device()}, "train")
+    return {"loss_k1": round(loss_step, 4),
+            "loss_k%d" % K: round(loss_block, 4),
+            "steps": K + K * sizes["blocks"], "params_on_device": n,
+            "mfu_gauge": telemetry.gauge_value("module.mfu")}
+
+
+def _serve_predictor(mx, sizes, ctx):
+    """A ResNet Predictor from a seeded random checkpoint, serving the
+    logits beside the softmax (the logits are what gets compared)."""
+    hw = sizes["image"]
+    net = _resnet(sizes)
+    mx.random.seed(sizes["seed"])
+    mod = mx.mod.Module(net, context=ctx)
+    mod.bind(data_shapes=[("data", (1, hw, hw, 3))], label_shapes=None,
+             for_training=False)
+    mod.init_params(_xavier(mx))
+    arg, aux = mod.get_params()
+    params = {"arg:%s" % k: v for k, v in arg.items()}
+    params.update({"aux:%s" % k: v for k, v in aux.items()})
+    return mx.Predictor(net, params, {"data": (1, hw, hw, 3)}, ctx=ctx,
+                        output_names=["fc1_output", "softmax_output"])
+
+
+def phase_serve(sizes, ctx):
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import telemetry
+
+    pred = _serve_predictor(mx, sizes, ctx)
+    X, _ = _images(sizes, sizes["requests"])
+    buckets = sizes["buckets"]
+    server = mx.serving.ModelServer({"resnet": pred}, max_batch=buckets[-1],
+                                    buckets=buckets,
+                                    wait_ms=sizes["wait_ms"])
+    try:
+        server.warmup()
+        programs = telemetry.counter_value("serving.bucket_programs")
+        compiled = telemetry.counter_value("mem.programs_compiled")
+        futures = [None] * len(X)
+
+        def client(idx):
+            for i in idx:
+                futures[i] = server.submit("resnet", {"data": X[i]},
+                                           timeout_ms=120000)
+
+        threads = [threading.Thread(target=client,
+                                    args=(range(t, len(X), sizes["threads"]),))
+                   for t in range(sizes["threads"])]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(300)
+            _check(not t.is_alive(), "a client thread did not finish")
+        outs = [f.result(timeout=300) for f in futures]
+        _check(telemetry.counter_value("serving.bucket_programs") == programs
+               and telemetry.counter_value("mem.programs_compiled")
+               == compiled, "a program compiled after warm-up")
+    finally:
+        server.close()
+    worst = 0.0
+    for x, (logits, prob) in zip(X, outs):
+        _check(logits.shape == (sizes["classes"],)
+               and prob.shape == (sizes["classes"],), "output shapes %s %s"
+               % (logits.shape, prob.shape))
+        _check(np.isfinite(logits).all() and np.isfinite(prob).all()
+               and abs(float(prob.sum()) - 1.0) < 1e-3,
+               "served output not finite / not a distribution")
+        pred.forward(data=x[None])
+        worst = max(worst, _rel_err(logits, pred.get_output(0)[0]))
+    _check(worst <= SERVE_RTOL, "served logits differ from Predictor."
+           "forward by %.3g of max |logit| (tolerance %.3g)"
+           % (worst, SERVE_RTOL))
+    out_dev = pred._exec.outputs[0].data.devices()
+    _check(out_dev == {ctx.jax_device()},
+           "Predictor output on %s, context %s" % (out_dev, ctx))
+    pred.close()
+    return {"requests": len(outs), "bucket_programs": programs,
+            "max_rel_err": float("%.3g" % worst)}
+
+
+def phase_generate(sizes, ctx):
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import telemetry
+    from mxnet_tpu.models import TransformerLM
+    from mxnet_tpu.obs import memory
+
+    lm = TransformerLM(vocab=sizes["vocab"], num_layers=sizes["num_layers"],
+                       num_heads=sizes["num_heads"], d_model=sizes["d_model"],
+                       max_len=sizes["max_len"])
+    mx.random.seed(sizes["seed"])
+    mod = mx.mod.Module(lm.training_symbol(), data_names=("data",),
+                        label_names=("softmax_label",), context=ctx)
+    mod.bind(data_shapes=[("data", (2, 8))],
+             label_shapes=[("softmax_label", (2, 8))])
+    mod.init_params(_xavier(mx))
+    arg, aux = mod.get_params()
+    params = dict(arg)
+    params.update(aux)
+
+    dev = ctx.jax_device()
+    limit = memory.budget_bytes(dev)
+    if dev.platform == "tpu":
+        # admission below runs against what the chip really has
+        _check(limit and limit == dev.memory_stats()["bytes_limit"],
+               "admission budget %r is not the device's bytes_limit" % limit)
+    rng = np.random.RandomState(sizes["seed"])
+    longest = sizes["seq_buckets"][-1]
+    prompts = [rng.randint(0, lm.vocab, size=rng.randint(2, longest + 1))
+               for _ in range(sizes["prompts"])]
+    server = mx.serving.ModelServer({})
+    try:
+        session = server.add_generative_tenant(
+            "lm", lm, params, ctx=ctx, max_sessions=sizes["max_sessions"],
+            max_len=sizes["max_len"], max_decode_tokens=sizes["new_tokens"],
+            seq_buckets=sizes["seq_buckets"])
+        server.warmup()
+        programs = telemetry.counter_value("serving.decode.bucket_programs")
+        compiled = telemetry.counter_value("mem.programs_compiled")
+        ring = telemetry.gauge_value("kv.ring_bytes")
+        _check(ring and ring > 0, "kv.ring_bytes gauge %r" % ring)
+        futures = [server.submit_generate("lm", p,
+                                          max_new_tokens=sizes["new_tokens"],
+                                          timeout_ms=600000)
+                   for p in prompts]
+        results = [f.result(timeout=600) for f in futures]
+        for p, r in zip(prompts, results):
+            _check(len(r.tokens) == sizes["new_tokens"]
+                   and r.finish_reason == "length"
+                   and r.prompt_len == len(p)
+                   and ((0 <= r.tokens) & (r.tokens < lm.vocab)).all(),
+                   "bad generation %r for a %d-token prompt" % (r, len(p)))
+        _check(telemetry.counter_value("serving.decode.bucket_programs")
+               == programs
+               and telemetry.counter_value("mem.programs_compiled")
+               == compiled, "a decode program compiled after warm-up")
+
+        # one session by hand, the way tests/test_transformer_lm.py does:
+        # prefill + greedy decode through the tenant's own (warm)
+        # programs, every step's logits against ONE full-recompute
+        # forward over the final sequence (causal: row t of it is the
+        # recompute answer after t+1 tokens).  The batcher is idle — every
+        # future above has resolved — so slot 0 is free.
+        prompt = list(prompts[0][:sizes["seq_buckets"][0] - 1])
+        n, bucket = len(prompt), sizes["seq_buckets"][0]
+        exe, fn = session._program(session._prefill_pred, 1, bucket, True)
+        data = np.zeros((1, bucket), np.float32)
+        data[0, :n] = prompt
+        kv_logits = [session._run(exe, fn, data, np.zeros((1,), np.float32),
+                                  np.full((1,), n, np.float32))[0]]
+        toks = list(prompt)
+        exe, fn = session._program(session._decode_pred, 1, 1, False)
+        for _ in range(sizes["check_steps"]):
+            toks.append(int(np.argmax(kv_logits[-1])))
+            kv_logits.append(session._run(
+                exe, fn, np.asarray([[toks[-1]]], np.float32),
+                np.zeros((1,), np.float32),
+                np.full((1,), len(toks) - 1, np.float32))[0])
+    finally:
+        server.close()
+    scorer = mx.Predictor(lm.score_symbol(), dict(params),
+                          {"data": (1, len(toks))}, ctx=ctx)
+    scorer.forward(data=np.asarray([toks], np.float32))
+    ref = scorer.get_output(0).reshape(len(toks), lm.vocab)
+    scorer.close()
+    worst = max(_rel_err(kv, ref[n - 1 + i])
+                for i, kv in enumerate(kv_logits))
+    _check(worst <= GENERATE_RTOL, "KV prefill/decode logits differ from "
+           "full recompute by %.3g of max |logit| (tolerance %.3g)"
+           % (worst, GENERATE_RTOL))
+    return {"prompts": len(prompts),
+            "tokens": int(sum(len(r.tokens) for r in results)),
+            "decode_bucket_programs": programs, "kv_ring_bytes": int(ring),
+            "bytes_limit": limit, "max_rel_err": float("%.3g" % worst)}
+
+
+def phase_kernel(sizes, ctx):
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    dev = ctx.jax_device()
+    if dev.platform == "tpu":
+        _check(not pk._INTERPRET, "bn_stats would run interpreted")
+    worst = 0.0
+    for i, shape in enumerate(sizes["shapes"]):
+        _check(pk.bn_stats_supported(shape, -1),
+               "bn_stats does not support %s" % (shape,))
+        x = jax.device_put(jax.random.normal(
+            jax.random.key(sizes["seed"] + i), shape, jnp.bfloat16) + 0.5,
+            dev)
+        mean, mean_sq = jax.jit(lambda v: pk.bn_stats(v, -1))(x)
+        axes = tuple(range(len(shape) - 1))
+        xf = x.astype(jnp.float32)
+        worst = max(worst,
+                    _rel_err(mean, jnp.mean(xf, axis=axes)),
+                    _rel_err(mean_sq, jnp.mean(xf * xf, axis=axes)))
+    _check(worst <= KERNEL_RTOL, "bn_stats differs from jnp by %.3g "
+           "(tolerance %.3g)" % (worst, KERNEL_RTOL))
+    return {"shapes": len(sizes["shapes"]),
+            "max_rel_err": float("%.3g" % worst)}
+
+
+def phase_four_chips(sizes, ctxs):
+    """`ctxs`: four contexts naming four distinct devices."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import telemetry
+
+    devices = [c.jax_device() for c in ctxs]
+    B = sizes["batch"]
+    X, y = _images(sizes, B * sizes["steps"])
+    it = mx.io.NDArrayIter(X, y, batch_size=B)
+    mx.random.seed(sizes["seed"])
+    mod = mx.mod.Module(_resnet(sizes), context=list(ctxs),
+                        compute_dtype="bfloat16")
+    metric = mx.metric.CrossEntropy()
+    d0 = telemetry.counter_value("executor.train_dispatches")
+    # kvstore=None: the gradient all-reduce is the one XLA inserts into
+    # the single SPMD step (a kvstore would disarm the fused dispatch)
+    mod.fit(it, eval_metric=metric, kvstore=None, optimizer="sgd",
+            optimizer_params={"learning_rate": 0.01, "momentum": 0.9},
+            initializer=_xavier(mx), num_epoch=1, steps_per_dispatch=1)
+    _check(telemetry.counter_value("executor.train_dispatches") - d0
+           == sizes["steps"], "dispatch count")
+    _check(np.isfinite(float(metric.get()[1])), "loss %r" % (metric.get(),))
+    exe = mod._exec_group.execs[0]
+    _check_on_devices(exe, set(devices), "four_chips")
+    # the batch input as the last step consumed it: one shard per device
+    shards = exe._place(exe._gather_args())[
+        exe._arg_names.index("data")].addressable_shards
+    _check({s.device for s in shards} == set(devices)
+           and all(s.data.shape[0] == B // len(devices) for s in shards),
+           "batch shards on %s" % [s.device for s in shards])
+    in_use = {}
+    for d in devices:
+        stats = d.memory_stats()
+        if stats is not None:  # XLA:CPU reports none
+            in_use[str(d)] = stats["bytes_in_use"]
+            _check(stats["bytes_in_use"] > 0, "%s holds no memory" % d)
+
+    # a context with device_id 3 computes on device 3
+    pred = _serve_predictor(mx, dict(sizes, seed=sizes["seed"] + 1),
+                            ctxs[3])
+    pred.forward(data=X[:1])
+    out = pred._exec.outputs[0].data
+    _check(out.devices() == {devices[3]} and np.isfinite(
+        pred.get_output(0)).all(), "Predictor(ctx=%s) computed on %s"
+        % (ctxs[3], out.devices()))
+    pred.close()
+    return {"devices": [str(d) for d in devices],
+            "loss": round(float(metric.get()[1]), 4),
+            "bytes_in_use": in_use, "predictor_device": str(devices[3])}
+
+
+# ----------------------------------------------------------------------
+# driver
+# ----------------------------------------------------------------------
+
+def run_phase(name, fn, sizes, ctx, clock, report):
+    """Run one phase; an exception propagates (traceback, non-zero exit)."""
+    print("[chip_smoke] phase %s ..." % name, flush=True)
+    c0, t0 = clock.seconds, time.perf_counter()
+    facts = fn(sizes, ctx)
+    seconds = time.perf_counter() - t0
+    compile_s = clock.seconds - c0
+    first = ctx[0] if isinstance(ctx, list) else ctx
+    report[name] = dict(facts, ok=True, seconds=round(seconds, 2),
+                        compile_seconds=round(compile_s, 2),
+                        device=str(first.jax_device()))
+    print("[chip_smoke] phase %s ok: %.1f s (%.1f s compiling) %s"
+          % (name, seconds, compile_s, json.dumps(facts)), flush=True)
+
+
+def main():
+    try:
+        import jax
+
+        # import BEFORE any backend touch: the package places the compile
+        # cache at import (mxnet_tpu.base.compile_cache_dir)
+        import mxnet_tpu as mx
+    except ImportError as e:
+        sys.exit("chip_smoke: cannot import the system under test: %s" % e)
+    from mxnet_tpu import base, telemetry
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        sys.exit("chip_smoke: JAX's default backend is %r, not a TPU "
+                 "(jax.devices() = %s); nothing was run"
+                 % (backend, jax.devices()))
+    import jaxlib
+    import libtpu
+
+    dev = jax.devices()[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind,
+              "count": len(jax.devices())}
+    libtpu_version = libtpu.__version__
+    print("[chip_smoke] device %s; jax %s jaxlib %s libtpu %s; compile "
+          "cache %s" % (json.dumps(device), jax.__version__,
+                        jaxlib.__version__, libtpu_version,
+                        base.compile_cache_dir()), flush=True)
+
+    telemetry.set_enabled(True)
+    clock = CompileClock()
+    report = {}
+    ctx = mx.tpu(0)
+    t0 = time.perf_counter()
+    run_phase("fence", phase_fence, FULL["fence"], ctx, clock, report)
+    run_phase("train", phase_train, FULL["train"], ctx, clock, report)
+    run_phase("serve", phase_serve, FULL["serve"], ctx, clock, report)
+    run_phase("generate", phase_generate, FULL["generate"], ctx, clock,
+              report)
+    run_phase("kernel", phase_kernel, FULL["kernel"], ctx, clock, report)
+    if jax.device_count() >= 4:
+        run_phase("four_chips", phase_four_chips, FULL["four_chips"],
+                  [mx.tpu(i) for i in range(4)], clock, report)
+    else:
+        report["four_chips"] = {"ok": None, "skipped": "%d device(s)"
+                                % jax.device_count()}
+    fallbacks = telemetry.counter_value("mem.program_fallbacks")
+    _check(fallbacks == 0, "mem.program_fallbacks = %d: an AOT compile "
+           "fell back to jax.jit" % fallbacks)
+    print(json.dumps({
+        "ok": True, "device": device, "phases": report,
+        "seconds": round(time.perf_counter() - t0, 1),
+        "compile_seconds": round(clock.seconds, 1),
+        "program_fallbacks": fallbacks,
+        "compile_cache": base.compile_cache_dir(),
+        "jax": jax.__version__, "jaxlib": jaxlib.__version__,
+        "libtpu": libtpu_version,
+    }))
+
+
+if __name__ == "__main__":
+    main()

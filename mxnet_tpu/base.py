@@ -15,6 +15,7 @@ from . import locks
 
 __all__ = [
     "MXNetError",
+    "compile_cache_dir",
     "get_env",
     "env_int",
     "env_bool",
@@ -31,6 +32,29 @@ class MXNetError(RuntimeError):
 
 string_types = (str,)
 numeric_types = (float, int)
+
+
+def compile_cache_dir():
+    """Place JAX's persistent compilation cache; returns the directory.
+
+    Called ONCE, from the package's __init__, before anything can
+    initialise a backend or compile.  Where ``JAX_COMPILATION_CACHE_DIR``
+    is set the caller owns the placement: JAX reads the variable itself
+    and this sets nothing in code.  Otherwise the cache is
+    ``<checkout>/.jax_cache`` — a fixed path derived from the package's
+    location (the path is part of the cache key, so a temporary or
+    per-process directory would never hit), git-ignored, and the same
+    for every child process that imports this checkout."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def get_env(name, default=None):

@@ -7,9 +7,12 @@ reference ABI values (cpu=1, gpu=2, cpu_pinned=3) with tpu=4 appended.
 
 TPU-first notes:
   * There is no per-device stream/worker state here — XLA/PJRT owns streams.
-  * `gpu()` is accepted for API compatibility and resolves to the best
-    available accelerator so reference scripts run unmodified
-    (SURVEY.md §7 north star).
+  * `gpu()` is accepted for API compatibility and resolves to the
+    accelerator of the default backend so reference scripts run unmodified
+    on a TPU host (SURVEY.md §7 north star).
+  * A context names a device or raises: `tpu(i)`/`gpu(i)` on a host with
+    no accelerator, or any `device_id` past the end of the device list,
+    is an MXNetError — never a quiet substitute device.
 """
 from __future__ import annotations
 
@@ -17,8 +20,10 @@ import threading
 
 import jax
 from . import locks
+from .base import MXNetError
 
-__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_tpus", "num_gpus"]
+__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_tpus",
+           "num_gpus", "default_device"]
 
 
 class Context:
@@ -82,33 +87,39 @@ class Context:
     # TPU-native: resolve to a concrete jax.Device.
     # ------------------------------------------------------------------
     def jax_device(self):
-        """Resolve this context to a `jax.Device`.
+        """Resolve this context to a `jax.Device`, or raise.
 
         'tpu'/'gpu' resolve to the default-backend accelerator (on a TPU
         machine both give the TPU chip, so reference gpu scripts run as-is);
-        'cpu'/'cpu_pinned' resolve to a host CPU device.
-        """
-        dtype = self.device_type
-        if dtype in ("cpu", "cpu_pinned"):
-            devs = _cpu_devices()
+        'cpu'/'cpu_pinned' resolve to a host CPU device.  A context that
+        names no device of this process — an accelerator on a CPU-only
+        host, a device_id past the end — raises MXNetError with what
+        `jax.local_devices()` holds.  device_id indexes THIS process's
+        devices: in a multi-process mesh each rank's tpu(0) is its own
+        first chip (the reference's per-host gpu(i))."""
+        if self.device_type in ("cpu", "cpu_pinned"):
+            try:
+                devs = jax.local_devices(backend="cpu")
+            except RuntimeError:  # JAX_PLATFORMS excludes the cpu backend
+                devs = []
         else:
-            devs = _accel_devices()
-        if not devs:
-            devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+            devs = [d for d in jax.local_devices() if d.platform != "cpu"]
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "context %s names no device: this process has %d %s "
+                "device(s); jax.local_devices() = %s"
+                % (self, len(devs),
+                   "cpu" if self.device_type.startswith("cpu")
+                   else "accelerator", jax.local_devices()))
+        return devs[self.device_id]
 
 
-def _cpu_devices():
-    try:
-        return jax.devices("cpu")
-    except RuntimeError:
-        return jax.devices()
-
-
-def _accel_devices():
-    devs = jax.devices()
-    accel = [d for d in devs if d.platform != "cpu"]
-    return accel if accel else devs
+def default_device():
+    """The device JAX computes on when nothing is committed elsewhere
+    (uncommitted arrays land here): this process's first device of the
+    default backend, unless jax_default_device says otherwise."""
+    d = jax.config.jax_default_device
+    return d if isinstance(d, jax.Device) else jax.local_devices(backend=d)[0]
 
 
 # module-level default context (parity: context.py current_context)
@@ -131,7 +142,7 @@ def tpu(device_id=0):
 
 
 def num_tpus():
-    return len([d for d in jax.devices() if d.platform != "cpu"])
+    return len([d for d in jax.local_devices() if d.platform != "cpu"])
 
 
 def num_gpus():

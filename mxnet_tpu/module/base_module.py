@@ -169,12 +169,26 @@ class BaseModule:
             return 0.0
         return group.execs[0].flops_per_step(is_train=True)
 
+    def _peak_flops(self):
+        """Peak FLOP/s of the device(s) the bound executor computes on
+        (chips x per-chip peak), or None when there is no executor or
+        the device has no known peak (telemetry.peak_flops)."""
+        from .. import telemetry
+
+        group = getattr(self, "_exec_group", None)
+        if group is None or not getattr(group, "execs", None):
+            return None
+        devices = group.execs[0].devices()
+        peak = telemetry.peak_flops(devices[0])
+        return peak * len(devices) if peak else None
+
     def _observe_steps(self, elapsed, steps):
         """Telemetry for one training dispatch covering `steps` steps:
         step-time histogram, the global step counter, and the per-step
-        MFU gauge (bound symbol FLOPs / measured time / hardware peak,
-        tools/tpu_constants.py).  Call sites guard with
-        telemetry.enabled() so the disabled path never even times."""
+        MFU gauge (bound symbol FLOPs / measured time / the peak of the
+        device the executor runs on, telemetry.PEAK_FLOPS — not
+        published on a device with no known peak).  Call sites guard
+        with telemetry.enabled() so the disabled path never even times."""
         from .. import telemetry
 
         if not telemetry.enabled():
@@ -183,11 +197,12 @@ class BaseModule:
         telemetry.inc("module.steps", steps)
         telemetry.set_gauge("module.step_ms", elapsed * 1e3)
         flops = self._flops_per_step()
-        if flops > 0.0 and elapsed > 0.0:
+        peak = self._peak_flops()
+        if peak and flops > 0.0 and elapsed > 0.0:
             # clamp: the analytic count is approximate (bwd = 2x fwd by
             # convention), and MFU > 1 would only ever mean "count was
             # high", never "hardware beat its peak"
-            mfu = min(1.0, flops * steps / elapsed / telemetry.peak_flops())
+            mfu = min(1.0, flops * steps / elapsed / peak)
             telemetry.set_gauge("module.mfu", mfu)
 
     def _run_epoch(self, train_data, epoch, eval_metric, batch_end_callback,

@@ -49,20 +49,17 @@ def _split_input_slice(batch_size, work_load_list):
 
 
 def _make_mesh(contexts):
-    """Build a 1-D 'data' mesh over the resolved jax devices of `contexts`."""
-    import jax
+    """Build a 1-D 'data' mesh over the resolved jax devices of `contexts`
+    (None for a single context).  Two contexts that resolve to one device
+    raise: running N-way data parallelism on fewer devices under the
+    contexts' names would misreport where the job ran."""
     from jax.sharding import Mesh
 
-    devices = []
-    seen = set()
-    for ctx in contexts:
-        d = ctx.jax_device()
-        if id(d) in seen:
-            # same physical device requested twice (e.g. cpu(0), cpu(1) on a
-            # 1-device host): fall back to single-device execution
-            return None
-        seen.add(id(d))
-        devices.append(d)
+    devices = [ctx.jax_device() for ctx in contexts]
+    if len(set(devices)) != len(devices):
+        raise MXNetError(
+            "contexts %s resolve to %s: each context of a data-parallel "
+            "group must name a distinct device" % (list(contexts), devices))
     if len(devices) <= 1:
         return None
     return Mesh(_np.array(devices), ("data",))

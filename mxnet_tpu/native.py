@@ -3,13 +3,16 @@
 The runtime's host-side hot paths are C++ (SURVEY.md requirement: native
 components for the IO/runtime layer, like the reference's dmlc-core/C++
 iterators).  The shared object is built on demand with g++ the first time
-it's needed and cached next to the package; `setup.py build_native` does
-the same ahead of time.  Pure-Python fallbacks keep everything working if
-no toolchain is present.
+it's needed and cached next to the package (mxnet_tpu/_native/, git-ignored:
+a checkout builds from src/*.cc, nothing built is committed);
+`setup.py build_native` does the same ahead of time.  Pure-Python fallbacks
+keep everything working if no toolchain is present — a failed build is
+logged once with the compiler's stderr, then the fallback runs.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -32,9 +35,32 @@ def _build(name, sources, extra=()):
         os.path.getmtime(out) >= os.path.getmtime(s) for s in srcs
     ):
         return out
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", out] + srcs + list(extra)
-    subprocess.run(cmd, check=True, capture_output=True)
+    # link to a private name, then rename: another process building the
+    # same library (a launcher's ranks on a fresh checkout) never loads
+    # a half-written file
+    tmp = "%s.%d.tmp" % (out, os.getpid())
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-o", tmp] + srcs + list(extra)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
     return out
+
+
+_FAILED = set()
+
+
+def _log_build_failure(name, err):
+    """One warning per library per process."""
+    if name in _FAILED:
+        return
+    _FAILED.add(name)
+    detail = err.stderr if isinstance(err, subprocess.CalledProcessError) \
+        else repr(err)
+    logging.warning("native library %r unavailable, using the Python "
+                    "fallback: %s", name, (detail or "").strip())
 
 
 def _load(name, sources, extra=()):
@@ -45,7 +71,10 @@ def _load(name, sources, extra=()):
             # mxlint: disable=E009 -- build-once gate: concurrent first-callers must wait for ONE g++ run
             path = _build(name, sources, extra)
             lib = ctypes.CDLL(path)
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            # no g++ (FileNotFoundError), a compile/link error, or a
+            # library the loader rejects
+            _log_build_failure(name, e)
             lib = None
         _LIB[name] = lib
         return lib
@@ -103,7 +132,8 @@ def _embedded_lib_path(name, sources):
             with open(flags_path, "w") as f:
                 f.write(flags)
             return path
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            _log_build_failure(name, e)
             return None
 
 

@@ -480,35 +480,35 @@ def inject_oom(site_substr):
 # ----------------------------------------------------------------------
 # byte-budget admission
 # ----------------------------------------------------------------------
-_DEVICE_LIMIT = -1  # unresolved sentinel (device query is one-shot)
+_DEVICE_LIMIT = {}  # jax.Device -> bytes_limit or None (one query each)
 
 
-def _device_limit():
-    global _DEVICE_LIMIT
-    if _DEVICE_LIMIT == -1:
-        limit = None
-        try:
-            import jax
+def _device_limit(device=None):
+    """``memory_stats()["bytes_limit"]`` of `device` (default: the device
+    JAX computes on when nothing is placed) — None where the backend
+    reports no limit (XLA:CPU)."""
+    if device is None:
+        from ..context import default_device
 
-            stats = jax.devices()[0].memory_stats()
-            if stats:
-                limit = int(stats.get("bytes_limit", 0)) or None
-        except Exception:
-            limit = None
-        _DEVICE_LIMIT = limit
-    return _DEVICE_LIMIT
+        device = default_device()
+    if device not in _DEVICE_LIMIT:
+        stats = device.memory_stats()
+        _DEVICE_LIMIT[device] = (
+            int(stats.get("bytes_limit", 0)) or None) if stats else None
+    return _DEVICE_LIMIT[device]
 
 
-def budget_bytes():
+def budget_bytes(device=None):
     """The admission budget: ``MXTPU_MEM_BUDGET_MB`` when set (> 0),
-    else the platform-queried device memory (``memory_stats()``
-    bytes_limit — None on XLA:CPU), else None = unlimited."""
+    else the memory limit of `device` — the device the tenant is bound
+    to; default JAX's default device — as the platform reports it
+    (None on XLA:CPU), else None = unlimited."""
     from .. import config
 
     mb = config.get("MXTPU_MEM_BUDGET_MB")
     if mb:
         return int(mb) << 20
-    return _device_limit()
+    return _device_limit(device)
 
 
 def headroom_bytes():
@@ -519,15 +519,16 @@ def headroom_bytes():
     return budget - live_bytes()
 
 
-def admit(what, predicted_bytes):
-    """Preflight `predicted_bytes` for `what` against the budget: raise
+def admit(what, predicted_bytes, device=None):
+    """Preflight `predicted_bytes` for `what` against the budget of
+    `device` (the jax.Device the tenant is bound to): raise
     :class:`MemoryBudgetError` naming predicted vs available when it
     does not fit (the add_tenant gate — refuse at admission, not OOM
     mid-traffic).  Returns the predicted bytes for booking."""
     from .. import telemetry
 
     predicted = int(predicted_bytes)
-    budget = budget_bytes()
+    budget = budget_bytes(device)
     if budget is not None:
         live = live_bytes()
         if live + predicted > budget:
@@ -633,7 +634,7 @@ def reset():
     """Test helper: clear the census, the footprint table, the peak
     tracker, and any armed injection.  Live Program objects keep their
     executables but re-register footprints on their next compile."""
-    global _LIVE_TOTAL, _PEAK, _BOOKS, _INJECT, _DEVICE_LIMIT
+    global _LIVE_TOTAL, _PEAK, _BOOKS, _INJECT
     with _CENSUS_LOCK:
         _LIVE.clear()
         _LIVE_TOTAL = 0
@@ -643,5 +644,5 @@ def reset():
         _FOOTPRINTS.clear()
         _SITE_BYTES.clear()
     _INJECT = None
-    _DEVICE_LIMIT = -1
+    _DEVICE_LIMIT.clear()
     _LAST_POSTMORTEM[0] = None

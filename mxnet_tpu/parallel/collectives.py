@@ -33,35 +33,16 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# the replication-checking kwarg was renamed check_rep -> check_vma
-# across jax releases; resolve ONCE so every shard_map call site in the
-# framework stays version-portable (this fixed 35 real test failures)
-import inspect as _inspect
-
-_CHECK_KW = ("check_rep" if "check_rep"
-             in _inspect.signature(shard_map).parameters else "check_vma")
+from jax import shard_map
 
 
 def shard_map_unchecked(f, mesh, in_specs, out_specs):
-    """shard_map with the replication check disabled, under whichever
-    keyword this jax spells it (check_rep / check_vma)."""
+    """shard_map with the replication (varying-manual-axes) check off."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{_CHECK_KW: False})
+                     check_vma=False)
 
 
-def axis_size(axis_name):
-    """Static size of a mapped mesh axis, on every jax this framework
-    targets: newer releases expose lax.axis_size; older ones fold
-    lax.psum(1, axis) to the same static int.  (Portability shim like
-    shard_map_unchecked — this fixed real test failures.)"""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+axis_size = lax.axis_size  # static size of a mapped mesh axis
 
 
 __all__ = ["allreduce", "allgather", "reduce_scatter", "alltoall", "ring_permute",

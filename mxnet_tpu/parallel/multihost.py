@@ -71,22 +71,8 @@ def initialize(coordinator=None, num_processes=None, process_id=None,
         process_id = int(os.environ.get(
             "MXTPU_PROCESS_ID", os.environ.get("DMLC_WORKER_ID", "0")))
     if num_processes > 1 or coordinator is not None:
-        # the CPU backend ships no cross-process collectives by default
-        # ("Multiprocess computations aren't implemented on the CPU
-        # backend"): select the gloo implementation so a localhost
-        # "DCN" of CPU processes can all-reduce.  Set UNCONDITIONALLY —
-        # the knob only governs the CPU backend (TPU/GPU jobs ignore
-        # it), and gating on JAX_PLATFORMS=='cpu' missed every CPU host
-        # that never set the env var — but never clobber an
-        # implementation the user already chose (e.g. 'mpi')
-        try:
-            cur = getattr(jax.config, "jax_cpu_collectives_implementation",
-                          None)
-            if cur in (None, "", "none"):
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
-        except Exception:  # pragma: no cover — older jaxlib
-            pass
+        # a localhost "DCN" of CPU processes all-reduces over gloo, the
+        # default jax_cpu_collectives_implementation (TPU jobs ignore it)
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
                                    process_id=process_id)

@@ -28,6 +28,7 @@ discipline as the obs watchdog's compile bracket).
 """
 from __future__ import annotations
 
+import os
 import socket
 import threading
 import time
@@ -88,6 +89,22 @@ def _serving_extract(tenants=()):
     }
 
 
+def _check_one_chip():
+    """A replica that ``launch.py --serve-replicas`` bound to one chip
+    (TPU_CHIPS_PER_PROCESS_BOUNDS=1,1,1) must see exactly that chip:
+    seeing more means the binding did not take and this process holds
+    chips its sibling replicas need."""
+    import jax
+
+    if (os.environ.get("TPU_CHIPS_PER_PROCESS_BOUNDS") == "1,1,1"
+            and jax.default_backend() == "tpu"
+            and jax.local_device_count() != 1):
+        raise MXNetError(
+            "replica was launched on one chip (TPU_VISIBLE_CHIPS=%s) but "
+            "sees %d: %s" % (os.environ.get("TPU_VISIBLE_CHIPS"),
+                             jax.local_device_count(), jax.local_devices()))
+
+
 class ReplicaAgent:
     """Serve one ModelServer to remote routers (module docstring).
 
@@ -102,7 +119,20 @@ class ReplicaAgent:
                  generative=None):
         from .. import config
 
+        _check_one_chip()
         self._tenants = dict(tenants)
+        # what this replica's tenants compute on, as JAX reports it —
+        # rides every HEALTH reply so a router-side report can name the
+        # device without the router process touching JAX
+        import jax
+
+        from ..context import default_device
+
+        dev = next((p._ctx.jax_device() for p in self._tenants.values()),
+                   None) or default_device()
+        self._device = {"platform": dev.platform,
+                        "device_kind": dev.device_kind,
+                        "device_count": jax.local_device_count()}
         # generative tenants: name -> {"model": lm, "params": {...},
         # **add_generative_tenant kwargs}; re-registered on every server
         # (re)construction (the rebucket swap included)
@@ -342,6 +372,7 @@ class ReplicaAgent:
             health = self._server.health()
         health["replica"] = self.replica_id
         health["name"] = self.name
+        health["device"] = self._device
         health["serving"] = _serving_extract(health.get("tenants", ()))
         wire.send(conn, wire.HEALTH_R, lock=send_lock, **health)
 

@@ -27,7 +27,9 @@ decode rows write into (duplicate scatter indices there are harmless
 garbage).  The rings thread FUNCTIONALLY through every program call —
 caches in, updated caches out — which on TPU rides the serve program's
 donated input tuple (in-place update), and on CPU costs one buffer
-copy per step.
+copy per step.  Donation deletes the buffers passed in, so every call
+REPLACES the rings it was given with the ones it got back; warm-up runs
+on throwaway rings of its own and never touches the live ones.
 
 Retirement (EOS, token budget, or ring-full) resolves the request's
 future with a :class:`GenerateResult` and frees the slot under
@@ -247,32 +249,42 @@ class GenerativeSession:
         """Compile-and-run every prefill sequence bucket and decode
         batch bucket with dummy fills (ModelServer.warmup calls this;
         `buckets` — the server's BATCH ladder — is ignored: generative
-        programs bucket by sequence length and session count)."""
+        programs bucket by sequence length and session count).  The
+        fills thread throwaway rings: a re-warm beside live traffic must
+        neither donate nor overwrite the rings the batcher holds."""
+        rings = [_np.zeros(self._cache_shape, _np.float32)
+                 for _ in self._cache_names]
         n = 0
         for t in self._seq_ladder:
             exe, fn = self._program(self._prefill_pred, 1, t, True)
-            self._run(exe, fn, _np.zeros((1, t), _np.float32),
-                      _np.full((1,), self._slots, _np.float32),
-                      _np.ones((1,), _np.float32), commit=False)
+            _, rings = self._call(exe, fn, rings,
+                                  _np.zeros((1, t), _np.float32),
+                                  _np.full((1,), self._slots, _np.float32),
+                                  _np.ones((1,), _np.float32))
             n += 1
         for b in self._decode_ladder:
             exe, fn = self._program(self._decode_pred, b, 1, False)
-            self._run(exe, fn, _np.zeros((b, 1), _np.float32),
-                      _np.full((b,), self._slots, _np.float32),
-                      _np.zeros((b,), _np.float32), commit=False)
+            _, rings = self._call(exe, fn, rings,
+                                  _np.zeros((b, 1), _np.float32),
+                                  _np.full((b,), self._slots, _np.float32),
+                                  _np.zeros((b,), _np.float32))
             n += 1
         return n
 
-    def _run(self, exe, fn, data, slot, length, commit=True):
-        """One program call threading the rings through.  `commit=False`
-        (warmup) runs against the rings but DISCARDS the updated caches
-        — dummy fills target the scratch slot anyway."""
+    def _call(self, exe, fn, rings, data, slot, length):
+        """One program call threading `rings` through: returns (host
+        logits, updated rings).  The rings passed in are donated on
+        device backends — the caller keeps only what comes back."""
         other_vals, aux_vals = exe.serve_args(self._input_names)
-        ins = tuple([data, slot, length] + list(self._caches))
+        ins = tuple([data, slot, length] + list(rings))
         outs = fn(ins, other_vals, aux_vals, _np.uint32(0))
-        logits = _np.asarray(outs[0])
-        if commit:
-            self._caches = list(outs[1:])
+        return _np.asarray(outs[0]), list(outs[1:])
+
+    def _run(self, exe, fn, data, slot, length):
+        """One LIVE program call: the session's rings go in, the updated
+        rings replace them; returns the host logits."""
+        logits, self._caches = self._call(exe, fn, self._caches, data,
+                                          slot, length)
         return logits
 
     # ------------------------------------------------------------------

@@ -27,6 +27,7 @@ import threading
 import time
 
 from ..base import MXNetError
+from ..context import current_context
 from .bucket import bucket_ladder
 from .decode import GenerateRequest, GenerativeSession
 from .request import Request, RequestQueue, ServerClosed
@@ -133,7 +134,8 @@ class ModelServer:
         # a queue lane or compiles anything
         from ..obs import memory
 
-        memory.admit("tenant %r" % name, predictor.footprint_bytes())
+        memory.admit("tenant %r" % name, predictor.footprint_bytes(),
+                     device=predictor._ctx.jax_device())
         with self._lock:
             if self._closed:
                 raise ServerClosed("cannot add tenant %r: server is closed"
@@ -196,7 +198,8 @@ class ModelServer:
         ring_bytes = ((slots + 1) * int(model.num_heads) * ring_len
                       * int(model.d_head) * 4 * len(model.cache_names()))
         memory.admit("generative tenant %r" % name,
-                     2 * param_bytes + ring_bytes)
+                     2 * param_bytes + ring_bytes,
+                     device=(ctx or current_context()).jax_device())
         # build outside the lock — Predictor construction compiles the
         # smallest prefill/decode buckets and must not stall submits
         session = GenerativeSession(

@@ -43,7 +43,8 @@ __all__ = [
     "observe_values", "attach_value_histogram", "ValueHistogram",
     "counter_value", "gauge_value", "histogram_moments",
     "histogram_quantile", "snapshot", "reset", "flush",
-    "rank_suffixed", "note_retrace", "peak_flops", "flops_of_jaxpr",
+    "rank_suffixed", "note_retrace", "PEAK_FLOPS", "peak_flops",
+    "flops_of_jaxpr",
     "TIME_BUCKETS", "BYTE_BUCKETS", "COUNT_BUCKETS",
 ]
 
@@ -596,13 +597,20 @@ def flush(path=None, extra=None):
 # MFU support: hardware peak + an analytic FLOP counter over jaxprs
 # ----------------------------------------------------------------------
 
-def peak_flops():
-    """Accelerator peak FLOP/s for MFU math — ``MXTPU_PEAK_FLOPS`` when
-    set to a positive number, else the shared v5e constant
-    (tools/tpu_constants.py, the same source the bench table and
-    scaling model use).  A malformed override is warned about ONCE and
-    ignored — a typo'd env var must not kill the training loop from a
-    telemetry call."""
+# Published dense bf16 peak FLOP/s per chip (MAC=2 convention), keyed by
+# jax ``device_kind`` — the ONE table MFU math divides by.  A device that
+# is not here has no peak: nothing substitutes another chip's.
+#   "TPU v5 lite": Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16
+PEAK_FLOPS = {"TPU v5 lite": 197e12}
+
+
+def peak_flops(device=None):
+    """Peak FLOP/s for MFU math on `device` (default: JAX's default
+    device) — ``MXTPU_PEAK_FLOPS`` when set to a positive number, else
+    the :data:`PEAK_FLOPS` entry for its ``device_kind``, else None: an
+    unknown device has no MFU.  A malformed override is warned about
+    ONCE and ignored — a typo'd env var must not kill the training loop
+    from a telemetry call."""
     raw = _os.environ.get("MXTPU_PEAK_FLOPS", "")
     if raw:
         try:
@@ -614,22 +622,15 @@ def peak_flops():
                 _BAD_PEAK_WARNED.add(raw)
                 import warnings
 
-                warnings.warn("MXTPU_PEAK_FLOPS=%r is not a number; using "
-                              "the v5e default for the MFU gauge" % raw)
-    global _DEFAULT_PEAK
-    if _DEFAULT_PEAK is None:
-        # resolved once: a FAILED import is not cached by sys.modules,
-        # and this runs per training dispatch via the MFU gauge
-        try:
-            from tools.tpu_constants import V5E_PEAK_FLOPS
+                warnings.warn("MXTPU_PEAK_FLOPS=%r is not a number; "
+                              "ignored" % raw)
+    if device is None:
+        from .context import default_device
 
-            _DEFAULT_PEAK = float(V5E_PEAK_FLOPS)
-        except ImportError:  # installed without the tools/ tree
-            _DEFAULT_PEAK = 197e12
-    return _DEFAULT_PEAK
+        device = default_device()
+    return PEAK_FLOPS.get(device.device_kind)
 
 
-_DEFAULT_PEAK = None
 _BAD_PEAK_WARNED = set()
 
 

@@ -160,14 +160,19 @@ def main():
     if not args.no_fit:
         fit = run_fit_transformer if args.transformer else run_fit
         losses, digest = fit(mx, np, mesh, args.steps_per_dispatch)
-        # ONE unbuffered write: both ranks share the launcher's stdout
-        # pipe, and separate print() writes from two processes can
-        # interleave mid-line (single writes under PIPE_BUF are atomic)
-        sys.stdout.write("SPMDFIT rank=%d axes=%s losses=%s digest=%s\n"
-                         % (rank, ",".join(mesh.axis_names),
-                            ";".join("%.6f" % l for l in losses),
-                            ";".join("%.6f" % v for v in digest)))
-        sys.stdout.flush()
+        # the ranks share the launcher's stdout pipe and a record (a
+        # full parameter digest) is far larger than PIPE_BUF, so two
+        # concurrent writes interleave mid-record: take turns, one rank
+        # per barrier
+        record = ("SPMDFIT rank=%d axes=%s losses=%s digest=%s\n"
+                  % (rank, ",".join(mesh.axis_names),
+                     ";".join("%.6f" % l for l in losses),
+                     ";".join("%.6f" % v for v in digest)))
+        for turn in range(jax.process_count()):
+            if turn == rank:
+                sys.stdout.write(record)
+                sys.stdout.flush()
+            multihost.sync_global_devices("spmd_fit_record_%d" % turn)
     else:
         sys.stdout.write("SPMDMESH rank=%d axes=%s devices=%d\n"
                          % (rank, ",".join(mesh.axis_names),

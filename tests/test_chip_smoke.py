@@ -1,0 +1,234 @@
+"""Bring-up pins (PR 21): chip_smoke.py's phases at tiny sizes on the CPU
+mesh, contexts that name a device or raise, per-context placement, the
+compile-cache resolver, and the launcher's one-process-per-chip
+environment.  All CPU; ~25 s together."""
+import os
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import base, telemetry
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+sys.path.insert(0, os.path.join(ROOT, "tools"))
+
+import chip_smoke  # noqa: E402
+import launch  # noqa: E402
+
+TINY = {
+    "fence": {"n": 128, "chain": 2, "reps": 1},
+    "train": {"depth": 18, "image": 32, "classes": 10, "batch": 8, "k": 2,
+              "blocks": 2, "seed": 0},
+    "serve": {"depth": 18, "image": 32, "classes": 10, "buckets": [2, 4],
+              "requests": 8, "threads": 2, "wait_ms": 5.0, "seed": 1},
+    "generate": {"vocab": 32, "num_layers": 2, "num_heads": 2, "d_model": 32,
+                 "max_len": 48, "max_sessions": 2, "seq_buckets": [8, 16],
+                 "prompts": 3, "new_tokens": 4, "check_steps": 3, "seed": 2},
+    "kernel": {"shapes": [(4, 4, 4, 64), (8, 2, 2, 256)], "seed": 3},
+    "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
+                   "steps": 2, "seed": 4},
+}
+
+
+# ----------------------------------------------------------------------
+# chip_smoke.py
+# ----------------------------------------------------------------------
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    """On a CPU-only JAX it exits non-zero with one line, before any
+    work, and prints no result."""
+    proc = subprocess.run([sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                          capture_output=True, text=True, timeout=120,
+                          cwd=ROOT)
+    assert proc.returncode != 0
+    assert proc.stdout == ""
+    lines = proc.stderr.strip().splitlines()
+    assert len(lines) == 1 and "not a TPU" in lines[0], proc.stderr
+
+
+def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
+    """Every phase function, tiny, on mx.cpu(2) — NOT the default device,
+    so the phases' own placement checks (parameters, optimizer state and
+    Predictor outputs on the context's device) pin per-context placement
+    for fit, serve and decode at once."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    telemetry.set_enabled(True)
+    clock = chip_smoke.CompileClock()
+    report = {}
+    ctx = mx.cpu(2)
+    for name in ("fence", "train", "serve", "generate"):
+        chip_smoke.run_phase(name, getattr(chip_smoke, "phase_" + name),
+                             TINY[name], ctx, clock, report)
+    monkeypatch.setattr(pk, "_INTERPRET", True)
+    chip_smoke.run_phase("kernel", chip_smoke.phase_kernel, TINY["kernel"],
+                         ctx, clock, report)
+    chip_smoke.run_phase("four_chips", chip_smoke.phase_four_chips,
+                         TINY["four_chips"], [mx.cpu(i) for i in range(4)],
+                         clock, report)
+    assert all(r["ok"] for r in report.values())
+    assert report["train"]["device"] == str(jax.devices()[2])
+    assert report["four_chips"]["predictor_device"] == str(jax.devices()[3])
+    assert clock.seconds > 0  # the AOT wrapper's compiles are seen
+    assert report["train"]["mfu_gauge"] is None  # CPU: no peak, no MFU
+    assert telemetry.counter_value("mem.program_fallbacks") == 0
+
+
+def test_a_failing_phase_propagates():
+    def boom(sizes, ctx):
+        chip_smoke._check(False, "boom")
+
+    report = {}
+    with pytest.raises(RuntimeError, match="boom"):
+        chip_smoke.run_phase("x", boom, {}, mx.cpu(), chip_smoke.CompileClock(),
+                             report)
+    assert report == {}
+
+
+# ----------------------------------------------------------------------
+# a context names a device or raises
+# ----------------------------------------------------------------------
+
+def test_contexts_name_a_device_or_raise():
+    assert mx.cpu(7).jax_device() == jax.devices()[7]
+    for ctx in (mx.tpu(0), mx.gpu(0), mx.cpu(9), mx.cpu(-1)):
+        with pytest.raises(mx.MXNetError, match="names no device"):
+            ctx.jax_device()
+
+
+def test_make_mesh_raises_on_a_duplicated_device():
+    from mxnet_tpu.module.executor_group import _make_mesh
+
+    assert _make_mesh([mx.cpu(0)]) is None
+    assert _make_mesh([mx.cpu(0), mx.cpu(1)]).devices.size == 2
+    with pytest.raises(mx.MXNetError, match="distinct device"):
+        _make_mesh([mx.cpu(1), mx.cpu(1)])
+    with pytest.raises(mx.MXNetError, match="names no device"):
+        mx.mod.Module(mx.sym.Variable("data"), context=[mx.cpu(0), mx.tpu(0)]
+                      ).bind(data_shapes=[("data", (2, 2))], label_shapes=None)
+
+
+def test_executor_and_predictor_compute_on_their_context():
+    """Bound to mx.cpu(2): outputs, gradients and the (written-back)
+    parameters live on device 2, and a Predictor's bucket executors share
+    ONE placed copy of each parameter."""
+    dev = jax.devices()[2]
+    net = mx.sym.FullyConnected(mx.sym.Variable("data"), num_hidden=3,
+                                name="fc")
+    exe = net.simple_bind(mx.cpu(2), data=(4, 5))
+    exe.arg_dict["fc_weight"][:] = np.ones((3, 5), np.float32)
+    assert exe.arg_dict["fc_weight"].data.devices() != {dev}  # a label so far
+    exe.forward(is_train=True, data=np.ones((4, 5), np.float32))
+    exe.backward([mx.nd.ones((4, 3))])
+    assert exe.outputs[0].data.devices() == {dev}
+    assert exe.grad_dict["fc_weight"].data.devices() == {dev}
+    assert exe.arg_dict["fc_weight"].data.devices() == {dev}  # moved once
+    np.testing.assert_allclose(exe.outputs[0].asnumpy(), 5.0)
+    # the default device needs no placement and gets none
+    exe0 = net.simple_bind(mx.cpu(0), data=(4, 5))
+    assert exe0._device is None and exe._device == dev
+
+    params = {"arg:fc_weight": mx.nd.ones((3, 5)), "arg:fc_bias": mx.nd.zeros((3,))}
+    pred = mx.Predictor(net, params, {"data": (1, 5)}, ctx=mx.cpu(2))
+    pred.forward(data=np.ones((1, 5), np.float32))
+    assert pred._exec.outputs[0].data.devices() == {dev}
+    other = pred.executor_for({"data": (4, 5)})
+    other.forward(is_train=False, data=np.ones((4, 5), np.float32))
+    assert other.outputs[0].data.devices() == {dev}
+    assert (other.arg_dict["fc_weight"].data
+            is pred._exec.arg_dict["fc_weight"].data)
+    pred.close()
+
+
+def test_memory_limit_is_read_from_the_tenants_device():
+    from mxnet_tpu.obs import memory
+
+    class Dev:
+        def __init__(self, limit):
+            self.limit = limit
+
+        def memory_stats(self):
+            return {"bytes_limit": self.limit}
+
+    a, b = Dev(1 << 30), Dev(2 << 30)
+    try:
+        assert memory.budget_bytes(a) == 1 << 30
+        assert memory.budget_bytes(b) == 2 << 30
+        assert memory.budget_bytes() is None  # XLA:CPU reports no limit
+        with pytest.raises(memory.MemoryBudgetError):
+            memory.admit("too big", 3 << 30, device=b)
+    finally:
+        memory._DEVICE_LIMIT.clear()
+
+
+# ----------------------------------------------------------------------
+# one compile cache, placeable from outside
+# ----------------------------------------------------------------------
+
+def test_compile_cache_dir_resolver(monkeypatch):
+    in_code = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert base.compile_cache_dir() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir == in_code  # untouched
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert base.compile_cache_dir() == os.path.join(ROOT, ".jax_cache")
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        # what import did, before any backend existed
+        assert in_code == os.path.join(ROOT, ".jax_cache")
+
+
+# ----------------------------------------------------------------------
+# one process per chip (tools/launch.py)
+# ----------------------------------------------------------------------
+
+def test_launcher_keeps_host_roles_off_the_chip():
+    for role in ("scheduler", "server"):
+        env = launch._role_env(role, {"JAX_PLATFORMS": "tpu,cpu"})
+        assert env == {"DMLC_ROLE": role, "JAX_PLATFORMS": "cpu"}
+    assert launch._role_env("worker", {}) == {"DMLC_ROLE": "worker"}
+
+
+def test_launcher_gives_each_child_one_chip_or_refuses(monkeypatch):
+    class Parser:
+        def error(self, msg):
+            raise SystemExit(msg)
+
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert launch._host_chips() == []  # a CPU job is handed no chips
+    assert launch._one_chip_envs(3, Parser(), "x") == [{}, {}, {}]
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "2,3")
+    assert launch._host_chips() == ["2", "3"]
+    envs = launch._one_chip_envs(2, Parser(), "x")
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["2", "3"]
+    assert all(e["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    assert len({e["TPU_MESH_CONTROLLER_PORT"] for e in envs}) == 2
+    with pytest.raises(SystemExit, match="one TPU chip per process"):
+        launch._one_chip_envs(3, Parser(), "--serve-replicas 3")
+
+    class Args:
+        local_devices, num_workers = 0, 2
+
+    with pytest.raises(SystemExit, match="One process drives all chips"):
+        launch._local_spmd_env(Args, Parser())
+    Args.local_devices = 2  # forced host devices: a CPU job by definition
+    assert launch._local_spmd_env(Args, Parser()) == {
+        "MXTPU_LOCAL_DEVICES": "2", "JAX_PLATFORMS": "cpu"}
+
+
+def test_pallas_bn_switch_says_so_once_off_the_tpu():
+    import warnings
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    with warnings.catch_warnings(record=True) as seen:
+        warnings.simplefilter("default")
+        for _ in range(3):  # e.g. three BN layers of one trace
+            assert not pk.bn_stats_supported((8, 4, 4, 128), -1)
+    assert len(seen) == 1 and "MXNET_TPU_PALLAS_BN" in str(seen[0].message)

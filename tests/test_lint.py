@@ -1331,10 +1331,7 @@ import functools
 import jax
 from jax import lax
 
-try:
-    from jax import shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @functools.partial(shard_map, mesh=None, in_specs=(), out_specs=())

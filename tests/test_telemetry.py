@@ -68,6 +68,9 @@ def test_fit_populates_registry_and_all_sinks(tmp_path, monkeypatch):
     JSONL epoch record and ≥2 counter lanes in the dumped trace."""
     jsonl = str(tmp_path / "fit.jsonl")
     monkeypatch.setenv("MXTPU_TELEMETRY_FILE", jsonl)
+    # the CPU device has no entry in telemetry.PEAK_FLOPS: the MFU gauge
+    # (and its trace lane) exists here only because a peak is declared
+    monkeypatch.setenv("MXTPU_PEAK_FLOPS", "1e12")
     prof = str(tmp_path / "prof.json")
     profiler.profiler_set_config(mode="all", filename=prof)
     profiler.profiler_set_state("run")
@@ -127,7 +130,9 @@ def test_fit_block_dispatch_histogram_counts_dispatches():
     assert snap["counters"]["executor.train_dispatches"] == 2
     assert snap["histograms"]["executor.dispatch_seconds.block"]["count"] == 2
     assert snap["counters"]["io.blocks_staged"] == 2
-    assert 0.0 < snap["gauges"]["module.mfu"] <= 1.0
+    # no peak is known for the CPU device and none was declared: the MFU
+    # gauge is not published (never divided by another chip's peak)
+    assert "module.mfu" not in snap["gauges"]
     # H2D counted where transfers happen and EXACTLY once per transfer:
     # per-batch nd.array creation in NDArrayIter (8 x (16,10)+(16,)) plus
     # the stage-time placement of each stacked block (2 x (4,16,10)+(4,16))
@@ -348,10 +353,16 @@ def test_executor_flops_per_step_positive():
 def test_peak_flops_env_override(monkeypatch):
     monkeypatch.setenv("MXTPU_PEAK_FLOPS", "1e12")
     assert telemetry.peak_flops() == 1e12
+    # no override: the peak is the PEAK_FLOPS entry for the device_kind,
+    # and a device that is not in the table has none
     monkeypatch.setenv("MXTPU_PEAK_FLOPS", "0")
+    assert telemetry.peak_flops() is None  # this host's CPU device
     from tools.tpu_constants import V5E_PEAK_FLOPS
 
-    assert telemetry.peak_flops() == V5E_PEAK_FLOPS
+    class V5e:
+        device_kind = "TPU v5 lite"
+
+    assert telemetry.peak_flops(V5e()) == V5E_PEAK_FLOPS == 197e12
 
 
 # ----------------------------------------------------------------------

@@ -45,6 +45,17 @@ address list is exported to every replica AND printed as one
 (or bench.py --serve --replicas N, which wraps this) can connect:
 
     python tools/launch.py --serve-replicas 4 python serve_my_model.py
+
+One process per chip.  A TPU chip belongs to one process at a time, and
+this launcher never imports JAX, so it never holds one.  Scheduler and
+server roles compute on the host and are started with JAX_PLATFORMS=cpu.
+On a host with TPU chips (counted from the device nodes, not through
+JAX) every --serve-replicas child is given exactly one chip
+(TPU_VISIBLE_CHIPS and the single-process bounds libtpu reads), and a
+fleet larger than the host's chips is refused before anything starts;
+--local-spmd ranks are CPU processes when --local-devices is given, and
+more than one rank sharing the chips is refused — one process drives
+every chip of a host (Module(context=[mx.tpu(i) ...])).
 """
 from __future__ import annotations
 
@@ -57,6 +68,77 @@ import sys
 
 # servers/scheduler block inside this import-and-serve bootstrap
 _SERVER_BOOTSTRAP = "import mxnet_tpu.kvstore_server as s; s.init_server_module()"
+
+
+def _role_env(role, env):
+    """Finish one role's environment in place; returns it.  Scheduler
+    and server roles run the optimizer on host arrays and must never
+    take a chip away from the workers: they get JAX_PLATFORMS=cpu."""
+    env["DMLC_ROLE"] = role
+    if role != "worker":
+        env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+def _host_chips():
+    """Ids of the TPU chips this launcher may hand out, found WITHOUT
+    touching JAX (a parent that initialises the TPU client holds the
+    chips its children need): the accel / vfio device nodes libtpu
+    itself enumerates, narrowed by an operator-set TPU_VISIBLE_CHIPS.
+    Empty on a host without chips or for a job pinned to the CPU."""
+    import glob
+
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        return []
+    visible = os.environ.get("TPU_VISIBLE_CHIPS")
+    if visible:
+        return [c.strip() for c in visible.split(",") if c.strip()]
+    nodes = (glob.glob("/dev/accel[0-9]*")
+             or glob.glob("/dev/vfio/[0-9]*"))
+    return [str(i) for i in range(len(nodes))]
+
+
+def _one_chip_envs(n, parser, what):
+    """Per-child environment additions binding each of `n` children to
+    exactly one chip — the variables libtpu reads to run as a
+    single-chip process beside its siblings.  Empty dicts on a host
+    without chips; more children than chips is refused up front, before
+    anything starts (a chip belongs to one process at a time)."""
+    chips = _host_chips()
+    if not chips:
+        return [{} for _ in range(n)]
+    if n > len(chips):
+        parser.error(
+            "%s needs one TPU chip per process but this host offers %d "
+            "(%s): a chip belongs to one process at a time"
+            % (what, len(chips), ",".join(chips)))
+    return [{
+        "TPU_VISIBLE_CHIPS": chips[i],
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        # each single-chip runtime needs its own controller port
+        "TPU_MESH_CONTROLLER_ADDRESS": "localhost:%d" % (8476 + i),
+        "TPU_MESH_CONTROLLER_PORT": str(8476 + i),
+    } for i in range(n)]
+
+
+def _local_spmd_env(args, parser):
+    """Platform half of a --local-spmd rank's environment: ranks given
+    --local-devices K are CPU processes by definition (K forced host
+    devices each); without it, more than one rank on a host with TPU
+    chips is refused — the first would take every chip."""
+    if args.local_devices > 0:
+        return {"MXTPU_LOCAL_DEVICES": str(args.local_devices),
+                "JAX_PLATFORMS": "cpu"}
+    if args.num_workers > 1 and _host_chips():
+        parser.error(
+            "--local-spmd -n %d on a host with TPU chips: the first rank "
+            "would take every chip and the others fail.  One process "
+            "drives all chips of a host (Module(context=[mx.tpu(i) "
+            "...])); --local-spmd joins CPU processes (give "
+            "--local-devices K) or one process per host"
+            % args.num_workers)
+    return {}
 
 
 def _routable_ip():
@@ -80,9 +162,8 @@ def _spawn_local_scheduler(base_env):
     base_env["DMLC_PS_ROOT_URI"] = _routable_ip()
     env = dict(os.environ)
     env.update(base_env)
-    env["DMLC_ROLE"] = "scheduler"
     return subprocess.Popen([sys.executable, "-c", _SERVER_BOOTSTRAP],
-                            env=env)
+                            env=_role_env("scheduler", env))
 
 
 def _free_port():
@@ -142,7 +223,7 @@ def _watch_generation(workers, poll=0.2):
         _time.sleep(poll)
 
 
-def _run_elastic(args, repo_root):
+def _run_elastic(args, repo_root, platform_env):
     """Elastic supervisor (docs/checkpoint.md "Elastic workflow"): run
     the SPMD job as a sequence of GENERATIONS.  Each generation is a
     fresh set of worker processes on a fresh coordinator; when a rank
@@ -178,8 +259,7 @@ def _run_elastic(args, repo_root):
             env["MXTPU_ELASTIC_GENERATION"] = str(generation)
             # lenient resume: an empty dir (generation 0) starts fresh
             env["MXTPU_CKPT_RESUME"] = ckpt_dir
-            if args.local_devices > 0:
-                env["MXTPU_LOCAL_DEVICES"] = str(args.local_devices)
+            env.update(platform_env)
             env["PYTHONPATH"] = (repo_root + os.pathsep
                                  + os.environ.get("PYTHONPATH", ""))
             workers.append(subprocess.Popen(args.command, env=env))
@@ -306,6 +386,9 @@ def main():
     if args.serve_replicas:
         if args.launcher != "local" or args.local_spmd:
             parser.error("--serve-replicas implies the local launcher")
+        chip_envs = _one_chip_envs(
+            args.serve_replicas, parser,
+            "--serve-replicas %d" % args.serve_replicas)
         ports = [_free_port() for _ in range(args.serve_replicas)]
         addrs = ",".join("127.0.0.1:%d" % p for p in ports)
         # the line the operator's router (and bench.py --serve
@@ -342,6 +425,7 @@ def main():
             env["MXTPU_PROCESS_ID"] = str(i + 1)
             env["PYTHONPATH"] = (repo_root + os.pathsep
                                  + os.environ.get("PYTHONPATH", ""))
+            env.update(chip_envs[i])
             procs.append(subprocess.Popen(args.command, env=env))
         rc = 0
         try:
@@ -365,7 +449,8 @@ def main():
         if args.num_servers:
             parser.error("--elastic requires -s 0 (no parameter servers)")
         args.num_servers = 0
-        sys.exit(_run_elastic(args, repo_root))
+        sys.exit(_run_elastic(args, repo_root,
+                              _local_spmd_env(args, parser)))
     if args.num_servers is None:
         args.num_servers = args.num_workers
     if args.local_spmd and args.launcher != "local":
@@ -385,8 +470,7 @@ def main():
         # DMLC port for the (optional) parameter-server control plane —
         # both on this host; each worker is one mesh process
         base_env["MXTPU_COORDINATOR"] = "127.0.0.1:%d" % _free_port()
-        if args.local_devices > 0:
-            base_env["MXTPU_LOCAL_DEVICES"] = str(args.local_devices)
+        base_env.update(_local_spmd_env(args, parser))
         if args.obs and not os.environ.get("MXTPU_OBS_PORT"):
             # a third port for the rank-0 observability aggregator
             # (obs/aggregate.py); an operator-exported port passes
@@ -397,11 +481,20 @@ def main():
 
     if args.launcher == "local":
         procs = []
+        # several parameter-server workers on one chip host: one chip
+        # each (a lone worker, or SPMD ranks, keep what _local_spmd_env
+        # decided)
+        chip_envs = None
+        if args.num_workers > 1 and not args.local_spmd:
+            chip_envs = _one_chip_envs(args.num_workers, parser,
+                                       "-n %d" % args.num_workers)
 
-        def spawn(role, rank=None):
+        def spawn(role, rank=None, index=0):
             env = dict(os.environ)
             env.update(base_env)
-            env["DMLC_ROLE"] = role
+            _role_env(role, env)
+            if role == "worker" and chip_envs:
+                env.update(chip_envs[index])
             if rank is not None:
                 env["MXTPU_PROCESS_ID"] = str(rank)
                 env["DMLC_WORKER_ID"] = str(rank)
@@ -415,7 +508,8 @@ def main():
             procs.append(spawn("scheduler"))
             for _ in range(args.num_servers):
                 procs.append(spawn("server"))
-        workers = [spawn("worker", rank=i if args.local_spmd else None)
+        workers = [spawn("worker", rank=i if args.local_spmd else None,
+                         index=i)
                    for i in range(args.num_workers)]
         rc = 0
         for p in workers:
@@ -438,8 +532,7 @@ def main():
                 # OpenMPI's mpirun takes --hostfile; MPICH's Hydra takes -f
                 flag = "--hostfile" if args.mpi_flavor == "openmpi" else "-f"
                 argv += [flag, args.hostfile]
-            env = dict(base_env)
-            env["DMLC_ROLE"] = role
+            env = _role_env(role, dict(base_env))
             if args.mpi_flavor == "openmpi":
                 for k, v in env.items():
                     argv += ["-x", "%s=%s" % (k, v)]
@@ -483,8 +576,7 @@ def main():
             scripts.append(script.name)
             lines = ["#!/bin/sh"]
             lines += ["export %s=%s" % (k, shlex.quote(v))
-                      for k, v in base_env.items()]
-            lines.append("export DMLC_ROLE=%s" % role)
+                      for k, v in _role_env(role, dict(base_env)).items()]
             lines.append("exec %s" % " ".join(shlex.quote(c) for c in cmd))
             script.write("\n".join(lines) + "\n")
             script.close()
@@ -536,8 +628,8 @@ def main():
     procs = []
 
     def ssh_spawn(host, role):
-        env_str = " ".join("%s=%s" % (k, v) for k, v in base_env.items())
-        env_str += " DMLC_ROLE=%s" % role
+        env_str = " ".join("%s=%s" % (k, v) for k, v in
+                           _role_env(role, dict(base_env)).items())
         if role != "worker":
             remote = "python -c %r" % _SERVER_BOOTSTRAP
         else:

@@ -1,7 +1,10 @@
-"""Shared TPU v5e hardware constants — ONE source for the benchmark
-table's MFU (tools/benchmark_score.py, bench.py docs) and the scaling
-model's efficiency math (tools/scaling_model.py, SCALING.md)."""
+"""Shared TPU v5e hardware constants for the analytic tools
+(tools/benchmark_score.py, tools/mfu_decompose.py, tools/scaling_model.py,
+SCALING.md).  The compute peak is read from the package's one table
+(mxnet_tpu.telemetry.PEAK_FLOPS, keyed by device_kind, source named
+there); the bandwidths are this model's assumptions."""
+from mxnet_tpu.telemetry import PEAK_FLOPS
 
-V5E_PEAK_FLOPS = 197e12   # bf16 peak, MAC=2 convention on both sides
+V5E_PEAK_FLOPS = PEAK_FLOPS["TPU v5 lite"]   # bf16 peak, MAC=2 convention
 V5E_ICI_BW = 90e9         # B/s per chip effective all-reduce bandwidth
 V5E_DCN_BW = 6.25e9       # B/s per chip (50 Gbps NIC) for cross-pod DP
