@@ -29,11 +29,13 @@ apart from the rest:
             Predictor bound to chip 3, in this same process
 
 It exits non-zero, before any work, when JAX's default backend is not a
-TPU, and prints as the last line of stdout one JSON object with
-"ok": true and the device as JAX reports it only when every phase ran
-and passed.  The phase functions take (sizes, ctx) so the tier-1 tests
-(tests/test_chip_smoke.py) call them tiny on mx.cpu(); main() has no
-switch that skips the device check.
+TPU.  Only when every phase ran and passed does it print, as the last
+line of stdout, the result: one JSON object with exactly the keys "ok"
+(true) and "device" ({"platform", "kind", "count"} as JAX reports them).
+The line before it, "[chip_smoke] report {...}", carries the per-phase
+seconds, compile seconds and facts.  The phase functions take
+(sizes, ctx) so the tier-1 tests (tests/test_chip_smoke.py) call them
+tiny on mx.cpu(); main() has no switch that skips the device check.
 """
 import json
 import sys
@@ -563,15 +565,17 @@ def main():
     fallbacks = telemetry.counter_value("mem.program_fallbacks")
     _check(fallbacks == 0, "mem.program_fallbacks = %d: an AOT compile "
            "fell back to jax.jit" % fallbacks)
-    print(json.dumps({
-        "ok": True, "device": device, "phases": report,
+    print("[chip_smoke] report " + json.dumps({
+        "phases": report,
         "seconds": round(time.perf_counter() - t0, 1),
         "compile_seconds": round(clock.seconds, 1),
         "program_fallbacks": fallbacks,
         "compile_cache": base.compile_cache_dir(),
         "jax": jax.__version__, "jaxlib": jaxlib.__version__,
         "libtpu": libtpu_version,
-    }))
+    }), flush=True)
+    # the result line: exactly these keys, and the last thing on stdout
+    print(json.dumps({"ok": True, "device": device}), flush=True)
 
 
 if __name__ == "__main__":

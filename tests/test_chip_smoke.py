@@ -91,6 +91,35 @@ def test_a_failing_phase_propagates():
     assert report == {}
 
 
+def test_the_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
+    """main() past the device check (backend and context stubbed, phases
+    empty): the LAST stdout line is {"ok", "device": {"platform", "kind",
+    "count"}} and nothing else — the driver refuses any other key there;
+    the per-phase report rides the line before it."""
+    import json
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mx, "tpu", lambda i=0: mx.cpu(i))
+    for name in ("fence", "train", "serve", "generate", "kernel"):
+        monkeypatch.setattr(chip_smoke, "phase_" + name, lambda s, c: {})
+    monkeypatch.setattr(chip_smoke, "phase_four_chips",
+                        lambda s, c: {"predictor_device": "x"})
+    chip_smoke.main()
+    lines = capsys.readouterr().out.strip().splitlines()
+    result = json.loads(lines[-1])
+    assert set(result) == {"ok", "device"} and result["ok"] is True
+    device = result["device"]
+    assert set(device) == {"platform", "kind", "count"}
+    assert device == {"platform": jax.devices()[0].platform,
+                      "kind": jax.devices()[0].device_kind,
+                      "count": len(jax.devices())}
+    assert isinstance(device["count"], int)
+    assert lines[-2].startswith("[chip_smoke] report ")
+    report = json.loads(lines[-2][len("[chip_smoke] report "):])
+    assert set(report["phases"]) == {"fence", "train", "serve", "generate",
+                                     "kernel", "four_chips"}
+
+
 # ----------------------------------------------------------------------
 # a context names a device or raises
 # ----------------------------------------------------------------------
