@@ -1,0 +1,57 @@
+"""The load generator's schedule is a pure function of the file and the
+seed."""
+import numpy as np
+
+from benchmarks.harness import loadgen
+
+SPEC = {"prompt_len": {"median": 128, "sigma": 0.8, "min": 16, "max": 512},
+        "output_len": {"median": 64, "sigma": 0.7, "min": 16, "max": 256}}
+
+
+def _requests(seed, n):
+    lst = loadgen.RequestList(SPEC, 50272, seed)
+    return [lst[i] for i in range(n)]
+
+
+def _same(a, b):
+    return a.budget == b.budget and np.array_equal(a.prompt, b.prompt)
+
+
+def test_same_seed_same_requests_whatever_the_count():
+    few = _requests(3, 20)
+    many = _requests(3, 200)
+    assert all(_same(a, b) for a, b in zip(few, many))
+    other = _requests(4, 20)
+    assert not all(_same(a, b) for a, b in zip(few, other))
+
+
+def test_lengths_keep_to_the_files_limits():
+    reqs = _requests(0, 500)
+    plen = np.array([len(r.prompt) for r in reqs])
+    olen = np.array([r.budget for r in reqs])
+    assert plen.min() >= 16 and plen.max() <= 512
+    assert olen.min() >= 16 and olen.max() <= 256
+    assert 100 < np.median(plen) < 160 and 50 < np.median(olen) < 80
+    assert all(r.prompt.dtype == np.int32 and r.prompt.max() < 50272
+               for r in reqs)
+
+
+def test_arrivals_repeat_and_every_seed_sends_the_same_number():
+    a = loadgen.arrival_times(5.0, 400.0, 7)
+    b = loadgen.arrival_times(5.0, 400.0, 7)
+    assert np.array_equal(a, b) and np.all(np.diff(a) >= 0)
+    assert 0 < a[0] and a[-1] < 400.0
+    assert len(a) == 2000 == len(loadgen.arrival_times(5.0, 400.0, 8))
+    assert not np.array_equal(a, loadgen.arrival_times(5.0, 400.0, 8))
+    gaps = np.diff(a)
+    assert abs(gaps.std() / gaps.mean() - 1.0) < 0.25   # Poisson
+
+
+def test_every_seed_offers_nearly_the_same_work():
+    totals = []
+    for seed in range(6):
+        reqs = _requests(seed, 2 * loadgen.BLOCK)
+        totals.append((sum(len(r.prompt) for r in reqs),
+                       sum(r.budget for r in reqs)))
+    totals = np.asarray(totals, float)
+    assert np.all(totals.std(axis=0) / totals.mean(axis=0) < 0.01)
