@@ -24,7 +24,7 @@ The JSON line reports `dispatches` (= ceil(steps/K)) and
 
 `--smoke` runs a tiny model on CPU (JAX_PLATFORMS=cpu) through the REAL
 K-step path end-to-end — fit -> DeviceStagedIter -> fused_update_block —
-with the profiler on, and reports the h2d_stage / fused_dispatch lanes;
+with the profiler on, and reports the io.stage / fit.dispatch lanes;
 tests/test_bench_smoke.py pins it so this harness cannot silently rot.
 
 Methodology note: the timed loop runs several steps per fence and is
@@ -1307,7 +1307,7 @@ def imperative(args):
 
 def smoke(args):
     """Tiny-model CPU run of the REAL K-step path end-to-end: fit ->
-    DeviceStagedIter (background h2d_stage engine op) ->
+    DeviceStagedIter (background h2d_stage engine op, io.stage span) ->
     Executor.fused_update_block (lax.scan dispatch).  Prints ONE JSON
     line with the dispatch count (= ceil(steps/K)) and the profiler-lane
     evidence that staging ran asynchronously."""
@@ -1356,8 +1356,8 @@ def smoke(args):
 
     with open(fname) as f:
         events = json.load(f)["traceEvents"]
-    h2d = [e for e in events if e["name"] == "h2d_stage"]
-    fused = [e for e in events if e["name"].startswith("fused_dispatch(")]
+    h2d = [e for e in events if e["name"] == "io.stage"]
+    fused = [e for e in events if e["name"] == "fit.dispatch"]
 
     def overlaps(a, b):
         return a["ts"] < b["ts"] + b["dur"] and b["ts"] < a["ts"] + a["dur"]

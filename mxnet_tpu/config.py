@@ -177,7 +177,7 @@ REGISTRY = [
            "blocks are host-decoded and jax.device_put ahead of compute "
            "by a background engine op (2 = classic double buffering, "
            "reference src/io/iter_prefetcher.h); raise only if H2D "
-           "stalls show between fused_dispatch spans in the profile",
+           "stalls show between fit.dispatch spans in the profile",
            Tunable(workloads=("train",), choices=(2, 3, 4))),
     # ---- multi-process data service (data/; docs/data.md) ----
     EnvVar("MXTPU_DATA_WORKERS", int, 2,

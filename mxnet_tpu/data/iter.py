@@ -6,7 +6,7 @@ iterator contract (``provide_data``/``provide_label``/``reset``/
 ``Module.fit`` — decode+augment in worker processes overlaps H2D
 staging overlaps device compute, each stage on its own profiler lane
 (``data_decode(w<i>)`` per worker, the ``data_service`` buffer gauge,
-``h2d_stage``, ``fused_dispatch(K)``).
+``io.stage``, ``fit.dispatch``).
 
 The consumer-side fetch rides engine.ThreadedIter like every other
 pipeline stage (one engine op per batch, `mx.waitall()` fences it),

@@ -35,6 +35,7 @@ class ThreadedIter:
 
         self._next_fn = next_fn
         self._name = name
+        self._gauge = "io.buffer.%s" % name
         self._priority = priority
         self._queue = _queue.Queue()       # unbounded; credits bound it
         self._var = _get_engine().new_variable()  # WAW chain serializes fetches
@@ -79,36 +80,32 @@ class ThreadedIter:
         return self
 
     def __next__(self):
-        import time as _time
-
         from . import get as _get_engine
-        from .. import telemetry
+        from .. import profiler, telemetry
 
-        tel = telemetry.enabled()
-        t0 = _time.time() if tel else 0.0
-        # never hard-block: when the queue is empty, help the engine run
-        # ready ops instead — the consumer may itself be inside an engine
-        # op (nested engine-backed iterators, e.g. PrefetchingIter over
-        # ImageRecordIter), and a blind get() would pin a worker while
-        # the fetch that must fill this queue starves in the ready heap
-        while True:
-            try:
-                item, err = self._queue.get_nowait()
-                break
-            except _queue.Empty:
-                if not _get_engine().help_one():
-                    try:
-                        item, err = self._queue.get(timeout=0.05)
-                        break
-                    except _queue.Empty:
-                        continue
-        if tel:
-            # how long the consumer stalled waiting for this pipeline
-            # (≈0 when lookahead keeps up) and how full its buffer ran
-            telemetry.observe("io.consumer_wait_seconds",
-                              _time.time() - t0)
-            telemetry.set_gauge("io.buffer.%s" % self._name,
-                                self._queue.qsize())
+        # how long the consumer stalled waiting for this pipeline (≈0
+        # when lookahead keeps up).  Never hard-block: when the queue is
+        # empty, help the engine run ready ops instead — the consumer may
+        # itself be inside an engine op (nested engine-backed iterators,
+        # e.g. PrefetchingIter over ImageRecordIter), and a blind get()
+        # would pin a worker while the fetch that must fill this queue
+        # starves in the ready heap
+        with profiler.span("io.consumer_wait", cat="io",
+                           hist="io.consumer_wait_seconds", pipe=self._name):
+            while True:
+                try:
+                    item, err = self._queue.get_nowait()
+                    break
+                except _queue.Empty:
+                    if not _get_engine().help_one():
+                        try:
+                            item, err = self._queue.get(timeout=0.05)
+                            break
+                        except _queue.Empty:
+                            continue
+        if telemetry.enabled():
+            # how full this pipeline's buffer ran
+            telemetry.set_gauge(self._gauge, self._queue.qsize())
         if err is not None:
             self._queue.put((_END, None))  # subsequent next() stops cleanly
             raise err

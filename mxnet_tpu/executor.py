@@ -660,16 +660,6 @@ class Executor:
         if evicted and programs and telemetry.enabled():
             telemetry.inc("mem.programs_evicted", len(programs))
 
-    def _note_dispatch(self, kind, elapsed):
-        """One training dispatch: wall latency split by dispatch shape
-        (`step` = single fused fwd+bwd(+update), `block` = K-step scan)."""
-        from . import telemetry
-
-        if not telemetry.enabled():
-            return
-        telemetry.inc("executor.train_dispatches")
-        telemetry.observe("executor.dispatch_seconds.%s" % kind, elapsed)
-
     def _note_bytes(self, name, nbytes):
         from . import telemetry
 
@@ -746,9 +736,8 @@ class Executor:
         args = self._place(self._gather_args())
         import numpy as _np
 
-        with profiler.span("forward(is_train=%s)%s"
-                           % (is_train, "" if compiled else " +compile"),
-                           cat="executor"):
+        with profiler.span("executor.forward", cat="executor",
+                           is_train=is_train, compiled=compiled):
             outs, aux_upd = fn(args, self._place_repl(self._gather_aux()),
                                _np.uint32(self._step_seed))
         self._outputs_cache = [NDArray(o, self._first_ctx) for o in outs]
@@ -1003,8 +992,6 @@ class Executor:
         nondiff_vals = tuple(all_vals[i] for i in nondiff_idx)
         state_tuples = tuple(self._place_repl(
             tuple(l.data for l in leaves_by_name[n])) for n in diff_names)
-        import time as _time
-
         from . import profiler, telemetry
         from .obs import recorder
 
@@ -1025,9 +1012,9 @@ class Executor:
             if first_call:
                 recorder.record("compile", "enter", seq, detail="step")
             recorder.record("dispatch", "enter", seq, detail="step")
-        t0 = _time.time() if tel else 0.0
         try:
-            with profiler.span("fused_step(fwd+bwd+update)", cat="executor"):
+            with profiler.span("fit.dispatch", cat="executor",
+                               hist="executor.dispatch_seconds.step", k=1):
                 outs, aux_upd, new_params, new_states = fn(
                     diff_vals, nondiff_vals, self._place_repl(self._gather_aux()),
                     state_tuples, _np.uint32(self._step_seed), scalars,
@@ -1038,7 +1025,7 @@ class Executor:
                     recorder.record("compile", "exit", seq)
                 recorder.record("dispatch", "exit", seq)
         if tel:
-            self._note_dispatch("step", _time.time() - t0)
+            telemetry.inc("executor.train_dispatches")
         self._train_dispatches += 1
         self._outputs_cache = [NDArray(o, self._first_ctx) for o in outs]
         if not self._aux_applied:
@@ -1090,14 +1077,16 @@ class Executor:
 
         return global_put(arr, sh)
 
-    def stage_block(self, named_arrays, count):
+    def stage_block(self, named_arrays, count, seq=0):
         """Stage a stacked block of `count` batches for the next
         `fused_update_block()`.  `named_arrays` maps input arg name ->
-        (count, ...) array (host or already device-put)."""
+        (count, ...) array (host or already device-put); `seq` is the
+        block's number in its staging iterator (StagedBlock.seq), the
+        `block` attribute of the `fit.dispatch` span."""
         unknown = [n for n in named_arrays if n not in self.arg_dict]
         if unknown:
             raise MXNetError("stage_block: unknown arguments %s" % unknown)
-        self._staged_block = (dict(named_arrays), int(count))
+        self._staged_block = (dict(named_arrays), int(count), seq)
         self._pending_fused_block = True
         # the staged block supersedes any deferred single step (mirror of
         # forward() clearing stale block state): without this, a later
@@ -1325,7 +1314,7 @@ class Executor:
 
         from .optimizer import schedule_prefix
 
-        named, k = self._staged_block
+        named, k, block_seq = self._staged_block
         updater = self._fused_updater
         opt = updater.optimizer
         an = self._arg_names
@@ -1384,8 +1373,6 @@ class Executor:
                             for i in stream_idx)
         state_tuples = tuple(self._place_repl(
             tuple(l.data for l in leaves_by_name[n])) for n in diff_names)
-        import time as _time
-
         from . import profiler, telemetry
         from .obs import recorder
 
@@ -1424,9 +1411,10 @@ class Executor:
                 recorder.record("compile", "enter", seq, detail=detail)
             recorder.record("dispatch", "enter", seq, detail=detail,
                             nbytes=rec_bytes)
-        t0 = _time.time() if tel else 0.0
         try:
-            with profiler.span("fused_dispatch(K=%d)" % k, cat="executor"):
+            with profiler.span("fit.dispatch", cat="executor",
+                               hist="executor.dispatch_seconds.block", k=k,
+                               block=block_seq):
                 outs, aux_upd, new_params, new_states = fn(
                     diff_vals, static_vals, self._place_repl(self._gather_aux()),
                     state_tuples, stream_vals, seeds, scalars)
@@ -1436,7 +1424,7 @@ class Executor:
                     recorder.record("compile", "exit", seq)
                 recorder.record("dispatch", "exit", seq)
         if tel:
-            self._note_dispatch("block", _time.time() - t0)
+            telemetry.inc("executor.train_dispatches")
         self._train_dispatches += 1
         self._last_block_count = k
         # outputs arrive stacked (K, ...): ONE per-dispatch host readback
@@ -1457,8 +1445,6 @@ class Executor:
         buffers, params untouched.  The shared probe under
         measure_comm's comm-only leg and autotune_comm_bucket's
         two-point model fit.  Returns mean seconds per sweep."""
-        import time as _time
-
         import numpy as _np
 
         from . import profiler
@@ -1479,12 +1465,11 @@ class Executor:
                       _np.dtype(self.arg_dict[nm].dtype)),
             self._repl_sharding) for nm in diff_names)
         jax.block_until_ready(comm_fn(gz))  # compile
-        with profiler.span("comm_allreduce(buckets=%d)" % n_buckets,
-                           cat="comm"):
-            t0 = _time.time()
+        with profiler.span("comm.allreduce", cat="comm",
+                           buckets=n_buckets) as sweeps:
             for _ in range(iters):
                 jax.block_until_ready(comm_fn(gz))
-            return (_time.time() - t0) / iters
+        return sweeps.seconds / iters
 
     def autotune_comm_bucket(self, iters=2):
         """MXTPU_COMM_BUCKET_MB=auto: derive the bucket target at fit
@@ -1599,8 +1584,8 @@ class Executor:
         ``overlap_frac = (t_nocomm + K*t_comm + - t_full) / (K*t_comm)``
         clamped to [0, 1]: the fraction of collective time hidden under
         backward compute.  Records comm.gbps / comm.overlap_frac gauges
-        (chrome counter lanes while profiling) plus comm_allreduce /
-        comm_overlap_probe spans beside fused_dispatch(K).
+        (chrome counter lanes while profiling) plus comm.allreduce /
+        comm.overlap_probe spans beside fit.dispatch.
 
         A COLLECTIVE probe: on a multi-process mesh every process must
         call it at the same point (bench.py --spmd-procs does).  Runs on
@@ -1636,7 +1621,7 @@ class Executor:
         def _fence(x):
             jax.block_until_ready(x)
 
-        with profiler.span("comm_overlap_probe", cat="comm"):
+        with profiler.span("comm.overlap_probe", cat="comm"):
             # -- comm-only: one bucketed hierarchical sweep ------------
             t_comm = self._time_comm_only(axes, bucket_bytes, iters=iters)
             # -- compute-only vs full block on throwaway inputs --------
@@ -1743,18 +1728,16 @@ class Executor:
                 out_grads = [out_grads]
             heads = tuple(g.data if isinstance(g, NDArray) else jnp.asarray(g) for g in out_grads)
         import numpy as _np
-        import time as _time
 
         from . import profiler, telemetry
 
-        tel = telemetry.enabled()
-        t0 = _time.time() if tel else 0.0
-        with profiler.span("forward_backward", cat="executor"):
+        with profiler.span("executor.forward_backward", cat="executor",
+                           hist="executor.dispatch_seconds.step"):
             outs, aux_upd, grads = fn(diff_vals, nondiff_vals,
                                       self._place_repl(self._gather_aux()),
                                       _np.uint32(self._step_seed), heads)
-        if tel:
-            self._note_dispatch("step", _time.time() - t0)
+        if telemetry.enabled():
+            telemetry.inc("executor.train_dispatches")
         self._train_dispatches += 1
         self._outputs_cache = [NDArray(o, self._first_ctx) for o in outs]
         if not self._aux_applied:

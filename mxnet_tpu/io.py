@@ -402,13 +402,17 @@ class StagedBlock:
       on the host so update_metric never reads the device block back;
     * ``count`` — number of real steps K (the last block of an epoch may
       be short);
-    * ``pad`` — pad rows of the FINAL step (earlier steps are full).
+    * ``pad`` — pad rows of the FINAL step (earlier steps are full);
+    * ``seq`` — the block's number within its DeviceStagedIter (0 when
+      built by hand): the ``block`` attribute of the spans that staged
+      it and of the one that dispatches it.
     """
 
-    __slots__ = ("data", "label", "label_host", "count", "pad",
+    __slots__ = ("data", "label", "label_host", "count", "pad", "seq",
                  "_mem_booked")
 
-    def __init__(self, data, label, label_host, count, pad=0):
+    def __init__(self, data, label, label_host, count, pad=0, seq=0):
+        self.seq = seq
         self.data = data
         self.label = label
         self.label_host = label_host
@@ -471,8 +475,8 @@ class DeviceStagedIter(DataIter):
     so SanitizerEngine sees a fully-declared pipeline and `mx.waitall()`
     fences staging along with everything else).  ``MXTPU_STAGE_BUFFERS``
     blocks are kept in flight (default 2 = classic double buffering).
-    Each staging op records an ``h2d_stage`` profiler span, so overlap
-    with the ``fused_dispatch(K)`` lane is visible in the trace.
+    Each staging op records an ``io.stage`` profiler span, so overlap
+    with the ``fit.dispatch`` lane is visible in the trace.
 
     `place_fn(name, stacked_array)` does the actual device placement —
     Module.fit passes Executor.place_block_input so blocks land with the
@@ -494,6 +498,7 @@ class DeviceStagedIter(DataIter):
                                    else config.get("MXTPU_STAGE_BUFFERS")))
         self.batch_size = getattr(data_iter, "batch_size", 0)
         self._bg = None
+        self._seq = 0  # blocks staged so far: the `block` of their spans
         self._start()
 
     @property
@@ -518,33 +523,32 @@ class DeviceStagedIter(DataIter):
     def _fetch_block(self):
         """One staging op: pull up to K batches, stack host-side, device-
         put.  Runs on an engine worker while the consumer's previous
-        block computes on device; the whole decode+stack+H2D is recorded
-        as one `h2d_stage` profiler span."""
-        import time as _time
-
+        block computes on device.  The whole op is one `io.stage` span
+        and its legs are `io.stage.fetch` / `.readback` / `.stack` /
+        `.put`; all carry the block's number, as does the `fit.dispatch`
+        span that consumes the block."""
         from . import profiler, telemetry
 
-        t0 = _time.time()
-        batches = []
-        while len(batches) < self._k:
-            try:
-                batches.append(self._inner.next())
-            except StopIteration:
-                break
-        if not batches:
-            raise StopIteration
-        block = self._assemble(batches)
-        if profiler.spans_active():
-            t1 = _time.time()
-            profiler.record_span("h2d_stage", int(t0 * 1e6),
-                                 int((t1 - t0) * 1e6), cat="io")
+        seq = self._seq = self._seq + 1
+        with profiler.span("io.stage", cat="io", hist="io.h2d_stage_seconds",
+                           block=seq):
+            batches = []
+            with profiler.span("io.stage.fetch", cat="io",
+                               hist="io.stage.fetch_seconds", block=seq):
+                while len(batches) < self._k:
+                    try:
+                        batches.append(self._inner.next())
+                    except StopIteration:
+                        break
+            if not batches:
+                raise StopIteration
+            block = self._assemble(batches, seq)
         if telemetry.enabled():
-            telemetry.observe("io.h2d_stage_seconds", _time.time() - t0)
             telemetry.inc("io.blocks_staged")
         return block
 
-    def _assemble(self, batches):
-        from . import telemetry
+    def _assemble(self, batches, seq):
+        from . import profiler, telemetry
 
         def host(a):
             if isinstance(a, NDArray):
@@ -552,16 +556,28 @@ class DeviceStagedIter(DataIter):
                 # read BACK to host before stacking — a real D2H leg of
                 # the staging path, counted so the transfer books
                 # balance (numpy-producing iterators skip it)
-                out = a.asnumpy()
+                with profiler.span("io.stage.readback", cat="io",
+                                   hist="io.stage.readback_seconds",
+                                   block=seq):
+                    out = a.asnumpy()
                 if telemetry.enabled():
                     telemetry.inc("executor.d2h_bytes", int(out.nbytes))
                 return out
             return _np.asarray(a)
 
         def stack_put(names, rows):
-            return [stage_put(name, _np.stack([host(b[i]) for b in rows]),
-                              self._place_fn)
-                    for i, name in enumerate(names)]
+            placed = []
+            for i, name in enumerate(names):
+                parts = [host(b[i]) for b in rows]
+                with profiler.span("io.stage.stack", cat="io",
+                                   hist="io.stage.stack_seconds", block=seq):
+                    stacked = _np.stack(parts)
+                # the time device_put takes to ENQUEUE the transfer, not
+                # the transfer: no fence is added here
+                with profiler.span("io.stage.put", cat="io",
+                                   hist="io.stage.put_seconds", block=seq):
+                    placed.append(stage_put(name, stacked, self._place_fn))
+            return placed
 
         data_names = self._names(self.provide_data)
         data = stack_put(data_names, [b.data for b in batches])
@@ -571,7 +587,7 @@ class DeviceStagedIter(DataIter):
             label = stack_put(label_names, [b.label for b in batches])
             label_host = [[host(a) for a in b.label] for b in batches]
         return StagedBlock(data, label, label_host, len(batches),
-                           pad=batches[-1].pad or 0)
+                           pad=batches[-1].pad or 0, seq=seq)
 
     def next(self):
         if self._bg is None:

@@ -187,15 +187,14 @@ class BaseModule:
         step-time histogram, the global step counter, and the per-step
         MFU gauge (bound symbol FLOPs / measured time / the peak of the
         device the executor runs on, telemetry.PEAK_FLOPS — not
-        published on a device with no known peak).  Call sites guard
-        with telemetry.enabled() so the disabled path never even times."""
+        published on a device with no known peak).  `elapsed` is the
+        `seconds` of the loop's `fit.step` / `fit.block` span."""
         from .. import telemetry
 
         if not telemetry.enabled():
             return
         telemetry.observe("module.step_seconds", elapsed)
         telemetry.inc("module.steps", steps)
-        telemetry.set_gauge("module.step_ms", elapsed * 1e3)
         flops = self._flops_per_step()
         peak = self._peak_flops()
         if peak and flops > 0.0 and elapsed > 0.0:
@@ -228,7 +227,7 @@ class BaseModule:
                     "block path is unavailable (non-fused optimizer, "
                     "kvstore-side update, inputs_need_grad, or a monitor is "
                     "installed); falling back to one dispatch per step", k)
-        from .. import telemetry
+        from .. import profiler, telemetry
 
         tel = telemetry.enabled()
         mgr = getattr(self, "_ckpt_mgr", None)
@@ -236,14 +235,14 @@ class BaseModule:
         for nbatch, data_batch in enumerate(train_data, skip):
             if monitor is not None:
                 monitor.tic()
-            t0 = time.perf_counter() if tel else 0.0
-            self.forward_backward(data_batch)
-            self.update()
-            self.update_metric(eval_metric, data_batch.label)
+            with profiler.span("fit.step", cat="module") as step:
+                self.forward_backward(data_batch)
+                self.update()
+                self.update_metric(eval_metric, data_batch.label)
             if tel:
                 # update_metric read the outputs back, so the elapsed
                 # time covers the real device step, not just dispatch
-                self._observe_steps(time.perf_counter() - t0, 1)
+                self._observe_steps(step.seconds, 1)
             if mgr is not None:
                 # the dispatch boundary: the snapshot D2H reads the
                 # post-update arrays and the shard write overlaps the

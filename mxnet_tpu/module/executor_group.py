@@ -202,7 +202,7 @@ class DataParallelExecutorGroup:
         named = dict(zip(self.data_names, block.data))
         if self.label_names and block.label:
             named.update(zip(self.label_names, block.label))
-        self.execs[0].stage_block(named, block.count)
+        self.execs[0].stage_block(named, block.count, seq=block.seq)
 
     def get_outputs(self, merge_multi_context=True):
         outs = self.execs[0].outputs

@@ -441,9 +441,7 @@ class Module(BaseModule):
         fast-forward already happened in _run_epoch, and checkpoints
         only cut at dispatch boundaries, so skip is a multiple of K and
         the block boundaries line up with the interrupted run's."""
-        import time as _time
-
-        from .. import telemetry
+        from .. import profiler, telemetry
         from ..io import DeviceStagedIter
         from .base_module import _fire
 
@@ -455,31 +453,42 @@ class Module(BaseModule):
         mgr = getattr(self, "_ckpt_mgr", None)
         try:
             for block in staged:
-                t0 = _time.perf_counter() if tel else 0.0
-                self.forward_backward(block)
-                self.update()
-                if block.label_host is not None:
-                    self.update_metric(eval_metric, block.label_host)
+                with profiler.span("fit.block", cat="module", k=block.count,
+                                   block=block.seq) as disp:
+                    self.forward_backward(block)
+                    self.update()
+                    if block.label_host is not None:
+                        # the first read of the dispatch's outputs: the
+                        # loop thread waiting for the device
+                        with profiler.span(
+                                "fit.device_wait", cat="module",
+                                hist="module.device_wait_seconds"):
+                            self.update_metric(eval_metric, block.label_host)
                 if tel:
                     # one observation per DISPATCH (covering K steps):
                     # the histogram count is the dispatch count and the
                     # MFU gauge normalizes by block.count steps
-                    self._observe_steps(_time.perf_counter() - t0,
-                                        block.count)
+                    self._observe_steps(disp.seconds, block.count)
                 nbatch += block.count
-                if mgr is not None:
-                    # dispatch boundary: snapshot D2H sees the post-block
-                    # arrays; the shard write overlaps the next dispatch
-                    mgr.note_dispatch(self, epoch, nbatch,
-                                      steps=block.count)
-                if batch_end_callback is not None:
-                    # one callback per dispatch (nbatch = last step index):
-                    # per-step callbacks would force per-step host sync,
-                    # defeating the amortization
-                    _fire(batch_end_callback,
-                          BatchEndParam(epoch=epoch, nbatch=nbatch - 1,
-                                        eval_metric=eval_metric,
-                                        locals=locals()))
+                if mgr is None and batch_end_callback is None:
+                    continue
+                # trace only: names the gap in which the checkpoint note
+                # and the user's callback run
+                with profiler.span("fit.callback", cat="module"):
+                    if mgr is not None:
+                        # dispatch boundary: snapshot D2H sees the
+                        # post-block arrays; the shard write overlaps the
+                        # next dispatch
+                        mgr.note_dispatch(self, epoch, nbatch,
+                                          steps=block.count)
+                    if batch_end_callback is not None:
+                        # one callback per dispatch (nbatch = last step
+                        # index): per-step callbacks would force per-step
+                        # host sync, defeating the amortization
+                        _fire(batch_end_callback,
+                              BatchEndParam(epoch=epoch, nbatch=nbatch - 1,
+                                            eval_metric=eval_metric,
+                                            locals=locals()))
         finally:
             staged.close()  # the epoch owns train_data; fit resets it
         return nbatch

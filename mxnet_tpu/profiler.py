@@ -8,9 +8,14 @@ unit of execution is a jitted XLA executable, so we record per-call spans
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
+from . import telemetry
 
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "record_span", "record_counter", "record_flow",
@@ -62,10 +67,11 @@ def profiler_set_config(mode="symbolic", filename="profile.json"):
 
 def profiler_set_state(state="stop"):
     """Start/stop profiling (parity: profiler.py profiler_set_state)."""
-    global _JAX_TRACE_DIR
+    global _JAX_TRACE_DIR, _WALL_MINUS_PERF_NS
     if state not in ("run", "stop"):
         raise ValueError("state must be 'run' or 'stop'")
     if state == "run" and not _STATE["running"]:
+        _WALL_MINUS_PERF_NS = time.time_ns() - time.perf_counter_ns()
         _STATE["running"] = True
         if _STATE["mode"] == "xla":
             import jax
@@ -215,21 +221,90 @@ def record_counter(name, value, ts_us=None):
                         "args": {"value": float(value)}})
 
 
-class span:
-    """Context manager measuring one span."""
+# wall clock minus perf_counter, in ns.  A span reads perf_counter_ns
+# once at each end; adding this constant puts its start on the wall
+# clock the other chrome events (engine ops, counter lanes) are stamped
+# with.  Re-taken whenever the chrome profiler starts, so a long-lived
+# process does not carry hours of slew between the two clocks.
+_WALL_MINUS_PERF_NS = time.time_ns() - time.perf_counter_ns()
+_SPAN_IDS = itertools.count(1)
+_SPAN_TLS = threading.local()  # .stack: ids of the spans open on this thread
+_tracing = _TraceAnnotation.is_enabled  # a profiler session wants TraceMes
 
-    def __init__(self, name, cat="operator"):
+
+class span:
+    """Context manager around one host step: the ONE span primitive.
+
+    One clock read on entry and one on exit (``time.perf_counter_ns``)
+    feed three sinks:
+
+    * the JAX profiler — the body runs under
+      ``jax.profiler.TraceAnnotation("mx:" + name, **attrs)``, so under
+      any running profiler session the span is an event of the
+      ``/host:CPU`` plane of the same ``.xplane.pb`` as the device's
+      ``XLA Ops``.  That file's timestamps count from the session's
+      start, so a span with no parent on its thread also carries
+      ``wall_ns``, its start on the wall clock: one such event aligns
+      the chrome events below with the xplane.  With no session this
+      sink costs one level check;
+    * telemetry — with ``hist`` given and the registry on, the duration
+      in seconds goes into that histogram (not when the body raised);
+    * the chrome event list while ``profiler_set_state("run")`` — as
+      ``record_span`` does, with ``args`` holding ``id``, ``parent`` (the
+      id of the span open on this thread when this one started, 0 for
+      none) and the ``attrs``.
+
+    ``name`` is a static string and ``attrs`` are values the caller
+    already holds (an int, a bucket, a tenant's name): the guards live
+    in here, so a call site builds nothing that is thrown away when
+    every sink is off.  After exit ``seconds`` holds the duration."""
+
+    __slots__ = ("name", "cat", "hist", "attrs", "id", "parent", "seconds",
+                 "_t0", "_ann")
+
+    def __init__(self, name, cat="operator", hist=None, **attrs):
         self.name = name
         self.cat = cat
+        self.hist = hist
+        self.attrs = attrs
+        self.seconds = None
 
     def __enter__(self):
-        self.t0 = time.time()
+        try:
+            stack = _SPAN_TLS.stack
+        except AttributeError:
+            stack = _SPAN_TLS.stack = []
+        self.parent = parent = stack[-1] if stack else 0
+        self.id = next(_SPAN_IDS)
+        stack.append(self.id)
+        self._t0 = t0 = time.perf_counter_ns()
+        # constructing the annotation starts it; with no session of the
+        # JAX profiler at host level >= 1 it is not even built
+        if not _tracing():
+            self._ann = None
+        elif parent:
+            self._ann = _TraceAnnotation("mx:" + self.name, **self.attrs)
+        else:
+            self._ann = _TraceAnnotation("mx:" + self.name,
+                                         wall_ns=t0 + _WALL_MINUS_PERF_NS,
+                                         **self.attrs)
         return self
 
-    def __exit__(self, *exc):
+    def __exit__(self, exc_type, exc, tb):
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        dur_ns = time.perf_counter_ns() - self._t0
+        _SPAN_TLS.stack.pop()
+        self.seconds = seconds = dur_ns * 1e-9
+        if (self.hist is not None and exc_type is None
+                and telemetry.enabled()):
+            telemetry.observe(self.hist, seconds)
         if _STATE["running"]:
-            t1 = time.time()
-            record_span(self.name, int(self.t0 * 1e6), int((t1 - self.t0) * 1e6), self.cat)
+            record_span(self.name,
+                        (self._t0 + _WALL_MINUS_PERF_NS) // 1000,
+                        dur_ns // 1000, self.cat,
+                        args=dict(self.attrs, id=self.id,
+                                  parent=self.parent))
 
 
 def _metadata_events():
@@ -266,8 +341,6 @@ def dump_profile():
     rank = _TRACE_META["rank"]
     if rank is None and rank_env != "":
         rank = int(rank_env)
-    from . import telemetry
-
     path = telemetry.rank_suffixed(_STATE["filename"])
     with _LOCK:
         payload = {"traceEvents": _metadata_events() + list(_EVENTS),

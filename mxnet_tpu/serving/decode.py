@@ -52,6 +52,14 @@ from .request import Request
 
 __all__ = ["GenerativeSession", "GenerateRequest", "GenerateResult"]
 
+# the histograms of one program call's legs (dispatch, device wait,
+# read-back) when the call is a decode step; a prefill or a warm-up
+# fill records the same spans and feeds no histogram
+_STEP_HISTS = ("serving.decode.dispatch_seconds",
+               "serving.decode.device_wait_seconds",
+               "serving.decode.d2h_seconds")
+_NO_HISTS = (None, None, None)
+
 
 class GenerateResult:
     """What a ``submit_generate`` future resolves to.
@@ -271,20 +279,36 @@ class GenerativeSession:
             n += 1
         return n
 
-    def _call(self, exe, fn, rings, data, slot, length):
+    def _call(self, exe, fn, rings, data, slot, length, hists=_NO_HISTS):
         """One program call threading `rings` through: returns (host
         logits, updated rings).  The rings passed in are donated on
-        device backends — the caller keeps only what comes back."""
-        other_vals, aux_vals = exe.serve_args(self._input_names)
-        ins = tuple([data, slot, length] + list(rings))
-        outs = fn(ins, other_vals, aux_vals, _np.uint32(0))
-        return _np.asarray(outs[0]), list(outs[1:])
+        device backends — the caller keeps only what comes back.
+        `hists` names the histograms of the three legs (dispatch, device
+        wait, read-back); only a decode step passes them."""
+        from .. import profiler
 
-    def _run(self, exe, fn, data, slot, length):
+        with profiler.span("decode.dispatch", cat="serving", hist=hists[0]):
+            other_vals, aux_vals = exe.serve_args(self._input_names)
+            ins = tuple([data, slot, length] + list(rings))
+            outs = fn(ins, other_vals, aux_vals, _np.uint32(0))
+            # request the logits' copy behind the program, as np.asarray
+            # itself does first: a copy requested only after the fence
+            # below costs one more host round trip a call
+            outs[0].copy_to_host_async()
+        # the fence np.asarray would perform anyway, made explicit so
+        # that waiting for the device and copying are two numbers
+        with profiler.span("decode.device_wait", cat="serving",
+                           hist=hists[1]):
+            outs[0].block_until_ready()
+        with profiler.span("decode.d2h", cat="serving", hist=hists[2]):
+            logits = _np.asarray(outs[0])
+        return logits, list(outs[1:])
+
+    def _run(self, exe, fn, data, slot, length, hists=_NO_HISTS):
         """One LIVE program call: the session's rings go in, the updated
         rings replace them; returns the host logits."""
         logits, self._caches = self._call(exe, fn, self._caches, data,
-                                          slot, length)
+                                          slot, length, hists)
         return logits
 
     # ------------------------------------------------------------------
@@ -310,26 +334,26 @@ class GenerativeSession:
         return leftovers
 
     def _prefill(self, req):
-        from .. import telemetry
+        from .. import profiler, telemetry
 
-        t0 = time.monotonic()
-        req.service_at = t0
         tokens = req.inputs["data"].reshape(-1)
         n = tokens.shape[0]
         bucket = choose_bucket(self._seq_ladder, n)
-        exe, fn = self._program(self._prefill_pred, 1, bucket, True)
-        slot = self._free.pop()
-        data = _np.zeros((1, bucket), _np.float32)
-        data[0, :n] = tokens
-        logits = self._run(exe, fn, data,
-                           _np.full((1,), slot, _np.float32),
-                           _np.full((1,), n, _np.float32))
-        sess = _Session(req, slot, n)
-        self._active.append(sess)
+        with profiler.span("serve.prefill", cat="serving",
+                           hist="serving.prefill_seconds", bucket=bucket,
+                           prompt=n):
+            req.service_at = time.monotonic()
+            exe, fn = self._program(self._prefill_pred, 1, bucket, True)
+            slot = self._free.pop()
+            data = _np.zeros((1, bucket), _np.float32)
+            data[0, :n] = tokens
+            logits = self._run(exe, fn, data,
+                               _np.full((1,), slot, _np.float32),
+                               _np.full((1,), n, _np.float32))
+            sess = _Session(req, slot, n)
+            self._active.append(sess)
         if telemetry.enabled():
             telemetry.inc("serving.decode.sessions")
-            telemetry.observe("serving.prefill_seconds",
-                              time.monotonic() - t0)
             self._note_occupancy()
         self._emit(sess, int(_np.argmax(logits[0])))
 
@@ -357,36 +381,48 @@ class GenerativeSession:
         """One token-level iteration: re-pack ALL active sessions into
         the smallest decode bucket, run one step, sample, retire.
         Returns tokens produced (0 when idle)."""
-        from .. import telemetry
+        from .. import profiler, telemetry
 
         act = self._active
         if not act:
             return 0
-        t0 = time.monotonic()
         n = len(act)
         bucket = choose_bucket(self._decode_ladder, n)
-        exe, fn = self._program(self._decode_pred, bucket, 1, False)
-        data = _np.zeros((bucket, 1), _np.float32)
-        slot = _np.full((bucket,), self._slots, _np.float32)  # scratch
-        length = _np.zeros((bucket,), _np.float32)
-        for i, sess in enumerate(act):
-            data[i, 0] = sess.generated[-1]
-            slot[i] = sess.slot
-            length[i] = sess.fed
-        logits = self._run(exe, fn, data, slot, length)
-        for i, sess in enumerate(list(act)):
-            sess.fed += 1
-            self._emit(sess, int(_np.argmax(logits[i])))
-        dt = time.monotonic() - t0
+        with profiler.span("serve.decode_step", cat="serving",
+                           hist="serving.decode.step_seconds", n=n,
+                           bucket=bucket):
+            with profiler.span("decode.pack", cat="serving",
+                               hist="serving.decode.pack_seconds"):
+                exe, fn = self._program(self._decode_pred, bucket, 1, False)
+                data = _np.zeros((bucket, 1), _np.float32)
+                slot = _np.full((bucket,), self._slots, _np.float32)  # scratch
+                length = _np.zeros((bucket,), _np.float32)
+                for i, sess in enumerate(act):
+                    data[i, 0] = sess.generated[-1]
+                    slot[i] = sess.slot
+                    length[i] = sess.fed
+            logits = self._run(exe, fn, data, slot, length, _STEP_HISTS)
+            with profiler.span("decode.emit", cat="serving",
+                               hist="serving.decode.emit_seconds"):
+                for i, sess in enumerate(list(act)):
+                    sess.fed += 1
+                    self._emit(sess, int(_np.argmax(logits[i])))
         self._tokens_done += n
         if telemetry.enabled():
             telemetry.inc("serving.decode.dispatches")
             telemetry.inc("serving.decode.tokens", n)
-            telemetry.observe("serving.decode.step_seconds", dt)
             telemetry.set_gauge("serving.decode.batch_fill_ratio",
                                 n / bucket)
-            telemetry.set_gauge("serving.decode.tokens_per_s",
-                                n / max(dt, 1e-9))
+            # position-steps: over a window their ratio is the mean
+            # reserved over used.  Reserved are the KV ring sets bound on
+            # the device — the live set plus the zero-filled placeholder
+            # set each bucket program's executor binds — times a ring's
+            # rows and length; used are the positions the active
+            # sessions had filled when the step was packed
+            telemetry.inc("kv.reserved_positions",
+                          (1 + len(self._programs)) * self._cache_shape[0]
+                          * self._max_len)
+            telemetry.inc("kv.used_positions", int(length.sum()))
             self._note_occupancy()
         return n
 

@@ -434,15 +434,17 @@ class ModelServer:
         recompiling).  Exit only when stopping, the queue is drained,
         and every decode session has retired — the zero-lost-futures
         contract."""
-        from .. import telemetry
+        from .. import profiler, telemetry
 
         while True:
             gens = self._generative()
             ticking = any(s.active() for s in gens)
             until = (time.monotonic() + self._window_s) if ticking else None
-            tenant = self._queue.next_work(self._wait_s, self._max_batch,
-                                           lambda: self._stopping,
-                                           until=until)
+            with profiler.span("serve.wait_work", cat="serving",
+                               hist="serving.loop.wait_seconds"):
+                tenant = self._queue.next_work(self._wait_s, self._max_batch,
+                                               lambda: self._stopping,
+                                               until=until)
             if tenant is not None:
                 session = self._sessions[tenant]
                 if getattr(session, "is_generative", False):

@@ -229,8 +229,8 @@ class TenantSession:
                 recorder.record("compile", "enter", rec_seq,
                                 detail="serve:%s,b=%d" % (self.name, bucket))
         try:
-            with profiler.span("serve_dispatch(%s,b=%d)" % (self.name, bucket),
-                               cat="serving"):
+            with profiler.span("serve.dispatch", cat="serving",
+                               tenant=self.name, bucket=bucket):
                 outs = tuple(fn(staged, other_vals, aux_vals, _np.uint32(0)))
         finally:
             self._ran_buckets.add(bucket)

@@ -37,6 +37,7 @@ import json
 import os as _os
 import threading
 import time
+from bisect import bisect_left
 
 __all__ = [
     "enabled", "set_enabled", "inc", "set_gauge", "observe",
@@ -104,12 +105,8 @@ class _Histogram:
 
     def observe(self, value):
         value = float(value)
-        i = 0
-        for b in self.boundaries:
-            if value <= b:
-                break
-            i += 1
-        self.bucket_counts[i] += 1
+        # the first boundary the value does not exceed (le_inf past all)
+        self.bucket_counts[bisect_left(self.boundaries, value)] += 1
         self.count += 1
         self.sum += value
         if self.min is None or value < self.min:

@@ -25,8 +25,8 @@ def test_bench_smoke_runs_k_step_path():
     # the acceptance pin: dispatch count = ceil(steps / K)
     assert out["steps"] == 24 and out["steps_per_dispatch"] == 4
     assert out["dispatches"] == out["expected_dispatches"] == 6
-    # both profiler lanes exist: one h2d_stage span per staged block and
-    # one fused_dispatch span per dispatch
+    # both profiler lanes exist: one io.stage span per staged block and
+    # one fit.dispatch span per dispatch
     assert out["fused_dispatch_spans"] == 6
     assert out["h2d_stage_spans"] >= 6
     # staging ran asynchronously: off the dispatching thread, or

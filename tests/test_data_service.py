@@ -348,8 +348,8 @@ def test_sanitizer_clean_epoch(rec_prefix):
 def test_profiler_renders_per_worker_decode_lanes(rec_prefix, tmp_path):
     """Worker decode is visible in the trace: one data_decode(w<i>)
     lane per worker PROCESS (spans recorded consumer-side on the
-    worker's behalf), named via thread metadata — so decode / h2d_stage
-    / fused_dispatch overlap can be read off one timeline."""
+    worker's behalf), named via thread metadata — so decode / io.stage
+    / fit.dispatch overlap can be read off one timeline."""
     import json
 
     from mxnet_tpu import profiler

@@ -390,7 +390,7 @@ def _fake_trace(rank, offset_us):
          "args": {"name": "host"}},
         {"name": "process_sort_index", "ph": "M", "pid": 0, "tid": 0,
          "args": {"sort_index": 0}},
-        {"name": "fused_dispatch(K=2)", "cat": "executor", "ph": "X",
+        {"name": "fit.dispatch", "cat": "executor", "ph": "X",
          "ts": 1000.0, "dur": 50, "pid": 0, "tid": 7}],
         "displayTimeUnit": "ms",
         "otherData": {"rank": rank, "clock_offset_us": offset_us}}
@@ -683,7 +683,7 @@ def test_stitch_two_rank_profiles_and_cluster_table(tmp_path):
     # real spans from BOTH ranks, on disjoint pid ranges
     span_pids = {e["pid"] // 100 for e in merged["traceEvents"]
                  if e.get("ph") == "X"
-                 and str(e.get("name", "")).startswith("fused_dispatch")}
+                 and e.get("name") == "fit.dispatch"}
     assert span_pids == {0, 1}, span_pids
     # the same run's cluster JSONL renders the per-rank skew table; the
     # exit-time force_write ends it on the run's real final state

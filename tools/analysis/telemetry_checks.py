@@ -26,6 +26,17 @@ site, before the callee can bail.
 Anything else — a guard smuggled through a container, an attribute, a
 cross-function contract — is flagged; restructure to one of the two
 shapes or allowlist with the justification that makes it safe.
+
+``profiler.span(name, hist=..., **attrs)`` is NOT a recording call and
+stays out of E004's set: its guards (``telemetry.enabled()``, the
+chrome profiler's running flag, the JAX profiler's own level check)
+live inside the primitive, and a context manager cannot sit under an
+``if``.  The same discipline therefore binds its ARGUMENTS instead:
+``name`` is a static string and ``attrs`` are values the caller already
+holds (an int, a bucket, a tenant's name) — never a formatted string, a
+``len()`` over a fresh list or a byte sum, which would be built and
+thrown away whenever every sink is off.  Do not wrap a span in ``if
+telemetry.enabled()``.
 """
 from __future__ import annotations
 
