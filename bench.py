@@ -319,7 +319,8 @@ def main():
         blocks_per_chunk = max(1, -(-args.steps // K // 3))
         it = _endless_iter(mx, rng, BATCH, (224, 224, 3), 1000)
         staged = mx.io.DeviceStagedIter(it, steps_per_dispatch=K,
-                                        place_fn=exe.place_block_input)
+                                        place_fn=exe.place_step_input,
+                                        stack_fn=exe.stack_block_input)
         rates, steps_done = [], 0
         try:
             block = next(staged)  # compile + settle
@@ -954,7 +955,8 @@ def _knobs_train_side(args, smoke, knobs):
                                              "momentum": 0.9})
         exe = mod._exec_group.execs[0]
         staged = mx.io.DeviceStagedIter(it, steps_per_dispatch=K,
-                                        place_fn=exe.place_block_input)
+                                        place_fn=exe.place_step_input,
+                                        stack_fn=exe.stack_block_input)
         blocks_per_chunk = max(1, -(-steps // K // 3))
         rates = []
         try:
@@ -1533,7 +1535,8 @@ def spmd_worker(args):
                          "executor._comm_mode) — the row would be "
                          "mislabelled")
     staged = mx.io.DeviceStagedIter(it, steps_per_dispatch=K,
-                                    place_fn=exe.place_block_input)
+                                    place_fn=exe.place_step_input,
+                                    stack_fn=exe.stack_block_input)
     blocks_per_chunk = max(1, -(-args.steps // K // 3))
     rates, steps_done = [], 0
     try:
@@ -1570,7 +1573,8 @@ def spmd_worker(args):
         mgr = CheckpointManager(directory=args.ckpt_dir,
                                 every_steps=K * blocks_per_chunk)
         staged = mx.io.DeviceStagedIter(it, steps_per_dispatch=K,
-                                        place_fn=exe.place_block_input)
+                                        place_fn=exe.place_step_input,
+                                        stack_fn=exe.stack_block_input)
         ab_plain = []
         armed_secs = blocked_secs = 0.0
         nb = 0
@@ -1633,7 +1637,8 @@ def spmd_worker(args):
         default_mb = float(_config.spec("MXTPU_COMM_BUCKET_MB").default)
         auto_rates, dflt_rates = [], []
         staged = mx.io.DeviceStagedIter(it, steps_per_dispatch=K,
-                                        place_fn=exe.place_block_input)
+                                        place_fn=exe.place_step_input,
+                                        stack_fn=exe.stack_block_input)
         try:
             for chunk in range(10):
                 auto_side = chunk % 2 == 0

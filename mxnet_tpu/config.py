@@ -173,8 +173,8 @@ REGISTRY = [
            "step (the pre-block behavior); see docs/perf.md",
            Tunable(workloads=("train",), choices=(1, 2, 4, 8))),
     EnvVar("MXTPU_STAGE_BUFFERS", int, 2,
-           "io.DeviceStagedIter lookahead: how many stacked K-step input "
-           "blocks are host-decoded and jax.device_put ahead of compute "
+           "io.DeviceStagedIter lookahead: how many K-step input blocks "
+           "are fetched and assembled on the device ahead of compute "
            "by a background engine op (2 = classic double buffering, "
            "reference src/io/iter_prefetcher.h); raise only if H2D "
            "stalls show between fit.dispatch spans in the profile",

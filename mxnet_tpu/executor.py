@@ -22,8 +22,11 @@ Architecture mapping (SURVEY.md §7 phase 3):
 """
 from __future__ import annotations
 
+import functools as _functools
+
 import jax
 import jax.numpy as jnp
+import numpy as _np
 
 from .base import MXNetError
 from .context import Context, current_context
@@ -193,6 +196,22 @@ def _auto_spec(shape, mesh, axis="model"):
             spec[d] = axis
             return P(*spec)
     return P()
+
+
+@_functools.partial(jax.jit, static_argnums=(1, 2))
+def _split_rows(x, starts, rows):
+    """`rows` rows of `x` from each row of `starts`, on the device(s) `x`
+    lives on: the pieces of one step's device batch
+    (Executor.place_step_input)."""
+    return tuple(jax.lax.slice_in_dim(x, s, s + rows) for s in starts)
+
+
+@jax.jit
+def _stack_steps(*steps):
+    """K steps' pieces on one device as one (K, ...) array there
+    (Executor.stack_block_input).  jit's own cache keys it by K, shape
+    and dtype; a committed input decides the device."""
+    return jnp.stack(steps)
 
 
 def _resolve_group2ctx(symbol, group2ctx, mesh):
@@ -1060,11 +1079,13 @@ class Executor:
         return NamedSharding(self._mesh, batch_pspec(self._mesh, lead_dims=1))
 
     def place_block_input(self, name, arr):
-        """Device-put one stacked input block with the right sharding —
-        the H2D half of the staging pipeline; io.DeviceStagedIter calls
-        this from a background engine op so the transfer overlaps device
-        compute.  Idempotent: re-putting an already-placed block is a
-        no-op, so the dispatch path can call it again safely."""
+        """Device-put one STACKED (K, batch, ...) input block with the
+        right sharding — what the dispatch path does to every block it
+        is handed, and the H2D of a block that was stacked on the host
+        (a hand-built StagedBlock, a DeviceStagedIter built without
+        `place_fn`).  Idempotent: a block that place_step_input /
+        stack_block_input assembled on the device already carries
+        block_input_sharding() and comes back as it is."""
         if not isinstance(arr, jax.Array):
             # count H2D bytes only for HOST arrays: the dispatch path
             # re-places already-staged device blocks (the idempotent
@@ -1076,6 +1097,85 @@ class Executor:
         from .parallel.mesh import global_put
 
         return global_put(arr, sh)
+
+    def _step_layout(self, name):
+        """Where one step's array of input `name` goes: (devices, starts,
+        rows) — this process's devices of the block, the row of the
+        batch axis at which each one's piece starts, and the rows of a
+        piece.  A mesh-less executor has one device and one piece, the
+        whole array; devices that differ only along a non-data mesh
+        axis start at the same row."""
+        shape = tuple(self.arg_dict[name].shape)
+        if self._mesh is None:
+            return (self._first_ctx.jax_device(),), (0,), shape[0]
+        idx = self._data_sharding.addressable_devices_indices_map(shape)
+        spans = [i[0].indices(shape[0]) for i in idx.values()]
+        return (tuple(idx), tuple(s[0] for s in spans),
+                spans[0][1] - spans[0][0])
+
+    def place_step_input(self, name, arr):
+        """ONE step's array of input `name`, as the source iterator made
+        it (an NDArray, or a host array), laid out over the block's
+        devices: the `place_fn` of io.DeviceStagedIter.  Returns (came
+        from the host?, one single-device piece per device of
+        _step_layout), which stack_block_input stacks where the pieces
+        lie.
+
+        * A DEVICE array never comes back to the host: one jitted
+          program cuts it along the batch axis on the device it lives
+          on, and each piece is copied chip to chip, one single-device
+          copy each.  (Not jax.device_put of the whole array to a
+          NamedSharding: where no shard of the source has a wanted
+          index, jax/_src/array.py shard_sharded_device_array_slow_path
+          serves the request from `x._value`, the host round trip this
+          replaces.)
+        * A HOST array crosses once, each device's rows (a view of a
+          contiguous batch axis) straight from the iterator's own
+          buffer, counted in `executor.h2d_bytes`.
+
+        Only enqueues: stack_block_input waits where something must."""
+        devices, starts, rows = self._step_layout(name)
+        if isinstance(arr, NDArray):
+            arr = arr.data
+        host = not isinstance(arr, jax.Array)
+        if host:
+            arr = _np.asarray(arr)
+            self._note_bytes("executor.h2d_bytes", arr.nbytes)
+        if tuple(arr.shape) != tuple(self.arg_dict[name].shape):
+            raise MXNetError(
+                "Shape mismatch for argument %s: bound %s, got %s"
+                % (name, self.arg_dict[name].shape, tuple(arr.shape)))
+        if rows == arr.shape[0]:
+            cut = {0: arr}
+        elif host:
+            cut = {s: arr[s:s + rows] for s in set(starts)}
+        else:
+            uniq = tuple(sorted(set(starts)))
+            cut = dict(zip(uniq, _split_rows(arr, uniq, rows)))
+        return host, [jax.device_put(cut[s], d)
+                      for s, d in zip(starts, devices)]
+
+    def stack_block_input(self, name, steps):
+        """The (K, batch, ...) block of input `name` from its K steps
+        (place_step_input): one jitted stack on every device of the
+        block, over the pieces that device holds, and — on a mesh — one
+        global array over the per-device stacks, carrying
+        block_input_sharding().  The `stack_fn` of io.DeviceStagedIter.
+        A short last block is a smaller K.
+
+        Device arrays are immutable and nothing is waited for.  A step
+        that came from the HOST is: its source may refill the buffer at
+        its next next(), and until the stack has run the pieces may
+        still be in flight from it (or, on the CPU backend, BE it)."""
+        stacks = [_stack_steps(*on_dev)
+                  for on_dev in zip(*(pieces for _, pieces in steps))]
+        if any(host for host, _ in steps):
+            jax.block_until_ready(stacks)
+        sh = self.block_input_sharding()
+        if sh is None:
+            return stacks[0]
+        shape = (len(steps),) + tuple(self.arg_dict[name].shape)
+        return jax.make_array_from_single_device_arrays(shape, sh, stacks)
 
     def stage_block(self, named_arrays, count, seq=0):
         """Stage a stacked block of `count` batches for the next

@@ -13,6 +13,7 @@ import os
 import struct
 from collections import namedtuple
 
+import jax
 import numpy as _np
 
 from .base import MXNetError
@@ -394,12 +395,15 @@ class PrefetchingIter(DataIter):
 class StagedBlock:
     """K training batches stacked on a new leading axis, resident on
     device: the unit of work of the K-step fused dispatch
-    (Executor.fused_update_block).
+    (Executor.fused_update_block).  DeviceStagedIter stacks it on the
+    device from the K steps' arrays; the batches' data never returns to
+    the host.
 
     * ``data`` / ``label`` — lists of (K, ...) device arrays aligned with
       ``provide_data`` / ``provide_label``;
-    * ``label_host`` — per-step numpy labels ([[arr, ...] per step]) kept
-      on the host so update_metric never reads the device block back;
+    * ``label_host`` — per-step numpy labels ([[arr, ...] per step]): the
+      one thing staging reads to the host, so update_metric never reads
+      the device block back;
     * ``count`` — number of real steps K (the last block of an epoch may
       be short);
     * ``pad`` — pad rows of the FINAL step (earlier steps are full);
@@ -443,30 +447,50 @@ class StagedBlock:
             pass
 
 
-def stage_put(name, arr, place_fn=None):
-    """Count and place ONE stacked host input — the H2D half shared by
-    the training staging pipeline (DeviceStagedIter blocks) and the
-    serving continuous batcher (request batches, serving/session.py):
-    the staged bytes land in the same `io.stage_bytes` /
-    `io.stage_block_bytes` books either way, so "is the host feeding
-    the device in big-enough transfers" has one answer across both
-    pipelines.  `place_fn(name, arr)` does the actual device placement;
-    None keeps the array host-side."""
+def _book_staged(nbytes):
+    """One staged input in the `io.stage_bytes` / `io.stage_block_bytes`
+    books."""
     from . import telemetry
 
     if telemetry.enabled():
-        telemetry.inc("io.stage_bytes", int(arr.nbytes))
+        telemetry.inc("io.stage_bytes", int(nbytes))
         # size DISTRIBUTION too: whether transfers are big enough to
         # amortize the per-transfer overhead is a bucket question
-        telemetry.observe("io.stage_block_bytes", int(arr.nbytes),
+        telemetry.observe("io.stage_block_bytes", int(nbytes),
                           buckets=telemetry.BYTE_BUCKETS)
+
+
+def stage_put(name, arr, place_fn=None):
+    """Count and place ONE stacked host input: the H2D of the serving
+    continuous batcher's request batches (serving/session.py).  The
+    bytes land in the books DeviceStagedIter keeps for training blocks
+    (`io.stage_bytes` / `io.stage_block_bytes`), so "is the host feeding
+    the device in big-enough transfers" has one answer across both
+    pipelines.  `place_fn(name, arr)` does the actual device placement;
+    None keeps the array host-side."""
+    _book_staged(arr.nbytes)
     return place_fn(name, arr) if place_fn is not None else arr
+
+
+def _to_host(a):
+    """One array of a batch as numpy that stays what it is: a host
+    array is copied (its source may refill the buffer), the read-back
+    of an NDArray is counted in `executor.d2h_bytes`, so the transfer
+    books balance."""
+    if not isinstance(a, NDArray):
+        return _np.array(a)
+    out = a.asnumpy()
+    from . import telemetry
+
+    if telemetry.enabled():
+        telemetry.inc("executor.d2h_bytes", int(out.nbytes))
+    return out
 
 
 class DeviceStagedIter(DataIter):
     """Async device staging: groups K batches from `data_iter` into one
-    stacked StagedBlock and `jax.device_put`s it from a BACKGROUND engine
-    op, so the host decode + H2D of block N+1 overlap block N's device
+    StagedBlock, assembled ON THE DEVICE(S) from a BACKGROUND engine op,
+    so the host decode + H2D of block N+1 overlap block N's device
     compute — the tf.data prefetch-to-device recipe layered on the
     reference's double-buffered PrefetcherIter (src/io/iter_prefetcher.h).
 
@@ -478,14 +502,22 @@ class DeviceStagedIter(DataIter):
     Each staging op records an ``io.stage`` profiler span, so overlap
     with the ``fit.dispatch`` lane is visible in the trace.
 
-    `place_fn(name, stacked_array)` does the actual device placement —
-    Module.fit passes Executor.place_block_input so blocks land with the
-    executor's input sharding; without it blocks stay host-side and the
+    The host neither stacks nor reads a batch's data back.
+    `place_fn(name, array)` lays ONE step's array out over the devices,
+    as the source made it — the NDArray of a device-resident batch
+    (NDArrayIter and every iterator that ends in `nd.array`) moves chip
+    to chip, a host array crosses the link once — and
+    `stack_fn(name, steps)` stacks the K results where they lie.
+    Module.fit passes Executor.place_step_input and
+    Executor.stack_block_input, so a block carries the executor's
+    block_input_sharding().  `io.stage.device_parts` /
+    `io.stage.host_parts` count which way the step arrays came.
+    Without the pair, blocks are stacked and stay on the host and the
     executor places them at dispatch (no overlap, same results).
     """
 
     def __init__(self, data_iter, steps_per_dispatch=None, place_fn=None,
-                 buffers=None):
+                 buffers=None, stack_fn=None):
         super().__init__()
         from . import config
 
@@ -493,12 +525,18 @@ class DeviceStagedIter(DataIter):
         k = (steps_per_dispatch if steps_per_dispatch is not None
              else config.get("MXTPU_STEPS_PER_DISPATCH"))
         self._k = max(1, int(k))
-        self._place_fn = place_fn
+        if (place_fn is None) != (stack_fn is None):
+            raise MXNetError("DeviceStagedIter: place_fn and stack_fn come "
+                             "as a pair (Executor.place_step_input, "
+                             "Executor.stack_block_input)")
+        self._place_fn = place_fn or (lambda name, a: _to_host(a))
+        self._stack_fn = stack_fn or (lambda name, steps: _np.stack(steps))
         self._buffers = max(1, int(buffers if buffers is not None
                                    else config.get("MXTPU_STAGE_BUFFERS")))
         self.batch_size = getattr(data_iter, "batch_size", 0)
         self._bg = None
         self._seq = 0  # blocks staged so far: the `block` of their spans
+        self._in_flight = None  # what place_fn made of the last step
         self._start()
 
     @property
@@ -521,73 +559,87 @@ class DeviceStagedIter(DataIter):
         return [d.name if isinstance(d, DataDesc) else d[0] for d in descs]
 
     def _fetch_block(self):
-        """One staging op: pull up to K batches, stack host-side, device-
-        put.  Runs on an engine worker while the consumer's previous
-        block computes on device.  The whole op is one `io.stage` span
-        and its legs are `io.stage.fetch` / `.readback` / `.stack` /
-        `.put`; all carry the block's number, as does the `fit.dispatch`
-        span that consumes the block."""
+        """One staging op: pull up to K batches, placing each step's
+        arrays as soon as it is fetched (a full batch is let go before
+        the next one is asked for, and at most two steps are in flight,
+        so a device holds few of them at a time), then stack them on
+        the device.  Runs on an engine worker
+        while the consumer's previous block computes on device.  The
+        whole op is one `io.stage` span and its legs are
+        `io.stage.fetch` / `.put` (one of each per step) and `.stack` /
+        `.readback` (labels only); all carry the block's number, as
+        does the `fit.dispatch` span that consumes the block."""
         from . import profiler, telemetry
 
         seq = self._seq = self._seq + 1
+        names = self._names(self.provide_data) \
+            + self._names(self.provide_label)
+        rows, labels = [], []  # per step: what place_fn made; the labels
+        parts = {True: 0, False: 0}  # arrived on the device? -> count
+        pad = 0
         with profiler.span("io.stage", cat="io", hist="io.h2d_stage_seconds",
                            block=seq):
-            batches = []
-            with profiler.span("io.stage.fetch", cat="io",
-                               hist="io.stage.fetch_seconds", block=seq):
-                while len(batches) < self._k:
+            while len(rows) < self._k:
+                with profiler.span("io.stage.fetch", cat="io",
+                                   hist="io.stage.fetch_seconds", block=seq):
                     try:
-                        batches.append(self._inner.next())
+                        batch = self._inner.next()
                     except StopIteration:
                         break
-            if not batches:
-                raise StopIteration
-            block = self._assemble(batches, seq)
-        if telemetry.enabled():
-            telemetry.inc("io.blocks_staged")
-        return block
-
-    def _assemble(self, batches, seq):
-        from . import profiler, telemetry
-
-        def host(a):
-            if isinstance(a, NDArray):
-                # a device-resident batch (e.g. NDArrayIter output) is
-                # read BACK to host before stacking — a real D2H leg of
-                # the staging path, counted so the transfer books
-                # balance (numpy-producing iterators skip it)
-                with profiler.span("io.stage.readback", cat="io",
-                                   hist="io.stage.readback_seconds",
-                                   block=seq):
-                    out = a.asnumpy()
-                if telemetry.enabled():
-                    telemetry.inc("executor.d2h_bytes", int(out.nbytes))
-                return out
-            return _np.asarray(a)
-
-        def stack_put(names, rows):
-            placed = []
-            for i, name in enumerate(names):
-                parts = [host(b[i]) for b in rows]
-                with profiler.span("io.stage.stack", cat="io",
-                                   hist="io.stage.stack_seconds", block=seq):
-                    stacked = _np.stack(parts)
-                # the time device_put takes to ENQUEUE the transfer, not
-                # the transfer: no fence is added here
+                # the ENQUEUE of this step's split and chip-to-chip
+                # copies (device arrays) or H2D (host arrays), then the
+                # wait for the step BEFORE it to have arrived: a device
+                # allocates its buffers when work is enqueued, so a
+                # staging thread that ran ahead of the link would have
+                # a whole block's full batches and pieces allocated at
+                # once (+3 GB on the chip that feeds four, PERF.md PR 24)
                 with profiler.span("io.stage.put", cat="io",
                                    hist="io.stage.put_seconds", block=seq):
-                    placed.append(stage_put(name, stacked, self._place_fn))
-            return placed
+                    rows.append(self._place_step(batch, names, parts))
+                    jax.block_until_ready(self._in_flight)
+                    self._in_flight = rows[-1]
+                labels.append(batch.label)
+                pad = batch.pad or 0
+                del batch  # the data, before the next fetch
+            if not rows:
+                raise StopIteration
+            block = self._assemble(names, rows, labels, pad, seq)
+        if telemetry.enabled():
+            telemetry.inc("io.blocks_staged")
+            telemetry.inc("io.stage.device_parts", parts[True])
+            telemetry.inc("io.stage.host_parts", parts[False])
+        return block
 
-        data_names = self._names(self.provide_data)
-        data = stack_put(data_names, [b.data for b in batches])
-        label, label_host = [], None
-        if batches[0].label:
-            label_names = self._names(self.provide_label)
-            label = stack_put(label_names, [b.label for b in batches])
-            label_host = [[host(a) for a in b.label] for b in batches]
-        return StagedBlock(data, label, label_host, len(batches),
-                           pad=batches[-1].pad or 0, seq=seq)
+    def _place_step(self, batch, names, parts):
+        arrays = list(batch.data) + list(batch.label or [])
+        for a in arrays:
+            parts[isinstance(a, NDArray)] += 1
+        return [self._place_fn(name, a) for name, a in zip(names, arrays)]
+
+    def _assemble(self, names, rows, labels, pad, seq):
+        """The StagedBlock of the placed steps `rows`.  No data array
+        comes to the host in staging: only the labels are read, for
+        label_host."""
+        from . import profiler
+
+        blocks = []
+        for name, steps in zip(names, zip(*rows)):
+            # the enqueue of the device stack (host arrays: the stack
+            # itself, see Executor.stack_block_input)
+            with profiler.span("io.stage.stack", cat="io",
+                               hist="io.stage.stack_seconds", block=seq):
+                blocks.append(self._stack_fn(name, steps))
+            _book_staged(blocks[-1].nbytes)
+        label_host = None
+        if labels[0]:
+            # a label waits here for its batch's own H2D, which is
+            # queued behind the data's
+            with profiler.span("io.stage.readback", cat="io",
+                               hist="io.stage.readback_seconds", block=seq):
+                label_host = [[_to_host(a) for a in row] for row in labels]
+        n_data = len(self.provide_data)
+        return StagedBlock(blocks[:n_data], blocks[n_data:], label_host,
+                           len(rows), pad=pad, seq=seq)
 
     def next(self):
         if self._bg is None:
@@ -615,6 +667,7 @@ class DeviceStagedIter(DataIter):
         if self._bg is not None:
             self._bg.close()
         self._bg = None
+        self._in_flight = None
 
     def __del__(self):
         if getattr(self, "_bg", None) is not None:

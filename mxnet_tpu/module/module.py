@@ -447,7 +447,8 @@ class Module(BaseModule):
 
         exe = self._exec_group.execs[0]
         staged = DeviceStagedIter(train_data, steps_per_dispatch=k,
-                                  place_fn=exe.place_block_input)
+                                  place_fn=exe.place_step_input,
+                                  stack_fn=exe.stack_block_input)
         nbatch = skip
         tel = telemetry.enabled()
         mgr = getattr(self, "_ckpt_mgr", None)

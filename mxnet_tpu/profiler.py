@@ -36,7 +36,9 @@ _STATE = {
     "running": False,
 }
 _EVENTS = []
-_LOCK = threading.Lock()
+# RLock: telemetry.set_gauge reaches record_counter from NDArray.__del__,
+# which GC can run on a thread that already holds this lock
+_LOCK = threading.RLock()
 _JAX_TRACE_DIR = None
 # tid -> human thread name, harvested as spans are recorded; dumped as
 # thread_name metadata so engine-worker lanes are labeled in the UI

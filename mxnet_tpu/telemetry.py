@@ -62,7 +62,10 @@ COUNT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, 128.0, 256.0,
                  512.0, 1024.0)
 
 _ENABLED = _os.environ.get("MXTPU_TELEMETRY", "1") not in ("0", "")
-_LOCK = threading.Lock()
+# RLock, as obs/memory._CENSUS_LOCK and for its reason: what runs under
+# it allocates (snapshot's dicts), an allocation can trigger GC, and a
+# collected NDArray's __del__ unbooks through set_gauge on this thread
+_LOCK = threading.RLock()
 _COUNTERS = {}
 _GAUGES = {}
 _HISTOGRAMS = {}

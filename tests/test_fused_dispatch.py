@@ -233,7 +233,8 @@ def test_fresh_forward_supersedes_stale_staged_block():
                        optimizer_params={"learning_rate": 0.1})
     exe = mod._exec_group.execs[0]
     staged = DeviceStagedIter(it, steps_per_dispatch=2,
-                              place_fn=exe.place_block_input)
+                              place_fn=exe.place_step_input,
+                              stack_fn=exe.stack_block_input)
     mod.forward_backward(next(staged))  # staged; update() skipped
     staged.close()
     assert exe._pending_fused_block
@@ -253,7 +254,8 @@ def test_fresh_forward_supersedes_stale_staged_block():
     assert exe._pending_fused
     staged2 = DeviceStagedIter(mx.io.NDArrayIter(X, y, batch_size=32),
                                steps_per_dispatch=2,
-                               place_fn=exe.place_block_input)
+                               place_fn=exe.place_step_input,
+                               stack_fn=exe.stack_block_input)
     mod.forward_backward(next(staged2))
     staged2.close()
     assert exe._pending_fused_block and not exe._pending_fused
