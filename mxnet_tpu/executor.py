@@ -367,7 +367,8 @@ class Executor:
             for node in self._order:
                 if node.op is None or not getattr(node.op, "input_axes", None):
                     continue
-                for (src, _), in_name in zip(node.inputs, node.op.inputs):
+                for (src, _), in_name in zip(
+                        node.inputs, node.op.list_inputs(node.attrs)):
                     ax = node.op.input_axes.get(in_name)
                     if (ax and ax in mesh.axis_names and src.op is None
                             and not src.is_aux

@@ -28,6 +28,17 @@ set (shared names, so a training checkpoint serves directly):
 The serving graphs thread the KV rings functionally (caches in ->
 updated caches out); on TPU the serve program's donated-input tuple
 turns that into an in-place update.
+
+The block's choices are arguments of the ONE spec, not a second model
+file: with the defaults the block is OPT's (learned positions,
+LayerNorm, ReLU FFN, biases, tied head); ``norm="rms"``,
+``positions="rotary"``, ``qk_norm=True``, ``num_experts=E`` with
+``experts_per_token=k`` (a dropless routed SwiGLU layer of width
+`d_ff`, ``mx.sym.MoE``), ``bias=False`` and ``tied_head=False``
+together are OLMoE's.  The helper methods branch; the four graph
+builders are shared.  A routed model's serving graphs end with one
+more small output, ``moe_load (num_layers, num_experts)`` — tokens per
+expert in this call — which the batcher books as the ``moe.*`` counters.
 """
 from __future__ import annotations
 
@@ -37,18 +48,40 @@ __all__ = ["TransformerLM"]
 
 
 class TransformerLM:
-    """Decoder-only transformer LM spec (GPT-2 shape, pre-LN).
+    """Decoder-only pre-norm transformer LM spec.
 
     `vocab`: vocabulary size; `num_layers`/`num_heads`/`d_model`: the
     usual; `d_ff` defaults to ``4 * d_model``; `max_len` bounds the
-    positional table AND the serving KV ring; `dropout` applies to the
-    residual branches during training only."""
+    positions AND the serving KV ring; `dropout` applies to the
+    residual branches during training only.
+
+    The block (defaults: the GPT-2/OPT shape): `norm` ``"layer"`` |
+    ``"rms"`` (gain only), eps `norm_eps`; `positions` ``"learned"``
+    (a table added to the embedding) | ``"rotary"`` (Q and K turned per
+    head, rotate-half over the whole head, base `rope_theta`); `qk_norm`
+    normalizes the whole Q and K projections (kind `norm`) before the
+    heads are split; `num_experts` > 0 replaces the dense ReLU FFN by a
+    dropless routed SwiGLU layer — `experts_per_token` of `num_experts`
+    experts of width `d_ff`, router softmax scores used unnormalised;
+    `bias` false drops every projection bias; `tied_head` false gives
+    the head its own ``head_weight (vocab, d_model)``."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
-                 d_ff=None, max_len=64, dropout=0.0):
+                 d_ff=None, max_len=64, dropout=0.0, norm="layer",
+                 norm_eps=1e-5, positions="learned", rope_theta=10000.0,
+                 qk_norm=False, num_experts=0, experts_per_token=0,
+                 bias=True, tied_head=True):
         if d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
+        if norm not in ("layer", "rms"):
+            raise ValueError("norm must be 'layer' or 'rms', got %r" % norm)
+        if positions not in ("learned", "rotary"):
+            raise ValueError("positions must be 'learned' or 'rotary', "
+                             "got %r" % positions)
+        if num_experts and not 0 < experts_per_token <= num_experts:
+            raise ValueError("experts_per_token=%d must be in 1..%d"
+                             % (experts_per_token, num_experts))
         self.vocab = int(vocab)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -57,6 +90,15 @@ class TransformerLM:
         self.d_head = self.d_model // self.num_heads
         self.max_len = int(max_len)
         self.dropout = float(dropout)
+        self.norm = norm
+        self.norm_eps = float(norm_eps)
+        self.positions = positions
+        self.rope_theta = float(rope_theta)
+        self.qk_norm = bool(qk_norm)
+        self.num_experts = int(num_experts)
+        self.experts_per_token = int(experts_per_token)
+        self.bias = bool(bias)
+        self.tied_head = bool(tied_head)
 
     # ------------------------------------------------------------------
     # shared pieces
@@ -68,83 +110,151 @@ class TransformerLM:
     def _pos_weight(self):
         return sym.Variable("pos_weight", shape=(self.max_len, self.d_model))
 
+    def _norm(self, x, name):
+        """The model's norm as node `name`, with the parameters
+        ``<name>_gamma`` (and ``<name>_beta`` for LayerNorm)."""
+        gamma = sym.Variable(name + "_gamma", shape=(self.d_model,))
+        if self.norm == "rms":
+            return sym.RMSNorm(x, gamma=gamma, eps=self.norm_eps, name=name)
+        beta = sym.Variable(name + "_beta", shape=(self.d_model,))
+        return sym.LayerNorm(x, gamma=gamma, beta=beta, name=name)
+
+    def _linear(self, x, p, key, num_hidden, name):
+        if self.bias:
+            return sym.FullyConnected(
+                x, weight=p[key + "_weight"], bias=p[key + "_bias"],
+                num_hidden=num_hidden, flatten=False, name=name)
+        return sym.FullyConnected(x, weight=p[key + "_weight"],
+                                  num_hidden=num_hidden, no_bias=True,
+                                  flatten=False, name=name)
+
     def _block_params(self, i):
         d, ff = self.d_model, self.d_ff
         v = sym.Variable
-        return {
-            "ln1_gamma": v("l%d_ln1_gamma" % i, shape=(d,)),
-            "ln1_beta": v("l%d_ln1_beta" % i, shape=(d,)),
-            "qkv_weight": v("l%d_qkv_weight" % i, shape=(3 * d, d)),
-            "qkv_bias": v("l%d_qkv_bias" % i, shape=(3 * d,)),
-            "out_weight": v("l%d_out_weight" % i, shape=(d, d)),
-            "out_bias": v("l%d_out_bias" % i, shape=(d,)),
-            "ln2_gamma": v("l%d_ln2_gamma" % i, shape=(d,)),
-            "ln2_beta": v("l%d_ln2_beta" % i, shape=(d,)),
-            "ffn1_weight": v("l%d_ffn1_weight" % i, shape=(ff, d)),
-            "ffn1_bias": v("l%d_ffn1_bias" % i, shape=(ff,)),
-            "ffn2_weight": v("l%d_ffn2_weight" % i, shape=(d, ff)),
-            "ffn2_bias": v("l%d_ffn2_bias" % i, shape=(d,)),
-        }
+        p = {"qkv_weight": v("l%d_qkv_weight" % i, shape=(3 * d, d)),
+             "out_weight": v("l%d_out_weight" % i, shape=(d, d))}
+        if self.bias:
+            p["qkv_bias"] = v("l%d_qkv_bias" % i, shape=(3 * d,))
+            p["out_bias"] = v("l%d_out_bias" % i, shape=(d,))
+        if self.num_experts:
+            e = self.num_experts
+            p["router_weight"] = v("l%d_router_weight" % i, shape=(d, e))
+            p["gate_weight"] = v("l%d_gate_weight" % i, shape=(e, d, ff))
+            p["down_weight"] = v("l%d_down_weight" % i, shape=(e, ff, d))
+            p["up_weight"] = v("l%d_up_weight" % i, shape=(e, d, ff))
+        else:
+            p["ffn1_weight"] = v("l%d_ffn1_weight" % i, shape=(ff, d))
+            p["ffn2_weight"] = v("l%d_ffn2_weight" % i, shape=(d, ff))
+            if self.bias:
+                p["ffn1_bias"] = v("l%d_ffn1_bias" % i, shape=(ff,))
+                p["ffn2_bias"] = v("l%d_ffn2_bias" % i, shape=(d,))
+        return p
 
-    def _qkv(self, x, p, i):
-        qkv = sym.FullyConnected(x, weight=p["qkv_weight"],
-                                 bias=p["qkv_bias"],
-                                 num_hidden=3 * self.d_model,
-                                 flatten=False, name="l%d_qkv" % i)
-        return sym.SliceChannel(qkv, num_outputs=3, axis=2,
-                                name="l%d_qkv_split" % i)
+    def _qkv(self, x, p, i, index=None):
+        """The three projections of the normed stream, ready for the
+        attention op: QK-norm over the whole projections, then rotary
+        positions — each row's own `index` in a decode step, 0..T-1
+        without one — so K reaches the ring already rotated."""
+        qkv = self._linear(x, p, "qkv", 3 * self.d_model, "l%d_qkv" % i)
+        q, k, v = sym.SliceChannel(qkv, num_outputs=3, axis=2,
+                                   name="l%d_qkv_split" % i)
+        if self.qk_norm:
+            q = self._norm(q, "l%d_qnorm" % i)
+            k = self._norm(k, "l%d_knorm" % i)
+        if self.positions == "rotary":
+            rope = dict(num_heads=self.num_heads, theta=self.rope_theta)
+            if index is None:
+                q = sym._rotary(q, name="l%d_qrope" % i, **rope)
+                k = sym._rotary(k, name="l%d_krope" % i, **rope)
+            else:
+                q = sym._rotary_at(q, index, name="l%d_qrope" % i, **rope)
+                k = sym._rotary_at(k, index, name="l%d_krope" % i, **rope)
+        return q, k, v
 
-    def _ffn(self, h, p, i, train):
-        x = sym.LayerNorm(h, gamma=p["ln2_gamma"], beta=p["ln2_beta"],
-                          name="l%d_ln2" % i)
-        f = sym.Activation(
-            sym.FullyConnected(x, weight=p["ffn1_weight"],
-                               bias=p["ffn1_bias"], num_hidden=self.d_ff,
-                               flatten=False, name="l%d_ffn1" % i),
-            act_type="relu", name="l%d_gelu" % i)
-        f = sym.FullyConnected(f, weight=p["ffn2_weight"],
-                               bias=p["ffn2_bias"], num_hidden=self.d_model,
-                               flatten=False, name="l%d_ffn2" % i)
+    def _attn_out(self, ctx, p, i):
+        return self._linear(ctx, p, "out", self.d_model, "l%d_proj" % i)
+
+    def _ffn(self, h, p, i, train, loads=None):
+        """The block's second half on the residual stream `h`.  A routed
+        model's serving graphs pass `loads`, which collects each layer's
+        tokens-per-expert output."""
+        x = self._norm(h, "l%d_ln2" % i)
+        if self.num_experts:
+            f = sym.MoE(x, p["router_weight"], p["gate_weight"],
+                        p["down_weight"], p["up_weight"],
+                        num_experts=self.num_experts, hidden_size=self.d_ff,
+                        k=self.experts_per_token, act_type="silu",
+                        gated=True, no_bias=True, normalize=False,
+                        return_load=loads is not None, name="l%d_moe" % i)
+            if loads is not None:
+                loads.append(f[1])
+                f = f[0]
+        else:
+            f = sym.Activation(
+                self._linear(x, p, "ffn1", self.d_ff, "l%d_ffn1" % i),
+                act_type="relu", name="l%d_gelu" % i)
+            f = self._linear(f, p, "ffn2", self.d_model, "l%d_ffn2" % i)
         if train and self.dropout > 0:
             f = sym.Dropout(f, p=self.dropout, name="l%d_drop" % i)
         return h + f
 
     def _block_train(self, h, i, train):
         p = self._block_params(i)
-        x = sym.LayerNorm(h, gamma=p["ln1_gamma"], beta=p["ln1_beta"],
-                          name="l%d_ln1" % i)
+        x = self._norm(h, "l%d_ln1" % i)
         q, k, v = self._qkv(x, p, i)
         attn = sym._sdp_attention(q, k, v, num_heads=self.num_heads,
                                   causal=True, name="l%d_attn" % i)
-        a = sym.FullyConnected(attn[0], weight=p["out_weight"],
-                               bias=p["out_bias"], num_hidden=self.d_model,
-                               flatten=False, name="l%d_proj" % i)
+        a = self._attn_out(attn[0], p, i)
         if train and self.dropout > 0:
             a = sym.Dropout(a, p=self.dropout, name="l%d_adrop" % i)
         h = h + a
         return self._ffn(h, p, i, train)
 
-    def _trunk(self, data, train):
-        """Embedding + positions + the block stack + final LN; returns
-        hidden states ``(N, T, d_model)``."""
+    def _embed(self, data, index=None):
+        """Token embedding (plus the learned position table's rows: each
+        row's own `index` in a decode step, 0..T-1 without one).
+        Returns (hidden, embed_weight)."""
         embed_w = self._embed_weight()
         h = sym.Embedding(data, weight=embed_w, input_dim=self.vocab,
                           output_dim=self.d_model, name="embed")
-        h = sym._add_positional(h, self._pos_weight(), name="pos_add")
-        for i in range(self.num_layers):
-            h = self._block_train(h, i, train)
-        h = sym.LayerNorm(h, gamma=sym.Variable("ln_f_gamma",
-                                                shape=(self.d_model,)),
-                          beta=sym.Variable("ln_f_beta",
-                                            shape=(self.d_model,)),
-                          name="ln_f")
+        if self.positions == "learned":
+            if index is None:
+                h = sym._add_positional(h, self._pos_weight(),
+                                        name="pos_add")
+            else:
+                h = sym._add_positional_at(h, self._pos_weight(), index,
+                                           name="pos_add")
         return h, embed_w
 
-    def _tied_logits(self, h2d, embed_w, name):
-        """Weight-tied LM head: ``h @ embed_weight^T`` over flattened
-        positions (the tie halves head params and is the reference
-        transformer-LM convention)."""
-        return sym.dot(h2d, embed_w, transpose_b=True, name=name)
+    def _trunk(self, data, train):
+        """Embedding + positions + the block stack + final norm; returns
+        hidden states ``(N, T, d_model)``."""
+        h, embed_w = self._embed(data)
+        for i in range(self.num_layers):
+            h = self._block_train(h, i, train)
+        return self._norm(h, "ln_f"), embed_w
+
+    def _head(self, h2d, embed_w, name):
+        """LM head over flattened positions: ``h @ W^T`` with W the
+        embedding table (the tie halves head params and is the reference
+        transformer-LM convention) or the head's own matrix."""
+        w = embed_w if self.tied_head else sym.Variable(
+            "head_weight", shape=(self.vocab, self.d_model))
+        return sym.dot(h2d, w, transpose_b=True, name=name)
+
+    def _serving_outputs(self, logits, rings, loads):
+        """``[logits, rings..., moe_load]``: a routed model's serving
+        graphs end with tokens per (layer, expert) of this call."""
+        extra = []
+        if loads:
+            extra = [sym.Reshape(sym.Concat(*loads, dim=0),
+                                 shape=(self.num_layers, self.num_experts),
+                                 name="moe_load")]
+        return sym.Group([logits] + rings + extra)
+
+    def extra_outputs(self):
+        """Names of the serving graphs' outputs after the rings."""
+        return ("moe_load",) if self.num_experts else ()
 
     # ------------------------------------------------------------------
     # training
@@ -161,7 +271,7 @@ class TransformerLM:
             label = sym.Variable("softmax_label")
             h, embed_w = self._trunk(data, train=True)
             flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")
-            logits = self._tied_logits(flat, embed_w, "logits")
+            logits = self._head(flat, embed_w, "logits")
             lab = sym.Reshape(label, shape=(-1,), name="label_flat")
             out = sym.SoftmaxOutput(logits, lab, use_ignore=True,
                                     ignore_label=invalid_label,
@@ -185,7 +295,7 @@ class TransformerLM:
         data = sym.Variable("data")
         h, embed_w = self._trunk(data, train=False)
         flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")
-        return self._tied_logits(flat, embed_w, "logits")
+        return self._head(flat, embed_w, "logits")
 
     def cache_names(self):
         """The serving graphs' KV-ring input names, in wire order."""
@@ -211,15 +321,11 @@ class TransformerLM:
         slot = sym.Variable("slot")
         length = sym.Variable("length")
         caches = self._cache_vars()
-        embed_w = self._embed_weight()
-        h = sym.Embedding(data, weight=embed_w, input_dim=self.vocab,
-                          output_dim=self.d_model, name="embed")
-        h = sym._add_positional(h, self._pos_weight(), name="pos_add")
-        outs = []
+        h, embed_w = self._embed(data)
+        outs, loads = [], [] if self.num_experts else None
         for i in range(self.num_layers):
             p = self._block_params(i)
-            x = sym.LayerNorm(h, gamma=p["ln1_gamma"], beta=p["ln1_beta"],
-                              name="l%d_ln1" % i)
+            x = self._norm(h, "l%d_ln1" % i)
             q, k, v = self._qkv(x, p, i)
             attn = sym._sdp_attention(q, k, v, num_heads=self.num_heads,
                                       causal=True, name="l%d_attn" % i)
@@ -227,21 +333,13 @@ class TransformerLM:
                 caches["k_cache_%d" % i], caches["v_cache_%d" % i],
                 attn[1], attn[2], slot, name="l%d_kv_write" % i)
             outs += [wrote[0], wrote[1]]
-            a = sym.FullyConnected(attn[0], weight=p["out_weight"],
-                                   bias=p["out_bias"],
-                                   num_hidden=self.d_model,
-                                   flatten=False, name="l%d_proj" % i)
-            h = h + a
-            h = self._ffn(h, p, i, train=False)
-        h = sym.LayerNorm(h, gamma=sym.Variable("ln_f_gamma",
-                                                shape=(self.d_model,)),
-                          beta=sym.Variable("ln_f_beta",
-                                            shape=(self.d_model,)),
-                          name="ln_f")
+            h = h + self._attn_out(attn[0], p, i)
+            h = self._ffn(h, p, i, train=False, loads=loads)
+        h = self._norm(h, "ln_f")
         # logits at the prompt's true tail, not the pad
         last = sym._take_step(h, length - 1, name="last_h")
-        logits = self._tied_logits(last, embed_w, "next_logits")
-        return sym.Group([logits] + outs)
+        logits = self._head(last, embed_w, "next_logits")
+        return self._serving_outputs(logits, outs, loads)
 
     def decode_symbol(self):
         """One decode step for a packed session batch: inputs ``data
@@ -252,33 +350,20 @@ class TransformerLM:
         slot = sym.Variable("slot")
         length = sym.Variable("length")
         caches = self._cache_vars()
-        embed_w = self._embed_weight()
-        h = sym.Embedding(data, weight=embed_w, input_dim=self.vocab,
-                          output_dim=self.d_model, name="embed")
-        h = sym._add_positional_at(h, self._pos_weight(), length,
-                                   name="pos_add")
-        outs = []
+        h, embed_w = self._embed(data, index=length)
+        outs, loads = [], [] if self.num_experts else None
         for i in range(self.num_layers):
             p = self._block_params(i)
-            x = sym.LayerNorm(h, gamma=p["ln1_gamma"], beta=p["ln1_beta"],
-                              name="l%d_ln1" % i)
-            q, k, v = self._qkv(x, p, i)
+            x = self._norm(h, "l%d_ln1" % i)
+            q, k, v = self._qkv(x, p, i, index=length)
             step = sym._cached_attention(
                 q, k, v, caches["k_cache_%d" % i],
                 caches["v_cache_%d" % i], slot, length,
                 num_heads=self.num_heads, name="l%d_attn" % i)
             outs += [step[1], step[2]]
-            a = sym.FullyConnected(step[0], weight=p["out_weight"],
-                                   bias=p["out_bias"],
-                                   num_hidden=self.d_model,
-                                   flatten=False, name="l%d_proj" % i)
-            h = h + a
-            h = self._ffn(h, p, i, train=False)
-        h = sym.LayerNorm(h, gamma=sym.Variable("ln_f_gamma",
-                                                shape=(self.d_model,)),
-                          beta=sym.Variable("ln_f_beta",
-                                            shape=(self.d_model,)),
-                          name="ln_f")
+            h = h + self._attn_out(step[0], p, i)
+            h = self._ffn(h, p, i, train=False, loads=loads)
+        h = self._norm(h, "ln_f")
         flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")
-        logits = self._tied_logits(flat, embed_w, "next_logits")
-        return sym.Group([logits] + outs)
+        logits = self._head(flat, embed_w, "next_logits")
+        return self._serving_outputs(logits, outs, loads)

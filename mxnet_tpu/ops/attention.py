@@ -23,6 +23,14 @@ needs three graph-level primitives beyond the classic registry:
   decode program serves every session mix — sessions join/leave
   between steps without recompiling.
 
+The block vocabulary of current open decoders rides beside them:
+``RMSNorm`` and the rotary pair ``_rotary`` / ``_rotary_at`` (positions
+0..T-1 of a full sequence, or each row's own traced position — the
+same split as ``_add_positional`` / ``_add_positional_at``).  Rotary
+turns Q and K BEFORE the attention ops see them, so the ring holds
+rotated keys and ``_sdp_attention`` / ``_cached_attention`` /
+``_kv_cache_write`` are the same for learned and rotary positions.
+
 Everything is pure jnp/lax: the ops trace into the surrounding XLA
 executable on CPU and TPU alike (the blockwise/ring Pallas kernels in
 parallel/ remain the long-context training path; decode works on
@@ -68,6 +76,68 @@ def layer_norm(data, gamma, beta, axis=-1, eps=1e-5, **kw):
     mean = jnp.mean(data, axis=axis, keepdims=True)
     var = jnp.var(data, axis=axis, keepdims=True)
     return (data - mean) * lax.rsqrt(var + eps) * gamma + beta
+
+
+def _infer_rms(in_shapes, attrs):
+    data = in_shapes[0]
+    return [data, (data[-1],)], [data]
+
+
+@register("RMSNorm", inputs=("data", "gamma"), infer_shape=_infer_rms)
+def rms_norm(data, gamma, eps=1e-5, **kw):
+    """Root-mean-square normalization over the last axis with a learned
+    gain and no shift (Zhang & Sennrich 2019): ``x / sqrt(mean(x^2) +
+    eps) * gamma``, the statistics in float32."""
+    x = data.astype(jnp.float32)
+    scale = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                      + float(_lit(eps)))
+    return (x * scale).astype(data.dtype) * gamma
+
+
+# ----------------------------------------------------------------------
+# rotary positions
+# ----------------------------------------------------------------------
+
+
+def _rotate(data, positions, num_heads, theta):
+    """Rotate each head of ``data (N, T, d_model)`` by its row's
+    ``positions (N, T)``: the rotate-half convention over the WHOLE head
+    (pairs ``(i, i + d_head/2)``, angle ``pos * theta^(-2i/d_head)``),
+    angles and products in float32."""
+    h = int(_lit(num_heads))
+    n, t, d = data.shape
+    half = d // h // 2
+    inv_freq = float(_lit(theta)) ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x = data.astype(jnp.float32).reshape(n, t, h, 2, half)
+    x1, x2 = x[:, :, :, 0], x[:, :, :, 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=3)
+    return out.reshape(n, t, d).astype(data.dtype)
+
+
+def _infer_same(in_shapes, attrs):
+    return list(in_shapes), [in_shapes[0]]
+
+
+@register("_rotary", inputs=("data",), infer_shape=_infer_same)
+def rotary(data, num_heads=1, theta=10000.0, **kw):
+    """Rotary position embedding of a full sequence ``(N, T, d_model)``:
+    row t sits at position t (training / prefill)."""
+    n, t, _ = data.shape
+    pos = jnp.broadcast_to(jnp.arange(t)[None, :], (n, t))
+    return _rotate(data, pos, num_heads, theta)
+
+
+@register("_rotary_at", inputs=("data", "index"), infer_shape=_infer_same)
+def rotary_at(data, index, num_heads=1, theta=10000.0, **kw):
+    """Rotary position embedding where row b's first token sits at
+    ``index[b]`` — the decode step's ``length``, a traced operand, so
+    one compiled program serves every position."""
+    t = data.shape[1]
+    pos = _as_index(index)[:, None] + jnp.arange(t)[None, :]
+    return _rotate(data, pos, num_heads, theta)
 
 
 # ----------------------------------------------------------------------

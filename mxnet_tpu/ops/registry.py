@@ -39,6 +39,7 @@ class Op:
         "name",
         "fn",
         "inputs",
+        "inputs_for",
         "aux",
         "num_outputs",
         "infer_shape",
@@ -59,6 +60,7 @@ class Op:
         name,
         fn,
         inputs=("data",),
+        inputs_for=None,
         aux=(),
         num_outputs=1,
         infer_shape=None,
@@ -76,6 +78,11 @@ class Op:
         self.name = name
         self.fn = fn
         self.inputs = tuple(inputs)
+        # inputs_for(attrs) -> the slots THIS node takes, for an op whose
+        # operand list depends on its attributes beyond a trailing bias
+        # (MoE: gated experts add a third matrix, no_bias drops three
+        # vectors from the middle); `inputs` stays the default list
+        self.inputs_for = inputs_for
         self.aux = tuple(aux)
         self.num_outputs = num_outputs
         self.infer_shape = infer_shape
@@ -101,6 +108,14 @@ class Op:
         self.doc = doc
         # declarative parameter specs (dmlc::Parameter analog, ops/params.py)
         self.params = params
+
+
+    def list_inputs(self, attrs):
+        """Input slot names of a node of this op with attributes `attrs`
+        (FListInputNames, which takes the attrs in the reference too)."""
+        if self.inputs_for is None:
+            return self.inputs
+        return tuple(self.inputs_for(attrs))
 
 
 def register(name, **kwargs):

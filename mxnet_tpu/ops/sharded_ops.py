@@ -13,8 +13,9 @@ hidden_size=1024, k=2)` inside an ordinary model file, trained with
 counterpart exists (SURVEY.md §2.5 marks EP absent from the 2017
 reference); the design is the GShard/GSPMD dense-einsum formulation:
 
-  * capacity-bounded top-k routing (parallel/moe.py top_k_gating — the
-    SAME router as the shard_map library path, so both lower identically)
+  * with `capacity_factor`: capacity-bounded top-k routing (parallel/
+    moe.py top_k_gating — the SAME router as the shard_map library path,
+    so both lower identically)
   * dispatch/combine einsums over a static [T, E, C] routing tensor —
     shape-static, fully differentiable (gate gradients flow through the
     combine weights), one XLA program
@@ -27,6 +28,10 @@ reference); the design is the GShard/GSPMD dense-einsum formulation:
 
 Without a mesh (or without an 'expert' axis) the same math runs dense —
 single-device numerics are identical by construction.
+
+Without `capacity_factor` the op is the DROPLESS layer open decoders
+run (OLMoE: `gated`, `no_bias`, `act_type="silu"`, `normalize=False`):
+sort-and-segment (parallel/moe.py dropless_experts), no [T, E, C].
 """
 from __future__ import annotations
 
@@ -37,8 +42,21 @@ from .registry import register
 from .tensor import _lit
 
 
-def _f(v, default):
-    return float(_lit(v)) if v is not None else default
+def _moe_inputs(attrs):
+    """data, the router, then expert matrix i (and its bias): 1 = in,
+    2 = out, 3 = the gated branch's second in-projection."""
+    gated = _bool_attr(attrs.get("gated", False))
+    no_bias = _bool_attr(attrs.get("no_bias", False))
+    names = ["data", "gate_weight"]
+    for i in (1, 2, 3) if gated else (1, 2):
+        names.append("expert%d_weight" % i)
+        if not no_bias:
+            names.append("expert%d_bias" % i)
+    return names
+
+
+def _moe_outputs(attrs):
+    return 2 if _bool_attr(attrs.get("return_load", False)) else 1
 
 
 def _infer_moe(in_shapes, attrs):
@@ -46,8 +64,12 @@ def _infer_moe(in_shapes, attrs):
     E = int(_lit(attrs["num_experts"]))
     H = int(_lit(attrs["hidden_size"]))
     D = data[-1]
-    shapes = [data, (D, E), (E, D, H), (E, H), (E, H, D), (E, D)]
-    return shapes, [tuple(data)]
+    by_slot = {"data": data, "gate_weight": (D, E),
+               "expert1_weight": (E, D, H), "expert1_bias": (E, H),
+               "expert2_weight": (E, H, D), "expert2_bias": (E, D),
+               "expert3_weight": (E, D, H), "expert3_bias": (E, H)}
+    outs = [tuple(data)] + [(E,)] * (_moe_outputs(attrs) - 1)
+    return [by_slot[n] for n in _moe_inputs(attrs)], outs
 
 
 def _constrain(x, mesh, spec):
@@ -60,48 +82,80 @@ def _constrain(x, mesh, spec):
     "MoE",
     inputs=("data", "gate_weight", "expert1_weight", "expert1_bias",
             "expert2_weight", "expert2_bias"),
+    inputs_for=_moe_inputs,
+    num_outputs=_moe_outputs,
     aliases=("_contrib_MoE",),
     infer_shape=_infer_moe,
     need_mesh=True,
-    input_axes={"expert1_weight": "expert", "expert1_bias": "expert",
-                "expert2_weight": "expert", "expert2_bias": "expert"},
+    input_axes={"expert%d_%s" % (i, w): "expert"
+                for i in (1, 2, 3) for w in ("weight", "bias")},
 )
-def moe(data, gate_weight, w1, b1, w2, b2, num_experts, hidden_size,
-        k=2, capacity_factor=1.0, mesh=None, **kw):
-    """Top-k routed expert FFN: out[t] = sum_e gate[t,e] *
-    (relu(x[t] @ w1[e] + b1[e]) @ w2[e] + b2[e]) over t's top-k experts,
-    capacity-bounded (overflow tokens pass through with zero expert term,
-    Switch-Transformer semantics)."""
-    from ..parallel.moe import top_k_gating
+def moe(data, gate_weight, *experts, num_experts, hidden_size, k=2,
+        capacity_factor=None, act_type="relu", gated=False, no_bias=False,
+        normalize=True, return_load=False, mesh=None, **kw):
+    """Top-k routed expert FFN: out[t] = sum_e gate[t,e] * FFN_e(x[t])
+    over t's top-k experts, FFN_e = ``act(x w1 + b1) @ w2 + b2``, or with
+    `gated` ``(act(x w1 + b1) * (x w3 + b3)) @ w2 + b2``; `no_bias`
+    leaves the vectors out (the operands are then the matrices alone).
+    `normalize` renormalises the router's softmax scores over the
+    experts a token keeps; false uses them as they are.
+
+    Without `capacity_factor` the layer is DROPLESS (parallel/moe.py
+    dropless_experts: sort by expert, one segment matmul an expert).
+    With it, each expert holds ``capacity_factor * k * T / E`` tokens
+    and overflow tokens pass through with a zero expert term (Switch-
+    Transformer semantics) — the shape-static GShard form whose
+    expert-major tensors the 'expert' mesh axis shards.  The router runs
+    in float32 at `highest` precision in both.  `return_load` adds a
+    second output, tokens per expert [E]."""
+    from ..parallel import moe as _moe
     from ..parallel.mesh import P
 
     E = int(_lit(num_experts))
     kk = int(_lit(k))
-    cf = _f(capacity_factor, 1.0)
+    gated, no_bias = _bool_attr(gated), _bool_attr(no_bias)
+    normalize = _bool_attr(normalize)
+    act = str(_lit(act_type))
+    step = 1 if no_bias else 2
+    weights = experts[0::step]
+    biases = None if no_bias else experts[1::step]
     lead = data.shape[:-1]
     d_model = data.shape[-1]
     x = data.reshape(-1, d_model)
     T = x.shape[0]
-    capacity = max(1, int(cf * kk * T // E))
 
-    ep = mesh is not None and "expert" in mesh.axis_names
-
-    logits = x.astype(jnp.float32) @ gate_weight.astype(jnp.float32)
-    dispatch, combine = top_k_gating(logits, kk, capacity)     # [T, E, C]
-
-    xe = jnp.einsum("tec,td->ecd", dispatch, x.astype(jnp.float32))
-    if ep:
-        # expert-major tensors live on the 'expert' axis; GSPMD derives
-        # the dispatch/return all_to_all from this constraint pair
-        xe = _constrain(xe, mesh, P("expert"))
-    he = jax.nn.relu(jnp.einsum("ecd,edh->ech", xe, w1.astype(jnp.float32))
-                     + b1.astype(jnp.float32)[:, None, :])
-    ye = jnp.einsum("ech,ehd->ecd", he, w2.astype(jnp.float32)) \
-        + b2.astype(jnp.float32)[:, None, :]
-    if ep:
-        ye = _constrain(ye, mesh, P("expert"))
-    out = jnp.einsum("tec,ecd->td", combine, ye)
-    return out.reshape(lead + (d_model,)).astype(data.dtype)
+    with jax.named_scope("mx:moe.route"):
+        logits = _moe.router_logits(x, gate_weight)
+    if capacity_factor is None:
+        with jax.named_scope("mx:moe.experts"):
+            out, load = _moe.dropless_experts(
+                x, logits, kk, weights, biases, act, gated, normalize)
+    else:
+        capacity = max(1, int(float(_lit(capacity_factor)) * kk * T // E))
+        ep = mesh is not None and "expert" in mesh.axis_names
+        with jax.named_scope("mx:moe.route"):
+            dispatch, combine = _moe.top_k_gating(
+                logits, kk, capacity, normalize)               # [T, E, C]
+        with jax.named_scope("mx:moe.experts"):
+            f32 = jnp.float32
+            xe = jnp.einsum("tec,td->ecd", dispatch, x.astype(f32))
+            if ep:
+                # expert-major tensors live on the 'expert' axis; GSPMD
+                # derives the dispatch/return all_to_all from this
+                # constraint pair
+                xe = _constrain(xe, mesh, P("expert"))
+            ye = _moe.expert_ffn(
+                lambda r, w: jnp.einsum("eci,eio->eco", r, w.astype(f32)),
+                xe, weights,
+                None if no_bias else [b.astype(f32)[:, None, :]
+                                      for b in biases],
+                act, gated)
+            if ep:
+                ye = _constrain(ye, mesh, P("expert"))
+            out = jnp.einsum("tec,ecd->td", combine, ye)
+            load = dispatch.sum((0, 2))
+    out = out.reshape(lead + (d_model,)).astype(data.dtype)
+    return (out, load) if _bool_attr(return_load) else out
 
 
 # ----------------------------------------------------------------------

@@ -8,6 +8,13 @@ routed with a capacity-bounded top-k gate, and the dispatch/combine is
 TPU torus (the same primitive Ulysses SP uses, parallel/ring_attention.py).
 
 Pieces:
+  * router_logits / dropless_experts — the dropless layer of ONE device
+    (what today's open MoE decoders run): every token-expert pair is
+    computed.  Pairs are sorted by expert and each expert multiplies its
+    own contiguous segment (`lax.ragged_dot`: on the TPU a grouped
+    matmul that visits only the tiles a non-empty segment touches, so a
+    decode step reads the experts that were hit and a prefill runs one
+    matmul a segment); no [T, E, C] tensor exists.
   * top_k_gating(logits, k, capacity) — deterministic capacity-bounded
     router (Switch/GShard-style): per-expert position via a cumulative
     count, tokens over capacity dropped (combine weight 0).
@@ -30,10 +37,72 @@ from jax import lax
 from .collectives import axis_size, shard_map, shard_map_unchecked
 from .mesh import P
 
-__all__ = ["top_k_gating", "moe_apply", "moe_sharded"]
+__all__ = ["router_logits", "dropless_experts", "top_k_gating", "moe_apply",
+           "moe_sharded"]
+
+ACTIVATIONS = {"relu": jax.nn.relu, "silu": jax.nn.silu}
 
 
-def top_k_gating(logits, k, capacity):
+def router_logits(x, gate_w):
+    """Router scores [T, E] in float32: the matmul at `highest`
+    precision whatever the surrounding default (D x E a token — a
+    bfloat16 pass here moves which experts a token gets)."""
+    return jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+
+
+def expert_ffn(matmul, x, weights, biases, act, gated):
+    """One expert FFN over rows that `matmul(rows, w)` / `biases(b)` map
+    to their experts: ``act(x w1 + b1) [* (x w3 + b3)] -> w2 + b2``.
+    `weights` is (w1, w2[, w3]); `biases` the same order or None."""
+    def lin(rows, i):
+        y = matmul(rows, weights[i])
+        return y if biases is None else y + biases[i]
+
+    h = ACTIVATIONS[act](lin(x, 0))
+    if gated:
+        h = h * lin(x, 2)
+    return lin(h, 1)
+
+
+def dropless_experts(x, logits, k, weights, biases=None, act="relu",
+                     gated=False, normalize=True):
+    """Every token through its k best experts, none dropped.
+
+    x [T, D]; logits [T, E] router scores (softmax here, float32);
+    weights (w1 [E, D, H],
+    w2 [E, H, D][, w3 [E, D, H]]), biases likewise ([E, H] / [E, D]) or
+    None.  The T*k (token, expert) pairs are sorted by expert, each
+    expert multiplies its contiguous segment of rows, and the results
+    return to token order weighted by the router score (renormalised
+    over the k kept when `normalize`).  Returns (out [T, D], load [E]
+    — tokens per expert, float32).  Differentiable in x, probs and the
+    expert parameters."""
+    t_len, n_exp = logits.shape
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    top_w, top_e = lax.top_k(probs, k)                     # [T, k]
+    if normalize:
+        top_w = top_w / top_w.sum(-1, keepdims=True)
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)               # pair -> sorted
+    sorted_e = flat_e[order]
+    load = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1)
+    rows = x[order // k]                                   # [T*k, D]
+
+    def matmul(r, w):
+        return lax.ragged_dot(r, w.astype(r.dtype), load)
+
+    ys = expert_ffn(matmul, rows, weights,
+                    None if biases is None
+                    else [b.astype(x.dtype)[sorted_e] for b in biases],
+                    act, gated)
+    pairs = ys[jnp.argsort(order)].reshape(t_len, k, -1)   # token order
+    # on the vector unit: a matmul would round the scores to bfloat16
+    out = (pairs * top_w.astype(pairs.dtype)[:, :, None]).sum(1)
+    return out, load.astype(jnp.float32)
+
+
+def top_k_gating(logits, k, capacity, normalize=True):
     """Capacity-bounded top-k routing.
 
     logits: [T, E] router scores.  Returns (dispatch, combine):
@@ -41,7 +110,8 @@ def top_k_gating(logits, k, capacity):
       combine  [T, E, C] float:   dispatch * softmax gate weight
     Tokens beyond `capacity` of an expert are dropped (zero combine),
     matching Switch-Transformer semantics; position assignment is by
-    token order (deterministic, shape-static).
+    token order (deterministic, shape-static).  `normalize`
+    renormalises the scores over the experts a token KEEPS.
     """
     t_len, n_exp = logits.shape
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
@@ -55,8 +125,8 @@ def top_k_gating(logits, k, capacity):
     slot = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)  # [T, E, C]
     dispatch = slot * keep[..., None]
     gates = probs * keep
-    denom = gates.sum(-1, keepdims=True)
-    gates = gates / jnp.maximum(denom, 1e-9)              # renormalize kept
+    if normalize:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     combine = dispatch * gates[..., None]
     return dispatch, combine
 
@@ -79,8 +149,8 @@ def moe_apply(expert_fn, params, x, gate_w, k=1, capacity_factor=1.0,
     n_exp = n_shards * local_experts
     capacity = max(1, int(capacity_factor * k * t_local // n_exp))
 
-    logits = x.astype(jnp.float32) @ gate_w.astype(jnp.float32)
-    dispatch, combine = top_k_gating(logits, k, capacity)  # [T,E,C]
+    dispatch, combine = top_k_gating(router_logits(x, gate_w), k,
+                                     capacity)             # [T,E,C]
 
     # gather expert inputs: [E, C, D] on every shard, then all_to_all so
     # shard s ends up with ITS experts' slots from ALL shards:

@@ -146,6 +146,9 @@ class GenerativeSession:
             else config.get("MXTPU_SERVE_MAX_DECODE_TOKENS"))
         self._eos_default = None if eos_id is None else int(eos_id)
         self._cache_names = list(model.cache_names())
+        # a routed model's programs end with tokens per (layer, expert)
+        self._reports_moe_load = "moe_load" in tuple(
+            getattr(model, "extra_outputs", tuple)())
         self._input_names = ["data", "slot", "length"] + self._cache_names
         cshape = (self._slots + 1, model.num_heads, self._max_len,
                   model.d_head)
@@ -265,23 +268,24 @@ class GenerativeSession:
         n = 0
         for t in self._seq_ladder:
             exe, fn = self._program(self._prefill_pred, 1, t, True)
-            _, rings = self._call(exe, fn, rings,
-                                  _np.zeros((1, t), _np.float32),
-                                  _np.full((1,), self._slots, _np.float32),
-                                  _np.ones((1,), _np.float32))
+            _, rings, _ = self._call(
+                exe, fn, rings, _np.zeros((1, t), _np.float32),
+                _np.full((1,), self._slots, _np.float32),
+                _np.ones((1,), _np.float32))
             n += 1
         for b in self._decode_ladder:
             exe, fn = self._program(self._decode_pred, b, 1, False)
-            _, rings = self._call(exe, fn, rings,
-                                  _np.zeros((b, 1), _np.float32),
-                                  _np.full((b,), self._slots, _np.float32),
-                                  _np.zeros((b,), _np.float32))
+            _, rings, _ = self._call(
+                exe, fn, rings, _np.zeros((b, 1), _np.float32),
+                _np.full((b,), self._slots, _np.float32),
+                _np.zeros((b,), _np.float32))
             n += 1
         return n
 
     def _call(self, exe, fn, rings, data, slot, length, hists=_NO_HISTS):
         """One program call threading `rings` through: returns (host
-        logits, updated rings).  The rings passed in are donated on
+        logits, updated rings, host outputs after the rings — a routed
+        model's `moe_load`).  The rings passed in are donated on
         device backends — the caller keeps only what comes back.
         `hists` names the histograms of the three legs (dispatch, device
         wait, read-back); only a decode step passes them."""
@@ -294,22 +298,42 @@ class GenerativeSession:
             # request the logits' copy behind the program, as np.asarray
             # itself does first: a copy requested only after the fence
             # below costs one more host round trip a call
-            outs[0].copy_to_host_async()
+            n_rings = len(rings)
+            small = (outs[0],) + tuple(outs[1 + n_rings:])
+            for o in small:
+                o.copy_to_host_async()
         # the fence np.asarray would perform anyway, made explicit so
         # that waiting for the device and copying are two numbers
         with profiler.span("decode.device_wait", cat="serving",
                            hist=hists[1]):
             outs[0].block_until_ready()
         with profiler.span("decode.d2h", cat="serving", hist=hists[2]):
-            logits = _np.asarray(outs[0])
-        return logits, list(outs[1:])
+            logits, *extra = (_np.asarray(o) for o in small)
+        return logits, list(outs[1:1 + n_rings]), extra
 
     def _run(self, exe, fn, data, slot, length, hists=_NO_HISTS):
         """One LIVE program call: the session's rings go in, the updated
         rings replace them; returns the host logits."""
-        logits, self._caches = self._call(exe, fn, self._caches, data,
-                                          slot, length, hists)
+        logits, self._caches, extra = self._call(
+            exe, fn, self._caches, data, slot, length, hists)
+        if self._reports_moe_load:
+            self._book_moe_load(extra[0])
         return logits
+
+    @staticmethod
+    def _book_moe_load(load):
+        """The `moe.*` counters of one program call from its `moe_load
+        (layers, experts)` output: token-expert pairs computed (padded
+        rows included — the device computed them), experts that got at
+        least one token, expert slots offered, and the fullest expert's
+        tokens, each summed over the layers."""
+        from .. import telemetry
+
+        if telemetry.enabled():
+            telemetry.inc("moe.pairs", int(load.sum()))
+            telemetry.inc("moe.experts_hit", int((load > 0).sum()))
+            telemetry.inc("moe.expert_slots", int(load.size))
+            telemetry.inc("moe.max_load", int(load.max(axis=-1).sum()))
 
     # ------------------------------------------------------------------
     # admission: prefill newly-arrived prompts into free slots

@@ -183,13 +183,20 @@ class ModelServer:
                     "got %r" % (name, slo_target))
             slo = (float(slo_ms) / 1e3, target)
         # byte-budget admission: predict the footprint ANALYTICALLY —
-        # two parameter copies (prefill + decode predictors) plus the
+        # the parameters as often as the device will hold them, plus the
         # KV ring shape GenerativeSession will allocate — so refusal
-        # happens before any compile or ring allocation
+        # happens before any compile or ring allocation.  The prefill and
+        # the decode predictor bind the arrays they are given: NDArrays
+        # already on the tenant's device are held once, anything else is
+        # placed by each predictor for itself
         from .. import config
+        from ..ndarray import NDArray
         from ..obs import memory
 
+        ctx = ctx or current_context()
         param_bytes = sum(memory.nbytes_of(v) for v in params.values())
+        shared = all(isinstance(v, NDArray) and v.context == ctx
+                     for v in params.values())
         slots = int(max_sessions if max_sessions is not None
                     else config.get("MXTPU_SERVE_MAX_SESSIONS"))
         ring_len = int(max_len if max_len is not None
@@ -198,8 +205,8 @@ class ModelServer:
         ring_bytes = ((slots + 1) * int(model.num_heads) * ring_len
                       * int(model.d_head) * 4 * len(model.cache_names()))
         memory.admit("generative tenant %r" % name,
-                     2 * param_bytes + ring_bytes,
-                     device=(ctx or current_context()).jax_device())
+                     (1 if shared else 2) * param_bytes + ring_bytes,
+                     device=ctx.jax_device())
         # build outside the lock — Predictor construction compiles the
         # smallest prefill/decode buckets and must not stall submits
         session = GenerativeSession(

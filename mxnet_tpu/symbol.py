@@ -521,7 +521,7 @@ def _create(op_name, input_syms, attrs, name=None, aux_syms=None):
     # auto-create missing weight/bias variables (parity: nnvm Symbol compose
     # auto-creating named variable nodes for unbound op inputs)
     if not op.variadic:
-        declared = op.inputs
+        declared = op.list_inputs(full_attrs)
         while len(inputs) < len(declared):
             in_name = "%s_%s" % (name, declared[len(inputs)])
             from .ops.tensor import _bool as _b
