@@ -23,6 +23,10 @@ apart from the rest:
   generate  TransformerLM via add_generative_tenant + submit_generate;
             one session's prefill/decode logits against the
             full-recompute score_symbol forward
+  kv_ring   the decode program of an OPT-shaped TransformerLM (32 heads
+            of 64, 8 sessions, ring 768) as XLA compiled it: every KV
+            ring parameter aliased to its output, no instruction that
+            copies a ring; prints the rings' on-device layout
   kernel    ops/pallas_kernels.bn_stats under Mosaic at two ResNet-50
             shapes against the jnp reduction
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
@@ -38,6 +42,7 @@ seconds, compile seconds and facts.  The phase functions take
 tiny on mx.cpu(); main() has no switch that skips the device check.
 """
 import json
+import re
 import sys
 import threading
 import time
@@ -53,6 +58,9 @@ FULL = {
                  "d_model": 512, "max_len": 320, "max_sessions": 4,
                  "seq_buckets": [16, 64], "prompts": 8, "new_tokens": 32,
                  "check_steps": 8, "seed": 2},
+    "kv_ring": {"vocab": 8192, "num_layers": 2, "num_heads": 32,
+                "d_model": 2048, "d_ff": 2048, "max_len": 768,
+                "max_sessions": 8, "seq_buckets": [64], "seed": 5},
     "kernel": {"shapes": [(512, 56, 56, 64), (512, 7, 7, 2048)], "seed": 3},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
@@ -417,6 +425,82 @@ def phase_generate(sizes, ctx):
             "bytes_limit": limit, "max_rel_err": float("%.3g" % worst)}
 
 
+def ring_hlo_facts(text, ring_shape):
+    """What a compiled decode program does with its KV rings, read from
+    its optimised HLO `text`: the entry parameters of `ring_shape` with
+    their on-device layouts, which of them ``input_output_alias`` gives
+    to an output, and every instruction — entry or fused — that copies
+    an array of the ring's shape (``copy``, or the asynchronous
+    ``copy-start``)."""
+    dims = re.escape(",".join(str(d) for d in ring_shape))
+    entry = text[text.index("ENTRY"):]
+    params = {int(n): layout for layout, n in re.findall(
+        r"= f32\[%s\](\{\S*\})? parameter\((\d+)\)" % dims, entry)}
+    alias = re.search(r"input_output_alias=\{(.*?)\}, entry_computation",
+                      text, re.S)
+    aliased = set(int(n) for n in re.findall(
+        r"\((\d+), \{[\d, ]*\}", alias.group(1))) if alias else set()
+    copies = [line.strip()[:160] for line in text.splitlines()
+              if re.search(r"f32\[%s\]" % dims, line)
+              and re.search(r" (copy|copy-start)\(", line)]
+    return {"ring_params": len(params),
+            "layouts": sorted(set(params.values())),
+            "aliased": len(aliased & set(params)), "copies": copies}
+
+
+def phase_kv_ring(sizes, ctx):
+    """The decode step touches a KV ring where it lies (PERF.md section
+    6, PR 26): compile the largest decode bucket of an OPT-shaped LM and
+    read what XLA made of the rings.  Only a device backend donates, so
+    only there are aliasing and copies judged; on the CPU the phase
+    still compiles, runs and parses."""
+    import numpy as np
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.models import TransformerLM
+
+    lm = TransformerLM(vocab=sizes["vocab"], num_layers=sizes["num_layers"],
+                       num_heads=sizes["num_heads"], d_model=sizes["d_model"],
+                       d_ff=sizes["d_ff"], max_len=sizes["max_len"])
+    slots = sizes["max_sessions"]
+    ring = tuple(lm.cache_shape(slots + 1))
+    step = lm.decode_symbol()
+    inputs = dict(data=(slots, 1), slot=(slots,), length=(slots,),
+                  **{n: ring for n in lm.cache_names()})
+    shapes, _, _ = step.infer_shape(**inputs)
+    rng = np.random.RandomState(sizes["seed"])
+    params = {n: mx.nd.array((rng.randn(*s) * 0.02).astype(np.float32),
+                             ctx=ctx)
+              for n, s in zip(step.list_arguments(), shapes)
+              if n not in inputs}
+    server = mx.serving.ModelServer({})
+    try:
+        session = server.add_generative_tenant(
+            "ring", lm, params, ctx=ctx, max_sessions=slots,
+            max_len=sizes["max_len"], seq_buckets=sizes["seq_buckets"])
+        server.warmup()
+        _exe, fn = session._program(session._decode_pred, slots, 1, False)
+        facts = ring_hlo_facts(fn.hlo_text(), ring)
+    finally:
+        server.close()
+    print("[chip_smoke] kv_ring: %d ring parameters f32%s, layout(s) %s; "
+          "%d aliased to an output; %d ring copies"
+          % (facts["ring_params"], list(ring), facts["layouts"],
+             facts["aliased"], len(facts["copies"])), flush=True)
+    _check(facts["ring_params"] == len(lm.cache_names()),
+           "found %d ring parameters of %d in the decode program's HLO"
+           % (facts["ring_params"], len(lm.cache_names())))
+    if ctx.jax_device().platform != "cpu":
+        _check(facts["aliased"] == facts["ring_params"],
+               "only %d of %d KV ring parameters are aliased to an output: "
+               "the rest are rewritten whole every step"
+               % (facts["aliased"], facts["ring_params"]))
+        _check(not facts["copies"], "the decode program copies a KV ring: "
+               + "; ".join(facts["copies"][:4]))
+    facts["copies"] = len(facts["copies"])
+    return facts
+
+
 def phase_kernel(sizes, ctx):
     import jax
     import jax.numpy as jnp
@@ -555,6 +639,7 @@ def main():
     run_phase("serve", phase_serve, FULL["serve"], ctx, clock, report)
     run_phase("generate", phase_generate, FULL["generate"], ctx, clock,
               report)
+    run_phase("kv_ring", phase_kv_ring, FULL["kv_ring"], ctx, clock, report)
     run_phase("kernel", phase_kernel, FULL["kernel"], ctx, clock, report)
     if jax.device_count() >= 4:
         run_phase("four_chips", phase_four_chips, FULL["four_chips"],

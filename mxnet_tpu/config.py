@@ -261,9 +261,9 @@ REGISTRY = [
            "generative tenant — the hard cap on concurrently decoding "
            "sessions (admission control: a prompt past the cap waits "
            "queued until a session retires and frees its slot). The "
-           "device ring is preallocated at (slots+1, heads, "
-           "MXTPU_SERVE_KV_MAX_LEN, d_head) per layer — +1 is the "
-           "scratch slot padded decode rows write into"),
+           "device ring is preallocated at the model's "
+           "cache_shape(slots+1, MXTPU_SERVE_KV_MAX_LEN) per layer — +1 "
+           "is the scratch slot padded decode rows write into"),
     EnvVar("MXTPU_SERVE_MAX_DECODE_TOKENS", int, 64,
            "Default per-session generation budget: a decode session "
            "retires (future resolves, slot freed) after this many new "

@@ -304,10 +304,23 @@ class TransformerLM:
             names += ["k_cache_%d" % i, "v_cache_%d" % i]
         return names
 
-    def cache_shape(self, slots):
-        """Per-layer ring shape for `slots` sessions (callers add the
-        +1 scratch slot themselves — serving/decode.py owns that)."""
-        return (slots, self.num_heads, self.max_len, self.d_head)
+    def cache_shape(self, slots, max_len=None):
+        """THE stored shape of one layer's K ring, and of its V ring, for
+        `slots` pages of `max_len` positions (default: the model's
+        own): ``(slots, num_heads, max_len, d_head)``.  Whoever allocates
+        or sizes a ring asks here (serving/decode.py, which adds the +1
+        scratch slot, and the server's admission), and the ring ops
+        (ops/attention.py) read and write exactly this order.
+
+        One order for every head width, measured on a TPU v5e (PERF.md
+        section 6, PR 26): the runtime stores a 64-wide minor axis with
+        the POSITIONS on the lanes (``[slot][head][d_head][position]``,
+        dense) and a 128-wide one as written, which is in both cases
+        the layout the decode step's attention reads; rings transposed by
+        hand were no faster at d_head 64 and 18-36% slower at 128."""
+        return (int(slots), self.num_heads,
+                self.max_len if max_len is None else int(max_len),
+                self.d_head)
 
     def _cache_vars(self):
         return {n: sym.Variable(n) for n in self.cache_names()}

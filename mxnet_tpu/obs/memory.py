@@ -300,6 +300,14 @@ class Program:
                 raise
         return self._call_slow(args)
 
+    def hlo_text(self):
+        """The optimised HLO of the executable this entry dispatched
+        last — what the compiler did with the program, layouts, copies
+        and ``input_output_alias`` included — or None before the first
+        call and on the jit fallback."""
+        c = self._current
+        return None if c is None else c.as_text()
+
     def _call_slow(self, args):
         with self._lock:
             if self._fallback:

@@ -16,12 +16,22 @@ needs three graph-level primitives beyond the classic registry:
   can write them into a KV-cache slot without recomputing the
   projections.
 * ``_cached_attention`` / ``_kv_cache_write`` — the decode-side pair.
-  The KV ring is a preallocated ``(slots, heads, max_len, d_head)``
-  buffer per layer; the SLOT INDEX and LENGTH ride as traced operands
-  (the vLLM/PagedAttention discipline, see the paged-attention kernel
-  walkthrough: gather pages by index, mask by length), so one compiled
-  decode program serves every session mix — sessions join/leave
-  between steps without recompiling.
+  The KV ring is one preallocated buffer per layer for K and one for V,
+  a PAGE of ``max_len`` positions per slot; its stored shape belongs to
+  the model (``TransformerLM.cache_shape`` — every allocator asks it).
+  The SLOT INDEX and LENGTH ride as traced operands (the
+  vLLM/PagedAttention discipline: address pages by index, mask by
+  length), so one compiled decode program serves every session mix —
+  sessions join/leave between steps without recompiling.  A decode step
+  touches a ring only where it changes and reads it where it lies: each
+  packed row's K/V is ONE ``dynamic_update_slice`` at ``(slot, :,
+  length, :)`` (XLA keeps the ring's layout and, under donation, updates
+  the donated buffer in place), and each row's attention reads its own
+  page through a ``dynamic_slice`` that XLA fuses into the reduction —
+  no gathered ``(B, heads, max_len, d_head)`` copy of the pages, no
+  ring-sized temporary.  (A scatter over the two separated index axes
+  ``[slot, :, length, :]`` made XLA:TPU convert the WHOLE ring between
+  two layouts twice a layer a step: PERF.md section 6, PR 26.)
 
 The block vocabulary of current open decoders rides beside them:
 ``RMSNorm`` and the rotary pair ``_rotary`` / ``_rotary_at`` (positions
@@ -197,6 +207,25 @@ def _infer_cached(in_shapes, attrs):
     return [q, q, q, kc, kc, slot, slot], [q, kc, kc]
 
 
+def _page(cache, slot_i):
+    """``cache[slot_i]`` — one slot's page ``(H, max_len, d_head)`` as a
+    dynamic slice: fused into the reduction that reads it, so the page
+    is read where it lies and never copied out."""
+    return lax.dynamic_index_in_dim(cache, slot_i, 0, keepdims=False)
+
+
+def _write_rows(cache, rows, slot_i, len_i):
+    """``cache[slot_i[b], :, len_i[b], :] = rows[b]`` for every packed
+    row, one ``dynamic_update_slice`` each, in row order.  The update
+    window is contiguous in the ring as stored, so XLA keeps the ring's
+    layout and — the serve program donates the rings — writes the donated
+    buffer in place."""
+    for b in range(rows.shape[0]):
+        cache = lax.dynamic_update_slice(
+            cache, rows[b][None, :, None, :], (slot_i[b], 0, len_i[b], 0))
+    return cache
+
+
 @register("_cached_attention",
           inputs=("query", "key", "value", "k_cache", "v_cache", "slot",
                   "length"),
@@ -204,20 +233,25 @@ def _infer_cached(in_shapes, attrs):
 def cached_attention(query, key, value, k_cache, v_cache, slot, length,
                      num_heads=1, **kw):
     """One decode step of multi-head attention against a slot-indexed
-    KV ring (the PagedAttention shape: gather this session's page by
+    KV ring (the PagedAttention shape: address each session's page by
     slot index, mask by length — both TRACED operands, so one compiled
     program serves any session mix).
 
     query/key/value: ``(B, 1, d_model)`` projections of the current
-    token; ``k_cache``/``v_cache``: ``(slots, H, max_len, d_head)``
-    rings; ``slot``/``length``: ``(B,)`` — session slot index and the
-    number of tokens already cached (== the new token's position).
+    token; ``k_cache``/``v_cache``: rings of the model's ``cache_shape``,
+    ``(slots, H, max_len, d_head)``; ``slot``/``length``: ``(B,)`` —
+    session slot index and the number of tokens already cached (== the
+    new token's position).
 
-    The step's K/V are scattered into ``cache[slot, :, length]`` FIRST,
-    then attention runs over ``cache[slot, :, :length+1]`` (mask), so
-    the new token attends to itself like the full-sequence forward.
-    Padded rows of a partial decode batch point at the ring's scratch
-    slot; duplicate scatter indices there are harmless garbage.
+    The step's K/V are written at ``cache[slot, :, length]`` FIRST, one
+    in-place row update a packed row, then each row attends over its own
+    page ``cache[slot, :, :length+1]`` (mask), so the new token attends
+    to itself like the full-sequence forward.  A row reads only its own
+    page, in place: the step's ring traffic is B pages, whatever the
+    number of slots.  Padded rows of a partial decode batch point at the
+    ring's scratch slot with length 0: their writes land one after the
+    other on its position 0 and their softmax stays finite — garbage
+    nobody reads.
 
     Outputs: context ``(B, 1, d_model)``, updated k_cache, updated
     v_cache (functional update — the serving session threads the rings
@@ -228,22 +262,18 @@ def cached_attention(query, key, value, k_cache, v_cache, slot, length,
     dh = d // h
     slot_i = _as_index(slot)
     len_i = _as_index(length)
-    kn = key.reshape(b, h, dh)
-    vn = value.reshape(b, h, dh)
-    # scatter this step's K/V at [slot, :, length, :] — advanced indices
-    # (B,) broadcast to the front, so the update block is (B, H, d_head)
-    kc = k_cache.at[slot_i, :, len_i, :].set(kn)
-    vc = v_cache.at[slot_i, :, len_i, :].set(vn)
-    ks = kc[slot_i]  # (B, H, max_len, d_head) — this session's page
-    vs = vc[slot_i]
-    qh = query.reshape(b, h, 1, dh)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", qh, ks) / jnp.sqrt(
-        jnp.asarray(dh, qh.dtype))
+    kc = _write_rows(k_cache, key.reshape(b, h, dh), slot_i, len_i)
+    vc = _write_rows(v_cache, value.reshape(b, h, dh), slot_i, len_i)
+    qh = query.reshape(b, h, dh)
+    scores = jnp.stack(
+        [jnp.einsum("hd,hkd->hk", qh[i], _page(kc, slot_i[i]))
+         for i in range(b)]) / jnp.sqrt(jnp.asarray(dh, qh.dtype))
     max_len = k_cache.shape[2]
-    keep = jnp.arange(max_len)[None, None, None, :] <= \
-        len_i[:, None, None, None]
-    scores = jnp.where(keep, scores, _NEG)
-    ctx = jnp.einsum("bhqk,bhkd->bhqd", jnn.softmax(scores, axis=-1), vs)
+    keep = jnp.arange(max_len)[None, None, :] <= len_i[:, None, None]
+    probs = jnn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
+    ctx = jnp.stack(
+        [jnp.einsum("hk,hkd->hd", probs[i], _page(vc, slot_i[i]))
+         for i in range(b)])
     return ctx.reshape(b, 1, d), kc, vc
 
 

@@ -21,15 +21,20 @@ many decode iterations — and two program families:
   iteration-level re-pack the batcher already does for classic
   tenants.
 
-The KV ring is preallocated at ``(max_sessions + 1, heads, max_len,
-d_head)`` per layer; index ``max_sessions`` is the SCRATCH slot padded
-decode rows write into (duplicate scatter indices there are harmless
-garbage).  The rings thread FUNCTIONALLY through every program call —
-caches in, updated caches out — which on TPU rides the serve program's
-donated input tuple (in-place update), and on CPU costs one buffer
-copy per step.  Donation deletes the buffers passed in, so every call
-REPLACES the rings it was given with the ones it got back; warm-up runs
-on throwaway rings of its own and never touches the live ones.
+The KV ring is preallocated per layer, ``max_sessions + 1`` pages of
+``max_len`` positions, in the shape the MODEL states
+(``model.cache_shape(slots, max_len)`` — the session never spells the
+axes out); index ``max_sessions`` is the SCRATCH slot padded decode rows
+write into (their writes land on its position 0 one after the other:
+harmless garbage).  The rings thread FUNCTIONALLY through every program
+call — caches in, updated caches out — which on TPU rides the serve
+program's donated input tuple: a decode step writes one row a session
+in place and each row's attention reads its own page where it lies
+(ops/attention.py), so a step moves B pages, not the rings; on CPU,
+which does not donate, it costs one buffer copy per step.  Donation
+deletes the buffers passed in, so every call REPLACES the rings it was
+given with the ones it got back; warm-up runs on throwaway rings of its
+own and never touches the live ones.
 
 Retirement (EOS, token budget, or ring-full) resolves the request's
 future with a :class:`GenerateResult` and frees the slot under
@@ -118,9 +123,9 @@ class GenerativeSession:
     """One generative LM tenant (module docstring).
 
     `model` is duck-typed (models/transformer_lm.py TransformerLM is
-    the zoo instance): attributes ``num_layers`` / ``num_heads`` /
-    ``d_head`` / ``vocab`` / ``max_len`` and methods
-    ``prefill_symbol()`` / ``decode_symbol()`` / ``cache_names()``.
+    the zoo instance): attribute ``max_len`` and methods
+    ``prefill_symbol()`` / ``decode_symbol()`` / ``cache_names()`` /
+    ``cache_shape(slots, max_len)`` (the stored shape of one ring).
     `params` maps parameter name -> array (a training checkpoint's
     arg+aux dicts merged).  Knob defaults come from the config
     registry: ``MXTPU_SERVE_MAX_SESSIONS`` / ``_MAX_DECODE_TOKENS`` /
@@ -150,8 +155,9 @@ class GenerativeSession:
         self._reports_moe_load = "moe_load" in tuple(
             getattr(model, "extra_outputs", tuple)())
         self._input_names = ["data", "slot", "length"] + self._cache_names
-        cshape = (self._slots + 1, model.num_heads, self._max_len,
-                  model.d_head)
+        # the model owns the ring's stored shape; the +1 is the scratch
+        # slot padded decode rows point at
+        cshape = tuple(model.cache_shape(self._slots + 1, self._max_len))
         self._cache_shape = cshape
         # sequence-length ladder for prefill; decode-batch ladder for
         # the packed step — both compile-once through the predictors'

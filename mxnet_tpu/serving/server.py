@@ -23,6 +23,7 @@ complete — no future is ever left unresolved.
 """
 from __future__ import annotations
 
+import math
 import threading
 import time
 
@@ -202,8 +203,8 @@ class ModelServer:
         ring_len = int(max_len if max_len is not None
                        else config.get("MXTPU_SERVE_KV_MAX_LEN"))
         ring_len = min(ring_len, int(model.max_len))
-        ring_bytes = ((slots + 1) * int(model.num_heads) * ring_len
-                      * int(model.d_head) * 4 * len(model.cache_names()))
+        ring_bytes = (math.prod(model.cache_shape(slots + 1, ring_len))
+                      * 4 * len(model.cache_names()))
         memory.admit("generative tenant %r" % name,
                      (1 if shared else 2) * param_bytes + ring_bytes,
                      device=ctx.jax_device())

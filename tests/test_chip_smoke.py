@@ -29,6 +29,9 @@ TINY = {
     "generate": {"vocab": 32, "num_layers": 2, "num_heads": 2, "d_model": 32,
                  "max_len": 48, "max_sessions": 2, "seq_buckets": [8, 16],
                  "prompts": 3, "new_tokens": 4, "check_steps": 3, "seed": 2},
+    "kv_ring": {"vocab": 32, "num_layers": 2, "num_heads": 2, "d_model": 32,
+                "d_ff": 32, "max_len": 48, "max_sessions": 2,
+                "seq_buckets": [8], "seed": 5},
     "kernel": {"shapes": [(4, 4, 4, 64), (8, 2, 2, 256)], "seed": 3},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
@@ -63,9 +66,11 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     clock = chip_smoke.CompileClock()
     report = {}
     ctx = mx.cpu(2)
-    for name in ("fence", "train", "serve", "generate"):
+    for name in ("fence", "train", "serve", "generate", "kv_ring"):
         chip_smoke.run_phase(name, getattr(chip_smoke, "phase_" + name),
                              TINY[name], ctx, clock, report)
+    # two layers' K and V rings, found in the compiled decode program
+    assert report["kv_ring"]["ring_params"] == 4
     monkeypatch.setattr(pk, "_INTERPRET", True)
     chip_smoke.run_phase("kernel", chip_smoke.phase_kernel, TINY["kernel"],
                          ctx, clock, report)
@@ -100,7 +105,7 @@ def test_the_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(mx, "tpu", lambda i=0: mx.cpu(i))
-    for name in ("fence", "train", "serve", "generate", "kernel"):
+    for name in ("fence", "train", "serve", "generate", "kv_ring", "kernel"):
         monkeypatch.setattr(chip_smoke, "phase_" + name, lambda s, c: {})
     monkeypatch.setattr(chip_smoke, "phase_four_chips",
                         lambda s, c: {"predictor_device": "x"})
@@ -117,7 +122,7 @@ def test_the_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert lines[-2].startswith("[chip_smoke] report ")
     report = json.loads(lines[-2][len("[chip_smoke] report "):])
     assert set(report["phases"]) == {"fence", "train", "serve", "generate",
-                                     "kernel", "four_chips"}
+                                     "kv_ring", "kernel", "four_chips"}
 
 
 # ----------------------------------------------------------------------

@@ -231,7 +231,7 @@ def test_defaults_are_the_opt_block():
     assert lm.score_symbol().list_arguments() == want
     assert lm.training_symbol().list_arguments() == want + ["softmax_label"]
     assert lm.extra_outputs() == ()
-    ring = (3, 2, 32, 8)
+    ring = lm.cache_shape(3)
     shapes = dict(data=(2, 1), slot=(2,), length=(2,),
                   **{n: ring for n in lm.cache_names()})
     _, outs, _ = lm.decode_symbol().infer_shape(**shapes)

@@ -422,11 +422,6 @@ def test_attention_ops_match_numpy_oracle():
     assert np.allclose(got, want, rtol=1e-5, atol=1e-5)
 
     # _sdp_attention: causal softmax attention + per-head K/V reshapes
-    def np_softmax(s):
-        m = s.max(-1, keepdims=True)
-        e = np.exp(s - m)
-        return e / e.sum(-1, keepdims=True)
-
     q = rng.randn(n, t, d).astype(np.float32)
     k = rng.randn(n, t, d).astype(np.float32)
     v = rng.randn(n, t, d).astype(np.float32)
@@ -439,53 +434,18 @@ def test_attention_ops_match_numpy_oracle():
     scores = np.einsum("nhqd,nhkd->nhqk", qh, kh_ref) / np.sqrt(dh)
     scores = np.where(np.tril(np.ones((t, t), bool))[None, None],
                       scores, -1e30)
-    ctx_ref = np.einsum("nhqk,nhkd->nhqd", np_softmax(scores), vh_ref)
+    ctx_ref = np.einsum("nhqk,nhkd->nhqd", _np_softmax(scores), vh_ref)
     ctx_ref = ctx_ref.transpose(0, 2, 1, 3).reshape(n, t, d)
     assert np.allclose(ctx.asnumpy(), ctx_ref, rtol=1e-4, atol=1e-5)
     assert np.allclose(kh.asnumpy(), kh_ref) and np.allclose(vh.asnumpy(),
                                                              vh_ref)
 
-    # _kv_cache_write: block lands at ring slot [slot, :, :T)
-    slots, max_len = 3, 8
-    kc = rng.randn(slots, h, max_len, dh).astype(np.float32)
-    vc = rng.randn(slots, h, max_len, dh).astype(np.float32)
-    kb = rng.randn(1, h, t, dh).astype(np.float32)
-    vb = rng.randn(1, h, t, dh).astype(np.float32)
-    kc2, vc2 = mx.nd._kv_cache_write(mx.nd.array(kc), mx.nd.array(vc),
-                                     mx.nd.array(kb), mx.nd.array(vb),
-                                     mx.nd.array(np.array([1.0], np.float32)))
-    kc_ref, vc_ref = kc.copy(), vc.copy()
-    kc_ref[1, :, :t] = kb[0]
-    vc_ref[1, :, :t] = vb[0]
-    assert np.allclose(kc2.asnumpy(), kc_ref)
-    assert np.allclose(vc2.asnumpy(), vc_ref)
-
-    # _cached_attention: one decode step == attention over the slot's
-    # cached prefix + the step's own K/V written at position `length`
-    b = 2
-    slot = np.array([1, 2], np.float32)
-    length = np.array([3, 5], np.float32)
-    q1 = rng.randn(b, 1, d).astype(np.float32)
-    k1 = rng.randn(b, 1, d).astype(np.float32)
-    v1 = rng.randn(b, 1, d).astype(np.float32)
-    ctx1, kc3, vc3 = mx.nd._cached_attention(
-        mx.nd.array(q1), mx.nd.array(k1), mx.nd.array(v1),
-        mx.nd.array(kc_ref), mx.nd.array(vc_ref), mx.nd.array(slot),
-        mx.nd.array(length), num_heads=h)
-    kc_up, vc_up = kc_ref.copy(), vc_ref.copy()
-    ctx1_ref = np.zeros((b, 1, d), np.float32)
-    for i in range(b):
-        s, L = int(slot[i]), int(length[i])
-        kc_up[s, :, L] = k1[i].reshape(h, dh)
-        vc_up[s, :, L] = v1[i].reshape(h, dh)
-        qi = q1[i].reshape(h, 1, dh)
-        sc = np.einsum("hqd,hkd->hqk", qi, kc_up[s]) / np.sqrt(dh)
-        sc[:, :, L + 1:] = -1e30
-        ctx1_ref[i, 0] = np.einsum(
-            "hqk,hkd->hqd", np_softmax(sc), vc_up[s]).reshape(d)
-    assert np.allclose(ctx1.asnumpy(), ctx1_ref, rtol=1e-4, atol=1e-5)
-    assert np.allclose(kc3.asnumpy(), kc_up)
-    assert np.allclose(vc3.asnumpy(), vc_up)
+    # _kv_cache_write + _cached_attention through their contract (write a
+    # prompt, step, read back what the step attends to): the ring's
+    # stored shape is the model's to choose, so nothing here indexes it
+    max_len = 8
+    _ring_case(h, dh, max_len, slots=[1, 2], lens=[3, 5], padded=0, seed=7)
+    q1 = rng.randn(2, 1, d).astype(np.float32)
 
     # _add_positional / _add_positional_at
     pos = rng.randn(max_len, d).astype(np.float32)
@@ -500,3 +460,161 @@ def test_attention_ops_match_numpy_oracle():
     tk = np.array([0, 3], np.float32)
     got = mx.nd._take_step(mx.nd.array(x), mx.nd.array(tk)).asnumpy()
     assert np.allclose(got, x[np.arange(n), tk.astype(int)])
+
+
+def _np_softmax(s):
+    e = np.exp(s - s.max(-1, keepdims=True))
+    return e / e.sum(-1, keepdims=True)
+
+
+def _np_attention(q, ks, vs):
+    """Plain attention of one query ``(H, d_head)`` over a session's
+    whole sequence ``ks``/``vs (T, H, d_head)`` — the oracle the ring
+    ops are held to."""
+    dh = q.shape[-1]
+    sc = np.einsum("hd,thd->ht", q, ks) / np.sqrt(dh)
+    return np.einsum("ht,thd->hd", _np_softmax(sc), vs)
+
+
+def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2):
+    """Drive `_kv_cache_write` + `_cached_attention` the way a serving
+    session does and hold every step's context to `_np_attention` over
+    the session's full sequence.
+
+    Each session `i` owns ring slot ``slots[i]`` and has ``lens[i]``
+    tokens cached: a prompt's K/V block (padded to a bucket with garbage
+    behind its true length, as a padded prefill leaves it) goes in
+    through `_kv_cache_write`; then `steps` decode steps run on the
+    RETURNED rings, `padded` extra rows pointing at the scratch slot with
+    length 0 beside the live ones.  A later step reads what an earlier
+    one wrote, so a row that lands anywhere but ``(slot, :, length)``
+    shows.  The rings are only ever built from ``cache_shape`` and
+    handed from op to op: their axis order is the model's own."""
+    rng = np.random.RandomState(seed)
+    d = h * dh
+    lm = TransformerLM(vocab=8, num_layers=1, num_heads=h, d_model=d,
+                       max_len=max_len)
+    n_slots = max(slots) + 1
+    scratch = n_slots                      # serving/decode.py's +1 slot
+    shape = lm.cache_shape(n_slots + 1)
+    kc = mx.nd.array(rng.randn(*shape).astype(np.float32))
+    vc = mx.nd.array(rng.randn(*shape).astype(np.float32))
+    hist_k, hist_v = [], []
+    for s, n in zip(slots, lens):
+        t = min(max_len, n + 2)            # a bucket longer than the prompt
+        kb = rng.randn(1, h, t, dh).astype(np.float32)
+        vb = rng.randn(1, h, t, dh).astype(np.float32)
+        if n:
+            kc, vc = mx.nd._kv_cache_write(
+                kc, vc, mx.nd.array(kb), mx.nd.array(vb),
+                mx.nd.array(np.array([s], np.float32)))
+        hist_k.append(list(kb[0, :, :n].transpose(1, 0, 2)))
+        hist_v.append(list(vb[0, :, :n].transpose(1, 0, 2)))
+    live = len(slots)
+    b = live + padded
+    slot = np.array(list(slots) + [scratch] * padded, np.float32)
+    for _ in range(steps):
+        length = np.array([len(k) for k in hist_k] + [0] * padded,
+                          np.float32)
+        q, k, v = (rng.randn(b, 1, d).astype(np.float32) for _ in range(3))
+        ctx, kc, vc = mx.nd._cached_attention(
+            mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), kc, vc,
+            mx.nd.array(slot), mx.nd.array(length), num_heads=h)
+        assert kc.shape == shape and vc.shape == shape
+        got = ctx.asnumpy()
+        assert got.shape == (b, 1, d) and np.isfinite(got).all()
+        for i in range(live):
+            hist_k[i].append(k[i, 0].reshape(h, dh))
+            hist_v[i].append(v[i, 0].reshape(h, dh))
+            want = _np_attention(q[i, 0].reshape(h, dh),
+                                 np.stack(hist_k[i]), np.stack(hist_v[i]))
+            assert np.allclose(got[i, 0], want.reshape(d), rtol=1e-4,
+                               atol=1e-5), (i, len(hist_k[i]))
+
+
+_MAX_LEN = 12
+RING_CASES = {
+    # sessions two slots apart, neither at slot 0
+    "nonadjacent_slots": dict(slots=[1, 4], lens=[3, 5], padded=0),
+    # one live row in a bucket of four: three padded rows share the scratch
+    # slot (duplicate writes there, length 0) and must not leak
+    "padded_rows_share_scratch": dict(slots=[2], lens=[4], padded=3),
+    # an empty session (its first step attends only to itself) beside one
+    # whose second step writes the ring's last position, max_len - 1
+    "length_zero_and_ring_end": dict(slots=[0, 3],
+                                     lens=[0, _MAX_LEN - 2], padded=0),
+    # a full bucket: every slot live, in an order that is not the slots'
+    "full_bucket_shuffled": dict(slots=[2, 0, 3, 1], lens=[1, 7, 2, 5],
+                                 padded=0),
+}
+
+
+@pytest.mark.parametrize("d_head", [64, 128])
+@pytest.mark.parametrize("case", sorted(RING_CASES))
+def test_ring_ops_match_full_sequence_attention(case, d_head):
+    """`_cached_attention` + `_kv_cache_write` against plain numpy
+    attention over each session's full sequence, two steps in a row on
+    the returned rings, at both head widths the benchmark's decoders
+    have (64: OPT; 128: OLMoE)."""
+    _ring_case(2, d_head, _MAX_LEN, seed=11, **RING_CASES[case])
+
+
+def _walk_eqns(jaxpr):
+    """Every equation of `jaxpr` and of the jaxprs its equations carry
+    (pjit bodies, custom_jvp calls); an equation that carries one is its
+    wrapper, not work of its own, and is not yielded."""
+    for eqn in jaxpr.eqns:
+        inner = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                 if hasattr(getattr(v, "jaxpr", v), "eqns")]
+        if inner:
+            for j in inner:
+                yield from _walk_eqns(j)
+        else:
+            yield eqn
+
+
+@pytest.mark.parametrize("bucket", [2, 4])
+def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
+    """The invariant of PR 26, where the CPU can see it: in the jaxpr of
+    a small LM's decode program the ONLY equations that produce an array
+    of ``B x H x max_len x d_head`` elements or more are the rings' row
+    updates — B `dynamic_update_slice`s a ring — so there is no gathered
+    page batch, no scatter and no ring-sized temporary; and what reads a
+    ring is a `dynamic_slice` of ONE page (which XLA fuses into the
+    reduction that consumes it), never more.  chip_smoke.py holds the
+    compiled program to the same on the chip."""
+    import jax
+
+    # a ring long enough that one page outweighs every weight matrix
+    max_len = 128
+    lm, params = _lm_and_params(num_layers=2, num_heads=2, d_model=16,
+                                max_len=max_len)
+    gs = GenerativeSession("lm", lm, params, max_sessions=4,
+                           max_len=max_len, seq_buckets=[8])
+    try:
+        exe, fn = gs._program(gs._decode_pred, bucket, 1, False)
+        other, aux = exe.serve_args(gs._input_names)
+        ins = (np.zeros((bucket, 1), np.float32),
+               np.zeros((bucket,), np.float32),
+               np.zeros((bucket,), np.float32)) + tuple(gs._caches)
+        jaxpr = jax.make_jaxpr(fn._jit)(ins, other, aux, np.uint32(0))
+    finally:
+        gs.close()
+    ring = tuple(lm.cache_shape(5, max_len))
+    page = int(np.prod(ring[1:]))
+    updates, big, page_makers = 0, [], set()
+    for eqn in _walk_eqns(jaxpr.jaxpr):
+        for out in eqn.outvars:
+            size = int(np.prod(out.aval.shape)) if out.aval.shape else 1
+            if eqn.primitive.name == "dynamic_update_slice":
+                assert tuple(out.aval.shape) == ring
+                updates += 1
+            elif size >= bucket * page:
+                big.append((eqn.primitive.name, tuple(out.aval.shape)))
+            elif size >= page:
+                page_makers.add(eqn.primitive.name)
+    assert big == [], big
+    # B rows x (K ring + V ring) x layers
+    assert updates == bucket * 2 * lm.num_layers
+    assert page_makers <= {"dynamic_slice", "squeeze", "reshape"}, \
+        page_makers
