@@ -136,6 +136,15 @@ def summarize(recs, t0, seconds, waiting_counts):
             attempted, failed)
 
 
+def left_waiting(recs, t_end, limits):
+    """Requests that had the TTFT limit's time inside the window and
+    still had no first token at its end: the backlog an open loop above
+    its knee leaves (benchmarks/README.md, "Finding the knee")."""
+    allowed = limits.get("ttft_ms", 0.0) / 1e3
+    return sum(1 for r in recs if r.due + allowed <= t_end
+               and (not r.times or r.times[0] > t_end))
+
+
 def _warm_up(server, tenant, vocab, seed):
     """Compile every program the window can use by sending real requests:
     one prompt per prefill bucket, and budgets staggered so that the
@@ -226,7 +235,7 @@ def run(cell, args, devices, clock, process_start):
     try:
         compiled = clock.read()
         trace_s = cell.traffic.get("trace_seconds", 4.0) if args.trace else 0.0
-        summary, t0, before, after, tracer, _recs = served.window(
+        summary, t0, before, after, tracer, recs = served.window(
             args.seconds, trace_s=trace_s)
         served.close()
     except BaseException:
@@ -244,9 +253,13 @@ def run(cell, args, devices, clock, process_start):
                "ttft_ms": {str(p): q(w.series["ttft_ms"], p)
                            for p in (0.5, 0.75, 0.9, 0.95)},
                "itl_ms": {str(p): q(w.series["itl_ms"], p)
-                          for p in (0.5, 0.9, 0.99)},
+                          for p in (0.5, 0.9, 0.95, 0.98, 0.99)},
+               "late_p99_ms": q(w.series["late_ms"], 0.99),
                "compiles_in_window": compiled_in_window,
                "watched": {c: w.counter_delta(c) for c in WATCHED_COUNTERS}}
+    if "limits" in cell.traffic:   # an open loop's: a closed one queues by design
+        w.notes["left_waiting"] = left_waiting(recs, t0 + args.seconds,
+                                               cell.traffic["limits"])
     w.correct = bool(served.correct and quiet and compiled_in_window == 0
                      and w.failed == 0 and w.attempted > 0)
     if tracer is not None:
@@ -271,10 +284,6 @@ def sweep(cell, args, devices, rates):
                 and (len(r.times) < 2
                      or (r.times[-1] - r.times[0]) * 1e3 / (len(r.times) - 1)
                      <= limits.get("itl_mean_ms", float("inf"))))
-            # had the TTFT limit's time inside the window, and nothing yet
-            backlog = sum(1 for r in recs
-                          if r.due + limits.get("ttft_ms", 0.0) / 1e3 <= t_end
-                          and (not r.times or r.times[0] > t_end))
             q = loadgen.quantile
             print("[sweep] " + " ".join("%s=%s" % kv for kv in [
                 ("rate_per_s", rate), ("sent", attempted), ("failed", failed),
@@ -284,7 +293,8 @@ def sweep(cell, args, devices, rates):
                 ("itl_p50_ms", round(q(series["itl_ms"], 0.5), 2)),
                 ("itl_p99_ms", round(q(series["itl_ms"], 0.99), 2)),
                 ("tokens_per_s", round(scalars["tokens_per_s"], 1)),
-                ("left_waiting", backlog),
+                ("late_p99_ms", round(q(series["late_ms"], 0.99), 2)),
+                ("left_waiting", left_waiting(recs, t_end, limits)),
                 ("drain_s", round(max(r.times[-1] for r in recs if r.times)
                                   - t_end, 2))]), flush=True)
     finally:

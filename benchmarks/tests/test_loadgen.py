@@ -1,6 +1,7 @@
 """The load generator's schedule is a pure function of the file and the
 seed."""
 import numpy as np
+import pytest
 
 from benchmarks.harness import loadgen
 
@@ -55,3 +56,43 @@ def test_every_seed_offers_nearly_the_same_work():
                        sum(r.budget for r in reqs)))
     totals = np.asarray(totals, float)
     assert np.all(totals.std(axis=0) / totals.mean(axis=0) < 0.01)
+
+
+BUCKETS = (64, 128, 256, 512)   # the serving cells' prefill buckets
+
+
+def _work(seed, n):
+    """(prompt tokens, budgeted output tokens, prompts per prefill bucket)
+    of a seed's first `n` requests."""
+    reqs = _requests(seed, n)
+    plen = np.array([len(r.prompt) for r in reqs])
+    per_bucket = np.bincount(np.searchsorted(BUCKETS, plen),
+                             minlength=len(BUCKETS))
+    return plen.sum(), sum(r.budget for r in reqs), per_bucket
+
+
+def _apart(totals):
+    totals = np.asarray(totals, float)
+    return (totals.max(axis=0) - totals.min(axis=0)) / totals.min(axis=0)
+
+
+@pytest.mark.parametrize("blocks", [4, 5, 6])
+def test_whole_blocks_offer_every_seed_the_same_work(blocks):
+    """An open loop's count is a multiple of BLOCK (test_spec.py holds the
+    traffic files to it): then eight seeds' tokens lie within 1% and their
+    prompts per prefill bucket within 3 (a length at a bucket's edge
+    falls either side of it once a block)."""
+    work = [_work(seed, blocks * loadgen.BLOCK) for seed in range(1, 9)]
+    assert np.all(_apart([w[:2] for w in work]) < 0.01)
+    per_bucket = np.asarray([w[2] for w in work])
+    assert np.all(per_bucket.max(axis=0) - per_bucket.min(axis=0) <= 3)
+
+
+def test_a_part_block_does_not():
+    """90 requests (the retired 1.8 req/s chat cell: one block and 26 of a
+    second) gave the seeds work 10% apart and 14 to 20 prompts of the
+    512 bucket: which 26 a seed drew differed."""
+    work = [_work(seed, 90) for seed in range(1, 9)]
+    assert np.all(_apart([w[:2] for w in work]) > 0.05)
+    per_bucket = np.asarray([w[2] for w in work])
+    assert np.all(per_bucket.max(axis=0) - per_bucket.min(axis=0) >= 5)

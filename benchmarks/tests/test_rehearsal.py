@@ -119,11 +119,12 @@ def test_the_sweep_prints_one_line_per_rate(capsys):
     from benchmarks.jobs import generate
 
     bench = spec.load_benchmark(REHEARSAL, "cells.json")
-    cell = spec.Cell(bench, "opt1b3_chat", REHEARSAL)
+    cell = spec.Cell(bench, "opt1b3_chat_k80", REHEARSAL)
     args = argparse.Namespace(seed=0, seconds=1.0)
     generate.sweep(cell, args, jax.devices()[:1], [10.0, 20.0])
     lines = [ln for ln in capsys.readouterr().out.splitlines()
              if ln.startswith("[sweep] ")]
     assert [ln.split()[1] for ln in lines] == ["rate_per_s=10.0",
                                                "rate_per_s=20.0"]
-    assert all("left_waiting=" in ln and "ttft_p90_ms=" in ln for ln in lines)
+    assert all("left_waiting=" in ln and "ttft_p90_ms=" in ln
+               and "late_p99_ms=" in ln for ln in lines)

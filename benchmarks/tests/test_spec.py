@@ -85,3 +85,20 @@ def test_every_config_is_used_and_every_file_sits_under_paths():
     for f in files:
         with open(os.path.join(spec.ROOT, f)) as fh:
             json.load(fh)
+
+
+def test_an_open_loop_sends_whole_blocks_of_requests():
+    """The generator makes lengths in stratified blocks of BLOCK: only a
+    count that is a multiple of it offers every seed the same work
+    (test_loadgen.py), so an open-loop mix's rate is chosen to give one."""
+    from benchmarks.harness import loadgen
+
+    open_loops = 0
+    for w in BENCH["workloads"]:
+        arrivals = spec.Cell(BENCH, w["name"]).traffic.get("arrivals", {})
+        if arrivals.get("process") == "open":
+            open_loops += 1
+            n = len(loadgen.arrival_times(arrivals["rate_per_s"],
+                                          BENCH["run_seconds"], seed=0))
+            assert n % loadgen.BLOCK == 0, (w["traffic"], n)
+    assert open_loops
