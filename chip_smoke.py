@@ -293,7 +293,9 @@ def phase_serve(sizes, ctx):
                 futures[i] = server.submit("resnet", {"data": X[i]},
                                            timeout_ms=120000)
 
-        threads = [threading.Thread(target=client,
+        # daemon: a client that did not finish fails the check below and
+        # must not keep the interpreter from exiting
+        threads = [threading.Thread(target=client, daemon=True,
                                     args=(range(t, len(X), sizes["threads"]),))
                    for t in range(sizes["threads"])]
         for t in threads:

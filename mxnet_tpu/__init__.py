@@ -16,9 +16,6 @@ from .base import MXNetError
 
 # one compile cache, placed before any backend can initialise (base.py)
 base.compile_cache_dir()
-# config imports FIRST among env readers: it materializes a TUNED.json
-# profile (MXTPU_TUNED_FILE) into os.environ, and modules that read env
-# vars at import time (lazy.py, telemetry.py) must see those values.
 from . import config
 from .context import Context, cpu, gpu, tpu, current_context, num_tpus, num_gpus
 from . import ops

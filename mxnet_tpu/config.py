@@ -12,40 +12,15 @@ Many reference knobs (engine thread pools, GPU memory pool, bulk-exec
 segment sizes) have no analog because XLA/PJRT owns those resources —
 they are listed as `absorbed` so users migrating scripts get an answer
 instead of silence.
-
-Performance knobs additionally carry a *tunable* annotation (range or
-choices + the workloads they affect) so `tools/autotune.py` can
-introspect the search space instead of hand-listing it, and the module
-loads a per-(model, host-fingerprint) `TUNED.json` profile
-(MXTPU_TUNED_FILE) at import as overridable defaults.  Precedence is
-pinned: explicit env var > tuned profile > registered default — tuned
-values materialize into os.environ ONLY for names the user did not set,
-so import-time readers (lazy.py, telemetry.py) see them too.
 """
 from __future__ import annotations
 
-import json
 import os
-import warnings
 from collections import namedtuple
 
-__all__ = ["EnvVar", "Tunable", "REGISTRY", "ABSORBED", "get", "spec",
-           "describe", "tunables", "validate_knob", "host_fingerprint",
-           "load_tuned_profile", "tuned_knobs", "TUNED_SCHEMA"]
+__all__ = ["EnvVar", "REGISTRY", "ABSORBED", "get", "spec", "describe"]
 
-# Search-space annotation for autotunable knobs: either a discrete
-# `choices` tuple or a numeric [lo, hi] range (inclusive), plus the
-# workload families ("train", "serve", "imperative", "data") whose
-# throughput the knob can move — tools/autotune.py searches only the
-# knobs whose workloads intersect the benched workload.  `extra` lists
-# non-numeric special values the type accepts (e.g. "auto").
-Tunable = namedtuple("Tunable", ["workloads", "choices", "lo", "hi", "extra"])
-Tunable.__new__.__defaults__ = (None, None, None, ())
-
-EnvVar = namedtuple("EnvVar", ["name", "type", "default", "desc", "tunable"])
-EnvVar.__new__.__defaults__ = (None,)  # tunable is opt-in per knob
-
-TUNED_SCHEMA = "mxtpu-tuned-v1"
+EnvVar = namedtuple("EnvVar", ["name", "type", "default", "desc"])
 
 
 def _float_or_auto(raw):
@@ -138,9 +113,7 @@ REGISTRY = [
            "two-point probe (per-collective fixed cost vs wire rate), "
            "books the decision in tune.* telemetry and the flight "
            "recorder, and recompiles the block once (docs/perf.md "
-           "'Autotuning')",
-           Tunable(workloads=("train",), lo=0.25, hi=64.0,
-                   extra=("auto",))),
+           "'Autotuning')"),
     # ---- dependency engine (engine/) ----
     EnvVar("MXNET_ENGINE_TYPE", str, "ThreadedEnginePerDevice",
            "Execution engine backend (engine/): ThreadedEnginePerDevice "
@@ -170,15 +143,13 @@ REGISTRY = [
            "— so the fixed per-dispatch host cost (magnitude on the TPU "
            "host: not measured) is paid once per K steps.  1 = one "
            "dispatch per "
-           "step (the pre-block behavior); see docs/perf.md",
-           Tunable(workloads=("train",), choices=(1, 2, 4, 8))),
+           "step (the pre-block behavior); see docs/perf.md"),
     EnvVar("MXTPU_STAGE_BUFFERS", int, 2,
            "io.DeviceStagedIter lookahead: how many K-step input blocks "
            "are fetched and assembled on the device ahead of compute "
            "by a background engine op (2 = classic double buffering, "
            "reference src/io/iter_prefetcher.h); raise only if H2D "
-           "stalls show between fit.dispatch spans in the profile",
-           Tunable(workloads=("train",), choices=(2, 3, 4))),
+           "stalls show between fit.dispatch spans in the profile"),
     # ---- multi-process data service (data/; docs/data.md) ----
     EnvVar("MXTPU_DATA_WORKERS", int, 2,
            "Worker PROCESSES per data service (data.DataService / "
@@ -187,8 +158,7 @@ REGISTRY = [
            "decodes into its own shared-memory ring, with a "
            "src/imdecode.cc thread pool per worker.  Scale toward the "
            "host's physical cores; the batch SEQUENCE is identical for "
-           "any value (docs/data.md)",
-           Tunable(workloads=("data",), choices=(1, 2, 4, 8))),
+           "any value (docs/data.md)"),
     EnvVar("MXTPU_DATA_RING_SLOTS", int, 4,
            "Shared-memory slots per data-service worker — the "
            "backpressure bound: a worker this many decoded batches "
@@ -224,15 +194,13 @@ REGISTRY = [
            "Cap on a pending lazy chain: recording the Nth op flushes "
            "the graph even without a sync point, bounding host memory "
            "held by deferred operands and compile time of the fused "
-           "program (lazy.py)",
-           Tunable(workloads=("imperative",), choices=(16, 32, 64, 128))),
+           "program (lazy.py)"),
     # ---- inference serving (serving/; docs/serving.md) ----
     EnvVar("MXTPU_SERVE_MAX_BATCH", int, 32,
            "serving.ModelServer: largest batch bucket the continuous "
            "batcher packs requests into (the top of the bucket ladder). "
            "One forward program is compiled per (tenant, bucket) and "
-           "reused across every later fill",
-           Tunable(workloads=("serve",), choices=(8, 16, 32, 64))),
+           "reused across every later fill"),
     EnvVar("MXTPU_SERVE_BUCKETS", str, "",
            "Comma-separated batch-bucket ladder for the continuous "
            "batcher (e.g. '1,2,4,8,16'); empty = powers of two up to "
@@ -254,8 +222,7 @@ REGISTRY = [
            "may wait this many ms for more requests to arrive before "
            "the batcher dispatches a partial fill (a full "
            "MXTPU_SERVE_MAX_BATCH dispatches immediately). Larger = "
-           "better fill ratio, worse p99 under light load",
-           Tunable(workloads=("serve",), lo=0.0, hi=20.0)),
+           "better fill ratio, worse p99 under light load"),
     EnvVar("MXTPU_SERVE_MAX_SESSIONS", int, 8,
            "Generative serving (serving/decode.py): KV-cache slots per "
            "generative tenant — the hard cap on concurrently decoding "
@@ -275,8 +242,7 @@ REGISTRY = [
            "least this often, admitting newly-arrived prompts (prefill)"
            " between steps — the Orca iteration-level re-pack cadence. "
            "Smaller = lower per-token latency, larger = better prefill "
-           "batching under mixed load",
-           Tunable(workloads=("serve",), lo=0.5, hi=10.0)),
+           "batching under mixed load"),
     EnvVar("MXTPU_SERVE_KV_MAX_LEN", int, 256,
            "KV-ring size per slot: max total tokens (prompt + "
            "generated) a decode session may hold. Bounds the "
@@ -361,9 +327,9 @@ REGISTRY = [
     EnvVar("MXTPU_TELEMETRY", int, 1,
            "Metrics registry (telemetry.py): counters/gauges/histograms "
            "across engine, io, executor, kvstore, and module layers, "
-           "read via telemetry.snapshot() and reported by bench.py and "
-           "callback.Speedometer.  0 disables recording entirely — every "
-           "instrumentation site fast-paths out behind "
+           "read via telemetry.snapshot() and reported by the benchmark's "
+           "readers and callback.Speedometer.  0 disables recording "
+           "entirely — every instrumentation site fast-paths out behind "
            "telemetry.enabled() (mxlint E004 enforces the guard)"),
     EnvVar("MXTPU_TELEMETRY_FILE", str, "",
            "Non-empty: telemetry.flush() appends one JSONL record of "
@@ -421,8 +387,8 @@ REGISTRY = [
            "(NDArray payloads, KV rings, serve slots, staged blocks, "
            "checkpoint blobs), rendered as mem.live_bytes.<tag> "
            "gauges/counter lanes with a top-K high-watermark tracker. "
-           "0 disarms the bookkeeping (the booking guard itself stays, "
-           "bench.py --serve --mem-ab pins its cost)"),
+           "0 disarms the bookkeeping (the booking guard itself stays; "
+           "its cost on the chip: not measured)"),
     EnvVar("MXTPU_MEM_PROGRAMS", int, 1,
            "Per-program footprint accounting (obs/memory.py): compile-"
            "cache sites compile ahead-of-time and harvest XLA's "
@@ -558,8 +524,8 @@ REGISTRY = [
            "stem (11456 vs 11759 img/s inference — the fold's relayout "
            "copies outweigh the MXU fill, README Per-model MFU item 5) "
            "but FASTER on Inception-v3's 3x-larger 299^2 3x3 stem "
-           "(README Roofline item 8; A/B via `bench.py --ab s2d_stem`). "
-           "Default OFF"),
+           "(README Roofline item 8, July 2026; not re-measured, "
+           "ROADMAP.md D4). Default OFF"),
     EnvVar("MXTPU_BF16_WGRAD", int, 0,
            "bf16-accumulated WEIGHT gradients for small-kernel (max dim "
            "<=7) convolutions (ops/nn.py _conv_call custom-vjp): the "
@@ -567,8 +533,8 @@ REGISTRY = [
            "preferred_element_type=bf16, cast to the fp32 master dtype "
            "after — keeps the fast bf16 grad kernels reachable instead "
            "of the f32-output kernels that cost Inception-v3 27% of "
-           "device time (README Roofline item 8; A/B via `bench.py "
-           "--ab bf16_wgrad`). Activation gradients keep exact f32 "
+           "device time (README Roofline item 8, July 2026; not "
+           "re-measured, ROADMAP.md D4). Activation gradients keep exact f32 "
            "accumulation. Changes gradient numerics (tolerance-pinned "
            "in tests/test_mfu_sinks.py); default OFF"),
     EnvVar("MXTPU_FROZEN_BN", int, 0,
@@ -577,34 +543,9 @@ REGISTRY = [
            "(running stats carried, never recomputed) and BN "
            "gamma/beta excluded from the optimizer update "
            "(symbol.freeze_batchnorm; +17.9% measured on ResNet-50 "
-           "training, README Roofline items 6/8; A/B via `bench.py "
-           "--ab frozen_bn`). A fine-tuning SEMANTICS mode, not a "
+           "training in July 2026, README Roofline items 6/8; not "
+           "re-measured, ROADMAP.md D4). A fine-tuning SEMANTICS mode, not a "
            "free perf knob: stats must already be trained. Default OFF"),
-    # ---- autotuning (tools/autotune.py; docs/perf.md "Autotuning") ----
-    EnvVar("MXTPU_TUNED_FILE", str, "",
-           "Path to a TUNED.json profile (schema mxtpu-tuned-v1, "
-           "written by tools/autotune.py).  Loaded once at mxnet_tpu "
-           "import: schema/knob/range violations raise MXNetError, a "
-           "host-fingerprint mismatch is leniently IGNORED with a "
-           "logged reason, and surviving knob values materialize into "
-           "os.environ only where the user has not set the variable — "
-           "pinning precedence env var > tuned profile > registered "
-           "default.  Empty = no profile"),
-    EnvVar("MXTPU_TUNED_MODEL", str, "",
-           "Which model entry of MXTPU_TUNED_FILE applies to this "
-           "process (TUNED.json is keyed per model).  Empty picks the "
-           "file's sole model when exactly one is present; with "
-           "several models an empty selection ignores the file with a "
-           "logged reason instead of guessing"),
-    EnvVar("MXTPU_AUTOTUNE_TRIALS", int, 24,
-           "tools/autotune.py budget: maximum matched A/B trials per "
-           "search (coordinate descent stops early when a full sweep "
-           "over the tunable space yields no accepted move)"),
-    EnvVar("MXTPU_AUTOTUNE_NOISE_MULT", float, 2.0,
-           "tools/autotune.py acceptance bar: a candidate must beat "
-           "the incumbent by more than this many times the combined "
-           "per-side stdev (noise floor) to be adopted — early-stops "
-           "moves inside measurement noise"),
     # ---- JAX/XLA passthrough the test/dev flows rely on ----
     EnvVar("JAX_PLATFORMS", str, "", "Force a JAX backend, e.g. 'cpu'"),
     EnvVar("XLA_FLAGS", str, "",
@@ -632,13 +573,6 @@ ABSORBED = {
 }
 
 _BY_NAME = {v.name: v for v in REGISTRY}
-
-# knob values a loaded TUNED.json profile materialized into os.environ
-# this process (name -> string value); introspection only — os.environ
-# is the single source the readers consult.
-_TUNED_APPLIED = {}
-# why the configured profile was leniently ignored, when it was (str|None)
-_TUNED_IGNORED_REASON = None
 
 
 def spec(name):
@@ -670,183 +604,3 @@ def describe():
     for k, why in sorted(ABSORBED.items()):
         lines.append("  %-34s -> %s" % (k, why))
     return "\n".join(lines)
-
-
-# --------------------------------------------------------------------------
-# tunable introspection + TUNED.json profile loading (tools/autotune.py;
-# docs/perf.md "Autotuning")
-# --------------------------------------------------------------------------
-
-def _err(msg):
-    # base imports locks only; config must stay import-cycle-free, so
-    # pull MXNetError lazily instead of at module import.
-    from .base import MXNetError
-    raise MXNetError(msg)
-
-
-def tunables(workload=None):
-    """Registered knobs carrying a Tunable annotation.
-
-    `workload` filters to knobs whose annotation names that workload
-    family ("train", "serve", "imperative", "data"); None returns all.
-    This is the search space tools/autotune.py walks — declared on the
-    registration, never hand-listed.
-    """
-    out = []
-    for v in REGISTRY:
-        if v.tunable is None:
-            continue
-        if workload is not None and workload not in v.tunable.workloads:
-            continue
-        out.append(v)
-    return out
-
-
-def validate_knob(name, value, where="knob"):
-    """Check `value` against `name`'s tunable annotation; return the
-    canonical (typed) value.  Raises MXNetError on an unknown knob or a
-    value outside the declared choices/range — the TUNED.json and
-    --knobs validation path, so messages name the offending entry."""
-    spec = _BY_NAME.get(name)
-    if spec is None or spec.tunable is None:
-        _err("%s: '%s' is not a registered tunable knob (tunables: %s)"
-             % (where, name, sorted(v.name for v in tunables())))
-    t = spec.tunable
-    if t.extra and str(value).strip().lower() in t.extra:
-        return str(value).strip().lower()
-    try:
-        typed = spec.type(value)
-    except (TypeError, ValueError):
-        _err("%s: %s=%r does not parse as %s"
-             % (where, name, value, spec.type.__name__))
-    if t.choices is not None and typed not in t.choices:
-        _err("%s: %s=%r not in declared choices %s"
-             % (where, name, value, list(t.choices)))
-    if t.lo is not None and not (t.lo <= typed <= t.hi):
-        _err("%s: %s=%r outside declared range [%s, %s]"
-             % (where, name, value, t.lo, t.hi))
-    return typed
-
-
-def host_fingerprint():
-    """Host/mesh identity a tuned profile is keyed by.
-
-    Computable WITHOUT importing jax — config loads before the runtime
-    — so it is built from the env that determines the mesh: platform
-    selection, host core count, forced per-process device count, and
-    the tracker's process count.  tools/autotune.py records the
-    jax-derived device_count/mesh alongside for humans; matching uses
-    only these fields.
-    """
-    import re as _re
-    platform = (os.environ.get("JAX_PLATFORMS", "").split(",")[0]
-                .strip().lower() or "default")
-    forced = 0
-    m = _re.search(r"--xla_force_host_platform_device_count=(\d+)",
-                   os.environ.get("XLA_FLAGS", ""))
-    if m:
-        forced = int(m.group(1))
-    try:
-        local = int(os.environ.get("MXTPU_LOCAL_DEVICES", "0") or 0)
-    except ValueError:
-        local = 0
-    try:
-        procs = int(os.environ.get("DMLC_NUM_WORKER", "1") or 1)
-    except ValueError:
-        procs = 1
-    return {
-        "platform": platform,
-        "cpu_count": os.cpu_count() or 0,
-        "local_devices": local or forced,
-        "processes": procs,
-    }
-
-
-def load_tuned_profile(path, model=None, fingerprint=None):
-    """Parse + validate one TUNED.json; return (knobs, ignored_reason).
-
-    Schema (`mxtpu-tuned-v1`) violations — wrong/missing schema tag,
-    unknown knob names, values outside the registered tunable range —
-    raise MXNetError with the offending entry named: a corrupt profile
-    must be loud, silently mis-tuning is the failure mode this guards.
-    A host-fingerprint or model-selection mismatch is NOT an error —
-    the file is honest, it just measured a different box — so those
-    return ({}, reason) and the caller logs the reason and moves on.
-    """
-    try:
-        with open(path, "r") as f:
-            doc = json.load(f)
-    except OSError as e:
-        return {}, "unreadable (%s)" % (e,)
-    except ValueError as e:
-        _err("TUNED file '%s' is not valid JSON: %s" % (path, e))
-    if not isinstance(doc, dict) or doc.get("schema") != TUNED_SCHEMA:
-        _err("'%s' is not a %s profile (schema=%r)"
-             % (path, TUNED_SCHEMA, doc.get("schema")
-                if isinstance(doc, dict) else type(doc).__name__))
-    models = doc.get("models")
-    if not isinstance(models, dict) or not models:
-        _err("TUNED file '%s' has no 'models' table" % (path,))
-    # validate EVERY entry before applying ANY — a profile is adopted
-    # atomically or rejected atomically, never half-applied.
-    for mname, entry in models.items():
-        knobs = entry.get("knobs") if isinstance(entry, dict) else None
-        if not isinstance(knobs, dict):
-            _err("TUNED file '%s' model '%s' has no 'knobs' table"
-                 % (path, mname))
-        for k, val in knobs.items():
-            validate_knob(k, val, where="TUNED file '%s' model '%s'"
-                          % (path, mname))
-    want = fingerprint if fingerprint is not None else host_fingerprint()
-    have = doc.get("fingerprint", {})
-    mismatched = sorted(k for k in want
-                        if have.get(k) is not None and have[k] != want[k])
-    if mismatched:
-        return {}, ("host fingerprint mismatch on %s (profile %s, host %s)"
-                    % (mismatched,
-                       {k: have.get(k) for k in mismatched},
-                       {k: want[k] for k in mismatched}))
-    if model is None:
-        model = os.environ.get("MXTPU_TUNED_MODEL", "")
-    if not model:
-        if len(models) == 1:
-            model = next(iter(models))
-        else:
-            return {}, ("MXTPU_TUNED_MODEL unset and profile has %d models "
-                        "%s" % (len(models), sorted(models)))
-    if model not in models:
-        return {}, ("model '%s' not in profile (has %s)"
-                    % (model, sorted(models)))
-    return dict(models[model]["knobs"]), None
-
-
-def tuned_knobs():
-    """Knob values the loaded profile applied this process (name -> str)."""
-    return dict(_TUNED_APPLIED)
-
-
-def _materialize_tuned():
-    """Import-time hook: load MXTPU_TUNED_FILE and export its knobs.
-
-    Applied values land in os.environ ONLY for names the user left
-    unset — an explicitly-set env var always wins, including for
-    variables modules read at import time (config imports first in
-    mxnet_tpu/__init__.py exactly so those readers see tuned values).
-    """
-    global _TUNED_IGNORED_REASON
-    path = os.environ.get("MXTPU_TUNED_FILE", "")
-    if not path:
-        return
-    knobs, reason = load_tuned_profile(path)
-    if reason is not None:
-        _TUNED_IGNORED_REASON = reason
-        warnings.warn("MXTPU_TUNED_FILE=%s ignored: %s" % (path, reason))
-        return
-    for name, val in knobs.items():
-        if name in os.environ:  # explicit env var beats the profile
-            continue
-        os.environ[name] = str(val)
-        _TUNED_APPLIED[name] = str(val)
-
-
-_materialize_tuned()

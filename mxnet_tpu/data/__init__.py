@@ -20,7 +20,8 @@ tops out long before one TPU chip does):
 Knobs: ``MXTPU_DATA_WORKERS`` / ``MXTPU_DATA_RING_SLOTS`` /
 ``MXTPU_DATA_SLOT_BYTES`` / ``MXTPU_DATA_HOST_INDEX`` /
 ``MXTPU_DATA_NUM_HOSTS`` (config.py).  Metrics: the ``data.*``
-namespace (docs/observability.md).  Bench: ``bench.py --decode``.
+namespace (docs/observability.md).  Decode throughput on the chip's
+host: not measured (no cell reads through a ``DataService``).
 See docs/data.md.
 """
 from __future__ import annotations

@@ -328,8 +328,8 @@ class Executor:
         self._mem_programs = []
         # training-dispatch telemetry: how many device round-trips the
         # training loop has issued (fused single steps, K-step blocks,
-        # and materialized fwd+bwd calls each count 1) — bench.py reports
-        # dispatches = ceil(steps / steps_per_dispatch) from this
+        # and materialized fwd+bwd calls each count 1):
+        # ceil(steps / steps_per_dispatch), pinned in test_fused_dispatch.py
         self._train_dispatches = 0
         # >0 after a K-step block dispatch: outputs are stacked (K, ...)
         # and update_metric consumes the whole block; any plain forward
@@ -1689,7 +1689,7 @@ class Executor:
         comm.overlap_probe spans beside fit.dispatch.
 
         A COLLECTIVE probe: on a multi-process mesh every process must
-        call it at the same point (bench.py --spmd-procs does).  Runs on
+        call it at the same point.  Runs on
         throwaway copies — params/optimizer state are not advanced.
         Requires a prior comm-mode fused_update_block (the probe reuses
         its shapes)."""

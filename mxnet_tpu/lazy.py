@@ -3,8 +3,8 @@
 The imperative API used to pay one XLA dispatch per primitive: every
 ``a + b`` pushed through :func:`ndarray._engine_invoke` called ``op.fn``
 un-jitted on an engine worker, one device dispatch each — a fixed host
-cost per op (magnitude on the TPU host: not measured; bench.py
---imperative is the harness), for every imperative workload the K-step fused
+cost per op (magnitude on the TPU host: not measured; no cell runs an
+imperative chain), for every imperative workload the K-step fused
 training path (docs/perf.md) cannot reach: init, metrics, monitor
 sweeps, user scripts.
 

@@ -104,8 +104,8 @@ def _site():
     module and the threading internals.  Walks raw frames
     (sys._getframe) rather than traceback.extract_stack(): this runs
     on EVERY sentinel acquire, and extract_stack's per-frame linecache
-    lookups dominate the <5% overhead budget (bench --serve --lock-ab
-    measures it)."""
+    lookups would dominate the sentinel's cost (on the chip: not
+    measured)."""
     f = sys._getframe(1)
     while f is not None:
         fname = f.f_code.co_filename
@@ -348,7 +348,7 @@ def condition(name, lock=None):
 
 
 # ---------------------------------------------------------------------------
-# introspection (tests, bench.py --lock-ab, postmortem tooling)
+# introspection (tests, postmortem tooling)
 # ---------------------------------------------------------------------------
 
 def order_graph():

@@ -6,9 +6,9 @@ HWIO — the layout that keeps the fast bf16 grad kernels reachable,
 README Roofline item 2), threaded through every tower exactly like
 models/resnet.py.  The 299^2 3x3/s2 stem conv is eligible for the
 space-to-depth rewrite (`MXNET_TPU_S2D_STEM`, ops/nn.py
-space_to_depth_stem): C_in=3 at 299x299 stem convs are 46% of
-inference device time at ~25% MFU (BENCH_TABLE attribution; A/B via
-`bench.py --ab s2d_stem`)."""
+space_to_depth_stem): the switch's effect on this
+model is not measured on the chip (no cell runs Inception-v3;
+ROADMAP.md D4)."""
 from .. import symbol as sym
 
 __all__ = ["get_inception_v3"]

@@ -13,8 +13,8 @@ set (shared names, so a training checkpoint serves directly):
   only by shape — exactly what the bucketed compile-once machinery
   wants.
 * :meth:`score_symbol` — the same forward emitting raw per-position
-  logits ``(N, T, vocab)``: the decode-parity reference and the
-  full-recompute side of ``bench.py --ab kv_decode``.
+  logits ``(N, T, vocab)``: the decode-parity reference
+  (tests/test_transformer_lm.py; the generate cells' ``logit_rel_err``).
 * :meth:`prefill_symbol` — serving prefill: run the prompt through a
   sequence bucket, write each layer's per-head K/V block into the
   session's KV-ring slot (``_kv_cache_write``), and emit the

@@ -91,8 +91,8 @@ def _hist_quantile(hist, q):
 def step_skew(per_rank_mean_s):
     """Straggler attribution over ``{rank: mean step seconds}``:
     ``max_over_median`` (1.0 = perfectly even; 2.0 = the slowest rank
-    takes twice the median step) and which rank is slowest.  Shared by
-    the aggregator's cluster records and ``bench.py --spmd-procs``."""
+    takes twice the median step) and which rank is slowest.  Feeds
+    the aggregator's cluster records."""
     vals = {r: float(v) for r, v in (per_rank_mean_s or {}).items()
             if v is not None and float(v) > 0}
     if not vals:

@@ -66,7 +66,7 @@ from ..base import MXNetError
 __all__ = [
     "Program", "program", "footprints", "program_bytes",
     "book", "unbook", "rebook", "live_bytes", "census", "peak",
-    "set_census", "census_enabled", "census_stats",
+    "set_census", "census_enabled",
     "budget_bytes", "headroom_bytes", "admit", "MemoryBudgetError",
     "health_section", "write_postmortem", "inject_oom", "InjectedOOM",
     "last_postmortem_path", "reset", "nbytes_of",
@@ -129,14 +129,12 @@ _CENSUS_LOCK = threading.RLock()
 _LIVE = {}          # tag -> live bytes
 _LIVE_TOTAL = 0
 _PEAK = {"bytes": 0, "top": [], "wall_time": None}
-_BOOKS = 0          # census ops, for the bench A/B's "really armed" pin
 _CENSUS_ON = os.environ.get("MXTPU_MEM_CENSUS", "1") not in ("0", "")
 
 
 def set_census(flag):
-    """Arm/disarm the census in-process (tests, bench --mem-ab;
-    ``MXTPU_MEM_CENSUS=0`` sets the import-time default).  Returns the
-    previous state."""
+    """Arm/disarm the census in-process (tests; ``MXTPU_MEM_CENSUS=0``
+    sets the import-time default).  Returns the previous state."""
     global _CENSUS_ON
     prev = _CENSUS_ON
     _CENSUS_ON = bool(flag)
@@ -167,11 +165,10 @@ def rebook(tag, old_nbytes, new_nbytes):
 
 
 def _account(tag, delta):
-    global _LIVE_TOTAL, _PEAK, _BOOKS
+    global _LIVE_TOTAL, _PEAK
     if not _CENSUS_ON or delta == 0:
         return
     with _CENSUS_LOCK:
-        _BOOKS += 1
         n = _LIVE.get(tag, 0) + delta
         _LIVE[tag] = n if n > 0 else 0
         _LIVE_TOTAL = total = max(0, _LIVE_TOTAL + delta)
@@ -213,13 +210,6 @@ def peak():
         return {"bytes": _PEAK["bytes"],
                 "top": [list(kv) for kv in _PEAK["top"]],
                 "wall_time": _PEAK["wall_time"]}
-
-
-def census_stats():
-    """{books, live_bytes, tags} — the bench A/B's armed-side pin."""
-    with _CENSUS_LOCK:
-        return {"books": _BOOKS, "live_bytes": _LIVE_TOTAL,
-                "tags": len([t for t in _LIVE if _LIVE[t] > 0])}
 
 
 # ----------------------------------------------------------------------
@@ -642,12 +632,11 @@ def reset():
     """Test helper: clear the census, the footprint table, the peak
     tracker, and any armed injection.  Live Program objects keep their
     executables but re-register footprints on their next compile."""
-    global _LIVE_TOTAL, _PEAK, _BOOKS, _INJECT
+    global _LIVE_TOTAL, _PEAK, _INJECT
     with _CENSUS_LOCK:
         _LIVE.clear()
         _LIVE_TOTAL = 0
         _PEAK = {"bytes": 0, "top": [], "wall_time": None}
-        _BOOKS = 0
     with _TABLE_LOCK:
         _FOOTPRINTS.clear()
         _SITE_BYTES.clear()

@@ -126,7 +126,7 @@ def sample_fraction():
 
 
 def set_sample(fraction):
-    """Set the sampled fraction (tests, bench A/B); returns the
+    """Set the sampled fraction (tests); returns the
     previous value.  ``MXTPU_TRACE_SAMPLE`` sets the import-time
     default."""
     global _SAMPLE

@@ -270,8 +270,8 @@ def space_to_depth_stem(data, weight, kernel, stride, pad, dilate=(1, 1),
                         groups=1, layout=None):
     """EXACT factor-2 space-to-depth rewrite of a 2-D stride-2 conv.
 
-    A C_in<=4 stem conv runs at ~12% MFU on the MXU (round-5 audit,
-    tools/mfu_decompose.py: 3 channels fill 3/128 contraction lanes).
+    A C_in<=4 stem conv fills 3/128 of the MXU's contraction lanes (its
+    MFU on the chip: not measured since the round-5 audit's tool went).
     Folding factor-2 space-to-depth turns a [H, W, C] x (ky, kx)/s2 conv
     into an equivalent stride-1 conv on [ceil(H/2), ceil(W/2), 4*C]:
     input row 2Y+py folds into channel c*4 + py*2 + px, and each tap ky
@@ -712,8 +712,8 @@ def lrn(data, nsize=5, alpha=1e-4, beta=0.75, knorm=2.0, **kw):
     The window sum is nsize explicitly-shifted adds, NOT a
     `lax.reduce_window` over the channel axis: channels are the tiled
     minor dim on TPU, and a cross-lane windowed reduce there dominated
-    the whole AlexNet inference step (19.4 of 36.4 device ms — the
-    round-5 MFU audit, tools/mfu_decompose.py).  Shifted slices of a
+    the whole AlexNet inference step (19.4 of 36.4 device ms in the
+    round-5 MFU audit; no cell measures it).  Shifted slices of a
     zero-padded copy fuse into plain elementwise adds instead."""
     nsize = int(_lit(nsize))
     alpha, beta, knorm = float(_lit(alpha)), float(_lit(beta)), float(_lit(knorm))

@@ -314,8 +314,8 @@ class ModelServer:
         """Pre-compile every (tenant, bucket) program with one dummy
         fill each, synchronously, bypassing the queue — call BEFORE
         taking traffic so no real request ever pays an XLA compile
-        (bench.py --serve does, and then asserts its timed window is
-        compile-free).  Returns the number of programs visited."""
+        (tests/test_serving.py pins that traffic after it compiles
+        nothing).  Returns the number of programs visited."""
         buckets = list(buckets) if buckets is not None else list(self.ladder)
         with self._lock:  # consistent view vs concurrent add_tenant
             sessions = list(self._sessions.values())

@@ -13,7 +13,7 @@ bytes, kvstore push/pull, and per-step MFU at the module level.
 
 Three sinks:
 
-  * :func:`snapshot` — nested plain-dict view for tests and bench;
+  * :func:`snapshot` — nested plain-dict view for tests and the benchmark;
   * a JSONL writer (:func:`flush`, path from ``MXTPU_TELEMETRY_FILE``)
     emitting one record per flush with monotonic step stamps, which
     ``tools/parse_log.py --telemetry`` renders as a table;
@@ -28,8 +28,9 @@ additionally guard the call itself behind :func:`enabled` so no
 timestamping, formatting, or argument construction happens when
 telemetry is off — mxlint check E004 enforces exactly that.  Telemetry
 is ON by default (``MXTPU_TELEMETRY=0`` disables); unlike profiling it
-is cheap enough to leave on, and the always-on registry is what
-bench.py, Speedometer, and later robustness PRs report through.
+is cheap enough to leave on, and the always-on registry is what the
+benchmark's readers (benchmarks/readers/), Speedometer and the obs
+plane report through.
 """
 from __future__ import annotations
 
@@ -512,8 +513,8 @@ def histogram_quantile(name, q):
 
 
 def snapshot():
-    """Nested plain-dict view of the whole registry — the test/bench
-    sink.  Stable schema: top-level ``counters`` / ``gauges`` /
+    """Nested plain-dict view of the whole registry — the test and
+    benchmark sink.  Stable schema: top-level ``counters`` / ``gauges`` /
     ``histograms``; histogram values carry count/sum/min/max/buckets."""
     with _LOCK:
         return {

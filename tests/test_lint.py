@@ -37,17 +37,17 @@ def _ids(findings):
 # ----------------------------------------------------------------------
 
 def test_repo_is_lint_clean():
-    """`python -m tools.analysis mxnet_tpu bench.py tools` must exit
-    0: every finding fixed or allowlisted with a justification
-    (docs/static_analysis.md).  bench.py is in the sweep because its
-    A/B harness (`--ab`) toggles framework env vars; ISSUE 12 widened
-    the target from tools/bandwidth + tools/launch.py to ALL of
-    tools/ — the trace/SPMD checks (E006/E007) apply to the bandwidth
-    tool's jit+psum probes and the new check modules themselves must
-    hold their own gate."""
+    """`python -m tools.analysis mxnet_tpu chip_smoke.py tools` must
+    exit 0: every finding fixed or allowlisted with a justification
+    (docs/static_analysis.md).  chip_smoke.py is in the sweep because
+    it is the root script left that drives the framework on the chip;
+    ISSUE 12 widened the target from tools/bandwidth + tools/launch.py
+    to ALL of tools/ — the trace/SPMD checks (E006/E007) apply to the
+    bandwidth tool's jit+psum probes and the new check modules
+    themselves must hold their own gate."""
     findings, suppressed, errors = run_paths(
         [os.path.join(ROOT, "mxnet_tpu"),
-         os.path.join(ROOT, "bench.py"),
+         os.path.join(ROOT, "chip_smoke.py"),
          os.path.join(ROOT, "tools")])
     assert not errors, errors
     assert not findings, "\n".join(str(f) for f in findings)
@@ -2076,41 +2076,24 @@ def test_cli_changed_mode_restricts_to_the_diff(tmp_path):
     assert bad.returncode == 2, bad.stdout + bad.stderr
 
 
-# ----------------------------------------------------------------------
-# autotuner surfaces (ISSUE 20, docs/perf.md "Autotuning")
-# ----------------------------------------------------------------------
-
-def test_repo_gate_sweeps_the_autotuner():
-    """The gate walk covers tools/autotune.py and tools/parse_log.py —
-    the tuner toggles framework env vars per trial and its telemetry
-    bookings are exactly the E004/W103 surfaces, so a target-list edit
-    must not silently drop them."""
-    from tools.analysis.core import iter_py_files
-
-    files = iter_py_files([os.path.join(ROOT, "tools")])
-    swept = {os.path.relpath(f, ROOT) for f in files}
-    assert os.path.join("tools", "autotune.py") in swept
-    assert os.path.join("tools", "parse_log.py") in swept
-
-
-# the tuner's trial loop books tune.* telemetry once PER A/B TRIAL —
-# cheap next to a measured trial, but the guard contract is uniform:
-# corpus pins the unguarded shape as a violation and the shipped
+# a loop that books telemetry once per iteration of slow measured work
+# is cheap next to that work, but the guard contract is uniform: the
+# corpus pins the unguarded shape as a violation and the
 # `if telemetry.enabled():` shape as clean.
-E004_TUNE_UNGUARDED = """
+E004_LOOP_UNGUARDED = """
 from . import telemetry
 
 def run_trials(trials, measure):
     best = {}
     for t, cand in enumerate(trials):
         delta = measure(cand)
-        telemetry.inc("tune.trials")
-        telemetry.set_gauge("tune.trial", t)
-        telemetry.set_gauge("tune.tuned_knobs", len(best))
+        telemetry.inc("probe.trials")
+        telemetry.set_gauge("probe.trial", t)
+        telemetry.set_gauge("probe.kept", len(best))
     return best
 """
 
-E004_TUNE_GUARDED = """
+E004_LOOP_GUARDED = """
 from . import telemetry
 
 def run_trials(trials, measure):
@@ -2118,64 +2101,45 @@ def run_trials(trials, measure):
     for t, cand in enumerate(trials):
         delta = measure(cand)
         if telemetry.enabled():
-            telemetry.inc("tune.trials")
-            telemetry.set_gauge("tune.trial", t)
-            telemetry.set_gauge("tune.tuned_knobs", len(best))
+            telemetry.inc("probe.trials")
+            telemetry.set_gauge("probe.trial", t)
+            telemetry.set_gauge("probe.kept", len(best))
     return best
 """
 
 
-def test_e004_covers_the_tuner_trial_loop_shape(tmp_path):
-    findings, _, _ = _lint_src(tmp_path, E004_TUNE_UNGUARDED)
+def test_e004_covers_a_per_trial_booking_loop(tmp_path):
+    findings, _, _ = _lint_src(tmp_path, E004_LOOP_UNGUARDED)
     assert _ids(findings).count("E004") == 3, findings
-    findings, _, _ = _lint_src(tmp_path, E004_TUNE_GUARDED)
+    findings, _, _ = _lint_src(tmp_path, E004_LOOP_GUARDED)
     assert findings == [], findings
 
 
-# W103 resolves a registry whose EnvVar rows carry the 5th Tunable
-# field (the tunable-annotation format config.py uses since the
-# autotuner): annotated names read clean, an unregistered tuning knob
-# still fires.
-TUNE_KNOB_CONFIG = """
+# W103 reads a registration's NAME whatever else the row carries: rows
+# with a trailing annotation read clean, an unregistered variable still
+# fires.
+ANNOTATED_CONFIG = """
 EnvVar = None
-Tunable = None
+Note = None
 REGISTRY = [
     EnvVar("MXTPU_STEPS_PER_DISPATCH", int, 1, "fused K",
-           Tunable(workloads=("train",), choices=(1, 2, 4, 8))),
+           Note(choices=(1, 2, 4, 8))),
     EnvVar("MXTPU_SERVE_WAIT_MS", float, 2.0, "fill wait",
-           Tunable(workloads=("serve",), lo=0.0, hi=20.0)),
+           Note(lo=0.0, hi=20.0)),
 ]
 ABSORBED = {}
 """
 
-TUNE_KNOB_READS = """
+ANNOTATED_READS = """
 import os
 a = os.environ.get("MXTPU_STEPS_PER_DISPATCH", "1")
 b = os.environ.get("MXTPU_SERVE_WAIT_MS")
-c = os.environ.get("MXTPU_AUTOTUNE_SECRET")
+c = os.environ.get("MXTPU_UNREGISTERED_SECRET")
 """
 
 
-def test_w103_resolves_tunable_annotated_registry(tmp_path):
-    findings, _, _ = _lint_src(tmp_path, TUNE_KNOB_READS,
-                               config_src=TUNE_KNOB_CONFIG)
+def test_w103_resolves_registry_rows_with_extra_fields(tmp_path):
+    findings, _, _ = _lint_src(tmp_path, ANNOTATED_READS,
+                               config_src=ANNOTATED_CONFIG)
     assert _ids(findings) == ["W103"]
-    assert "MXTPU_AUTOTUNE_SECRET" in findings[0].message
-
-
-def test_autotune_knobs_registered_in_real_config():
-    """Every knob the tuner reads/searches is a registered tunable in
-    the real config.py, and the tuner's own control vars are registered
-    (so env_var.md documents them and W103 passes the reads)."""
-    from mxnet_tpu import config
-
-    names = {v.name for v in config.REGISTRY}
-    for required in ("MXTPU_TUNED_FILE", "MXTPU_TUNED_MODEL",
-                     "MXTPU_AUTOTUNE_TRIALS",
-                     "MXTPU_AUTOTUNE_NOISE_MULT"):
-        assert required in names
-    tunable = {v.name for v in config.tunables()}
-    for knob in ("MXTPU_STEPS_PER_DISPATCH", "MXTPU_STAGE_BUFFERS",
-                 "MXTPU_COMM_BUCKET_MB", "MXTPU_SERVE_MAX_BATCH",
-                 "MXTPU_SERVE_WAIT_MS", "MXTPU_LAZY_MAX_OPS"):
-        assert knob in tunable
+    assert "MXTPU_UNREGISTERED_SECRET" in findings[0].message

@@ -198,8 +198,8 @@ def test_admission_control_rejects_when_full():
 
 def test_warmup_precompiles_every_bucket():
     """ModelServer.warmup() visits every (tenant, bucket) program, so
-    traffic after it never compiles (the bench.py --serve timed-window
-    guarantee)."""
+    traffic after it never compiles (what a cell's `correct` demands
+    of its timed window)."""
     pred = _predictor(_mlp(16, 5, 0))
     server = serving.ModelServer({"m": pred}, max_batch=8, wait_ms=20,
                                  timeout_ms=60000)

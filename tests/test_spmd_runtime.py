@@ -142,35 +142,6 @@ def test_local_spmd_dist_kvstore_parity():
     assert all("sum=3.0" in l for l in kv_lines), kv_lines
 
 
-def test_bench_spmd_procs_smoke_row():
-    """`bench.py --spmd-procs 2 --smoke` reports a MEASURED multi-process
-    row whose snapshot carries the comm telemetry (bucket bytes, measured
-    collective GB/s, overlap fraction) — the ISSUE 10 acceptance row."""
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--spmd-procs", "2", "--smoke", "--steps", "8"],
-        env=_clean_env(), capture_output=True, text=True, timeout=600,
-        cwd=REPO)
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    row = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert "2 procs" in row["metric"]
-    assert row["value"] > 0 and row["steps"] >= 8
-    assert row["mesh_axes"] == ["data_dcn", "data_ici"]
-    comm = row["comm"]
-    assert comm["buckets"] >= 1
-    assert comm["bucket_bytes"] and all(b > 0 for b in comm["bucket_bytes"])
-    assert comm["bytes_reduced"] > 0 and comm["dispatches"] > 0
-    assert comm["gbps"] > 0
-    assert 0.0 <= comm["overlap_frac"] <= 1.0
-    # ISSUE 11: the per-rank skew column — one mean step time per rank
-    # plus the max/median straggler attribution (obs/aggregate.step_skew)
-    skew = row["rank_skew"]
-    assert len(skew["per_rank_step_s"]) == 2
-    assert all(v > 0 for v in skew["per_rank_step_s"])
-    assert skew["max_over_median"] >= 1.0
-    assert skew["slowest_rank"] in (0, 1)
-
-
 # ----------------------------------------------------------------------
 # single-host bucketed-collective checks (in-process, 8-device mesh)
 # ----------------------------------------------------------------------

@@ -42,7 +42,7 @@ builds its tenants and calls `mxnet_tpu.router.ReplicaAgent(...).
 serve_forever()` on its own exported MXTPU_ROUTER_PORT.  The full
 address list is exported to every replica AND printed as one
 `MXTPU_ROUTER_REPLICAS=...` line on stdout, so the operator's Router
-(or bench.py --serve --replicas N, which wraps this) can connect:
+can connect:
 
     python tools/launch.py --serve-replicas 4 python serve_my_model.py
 
@@ -391,9 +391,9 @@ def main():
             "--serve-replicas %d" % args.serve_replicas)
         ports = [_free_port() for _ in range(args.serve_replicas)]
         addrs = ",".join("127.0.0.1:%d" % p for p in ports)
-        # the line the operator's router (and bench.py --serve
-        # --replicas) reads back; flushed BEFORE the fleet spawns so a
-        # wrapper can start connecting while replicas warm up
+        # the line the operator's router reads back; flushed BEFORE the
+        # fleet spawns so a wrapper can start connecting while replicas
+        # warm up
         print("MXTPU_ROUTER_REPLICAS=%s" % addrs, flush=True)
         procs = []
 

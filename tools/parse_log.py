@@ -45,10 +45,7 @@ when the run recorded the ``serving.decode`` namespace (docs/serving.md
 (``live_mb`` booked live bytes at flush, ``peak_mb`` the process
 high-watermark, ``mem_headroom_pct`` % headroom under the byte budget)
 when it recorded the ``mem`` namespace (docs/observability.md "Memory
-observability"), and the autotuning columns (``tuned_knobs`` knobs
-adopted so far, ``trial`` the current A/B trial number,
-``best_delta_pct`` the final defaults-vs-best delta) when it recorded
-the ``tune`` namespace (tools/autotune.py; docs/perf.md "Autotuning").
+observability").
 Older logs render '-' in columns they predate.
 
 With ``--cluster`` the input is the rank-0 CLUSTER JSONL
@@ -156,8 +153,6 @@ def parse_telemetry(lines):
         dec_step_h = hist.get("serving.decode.step_seconds", {})
         has_mem = any(k.startswith("mem.")
                       for k in list(counters) + list(gauges))
-        has_tune = any(k.startswith("tune.")
-                       for k in list(counters) + list(gauges))
         rows.append({
             "flush_seq": rec.get("flush_seq"),
             "step": rec.get("step"),
@@ -294,15 +289,6 @@ def parse_telemetry(lines):
                         if has_mem else None),
             "mem_headroom_pct": (gauges.get("mem.headroom_pct")
                                  if has_mem else None),
-            # autotuning columns (tools/autotune.py, docs/perf.md
-            # "Autotuning"): knobs adopted so far, current trial number,
-            # and the final defaults-vs-best delta — '-' for logs that
-            # predate the tuner (no tune.* namespace)
-            "tuned_knobs": (gauges.get("tune.tuned_knobs", 0)
-                            if has_tune else None),
-            "trial": (gauges.get("tune.trial") if has_tune else None),
-            "best_delta_pct": (gauges.get("tune.best_delta_pct")
-                               if has_tune else None),
         })
     return rows
 
@@ -370,8 +356,7 @@ _TELEMETRY_COLS = ["flush_seq", "step", "epoch", "step_p50", "step_max",
                    "ckpt_secs", "ckpt_bytes", "resumes", "lock_wait_ms",
                    "contended", "tokens_s", "active_sessions",
                    "kv_slot_occupancy", "live_mb", "peak_mb",
-                   "mem_headroom_pct", "tuned_knobs", "trial",
-                   "best_delta_pct"]
+                   "mem_headroom_pct"]
 
 
 def _print_rows(rows, cols, fmt):
