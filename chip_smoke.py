@@ -468,6 +468,7 @@ def phase_kv_ring(sizes, ctx):
     ring = tuple(lm.cache_shape(slots + 1))
     step = lm.decode_symbol()
     inputs = dict(data=(slots, 1), slot=(slots,), length=(slots,),
+                  last_token=(slots + 1,),
                   **{n: ring for n in lm.cache_names()})
     shapes, _, _ = step.infer_shape(**inputs)
     rng = np.random.RandomState(sizes["seed"])

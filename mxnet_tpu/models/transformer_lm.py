@@ -27,7 +27,12 @@ set (shared names, so a training checkpoint serves directly):
 
 The serving graphs thread the KV rings functionally (caches in ->
 updated caches out); on TPU the serve program's donated-input tuple
-turns that into an in-place update.
+turns that into an in-place update.  One more vector rides with the
+rings, ``last_token (slots + 1,)``: both serving graphs sample the
+greedy token of their logits on the device (``_greedy_token``), return
+it as a small output and write it at ``last_token[slot]``; a decode row
+whose ``data`` is negative takes its token from there (``_token_feed``),
+so the batcher can dispatch a step before it has read the one before.
 
 The block's choices are arguments of the ONE spec, not a second model
 file: with the defaults the block is OPT's (learned positions,
@@ -242,18 +247,22 @@ class TransformerLM:
             "head_weight", shape=(self.vocab, self.d_model))
         return sym.dot(h2d, w, transpose_b=True, name=name)
 
-    def _serving_outputs(self, logits, rings, loads):
-        """``[logits, rings..., moe_load]``: a routed model's serving
-        graphs end with tokens per (layer, expert) of this call."""
+    def _serving_outputs(self, logits, rings, loads, last_token, slot):
+        """``[logits, rings..., last_token, token, moe_load]``: what is
+        threaded from call to call (the rings, then the last sampled
+        token of every slot), then what the batcher reads — the greedy
+        token of each row and, for a routed model, tokens per (layer,
+        expert) of this call."""
+        sampled = sym._greedy_token(logits, last_token, slot, name="token")
         extra = []
         if loads:
             extra = [sym.Reshape(sym.Concat(*loads, dim=0),
                                  shape=(self.num_layers, self.num_experts),
                                  name="moe_load")]
-        return sym.Group([logits] + rings + extra)
+        return sym.Group([logits] + rings + [sampled[1], sampled[0]] + extra)
 
     def extra_outputs(self):
-        """Names of the serving graphs' outputs after the rings."""
+        """Names of the serving graphs' outputs after the token."""
         return ("moe_load",) if self.num_experts else ()
 
     # ------------------------------------------------------------------
@@ -328,11 +337,13 @@ class TransformerLM:
     def prefill_symbol(self):
         """Prefill one prompt (batch 1, padded to a sequence bucket):
         outputs ``[next_logits (1, vocab), k_cache_0', v_cache_0',
-        ...]``.  Inputs beyond the caches: ``data (1, T)``, ``slot
-        (1,)``, ``length (1,)`` (true prompt length)."""
+        ..., last_token', token (1,)]``.  Inputs beyond the caches:
+        ``data (1, T)``, ``slot (1,)``, ``length (1,)`` (true prompt
+        length), ``last_token (slots + 1,)``."""
         data = sym.Variable("data")
         slot = sym.Variable("slot")
         length = sym.Variable("length")
+        last_token = sym.Variable("last_token")
         caches = self._cache_vars()
         h, embed_w = self._embed(data)
         outs, loads = [], [] if self.num_experts else None
@@ -352,17 +363,21 @@ class TransformerLM:
         # logits at the prompt's true tail, not the pad
         last = sym._take_step(h, length - 1, name="last_h")
         logits = self._head(last, embed_w, "next_logits")
-        return self._serving_outputs(logits, outs, loads)
+        return self._serving_outputs(logits, outs, loads, last_token, slot)
 
     def decode_symbol(self):
         """One decode step for a packed session batch: inputs ``data
-        (B, 1)`` (each session's last token), ``slot (B,)``, ``length
-        (B,)`` (tokens already cached), plus the rings; outputs
-        ``[logits (B, vocab), k_cache_0', v_cache_0', ...]``."""
+        (B, 1)`` (each session's last token, or a negative number for
+        "the one ``last_token[slot]`` holds"), ``slot (B,)``, ``length
+        (B,)`` (tokens already cached), plus the rings and ``last_token
+        (slots + 1,)``; outputs ``[logits (B, vocab), k_cache_0',
+        v_cache_0', ..., last_token', token (B,)]``."""
         data = sym.Variable("data")
         slot = sym.Variable("slot")
         length = sym.Variable("length")
+        last_token = sym.Variable("last_token")
         caches = self._cache_vars()
+        data = sym._token_feed(data, last_token, slot, name="token_feed")
         h, embed_w = self._embed(data, index=length)
         outs, loads = [], [] if self.num_experts else None
         for i in range(self.num_layers):
@@ -379,4 +394,4 @@ class TransformerLM:
         h = self._norm(h, "ln_f")
         flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")
         logits = self._head(flat, embed_w, "next_logits")
-        return self._serving_outputs(logits, outs, loads)
+        return self._serving_outputs(logits, outs, loads, last_token, slot)

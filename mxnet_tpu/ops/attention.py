@@ -2,7 +2,7 @@
 training/prefill and the slot-indexed KV-cache decode step.
 
 The transformer LM workload (models/transformer_lm.py, ROADMAP item 2)
-needs three graph-level primitives beyond the classic registry:
+needs four graph-level primitives beyond the classic registry:
 
 * ``LayerNorm`` — the reference op the zoo lacked (InstanceNorm
   normalizes spatial dims; a transformer normalizes the channel dim).
@@ -32,6 +32,14 @@ needs three graph-level primitives beyond the classic registry:
   ring-sized temporary.  (A scatter over the two separated index axes
   ``[slot, :, length, :]`` made XLA:TPU convert the WHOLE ring between
   two layouts twice a layer a step: PERF.md section 6, PR 26.)
+
+* ``_token_feed`` / ``_greedy_token`` — the sampled token stays on the
+  device.  Every serving program ends by taking the greedy token of its
+  logits and writing it into ``last_token (slots + 1,)``, a vector
+  addressed by slot like the rings and threaded the same way; a decode
+  row whose ``data`` is negative reads its token from there.  The host
+  can so dispatch step n+1 before it has read step n
+  (serving/decode.py).
 
 The block vocabulary of current open decoders rides beside them:
 ``RMSNorm`` and the rotary pair ``_rotary`` / ``_rotary_at`` (positions
@@ -344,3 +352,45 @@ def take_step(data, index, **kw):
     from the request's true tail, not the pad."""
     idx = _as_index(index)
     return data[jnp.arange(data.shape[0]), idx]
+
+
+# ----------------------------------------------------------------------
+# the sampled token, kept on the device between steps
+# ----------------------------------------------------------------------
+
+
+def _infer_token_feed(in_shapes, attrs):
+    data, last_token, slot = in_shapes
+    return [data, last_token, slot], [data]
+
+
+@register("_token_feed", inputs=("data", "last_token", "slot"),
+          infer_shape=_infer_token_feed)
+def token_feed(data, last_token, slot, **kw):
+    """The token ids ``(B, 1)`` a decode step embeds: ``data`` where the
+    host knew the token when it packed the row, and ``last_token[slot]``
+    — what the slot's previous program sampled — where it wrote a
+    negative number because that token was still in flight."""
+    return jnp.where(data < 0, last_token[_as_index(slot)][:, None], data)
+
+
+def _infer_greedy(in_shapes, attrs):
+    logits, last_token, slot = in_shapes
+    rows = (logits[0],)
+    return [logits, last_token, rows], [rows, last_token]
+
+
+@register("_greedy_token", inputs=("logits", "last_token", "slot"),
+          num_outputs=2, infer_shape=_infer_greedy)
+def greedy_token(logits, last_token, slot, **kw):
+    """Greedy sampling where the logits are: ``argmax`` of each row of
+    ``logits (B, vocab)``, the first index on a tie as ``numpy.argmax``
+    has it, in the wire's float32.  Outputs the tokens ``(B,)`` and
+    ``last_token`` with ``last_token[slot[b]] = token[b]`` written in row
+    order (padded rows all land on the scratch slot)."""
+    token = jnp.argmax(logits, axis=-1).astype(last_token.dtype)
+    slot_i = _as_index(slot)
+    for b in range(token.shape[0]):
+        last_token = lax.dynamic_update_slice(
+            last_token, token[b:b + 1], (slot_i[b],))
+    return token, last_token

@@ -36,16 +36,44 @@ deletes the buffers passed in, so every call REPLACES the rings it was
 given with the ones it got back; warm-up runs on throwaway rings of its
 own and never touches the live ones.
 
+**The loop runs one step ahead of the host.**  Step n+1 needs one thing
+of step n, a token id a session, and that never leaves the device: every
+program samples the greedy token of its logits and writes it at
+``last_token[slot]``, a ``(max_sessions + 1,)`` vector threaded and
+donated with the rings, and a decode row whose ``data`` is negative
+reads its token from there (ops/attention.py ``_token_feed`` /
+``_greedy_token``).  Rows are addressed by slot, so this survives any
+re-pack, bucket change or admission.  A program call is therefore
+DISPATCHED and left in flight (:class:`_Flight`); the host reads its
+small outputs — the ``(B,)`` tokens and a routed model's ``moe_load``,
+never the ``(B, vocab)`` logits — one call later.  :meth:`decode_step`
+packs and dispatches step n+1 first, from what the host knows without
+the token (who is live, their slots, how many positions each has fed:
+retirement by budget or ring-full is a count, so a session whose token
+in flight is its last is simply not packed), and only then fences on
+step n, reads it and emits — one ``on_token`` a session a step.  A
+prefill is dispatched by :meth:`admit` the same way and read after the
+step that was in flight before it.  So the host's pack, dispatch and
+emit, and the completion's way back to the host, pass under the device's
+step instead of beside it.  EOS needs the value: a session that turns
+out to have hit EOS has one row in flight, whose token is dropped
+(``serving.decode.dropped_rows``) and whose K/V row lands in its own,
+already freed slot at a position the slot's next tenant overwrites
+before it attends that far.  A step with no predecessor (the first
+after idle) is dispatched and read by the next call.
+
 Retirement (EOS, token budget, or ring-full) resolves the request's
 future with a :class:`GenerateResult` and frees the slot under
 admission control: prompts that arrive while all slots are busy wait
 in the tenant queue and are re-offered every decode window.  The
 server's close/drain contract extends to sessions: every future is
 resolved when close() returns, with partial tokens and
-``finish_reason='closed'`` on a no-drain shutdown — never lost.
+``finish_reason='closed'`` on a no-drain shutdown — never lost; tokens
+still in flight then are read first, they are computed already.
 """
 from __future__ import annotations
 
+import logging
 import time
 
 import numpy as _np
@@ -57,12 +85,13 @@ from .request import Request
 
 __all__ = ["GenerativeSession", "GenerateRequest", "GenerateResult"]
 
-# the histograms of one program call's legs (dispatch, device wait,
-# read-back) when the call is a decode step; a prefill or a warm-up
-# fill records the same spans and feeds no histogram
-_STEP_HISTS = ("serving.decode.dispatch_seconds",
-               "serving.decode.device_wait_seconds",
-               "serving.decode.d2h_seconds")
+# the histograms of the three legs in which a decode step is READ (device
+# wait, read-back, emit), one `decode_step` call after the one whose
+# `pack` and `dispatch` legs sent it.  A prefill and a synchronous `_run`
+# record the same spans and feed no histogram
+_LAND_HISTS = ("serving.decode.device_wait_seconds",
+               "serving.decode.d2h_seconds",
+               "serving.decode.emit_seconds")
 _NO_HISTS = (None, None, None)
 
 
@@ -106,17 +135,37 @@ class GenerateRequest(Request):
 
 
 class _Session:
-    """One ACTIVE decode session (post-prefill, slot held)."""
+    """One ACTIVE decode session (slot held, prefill dispatched)."""
 
-    __slots__ = ("req", "slot", "prompt_len", "generated", "fed")
+    __slots__ = ("req", "slot", "prompt_len", "generated", "fed", "retired")
 
     def __init__(self, req, slot, prompt_len):
         self.req = req
         self.slot = slot
         self.prompt_len = prompt_len
-        self.generated = []  # sampled tokens; the last one is NOT fed yet
-        # positions cached so far == tokens fed through the model
+        self.generated = []  # the tokens the host has read
+        # positions the dispatched programs have written into the ring
+        # == tokens fed through the model, read back or not
         self.fed = prompt_len
+        self.retired = False
+
+    def sampled(self):
+        """Tokens sampled on the device so far: the prefill's and one a
+        decode row, whether or not the host has read them."""
+        return self.fed - self.prompt_len + 1
+
+
+class _Flight:
+    """One dispatched program call whose outputs the host has not read:
+    `outs` are the device's ``token (B,)`` and the outputs after it,
+    `rows` the sessions of the packed rows in order."""
+
+    __slots__ = ("outs", "rows", "prefill")
+
+    def __init__(self, outs, rows, prefill):
+        self.outs = outs
+        self.rows = rows
+        self.prefill = prefill
 
 
 class GenerativeSession:
@@ -125,7 +174,10 @@ class GenerativeSession:
     `model` is duck-typed (models/transformer_lm.py TransformerLM is
     the zoo instance): attribute ``max_len`` and methods
     ``prefill_symbol()`` / ``decode_symbol()`` / ``cache_names()`` /
-    ``cache_shape(slots, max_len)`` (the stored shape of one ring).
+    ``cache_shape(slots, max_len)`` (the stored shape of one ring).  Both
+    graphs take ``data``, ``slot``, ``length``, the rings and
+    ``last_token (slots + 1,)`` and return ``[logits, rings...,
+    last_token, token (B,), extra_outputs()...]``.
     `params` maps parameter name -> array (a training checkpoint's
     arg+aux dicts merged).  Knob defaults come from the config
     registry: ``MXTPU_SERVE_MAX_SESSIONS`` / ``_MAX_DECODE_TOKENS`` /
@@ -154,7 +206,9 @@ class GenerativeSession:
         # a routed model's programs end with tokens per (layer, expert)
         self._reports_moe_load = "moe_load" in tuple(
             getattr(model, "extra_outputs", tuple)())
-        self._input_names = ["data", "slot", "length"] + self._cache_names
+        # every call threads the rings, then each slot's last token
+        self._input_names = (["data", "slot", "length"] + self._cache_names
+                             + ["last_token"])
         # the model owns the ring's stored shape; the +1 is the scratch
         # slot padded decode rows point at
         cshape = tuple(model.cache_shape(self._slots + 1, self._max_len))
@@ -173,11 +227,11 @@ class GenerativeSession:
             model.decode_symbol(), dict(params),
             self._shapes(self._decode_ladder[0], 1, prefill=False),
             ctx=ctx)
-        # the device-resident KV rings, threaded through every call
-        self._caches = [_np.zeros(cshape, _np.float32)
-                        for _ in self._cache_names]
+        # the device-resident state, threaded through every call
+        self._state = self._fresh_state()
         self._free = list(range(self._slots))  # LIFO slot pool
         self._active = []
+        self._flights = []  # dispatched and unread, oldest first
         self._prog_lock = locks.lock("serving.decode_progs")
         self._programs = {}
         self._tokens_done = 0
@@ -189,12 +243,9 @@ class GenerativeSession:
         if telemetry.enabled():
             from ..obs import memory
 
-            self._mem_booked = sum(c.nbytes for c in self._caches)
+            self._mem_booked = sum(c.nbytes for c in self._state)
             memory.book("kv_ring.%s" % name, self._mem_booked)
-        if telemetry.enabled():
-            telemetry.set_gauge(
-                "kv.ring_bytes",
-                sum(c.nbytes for c in self._caches))
+            telemetry.set_gauge("kv.ring_bytes", self._mem_booked)
             telemetry.set_gauge("kv.slot_occupancy", 0.0)
             telemetry.set_gauge("serving.decode.active_sessions", 0)
 
@@ -205,7 +256,14 @@ class GenerativeSession:
         shp = {"data": (batch, seq), "slot": (batch,),
                "length": (batch,)}
         shp.update({n: self._cache_shape for n in self._cache_names})
+        shp["last_token"] = (self._slots + 1,)
         return shp
+
+    def _fresh_state(self):
+        """Zeroed rings and last-token vector, in wire order."""
+        return ([_np.zeros(self._cache_shape, _np.float32)
+                 for _ in self._cache_names]
+                + [_np.zeros((self._slots + 1,), _np.float32)])
 
     def validate(self, inputs):
         """A classic submit() against a generative tenant is a client
@@ -236,7 +294,10 @@ class GenerativeSession:
         return len(self._free)
 
     def active(self):
-        return len(self._active)
+        """Sessions mid-generation — or, once the last of them has hit
+        EOS, the flight that still holds its dropped row: truthy while
+        :meth:`decode_step` has anything left to do."""
+        return len(self._active) or len(self._flights)
 
     def budget_for(self, max_new_tokens):
         return (self._budget_default if max_new_tokens is None
@@ -267,64 +328,111 @@ class GenerativeSession:
         batch bucket with dummy fills (ModelServer.warmup calls this;
         `buckets` — the server's BATCH ladder — is ignored: generative
         programs bucket by sequence length and session count).  The
-        fills thread throwaway rings: a re-warm beside live traffic must
+        fills thread throwaway state: a re-warm beside live traffic must
         neither donate nor overwrite the rings the batcher holds."""
-        rings = [_np.zeros(self._cache_shape, _np.float32)
-                 for _ in self._cache_names]
+        state = self._fresh_state()
         n = 0
         for t in self._seq_ladder:
             exe, fn = self._program(self._prefill_pred, 1, t, True)
-            _, rings, _ = self._call(
-                exe, fn, rings, _np.zeros((1, t), _np.float32),
+            _, state, _ = self._call(
+                exe, fn, state, _np.zeros((1, t), _np.float32),
                 _np.full((1,), self._slots, _np.float32),
                 _np.ones((1,), _np.float32))
             n += 1
         for b in self._decode_ladder:
             exe, fn = self._program(self._decode_pred, b, 1, False)
-            _, rings, _ = self._call(
-                exe, fn, rings, _np.zeros((b, 1), _np.float32),
+            _, state, _ = self._call(
+                exe, fn, state, _np.zeros((b, 1), _np.float32),
                 _np.full((b,), self._slots, _np.float32),
                 _np.zeros((b,), _np.float32))
             n += 1
         return n
 
-    def _call(self, exe, fn, rings, data, slot, length, hists=_NO_HISTS):
-        """One program call threading `rings` through: returns (host
-        logits, updated rings, host outputs after the rings — a routed
-        model's `moe_load`).  The rings passed in are donated on
-        device backends — the caller keeps only what comes back.
-        `hists` names the histograms of the three legs (dispatch, device
-        wait, read-back); only a decode step passes them."""
+    def _launch(self, exe, fn, state, data, slot, length, logits):
+        """Queue one program call threading `state` through and ask for
+        the copies of its small outputs behind it (a copy asked for only
+        after a fence costs one more host round trip a call): returns
+        (those outputs still on the device — the logits if `logits`,
+        the tokens, a routed model's `moe_load` — and the updated
+        state).  The state passed in is donated on device backends —
+        the caller keeps only what comes back."""
+        other_vals, aux_vals = exe.serve_args(self._input_names)
+        ins = tuple([data, slot, length] + list(state))
+        outs = fn(ins, other_vals, aux_vals, _np.uint32(0))
+        n_state = len(state)
+        small = tuple(outs[1 + n_state:])
+        if logits:
+            small = (outs[0],) + small
+        for o in small:
+            o.copy_to_host_async()
+        return small, list(outs[1:1 + n_state])
+
+    def _call(self, exe, fn, state, data, slot, length):
+        """One SYNCHRONOUS program call on `state`: returns (host
+        logits, updated state, host outputs after the token).  What the
+        warm-up and `_run` use; the batcher's own calls stay in flight
+        (`_dispatch` / `_land`)."""
         from .. import profiler
 
-        with profiler.span("decode.dispatch", cat="serving", hist=hists[0]):
-            other_vals, aux_vals = exe.serve_args(self._input_names)
-            ins = tuple([data, slot, length] + list(rings))
-            outs = fn(ins, other_vals, aux_vals, _np.uint32(0))
-            # request the logits' copy behind the program, as np.asarray
-            # itself does first: a copy requested only after the fence
-            # below costs one more host round trip a call
-            n_rings = len(rings)
-            small = (outs[0],) + tuple(outs[1 + n_rings:])
-            for o in small:
-                o.copy_to_host_async()
+        with profiler.span("decode.dispatch", cat="serving"):
+            small, state = self._launch(exe, fn, state, data, slot, length,
+                                        logits=True)
         # the fence np.asarray would perform anyway, made explicit so
         # that waiting for the device and copying are two numbers
-        with profiler.span("decode.device_wait", cat="serving",
-                           hist=hists[1]):
-            outs[0].block_until_ready()
-        with profiler.span("decode.d2h", cat="serving", hist=hists[2]):
-            logits, *extra = (_np.asarray(o) for o in small)
-        return logits, list(outs[1:1 + n_rings]), extra
+        with profiler.span("decode.device_wait", cat="serving"):
+            small[0].block_until_ready()
+        with profiler.span("decode.d2h", cat="serving"):
+            logits, _token, *extra = (_np.asarray(o) for o in small)
+        return logits, state, extra
 
-    def _run(self, exe, fn, data, slot, length, hists=_NO_HISTS):
-        """One LIVE program call: the session's rings go in, the updated
-        rings replace them; returns the host logits."""
-        logits, self._caches, extra = self._call(
-            exe, fn, self._caches, data, slot, length, hists)
+    def _run(self, exe, fn, data, slot, length):
+        """One LIVE program call, start to end: the session's state goes
+        in, the updated state replaces it; returns the host logits
+        ``(B, vocab)``.  The path of whoever needs logits and not tokens
+        (the benchmark's reference check, chip_smoke.py), for a batcher
+        that is idle."""
+        logits, self._state, extra = self._call(
+            exe, fn, self._state, data, slot, length)
         if self._reports_moe_load:
             self._book_moe_load(extra[0])
         return logits
+
+    def _dispatch(self, exe, fn, data, slot, length, rows, prefill,
+                  hist=None):
+        """Queue one program call on the live state and leave it in
+        flight: nothing here waits for the device."""
+        from .. import profiler
+
+        with profiler.span("decode.dispatch", cat="serving", hist=hist):
+            outs, self._state = self._launch(
+                exe, fn, self._state, data, slot, length, logits=False)
+        self._flights.append(_Flight(outs, rows, prefill))
+
+    def _land(self, flight, hists=_NO_HISTS):
+        """Fence on one flight, read its tokens and emit them, one a
+        row — but for a row whose session has retired since (it hit EOS
+        while the row was in flight): that token is dropped."""
+        from .. import profiler, telemetry
+
+        with profiler.span("decode.device_wait", cat="serving",
+                           hist=hists[0]):
+            flight.outs[0].block_until_ready()
+        with profiler.span("decode.d2h", cat="serving", hist=hists[1]):
+            token, *extra = (_np.asarray(o) for o in flight.outs)
+        if self._reports_moe_load:
+            self._book_moe_load(extra[0])
+        with profiler.span("decode.emit", cat="serving", hist=hists[2]):
+            live = [(sess, int(t)) for sess, t in zip(flight.rows, token)
+                    if not sess.retired]
+            for sess, t in live:
+                self._emit(sess, t)
+        dropped = len(flight.rows) - len(live)
+        if not flight.prefill:  # a session's first token is the prefill's
+            self._tokens_done += len(live)
+            if telemetry.enabled():
+                telemetry.inc("serving.decode.tokens", len(live))
+        if dropped and telemetry.enabled():
+            telemetry.inc("serving.decode.dropped_rows", dropped)
 
     @staticmethod
     def _book_moe_load(load):
@@ -345,10 +453,11 @@ class GenerativeSession:
     # admission: prefill newly-arrived prompts into free slots
     # ------------------------------------------------------------------
     def admit(self, reqs):
-        """Prefill each request into a free slot; returns the requests
-        that found NO free slot (the server re-queues them at the
-        front — admission control, not failure).  A prefill error
-        fails ITS request only."""
+        """Dispatch each request's prefill into a free slot; returns the
+        requests that found NO free slot (the server re-queues them at
+        the front — admission control, not failure).  The first token
+        is read by the next :meth:`decode_step`.  A prefill that cannot
+        be dispatched fails ITS request only."""
         leftovers = []
         for req in reqs:
             if self._closed:
@@ -359,7 +468,6 @@ class GenerativeSession:
                 try:
                     self._prefill(req)
                 except BaseException as e:  # noqa: BLE001
-                    self._release_maybe(req)
                     req.fail(e)
         return leftovers
 
@@ -369,30 +477,25 @@ class GenerativeSession:
         tokens = req.inputs["data"].reshape(-1)
         n = tokens.shape[0]
         bucket = choose_bucket(self._seq_ladder, n)
-        with profiler.span("serve.prefill", cat="serving",
-                           hist="serving.prefill_seconds", bucket=bucket,
-                           prompt=n):
+        with profiler.span("serve.prefill_dispatch", cat="serving",
+                           bucket=bucket, prompt=n):
             req.service_at = time.monotonic()
             exe, fn = self._program(self._prefill_pred, 1, bucket, True)
-            slot = self._free.pop()
             data = _np.zeros((1, bucket), _np.float32)
             data[0, :n] = tokens
-            logits = self._run(exe, fn, data,
-                               _np.full((1,), slot, _np.float32),
-                               _np.full((1,), n, _np.float32))
-            sess = _Session(req, slot, n)
+            sess = _Session(req, self._free.pop(), n)
+            try:
+                self._dispatch(exe, fn, data,
+                               _np.full((1,), sess.slot, _np.float32),
+                               _np.full((1,), n, _np.float32),
+                               [sess], prefill=True)
+            except BaseException:
+                self._free.append(sess.slot)
+                raise
             self._active.append(sess)
         if telemetry.enabled():
             telemetry.inc("serving.decode.sessions")
             self._note_occupancy()
-        self._emit(sess, int(_np.argmax(logits[0])))
-
-    def _release_maybe(self, req):
-        """Roll back a slot a failed prefill may have claimed."""
-        for sess in list(self._active):
-            if sess.req is req:
-                self._active.remove(sess)
-                self._free.append(sess.slot)
 
     def _note_occupancy(self):
         from .. import telemetry
@@ -407,54 +510,85 @@ class GenerativeSession:
     # ------------------------------------------------------------------
     # the decode iteration
     # ------------------------------------------------------------------
+    def _wants_row(self, sess):
+        """Whether `sess` decodes on after the tokens sampled so far —
+        a count: the budget and the ring's end need no token's value."""
+        sampled = sess.sampled()
+        return (sampled < sess.req.max_new_tokens
+                and sess.prompt_len + sampled < self._max_len)
+
     def decode_step(self):
-        """One token-level iteration: re-pack ALL active sessions into
-        the smallest decode bucket, run one step, sample, retire.
-        Returns tokens produced (0 when idle)."""
+        """One token-level iteration, a step ahead of the host: re-pack
+        every session that decodes on into the smallest decode bucket
+        and DISPATCH that step; then land what was in flight before it —
+        the previous step (fence, read, emit, retire) and the prefills
+        admitted since.  Returns the rows dispatched (0 when the call
+        only landed, or found nothing to do)."""
+        from .. import profiler
+
+        landing, self._flights = self._flights, []
+        rows = [s for s in self._active if self._wants_row(s)]
+        # at most one step is in flight, and it is the oldest: this call
+        # lands all it finds and leaves the one it dispatches
+        step = landing.pop(0) if landing and not landing[0].prefill else None
+        n = len(rows)
+        bucket = choose_bucket(self._decode_ladder, n) if n else 0
+        if rows or step is not None:
+            with profiler.span("serve.decode_step", cat="serving",
+                               hist="serving.decode.step_seconds", n=n,
+                               bucket=bucket):
+                if rows:
+                    self._dispatch_step(rows, bucket)
+                if step is not None:
+                    self._land(step, _LAND_HISTS)
+        for flight in landing:
+            with profiler.span("serve.prefill", cat="serving",
+                               hist="serving.prefill_seconds"):
+                self._land(flight)
+        self._note_occupancy()
+        return n
+
+    def _dispatch_step(self, rows, bucket):
+        """Pack `rows` into the `bucket`-row decode program and dispatch
+        it.  A row whose last token the host has not read yet says so
+        with a negative `data`, and the program takes the token from
+        ``last_token[slot]``."""
         from .. import profiler, telemetry
 
-        act = self._active
-        if not act:
-            return 0
-        n = len(act)
-        bucket = choose_bucket(self._decode_ladder, n)
-        with profiler.span("serve.decode_step", cat="serving",
-                           hist="serving.decode.step_seconds", n=n,
-                           bucket=bucket):
-            with profiler.span("decode.pack", cat="serving",
-                               hist="serving.decode.pack_seconds"):
-                exe, fn = self._program(self._decode_pred, bucket, 1, False)
-                data = _np.zeros((bucket, 1), _np.float32)
-                slot = _np.full((bucket,), self._slots, _np.float32)  # scratch
-                length = _np.zeros((bucket,), _np.float32)
-                for i, sess in enumerate(act):
-                    data[i, 0] = sess.generated[-1]
-                    slot[i] = sess.slot
-                    length[i] = sess.fed
-            logits = self._run(exe, fn, data, slot, length, _STEP_HISTS)
-            with profiler.span("decode.emit", cat="serving",
-                               hist="serving.decode.emit_seconds"):
-                for i, sess in enumerate(list(act)):
-                    sess.fed += 1
-                    self._emit(sess, int(_np.argmax(logits[i])))
-        self._tokens_done += n
+        with profiler.span("decode.pack", cat="serving",
+                           hist="serving.decode.pack_seconds"):
+            exe, fn = self._program(self._decode_pred, bucket, 1, False)
+            data = _np.zeros((bucket, 1), _np.float32)
+            slot = _np.full((bucket,), self._slots, _np.float32)  # scratch
+            length = _np.zeros((bucket,), _np.float32)
+            ahead = 0
+            for i, sess in enumerate(rows):
+                unread = sess.sampled() > len(sess.generated)
+                ahead += unread
+                data[i, 0] = -1.0 if unread else sess.generated[-1]
+                slot[i] = sess.slot
+                length[i] = sess.fed
+        self._dispatch(exe, fn, data, slot, length, rows, prefill=False,
+                       hist="serving.decode.dispatch_seconds")
+        for sess in rows:
+            sess.fed += 1
         if telemetry.enabled():
+            n = len(rows)
             telemetry.inc("serving.decode.dispatches")
-            telemetry.inc("serving.decode.tokens", n)
+            if ahead:
+                telemetry.inc("serving.decode.runahead_steps")
             telemetry.set_gauge("serving.decode.batch_fill_ratio",
                                 n / bucket)
             # position-steps: over a window their ratio is the mean
             # reserved over used.  Reserved are the KV ring sets bound on
             # the device — the live set plus the zero-filled placeholder
             # set each bucket program's executor binds — times a ring's
-            # rows and length; used are the positions the active
+            # rows and length; used are the positions the packed
             # sessions had filled when the step was packed
             telemetry.inc("kv.reserved_positions",
                           (1 + len(self._programs)) * self._cache_shape[0]
                           * self._max_len)
             telemetry.inc("kv.used_positions", int(length.sum()))
-            self._note_occupancy()
-        return n
 
     def _emit(self, sess, token):
         """Book one sampled token; retire on EOS / budget / ring-full."""
@@ -479,6 +613,7 @@ class GenerativeSession:
         steps; the next step simply re-packs without them)."""
         from .. import telemetry
 
+        sess.retired = True
         if sess in self._active:
             self._active.remove(sess)
         self._free.append(sess.slot)
@@ -492,7 +627,18 @@ class GenerativeSession:
     def finish_all(self, reason="closed"):
         """Retire every active session NOW with its partial tokens —
         the close(drain=False) path.  Zero lost futures, by
-        construction."""
+        construction.  Tokens in flight are computed already and are
+        the sessions' (an admitted session has its first token); a
+        flight that cannot be read is dropped."""
+        landing, self._flights = self._flights, []
+        try:
+            for flight in landing:
+                self._land(flight)
+        except Exception:  # noqa: BLE001 — the futures come first
+            logging.getLogger(__name__).warning(
+                "tenant %r: a program call in flight at shutdown could "
+                "not be read; its tokens are dropped", self.name,
+                exc_info=True)
         for sess in list(self._active):
             self._retire(sess, reason)
 
@@ -503,6 +649,7 @@ class GenerativeSession:
         new prompts (a request-level error, not a server-level one)."""
         from .. import telemetry
 
+        self._flights = []
         for sess in list(self._active):
             self._active.remove(sess)
             self._free.append(sess.slot)
@@ -518,8 +665,8 @@ class GenerativeSession:
                 "tokens_decoded": self._tokens_done}
 
     def drain(self):
-        """Generative dispatches are synchronous on the batcher thread
-        (the decode loop IS the pipeline) — nothing to fence."""
+        """The batcher thread lands its own flights (the decode loop IS
+        the pipeline) — nothing to fence from outside."""
 
     def close(self):
         self._closed = True

@@ -232,21 +232,32 @@ def test_defaults_are_the_opt_block():
     assert lm.training_symbol().list_arguments() == want + ["softmax_label"]
     assert lm.extra_outputs() == ()
     ring = lm.cache_shape(3)
-    shapes = dict(data=(2, 1), slot=(2,), length=(2,),
+    shapes = dict(data=(2, 1), slot=(2,), length=(2,), last_token=(3,),
                   **{n: ring for n in lm.cache_names()})
+    # logits, the rings, each slot's last token, the sampled tokens
     _, outs, _ = lm.decode_symbol().infer_shape(**shapes)
-    assert outs == [(2, 24)] + [ring] * 4
+    assert outs == [(2, 24)] + [ring] * 4 + [(3,), (2,)]
     shapes.update(data=(1, 8), slot=(1,), length=(1,))
     _, outs, _ = lm.prefill_symbol().infer_shape(**shapes)
-    assert outs == [(1, 24)] + [ring] * 4
+    assert outs == [(1, 24)] + [ring] * 4 + [(3,), (1,)]
 
     routed = family.model(CONFIG)
     assert routed.extra_outputs() == ("moe_load",)
     names = routed.decode_symbol().list_outputs()
-    assert len(names) == 1 + 2 * CONFIG["num_hidden_layers"] + 1
+    assert len(names) == 1 + 2 * CONFIG["num_hidden_layers"] + 2 + 1
     assert "pos_weight" not in routed.score_symbol().list_arguments()
     assert not any(a.endswith("_bias") or a.endswith("_beta")
                    for a in routed.score_symbol().list_arguments())
+
+
+def test_runahead_matches_one_at_a_time_greedy_decode_routed(held):
+    """The loop one step ahead of the host, on a routed model: same
+    tokens, `finish_reason` and `on_token` order as one request at a
+    time through `score_symbol` (tests/test_transformer_lm.py)."""
+    from test_transformer_lm import assert_runahead_matches_one_at_a_time
+
+    assert_runahead_matches_one_at_a_time(family.model(CONFIG), held,
+                                          seq_bucket=16)
 
 
 def test_batcher_books_the_moe_counters(held):

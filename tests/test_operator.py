@@ -748,6 +748,7 @@ COVERED_ELSEWHERE = {
     # numpy oracles + per-step KV-decode vs full-recompute parity)
     "LayerNorm", "_sdp_attention", "_cached_attention", "_kv_cache_write",
     "_add_positional", "_add_positional_at", "_take_step",
+    "_token_feed", "_greedy_token",
     # test_olmoe.py (numpy oracles, and the plain jax.numpy reference of
     # the benchmark through the score graph and the KV ring)
     "RMSNorm", "_rotary", "_rotary_at",

@@ -234,11 +234,40 @@ def test_children_do_not_sum_past_their_parent(request, run, parent,
                                                children):
     hists, _ = request.getfixturevalue(run)
     assert sum(hists[c]["sum"] for c in children) <= hists[parent]["sum"]
-    # a prefill's program call records the same spans and feeds none of
-    # the decode step's histograms: one observation per step
-    if run == "served":
-        for c in children:
-            assert hists[c]["count"] == hists[parent]["count"]
+
+
+def test_the_legs_of_a_decode_step_belong_to_two_steps(served):
+    """`pack` and `dispatch` are the step's own; `device_wait`, `d2h`
+    and `emit` are of the step dispatched one call before.  So the first
+    step after idle feeds the first two alone (nothing is in flight
+    before it but the prefill, which feeds none of these histograms),
+    the last call before the drain — every token in flight is a
+    session's last, nothing to pack — feeds the last three alone, and
+    every step between feeds all five.  The fixture's one request of
+    four tokens: a prefill, three dispatched steps, four calls."""
+    hists, events = served
+    assert hists["serving.decode.step_seconds"]["count"] == 4
+    for leg in DECODE_LEGS:
+        assert hists[leg]["count"] == 3
+    assert hists["serving.prefill_seconds"]["count"] == 1
+    by_id = {e["args"]["id"]: e for e in events if "id" in e.get("args", {})}
+    steps = sorted((e for e in events if e["name"] == "serve.decode_step"),
+                   key=lambda e: e["ts"])
+    legs = [sorted(e["name"] for e in events
+                   if e["args"].get("parent") == step["args"]["id"])
+            for step in steps]
+    ahead = ["decode.dispatch", "decode.pack"]
+    behind = ["decode.d2h", "decode.device_wait", "decode.emit"]
+    assert legs == [ahead, sorted(ahead + behind), sorted(ahead + behind),
+                    behind]
+    assert [s["args"]["n"] for s in steps] == [1, 1, 1, 0]
+    # the prefill: dispatched at admission, read after the first step
+    # was dispatched behind it
+    (sent,) = [e for e in events if e["name"] == "serve.prefill_dispatch"]
+    (read,) = [e for e in events if e["name"] == "serve.prefill"]
+    assert sent["args"]["bucket"] == 8 and sent["args"]["prompt"] == 3
+    assert sent["ts"] < steps[0]["ts"] < read["ts"] < steps[1]["ts"]
+    assert by_id[read["args"]["id"]] is read
 
 
 @pytest.mark.parametrize("run,child,parent", [
@@ -249,10 +278,11 @@ def test_children_do_not_sum_past_their_parent(request, run, parent,
     ("fit_run", "fit.dispatch", "fit.block"),
     ("fit_run", "fit.device_wait", "fit.block"),
     ("served", "decode.pack", "serve.decode_step"),
-    ("served", "decode.emit", "serve.decode_step"),
-    ("served", "decode.dispatch", ("serve.decode_step", "serve.prefill")),
+    ("served", "decode.dispatch", ("serve.decode_step",
+                                   "serve.prefill_dispatch")),
     ("served", "decode.device_wait", ("serve.decode_step", "serve.prefill")),
     ("served", "decode.d2h", ("serve.decode_step", "serve.prefill")),
+    ("served", "decode.emit", ("serve.decode_step", "serve.prefill")),
 ])
 def test_args_parent_is_the_span_that_was_open_on_the_thread(
         request, run, child, parent):
@@ -347,7 +377,37 @@ def test_parent_stacks_are_per_thread():
 
 
 # ----------------------------------------------------------------------
-# (e) KV positions reserved and used
+# (e) the run-ahead's own counter
+# ----------------------------------------------------------------------
+def test_runahead_steps_count_the_steps_dispatched_before_a_read(
+        fresh_telemetry):
+    """`serving.decode.runahead_steps` grows when a step is dispatched
+    with a row whose token the host has not read — never past
+    `serving.decode.dispatches`, and with two live sessions by every
+    step: the first follows the unread prefills, each later one the
+    unread step before it."""
+    lm, params = _lm_and_params()
+    gs = GenerativeSession("lm", lm, params, max_sessions=2, max_len=16,
+                           seq_buckets=[8])
+    try:
+        reqs = [GenerateRequest("lm", [3, 4, 5][:2 + i], 60.0, 5 + i)
+                for i in range(2)]
+        assert gs.admit(reqs) == []
+        while gs.active():
+            gs.decode_step()
+        ahead = telemetry.counter_value("serving.decode.runahead_steps")
+        steps = telemetry.counter_value("serving.decode.dispatches")
+        assert 0 < ahead <= steps
+        # budgets 5 and 6: the prefill's token, then 5 dispatched steps
+        assert (ahead, steps) == (5, 5)
+        assert telemetry.counter_value("serving.decode.tokens") == 4 + 5
+        assert telemetry.counter_value("serving.decode.dropped_rows") == 0
+    finally:
+        gs.close()
+
+
+# ----------------------------------------------------------------------
+# (f) KV positions reserved and used
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize("sessions", [1, 2])
 def test_kv_position_counters_grow_with_every_decode_step(

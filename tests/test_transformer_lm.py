@@ -180,6 +180,183 @@ def test_join_leave_mid_batch_matches_solo_decode():
 
 
 # ----------------------------------------------------------------------
+# the loop one step ahead of the host (serving/decode.py): the token a
+# row needs is on the device, so nothing below may depend on when the
+# host reads it
+# ----------------------------------------------------------------------
+RUNAHEAD_BUDGETS = [3, 9, 1, 5, 8, 2, 7]  # staggered retirement, a budget of one
+
+
+def _stream_requests(prompts, budgets, eos_ids=None):
+    """Requests whose `on_token` keeps what it was called with, in
+    order: (requests, one list of streamed tokens a request)."""
+    streams = [[] for _ in prompts]
+    reqs = [GenerateRequest("lm", p, 60.0, b, on_token=s.append,
+                            eos_id=None if eos_ids is None else eos_ids[i])
+            for i, (p, b, s) in enumerate(zip(prompts, budgets, streams))]
+    return reqs, streams
+
+
+def assert_runahead_matches_one_at_a_time(lm, params, seq_bucket=8):
+    """N concurrent requests with mixed budgets through 3 slots —
+    retirements mid-run, admissions into freed slots, more requests
+    than slots — give the tokens, `finish_reason` and `on_token` order
+    of one request at a time decoded greedily through `score_symbol`.
+    (tests/test_olmoe.py runs this on a routed model.)"""
+    rng = np.random.RandomState(21)
+    prompts = [rng.randint(0, lm.vocab, size=rng.randint(2, 8)).tolist()
+               for _ in RUNAHEAD_BUDGETS]
+    telemetry.set_enabled(True)
+    ahead0 = telemetry.counter_value("serving.decode.runahead_steps")
+    steps0 = telemetry.counter_value("serving.decode.dispatches")
+    gs = GenerativeSession("lm", lm, params, max_sessions=3,
+                           max_len=lm.max_len, seq_buckets=[seq_bucket])
+    try:
+        reqs, streams = _stream_requests(prompts, RUNAHEAD_BUDGETS)
+        results = _drive(gs, reqs)
+        assert gs.free_slots() == 3 and not gs._flights
+    finally:
+        gs.close()
+    for p, b, r, streamed in zip(prompts, RUNAHEAD_BUDGETS, results,
+                                 streams):
+        want = _greedy_reference(lm, params, p, b)
+        assert r.tokens.tolist() == want, (p, r.tokens.tolist(), want)
+        assert streamed == want
+        assert r.finish_reason == "length" and r.prompt_len == len(p)
+    # all but the steps that found no token in flight ran ahead
+    ahead = telemetry.counter_value("serving.decode.runahead_steps") - ahead0
+    steps = telemetry.counter_value("serving.decode.dispatches") - steps0
+    assert 0 < ahead <= steps
+
+
+def test_runahead_matches_one_at_a_time_greedy_decode():
+    lm, params = _lm_and_params(seed=8)
+    assert_runahead_matches_one_at_a_time(lm, params)
+
+
+def test_eos_mid_run_drops_the_row_in_flight_and_the_slot_serves_on():
+    """EOS needs the token's value, which the host reads one step late:
+    the session's next row is in flight by then.  Its token is dropped
+    (counted once), the request ends AT the EOS token, and the slot's
+    next tenant — the row's K/V landed in its slot — decodes what it
+    decodes alone."""
+    lm, params = _lm_and_params(seed=3)
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, lm.vocab, size=n).tolist() for n in (4, 6)]
+    free = [_greedy_reference(lm, params, p, 8) for p in prompts]
+    # the first request's EOS: a token it samples mid-run, not before
+    cut = next(i for i in range(2, 7) if free[0][i] not in free[0][:i])
+    telemetry.set_enabled(True)
+    dropped0 = telemetry.counter_value("serving.decode.dropped_rows")
+    gs = GenerativeSession("lm", lm, params, max_sessions=1,
+                           max_len=lm.max_len, seq_buckets=[8])
+    try:
+        reqs, streams = _stream_requests(prompts, [8, 8],
+                                         eos_ids=[free[0][cut], None])
+        first, second = _drive(gs, reqs)
+    finally:
+        gs.close()
+    assert first.tokens.tolist() == streams[0] == free[0][:cut + 1]
+    assert first.finish_reason == "eos"
+    assert telemetry.counter_value(
+        "serving.decode.dropped_rows") - dropped0 == 1
+    assert second.tokens.tolist() == streams[1] == free[1]
+    assert second.finish_reason == "length"
+
+
+def test_finish_all_with_a_step_in_flight_keeps_the_tokens_computed():
+    """close(drain=False) between a dispatch and its read: every future
+    resolves 'closed', and with every token the device had sampled."""
+    lm, params = _lm_and_params(seed=4)
+    rng = np.random.RandomState(9)
+    prompts = [rng.randint(0, lm.vocab, size=4).tolist() for _ in range(2)]
+    gs = GenerativeSession("lm", lm, params, max_sessions=2,
+                           max_len=lm.max_len, seq_buckets=[8])
+    reqs = [GenerateRequest("lm", p, 60.0, 20) for p in prompts]
+    assert gs.admit(reqs) == []
+    for _ in range(3):
+        gs.decode_step()
+    (flight,) = gs._flights
+    assert not flight.prefill and len(flight.rows) == 2
+    gs.close()
+    assert not gs._flights and gs.free_slots() == 2
+    for p, r in zip(prompts, reqs):
+        out = r.future.result(timeout=0)
+        assert out.finish_reason == "closed"
+        # the prefill's token and one a dispatched step
+        assert out.tokens.tolist() == _greedy_reference(lm, params, p, 4)
+
+
+def test_a_failing_step_with_one_in_flight_loses_no_future():
+    """A decode step that cannot be dispatched fails the sessions it
+    would have served — the one in flight included — and nothing else:
+    the queued request behind them is served, correctly, by the same
+    tenant."""
+    lm, params = _lm_and_params(seed=6)
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(0, lm.vocab, size=4).tolist() for _ in range(3)]
+    server = mx.serving.ModelServer({}, wait_ms=1.0)
+    try:
+        gs = server.add_generative_tenant(
+            "lm", lm, params, max_sessions=2, max_len=lm.max_len,
+            seq_buckets=[8])
+        server.warmup()
+        launch, tripped = gs._launch, []
+
+        def flaky(exe, fn, state, data, slot, length, logits):
+            # the first step that packs both sessions with both tokens
+            # still on the device: their previous step is in flight
+            if not tripped and data.shape == (2, 1) and (data < 0).all():
+                tripped.append(1)
+                raise RuntimeError("injected step failure")
+            return launch(exe, fn, state, data, slot, length, logits)
+
+        gs._launch = flaky
+        futs = [server.submit_generate("lm", p, max_new_tokens=12)
+                for p in prompts]
+        for f in futs[:2]:
+            with pytest.raises(RuntimeError, match="injected"):
+                f.result(timeout=60)
+        third = futs[2].result(timeout=60)
+        assert third.tokens.tolist() == _greedy_reference(
+            lm, params, prompts[2], 12)
+        assert gs.free_slots() == 2 and not gs._flights
+    finally:
+        server.close()
+
+
+def test_run_still_returns_host_logits_of_the_served_tokens():
+    """The synchronous path others reach for logits (the benchmark's
+    reference check, chip_smoke.py): `_run` takes host token ids and
+    returns host logits ``(B, vocab)`` whose argmax is what the batcher
+    serves for the same prompt."""
+    lm, params = _lm_and_params(seed=2)
+    prompt = [5, 9, 3, 7]
+    gs = GenerativeSession("lm", lm, params, max_sessions=2,
+                           max_len=lm.max_len, seq_buckets=[8])
+    try:
+        (served,) = _drive(gs, [GenerateRequest("lm", prompt, 60.0, 6)])
+        exe, fn = gs._program(gs._prefill_pred, 1, 8, True)
+        data = np.zeros((1, 8), np.float32)
+        data[0, :4] = prompt
+        logits = gs._run(exe, fn, data, np.zeros((1,), np.float32),
+                         np.full((1,), 4, np.float32))
+        assert isinstance(logits, np.ndarray)
+        assert logits.shape == (1, lm.vocab)
+        toks = [int(np.argmax(logits[0]))]
+        exe, fn = gs._program(gs._decode_pred, 1, 1, False)
+        for i in range(5):
+            logits = gs._run(exe, fn, np.asarray([[toks[-1]]], np.float32),
+                             np.zeros((1,), np.float32),
+                             np.full((1,), 4 + i, np.float32))
+            assert logits.shape == (1, lm.vocab)
+            toks.append(int(np.argmax(logits[0])))
+    finally:
+        gs.close()
+    assert toks == served.tokens.tolist()
+
+
+# ----------------------------------------------------------------------
 # compile-once and the telemetry surface
 # ----------------------------------------------------------------------
 def test_decode_compiles_once_per_bucket():
@@ -462,6 +639,37 @@ def test_attention_ops_match_numpy_oracle():
     assert np.allclose(got, x[np.arange(n), tk.astype(int)])
 
 
+def test_token_ops_match_numpy_oracle():
+    """`_greedy_token` is `numpy.argmax` a row — the first index on a
+    tie — written at ``last_token[slot]``; `_token_feed` takes the
+    host's token where it is not negative and the slot's last one where
+    it is."""
+    rng = np.random.RandomState(3)
+    logits = rng.randn(4, 11).astype(np.float32)
+    logits[1, [2, 6]] = logits[1].max() + 1.0    # a tie: index 2 wins
+    logits[3, 10] = logits[3].max() + 1.0        # the last column
+    last = np.asarray([50, 51, 52, 53, 54, 55], np.float32)
+    slot = np.asarray([4, 0, 2, 5], np.float32)
+    token, wrote = mx.nd._greedy_token(mx.nd.array(logits),
+                                       mx.nd.array(last), mx.nd.array(slot))
+    want = np.argmax(logits, axis=1)
+    assert want[1] == 2 and want[3] == 10
+    assert token.asnumpy().tolist() == want.tolist()
+    assert token.asnumpy().dtype == np.float32
+    assert wrote.asnumpy().tolist() == [want[1], 51, want[2], 53, want[0],
+                                        want[3]]
+    # padded rows all write the scratch slot: the last row's token stays
+    token, wrote = mx.nd._greedy_token(
+        mx.nd.array(logits), mx.nd.array(last),
+        mx.nd.array(np.asarray([1, 5, 5, 5], np.float32)))
+    assert wrote.asnumpy().tolist() == [50, want[0], 52, 53, 54, want[3]]
+
+    data = np.asarray([[7], [-1], [0], [-1]], np.float32)
+    fed = mx.nd._token_feed(mx.nd.array(data), mx.nd.array(last),
+                            mx.nd.array(slot))
+    assert fed.asnumpy().tolist() == [[7], [50], [0], [55]]
+
+
 def _np_softmax(s):
     e = np.exp(s - s.max(-1, keepdims=True))
     return e / e.sum(-1, keepdims=True)
@@ -596,19 +804,22 @@ def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
         other, aux = exe.serve_args(gs._input_names)
         ins = (np.zeros((bucket, 1), np.float32),
                np.zeros((bucket,), np.float32),
-               np.zeros((bucket,), np.float32)) + tuple(gs._caches)
+               np.zeros((bucket,), np.float32)) + tuple(gs._state)
         jaxpr = jax.make_jaxpr(fn._jit)(ins, other, aux, np.uint32(0))
     finally:
         gs.close()
     ring = tuple(lm.cache_shape(5, max_len))
     page = int(np.prod(ring[1:]))
-    updates, big, page_makers = 0, [], set()
+    updates, token_writes, big, page_makers = 0, 0, [], set()
     for eqn in _walk_eqns(jaxpr.jaxpr):
         for out in eqn.outvars:
             size = int(np.prod(out.aval.shape)) if out.aval.shape else 1
             if eqn.primitive.name == "dynamic_update_slice":
-                assert tuple(out.aval.shape) == ring
-                updates += 1
+                if tuple(out.aval.shape) == ring:
+                    updates += 1
+                else:  # the sampled token, one a row, into `last_token`
+                    assert tuple(out.aval.shape) == (5,)
+                    token_writes += 1
             elif size >= bucket * page:
                 big.append((eqn.primitive.name, tuple(out.aval.shape)))
             elif size >= page:
@@ -616,5 +827,6 @@ def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
     assert big == [], big
     # B rows x (K ring + V ring) x layers
     assert updates == bucket * 2 * lm.num_layers
+    assert token_writes == bucket
     assert page_makers <= {"dynamic_slice", "squeeze", "reshape"}, \
         page_makers
