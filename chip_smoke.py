@@ -465,11 +465,12 @@ def phase_kv_ring(sizes, ctx):
                        num_heads=sizes["num_heads"], d_model=sizes["d_model"],
                        d_ff=sizes["d_ff"], max_len=sizes["max_len"])
     slots = sizes["max_sessions"]
-    ring = tuple(lm.cache_shape(slots + 1))
+    spec = lm.cache_spec(slots + 1)
+    ring = tuple(spec["k_cache_0"].shape)
     step = lm.decode_symbol()
     inputs = dict(data=(slots, 1), slot=(slots,), length=(slots,),
                   last_token=(slots + 1,),
-                  **{n: ring for n in lm.cache_names()})
+                  **{n: e.shape for n, e in spec.items()})
     shapes, _, _ = step.infer_shape(**inputs)
     rng = np.random.RandomState(sizes["seed"])
     params = {n: mx.nd.array((rng.randn(*s) * 0.02).astype(np.float32),
@@ -490,9 +491,9 @@ def phase_kv_ring(sizes, ctx):
           "%d aliased to an output; %d ring copies"
           % (facts["ring_params"], list(ring), facts["layouts"],
              facts["aliased"], len(facts["copies"])), flush=True)
-    _check(facts["ring_params"] == len(lm.cache_names()),
+    _check(facts["ring_params"] == len(spec),
            "found %d ring parameters of %d in the decode program's HLO"
-           % (facts["ring_params"], len(lm.cache_names())))
+           % (facts["ring_params"], len(spec)))
     if ctx.jax_device().platform != "cpu":
         _check(facts["aliased"] == facts["ring_params"],
                "only %d of %d KV ring parameters are aliased to an output: "

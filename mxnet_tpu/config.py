@@ -228,9 +228,11 @@ REGISTRY = [
            "generative tenant — the hard cap on concurrently decoding "
            "sessions (admission control: a prompt past the cap waits "
            "queued until a session retires and frees its slot). The "
-           "device ring is preallocated at the model's "
-           "cache_shape(slots+1, MXTPU_SERVE_KV_MAX_LEN) per layer — +1 "
-           "is the scratch slot padded decode rows write into"),
+           "session's device state is preallocated as the model's "
+           "cache_spec(slots+1, MXTPU_SERVE_KV_MAX_LEN) states it — KV "
+           "rings for attention layers, a conv window and a recurrent "
+           "state for state-space layers; +1 is the scratch slot padded "
+           "decode rows write into"),
     EnvVar("MXTPU_SERVE_MAX_DECODE_TOKENS", int, 64,
            "Default per-session generation budget: a decode session "
            "retires (future resolves, slot freed) after this many new "
@@ -245,10 +247,11 @@ REGISTRY = [
            "batching under mixed load"),
     EnvVar("MXTPU_SERVE_KV_MAX_LEN", int, 256,
            "KV-ring size per slot: max total tokens (prompt + "
-           "generated) a decode session may hold. Bounds the "
-           "preallocated per-layer device ring "
-           "((slots+1) x heads x THIS x d_head floats) and is clamped "
-           "to the model's positional table (TransformerLM.max_len)"),
+           "generated) a decode session may hold. Bounds every KV ring "
+           "of the model's cache_spec ((slots+1) x kv_heads x THIS x "
+           "d_head floats a ring; a state-space layer's recurrent state "
+           "does not grow with it) and is clamped to the model's "
+           "max_len"),
     # ---- multi-replica serving tier (router/; docs/serving.md
     #      "Multi-replica tier") ----
     EnvVar("MXTPU_ROUTER_PORT", int, 0,

@@ -44,12 +44,234 @@ together are OLMoE's.  The helper methods branch; the four graph
 builders are shared.  A routed model's serving graphs end with one
 more small output, ``moe_load (num_layers, num_experts)`` — tokens per
 expert in this call — which the batcher books as the ``moe.*`` counters.
+
+**Layer kinds.**  `layer_types` names each layer's MIXER — the half of
+the block before the FFN — ``"attention"`` (the default for every layer)
+or ``"mamba"`` (a Mamba-2 state-space mixer, ops/ssm.py).  A kind is one
+class below (:class:`_Attention`, :class:`_Mamba2`) that declares, in
+that one place, its parameters, its full-sequence forward, its prefill,
+its decode step and the device-resident state it keeps between calls;
+the four graph builders walk the pattern and know no kind by name.
+``num_kv_heads`` (grouped-query attention), ``positions="none"``,
+``ffn="swiglu"`` (a dense gated FFN), ``attention_multiplier`` and the
+three stream multipliers (``embedding_`` / ``residual_multiplier``,
+``logits_scaling``) with ``layer_types`` of nine ``"mamba"`` to one
+``"attention"`` are Granite 4.0-H's.
+
+**Cache spec.**  :meth:`TransformerLM.cache_spec` is the ONE statement of
+what a serving session holds on the device between calls: an ordered
+``{name: CacheEntry(kind, shape)}`` over all layers — an attention layer's
+two KV rings (kind ``"ring"``, pages addressed by slot and masked by
+length, so stale contents are harmless) and a Mamba layer's conv window
+and recurrent state (kind ``"state"``, a fixed size a slot whatever the
+context, wholly rewritten by a prefill).  The serving graphs take and
+return exactly these names in this order; whoever allocates, sizes,
+charges or counts session state asks here.
 """
 from __future__ import annotations
 
-from .. import symbol as sym
+import math
+from typing import NamedTuple
 
-__all__ = ["TransformerLM"]
+from .. import symbol as sym
+from ..ops import ssm as _ssm
+
+__all__ = ["TransformerLM", "CacheEntry"]
+
+
+class CacheEntry(NamedTuple):
+    """One device buffer a serving session threads through its calls:
+    `kind` ``"ring"`` (KV pages, masked by length) or ``"state"`` (a
+    recurrent layer's, overwritten whole by a prefill); `shape` as
+    stored, float32."""
+
+    kind: str
+    shape: tuple
+
+    @property
+    def nbytes(self):
+        return 4 * math.prod(self.shape)
+
+
+class _Attention:
+    """The attention mixer of layer i: fused QKV projection, QK-norm and
+    rotary as the spec says, causal softmax attention over `num_heads`
+    query heads and `num_kv_heads` K/V heads, output projection.  State:
+    two KV rings."""
+
+    def __init__(self, lm):
+        self.lm = lm
+        # what the three attention ops take beyond their operands; the
+        # two options appear on a node only when the spec sets them
+        self.attrs = dict(num_heads=lm.num_heads)
+        self.sdp_attrs = dict(num_heads=lm.num_heads, causal=True)
+        for attrs in (self.attrs, self.sdp_attrs):
+            if lm.num_kv_heads != lm.num_heads:
+                attrs["num_kv_heads"] = lm.num_kv_heads
+            if lm.attention_multiplier is not None:
+                attrs["scale"] = lm.attention_multiplier
+
+    def params(self, i):
+        lm, v = self.lm, sym.Variable
+        d, wide = lm.d_model, lm.d_model + 2 * lm.num_kv_heads * lm.d_head
+        p = {"qkv_weight": v("l%d_qkv_weight" % i, shape=(wide, d)),
+             "out_weight": v("l%d_out_weight" % i, shape=(d, d))}
+        if lm.bias:
+            p["qkv_bias"] = v("l%d_qkv_bias" % i, shape=(wide,))
+            p["out_bias"] = v("l%d_out_bias" % i, shape=(d,))
+        return p
+
+    def cache_spec(self, i, slots, max_len):
+        """``(slots, num_kv_heads, max_len, d_head)`` for K and for V.
+
+        One order for every head width, measured on a TPU v5e (PERF.md
+        section 6, PR 26): the runtime stores a 64-wide minor axis with
+        the POSITIONS on the lanes (``[slot][head][d_head][position]``,
+        dense) and a 128-wide one as written, which is in both cases
+        the layout the decode step's attention reads; rings transposed by
+        hand were no faster at d_head 64 and 18-36% slower at 128."""
+        ring = CacheEntry("ring", (int(slots), self.lm.num_kv_heads,
+                                   int(max_len), self.lm.d_head))
+        return [("k_cache_%d" % i, ring), ("v_cache_%d" % i, ring)]
+
+    def _qkv(self, x, p, i, index=None):
+        """The three projections of the normed stream, ready for the
+        attention op: QK-norm over the whole projections, then rotary
+        positions — each row's own `index` in a decode step, 0..T-1
+        without one — so K reaches the ring already rotated."""
+        lm = self.lm
+        kv_width = lm.num_kv_heads * lm.d_head
+        qkv = lm._linear(x, p, "qkv", lm.d_model + 2 * kv_width,
+                         "l%d_qkv" % i)
+        if kv_width == lm.d_model:
+            q, k, v = sym.SliceChannel(qkv, num_outputs=3, axis=2,
+                                       name="l%d_qkv_split" % i)
+        else:
+            edges = (0, lm.d_model, lm.d_model + kv_width,
+                     lm.d_model + 2 * kv_width)
+            q, k, v = (sym.slice_axis(qkv, axis=2, begin=a, end=b,
+                                      name="l%d_%s_split" % (i, n))
+                       for n, a, b in zip("qkv", edges, edges[1:]))
+        if lm.qk_norm:
+            q = lm._norm(q, "l%d_qnorm" % i)
+            k = lm._norm(k, "l%d_knorm" % i, width=kv_width)
+        if lm.positions == "rotary":
+            rope = dict(theta=lm.rope_theta)
+            heads = (lm.num_heads, lm.num_kv_heads)
+            if index is None:
+                q, k = (sym._rotary(t, name="l%d_%srope" % (i, n),
+                                    num_heads=h, **rope)
+                        for t, n, h in zip((q, k), "qk", heads))
+            else:
+                q, k = (sym._rotary_at(t, index, name="l%d_%srope" % (i, n),
+                                       num_heads=h, **rope)
+                        for t, n, h in zip((q, k), "qk", heads))
+        return q, k, v
+
+    def _out(self, ctx, p, i):
+        return self.lm._linear(ctx, p, "out", self.lm.d_model,
+                               "l%d_proj" % i)
+
+    def _attend(self, x, p, i):
+        q, k, v = self._qkv(x, p, i)
+        return sym._sdp_attention(q, k, v, name="l%d_attn" % i,
+                                  **self.sdp_attrs)
+
+    def full(self, x, p, i):
+        return self._out(self._attend(x, p, i)[0], p, i)
+
+    def prefill(self, x, p, i, caches, slot, length):
+        attn = self._attend(x, p, i)
+        wrote = sym._kv_cache_write(
+            caches["k_cache_%d" % i], caches["v_cache_%d" % i],
+            attn[1], attn[2], slot, name="l%d_kv_write" % i)
+        return self._out(attn[0], p, i), [wrote[0], wrote[1]]
+
+    def decode(self, x, p, i, caches, slot, length):
+        q, k, v = self._qkv(x, p, i, index=length)
+        step = sym._cached_attention(
+            q, k, v, caches["k_cache_%d" % i], caches["v_cache_%d" % i],
+            slot, length, name="l%d_attn" % i, **self.attrs)
+        return self._out(step[0], p, i), [step[1], step[2]]
+
+
+class _Mamba2:
+    """The Mamba-2 mixer of layer i (ops/ssm.py has the equations): input
+    projection ``[z | x | B | C | dt]``, causal conv + state-space scan +
+    gated RMSNorm in ONE op node a form, output projection; no projection
+    bias.  State: the conv window and the recurrent state."""
+
+    # the mixer's own small parameters, in the ops' operand order
+    SMALL = ("conv_weight", "conv_bias", "dt_bias", "A_log", "D",
+             "mnorm_gamma")
+
+    def __init__(self, lm):
+        self.lm = lm
+        self.sizes = (lm.mamba_heads, lm.mamba_head_dim, lm.mamba_state,
+                      lm.mamba_groups, lm.mamba_conv)
+        self.d_inner = lm.mamba_heads * lm.mamba_head_dim
+        self.conv_dim = self.d_inner + 2 * lm.mamba_groups * lm.mamba_state
+        self.d_proj = self.d_inner + self.conv_dim + lm.mamba_heads
+        self.attrs = dict(num_heads=lm.mamba_heads,
+                          head_dim=lm.mamba_head_dim,
+                          state_size=lm.mamba_state, n_groups=lm.mamba_groups,
+                          conv_kernel=lm.mamba_conv,
+                          chunk_size=lm.mamba_chunk, eps=lm.norm_eps)
+
+    def params(self, i):
+        v, d = sym.Variable, self.lm.d_model
+        p = {"inproj_weight": v("l%d_inproj_weight" % i,
+                                shape=(self.d_proj, d))}
+        for n, shape in zip(self.SMALL, _ssm.param_shapes(*self.sizes)):
+            p[n] = v("l%d_%s" % (i, n), shape=shape)
+        p["outproj_weight"] = v("l%d_outproj_weight" % i,
+                                shape=(d, self.d_inner))
+        return p
+
+    def cache_spec(self, i, slots, max_len):
+        """The conv window ``(slots, d_conv - 1, conv_dim)`` — channels on
+        the lanes; stored ``(conv_dim, d_conv - 1)`` a TPU tile would pad
+        the 3 taps to 128 — and the state ``(slots, heads, head_dim,
+        d_state)``; neither grows with `max_len`."""
+        lm = self.lm
+        return [("conv_state_%d" % i, CacheEntry(
+                    "state", (int(slots), lm.mamba_conv - 1, self.conv_dim))),
+                ("ssm_state_%d" % i, CacheEntry(
+                    "state", (int(slots), lm.mamba_heads, lm.mamba_head_dim,
+                              lm.mamba_state)))]
+
+    def _in(self, x, p, i):
+        proj = sym.FullyConnected(x, weight=p["inproj_weight"],
+                                  num_hidden=self.d_proj, no_bias=True,
+                                  flatten=False, name="l%d_inproj" % i)
+        return [proj] + [p[n] for n in self.SMALL]
+
+    def _out(self, y, p, i):
+        return sym.FullyConnected(y, weight=p["outproj_weight"],
+                                  num_hidden=self.lm.d_model, no_bias=True,
+                                  flatten=False, name="l%d_outproj" % i)
+
+    def full(self, x, p, i):
+        y = sym._ssm_scan(*self._in(x, p, i), name="l%d_ssm" % i,
+                          **self.attrs)
+        return self._out(y, p, i)
+
+    def prefill(self, x, p, i, caches, slot, length):
+        y = sym._ssm_prefill(
+            *self._in(x, p, i), caches["conv_state_%d" % i],
+            caches["ssm_state_%d" % i], slot, length, name="l%d_ssm" % i,
+            **self.attrs)
+        return self._out(y[0], p, i), [y[1], y[2]]
+
+    def decode(self, x, p, i, caches, slot, length):
+        y = sym._ssm_step(
+            *self._in(x, p, i), caches["conv_state_%d" % i],
+            caches["ssm_state_%d" % i], slot, name="l%d_ssm" % i,
+            **self.attrs)
+        return self._out(y[0], p, i), [y[1], y[2]]
+
+
+_KINDS = {"attention": _Attention, "mamba": _Mamba2}
 
 
 class TransformerLM:
@@ -69,24 +291,60 @@ class TransformerLM:
     dropless routed SwiGLU layer — `experts_per_token` of `num_experts`
     experts of width `d_ff`, router softmax scores used unnormalised;
     `bias` false drops every projection bias; `tied_head` false gives
-    the head its own ``head_weight (vocab, d_model)``."""
+    the head its own ``head_weight (vocab, d_model)``.
+
+    Further choices (defaults: as if absent): `layer_types` — one mixer
+    kind a layer, ``"attention"`` | ``"mamba"`` (module docstring;
+    default all attention); `num_kv_heads` K/V heads shared by groups of
+    query heads; `positions` ``"none"`` (no position signal at all);
+    `ffn` ``"relu"`` | ``"swiglu"`` (``W_out(silu(a) * b)``, ``[a | b]`` one
+    fused ``(2 d_ff, d_model)`` projection); `embedding_multiplier` scales
+    the embedded tokens, `residual_multiplier` every branch before it
+    joins the stream, `attention_multiplier` replaces ``1/sqrt(d_head)``,
+    `logits_scaling` divides the logits; the Mamba-2 mixer's sizes
+    `mamba_heads` x `mamba_head_dim` (its inner width), `mamba_state`,
+    `mamba_groups`, `mamba_conv` taps and the prefill scan's
+    `mamba_chunk`."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
                  d_ff=None, max_len=64, dropout=0.0, norm="layer",
                  norm_eps=1e-5, positions="learned", rope_theta=10000.0,
                  qk_norm=False, num_experts=0, experts_per_token=0,
-                 bias=True, tied_head=True):
+                 bias=True, tied_head=True, layer_types=None,
+                 num_kv_heads=None, ffn="relu", embedding_multiplier=1.0,
+                 residual_multiplier=1.0, attention_multiplier=None,
+                 logits_scaling=1.0, mamba_heads=0, mamba_head_dim=0,
+                 mamba_state=0, mamba_groups=1, mamba_conv=4,
+                 mamba_chunk=256):
         if d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
         if norm not in ("layer", "rms"):
             raise ValueError("norm must be 'layer' or 'rms', got %r" % norm)
-        if positions not in ("learned", "rotary"):
-            raise ValueError("positions must be 'learned' or 'rotary', "
-                             "got %r" % positions)
+        if positions not in ("learned", "rotary", "none"):
+            raise ValueError("positions must be 'learned', 'rotary' or "
+                             "'none', got %r" % positions)
         if num_experts and not 0 < experts_per_token <= num_experts:
             raise ValueError("experts_per_token=%d must be in 1..%d"
                              % (experts_per_token, num_experts))
+        if ffn not in ("relu", "swiglu"):
+            raise ValueError("ffn must be 'relu' or 'swiglu', got %r" % ffn)
+        num_kv_heads = num_heads if num_kv_heads is None else int(num_kv_heads)
+        if num_kv_heads < 1 or num_heads % num_kv_heads:
+            raise ValueError("num_heads=%d not a multiple of num_kv_heads=%d"
+                             % (num_heads, num_kv_heads))
+        layer_types = (("attention",) * int(num_layers) if layer_types is None
+                       else tuple(layer_types))
+        if len(layer_types) != int(num_layers) or set(layer_types) - set(_KINDS):
+            raise ValueError("layer_types must name num_layers=%d kinds of %s,"
+                             " got %r" % (num_layers, sorted(_KINDS),
+                                          layer_types))
+        if "mamba" in layer_types and (
+                min(mamba_heads, mamba_head_dim, mamba_state, mamba_groups) < 1
+                or mamba_heads % mamba_groups or mamba_conv < 2):
+            raise ValueError("a 'mamba' layer needs mamba_heads, "
+                             "mamba_head_dim, mamba_state >= 1, mamba_heads a "
+                             "multiple of mamba_groups and mamba_conv >= 2")
         self.vocab = int(vocab)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -104,6 +362,19 @@ class TransformerLM:
         self.experts_per_token = int(experts_per_token)
         self.bias = bool(bias)
         self.tied_head = bool(tied_head)
+        self.layer_types = layer_types
+        self.num_kv_heads = num_kv_heads
+        self.ffn = ffn
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.residual_multiplier = float(residual_multiplier)
+        self.attention_multiplier = (None if attention_multiplier is None
+                                     else float(attention_multiplier))
+        self.logits_scaling = float(logits_scaling)
+        self.mamba_heads, self.mamba_head_dim = int(mamba_heads), int(mamba_head_dim)
+        self.mamba_state, self.mamba_groups = int(mamba_state), int(mamba_groups)
+        self.mamba_conv, self.mamba_chunk = int(mamba_conv), int(mamba_chunk)
+        kinds = {k: _KINDS[k](self) for k in set(layer_types)}
+        self._mixers = [kinds[k] for k in layer_types]
 
     # ------------------------------------------------------------------
     # shared pieces
@@ -115,13 +386,15 @@ class TransformerLM:
     def _pos_weight(self):
         return sym.Variable("pos_weight", shape=(self.max_len, self.d_model))
 
-    def _norm(self, x, name):
+    def _norm(self, x, name, width=None):
         """The model's norm as node `name`, with the parameters
-        ``<name>_gamma`` (and ``<name>_beta`` for LayerNorm)."""
-        gamma = sym.Variable(name + "_gamma", shape=(self.d_model,))
+        ``<name>_gamma`` (and ``<name>_beta`` for LayerNorm) of `width`
+        (default `d_model`) channels."""
+        shape = (self.d_model if width is None else width,)
+        gamma = sym.Variable(name + "_gamma", shape=shape)
         if self.norm == "rms":
             return sym.RMSNorm(x, gamma=gamma, eps=self.norm_eps, name=name)
-        beta = sym.Variable(name + "_beta", shape=(self.d_model,))
+        beta = sym.Variable(name + "_beta", shape=shape)
         return sym.LayerNorm(x, gamma=gamma, beta=beta, name=name)
 
     def _linear(self, x, p, key, num_hidden, name):
@@ -134,13 +407,10 @@ class TransformerLM:
                                   flatten=False, name=name)
 
     def _block_params(self, i):
+        """Layer i's parameter variables: its mixer's, then its FFN's."""
         d, ff = self.d_model, self.d_ff
         v = sym.Variable
-        p = {"qkv_weight": v("l%d_qkv_weight" % i, shape=(3 * d, d)),
-             "out_weight": v("l%d_out_weight" % i, shape=(d, d))}
-        if self.bias:
-            p["qkv_bias"] = v("l%d_qkv_bias" % i, shape=(3 * d,))
-            p["out_bias"] = v("l%d_out_bias" % i, shape=(d,))
+        p = self._mixers[i].params(i)
         if self.num_experts:
             e = self.num_experts
             p["router_weight"] = v("l%d_router_weight" % i, shape=(d, e))
@@ -148,36 +418,19 @@ class TransformerLM:
             p["down_weight"] = v("l%d_down_weight" % i, shape=(e, ff, d))
             p["up_weight"] = v("l%d_up_weight" % i, shape=(e, d, ff))
         else:
-            p["ffn1_weight"] = v("l%d_ffn1_weight" % i, shape=(ff, d))
+            wide = 2 * ff if self.ffn == "swiglu" else ff
+            p["ffn1_weight"] = v("l%d_ffn1_weight" % i, shape=(wide, d))
             p["ffn2_weight"] = v("l%d_ffn2_weight" % i, shape=(d, ff))
             if self.bias:
-                p["ffn1_bias"] = v("l%d_ffn1_bias" % i, shape=(ff,))
+                p["ffn1_bias"] = v("l%d_ffn1_bias" % i, shape=(wide,))
                 p["ffn2_bias"] = v("l%d_ffn2_bias" % i, shape=(d,))
         return p
 
-    def _qkv(self, x, p, i, index=None):
-        """The three projections of the normed stream, ready for the
-        attention op: QK-norm over the whole projections, then rotary
-        positions — each row's own `index` in a decode step, 0..T-1
-        without one — so K reaches the ring already rotated."""
-        qkv = self._linear(x, p, "qkv", 3 * self.d_model, "l%d_qkv" % i)
-        q, k, v = sym.SliceChannel(qkv, num_outputs=3, axis=2,
-                                   name="l%d_qkv_split" % i)
-        if self.qk_norm:
-            q = self._norm(q, "l%d_qnorm" % i)
-            k = self._norm(k, "l%d_knorm" % i)
-        if self.positions == "rotary":
-            rope = dict(num_heads=self.num_heads, theta=self.rope_theta)
-            if index is None:
-                q = sym._rotary(q, name="l%d_qrope" % i, **rope)
-                k = sym._rotary(k, name="l%d_krope" % i, **rope)
-            else:
-                q = sym._rotary_at(q, index, name="l%d_qrope" % i, **rope)
-                k = sym._rotary_at(k, index, name="l%d_krope" % i, **rope)
-        return q, k, v
-
-    def _attn_out(self, ctx, p, i):
-        return self._linear(ctx, p, "out", self.d_model, "l%d_proj" % i)
+    def _join(self, h, branch):
+        """The residual stream plus a branch (times `residual_multiplier`)."""
+        if self.residual_multiplier != 1.0:
+            branch = branch * self.residual_multiplier
+        return h + branch
 
     def _ffn(self, h, p, i, train, loads=None):
         """The block's second half on the residual stream `h`.  A routed
@@ -194,6 +447,12 @@ class TransformerLM:
             if loads is not None:
                 loads.append(f[1])
                 f = f[0]
+        elif self.ffn == "swiglu":
+            a, b = sym.SliceChannel(
+                self._linear(x, p, "ffn1", 2 * self.d_ff, "l%d_ffn1" % i),
+                num_outputs=2, axis=2, name="l%d_ffn_split" % i)
+            f = sym.Activation(a, act_type="silu", name="l%d_silu" % i) * b
+            f = self._linear(f, p, "ffn2", self.d_model, "l%d_ffn2" % i)
         else:
             f = sym.Activation(
                 self._linear(x, p, "ffn1", self.d_ff, "l%d_ffn1" % i),
@@ -201,18 +460,15 @@ class TransformerLM:
             f = self._linear(f, p, "ffn2", self.d_model, "l%d_ffn2" % i)
         if train and self.dropout > 0:
             f = sym.Dropout(f, p=self.dropout, name="l%d_drop" % i)
-        return h + f
+        return self._join(h, f)
 
     def _block_train(self, h, i, train):
         p = self._block_params(i)
         x = self._norm(h, "l%d_ln1" % i)
-        q, k, v = self._qkv(x, p, i)
-        attn = sym._sdp_attention(q, k, v, num_heads=self.num_heads,
-                                  causal=True, name="l%d_attn" % i)
-        a = self._attn_out(attn[0], p, i)
+        a = self._mixers[i].full(x, p, i)
         if train and self.dropout > 0:
             a = sym.Dropout(a, p=self.dropout, name="l%d_adrop" % i)
-        h = h + a
+        h = self._join(h, a)
         return self._ffn(h, p, i, train)
 
     def _embed(self, data, index=None):
@@ -222,6 +478,8 @@ class TransformerLM:
         embed_w = self._embed_weight()
         h = sym.Embedding(data, weight=embed_w, input_dim=self.vocab,
                           output_dim=self.d_model, name="embed")
+        if self.embedding_multiplier != 1.0:
+            h = h * self.embedding_multiplier
         if self.positions == "learned":
             if index is None:
                 h = sym._add_positional(h, self._pos_weight(),
@@ -245,7 +503,10 @@ class TransformerLM:
         transformer-LM convention) or the head's own matrix."""
         w = embed_w if self.tied_head else sym.Variable(
             "head_weight", shape=(self.vocab, self.d_model))
-        return sym.dot(h2d, w, transpose_b=True, name=name)
+        if self.logits_scaling == 1.0:
+            return sym.dot(h2d, w, transpose_b=True, name=name)
+        raw = sym.dot(h2d, w, transpose_b=True, name=name + "_unscaled")
+        return sym._div_scalar(raw, scalar=self.logits_scaling, name=name)
 
     def _serving_outputs(self, logits, rings, loads, last_token, slot):
         """``[logits, rings..., last_token, token, moe_load]``: what is
@@ -306,38 +567,28 @@ class TransformerLM:
         flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")
         return self._head(flat, embed_w, "logits")
 
-    def cache_names(self):
-        """The serving graphs' KV-ring input names, in wire order."""
-        names = []
-        for i in range(self.num_layers):
-            names += ["k_cache_%d" % i, "v_cache_%d" % i]
-        return names
-
-    def cache_shape(self, slots, max_len=None):
-        """THE stored shape of one layer's K ring, and of its V ring, for
-        `slots` pages of `max_len` positions (default: the model's
-        own): ``(slots, num_heads, max_len, d_head)``.  Whoever allocates
-        or sizes a ring asks here (serving/decode.py, which adds the +1
-        scratch slot, and the server's admission), and the ring ops
-        (ops/attention.py) read and write exactly this order.
-
-        One order for every head width, measured on a TPU v5e (PERF.md
-        section 6, PR 26): the runtime stores a 64-wide minor axis with
-        the POSITIONS on the lanes (``[slot][head][d_head][position]``,
-        dense) and a 128-wide one as written, which is in both cases
-        the layout the decode step's attention reads; rings transposed by
-        hand were no faster at d_head 64 and 18-36% slower at 128."""
-        return (int(slots), self.num_heads,
-                self.max_len if max_len is None else int(max_len),
-                self.d_head)
+    def cache_spec(self, slots, max_len=None):
+        """What a serving session holds on the device between calls, for
+        `slots` pages and rings of `max_len` positions (default: the
+        model's own): an ordered ``{name: CacheEntry(kind, shape)}`` over
+        all layers, each layer's entries as its mixer kind declares them
+        (module docstring).  The serving graphs' state inputs and outputs
+        are exactly these names in this order; serving/decode.py (which
+        adds the +1 scratch slot), the server's admission and chip_smoke
+        ask here, and the ops read and write exactly these shapes."""
+        max_len = self.max_len if max_len is None else int(max_len)
+        spec = {}
+        for i, mixer in enumerate(self._mixers):
+            spec.update(mixer.cache_spec(i, slots, max_len))
+        return spec
 
     def _cache_vars(self):
-        return {n: sym.Variable(n) for n in self.cache_names()}
+        return {n: sym.Variable(n) for n in self.cache_spec(1)}
 
     def prefill_symbol(self):
         """Prefill one prompt (batch 1, padded to a sequence bucket):
-        outputs ``[next_logits (1, vocab), k_cache_0', v_cache_0',
-        ..., last_token', token (1,)]``.  Inputs beyond the caches:
+        outputs ``[next_logits (1, vocab), <cache_spec entries>'...,
+        last_token', token (1,)]``.  Inputs beyond the cache entries:
         ``data (1, T)``, ``slot (1,)``, ``length (1,)`` (true prompt
         length), ``last_token (slots + 1,)``."""
         data = sym.Variable("data")
@@ -347,17 +598,12 @@ class TransformerLM:
         caches = self._cache_vars()
         h, embed_w = self._embed(data)
         outs, loads = [], [] if self.num_experts else None
-        for i in range(self.num_layers):
+        for i, mixer in enumerate(self._mixers):
             p = self._block_params(i)
             x = self._norm(h, "l%d_ln1" % i)
-            q, k, v = self._qkv(x, p, i)
-            attn = sym._sdp_attention(q, k, v, num_heads=self.num_heads,
-                                      causal=True, name="l%d_attn" % i)
-            wrote = sym._kv_cache_write(
-                caches["k_cache_%d" % i], caches["v_cache_%d" % i],
-                attn[1], attn[2], slot, name="l%d_kv_write" % i)
-            outs += [wrote[0], wrote[1]]
-            h = h + self._attn_out(attn[0], p, i)
+            y, state = mixer.prefill(x, p, i, caches, slot, length)
+            outs += state
+            h = self._join(h, y)
             h = self._ffn(h, p, i, train=False, loads=loads)
         h = self._norm(h, "ln_f")
         # logits at the prompt's true tail, not the pad
@@ -369,9 +615,9 @@ class TransformerLM:
         """One decode step for a packed session batch: inputs ``data
         (B, 1)`` (each session's last token, or a negative number for
         "the one ``last_token[slot]`` holds"), ``slot (B,)``, ``length
-        (B,)`` (tokens already cached), plus the rings and ``last_token
-        (slots + 1,)``; outputs ``[logits (B, vocab), k_cache_0',
-        v_cache_0', ..., last_token', token (B,)]``."""
+        (B,)`` (tokens already cached), plus the cache entries and
+        ``last_token (slots + 1,)``; outputs ``[logits (B, vocab),
+        <cache_spec entries>'..., last_token', token (B,)]``."""
         data = sym.Variable("data")
         slot = sym.Variable("slot")
         length = sym.Variable("length")
@@ -380,16 +626,12 @@ class TransformerLM:
         data = sym._token_feed(data, last_token, slot, name="token_feed")
         h, embed_w = self._embed(data, index=length)
         outs, loads = [], [] if self.num_experts else None
-        for i in range(self.num_layers):
+        for i, mixer in enumerate(self._mixers):
             p = self._block_params(i)
             x = self._norm(h, "l%d_ln1" % i)
-            q, k, v = self._qkv(x, p, i, index=length)
-            step = sym._cached_attention(
-                q, k, v, caches["k_cache_%d" % i],
-                caches["v_cache_%d" % i], slot, length,
-                num_heads=self.num_heads, name="l%d_attn" % i)
-            outs += [step[1], step[2]]
-            h = h + self._attn_out(step[0], p, i)
+            y, state = mixer.decode(x, p, i, caches, slot, length)
+            outs += state
+            h = self._join(h, y)
             h = self._ffn(h, p, i, train=False, loads=loads)
         h = self._norm(h, "ln_f")
         flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")

@@ -7,6 +7,7 @@ from . import contrib_ops  # noqa: F401
 from . import quant_ops  # noqa: F401
 from . import rnn_op  # noqa: F401
 from . import attention  # noqa: F401
+from . import ssm  # noqa: F401
 from . import spatial  # noqa: F401
 from . import optim_ops  # noqa: F401
 from . import sharded_ops  # noqa: F401

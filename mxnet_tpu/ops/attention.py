@@ -18,7 +18,7 @@ needs four graph-level primitives beyond the classic registry:
 * ``_cached_attention`` / ``_kv_cache_write`` — the decode-side pair.
   The KV ring is one preallocated buffer per layer for K and one for V,
   a PAGE of ``max_len`` positions per slot; its stored shape belongs to
-  the model (``TransformerLM.cache_shape`` — every allocator asks it).
+  the model (``TransformerLM.cache_spec`` — every allocator asks it).
   The SLOT INDEX and LENGTH ride as traced operands (the
   vLLM/PagedAttention discipline: address pages by index, mask by
   length), so one compiled decode program serves every session mix —
@@ -163,26 +163,49 @@ def rotary_at(data, index, num_heads=1, theta=10000.0, **kw):
 # ----------------------------------------------------------------------
 
 
+def _kv_heads(attrs_or_kw, num_heads):
+    """`num_kv_heads` of a node: K/V heads, each shared by ``num_heads /
+    num_kv_heads`` query heads (grouped-query attention); absent = one
+    K/V head a query head."""
+    kv = attrs_or_kw.get("num_kv_heads")
+    return num_heads if kv is None else int(_lit(kv))
+
+
+def _scaled(scores, d_head, scale):
+    """``q k^T`` times the node's `scale`; without one, divided by
+    ``sqrt(d_head)`` as every model before the option was."""
+    if scale is None:
+        return scores / jnp.sqrt(jnp.asarray(d_head, scores.dtype))
+    return scores * float(_lit(scale))
+
+
 def _infer_sdp(in_shapes, attrs):
     q = in_shapes[0]
     num_heads = int(_lit(attrs.get("num_heads", 1)))
     n, t, d = q
     dh = d // num_heads
-    heads = (n, num_heads, t, dh)
-    return [q, q, q], [q, heads, heads]
+    kv = _kv_heads(attrs, num_heads)
+    heads = (n, kv, t, dh)
+    kv_in = (n, t, kv * dh)
+    return [q, kv_in, kv_in], [q, heads, heads]
 
 
 @register("_sdp_attention", inputs=("query", "key", "value"),
           num_outputs=3, infer_shape=_infer_sdp)
-def sdp_attention(query, key, value, num_heads=1, causal=True, **kw):
+def sdp_attention(query, key, value, num_heads=1, causal=True, scale=None,
+                  **kw):
     """Fused multi-head scaled-dot-product attention.
 
     Inputs are the PROJECTED ``(N, T, d_model)`` tensors (the graph
-    keeps one FullyConnected for the joint QKV projection).  Outputs:
+    keeps one FullyConnected for the joint QKV projection); with
+    ``num_kv_heads`` < `num_heads` key and value are ``(N, T,
+    num_kv_heads * d_head)`` and each K/V head serves ``num_heads /
+    num_kv_heads`` consecutive query heads.  `scale` multiplies the
+    scores in place of ``1 / sqrt(d_head)``.  Outputs:
 
       0. context ``(N, T, d_model)`` — heads re-merged;
-      1. K per head ``(N, H, T, d_head)``;
-      2. V per head ``(N, H, T, d_head)``.
+      1. K per K/V head ``(N, H_kv, T, d_head)``;
+      2. V per K/V head ``(N, H_kv, T, d_head)``.
 
     Outputs 1/2 cost nothing (they are the reshapes the op computes
     anyway) and exist for the serving prefill graph, which writes them
@@ -191,17 +214,21 @@ def sdp_attention(query, key, value, num_heads=1, causal=True, **kw):
     h = int(_lit(num_heads))
     n, t, d = query.shape
     dh = d // h
+    kv = _kv_heads(kw, h)
 
-    def heads(x):
-        return x.reshape(n, t, h, dh).transpose(0, 2, 1, 3)
+    def heads(x, count):
+        return x.reshape(n, t, count, dh).transpose(0, 2, 1, 3)
 
-    qh, kh, vh = heads(query), heads(key), heads(value)
-    scores = jnp.einsum("nhqd,nhkd->nhqk", qh, kh) / jnp.sqrt(
-        jnp.asarray(dh, qh.dtype))
+    qh, kh, vh = heads(query, h), heads(key, kv), heads(value, kv)
+    # query heads grouped over their K/V head (groups of one without
+    # `num_kv_heads`); K and V are never repeated
+    qg = qh.reshape(n, kv, h // kv, t, dh)
+    scores = _scaled(jnp.einsum("ngrqd,ngkd->ngrqk", qg, kh), dh, scale)
     if _bool(causal):
         keep = jnp.tril(jnp.ones((t, t), dtype=bool))
-        scores = jnp.where(keep[None, None], scores, _NEG)
-    ctx = jnp.einsum("nhqk,nhkd->nhqd", jnn.softmax(scores, axis=-1), vh)
+        scores = jnp.where(keep, scores, _NEG)
+    ctx = jnp.einsum("ngrqk,ngkd->ngrqd", jnn.softmax(scores, axis=-1),
+                     vh).reshape(n, h, t, dh)
     return ctx.transpose(0, 2, 1, 3).reshape(n, t, d), kh, vh
 
 
@@ -212,7 +239,10 @@ def sdp_attention(query, key, value, num_heads=1, causal=True, **kw):
 
 def _infer_cached(in_shapes, attrs):
     q, k, v, kc, vc, slot, length = in_shapes
-    return [q, q, q, kc, kc, slot, slot], [q, kc, kc]
+    num_heads = int(_lit(attrs.get("num_heads", 1)))
+    kv = _kv_heads(attrs, num_heads)
+    kv_in = q if kv == num_heads else (q[0], q[1], q[2] // num_heads * kv)
+    return [q, kv_in, kv_in, kc, kc, slot, slot], [q, kc, kc]
 
 
 def _page(cache, slot_i):
@@ -239,17 +269,19 @@ def _write_rows(cache, rows, slot_i, len_i):
                   "length"),
           num_outputs=3, infer_shape=_infer_cached)
 def cached_attention(query, key, value, k_cache, v_cache, slot, length,
-                     num_heads=1, **kw):
+                     num_heads=1, scale=None, **kw):
     """One decode step of multi-head attention against a slot-indexed
     KV ring (the PagedAttention shape: address each session's page by
     slot index, mask by length — both TRACED operands, so one compiled
     program serves any session mix).
 
     query/key/value: ``(B, 1, d_model)`` projections of the current
-    token; ``k_cache``/``v_cache``: rings of the model's ``cache_shape``,
-    ``(slots, H, max_len, d_head)``; ``slot``/``length``: ``(B,)`` —
-    session slot index and the number of tokens already cached (== the
-    new token's position).
+    token (key/value ``(B, 1, num_kv_heads * d_head)`` under
+    grouped-query attention); ``k_cache``/``v_cache``: rings as the
+    model's ``cache_spec`` states them, ``(slots, H_kv, max_len,
+    d_head)``; ``slot``/``length``: ``(B,)`` — session slot index and the
+    number of tokens already cached (== the new token's position).
+    `scale` multiplies the scores in place of ``1 / sqrt(d_head)``.
 
     The step's K/V are written at ``cache[slot, :, length]`` FIRST, one
     in-place row update a packed row, then each row attends over its own
@@ -268,20 +300,38 @@ def cached_attention(query, key, value, k_cache, v_cache, slot, length,
     h = int(_lit(num_heads))
     b, one, d = query.shape
     dh = d // h
+    kv = _kv_heads(kw, h)
     slot_i = _as_index(slot)
     len_i = _as_index(length)
-    kc = _write_rows(k_cache, key.reshape(b, h, dh), slot_i, len_i)
-    vc = _write_rows(v_cache, value.reshape(b, h, dh), slot_i, len_i)
-    qh = query.reshape(b, h, dh)
-    scores = jnp.stack(
-        [jnp.einsum("hd,hkd->hk", qh[i], _page(kc, slot_i[i]))
-         for i in range(b)]) / jnp.sqrt(jnp.asarray(dh, qh.dtype))
+    kc = _write_rows(k_cache, key.reshape(b, kv, dh), slot_i, len_i)
+    vc = _write_rows(v_cache, value.reshape(b, kv, dh), slot_i, len_i)
     max_len = k_cache.shape[2]
     keep = jnp.arange(max_len)[None, None, :] <= len_i[:, None, None]
-    probs = jnn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
-    ctx = jnp.stack(
-        [jnp.einsum("hk,hkd->hd", probs[i], _page(vc, slot_i[i]))
-         for i in range(b)])
+    if kv == h:
+        # The grouped form below with groups of one is the same
+        # mathematics, and was measured (PR 31, TPU v5e, PERF.md section
+        # 6): the 1-row program compiles to the same HLO, but in the
+        # 8-row one XLA splits the softmax's multi-output fusion (+2
+        # loop fusions a layer) and `opt1b3_offline` read 1,028.8 /
+        # 1,029.3 against 1,034.1 / 1,034.2 tokens/s, peak +0.7 MB.
+        qh = query.reshape(b, h, dh)
+        scores = _scaled(jnp.stack(
+            [jnp.einsum("hd,hkd->hk", qh[i], _page(kc, slot_i[i]))
+             for i in range(b)]), dh, scale)
+        probs = jnn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
+        ctx = jnp.stack(
+            [jnp.einsum("hk,hkd->hd", probs[i], _page(vc, slot_i[i]))
+             for i in range(b)])
+    else:  # each ring head read once, by its group of query heads
+        qg = query.reshape(b, kv, h // kv, dh)
+        scores = _scaled(jnp.stack(
+            [jnp.einsum("grd,gkd->grk", qg[i], _page(kc, slot_i[i]))
+             for i in range(b)]), dh, scale).reshape(b, h, max_len)
+        probs = jnn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
+        probs = probs.reshape(b, kv, h // kv, max_len)
+        ctx = jnp.stack(
+            [jnp.einsum("grk,gkd->grd", probs[i], _page(vc, slot_i[i]))
+             for i in range(b)])
     return ctx.reshape(b, 1, d), kc, vc
 
 
@@ -295,7 +345,7 @@ def _infer_kv_write(in_shapes, attrs):
           num_outputs=2, infer_shape=_infer_kv_write)
 def kv_cache_write(k_cache, v_cache, k_block, v_block, slot, **kw):
     """Prefill-side cache fill: write one request's per-head K/V block
-    ``(1, H, T, d_head)`` into ring slot ``slot`` at positions
+    ``(1, H_kv, T, d_head)`` into ring slot ``slot`` at positions
     ``[0, T)``.  Positions beyond the request's true length hold
     garbage from the padded prefill — safe by construction: decode
     masks by length and OVERWRITES position `length` before the mask

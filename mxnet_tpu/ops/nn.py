@@ -746,6 +746,8 @@ def activation(data, act_type="relu", **kw):
         return jax.nn.softplus(data)
     if act == "softsign":
         return jax.nn.soft_sign(data)
+    if act == "silu":
+        return jax.nn.silu(data)
     raise ValueError("unknown act_type %s" % act)
 
 

@@ -21,12 +21,26 @@ many decode iterations — and two program families:
   iteration-level re-pack the batcher already does for classic
   tenants.
 
-The KV ring is preallocated per layer, ``max_sessions + 1`` pages of
-``max_len`` positions, in the shape the MODEL states
-(``model.cache_shape(slots, max_len)`` — the session never spells the
-axes out); index ``max_sessions`` is the SCRATCH slot padded decode rows
-write into (their writes land on its position 0 one after the other:
-harmless garbage).  The rings thread FUNCTIONALLY through every program
+**State of more than one kind.**  What a session keeps on the device
+between calls is whatever the MODEL's ``cache_spec(slots, max_len)``
+states — an ordered ``{name: CacheEntry(kind, shape)}`` the session never
+spells out: per attention layer two KV RINGS (kind ``"ring"``:
+``max_sessions + 1`` pages of ``max_len`` positions), per state-space
+layer a conv window and a recurrent STATE (kind ``"state"``: a fixed size
+a slot, whatever the context).  Every entry is preallocated, threaded,
+donated, booked and charged the same way; the kinds differ in what may
+be stale.  A ring is masked by ``length``, so what a slot's previous
+tenant or a padded prefill left beyond it is never read.  A recurrent
+state has no mask: a prefill therefore computes the prompt's state from
+an empty one, counts no position of the bucket's pad (``length`` rides
+into the scan), and writes the WHOLE of the slot's window and state —
+nothing of the previous tenant survives — and a decode step writes only
+the slots of its rows.  Index ``max_sessions`` is the SCRATCH slot that
+padded decode rows (and the warm-up's fills) point at: their ring writes
+land on its position 0 one after the other and their state updates on
+its one state, garbage nobody reads.
+
+The entries thread FUNCTIONALLY through every program
 call — caches in, updated caches out — which on TPU rides the serve
 program's donated input tuple: a decode step writes one row a session
 in place and each row's attention reads its own page where it lies
@@ -173,11 +187,13 @@ class GenerativeSession:
 
     `model` is duck-typed (models/transformer_lm.py TransformerLM is
     the zoo instance): attribute ``max_len`` and methods
-    ``prefill_symbol()`` / ``decode_symbol()`` / ``cache_names()`` /
-    ``cache_shape(slots, max_len)`` (the stored shape of one ring).  Both
-    graphs take ``data``, ``slot``, ``length``, the rings and
-    ``last_token (slots + 1,)`` and return ``[logits, rings...,
-    last_token, token (B,), extra_outputs()...]``.
+    ``prefill_symbol()`` / ``decode_symbol()`` / ``cache_spec(slots,
+    max_len)`` (ordered name -> entry with ``kind`` ``"ring"`` |
+    ``"state"``, ``shape`` and ``nbytes``: every buffer the session
+    keeps on the device).  Both graphs take ``data``, ``slot``,
+    ``length``, the spec's entries and ``last_token (slots + 1,)`` and
+    return ``[logits, entries..., last_token, token (B,),
+    extra_outputs()...]``.
     `params` maps parameter name -> array (a training checkpoint's
     arg+aux dicts merged).  Knob defaults come from the config
     registry: ``MXTPU_SERVE_MAX_SESSIONS`` / ``_MAX_DECODE_TOKENS`` /
@@ -202,17 +218,26 @@ class GenerativeSession:
             max_decode_tokens if max_decode_tokens is not None
             else config.get("MXTPU_SERVE_MAX_DECODE_TOKENS"))
         self._eos_default = None if eos_id is None else int(eos_id)
-        self._cache_names = list(model.cache_names())
+        # the model owns what is cached and in which shape; the +1 is the
+        # scratch slot padded decode rows point at
+        self._spec = dict(model.cache_spec(self._slots + 1, self._max_len))
+        self._cache_bytes = sum(e.nbytes for e in self._spec.values())
+        self._state_bytes = sum(e.nbytes for e in self._spec.values()
+                                if e.kind == "state")
+        self._has_ring = any(e.kind == "ring" for e in self._spec.values())
         # a routed model's programs end with tokens per (layer, expert)
         self._reports_moe_load = "moe_load" in tuple(
             getattr(model, "extra_outputs", tuple)())
-        # every call threads the rings, then each slot's last token
-        self._input_names = (["data", "slot", "length"] + self._cache_names
+        # every call threads the cache entries, then each slot's last token
+        self._input_names = (["data", "slot", "length"] + list(self._spec)
                              + ["last_token"])
-        # the model owns the ring's stored shape; the +1 is the scratch
-        # slot padded decode rows point at
-        cshape = tuple(model.cache_shape(self._slots + 1, self._max_len))
-        self._cache_shape = cshape
+        graphs = {True: model.prefill_symbol(), False: model.decode_symbol()}
+        # a graph takes the inputs it uses: the decode step of a model
+        # with neither a ring nor a position table has no use for `length`
+        self._wire = {
+            prefill: [n for n in self._input_names
+                      if n in set(graph.list_arguments())]
+            for prefill, graph in graphs.items()}
         # sequence-length ladder for prefill; decode-batch ladder for
         # the packed step — both compile-once through the predictors'
         # signature caches
@@ -221,10 +246,10 @@ class GenerativeSession:
                             bucket_ladder(self._max_len, ""))
         self._decode_ladder = bucket_ladder(self._slots, "")
         self._prefill_pred = Predictor(
-            model.prefill_symbol(), dict(params),
+            graphs[True], dict(params),
             self._shapes(1, self._seq_ladder[0], prefill=True), ctx=ctx)
         self._decode_pred = Predictor(
-            model.decode_symbol(), dict(params),
+            graphs[False], dict(params),
             self._shapes(self._decode_ladder[0], 1, prefill=False),
             ctx=ctx)
         # the device-resident state, threaded through every call
@@ -236,14 +261,14 @@ class GenerativeSession:
         self._programs = {}
         self._tokens_done = 0
         self._closed = False
-        # book the ring in the live-buffer census: nbytes is constant
+        # book the state in the live-buffer census: nbytes is constant
         # for the session's lifetime (numpy seeds become device arrays
         # of the same shape/dtype), so book once and unbook at close()
         self._mem_booked = 0
         if telemetry.enabled():
             from ..obs import memory
 
-            self._mem_booked = sum(c.nbytes for c in self._state)
+            self._mem_booked = self._cache_bytes + self._state[-1].nbytes
             memory.book("kv_ring.%s" % name, self._mem_booked)
             telemetry.set_gauge("kv.ring_bytes", self._mem_booked)
             telemetry.set_gauge("kv.slot_occupancy", 0.0)
@@ -255,14 +280,13 @@ class GenerativeSession:
     def _shapes(self, batch, seq, prefill):
         shp = {"data": (batch, seq), "slot": (batch,),
                "length": (batch,)}
-        shp.update({n: self._cache_shape for n in self._cache_names})
+        shp.update({n: e.shape for n, e in self._spec.items()})
         shp["last_token"] = (self._slots + 1,)
-        return shp
+        return {n: shp[n] for n in self._wire[bool(prefill)]}
 
     def _fresh_state(self):
-        """Zeroed rings and last-token vector, in wire order."""
-        return ([_np.zeros(self._cache_shape, _np.float32)
-                 for _ in self._cache_names]
+        """Zeroed cache entries and last-token vector, in wire order."""
+        return ([_np.zeros(e.shape, _np.float32) for e in self._spec.values()]
                 + [_np.zeros((self._slots + 1,), _np.float32)])
 
     def validate(self, inputs):
@@ -320,7 +344,7 @@ class GenerativeSession:
                     self._shapes(batch, seq, prefill))
                 if telemetry.enabled():
                     telemetry.inc("serving.decode.bucket_programs")
-            fn = exe.serve_program(self._input_names)
+            fn = exe.serve_program(self._wire[bool(prefill)])
         return exe, fn
 
     def warm(self, buckets=None):
@@ -356,9 +380,11 @@ class GenerativeSession:
         the tokens, a routed model's `moe_load` — and the updated
         state).  The state passed in is donated on device backends —
         the caller keeps only what comes back."""
-        other_vals, aux_vals = exe.serve_args(self._input_names)
-        ins = tuple([data, slot, length] + list(state))
-        outs = fn(ins, other_vals, aux_vals, _np.uint32(0))
+        names = [n for n in self._input_names if n in exe.arg_dict]
+        other_vals, aux_vals = exe.serve_args(names)
+        wire = dict(zip(self._input_names, [data, slot, length] + list(state)))
+        outs = fn(tuple(wire[n] for n in names), other_vals, aux_vals,
+                  _np.uint32(0))
         n_state = len(state)
         small = tuple(outs[1 + n_state:])
         if logits:
@@ -495,6 +521,10 @@ class GenerativeSession:
             self._active.append(sess)
         if telemetry.enabled():
             telemetry.inc("serving.decode.sessions")
+            # positions the prefill program computed, and those of them
+            # past the prompt's end: computed and thrown away
+            telemetry.inc("serving.prefill.bucket_positions", bucket)
+            telemetry.inc("serving.prefill.pad_positions", bucket - n)
             self._note_occupancy()
 
     def _note_occupancy(self):
@@ -579,16 +609,21 @@ class GenerativeSession:
                 telemetry.inc("serving.decode.runahead_steps")
             telemetry.set_gauge("serving.decode.batch_fill_ratio",
                                 n / bucket)
-            # position-steps: over a window their ratio is the mean
-            # reserved over used.  Reserved are the KV ring sets bound on
-            # the device — the live set plus the zero-filled placeholder
-            # set each bucket program's executor binds — times a ring's
-            # rows and length; used are the positions the packed
-            # sessions had filled when the step was packed
-            telemetry.inc("kv.reserved_positions",
-                          (1 + len(self._programs)) * self._cache_shape[0]
-                          * self._max_len)
-            telemetry.inc("kv.used_positions", int(length.sum()))
+            # the cache sets bound on the device: the live one plus the
+            # zero-filled placeholder set each bucket program's executor
+            # binds.  All their bytes, and the part that is recurrent state
+            sets = 1 + len(self._programs)
+            telemetry.inc("cache.reserved_bytes", sets * self._cache_bytes)
+            telemetry.inc("cache.state_bytes", sets * self._state_bytes)
+            if self._has_ring:
+                # position-steps: over a window their ratio is the mean
+                # reserved over used.  Reserved are a ring set's pages
+                # times their length, in every bound set; used are the
+                # positions the packed sessions had filled when the step
+                # was packed.  A model with no ring has neither
+                telemetry.inc("kv.reserved_positions",
+                              sets * (self._slots + 1) * self._max_len)
+                telemetry.inc("kv.used_positions", int(length.sum()))
 
     def _emit(self, sess, token):
         """Book one sampled token; retire on EOS / budget / ring-full."""

@@ -23,7 +23,6 @@ complete — no future is ever left unresolved.
 """
 from __future__ import annotations
 
-import math
 import threading
 import time
 
@@ -184,8 +183,9 @@ class ModelServer:
                     "got %r" % (name, slo_target))
             slo = (float(slo_ms) / 1e3, target)
         # byte-budget admission: predict the footprint ANALYTICALLY —
-        # the parameters as often as the device will hold them, plus the
-        # KV ring shape GenerativeSession will allocate — so refusal
+        # the parameters as often as the device will hold them, plus every
+        # entry of the cache spec GenerativeSession will allocate (rings
+        # and recurrent state alike, each by its own bytes) — so refusal
         # happens before any compile or ring allocation.  The prefill and
         # the decode predictor bind the arrays they are given: NDArrays
         # already on the tenant's device are held once, anything else is
@@ -203,10 +203,10 @@ class ModelServer:
         ring_len = int(max_len if max_len is not None
                        else config.get("MXTPU_SERVE_KV_MAX_LEN"))
         ring_len = min(ring_len, int(model.max_len))
-        ring_bytes = (math.prod(model.cache_shape(slots + 1, ring_len))
-                      * 4 * len(model.cache_names()))
+        cache_bytes = sum(entry.nbytes for entry in
+                          model.cache_spec(slots + 1, ring_len).values())
         memory.admit("generative tenant %r" % name,
-                     (1 if shared else 2) * param_bytes + ring_bytes,
+                     (1 if shared else 2) * param_bytes + cache_bytes,
                      device=ctx.jax_device())
         # build outside the lock — Predictor construction compiles the
         # smallest prefill/decode buckets and must not stall submits

@@ -231,9 +231,10 @@ def test_defaults_are_the_opt_block():
     assert lm.score_symbol().list_arguments() == want
     assert lm.training_symbol().list_arguments() == want + ["softmax_label"]
     assert lm.extra_outputs() == ()
-    ring = lm.cache_shape(3)
+    spec = lm.cache_spec(3)
+    ring = spec["k_cache_0"].shape
     shapes = dict(data=(2, 1), slot=(2,), length=(2,), last_token=(3,),
-                  **{n: ring for n in lm.cache_names()})
+                  **{n: e.shape for n, e in spec.items()})
     # logits, the rings, each slot's last token, the sampled tokens
     _, outs, _ = lm.decode_symbol().infer_shape(**shapes)
     assert outs == [(2, 24)] + [ring] * 4 + [(3,), (2,)]

@@ -752,6 +752,10 @@ COVERED_ELSEWHERE = {
     # test_olmoe.py (numpy oracles, and the plain jax.numpy reference of
     # the benchmark through the score graph and the KV ring)
     "RMSNorm", "_rotary", "_rotary_at",
+    # test_granite_hybrid.py (ops/ssm.py: the chunked scan, the padded
+    # prefill and the decode step against a float64 numpy recurrence run
+    # one position at a time)
+    "_ssm_scan", "_ssm_prefill", "_ssm_step",
     # test_contrib_ops2.py
     "_contrib_fft", "_contrib_ifft", "_contrib_quantize",
     "_contrib_dequantize", "_contrib_count_sketch", "_contrib_Proposal",

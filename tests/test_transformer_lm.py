@@ -675,16 +675,21 @@ def _np_softmax(s):
     return e / e.sum(-1, keepdims=True)
 
 
-def _np_attention(q, ks, vs):
+def _np_attention(q, ks, vs, scale=None):
     """Plain attention of one query ``(H, d_head)`` over a session's
-    whole sequence ``ks``/``vs (T, H, d_head)`` — the oracle the ring
-    ops are held to."""
-    dh = q.shape[-1]
-    sc = np.einsum("hd,thd->ht", q, ks) / np.sqrt(dh)
+    whole sequence ``ks``/``vs (T, H_kv, d_head)`` — the oracle the ring
+    ops are held to.  Fewer K/V heads than query heads are REPEATED, each
+    for its group of consecutive query heads; `scale` replaces
+    ``1 / sqrt(d_head)``."""
+    h, dh = q.shape
+    ks, vs = (np.repeat(x, h // x.shape[1], axis=1) for x in (ks, vs))
+    sc = np.einsum("hd,thd->ht", q, ks) * (
+        1 / np.sqrt(dh) if scale is None else scale)
     return np.einsum("ht,thd->hd", _np_softmax(sc), vs)
 
 
-def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2):
+def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2,
+               kv_heads=None, scale=None):
     """Drive `_kv_cache_write` + `_cached_attention` the way a serving
     session does and hold every step's context to `_np_attention` over
     the session's full sequence.
@@ -696,22 +701,24 @@ def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2):
     RETURNED rings, `padded` extra rows pointing at the scratch slot with
     length 0 beside the live ones.  A later step reads what an earlier
     one wrote, so a row that lands anywhere but ``(slot, :, length)``
-    shows.  The rings are only ever built from ``cache_shape`` and
+    shows.  The rings are only ever built from ``cache_spec`` and
     handed from op to op: their axis order is the model's own."""
     rng = np.random.RandomState(seed)
     d = h * dh
+    kv = h if kv_heads is None else kv_heads
     lm = TransformerLM(vocab=8, num_layers=1, num_heads=h, d_model=d,
-                       max_len=max_len)
+                       max_len=max_len, num_kv_heads=kv_heads)
+    options = {} if kv_heads is None else dict(num_kv_heads=kv, scale=scale)
     n_slots = max(slots) + 1
     scratch = n_slots                      # serving/decode.py's +1 slot
-    shape = lm.cache_shape(n_slots + 1)
+    shape = lm.cache_spec(n_slots + 1)["k_cache_0"].shape
     kc = mx.nd.array(rng.randn(*shape).astype(np.float32))
     vc = mx.nd.array(rng.randn(*shape).astype(np.float32))
     hist_k, hist_v = [], []
     for s, n in zip(slots, lens):
         t = min(max_len, n + 2)            # a bucket longer than the prompt
-        kb = rng.randn(1, h, t, dh).astype(np.float32)
-        vb = rng.randn(1, h, t, dh).astype(np.float32)
+        kb = rng.randn(1, kv, t, dh).astype(np.float32)
+        vb = rng.randn(1, kv, t, dh).astype(np.float32)
         if n:
             kc, vc = mx.nd._kv_cache_write(
                 kc, vc, mx.nd.array(kb), mx.nd.array(vb),
@@ -724,18 +731,19 @@ def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2):
     for _ in range(steps):
         length = np.array([len(k) for k in hist_k] + [0] * padded,
                           np.float32)
-        q, k, v = (rng.randn(b, 1, d).astype(np.float32) for _ in range(3))
+        q, k, v = (rng.randn(b, 1, width).astype(np.float32)
+                   for width in (d, kv * dh, kv * dh))
         ctx, kc, vc = mx.nd._cached_attention(
             mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), kc, vc,
-            mx.nd.array(slot), mx.nd.array(length), num_heads=h)
+            mx.nd.array(slot), mx.nd.array(length), num_heads=h, **options)
         assert kc.shape == shape and vc.shape == shape
         got = ctx.asnumpy()
         assert got.shape == (b, 1, d) and np.isfinite(got).all()
         for i in range(live):
-            hist_k[i].append(k[i, 0].reshape(h, dh))
-            hist_v[i].append(v[i, 0].reshape(h, dh))
-            want = _np_attention(q[i, 0].reshape(h, dh),
-                                 np.stack(hist_k[i]), np.stack(hist_v[i]))
+            hist_k[i].append(k[i, 0].reshape(kv, dh))
+            hist_v[i].append(v[i, 0].reshape(kv, dh))
+            want = _np_attention(q[i, 0].reshape(h, dh), np.stack(hist_k[i]),
+                                 np.stack(hist_v[i]), scale)
             assert np.allclose(got[i, 0], want.reshape(d), rtol=1e-4,
                                atol=1e-5), (i, len(hist_k[i]))
 
@@ -754,6 +762,10 @@ RING_CASES = {
     # a full bucket: every slot live, in an order that is not the slots'
     "full_bucket_shuffled": dict(slots=[2, 0, 3, 1], lens=[1, 7, 2, 5],
                                  padded=0),
+    # grouped-query heads (a ring of 2 K/V heads read by 6 query heads)
+    # and a stated scale in place of 1/sqrt(d_head), beside padded rows
+    "grouped_query_heads": dict(h=6, kv_heads=2, scale=0.03, slots=[3, 1],
+                                lens=[0, 6], padded=2),
 }
 
 
@@ -763,8 +775,9 @@ def test_ring_ops_match_full_sequence_attention(case, d_head):
     """`_cached_attention` + `_kv_cache_write` against plain numpy
     attention over each session's full sequence, two steps in a row on
     the returned rings, at both head widths the benchmark's decoders
-    have (64: OPT; 128: OLMoE)."""
-    _ring_case(2, d_head, _MAX_LEN, seed=11, **RING_CASES[case])
+    have (64: OPT, Granite's attention layers; 128: OLMoE)."""
+    _ring_case(**dict(dict(h=2), **RING_CASES[case]), dh=d_head,
+               max_len=_MAX_LEN, seed=11)
 
 
 def _walk_eqns(jaxpr):
@@ -808,7 +821,7 @@ def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
         jaxpr = jax.make_jaxpr(fn._jit)(ins, other, aux, np.uint32(0))
     finally:
         gs.close()
-    ring = tuple(lm.cache_shape(5, max_len))
+    ring = tuple(lm.cache_spec(5, max_len)["k_cache_0"].shape)
     page = int(np.prod(ring[1:]))
     updates, token_writes, big, page_makers = 0, 0, [], set()
     for eqn in _walk_eqns(jaxpr.jaxpr):
