@@ -23,10 +23,13 @@ apart from the rest:
   generate  TransformerLM via add_generative_tenant + submit_generate;
             one session's prefill/decode logits against the
             full-recompute score_symbol forward
-  kv_ring   the decode program of an OPT-shaped TransformerLM (32 heads
-            of 64, 8 sessions, ring 768) as XLA compiled it: every KV
-            ring parameter aliased to its output, no instruction that
-            copies a ring; prints the rings' on-device layout
+  kv_ring   the decode programs of three TransformerLMs shaped like the
+            benchmark's decoders (32 heads of 64; 16 of 128; 32 query on
+            8 K/V heads of 64 with rings of 2,304; 8 sessions) as XLA
+            compiled them: every KV ring parameter aliased to its
+            output, no instruction that copies a ring, ONE attention
+            kernel call a layer, and no ring fatter on the device than
+            cache_spec states; prints the rings' on-device layout
   kernel    ops/pallas_kernels.bn_stats under Mosaic at two ResNet-50
             shapes against the jnp reduction
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
@@ -58,9 +61,13 @@ FULL = {
                  "d_model": 512, "max_len": 320, "max_sessions": 4,
                  "seq_buckets": [16, 64], "prompts": 8, "new_tokens": 32,
                  "check_steps": 8, "seed": 2},
-    "kv_ring": {"vocab": 8192, "num_layers": 2, "num_heads": 32,
-                "d_model": 2048, "d_ff": 2048, "max_len": 768,
-                "max_sessions": 8, "seq_buckets": [64], "seed": 5},
+    "kv_ring": {"vocab": 8192, "num_layers": 2, "d_model": 2048,
+                "d_ff": 2048, "max_sessions": 8, "seq_buckets": [64],
+                "seed": 5,
+                "shapes": [dict(num_heads=32, max_len=768),
+                           dict(num_heads=16, max_len=768),
+                           dict(num_heads=32, num_kv_heads=8,
+                                max_len=2304)]},
     "kernel": {"shapes": [(512, 56, 56, 64), (512, 7, 7, 2048)], "seed": 3},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
@@ -433,7 +440,7 @@ def ring_hlo_facts(text, ring_shape):
     their on-device layouts, which of them ``input_output_alias`` gives
     to an output, and every instruction — entry or fused — that copies
     an array of the ring's shape (``copy``, or the asynchronous
-    ``copy-start``)."""
+    ``copy-start``), and how many Pallas kernel calls it holds."""
     dims = re.escape(",".join(str(d) for d in ring_shape))
     entry = text[text.index("ENTRY"):]
     params = {int(n): layout for layout, n in re.findall(
@@ -447,62 +454,87 @@ def ring_hlo_facts(text, ring_shape):
               and re.search(r" (copy|copy-start)\(", line)]
     return {"ring_params": len(params),
             "layouts": sorted(set(params.values())),
-            "aliased": len(aliased & set(params)), "copies": copies}
+            "aliased": len(aliased & set(params)), "copies": copies,
+            "kernel_calls": text.count('custom_call_target="tpu_custom_call"')}
 
 
 def phase_kv_ring(sizes, ctx):
     """The decode step touches a KV ring where it lies (PERF.md section
-    6, PR 26): compile the largest decode bucket of an OPT-shaped LM and
-    read what XLA made of the rings.  Only a device backend donates, so
-    only there are aliasing and copies judged; on the CPU the phase
-    still compiles, runs and parses."""
+    6, PR 26 and PR 32): for each of `sizes["shapes"]` compile the
+    largest decode bucket of an LM of that shape and read what XLA made
+    of the rings.  Only a device backend donates and only the TPU has
+    the kernel, so only there are aliasing, copies, kernel calls and
+    the rings' size on the device judged; on the CPU the phase still
+    compiles, runs and parses."""
     import numpy as np
 
     import mxnet_tpu as mx
     from mxnet_tpu.models import TransformerLM
 
-    lm = TransformerLM(vocab=sizes["vocab"], num_layers=sizes["num_layers"],
-                       num_heads=sizes["num_heads"], d_model=sizes["d_model"],
-                       d_ff=sizes["d_ff"], max_len=sizes["max_len"])
+    platform = ctx.jax_device().platform
     slots = sizes["max_sessions"]
-    spec = lm.cache_spec(slots + 1)
-    ring = tuple(spec["k_cache_0"].shape)
-    step = lm.decode_symbol()
-    inputs = dict(data=(slots, 1), slot=(slots,), length=(slots,),
-                  last_token=(slots + 1,),
-                  **{n: e.shape for n, e in spec.items()})
-    shapes, _, _ = step.infer_shape(**inputs)
-    rng = np.random.RandomState(sizes["seed"])
-    params = {n: mx.nd.array((rng.randn(*s) * 0.02).astype(np.float32),
-                             ctx=ctx)
-              for n, s in zip(step.list_arguments(), shapes)
-              if n not in inputs}
-    server = mx.serving.ModelServer({})
-    try:
-        session = server.add_generative_tenant(
-            "ring", lm, params, ctx=ctx, max_sessions=slots,
-            max_len=sizes["max_len"], seq_buckets=sizes["seq_buckets"])
-        server.warmup()
-        _exe, fn = session._program(session._decode_pred, slots, 1, False)
-        facts = ring_hlo_facts(fn.hlo_text(), ring)
-    finally:
-        server.close()
-    print("[chip_smoke] kv_ring: %d ring parameters f32%s, layout(s) %s; "
-          "%d aliased to an output; %d ring copies"
-          % (facts["ring_params"], list(ring), facts["layouts"],
-             facts["aliased"], len(facts["copies"])), flush=True)
-    _check(facts["ring_params"] == len(spec),
-           "found %d ring parameters of %d in the decode program's HLO"
-           % (facts["ring_params"], len(spec)))
-    if ctx.jax_device().platform != "cpu":
-        _check(facts["aliased"] == facts["ring_params"],
-               "only %d of %d KV ring parameters are aliased to an output: "
-               "the rest are rewritten whole every step"
-               % (facts["aliased"], facts["ring_params"]))
-        _check(not facts["copies"], "the decode program copies a KV ring: "
-               + "; ".join(facts["copies"][:4]))
-    facts["copies"] = len(facts["copies"])
-    return facts
+    total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
+             "layouts": [], "rings": []}
+    for shape in sizes["shapes"]:
+        lm = TransformerLM(vocab=sizes["vocab"],
+                           num_layers=sizes["num_layers"],
+                           d_model=sizes["d_model"], d_ff=sizes["d_ff"],
+                           **shape)
+        spec = lm.cache_spec(slots + 1)
+        ring = tuple(spec["k_cache_0"].shape)
+        step = lm.decode_symbol()
+        inputs = dict(data=(slots, 1), slot=(slots,), length=(slots,),
+                      last_token=(slots + 1,),
+                      **{n: e.shape for n, e in spec.items()})
+        shapes, _, _ = step.infer_shape(**inputs)
+        rng = np.random.RandomState(sizes["seed"])
+        params = {n: mx.nd.array((rng.randn(*s) * 0.02).astype(np.float32),
+                                 ctx=ctx)
+                  for n, s in zip(step.list_arguments(), shapes)
+                  if n not in inputs}
+        server = mx.serving.ModelServer({})
+        try:
+            session = server.add_generative_tenant(
+                "ring", lm, params, ctx=ctx, max_sessions=slots,
+                max_len=shape["max_len"], seq_buckets=sizes["seq_buckets"])
+            server.warmup()
+            _exe, fn = session._program(session._decode_pred, slots, 1,
+                                        False)
+            facts = ring_hlo_facts(fn.hlo_text(), ring)
+            # the live set as the warm-up's programs left it on the device
+            held = [(n, e.nbytes, getattr(a, "on_device_size_in_bytes",
+                                          lambda: e.nbytes)())
+                    for (n, e), a in zip(spec.items(), session._state)]
+        finally:
+            server.close()
+        print("[chip_smoke] kv_ring: %d ring parameters f32%s, layout(s) %s;"
+              " %d aliased to an output; %d ring copies; %d kernel calls"
+              % (facts["ring_params"], list(ring), facts["layouts"],
+                 facts["aliased"], len(facts["copies"]),
+                 facts["kernel_calls"]), flush=True)
+        _check(facts["ring_params"] == len(spec),
+               "found %d ring parameters of %d in the decode program's HLO"
+               % (facts["ring_params"], len(spec)))
+        if platform != "cpu":
+            _check(facts["aliased"] == facts["ring_params"],
+                   "only %d of %d KV ring parameters are aliased to an "
+                   "output: the rest are rewritten whole every step"
+                   % (facts["aliased"], facts["ring_params"]))
+            _check(not facts["copies"], "the decode program copies a KV "
+                   "ring: " + "; ".join(facts["copies"][:4]))
+            fat = ["%s %d > %d" % row for row in held if row[2] > row[1]]
+            _check(not fat, "rings fatter on the device than cache_spec "
+                   "states (bytes): " + "; ".join(fat[:4]))
+        if platform == "tpu":
+            _check(facts["kernel_calls"] == lm.num_layers,
+                   "%d attention kernel calls in a decode program of %d "
+                   "layers" % (facts["kernel_calls"], lm.num_layers))
+        for key in ("ring_params", "aliased", "kernel_calls"):
+            total[key] += facts[key]
+        total["copies"] += len(facts["copies"])
+        total["layouts"] = sorted(set(total["layouts"] + facts["layouts"]))
+        total["rings"].append(list(ring))
+    return total
 
 
 def phase_kernel(sizes, ctx):

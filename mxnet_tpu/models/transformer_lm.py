@@ -122,16 +122,20 @@ class _Attention:
         return p
 
     def cache_spec(self, i, slots, max_len):
-        """``(slots, num_kv_heads, max_len, d_head)`` for K and for V.
+        """``(slots, num_kv_heads, d_head, max_len)`` for K and for V:
+        the POSITIONS on the minor axis, for every head width.
 
-        One order for every head width, measured on a TPU v5e (PERF.md
-        section 6, PR 26): the runtime stores a 64-wide minor axis with
-        the POSITIONS on the lanes (``[slot][head][d_head][position]``,
-        dense) and a 128-wide one as written, which is in both cases
-        the layout the decode step's attention reads; rings transposed by
-        hand were no faster at d_head 64 and 18-36% slower at 128."""
+        It is the order the decode step's attention reads (ops/
+        attention.py): scores reduce over d_head and the context over
+        positions without moving either, and the TPU kernel's operands
+        have this layout as they are stored — no copy, no padding of a
+        64-wide head to 128 lanes.  A step's new row is one position of
+        every ``(head, d_head)`` line, so it is written as part of the
+        128-position block that holds it (PERF.md section 6, PR 32;
+        PR 26 had found the runtime keeping 64-wide heads in this very
+        order under the old shape)."""
         ring = CacheEntry("ring", (int(slots), self.lm.num_kv_heads,
-                                   int(max_len), self.lm.d_head))
+                                   self.lm.d_head, int(max_len)))
         return [("k_cache_%d" % i, ring), ("v_cache_%d" % i, ring)]
 
     def _qkv(self, x, p, i, index=None):

@@ -17,21 +17,37 @@ needs four graph-level primitives beyond the classic registry:
   projections.
 * ``_cached_attention`` / ``_kv_cache_write`` — the decode-side pair.
   The KV ring is one preallocated buffer per layer for K and one for V,
-  a PAGE of ``max_len`` positions per slot; its stored shape belongs to
-  the model (``TransformerLM.cache_spec`` — every allocator asks it).
-  The SLOT INDEX and LENGTH ride as traced operands (the
-  vLLM/PagedAttention discipline: address pages by index, mask by
+  a PAGE of ``max_len`` positions per slot, stored ``(slots, H_kv,
+  d_head, max_len)``: the positions on the minor axis, which on a TPU
+  are the lanes (``TransformerLM.cache_spec`` owns the shape — every
+  allocator asks it).  The SLOT INDEX and LENGTH ride as traced operands
+  (the vLLM/PagedAttention discipline: address pages by index, bound by
   length), so one compiled decode program serves every session mix —
-  sessions join/leave between steps without recompiling.  A decode step
-  touches a ring only where it changes and reads it where it lies: each
-  packed row's K/V is ONE ``dynamic_update_slice`` at ``(slot, :,
-  length, :)`` (XLA keeps the ring's layout and, under donation, updates
-  the donated buffer in place), and each row's attention reads its own
-  page through a ``dynamic_slice`` that XLA fuses into the reduction —
-  no gathered ``(B, heads, max_len, d_head)`` copy of the pages, no
-  ring-sized temporary.  (A scatter over the two separated index axes
-  ``[slot, :, length, :]`` made XLA:TPU convert the WHOLE ring between
-  two layouts twice a layer a step: PERF.md section 6, PR 26.)
+  sessions join/leave between steps without recompiling.  WRITE, THEN
+  READ: the step's K/V row goes to ``(slot, :, :, length)`` before the
+  row attends to positions ``0..length``, so a token attends to itself.
+
+  *On a TPU* a decode step's attention is ONE kernel a layer
+  (``ops/kv_ring_kernel.py``, Pallas) wherever the ring's shape gives it
+  a block (``decode_block``: the largest multiple of 128 positions that
+  divides ``max_len`` and keeps ``H_kv * d_head * block`` floats within
+  1 MiB).  It reads each packed row's page block by block ONLY AS FAR AS
+  the block that holds position ``length`` — the blocks beyond are not
+  fetched — with an online softmax over the blocks, puts the new row
+  into that last block while it is in fast memory and sends the block
+  back to the donated ring by one dense DMA.  *Everywhere else* (the
+  CPU, a ring with no block) the ``jax.numpy`` body below runs, and is
+  the kernel's oracle: each packed row's K/V is one
+  ``dynamic_update_slice`` (in place under donation), each row's
+  attention reads its whole page through a ``dynamic_slice`` fused into
+  the reduction and masks by length.  ``lax.platform_dependent`` chooses
+  when the program is lowered, by the platform it is lowered for; no
+  switch, no environment variable.  (A scatter over the two separated
+  index axes ``[slot, :, :, length]`` made XLA:TPU convert the WHOLE
+  ring between two layouts twice a layer a step: PERF.md section 6,
+  PR 26.  With the positions on the lanes a row write is one lane of
+  2,048 vectors, which XLA stores one by one — 7 us a row a ring; the
+  kernel's block write-back replaced it: PERF.md section 6, PR 32.)
 
 * ``_token_feed`` / ``_greedy_token`` — the sampled token stays on the
   device.  Every serving program ends by taking the greedy token of its
@@ -49,13 +65,16 @@ turns Q and K BEFORE the attention ops see them, so the ring holds
 rotated keys and ``_sdp_attention`` / ``_cached_attention`` /
 ``_kv_cache_write`` are the same for learned and rotary positions.
 
-Everything is pure jnp/lax: the ops trace into the surrounding XLA
-executable on CPU and TPU alike (the blockwise/ring Pallas kernels in
-parallel/ remain the long-context training path; decode works on
-max_len-bounded buffers where one fused softmax is the right shape).
+Everything but the decode step's TPU kernel is pure jnp/lax: the ops
+trace into the surrounding XLA executable on CPU and TPU alike (the
+blockwise/ring Pallas kernels in parallel/ remain the long-context
+training path).
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 from jax import lax, nn as jnn
 
@@ -176,7 +195,7 @@ def _scaled(scores, d_head, scale):
     ``sqrt(d_head)`` as every model before the option was."""
     if scale is None:
         return scores / jnp.sqrt(jnp.asarray(d_head, scores.dtype))
-    return scores * float(_lit(scale))
+    return scores * _lit(scale)
 
 
 def _infer_sdp(in_shapes, attrs):
@@ -245,23 +264,101 @@ def _infer_cached(in_shapes, attrs):
     return [q, kv_in, kv_in, kc, kc, slot, slot], [q, kc, kc]
 
 
+_LANES = 128
+_BLOCK_BYTES = 1 << 20
+
+
+def decode_block(ring_shape, platform, itemsize=4):
+    """Positions of a page that one step of the TPU kernel holds in fast
+    memory, for a ring ``(slots, H_kv, d_head, max_len)``: the largest
+    multiple of 128 that divides ``max_len`` and keeps a block ``(H_kv,
+    d_head, block)`` within 1 MiB.  None where ``_cached_attention``
+    runs its ``jax.numpy`` body and reads whole pages: off the TPU, or
+    for a ring the kernel's tiling does not divide (``max_len`` not a
+    multiple of 128; a ``d_head`` under 8 or that does not divide 128;
+    heads that do not fill whole tiles of 128 lines).  The decode
+    program reads ``length // block + 1`` blocks of a row's page —
+    whoever counts what a step reads (serving/decode.py) asks here."""
+    _, h_kv, d_head, max_len = ring_shape
+    tiled = (d_head >= 8 and _LANES % d_head == 0
+             and h_kv * d_head % _LANES == 0 and max_len % _LANES == 0)
+    if platform != "tpu" or not tiled:
+        return None
+    fits = [blk for blk in range(_LANES, max_len + 1, _LANES)
+            if max_len % blk == 0
+            and h_kv * d_head * blk * itemsize <= _BLOCK_BYTES]
+    return max(fits, default=None)
+
+
 def _page(cache, slot_i):
-    """``cache[slot_i]`` — one slot's page ``(H, max_len, d_head)`` as a
+    """``cache[slot_i]`` — one slot's page ``(H, d_head, max_len)`` as a
     dynamic slice: fused into the reduction that reads it, so the page
     is read where it lies and never copied out."""
     return lax.dynamic_index_in_dim(cache, slot_i, 0, keepdims=False)
 
 
 def _write_rows(cache, rows, slot_i, len_i):
-    """``cache[slot_i[b], :, len_i[b], :] = rows[b]`` for every packed
-    row, one ``dynamic_update_slice`` each, in row order.  The update
-    window is contiguous in the ring as stored, so XLA keeps the ring's
-    layout and — the serve program donates the rings — writes the donated
-    buffer in place."""
+    """``cache[slot_i[b], :, :, len_i[b]] = rows[b]`` for every packed
+    row, one ``dynamic_update_slice`` each, in row order: XLA keeps the
+    ring's layout and — the serve program donates the rings — writes the
+    donated buffer in place."""
     for b in range(rows.shape[0]):
         cache = lax.dynamic_update_slice(
-            cache, rows[b][None, :, None, :], (slot_i[b], 0, len_i[b], 0))
+            cache, rows[b][None, :, :, None], (slot_i[b], 0, 0, len_i[b]))
     return cache
+
+
+def _ring_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i,
+                    scale=None):
+    """The decode step against the rings in ``jax.numpy``: what runs
+    wherever the TPU kernel does not, and the kernel's oracle.  ``q (B,
+    H_q, d)``, ``k_new`` / ``v_new (B, H_kv, d)`` → ``(context (B, H_q,
+    d), k_cache', v_cache')``.  Rows are written first, then each row
+    reads its whole page and masks by length."""
+    b, h, dh = q.shape
+    kv = k_new.shape[1]
+    kc = _write_rows(k_cache, k_new, slot_i, len_i)
+    vc = _write_rows(v_cache, v_new, slot_i, len_i)
+    max_len = k_cache.shape[3]
+    keep = jnp.arange(max_len)[None, None, :] <= len_i[:, None, None]
+    # each ring head read once, by its group of query heads (groups of
+    # one are plain multi-head attention)
+    qg = q.reshape(b, kv, h // kv, dh)
+    scores = _scaled(jnp.stack(
+        [jnp.einsum("grd,gdk->grk", qg[i], _page(kc, slot_i[i]))
+         for i in range(b)]), dh, scale).reshape(b, h, max_len)
+    probs = jnn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
+    probs = probs.reshape(b, kv, h // kv, max_len)
+    ctx = jnp.stack(
+        [jnp.einsum("grk,gdk->grd", probs[i], _page(vc, slot_i[i]))
+         for i in range(b)])
+    return ctx.reshape(b, h, dh), kc, vc
+
+
+# tests flip this to have `_cached_attention` run the TPU's kernel in
+# Pallas interpret mode on the CPU
+_INTERPRET = False
+
+
+@functools.partial(jax.jit, static_argnames=("block", "scale", "interpret"))
+def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
+                      block, scale, interpret):
+    """The decode step against the rings on whatever platform the
+    program is lowered for: the TPU's kernel (with `block` positions a
+    step; `interpret` runs it in Pallas's interpreter, for tests) or the
+    ``jax.numpy`` body.  Jitted, so that the layers of a decode program,
+    whose attention is one and the same, trace and lower both once."""
+    operands = (q, k_new, v_new, k_cache, v_cache, slot_i, len_i)
+    body = functools.partial(_ring_attention, scale=scale)
+    if block is None:
+        return body(*operands)
+
+    def kernel(*operands):
+        from .kv_ring_kernel import ring_attention
+
+        return ring_attention(*operands, block=block, scale=scale,
+                              interpret=interpret)
+    return lax.platform_dependent(*operands, tpu=kernel, default=body)
 
 
 @register("_cached_attention",
@@ -272,26 +369,27 @@ def cached_attention(query, key, value, k_cache, v_cache, slot, length,
                      num_heads=1, scale=None, **kw):
     """One decode step of multi-head attention against a slot-indexed
     KV ring (the PagedAttention shape: address each session's page by
-    slot index, mask by length — both TRACED operands, so one compiled
+    slot index, bound by length — both TRACED operands, so one compiled
     program serves any session mix).
 
     query/key/value: ``(B, 1, d_model)`` projections of the current
     token (key/value ``(B, 1, num_kv_heads * d_head)`` under
     grouped-query attention); ``k_cache``/``v_cache``: rings as the
-    model's ``cache_spec`` states them, ``(slots, H_kv, max_len,
-    d_head)``; ``slot``/``length``: ``(B,)`` — session slot index and the
+    model's ``cache_spec`` states them, ``(slots, H_kv, d_head,
+    max_len)``; ``slot``/``length``: ``(B,)`` — session slot index and the
     number of tokens already cached (== the new token's position).
     `scale` multiplies the scores in place of ``1 / sqrt(d_head)``.
 
-    The step's K/V are written at ``cache[slot, :, length]`` FIRST, one
-    in-place row update a packed row, then each row attends over its own
-    page ``cache[slot, :, :length+1]`` (mask), so the new token attends
-    to itself like the full-sequence forward.  A row reads only its own
-    page, in place: the step's ring traffic is B pages, whatever the
-    number of slots.  Padded rows of a partial decode batch point at the
-    ring's scratch slot with length 0: their writes land one after the
-    other on its position 0 and their softmax stays finite — garbage
-    nobody reads.
+    The step's K/V are written at ``cache[slot, :, :, length]`` FIRST,
+    then each row attends over its own page ``cache[slot, :, :,
+    :length+1]``, so the new token attends to itself like the
+    full-sequence forward.  A row reads only its own page, in place: the
+    step's ring traffic is B pages at most, whatever the number of
+    slots — and on a TPU (module docstring) only the blocks of each page
+    up to the one that holds `length`.  Padded rows of a partial decode
+    batch point at the ring's scratch slot with length 0: their writes
+    land one after the other on its position 0 and their softmax stays
+    finite — garbage nobody reads.
 
     Outputs: context ``(B, 1, d_model)``, updated k_cache, updated
     v_cache (functional update — the serving session threads the rings
@@ -301,37 +399,14 @@ def cached_attention(query, key, value, k_cache, v_cache, slot, length,
     b, one, d = query.shape
     dh = d // h
     kv = _kv_heads(kw, h)
-    slot_i = _as_index(slot)
-    len_i = _as_index(length)
-    kc = _write_rows(k_cache, key.reshape(b, kv, dh), slot_i, len_i)
-    vc = _write_rows(v_cache, value.reshape(b, kv, dh), slot_i, len_i)
-    max_len = k_cache.shape[2]
-    keep = jnp.arange(max_len)[None, None, :] <= len_i[:, None, None]
-    if kv == h:
-        # The grouped form below with groups of one is the same
-        # mathematics, and was measured (PR 31, TPU v5e, PERF.md section
-        # 6): the 1-row program compiles to the same HLO, but in the
-        # 8-row one XLA splits the softmax's multi-output fusion (+2
-        # loop fusions a layer) and `opt1b3_offline` read 1,028.8 /
-        # 1,029.3 against 1,034.1 / 1,034.2 tokens/s, peak +0.7 MB.
-        qh = query.reshape(b, h, dh)
-        scores = _scaled(jnp.stack(
-            [jnp.einsum("hd,hkd->hk", qh[i], _page(kc, slot_i[i]))
-             for i in range(b)]), dh, scale)
-        probs = jnn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
-        ctx = jnp.stack(
-            [jnp.einsum("hk,hkd->hd", probs[i], _page(vc, slot_i[i]))
-             for i in range(b)])
-    else:  # each ring head read once, by its group of query heads
-        qg = query.reshape(b, kv, h // kv, dh)
-        scores = _scaled(jnp.stack(
-            [jnp.einsum("grd,gkd->grk", qg[i], _page(kc, slot_i[i]))
-             for i in range(b)]), dh, scale).reshape(b, h, max_len)
-        probs = jnn.softmax(jnp.where(keep, scores, _NEG), axis=-1)
-        probs = probs.reshape(b, kv, h // kv, max_len)
-        ctx = jnp.stack(
-            [jnp.einsum("grk,gkd->grd", probs[i], _page(vc, slot_i[i]))
-             for i in range(b)])
+    scale = None if scale is None else float(_lit(scale))
+    ctx, kc, vc = _decode_attention(
+        query.reshape(b, h, dh), key.reshape(b, kv, dh),
+        value.reshape(b, kv, dh), k_cache, v_cache, _as_index(slot),
+        _as_index(length), scale=scale, interpret=_INTERPRET,
+        # the block a lowering for the TPU would use; which platform the
+        # program is lowered for is not known here
+        block=decode_block(k_cache.shape, "tpu", k_cache.dtype.itemsize))
     return ctx.reshape(b, 1, d), kc, vc
 
 
@@ -346,14 +421,15 @@ def _infer_kv_write(in_shapes, attrs):
 def kv_cache_write(k_cache, v_cache, k_block, v_block, slot, **kw):
     """Prefill-side cache fill: write one request's per-head K/V block
     ``(1, H_kv, T, d_head)`` into ring slot ``slot`` at positions
-    ``[0, T)``.  Positions beyond the request's true length hold
+    ``[0, T)``, turned to the ring's stored order ``(H_kv, d_head, T)``.
+    Positions beyond the request's true length hold
     garbage from the padded prefill — safe by construction: decode
     masks by length and OVERWRITES position `length` before the mask
     ever exposes it."""
     slot_i = _as_index(slot).reshape(())
     start = (slot_i, 0, 0, 0)
-    return (lax.dynamic_update_slice(k_cache, k_block, start),
-            lax.dynamic_update_slice(v_cache, v_block, start))
+    return (lax.dynamic_update_slice(k_cache, k_block.swapaxes(2, 3), start),
+            lax.dynamic_update_slice(v_cache, v_block.swapaxes(2, 3), start))
 
 
 # ----------------------------------------------------------------------

@@ -87,7 +87,9 @@ still in flight then are read first, they are computed already.
 """
 from __future__ import annotations
 
+import importlib
 import logging
+import threading
 import time
 
 import numpy as _np
@@ -252,6 +254,23 @@ class GenerativeSession:
             graphs[False], dict(params),
             self._shapes(self._decode_ladder[0], 1, prefill=False),
             ctx=ctx)
+        # positions of a page that one step of the decode program's
+        # attention reads at a time, where it stops at the block that
+        # holds `length`; None where it reads whole pages (the CPU)
+        self._ring_block = None
+        if self._has_ring:
+            from ..ops.attention import decode_block
+
+            ring = next(e for e in self._spec.values() if e.kind == "ring")
+            self._ring_block = decode_block(
+                ring.shape, self._decode_pred._ctx.jax_device().platform)
+            if self._ring_block:
+                # the decode programs will lower the kernel: importing
+                # Pallas is over a second of Python, spent here beside
+                # the prefill programs' compiles instead of after them
+                threading.Thread(
+                    target=importlib.import_module, daemon=True,
+                    args=("mxnet_tpu.ops.kv_ring_kernel",)).start()
         # the device-resident state, threaded through every call
         self._state = self._fresh_state()
         self._free = list(range(self._slots))  # LIFO slot pool
@@ -284,10 +303,22 @@ class GenerativeSession:
         shp["last_token"] = (self._slots + 1,)
         return {n: shp[n] for n in self._wire[bool(prefill)]}
 
-    def _fresh_state(self):
-        """Zeroed cache entries and last-token vector, in wire order."""
-        return ([_np.zeros(e.shape, _np.float32) for e in self._spec.values()]
-                + [_np.zeros((self._slots + 1,), _np.float32)])
+    def _fresh_state(self, on_device=False):
+        """Zeroed cache entries and last-token vector, in wire order: on
+        the host (the live set: it reaches the device with the first
+        call that takes it, after the warm-up's set is gone — two sets
+        at once do not fit beside OLMoE's prefill), or `on_device` (the
+        warm-up's: gigabytes need not cross the host link to be
+        zeros)."""
+        shapes = [e.shape for e in self._spec.values()]
+        shapes.append((self._slots + 1,))
+        if not on_device:
+            return [_np.zeros(shape, _np.float32) for shape in shapes]
+        import jax.numpy as jnp
+
+        device = self._decode_pred._ctx.jax_device()
+        return [jnp.zeros(shape, jnp.float32, device=device)
+                for shape in shapes]
 
     def validate(self, inputs):
         """A classic submit() against a generative tenant is a client
@@ -354,7 +385,7 @@ class GenerativeSession:
         programs bucket by sequence length and session count).  The
         fills thread throwaway state: a re-warm beside live traffic must
         neither donate nor overwrite the rings the batcher holds."""
-        state = self._fresh_state()
+        state = self._fresh_state(on_device=True)
         n = 0
         for t in self._seq_ladder:
             exe, fn = self._program(self._prefill_pred, 1, t, True)
@@ -624,6 +655,14 @@ class GenerativeSession:
                 telemetry.inc("kv.reserved_positions",
                               sets * (self._slots + 1) * self._max_len)
                 telemetry.inc("kv.used_positions", int(length.sum()))
+                # what the dispatched program's attention reads of the
+                # packed rows' pages: all of each, or the blocks up to
+                # the one that holds `length` (ops/attention.py)
+                blk = self._ring_block or self._max_len
+                read = (length[:n].astype(_np.int64) // blk + 1) * blk
+                telemetry.inc("kv.page_positions", n * self._max_len)
+                telemetry.inc("kv.skipped_positions",
+                              int(n * self._max_len - read.sum()))
 
     def _emit(self, sess, token):
         """Book one sampled token; retire on EOS / budget / ring-full."""

@@ -213,7 +213,7 @@ def test_the_spec_states_both_kinds_of_state_in_layer_order():
     assert list(spec) == ["conv_state_0", "ssm_state_0", "k_cache_1",
                           "v_cache_1", "conv_state_2", "ssm_state_2",
                           "conv_state_3", "ssm_state_3"]
-    assert spec["k_cache_1"] == ("ring", (4, 2, 48, 16))   # K/V heads: 2
+    assert spec["k_cache_1"] == ("ring", (4, 2, 16, 48))   # K/V heads: 2
     assert spec["conv_state_0"] == ("state", (4, 3, 64 + 2 * 16))
     assert spec["ssm_state_3"] == ("state", (4, 4, 16, 16))
     assert spec["ssm_state_3"].nbytes == 4 * 4 * 4 * 16 * 16

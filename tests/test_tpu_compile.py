@@ -1,0 +1,80 @@
+"""The decode-attention kernel compiled for a TPU v5e that is described,
+not attached (the TPU's compiler is installed where the tests run): what
+Pallas's interpreter cannot see — Mosaic refusing a slice, a layout or
+the fast memory a kernel asks for — at the real widths of the
+benchmark's three decoders.  Nothing runs; a compile that passes is not
+a chip run.  All such compiles live in this ONE file: only one process a
+host may hold the TPU's library, and the topology is described inside a
+fixture so that every xdist worker collects the same tests."""
+import os
+
+import numpy as np
+import pytest
+
+import chip_smoke
+from mxnet_tpu.ops import attention
+
+SLOTS = 9
+# (rows, query heads, K/V heads, d_head, ring length, scale)
+SHAPES = {"opt": (8, 32, 32, 64, 768, None),
+          "olmoe": (8, 16, 16, 128, 768, None),
+          "granite": (8, 32, 8, 64, 2304, 1 / 64),
+          "one_row": (1, 32, 32, 64, 768, None)}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no log files
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described chip can be written to the persistent
+    # cache but not read back: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("name", sorted(SHAPES))
+def test_the_decode_attention_compiles_for_a_v5e(name, one_chip):
+    """Lowered for the TPU, `_decode_attention` is the kernel: ONE
+    `tpu_custom_call`, both rings aliased to its outputs, no copy of a
+    ring, and the rings in the layout `cache_spec`'s shape has by
+    default."""
+    import jax
+    import jax.numpy as jnp
+
+    rows, h_q, h_kv, d_head, max_len, scale = SHAPES[name]
+    ring = (SLOTS, h_kv, d_head, max_len)
+    block = attention.decode_block(ring, "tpu")
+    assert block == {768: 128, 2304: 384}[max_len]
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(*operands):
+        return attention._decode_attention(*operands, block=block,
+                                           scale=scale, interpret=False)
+
+    compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
+        arg((rows, h_q, d_head)), arg((rows, h_kv, d_head)),
+        arg((rows, h_kv, d_head)), arg(ring), arg(ring),
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32)).compile()
+    facts = chip_smoke.ring_hlo_facts(compiled.as_text(), ring)
+    assert facts["kernel_calls"] == 1
+    assert facts["ring_params"] == facts["aliased"] == 2
+    assert facts["copies"] == []
+    assert facts["layouts"] == ["{3,2,1,0:T(8,128)}"]
+    # nothing but the rings and the small operands: no ring-sized scratch
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < np.prod(ring[1:]) * 4

@@ -16,8 +16,10 @@ The decode pins are the acceptance criteria of the KV-cache PR:
 * zero lost futures — close(drain=False) mid-window resolves every
   submitted generation, active or queued.
 """
+import contextlib
 import threading
 import time
+from unittest import mock
 
 import numpy as np
 import pytest
@@ -688,8 +690,30 @@ def _np_attention(q, ks, vs, scale=None):
     return np.einsum("ht,thd->hd", _np_softmax(sc), vs)
 
 
+@contextlib.contextmanager
+def _tpu_kernel_interpreted(block_bytes):
+    """Inside, `_cached_attention` takes the branch a lowering for the
+    TPU keeps — the Pallas kernel — run by Pallas's interpreter, with
+    blocks of `block_bytes` (a ring of test size would fit one).  Yields
+    the list of kernel calls traced."""
+    from mxnet_tpu.ops import attention
+
+    calls = []
+
+    def take_tpu(*operands, tpu, default):
+        calls.append(tpu)
+        return tpu(*operands)
+
+    # a trace made under an earlier patch would be served from the cache
+    attention._decode_attention.clear_cache()
+    with mock.patch.object(attention.lax, "platform_dependent", take_tpu), \
+            mock.patch.object(attention, "_BLOCK_BYTES", block_bytes), \
+            mock.patch.object(attention, "_INTERPRET", True):
+        yield calls
+
+
 def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2,
-               kv_heads=None, scale=None):
+               kv_heads=None, scale=None, kernel_block=None):
     """Drive `_kv_cache_write` + `_cached_attention` the way a serving
     session does and hold every step's context to `_np_attention` over
     the session's full sequence.
@@ -702,7 +726,13 @@ def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2,
     length 0 beside the live ones.  A later step reads what an earlier
     one wrote, so a row that lands anywhere but ``(slot, :, length)``
     shows.  The rings are only ever built from ``cache_spec`` and
-    handed from op to op: their axis order is the model's own."""
+    handed from op to op: their axis order is the model's own.
+
+    With `kernel_block` every step runs twice on the same rings: through
+    the TPU kernel in interpret mode, at that many positions a block,
+    and through the ``jax.numpy`` body — the kernel is held to numpy AND
+    to the body (same rings exactly, contexts to float32 rounding), and
+    its rings are the ones the next step gets."""
     rng = np.random.RandomState(seed)
     d = h * dh
     kv = h if kv_heads is None else kv_heads
@@ -733,9 +763,23 @@ def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2,
                           np.float32)
         q, k, v = (rng.randn(b, 1, width).astype(np.float32)
                    for width in (d, kv * dh, kv * dh))
-        ctx, kc, vc = mx.nd._cached_attention(
+        step = lambda: mx.nd._cached_attention(
             mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), kc, vc,
             mx.nd.array(slot), mx.nd.array(length), num_heads=h, **options)
+        if kernel_block:
+            body = [x.asnumpy() for x in step()]
+            with _tpu_kernel_interpreted(kv * dh * kernel_block * 4) as ran:
+                ctx, kc, vc = step()
+                got = ctx.asnumpy()       # traced and run inside the patch
+                kc, vc = mx.nd.array(kc.asnumpy()), mx.nd.array(vc.asnumpy())
+            assert len(ran) == 1
+            # padded rows all write the scratch slot: any of them may win
+            assert np.array_equal(kc.asnumpy()[:scratch], body[1][:scratch])
+            assert np.array_equal(vc.asnumpy()[:scratch], body[2][:scratch])
+            assert np.allclose(got[:live], body[0][:live], rtol=2e-6,
+                               atol=2e-6)
+        else:
+            ctx, kc, vc = step()
         assert kc.shape == shape and vc.shape == shape
         got = ctx.asnumpy()
         assert got.shape == (b, 1, d) and np.isfinite(got).all()
@@ -769,27 +813,60 @@ RING_CASES = {
 }
 
 
+# The TPU kernel (ops/kv_ring_kernel.py) in interpret mode: rings of 256
+# positions read in blocks of 128, so `lens` straddle the block edge
+_KERNEL = dict(max_len=256, kernel_block=128)
+RING_CASES.update({
+    # B = 1: the step writes the first block's last position (127), the
+    # next one the second block's first (128)
+    "kernel_one_row": dict(_KERNEL, slots=[2], lens=[127], padded=0),
+    # B = 8, every slot live and shuffled: empty, either side of the
+    # block edge, and a ring whose second step writes max_len - 1
+    "kernel_full_bucket_block_edges": dict(
+        _KERNEL, slots=[3, 0, 6, 1, 7, 4, 2, 5],
+        lens=[0, 126, 127, 128, 129, 254, 60, 200], padded=0),
+    # non-adjacent slots beside padded rows that share the scratch slot
+    "kernel_padded_rows_share_scratch": dict(
+        _KERNEL, slots=[2, 5], lens=[0, 130], padded=2),
+    # four query heads a ring head, a stated scale, padded rows
+    "kernel_grouped_query_heads": dict(
+        _KERNEL, h=8, kv_heads=2, scale=0.03, slots=[3, 1], lens=[127, 5],
+        padded=2),
+})
+
+
 @pytest.mark.parametrize("d_head", [64, 128])
 @pytest.mark.parametrize("case", sorted(RING_CASES))
 def test_ring_ops_match_full_sequence_attention(case, d_head):
     """`_cached_attention` + `_kv_cache_write` against plain numpy
     attention over each session's full sequence, two steps in a row on
     the returned rings, at both head widths the benchmark's decoders
-    have (64: OPT, Granite's attention layers; 128: OLMoE)."""
-    _ring_case(**dict(dict(h=2), **RING_CASES[case]), dh=d_head,
-               max_len=_MAX_LEN, seed=11)
+    have (64: OPT, Granite's attention layers; 128: OLMoE).  The
+    ``kernel_`` cases run the TPU's kernel (interpreted) beside the
+    ``jax.numpy`` body."""
+    _ring_case(**dict(dict(h=2, max_len=_MAX_LEN), **RING_CASES[case]),
+               dh=d_head, seed=11)
 
 
-def _walk_eqns(jaxpr):
+def _walk_eqns(jaxpr, platform="cpu"):
     """Every equation of `jaxpr` and of the jaxprs its equations carry
     (pjit bodies, custom_jvp calls); an equation that carries one is its
-    wrapper, not work of its own, and is not yielded."""
+    wrapper, not work of its own, and is not yielded.  Of a
+    ``lax.platform_dependent`` choice only the branch that a lowering
+    for `platform` keeps is walked."""
     for eqn in jaxpr.eqns:
         inner = [getattr(v, "jaxpr", v) for v in eqn.params.values()
                  if hasattr(getattr(v, "jaxpr", v), "eqns")]
+        if eqn.primitive.name == "pallas_call":
+            inner = []                    # a kernel is work of its own
+        chosen = eqn.params.get("branches_platforms")
+        if chosen:
+            index = [i for i, names in enumerate(chosen)
+                     if names is None or platform in names][0]
+            inner = [eqn.params["branches"][index].jaxpr]
         if inner:
             for j in inner:
-                yield from _walk_eqns(j)
+                yield from _walk_eqns(j, platform)
         else:
             yield eqn
 
@@ -797,18 +874,22 @@ def _walk_eqns(jaxpr):
 @pytest.mark.parametrize("bucket", [2, 4])
 def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
     """The invariant of PR 26, where the CPU can see it: in the jaxpr of
-    a small LM's decode program the ONLY equations that produce an array
-    of ``B x H x max_len x d_head`` elements or more are the rings' row
-    updates — B `dynamic_update_slice`s a ring — so there is no gathered
-    page batch, no scatter and no ring-sized temporary; and what reads a
-    ring is a `dynamic_slice` of ONE page (which XLA fuses into the
-    reduction that consumes it), never more.  chip_smoke.py holds the
-    compiled program to the same on the chip."""
+    a small LM's decode program, as a lowering for the CPU keeps it, the
+    ONLY equations that produce an array of ``B x H x max_len x d_head``
+    elements or more are the rings' row updates — B
+    `dynamic_update_slice`s a ring — so there is no gathered page batch,
+    no scatter and no ring-sized temporary; and what reads a ring is a
+    `dynamic_slice` of ONE page (which XLA fuses into the reduction that
+    consumes it), never more.  As a lowering for the TPU keeps it (PR
+    32), the rings are touched by ONE kernel call a layer and by nothing
+    else.  chip_smoke.py holds the compiled program to the same on the
+    chip."""
     import jax
 
-    # a ring long enough that one page outweighs every weight matrix
-    max_len = 128
-    lm, params = _lm_and_params(num_layers=2, num_heads=2, d_model=16,
+    # a ring long enough that one page outweighs every weight matrix,
+    # of a shape the TPU's kernel tiles (two heads of 64)
+    max_len = 1024
+    lm, params = _lm_and_params(num_layers=2, num_heads=2, d_model=128,
                                 max_len=max_len)
     gs = GenerativeSession("lm", lm, params, max_sessions=4,
                            max_len=max_len, seq_buckets=[8])
@@ -843,3 +924,66 @@ def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
     assert token_writes == bucket
     assert page_makers <= {"dynamic_slice", "squeeze", "reshape"}, \
         page_makers
+    on_tpu = [eqn for eqn in _walk_eqns(jaxpr.jaxpr, "tpu")
+              if any(tuple(out.aval.shape) == ring for out in eqn.outvars)]
+    assert [eqn.primitive.name for eqn in on_tpu] == \
+        ["pallas_call"] * lm.num_layers
+
+
+def test_decode_block_is_read_off_the_rings_shape_and_the_platform():
+    """`ops.attention.decode_block`: the largest multiple of 128
+    positions that divides the ring and keeps one block of all K/V heads
+    within 1 MiB of float32, where the TPU's kernel runs; None where the
+    ``jax.numpy`` body reads whole pages."""
+    from mxnet_tpu.ops.attention import decode_block
+
+    assert decode_block((9, 32, 64, 768), "tpu") == 128      # OPT
+    assert decode_block((9, 16, 128, 768), "tpu") == 128     # OLMoE
+    assert decode_block((9, 8, 64, 2304), "tpu") == 384      # Granite
+    assert decode_block((3, 2, 64, 256), "tpu") == 256
+    assert decode_block((9, 32, 64, 768), "cpu") is None
+    assert decode_block((5, 2, 8, 48), "tpu") is None        # no 128 divides
+    assert decode_block((5, 2, 24, 256), "tpu") is None      # 128 % d_head
+    assert decode_block((5, 1, 64, 256), "tpu") is None      # half a tile
+    assert decode_block((9, 64, 128, 768), "tpu") is None    # no block fits
+
+
+@pytest.mark.parametrize("block", [None, 128])
+def test_page_and_skipped_position_counters(block, monkeypatch):
+    """Per decode step `kv.page_positions` grows by ``max_len`` a packed
+    row and `kv.skipped_positions` by what the dispatched program's
+    attention does not read of those pages: nothing where it reads whole
+    pages (the CPU's program: `block` None), everything beyond the block
+    that holds `length` where it reads by blocks."""
+    from mxnet_tpu.ops import attention
+
+    max_len = 256
+    if block:  # what a session on a TPU is told; the counters are host side
+        monkeypatch.setattr(attention, "decode_block",
+                            lambda shape, platform, itemsize=4: block)
+    lm, params = _lm_and_params(max_len=max_len)
+    telemetry.set_enabled(True)
+    names = ("kv.page_positions", "kv.skipped_positions",
+             "kv.used_positions", "serving.decode.dispatches")
+    before = {n: telemetry.counter_value(n) for n in names}
+    gs = GenerativeSession("lm", lm, params, max_sessions=2, max_len=max_len,
+                           max_decode_tokens=16, seq_buckets=[8, 128])
+    try:
+        assert gs._ring_block == block
+        # lengths 5..9 stay in the first block; 126..130 cross into the
+        # second at the third of five steps
+        reqs = [GenerateRequest("lm", [1 + i % 20 for i in range(n)], 60.0, 6)
+                for n in (5, 126)]
+        _drive(gs, reqs)
+    finally:
+        gs.close()
+    moved = {n: telemetry.counter_value(n) - before[n] for n in names}
+    lengths = [5 + i for i in range(5)] + [126 + i for i in range(5)]
+    assert moved["serving.decode.dispatches"] == 5
+    assert moved["kv.used_positions"] == sum(lengths)
+    assert moved["kv.page_positions"] == len(lengths) * max_len
+    read = sum((n // block + 1) * block for n in lengths) if block else \
+        len(lengths) * max_len
+    assert moved["kv.skipped_positions"] == len(lengths) * max_len - read
+    assert moved["kv.skipped_positions"] == (
+        (5 * 128 + 2 * 128) if block else 0)
