@@ -46,25 +46,32 @@ more small output, ``moe_load (num_layers, num_experts)`` — tokens per
 expert in this call — which the batcher books as the ``moe.*`` counters.
 
 **Layer kinds.**  `layer_types` names each layer's MIXER — the half of
-the block before the FFN — ``"attention"`` (the default for every layer)
-or ``"mamba"`` (a Mamba-2 state-space mixer, ops/ssm.py).  A kind is one
-class below (:class:`_Attention`, :class:`_Mamba2`) that declares, in
-that one place, its parameters, its full-sequence forward, its prefill,
-its decode step and the device-resident state it keeps between calls;
-the four graph builders walk the pattern and know no kind by name.
+the block before the FFN — ``"attention"`` (the default for every layer),
+``"mamba"`` (a Mamba-2 state-space mixer, ops/ssm.py) or
+``"linear_attention"`` (a Gated DeltaNet delta-rule mixer, ops/gdn.py).
+A kind is one class below (:class:`_Attention`, :class:`_Mamba2`,
+:class:`_GatedDeltaNet`) that declares, in that one place, its
+parameters, its full-sequence forward, its prefill, its decode step, the
+device-resident state it keeps between calls and the counters a program
+call adds to; the four graph builders walk the pattern and know no kind
+by name.
 ``num_kv_heads`` (grouped-query attention), ``positions="none"``,
 ``ffn="swiglu"`` (a dense gated FFN), ``attention_multiplier`` and the
 three stream multipliers (``embedding_`` / ``residual_multiplier``,
 ``logits_scaling``) with ``layer_types`` of nine ``"mamba"`` to one
-``"attention"`` are Granite 4.0-H's.
+``"attention"`` are Granite 4.0-H's.  ``block_norm="output"`` (each
+branch's OUTPUT is normed before it joins the stream, ``h + norm(f(h))``,
+where every block before normed its input), ``qk_norm``,
+``positions="none"``, ``ffn="swiglu"``, an untied head and ``layer_types``
+of three ``"linear_attention"`` to one ``"attention"`` are Olmo-Hybrid's.
 
 **Cache spec.**  :meth:`TransformerLM.cache_spec` is the ONE statement of
 what a serving session holds on the device between calls: an ordered
 ``{name: CacheEntry(kind, shape)}`` over all layers — an attention layer's
 two KV rings (kind ``"ring"``, pages addressed by slot and masked by
-length, so stale contents are harmless) and a Mamba layer's conv window
-and recurrent state (kind ``"state"``, a fixed size a slot whatever the
-context, wholly rewritten by a prefill).  The serving graphs take and
+length, so stale contents are harmless) and a Mamba or Gated DeltaNet layer's
+conv window and recurrent state (kind ``"state"``, a fixed size a slot
+whatever the context, wholly rewritten by a prefill).  The serving graphs take and
 return exactly these names in this order; whoever allocates, sizes,
 charges or counts session state asks here.
 """
@@ -74,7 +81,7 @@ import math
 from typing import NamedTuple
 
 from .. import symbol as sym
-from ..ops import ssm as _ssm
+from ..ops import gdn as _gdn, ssm as _ssm
 
 __all__ = ["TransformerLM", "CacheEntry"]
 
@@ -199,50 +206,30 @@ class _Attention:
         return self._out(step[0], p, i), [step[1], step[2]]
 
 
-class _Mamba2:
-    """The Mamba-2 mixer of layer i (ops/ssm.py has the equations): input
-    projection ``[z | x | B | C | dt]``, causal conv + state-space scan +
-    gated RMSNorm in ONE op node a form, output projection; no projection
-    bias.  State: the conv window and the recurrent state."""
-
-    # the mixer's own small parameters, in the ops' operand order
-    SMALL = ("conv_weight", "conv_bias", "dt_bias", "A_log", "D",
-             "mnorm_gamma")
-
-    def __init__(self, lm):
-        self.lm = lm
-        self.sizes = (lm.mamba_heads, lm.mamba_head_dim, lm.mamba_state,
-                      lm.mamba_groups, lm.mamba_conv)
-        self.d_inner = lm.mamba_heads * lm.mamba_head_dim
-        self.conv_dim = self.d_inner + 2 * lm.mamba_groups * lm.mamba_state
-        self.d_proj = self.d_inner + self.conv_dim + lm.mamba_heads
-        self.attrs = dict(num_heads=lm.mamba_heads,
-                          head_dim=lm.mamba_head_dim,
-                          state_size=lm.mamba_state, n_groups=lm.mamba_groups,
-                          conv_kernel=lm.mamba_conv,
-                          chunk_size=lm.mamba_chunk, eps=lm.norm_eps)
+class _Recurrent:
+    """What the two recurrent kinds share: a fused input projection of
+    `d_proj` rows, ONE op node a form (``OPS``: full sequence, prefill,
+    decode step) that takes the projection and the mixer's `SMALL`
+    parameters (of `small_shapes`) and returns `d_inner` channels, an
+    output projection, no bias anywhere; and two kind-``"state"`` entries
+    a layer, ``conv_state_<i>`` and ``<STATE>_<i>`` (`state_shapes`: one
+    slot's), neither of which grows with `max_len`."""
 
     def params(self, i):
         v, d = sym.Variable, self.lm.d_model
         p = {"inproj_weight": v("l%d_inproj_weight" % i,
                                 shape=(self.d_proj, d))}
-        for n, shape in zip(self.SMALL, _ssm.param_shapes(*self.sizes)):
+        for n, shape in zip(self.SMALL, self.small_shapes):
             p[n] = v("l%d_%s" % (i, n), shape=shape)
         p["outproj_weight"] = v("l%d_outproj_weight" % i,
                                 shape=(d, self.d_inner))
         return p
 
     def cache_spec(self, i, slots, max_len):
-        """The conv window ``(slots, d_conv - 1, conv_dim)`` — channels on
-        the lanes; stored ``(conv_dim, d_conv - 1)`` a TPU tile would pad
-        the 3 taps to 128 — and the state ``(slots, heads, head_dim,
-        d_state)``; neither grows with `max_len`."""
-        lm = self.lm
-        return [("conv_state_%d" % i, CacheEntry(
-                    "state", (int(slots), lm.mamba_conv - 1, self.conv_dim))),
-                ("ssm_state_%d" % i, CacheEntry(
-                    "state", (int(slots), lm.mamba_heads, lm.mamba_head_dim,
-                              lm.mamba_state)))]
+        return [("%s_%d" % (name, i), CacheEntry("state",
+                                                 (int(slots),) + shape))
+                for name, shape in zip(("conv_state", self.STATE),
+                                       self.state_shapes)]
 
     def _in(self, x, p, i):
         proj = sym.FullyConnected(x, weight=p["inproj_weight"],
@@ -255,27 +242,100 @@ class _Mamba2:
                                   num_hidden=self.lm.d_model, no_bias=True,
                                   flatten=False, name="l%d_outproj" % i)
 
+    def _states(self, caches, i):
+        return [caches["conv_state_%d" % i],
+                caches["%s_%d" % (self.STATE, i)]]
+
     def full(self, x, p, i):
-        y = sym._ssm_scan(*self._in(x, p, i), name="l%d_ssm" % i,
-                          **self.attrs)
+        y = getattr(sym, self.OPS[0])(*self._in(x, p, i),
+                                      name="l%d_%s" % (i, self.NODE),
+                                      **self.attrs)
         return self._out(y, p, i)
 
     def prefill(self, x, p, i, caches, slot, length):
-        y = sym._ssm_prefill(
-            *self._in(x, p, i), caches["conv_state_%d" % i],
-            caches["ssm_state_%d" % i], slot, length, name="l%d_ssm" % i,
-            **self.attrs)
+        y = getattr(sym, self.OPS[1])(
+            *self._in(x, p, i), *self._states(caches, i), slot, length,
+            name="l%d_%s" % (i, self.NODE), **self.attrs)
         return self._out(y[0], p, i), [y[1], y[2]]
 
     def decode(self, x, p, i, caches, slot, length):
-        y = sym._ssm_step(
-            *self._in(x, p, i), caches["conv_state_%d" % i],
-            caches["ssm_state_%d" % i], slot, name="l%d_ssm" % i,
-            **self.attrs)
+        y = getattr(sym, self.OPS[2])(
+            *self._in(x, p, i), *self._states(caches, i), slot,
+            name="l%d_%s" % (i, self.NODE), **self.attrs)
         return self._out(y[0], p, i), [y[1], y[2]]
 
 
-_KINDS = {"attention": _Attention, "mamba": _Mamba2}
+class _Mamba2(_Recurrent):
+    """The Mamba-2 mixer of layer i (ops/ssm.py has the equations): input
+    projection ``[z | x | B | C | dt]``, causal conv + state-space scan +
+    gated RMSNorm in ONE op node a form, output projection.  State: the
+    conv window ``(slots, d_conv - 1, conv_dim)`` — channels on the
+    lanes; stored ``(conv_dim, d_conv - 1)`` a TPU tile would pad the 3
+    taps to 128 — and the recurrent state ``(slots, heads, head_dim,
+    d_state)``."""
+
+    OPS, NODE, STATE = ("_ssm_scan", "_ssm_prefill", "_ssm_step"), "ssm", \
+        "ssm_state"
+    # the mixer's own small parameters, in the ops' operand order
+    SMALL = ("conv_weight", "conv_bias", "dt_bias", "A_log", "D",
+             "mnorm_gamma")
+
+    def __init__(self, lm):
+        self.lm = lm
+        sizes = (lm.mamba_heads, lm.mamba_head_dim, lm.mamba_state,
+                 lm.mamba_groups, lm.mamba_conv)
+        self.small_shapes = _ssm.param_shapes(*sizes)
+        self.d_inner = lm.mamba_heads * lm.mamba_head_dim
+        conv_dim = self.d_inner + 2 * lm.mamba_groups * lm.mamba_state
+        self.d_proj = self.d_inner + conv_dim + lm.mamba_heads
+        self.state_shapes = ((lm.mamba_conv - 1, conv_dim), sizes[:3])
+        self.attrs = dict(num_heads=lm.mamba_heads,
+                          head_dim=lm.mamba_head_dim,
+                          state_size=lm.mamba_state, n_groups=lm.mamba_groups,
+                          conv_kernel=lm.mamba_conv,
+                          chunk_size=lm.mamba_chunk, eps=lm.norm_eps)
+
+
+class _GatedDeltaNet(_Recurrent):
+    """The Gated DeltaNet mixer of layer i (ops/gdn.py has the equations):
+    input projection ``[q | k | v | z | b | a]``, causal conv over ``[q | k
+    | v]`` + delta rule + gated per-head RMSNorm in ONE op node a form,
+    output projection.  State: the conv window ``(slots, taps - 1,
+    conv_dim)`` and the delta-rule state ``(slots, key_dim, heads *
+    value_dim)`` — the key axis leading, the heads' values side by side
+    on the lanes (ops/gdn.py: for heads of 96 x 192 a TPU tile then pads
+    nothing)."""
+
+    OPS, NODE, STATE = ("_gdn_scan", "_gdn_prefill", "_gdn_step"), "gdn", \
+        "gdn_state"
+    # the mixer's own small parameters, in the ops' operand order
+    SMALL = ("conv_weight", "dt_bias", "A_log", "gnorm_gamma")
+
+    def __init__(self, lm):
+        self.lm = lm
+        h, dk, dv = lm.linear_heads, lm.linear_key_dim, lm.linear_value_dim
+        self.small_shapes = _gdn.param_shapes(h, dk, dv, lm.linear_conv)
+        self.d_inner = h * dv
+        conv_dim = h * (2 * dk + dv)
+        self.d_proj = conv_dim + self.d_inner + 2 * h
+        self.state_shapes = ((lm.linear_conv - 1, conv_dim),
+                             (dk, self.d_inner))
+        self.attrs = dict(num_heads=h, key_dim=dk, value_dim=dv,
+                          conv_kernel=lm.linear_conv,
+                          chunk_size=lm.linear_chunk,
+                          neg_eigval=lm.linear_neg_eigval, eps=lm.norm_eps)
+
+    def counters(self, i, positions, rows):
+        """What one program call adds: the bucket positions a prefill
+        scans in this layer (the pad included), and the bytes of window
+        and state a decode step's `rows` rows read and write."""
+        page = sum(e.nbytes for _, e in self.cache_spec(i, 1, 0))
+        return {"gdn.scan_positions": positions,
+                "gdn.state_bytes": 2 * rows * page}
+
+
+_KINDS = {"attention": _Attention, "mamba": _Mamba2,
+          "linear_attention": _GatedDeltaNet}
 
 
 class TransformerLM:
@@ -298,8 +358,10 @@ class TransformerLM:
     the head its own ``head_weight (vocab, d_model)``.
 
     Further choices (defaults: as if absent): `layer_types` — one mixer
-    kind a layer, ``"attention"`` | ``"mamba"`` (module docstring;
-    default all attention); `num_kv_heads` K/V heads shared by groups of
+    kind a layer, ``"attention"`` | ``"mamba"`` | ``"linear_attention"``
+    (module docstring; default all attention); `block_norm` ``"input"``
+    (``h + f(norm(h))``) | ``"output"`` (``h + norm(f(h))``), for both
+    halves of every block; `num_kv_heads` K/V heads shared by groups of
     query heads; `positions` ``"none"`` (no position signal at all);
     `ffn` ``"relu"`` | ``"swiglu"`` (``W_out(silu(a) * b)``, ``[a | b]`` one
     fused ``(2 d_ff, d_model)`` projection); `embedding_multiplier` scales
@@ -308,7 +370,10 @@ class TransformerLM:
     `logits_scaling` divides the logits; the Mamba-2 mixer's sizes
     `mamba_heads` x `mamba_head_dim` (its inner width), `mamba_state`,
     `mamba_groups`, `mamba_conv` taps and the prefill scan's
-    `mamba_chunk`."""
+    `mamba_chunk`; the Gated DeltaNet mixer's `linear_heads` heads of
+    `linear_key_dim` x `linear_value_dim`, `linear_conv` taps, the chunk
+    `linear_chunk` of its full-sequence form and `linear_neg_eigval`
+    (``beta`` reaches 2)."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
                  d_ff=None, max_len=64, dropout=0.0, norm="layer",
@@ -319,7 +384,9 @@ class TransformerLM:
                  residual_multiplier=1.0, attention_multiplier=None,
                  logits_scaling=1.0, mamba_heads=0, mamba_head_dim=0,
                  mamba_state=0, mamba_groups=1, mamba_conv=4,
-                 mamba_chunk=256):
+                 mamba_chunk=256, block_norm="input", linear_heads=0,
+                 linear_key_dim=0, linear_value_dim=0, linear_conv=4,
+                 linear_chunk=64, linear_neg_eigval=True):
         if d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
@@ -333,6 +400,9 @@ class TransformerLM:
                              % (experts_per_token, num_experts))
         if ffn not in ("relu", "swiglu"):
             raise ValueError("ffn must be 'relu' or 'swiglu', got %r" % ffn)
+        if block_norm not in ("input", "output"):
+            raise ValueError("block_norm must be 'input' or 'output', got %r"
+                             % block_norm)
         num_kv_heads = num_heads if num_kv_heads is None else int(num_kv_heads)
         if num_kv_heads < 1 or num_heads % num_kv_heads:
             raise ValueError("num_heads=%d not a multiple of num_kv_heads=%d"
@@ -349,6 +419,12 @@ class TransformerLM:
             raise ValueError("a 'mamba' layer needs mamba_heads, "
                              "mamba_head_dim, mamba_state >= 1, mamba_heads a "
                              "multiple of mamba_groups and mamba_conv >= 2")
+        if "linear_attention" in layer_types and (
+                min(linear_heads, linear_key_dim, linear_value_dim) < 1
+                or linear_conv < 2):
+            raise ValueError("a 'linear_attention' layer needs linear_heads, "
+                             "linear_key_dim, linear_value_dim >= 1 and "
+                             "linear_conv >= 2")
         self.vocab = int(vocab)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -377,6 +453,12 @@ class TransformerLM:
         self.mamba_heads, self.mamba_head_dim = int(mamba_heads), int(mamba_head_dim)
         self.mamba_state, self.mamba_groups = int(mamba_state), int(mamba_groups)
         self.mamba_conv, self.mamba_chunk = int(mamba_conv), int(mamba_chunk)
+        self.block_norm = block_norm
+        self.linear_heads = int(linear_heads)
+        self.linear_key_dim = int(linear_key_dim)
+        self.linear_value_dim = int(linear_value_dim)
+        self.linear_conv, self.linear_chunk = int(linear_conv), int(linear_chunk)
+        self.linear_neg_eigval = bool(linear_neg_eigval)
         kinds = {k: _KINDS[k](self) for k in set(layer_types)}
         self._mixers = [kinds[k] for k in layer_types]
 
@@ -430,6 +512,15 @@ class TransformerLM:
                 p["ffn2_bias"] = v("l%d_ffn2_bias" % i, shape=(d,))
         return p
 
+    def _branch_in(self, h, name):
+        """What a branch (mixer or FFN) reads of the stream `h`: its norm
+        `name`, or — where the block norms the branch's output — `h`."""
+        return self._norm(h, name) if self.block_norm == "input" else h
+
+    def _branch_out(self, y, name):
+        """What a branch adds to the stream: `y`, or its norm `name`."""
+        return y if self.block_norm == "input" else self._norm(y, name)
+
     def _join(self, h, branch):
         """The residual stream plus a branch (times `residual_multiplier`)."""
         if self.residual_multiplier != 1.0:
@@ -440,7 +531,7 @@ class TransformerLM:
         """The block's second half on the residual stream `h`.  A routed
         model's serving graphs pass `loads`, which collects each layer's
         tokens-per-expert output."""
-        x = self._norm(h, "l%d_ln2" % i)
+        x = self._branch_in(h, "l%d_ln2" % i)
         if self.num_experts:
             f = sym.MoE(x, p["router_weight"], p["gate_weight"],
                         p["down_weight"], p["up_weight"],
@@ -462,14 +553,15 @@ class TransformerLM:
                 self._linear(x, p, "ffn1", self.d_ff, "l%d_ffn1" % i),
                 act_type="relu", name="l%d_gelu" % i)
             f = self._linear(f, p, "ffn2", self.d_model, "l%d_ffn2" % i)
+        f = self._branch_out(f, "l%d_ln2" % i)
         if train and self.dropout > 0:
             f = sym.Dropout(f, p=self.dropout, name="l%d_drop" % i)
         return self._join(h, f)
 
     def _block_train(self, h, i, train):
         p = self._block_params(i)
-        x = self._norm(h, "l%d_ln1" % i)
-        a = self._mixers[i].full(x, p, i)
+        x = self._branch_in(h, "l%d_ln1" % i)
+        a = self._branch_out(self._mixers[i].full(x, p, i), "l%d_ln1" % i)
         if train and self.dropout > 0:
             a = sym.Dropout(a, p=self.dropout, name="l%d_adrop" % i)
         h = self._join(h, a)
@@ -586,6 +678,19 @@ class TransformerLM:
             spec.update(mixer.cache_spec(i, slots, max_len))
         return spec
 
+    def call_counters(self, positions=0, rows=0):
+        """The telemetry counters that ONE serving program call adds to
+        beyond the session's own, ``{name: increment}`` summed over the
+        layers whose kind declares any: a prefill of a bucket of
+        `positions`, or a decode step of `rows` real rows.  The session
+        books them at dispatch."""
+        total = {}
+        for i, mixer in enumerate(self._mixers):
+            if hasattr(mixer, "counters"):
+                for name, n in mixer.counters(i, positions, rows).items():
+                    total[name] = total.get(name, 0) + n
+        return total
+
     def _cache_vars(self):
         return {n: sym.Variable(n) for n in self.cache_spec(1)}
 
@@ -604,10 +709,10 @@ class TransformerLM:
         outs, loads = [], [] if self.num_experts else None
         for i, mixer in enumerate(self._mixers):
             p = self._block_params(i)
-            x = self._norm(h, "l%d_ln1" % i)
+            x = self._branch_in(h, "l%d_ln1" % i)
             y, state = mixer.prefill(x, p, i, caches, slot, length)
             outs += state
-            h = self._join(h, y)
+            h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
             h = self._ffn(h, p, i, train=False, loads=loads)
         h = self._norm(h, "ln_f")
         # logits at the prompt's true tail, not the pad
@@ -632,10 +737,10 @@ class TransformerLM:
         outs, loads = [], [] if self.num_experts else None
         for i, mixer in enumerate(self._mixers):
             p = self._block_params(i)
-            x = self._norm(h, "l%d_ln1" % i)
+            x = self._branch_in(h, "l%d_ln1" % i)
             y, state = mixer.decode(x, p, i, caches, slot, length)
             outs += state
-            h = self._join(h, y)
+            h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
             h = self._ffn(h, p, i, train=False, loads=loads)
         h = self._norm(h, "ln_f")
         flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")

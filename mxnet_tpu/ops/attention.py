@@ -30,8 +30,9 @@ needs four graph-level primitives beyond the classic registry:
   *On a TPU* a decode step's attention is ONE kernel a layer
   (``ops/kv_ring_kernel.py``, Pallas) wherever the ring's shape gives it
   a block (``decode_block``: the largest multiple of 128 positions that
-  divides ``max_len`` and keeps ``H_kv * d_head * block`` floats within
-  1 MiB).  It reads each packed row's page block by block ONLY AS FAR AS
+  divides ``max_len`` and keeps ``heads * d_head * block`` floats within
+  1 MiB, `heads` being all K/V heads or, for a ring too wide for that,
+  ``decode_heads``' group of whole heads).  It reads each packed row's page block by block ONLY AS FAR AS
   the block that holds position ``length`` — the blocks beyond are not
   fetched — with an online softmax over the blocks, puts the new row
   into that last block while it is in fast memory and sends the block
@@ -268,26 +269,39 @@ _LANES = 128
 _BLOCK_BYTES = 1 << 20
 
 
+def decode_heads(ring_shape, itemsize=4):
+    """K/V heads of a ring ``(slots, H_kv, d_head, max_len)`` that one
+    block of the TPU kernel holds: ALL of them wherever 128 positions of
+    all heads are within 1 MiB; for a wider ring the most heads that
+    divide ``H_kv``, fit, and fill whole tiles of 128 lines (heads are
+    independent in a decode step; the kernel's grid walks the groups).
+    None for a ring the kernel's tiling does not divide: a ``d_head``
+    under 8 or that does not divide 128, or no such group of heads."""
+    _, h_kv, d_head, _ = ring_shape
+    if d_head < 8 or _LANES % d_head:
+        return None
+    return next((h for h in range(h_kv, 0, -1)
+                 if h_kv % h == 0 and h * d_head % _LANES == 0
+                 and h * d_head * _LANES * itemsize <= _BLOCK_BYTES), None)
+
+
 def decode_block(ring_shape, platform, itemsize=4):
     """Positions of a page that one step of the TPU kernel holds in fast
     memory, for a ring ``(slots, H_kv, d_head, max_len)``: the largest
-    multiple of 128 that divides ``max_len`` and keeps a block ``(H_kv,
-    d_head, block)`` within 1 MiB.  None where ``_cached_attention``
-    runs its ``jax.numpy`` body and reads whole pages: off the TPU, or
-    for a ring the kernel's tiling does not divide (``max_len`` not a
-    multiple of 128; a ``d_head`` under 8 or that does not divide 128;
-    heads that do not fill whole tiles of 128 lines).  The decode
+    multiple of 128 that divides ``max_len`` and keeps a block
+    ``(decode_heads, d_head, block)`` within 1 MiB.  None where
+    ``_cached_attention`` runs its ``jax.numpy`` body and reads whole
+    pages: off the TPU, or for a ring the kernel's tiling does not divide
+    (``max_len`` not a multiple of 128; no ``decode_heads``).  The decode
     program reads ``length // block + 1`` blocks of a row's page —
     whoever counts what a step reads (serving/decode.py) asks here."""
-    _, h_kv, d_head, max_len = ring_shape
-    tiled = (d_head >= 8 and _LANES % d_head == 0
-             and h_kv * d_head % _LANES == 0 and max_len % _LANES == 0)
-    if platform != "tpu" or not tiled:
+    _, _, d_head, max_len = ring_shape
+    heads = decode_heads(ring_shape, itemsize)
+    if platform != "tpu" or heads is None or max_len % _LANES:
         return None
-    fits = [blk for blk in range(_LANES, max_len + 1, _LANES)
-            if max_len % blk == 0
-            and h_kv * d_head * blk * itemsize <= _BLOCK_BYTES]
-    return max(fits, default=None)
+    return max(blk for blk in range(_LANES, max_len + 1, _LANES)
+               if max_len % blk == 0
+               and heads * d_head * blk * itemsize <= _BLOCK_BYTES)
 
 
 def _page(cache, slot_i):
@@ -340,12 +354,14 @@ def _ring_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i,
 _INTERPRET = False
 
 
-@functools.partial(jax.jit, static_argnames=("block", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block", "heads", "scale",
+                                             "interpret"))
 def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
-                      block, scale, interpret):
+                      block, heads, scale, interpret):
     """The decode step against the rings on whatever platform the
-    program is lowered for: the TPU's kernel (with `block` positions a
-    step; `interpret` runs it in Pallas's interpreter, for tests) or the
+    program is lowered for: the TPU's kernel (with `block` positions of
+    `heads` K/V heads a step; `interpret` runs it in Pallas's
+    interpreter, for tests) or the
     ``jax.numpy`` body.  Jitted, so that the layers of a decode program,
     whose attention is one and the same, trace and lower both once."""
     operands = (q, k_new, v_new, k_cache, v_cache, slot_i, len_i)
@@ -356,8 +372,8 @@ def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
     def kernel(*operands):
         from .kv_ring_kernel import ring_attention
 
-        return ring_attention(*operands, block=block, scale=scale,
-                              interpret=interpret)
+        return ring_attention(*operands, block=block, heads=heads,
+                              scale=scale, interpret=interpret)
     return lax.platform_dependent(*operands, tpu=kernel, default=body)
 
 
@@ -406,7 +422,8 @@ def cached_attention(query, key, value, k_cache, v_cache, slot, length,
         _as_index(length), scale=scale, interpret=_INTERPRET,
         # the block a lowering for the TPU would use; which platform the
         # program is lowered for is not known here
-        block=decode_block(k_cache.shape, "tpu", k_cache.dtype.itemsize))
+        block=decode_block(k_cache.shape, "tpu", k_cache.dtype.itemsize),
+        heads=decode_heads(k_cache.shape, k_cache.dtype.itemsize))
     return ctx.reshape(b, 1, d), kc, vc
 
 

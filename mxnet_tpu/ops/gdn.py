@@ -1,0 +1,313 @@
+"""Gated DeltaNet — the delta-rule linear-attention mixer (Yang, Kautz &
+Hatamizadeh, arXiv:2412.06464; the delta rule in chunks: Yang et al.,
+arXiv:2406.06484) in the three forms a hybrid decoder needs.
+
+All three take the layer's fused input projection ``data (N, T, 2 H d_k
++ 2 H d_v + 2 H)`` laid out ``[q | k | v | z | b | a]`` (H heads; q and k
+of `key_dim` d_k, v and the gate z of `value_dim` d_v, one ``b`` and one
+``a`` a head) and the mixer's small parameters, and return the gated,
+normalized ``y (N, T, H d_v)`` the output projection consumes.  Per head:
+
+    [q | k | v] = silu(causal_depthwise_conv1d(q | k | v))      no bias
+    q = q / ||q|| / sqrt(d_k);   k = k / ||k||
+    beta = beta_scale * sigmoid(b)            2 admits negative eigenvalues
+    alpha = exp(-exp(A_log) * softplus(a + dt_bias))
+    S_t = alpha_t S_{t-1} + beta_t (v_t - alpha_t S_{t-1} k_t) k_t^T
+    o_t = S_t q_t                                      S is (d_v, d_k)
+    y   = RMSNorm_{d_v}(o) * norm_gamma * silu(z)      gamma shared by heads
+
+Where Mamba-2's state only decays and accumulates, this one is multiplied
+by ``alpha (I - beta k k^T)`` at every position: a write first takes out
+what the state already answers for its key.
+
+* ``_gdn_scan`` — a whole sequence from an empty state: training and
+  scoring, in the chunked WY / UT form.  Inside a chunk of `chunk_size`
+  positions the rule's corrections form a unit lower-triangular system
+  in ``beta``, ``K K^T`` and the cumulative decays; it is SOLVED once a
+  chunk (``(I + A) [U | W] = beta [V | K Gamma]``), after which a chunk's
+  output is an in-chunk product plus the carried state's part, and one
+  state is carried from chunk to chunk.
+* ``_gdn_prefill`` — the same over a PADDED sequence bucket, for serving:
+  positions at and beyond ``length`` get ``alpha = 1, beta = 0``, so the
+  state passes through them; the conv window (the last ``K - 1`` raw
+  ``[q | k | v]`` rows before ``length``, zeros before the sequence) and
+  the final state are written WHOLE at ``slot``.
+* ``_gdn_step`` — one position for B packed decode rows: each row's page
+  is read where it lies, advanced and written back by one
+  ``dynamic_update_slice`` (ops/ssm.py ``_ssm_step``'s discipline).  Both
+  products with the old state — ``S k`` for the correction and ``S q``
+  for the output, ``o = alpha S q + beta (v - alpha S k)(k . q)`` — are
+  reductions of ONE read of the page; the update is a second pass.
+
+Stored shapes belong to the model (``TransformerLM.cache_spec``): a conv
+window ``(slots, K - 1, 2 H d_k + H d_v)`` and a state ``(slots, d_k, H *
+d_v)`` — the KEY axis leading and every head's values side by side on the
+lanes, so that for heads of 96 x 192 a TPU tile pads nothing (5,760 = 45
+x 128 lanes, 96 = 12 x 8 sublanes; stored ``(H, d_v, d_k)`` each line of
+96 would take 128) and ``S k`` is a sum of whole vectors.
+
+Precision: everything after the projection is float32, the chunk's
+solve and products at ``highest`` (ops/ssm.py says why: at one bfloat16
+pass the carried state rounds like a bf16 recurrence), the step's
+products multiply-adds on the vector unit.  Pure ``jax.numpy`` / ``lax``,
+differentiable.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax import lax, nn as jnn
+from jax.scipy.linalg import solve_triangular
+
+from .attention import _as_index
+from .ssm import _conv_full
+from .registry import register
+from .tensor import _bool, _lit
+
+_HIGHEST = lax.Precision.HIGHEST
+_L2_EPS = 1e-6        # inside the root of q's and k's norm
+PARAMS = ("conv_weight", "dt_bias", "A_log", "norm_gamma")
+_ATTRS = dict(num_heads=1, key_dim=1, value_dim=1, conv_kernel=4,
+              chunk_size=64, neg_eigval=True, eps=1e-6)
+
+
+def _attrs(kw):
+    return {k: kw.get(k, v) for k, v in _ATTRS.items()}
+
+
+def _sizes(attrs):
+    """(heads H, key width d_k, value width d_v, conv taps K) of a node."""
+    return tuple(int(_lit(attrs.get(k, _ATTRS[k]))) for k in (
+        "num_heads", "key_dim", "value_dim", "conv_kernel"))
+
+
+def param_shapes(heads, key_dim, value_dim, kernel):
+    """Shapes of the mixer's own parameters, in `PARAMS` order."""
+    conv_dim = heads * (2 * key_dim + value_dim)
+    return [(kernel, conv_dim), (heads,), (heads,), (value_dim,)]
+
+
+def _infer(in_shapes, attrs, n_state=0):
+    h, dk, dv, k = _sizes(attrs)
+    data = in_shapes[0]
+    out = tuple(data[:-1]) + (h * dv,)
+    ins = [data] + param_shapes(h, dk, dv, k)
+    states = list(in_shapes[len(ins):len(ins) + n_state])
+    return ins + states + list(in_shapes[len(ins) + n_state:]), \
+        [out] + states
+
+
+def _split(data, h, dk, dv):
+    """``[q k v | z | b | a]`` of the fused projection, in float32."""
+    conv_dim = h * (2 * dk + dv)
+    data = data.astype(jnp.float32)
+    z_end = conv_dim + h * dv
+    return (data[..., :conv_dim], data[..., conv_dim:z_end],
+            data[..., z_end:z_end + h], data[..., z_end + h:])
+
+
+def _heads(qkv, h, dk, dv):
+    """The conv's output split into normalized ``q``, ``k (..., H, d_k)``
+    and ``v (..., H, d_v)``."""
+    lead = qkv.shape[:-1]
+    q = qkv[..., :h * dk].reshape(lead + (h, dk))
+    k = qkv[..., h * dk:2 * h * dk].reshape(lead + (h, dk))
+    v = qkv[..., 2 * h * dk:].reshape(lead + (h, dv))
+    q, k = (x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
+            for x in (q, k))
+    return q * dk ** -0.5, k, v
+
+
+def _gates(b, a, dt_bias, a_log, attrs):
+    """``(beta, log alpha)``, each ``(..., H)``."""
+    beta = jnn.sigmoid(b) * (2.0 if _bool(attrs["neg_eigval"]) else 1.0)
+    return beta, -jnp.exp(a_log.astype(jnp.float32)) * jnn.softplus(
+        a + dt_bias)
+
+
+def _gated_norm(o, z, gamma, eps):
+    """``RMSNorm(o) * gamma * silu(z)`` over each head's `d_v`."""
+    o = o * lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + eps)
+    return o * gamma * jnn.silu(z)
+
+
+def _chunked(q, k, v, beta, g, chunk):
+    """The delta rule in chunks from an empty state.  ``q`` / ``k (N, T,
+    H, d_k)`` normalized, ``v (N, T, H, d_v)``, ``beta`` / ``g (N, T, H)``
+    (``g`` the log decay; 0 and ``beta`` 0 where a position must not
+    count).  Returns ``(o (N, T, H, d_v), final state (N, H, d_k,
+    d_v))``."""
+    n, t, h, dk = q.shape
+    dv = v.shape[-1]
+    size = min(int(chunk), t)
+    pad = -t % size
+    if pad:  # beta = 0, g = 0 there: the state passes through
+        q, k, v, beta, g = (
+            jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, beta, g))
+    nc = (t + pad) // size
+
+    def chunks(x):  # (N, T, H, ...) -> (N, nc, H, L, ...)
+        x = x.reshape((n, nc, size, h) + x.shape[3:])
+        return jnp.moveaxis(x, 3, 2)
+
+    q, k, v, beta, g = (chunks(x) for x in (q, k, v, beta, g))
+    cum = jnp.cumsum(g, axis=-1)                       # inclusive, (.., L)
+    seg = cum[..., :, None] - cum[..., None, :]        # (.., L, L): l - m
+    lower = jnp.tril(jnp.ones((size, size), bool))
+    decay = jnp.exp(jnp.where(lower, seg, -jnp.inf))   # m <= l
+    kb = k * beta[..., None]
+    # (I + A)[U | W] = [beta V | beta K Gamma], A strictly lower: what each
+    # position writes once the positions before it in the chunk have
+    a = jnp.einsum("nchld,nchmd->nchlm", kb, k, precision=_HIGHEST) * decay
+    rhs = jnp.concatenate(
+        [v * beta[..., None], kb * jnp.exp(cum)[..., None]], axis=-1)
+    solved = solve_triangular(a, rhs, lower=True, unit_diagonal=True)
+    u, w = solved[..., :dv], solved[..., dv:]
+    qk = jnp.einsum("nchld,nchmd->nchlm", q, k, precision=_HIGHEST) * decay
+    q_in = q * jnp.exp(cum)[..., None]                 # reads the carried
+    k_out = k * jnp.exp(cum[..., -1:] - cum)[..., None]  # decayed to the end
+    through = jnp.exp(cum[..., -1])                    # (N, nc, H)
+
+    def carry(state, xs):  # state (N, H, d_k, d_v), one chunk
+        u_c, w_c, qk_c, q_c, k_c, thr = xs
+        v_new = u_c - jnp.einsum("nhld,nhdv->nhlv", w_c, state,
+                                 precision=_HIGHEST)
+        o = (jnp.einsum("nhld,nhdv->nhlv", q_c, state, precision=_HIGHEST)
+             + jnp.einsum("nhlm,nhmv->nhlv", qk_c, v_new,
+                          precision=_HIGHEST))
+        state = (state * thr[..., None, None]
+                 + jnp.einsum("nhld,nhlv->nhdv", k_c, v_new,
+                              precision=_HIGHEST))
+        return state, o
+
+    final, o = lax.scan(
+        carry, jnp.zeros((n, h, dk, dv), jnp.float32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (u, w, qk, q_in, k_out, through)))
+    o = jnp.moveaxis(o, (0, 2), (1, 3))                # (N, nc, L, H, d_v)
+    return o.reshape(n, nc * size, h, dv)[:, :t], final
+
+
+def _mix(data, conv_weight, dt_bias, a_log, norm_gamma, attrs, length=None):
+    """Conv, chunked rule and gated norm of whole sequences; positions at
+    and beyond ``length (N,)`` leave the state untouched.  Returns ``(y,
+    raw [q | k | v], final state (N, H, d_k, d_v))``."""
+    h, dk, dv, _ = _sizes(attrs)
+    raw, z, b, a = _split(data, h, dk, dv)
+    q, k, v = _heads(_conv_full(raw, conv_weight, 0.0), h, dk, dv)
+    beta, g = _gates(b, a, dt_bias, a_log, attrs)
+    if length is not None:
+        live = (jnp.arange(data.shape[1])[None, :] < length[:, None])[..., None]
+        beta, g = jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
+    o, final = _chunked(q, k, v, beta, g, _lit(attrs["chunk_size"]))
+    y = _gated_norm(o, z.reshape(o.shape), norm_gamma,
+                    float(_lit(attrs["eps"])))
+    return (y.reshape(data.shape[:2] + (h * dv,)).astype(data.dtype), raw,
+            final)
+
+
+@register("_gdn_scan", inputs=("data",) + PARAMS, infer_shape=_infer)
+def gdn_scan(data, conv_weight, dt_bias, A_log, norm_gamma, **kw):
+    """The Gated DeltaNet mixer over whole sequences ``data (N, T,
+    d_proj)`` from an empty state (module docstring); returns ``y (N, T,
+    H d_v)``."""
+    with jax.named_scope("mx:gdn.scan"):
+        return _mix(data, conv_weight, dt_bias, A_log, norm_gamma,
+                    _attrs(kw))[0]
+
+
+def _infer_stateful(in_shapes, attrs):
+    return _infer(in_shapes, attrs, n_state=2)
+
+
+def _stored(state):
+    """``(H, d_k, d_v)`` of one row as the session stores it, ``(d_k, H *
+    d_v)``."""
+    h, dk, dv = state.shape
+    return state.transpose(1, 0, 2).reshape(dk, h * dv)
+
+
+@register("_gdn_prefill",
+          inputs=("data",) + PARAMS + ("conv_state", "gdn_state", "slot",
+                                       "length"),
+          num_outputs=3, infer_shape=_infer_stateful)
+def gdn_prefill(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
+                gdn_state, slot, length, **kw):
+    """Serving prefill of the mixer: ``data (N, T, d_proj)`` padded to a
+    bucket, ``length (N,)`` the true lengths.  Outputs ``y``, and the two
+    state buffers with row n's conv window and final state — both as of
+    position ``length[n]``, the pad not counted — written at
+    ``slot[n]``."""
+    attrs = _attrs(kw)
+    k = _sizes(attrs)[3]
+    slot_i, len_i = _as_index(slot), _as_index(length)
+    with jax.named_scope("mx:gdn.scan"):
+        y, raw, final = _mix(data, conv_weight, dt_bias, A_log, norm_gamma,
+                             attrs, length=len_i)
+        padded = jnp.pad(raw, ((0, 0), (k - 1, 0), (0, 0)))
+        for n in range(data.shape[0]):
+            # padded row i is raw row i - (K-1): the window ending at length
+            window = lax.dynamic_slice_in_dim(padded[n], len_i[n], k - 1, 0)
+            conv_state = lax.dynamic_update_slice(
+                conv_state, window[None].astype(conv_state.dtype),
+                (slot_i[n], 0, 0))
+            gdn_state = lax.dynamic_update_slice(
+                gdn_state, _stored(final[n])[None].astype(gdn_state.dtype),
+                (slot_i[n], 0, 0))
+    return y, conv_state, gdn_state
+
+
+@register("_gdn_step",
+          inputs=("data",) + PARAMS + ("conv_state", "gdn_state", "slot"),
+          num_outputs=3, infer_shape=_infer_stateful)
+def gdn_step(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
+             gdn_state, slot, **kw):
+    """One decode step of the mixer for B packed rows: ``data (B, 1,
+    d_proj)``, row b's window and state at ``slot[b]``.  The rows are
+    advanced in row order, each page read where it lies, stepped and
+    written back with one ``dynamic_update_slice`` (padded rows all land
+    on the scratch slot, one after the other).  Outputs ``y (B, 1, H
+    d_v)`` and the two updated buffers."""
+    attrs = _attrs(kw)
+    h, dk, dv, _ = _sizes(attrs)
+    rows = data.shape[0]
+    slot_i = _as_index(slot)
+    with jax.named_scope("mx:gdn.step"):
+        raw, z, b, a = _split(data[:, 0], h, dk, dv)
+        window = jnp.concatenate(
+            [jnp.stack([lax.dynamic_index_in_dim(conv_state, slot_i[i], 0,
+                                                 keepdims=False)
+                        for i in range(rows)]).astype(jnp.float32),
+             raw[:, None]], axis=1)                       # (B, K, C)
+        q, k, v = _heads(jnn.silu((window * conv_weight).sum(axis=1)),
+                         h, dk, dv)
+        beta, g = _gates(b, a, dt_bias, A_log, attrs)     # (B, H)
+        alpha = jnp.exp(g)
+        kq = jnp.sum(k * q, axis=-1)                      # (B, H)
+        # along the stored state's lanes, (head, value): a head's scalar
+        # repeated over its values; along its leading axis, the key
+        wide = lambda x: jnp.repeat(x, dv, axis=-1)       # (B, H) -> (B, H dv)
+        keyed = lambda x: x.transpose(0, 2, 1)[..., None]  # (B, d_k, H, 1)
+        k_s, q_s = (jnp.broadcast_to(keyed(x), (rows, dk, h, dv))
+                    .reshape(rows, dk, h * dv) for x in (k, q))
+        out = []
+        for i in range(rows):
+            page = lax.dynamic_index_in_dim(gdn_state, slot_i[i], 0,
+                                            keepdims=False).astype(jnp.float32)
+            a_i = wide(alpha[i])
+            s_k = (page * k_s[i]).sum(axis=0) * a_i       # alpha S k
+            s_q = (page * q_s[i]).sum(axis=0) * a_i       # alpha S q
+            write = wide(beta[i]) * (v[i].reshape(-1) - s_k)
+            out.append(s_q + write * wide(kq[i]))
+            gdn_state = lax.dynamic_update_slice(
+                gdn_state,
+                (page * a_i + k_s[i] * write)[None].astype(gdn_state.dtype),
+                (slot_i[i], 0, 0))
+            conv_state = lax.dynamic_update_slice(
+                conv_state, window[i, 1:][None].astype(conv_state.dtype),
+                (slot_i[i], 0, 0))
+        o = jnp.stack(out).reshape(rows, h, dv)
+        y = _gated_norm(o, z.reshape(o.shape), norm_gamma,
+                        float(_lit(attrs["eps"])))
+    return (y.reshape(rows, 1, h * dv).astype(data.dtype), conv_state,
+            gdn_state)

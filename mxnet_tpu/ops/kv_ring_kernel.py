@@ -5,10 +5,13 @@ and each packed row reads its page only as far as it is filled.
 The ring is stored ``(slots, H_kv, d_head, max_len)`` — positions on the
 lanes (``TransformerLM.cache_spec`` owns the order) — which is the layout
 the kernel's operands have by default, so nothing is copied or padded on
-the way in.  A grid step ``(b, i)`` holds block `i` (`block` positions:
-``ops.attention.decode_block``) of row `b`'s page in VMEM, brought there
-by the pipeline from ``(slot[b], :, :, i * block)``; slot and length are
-scalar-prefetched, and for `i` beyond the block that holds position
+the way in.  A grid step ``(b, j, i)`` holds block `i` (`block` positions:
+``ops.attention.decode_block``) of head group `j` (`heads` K/V heads:
+``ops.attention.decode_heads`` — all of them, ONE group, wherever 128
+positions of all heads fit a block; heads are independent in a decode
+step, so a wider ring is cut into groups of whole heads) of row `b`'s
+page in VMEM, brought there by the pipeline from ``(slot[b], j * heads, :,
+i * block)``; slot and length are scalar-prefetched, and for `i` beyond the block that holds position
 ``length[b]`` the index map repeats that block, which the pipeline does
 not fetch again, and the body does nothing: the blocks beyond are
 SKIPPED, not masked.
@@ -53,7 +56,8 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
             o_ref, ko_hbm, vo_hbm,
             qb_ref, s_ref, m_ref, l_ref, a_ref, acc_ref, sem,
             *, h_kv, groups, d_head, blk, scale):
-    b, i = pl.program_id(0), pl.program_id(1)
+    # h_kv: the K/V heads of THIS grid step's head group
+    b, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     h_q = h_kv * groups
     chunks = blk // _LANE
     per = _LANE // d_head                 # heads a tile of 128 lines
@@ -61,9 +65,9 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
     last = length // blk
 
     def column(heads_ref, h):
-        """Head `h` of a row's ``(1, d_head, H)`` operand, each value
+        """Head `h` of a row's ``(1, 1, d_head, H)`` operand, each value
         broadcast along the lanes of its own d_head line."""
-        return jnp.broadcast_to(heads_ref[0, :, pl.ds(h, 1)],
+        return jnp.broadcast_to(heads_ref[0, 0, :, pl.ds(h, 1)],
                                 (d_head, _LANE))
 
     def unrolled(n, body):
@@ -103,7 +107,7 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
         for n, (block, ring) in enumerate(((k_ref, ko_hbm), (v_ref, vo_hbm))):
             pltpu.make_async_copy(
                 block.at[0, :, :, chunk],
-                ring.at[slot_ref[b], :, :,
+                ring.at[slot_ref[b], pl.ds(j * h_kv, h_kv), :,
                         pl.ds(pl.multiple_of(length // _LANE * _LANE, _LANE),
                               _LANE)],
                 sem.at[n]).start()
@@ -173,50 +177,64 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
         for n, ring in enumerate((ko_hbm, vo_hbm)):
             pltpu.make_async_copy(  # the wait needs the shapes only
                 k_ref.at[0, :, :, pl.ds(0, _LANE)],
-                ring.at[0, :, :, pl.ds(0, _LANE)], sem.at[n]).wait()
+                ring.at[0, pl.ds(0, h_kv), :, pl.ds(0, _LANE)],
+                sem.at[n]).wait()
 
 
 def ring_attention(q, k_new, v_new, k_cache, v_cache, slot, length, *,
-                   block, scale=None, interpret=False):
+                   block, heads=None, scale=None, interpret=False):
     """``q (B, H_q, d)``, ``k_new`` / ``v_new (B, H_kv, d)``, rings
     ``(slots, H_kv, d, max_len)``, ``slot`` / ``length (B,)`` int32 →
     ``(context (B, H_q, d), k_cache', v_cache')`` with the rings updated
-    in place where the caller donates them.  `block` positions a grid
-    step (``ops.attention.decode_block``, which also says for which
-    rings the kernel's tiling holds: ``d_head`` divides 128, the heads
-    fill whole 128-line tiles); `interpret` runs Pallas's interpreter.
+    in place where the caller donates them.  `block` positions and
+    `heads` K/V heads (default all) a grid step
+    (``ops.attention.decode_block`` / ``decode_heads``, which also say
+    for which rings the kernel's tiling holds: ``d_head`` divides 128,
+    a group's heads fill whole 128-line tiles); `interpret` runs
+    Pallas's interpreter.
     The caller jits (``ops.attention._decode_attention``): the layers of
     a decode program share one trace and one lowering of this."""
     bsz, h_q, d_head = q.shape
     slots, h_kv, _, max_len = k_cache.shape
     blk = int(block)
     nblk = max_len // blk
+    groups = h_q // h_kv
+    held = h_kv if heads is None else int(heads)   # K/V heads a grid step
+    parts = h_kv // held
+    hq = held * groups                             # query heads a grid step
 
-    def page(b, i, slot_r, len_r):
-        return slot_r[b], 0, 0, jnp.minimum(i, len_r[b] // blk)
+    def page(b, j, i, slot_r, len_r):
+        return slot_r[b], j, 0, jnp.minimum(i, len_r[b] // blk)
 
-    def row(b, i, slot_r, len_r):
-        return b, 0, 0
+    def row(b, j, i, slot_r, len_r):
+        return b, j, 0, 0
 
-    heads = lambda n: pl.BlockSpec((1, d_head, n), row)
-    ring = pl.BlockSpec((1, h_kv, d_head, blk), page)
+    def columns(x):
+        """``(B, H, d)`` → ``(B, parts, d, H / parts)``: a head group's
+        heads side by side, one column each."""
+        return x.reshape(bsz, parts, -1, d_head).transpose(0, 1, 3, 2)
+
+    cols = lambda n: pl.BlockSpec((1, 1, d_head, n), row)
+    ring = pl.BlockSpec((1, held, d_head, blk), page)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     f32 = jnp.float32
     ctx, kc, vc = pl.pallas_call(
-        functools.partial(_kernel, h_kv=h_kv, groups=h_q // h_kv,
+        functools.partial(_kernel, h_kv=held, groups=groups,
                           d_head=d_head, blk=blk, scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(bsz, nblk),
-            in_specs=[heads(h_q), heads(h_kv), heads(h_kv), ring, ring],
-            out_specs=[pl.BlockSpec((1, 1, h_q * d_head), row), hbm, hbm],
+            grid=(bsz, parts, nblk),
+            in_specs=[cols(hq), cols(held), cols(held), ring, ring],
+            out_specs=[pl.BlockSpec((1, 1, hq * d_head),
+                                    lambda b, j, i, slot_r, len_r: (b, 0, j)),
+                       hbm, hbm],
             scratch_shapes=[
-                pltpu.VMEM((h_q, d_head, _LANE), f32),    # q columns
-                pltpu.VMEM((blk // _LANE, h_q, _LANE), f32),  # scores, probs
-                pltpu.VMEM((h_q, _LANE), f32),            # running max
-                pltpu.VMEM((h_q, _LANE), f32),            # running sum
-                pltpu.VMEM((h_q, _LANE), f32),            # rescale
-                pltpu.VMEM((h_q, d_head, _LANE), f32),    # context
+                pltpu.VMEM((hq, d_head, _LANE), f32),     # q columns
+                pltpu.VMEM((blk // _LANE, hq, _LANE), f32),  # scores, probs
+                pltpu.VMEM((hq, _LANE), f32),             # running max
+                pltpu.VMEM((hq, _LANE), f32),             # running sum
+                pltpu.VMEM((hq, _LANE), f32),             # rescale
+                pltpu.VMEM((hq, d_head, _LANE), f32),     # context
                 pltpu.SemaphoreType.DMA((2,)),
             ]),
         out_shape=[jax.ShapeDtypeStruct((bsz, 1, h_q * d_head), q.dtype),
@@ -226,10 +244,10 @@ def ring_attention(q, k_new, v_new, k_cache, v_cache, slot, length, *,
         # (operands count the two prefetched scalars)
         input_output_aliases={5: 1, 6: 2},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
             vmem_limit_bytes=32 << 20),
         name="kv_ring_attention",
         interpret=interpret,
-    )(slot, length, q.transpose(0, 2, 1), k_new.transpose(0, 2, 1),
-      v_new.transpose(0, 2, 1), k_cache, v_cache)
+    )(slot, length, columns(q), columns(k_new), columns(v_new), k_cache,
+      v_cache)
     return ctx.reshape(bsz, h_q, d_head), kc, vc

@@ -230,6 +230,8 @@ class GenerativeSession:
         # a routed model's programs end with tokens per (layer, expert)
         self._reports_moe_load = "moe_load" in tuple(
             getattr(model, "extra_outputs", tuple)())
+        # counters the model's layer kinds declare for a program call
+        self._call_counters = getattr(model, "call_counters", None)
         # every call threads the cache entries, then each slot's last token
         self._input_names = (["data", "slot", "length"] + list(self._spec)
                              + ["last_token"])
@@ -491,6 +493,16 @@ class GenerativeSession:
         if dropped and telemetry.enabled():
             telemetry.inc("serving.decode.dropped_rows", dropped)
 
+    def _book_call(self, **call):
+        """The counters the model's layer kinds add for one program call
+        (``call_counters(positions=...)`` of a prefill bucket, ``(rows=
+        ...)`` of a decode step)."""
+        from .. import telemetry
+
+        if telemetry.enabled() and self._call_counters is not None:
+            for name, n in self._call_counters(**call).items():
+                telemetry.inc(name, n)
+
     @staticmethod
     def _book_moe_load(load):
         """The `moe.*` counters of one program call from its `moe_load
@@ -556,6 +568,7 @@ class GenerativeSession:
             # past the prompt's end: computed and thrown away
             telemetry.inc("serving.prefill.bucket_positions", bucket)
             telemetry.inc("serving.prefill.pad_positions", bucket - n)
+            self._book_call(positions=bucket)
             self._note_occupancy()
 
     def _note_occupancy(self):
@@ -646,6 +659,7 @@ class GenerativeSession:
             sets = 1 + len(self._programs)
             telemetry.inc("cache.reserved_bytes", sets * self._cache_bytes)
             telemetry.inc("cache.state_bytes", sets * self._state_bytes)
+            self._book_call(rows=n)
             if self._has_ring:
                 # position-steps: over a window their ratio is the mean
                 # reserved over used.  Reserved are a ring set's pages

@@ -32,7 +32,14 @@ TINY = {
     "kv_ring": {"vocab": 32, "num_layers": 2, "d_model": 32, "d_ff": 32,
                 "max_sessions": 2, "seq_buckets": [8], "seed": 5,
                 "shapes": [dict(num_heads=2, max_len=48),
-                           dict(num_heads=4, num_kv_heads=2, max_len=48)]},
+                           dict(num_heads=4, num_kv_heads=2, max_len=48),
+                           dict(num_heads=2, max_len=48,
+                                layer_types=["linear_attention",
+                                             "attention"],
+                                linear_heads=2, linear_key_dim=8,
+                                linear_value_dim=16, norm="rms",
+                                positions="none", bias=False,
+                                block_norm="output")]},
     "kernel": {"shapes": [(4, 4, 4, 64), (8, 2, 2, 256)], "seed": 3},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
@@ -72,10 +79,13 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     for name in ("fence", "train", "serve", "generate", "kv_ring"):
         chip_smoke.run_phase(name, getattr(chip_smoke, "phase_" + name),
                              TINY[name], ctx, clock, report)
-    # two layers' K and V rings of both shapes, found in the compiled
-    # decode programs; the CPU's programs hold no kernel call
-    assert report["kv_ring"]["ring_params"] == 8
-    assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48]]
+    # two layers' K and V rings of both attention-only shapes, then a
+    # delta-rule layer's window and state beside one layer's rings, found
+    # in the compiled decode programs; the CPU's programs hold no kernel
+    # call
+    assert report["kv_ring"]["ring_params"] == 8 + 4
+    assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
+                                          [3, 2, 16, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
     monkeypatch.setattr(pk, "_INTERPRET", True)
     chip_smoke.run_phase("kernel", chip_smoke.phase_kernel, TINY["kernel"],

@@ -756,6 +756,10 @@ COVERED_ELSEWHERE = {
     # prefill and the decode step against a float64 numpy recurrence run
     # one position at a time)
     "_ssm_scan", "_ssm_prefill", "_ssm_step",
+    # test_olmo_hybrid.py (ops/gdn.py: the chunked delta rule, the padded
+    # prefill and the decode step against a float64 numpy recurrence run
+    # one position at a time)
+    "_gdn_scan", "_gdn_prefill", "_gdn_step",
     # test_contrib_ops2.py
     "_contrib_fft", "_contrib_ifft", "_contrib_quantize",
     "_contrib_dequantize", "_contrib_count_sketch", "_contrib_Proposal",

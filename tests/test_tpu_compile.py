@@ -2,7 +2,7 @@
 not attached (the TPU's compiler is installed where the tests run): what
 Pallas's interpreter cannot see — Mosaic refusing a slice, a layout or
 the fast memory a kernel asks for — at the real widths of the
-benchmark's three decoders.  Nothing runs; a compile that passes is not
+benchmark's four decoders.  Nothing runs; a compile that passes is not
 a chip run.  All such compiles live in this ONE file: only one process a
 host may hold the TPU's library, and the topology is described inside a
 fixture so that every xdist worker collects the same tests."""
@@ -19,7 +19,11 @@ SLOTS = 9
 SHAPES = {"opt": (8, 32, 32, 64, 768, None),
           "olmoe": (8, 16, 16, 128, 768, None),
           "granite": (8, 32, 8, 64, 2304, 1 / 64),
+          "olmo_hybrid": (8, 30, 30, 128, 2304, None),
           "one_row": (1, 32, 32, 64, 768, None)}
+# positions and K/V heads a block, by the ring's bytes alone
+BLOCKS = {"opt": (128, 32), "olmoe": (128, 16), "granite": (384, 8),
+          "olmo_hybrid": (128, 15), "one_row": (128, 32)}
 
 
 @pytest.fixture(scope="module")
@@ -57,14 +61,16 @@ def test_the_decode_attention_compiles_for_a_v5e(name, one_chip):
     rows, h_q, h_kv, d_head, max_len, scale = SHAPES[name]
     ring = (SLOTS, h_kv, d_head, max_len)
     block = attention.decode_block(ring, "tpu")
-    assert block == {768: 128, 2304: 384}[max_len]
+    heads = attention.decode_heads(ring)
+    assert (block, heads) == BLOCKS[name]
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def step(*operands):
         return attention._decode_attention(*operands, block=block,
-                                           scale=scale, interpret=False)
+                                           heads=heads, scale=scale,
+                                           interpret=False)
 
     compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
         arg((rows, h_q, d_head)), arg((rows, h_kv, d_head)),

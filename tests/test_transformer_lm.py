@@ -713,7 +713,8 @@ def _tpu_kernel_interpreted(block_bytes):
 
 
 def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2,
-               kv_heads=None, scale=None, kernel_block=None):
+               kv_heads=None, scale=None, kernel_block=None,
+               kernel_heads=None):
     """Drive `_kv_cache_write` + `_cached_attention` the way a serving
     session does and hold every step's context to `_np_attention` over
     the session's full sequence.
@@ -732,7 +733,9 @@ def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2,
     the TPU kernel in interpret mode, at that many positions a block,
     and through the ``jax.numpy`` body — the kernel is held to numpy AND
     to the body (same rings exactly, contexts to float32 rounding), and
-    its rings are the ones the next step gets."""
+    its rings are the ones the next step gets.  `kernel_heads` K/V heads
+    are all a block may hold (default: every head), so the kernel's grid
+    walks ``kv / kernel_heads`` head groups."""
     rng = np.random.RandomState(seed)
     d = h * dh
     kv = h if kv_heads is None else kv_heads
@@ -768,7 +771,8 @@ def _ring_case(h, dh, max_len, slots, lens, padded, seed, steps=2,
             mx.nd.array(slot), mx.nd.array(length), num_heads=h, **options)
         if kernel_block:
             body = [x.asnumpy() for x in step()]
-            with _tpu_kernel_interpreted(kv * dh * kernel_block * 4) as ran:
+            with _tpu_kernel_interpreted(
+                    (kernel_heads or kv) * dh * kernel_block * 4) as ran:
                 ctx, kc, vc = step()
                 got = ctx.asnumpy()       # traced and run inside the patch
                 kc, vc = mx.nd.array(kc.asnumpy()), mx.nd.array(vc.asnumpy())
@@ -832,6 +836,12 @@ RING_CASES.update({
     "kernel_grouped_query_heads": dict(
         _KERNEL, h=8, kv_heads=2, scale=0.03, slots=[3, 1], lens=[127, 5],
         padded=2),
+    # a ring too wide for one block of all its heads (at the published
+    # sizes: 30 heads x 128 of 2,304 positions, 15 a block): six heads
+    # walked two at a time, groups of one, rows on either side of the edge
+    "kernel_head_groups": dict(
+        _KERNEL, h=6, kernel_heads=2, slots=[1, 4, 0], lens=[127, 3, 200],
+        padded=1),
 })
 
 
@@ -930,22 +940,32 @@ def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
         ["pallas_call"] * lm.num_layers
 
 
-def test_decode_block_is_read_off_the_rings_shape_and_the_platform():
-    """`ops.attention.decode_block`: the largest multiple of 128
-    positions that divides the ring and keeps one block of all K/V heads
-    within 1 MiB of float32, where the TPU's kernel runs; None where the
-    ``jax.numpy`` body reads whole pages."""
-    from mxnet_tpu.ops.attention import decode_block
+@pytest.mark.parametrize("ring,platform,block,heads", [
+    ((9, 32, 64, 768), "tpu", 128, 32),       # OPT: all heads, as before
+    ((9, 16, 128, 768), "tpu", 128, 16),      # OLMoE
+    ((9, 8, 64, 2304), "tpu", 384, 8),        # Granite
+    ((9, 30, 128, 2304), "tpu", 128, 15),     # Olmo-Hybrid: 1.875 MiB of heads
+    ((9, 64, 128, 768), "tpu", 128, 16),
+    ((3, 2, 64, 256), "tpu", 256, 2),
+    ((9, 32, 64, 768), "cpu", None, 32),
+    ((5, 2, 8, 48), "tpu", None, None),       # no 128 divides; 16 lines
+    ((5, 2, 24, 256), "tpu", None, None),     # 128 % d_head
+    ((5, 1, 64, 256), "tpu", None, None),     # half a tile
+    ((9, 3, 64, 256), "tpu", None, None),     # no group of whole tiles
+])
+def test_decode_block_is_read_off_the_rings_shape_and_the_platform(
+        ring, platform, block, heads):
+    """`ops.attention.decode_block` / `decode_heads`: the largest multiple
+    of 128 positions that divides the ring and keeps one block within
+    1 MiB of float32 — a block of ALL K/V heads wherever 128 positions of
+    them fit (the three rings before Olmo-Hybrid's pick what they picked),
+    else of the most whole heads that divide them and fill 128-line
+    tiles — where the TPU's kernel runs; None where the ``jax.numpy``
+    body reads whole pages.  Nothing but the ring's bytes is asked."""
+    from mxnet_tpu.ops.attention import decode_block, decode_heads
 
-    assert decode_block((9, 32, 64, 768), "tpu") == 128      # OPT
-    assert decode_block((9, 16, 128, 768), "tpu") == 128     # OLMoE
-    assert decode_block((9, 8, 64, 2304), "tpu") == 384      # Granite
-    assert decode_block((3, 2, 64, 256), "tpu") == 256
-    assert decode_block((9, 32, 64, 768), "cpu") is None
-    assert decode_block((5, 2, 8, 48), "tpu") is None        # no 128 divides
-    assert decode_block((5, 2, 24, 256), "tpu") is None      # 128 % d_head
-    assert decode_block((5, 1, 64, 256), "tpu") is None      # half a tile
-    assert decode_block((9, 64, 128, 768), "tpu") is None    # no block fits
+    assert decode_block(ring, platform) == block
+    assert decode_heads(ring) == heads
 
 
 @pytest.mark.parametrize("block", [None, 128])
