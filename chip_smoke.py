@@ -31,7 +31,9 @@ apart from the rest:
             entry aliased to its output, no instruction that copies one,
             ONE attention kernel call an attention layer, and no ring or
             recurrent state fatter on the device than cache_spec states;
-            prints the rings' on-device layout
+            prints the rings' on-device layout; and the 2,048-bucket
+            prefill of the fourth: ONE kernel call a delta-rule layer
+            (ops/gdn_kernel.py) and no triangular solve left in it
   kernel    ops/pallas_kernels.bn_stats under Mosaic at two ResNet-50
             shapes against the jnp reduction
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
@@ -74,6 +76,7 @@ FULL = {
                            # beside a ring of 30 heads x 128, which the
                            # kernel reads 15 heads at a time
                            dict(d_model=3840, num_heads=30, max_len=2304,
+                                seq_buckets=[64, 2048],
                                 layer_types=["linear_attention",
                                              "attention"],
                                 linear_heads=30, linear_key_dim=96,
@@ -470,6 +473,17 @@ def ring_hlo_facts(text, ring_shape):
             "kernel_calls": text.count('custom_call_target="tpu_custom_call"')}
 
 
+def delta_rule_hlo_facts(text):
+    """What a compiled prefill program makes of the delta rule's chunks,
+    read from its optimised HLO `text`: the triangular solves XLA left in
+    it (the op, or the custom calls a TPU expands it to) and the Pallas
+    kernel calls."""
+    return {"solves": len(re.findall(
+                r' triangular-solve\(|custom_call_target="[^"]*Triangular',
+                text)),
+            "kernel_calls": text.count('custom_call_target="tpu_custom_call"')}
+
+
 def phase_kv_ring(sizes, ctx):
     """The decode step touches a KV ring where it lies (PERF.md section
     6, PR 26 and PR 32): for each of `sizes["shapes"]` compile the
@@ -486,8 +500,10 @@ def phase_kv_ring(sizes, ctx):
     platform = ctx.jax_device().platform
     slots = sizes["max_sessions"]
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
-             "layouts": [], "rings": []}
+             "layouts": [], "rings": [], "delta_rule": []}
     for shape in sizes["shapes"]:
+        shape = dict(shape)
+        buckets = shape.pop("seq_buckets", sizes["seq_buckets"])
         lm = TransformerLM(**{**dict(vocab=sizes["vocab"],
                                      num_layers=sizes["num_layers"],
                                      d_model=sizes["d_model"],
@@ -514,7 +530,7 @@ def phase_kv_ring(sizes, ctx):
         try:
             session = server.add_generative_tenant(
                 "ring", lm, params, ctx=ctx, max_sessions=slots,
-                max_len=shape["max_len"], seq_buckets=sizes["seq_buckets"])
+                max_len=shape["max_len"], seq_buckets=buckets)
             server.warmup()
             _exe, fn = session._program(session._decode_pred, slots, 1,
                                         False)
@@ -526,6 +542,18 @@ def phase_kv_ring(sizes, ctx):
                 for key in ("ring_params", "aliased"):
                     facts[key] += more[key]
                 facts["copies"] += more["copies"]
+            # a model with delta-rule layers: its longest prefill bucket,
+            # as the warm-up compiled it
+            longest = max(buckets)
+            booked = lm.call_counters(positions=longest, platform=platform)
+            scanned = booked.get("gdn.scan_positions", 0) // longest
+            if scanned:
+                _exe, pre = session._program(session._prefill_pred, 1,
+                                             longest, True)
+                rule = dict(delta_rule_hlo_facts(pre.hlo_text()),
+                            bucket=longest, layers=scanned,
+                            kernel_layers=booked["gdn.kernel_positions"]
+                            // longest)
             # the live set as the warm-up's programs left it on the device
             held = [(n, e.nbytes, getattr(a, "on_device_size_in_bytes",
                                           lambda: e.nbytes)())
@@ -558,6 +586,20 @@ def phase_kv_ring(sizes, ctx):
             _check(facts["kernel_calls"] == ring_layers,
                    "%d attention kernel calls in a decode program of %d "
                    "attention layers" % (facts["kernel_calls"], ring_layers))
+        if scanned:
+            print("[chip_smoke] kv_ring: the %(bucket)d-bucket prefill of "
+                  "%(layers)d delta-rule layer(s): %(kernel_calls)d kernel "
+                  "call(s), %(solves)d triangular solve(s)" % rule,
+                  flush=True)
+            total["delta_rule"].append(rule)
+        if scanned and platform == "tpu":
+            _check(rule["kernel_layers"] == rule["kernel_calls"]
+                   == rule["layers"], "%(kernel_calls)d kernel calls in a "
+                   "prefill program of %(layers)d delta-rule layers, of "
+                   "which the shape function gives the kernel "
+                   "%(kernel_layers)d" % rule)
+            _check(not rule["solves"], "the prefill program still holds "
+                   "%(solves)d triangular solve(s)" % rule)
         for key in ("ring_params", "aliased", "kernel_calls"):
             total[key] += facts[key]
         total["copies"] += len(facts["copies"])

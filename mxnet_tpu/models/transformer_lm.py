@@ -325,12 +325,20 @@ class _GatedDeltaNet(_Recurrent):
                           chunk_size=lm.linear_chunk,
                           neg_eigval=lm.linear_neg_eigval, eps=lm.norm_eps)
 
-    def counters(self, i, positions, rows):
+    def counters(self, i, positions, rows, platform):
         """What one program call adds: the bucket positions a prefill
-        scans in this layer (the pad included), and the bytes of window
-        and state a decode step's `rows` rows read and write."""
+        scans in this layer (the pad included), those of them that a
+        program lowered for `platform` runs through the TPU's kernel
+        (all, or none where ``ops.gdn.chunk_heads`` says the ``jax.numpy``
+        body runs), and the bytes of window and state a decode step's
+        `rows` rows read and write."""
         page = sum(e.nbytes for _, e in self.cache_spec(i, 1, 0))
+        lm = self.lm
+        tiled = _gdn.chunk_heads(
+            (1, positions, lm.linear_heads, lm.linear_key_dim),
+            lm.linear_value_dim, lm.linear_chunk, platform) is not None
         return {"gdn.scan_positions": positions,
+                "gdn.kernel_positions": positions * tiled,
                 "gdn.state_bytes": 2 * rows * page}
 
 
@@ -678,16 +686,18 @@ class TransformerLM:
             spec.update(mixer.cache_spec(i, slots, max_len))
         return spec
 
-    def call_counters(self, positions=0, rows=0):
+    def call_counters(self, positions=0, rows=0, platform=None):
         """The telemetry counters that ONE serving program call adds to
         beyond the session's own, ``{name: increment}`` summed over the
         layers whose kind declares any: a prefill of a bucket of
-        `positions`, or a decode step of `rows` real rows.  The session
+        `positions`, or a decode step of `rows` real rows, of a program
+        lowered for `platform` (the session's device's).  The session
         books them at dispatch."""
         total = {}
         for i, mixer in enumerate(self._mixers):
             if hasattr(mixer, "counters"):
-                for name, n in mixer.counters(i, positions, rows).items():
+                for name, n in mixer.counters(i, positions, rows,
+                                               platform).items():
                     total[name] = total.get(name, 0) + n
         return total
 

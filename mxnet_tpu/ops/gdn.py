@@ -31,7 +31,17 @@ what the state already answers for its key.
   positions at and beyond ``length`` get ``alpha = 1, beta = 0``, so the
   state passes through them; the conv window (the last ``K - 1`` raw
   ``[q | k | v]`` rows before ``length``, zeros before the sequence) and
-  the final state are written WHOLE at ``slot``.
+  the final state are written WHOLE at ``slot``.  On a TPU its chunked
+  rule — everything between the L2 norms and the gated norm — is ONE
+  Pallas kernel a layer (``ops/gdn_kernel.py``: a chunk's solve and
+  products stay in VMEM, the state is carried there and comes out as it
+  is stored) wherever ``chunk_heads`` says the kernel tiles the shape;
+  ``lax.platform_dependent`` chooses at lowering, and `_chunked` below
+  is what runs everywhere else and the kernel's oracle.  The kernel is
+  lowered once a shape for all processes (`_exported_kernel`: a
+  ``jax.export`` kept beside JAX's compiled programs), so that a warm
+  start neither imports Pallas nor traces the kernel for its first
+  prefill.
 * ``_gdn_step`` — one position for B packed decode rows: each row's page
   is read where it lies, advanced and written back by one
   ``dynamic_update_slice`` (ops/ssm.py ``_ssm_step``'s discipline).  Both
@@ -49,17 +59,24 @@ x 128 lanes, 96 = 12 x 8 sublanes; stored ``(H, d_v, d_k)`` each line of
 Precision: everything after the projection is float32, the chunk's
 solve and products at ``highest`` (ops/ssm.py says why: at one bfloat16
 pass the carried state rounds like a bf16 recurrence), the step's
-products multiply-adds on the vector unit.  Pure ``jax.numpy`` / ``lax``,
-differentiable.
+products multiply-adds on the vector unit.  ``_gdn_scan`` and ``_gdn_step``
+are pure ``jax.numpy`` / ``lax``, and so is ``_gdn_prefill`` off the TPU;
+``_gdn_scan`` is differentiable (the kernel has no backward and is not on
+its path).
 """
 from __future__ import annotations
 
+import functools
+import hashlib
+import os
+
 import jax
 import jax.numpy as jnp
+import jaxlib
 from jax import lax, nn as jnn
 from jax.scipy.linalg import solve_triangular
 
-from .attention import _as_index
+from .attention import _LANES, _as_index
 from .ssm import _conv_full
 from .registry import register
 from .tensor import _bool, _lit
@@ -188,18 +205,153 @@ def _chunked(q, k, v, beta, g, chunk):
     return o.reshape(n, nc * size, h, dv)[:, :t], final
 
 
+def _stored(state):
+    """``(N, H, d_k, d_v)`` as the session stores it, ``(N, d_k, H *
+    d_v)``."""
+    n, h, dk, dv = state.shape
+    return state.transpose(0, 2, 1, 3).reshape(n, dk, h * dv)
+
+
+def _stored_chunked(q, k, v, beta, g, chunk):
+    """`_chunked` with the final state as the session stores it."""
+    o, final = _chunked(q, k, v, beta, g, chunk)
+    return o, _stored(final)
+
+
+_SUBLANES = 8
+_WALK_BYTES = 4 << 20
+_VMEM_BYTES = 24 << 20
+
+
+def chunk_heads(shape, value_dim, chunk, platform):
+    """Heads that one step of the TPU kernel's walk over a chunk takes
+    (``ops/gdn_kernel.py``), for ``q`` / ``k`` of `shape` ``(N, T, H,
+    d_k)``, values of `value_dim` and chunks of `chunk` positions: the
+    most heads that divide ``H`` and whose operands and products of one
+    chunk are within 4 MiB — an even number where one fits (the kernel
+    solves two heads' systems side by side).  None where `_gdn_prefill`
+    runs its ``jax.numpy`` body: off the TPU, or for a shape the kernel's
+    tiling does not divide — ``T`` no whole number of chunks, a chunk that
+    is no whole number of 8-row tiles, or a chunk of all heads (the
+    pipeline's two buffers an operand, the head-leading copies and the
+    state) beyond 24 MiB of the 32 MiB of VMEM the kernel asks for (it asks
+    for no more: what a kernel may use, XLA may not keep activations in
+    across it).  Whoever counts what a prefill runs
+    (``TransformerLM.call_counters``) asks here."""
+    _, t, h, dk = shape
+    size = min(int(chunk), t)
+    if platform != "tpu" or not size or t % size or size % _SUBLANES:
+        return None
+    pad = lambda width: -(-width // _LANES) * _LANES
+    dv = int(value_dim)
+    piped = 2 * size * (2 * h * dk + 2 * h * dv + 2 * pad(h)) + 2 * dk * h * dv
+    turned = (h * (2 * size * (pad(dk) + pad(dv)) + dk * pad(dv))
+              + 2 * h * pad(size))
+    if 4 * (piped + turned) > _VMEM_BYTES:
+        return None
+    a_head = 4 * (size * (3 * pad(dk) + 4 * pad(dv) + 6 * pad(size))
+                  + dk * pad(dv))
+    fit = [n for n in range(1, h + 1)
+           if h % n == 0 and (n == 1 or n * a_head <= _WALK_BYTES)]
+    return max(fit, key=lambda n: (n % 2 == 0, n))
+
+
+# tests flip this to have `_gdn_prefill` run the TPU's kernel in Pallas
+# interpret mode on the CPU
+_INTERPRET = False
+
+_EXPORTED = {}   # (operand shapes, chunk, heads) -> jax.export.Exported
+
+
+def _exported_kernel(shapes, chunk, heads):
+    """The TPU kernel for float32 operands of `shapes`, lowered ONCE a
+    shape for all processes: a ``jax.export.Exported`` kept beside JAX's
+    compiled programs (``jax_compilation_cache_dir``) under a name made of
+    the kernel's source, the JAX versions and the shapes.  A serving
+    process traces a prefill first; calling the exported kernel there
+    needs neither Pallas (1.3 s of import that nothing hides: PERF.md
+    section 6, PR 34) nor a trace and a lowering of the kernel a bucket
+    (0.2 s each), so a warm start pays for neither, as it pays for no
+    compile.  A file that is missing, stale or unreadable is made anew."""
+    key = (shapes, chunk, heads)
+    if key in _EXPORTED:
+        return _EXPORTED[key]
+    from jax import export
+
+    with open(os.path.join(os.path.dirname(__file__), "gdn_kernel.py"),
+              "rb") as f:
+        stamp = hashlib.sha1(f.read() + repr(
+            (key, jax.__version__, jaxlib.__version__)).encode()).hexdigest()
+    folder = jax.config.jax_compilation_cache_dir
+    path = folder and os.path.join(folder, "mx-gdn-kernel-%s.export" % stamp)
+    try:
+        with open(path, "rb") as f:
+            _EXPORTED[key] = export.deserialize(bytearray(f.read()))
+        return _EXPORTED[key]
+    except Exception:  # no cache, no file, or not a whole one
+        pass
+    from .gdn_kernel import chunked_delta_rule
+
+    exported = _EXPORTED[key] = export.export(
+        jax.jit(functools.partial(chunked_delta_rule, chunk=chunk,
+                                  heads=heads)), platforms=("tpu",))(
+        *(jax.ShapeDtypeStruct(shape, jnp.float32) for shape in shapes))
+    try:
+        os.makedirs(folder, exist_ok=True)
+        with open("%s.%d" % (path, os.getpid()), "wb") as f:
+            f.write(exported.serialize())
+        os.replace(f.name, path)
+    except (OSError, TypeError):  # no folder to keep it in
+        pass
+    return exported
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
+def _delta_rule(q, k, v, beta, g, *, chunk, heads, interpret):
+    """`_chunked` on whatever platform the program is lowered for — the
+    TPU's kernel (walking `heads` heads at a time; `interpret` runs it in
+    Pallas's interpreter, for tests) or the ``jax.numpy`` body — with the
+    final state as the session stores it.  Jitted, so that the delta-rule
+    layers of a prefill program, whose rule is one and the same, trace
+    and lower both once."""
+    operands = (q, k, v, beta, g)
+    body = functools.partial(_stored_chunked, chunk=chunk)
+    if heads is None:
+        return body(*operands)
+
+    def kernel(*operands):
+        if interpret:
+            from .gdn_kernel import chunked_delta_rule
+
+            return chunked_delta_rule(*operands, chunk=chunk, heads=heads,
+                                      interpret=True)
+        return tuple(_exported_kernel(
+            tuple(x.shape for x in operands), chunk, heads).call(*operands))
+    return lax.platform_dependent(*operands, tpu=kernel, default=body)
+
+
 def _mix(data, conv_weight, dt_bias, a_log, norm_gamma, attrs, length=None):
     """Conv, chunked rule and gated norm of whole sequences; positions at
-    and beyond ``length (N,)`` leave the state untouched.  Returns ``(y,
-    raw [q | k | v], final state (N, H, d_k, d_v))``."""
+    and beyond ``length (N,)`` leave the state untouched (the serving
+    prefill: its rule may run as the TPU's kernel, `_delta_rule`; without
+    `length`, training and scoring, it is the differentiable body).
+    Returns ``(y, raw [q | k | v], final state (N, d_k, H * d_v) as a
+    session stores it)``."""
     h, dk, dv, _ = _sizes(attrs)
     raw, z, b, a = _split(data, h, dk, dv)
     q, k, v = _heads(_conv_full(raw, conv_weight, 0.0), h, dk, dv)
     beta, g = _gates(b, a, dt_bias, a_log, attrs)
-    if length is not None:
+    chunk = int(_lit(attrs["chunk_size"]))
+    if length is None:
+        o, final = _stored_chunked(q, k, v, beta, g, chunk)
+    else:
         live = (jnp.arange(data.shape[1])[None, :] < length[:, None])[..., None]
         beta, g = jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
-    o, final = _chunked(q, k, v, beta, g, _lit(attrs["chunk_size"]))
+        # the heads a lowering for the TPU would walk at a time; which
+        # platform the program is lowered for is not known here
+        o, final = _delta_rule(
+            q, k, v, beta, g, chunk=chunk, interpret=_INTERPRET,
+            heads=chunk_heads(q.shape, dv, chunk, "tpu"))
     y = _gated_norm(o, z.reshape(o.shape), norm_gamma,
                     float(_lit(attrs["eps"])))
     return (y.reshape(data.shape[:2] + (h * dv,)).astype(data.dtype), raw,
@@ -218,13 +370,6 @@ def gdn_scan(data, conv_weight, dt_bias, A_log, norm_gamma, **kw):
 
 def _infer_stateful(in_shapes, attrs):
     return _infer(in_shapes, attrs, n_state=2)
-
-
-def _stored(state):
-    """``(H, d_k, d_v)`` of one row as the session stores it, ``(d_k, H *
-    d_v)``."""
-    h, dk, dv = state.shape
-    return state.transpose(1, 0, 2).reshape(dk, h * dv)
 
 
 @register("_gdn_prefill",
@@ -252,7 +397,7 @@ def gdn_prefill(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
                 conv_state, window[None].astype(conv_state.dtype),
                 (slot_i[n], 0, 0))
             gdn_state = lax.dynamic_update_slice(
-                gdn_state, _stored(final[n])[None].astype(gdn_state.dtype),
+                gdn_state, final[n][None].astype(gdn_state.dtype),
                 (slot_i[n], 0, 0))
     return y, conv_state, gdn_state
 

@@ -260,12 +260,14 @@ class GenerativeSession:
         # attention reads at a time, where it stops at the block that
         # holds `length`; None where it reads whole pages (the CPU)
         self._ring_block = None
+        # the platform the programs are lowered for: what the layer kinds'
+        # counters and the ring's block are asked with
+        self._platform = self._decode_pred._ctx.jax_device().platform
         if self._has_ring:
             from ..ops.attention import decode_block
 
             ring = next(e for e in self._spec.values() if e.kind == "ring")
-            self._ring_block = decode_block(
-                ring.shape, self._decode_pred._ctx.jax_device().platform)
+            self._ring_block = decode_block(ring.shape, self._platform)
             if self._ring_block:
                 # the decode programs will lower the kernel: importing
                 # Pallas is over a second of Python, spent here beside
@@ -496,11 +498,13 @@ class GenerativeSession:
     def _book_call(self, **call):
         """The counters the model's layer kinds add for one program call
         (``call_counters(positions=...)`` of a prefill bucket, ``(rows=
-        ...)`` of a decode step)."""
+        ...)`` of a decode step), told the platform its programs are
+        lowered for."""
         from .. import telemetry
 
         if telemetry.enabled() and self._call_counters is not None:
-            for name, n in self._call_counters(**call).items():
+            for name, n in self._call_counters(platform=self._platform,
+                                               **call).items():
                 telemetry.inc(name, n)
 
     @staticmethod

@@ -122,7 +122,12 @@ def kvstore_check(mx, np, rank):
     got = out.asnumpy()
     assert np.allclose(got, expect), (got.ravel()[:4], expect)
     kv.close()
-    print("KVOK rank=%d sum=%.1f" % (rank, float(got.ravel()[0])))
+    # ONE write, line end included: the ranks share the launcher's pipe,
+    # and `print` writes its text and its newline apart, between which the
+    # other rank's line can land (seen under tier-1's load, PR 34)
+    sys.stdout.write("KVOK rank=%d sum=%.1f\n"
+                     % (rank, float(got.ravel()[0])))
+    sys.stdout.flush()
 
 
 def main():

@@ -87,6 +87,11 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
                                           [3, 2, 16, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
+    # the third shape's longest prefill bucket, read for the delta rule:
+    # one such layer, no kernel in a program lowered for the CPU
+    assert report["kv_ring"]["delta_rule"] == [
+        {"solves": 0, "kernel_calls": 0, "bucket": 8, "layers": 1,
+         "kernel_layers": 0}]
     monkeypatch.setattr(pk, "_INTERPRET", True)
     chip_smoke.run_phase("kernel", chip_smoke.phase_kernel, TINY["kernel"],
                          ctx, clock, report)
@@ -99,6 +104,21 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     assert clock.seconds > 0  # the AOT wrapper's compiles are seen
     assert report["train"]["mfu_gauge"] is None  # CPU: no peak, no MFU
     assert telemetry.counter_value("mem.program_fallbacks") == fallbacks
+
+
+def test_the_delta_rule_facts_count_solves_and_kernel_calls():
+    """`delta_rule_hlo_facts` on the three ways a chunk's solve shows in
+    optimised HLO: the op, the custom calls a TPU expands it to, and the
+    Pallas kernel that replaces it."""
+    kernel = ('%x = (f32[1,64,384]{2,1,0}, f32[1,16,384]{2,1,0}) custom-call('
+              '%q), custom_call_target="tpu_custom_call"\n')
+    assert chip_smoke.delta_rule_hlo_facts(3 * kernel) == {
+        "solves": 0, "kernel_calls": 3}
+    old = ('%t = f32[8,8]{1,0} triangular-solve(%a, %b), left_side=true\n'
+           '%i = f32[1,32,30,1,64,64]{5,4,3,2,1,0} custom-call(%a), '
+           'custom_call_target="InvertDiagBlocksLowerTriangular"\n')
+    assert chip_smoke.delta_rule_hlo_facts(old + kernel) == {
+        "solves": 2, "kernel_calls": 1}
 
 
 def test_a_failing_phase_propagates():

@@ -569,12 +569,15 @@ def test_admission_charges_the_specs_bytes(held, monkeypatch):
 
 def test_the_delta_rule_counters(held):
     """Per prefill `gdn.scan_positions` grows by the bucket (the pad
-    included) times the linear layers; per decode dispatch
-    `gdn.state_bytes` by the window and state of each real row, read and
-    written, times the linear layers — the kind declares both
-    (`TransformerLM.call_counters`), the session books what it is told."""
+    included) times the linear layers — and `gdn.kernel_positions` by as
+    much where the prefill's program runs the TPU's kernel, by nothing on
+    the CPU; per decode dispatch `gdn.state_bytes` by the window and
+    state of each real row, read and written, times the linear layers —
+    the kind declares all three (`TransformerLM.call_counters`) from the
+    shapes and the platform it is told, the session books what it is
+    told."""
     telemetry.set_enabled(True)
-    names = ("gdn.scan_positions", "gdn.state_bytes",
+    names = ("gdn.scan_positions", "gdn.kernel_positions", "gdn.state_bytes",
              "serving.decode.dispatches", "cache.state_bytes")
     before = {n: telemetry.counter_value(n) for n in names}
     gs = _session(held, max_sessions=2)
@@ -588,10 +591,23 @@ def test_the_delta_rule_counters(held):
     page = 4 * (3 * CONV_DIM + DK * H * DV)
     assert moved["serving.decode.dispatches"] == 2
     assert moved["gdn.scan_positions"] == 3 * (8 + 32)
+    assert moved["gdn.kernel_positions"] == 0       # the CPU's programs
     assert moved["gdn.state_bytes"] == 2 * 2 * 3 * 2 * page
     assert moved["cache.state_bytes"] > 0
     lm = family.model(CONFIG)
-    assert lm.call_counters(positions=32) == {"gdn.scan_positions": 96,
-                                              "gdn.state_bytes": 0}
+    assert lm.call_counters(positions=32, platform="cpu") == {
+        "gdn.scan_positions": 96, "gdn.kernel_positions": 0,
+        "gdn.state_bytes": 0}
+    # a program lowered for the TPU runs the kernel in every bucket of
+    # whole chunks (of 8 here), and the body in any other
+    assert lm.call_counters(positions=32, platform="tpu") == {
+        "gdn.scan_positions": 96, "gdn.kernel_positions": 96,
+        "gdn.state_bytes": 0}
+    assert lm.call_counters(positions=36, platform="tpu") == {
+        "gdn.scan_positions": 108, "gdn.kernel_positions": 0,
+        "gdn.state_bytes": 0}
+    assert lm.call_counters(rows=2, platform="tpu") == {
+        "gdn.scan_positions": 0, "gdn.kernel_positions": 0,
+        "gdn.state_bytes": 2 * 2 * 3 * page}
     # a model none of whose kinds declares a counter books none
     assert TransformerLM(vocab=8).call_counters(positions=32, rows=4) == {}

@@ -1,8 +1,8 @@
-"""The decode-attention kernel compiled for a TPU v5e that is described,
-not attached (the TPU's compiler is installed where the tests run): what
-Pallas's interpreter cannot see — Mosaic refusing a slice, a layout or
-the fast memory a kernel asks for — at the real widths of the
-benchmark's four decoders.  Nothing runs; a compile that passes is not
+"""The decode-attention kernel and the delta rule's prefill kernel
+compiled for a TPU v5e that is described, not attached (the TPU's
+compiler is installed where the tests run): what Pallas's interpreter
+cannot see — Mosaic refusing a slice, a layout or the fast memory a
+kernel asks for — at the real widths of the benchmark's four decoders.  Nothing runs; a compile that passes is not
 a chip run.  All such compiles live in this ONE file: only one process a
 host may hold the TPU's library, and the topology is described inside a
 fixture so that every xdist worker collects the same tests."""
@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import chip_smoke
-from mxnet_tpu.ops import attention
+from mxnet_tpu.ops import attention, gdn
 
 SLOTS = 9
 # (rows, query heads, K/V heads, d_head, ring length, scale)
@@ -84,3 +84,38 @@ def test_the_decode_attention_compiles_for_a_v5e(name, one_chip):
     # nothing but the rings and the small operands: no ring-sized scratch
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < np.prod(ring[1:]) * 4
+
+
+@pytest.mark.parametrize("bucket", [768, 2048])
+def test_the_delta_rule_prefill_compiles_for_a_v5e(bucket, one_chip):
+    """`_gdn_prefill` at Olmo-Hybrid's widths (30 heads of 96 x 192, chunks
+    of 64, the cell's shortest and longest bucket) lowered for the TPU:
+    ONE `tpu_custom_call` — the kernel, walking six heads at a time —
+    no triangular solve, the window and the state aliased to their
+    outputs, and nothing the size of the operands kept beside them."""
+    import jax
+    import jax.numpy as jnp
+
+    h, dk, dv, taps, slots = 30, 96, 192, 4, 9
+    conv_dim = h * (2 * dk + dv)
+    attrs = dict(num_heads=h, key_dim=dk, value_dim=dv, conv_kernel=taps,
+                 chunk_size=64, neg_eigval=True, eps=1e-6)
+    assert gdn.chunk_heads((1, bucket, h, dk), dv, 64, "tpu") == 6
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def prefill(*operands):
+        return gdn.gdn_prefill(*operands, **attrs)
+
+    gdn._delta_rule.clear_cache()
+    compiled = jax.jit(prefill, donate_argnums=(5, 6)).lower(
+        arg(1, bucket, conv_dim + h * dv + 2 * h), arg(taps, conv_dim),
+        arg(h), arg(h), arg(dv), arg(slots, taps - 1, conv_dim),
+        arg(slots, dk, h * dv), arg(1), arg(1)).compile()
+    text = compiled.as_text()
+    assert chip_smoke.delta_rule_hlo_facts(text) == {"solves": 0,
+                                                     "kernel_calls": 1}
+    state = chip_smoke.ring_hlo_facts(text, (slots, dk, h * dv))
+    assert state["ring_params"] == state["aliased"] == 1
+    assert state["copies"] == []
