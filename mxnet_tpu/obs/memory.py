@@ -298,6 +298,21 @@ class Program:
         c = self._current
         return None if c is None else c.as_text()
 
+    def module_name(self):
+        """The name of the executable this entry dispatched last, as its
+        HLO module has it (``jit_<function>``) — what a device trace's
+        ``XLA Modules`` line calls each of its runs, before the
+        runtime's own fingerprint in brackets — or None before the first
+        call and on the jit fallback.  Read from the compiled object:
+        nothing is lowered or traced for it."""
+        c = self._current
+        if c is None:
+            return None
+        try:
+            return c.runtime_executable().hlo_modules()[0].name
+        except Exception:  # noqa: BLE001 — a name, never a failure
+            return None
+
     def _call_slow(self, args):
         with self._lock:
             if self._fallback:

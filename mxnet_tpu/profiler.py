@@ -259,17 +259,19 @@ class span:
     ``name`` is a static string and ``attrs`` are values the caller
     already holds (an int, a bucket, a tenant's name): the guards live
     in here, so a call site builds nothing that is thrown away when
-    every sink is off.  After exit ``seconds`` holds the duration."""
+    every sink is off.  After exit ``seconds`` holds the duration and
+    ``end_ns`` the span's end on the ``perf_counter_ns`` clock, so a
+    caller that needs WHEN the step ended reads no clock of its own."""
 
     __slots__ = ("name", "cat", "hist", "attrs", "id", "parent", "seconds",
-                 "_t0", "_ann")
+                 "end_ns", "_t0", "_ann")
 
     def __init__(self, name, cat="operator", hist=None, **attrs):
         self.name = name
         self.cat = cat
         self.hist = hist
         self.attrs = attrs
-        self.seconds = None
+        self.seconds = self.end_ns = None
 
     def __enter__(self):
         try:
@@ -297,6 +299,7 @@ class span:
             self._ann.__exit__(exc_type, exc, tb)
         dur_ns = time.perf_counter_ns() - self._t0
         _SPAN_TLS.stack.pop()
+        self.end_ns = self._t0 + dur_ns
         self.seconds = seconds = dur_ns * 1e-9
         if (self.hist is not None and exc_type is None
                 and telemetry.enabled()):

@@ -76,6 +76,25 @@ already freed slot at a position the slot's next tenant overwrites
 before it attends that far.  A step with no predecessor (the first
 after idle) is dispatched and read by the next call.
 
+**Device time, from the fences.**  The device runs the flights in the
+order they were enqueued and :meth:`_land` fences on every one.  When
+the host was BLOCKED at the fence of flight k-1 and of flight k (each
+``decode.device_wait`` span outlasted ``_FENCE_FLOOR_S``: the array
+was not there yet), the two exits are the two completions plus the same
+way back to the host, and their difference is flight k's time on the
+device, from ``max(ready(k-1), enqueued(k))``.  Such a flight is SEEN
+and its interval goes into the histogram of its program
+(``serving.device.decode_seconds`` / ``.prefill_seconds``, and the same
+name with ``.<bucket>``); a flight whose own fence, or whose
+predecessor's while it was queued behind it, did not block is counted
+(``serving.device.flights``) and feeds nothing.  A flight enqueued
+AFTER its predecessor's fence returned found the chip with nothing
+queued: that gap is ``serving.device.starved_seconds`` if a session was
+live through it — a lower bound, the fence's exit is late by the
+completion's way to the host.  Two subtractions and one ``observe`` a
+flight on clock readings the spans already took; no profiler session is
+needed (docs/observability.md "Device time without a profiler").
+
 Retirement (EOS, token budget, or ring-full) resolves the request's
 future with a :class:`GenerateResult` and frees the slot under
 admission control: prompts that arrive while all slots are busy wait
@@ -109,6 +128,36 @@ _LAND_HISTS = ("serving.decode.device_wait_seconds",
                "serving.decode.d2h_seconds",
                "serving.decode.emit_seconds")
 _NO_HISTS = (None, None, None)
+# a fence that lasted longer found its array NOT there, so its exit is
+# the program's completion plus the completion's way to the host.  On
+# the v5e a fence on an array that is there lasts 0.4 us (19 us the
+# longest of 20,000) and the first fence on a finished program's output
+# whose host copy was asked for 17 us (45 us the longest of 300):
+# PERF.md section 6, PR 35
+_FENCE_FLOOR_S = 100e-6
+# what a flight that was NOT seen adds to the two counters a mean device
+# time divides by (`serving.device.decode_seen`, `.prefill_positions`),
+# where a seen one adds 1: a window that saw one flight in a thousand
+# reads its mean 0.1% low, and a window that saw none divides 0 by
+# something — a reader that takes a zero denominator for "no reading"
+# (the benchmark's `ratio`) then reads 0, not nothing
+_UNSEEN = 1e-6
+
+
+def device_interval(last, sent_ns, ready_ns, blocked):
+    """Flight k's time on the device from two fences (module docstring).
+    `last`: ``(ready_ns, blocked, ...)`` of the flight landed before it,
+    or None; `sent_ns`: when k was enqueued; `ready_ns`, `blocked`: when
+    its own fence returned and whether it blocked.  Returns ``(start_ns,
+    seen, gap_ns)``: k ran from `start_ns` to `ready_ns`, which is its
+    device time if `seen`; `gap_ns` > 0 is how long the chip had nothing
+    queued before k reached it (0: k was queued behind its predecessor,
+    or has none)."""
+    if last is None:
+        return sent_ns, blocked, 0
+    if sent_ns > last[0]:
+        return sent_ns, blocked, sent_ns - last[0]
+    return last[0], blocked and last[1], 0
 
 
 class GenerateResult:
@@ -171,17 +220,44 @@ class _Session:
         return self.fed - self.prompt_len + 1
 
 
+class _Bucket:
+    """What a flight says of the bucket program it runs: `kind`
+    (``"decode"`` | ``"prefill"``), `bucket` (rows | positions),
+    `program` (the executable's name in a device trace's ``XLA
+    Modules`` line; ``""`` until its first flight has compiled it) and
+    `hists`, the two histograms its device time goes into.  Built once a
+    bucket program: a call formats no name."""
+
+    __slots__ = ("kind", "bucket", "program", "hists")
+
+    def __init__(self, kind, bucket):
+        self.kind = kind
+        self.bucket = bucket
+        self.program = ""
+        hist = "serving.device.%s_seconds" % kind
+        self.hists = (hist, "%s.%d" % (hist, bucket))
+
+
 class _Flight:
     """One dispatched program call whose outputs the host has not read:
     `outs` are the device's ``token (B,)`` and the outputs after it,
-    `rows` the sessions of the packed rows in order."""
+    `rows` the sessions of the packed rows in order, `prog` the
+    :class:`_Bucket` of its program, `seq` its number among the
+    session's flights and `enqueued_ns` the end of its
+    ``decode.dispatch`` span."""
 
-    __slots__ = ("outs", "rows", "prefill")
+    __slots__ = ("outs", "rows", "prog", "seq", "enqueued_ns")
 
-    def __init__(self, outs, rows, prefill):
+    def __init__(self, outs, rows, prog, seq, enqueued_ns):
         self.outs = outs
         self.rows = rows
-        self.prefill = prefill
+        self.prog = prog
+        self.seq = seq
+        self.enqueued_ns = enqueued_ns
+
+    @property
+    def prefill(self):
+        return self.prog.kind == "prefill"
 
 
 class GenerativeSession:
@@ -280,8 +356,16 @@ class GenerativeSession:
         self._free = list(range(self._slots))  # LIFO slot pool
         self._active = []
         self._flights = []  # dispatched and unread, oldest first
+        self._seq = 0  # flights dispatched: a flight's `seq`
+        # (ready_ns, blocked, live) of the flight landed last: when its
+        # fence returned, whether that fence blocked, whether a session
+        # was active once it had emitted; None when no flight's device
+        # time can start from it (nothing landed, or a synchronous call
+        # or a drain came between)
+        self._last_fence = None
         self._prog_lock = locks.lock("serving.decode_progs")
         self._programs = {}
+        self._buckets = {}  # (kind, bucket) -> _Bucket, beside _programs
         self._tokens_done = 0
         self._closed = False
         # book the state in the live-buffer census: nbytes is constant
@@ -377,6 +461,7 @@ class GenerativeSession:
             if exe is None:
                 exe = self._programs[key] = pred.executor_for(
                     self._shapes(batch, seq, prefill))
+                self._buckets[key] = _Bucket(*key)
                 if telemetry.enabled():
                     telemetry.inc("serving.decode.bucket_programs")
             fn = exe.serve_program(self._wire[bool(prefill)])
@@ -432,7 +517,8 @@ class GenerativeSession:
         """One SYNCHRONOUS program call on `state`: returns (host
         logits, updated state, host outputs after the token).  What the
         warm-up and `_run` use; the batcher's own calls stay in flight
-        (`_dispatch` / `_land`)."""
+        (`_dispatch` / `_land`).  It times no program, and no flight's
+        device time starts from a fence before it."""
         from .. import profiler
 
         with profiler.span("decode.dispatch", cat="serving"):
@@ -444,6 +530,7 @@ class GenerativeSession:
             small[0].block_until_ready()
         with profiler.span("decode.d2h", cat="serving"):
             logits, _token, *extra = (_np.asarray(o) for o in small)
+        self._last_fence = None
         return logits, state, extra
 
     def _run(self, exe, fn, data, slot, length):
@@ -458,25 +545,34 @@ class GenerativeSession:
             self._book_moe_load(extra[0])
         return logits
 
-    def _dispatch(self, exe, fn, data, slot, length, rows, prefill,
+    def _dispatch(self, exe, fn, data, slot, length, rows, prog,
                   hist=None):
-        """Queue one program call on the live state and leave it in
-        flight: nothing here waits for the device."""
+        """Queue one call of the bucket program `prog` on the live state
+        and leave it in flight: nothing here waits for the device."""
         from .. import profiler
 
-        with profiler.span("decode.dispatch", cat="serving", hist=hist):
+        self._seq = seq = self._seq + 1
+        with profiler.span("decode.dispatch", cat="serving", hist=hist,
+                           seq=seq, program=prog.program) as sent:
             outs, self._state = self._launch(
                 exe, fn, self._state, data, slot, length, logits=False)
-        self._flights.append(_Flight(outs, rows, prefill))
+        if not prog.program:
+            # read once, off the compiled object the first call has just
+            # made: beside that compile, never in a warmed bucket's path
+            prog.program = fn.module_name() or ""
+        self._flights.append(_Flight(outs, rows, prog, seq, sent.end_ns))
 
-    def _land(self, flight, hists=_NO_HISTS):
+    def _land(self, flight, hists=_NO_HISTS, book=True):
         """Fence on one flight, read its tokens and emit them, one a
         row — but for a row whose session has retired since (it hit EOS
-        while the row was in flight): that token is dropped."""
+        while the row was in flight): that token is dropped.  Then, if
+        `book`, book the flight's device time from the fence (module
+        docstring)."""
         from .. import profiler, telemetry
 
         with profiler.span("decode.device_wait", cat="serving",
-                           hist=hists[0]):
+                           hist=hists[0], seq=flight.seq,
+                           program=flight.prog.program) as wait:
             flight.outs[0].block_until_ready()
         with profiler.span("decode.d2h", cat="serving", hist=hists[1]):
             token, *extra = (_np.asarray(o) for o in flight.outs)
@@ -494,6 +590,44 @@ class GenerativeSession:
                 telemetry.inc("serving.decode.tokens", len(live))
         if dropped and telemetry.enabled():
             telemetry.inc("serving.decode.dropped_rows", dropped)
+        # after the emit: whether a session is live through the gap to
+        # the next flight is known once this one's rows have retired
+        if book:
+            self._book_device(flight, wait.end_ns,
+                              wait.seconds > _FENCE_FLOOR_S)
+        else:
+            self._last_fence = None
+
+    def _book_device(self, flight, ready_ns, blocked):
+        """Flight k's time on the device from its fence and the one
+        before it (module docstring): `ready_ns` the end of its
+        ``decode.device_wait`` span, `blocked` whether that span
+        outlasted the floor."""
+        from .. import telemetry
+
+        if not telemetry.enabled():
+            self._last_fence = None
+            return
+        last = self._last_fence
+        self._last_fence = (ready_ns, blocked, bool(self._active))
+        start, seen, gap = device_interval(last, flight.enqueued_ns,
+                                           ready_ns, blocked)
+        telemetry.inc("serving.device.flights")
+        if gap and last[2]:  # a session was live through the gap
+            telemetry.observe("serving.device.starved_seconds", gap * 1e-9)
+        prog = flight.prog
+        if seen:
+            telemetry.inc("serving.device.seen_flights")
+            for hist in prog.hists:
+                telemetry.observe(hist, (ready_ns - start) * 1e-9)
+        # what the two means divide by: steps, and a prefill's positions
+        # (so that microseconds a position divide like by like)
+        weight = 1 if seen else _UNSEEN
+        if prog.kind == "prefill":
+            telemetry.inc("serving.device.prefill_positions",
+                          weight * prog.bucket)
+        else:
+            telemetry.inc("serving.device.decode_seen", weight)
 
     def _book_call(self, **call):
         """The counters the model's layer kinds add for one program call
@@ -561,7 +695,7 @@ class GenerativeSession:
                 self._dispatch(exe, fn, data,
                                _np.full((1,), sess.slot, _np.float32),
                                _np.full((1,), n, _np.float32),
-                               [sess], prefill=True)
+                               [sess], self._buckets["prefill", bucket])
             except BaseException:
                 self._free.append(sess.slot)
                 raise
@@ -612,16 +746,22 @@ class GenerativeSession:
         n = len(rows)
         bucket = choose_bucket(self._decode_ladder, n) if n else 0
         if rows or step is not None:
+            # `seq`: the flight this call dispatches, `landed`: the one
+            # it reads (0: none)
             with profiler.span("serve.decode_step", cat="serving",
                                hist="serving.decode.step_seconds", n=n,
-                               bucket=bucket):
+                               bucket=bucket,
+                               seq=self._seq + 1 if rows else 0,
+                               landed=step.seq if step is not None else 0):
                 if rows:
                     self._dispatch_step(rows, bucket)
                 if step is not None:
                     self._land(step, _LAND_HISTS)
         for flight in landing:
             with profiler.span("serve.prefill", cat="serving",
-                               hist="serving.prefill_seconds"):
+                               hist="serving.prefill_seconds",
+                               bucket=flight.prog.bucket, seq=flight.seq,
+                               program=flight.prog.program):
                 self._land(flight)
         self._note_occupancy()
         return n
@@ -646,7 +786,8 @@ class GenerativeSession:
                 data[i, 0] = -1.0 if unread else sess.generated[-1]
                 slot[i] = sess.slot
                 length[i] = sess.fed
-        self._dispatch(exe, fn, data, slot, length, rows, prefill=False,
+        self._dispatch(exe, fn, data, slot, length, rows,
+                       self._buckets["decode", bucket],
                        hist="serving.decode.dispatch_seconds")
         for sess in rows:
             sess.fed += 1
@@ -655,8 +796,6 @@ class GenerativeSession:
             telemetry.inc("serving.decode.dispatches")
             if ahead:
                 telemetry.inc("serving.decode.runahead_steps")
-            telemetry.set_gauge("serving.decode.batch_fill_ratio",
-                                n / bucket)
             # the cache sets bound on the device: the live one plus the
             # zero-filled placeholder set each bucket program's executor
             # binds.  All their bytes, and the part that is recurrent state
@@ -725,7 +864,8 @@ class GenerativeSession:
         landing, self._flights = self._flights, []
         try:
             for flight in landing:
-                self._land(flight)
+                # a shutdown's fences time no program
+                self._land(flight, book=False)
         except Exception:  # noqa: BLE001 — the futures come first
             logging.getLogger(__name__).warning(
                 "tenant %r: a program call in flight at shutdown could "
@@ -742,6 +882,7 @@ class GenerativeSession:
         from .. import telemetry
 
         self._flights = []
+        self._last_fence = None
         for sess in list(self._active):
             self._active.remove(sess)
             self._free.append(sess.slot)
@@ -758,7 +899,9 @@ class GenerativeSession:
 
     def drain(self):
         """The batcher thread lands its own flights (the decode loop IS
-        the pipeline) — nothing to fence from outside."""
+        the pipeline) — nothing to fence from outside; no flight's
+        device time starts from a fence before a drain."""
+        self._last_fence = None
 
     def close(self):
         self._closed = True

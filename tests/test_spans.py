@@ -435,3 +435,330 @@ def test_kv_position_counters_grow_with_every_decode_step(
             reserved, used = r, u
     finally:
         gs.close()
+
+
+# ----------------------------------------------------------------------
+# (g) device time by program, from the fences (PR 35)
+# ----------------------------------------------------------------------
+MS = 1_000_000  # ns
+LATENCY = 300_000  # a completion's way back to the host, ns
+
+
+class _FakeChip:
+    """A clock the test owns and a device that runs what is launched in
+    order: `launch` costs the host 1 ms and queues a program of 10 ms (a
+    prefill) or 4 ms (a step) behind what the device still has; a fence
+    returns `LATENCY` after the program's end, or after 1 us if that is
+    past.  The programs really run (on the CPU); only time is made up."""
+
+    DISPATCH, PREFILL, STEP = 1 * MS, 10 * MS, 4 * MS
+
+    def __init__(self, monkeypatch, gs):
+        import time as real
+        import types
+
+        self.now = 1_000 * MS
+        self.free_at = 0
+        monkeypatch.setattr(profiler, "time", types.SimpleNamespace(
+            perf_counter_ns=lambda: self.now, time_ns=real.time_ns,
+            time=real.time))
+        launch = gs._launch
+
+        def fake_launch(exe, fn, state, data, slot, length, logits):
+            small, state = launch(exe, fn, state, data, slot, length, logits)
+            self.now += self.DISPATCH
+            done = (max(self.free_at, self.now)
+                    + (self.PREFILL if data.shape[1] > 1 else self.STEP))
+            self.free_at = done
+            return (_FakeOut(self, small[0], done),) + small[1:], state
+
+        monkeypatch.setattr(gs, "_launch", fake_launch)
+
+    def host(self, ns):
+        """The host does something else for `ns`."""
+        self.now += ns
+
+
+class _FakeOut:
+    def __init__(self, chip, real, done):
+        self.chip, self.real, self.done = chip, real, done
+
+    def block_until_ready(self):
+        self.real.block_until_ready()
+        chip = self.chip
+        chip.now = max(chip.now + 1_000, self.done + LATENCY)
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.real)
+
+
+@pytest.fixture
+def chip_session(fresh_telemetry, monkeypatch):
+    lm, params = _lm_and_params()
+    gs = GenerativeSession("lm", lm, params, max_sessions=2, max_len=16,
+                           seq_buckets=[8])
+    chip = _FakeChip(monkeypatch, gs)
+    yield gs, chip
+    gs.close()
+
+
+def _device(name):
+    hists = telemetry.snapshot()["histograms"]
+    h = hists.get("serving.device." + name)
+    return (0, 0.0) if h is None else (h["count"], round(h["sum"] * 1e3, 6))
+
+
+def _flights():
+    return (telemetry.counter_value("serving.device.flights"),
+            telemetry.counter_value("serving.device.seen_flights"))
+
+
+def test_two_blocked_fences_book_the_difference_of_their_exits(chip_session):
+    """(a) A prefill and the steps queued behind it, the host blocked at
+    every fence: each step books `ready(k) - ready(k-1)`, its 4 ms on
+    the device to the nanosecond — the completion's way back cancels;
+    the prefill has no flight before it and books from its enqueue, way
+    back included."""
+    gs, chip = chip_session
+    assert gs.admit([GenerateRequest("lm", [3, 4, 5], 60.0, 6)]) == []
+    for _ in range(3):
+        gs.decode_step()
+    assert _flights() == (3, 3)
+    assert _device("prefill_seconds") == (1, 10.3)
+    assert _device("prefill_seconds.8") == (1, 10.3)
+    assert _device("decode_seconds") == (2, 8.0)
+    assert _device("decode_seconds.1") == (2, 8.0)
+    assert _device("starved_seconds") == (0, 0.0)
+    assert telemetry.counter_value("serving.device.prefill_positions") == 8
+    assert telemetry.counter_value("serving.device.decode_seen") == 2
+
+
+@pytest.mark.parametrize("budget,starved", [(6, (1, 2.0)), (1, (0, 0.0))])
+def test_a_flight_enqueued_after_its_predecessor_books_from_its_enqueue(
+        chip_session, budget, starved):
+    """(b) The prefill is read before anything is dispatched behind it,
+    and the host then takes 1 ms more: the next flight reaches a chip
+    with nothing queued.  It books from its own enqueue, and the 2 ms
+    between the fence's exit and that enqueue are starvation while the
+    first session lives on (budget 6) and nothing once it has retired
+    (budget 1: the server was idle)."""
+    gs, chip = chip_session
+    assert gs.admit([GenerateRequest("lm", [3, 4, 5], 60.0, budget)]) == []
+    gs._land(gs._flights.pop())
+    assert bool(gs._active) == (budget > 1)
+    chip.host(1 * MS)
+    if budget > 1:
+        gs.decode_step()  # dispatches the step, 1 ms of dispatch later
+        gs.decode_step()
+        assert _device("decode_seconds") == (1, 4.3)
+    else:
+        assert gs.admit([GenerateRequest("lm", [7, 8], 60.0, 1)]) == []
+        gs.decode_step()
+        assert _device("prefill_seconds") == (2, 20.6)
+    assert _device("starved_seconds") == starved
+    assert _flights() == (2, 2)
+
+
+def test_a_fence_that_did_not_block_hides_the_flight_behind_it(chip_session):
+    """(c) The host comes 20 ms late to the first step's fence: it
+    returns at once, so when that step ended is not known and the step
+    queued behind it has no start — both are counted, neither is seen,
+    and the step after them is seen again."""
+    gs, chip = chip_session
+    assert gs.admit([GenerateRequest("lm", [3, 4, 5], 60.0, 8)]) == []
+    gs.decode_step()            # step 1 out, the prefill read
+    chip.host(20 * MS)
+    gs.decode_step()            # step 2 out; step 1's fence: at once
+    assert _flights() == (2, 1) and _device("decode_seconds") == (0, 0.0)
+    gs.decode_step()            # step 3 out; step 2: blocked, no start
+    assert _flights() == (3, 1) and _device("decode_seconds") == (0, 0.0)
+    gs.decode_step()            # step 3: blocked behind a blocked fence
+    assert _flights() == (4, 2) and _device("decode_seconds") == (1, 4.0)
+    assert _device("decode_seconds.1") == (1, 4.0)
+    # what was not seen weighs next to nothing in the means' divisor
+    assert telemetry.counter_value("serving.device.decode_seen") == (
+        pytest.approx(1.0, abs=1e-5))
+    assert telemetry.counter_value("serving.device.decode_seen") > 1.0
+
+
+def test_a_prefill_between_two_steps_is_charged_to_the_prefill(chip_session):
+    """(d) A second prompt is admitted while the first session decodes:
+    its 10 ms go to `prefill_seconds` and `.8`, the 4 ms of the step
+    queued behind it to `decode_seconds` — now of the 2-row bucket."""
+    gs, chip = chip_session
+    assert gs.admit([GenerateRequest("lm", [3, 4, 5], 60.0, 8)]) == []
+    gs.decode_step()
+    gs.decode_step()
+    assert _device("decode_seconds.1") == (1, 4.0)
+    before = _device("prefill_seconds")
+    assert gs.admit([GenerateRequest("lm", [6, 7], 60.0, 8)]) == []
+    gs.decode_step()   # step 3 (2 rows) out; step 2 and the prefill read
+    gs.decode_step()   # step 3 read
+    count, total = _device("prefill_seconds")
+    assert (count - before[0], round(total - before[1], 6)) == (1, 10.0)
+    assert _device("prefill_seconds.8") == (count, total)
+    assert _device("decode_seconds.1") == (2, 8.0)
+    assert _device("decode_seconds.2") == (1, 4.0)
+    assert _device("decode_seconds") == (3, 12.0)
+    assert telemetry.counter_value("serving.device.prefill_positions") == 16
+    assert _flights() == (5, 5)
+
+
+@pytest.mark.parametrize("how", ["warm", "run", "drain", "finish_all"])
+def test_synchronous_calls_and_drains_time_nothing(chip_session, how):
+    """(e) The warm-up, `_run` (the reference check's path) and a drain
+    feed none of the device histograms or counters and leave no fence
+    for the next flight to start from."""
+    gs, chip = chip_session
+    assert gs.admit([GenerateRequest("lm", [3, 4, 5], 60.0, 8)]) == []
+    gs.decode_step()
+    gs.decode_step()
+    assert gs._last_fence is not None
+    before = telemetry.snapshot()
+    if how == "warm":
+        gs.warm()
+    elif how == "run":
+        exe, fn = gs._program(gs._decode_pred, 1, 1, False)
+        gs._run(exe, fn, np.zeros((1, 1), np.float32),
+                np.full((1,), 2, np.float32), np.zeros((1,), np.float32))
+    elif how == "drain":
+        gs.drain()
+    else:
+        assert gs._flights
+        gs.finish_all()
+    after = telemetry.snapshot()
+    assert gs._last_fence is None
+    for kind in ("counters", "histograms"):
+        assert ({k: v for k, v in after[kind].items() if ".device." in k}
+                == {k: v for k, v in before[kind].items()
+                    if ".device." in k})
+
+
+def test_with_telemetry_off_no_device_time_is_booked(chip_session):
+    gs, chip = chip_session
+    telemetry.set_enabled(False)
+    assert gs.admit([GenerateRequest("lm", [3, 4, 5], 60.0, 4)]) == []
+    while gs.active():
+        gs.decode_step()
+    telemetry.set_enabled(True)
+    snap = telemetry.snapshot()
+    assert not [k for kind in ("counters", "histograms")
+                for k in snap[kind] if ".device." in k]
+    assert gs._last_fence is None
+
+
+@pytest.mark.parametrize("last,sent,ready,blocked,expect", [
+    (None, 5, 9, True, (5, True, 0)),
+    (None, 5, 9, False, (5, False, 0)),
+    ((7, True), 5, 9, True, (7, True, 0)),
+    ((7, False), 5, 9, True, (7, False, 0)),
+    ((7, True), 5, 9, False, (7, False, 0)),
+    ((4, False), 5, 9, True, (5, True, 1)),
+    ((4, True), 5, 9, False, (5, False, 1)),
+])
+def test_device_interval_is_the_rule(last, sent, ready, blocked, expect):
+    from mxnet_tpu.serving.decode import device_interval
+
+    assert device_interval(last, sent, ready, blocked) == expect
+
+
+@pytest.mark.parametrize("name,attrs", [
+    ("decode.dispatch", {"seq", "program"}),
+    ("decode.device_wait", {"seq", "program"}),
+    ("serve.prefill", {"seq", "program", "bucket"}),
+    ("serve.decode_step", {"seq", "landed", "n", "bucket"}),
+])
+def test_the_spans_say_which_flight_and_program(served, name, attrs):
+    """(f) `seq` numbers a session's flights; `program` is the
+    executable's name as its HLO module has it — what `XLA Modules`
+    calls its runs; a step says which flight it dispatched and which it
+    read.  The fixture's request: flight 1 the prefill, 2–4 the steps."""
+    _, events = served
+    spans = sorted((e for e in events if e["name"] == name),
+                   key=lambda e: e["ts"])
+    assert spans and all(attrs <= set(e["args"]) for e in spans)
+    if name == "serve.decode_step":
+        assert [(e["args"]["seq"], e["args"]["landed"]) for e in spans] == [
+            (2, 0), (3, 2), (4, 3), (0, 4)]
+    elif name == "serve.prefill":
+        assert [(e["args"]["seq"], e["args"]["bucket"]) for e in spans] == [
+            (1, 8)]
+    else:
+        own = [e for e in spans if e["args"].get("seq")]
+        assert [e["args"]["seq"] for e in own] == [1, 2, 3, 4]
+        # a program is named once its first call has compiled it: the
+        # steps after the first, of the one decode bucket
+        assert [e["args"]["program"] for e in own][2:] == ["jit_f", "jit_f"]
+
+
+def test_program_is_the_name_of_the_compiled_module():
+    from mxnet_tpu.obs import memory
+
+    def work(x):
+        return x * 2.0
+
+    p = memory.program(work, site="test.module_name")
+    try:
+        assert p.module_name() is None
+        p(np.ones((4,), np.float32))
+        name = p.module_name()
+        assert name == "jit_work"
+        assert p._current.as_text().startswith("HloModule " + name)
+    finally:
+        p.release()
+
+
+def _synthetic_trace():
+    """Four flights as a trace would hold them (ns): a prefill from an
+    idle chip, two steps queued behind it, and a step whose fence the
+    host reached late.  A module starts when the one before it ends; a
+    blocked fence returns 300 us after its module."""
+    mods = [(1_000, 11_000, "P", 10), (11_000, 15_000, "D", 11),
+            (15_000, 19_000, "D", 12), (19_000, 23_000, "D", 13)]
+    sent = {1: 900, 2: 2_000, 3: 12_400, 4: 16_400}
+    fence = {1: (2_100, 11_300), 2: (12_500, 15_300), 3: (16_500, 19_300),
+             4: (40_000, 40_001)}
+    spans = {
+        "mx:decode.dispatch": [(sent[q] - 500, sent[q], q, "jit_f", 0)
+                               for q in sent],
+        "mx:decode.device_wait": [(s, e, q, "jit_f", 0)
+                                  for q, (s, e) in fence.items()],
+        "mx:serve.decode_step": [(0, 0, q, "", 1) for q in (2, 3, 4)],
+        "mx:serve.prefill": [(0, 0, 1, "jit_f", 8)]}
+    enqueues = [(sent[q] - 100, 9 + q) for q in sent]
+    return spans, enqueues, [(s, e, "jit_f(%s)" % n, r)
+                             for s, e, n, r in mods]
+
+
+@pytest.mark.parametrize("with_run_ids", [True, False])
+def test_the_tool_joins_a_flight_to_its_module_and_holds_the_estimate(
+        with_run_ids):
+    """`tools/device_time_check.py`: a dispatch span's `DoEnqueueProgram`
+    gives the run's id, and without those events a blocked fence's
+    flight is the module that ended last before it returned; the
+    estimate of a flight between two blocked fences is its module's
+    duration, one dated from its enqueue is long by the way there and
+    back, and a fence that returned at once hides its flight."""
+    from tools import device_time_check as tool
+
+    spans, enqueues, modules = _synthetic_trace()
+    rows = tool.join(spans, enqueues if with_run_ids else [], modules)
+    assert [(r["seq"], r["kind"], r["bucket"]) for r in rows] == [
+        (1, "prefill", 8), (2, "decode", 1), (3, "decode", 1),
+        (4, "decode", 1)]
+    assert [r["by"] for r in rows] == (
+        ["run_id"] * 4 if with_run_ids else ["nearest"] * 4)
+    assert [r["module"][:2] for r in rows] == [m[:2] for m in modules]
+    report, gaps_s = tool.compare(rows, floor_s=100e-9)
+    assert report["decode"]["flights"] == 3 and report["decode"]["seen"] == 2
+    assert report["decode"]["unseen_own_fence"] == 1
+    assert report["decode"]["estimate_minus_module_ms"]["mean"] == 0.0
+    assert report["decode"]["completion_latency_ms"]["mean"] == (
+        pytest.approx(0.0003))
+    # from its enqueue at 900: the 100 ns to the device and the way back
+    assert report["prefill"]["estimate_minus_module_ms"]["mean"] == (
+        pytest.approx(0.0004))
+    assert gaps_s == 0.0

@@ -236,6 +236,39 @@ def test_runahead_matches_one_at_a_time_greedy_decode():
     assert_runahead_matches_one_at_a_time(lm, params)
 
 
+@pytest.mark.parametrize("budgets", [[4], [3, 6, 2, 5]])
+def test_every_dispatched_flight_is_counted_once_when_it_lands(budgets):
+    """`serving.device.flights` is the prefills plus the decode steps
+    dispatched, whatever the fences saw of them: on a real (CPU) session
+    most return at once, so few flights are SEEN, never more than
+    landed, and what the means divide by grows with every flight."""
+    lm, params = _lm_and_params(seed=5)
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, lm.vocab, size=4).tolist() for _ in budgets]
+    prev = telemetry.set_enabled(True)
+    telemetry.reset()
+    gs = GenerativeSession("lm", lm, params, max_sessions=2,
+                           max_len=lm.max_len, seq_buckets=[8])
+    try:
+        _drive(gs, [GenerateRequest("lm", p, 60.0, b)
+                    for p, b in zip(prompts, budgets)])
+        steps = telemetry.counter_value("serving.decode.dispatches")
+        flights = telemetry.counter_value("serving.device.flights")
+        assert flights == len(budgets) + steps == gs._seq
+        assert steps >= max(budgets) - 1
+        seen = telemetry.counter_value("serving.device.seen_flights")
+        assert 0 <= seen <= flights
+        hists = telemetry.snapshot()["histograms"]
+        assert seen == sum(h["count"] for name, h in hists.items() if name in (
+            "serving.device.decode_seconds", "serving.device.prefill_seconds"))
+        assert telemetry.counter_value("serving.device.decode_seen") > 0
+        assert telemetry.counter_value("serving.device.prefill_positions") > 0
+    finally:
+        gs.close()
+        telemetry.reset()
+        telemetry.set_enabled(prev)
+
+
 def test_eos_mid_run_drops_the_row_in_flight_and_the_slot_serves_on():
     """EOS needs the token's value, which the host reads one step late:
     the session's next row is in flight by then.  Its token is dropped
