@@ -33,7 +33,10 @@ apart from the rest:
             recurrent state fatter on the device than cache_spec states;
             prints the rings' on-device layout; and the 2,048-bucket
             prefill of the fourth: ONE kernel call a delta-rule layer
-            (ops/gdn_kernel.py) and no triangular solve left in it
+            (ops/gdn_kernel.py) and no triangular solve left in it; and
+            every tenant's prefill bucket programs timed warm: none may
+            run longer than 1.5 times the next larger bucket's (the
+            first shape has OPT-1.3B's FFN of 8,192 and its four buckets)
   kernel    ops/pallas_kernels.bn_stats under Mosaic at two ResNet-50
             shapes against the jnp reduction
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
@@ -68,7 +71,8 @@ FULL = {
     "kv_ring": {"vocab": 8192, "num_layers": 2, "d_model": 2048,
                 "d_ff": 2048, "max_sessions": 8, "seq_buckets": [64],
                 "seed": 5,
-                "shapes": [dict(num_heads=32, max_len=768),
+                "shapes": [dict(num_heads=32, max_len=768, d_ff=8192,
+                                seq_buckets=[64, 128, 256, 512]),
                            dict(num_heads=16, max_len=768),
                            dict(num_heads=32, num_kv_heads=8,
                                 max_len=2304),
@@ -484,6 +488,40 @@ def delta_rule_hlo_facts(text):
             "kernel_calls": text.count('custom_call_target="tpu_custom_call"')}
 
 
+BUCKET_RATIO = 1.5  # a prefill bucket's time over the next larger one's
+
+
+def prefill_bucket_ms(session, buckets, calls=3):
+    """Each prefill bucket's warm program through the session's own
+    synchronous call, a whole bucket of tokens into slot 0: the best of
+    `calls` on the host's clock, in ms."""
+    import numpy as np
+
+    best = {}
+    for t in buckets:
+        exe, fn = session._program(session._prefill_pred, 1, t, True)
+        operands = (np.zeros((1, t), np.float32), np.zeros((1,), np.float32),
+                    np.full((1,), t, np.float32))
+        took = []
+        for _ in range(calls):
+            t0 = time.perf_counter()
+            session._run(exe, fn, *operands)
+            took.append(time.perf_counter() - t0)
+        best[t] = 1e3 * min(took)
+    return best
+
+
+def slow_buckets(ms, ratio=BUCKET_RATIO):
+    """The buckets of `ms` ({bucket: ms}) that run longer than `ratio`
+    times the next larger one: a program's time grows with its bucket,
+    so such a bucket holds something the compiler made badly (PERF.md
+    section 6, PR 36: OPT-1.3B's 256 bucket at 21 ms beside 3.9 and
+    5.7)."""
+    order = sorted(ms)
+    return [t for t, larger in zip(order, order[1:])
+            if ms[t] > ratio * ms[larger]]
+
+
 def phase_kv_ring(sizes, ctx):
     """The decode step touches a KV ring where it lies (PERF.md section
     6, PR 26 and PR 32): for each of `sizes["shapes"]` compile the
@@ -491,7 +529,8 @@ def phase_kv_ring(sizes, ctx):
     of the rings.  Only a device backend donates and only the TPU has
     the kernel, so only there are aliasing, copies, kernel calls and
     the rings' size on the device judged; on the CPU the phase still
-    compiles, runs and parses."""
+    compiles, runs and parses.  Each tenant's prefill buckets are timed
+    warm, and on a device backend held against their neighbours."""
     import numpy as np
 
     import mxnet_tpu as mx
@@ -500,7 +539,7 @@ def phase_kv_ring(sizes, ctx):
     platform = ctx.jax_device().platform
     slots = sizes["max_sessions"]
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
-             "layouts": [], "rings": [], "delta_rule": []}
+             "layouts": [], "rings": [], "delta_rule": [], "prefill_ms": []}
     for shape in sizes["shapes"]:
         shape = dict(shape)
         buckets = shape.pop("seq_buckets", sizes["seq_buckets"])
@@ -554,6 +593,7 @@ def phase_kv_ring(sizes, ctx):
                             bucket=longest, layers=scanned,
                             kernel_layers=booked["gdn.kernel_positions"]
                             // longest)
+            prefill_ms = prefill_bucket_ms(session, buckets)
             # the live set as the warm-up's programs left it on the device
             held = [(n, e.nbytes, getattr(a, "on_device_size_in_bytes",
                                           lambda: e.nbytes)())
@@ -582,6 +622,16 @@ def phase_kv_ring(sizes, ctx):
             print("[chip_smoke] kv_ring: on-device bytes over cache_spec's: "
                   + ", ".join("%s %.3f" % (n, on / want)
                               for n, want, on in held), flush=True)
+        print("[chip_smoke] kv_ring: prefill buckets, ms a warm program: "
+              + ", ".join("%d: %.2f" % row for row in sorted(
+                  prefill_ms.items())), flush=True)
+        total["prefill_ms"].append({str(t): float("%.3g" % ms)
+                                    for t, ms in sorted(prefill_ms.items())})
+        if platform != "cpu":
+            slow = slow_buckets(prefill_ms)
+            _check(not slow, "prefill bucket(s) %s run longer than %.1f "
+                   "times the next larger bucket's program: %s"
+                   % (slow, BUCKET_RATIO, prefill_ms))
         if platform == "tpu":
             _check(facts["kernel_calls"] == ring_layers,
                    "%d attention kernel calls in a decode program of %d "

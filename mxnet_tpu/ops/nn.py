@@ -98,12 +98,24 @@ def _infer_fc(in_shapes, attrs):
             "no_bias": P.Bool(), "flatten": P.Bool()},
 )
 def fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False, flatten=True, **kw):
+    """``data @ weight.T + bias`` over the last axis (`flatten`: over all
+    but the first).  The product and the bias are taken on the ``(rows,
+    channels)`` view of `data` and the leading axes put back behind them:
+    one plain 2-D matmul whatever the batch's rank.  On the 3-D
+    ``(1, T, d)`` operand XLA's TPU compiler kept a 3-D and a 2-D copy of
+    each product's result, and at T = 256 of OPT-1.3B's widths that left
+    four layers' FFN fusions (both matmuls, the residual add and the next
+    norm's channel sum in one) with their operands in HBM and 512 tiles
+    that recomputed the first matmul: 4.3 ms each where the same fusion
+    takes 0.1 ms (PERF.md section 6, PR 36; `chip_smoke.py` kv_ring holds
+    every prefill bucket's program against its neighbour's)."""
     if _bool(flatten):
         data = data.reshape((data.shape[0], -1))
-    out = jnp.dot(data, weight.T)
+    lead = data.shape[:-1]
+    out = jnp.dot(data.reshape((-1, data.shape[-1])), weight.T)
     if bias is not None and not _bool(no_bias):
         out = out + bias
-    return out
+    return out.reshape(lead + (out.shape[-1],))
 
 
 # ----------------------------------------------------------------------

@@ -31,7 +31,8 @@ TINY = {
                  "prompts": 3, "new_tokens": 4, "check_steps": 3, "seed": 2},
     "kv_ring": {"vocab": 32, "num_layers": 2, "d_model": 32, "d_ff": 32,
                 "max_sessions": 2, "seq_buckets": [8], "seed": 5,
-                "shapes": [dict(num_heads=2, max_len=48),
+                "shapes": [dict(num_heads=2, max_len=48, d_ff=64,
+                                seq_buckets=[8, 16]),
                            dict(num_heads=4, num_kv_heads=2, max_len=48),
                            dict(num_heads=2, max_len=48,
                                 layer_types=["linear_attention",
@@ -92,6 +93,11 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     assert report["kv_ring"]["delta_rule"] == [
         {"solves": 0, "kernel_calls": 0, "bucket": 8, "layers": 1,
          "kernel_layers": 0}]
+    # every tenant's prefill buckets timed warm (judged on a device only)
+    assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
+        ["16", "8"], ["8"], ["8"]]
+    assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
+               for v in ms.values())
     monkeypatch.setattr(pk, "_INTERPRET", True)
     chip_smoke.run_phase("kernel", chip_smoke.phase_kernel, TINY["kernel"],
                          ctx, clock, report)
@@ -104,6 +110,18 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     assert clock.seconds > 0  # the AOT wrapper's compiles are seen
     assert report["train"]["mfu_gauge"] is None  # CPU: no peak, no MFU
     assert telemetry.counter_value("mem.program_fallbacks") == fallbacks
+
+
+@pytest.mark.parametrize("ms,slow", [
+    # OPT-1.3B's four prefill programs at the parent of PR 36 and since
+    ({64: 4.02, 128: 3.94, 256: 21.19, 512: 5.67}, [256]),
+    ({64: 4.02, 128: 3.94, 256: 4.4, 512: 5.67}, []),
+    # a small bucket may cost what the next one costs, not half as much again
+    ({64: 6.1, 2048: 4.0}, [64]),
+    ({768: 13.6, 1024: 17.7, 1536: 26.8, 2048: 37.7}, []),
+    ({64: 1.0}, [])])
+def test_a_bucket_slower_than_its_larger_neighbour_is_named(ms, slow):
+    assert chip_smoke.slow_buckets(ms) == slow
 
 
 def test_the_delta_rule_facts_count_solves_and_kernel_calls():

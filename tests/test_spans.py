@@ -762,3 +762,37 @@ def test_the_tool_joins_a_flight_to_its_module_and_holds_the_estimate(
     assert report["prefill"]["estimate_minus_module_ms"]["mean"] == (
         pytest.approx(0.0004))
     assert gaps_s == 0.0
+
+
+def test_the_tool_sums_a_programs_device_ops_by_name():
+    """`--ops decode.1`: the `XLA Ops` events inside each run of that
+    program's `XLA Modules` interval, summed by what kind of op each is
+    (instruction and operand names dropped) as ms a run, self times —
+    a `while` is charged what its body leaves — and no other program's
+    ops among them."""
+    from tools import device_time_check as tool
+
+    spans, enqueues, modules = _synthetic_trace()
+    rows = tool.join(spans, enqueues, modules)
+    ops = [(1_000, 9_000, "%fusion.9 = f32[8]{0} fusion(%p.0)")]  # prefill
+    for start in (11_000, 15_000, 19_000):  # the three steps
+        ops += [(start, start + 3_000,
+                 "%while.1 = (f32[4]{0}) while(%tuple.1)"),
+                (start + 500, start + 1_500,
+                 "%fusion.1 = f32[4]{0:T(128)} fusion(%p.1)"),
+                (start + 1_500, start + 2_500,
+                 "%fusion.2 = f32[4]{0:T(128)} fusion(%p.2)"),
+                (start + 3_000, start + 4_000,
+                 "%copy.3 = f32[4]{0} copy(%p.3)")]
+    ops.sort()
+    table = tool.ops_by_name(rows, ops, "decode", 1)
+    assert table["runs"] == 3
+    assert table["module_ms"] == pytest.approx(0.004)
+    assert [(name, round(ms, 6), n) for name, ms, n in table["ops"]] == [
+        ("f32[4]{0:T(128)} fusion(%)", 0.002, 2.0),
+        ("(f32[4]{0}) while(%)", 0.001, 1.0),
+        ("f32[4]{0} copy(%)", 0.001, 1.0)]
+    assert tool.ops_by_name(rows, ops, "prefill", 8)["ops"] == [
+        ["f32[8]{0} fusion(%)", pytest.approx(0.008), 1.0]]
+    assert tool.ops_by_name(rows, ops, "prefill", 64) == {
+        "runs": 0, "module_ms": None, "ops": []}

@@ -119,3 +119,74 @@ def test_the_delta_rule_prefill_compiles_for_a_v5e(bucket, one_chip):
     state = chip_smoke.ring_hlo_facts(text, (slots, dk, h * dv))
     assert state["ring_params"] == state["aliased"] == 1
     assert state["copies"] == []
+
+
+OPT_BUCKETS = [64, 128, 256, 512]  # benchmarks/traffic/gen_closed_c16.json
+
+
+@pytest.fixture(scope="module")
+def opt_prefill_cycles(one_chip):
+    """OPT-1.3B's prefill graph (the benchmark's configuration: twelve
+    layers at the published widths, nine pages of 768) jitted for the
+    described v5e at each of the cell's buckets: {bucket: (the sum of
+    the compiler's own `estimated_cycles` over the program's ops, the
+    largest single one)}."""
+    import json
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.families import opt
+    from mxnet_tpu.executor import _run_graph
+    from mxnet_tpu.symbol import _topo_order
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "opt-1.3b.json")) as f:
+        lm = opt.model(json.load(f))
+    graph = lm.prefill_symbol()
+    order = _topo_order(graph._entries)
+    names = graph.list_arguments()
+    spec = lm.cache_spec(SLOTS, 768)
+
+    def prefill(state, weights):
+        vals = {**state, **weights}
+        outs, _ = _run_graph(graph._entries, order, names, [],
+                             tuple(vals[n] for n in names), (), False,
+                             jax.random.key(0))
+        return outs
+
+    cycles = {}
+    for t in OPT_BUCKETS:
+        wire = dict(data=(1, t), slot=(1,), length=(1,), last_token=(SLOTS,),
+                    **{n: e.shape for n, e in spec.items()})
+        shapes, _, _ = graph.infer_shape(**wire)
+        args = {n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+                for n, s in zip(names, shapes)}
+        text = jax.jit(prefill, donate_argnums=(0,)).lower(
+            {n: a for n, a in args.items() if n in wire},
+            {n: a for n, a in args.items() if n not in wire}
+        ).compile().as_text()
+        found = [int(c) for c in re.findall(
+            r'"estimated_cycles":"(\d+)"', text[text.index("ENTRY"):])]
+        cycles[t] = (sum(found), max(found))
+    return cycles
+
+
+@pytest.mark.parametrize("bucket,larger", list(zip(OPT_BUCKETS,
+                                                   OPT_BUCKETS[1:])))
+def test_opts_prefill_programs_grow_with_their_bucket(bucket, larger,
+                                                      opt_prefill_cycles):
+    """By the TPU compiler's own estimate no prefill bucket of OPT-1.3B
+    costs more than 1.5 times the next larger one, and no single fusion
+    of it more than the larger program's costliest: at the parent of
+    PR 36 the 256 bucket read 30.2 M cycles beside 8.7 M and 13.7 M —
+    three FFN fusions of 7.5 M each, tiled 512 ways with their first
+    matmul recomputed — and ran 21.2 ms on the chip beside 3.9 and 5.7
+    (PERF.md section 6, PR 36).  An estimate is not a time:
+    `chip_smoke.py` kv_ring holds the same rule on the chip."""
+    total, worst = opt_prefill_cycles[bucket]
+    total_larger, worst_larger = opt_prefill_cycles[larger]
+    assert total <= 1.5 * total_larger, opt_prefill_cycles
+    assert worst <= 1.5 * worst_larger, opt_prefill_cycles

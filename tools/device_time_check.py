@@ -3,6 +3,7 @@
 
     python tools/device_time_check.py --workload <serving cell> --seed <n>
         [--seconds 50] [--out chiprun_out/device_time_<cell>.json]
+        [--ops <kind>.<bucket> ...]
 
 runs one traced run of a serving cell of BENCHMARK.json through the
 benchmark's own `measure` (so the per-layer metrics, the new `device.*`
@@ -26,6 +27,13 @@ The join (docs/observability.md "Device time without a profiler"):
 * where a trace has no `DoEnqueueProgram` (host level 0), the flight's
   run is the `XLA Modules` event of its program that ended last before
   the fence returned — right for every flight whose fence blocked.
+
+`--ops prefill.256` (repeatable) prints the device ops of that program
+summed by name — each run's `XLA Ops` events inside its `XLA Modules`
+interval, self times, as ms a run — and writes the program's optimised
+HLO beside the report (`device_time_<cell>.<kind>.<bucket>.hlo`): which
+op of a bucket's program takes its time, and what the compiler made of
+it, in one command.
 
 Per kind it reports the estimate (`serving.decode.device_interval` on
 the spans' times, the rule `GenerativeSession._book_device` books by)
@@ -51,13 +59,15 @@ if ROOT not in sys.path:
 SPANS = ("mx:decode.dispatch", "mx:decode.device_wait",
          "mx:serve.decode_step", "mx:serve.prefill")
 ENQUEUE = "DoEnqueueProgram"
+OPS_SHOWN = 12  # lines of an --ops table on the terminal; the file has all
 
 
 def read_trace(path):
-    """(spans, enqueues, modules, busy) of one xplane: `spans[name]` =
+    """(spans, enqueues, modules, ops) of one xplane: `spans[name]` =
     [(start_ns, end_ns, seq, program, bucket)], `enqueues` = [(start_ns,
     run_id)], `modules` = [(start_ns, end_ns, name, run_id)] of the
-    first chip, `busy` = the merged [start, end] of its `XLA Ops`."""
+    first chip, `ops` = [(start_ns, end_ns, name)] of its `XLA Ops`, by
+    start."""
     from benchmarks.harness import trace_reduce
 
     data = trace_reduce.load(path)
@@ -76,7 +86,7 @@ def read_trace(path):
                         modules.append((start, end, ev.name,
                                         stats.get("run_id")))
                     elif line.name == trace_reduce.OPS_LINE:
-                        ops.append((start, end))
+                        ops.append((start, end, ev.name))
                 elif ev.name in spans:
                     stats = dict(ev.stats)
                     spans[ev.name].append((start, end, int(stats["seq"]),
@@ -86,7 +96,39 @@ def read_trace(path):
                     enqueues.append((start, dict(ev.stats).get("run_id")))
     enqueues.sort(key=lambda e: e[0])
     modules.sort(key=lambda m: m[:2])
-    return spans, enqueues, modules, trace_reduce._merge(ops)
+    ops.sort()
+    return spans, enqueues, modules, ops
+
+
+def ops_by_name(rows, ops, kind, bucket):
+    """The device ops of the `(kind, bucket)` program's runs in the
+    trace, summed by what kind of op each is (`trace_reduce.op_kind`:
+    opcode, shapes, layouts and tiles, names dropped): {runs, module_ms,
+    ops: [[name, ms a run, events a run]]} by falling time, self times
+    (a `while` is charged what its body leaves)."""
+    from benchmarks.harness import trace_reduce
+
+    runs = [r["module"] for r in rows
+            if r["module"] and (r["kind"], r["bucket"]) == (kind, bucket)]
+    if not runs:
+        return {"runs": 0, "module_ms": None, "ops": []}
+    starts = [op[0] for op in ops]  # sorted, as `ops` is
+    seconds, counts = {}, {}
+    for m_start, m_end, _name in runs:
+        inside = [(s, e, trace_reduce.op_kind(name)) for s, e, name in ops[
+            bisect.bisect_left(starts, m_start):
+            bisect.bisect_right(starts, m_end)] if e <= m_end]
+        for name, t in trace_reduce._self_times(inside).items():
+            seconds[name] = seconds.get(name, 0.0) + t
+        for _s, _e, name in inside:
+            counts[name] = counts.get(name, 0) + 1
+    n = len(runs)
+    table = sorted(([name, 1e3 * t / n, counts[name] / n]
+                    for name, t in seconds.items()), key=lambda r: -r[1])
+    return {"runs": n,
+            "module_ms": statistics.fmean((e - s) * 1e-6
+                                          for s, e, _name in runs),
+            "ops": table}
 
 
 def join(spans, enqueues, modules):
@@ -193,7 +235,13 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
     ap.add_argument("--out", default=None)
+    ap.add_argument("--ops", action="append", default=[],
+                    metavar="KIND.BUCKET",
+                    help="print that program's device ops summed by name "
+                         "and keep its optimised HLO (e.g. prefill.256)")
     args = ap.parse_args(argv)
+    wanted = [(k, int(b)) for k, _dot, b in
+              (tag.rpartition(".") for tag in args.ops)]
     args.trace = 1
 
     from benchmarks import run as bench_run
@@ -246,12 +294,22 @@ def main(argv=None):
             named.append(time.perf_counter() - t0)
 
     memory.Program.module_name = timed_module_name
+    # the compiled programs by (kind, bucket), for --ops' HLO
+    programs, program_of = {}, decode.GenerativeSession._program
+
+    def remembered_program(self, pred, batch, seq, prefill):
+        exe, fn = program_of(self, pred, batch, seq, prefill)
+        programs[("prefill", seq) if prefill else ("decode", batch)] = fn
+        return exe, fn
+
+    decode.GenerativeSession._program = remembered_program
     result = bench_run.measure(cell, args, devices, device.CompileClock(),
                                PROCESS_START)
     stop.set()
     print(json.dumps(result), flush=True)
 
-    spans, enqueues, modules, busy = read_trace(kept)
+    spans, enqueues, modules, ops = read_trace(kept)
+    busy = trace_reduce._merge(op[:2] for op in ops)
     rows = join(spans, enqueues, modules)
     report, gaps_s = compare(rows, decode._FENCE_FLOOR_S)
     names, by_program = {}, {}
@@ -298,6 +356,19 @@ def main(argv=None):
           flush=True)
     out = args.out or os.path.join(ROOT, "chiprun_out",
                                    "device_time_%s.json" % cell.name)
+    summary["ops_by_program"] = {}
+    for kind, bucket in wanted:
+        tag = "%s.%d" % (kind, bucket)
+        table = summary["ops_by_program"][tag] = ops_by_name(
+            rows, ops, kind, bucket)
+        print("[device_time] ops of %s: %d runs, %s ms a run" % (
+            tag, table["runs"], table["module_ms"]))
+        for name, ms, n in table["ops"][:OPS_SHOWN]:
+            print("  %8.4f ms  x%-5.4g %s" % (ms, n, name))
+        if (kind, bucket) in programs:
+            with open("%s.%s.hlo" % (os.path.splitext(out)[0], tag),
+                      "w") as f:
+                f.write(programs[kind, bucket].hlo_text() or "")
     with open(out, "w") as f:
         json.dump(summary, f, indent=1)
     os.remove(kept)
