@@ -23,11 +23,13 @@ apart from the rest:
   generate  TransformerLM via add_generative_tenant + submit_generate;
             one session's prefill/decode logits against the
             full-recompute score_symbol forward
-  kv_ring   the decode programs of four TransformerLMs shaped like the
+  kv_ring   the decode programs of five TransformerLMs shaped like the
             benchmark's decoders (32 heads of 64; 16 of 128; 32 query on
             8 K/V heads of 64 with rings of 2,304; a delta-rule layer of
             30 heads of 96 x 192 beside 30 heads of 128 with rings of
-            2,304; 8 sessions) as XLA compiled them: every cache_spec
+            2,304; a window layer's rings of 2,048 positions, which wrap,
+            beside a full layer's of 6,144, 32 query on 4 K/V heads of
+            128; 8 sessions) as XLA compiled them: every cache_spec
             entry aliased to its output, no instruction that copies one,
             ONE attention kernel call an attention layer, and no ring or
             recurrent state fatter on the device than cache_spec states;
@@ -86,7 +88,17 @@ FULL = {
                                 linear_heads=30, linear_key_dim=96,
                                 linear_value_dim=192, norm="rms",
                                 positions="none", bias=False,
-                                block_norm="output")]},
+                                block_norm="output"),
+                           # a window layer's ring of 2,048 positions,
+                           # which wraps, beside a full layer's of 6,144:
+                           # 32 query heads of 128 over 4 K/V heads
+                           dict(num_heads=32, num_kv_heads=4, head_dim=128,
+                                max_len=6144, seq_buckets=[64, 2048],
+                                layer_types=["window_attention",
+                                             "attention"],
+                                sliding_window=2048, norm="rms",
+                                positions={"window_attention": "rotary"},
+                                qk_norm="head", out_gate=True, bias=False)]},
     "kernel": {"shapes": [(512, 56, 56, 64), (512, 7, 7, 2048)], "seed": 3},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
@@ -548,7 +560,11 @@ def phase_kv_ring(sizes, ctx):
                                      d_model=sizes["d_model"],
                                      d_ff=sizes["d_ff"]), **shape})
         spec = lm.cache_spec(slots + 1)
-        ring = next(e.shape for e in spec.values() if e.kind == "ring")
+        # the model's rings, one shape a length (a window layer's is its
+        # own): the first is read for its layout, the others beside it
+        rings = list(dict.fromkeys(e.shape for e in spec.values()
+                                   if e.kind == "ring"))
+        ring = rings[0]
         ring_layers = sum(e.kind == "ring" for e in spec.values()) // 2
         # judged: every entry of at least a hundredth of the set's bytes
         # (a conv window of three rows lies in tiles of four, and XLA may
@@ -654,7 +670,7 @@ def phase_kv_ring(sizes, ctx):
             total[key] += facts[key]
         total["copies"] += len(facts["copies"])
         total["layouts"] = sorted(set(total["layouts"] + facts["layouts"]))
-        total["rings"].append(list(ring))
+        total["rings"] += [list(r) for r in rings]
     return total
 
 

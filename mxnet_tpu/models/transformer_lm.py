@@ -47,14 +47,20 @@ expert in this call — which the batcher books as the ``moe.*`` counters.
 
 **Layer kinds.**  `layer_types` names each layer's MIXER — the half of
 the block before the FFN — ``"attention"`` (the default for every layer),
-``"mamba"`` (a Mamba-2 state-space mixer, ops/ssm.py) or
-``"linear_attention"`` (a Gated DeltaNet delta-rule mixer, ops/gdn.py).
-A kind is one class below (:class:`_Attention`, :class:`_Mamba2`,
-:class:`_GatedDeltaNet`) that declares, in that one place, its
-parameters, its full-sequence forward, its prefill, its decode step, the
-device-resident state it keeps between calls and the counters a program
-call adds to; the four graph builders walk the pattern and know no kind
-by name.
+``"window_attention"`` (attention over a sliding window of
+`sliding_window` positions), ``"mamba"`` (a Mamba-2 state-space mixer,
+ops/ssm.py) or ``"linear_attention"`` (a Gated DeltaNet delta-rule mixer,
+ops/gdn.py).  A kind is one class below (:class:`_Attention`,
+:class:`_WindowAttention`, :class:`_Mamba2`, :class:`_GatedDeltaNet`) that
+declares, in that one place, its parameters, its full-sequence forward,
+its prefill, its decode step, the device-resident state it keeps between
+calls and the counters a program call adds to; the four graph builders
+walk the pattern and know no kind by name.  `ffn_types` names each
+layer's FFN — the other half — the same way: ``"dense"``
+(:class:`_DenseFFN`, of width `d_ff`) or ``"routed"``
+(:class:`_RoutedFFN`: ``mx.sym.MoE`` experts of width `expert_d_ff`, a
+shared expert, a held range); by default every layer's is the one
+`num_experts` implies.
 ``num_kv_heads`` (grouped-query attention), ``positions="none"``,
 ``ffn="swiglu"`` (a dense gated FFN), ``attention_multiplier`` and the
 three stream multipliers (``embedding_`` / ``residual_multiplier``,
@@ -64,12 +70,23 @@ branch's OUTPUT is normed before it joins the stream, ``h + norm(f(h))``,
 where every block before normed its input), ``qk_norm``,
 ``positions="none"``, ``ffn="swiglu"``, an untied head and ``layer_types``
 of three ``"linear_attention"`` to one ``"attention"`` are Olmo-Hybrid's.
+``head_dim`` (a head width that is not ``d_model / num_heads``),
+``qk_norm="head"``, ``out_gate``, ``block_norm="both"``, positions by
+attention kind (``{"window_attention": "rotary"}``: the full layers have
+no position signal), ``layer_types`` of three ``"window_attention"`` to
+one ``"attention"``, ``ffn_types`` of leading ``"dense"`` layers before
+``"routed"`` ones, ``router_score="sigmoid"`` with ``router_bias``,
+``route_norm``, ``route_scale``, ``shared_d_ff`` and ``held_experts`` are
+Trinity's (`afmoe`), held as one chip's share of its experts.
 
 **Cache spec.**  :meth:`TransformerLM.cache_spec` is the ONE statement of
 what a serving session holds on the device between calls: an ordered
 ``{name: CacheEntry(kind, shape)}`` over all layers — an attention layer's
 two KV rings (kind ``"ring"``, pages addressed by slot and masked by
-length, so stale contents are harmless) and a Mamba or Gated DeltaNet layer's
+length, so stale contents are harmless; A RING'S LENGTH IS ITS KIND'S,
+the last axis of its shape: the session's ``max_len`` for a full layer,
+``min(sliding_window, max_len)`` for a window layer, whose ring a longer
+session writes modulo) and a Mamba or Gated DeltaNet layer's
 conv window and recurrent state (kind ``"state"``, a fixed size a slot
 whatever the context, wholly rewritten by a prefill).  The serving graphs take and
 return exactly these names in this order; whoever allocates, sizes,
@@ -88,9 +105,10 @@ __all__ = ["TransformerLM", "CacheEntry"]
 
 class CacheEntry(NamedTuple):
     """One device buffer a serving session threads through its calls:
-    `kind` ``"ring"`` (KV pages, masked by length) or ``"state"`` (a
-    recurrent layer's, overwritten whole by a prefill); `shape` as
-    stored, float32."""
+    `kind` ``"ring"`` (KV pages, masked by length; ``shape[3]`` is the
+    ring's own length in positions, which differs by layer kind) or
+    ``"state"`` (a recurrent layer's, overwritten whole by a prefill);
+    `shape` as stored, float32."""
 
     kind: str
     shape: tuple
@@ -101,15 +119,21 @@ class CacheEntry(NamedTuple):
 
 
 class _Attention:
-    """The attention mixer of layer i: fused QKV projection, QK-norm and
-    rotary as the spec says, causal softmax attention over `num_heads`
-    query heads and `num_kv_heads` K/V heads, output projection.  State:
-    two KV rings."""
+    """The attention mixer of layer i: fused QKV projection (and the
+    output gate's, where the spec has one), QK-norm and rotary as the
+    spec says, causal softmax attention over `num_heads` query heads and
+    `num_kv_heads` K/V heads of `d_head`, output projection.  State: two
+    KV rings of the session's `max_len` positions."""
+
+    KIND = "attention"
 
     def __init__(self, lm):
         self.lm = lm
-        # what the three attention ops take beyond their operands; the
-        # two options appear on a node only when the spec sets them
+        self.rope = lm.kind_positions[self.KIND] == "rotary"
+        self.q_width = lm.num_heads * lm.d_head
+        self.kv_width = lm.num_kv_heads * lm.d_head
+        # what the three attention ops take beyond their operands; an
+        # option appears on a node only when the spec sets it
         self.attrs = dict(num_heads=lm.num_heads)
         self.sdp_attrs = dict(num_heads=lm.num_heads, causal=True)
         for attrs in (self.attrs, self.sdp_attrs):
@@ -120,16 +144,21 @@ class _Attention:
 
     def params(self, i):
         lm, v = self.lm, sym.Variable
-        d, wide = lm.d_model, lm.d_model + 2 * lm.num_kv_heads * lm.d_head
+        d = lm.d_model
+        wide = (1 + lm.out_gate) * self.q_width + 2 * self.kv_width
         p = {"qkv_weight": v("l%d_qkv_weight" % i, shape=(wide, d)),
-             "out_weight": v("l%d_out_weight" % i, shape=(d, d))}
+             "out_weight": v("l%d_out_weight" % i, shape=(d, self.q_width))}
         if lm.bias:
             p["qkv_bias"] = v("l%d_qkv_bias" % i, shape=(wide,))
             p["out_bias"] = v("l%d_out_bias" % i, shape=(d,))
         return p
 
+    def ring_len(self, max_len):
+        """Positions of this kind's rings in a session of `max_len`."""
+        return int(max_len)
+
     def cache_spec(self, i, slots, max_len):
-        """``(slots, num_kv_heads, d_head, max_len)`` for K and for V:
+        """``(slots, num_kv_heads, d_head, ring_len)`` for K and for V:
         the POSITIONS on the minor axis, for every head width.
 
         It is the order the decode step's attention reads (ops/
@@ -142,31 +171,43 @@ class _Attention:
         PR 26 had found the runtime keeping 64-wide heads in this very
         order under the old shape)."""
         ring = CacheEntry("ring", (int(slots), self.lm.num_kv_heads,
-                                   self.lm.d_head, int(max_len)))
+                                   self.lm.d_head, self.ring_len(max_len)))
         return [("k_cache_%d" % i, ring), ("v_cache_%d" % i, ring)]
 
-    def _qkv(self, x, p, i, index=None):
-        """The three projections of the normed stream, ready for the
-        attention op: QK-norm over the whole projections, then rotary
-        positions — each row's own `index` in a decode step, 0..T-1
-        without one — so K reaches the ring already rotated."""
+    def _head_norm(self, x, name, heads):
+        """QK-norm of one projection: over all its channels, or — the
+        spec's ``qk_norm="head"`` — each head on its own with one
+        ``(d_head,)`` gain for all of them."""
         lm = self.lm
-        kv_width = lm.num_kv_heads * lm.d_head
-        qkv = lm._linear(x, p, "qkv", lm.d_model + 2 * kv_width,
-                         "l%d_qkv" % i)
-        if kv_width == lm.d_model:
-            q, k, v = sym.SliceChannel(qkv, num_outputs=3, axis=2,
-                                       name="l%d_qkv_split" % i)
+        if lm.qk_norm != "head":
+            return lm._norm(x, name, width=heads * lm.d_head)
+        gamma = sym.Variable(name + "_gamma", shape=(lm.d_head,))
+        return sym.RMSNorm(x, gamma=gamma, eps=lm.norm_eps, num_heads=heads,
+                           name=name)
+
+    def _qkv(self, x, p, i, index=None):
+        """The projections of the normed stream, ready for the attention
+        op: QK-norm, then rotary positions where this kind has them —
+        each row's own `index` in a decode step, 0..T-1 without one — so
+        K reaches the ring already rotated.  Returns ``(q, k, v, gate)``,
+        `gate` the output gate's projection or None."""
+        lm = self.lm
+        q_width, kv_width = self.q_width, self.kv_width
+        widths = [q_width, kv_width, kv_width] + [q_width] * lm.out_gate
+        qkv = lm._linear(x, p, "qkv", sum(widths), "l%d_qkv" % i)
+        if widths == [lm.d_model] * 3:
+            parts = list(sym.SliceChannel(qkv, num_outputs=3, axis=2,
+                                          name="l%d_qkv_split" % i))
         else:
-            edges = (0, lm.d_model, lm.d_model + kv_width,
-                     lm.d_model + 2 * kv_width)
-            q, k, v = (sym.slice_axis(qkv, axis=2, begin=a, end=b,
-                                      name="l%d_%s_split" % (i, n))
-                       for n, a, b in zip("qkv", edges, edges[1:]))
+            edges = [sum(widths[:n]) for n in range(len(widths) + 1)]
+            parts = [sym.slice_axis(qkv, axis=2, begin=a, end=b,
+                                    name="l%d_%s_split" % (i, n))
+                     for n, a, b in zip("qkvg", edges, edges[1:])]
+        q, k, v = parts[:3]
         if lm.qk_norm:
-            q = lm._norm(q, "l%d_qnorm" % i)
-            k = lm._norm(k, "l%d_knorm" % i, width=kv_width)
-        if lm.positions == "rotary":
+            q = self._head_norm(q, "l%d_qnorm" % i, lm.num_heads)
+            k = self._head_norm(k, "l%d_knorm" % i, lm.num_kv_heads)
+        if self.rope:
             rope = dict(theta=lm.rope_theta)
             heads = (lm.num_heads, lm.num_kv_heads)
             if index is None:
@@ -177,33 +218,76 @@ class _Attention:
                 q, k = (sym._rotary_at(t, index, name="l%d_%srope" % (i, n),
                                        num_heads=h, **rope)
                         for t, n, h in zip((q, k), "qk", heads))
-        return q, k, v
+        return q, k, v, parts[3] if lm.out_gate else None
 
-    def _out(self, ctx, p, i):
+    def _out(self, ctx, gate, p, i):
+        if gate is not None:
+            ctx = ctx * sym.Activation(gate, act_type="sigmoid",
+                                       name="l%d_out_gate" % i)
         return self.lm._linear(ctx, p, "out", self.lm.d_model,
                                "l%d_proj" % i)
 
     def _attend(self, x, p, i):
-        q, k, v = self._qkv(x, p, i)
+        q, k, v, gate = self._qkv(x, p, i)
         return sym._sdp_attention(q, k, v, name="l%d_attn" % i,
-                                  **self.sdp_attrs)
+                                  **self.sdp_attrs), gate
 
     def full(self, x, p, i):
-        return self._out(self._attend(x, p, i)[0], p, i)
+        attn, gate = self._attend(x, p, i)
+        return self._out(attn[0], gate, p, i)
 
-    def prefill(self, x, p, i, caches, slot, length):
-        attn = self._attend(x, p, i)
-        wrote = sym._kv_cache_write(
+    def _ring_write(self, caches, i, attn, slot, length):
+        return sym._kv_cache_write(
             caches["k_cache_%d" % i], caches["v_cache_%d" % i],
             attn[1], attn[2], slot, name="l%d_kv_write" % i)
-        return self._out(attn[0], p, i), [wrote[0], wrote[1]]
+
+    def prefill(self, x, p, i, caches, slot, length):
+        attn, gate = self._attend(x, p, i)
+        wrote = self._ring_write(caches, i, attn, slot, length)
+        return self._out(attn[0], gate, p, i), [wrote[0], wrote[1]]
 
     def decode(self, x, p, i, caches, slot, length):
-        q, k, v = self._qkv(x, p, i, index=length)
+        q, k, v, gate = self._qkv(x, p, i, index=length)
         step = sym._cached_attention(
             q, k, v, caches["k_cache_%d" % i], caches["v_cache_%d" % i],
             slot, length, name="l%d_attn" % i, **self.attrs)
-        return self._out(step[0], p, i), [step[1], step[2]]
+        return self._out(step[0], gate, p, i), [step[1], step[2]]
+
+
+class _WindowAttention(_Attention):
+    """The attention mixer with a sliding window: row i attends to ``j <=
+    i`` with ``i - j < sliding_window`` (itself and the W - 1 before it).
+    State: two KV rings of ``min(W, max_len)`` positions, whatever the
+    session's length — a prefill writes the prompt's last W positions and
+    a decode step writes at ``length mod W`` (ops/attention.py), so a
+    ring that is full holds exactly the window."""
+
+    KIND = "window_attention"
+
+    def __init__(self, lm):
+        super().__init__(lm)
+        self.attrs["window"] = self.sdp_attrs["window"] = lm.sliding_window
+
+    def ring_len(self, max_len):
+        return min(self.lm.sliding_window, int(max_len))
+
+    def _ring_write(self, caches, i, attn, slot, length):
+        return sym._kv_cache_write(
+            caches["k_cache_%d" % i], caches["v_cache_%d" % i],
+            attn[1], attn[2], slot, length, window=self.lm.sliding_window,
+            name="l%d_kv_write" % i)
+
+    def counters(self, i, rows=0, lengths=(), pages=0, max_len=None,
+                 **call):
+        """What one decode step adds: a window row for each real row of
+        this layer, those of them whose ring has wrapped (``length >=
+        W``: the row is written modulo and the whole ring is read), and
+        the bytes of this layer's rings among the `pages` pages bound."""
+        page = sum(e.nbytes for _, e in self.cache_spec(
+            i, 1, self.lm.max_len if max_len is None else max_len))
+        wrapped = sum(1 for n in lengths if n >= self.lm.sliding_window)
+        return {"kv.window_rows": rows, "kv.wrapped_rows": wrapped,
+                "cache.window_bytes": pages * page}
 
 
 class _Recurrent:
@@ -325,7 +409,7 @@ class _GatedDeltaNet(_Recurrent):
                           chunk_size=lm.linear_chunk,
                           neg_eigval=lm.linear_neg_eigval, eps=lm.norm_eps)
 
-    def counters(self, i, positions, rows, platform):
+    def counters(self, i, positions=0, rows=0, platform=None, **call):
         """What one program call adds: the bucket positions a prefill
         scans in this layer (the pad included), those of them that a
         program lowered for `platform` runs through the TPU's kernel
@@ -342,8 +426,118 @@ class _GatedDeltaNet(_Recurrent):
                 "gdn.state_bytes": 2 * rows * page}
 
 
-_KINDS = {"attention": _Attention, "mamba": _Mamba2,
-          "linear_attention": _GatedDeltaNet}
+_KINDS = {"attention": _Attention, "window_attention": _WindowAttention,
+          "mamba": _Mamba2, "linear_attention": _GatedDeltaNet}
+
+
+class _DenseFFN:
+    """The dense FFN of layer i: ``W2 relu(W1 x)``, or with
+    ``ffn="swiglu"`` ``W2 (silu(a) * b)`` with ``[a | b]`` one fused
+    projection, of width `d_ff`."""
+
+    def __init__(self, lm):
+        self.lm = lm
+
+    def params(self, i):
+        lm, v = self.lm, sym.Variable
+        d, ff = lm.d_model, lm.d_ff
+        wide = 2 * ff if lm.ffn == "swiglu" else ff
+        p = {"ffn1_weight": v("l%d_ffn1_weight" % i, shape=(wide, d)),
+             "ffn2_weight": v("l%d_ffn2_weight" % i, shape=(d, ff))}
+        if lm.bias:
+            p["ffn1_bias"] = v("l%d_ffn1_bias" % i, shape=(wide,))
+            p["ffn2_bias"] = v("l%d_ffn2_bias" % i, shape=(d,))
+        return p
+
+    def apply(self, x, p, i, loads):
+        lm = self.lm
+        if lm.ffn == "swiglu":
+            a, b = sym.SliceChannel(
+                lm._linear(x, p, "ffn1", 2 * lm.d_ff, "l%d_ffn1" % i),
+                num_outputs=2, axis=2, name="l%d_ffn_split" % i)
+            f = sym.Activation(a, act_type="silu", name="l%d_silu" % i) * b
+        else:
+            f = sym.Activation(
+                lm._linear(x, p, "ffn1", lm.d_ff, "l%d_ffn1" % i),
+                act_type="relu", name="l%d_gelu" % i)
+        return lm._linear(f, p, "ffn2", lm.d_model, "l%d_ffn2" % i)
+
+
+class _RoutedFFN:
+    """The routed FFN of layer i (``mx.sym.MoE``, dropless):
+    `experts_per_token` of `num_experts` SwiGLU experts of width
+    `expert_d_ff` by the router's scores, plus — `shared_d_ff` — one
+    expert every token passes.  `held_experts` ``(first, count)`` are the
+    experts whose matrices this model holds, one chip's share of the
+    layer: the router and the choice stay `num_experts` wide."""
+
+    def __init__(self, lm):
+        self.lm = lm
+        held = lm.held_experts
+        self.held = lm.num_experts if held is None else held[1]
+        # beyond OLMoE's: an option appears on a node only when the
+        # spec sets it
+        self.attrs = {}
+        if lm.router_score != "softmax":
+            self.attrs["score_func"] = lm.router_score
+        if lm.router_bias:
+            self.attrs["select_bias"] = True
+        if lm.route_scale != 1.0:
+            self.attrs["route_scale"] = lm.route_scale
+        if lm.shared_d_ff:
+            self.attrs["shared_size"] = lm.shared_d_ff
+        if held is not None:
+            self.attrs.update(held_first=held[0], held_count=held[1])
+
+    def params(self, i):
+        lm, v = self.lm, sym.Variable
+        d, ff, e, s = lm.d_model, lm.expert_d_ff, self.held, lm.shared_d_ff
+        p = {"router_weight": v("l%d_router_weight" % i,
+                                shape=(d, lm.num_experts))}
+        if lm.router_bias:
+            p["router_bias"] = v("l%d_router_bias" % i,
+                                 shape=(lm.num_experts,))
+        p["gate_weight"] = v("l%d_gate_weight" % i, shape=(e, d, ff))
+        p["down_weight"] = v("l%d_down_weight" % i, shape=(e, ff, d))
+        p["up_weight"] = v("l%d_up_weight" % i, shape=(e, d, ff))
+        if s:
+            p["shared_gate_weight"] = v("l%d_shared_gate_weight" % i,
+                                        shape=(d, s))
+            p["shared_down_weight"] = v("l%d_shared_down_weight" % i,
+                                        shape=(s, d))
+            p["shared_up_weight"] = v("l%d_shared_up_weight" % i,
+                                      shape=(d, s))
+        return p
+
+    def apply(self, x, p, i, loads):
+        lm = self.lm
+        operands = [x, p["router_weight"]]
+        operands += [p["router_bias"]] if lm.router_bias else []
+        operands += [p["gate_weight"], p["down_weight"], p["up_weight"]]
+        if lm.shared_d_ff:
+            operands += [p["shared_gate_weight"], p["shared_down_weight"],
+                         p["shared_up_weight"]]
+        f = sym.MoE(*operands, num_experts=lm.num_experts,
+                    hidden_size=lm.expert_d_ff, k=lm.experts_per_token,
+                    act_type="silu", gated=True, no_bias=True,
+                    normalize=lm.route_norm,
+                    return_load=loads is not None, name="l%d_moe" % i,
+                    **self.attrs)
+        if loads is None:
+            return f
+        loads.append(f[1])
+        return f[0]
+
+    def counters(self, i, positions=0, computed=0, **call):
+        """What one program call adds: the (token, expert) pairs the
+        router made of the rows the program computed — a prefill's bucket
+        `positions`, a decode step's `computed` rows, the pad included as
+        `moe.pairs` includes it — whichever chip holds the expert."""
+        return {"moe.routed_pairs":
+                (positions + computed) * self.lm.experts_per_token}
+
+
+_FFNS = {"dense": _DenseFFN, "routed": _RoutedFFN}
 
 
 class TransformerLM:
@@ -381,7 +575,25 @@ class TransformerLM:
     `mamba_chunk`; the Gated DeltaNet mixer's `linear_heads` heads of
     `linear_key_dim` x `linear_value_dim`, `linear_conv` taps, the chunk
     `linear_chunk` of its full-sequence form and `linear_neg_eigval`
-    (``beta`` reaches 2)."""
+    (``beta`` reaches 2); `head_dim` — the width of a head where it is not
+    ``d_model / num_heads`` (the projections are then ``num_heads *
+    head_dim`` wide); `sliding_window` W of the ``"window_attention"``
+    kind (row i attends to ``j <= i`` with ``i - j < W``); `positions`
+    may be a dict by attention kind, ``{"window_attention": "rotary"}``
+    (kinds left out have none; no learned table then); `qk_norm`
+    ``"head"`` norms each head of Q and K on its own with one ``(head_dim,)``
+    gain; `out_gate` multiplies attention's context by ``sigmoid(x Wg)``,
+    `Wg` fused behind ``[q | k | v]``; `block_norm` ``"both"`` norms a
+    branch's input AND its output (``<name>`` and ``<name>_post``);
+    `ffn_types` — one FFN kind a layer, ``"dense"`` | ``"routed"``;
+    `expert_d_ff` — a routed expert's width (default `d_ff`);
+    `shared_d_ff` — the width of one expert every token passes;
+    `router_score` ``"softmax"`` | ``"sigmoid"``; `router_bias` adds
+    ``l<i>_router_bias (num_experts,)`` to the scores for the choice only;
+    `route_norm` renormalises the chosen scores, `route_scale` multiplies
+    them; `held_experts` ``(first, count)`` — the experts whose matrices
+    this model holds, one chip's share: the router stays `num_experts`
+    wide."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
                  d_ff=None, max_len=64, dropout=0.0, norm="layer",
@@ -394,23 +606,52 @@ class TransformerLM:
                  mamba_state=0, mamba_groups=1, mamba_conv=4,
                  mamba_chunk=256, block_norm="input", linear_heads=0,
                  linear_key_dim=0, linear_value_dim=0, linear_conv=4,
-                 linear_chunk=64, linear_neg_eigval=True):
-        if d_model % num_heads:
+                 linear_chunk=64, linear_neg_eigval=True, head_dim=None,
+                 sliding_window=0, out_gate=False, ffn_types=None,
+                 expert_d_ff=None, shared_d_ff=0, router_score="softmax",
+                 router_bias=False, route_norm=False, route_scale=1.0,
+                 held_experts=None):
+        if head_dim is None and d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
         if norm not in ("layer", "rms"):
             raise ValueError("norm must be 'layer' or 'rms', got %r" % norm)
-        if positions not in ("learned", "rotary", "none"):
-            raise ValueError("positions must be 'learned', 'rotary' or "
-                             "'none', got %r" % positions)
+        # one position signal for the model, or one an attention kind
+        kind_positions = dict.fromkeys(("attention", "window_attention"),
+                                       positions)
+        if isinstance(positions, dict):
+            kind_positions.update(dict.fromkeys(kind_positions, "none"),
+                                  **positions)
+            if (set(kind_positions.values()) - {"rotary", "none"}
+                    or len(kind_positions) != 2):
+                raise ValueError(
+                    "positions by kind must map 'attention' / "
+                    "'window_attention' to 'rotary' or 'none', got %r"
+                    % (positions,))
+        elif positions not in ("learned", "rotary", "none"):
+            raise ValueError("positions must be 'learned', 'rotary', 'none' "
+                             "or a dict by attention kind, got %r"
+                             % (positions,))
         if num_experts and not 0 < experts_per_token <= num_experts:
             raise ValueError("experts_per_token=%d must be in 1..%d"
                              % (experts_per_token, num_experts))
         if ffn not in ("relu", "swiglu"):
             raise ValueError("ffn must be 'relu' or 'swiglu', got %r" % ffn)
-        if block_norm not in ("input", "output"):
-            raise ValueError("block_norm must be 'input' or 'output', got %r"
-                             % block_norm)
+        if block_norm not in ("input", "output", "both"):
+            raise ValueError("block_norm must be 'input', 'output' or "
+                             "'both', got %r" % block_norm)
+        if qk_norm == "head" and norm != "rms":
+            raise ValueError("qk_norm='head' is an RMSNorm: needs norm='rms'")
+        if router_score not in ("softmax", "sigmoid"):
+            raise ValueError("router_score must be 'softmax' or 'sigmoid', "
+                             "got %r" % router_score)
+        if held_experts is not None:
+            first, count = (int(n) for n in held_experts)
+            if not 0 <= first < first + count <= num_experts:
+                raise ValueError("held_experts=%r must be (first, count) "
+                                 "within num_experts=%d"
+                                 % (held_experts, num_experts))
+            held_experts = (first, count)
         num_kv_heads = num_heads if num_kv_heads is None else int(num_kv_heads)
         if num_kv_heads < 1 or num_heads % num_kv_heads:
             raise ValueError("num_heads=%d not a multiple of num_kv_heads=%d"
@@ -433,19 +674,33 @@ class TransformerLM:
             raise ValueError("a 'linear_attention' layer needs linear_heads, "
                              "linear_key_dim, linear_value_dim >= 1 and "
                              "linear_conv >= 2")
+        if "window_attention" in layer_types and sliding_window < 1:
+            raise ValueError("a 'window_attention' layer needs "
+                             "sliding_window >= 1")
+        ffn_types = ((("routed" if num_experts else "dense"),)
+                     * int(num_layers) if ffn_types is None
+                     else tuple(ffn_types))
+        if len(ffn_types) != int(num_layers) or set(ffn_types) - set(_FFNS):
+            raise ValueError("ffn_types must name num_layers=%d kinds of %s,"
+                             " got %r" % (num_layers, sorted(_FFNS),
+                                          ffn_types))
+        if "routed" in ffn_types and not num_experts:
+            raise ValueError("a 'routed' FFN needs num_experts >= 1")
         self.vocab = int(vocab)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
         self.d_model = int(d_model)
         self.d_ff = int(d_ff) if d_ff is not None else 4 * self.d_model
-        self.d_head = self.d_model // self.num_heads
+        self.head_dim = None if head_dim is None else int(head_dim)
+        self.d_head = (self.d_model // self.num_heads if head_dim is None
+                       else self.head_dim)
         self.max_len = int(max_len)
         self.dropout = float(dropout)
         self.norm = norm
         self.norm_eps = float(norm_eps)
         self.positions = positions
         self.rope_theta = float(rope_theta)
-        self.qk_norm = bool(qk_norm)
+        self.qk_norm = qk_norm if qk_norm == "head" else bool(qk_norm)
         self.num_experts = int(num_experts)
         self.experts_per_token = int(experts_per_token)
         self.bias = bool(bias)
@@ -467,8 +722,21 @@ class TransformerLM:
         self.linear_value_dim = int(linear_value_dim)
         self.linear_conv, self.linear_chunk = int(linear_conv), int(linear_chunk)
         self.linear_neg_eigval = bool(linear_neg_eigval)
+        self.kind_positions = kind_positions
+        self.sliding_window = int(sliding_window)
+        self.out_gate = bool(out_gate)
+        self.ffn_types = ffn_types
+        self.expert_d_ff = self.d_ff if expert_d_ff is None else int(expert_d_ff)
+        self.shared_d_ff = int(shared_d_ff)
+        self.router_score = router_score
+        self.router_bias = bool(router_bias)
+        self.route_norm = bool(route_norm)
+        self.route_scale = float(route_scale)
+        self.held_experts = held_experts
         kinds = {k: _KINDS[k](self) for k in set(layer_types)}
         self._mixers = [kinds[k] for k in layer_types]
+        kinds = {k: _FFNS[k](self) for k in set(ffn_types)}
+        self._ffns = [kinds[k] for k in ffn_types]
 
     # ------------------------------------------------------------------
     # shared pieces
@@ -502,32 +770,22 @@ class TransformerLM:
 
     def _block_params(self, i):
         """Layer i's parameter variables: its mixer's, then its FFN's."""
-        d, ff = self.d_model, self.d_ff
-        v = sym.Variable
         p = self._mixers[i].params(i)
-        if self.num_experts:
-            e = self.num_experts
-            p["router_weight"] = v("l%d_router_weight" % i, shape=(d, e))
-            p["gate_weight"] = v("l%d_gate_weight" % i, shape=(e, d, ff))
-            p["down_weight"] = v("l%d_down_weight" % i, shape=(e, ff, d))
-            p["up_weight"] = v("l%d_up_weight" % i, shape=(e, d, ff))
-        else:
-            wide = 2 * ff if self.ffn == "swiglu" else ff
-            p["ffn1_weight"] = v("l%d_ffn1_weight" % i, shape=(wide, d))
-            p["ffn2_weight"] = v("l%d_ffn2_weight" % i, shape=(d, ff))
-            if self.bias:
-                p["ffn1_bias"] = v("l%d_ffn1_bias" % i, shape=(wide,))
-                p["ffn2_bias"] = v("l%d_ffn2_bias" % i, shape=(d,))
+        p.update(self._ffns[i].params(i))
         return p
 
     def _branch_in(self, h, name):
         """What a branch (mixer or FFN) reads of the stream `h`: its norm
-        `name`, or — where the block norms the branch's output — `h`."""
-        return self._norm(h, name) if self.block_norm == "input" else h
+        `name`, or — where the block norms the branch's output only — `h`."""
+        return h if self.block_norm == "output" else self._norm(h, name)
 
     def _branch_out(self, y, name):
-        """What a branch adds to the stream: `y`, or its norm `name`."""
-        return y if self.block_norm == "input" else self._norm(y, name)
+        """What a branch adds to the stream: `y`, or its norm — `name`
+        where the block norms outputs only, ``<name>_post`` where it norms
+        both ends of a branch."""
+        if self.block_norm == "input":
+            return y
+        return self._norm(y, name + "_post" * (self.block_norm == "both"))
 
     def _join(self, h, branch):
         """The residual stream plus a branch (times `residual_multiplier`)."""
@@ -536,31 +794,12 @@ class TransformerLM:
         return h + branch
 
     def _ffn(self, h, p, i, train, loads=None):
-        """The block's second half on the residual stream `h`.  A routed
-        model's serving graphs pass `loads`, which collects each layer's
+        """The block's second half on the residual stream `h`: layer i's
+        FFN kind between the block's norms.  A routed model's serving
+        graphs pass `loads`, which collects each routed layer's
         tokens-per-expert output."""
         x = self._branch_in(h, "l%d_ln2" % i)
-        if self.num_experts:
-            f = sym.MoE(x, p["router_weight"], p["gate_weight"],
-                        p["down_weight"], p["up_weight"],
-                        num_experts=self.num_experts, hidden_size=self.d_ff,
-                        k=self.experts_per_token, act_type="silu",
-                        gated=True, no_bias=True, normalize=False,
-                        return_load=loads is not None, name="l%d_moe" % i)
-            if loads is not None:
-                loads.append(f[1])
-                f = f[0]
-        elif self.ffn == "swiglu":
-            a, b = sym.SliceChannel(
-                self._linear(x, p, "ffn1", 2 * self.d_ff, "l%d_ffn1" % i),
-                num_outputs=2, axis=2, name="l%d_ffn_split" % i)
-            f = sym.Activation(a, act_type="silu", name="l%d_silu" % i) * b
-            f = self._linear(f, p, "ffn2", self.d_model, "l%d_ffn2" % i)
-        else:
-            f = sym.Activation(
-                self._linear(x, p, "ffn1", self.d_ff, "l%d_ffn1" % i),
-                act_type="relu", name="l%d_gelu" % i)
-            f = self._linear(f, p, "ffn2", self.d_model, "l%d_ffn2" % i)
+        f = self._ffns[i].apply(x, p, i, loads)
         f = self._branch_out(f, "l%d_ln2" % i)
         if train and self.dropout > 0:
             f = sym.Dropout(f, p=self.dropout, name="l%d_drop" % i)
@@ -621,14 +860,17 @@ class TransformerLM:
         sampled = sym._greedy_token(logits, last_token, slot, name="token")
         extra = []
         if loads:
+            held = (self.held_experts or (0, self.num_experts))[1]
             extra = [sym.Reshape(sym.Concat(*loads, dim=0),
-                                 shape=(self.num_layers, self.num_experts),
-                                 name="moe_load")]
+                                 shape=(len(loads), held), name="moe_load")]
         return sym.Group([logits] + rings + [sampled[1], sampled[0]] + extra)
+
+    def _routed(self):
+        return "routed" in self.ffn_types
 
     def extra_outputs(self):
         """Names of the serving graphs' outputs after the token."""
-        return ("moe_load",) if self.num_experts else ()
+        return ("moe_load",) if self._routed() else ()
 
     # ------------------------------------------------------------------
     # training
@@ -686,19 +928,23 @@ class TransformerLM:
             spec.update(mixer.cache_spec(i, slots, max_len))
         return spec
 
-    def call_counters(self, positions=0, rows=0, platform=None):
+    def call_counters(self, **call):
         """The telemetry counters that ONE serving program call adds to
         beyond the session's own, ``{name: increment}`` summed over the
-        layers whose kind declares any: a prefill of a bucket of
-        `positions`, or a decode step of `rows` real rows, of a program
-        lowered for `platform` (the session's device's).  The session
-        books them at dispatch."""
+        layers whose mixer or FFN kind declares any.  `call` says what the
+        call was, and a kind reads what concerns it: a prefill of a bucket
+        of `positions`; or a decode step of `rows` real rows at `lengths`
+        (each row's positions cached before the step) in a program of
+        `computed` rows (the pad included), with `pages` pages of cache
+        bound on the device (bound sets x slots) for sessions of
+        `max_len`; either of a program lowered for `platform` (the
+        session's device's).  The session books them at dispatch."""
         total = {}
-        for i, mixer in enumerate(self._mixers):
-            if hasattr(mixer, "counters"):
-                for name, n in mixer.counters(i, positions, rows,
-                                               platform).items():
-                    total[name] = total.get(name, 0) + n
+        for i, kinds in enumerate(zip(self._mixers, self._ffns)):
+            for kind in kinds:
+                if hasattr(kind, "counters"):
+                    for name, n in kind.counters(i, **call).items():
+                        total[name] = total.get(name, 0) + n
         return total
 
     def _cache_vars(self):
@@ -716,7 +962,7 @@ class TransformerLM:
         last_token = sym.Variable("last_token")
         caches = self._cache_vars()
         h, embed_w = self._embed(data)
-        outs, loads = [], [] if self.num_experts else None
+        outs, loads = [], [] if self._routed() else None
         for i, mixer in enumerate(self._mixers):
             p = self._block_params(i)
             x = self._branch_in(h, "l%d_ln1" % i)
@@ -744,7 +990,7 @@ class TransformerLM:
         caches = self._cache_vars()
         data = sym._token_feed(data, last_token, slot, name="token_feed")
         h, embed_w = self._embed(data, index=length)
-        outs, loads = [], [] if self.num_experts else None
+        outs, loads = [], [] if self._routed() else None
         for i, mixer in enumerate(self._mixers):
             p = self._block_params(i)
             x = self._branch_in(h, "l%d_ln1" % i)

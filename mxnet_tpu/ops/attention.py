@@ -26,6 +26,11 @@ needs four graph-level primitives beyond the classic registry:
   sessions join/leave between steps without recompiling.  WRITE, THEN
   READ: the step's K/V row goes to ``(slot, :, :, length)`` before the
   row attends to positions ``0..length``, so a token attends to itself.
+  A WINDOW layer's ring (the node's `window` W) has ``min(W, max_len)``
+  positions for a session that may be longer: the row goes to ``length
+  mod W``, a prefill longer than W writes its last W positions where
+  decode steps would have put them, and once ``length >= W`` the whole
+  ring is read — it holds exactly the window.
 
   *On a TPU* a decode step's attention is ONE kernel a layer
   (``ops/kv_ring_kernel.py``, Pallas) wherever the ring's shape gives it
@@ -73,6 +78,7 @@ training path).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import jax
@@ -118,14 +124,20 @@ def layer_norm(data, gamma, beta, axis=-1, eps=1e-5, **kw):
 
 def _infer_rms(in_shapes, attrs):
     data = in_shapes[0]
-    return [data, (data[-1],)], [data]
+    return [data, (data[-1] // int(_lit(attrs.get("num_heads", 1))),)], [data]
 
 
 @register("RMSNorm", inputs=("data", "gamma"), infer_shape=_infer_rms)
-def rms_norm(data, gamma, eps=1e-5, **kw):
+def rms_norm(data, gamma, eps=1e-5, num_heads=1, **kw):
     """Root-mean-square normalization over the last axis with a learned
     gain and no shift (Zhang & Sennrich 2019): ``x / sqrt(mean(x^2) +
-    eps) * gamma``, the statistics in float32."""
+    eps) * gamma``, the statistics in float32.  With `num_heads` the last
+    axis is that many heads side by side, each normed on its own, and
+    `gamma` is ONE head's gain, shared by all."""
+    heads = int(_lit(num_heads))
+    if heads != 1:
+        split = data.shape[:-1] + (heads, data.shape[-1] // heads)
+        return rms_norm(data.reshape(split), gamma, eps).reshape(data.shape)
     x = data.astype(jnp.float32)
     scale = lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
                       + float(_lit(eps)))
@@ -199,6 +211,13 @@ def _scaled(scores, d_head, scale):
     return scores * _lit(scale)
 
 
+def _window_scope(window):
+    """The device scope ``mx:attn.window`` around a window layer's
+    attention (HLO metadata, like ``mx:moe.*``); nothing for a full one."""
+    return (contextlib.nullcontext() if window is None
+            else jax.named_scope("mx:attn.window"))
+
+
 def _infer_sdp(in_shapes, attrs):
     q = in_shapes[0]
     num_heads = int(_lit(attrs.get("num_heads", 1)))
@@ -213,7 +232,7 @@ def _infer_sdp(in_shapes, attrs):
 @register("_sdp_attention", inputs=("query", "key", "value"),
           num_outputs=3, infer_shape=_infer_sdp)
 def sdp_attention(query, key, value, num_heads=1, causal=True, scale=None,
-                  **kw):
+                  window=None, **kw):
     """Fused multi-head scaled-dot-product attention.
 
     Inputs are the PROJECTED ``(N, T, d_model)`` tensors (the graph
@@ -221,7 +240,9 @@ def sdp_attention(query, key, value, num_heads=1, causal=True, scale=None,
     ``num_kv_heads`` < `num_heads` key and value are ``(N, T,
     num_kv_heads * d_head)`` and each K/V head serves ``num_heads /
     num_kv_heads`` consecutive query heads.  `scale` multiplies the
-    scores in place of ``1 / sqrt(d_head)``.  Outputs:
+    scores in place of ``1 / sqrt(d_head)``.  With `window` W a causal row
+    i attends to ``j <= i`` with ``i - j < W``: itself and the W - 1
+    before it.  Outputs:
 
       0. context ``(N, T, d_model)`` — heads re-merged;
       1. K per K/V head ``(N, H_kv, T, d_head)``;
@@ -243,12 +264,15 @@ def sdp_attention(query, key, value, num_heads=1, causal=True, scale=None,
     # query heads grouped over their K/V head (groups of one without
     # `num_kv_heads`); K and V are never repeated
     qg = qh.reshape(n, kv, h // kv, t, dh)
-    scores = _scaled(jnp.einsum("ngrqd,ngkd->ngrqk", qg, kh), dh, scale)
-    if _bool(causal):
-        keep = jnp.tril(jnp.ones((t, t), dtype=bool))
-        scores = jnp.where(keep, scores, _NEG)
-    ctx = jnp.einsum("ngrqk,ngkd->ngrqd", jnn.softmax(scores, axis=-1),
-                     vh).reshape(n, h, t, dh)
+    with _window_scope(window):
+        scores = _scaled(jnp.einsum("ngrqd,ngkd->ngrqk", qg, kh), dh, scale)
+        if _bool(causal):
+            keep = jnp.tril(jnp.ones((t, t), dtype=bool))
+            if window is not None:
+                keep &= ~jnp.tril(keep, -int(_lit(window)))
+            scores = jnp.where(keep, scores, _NEG)
+        ctx = jnp.einsum("ngrqk,ngkd->ngrqd", jnn.softmax(scores, axis=-1),
+                         vh).reshape(n, h, t, dh)
     return ctx.transpose(0, 2, 1, 3).reshape(n, t, d), kh, vh
 
 
@@ -312,7 +336,8 @@ def _page(cache, slot_i):
 
 
 def _write_rows(cache, rows, slot_i, len_i):
-    """``cache[slot_i[b], :, :, len_i[b]] = rows[b]`` for every packed
+    """``cache[slot_i[b], :, :, len_i[b]] = rows[b]`` (`len_i`: the ring
+    position, which a window ring's caller has wrapped) for every packed
     row, one ``dynamic_update_slice`` each, in row order: XLA keeps the
     ring's layout and — the serve program donates the rings — writes the
     donated buffer in place."""
@@ -323,17 +348,22 @@ def _write_rows(cache, rows, slot_i, len_i):
 
 
 def _ring_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i,
-                    scale=None):
+                    scale=None, wraps=False):
     """The decode step against the rings in ``jax.numpy``: what runs
     wherever the TPU kernel does not, and the kernel's oracle.  ``q (B,
     H_q, d)``, ``k_new`` / ``v_new (B, H_kv, d)`` → ``(context (B, H_q,
     d), k_cache', v_cache')``.  Rows are written first, then each row
-    reads its whole page and masks by length."""
+    reads its whole page and masks by length.  A ring that `wraps` (a
+    window layer's: W positions for a session that may be longer) takes
+    the row at ``length mod W``, over the oldest position it held; once
+    ``length >= W`` the mask keeps every position, and they are exactly
+    the window's."""
     b, h, dh = q.shape
     kv = k_new.shape[1]
-    kc = _write_rows(k_cache, k_new, slot_i, len_i)
-    vc = _write_rows(v_cache, v_new, slot_i, len_i)
     max_len = k_cache.shape[3]
+    at = len_i % max_len if wraps else len_i
+    kc = _write_rows(k_cache, k_new, slot_i, at)
+    vc = _write_rows(v_cache, v_new, slot_i, at)
     keep = jnp.arange(max_len)[None, None, :] <= len_i[:, None, None]
     # each ring head read once, by its group of query heads (groups of
     # one are plain multi-head attention)
@@ -355,9 +385,9 @@ _INTERPRET = False
 
 
 @functools.partial(jax.jit, static_argnames=("block", "heads", "scale",
-                                             "interpret"))
+                                             "interpret", "wraps"))
 def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
-                      block, heads, scale, interpret):
+                      block, heads, scale, interpret, wraps=False):
     """The decode step against the rings on whatever platform the
     program is lowered for: the TPU's kernel (with `block` positions of
     `heads` K/V heads a step; `interpret` runs it in Pallas's
@@ -365,7 +395,7 @@ def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
     ``jax.numpy`` body.  Jitted, so that the layers of a decode program,
     whose attention is one and the same, trace and lower both once."""
     operands = (q, k_new, v_new, k_cache, v_cache, slot_i, len_i)
-    body = functools.partial(_ring_attention, scale=scale)
+    body = functools.partial(_ring_attention, scale=scale, wraps=wraps)
     if block is None:
         return body(*operands)
 
@@ -373,7 +403,7 @@ def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
         from .kv_ring_kernel import ring_attention
 
         return ring_attention(*operands, block=block, heads=heads,
-                              scale=scale, interpret=interpret)
+                              scale=scale, interpret=interpret, wraps=wraps)
     return lax.platform_dependent(*operands, tpu=kernel, default=body)
 
 
@@ -382,7 +412,7 @@ def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
                   "length"),
           num_outputs=3, infer_shape=_infer_cached)
 def cached_attention(query, key, value, k_cache, v_cache, slot, length,
-                     num_heads=1, scale=None, **kw):
+                     num_heads=1, scale=None, window=None, **kw):
     """One decode step of multi-head attention against a slot-indexed
     KV ring (the PagedAttention shape: address each session's page by
     slot index, bound by length — both TRACED operands, so one compiled
@@ -407,6 +437,13 @@ def cached_attention(query, key, value, k_cache, v_cache, slot, length,
     land one after the other on its position 0 and their softmax stays
     finite — garbage nobody reads.
 
+    With `window` W the ring is a window layer's: ``min(W, max_len)``
+    positions for a session that may be longer.  The step's row goes to
+    position ``length mod W`` and the row attends to the ``min(length +
+    1, W)`` positions that are filled — the window ``i - j < W`` and
+    nothing else, because the row it overwrote was the one that had just
+    left it.
+
     Outputs: context ``(B, 1, d_model)``, updated k_cache, updated
     v_cache (functional update — the serving session threads the rings
     through every call; on TPU the donated-input path makes the update
@@ -416,35 +453,57 @@ def cached_attention(query, key, value, k_cache, v_cache, slot, length,
     dh = d // h
     kv = _kv_heads(kw, h)
     scale = None if scale is None else float(_lit(scale))
-    ctx, kc, vc = _decode_attention(
-        query.reshape(b, h, dh), key.reshape(b, kv, dh),
-        value.reshape(b, kv, dh), k_cache, v_cache, _as_index(slot),
-        _as_index(length), scale=scale, interpret=_INTERPRET,
-        # the block a lowering for the TPU would use; which platform the
-        # program is lowered for is not known here
-        block=decode_block(k_cache.shape, "tpu", k_cache.dtype.itemsize),
-        heads=decode_heads(k_cache.shape, k_cache.dtype.itemsize))
+    with _window_scope(window):
+        ctx, kc, vc = _decode_attention(
+            query.reshape(b, h, dh), key.reshape(b, kv, dh),
+            value.reshape(b, kv, dh), k_cache, v_cache, _as_index(slot),
+            _as_index(length), scale=scale, interpret=_INTERPRET,
+            wraps=window is not None,
+            # the block a lowering for the TPU would use; which platform
+            # the program is lowered for is not known here
+            block=decode_block(k_cache.shape, "tpu", k_cache.dtype.itemsize),
+            heads=decode_heads(k_cache.shape, k_cache.dtype.itemsize))
     return ctx.reshape(b, 1, d), kc, vc
 
 
+def _kv_write_inputs(attrs):
+    """A window ring's write also takes the prompt's true `length`."""
+    names = ["k_cache", "v_cache", "k_block", "v_block", "slot"]
+    return names + ["length"] if attrs.get("window") is not None else names
+
+
 def _infer_kv_write(in_shapes, attrs):
-    kc, vc, kb, vb, slot = in_shapes
-    return [kc, kc, kb, kb, slot], [kc, kc]
+    kc, vc, kb, vb, slot = in_shapes[:5]
+    return [kc, kc, kb, kb] + [slot] * (len(in_shapes) - 4), [kc, kc]
 
 
 @register("_kv_cache_write",
           inputs=("k_cache", "v_cache", "k_block", "v_block", "slot"),
+          inputs_for=_kv_write_inputs,
           num_outputs=2, infer_shape=_infer_kv_write)
-def kv_cache_write(k_cache, v_cache, k_block, v_block, slot, **kw):
+def kv_cache_write(k_cache, v_cache, k_block, v_block, slot, length=None,
+                   window=None, **kw):
     """Prefill-side cache fill: write one request's per-head K/V block
     ``(1, H_kv, T, d_head)`` into ring slot ``slot`` at positions
     ``[0, T)``, turned to the ring's stored order ``(H_kv, d_head, T)``.
     Positions beyond the request's true length hold
     garbage from the padded prefill — safe by construction: decode
     masks by length and OVERWRITES position `length` before the mask
-    ever exposes it."""
+    ever exposes it.
+
+    A `window` layer's ring may be SHORTER than the bucket.  It then
+    takes the last positions of the prompt's true `length` n, each where
+    a decode step would have put it: ring position r holds the newest
+    ``p < n`` with ``p mod W == r``."""
     slot_i = _as_index(slot).reshape(())
     start = (slot_i, 0, 0, 0)
+    ring = k_cache.shape[3]
+    if window is not None and k_block.shape[2] > ring:
+        r = jnp.arange(ring)
+        n = _as_index(length).reshape(())
+        newest = r + ring * jnp.maximum((n - 1 - r) // ring, 0)
+        k_block, v_block = (jnp.take(block, newest, axis=2)
+                            for block in (k_block, v_block))
     return (lax.dynamic_update_slice(k_cache, k_block.swapaxes(2, 3), start),
             lax.dynamic_update_slice(v_cache, v_block.swapaxes(2, 3), start))
 

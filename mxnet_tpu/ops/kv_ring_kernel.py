@@ -16,6 +16,19 @@ i * block)``; slot and length are scalar-prefetched, and for `i` beyond the bloc
 not fetch again, and the body does nothing: the blocks beyond are
 SKIPPED, not masked.
 
+**A ring that wraps** (`wraps`: a window layer's, W positions for a
+session that may grow past them; ``TransformerLM.cache_spec`` gives each
+ring its own length and this one kernel serves both).  The step's row
+goes to position ``length mod W``, so the block that is WRITTEN, ``(length
+mod W) // block``, is no longer the last one READ, ``min(length // block,
+W / block - 1)``: once ``length >= W`` every block is read, the mask
+``position <= length`` keeps all of it, and what the ring then holds is
+exactly the window, the new row having taken the place of the one that
+just left it.  The row is put in and sent back in the grid step that
+holds its block and the DMA is awaited at the end of that same step;
+the output is finished in the last block's.  Without `wraps` the two
+blocks are one and the program is the one it was.
+
 Positions on the lanes make the two reductions cheap on the vector unit —
 scores reduce over d_head on the sublanes, the context accumulates over
 positions elementwise and is lane-reduced once a row — and make the new
@@ -55,14 +68,18 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
             q_ref, kn_ref, vn_ref, k_ref, v_ref,
             o_ref, ko_hbm, vo_hbm,
             qb_ref, s_ref, m_ref, l_ref, a_ref, acc_ref, sem,
-            *, h_kv, groups, d_head, blk, scale):
+            *, h_kv, groups, d_head, blk, nblk, scale, wraps):
     # h_kv: the K/V heads of THIS grid step's head group
     b, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     h_q = h_kv * groups
     chunks = blk // _LANE
     per = _LANE // d_head                 # heads a tile of 128 lines
     length = len_ref[b]
-    last = length // blk
+    # where the step's row goes, the block that holds it, the last block
+    # the row reads: one block unless the ring wraps
+    at = length % (nblk * blk) if wraps else length
+    put = at // blk
+    last = jnp.minimum(length // blk, nblk - 1) if wraps else put
 
     def column(heads_ref, h):
         """Head `h` of a row's ``(1, 1, d_head, H)`` operand, each value
@@ -87,17 +104,17 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
             qb_ref[h] = column(q_ref, h)
         unrolled(h_q, query)
 
-    @pl.when(i == last)
+    @pl.when(i == put)
     def _write_row():
         # the step's row is ONE LANE of this block: put it in where the
         # block lies in VMEM (a masked store a vector) and send the 128
         # positions around it back to the ring; the DMAs run under the
         # arithmetic below and are awaited at the end of this grid step,
         # before the pipeline may fetch into this buffer again
-        at = length % blk
-        chunk = pl.ds(pl.multiple_of(at // _LANE * _LANE, _LANE), _LANE)
+        off = at % blk
+        chunk = pl.ds(pl.multiple_of(off // _LANE * _LANE, _LANE), _LANE)
         lane = lax.broadcasted_iota(jnp.int32, (d_head, _LANE), 1) \
-            == at % _LANE
+            == off % _LANE
 
         def row(g):
             for new, block in ((kn_ref, k_ref), (vn_ref, v_ref)):
@@ -108,7 +125,7 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
             pltpu.make_async_copy(
                 block.at[0, :, :, chunk],
                 ring.at[slot_ref[b], pl.ds(j * h_kv, h_kv), :,
-                        pl.ds(pl.multiple_of(length // _LANE * _LANE, _LANE),
+                        pl.ds(pl.multiple_of(at // _LANE * _LANE, _LANE),
                               _LANE)],
                 sem.at[n]).start()
 
@@ -158,6 +175,13 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
             return carry
         lax.fori_loop(0, h_kv, context, 0)
 
+    def row_sent():
+        for n, ring in enumerate((ko_hbm, vo_hbm)):
+            pltpu.make_async_copy(  # the wait needs the shapes only
+                k_ref.at[0, :, :, pl.ds(0, _LANE)],
+                ring.at[0, pl.ds(0, h_kv), :, pl.ds(0, _LANE)],
+                sem.at[n]).wait()
+
     @pl.when(i == last)
     def _finish_row():
         # context[h, j] = sum over the lanes of acc[h, j, :] / l[h], 128
@@ -174,15 +198,16 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
             o_ref[0, :, pl.ds(pl.multiple_of(t * _LANE, _LANE), _LANE)] = (
                 jnp.sum(lines.T, axis=0, keepdims=True) / norm)
         unrolled(h_q // per, tile)
-        for n, ring in enumerate((ko_hbm, vo_hbm)):
-            pltpu.make_async_copy(  # the wait needs the shapes only
-                k_ref.at[0, :, :, pl.ds(0, _LANE)],
-                ring.at[0, pl.ds(0, h_kv), :, pl.ds(0, _LANE)],
-                sem.at[n]).wait()
+        if not wraps:     # the block that was written: the program it was
+            row_sent()
+
+    if wraps:
+        pl.when(i == put)(row_sent)
 
 
 def ring_attention(q, k_new, v_new, k_cache, v_cache, slot, length, *,
-                   block, heads=None, scale=None, interpret=False):
+                   block, heads=None, scale=None, interpret=False,
+                   wraps=False):
     """``q (B, H_q, d)``, ``k_new`` / ``v_new (B, H_kv, d)``, rings
     ``(slots, H_kv, d, max_len)``, ``slot`` / ``length (B,)`` int32 →
     ``(context (B, H_q, d), k_cache', v_cache')`` with the rings updated
@@ -191,7 +216,8 @@ def ring_attention(q, k_new, v_new, k_cache, v_cache, slot, length, *,
     (``ops.attention.decode_block`` / ``decode_heads``, which also say
     for which rings the kernel's tiling holds: ``d_head`` divides 128,
     a group's heads fill whole 128-line tiles); `interpret` runs
-    Pallas's interpreter.
+    Pallas's interpreter; `wraps`: the ring is a window's, written at
+    ``length mod max_len`` and read whole once it is full.
     The caller jits (``ops.attention._decode_attention``): the layers of
     a decode program share one trace and one lowering of this."""
     bsz, h_q, d_head = q.shape
@@ -220,7 +246,8 @@ def ring_attention(q, k_new, v_new, k_cache, v_cache, slot, length, *,
     f32 = jnp.float32
     ctx, kc, vc = pl.pallas_call(
         functools.partial(_kernel, h_kv=held, groups=groups,
-                          d_head=d_head, blk=blk, scale=scale),
+                          d_head=d_head, blk=blk, nblk=nblk, scale=scale,
+                          wraps=wraps),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bsz, parts, nblk),

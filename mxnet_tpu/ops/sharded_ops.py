@@ -31,7 +31,12 @@ single-device numerics are identical by construction.
 
 Without `capacity_factor` the op is the DROPLESS layer open decoders
 run (OLMoE: `gated`, `no_bias`, `act_type="silu"`, `normalize=False`):
-sort-and-segment (parallel/moe.py dropless_experts), no [T, E, C].
+sort-and-segment (parallel/moe.py dropless_experts), no [T, E, C].  That
+form also takes what later routers brought: `score_func="sigmoid"`, a
+`select_bias` operand that moves the choice and not the weights,
+`route_scale`, an always-on shared expert (`shared_size`), and
+`held_first` / `held_count` — WHICH of the `num_experts` the expert
+operands are, one chip's share of an expert-parallel layer.
 """
 from __future__ import annotations
 
@@ -43,16 +48,32 @@ from .tensor import _lit
 
 
 def _moe_inputs(attrs):
-    """data, the router, then expert matrix i (and its bias): 1 = in,
-    2 = out, 3 = the gated branch's second in-projection."""
+    """data, the router (and its selection bias), then expert matrix i
+    (and its bias): 1 = in, 2 = out, 3 = the gated branch's second
+    in-projection; then the shared expert's matrices in the same order."""
     gated = _bool_attr(attrs.get("gated", False))
     no_bias = _bool_attr(attrs.get("no_bias", False))
     names = ["data", "gate_weight"]
+    if _bool_attr(attrs.get("select_bias", False)):
+        names.append("select_bias")
     for i in (1, 2, 3) if gated else (1, 2):
         names.append("expert%d_weight" % i)
         if not no_bias:
             names.append("expert%d_bias" % i)
+    if int(_lit(attrs.get("shared_size", 0))):
+        names += ["shared%d_weight" % i for i in ((1, 2, 3) if gated
+                                                  else (1, 2))]
     return names
+
+
+def _held(attrs):
+    """(first, count) of the experts the expert operands are: all
+    `num_experts` unless the node says `held_count`."""
+    total = int(_lit(attrs["num_experts"]))
+    count = attrs.get("held_count")
+    if count is None:
+        return 0, total
+    return int(_lit(attrs.get("held_first", 0))), int(_lit(count))
 
 
 def _moe_outputs(attrs):
@@ -61,13 +82,18 @@ def _moe_outputs(attrs):
 
 def _infer_moe(in_shapes, attrs):
     data = in_shapes[0]
-    E = int(_lit(attrs["num_experts"]))
+    total = int(_lit(attrs["num_experts"]))
+    E = _held(attrs)[1]
     H = int(_lit(attrs["hidden_size"]))
+    S = int(_lit(attrs.get("shared_size", 0)))
     D = data[-1]
-    by_slot = {"data": data, "gate_weight": (D, E),
+    by_slot = {"data": data, "gate_weight": (D, total),
+               "select_bias": (total,),
                "expert1_weight": (E, D, H), "expert1_bias": (E, H),
                "expert2_weight": (E, H, D), "expert2_bias": (E, D),
-               "expert3_weight": (E, D, H), "expert3_bias": (E, H)}
+               "expert3_weight": (E, D, H), "expert3_bias": (E, H),
+               "shared1_weight": (D, S), "shared2_weight": (S, D),
+               "shared3_weight": (D, S)}
     outs = [tuple(data)] + [(E,)] * (_moe_outputs(attrs) - 1)
     return [by_slot[n] for n in _moe_inputs(attrs)], outs
 
@@ -90,9 +116,11 @@ def _constrain(x, mesh, spec):
     input_axes={"expert%d_%s" % (i, w): "expert"
                 for i in (1, 2, 3) for w in ("weight", "bias")},
 )
-def moe(data, gate_weight, *experts, num_experts, hidden_size, k=2,
+def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
         capacity_factor=None, act_type="relu", gated=False, no_bias=False,
-        normalize=True, return_load=False, mesh=None, **kw):
+        normalize=True, return_load=False, score_func="softmax",
+        select_bias=False, route_scale=1.0, shared_size=0, mesh=None,
+        **kw):
     """Top-k routed expert FFN: out[t] = sum_e gate[t,e] * FFN_e(x[t])
     over t's top-k experts, FFN_e = ``act(x w1 + b1) @ w2 + b2``, or with
     `gated` ``(act(x w1 + b1) * (x w3 + b3)) @ w2 + b2``; `no_bias`
@@ -107,7 +135,19 @@ def moe(data, gate_weight, *experts, num_experts, hidden_size, k=2,
     Transformer semantics) — the shape-static GShard form whose
     expert-major tensors the 'expert' mesh axis shards.  The router runs
     in float32 at `highest` precision in both.  `return_load` adds a
-    second output, tokens per expert [E]."""
+    second output, tokens per expert [E].
+
+    The dropless form's further options (absent = as before):
+    `score_func` ``"sigmoid"`` scores each expert on its own;
+    `select_bias` adds the operand ``select_bias [num_experts]`` to the
+    scores for the CHOICE of the k alone; `route_scale` multiplies the
+    weights; `shared_size` S adds ``shared{1,2,3}_weight`` (``[D, S]``,
+    ``[S, D]``, ``[D, S]``), one more FFN of the same form that every
+    token passes, unweighted; `held_first` / `held_count` say that the
+    expert operands are experts ``held_first .. held_first + held_count``
+    of the `num_experts` the router scores — the choice and the weights
+    stay over all of them, pairs of absent experts add nothing, and the
+    load is over the experts held."""
     from ..parallel import moe as _moe
     from ..parallel.mesh import P
 
@@ -116,9 +156,25 @@ def moe(data, gate_weight, *experts, num_experts, hidden_size, k=2,
     gated, no_bias = _bool_attr(gated), _bool_attr(no_bias)
     normalize = _bool_attr(normalize)
     act = str(_lit(act_type))
+    operands = list(operands)
+    bias = operands.pop(0) if _bool_attr(select_bias) else None
+    shared = ()
+    if int(_lit(shared_size)):
+        n_shared = 3 if gated else 2
+        operands, shared = operands[:-n_shared], operands[-n_shared:]
     step = 1 if no_bias else 2
-    weights = experts[0::step]
-    biases = None if no_bias else experts[1::step]
+    weights = operands[0::step]
+    biases = None if no_bias else operands[1::step]
+    held = _held(dict(kw, num_experts=num_experts))
+    options = dict(score=str(_lit(score_func)), select_bias=bias,
+                   scale=float(_lit(route_scale)),
+                   held=None if held == (0, E) else held)
+    if capacity_factor is not None and (
+            shared or bias is not None or options["held"]
+            or (options["score"], options["scale"]) != ("softmax", 1.0)):
+        raise ValueError("MoE: score_func, select_bias, route_scale, "
+                         "shared_size and held_count are the dropless "
+                         "form's (no capacity_factor)")
     lead = data.shape[:-1]
     d_model = data.shape[-1]
     x = data.reshape(-1, d_model)
@@ -129,7 +185,13 @@ def moe(data, gate_weight, *experts, num_experts, hidden_size, k=2,
     if capacity_factor is None:
         with jax.named_scope("mx:moe.experts"):
             out, load = _moe.dropless_experts(
-                x, logits, kk, weights, biases, act, gated, normalize)
+                x, logits, kk, weights, biases, act, gated, normalize,
+                **options)
+        if shared:
+            with jax.named_scope("mx:moe.shared"):
+                out = out + _moe.expert_ffn(
+                    lambda r, w: r @ w.astype(r.dtype), x, shared, None,
+                    act, gated)
     else:
         capacity = max(1, int(float(_lit(capacity_factor)) * kk * T // E))
         ep = mesh is not None and "expert" in mesh.axis_names

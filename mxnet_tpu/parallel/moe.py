@@ -66,7 +66,8 @@ def expert_ffn(matmul, x, weights, biases, act, gated):
 
 
 def dropless_experts(x, logits, k, weights, biases=None, act="relu",
-                     gated=False, normalize=True):
+                     gated=False, normalize=True, score="softmax",
+                     select_bias=None, scale=1.0, held=None):
     """Every token through its k best experts, none dropped.
 
     x [T, D]; logits [T, E] router scores (softmax here, float32);
@@ -77,16 +78,44 @@ def dropless_experts(x, logits, k, weights, biases=None, act="relu",
     return to token order weighted by the router score (renormalised
     over the k kept when `normalize`).  Returns (out [T, D], load [E]
     — tokens per expert, float32).  Differentiable in x, probs and the
-    expert parameters."""
+    expert parameters.
+
+    `score` ``"sigmoid"`` scores each expert on its own (and renormalises
+    with 1e-20 under the sum, as the models that route so do);
+    `select_bias [E]` is added for the CHOICE of the k only, the weights
+    stay the scores; `scale` multiplies the weights.  `held` ``(first,
+    count)`` says WHICH experts the weights are — rows ``first ..
+    first + count`` of the E the router scores, one chip's share of an
+    expert-parallel layer: the choice and the weights are over all E, the
+    pairs whose expert lives elsewhere add nothing here, and `load` is
+    ``[count]``, over the experts held."""
     t_len, n_exp = logits.shape
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_w, top_e = lax.top_k(probs, k)                     # [T, k]
+    if score == "sigmoid":
+        probs = jax.nn.sigmoid(logits.astype(jnp.float32))
+    else:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if select_bias is None:
+        top_w, top_e = lax.top_k(probs, k)                 # [T, k]
+    else:
+        _, top_e = lax.top_k(probs + select_bias.astype(jnp.float32), k)
+        top_w = jnp.take_along_axis(probs, top_e, axis=-1)
     if normalize:
-        top_w = top_w / top_w.sum(-1, keepdims=True)
+        total = top_w.sum(-1, keepdims=True)
+        top_w = top_w / (total + 1e-20 if score == "sigmoid" else total)
+    if scale != 1.0:
+        top_w = top_w * scale
     flat_e = top_e.reshape(-1)
+    if held is not None:
+        # pairs of experts held elsewhere sort behind every segment, at
+        # index `count`: no expert multiplies them, and their rows of the
+        # result — whatever a segment matmul leaves beyond its segments,
+        # zeros on the CPU and stale memory on a TPU — are set to 0
+        first, n_exp = held
+        flat_e = jnp.where((flat_e >= first) & (flat_e < first + n_exp),
+                           flat_e - first, n_exp)
     order = jnp.argsort(flat_e, stable=True)               # pair -> sorted
     sorted_e = flat_e[order]
-    load = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1)
+    load = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1, mode="drop")
     rows = x[order // k]                                   # [T*k, D]
 
     def matmul(r, w):
@@ -96,6 +125,8 @@ def dropless_experts(x, logits, k, weights, biases=None, act="relu",
                     None if biases is None
                     else [b.astype(x.dtype)[sorted_e] for b in biases],
                     act, gated)
+    if held is not None:
+        ys = jnp.where((sorted_e < n_exp)[:, None], ys, 0)
     pairs = ys[jnp.argsort(order)].reshape(t_len, k, -1)   # token order
     # on the vector unit: a matmul would round the scores to bfloat16
     out = (pairs * top_w.astype(pairs.dtype)[:, :, None]).sum(1)

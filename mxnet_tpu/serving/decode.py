@@ -25,7 +25,10 @@ many decode iterations — and two program families:
 between calls is whatever the MODEL's ``cache_spec(slots, max_len)``
 states — an ordered ``{name: CacheEntry(kind, shape)}`` the session never
 spells out: per attention layer two KV RINGS (kind ``"ring"``:
-``max_sessions + 1`` pages of ``max_len`` positions), per state-space
+``max_sessions + 1`` pages of as many positions as the layer's kind
+keeps — the session's ``max_len``, or a window layer's window, which a
+session outgrows and then writes modulo — every length read off the
+entry's own shape), per state-space
 layer a conv window and a recurrent STATE (kind ``"state"``: a fixed size
 a slot, whatever the context).  Every entry is preallocated, threaded,
 donated, booked and charged the same way; the kinds differ in what may
@@ -302,7 +305,11 @@ class GenerativeSession:
         self._cache_bytes = sum(e.nbytes for e in self._spec.values())
         self._state_bytes = sum(e.nbytes for e in self._spec.values()
                                 if e.kind == "state")
-        self._has_ring = any(e.kind == "ring" for e in self._spec.values())
+        # every ring's own positions a page (a window layer's are fewer
+        # than `max_len`); counters of positions are means over the rings
+        rings = [e.shape for e in self._spec.values() if e.kind == "ring"]
+        self._ring_lens = _np.asarray([r[3] for r in rings], _np.int64)
+        self._has_ring = bool(rings)
         # a routed model's programs end with tokens per (layer, expert)
         self._reports_moe_load = "moe_load" in tuple(
             getattr(model, "extra_outputs", tuple)())
@@ -332,25 +339,25 @@ class GenerativeSession:
             graphs[False], dict(params),
             self._shapes(self._decode_ladder[0], 1, prefill=False),
             ctx=ctx)
-        # positions of a page that one step of the decode program's
-        # attention reads at a time, where it stops at the block that
-        # holds `length`; None where it reads whole pages (the CPU)
-        self._ring_block = None
         # the platform the programs are lowered for: what the layer kinds'
-        # counters and the ring's block are asked with
+        # counters and the rings' blocks are asked with
         self._platform = self._decode_pred._ctx.jax_device().platform
-        if self._has_ring:
-            from ..ops.attention import decode_block
+        # positions of each ring's page that one step of the decode
+        # program's attention reads at a time, where it stops at the
+        # block that holds `length`; the whole page where it reads whole
+        # pages (the CPU)
+        from ..ops.attention import decode_block
 
-            ring = next(e for e in self._spec.values() if e.kind == "ring")
-            self._ring_block = decode_block(ring.shape, self._platform)
-            if self._ring_block:
-                # the decode programs will lower the kernel: importing
-                # Pallas is over a second of Python, spent here beside
-                # the prefill programs' compiles instead of after them
-                threading.Thread(
-                    target=importlib.import_module, daemon=True,
-                    args=("mxnet_tpu.ops.kv_ring_kernel",)).start()
+        blocks = [decode_block(r, self._platform) for r in rings]
+        self._ring_blocks = _np.asarray(
+            [b or r[3] for b, r in zip(blocks, rings)], _np.int64)
+        if any(blocks):
+            # the decode programs will lower the kernel: importing
+            # Pallas is over a second of Python, spent here beside
+            # the prefill programs' compiles instead of after them
+            threading.Thread(
+                target=importlib.import_module, daemon=True,
+                args=("mxnet_tpu.ops.kv_ring_kernel",)).start()
         # the device-resident state, threaded through every call
         self._state = self._fresh_state()
         self._free = list(range(self._slots))  # LIFO slot pool
@@ -629,11 +636,17 @@ class GenerativeSession:
         else:
             telemetry.inc("serving.device.decode_seen", weight)
 
+    def _per_ring(self, total):
+        """`total`, a sum over the rings, as the mean a ring: a whole
+        number wherever every ring has one length."""
+        mean, rest = divmod(int(total), len(self._ring_lens))
+        return total / len(self._ring_lens) if rest else mean
+
     def _book_call(self, **call):
         """The counters the model's layer kinds add for one program call
         (``call_counters(positions=...)`` of a prefill bucket, ``(rows=
-        ...)`` of a decode step), told the platform its programs are
-        lowered for."""
+        ..., lengths=..., ...)`` of a decode step), told the platform its
+        programs are lowered for."""
         from .. import telemetry
 
         if telemetry.enabled() and self._call_counters is not None:
@@ -802,24 +815,32 @@ class GenerativeSession:
             sets = 1 + len(self._programs)
             telemetry.inc("cache.reserved_bytes", sets * self._cache_bytes)
             telemetry.inc("cache.state_bytes", sets * self._state_bytes)
-            self._book_call(rows=n)
+            pages = sets * (self._slots + 1)
+            filled = length[:n].astype(_np.int64)
+            self._book_call(rows=n, lengths=filled.tolist(), computed=bucket,
+                            pages=pages, max_len=self._max_len)
             if self._has_ring:
-                # position-steps: over a window their ratio is the mean
-                # reserved over used.  Reserved are a ring set's pages
-                # times their length, in every bound set; used are the
-                # positions the packed sessions had filled when the step
-                # was packed.  A model with no ring has neither
+                # position-steps, each the mean over the rings (whose
+                # lengths differ where the model has window layers): over
+                # a window their ratio is the mean reserved over used.
+                # Reserved are a ring's pages times its length, in every
+                # bound set; used are the positions the packed sessions
+                # had filled — at most a ring's own length — when the
+                # step was packed.  A model with no ring has neither
+                lens, blks = self._ring_lens[:, None], self._ring_blocks[:, None]
+                whole = int(lens.sum())
                 telemetry.inc("kv.reserved_positions",
-                              sets * (self._slots + 1) * self._max_len)
-                telemetry.inc("kv.used_positions", int(length.sum()))
+                              self._per_ring(pages * whole))
+                telemetry.inc("kv.used_positions", self._per_ring(
+                    _np.minimum(filled, lens).sum()))
                 # what the dispatched program's attention reads of the
                 # packed rows' pages: all of each, or the blocks up to
-                # the one that holds `length` (ops/attention.py)
-                blk = self._ring_block or self._max_len
-                read = (length[:n].astype(_np.int64) // blk + 1) * blk
-                telemetry.inc("kv.page_positions", n * self._max_len)
+                # the one that holds `length` — every block of a ring
+                # that has wrapped (ops/attention.py)
+                read = _np.minimum((filled // blks + 1) * blks, lens)
+                telemetry.inc("kv.page_positions", self._per_ring(n * whole))
                 telemetry.inc("kv.skipped_positions",
-                              int(n * self._max_len - read.sum()))
+                              self._per_ring(n * whole - read.sum()))
 
     def _emit(self, sess, token):
         """Book one sampled token; retire on EOS / budget / ring-full."""

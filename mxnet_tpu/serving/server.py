@@ -188,8 +188,10 @@ class ModelServer:
         # and recurrent state alike, each by its own bytes) — so refusal
         # happens before any compile or ring allocation.  The prefill and
         # the decode predictor bind the arrays they are given: NDArrays
-        # already on the tenant's device are held once, anything else is
-        # placed by each predictor for itself
+        # already on the tenant's device are held once — and what the live
+        # census has booked of them is in the bytes `admit` adds to the
+        # prediction, so it is not predicted a second time — anything else
+        # is placed by each predictor for itself
         from .. import config
         from ..ndarray import NDArray
         from ..obs import memory
@@ -205,8 +207,9 @@ class ModelServer:
         ring_len = min(ring_len, int(model.max_len))
         cache_bytes = sum(entry.nbytes for entry in
                           model.cache_spec(slots + 1, ring_len).values())
+        live = sum(v._mem_booked for v in params.values()) if shared else 0
         memory.admit("generative tenant %r" % name,
-                     (1 if shared else 2) * param_bytes + cache_bytes,
+                     (1 if shared else 2) * param_bytes - live + cache_bytes,
                      device=ctx.jax_device())
         # build outside the lock — Predictor construction compiles the
         # smallest prefill/decode buckets and must not stall submits

@@ -40,7 +40,15 @@ TINY = {
                                 linear_heads=2, linear_key_dim=8,
                                 linear_value_dim=16, norm="rms",
                                 positions="none", bias=False,
-                                block_norm="output")]},
+                                block_norm="output"),
+                           dict(num_heads=4, num_kv_heads=2, head_dim=16,
+                                max_len=48,
+                                layer_types=["window_attention",
+                                             "attention"],
+                                sliding_window=16, norm="rms",
+                                positions={"window_attention": "rotary"},
+                                qk_norm="head", out_gate=True,
+                                bias=False)]},
     "kernel": {"shapes": [(4, 4, 4, 64), (8, 2, 2, 256)], "seed": 3},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
@@ -82,10 +90,12 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
                              TINY[name], ctx, clock, report)
     # two layers' K and V rings of both attention-only shapes, then a
     # delta-rule layer's window and state beside one layer's rings, found
-    # in the compiled decode programs; the CPU's programs hold no kernel
-    # call
-    assert report["kv_ring"]["ring_params"] == 8 + 4
+    # in the compiled decode programs, then a window layer's rings of 16
+    # positions beside a full layer's of 48; the CPU's programs hold no
+    # kernel call
+    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
+                                          [3, 2, 16, 48], [3, 2, 16, 16],
                                           [3, 2, 16, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
     # the third shape's longest prefill bucket, read for the delta rule:
@@ -95,7 +105,7 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
          "kernel_layers": 0}]
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
-        ["16", "8"], ["8"], ["8"]]
+        ["16", "8"], ["8"], ["8"], ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
     monkeypatch.setattr(pk, "_INTERPRET", True)

@@ -15,15 +15,20 @@ import chip_smoke
 from mxnet_tpu.ops import attention, gdn
 
 SLOTS = 9
-# (rows, query heads, K/V heads, d_head, ring length, scale)
+# (rows, query heads, K/V heads, d_head, ring length, scale[, wraps]);
+# Trinity's two: a full layer's ring of the session's 6,144 positions and
+# a window layer's of 2,048, which wraps
 SHAPES = {"opt": (8, 32, 32, 64, 768, None),
           "olmoe": (8, 16, 16, 128, 768, None),
           "granite": (8, 32, 8, 64, 2304, 1 / 64),
           "olmo_hybrid": (8, 30, 30, 128, 2304, None),
-          "one_row": (1, 32, 32, 64, 768, None)}
+          "one_row": (1, 32, 32, 64, 768, None),
+          "trinity_full": (8, 32, 4, 128, 6144, None),
+          "trinity_window": (8, 32, 4, 128, 2048, None, True)}
 # positions and K/V heads a block, by the ring's bytes alone
 BLOCKS = {"opt": (128, 32), "olmoe": (128, 16), "granite": (384, 8),
-          "olmo_hybrid": (128, 15), "one_row": (128, 32)}
+          "olmo_hybrid": (128, 15), "one_row": (128, 32),
+          "trinity_full": (512, 4), "trinity_window": (512, 4)}
 
 
 @pytest.fixture(scope="module")
@@ -58,7 +63,7 @@ def test_the_decode_attention_compiles_for_a_v5e(name, one_chip):
     import jax
     import jax.numpy as jnp
 
-    rows, h_q, h_kv, d_head, max_len, scale = SHAPES[name]
+    rows, h_q, h_kv, d_head, max_len, scale, *wraps = SHAPES[name]
     ring = (SLOTS, h_kv, d_head, max_len)
     block = attention.decode_block(ring, "tpu")
     heads = attention.decode_heads(ring)
@@ -70,7 +75,7 @@ def test_the_decode_attention_compiles_for_a_v5e(name, one_chip):
     def step(*operands):
         return attention._decode_attention(*operands, block=block,
                                            heads=heads, scale=scale,
-                                           interpret=False)
+                                           interpret=False, wraps=bool(wraps))
 
     compiled = jax.jit(step, donate_argnums=(3, 4)).lower(
         arg((rows, h_q, d_head)), arg((rows, h_kv, d_head)),

@@ -1022,7 +1022,7 @@ def test_page_and_skipped_position_counters(block, monkeypatch):
     gs = GenerativeSession("lm", lm, params, max_sessions=2, max_len=max_len,
                            max_decode_tokens=16, seq_buckets=[8, 128])
     try:
-        assert gs._ring_block == block
+        assert set(gs._ring_blocks.tolist()) == {block or max_len}
         # lengths 5..9 stay in the first block; 126..130 cross into the
         # second at the third of five steps
         reqs = [GenerateRequest("lm", [1 + i % 20 for i in range(n)], 60.0, 6)
