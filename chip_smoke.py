@@ -702,6 +702,54 @@ def phase_kernel(sizes, ctx):
             "max_rel_err": float("%.3g" % worst)}
 
 
+def _staged_block_facts(mx, exe, it, devices, sizes):
+    """One K-step block staged from the NDArrayIter `it`, as fit's
+    steps_per_dispatch > 1 stages it.  Beside an accelerator the batches
+    lie in host memory and every step array must reach each chip as that
+    chip's own rows (`io.stage.host_parts`, no `device_parts`), so the
+    first chip's memory grows by what the others' grows by — to within
+    one step's piece: no whole batch was parked there.  Where the CPU
+    backend computes, host and device are one platform and the arrays
+    come the device's way."""
+    import jax
+
+    from mxnet_tpu import telemetry
+
+    def parts():
+        return (telemetry.counter_value("io.stage.host_parts"),
+                telemetry.counter_value("io.stage.device_parts"))
+
+    def in_use():
+        return [(d.memory_stats() or {}).get("bytes_in_use") for d in devices]
+
+    it.reset()
+    parts0, before = parts(), in_use()
+    staging = mx.io.DeviceStagedIter(
+        it, steps_per_dispatch=sizes["steps"], place_fn=exe.place_step_input,
+        stack_fn=exe.stack_block_input)
+    block = staging.next()
+    jax.block_until_ready(block.data + block.label)
+    after = in_use()
+    staging.close()
+    host, device = (b - a for a, b in zip(parts0, parts()))
+    _check(block.count == sizes["steps"], "staged %d steps" % block.count)
+    arrays = 2 * block.count  # data and label of every step
+    want = (arrays, 0) if devices[0].platform != "cpu" else (0, arrays)
+    _check((host, device) == want,
+           "a staged block's step arrays came %d from host memory, %d from "
+           "a device on %s" % (host, device, devices[0].platform))
+    facts = {"host_parts": host, "device_parts": device}
+    if None not in before + after:
+        grew = [b - a for a, b in zip(before, after)]
+        piece = block.data[0].nbytes // (block.count * len(devices))
+        _check(grew[0] - max(grew[1:]) <= piece,
+               "staging grew %s by %d bytes and the others by at most %d: "
+               "more than a step's piece (%d) apart"
+               % (devices[0], grew[0], max(grew[1:]), piece))
+        facts["grew_bytes"] = grew
+    return facts
+
+
 def phase_four_chips(sizes, ctxs):
     """`ctxs`: four contexts naming four distinct devices."""
     import numpy as np
@@ -740,6 +788,7 @@ def phase_four_chips(sizes, ctxs):
         if stats is not None:  # XLA:CPU reports none
             in_use[str(d)] = stats["bytes_in_use"]
             _check(stats["bytes_in_use"] > 0, "%s holds no memory" % d)
+    staged = _staged_block_facts(mx, exe, it, devices, sizes)
 
     # a context with device_id 3 computes on device 3
     pred = _serve_predictor(mx, dict(sizes, seed=sizes["seed"] + 1),
@@ -752,7 +801,8 @@ def phase_four_chips(sizes, ctxs):
     pred.close()
     return {"devices": [str(d) for d in devices],
             "loss": round(float(metric.get()[1]), 4),
-            "bytes_in_use": in_use, "predictor_device": str(devices[3])}
+            "bytes_in_use": in_use, "staged": staged,
+            "predictor_device": str(devices[3])}
 
 
 # ----------------------------------------------------------------------

@@ -30,6 +30,7 @@ import numpy as _np
 
 from .base import MXNetError
 from .context import Context, current_context
+from . import ndarray as nd
 from .ndarray import NDArray
 from .symbol import _topo_order
 
@@ -352,6 +353,11 @@ class Executor:
             dev = self._first_ctx.jax_device()
             if dev != default_device():
                 self._device = dev
+        # the platform this executor computes on: an input whose payload
+        # lies on another one (host memory beside an accelerator,
+        # nd.host_array) is placed, not passed on (forward)
+        self._platform = (dev if mesh is None
+                          else mesh.devices.flat[0]).platform
         if mesh is not None:
             from .parallel.mesh import NamedSharding, P, batch_pspec
 
@@ -532,24 +538,43 @@ class Executor:
         Mesh-less: `vals` came through _gather_args and are resident."""
         if self._mesh is None:
             return vals
-        from .parallel.mesh import NamedSharding, global_put
+        from .parallel.mesh import global_put
 
-        placed = []
         data_names = set(self._data_arg_names)
-        for n, v in zip(self._arg_names, vals):
-            if n in data_names:
-                sh = self._data_sharding
-            elif n in self._param_shardings:
-                spec = self._param_shardings[n]
-                if spec == "auto":
-                    spec = _auto_spec(v.shape, self._mesh)
-                sh = NamedSharding(self._mesh, spec)
-            else:
-                sh = self._repl_sharding
-            # global_put = device_put that also materializes pjit/GDA-
-            # style global arrays when the mesh spans other processes
-            placed.append(global_put(v, sh))
-        return tuple(placed)
+        # global_put = device_put that also materializes pjit/GDA-
+        # style global arrays when the mesh spans other processes
+        return tuple(
+            global_put(v, self._arg_sharding(n, v.shape, n in data_names))
+            for n, v in zip(self._arg_names, vals))
+
+    def _arg_sharding(self, name, shape, is_input):
+        """The mesh sharding of argument `name`: batch inputs over
+        'data', params per their spec or replicated."""
+        from .parallel.mesh import NamedSharding
+
+        if is_input:
+            return self._data_sharding
+        if name in self._param_shardings:
+            spec = self._param_shardings[name]
+            if spec == "auto":
+                spec = _auto_spec(shape, self._mesh)
+            return NamedSharding(self._mesh, spec)
+        return self._repl_sharding
+
+    def _from_host(self, name, v):
+        """The host-resident payload `v` of an NDArray (nd.host_array: a
+        batch of an iterator over host memory) where this executor
+        computes: on its one device, or laid over its mesh as argument
+        `name` is sharded, every device's rows sent from the host
+        buffer itself.  The H2D of such a batch, counted here."""
+        v = _np.asarray(v)  # a view: the CPU backend's buffer is host memory
+        self._note_bytes("executor.h2d_bytes", v.nbytes)
+        if self._mesh is None:
+            return jax.device_put(v, self._first_ctx.jax_device())
+        from .parallel.mesh import global_put
+
+        return global_put(v, self._arg_sharding(
+            name, v.shape, name in self._data_arg_names))
 
     def _place_repl(self, vals):
         """Replicate aux/optimizer-state leaves over the mesh.  On a
@@ -598,9 +623,12 @@ class Executor:
                 raise MXNetError("Unknown argument %s" % name)
             if isinstance(value, NDArray):
                 v = value.data
+                if nd.off_platform(v, self._platform):
+                    v = self._from_host(name, v)
             else:
                 # raw numpy/list input converts (and transfers) here;
-                # NDArray inputs paid their H2D at creation (nd.array).
+                # NDArray inputs paid their H2D at creation (nd.array)
+                # or, host-resident ones, just above.
                 # Bytes counted AFTER conversion so list inputs (no
                 # .nbytes) are measured exactly.
                 host = not isinstance(value, jax.Array)
@@ -1120,25 +1148,30 @@ class Executor:
         devices: the `place_fn` of io.DeviceStagedIter.  Returns (came
         from the host?, one single-device piece per device of
         _step_layout), which stack_block_input stacks where the pieces
-        lie.
+        lie.  Which way it goes follows from where the payload LIES:
 
-        * A DEVICE array never comes back to the host: one jitted
-          program cuts it along the batch axis on the device it lives
-          on, and each piece is copied chip to chip, one single-device
-          copy each.  (Not jax.device_put of the whole array to a
-          NamedSharding: where no shard of the source has a wanted
-          index, jax/_src/array.py shard_sharded_device_array_slow_path
-          serves the request from `x._value`, the host round trip this
-          replaces.)
-        * A HOST array crosses once, each device's rows (a view of a
-          contiguous batch axis) straight from the iterator's own
-          buffer, counted in `executor.h2d_bytes`.
+        * In HOST memory — numpy, or an NDArray whose payload is on a
+          device of another platform than the block's (nd.host_array:
+          the CPU backend's device beside an accelerator, what
+          NDArrayIter yields) — it crosses once: each device's rows (a
+          view of a contiguous batch axis) go straight from the source's
+          own buffer over that device's own host link, all links at
+          once, counted in `executor.h2d_bytes`.
+        * On a DEVICE of the block's platform it never comes back to
+          the host: one jitted program cuts it along the batch axis on
+          the device it lives on, and each piece is copied chip to
+          chip, one single-device copy each.  (Not jax.device_put of
+          the whole array to a NamedSharding: where no shard of the
+          source has a wanted index, jax/_src/array.py
+          shard_sharded_device_array_slow_path serves the request from
+          `x._value`, the host round trip this replaces.)
 
         Only enqueues: stack_block_input waits where something must."""
         devices, starts, rows = self._step_layout(name)
         if isinstance(arr, NDArray):
             arr = arr.data
-        host = not isinstance(arr, jax.Array)
+        host = (not isinstance(arr, jax.Array)
+                or nd.off_platform(arr, self._platform))
         if host:
             arr = _np.asarray(arr)
             self._note_bytes("executor.h2d_bytes", arr.nbytes)

@@ -19,7 +19,7 @@ import numpy as _np
 from .base import MXNetError
 from . import ndarray as nd
 from .engine.threaded_iter import ThreadedIter
-from .ndarray import NDArray, array
+from .ndarray import NDArray, host_array
 
 __all__ = [
     "DataDesc", "DataBatch", "DataIter", "NDArrayIter", "ResizeIter",
@@ -53,6 +53,35 @@ class DataDesc(namedtuple("DataDesc", ["name", "shape"])):
             type_dict = dict(types)
             return [DataDesc(x[0], x[1], type_dict[x[0]]) for x in shapes]
         return [DataDesc(x[0], x[1]) for x in shapes]
+
+
+# XLA's CPU client takes a numpy buffer WITHOUT a copy only where it starts
+# at this boundary (cpu_function_runtime::MinAlign); numpy's own
+# allocations give 16
+HOST_ALIGN = 64
+
+
+def aligned_empty(shape, dtype):
+    """Uninitialised C-ordered numpy memory that a host-resident NDArray
+    can alias (ndarray.host_array): it starts at a HOST_ALIGN boundary."""
+    dtype = _np.dtype(dtype)
+    nbytes = int(_np.prod(shape, dtype=_np.int64)) * dtype.itemsize
+    raw = _np.empty(nbytes + HOST_ALIGN, _np.uint8)
+    at = -raw.ctypes.data % HOST_ALIGN
+    return raw[at:at + nbytes].view(dtype).reshape(shape)
+
+
+def _aligned(v, order=None):
+    """The rows of `v` (in `order`, when given) where a batch cut from
+    them can be aliased: `v` itself if it lies so, else one copy."""
+    if order is not None:
+        return _np.take(v, order, axis=0, mode="clip",
+                        out=aligned_empty(v.shape, v.dtype))
+    if v.flags.c_contiguous and v.ctypes.data % HOST_ALIGN == 0:
+        return v
+    out = aligned_empty(v.shape, v.dtype)
+    out[...] = v
+    return out
 
 
 def desc_shape(desc):
@@ -121,7 +150,17 @@ class DataIter:
 
 
 class NDArrayIter(DataIter):
-    """Iterate over in-memory arrays (parity: io.py NDArrayIter)."""
+    """Iterate over in-memory arrays (parity: io.py NDArrayIter).
+
+    The arrays are kept in host memory and a batch is a HOST-RESIDENT
+    NDArray (`cpu()` context, as the reference's batches are;
+    ndarray.host_array): `next()` copies nothing and touches no
+    accelerator — a full batch is a view of the store, which is why the
+    store is laid at a 64-byte boundary once, here (one copy of a source
+    that does not start at one, or that is shuffled).  Whoever consumes
+    the batch decides where it goes: DeviceStagedIter sends each device
+    its own rows over that device's own host link, an executor places a
+    whole batch on its device(s)."""
 
     def __init__(self, data, label=None, batch_size=1, shuffle=False,
                  last_batch_handle="pad", data_name="data", label_name="softmax_label"):
@@ -131,8 +170,9 @@ class NDArrayIter(DataIter):
         self.idx = _np.arange(self.data[0][1].shape[0])
         if shuffle:
             _np.random.shuffle(self.idx)
-            self.data = [(k, v[self.idx]) for k, v in self.data]
-            self.label = [(k, v[self.idx]) for k, v in self.label]
+        order = self.idx if shuffle else None
+        self.data = [(k, _aligned(v, order)) for k, v in self.data]
+        self.label = [(k, _aligned(v, order)) for k, v in self.label]
         if last_batch_handle == "discard":
             new_n = self.data[0][1].shape[0] - self.data[0][1].shape[0] % batch_size
             self.idx = self.idx[:new_n]
@@ -181,10 +221,14 @@ class NDArrayIter(DataIter):
     def _getdata(self, data_source):
         assert self.cursor < self.num_data, "DataIter needs reset."
         if self.cursor + self.batch_size <= self.num_data:
-            return [array(x[1][self.cursor : self.cursor + self.batch_size]) for x in data_source]
+            return [host_array(x[1][self.cursor : self.cursor + self.batch_size])
+                    for x in data_source]
         pad = self.batch_size - self.num_data + self.cursor
         return [
-            array(_np.concatenate((x[1][self.cursor :], x[1][:pad]), axis=0)) for x in data_source
+            host_array(_np.concatenate(
+                (x[1][self.cursor :], x[1][:pad]), axis=0,
+                out=aligned_empty((self.batch_size,) + x[1].shape[1:], x[1].dtype)))
+            for x in data_source
         ]
 
     def getdata(self):
@@ -472,17 +516,25 @@ def stage_put(name, arr, place_fn=None):
     return place_fn(name, arr) if place_fn is not None else arr
 
 
+def _in_host_memory(a):
+    """Is the batch array `a` in host memory — numpy, or a host-resident
+    NDArray (nd.host_array) of a process that computes elsewhere?"""
+    return (not isinstance(a, NDArray)
+            or nd.off_platform(a.data, jax.default_backend()))
+
+
 def _to_host(a):
     """One array of a batch as numpy that stays what it is: a host
     array is copied (its source may refill the buffer), the read-back
-    of an NDArray is counted in `executor.d2h_bytes`, so the transfer
-    books balance."""
+    of an NDArray from a device is counted in `executor.d2h_bytes`, so
+    the transfer books balance, and a host-resident NDArray is read
+    where it lies: no device is waited for and nothing is counted."""
     if not isinstance(a, NDArray):
         return _np.array(a)
     out = a.asnumpy()
     from . import telemetry
 
-    if telemetry.enabled():
+    if telemetry.enabled() and not _in_host_memory(a):
         telemetry.inc("executor.d2h_bytes", int(out.nbytes))
     return out
 
@@ -504,14 +556,16 @@ class DeviceStagedIter(DataIter):
 
     The host neither stacks nor reads a batch's data back.
     `place_fn(name, array)` lays ONE step's array out over the devices,
-    as the source made it — the NDArray of a device-resident batch
-    (NDArrayIter and every iterator that ends in `nd.array`) moves chip
-    to chip, a host array crosses the link once — and
-    `stack_fn(name, steps)` stacks the K results where they lie.
-    Module.fit passes Executor.place_step_input and
-    Executor.stack_block_input, so a block carries the executor's
-    block_input_sharding().  `io.stage.device_parts` /
-    `io.stage.host_parts` count which way the step arrays came.
+    from where the source left it — a batch in host memory (numpy, or
+    the host-resident NDArray of an NDArrayIter) goes to each device as
+    that device's own rows over that device's own host link, once; the
+    NDArray of a device-resident batch (an iterator that ends in
+    `nd.array`) moves chip to chip — and `stack_fn(name, steps)` stacks
+    the K results where they lie.  Module.fit passes
+    Executor.place_step_input and Executor.stack_block_input, so a block
+    carries the executor's block_input_sharding().
+    `io.stage.host_parts` / `io.stage.device_parts` count which way the
+    step arrays came, as place_fn says.
     Without the pair, blocks are stacked and stay on the host and the
     executor places them at dispatch (no overlap, same results).
     """
@@ -529,8 +583,11 @@ class DeviceStagedIter(DataIter):
             raise MXNetError("DeviceStagedIter: place_fn and stack_fn come "
                              "as a pair (Executor.place_step_input, "
                              "Executor.stack_block_input)")
-        self._place_fn = place_fn or (lambda name, a: _to_host(a))
-        self._stack_fn = stack_fn or (lambda name, steps: _np.stack(steps))
+        # either place_fn answers (came from host memory?, placed)
+        self._place_fn = place_fn or (
+            lambda name, a: (_in_host_memory(a), _to_host(a)))
+        self._stack_fn = stack_fn or (
+            lambda name, steps: _np.stack([s for _, s in steps]))
         self._buffers = max(1, int(buffers if buffers is not None
                                    else config.get("MXTPU_STAGE_BUFFERS")))
         self.batch_size = getattr(data_iter, "batch_size", 0)
@@ -612,9 +669,10 @@ class DeviceStagedIter(DataIter):
 
     def _place_step(self, batch, names, parts):
         arrays = list(batch.data) + list(batch.label or [])
-        for a in arrays:
-            parts[isinstance(a, NDArray)] += 1
-        return [self._place_fn(name, a) for name, a in zip(names, arrays)]
+        placed = [self._place_fn(name, a) for name, a in zip(names, arrays)]
+        for from_host, _ in placed:
+            parts[not from_host] += 1
+        return placed
 
     def _assemble(self, names, rows, labels, pad, seq):
         """The StagedBlock of the placed steps `rows`.  No data array

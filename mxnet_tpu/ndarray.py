@@ -632,6 +632,49 @@ def array(source_array, ctx=None, dtype=None):
     return NDArray(arr, ctx)
 
 
+def off_platform(value, platform):
+    """Does the jax.Array `value` live on devices of ANOTHER platform
+    than `platform`?  Beside an accelerator that is what tells host
+    memory (a `host_array`'s payload, on the CPU backend's device) from
+    the devices that compute; where the CPU backend computes, host and
+    device are one platform and nothing is off it."""
+    return isinstance(value, jax.Array) and not any(
+        d.platform == platform for d in value.devices())
+
+
+def host_array(source_array):
+    """An NDArray over numpy `source_array` whose payload STAYS IN HOST
+    MEMORY, as the reference's `cpu()` arrays do: what an iterator over
+    host memory yields (io.NDArrayIter).  The payload is an uncommitted
+    `jax.Array` of the CPU backend's first device: beside an accelerator
+    it touches none, and whoever needs the values on a device moves them
+    there — staging sends each device its own rows
+    (Executor.place_step_input), an executor places a whole batch
+    (Executor.forward), an imperative op's jit moves an uncommitted
+    operand to where it computes.  XLA's CPU client ALIASES a C-ordered
+    numpy buffer that starts at a 64-byte boundary (io.aligned_empty)
+    and copies any other, so the source stays as it is while the array
+    lives.
+
+    `executor.h2d_bytes` counts bytes where they cross: here only if the
+    payload is already where this process computes (the CPU backend:
+    nothing downstream sees a crossing), as `array` counts them."""
+    try:
+        dev = jax.local_devices(backend="cpu")[0]
+    except RuntimeError:  # JAX_PLATFORMS leaves the cpu backend out
+        return array(source_array)
+    with jax.default_device(dev):
+        arr = jnp.asarray(source_array)
+        if arr.dtype == jnp.float64:
+            arr = arr.astype(jnp.float32)
+    if not off_platform(arr, jax.default_backend()):
+        from . import telemetry
+
+        if telemetry.enabled():
+            telemetry.inc("executor.h2d_bytes", int(arr.nbytes))
+    return NDArray(arr, cpu())
+
+
 def empty(shape, ctx=None, dtype=None):
     return zeros(shape, ctx, dtype)
 

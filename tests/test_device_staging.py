@@ -14,15 +14,23 @@ from mxnet_tpu.io import DataBatch, DataDesc, DataIter, DeviceStagedIter
 BATCH, DIM, K = 8, 6, 4
 
 
-def _executor(mesh):
+def _contexts(mesh, first=0):
+    return ([mx.cpu(first + i) for i in range(4)] if mesh
+            else mx.cpu(first))
+
+
+def _module(mesh, first=0):
     data = mx.sym.Variable("data")
     net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(data, num_hidden=3),
                                name="softmax")
-    ctx = [mx.cpu(i) for i in range(4)] if mesh else mx.cpu()
-    mod = mx.mod.Module(net, context=ctx)
+    mod = mx.mod.Module(net, context=_contexts(mesh, first))
     mod.bind(data_shapes=[("data", (BATCH, DIM))],
              label_shapes=[("softmax_label", (BATCH,))])
-    return mod._exec_group.execs[0]
+    return mod
+
+
+def _executor(mesh):
+    return _module(mesh)._exec_group.execs[0]
 
 
 def _arrays(steps):
@@ -173,3 +181,184 @@ def test_no_data_array_crosses_to_the_host_during_staging(monkeypatch, mesh):
     staged.close()
     assert len(blocks) == 2
     assert sum(read) == y.nbytes and max(read) == BATCH * 4
+
+
+# ----------------------------------------------------------------------
+# A batch in host memory goes to each device as that device's own rows
+# (PR 39).  Tier-1 has one platform, so host memory has a STAND-IN here:
+# the CPU backend's first device, which no block below computes on.
+# `nd.off_platform` — the one rule that tells host memory from the
+# block's devices — is made to say so, and everything that follows from
+# it runs as it does beside an accelerator.
+# ----------------------------------------------------------------------
+
+def _host_device():
+    import jax
+
+    return jax.local_devices(backend="cpu")[0]
+
+
+def _block_devices(mesh):
+    ctx = _contexts(mesh, first=1)
+    return [c.jax_device() for c in (ctx if mesh else [ctx])]
+
+
+@pytest.fixture
+def host_standin(monkeypatch):
+    import jax
+
+    host = {_host_device()}
+    monkeypatch.setattr(
+        mx.nd, "off_platform",
+        lambda value, platform: isinstance(value, jax.Array)
+        and value.devices() == host)
+
+
+@pytest.fixture
+def puts(monkeypatch):
+    """(shape, target device) of every piece jax puts on a device:
+    pxla.batched_device_put is where device_put, jnp.asarray and the
+    shards of a sharded put all end."""
+    from jax._src.interpreters import pxla
+
+    made = []
+    put = pxla.batched_device_put
+
+    def recorded(aval, sharding, xs, devices, *args, **kwargs):
+        made.extend((tuple(x.shape), d) for x, d in zip(xs, devices))
+        return put(aval, sharding, xs, devices, *args, **kwargs)
+
+    monkeypatch.setattr(pxla, "batched_device_put", recorded)
+    return made
+
+
+def _pieces(devices):
+    """(shape, device) of one batch's data and label rows a device."""
+    rows = BATCH // len(devices)
+    return sorted([(shape, d) for d in devices
+                   for shape in ((rows, DIM), (rows,))], key=str)
+
+
+def _batches_of(X, y):
+    """What NDArrayIter(last_batch_handle="pad") yields, in numpy."""
+    n = len(X)
+    for at in range(0, n, BATCH):
+        rows = [i % n for i in range(at, at + BATCH)]
+        yield X[rows], y[rows]
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "mesh4"])
+@pytest.mark.parametrize("rows", [8 * BATCH, 6 * BATCH + 3],
+                         ids=["whole", "short_block_padded_batch"])
+def test_ndarrayiter_block_goes_to_each_device_from_host_memory(
+        fresh_telemetry, host_standin, puts, rows, mesh):
+    exe = _module(mesh, first=1)._exec_group.execs[0]
+    rng = np.random.RandomState(rows)
+    X = rng.randn(rows, DIM).astype("float32")
+    y = rng.randint(0, 3, rows).astype("float32")
+    want = list(_batches_of(X, y))
+    telemetry.reset()
+    del puts[:]
+    it = mx.io.NDArrayIter(X, y, batch_size=BATCH)
+    staged = DeviceStagedIter(it, steps_per_dispatch=K,
+                              place_fn=exe.place_step_input,
+                              stack_fn=exe.stack_block_input)
+    blocks = list(staged)
+    staged.close()
+    assert [b.count for b in blocks] == [K, len(want) - K]
+    assert blocks[-1].pad == (-rows) % BATCH
+    sh = exe.block_input_sharding()
+    devices = _block_devices(mesh)
+    at = 0
+    for b in blocks:
+        ref_x = np.stack([x for x, _ in want[at:at + b.count]])
+        ref_y = np.stack([l for _, l in want[at:at + b.count]])
+        for got, ref in ((b.data[0], ref_x), (b.label[0], ref_y)):
+            assert got.dtype == ref.dtype and got.shape == ref.shape
+            assert np.asarray(got).tobytes() == ref.tobytes()
+            assert got.sharding.device_set == set(devices)
+            if mesh:
+                assert got.sharding == sh
+        assert np.array_equal(np.stack([l[0] for l in b.label_host]), ref_y)
+        at += b.count
+    counters = telemetry.snapshot()["counters"]
+    # every step array came from host memory, each counted once
+    assert counters["io.stage.host_parts"] == 2 * len(want)
+    assert counters.get("io.stage.device_parts", 0) == 0
+    staged_bytes = sum(x.nbytes + l.nbytes for x, l in want)
+    assert counters["executor.h2d_bytes"] == staged_bytes
+    assert counters.get("executor.d2h_bytes", 0) == 0
+    # what crossed: a device's own rows to that device, and nothing else;
+    # no whole batch on the first device of a block of four
+    crossed = [(shape, to) for shape, to in puts if to != _host_device()]
+    assert sorted(set(crossed), key=str) == _pieces(devices)
+    assert len(crossed) == 2 * len(want) * len(devices)
+
+
+@pytest.mark.parametrize("source", ["aligned", "unaligned", "shuffled"])
+def test_ndarrayiter_next_makes_views_of_its_store_and_no_transfer(
+        fresh_telemetry, puts, source):
+    """`next()` on its own: every array of a full batch is the
+    iterator's own memory (an aligned store, laid once at construction)
+    on the host device, uncommitted, and nothing is put anywhere else."""
+    from mxnet_tpu.io import HOST_ALIGN, aligned_empty
+
+    rng = np.random.RandomState(7)
+    X = aligned_empty((4 * BATCH + 1, DIM), "float32")
+    X[:] = rng.randn(*X.shape)
+    if source == "unaligned":
+        X = X[1:]            # starts 24 bytes past a boundary
+    else:
+        X = X[:-1]
+    y = np.arange(len(X), dtype="float32")
+    np.random.seed(3)
+    it = mx.io.NDArrayIter(X, y, batch_size=BATCH,
+                           shuffle=source == "shuffled")
+    store = it.data[0][1]
+    assert store.ctypes.data % HOST_ALIGN == 0
+    assert (store is X) == (source == "aligned")
+    order = it.idx if source == "shuffled" else np.arange(len(X))
+    del puts[:]
+    for at, batch in zip(range(0, len(X), BATCH), it):
+        data, label = batch.data[0], batch.label[0]
+        assert data.context == mx.cpu() and label.context == mx.cpu()
+        for nd_arr, src in ((data, store), (label, it.label[0][1])):
+            payload = nd_arr.data
+            assert payload.devices() == {_host_device()}
+            assert not payload.committed
+            # a view of the store wherever the batch starts at a
+            # boundary (the data always; every second label batch)
+            start = src[at:].ctypes.data
+            assert (payload.unsafe_buffer_pointer() == start) == \
+                (start % HOST_ALIGN == 0)
+            assert src is not store or start % HOST_ALIGN == 0
+        assert np.array_equal(data.asnumpy(), X[order[at:at + BATCH]])
+        assert np.array_equal(label.asnumpy(), y[order[at:at + BATCH]])
+    assert puts and all(to == _host_device() for _, to in puts)
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "mesh4"])
+def test_forward_places_a_host_batch_on_its_own_devices(
+        fresh_telemetry, host_standin, puts, mesh):
+    """K=1 (`Module.forward`, `score`, `predict`): the executor places a
+    host-resident batch on its device(s) itself — each device its rows,
+    counted once — and computes what it computes from a device batch."""
+    mod = _module(mesh, first=1)
+    mod.init_params()
+    exe = mod._exec_group.execs[0]
+    X, y = _arrays(1)
+    mod.forward(mx.io.DataBatch(data=[mx.nd.array(X)],
+                                label=[mx.nd.array(y)]), is_train=False)
+    out = mod.get_outputs()[0].asnumpy()  # the parameters are placed now
+    batch = next(iter(mx.io.NDArrayIter(X, y, batch_size=BATCH)))
+    telemetry.reset()
+    del puts[:]
+    mod.forward(batch, is_train=False)
+    assert np.array_equal(mod.get_outputs()[0].asnumpy(), out)
+    devices = _block_devices(mesh)
+    assert exe.arg_dict["data"].data.sharding.device_set == set(devices)
+    assert telemetry.counter_value("executor.h2d_bytes") == \
+        X.nbytes + y.nbytes
+    rows = (BATCH // len(devices),)  # the parameters are put anew, too
+    assert sorted([p for p in puts if p[0][:1] == rows], key=str) == \
+        _pieces(devices)
