@@ -23,18 +23,22 @@ apart from the rest:
   generate  TransformerLM via add_generative_tenant + submit_generate;
             one session's prefill/decode logits against the
             full-recompute score_symbol forward
-  kv_ring   the decode programs of five TransformerLMs shaped like the
+  kv_ring   the decode programs of six TransformerLMs shaped like the
             benchmark's decoders (32 heads of 64; 16 of 128; 32 query on
             8 K/V heads of 64 with rings of 2,304; a delta-rule layer of
             30 heads of 96 x 192 beside 30 heads of 128 with rings of
             2,304; a window layer's rings of 2,048 positions, which wrap,
             beside a full layer's of 6,144, 32 query on 4 K/V heads of
-            128; 8 sessions) as XLA compiled them: every cache_spec
+            128; a delta-rule layer of 16 q/k heads under 32 value heads
+            of 128 x 128 beside a ring of 2 K/V heads of 256 — a head
+            over two tiles of 128 lines — under 16 query heads, a quarter
+            of each head rotated; 8 sessions) as XLA compiled them: every cache_spec
             entry aliased to its output, no instruction that copies one,
             ONE attention kernel call an attention layer, and no ring or
             recurrent state fatter on the device than cache_spec states;
             prints the rings' on-device layout; and the 2,048-bucket
-            prefill of the fourth: ONE kernel call a delta-rule layer
+            prefill of the fourth and the sixth: ONE kernel call a
+            delta-rule layer
             (ops/gdn_kernel.py) and no triangular solve left in it; and
             every tenant's prefill bucket programs timed warm: none may
             run longer than 1.5 times the next larger bucket's (the
@@ -98,6 +102,18 @@ FULL = {
                                              "attention"],
                                 sliding_window=2048, norm="rms",
                                 positions={"window_attention": "rotary"},
+                                qk_norm="head", out_gate=True, bias=False),
+                           # 16 q/k heads under 32 value heads of 128 x
+                           # 128 (the kernel sees 32) beside a ring of 2
+                           # K/V heads of 256, a head over two tiles
+                           dict(num_heads=16, num_kv_heads=2, head_dim=256,
+                                max_len=4096, seq_buckets=[64, 2048],
+                                layer_types=["linear_attention",
+                                             "attention"],
+                                linear_heads=32, linear_key_heads=16,
+                                linear_key_dim=128, linear_value_dim=128,
+                                linear_neg_eigval=False, norm="rms",
+                                positions="rotary", rotary_dim=64,
                                 qk_norm="head", out_gate=True, bias=False)]},
     "kernel": {"shapes": [(512, 56, 56, 64), (512, 7, 7, 2048)], "seed": 3},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,

@@ -50,8 +50,11 @@ the block before the FFN — ``"attention"`` (the default for every layer),
 ``"window_attention"`` (attention over a sliding window of
 `sliding_window` positions), ``"mamba"`` (a Mamba-2 state-space mixer,
 ops/ssm.py) or ``"linear_attention"`` (a Gated DeltaNet delta-rule mixer,
-ops/gdn.py).  A kind is one class below (:class:`_Attention`,
-:class:`_WindowAttention`, :class:`_Mamba2`, :class:`_GatedDeltaNet`) that
+ops/gdn.py: `linear_heads` value heads, one state each, over
+`linear_key_heads` heads of q and k — as many unless the spec says fewer,
+each then read by a group of value heads).  A kind is one class below
+(:class:`_Attention`, :class:`_WindowAttention`, :class:`_Mamba2`,
+:class:`_GatedDeltaNet`) that
 declares, in that one place, its parameters, its full-sequence forward,
 its prefill, its decode step, the device-resident state it keeps between
 calls and the counters a program call adds to; the four graph builders
@@ -78,6 +81,15 @@ one ``"attention"``, ``ffn_types`` of leading ``"dense"`` layers before
 ``"routed"`` ones, ``router_score="sigmoid"`` with ``router_bias``,
 ``route_norm``, ``route_scale``, ``shared_d_ff`` and ``held_experts`` are
 Trinity's (`afmoe`), held as one chip's share of its experts.
+``layer_types`` of three ``"linear_attention"`` to one ``"attention"`` with
+``ffn_types`` all ``"routed"`` (a recurrent mixer and an expert FFN in ONE
+block), ``linear_key_heads`` under twice as many ``linear_heads`` with
+``linear_neg_eigval=False``, ``head_dim=256`` with ``qk_norm="head"``,
+``out_gate`` and ``rotary_dim`` (the first quarter of each head turned),
+softmax ``route_norm`` experts of `expert_d_ff` with a ``shared_gate`` on
+the shared expert and ``held_experts`` are Qwen3-Next's (`qwen3_next`);
+its norm gains are stored as they are applied, ``1 + w`` of the published
+``w``.
 
 **Cache spec.**  :meth:`TransformerLM.cache_spec` is the ONE statement of
 what a serving session holds on the device between calls: an ordered
@@ -209,6 +221,8 @@ class _Attention:
             k = self._head_norm(k, "l%d_knorm" % i, lm.num_kv_heads)
         if self.rope:
             rope = dict(theta=lm.rope_theta)
+            if lm.rotary_dim is not None:
+                rope["rotary_dim"] = lm.rotary_dim
             heads = (lm.num_heads, lm.num_kv_heads)
             if index is None:
                 q, k = (sym._rotary(t, name="l%d_%srope" % (i, n),
@@ -384,7 +398,9 @@ class _GatedDeltaNet(_Recurrent):
     """The Gated DeltaNet mixer of layer i (ops/gdn.py has the equations):
     input projection ``[q | k | v | z | b | a]``, causal conv over ``[q | k
     | v]`` + delta rule + gated per-head RMSNorm in ONE op node a form,
-    output projection.  State: the conv window ``(slots, taps - 1,
+    output projection.  `linear_heads` are the VALUE heads (v, z, b, a, a
+    state each); q and k have `linear_key_heads` heads, as many unless the
+    spec says fewer.  State: the conv window ``(slots, taps - 1,
     conv_dim)`` and the delta-rule state ``(slots, key_dim, heads *
     value_dim)`` — the key axis leading, the heads' values side by side
     on the lanes (ops/gdn.py: for heads of 96 x 192 a TPU tile then pads
@@ -398,9 +414,10 @@ class _GatedDeltaNet(_Recurrent):
     def __init__(self, lm):
         self.lm = lm
         h, dk, dv = lm.linear_heads, lm.linear_key_dim, lm.linear_value_dim
-        self.small_shapes = _gdn.param_shapes(h, dk, dv, lm.linear_conv)
+        hk = lm.linear_key_heads
+        self.small_shapes = _gdn.param_shapes(h, dk, dv, lm.linear_conv, hk)
         self.d_inner = h * dv
-        conv_dim = h * (2 * dk + dv)
+        conv_dim = _gdn.conv_channels(h, dk, dv, hk)
         self.d_proj = conv_dim + self.d_inner + 2 * h
         self.state_shapes = ((lm.linear_conv - 1, conv_dim),
                              (dk, self.d_inner))
@@ -408,6 +425,8 @@ class _GatedDeltaNet(_Recurrent):
                           conv_kernel=lm.linear_conv,
                           chunk_size=lm.linear_chunk,
                           neg_eigval=lm.linear_neg_eigval, eps=lm.norm_eps)
+        if hk != h:   # on a node only when the spec sets it
+            self.attrs["num_key_heads"] = hk
 
     def counters(self, i, positions=0, rows=0, platform=None, **call):
         """What one program call adds: the bucket positions a prefill
@@ -467,7 +486,9 @@ class _RoutedFFN:
     """The routed FFN of layer i (``mx.sym.MoE``, dropless):
     `experts_per_token` of `num_experts` SwiGLU experts of width
     `expert_d_ff` by the router's scores, plus — `shared_d_ff` — one
-    expert every token passes.  `held_experts` ``(first, count)`` are the
+    expert every token passes, times — `shared_gate` — the sigmoid of its
+    own score ``x w_s``, ``w_s`` the ``(d_model, 1)``
+    ``l<i>_shared_score_weight``.  `held_experts` ``(first, count)`` are the
     experts whose matrices this model holds, one chip's share of the
     layer: the router and the choice stay `num_experts` wide."""
 
@@ -486,6 +507,8 @@ class _RoutedFFN:
             self.attrs["route_scale"] = lm.route_scale
         if lm.shared_d_ff:
             self.attrs["shared_size"] = lm.shared_d_ff
+        if lm.shared_gate:
+            self.attrs["shared_gate"] = True
         if held is not None:
             self.attrs.update(held_first=held[0], held_count=held[1])
 
@@ -507,6 +530,9 @@ class _RoutedFFN:
                                         shape=(s, d))
             p["shared_up_weight"] = v("l%d_shared_up_weight" % i,
                                       shape=(d, s))
+        if lm.shared_gate:
+            p["shared_score_weight"] = v("l%d_shared_score_weight" % i,
+                                         shape=(d, 1))
         return p
 
     def apply(self, x, p, i, loads):
@@ -517,6 +543,8 @@ class _RoutedFFN:
         if lm.shared_d_ff:
             operands += [p["shared_gate_weight"], p["shared_down_weight"],
                          p["shared_up_weight"]]
+        if lm.shared_gate:
+            operands.append(p["shared_score_weight"])
         f = sym.MoE(*operands, num_experts=lm.num_experts,
                     hidden_size=lm.expert_d_ff, k=lm.experts_per_token,
                     act_type="silu", gated=True, no_bias=True,
@@ -551,7 +579,9 @@ class TransformerLM:
     The block (defaults: the GPT-2/OPT shape): `norm` ``"layer"`` |
     ``"rms"`` (gain only), eps `norm_eps`; `positions` ``"learned"``
     (a table added to the embedding) | ``"rotary"`` (Q and K turned per
-    head, rotate-half over the whole head, base `rope_theta`); `qk_norm`
+    head, rotate-half over the whole head, base `rope_theta`, or with
+    `rotary_dim` over the first `rotary_dim` channels of each head alone,
+    the rest passing unturned); `qk_norm`
     normalizes the whole Q and K projections (kind `norm`) before the
     heads are split; `num_experts` > 0 replaces the dense ReLU FFN by a
     dropless routed SwiGLU layer — `experts_per_token` of `num_experts`
@@ -574,8 +604,10 @@ class TransformerLM:
     `mamba_groups`, `mamba_conv` taps and the prefill scan's
     `mamba_chunk`; the Gated DeltaNet mixer's `linear_heads` heads of
     `linear_key_dim` x `linear_value_dim`, `linear_conv` taps, the chunk
-    `linear_chunk` of its full-sequence form and `linear_neg_eigval`
-    (``beta`` reaches 2); `head_dim` — the width of a head where it is not
+    `linear_chunk` of its full-sequence form, `linear_neg_eigval`
+    (``beta`` reaches 2) and `linear_key_heads` — heads of q and k where
+    they are fewer than the `linear_heads` value heads, which they
+    divide; `head_dim` — the width of a head where it is not
     ``d_model / num_heads`` (the projections are then ``num_heads *
     head_dim`` wide); `sliding_window` W of the ``"window_attention"``
     kind (row i attends to ``j <= i`` with ``i - j < W``); `positions`
@@ -587,7 +619,8 @@ class TransformerLM:
     branch's input AND its output (``<name>`` and ``<name>_post``);
     `ffn_types` — one FFN kind a layer, ``"dense"`` | ``"routed"``;
     `expert_d_ff` — a routed expert's width (default `d_ff`);
-    `shared_d_ff` — the width of one expert every token passes;
+    `shared_d_ff` — the width of one expert every token passes,
+    `shared_gate` multiplies what it adds by ``sigmoid(x w_s)``;
     `router_score` ``"softmax"`` | ``"sigmoid"``; `router_bias` adds
     ``l<i>_router_bias (num_experts,)`` to the scores for the choice only;
     `route_norm` renormalises the chosen scores, `route_scale` multiplies
@@ -610,7 +643,8 @@ class TransformerLM:
                  sliding_window=0, out_gate=False, ffn_types=None,
                  expert_d_ff=None, shared_d_ff=0, router_score="softmax",
                  router_bias=False, route_norm=False, route_scale=1.0,
-                 held_experts=None):
+                 held_experts=None, linear_key_heads=None, rotary_dim=None,
+                 shared_gate=False):
         if head_dim is None and d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
@@ -674,6 +708,15 @@ class TransformerLM:
             raise ValueError("a 'linear_attention' layer needs linear_heads, "
                              "linear_key_dim, linear_value_dim >= 1 and "
                              "linear_conv >= 2")
+        linear_key_heads = int(linear_key_heads or linear_heads)
+        if "linear_attention" in layer_types and (
+                linear_key_heads < 1 or linear_heads % linear_key_heads):
+            raise ValueError("linear_heads=%d not a multiple of "
+                             "linear_key_heads=%d"
+                             % (linear_heads, linear_key_heads))
+        if shared_gate and not shared_d_ff:
+            raise ValueError("shared_gate needs a shared expert "
+                             "(shared_d_ff >= 1)")
         if "window_attention" in layer_types and sliding_window < 1:
             raise ValueError("a 'window_attention' layer needs "
                              "sliding_window >= 1")
@@ -733,6 +776,14 @@ class TransformerLM:
         self.route_norm = bool(route_norm)
         self.route_scale = float(route_scale)
         self.held_experts = held_experts
+        self.linear_key_heads = linear_key_heads
+        self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
+        self.shared_gate = bool(shared_gate)
+        if self.rotary_dim is not None and (
+                self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.d_head):
+            raise ValueError("rotary_dim=%d must be even and within the "
+                             "head's %d channels"
+                             % (self.rotary_dim, self.d_head))
         kinds = {k: _KINDS[k](self) for k in set(layer_types)}
         self._mixers = [kinds[k] for k in layer_types]
         kinds = {k: _FFNS[k](self) for k in set(ffn_types)}

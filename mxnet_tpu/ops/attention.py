@@ -149,13 +149,22 @@ def rms_norm(data, gamma, eps=1e-5, num_heads=1, **kw):
 # ----------------------------------------------------------------------
 
 
-def _rotate(data, positions, num_heads, theta):
+def _rotate(data, positions, num_heads, theta, rotary_dim=None):
     """Rotate each head of ``data (N, T, d_model)`` by its row's
     ``positions (N, T)``: the rotate-half convention over the WHOLE head
     (pairs ``(i, i + d_head/2)``, angle ``pos * theta^(-2i/d_head)``),
-    angles and products in float32."""
+    angles and products in float32.  With `rotary_dim` R the first R
+    channels of each head turn so, as a head of R (pairs ``(i, i + R/2)``,
+    angle ``pos * theta^(-2i/R)``), and the other ``d_head - R`` pass as
+    they are."""
     h = int(_lit(num_heads))
     n, t, d = data.shape
+    r = d // h if rotary_dim is None else int(_lit(rotary_dim))
+    if r != d // h:
+        x = data.reshape(n, t, h, d // h)
+        turned = _rotate(x[..., :r].reshape(n, t, h * r), positions, h, theta)
+        return jnp.concatenate([turned.reshape(n, t, h, r), x[..., r:]],
+                               axis=-1).reshape(n, t, d)
     half = d // h // 2
     inv_freq = float(_lit(theta)) ** (
         -jnp.arange(half, dtype=jnp.float32) / half)
@@ -172,22 +181,25 @@ def _infer_same(in_shapes, attrs):
 
 
 @register("_rotary", inputs=("data",), infer_shape=_infer_same)
-def rotary(data, num_heads=1, theta=10000.0, **kw):
+def rotary(data, num_heads=1, theta=10000.0, rotary_dim=None, **kw):
     """Rotary position embedding of a full sequence ``(N, T, d_model)``:
-    row t sits at position t (training / prefill)."""
+    row t sits at position t (training / prefill).  `rotary_dim`: the
+    leading channels of each head that turn (default: the whole head)."""
     n, t, _ = data.shape
     pos = jnp.broadcast_to(jnp.arange(t)[None, :], (n, t))
-    return _rotate(data, pos, num_heads, theta)
+    return _rotate(data, pos, num_heads, theta, rotary_dim)
 
 
 @register("_rotary_at", inputs=("data", "index"), infer_shape=_infer_same)
-def rotary_at(data, index, num_heads=1, theta=10000.0, **kw):
+def rotary_at(data, index, num_heads=1, theta=10000.0, rotary_dim=None,
+              **kw):
     """Rotary position embedding where row b's first token sits at
     ``index[b]`` — the decode step's ``length``, a traced operand, so
-    one compiled program serves every position."""
+    one compiled program serves every position.  `rotary_dim` as
+    ``_rotary``'s."""
     t = data.shape[1]
     pos = _as_index(index)[:, None] + jnp.arange(t)[None, :]
-    return _rotate(data, pos, num_heads, theta)
+    return _rotate(data, pos, num_heads, theta, rotary_dim)
 
 
 # ----------------------------------------------------------------------
@@ -300,9 +312,11 @@ def decode_heads(ring_shape, itemsize=4):
     divide ``H_kv``, fit, and fill whole tiles of 128 lines (heads are
     independent in a decode step; the kernel's grid walks the groups).
     None for a ring the kernel's tiling does not divide: a ``d_head``
-    under 8 or that does not divide 128, or no such group of heads."""
+    under 8, or that neither divides 128 (several heads a tile of 128
+    lines) nor is a multiple of it (a head over several tiles), or no such
+    group of heads."""
     _, h_kv, d_head, _ = ring_shape
-    if d_head < 8 or _LANES % d_head:
+    if d_head < 8 or (_LANES % d_head and d_head % _LANES):
         return None
     return next((h for h in range(h_kv, 0, -1)
                  if h_kv % h == 0 and h * d_head % _LANES == 0

@@ -2,11 +2,16 @@
 Hatamizadeh, arXiv:2412.06464; the delta rule in chunks: Yang et al.,
 arXiv:2406.06484) in the three forms a hybrid decoder needs.
 
-All three take the layer's fused input projection ``data (N, T, 2 H d_k
-+ 2 H d_v + 2 H)`` laid out ``[q | k | v | z | b | a]`` (H heads; q and k
-of `key_dim` d_k, v and the gate z of `value_dim` d_v, one ``b`` and one
-``a`` a head) and the mixer's small parameters, and return the gated,
-normalized ``y (N, T, H d_v)`` the output projection consumes.  Per head:
+All three take the layer's fused input projection ``data (N, T, 2 H_k
+d_k + 2 H d_v + 2 H)`` laid out ``[q | k | v | z | b | a]`` (`num_heads` H
+VALUE heads — v and the gate z of `value_dim` d_v, one ``b`` and one ``a``
+a head, one state a head — and `num_key_heads` H_k heads of q and k of
+`key_dim` d_k: as many as value heads unless the node says otherwise,
+else a divisor of H, and value head n then reads q/k head ``n // (H /
+H_k)`` — the conv and the L2 norms run over the H_k heads, and q and k are
+repeated to H heads AFTER them) and the mixer's small parameters, and
+return the gated, normalized ``y (N, T, H d_v)`` the output projection
+consumes.  Per value head:
 
     [q | k | v] = silu(causal_depthwise_conv1d(q | k | v))      no bias
     q = q / ||q|| / sqrt(d_k);   k = k / ||k||
@@ -50,7 +55,7 @@ what the state already answers for its key.
   reductions of ONE read of the page; the update is a second pass.
 
 Stored shapes belong to the model (``TransformerLM.cache_spec``): a conv
-window ``(slots, K - 1, 2 H d_k + H d_v)`` and a state ``(slots, d_k, H *
+window ``(slots, K - 1, 2 H_k d_k + H d_v)`` and a state ``(slots, d_k, H *
 d_v)`` — the KEY axis leading and every head's values side by side on the
 lanes, so that for heads of 96 x 192 a TPU tile pads nothing (5,760 = 45
 x 128 lanes, 96 = 12 x 8 sublanes; stored ``(H, d_v, d_k)`` each line of
@@ -84,8 +89,8 @@ from .tensor import _bool, _lit
 _HIGHEST = lax.Precision.HIGHEST
 _L2_EPS = 1e-6        # inside the root of q's and k's norm
 PARAMS = ("conv_weight", "dt_bias", "A_log", "norm_gamma")
-_ATTRS = dict(num_heads=1, key_dim=1, value_dim=1, conv_kernel=4,
-              chunk_size=64, neg_eigval=True, eps=1e-6)
+_ATTRS = dict(num_heads=1, num_key_heads=None, key_dim=1, value_dim=1,
+              conv_kernel=4, chunk_size=64, neg_eigval=True, eps=1e-6)
 
 
 def _attrs(kw):
@@ -93,45 +98,57 @@ def _attrs(kw):
 
 
 def _sizes(attrs):
-    """(heads H, key width d_k, value width d_v, conv taps K) of a node."""
-    return tuple(int(_lit(attrs.get(k, _ATTRS[k]))) for k in (
+    """(q/k heads H_k, value heads H, key width d_k, value width d_v, conv
+    taps K) of a node; without `num_key_heads`, ``H_k = H``."""
+    h, dk, dv, k = (int(_lit(attrs.get(k, _ATTRS[k]))) for k in (
         "num_heads", "key_dim", "value_dim", "conv_kernel"))
+    hk = attrs.get("num_key_heads")
+    return (h if hk is None else int(_lit(hk))), h, dk, dv, k
 
 
-def param_shapes(heads, key_dim, value_dim, kernel):
+def conv_channels(heads, key_dim, value_dim, key_heads=None):
+    """Channels of ``[q | k | v]``, what the conv runs over."""
+    key_heads = heads if key_heads is None else key_heads
+    return 2 * key_heads * key_dim + heads * value_dim
+
+
+def param_shapes(heads, key_dim, value_dim, kernel, key_heads=None):
     """Shapes of the mixer's own parameters, in `PARAMS` order."""
-    conv_dim = heads * (2 * key_dim + value_dim)
+    conv_dim = conv_channels(heads, key_dim, value_dim, key_heads)
     return [(kernel, conv_dim), (heads,), (heads,), (value_dim,)]
 
 
 def _infer(in_shapes, attrs, n_state=0):
-    h, dk, dv, k = _sizes(attrs)
+    hk, h, dk, dv, k = _sizes(attrs)
     data = in_shapes[0]
     out = tuple(data[:-1]) + (h * dv,)
-    ins = [data] + param_shapes(h, dk, dv, k)
+    ins = [data] + param_shapes(h, dk, dv, k, hk)
     states = list(in_shapes[len(ins):len(ins) + n_state])
     return ins + states + list(in_shapes[len(ins) + n_state:]), \
         [out] + states
 
 
-def _split(data, h, dk, dv):
+def _split(data, hk, h, dk, dv):
     """``[q k v | z | b | a]`` of the fused projection, in float32."""
-    conv_dim = h * (2 * dk + dv)
+    conv_dim = conv_channels(h, dk, dv, hk)
     data = data.astype(jnp.float32)
     z_end = conv_dim + h * dv
     return (data[..., :conv_dim], data[..., conv_dim:z_end],
             data[..., z_end:z_end + h], data[..., z_end + h:])
 
 
-def _heads(qkv, h, dk, dv):
+def _heads(qkv, hk, h, dk, dv):
     """The conv's output split into normalized ``q``, ``k (..., H, d_k)``
-    and ``v (..., H, d_v)``."""
+    and ``v (..., H, d_v)``: the H_k heads of q and k normed, then each
+    repeated for the ``H / H_k`` value heads that read it."""
     lead = qkv.shape[:-1]
-    q = qkv[..., :h * dk].reshape(lead + (h, dk))
-    k = qkv[..., h * dk:2 * h * dk].reshape(lead + (h, dk))
-    v = qkv[..., 2 * h * dk:].reshape(lead + (h, dv))
+    q = qkv[..., :hk * dk].reshape(lead + (hk, dk))
+    k = qkv[..., hk * dk:2 * hk * dk].reshape(lead + (hk, dk))
+    v = qkv[..., 2 * hk * dk:].reshape(lead + (h, dv))
     q, k = (x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
             for x in (q, k))
+    if hk != h:
+        q, k = (jnp.repeat(x, h // hk, axis=-2) for x in (q, k))
     return q * dk ** -0.5, k, v
 
 
@@ -337,9 +354,9 @@ def _mix(data, conv_weight, dt_bias, a_log, norm_gamma, attrs, length=None):
     `length`, training and scoring, it is the differentiable body).
     Returns ``(y, raw [q | k | v], final state (N, d_k, H * d_v) as a
     session stores it)``."""
-    h, dk, dv, _ = _sizes(attrs)
-    raw, z, b, a = _split(data, h, dk, dv)
-    q, k, v = _heads(_conv_full(raw, conv_weight, 0.0), h, dk, dv)
+    hk, h, dk, dv, _ = _sizes(attrs)
+    raw, z, b, a = _split(data, hk, h, dk, dv)
+    q, k, v = _heads(_conv_full(raw, conv_weight, 0.0), hk, h, dk, dv)
     beta, g = _gates(b, a, dt_bias, a_log, attrs)
     chunk = int(_lit(attrs["chunk_size"]))
     if length is None:
@@ -384,7 +401,7 @@ def gdn_prefill(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
     position ``length[n]``, the pad not counted — written at
     ``slot[n]``."""
     attrs = _attrs(kw)
-    k = _sizes(attrs)[3]
+    k = _sizes(attrs)[4]
     slot_i, len_i = _as_index(slot), _as_index(length)
     with jax.named_scope("mx:gdn.scan"):
         y, raw, final = _mix(data, conv_weight, dt_bias, A_log, norm_gamma,
@@ -414,18 +431,18 @@ def gdn_step(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
     on the scratch slot, one after the other).  Outputs ``y (B, 1, H
     d_v)`` and the two updated buffers."""
     attrs = _attrs(kw)
-    h, dk, dv, _ = _sizes(attrs)
+    hk, h, dk, dv, _ = _sizes(attrs)
     rows = data.shape[0]
     slot_i = _as_index(slot)
     with jax.named_scope("mx:gdn.step"):
-        raw, z, b, a = _split(data[:, 0], h, dk, dv)
+        raw, z, b, a = _split(data[:, 0], hk, h, dk, dv)
         window = jnp.concatenate(
             [jnp.stack([lax.dynamic_index_in_dim(conv_state, slot_i[i], 0,
                                                  keepdims=False)
                         for i in range(rows)]).astype(jnp.float32),
              raw[:, None]], axis=1)                       # (B, K, C)
         q, k, v = _heads(jnn.silu((window * conv_weight).sum(axis=1)),
-                         h, dk, dv)
+                         hk, h, dk, dv)
         beta, g = _gates(b, a, dt_bias, A_log, attrs)     # (B, H)
         alpha = jnp.exp(g)
         kq = jnp.sum(k * q, axis=-1)                      # (B, H)

@@ -73,7 +73,9 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
     b, j, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     h_q = h_kv * groups
     chunks = blk // _LANE
-    per = _LANE // d_head                 # heads a tile of 128 lines
+    # a tile of 128 (head, d_head) lines holds `per` heads, or a head
+    # lies over `tiles` of them
+    per, tiles = max(_LANE // d_head, 1), max(d_head // _LANE, 1)
     length = len_ref[b]
     # where the step's row goes, the block that holds it, the last block
     # the row reads: one block unless the ring wraps
@@ -197,7 +199,20 @@ def _kernel(slot_ref, len_ref,                       # scalar prefetch
             lines = acc_ref[pl.ds(t * per, per)].reshape(_LANE, _LANE)
             o_ref[0, :, pl.ds(pl.multiple_of(t * _LANE, _LANE), _LANE)] = (
                 jnp.sum(lines.T, axis=0, keepdims=True) / norm)
-        unrolled(h_q // per, tile)
+
+        def wide_tile(t):
+            # a head of 256 or more lies over `tiles` tiles: tile t is
+            # lines (t % tiles) * 128 .. of head t // tiles
+            h, part = t // tiles, t % tiles
+            lines = acc_ref[h, part * _LANE:(part + 1) * _LANE, :]
+            o_ref[0, :, t * _LANE:(t + 1) * _LANE] = (
+                jnp.sum(lines.T, axis=0, keepdims=True) / l_ref[h:h + 1, :])
+
+        if tiles == 1:
+            unrolled(h_q // per, tile)
+        else:
+            for t in range(h_q * tiles):
+                wide_tile(t)
         if not wraps:     # the block that was written: the program it was
             row_sent()
 
@@ -214,10 +229,11 @@ def ring_attention(q, k_new, v_new, k_cache, v_cache, slot, length, *,
     in place where the caller donates them.  `block` positions and
     `heads` K/V heads (default all) a grid step
     (``ops.attention.decode_block`` / ``decode_heads``, which also say
-    for which rings the kernel's tiling holds: ``d_head`` divides 128,
-    a group's heads fill whole 128-line tiles); `interpret` runs
-    Pallas's interpreter; `wraps`: the ring is a window's, written at
-    ``length mod max_len`` and read whole once it is full.
+    for which rings the kernel's tiling holds: ``d_head`` divides 128 or
+    is a multiple of it, a group's heads fill whole 128-line tiles);
+    `interpret` runs Pallas's interpreter; `wraps`: the ring is a
+    window's, written at ``length mod max_len`` and read whole once it is
+    full.
     The caller jits (``ops.attention._decode_attention``): the layers of
     a decode program share one trace and one lowering of this."""
     bsz, h_q, d_head = q.shape

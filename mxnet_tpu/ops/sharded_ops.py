@@ -34,7 +34,8 @@ run (OLMoE: `gated`, `no_bias`, `act_type="silu"`, `normalize=False`):
 sort-and-segment (parallel/moe.py dropless_experts), no [T, E, C].  That
 form also takes what later routers brought: `score_func="sigmoid"`, a
 `select_bias` operand that moves the choice and not the weights,
-`route_scale`, an always-on shared expert (`shared_size`), and
+`route_scale`, an always-on shared expert (`shared_size`) with a sigmoid
+gate of its own (`shared_gate`), and
 `held_first` / `held_count` — WHICH of the `num_experts` the expert
 operands are, one chip's share of an expert-parallel layer.
 """
@@ -50,7 +51,8 @@ from .tensor import _lit
 def _moe_inputs(attrs):
     """data, the router (and its selection bias), then expert matrix i
     (and its bias): 1 = in, 2 = out, 3 = the gated branch's second
-    in-projection; then the shared expert's matrices in the same order."""
+    in-projection; then the shared expert's matrices in the same order,
+    and its gate's ``(D, 1)`` column."""
     gated = _bool_attr(attrs.get("gated", False))
     no_bias = _bool_attr(attrs.get("no_bias", False))
     names = ["data", "gate_weight"]
@@ -63,6 +65,8 @@ def _moe_inputs(attrs):
     if int(_lit(attrs.get("shared_size", 0))):
         names += ["shared%d_weight" % i for i in ((1, 2, 3) if gated
                                                   else (1, 2))]
+    if _bool_attr(attrs.get("shared_gate", False)):
+        names.append("shared_gate_weight")
     return names
 
 
@@ -93,7 +97,7 @@ def _infer_moe(in_shapes, attrs):
                "expert2_weight": (E, H, D), "expert2_bias": (E, D),
                "expert3_weight": (E, D, H), "expert3_bias": (E, H),
                "shared1_weight": (D, S), "shared2_weight": (S, D),
-               "shared3_weight": (D, S)}
+               "shared3_weight": (D, S), "shared_gate_weight": (D, 1)}
     outs = [tuple(data)] + [(E,)] * (_moe_outputs(attrs) - 1)
     return [by_slot[n] for n in _moe_inputs(attrs)], outs
 
@@ -119,8 +123,8 @@ def _constrain(x, mesh, spec):
 def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
         capacity_factor=None, act_type="relu", gated=False, no_bias=False,
         normalize=True, return_load=False, score_func="softmax",
-        select_bias=False, route_scale=1.0, shared_size=0, mesh=None,
-        **kw):
+        select_bias=False, route_scale=1.0, shared_size=0,
+        shared_gate=False, mesh=None, **kw):
     """Top-k routed expert FFN: out[t] = sum_e gate[t,e] * FFN_e(x[t])
     over t's top-k experts, FFN_e = ``act(x w1 + b1) @ w2 + b2``, or with
     `gated` ``(act(x w1 + b1) * (x w3 + b3)) @ w2 + b2``; `no_bias`
@@ -143,7 +147,10 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
     scores for the CHOICE of the k alone; `route_scale` multiplies the
     weights; `shared_size` S adds ``shared{1,2,3}_weight`` (``[D, S]``,
     ``[S, D]``, ``[D, S]``), one more FFN of the same form that every
-    token passes, unweighted; `held_first` / `held_count` say that the
+    token passes, unweighted — or, with `shared_gate`, times
+    ``sigmoid(x w_s)``, ``w_s`` the last operand ``shared_gate_weight [D,
+    1]`` (the score in float32 at `highest`, like the router's);
+    `held_first` / `held_count` say that the
     expert operands are experts ``held_first .. held_first + held_count``
     of the `num_experts` the router scores — the choice and the weights
     stay over all of them, pairs of absent experts add nothing, and the
@@ -158,7 +165,11 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
     act = str(_lit(act_type))
     operands = list(operands)
     bias = operands.pop(0) if _bool_attr(select_bias) else None
-    shared = ()
+    shared, shared_score = (), None
+    if _bool_attr(shared_gate):
+        if not int(_lit(shared_size)):
+            raise ValueError("MoE: shared_gate needs shared_size")
+        shared_score = operands.pop()
     if int(_lit(shared_size)):
         n_shared = 3 if gated else 2
         operands, shared = operands[:-n_shared], operands[-n_shared:]
@@ -189,9 +200,13 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
                 **options)
         if shared:
             with jax.named_scope("mx:moe.shared"):
-                out = out + _moe.expert_ffn(
+                passed = _moe.expert_ffn(
                     lambda r, w: r @ w.astype(r.dtype), x, shared, None,
                     act, gated)
+                if shared_score is not None:
+                    passed = passed * jax.nn.sigmoid(_moe.router_logits(
+                        x, shared_score)).astype(passed.dtype)
+                out = out + passed
     else:
         capacity = max(1, int(float(_lit(capacity_factor)) * kk * T // E))
         ep = mesh is not None and "expert" in mesh.axis_names

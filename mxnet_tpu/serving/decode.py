@@ -351,6 +351,10 @@ class GenerativeSession:
         blocks = [decode_block(r, self._platform) for r in rings]
         self._ring_blocks = _np.asarray(
             [b or r[3] for b, r in zip(blocks, rings)], _np.int64)
+        # a page's positions in the rings the decode program reads
+        # through the TPU's kernel (those with a block), summed
+        self._kernel_positions = sum(
+            r[3] for b, r in zip(blocks, rings) if b)
         if any(blocks):
             # the decode programs will lower the kernel: importing
             # Pallas is over a second of Python, spent here beside
@@ -839,6 +843,8 @@ class GenerativeSession:
                 # that has wrapped (ops/attention.py)
                 read = _np.minimum((filled // blks + 1) * blks, lens)
                 telemetry.inc("kv.page_positions", self._per_ring(n * whole))
+                telemetry.inc("kv.kernel_positions",
+                              self._per_ring(n * self._kernel_positions))
                 telemetry.inc("kv.skipped_positions",
                               self._per_ring(n * whole - read.sum()))
 

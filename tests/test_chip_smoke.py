@@ -48,6 +48,16 @@ TINY = {
                                 sliding_window=16, norm="rms",
                                 positions={"window_attention": "rotary"},
                                 qk_norm="head", out_gate=True,
+                                bias=False),
+                           dict(num_heads=4, num_kv_heads=2, head_dim=32,
+                                max_len=48,
+                                layer_types=["linear_attention",
+                                             "attention"],
+                                linear_heads=4, linear_key_heads=2,
+                                linear_key_dim=8, linear_value_dim=8,
+                                linear_neg_eigval=False, norm="rms",
+                                positions="rotary", rotary_dim=8,
+                                qk_norm="head", out_gate=True,
                                 bias=False)]},
     "kernel": {"shapes": [(4, 4, 4, 64), (8, 2, 2, 256)], "seed": 3},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
@@ -91,21 +101,22 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     # two layers' K and V rings of both attention-only shapes, then a
     # delta-rule layer's window and state beside one layer's rings, found
     # in the compiled decode programs, then a window layer's rings of 16
-    # positions beside a full layer's of 48; the CPU's programs hold no
-    # kernel call
-    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4
+    # positions beside a full layer's of 48, then two key heads under four
+    # value heads beside one layer's rings of heads of 32; the CPU's
+    # programs hold no kernel call
+    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
                                           [3, 2, 16, 48], [3, 2, 16, 16],
-                                          [3, 2, 16, 48]]
+                                          [3, 2, 16, 48], [3, 2, 32, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
     # the third shape's longest prefill bucket, read for the delta rule:
     # one such layer, no kernel in a program lowered for the CPU
-    assert report["kv_ring"]["delta_rule"] == [
+    assert report["kv_ring"]["delta_rule"] == 2 * [
         {"solves": 0, "kernel_calls": 0, "bucket": 8, "layers": 1,
          "kernel_layers": 0}]
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
-        ["16", "8"], ["8"], ["8"], ["8"]]
+        ["16", "8"], ["8"], ["8"], ["8"], ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
     monkeypatch.setattr(pk, "_INTERPRET", True)

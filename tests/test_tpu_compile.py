@@ -17,18 +17,21 @@ from mxnet_tpu.ops import attention, gdn
 SLOTS = 9
 # (rows, query heads, K/V heads, d_head, ring length, scale[, wraps]);
 # Trinity's two: a full layer's ring of the session's 6,144 positions and
-# a window layer's of 2,048, which wraps
+# a window layer's of 2,048, which wraps; Qwen3-Next's: 16 rows of 16 query
+# heads of 256 — a head over two 128-line tiles — on 2 K/V heads
 SHAPES = {"opt": (8, 32, 32, 64, 768, None),
           "olmoe": (8, 16, 16, 128, 768, None),
           "granite": (8, 32, 8, 64, 2304, 1 / 64),
           "olmo_hybrid": (8, 30, 30, 128, 2304, None),
           "one_row": (1, 32, 32, 64, 768, None),
           "trinity_full": (8, 32, 4, 128, 6144, None),
-          "trinity_window": (8, 32, 4, 128, 2048, None, True)}
+          "trinity_window": (8, 32, 4, 128, 2048, None, True),
+          "qwen3_next": (16, 16, 2, 256, 4096, None)}
 # positions and K/V heads a block, by the ring's bytes alone
 BLOCKS = {"opt": (128, 32), "olmoe": (128, 16), "granite": (384, 8),
           "olmo_hybrid": (128, 15), "one_row": (128, 32),
-          "trinity_full": (512, 4), "trinity_window": (512, 4)}
+          "trinity_full": (512, 4), "trinity_window": (512, 4),
+          "qwen3_next": (512, 2)}
 
 
 @pytest.fixture(scope="module")
@@ -91,21 +94,53 @@ def test_the_decode_attention_compiles_for_a_v5e(name, one_chip):
     assert stats.temp_size_in_bytes < np.prod(ring[1:]) * 4
 
 
-@pytest.mark.parametrize("bucket", [768, 2048])
-def test_the_delta_rule_prefill_compiles_for_a_v5e(bucket, one_chip):
-    """`_gdn_prefill` at Olmo-Hybrid's widths (30 heads of 96 x 192, chunks
-    of 64, the cell's shortest and longest bucket) lowered for the TPU:
-    ONE `tpu_custom_call` — the kernel, walking six heads at a time —
-    no triangular solve, the window and the state aliased to their
-    outputs, and nothing the size of the operands kept beside them."""
+@pytest.mark.parametrize("rows, on_the_mxu", [(1, 0), (16, 1)])
+def test_a_one_row_product_is_not_the_mxus(rows, on_the_mxu, one_chip):
+    """Why a check of a decoder's precision goes through the decode
+    program of as many rows as the window runs (PR 40, second session):
+    XLA compiles a float32 product of ONE row as a multiply-and-reduce
+    fusion in float32, and from two rows on as a convolution at the
+    default precision, one bfloat16 pass — the one-row program reads a
+    third of the 16-row one's error against a float32 reference."""
+    import re
+
     import jax
     import jax.numpy as jnp
 
-    h, dk, dv, taps, slots = 30, 96, 192, 4, 9
-    conv_dim = h * (2 * dk + dv)
+    def arg(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    text = jax.jit(lambda x, w: jnp.tanh(x @ w.T)).lower(
+        arg((rows, 2048)), arg((8192, 2048))).compile().as_text()
+    assert len(re.findall(r"= \S+ convolution\(", text)) == on_the_mxu
+
+
+# (key heads, value heads, d_k, d_v, heads a step of the kernel's walk)
+GDN_WIDTHS = {"olmo_hybrid": (30, 30, 96, 192, 6),
+              "qwen3_next": (16, 32, 128, 128, 8)}
+
+
+@pytest.mark.parametrize("bucket", [768, 2048])
+@pytest.mark.parametrize("widths", sorted(GDN_WIDTHS))
+def test_the_delta_rule_prefill_compiles_for_a_v5e(widths, bucket, one_chip):
+    """`_gdn_prefill` at Olmo-Hybrid's widths (30 heads of 96 x 192, chunks
+    of 64, the cell's shortest and longest bucket) and at Qwen3-Next's (16
+    q/k heads under 32 value heads of 128 x 128: the kernel sees 32 heads)
+    lowered for the TPU: ONE `tpu_custom_call` — the kernel, walking six
+    or eight heads at a time — no triangular solve, the window and the
+    state aliased to their outputs, and nothing the size of the operands
+    kept beside them."""
+    import jax
+    import jax.numpy as jnp
+
+    hk, h, dk, dv, walk = GDN_WIDTHS[widths]
+    taps, slots = 4, 9
+    conv_dim = gdn.conv_channels(h, dk, dv, hk)
     attrs = dict(num_heads=h, key_dim=dk, value_dim=dv, conv_kernel=taps,
-                 chunk_size=64, neg_eigval=True, eps=1e-6)
-    assert gdn.chunk_heads((1, bucket, h, dk), dv, 64, "tpu") == 6
+                 chunk_size=64, neg_eigval=hk == h, eps=1e-6)
+    if hk != h:
+        attrs["num_key_heads"] = hk
+    assert gdn.chunk_heads((1, bucket, h, dk), dv, 64, "tpu") == walk
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
