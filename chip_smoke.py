@@ -32,11 +32,15 @@ apart from the rest:
             128; a delta-rule layer of 16 q/k heads under 32 value heads
             of 128 x 128 beside a ring of 2 K/V heads of 256 — a head
             over two tiles of 128 lines — under 16 query heads, a quarter
-            of each head rotated; 8 sessions) as XLA compiled them: every cache_spec
+            of each head rotated; 8 sessions, the sixth 16) as XLA
+            compiled them: every cache_spec
             entry aliased to its output, no instruction that copies one,
-            ONE attention kernel call an attention layer, and no ring or
+            ONE attention kernel call an attention layer, ONE step-kernel
+            call a delta-rule layer (ops/gdn_step_kernel.py) and nothing
+            of rows x page size beside it, and no ring or
             recurrent state fatter on the device than cache_spec states;
-            prints the rings' on-device layout; and the 2,048-bucket
+            prints the rings' on-device layout and the warm ms of the
+            delta-rule models' decode step; and the 2,048-bucket
             prefill of the fourth and the sixth: ONE kernel call a
             delta-rule layer
             (ops/gdn_kernel.py) and no triangular solve left in it; and
@@ -58,6 +62,7 @@ seconds, compile seconds and facts.  The phase functions take
 tiny on mx.cpu(); main() has no switch that skips the device check.
 """
 import json
+import math
 import re
 import sys
 import threading
@@ -108,6 +113,7 @@ FULL = {
                            # K/V heads of 256, a head over two tiles
                            dict(num_heads=16, num_kv_heads=2, head_dim=256,
                                 max_len=4096, seq_buckets=[64, 2048],
+                                max_sessions=16,
                                 layer_types=["linear_attention",
                                              "attention"],
                                 linear_heads=32, linear_key_heads=16,
@@ -516,6 +522,48 @@ def delta_rule_hlo_facts(text):
             "kernel_calls": text.count('custom_call_target="tpu_custom_call"')}
 
 
+def delta_step_hlo_facts(text, rows, state_shape):
+    """What a compiled decode program of `rows` rows makes of the delta
+    rule's step, read from its optimised HLO `text`: the calls of the
+    step kernel (``ops/gdn_step_kernel.py``; a Pallas kernel keeps its
+    name) and every array of ``rows x d_k x H d_v`` elements, the rows
+    leading — a key spread out to a page's size for all rows, or the
+    rows' pages gathered — for a state of `state_shape` ``(slots, d_k, H
+    d_v)``."""
+    page = state_shape[1] * state_shape[2]
+    fat = {"f32[%d,%s]" % (rows, dims)
+           for dims in re.findall(r"f32\[%d,([\d,]+)\]" % rows, text)
+           if math.prod(int(d) for d in dims.split(",")) == page}
+    return {"kernel_calls": sum(
+                'custom_call_target="tpu_custom_call"' in line
+                and "gdn_state_step" in line for line in text.splitlines()),
+            "row_pages": sorted(fat)}
+
+
+def _best_ms(session, exe, fn, calls, *operands):
+    """The best of `calls` synchronous calls of a warm program of the
+    session on the host's clock, in ms."""
+    took = []
+    for _ in range(calls):
+        t0 = time.perf_counter()
+        session._run(exe, fn, *operands)
+        took.append(time.perf_counter() - t0)
+    return 1e3 * min(took)
+
+
+def decode_step_ms(session, rows, calls=5):
+    """The warm decode program of `rows` rows through the session's own
+    synchronous call, every row a padded one on the scratch slot: the
+    best of `calls` on the host's clock, in ms."""
+    import numpy as np
+
+    exe, fn = session._program(session._decode_pred, rows, 1, False)
+    return _best_ms(session, exe, fn, calls,
+                    np.zeros((rows, 1), np.float32),
+                    np.full((rows,), rows, np.float32),
+                    np.zeros((rows,), np.float32))
+
+
 BUCKET_RATIO = 1.5  # a prefill bucket's time over the next larger one's
 
 
@@ -528,14 +576,10 @@ def prefill_bucket_ms(session, buckets, calls=3):
     best = {}
     for t in buckets:
         exe, fn = session._program(session._prefill_pred, 1, t, True)
-        operands = (np.zeros((1, t), np.float32), np.zeros((1,), np.float32),
-                    np.full((1,), t, np.float32))
-        took = []
-        for _ in range(calls):
-            t0 = time.perf_counter()
-            session._run(exe, fn, *operands)
-            took.append(time.perf_counter() - t0)
-        best[t] = 1e3 * min(took)
+        best[t] = _best_ms(session, exe, fn, calls,
+                           np.zeros((1, t), np.float32),
+                           np.zeros((1,), np.float32),
+                           np.full((1,), t, np.float32))
     return best
 
 
@@ -565,12 +609,13 @@ def phase_kv_ring(sizes, ctx):
     from mxnet_tpu.models import TransformerLM
 
     platform = ctx.jax_device().platform
-    slots = sizes["max_sessions"]
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
-             "layouts": [], "rings": [], "delta_rule": [], "prefill_ms": []}
+             "layouts": [], "rings": [], "delta_rule": [], "delta_step": [],
+             "prefill_ms": []}
     for shape in sizes["shapes"]:
         shape = dict(shape)
         buckets = shape.pop("seq_buckets", sizes["seq_buckets"])
+        slots = shape.pop("max_sessions", sizes["max_sessions"])
         lm = TransformerLM(**{**dict(vocab=sizes["vocab"],
                                      num_layers=sizes["num_layers"],
                                      d_model=sizes["d_model"],
@@ -625,6 +670,17 @@ def phase_kv_ring(sizes, ctx):
                             bucket=longest, layers=scanned,
                             kernel_layers=booked["gdn.kernel_positions"]
                             // longest)
+                # its decode program: the step kernel where the shape
+                # function gives it one, and nothing of rows x page size
+                stepped = lm.call_counters(rows=slots, platform=platform)
+                state, = {e.shape for n, e in spec.items()
+                          if n.startswith("gdn_state")}
+                stepped_by = dict(
+                    delta_step_hlo_facts(fn.hlo_text(), slots, state),
+                    rows=slots, layers=scanned,
+                    kernel_layers=scanned * stepped["gdn.step_kernel_bytes"]
+                    // stepped["gdn.state_bytes"],
+                    ms=float("%.3g" % decode_step_ms(session, slots)))
             prefill_ms = prefill_bucket_ms(session, buckets)
             # the live set as the warm-up's programs left it on the device
             held = [(n, e.nbytes, getattr(a, "on_device_size_in_bytes",
@@ -665,9 +721,11 @@ def phase_kv_ring(sizes, ctx):
                    "times the next larger bucket's program: %s"
                    % (slow, BUCKET_RATIO, prefill_ms))
         if platform == "tpu":
-            _check(facts["kernel_calls"] == ring_layers,
+            others = stepped_by["kernel_calls"] if scanned else 0
+            _check(facts["kernel_calls"] - others == ring_layers,
                    "%d attention kernel calls in a decode program of %d "
-                   "attention layers" % (facts["kernel_calls"], ring_layers))
+                   "attention layers" % (facts["kernel_calls"] - others,
+                                         ring_layers))
         if scanned:
             print("[chip_smoke] kv_ring: the %(bucket)d-bucket prefill of "
                   "%(layers)d delta-rule layer(s): %(kernel_calls)d kernel "
@@ -682,6 +740,22 @@ def phase_kv_ring(sizes, ctx):
                    "%(kernel_layers)d" % rule)
             _check(not rule["solves"], "the prefill program still holds "
                    "%(solves)d triangular solve(s)" % rule)
+        if scanned:
+            print("[chip_smoke] kv_ring: the %(rows)d-row decode step of "
+                  "%(layers)d delta-rule layer(s): %(kernel_calls)d "
+                  "step-kernel call(s), arrays of rows x page size: "
+                  "%(row_pages)s; %(ms)s ms a warm step" % stepped_by,
+                  flush=True)
+            total["delta_step"].append(stepped_by)
+        if scanned and platform == "tpu":
+            _check(stepped_by["kernel_layers"] == stepped_by["kernel_calls"]
+                   == stepped_by["layers"],
+                   "%(kernel_calls)d step-kernel calls "
+                   "in a decode program of %(layers)d delta-rule layers, "
+                   "of which the shape function gives the kernel "
+                   "%(kernel_layers)d" % stepped_by)
+            _check(not stepped_by["row_pages"], "the decode program holds "
+                   "arrays of rows x page size: %(row_pages)s" % stepped_by)
         for key in ("ring_params", "aliased", "kernel_calls"):
             total[key] += facts[key]
         total["copies"] += len(facts["copies"])

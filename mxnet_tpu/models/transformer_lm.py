@@ -433,16 +433,21 @@ class _GatedDeltaNet(_Recurrent):
         scans in this layer (the pad included), those of them that a
         program lowered for `platform` runs through the TPU's kernel
         (all, or none where ``ops.gdn.chunk_heads`` says the ``jax.numpy``
-        body runs), and the bytes of window and state a decode step's
-        `rows` rows read and write."""
+        body runs), the bytes of window and state a decode step's
+        `rows` rows read and write, and those of them that such a
+        program's step kernel moves (all, or none where
+        ``ops.gdn.step_heads`` says the body runs)."""
         page = sum(e.nbytes for _, e in self.cache_spec(i, 1, 0))
         lm = self.lm
         tiled = _gdn.chunk_heads(
             (1, positions, lm.linear_heads, lm.linear_key_dim),
             lm.linear_value_dim, lm.linear_chunk, platform) is not None
+        stepped = _gdn.step_heads((1,) + self.state_shapes[1],
+                                  lm.linear_value_dim, platform) is not None
         return {"gdn.scan_positions": positions,
                 "gdn.kernel_positions": positions * tiled,
-                "gdn.state_bytes": 2 * rows * page}
+                "gdn.state_bytes": 2 * rows * page,
+                "gdn.step_kernel_bytes": 2 * rows * page * stepped}
 
 
 _KINDS = {"attention": _Attention, "window_attention": _WindowAttention,

@@ -47,12 +47,24 @@ what the state already answers for its key.
   ``jax.export`` kept beside JAX's compiled programs), so that a warm
   start neither imports Pallas nor traces the kernel for its first
   prefill.
-* ``_gdn_step`` — one position for B packed decode rows: each row's page
-  is read where it lies, advanced and written back by one
-  ``dynamic_update_slice`` (ops/ssm.py ``_ssm_step``'s discipline).  Both
-  products with the old state — ``S k`` for the correction and ``S q``
-  for the output, ``o = alpha S q + beta (v - alpha S k)(k . q)`` — are
-  reductions of ONE read of the page; the update is a second pass.
+* ``_gdn_step`` — one position for B packed decode rows, each row's page
+  advanced where it lies in the donated buffer.  Both products with the
+  old state — ``S k`` for the correction and ``S q`` for the output, ``o =
+  alpha S q + beta (v - alpha S k)(k . q)`` — and the update ``alpha S +
+  k write^T`` need ONE read of the page.  On a TPU that is ONE Pallas
+  kernel a layer over the packed rows (``ops/gdn_step_kernel.py``: grid
+  ``(row, head group)``, the block of ``slot[b]``'s page brought to VMEM
+  by the pipeline, a head's key column spread over that head's lanes in
+  registers, the block sent back: a page is read once and written once)
+  wherever ``step_heads`` says the kernel tiles the state's shape;
+  ``lax.platform_dependent`` chooses at lowering, and `_step_body` is
+  what runs everywhere else and the kernel's oracle: the rows' pages
+  gathered, advanced in one expression and scattered back, nothing
+  spread out to ``rows x page`` (XLA made that of the keys and queries,
+  33.5 MB an operand a layer at 16 rows of 128 x 4,096, and read every
+  page three times: PERF.md section 6, PR 41).  The conv window, the
+  conv, the L2 norms, the gates and the gated norm are XLA's on every
+  platform.
 
 Stored shapes belong to the model (``TransformerLM.cache_spec``): a conv
 window ``(slots, K - 1, 2 H_k d_k + H d_v)`` and a state ``(slots, d_k, H *
@@ -64,10 +76,10 @@ x 128 lanes, 96 = 12 x 8 sublanes; stored ``(H, d_v, d_k)`` each line of
 Precision: everything after the projection is float32, the chunk's
 solve and products at ``highest`` (ops/ssm.py says why: at one bfloat16
 pass the carried state rounds like a bf16 recurrence), the step's
-products multiply-adds on the vector unit.  ``_gdn_scan`` and ``_gdn_step``
-are pure ``jax.numpy`` / ``lax``, and so is ``_gdn_prefill`` off the TPU;
-``_gdn_scan`` is differentiable (the kernel has no backward and is not on
-its path).
+products multiply-adds on the vector unit, in the kernel as in the body.
+``_gdn_scan`` is pure ``jax.numpy`` / ``lax``, and so are ``_gdn_prefill``
+and ``_gdn_step`` off the TPU; ``_gdn_scan`` is differentiable (the
+kernels have no backward and are not on its path).
 """
 from __future__ import annotations
 
@@ -419,17 +431,87 @@ def gdn_prefill(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
     return y, conv_state, gdn_state
 
 
+_STEP_BLOCK_BYTES = 1 << 20
+
+
+def step_heads(state_shape, value_dim, platform):
+    """Value heads that one grid step of the TPU's decode kernel holds
+    (``ops/gdn_step_kernel.py``), for a state stored `state_shape`
+    ``(slots, d_k, H d_v)`` of float32: the most heads that divide ``H``,
+    fill whole 128-lane tiles and make a block ``(d_k, heads d_v)`` of at
+    most 1 MiB — the pipeline holds two of them coming and two going, and
+    a row of several blocks has the next one on its way while one is
+    advanced — or the fewest that fill whole tiles where none is that
+    small.  Qwen3-Next's ``(17, 128, 4096)``, 32 heads of 128 values: 16,
+    half a page; Olmo-Hybrid's ``(9, 96, 5760)``, 30 heads of 192 — a head
+    is a tile and a half, so an even number: 10, a third of a page.  None
+    where `_gdn_step` runs its ``jax.numpy`` body: off the TPU, or for a
+    state the kernel's tiling does not divide — ``d_k`` no whole number
+    of 8-row tiles, no group of heads a whole number of lane tiles, or
+    the smallest such group beyond 4 MiB.  Whoever counts what a decode
+    step runs (``TransformerLM.call_counters``) asks here."""
+    _, dk, width = state_shape
+    dv = int(value_dim)
+    h = width // dv
+    if platform != "tpu" or dk % _SUBLANES:
+        return None
+    block = lambda n: 4 * dk * n * dv                 # bytes, float32
+    fit = [n for n in range(1, h + 1) if h % n == 0 and n * dv % _LANES == 0]
+    if not fit or block(fit[0]) > 4 * _STEP_BLOCK_BYTES:
+        return None
+    return max([n for n in fit if block(n) <= _STEP_BLOCK_BYTES] or fit[:1])
+
+
+def _step_body(k, q, v, alpha, beta, state, slot):
+    """One position of the rule for B rows: ``k`` / ``q (B, H, d_k)``, ``v
+    (B, H, d_v)``, ``alpha`` / ``beta (B, H)``, row b's page at
+    ``slot[b]`` of ``state (slots, d_k, H d_v)``.  The rows' pages are
+    gathered, advanced in one expression — a head's key against its own
+    ``(d_k, d_v)`` of the page, nothing spread out to a page's size — and
+    scattered back (padded rows all name the scratch slot: one of them
+    stays there).  Returns ``(o (B, H, d_v), state')``."""
+    rows, h, dk = k.shape
+    dv = v.shape[-1]
+    pages = state[slot].astype(jnp.float32).reshape(rows, dk, h, dv)
+    keyed = lambda x: x.transpose(0, 2, 1)[..., None]     # (B, d_k, H, 1)
+    a = alpha[..., None]
+    s_k = (pages * keyed(k)).sum(axis=1) * a              # alpha S k
+    s_q = (pages * keyed(q)).sum(axis=1) * a              # alpha S q
+    write = beta[..., None] * (v - s_k)
+    o = s_q + write * jnp.sum(k * q, axis=-1)[..., None]
+    pages = pages * a[:, None] + keyed(k) * write[:, None]
+    return o, state.at[slot].set(
+        pages.reshape(rows, dk, h * dv).astype(state.dtype))
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _state_step(k, q, v, alpha, beta, state, slot, *, heads, interpret):
+    """`_step_body` on whatever platform the program is lowered for: the
+    TPU's kernel (`heads` value heads a grid step; `interpret` runs it in
+    Pallas's interpreter, for tests) or the ``jax.numpy`` body.  Jitted,
+    so that the delta-rule layers of a decode program trace and lower
+    both once."""
+    operands = (k, q, v, alpha, beta, state, slot)
+    if heads is None:
+        return _step_body(*operands)
+
+    def kernel(*operands):
+        from .gdn_step_kernel import state_step
+
+        return state_step(*operands, heads=heads, interpret=interpret)
+    return lax.platform_dependent(*operands, tpu=kernel, default=_step_body)
+
+
 @register("_gdn_step",
           inputs=("data",) + PARAMS + ("conv_state", "gdn_state", "slot"),
           num_outputs=3, infer_shape=_infer_stateful)
 def gdn_step(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
              gdn_state, slot, **kw):
     """One decode step of the mixer for B packed rows: ``data (B, 1,
-    d_proj)``, row b's window and state at ``slot[b]``.  The rows are
-    advanced in row order, each page read where it lies, stepped and
-    written back with one ``dynamic_update_slice`` (padded rows all land
-    on the scratch slot, one after the other).  Outputs ``y (B, 1, H
-    d_v)`` and the two updated buffers."""
+    d_proj)``, row b's window and state at ``slot[b]``, each advanced by
+    one position and written back where it lay (`_state_step`; padded
+    rows all land on the scratch slot).  Outputs ``y (B, 1, H d_v)`` and
+    the two updated buffers."""
     attrs = _attrs(kw)
     hk, h, dk, dv, _ = _sizes(attrs)
     rows = data.shape[0]
@@ -441,34 +523,19 @@ def gdn_step(data, conv_weight, dt_bias, A_log, norm_gamma, conv_state,
                                                  keepdims=False)
                         for i in range(rows)]).astype(jnp.float32),
              raw[:, None]], axis=1)                       # (B, K, C)
-        q, k, v = _heads(jnn.silu((window * conv_weight).sum(axis=1)),
-                         hk, h, dk, dv)
-        beta, g = _gates(b, a, dt_bias, A_log, attrs)     # (B, H)
-        alpha = jnp.exp(g)
-        kq = jnp.sum(k * q, axis=-1)                      # (B, H)
-        # along the stored state's lanes, (head, value): a head's scalar
-        # repeated over its values; along its leading axis, the key
-        wide = lambda x: jnp.repeat(x, dv, axis=-1)       # (B, H) -> (B, H dv)
-        keyed = lambda x: x.transpose(0, 2, 1)[..., None]  # (B, d_k, H, 1)
-        k_s, q_s = (jnp.broadcast_to(keyed(x), (rows, dk, h, dv))
-                    .reshape(rows, dk, h * dv) for x in (k, q))
-        out = []
         for i in range(rows):
-            page = lax.dynamic_index_in_dim(gdn_state, slot_i[i], 0,
-                                            keepdims=False).astype(jnp.float32)
-            a_i = wide(alpha[i])
-            s_k = (page * k_s[i]).sum(axis=0) * a_i       # alpha S k
-            s_q = (page * q_s[i]).sum(axis=0) * a_i       # alpha S q
-            write = wide(beta[i]) * (v[i].reshape(-1) - s_k)
-            out.append(s_q + write * wide(kq[i]))
-            gdn_state = lax.dynamic_update_slice(
-                gdn_state,
-                (page * a_i + k_s[i] * write)[None].astype(gdn_state.dtype),
-                (slot_i[i], 0, 0))
             conv_state = lax.dynamic_update_slice(
                 conv_state, window[i, 1:][None].astype(conv_state.dtype),
                 (slot_i[i], 0, 0))
-        o = jnp.stack(out).reshape(rows, h, dv)
+        q, k, v = _heads(jnn.silu((window * conv_weight).sum(axis=1)),
+                         hk, h, dk, dv)
+        beta, g = _gates(b, a, dt_bias, A_log, attrs)     # (B, H)
+        # the heads a lowering for the TPU would hold at a time; which
+        # platform the program is lowered for is not known here
+        o, gdn_state = _state_step(
+            k, q, v, jnp.exp(g), beta, gdn_state, slot_i,
+            interpret=_INTERPRET,
+            heads=step_heads(gdn_state.shape, dv, "tpu"))
         y = _gated_norm(o, z.reshape(o.shape), norm_gamma,
                         float(_lit(attrs["eps"])))
     return (y.reshape(rows, 1, h * dv).astype(data.dtype), conv_state,

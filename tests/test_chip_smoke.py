@@ -114,6 +114,16 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     assert report["kv_ring"]["delta_rule"] == 2 * [
         {"solves": 0, "kernel_calls": 0, "bucket": 8, "layers": 1,
          "kernel_layers": 0}]
+    # and their decode programs, read for the step: one such layer, no
+    # kernel in a program lowered for the CPU, whose body gathers the two
+    # rows' pages (two heads of 8 x 16; four of 8 x 8), the step timed warm
+    steps = report["kv_ring"]["delta_step"]
+    assert [{k: v for k, v in step.items() if k not in ("ms", "row_pages")}
+            for step in steps] == 2 * [
+        {"kernel_calls": 0, "rows": 2, "layers": 1, "kernel_layers": 0}]
+    assert "f32[2,8,2,16]" in steps[0]["row_pages"]
+    assert "f32[2,8,4,8]" in steps[1]["row_pages"]
+    assert all(step["ms"] > 0 for step in steps)
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
         ["16", "8"], ["8"], ["8"], ["8"], ["8"]]

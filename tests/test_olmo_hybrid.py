@@ -13,6 +13,7 @@ sides float32 on the CPU: errors are float32 rounding (measured 6e-6 of
 the largest logit); the bound 1e-4 is far above that and a fortieth of
 what one bfloat16 pass leaves.  The file costs about 60 s.
 """
+import json
 import os
 import sys
 
@@ -24,7 +25,8 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.models import TransformerLM
 from mxnet_tpu.serving import GenerateRequest, GenerativeSession
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
 from benchmarks.families import olmo_hybrid as family  # noqa: E402
 from benchmarks.reference import olmo_hybrid as reference  # noqa: E402
 
@@ -577,12 +579,14 @@ def test_the_delta_rule_counters(held):
     much where the prefill's program runs the TPU's kernel, by nothing on
     the CPU; per decode dispatch `gdn.state_bytes` by the window and
     state of each real row, read and written, times the linear layers —
-    the kind declares all three (`TransformerLM.call_counters`) from the
+    and `gdn.step_kernel_bytes` by as much where the decode program runs
+    the TPU's step kernel — the kind declares all four (`TransformerLM.call_counters`) from the
     shapes and the platform it is told, the session books what it is
     told."""
     telemetry.set_enabled(True)
     names = ("gdn.scan_positions", "gdn.kernel_positions", "gdn.state_bytes",
-             "serving.decode.dispatches", "cache.state_bytes")
+             "gdn.step_kernel_bytes", "serving.decode.dispatches",
+             "cache.state_bytes")
     before = {n: telemetry.counter_value(n) for n in names}
     gs = _session(held, max_sessions=2)
     try:
@@ -597,21 +601,32 @@ def test_the_delta_rule_counters(held):
     assert moved["gdn.scan_positions"] == 3 * (8 + 32)
     assert moved["gdn.kernel_positions"] == 0       # the CPU's programs
     assert moved["gdn.state_bytes"] == 2 * 2 * 3 * 2 * page
+    assert moved["gdn.step_kernel_bytes"] == 0      # the CPU's programs
     assert moved["cache.state_bytes"] > 0
     lm = family.model(CONFIG)
     assert lm.call_counters(positions=32, platform="cpu") == {
         "gdn.scan_positions": 96, "gdn.kernel_positions": 0,
-        "gdn.state_bytes": 0}
+        "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0}
     # a program lowered for the TPU runs the kernel in every bucket of
     # whole chunks (of 8 here), and the body in any other
     assert lm.call_counters(positions=32, platform="tpu") == {
         "gdn.scan_positions": 96, "gdn.kernel_positions": 96,
-        "gdn.state_bytes": 0}
+        "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0}
     assert lm.call_counters(positions=36, platform="tpu") == {
         "gdn.scan_positions": 108, "gdn.kernel_positions": 0,
-        "gdn.state_bytes": 0}
+        "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0}
     assert lm.call_counters(rows=2, platform="tpu") == {
         "gdn.scan_positions": 0, "gdn.kernel_positions": 0,
-        "gdn.state_bytes": 2 * 2 * 3 * page}
+        "gdn.state_bytes": 2 * 2 * 3 * page, "gdn.step_kernel_bytes": 0}
+    # (four heads of 16 values fill no lane tile: `ops.gdn.step_heads`); at
+    # the published widths a program lowered for the TPU steps every page
+    # through the kernel, and none lowered for the CPU does
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        real = family.model(json.load(f))
+    stepped = real.call_counters(rows=8, platform="tpu")
+    assert stepped["gdn.step_kernel_bytes"] == stepped["gdn.state_bytes"] > 0
+    assert real.call_counters(rows=8, platform="cpu")[
+        "gdn.step_kernel_bytes"] == 0
     # a model none of whose kinds declares a counter books none
     assert TransformerLM(vocab=8).call_counters(positions=32, rows=4) == {}

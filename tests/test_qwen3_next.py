@@ -399,17 +399,25 @@ def test_cache_spec_and_counters_take_both_head_counts():
     page = 4 * (3 * conv_dim + 16 * 8 * 16)
     assert lm.call_counters(positions=32, platform="cpu") == {
         "gdn.scan_positions": 3 * 32, "gdn.kernel_positions": 0,
-        "gdn.state_bytes": 0, "moe.routed_pairs": 4 * 32 * 4}
+        "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0,
+        "moe.routed_pairs": 4 * 32 * 4}
     assert lm.call_counters(rows=3, lengths=[3, 8, 30], computed=4, pages=10,
                             max_len=128, platform="cpu") == {
         "gdn.scan_positions": 0, "gdn.kernel_positions": 0,
-        "gdn.state_bytes": 3 * 2 * 3 * page, "moe.routed_pairs": 4 * 4 * 4}
+        "gdn.state_bytes": 3 * 2 * 3 * page, "gdn.step_kernel_bytes": 0,
+        "moe.routed_pairs": 4 * 4 * 4}
+    # lowered for the TPU, the step kernel moves all of them: eight heads
+    # of 16 values are one lane tile
+    assert lm.call_counters(rows=3, platform="tpu")[
+        "gdn.step_kernel_bytes"] == 3 * 2 * 3 * page
     # at the published widths a 2,048 bucket runs through the kernel
     real = json.load(open(os.path.join(
         ROOT, "benchmarks", "configs", "qwen3-next-80b-a3b.json")))
     counted = family.model(real).call_counters(positions=2048, platform="tpu")
     assert counted["gdn.kernel_positions"] == counted["gdn.scan_positions"] \
         == 3 * 2048
+    stepped = family.model(real).call_counters(rows=16, platform="tpu")
+    assert stepped["gdn.step_kernel_bytes"] == stepped["gdn.state_bytes"] > 0
 
 
 def test_the_batcher_books_recurrent_state_experts_and_the_ring(held):

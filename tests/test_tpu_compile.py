@@ -1,9 +1,9 @@
-"""The decode-attention kernel and the delta rule's prefill kernel
-compiled for a TPU v5e that is described, not attached (the TPU's
+"""The decode-attention kernel and the delta rule's prefill and step
+kernels compiled for a TPU v5e that is described, not attached (the TPU's
 compiler is installed where the tests run): what Pallas's interpreter
 cannot see — Mosaic refusing a slice, a layout or the fast memory a
-kernel asks for — at the real widths of the benchmark's four decoders.  Nothing runs; a compile that passes is not
-a chip run.  All such compiles live in this ONE file: only one process a
+kernel asks for — at the real widths of the benchmark's decoders.
+Nothing runs; a compile that passes is not a chip run.  All such compiles live in this ONE file: only one process a
 host may hold the TPU's library, and the topology is described inside a
 fixture so that every xdist worker collects the same tests."""
 import os
@@ -161,6 +161,80 @@ def test_the_delta_rule_prefill_compiles_for_a_v5e(widths, bucket, one_chip):
     assert state["copies"] == []
 
 
+def _serving_program(graph, wire, one_chip):
+    """The serving `graph` compiled for the described chip as a session
+    compiles it: `wire` ({name: shape}: the call's inputs and the cache
+    entries) donated, every other argument a weight."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.executor import _run_graph
+    from mxnet_tpu.symbol import _topo_order
+
+    order = _topo_order(graph._entries)
+    names = graph.list_arguments()
+
+    def program(state, weights):
+        vals = {**state, **weights}
+        outs, _ = _run_graph(graph._entries, order, names, [],
+                             tuple(vals[n] for n in names), (), False,
+                             jax.random.key(0))
+        return outs
+
+    shapes, _, _ = graph.infer_shape(**wire)
+    args = {n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for n, s in zip(names, shapes)}
+    return jax.jit(program, donate_argnums=(0,)).lower(
+        {n: a for n, a in args.items() if n in wire},
+        {n: a for n, a in args.items() if n not in wire}).compile()
+
+
+# name -> (chip_smoke.py's kv_ring model, rows, heads a grid step)
+STEP_MODELS = {"olmo_hybrid": (3, 8, 10), "qwen3_next": (5, 16, 16)}
+
+
+@pytest.mark.parametrize("widths", sorted(STEP_MODELS))
+def test_the_delta_rule_step_compiles_for_a_v5e(widths, one_chip):
+    """The decode program of `chip_smoke.py`'s two delta-rule models — a
+    delta-rule layer at Olmo-Hybrid's widths (30 heads of 96 x 192, 8
+    rows) and at Qwen3-Next's (16 q/k heads under 32 value heads of 128 x
+    128, 16 rows), each beside an attention layer — lowered for the TPU:
+    ONE step-kernel call beside the ring's, no array of ``rows x d_k x H
+    d_v`` elements (the keys spread out to a page's size for all rows,
+    PR 40's 33.5 MB an operand a layer; or the rows' pages gathered), and
+    the state aliased to its output and never copied.  The whole program,
+    not the op alone: a program with nothing else in it has the chip's
+    128 MiB of VMEM to spare, and XLA stages the whole 36 MB state there
+    and back around the kernel."""
+    import warnings
+
+    from mxnet_tpu.models import TransformerLM
+
+    index, rows, heads = STEP_MODELS[widths]
+    sizes = chip_smoke.FULL["kv_ring"]
+    shape = {k: v for k, v in sizes["shapes"][index].items()
+             if k not in ("seq_buckets", "max_sessions")}
+    lm = TransformerLM(**{**{k: sizes[k] for k in (
+        "vocab", "num_layers", "d_model", "d_ff")}, **shape})
+    spec = lm.cache_spec(rows + 1)
+    state = spec["gdn_state_0"].shape
+    assert gdn.step_heads(state, lm.linear_value_dim, "tpu") == heads
+    wire = dict(data=(rows, 1), slot=(rows,), length=(rows,),
+                last_token=(rows + 1,),
+                **{n: e.shape for n, e in spec.items()})
+    gdn._state_step.clear_cache()
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        text = _serving_program(lm.decode_symbol(), wire,
+                                one_chip).as_text()
+    assert chip_smoke.delta_step_hlo_facts(text, rows, state) == {
+        "kernel_calls": 1, "row_pages": []}
+    facts = chip_smoke.ring_hlo_facts(text, state)
+    assert facts["kernel_calls"] == 2      # the ring's and the step's
+    assert facts["ring_params"] == facts["aliased"] == 1
+    assert facts["copies"] == []
+
+
 OPT_BUCKETS = [64, 128, 256, 512]  # benchmarks/traffic/gen_closed_c16.json
 
 
@@ -174,40 +248,19 @@ def opt_prefill_cycles(one_chip):
     import json
     import re
 
-    import jax
-    import jax.numpy as jnp
-
     from benchmarks.families import opt
-    from mxnet_tpu.executor import _run_graph
-    from mxnet_tpu.symbol import _topo_order
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "benchmarks", "configs",
                            "opt-1.3b.json")) as f:
         lm = opt.model(json.load(f))
     graph = lm.prefill_symbol()
-    order = _topo_order(graph._entries)
-    names = graph.list_arguments()
     spec = lm.cache_spec(SLOTS, 768)
-
-    def prefill(state, weights):
-        vals = {**state, **weights}
-        outs, _ = _run_graph(graph._entries, order, names, [],
-                             tuple(vals[n] for n in names), (), False,
-                             jax.random.key(0))
-        return outs
-
     cycles = {}
     for t in OPT_BUCKETS:
         wire = dict(data=(1, t), slot=(1,), length=(1,), last_token=(SLOTS,),
                     **{n: e.shape for n, e in spec.items()})
-        shapes, _, _ = graph.infer_shape(**wire)
-        args = {n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
-                for n, s in zip(names, shapes)}
-        text = jax.jit(prefill, donate_argnums=(0,)).lower(
-            {n: a for n, a in args.items() if n in wire},
-            {n: a for n, a in args.items() if n not in wire}
-        ).compile().as_text()
+        text = _serving_program(graph, wire, one_chip).as_text()
         found = [int(c) for c in re.findall(
             r'"estimated_cycles":"(\d+)"', text[text.index("ENTRY"):])]
         cycles[t] = (sum(found), max(found))
