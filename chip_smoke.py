@@ -17,7 +17,8 @@ apart from the rest:
             (a fence that returns early would "beat" it)
   train     ResNet-50 NHWC bf16 through Module.fit: steps at one step per
             dispatch (executor.fused_step), then blocks of K steps
-            through DeviceStagedIter (executor.fused_block)
+            through DeviceStagedIter (executor.fused_block), each but
+            the first dispatched before the one ahead of it is read
   serve     a ResNet-50 Predictor behind ModelServer, unbatched requests
             from several threads, results against Predictor.forward
   generate  TransformerLM via add_generative_tenant + submit_generate;
@@ -258,10 +259,11 @@ def _check_on_devices(exe, devices, what):
     return len(diff_names)
 
 
-def _fit(mod, it, metric, num_epoch, k):
+def _fit(mod, it, metric, num_epoch, k, batch_end_callback=None):
     mod.fit(it, eval_metric=metric, optimizer="sgd",
             optimizer_params={"learning_rate": 0.01, "momentum": 0.9},
-            num_epoch=num_epoch, steps_per_dispatch=k)
+            num_epoch=num_epoch, steps_per_dispatch=k,
+            batch_end_callback=batch_end_callback)
 
 
 def phase_train(sizes, ctx):
@@ -294,13 +296,28 @@ def phase_train(sizes, ctx):
     _check(np.isfinite(w1).all() and np.abs(w1 - w0).max() > 0,
            "K=1 fit did not move fc1_weight")
 
-    d0 = dispatches()
-    _fit(mod, it, metric, num_epoch=sizes["blocks"], k=K)
-    _check(dispatches() - d0 == sizes["blocks"],
-           "K=%d: %d dispatches for %d blocks"
-           % (K, dispatches() - d0, sizes["blocks"]))
-    loss_block = float(metric.get()[1])
-    _check(np.isfinite(loss_block), "K=%d loss %r" % (K, loss_block))
+    # the K-step loop: `blocks` passes over the data as ONE epoch (the
+    # same batches in the same order as `blocks` epochs), each block's
+    # loss read by its callback while the next is already dispatched
+    losses = []
+
+    def block_end(param):
+        losses.append(float(metric.get()[1]))
+        metric.reset()
+
+    d0, ahead0 = dispatches(), telemetry.counter_value(
+        "module.runahead_blocks")
+    _fit(mod, mx.io.ResizeIter(it, K * sizes["blocks"]), metric, num_epoch=1,
+         k=K, batch_end_callback=block_end)
+    _check(dispatches() - d0 == sizes["blocks"] == len(losses),
+           "K=%d: %d dispatches and %d callbacks for %d blocks"
+           % (K, dispatches() - d0, len(losses), sizes["blocks"]))
+    ahead = telemetry.counter_value("module.runahead_blocks") - ahead0
+    _check(ahead == sizes["blocks"] - 1,
+           "K=%d: %d of %d blocks were dispatched behind an unread one, "
+           "expected all but the first" % (K, ahead, sizes["blocks"]))
+    loss_block = losses[-1]
+    _check(np.isfinite(losses).all(), "K=%d losses %r" % (K, losses))
     w2 = exe.arg_dict["fc1_weight"].asnumpy()
     _check(np.isfinite(w2).all() and np.abs(w2 - w1).max() > 0,
            "K=%d fit did not move fc1_weight" % K)
@@ -309,7 +326,8 @@ def phase_train(sizes, ctx):
     n = _check_on_devices(exe, {ctx.jax_device()}, "train")
     return {"loss_k1": round(loss_step, 4),
             "loss_k%d" % K: round(loss_block, 4),
-            "steps": K + K * sizes["blocks"], "params_on_device": n,
+            "steps": K + K * sizes["blocks"], "runahead_blocks": ahead,
+            "params_on_device": n,
             "mfu_gauge": telemetry.gauge_value("module.mfu")}
 
 

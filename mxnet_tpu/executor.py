@@ -215,6 +215,25 @@ def _stack_steps(*steps):
     return jnp.stack(steps)
 
 
+def _host_step_fence(device_platform, host_platform="cpu"):
+    """What the staging thread waits for before the source of a step
+    that came from host memory may refill its buffer
+    (Executor.stack_block_input), from the two platforms alone:
+
+    * "pieces" where the devices are of another platform than host
+      memory (a chip): the buffer is needed until each device's rows
+      have ARRIVED.  Those copies ride the host links beside whatever
+      the devices compute; the stack is a program, queued in the
+      devices' compute stream behind every training block already
+      dispatched, and a thread that waited for it would stage one
+      block for every block the devices run — no run-ahead (PERF.md,
+      PR 42).
+    * "stack" where they are one platform (the CPU backend, which may
+      ALIAS a host buffer: the pieces can BE the source's buffer until
+      a program has copied them out)."""
+    return "stack" if device_platform == host_platform else "pieces"
+
+
 def _resolve_group2ctx(symbol, group2ctx, mesh):
     """Map ctx_group annotations to mesh shardings.
 
@@ -1198,13 +1217,16 @@ class Executor:
         A short last block is a smaller K.
 
         Device arrays are immutable and nothing is waited for.  A step
-        that came from the HOST is: its source may refill the buffer at
-        its next next(), and until the stack has run the pieces may
-        still be in flight from it (or, on the CPU backend, BE it)."""
+        that came from the HOST is, for as long as its source's buffer
+        is needed and no longer (the source may refill it at its next
+        next()): what that takes is _host_step_fence's to say."""
         stacks = [_stack_steps(*on_dev)
                   for on_dev in zip(*(pieces for _, pieces in steps))]
-        if any(host for host, _ in steps):
-            jax.block_until_ready(stacks)
+        from_host = [pieces for host, pieces in steps if host]
+        if from_host:
+            jax.block_until_ready(
+                stacks if _host_step_fence(self._platform) == "stack"
+                else from_host)
         sh = self.block_input_sharding()
         if sh is None:
             return stacks[0]

@@ -542,9 +542,15 @@ def _to_host(a):
 class DeviceStagedIter(DataIter):
     """Async device staging: groups K batches from `data_iter` into one
     StagedBlock, assembled ON THE DEVICE(S) from a BACKGROUND engine op,
-    so the host decode + H2D of block N+1 overlap block N's device
+    so the host decode + H2D of later blocks overlap block N's device
     compute — the tf.data prefetch-to-device recipe layered on the
     reference's double-buffered PrefetcherIter (src/io/iter_prefetcher.h).
+    Module.fit takes block N+1 and dispatches it while block N runs, so
+    the staging op works two and three blocks ahead of the devices; it
+    is held back by its buffers alone (below) and by the links, never by
+    the devices' compute queue: the one device program it enqueues, the
+    stack, it does not wait for beside a chip
+    (Executor.stack_block_input).
 
     The fetch rides engine.ThreadedIter (one engine op per block on the
     shared worker pool, its iterator var declared as the op's write set,
@@ -682,8 +688,9 @@ class DeviceStagedIter(DataIter):
 
         blocks = []
         for name, steps in zip(names, zip(*rows)):
-            # the enqueue of the device stack (host arrays: the stack
-            # itself, see Executor.stack_block_input)
+            # the enqueue of the device stack (host arrays: and the
+            # wait that frees the source's buffers, see
+            # Executor.stack_block_input)
             with profiler.span("io.stage.stack", cat="io",
                                hist="io.stage.stack_seconds", block=seq):
                 blocks.append(self._stack_fn(name, steps))

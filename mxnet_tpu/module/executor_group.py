@@ -220,28 +220,37 @@ class DataParallelExecutorGroup:
     def update_metric(self, eval_metric, labels):
         """Feed outputs to the metric.  After a K-step block dispatch the
         outputs are stacked (K, ...) and `labels` is the block's per-step
-        label list: the stacked arrays are read back ONCE (one D2H
-        transfer per dispatch instead of one per step) and the metric
-        consumes the block step by step on the host."""
+        label list (update_block_metric)."""
         from .. import telemetry
 
         exe = self.execs[0]
-        k = getattr(exe, "_last_block_count", 0)
-        if k:
-            # asnumpy (not np.asarray) so batch-sharded GLOBAL outputs of
-            # a multi-process mesh allgather their remote shards
-            preds = [o.asnumpy() for o in exe.outputs]
-            if telemetry.enabled():
-                telemetry.inc("executor.d2h_bytes",
-                              sum(int(p.nbytes) for p in preds))
-            for s in range(k):
-                eval_metric.update(list(labels[s]), [p[s] for p in preds])
+        if getattr(exe, "_last_block_count", 0):
+            self.update_block_metric(eval_metric, labels, exe.outputs)
             return
         preds = exe.outputs
         if telemetry.enabled():
             telemetry.inc("executor.d2h_bytes",
                           sum(int(p.data.nbytes) for p in preds))
         eval_metric.update(labels, preds)
+
+    def update_block_metric(self, eval_metric, labels, outputs):
+        """Feed one K-step block to the metric: `outputs` are that
+        dispatch's stacked (K, ...) outputs — the executor's, or the
+        ones the fit loop kept of the block before the one in flight —
+        and `labels` its per-step label list.  The stacked arrays are
+        read back ONCE (one D2H transfer per dispatch instead of one per
+        step) and the metric consumes the block step by step on the
+        host."""
+        from .. import telemetry
+
+        # asnumpy (not np.asarray) so batch-sharded GLOBAL outputs of
+        # a multi-process mesh allgather their remote shards
+        preds = [o.asnumpy() for o in outputs]
+        if telemetry.enabled():
+            telemetry.inc("executor.d2h_bytes",
+                          sum(int(p.nbytes) for p in preds))
+        for s, step_labels in enumerate(labels):
+            eval_metric.update(list(step_labels), [p[s] for p in preds])
 
     @property
     def grad_arrays(self):

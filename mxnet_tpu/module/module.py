@@ -440,56 +440,117 @@ class Module(BaseModule):
         continues the batch numbering after an exact resume — the data
         fast-forward already happened in _run_epoch, and checkpoints
         only cut at dispatch boundaries, so skip is a multiple of K and
-        the block boundaries line up with the interrupted run's."""
+        the block boundaries line up with the interrupted run's.
+
+        The loop keeps ONE block queued on the devices behind the one
+        that runs: a pass dispatches block n+1 and only then reads block
+        n's outputs, feeds the metric and fires block n's callback, so
+        the devices hold their next program whenever one ends.  That
+        read is the fence: the loop never queues block n+2 before block
+        n has finished.  The epoch's last pass dispatches nothing and
+        reads the block still in flight, so every block has been read
+        and called back when this returns.  ``batch_end_callback`` sees
+        what it always saw — once a dispatch, in order, ``nbatch`` the
+        last step of the block whose outputs were just read and
+        ``eval_metric`` holding the blocks up to it — except that the
+        module's parameters and outputs are by then ONE BLOCK NEWER
+        than that metric.  The checkpoint manager is told of a block as
+        soon as it is dispatched, with that block's cursor: a snapshot
+        reads the arrays it names."""
         from .. import profiler, telemetry
         from ..io import DeviceStagedIter
+        from ..obs import recorder
         from .base_module import _fire
 
-        exe = self._exec_group.execs[0]
+        group = self._exec_group
+        exe = group.execs[0]
         staged = DeviceStagedIter(train_data, steps_per_dispatch=k,
                                   place_fn=exe.place_step_input,
                                   stack_fn=exe.stack_block_input)
         nbatch = skip
         tel = telemetry.enabled()
         mgr = getattr(self, "_ckpt_mgr", None)
+        sent = None     # (block, its stacked outputs, nbatch after it): unread
+        unbooked = 0.0  # fit.block seconds _observe_steps has not seen
+
+        def take():
+            """The next staged block, None at the epoch's end.  While a
+            dispatched block is unread the wait is a `stage_wait` span
+            of the flight recorder, numbered like that dispatch: staging
+            may queue device work behind the block in flight (the CPU
+            backend's split and stack, a device-resident source's), so
+            if that block hangs in a collective the loop waits HERE and
+            not in the read, and the stall watchdog must see it."""
+            if sent is None:
+                return next(staged, None)
+            rec = recorder.enabled()
+            if rec:
+                recorder.record("stage_wait", "enter", exe._train_dispatches)
+            try:
+                return next(staged, None)
+            finally:
+                if rec:
+                    recorder.record("stage_wait", "exit",
+                                    exe._train_dispatches)
+
+        def read(sent):
+            block, outputs, _ = sent
+            if block.label_host is not None:
+                # the first read of that dispatch's outputs: the loop
+                # thread waiting for the device
+                with profiler.span("fit.device_wait", cat="module",
+                                   hist="module.device_wait_seconds"):
+                    group.update_block_metric(eval_metric, block.label_host,
+                                              outputs)
+
+        def finish(sent, seconds):
+            block, _, done = sent
+            if tel:
+                # one observation per DISPATCH (covering K steps): the
+                # histogram count is the dispatch count and the MFU
+                # gauge normalizes by block.count steps
+                self._observe_steps(seconds, block.count)
+            if batch_end_callback is not None:
+                # one callback per dispatch (nbatch = last step index):
+                # per-step callbacks would force per-step host sync,
+                # defeating the amortization
+                with profiler.span("fit.callback", cat="module"):
+                    _fire(batch_end_callback,
+                          BatchEndParam(epoch=epoch, nbatch=done - 1,
+                                        eval_metric=eval_metric,
+                                        locals=locals()))
+
         try:
-            for block in staged:
+            for block in iter(take, None):
                 with profiler.span("fit.block", cat="module", k=block.count,
                                    block=block.seq) as disp:
                     self.forward_backward(block)
                     self.update()
-                    if block.label_host is not None:
-                        # the first read of the dispatch's outputs: the
-                        # loop thread waiting for the device
-                        with profiler.span(
-                                "fit.device_wait", cat="module",
-                                hist="module.device_wait_seconds"):
-                            self.update_metric(eval_metric, block.label_host)
-                if tel:
-                    # one observation per DISPATCH (covering K steps):
-                    # the histogram count is the dispatch count and the
-                    # MFU gauge normalizes by block.count steps
-                    self._observe_steps(disp.seconds, block.count)
-                nbatch += block.count
-                if mgr is None and batch_end_callback is None:
-                    continue
-                # trace only: names the gap in which the checkpoint note
-                # and the user's callback run
-                with profiler.span("fit.callback", cat="module"):
+                    nbatch += block.count
                     if mgr is not None:
                         # dispatch boundary: snapshot D2H sees the
                         # post-block arrays; the shard write overlaps the
                         # next dispatch
                         mgr.note_dispatch(self, epoch, nbatch,
                                           steps=block.count)
-                    if batch_end_callback is not None:
-                        # one callback per dispatch (nbatch = last step
-                        # index): per-step callbacks would force per-step
-                        # host sync, defeating the amortization
-                        _fire(batch_end_callback,
-                              BatchEndParam(epoch=epoch, nbatch=nbatch - 1,
-                                            eval_metric=eval_metric,
-                                            locals=locals()))
+                    ahead = (block, exe.outputs, nbatch)
+                    if sent is not None:
+                        if tel:
+                            telemetry.inc("module.runahead_blocks")
+                        read(sent)
+                unbooked += disp.seconds
+                if sent is not None:
+                    finish(sent, unbooked)
+                    unbooked = 0.0
+                sent = ahead
+            if sent is not None:
+                # the epoch's last pass: nothing to dispatch, one block
+                # still unread
+                with profiler.span("fit.block", cat="module",
+                                   k=sent[0].count,
+                                   block=sent[0].seq) as disp:
+                    read(sent)
+                finish(sent, unbooked + disp.seconds)
         finally:
             staged.close()  # the epoch owns train_data; fit resets it
         return nbatch

@@ -140,6 +140,8 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
     assert report["four_chips"]["predictor_device"] == str(jax.devices()[3])
     assert clock.seconds > 0  # the AOT wrapper's compiles are seen
     assert report["train"]["mfu_gauge"] is None  # CPU: no peak, no MFU
+    # the K-step fit's second block was dispatched behind the unread first
+    assert report["train"]["runahead_blocks"] == TINY["train"]["blocks"] - 1
     assert telemetry.counter_value("mem.program_fallbacks") == fallbacks
 
 

@@ -362,3 +362,61 @@ def test_forward_places_a_host_batch_on_its_own_devices(
     rows = (BATCH // len(devices),)  # the parameters are put anew, too
     assert sorted([p for p in puts if p[0][:1] == rows], key=str) == \
         _pieces(devices)
+
+
+# ----------------------------------------------------------------------
+# what the staging thread waits for (PR 42)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("device,host,fence", [
+    ("tpu", "cpu", "pieces"), ("gpu", "cpu", "pieces"),
+    ("cpu", "cpu", "stack")])
+def test_the_fence_of_a_host_step_follows_from_the_two_platforms(
+        device, host, fence):
+    """Host memory beside a chip: the buffer is needed until the pieces
+    have arrived, and the stack — a program in the chips' compute queue —
+    is not waited for.  One platform (the CPU backend may alias the
+    buffer): until the stack has run."""
+    from mxnet_tpu.executor import _host_step_fence
+
+    assert _host_step_fence(device, host) == fence
+    assert _host_step_fence(device) == fence  # host memory is the CPU's
+
+
+@pytest.mark.parametrize("mesh", [False, True], ids=["one_device", "mesh4"])
+@pytest.mark.parametrize("platform,kind,waited", [
+    ("cpu", "numpy", "stacks"), ("tpu", "numpy", "pieces"),
+    ("cpu", "ndarray", None)],
+    ids=["one_platform", "beside_a_chip", "device_source"])
+def test_stack_block_input_waits_for_what_the_fence_names(
+        monkeypatch, mesh, platform, kind, waited):
+    """`stack_block_input` hands `jax.block_until_ready` the stacks where
+    host and device are one platform, every host step's pieces where the
+    executor computes on another one, and nothing for steps that were on
+    the device already."""
+    import jax
+
+    exe = _executor(mesh)
+    X, _ = _arrays(K)
+    steps = [exe.place_step_input(
+        "data", X[s * BATCH:(s + 1) * BATCH] if kind == "numpy"
+        else mx.nd.array(X[s * BATCH:(s + 1) * BATCH])) for s in range(K)]
+    assert [host for host, _ in steps] == [kind == "numpy"] * K
+    seen = []
+    wait = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: seen.append(x) or wait(x))
+    monkeypatch.setattr(exe, "_platform", platform)
+    block = exe.stack_block_input("data", steps)
+    assert np.asarray(block).tobytes() == X.tobytes()
+    if waited is None:
+        assert seen == []
+        return
+    (arrays,) = seen
+    devices = 4 if mesh else 1
+    if waited == "pieces":
+        assert [len(p) for p in arrays] == [devices] * K
+        assert all(a is b for got, (_, want) in zip(arrays, steps)
+                   for a, b in zip(got, want))
+    else:
+        assert len(arrays) == devices
+        assert all(a.shape[0] == K for a in arrays)

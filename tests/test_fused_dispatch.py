@@ -294,3 +294,175 @@ def test_schedule_prefix_matches_eager_updates():
             rows[s, r, 2] = b._index_update_count[key]
     np.testing.assert_array_equal(pref, rows)
     assert a.num_update == b.num_update == 3
+
+
+# ----------------------------------------------------------------------
+# the K-step loop runs one block ahead of what it reads (PR 42)
+# ----------------------------------------------------------------------
+def _recorded_fit(k, n=256, batch=32, callback=None, metric=None, epochs=1):
+    """One fit at block size k with a recording `update()` and a
+    recording callback: (events, module).  An event is ("dispatch", i) —
+    the i-th update() of the run — or ("callback", epoch, nbatch,
+    sum_metric, num_inst)."""
+    from mxnet_tpu import telemetry
+
+    telemetry.reset()
+    X, y = _toy_data(n=n)
+    mx.random.seed(11)
+    it = mx.io.NDArrayIter(X, y, batch_size=batch)
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    events = []
+    metric = metric or mx.metric.CrossEntropy()
+    update = mod.update
+
+    def recording_update():
+        update()
+        events.append(("dispatch",
+                       sum(e[0] == "dispatch" for e in events)))
+
+    def on_batch(param):
+        events.append(("callback", param.epoch, param.nbatch,
+                       param.eval_metric.sum_metric,
+                       param.eval_metric.num_inst))
+        if callback is not None:
+            callback(param)
+
+    mod.update = recording_update
+    mod.fit(it, num_epoch=epochs, initializer=mx.init.Xavier(),
+            optimizer="sgd",
+            optimizer_params={"learning_rate": 0.1, "momentum": 0.9},
+            steps_per_dispatch=k, eval_metric=metric,
+            batch_end_callback=on_batch)
+    return events, mod
+
+
+def test_callback_n_fires_after_dispatch_n_plus_1_and_before_n_plus_2():
+    """Block n+1 is dispatched before block n's outputs are read, and
+    block n+2 only after block n's callback: depth exactly one."""
+    events, _ = _recorded_fit(2)  # 8 steps, 4 blocks
+    kinds = [(e[0], e[2] if e[0] == "callback" else e[1]) for e in events]
+    assert kinds == [("dispatch", 0), ("dispatch", 1), ("callback", 1),
+                     ("dispatch", 2), ("callback", 3),
+                     ("dispatch", 3), ("callback", 5),
+                     ("callback", 7)]
+
+
+@pytest.mark.parametrize("n,k", [(256, 4), (192, 4), (32, 4), (256, 8)],
+                         ids=["whole_blocks", "short_last_block",
+                              "one_short_block", "one_whole_block"])
+def test_callbacks_see_the_metric_of_the_single_step_order(n, k):
+    """`nbatch`, `sum_metric` and `num_inst` at every callback of a K-step
+    fit are those a fit of single-step dispatches shows after the same
+    step: once a dispatch, in order, the last block and a short last
+    block included — drained before the epoch ends."""
+    steps, _ = _recorded_fit(1, n=n)
+    blocks, mod = _recorded_fit(k, n=n)
+    steps = {e[2]: e for e in steps if e[0] == "callback"}
+    got = [e for e in blocks if e[0] == "callback"]
+    total = n // 32
+    assert [e[2] for e in got] == [min(s + k, total) - 1
+                                   for s in range(0, total, k)]
+    assert blocks[-1] is got[-1]
+    for e in got:
+        want = steps[e[2]]
+        assert e[4] == want[4] == (e[2] + 1) * 32
+        assert e[3] == pytest.approx(want[3], rel=1e-5)
+    assert mod._exec_group.execs[0]._train_dispatches == len(got)
+
+
+def test_every_epoch_is_drained_before_it_ends():
+    """Each epoch's last block is read and called back before the next
+    epoch's first dispatch: the metric fit logs at the epoch's end, and
+    resets, holds every block."""
+    events, _ = _recorded_fit(4, epochs=2)  # 2 blocks an epoch
+    kinds = [(e[0], e[1:3] if e[0] == "callback" else e[1]) for e in events]
+    assert kinds == [("dispatch", 0), ("dispatch", 1), ("callback", (0, 3)),
+                     ("callback", (0, 7)),
+                     ("dispatch", 2), ("dispatch", 3), ("callback", (1, 3)),
+                     ("callback", (1, 7))]
+    # the metric was reset between the epochs, after the drain
+    assert [e[4] for e in events if e[0] == "callback"] == [128, 256] * 2
+
+
+def test_runahead_blocks_count_the_dispatches_behind_an_unread_block():
+    """`module.runahead_blocks`: every dispatch of an epoch but its
+    first follows a block whose outputs are still unread."""
+    from mxnet_tpu import telemetry
+
+    _recorded_fit(2, epochs=3)  # 4 dispatches an epoch
+    assert telemetry.counter_value("executor.train_dispatches") == 12
+    assert telemetry.counter_value("module.runahead_blocks") == 12 - 3
+
+
+def test_a_raising_callback_still_closes_the_staging_iterator(monkeypatch):
+    """An exception out of block n's callback, with block n+1 in flight,
+    leaves no staging op running on the source iterator."""
+    from mxnet_tpu import io as mxio
+
+    made = []
+    cls = mxio.DeviceStagedIter
+
+    class Recorded(cls):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            made.append(self)
+
+    monkeypatch.setattr(mxio, "DeviceStagedIter", Recorded)
+
+    def boom(param):
+        raise KeyError("callback %d" % param.nbatch)
+
+    with pytest.raises(KeyError, match="callback 1"):
+        _recorded_fit(2, callback=boom)
+    (staged,) = made
+    assert staged._bg is None
+    with pytest.raises(mx.MXNetError, match="closed"):
+        staged.next()
+
+
+def test_a_fit_without_labels_is_never_fenced_and_calls_back_in_order():
+    """No label, no metric read: the loop has no fence, and its order —
+    dispatch n+1, then block n's callback — is the same."""
+    X, _ = _toy_data(n=128)
+    data = mx.sym.Variable("data")
+    net = mx.sym.MakeLoss(mx.sym.sum(mx.sym.square(
+        mx.sym.FullyConnected(data, num_hidden=4, name="fc1"))))
+    mod = mx.mod.Module(net, label_names=None, context=mx.cpu())
+    events = []
+    update = mod.update
+
+    def recording_update():
+        update()
+        events.append("dispatch")
+
+    mod.update = recording_update
+    mod.fit(mx.io.NDArrayIter(X, None, batch_size=32), num_epoch=1,
+            initializer=mx.init.Xavier(), optimizer="sgd",
+            optimizer_params={"learning_rate": 0.01}, steps_per_dispatch=2,
+            batch_end_callback=lambda p: events.append(p.nbatch))
+    assert events == ["dispatch", "dispatch", 1, 3]
+
+
+def test_the_wait_for_a_block_behind_an_unread_one_is_in_the_flight_recorder():
+    """Staging may queue device work behind the block in flight, so a
+    hang there can hold the loop in `next()` before it reaches the read:
+    every wait for a staged block with a dispatch unread is a
+    `stage_wait` span numbered like that dispatch — an epoch's first
+    wait has nothing in flight and is none — and all are closed."""
+    from mxnet_tpu.obs import recorder
+
+    prev = recorder.set_enabled(True)
+    recorder.reset()
+    try:
+        _, mod = _recorded_fit(2, epochs=2)  # 4 dispatches an epoch
+        waits = [(e["phase"], e["seq"]) for e in recorder.events()
+                 if e["kind"] == "stage_wait"]
+        prog = recorder.progress()["stage_wait"]
+    finally:
+        recorder.reset()
+        recorder.set_enabled(prev)
+    # behind dispatches 1-3 a block follows, behind the 4th the epoch's end
+    assert waits == [(ph, seq) for seq in range(1, 9)
+                     for ph in ("enter", "exit")]
+    assert prog["entered"] == prog["exited"] == 8
+    assert recorder.open_spans() == []

@@ -312,6 +312,33 @@ def test_block_links_the_staging_spans_to_the_dispatch_across_threads(
                       else {1, 2})
 
 
+def test_the_passes_of_the_fit_loop_run_one_block_ahead(fit_run):
+    """`fit.block` is one pass of the K-step loop: the dispatch of block
+    n+1, then the read of block n.  The fixture's epoch of two blocks has
+    three passes — the first reads nothing, the last dispatches nothing
+    and carries the number of the block it reads — and two observations
+    of `module.step_seconds`, one a block read."""
+    hists, events = fit_run
+    passes = sorted((e for e in events if e["name"] == "fit.block"),
+                    key=lambda e: e["ts"])
+    assert [e["args"]["block"] for e in passes] == [1, 2, 2]
+    kids = [sorted((e for e in events
+                    if e.get("args", {}).get("parent") == p["args"]["id"]),
+                   key=lambda e: e["ts"]) for p in passes]
+    assert [[e["name"] for e in k] for k in kids] == [
+        ["fit.dispatch"], ["fit.dispatch", "fit.device_wait"],
+        ["fit.device_wait"]]
+    assert [k[0]["args"]["block"] for k in kids[:2]] == [1, 2]
+    assert hists["module.step_seconds"]["count"] == 2
+    assert hists["module.device_wait_seconds"]["count"] == 2
+    # a block's callback follows the pass that read it
+    calls = sorted((e for e in events if e["name"] == "fit.callback"),
+                   key=lambda e: e["ts"])
+    assert len(calls) == 2
+    for call, read in zip(calls, passes[1:]):
+        assert call["ts"] >= read["ts"] + read["dur"] - 1
+
+
 def test_span_names_are_static_and_what_varies_is_in_args(fit_run, served):
     for _, events in (fit_run, served):
         spans = [e for e in events if "id" in e.get("args", {})]
