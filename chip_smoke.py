@@ -48,8 +48,6 @@ apart from the rest:
             every tenant's prefill bucket programs timed warm: none may
             run longer than 1.5 times the next larger bucket's (the
             first shape has OPT-1.3B's FFN of 8,192 and its four buckets)
-  kernel    ops/pallas_kernels.bn_stats under Mosaic at two ResNet-50
-            shapes against the jnp reduction
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
             Predictor bound to chip 3, in this same process
 
@@ -122,7 +120,6 @@ FULL = {
                                 linear_neg_eigval=False, norm="rms",
                                 positions="rotary", rotary_dim=64,
                                 qk_norm="head", out_gate=True, bias=False)]},
-    "kernel": {"shapes": [(512, 56, 56, 64), (512, 7, 7, 2048)], "seed": 3},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
 }
@@ -134,7 +131,6 @@ FULL = {
 # on random weights.
 SERVE_RTOL = 2e-2      # served logits vs Predictor.forward at batch 1
 GENERATE_RTOL = 5e-2   # KV prefill/decode logits vs full recompute
-KERNEL_RTOL = 1e-2     # bn_stats (bf16 in, f32 accumulate) vs jnp
 
 
 def _rel_err(got, ref):
@@ -782,34 +778,6 @@ def phase_kv_ring(sizes, ctx):
     return total
 
 
-def phase_kernel(sizes, ctx):
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops import pallas_kernels as pk
-
-    dev = ctx.jax_device()
-    if dev.platform == "tpu":
-        _check(not pk._INTERPRET, "bn_stats would run interpreted")
-    worst = 0.0
-    for i, shape in enumerate(sizes["shapes"]):
-        _check(pk.bn_stats_supported(shape, -1),
-               "bn_stats does not support %s" % (shape,))
-        x = jax.device_put(jax.random.normal(
-            jax.random.key(sizes["seed"] + i), shape, jnp.bfloat16) + 0.5,
-            dev)
-        mean, mean_sq = jax.jit(lambda v: pk.bn_stats(v, -1))(x)
-        axes = tuple(range(len(shape) - 1))
-        xf = x.astype(jnp.float32)
-        worst = max(worst,
-                    _rel_err(mean, jnp.mean(xf, axis=axes)),
-                    _rel_err(mean_sq, jnp.mean(xf * xf, axis=axes)))
-    _check(worst <= KERNEL_RTOL, "bn_stats differs from jnp by %.3g "
-           "(tolerance %.3g)" % (worst, KERNEL_RTOL))
-    return {"shapes": len(sizes["shapes"]),
-            "max_rel_err": float("%.3g" % worst)}
-
-
 def _staged_block_facts(mx, exe, it, devices, sizes):
     """One K-step block staged from the NDArrayIter `it`, as fit's
     steps_per_dispatch > 1 stages it.  Beside an accelerator the batches
@@ -971,7 +939,6 @@ def main():
     run_phase("generate", phase_generate, FULL["generate"], ctx, clock,
               report)
     run_phase("kv_ring", phase_kv_ring, FULL["kv_ring"], ctx, clock, report)
-    run_phase("kernel", phase_kernel, FULL["kernel"], ctx, clock, report)
     if jax.device_count() >= 4:
         run_phase("four_chips", phase_four_chips, FULL["four_chips"],
                   [mx.tpu(i) for i in range(4)], clock, report)

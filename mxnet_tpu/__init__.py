@@ -58,7 +58,6 @@ from . import monitor as mon
 from .monitor import Monitor
 from . import profiler
 from . import telemetry
-from . import tune
 from . import module
 from . import module as mod
 from .module import Module

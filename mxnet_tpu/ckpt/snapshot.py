@@ -108,22 +108,22 @@ class CheckpointManager:
     drive the SAME manager schedule — triggers align by determinism of
     the dispatch sequence, and the commit barrier assumes it.
 
-    Knobs (config.py): ``MXTPU_CKPT_DIR`` / ``_EVERY_STEPS`` / ``_KEEP``
-    / ``_ASYNC``; constructor args override.
+    `directory` / `keep` default to ``MXTPU_CKPT_DIR`` /
+    ``MXTPU_CKPT_KEEP`` (config.py).  `every_steps` is the cadence in
+    training steps (0 = off); `async_write=False` writes shards
+    synchronously, for debugging.
     """
 
-    def __init__(self, directory=None, every_steps=None, keep=None,
-                 async_write=None, data_seed=0, knobs=None):
+    def __init__(self, directory=None, every_steps=0, keep=None,
+                 async_write=True, data_seed=0, knobs=None):
         from .. import config
 
         self.directory = (directory if directory is not None
                           else config.get("MXTPU_CKPT_DIR"))
-        self.every_steps = int(every_steps if every_steps is not None
-                               else config.get("MXTPU_CKPT_EVERY_STEPS"))
+        self.every_steps = int(every_steps)
         self.keep = int(keep if keep is not None
                         else config.get("MXTPU_CKPT_KEEP"))
-        self.async_write = bool(async_write if async_write is not None
-                                else config.get("MXTPU_CKPT_ASYNC"))
+        self.async_write = bool(async_write)
         self.enabled = bool(self.directory) and self.every_steps > 0
         self.data_seed = int(data_seed)
         self.knobs = dict(knobs or {})

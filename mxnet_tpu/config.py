@@ -11,27 +11,20 @@ variable here for documentation even when they read it directly).
 Many reference knobs (engine thread pools, GPU memory pool, bulk-exec
 segment sizes) have no analog because XLA/PJRT owns those resources —
 they are listed as `absorbed` so users migrating scripts get an answer
-instead of silence.
+instead of silence.  A variable this registry once held and nothing
+reads any more is listed as `retired`, with what holds its value now:
+the registry says only what someone sets.
 """
 from __future__ import annotations
 
 import os
 from collections import namedtuple
 
-__all__ = ["EnvVar", "REGISTRY", "ABSORBED", "get", "spec", "describe"]
+__all__ = ["EnvVar", "REGISTRY", "ABSORBED", "RETIRED", "get", "spec",
+           "describe", "warn_retired"]
 
 EnvVar = namedtuple("EnvVar", ["name", "type", "default", "desc"])
 
-
-def _float_or_auto(raw):
-    """Float parser that passes the literal 'auto' through (bucket MB)."""
-    s = str(raw).strip().lower()
-    if s == "auto":
-        return "auto"
-    return float(raw)
-
-
-_float_or_auto.__name__ = "float|auto"
 
 REGISTRY = [
     # ---- distributed kvstore (parallel/dist.py) ----
@@ -45,13 +38,6 @@ REGISTRY = [
            "(reference ps-lite CheckDeadNodes)"),
     EnvVar("MXNET_KVSTORE_BARRIER_TIMEOUT", float, 300.0,
            "Barrier wait limit; the barrier raises instead of hanging"),
-    EnvVar("MXNET_KVSTORE_PULL_TIMEOUT", float, 60.0,
-           "Version-gated pull wait limit; servers reply with an error "
-           "instead of serving stale values"),
-    EnvVar("MXNET_KVSTORE_REGISTER_TIMEOUT", float, 600.0,
-           "Scheduler wait limit for all roles to register at startup; "
-           "a role that dies before registering fails the job instead "
-           "of hanging it (parallel/dist.py Scheduler)"),
     # ---- topology (set by tools/launch.py, reference dmlc tracker) ----
     EnvVar("DMLC_ROLE", str, "worker", "Node role: worker/server/scheduler"),
     EnvVar("DMLC_PS_ROOT_URI", str, "127.0.0.1", "Scheduler host"),
@@ -90,30 +76,6 @@ REGISTRY = [
            "multihost.initialize forces "
            "--xla_force_host_platform_device_count to it).  0 = leave "
            "the platform's own device discovery alone"),
-    # ---- gradient collectives (executor.py + parallel/collectives.py;
-    #      docs/distributed.md) ----
-    EnvVar("MXTPU_COMM_BUCKETED", str, "auto",
-           "Explicit bucketed hierarchical gradient all-reduce in the "
-           "K-step fused dispatch (executor._comm_mode): grads pack "
-           "into MXTPU_COMM_BUCKET_MB buckets, each hierarchical-"
-           "psum'd ICI-first then DCN inside the scan body, so every "
-           "bucket's reduction overlaps the remaining backward compute "
-           "structurally.  'auto' (default) arms it on multi-process "
-           "meshes only; 1 forces it on any >1-device data mesh "
-           "(single-host SPMD included); 0 keeps the implicit XLA "
-           "partitioner collectives everywhere"),
-    EnvVar("MXTPU_COMM_BUCKET_MB", _float_or_auto, 4.0,
-           "Target gradient bucket size in MB for the explicit "
-           "collective path (collectives.plan_buckets): small grads "
-           "coalesce into transfers big enough to reach wire "
-           "bandwidth, large grads get their own bucket.  Smaller = "
-           "earlier first all-reduce (more overlap), larger = fewer "
-           "per-collective fixed costs.  'auto' re-derives the target "
-           "at fit start from a measured Executor.measure_comm() "
-           "two-point probe (per-collective fixed cost vs wire rate), "
-           "books the decision in tune.* telemetry and the flight "
-           "recorder, and recompiles the block once (docs/perf.md "
-           "'Autotuning')"),
     # ---- dependency engine (engine/) ----
     EnvVar("MXNET_ENGINE_TYPE", str, "ThreadedEnginePerDevice",
            "Execution engine backend (engine/): ThreadedEnginePerDevice "
@@ -144,26 +106,7 @@ REGISTRY = [
            "host: not measured) is paid once per K steps.  1 = one "
            "dispatch per "
            "step (the pre-block behavior); see docs/perf.md"),
-    EnvVar("MXTPU_STAGE_BUFFERS", int, 2,
-           "io.DeviceStagedIter lookahead: how many K-step input blocks "
-           "are fetched and assembled on the device ahead of compute "
-           "by a background engine op (2 = classic double buffering, "
-           "reference src/io/iter_prefetcher.h); raise only if H2D "
-           "stalls show between fit.dispatch spans in the profile"),
     # ---- multi-process data service (data/; docs/data.md) ----
-    EnvVar("MXTPU_DATA_WORKERS", int, 2,
-           "Worker PROCESSES per data service (data.DataService / "
-           "io.ShardedImageRecordIter num_workers default): each owns "
-           "batches b = w mod N of the (seed, epoch) epoch order and "
-           "decodes into its own shared-memory ring, with a "
-           "src/imdecode.cc thread pool per worker.  Scale toward the "
-           "host's physical cores; the batch SEQUENCE is identical for "
-           "any value (docs/data.md)"),
-    EnvVar("MXTPU_DATA_RING_SLOTS", int, 4,
-           "Shared-memory slots per data-service worker — the "
-           "backpressure bound: a worker this many decoded batches "
-           "ahead of the trainer blocks on the free-slot queue instead "
-           "of allocating without bound (data/shm.py)"),
     EnvVar("MXTPU_DATA_SLOT_BYTES", int, 0,
            "Bytes per data-service shared-memory slot; 0 = auto (one "
            "batch exactly: batch_size x data_shape float32 + labels). "
@@ -196,22 +139,6 @@ REGISTRY = [
            "held by deferred operands and compile time of the fused "
            "program (lazy.py)"),
     # ---- inference serving (serving/; docs/serving.md) ----
-    EnvVar("MXTPU_SERVE_MAX_BATCH", int, 32,
-           "serving.ModelServer: largest batch bucket the continuous "
-           "batcher packs requests into (the top of the bucket ladder). "
-           "One forward program is compiled per (tenant, bucket) and "
-           "reused across every later fill"),
-    EnvVar("MXTPU_SERVE_BUCKETS", str, "",
-           "Comma-separated batch-bucket ladder for the continuous "
-           "batcher (e.g. '1,2,4,8,16'); empty = powers of two up to "
-           "MXTPU_SERVE_MAX_BATCH. A fill is padded up to the smallest "
-           "bucket that holds it, so compiled-program count stays "
-           "O(len(ladder)) instead of one per observed batch size"),
-    EnvVar("MXTPU_SERVE_TIMEOUT_MS", float, 5000.0,
-           "Default per-request deadline: a request still queued this "
-           "many ms after submit() fails with a timeout error instead "
-           "of being dispatched (ModelServer.submit(timeout_ms=) "
-           "overrides per call). Counted in serving.timeouts"),
     EnvVar("MXTPU_SERVE_MAX_QUEUE", int, 1024,
            "Admission control: submit() raises instead of enqueueing "
            "when this many requests are already pending across all "
@@ -221,37 +148,8 @@ REGISTRY = [
            "Continuous-batcher batching window: a tenant's queue head "
            "may wait this many ms for more requests to arrive before "
            "the batcher dispatches a partial fill (a full "
-           "MXTPU_SERVE_MAX_BATCH dispatches immediately). Larger = "
+           "max_batch dispatches immediately). Larger = "
            "better fill ratio, worse p99 under light load"),
-    EnvVar("MXTPU_SERVE_MAX_SESSIONS", int, 8,
-           "Generative serving (serving/decode.py): KV-cache slots per "
-           "generative tenant — the hard cap on concurrently decoding "
-           "sessions (admission control: a prompt past the cap waits "
-           "queued until a session retires and frees its slot). The "
-           "session's device state is preallocated as the model's "
-           "cache_spec(slots+1, MXTPU_SERVE_KV_MAX_LEN) states it — KV "
-           "rings for attention layers, a conv window and a recurrent "
-           "state for state-space layers; +1 is the scratch slot padded "
-           "decode rows write into"),
-    EnvVar("MXTPU_SERVE_MAX_DECODE_TOKENS", int, 64,
-           "Default per-session generation budget: a decode session "
-           "retires (future resolves, slot freed) after this many new "
-           "tokens unless EOS lands first "
-           "(submit_generate(max_new_tokens=) overrides per request)"),
-    EnvVar("MXTPU_SERVE_DECODE_WINDOW_MS", float, 2.0,
-           "Token-level continuous-batching window: with decode "
-           "sessions active the batcher runs one packed decode step at "
-           "least this often, admitting newly-arrived prompts (prefill)"
-           " between steps — the Orca iteration-level re-pack cadence. "
-           "Smaller = lower per-token latency, larger = better prefill "
-           "batching under mixed load"),
-    EnvVar("MXTPU_SERVE_KV_MAX_LEN", int, 256,
-           "KV-ring size per slot: max total tokens (prompt + "
-           "generated) a decode session may hold. Bounds every KV ring "
-           "of the model's cache_spec ((slots+1) x kv_heads x THIS x "
-           "d_head floats a ring; a state-space layer's recurrent state "
-           "does not grow with it) and is clamped to the model's "
-           "max_len"),
     # ---- multi-replica serving tier (router/; docs/serving.md
     #      "Multi-replica tier") ----
     EnvVar("MXTPU_ROUTER_PORT", int, 0,
@@ -267,23 +165,6 @@ REGISTRY = [
            "This replica's index in the serving fleet (exported per "
            "process by launch.py --serve-replicas; names the replica "
            "in Router.health() and the chaos-test dead list)"),
-    EnvVar("MXTPU_ROUTER_POLL_MS", float, 200.0,
-           "Router health-poll cadence: every interval each replica "
-           "answers its ModelServer.health() probe + serving telemetry "
-           "extract. A replica silent for 5 intervals (>=2 s floor) is "
-           "declared dead and its in-flight requests replay to peers"),
-    EnvVar("MXTPU_ROUTER_REDISPATCH", int, 2,
-           "Drain-on-death budget: how many times one request may be "
-           "replayed to a new replica (submit-time snapshot) after "
-           "replica deaths/admission bounces before its future fails "
-           "with ReplicaDead. Counted in router.redispatches"),
-    EnvVar("MXTPU_ROUTER_ADAPT_WINDOW_S", float, 10.0,
-           "Traffic-adaptive bucket-ladder window: per replica, the "
-           "router derives the mean fill from the serving.batch_slots "
-           "counter deltas over this many seconds and pushes a re-warm "
-           "with a tighter ladder when >25% of the common bucket is "
-           "padding (router/policy.py derive_ladder). 0 = adaptation "
-           "off (ladders stay as deployed)"),
     # ---- request-scoped tracing (obs/tracing.py;
     #      docs/observability.md "Request tracing & SLOs") ----
     EnvVar("MXTPU_TRACE_SAMPLE", float, 0.0,
@@ -297,35 +178,6 @@ REGISTRY = [
            "recorded regardless of the head verdict so every failure "
            "is explained.  0 (default) = tracing entirely off — the "
            "fast path books nothing"),
-    EnvVar("MXTPU_TRACE_BUFFER", int, 4096,
-           "In-process span-buffer capacity of the request tracer "
-           "(obs/tracing.py): the oldest MXTPU_TRACE_BUFFER spans are "
-           "kept per process, later ones drop (counted in "
-           "trace.spans_dropped); the profiler chrome mirror is "
-           "unaffected"),
-    # ---- int8 post-training quantization (quant/; docs/perf.md "Int8
-    #      serving", docs/serving.md) ----
-    EnvVar("MXTPU_QUANT_CALIB_MODE", str, "minmax",
-           "quant.calibrate default range mode: 'minmax' keeps the "
-           "observed per-channel |activation| max; 'percentile' "
-           "additionally caps every channel at the "
-           "MXTPU_QUANT_PERCENTILE-th percentile of the node's |x| "
-           "distribution (value-range histogram), trading saturation "
-           "of rare outliers for resolution on the bulk of the values "
-           "(clipped mass recorded per node as clip_pct)"),
-    EnvVar("MXTPU_QUANT_PERCENTILE", float, 99.99,
-           "Percentile (0, 100] for MXTPU_QUANT_CALIB_MODE=percentile; "
-           "99.99 clips ~the top 1e-4 of activation mass"),
-    EnvVar("MXTPU_QUANT_HIST_BINS", int, 2048,
-           "Bucket count (even) of the auto-ranging value-range "
-           "histograms calibration records activation distributions "
-           "into (telemetry.ValueHistogram; also the per-node "
-           "quant.calib.act.* telemetry histograms)"),
-    EnvVar("MXTPU_QUANT_SKIP_FIRST_LAST", int, 1,
-           "quantize_symbol policy: leave the FIRST and LAST eligible "
-           "conv/FC layer in float (the input stem and classifier head "
-           "are the classic accuracy-critical layers; the reference's "
-           "quantization excluded them too). 0 quantizes them as well"),
     # ---- telemetry (telemetry.py; docs/observability.md) ----
     EnvVar("MXTPU_TELEMETRY", int, 1,
            "Metrics registry (telemetry.py): counters/gauges/histograms "
@@ -346,16 +198,6 @@ REGISTRY = [
            "On a device with no entry and no override the gauge is not "
            "published"),
     # ---- distributed observability (obs/; docs/observability.md) ----
-    EnvVar("MXTPU_OBS_RECORDER", int, 1,
-           "Flight recorder (obs/recorder.py): a fixed-slot per-rank "
-           "ring of collective/dispatch edge events (enter/exit, seq, "
-           "bytes) recorded always-on from the fused-dispatch and "
-           "host-collective paths — the post-mortem substrate of the "
-           "stall watchdog.  0 disables; every call site fast-paths "
-           "out behind recorder.enabled() (mxlint E004)"),
-    EnvVar("MXTPU_OBS_RING_SLOTS", int, 512,
-           "Flight-recorder ring capacity in events (fixed slots, "
-           "preallocated; oldest events overwrite first)"),
     EnvVar("MXTPU_OBS_STALL_SECONDS", float, 0.0,
            "Stall watchdog (obs/watchdog.py): a collective/dispatch "
            "edge event whose exit has not arrived after this many "
@@ -384,14 +226,6 @@ REGISTRY = [
            "mid-traffic.  0 (default) = the platform-queried device "
            "memory (memory_stats bytes_limit), or unlimited where the "
            "platform reports none (XLA:CPU)"),
-    EnvVar("MXTPU_MEM_CENSUS", int, 1,
-           "Live-buffer census (obs/memory.py): tag-attributed byte "
-           "accounting at the places device bytes are born and die "
-           "(NDArray payloads, KV rings, serve slots, staged blocks, "
-           "checkpoint blobs), rendered as mem.live_bytes.<tag> "
-           "gauges/counter lanes with a top-K high-watermark tracker. "
-           "0 disarms the bookkeeping (the booking guard itself stays; "
-           "its cost on the chip: not measured)"),
     EnvVar("MXTPU_MEM_PROGRAMS", int, 1,
            "Per-program footprint accounting (obs/memory.py): compile-"
            "cache sites compile ahead-of-time and harvest XLA's "
@@ -411,14 +245,14 @@ REGISTRY = [
            "cluster JSONL records"),
     EnvVar("MXTPU_OBS_CLUSTER_FILE", str, "",
            "Non-empty: rank 0's aggregator appends one cluster-level "
-           "JSONL record per interval (per-rank steps/step-time/comm "
+           "JSONL record per interval (per-rank steps/step-time "
            "columns + max/median step-skew straggler attribution) — "
            "render with `python tools/parse_log.py --cluster FILE`"),
     EnvVar("MXTPU_COLLECTIVE_CHECK", int, 0,
            "Cross-rank collective-schedule verifier (parallel/"
            "schedule_check.py, the runtime half of mxlint E007): every "
            "rank folds its flight-recorder stream of collective enter "
-           "events (kind, seq, bytes, bucket-plan fingerprint) into a "
+           "events (kind, seq, bytes, detail) into a "
            "rolling structural hash, ships the digest in the obs "
            "snapshot every MXTPU_OBS_INTERVAL_SECONDS, and compares "
            "against every peer.  A divergent schedule is reported as a "
@@ -449,26 +283,15 @@ REGISTRY = [
            "soak-test mode"),
     # ---- checkpoint / elastic training (mxnet_tpu/ckpt) ----
     EnvVar("MXTPU_CKPT_DIR", str, "",
-           "Non-empty arms periodic async distributed checkpoints in "
-           "Module.fit: every rank writes write-then-rename shard "
-           "files here, rank 0 commits the mxtpu-ckpt-v1 manifest "
-           "(docs/checkpoint.md).  Empty = checkpointing off"),
-    EnvVar("MXTPU_CKPT_EVERY_STEPS", int, 0,
-           "Snapshot cadence in TRAINING STEPS (batches); snapshots "
-           "land at the first dispatch boundary on or past the budget, "
-           "so with K-step fused dispatch the effective cadence rounds "
-           "up to a multiple of K.  0 = off even when MXTPU_CKPT_DIR "
-           "is set"),
+           "Where Module.fit(checkpoint_every_steps=N > 0) writes its "
+           "periodic async distributed checkpoints: every rank writes "
+           "write-then-rename shard files here, rank 0 commits the "
+           "mxtpu-ckpt-v1 manifest (docs/checkpoint.md).  Empty = "
+           "checkpointing off"),
     EnvVar("MXTPU_CKPT_KEEP", int, 2,
            "Committed checkpoints retained; older manifests are pruned "
            "manifest-first (an interrupted prune leaves orphan shards, "
            "never a manifest naming missing shards)"),
-    EnvVar("MXTPU_CKPT_ASYNC", int, 1,
-           "1 (default): shard writes ride a background engine op "
-           "overlapped with the next K-step dispatch (the serve_stage "
-           "pattern); the trainer only blocks on the PREVIOUS write at "
-           "the next trigger.  0 = synchronous write+commit, for "
-           "debugging or when the filesystem needs serialized I/O"),
     EnvVar("MXTPU_CKPT_RESUME", str, "",
            "Resume source consumed by Module.fit when resume_from is "
            "not passed explicitly: a checkpoint directory (newest "
@@ -503,21 +326,6 @@ REGISTRY = [
            "Start profiling at import; dump via mx.profiler.dump_profile()"),
     EnvVar("MXNET_PROFILER_FILENAME", str, "profile.json",
            "Profiler output path (profiler.py)"),
-    EnvVar("MXNET_BN_STATS_SAMPLE", int, 0,
-           "Ghost-batch BN statistics: compute train-mode batch-norm "
-           "mean/var on the leading N samples only (0 = full batch). "
-           "A SEMANTICS knob (ghost batch norm, a large-batch "
-           "regularizer) — measured NOT a perf knob: ResNet-50 b512 "
-           "step time is unchanged at N=128 (README Roofline item 6; "
-           "the forward stats passes are already hidden by XLA). "
-           "Opt-in, never default"),
-    EnvVar("MXNET_TPU_PALLAS_BN", int, 0,
-           "Use the hand-tiled Pallas kernel for BatchNorm train-mode "
-           "statistics on channel-minor TPU graphs (ops/pallas_kernels.py). "
-           "Default OFF: measured 27% SLOWER end-to-end on ResNet-50 batch "
-           "512 (1826 vs 2487 img/s) — the kernel wins nothing over XLA's "
-           "fused reduce and its custom_vjp pins an extra residual. Kept "
-           "for experimentation; see README Roofline item 5"),
     EnvVar("MXNET_TPU_S2D_STEM", int, 0,
            "EXACT space-to-depth rewrite of 2-D stride-2 stem "
            "convolutions (C_in<=4, any kernel/pad, odd sizes "
@@ -540,15 +348,6 @@ REGISTRY = [
            "re-measured, ROADMAP.md D4). Activation gradients keep exact f32 "
            "accumulation. Changes gradient numerics (tolerance-pinned "
            "in tests/test_mfu_sinks.py); default OFF"),
-    EnvVar("MXTPU_FROZEN_BN", int, 0,
-           "Default for Module.fit(frozen_bn=): 1 freezes every "
-           "BatchNorm for fine-tuning — use_global_stats forced on "
-           "(running stats carried, never recomputed) and BN "
-           "gamma/beta excluded from the optimizer update "
-           "(symbol.freeze_batchnorm; +17.9% measured on ResNet-50 "
-           "training in July 2026, README Roofline items 6/8; not "
-           "re-measured, ROADMAP.md D4). A fine-tuning SEMANTICS mode, not a "
-           "free perf knob: stats must already be trained. Default OFF"),
     # ---- JAX/XLA passthrough the test/dev flows rely on ----
     EnvVar("JAX_PLATFORMS", str, "", "Force a JAX backend, e.g. 'cpu'"),
     EnvVar("XLA_FLAGS", str, "",
@@ -575,13 +374,84 @@ ABSORBED = {
     "MXNET_ENABLE_GPU_P2P": "ICI collectives",
 }
 
+# variables this registry once held and no code reads any more: name ->
+# what holds the value now.  Accepted and without effect; importing the
+# package with one of them set says so once (warn_retired), and
+# docs/how_to/env_var.md lists them
+RETIRED = {
+    "MXTPU_COMM_BUCKETED":
+        "deleted: a data-parallel fit syncs gradients one way, by the "
+        "all-reduce XLA's partitioner puts into the step",
+    "MXTPU_COMM_BUCKET_MB": "deleted with MXTPU_COMM_BUCKETED",
+    "MXNET_TPU_PALLAS_BN":
+        "deleted: the chip measured the Pallas BatchNorm statistics "
+        "kernel 27% slower end to end (1,826 vs 2,487 img/s, v5e)",
+    "MXNET_BN_STATS_SAMPLE":
+        "deleted: the chip measured no change (2,474 vs 2,480 img/s, v5e)",
+    "MXTPU_FROZEN_BN": "Module.fit(frozen_bn=False)",
+    "MXTPU_SERVE_MAX_BATCH": "serving.ModelServer(max_batch=32)",
+    "MXTPU_SERVE_BUCKETS":
+        "serving.ModelServer(buckets=None): powers of two up to max_batch",
+    "MXTPU_SERVE_TIMEOUT_MS":
+        "serving.ModelServer(timeout_ms=5000.0), submit(timeout_ms=)",
+    "MXTPU_SERVE_MAX_SESSIONS":
+        "ModelServer.add_generative_tenant(max_sessions=8)",
+    "MXTPU_SERVE_MAX_DECODE_TOKENS":
+        "ModelServer.add_generative_tenant(max_decode_tokens=64)",
+    "MXTPU_SERVE_DECODE_WINDOW_MS":
+        "the constant serving.server.DECODE_WINDOW_MS = 2.0",
+    "MXTPU_SERVE_KV_MAX_LEN":
+        "ModelServer.add_generative_tenant(max_len=256)",
+    "MXTPU_STAGE_BUFFERS": "io.DeviceStagedIter(buffers=2)",
+    "MXTPU_DATA_WORKERS":
+        "data.DataService / io.ShardedImageRecordIter(num_workers=2)",
+    "MXTPU_DATA_RING_SLOTS":
+        "data.DataService / io.ShardedImageRecordIter(ring_slots=4)",
+    "MXTPU_ROUTER_POLL_MS": "router.Router(poll_ms=200.0)",
+    "MXTPU_ROUTER_REDISPATCH": "router.Router(redispatch_cap=2)",
+    "MXTPU_ROUTER_ADAPT_WINDOW_S": "router.Router(adapt_window_s=10.0)",
+    "MXTPU_QUANT_CALIB_MODE": "quant.calibrate(mode='minmax')",
+    "MXTPU_QUANT_PERCENTILE": "quant.calibrate(percentile=99.99)",
+    "MXTPU_QUANT_HIST_BINS": "quant.calibrate(hist_bins=2048)",
+    "MXTPU_QUANT_SKIP_FIRST_LAST":
+        "quant.quantize_symbol(skip_first_last=True)",
+    "MXTPU_TRACE_BUFFER": "the constant obs.tracing._CAP = 4096",
+    "MXTPU_OBS_RECORDER":
+        "always on (obs.recorder.set_enabled(False) turns it off in-process)",
+    "MXTPU_OBS_RING_SLOTS": "the constant obs.recorder._RING_SLOTS = 512",
+    "MXTPU_MEM_CENSUS":
+        "always on (obs.memory.set_census(False) turns it off in-process)",
+    "MXTPU_CKPT_EVERY_STEPS": "Module.fit(checkpoint_every_steps=0)",
+    "MXTPU_CKPT_ASYNC": "ckpt.CheckpointManager(async_write=True)",
+    "MXNET_KVSTORE_PULL_TIMEOUT":
+        "the constant parallel.dist.PULL_TIMEOUT = 60.0",
+    "MXNET_KVSTORE_REGISTER_TIMEOUT":
+        "the constant parallel.dist.REGISTER_TIMEOUT = 600.0",
+}
+
 _BY_NAME = {v.name: v for v in REGISTRY}
+
+
+def warn_retired(environ):
+    """One warning for each RETIRED variable `environ` sets, naming what
+    holds its value now.  Reads `environ`, never writes it."""
+    import warnings
+
+    for name in sorted(set(RETIRED).intersection(environ)):
+        warnings.warn("%s is set and has no effect: %s"
+                      % (name, RETIRED[name]), stacklevel=2)
+
+
+warn_retired(os.environ)
 
 
 def spec(name):
     """The EnvVar registration for `name` (KeyError on unknown names)."""
     s = _BY_NAME.get(name)
     if s is None:
+        if name in RETIRED:
+            raise KeyError("config variable %s was retired: %s"
+                           % (name, RETIRED[name]))
         raise KeyError("unknown config variable %s (see config.REGISTRY; "
                        "absorbed-by-XLA vars: %s)" % (name, sorted(ABSORBED)))
     return s
@@ -606,4 +476,8 @@ def describe():
     lines.append("absorbed by XLA/PJRT (accepted, ignored):")
     for k, why in sorted(ABSORBED.items()):
         lines.append("  %-34s -> %s" % (k, why))
+    lines.append("")
+    lines.append("retired (accepted, no effect):")
+    for k, now in sorted(RETIRED.items()):
+        lines.append("  %-34s -> %s" % (k, now))
     return "\n".join(lines)

@@ -17,7 +17,7 @@ tops out long before one TPU chip does):
   * per-host sharding (``host_index``/``num_hosts``) composed on top
     of worker sharding — the multi-process SPMD mesh's input story.
 
-Knobs: ``MXTPU_DATA_WORKERS`` / ``MXTPU_DATA_RING_SLOTS`` /
+Knobs: the ``num_workers`` / ``ring_slots`` arguments, and
 ``MXTPU_DATA_SLOT_BYTES`` / ``MXTPU_DATA_HOST_INDEX`` /
 ``MXTPU_DATA_NUM_HOSTS`` (config.py).  Metrics: the ``data.*``
 namespace (docs/observability.md).  Decode throughput on the chip's

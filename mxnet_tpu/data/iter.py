@@ -33,17 +33,17 @@ class ShardedImageRecordIter(DataIter):
     Accepts the same decode/augment surface (``data_shape``,
     ``rand_crop``/``rand_mirror``, ``mean_*``/``scale``/``resize``,
     ``label_width``, ``shuffle``/``seed``) plus the service knobs:
-    ``num_workers`` (default ``MXTPU_DATA_WORKERS``), ``ring_slots`` /
+    ``num_workers`` decode processes, ``ring_slots`` /
     ``slot_bytes`` (shm ring geometry), and ``host_index``/``num_hosts``
     for per-host sharding composed on top of worker sharding.
     """
 
     def __init__(self, path_imgrec=None, data_shape=None, batch_size=1,
-                 num_workers=None, label_width=1, shuffle=False, seed=0,
+                 num_workers=2, label_width=1, shuffle=False, seed=0,
                  rand_crop=False, rand_mirror=False, mean_r=0.0, mean_g=0.0,
                  mean_b=0.0, scale=1.0, resize=0, preprocess_threads=1,
                  prefetch_buffer=2, host_index=None, num_hosts=None,
-                 ring_slots=None, slot_bytes=None, data_name="data",
+                 ring_slots=4, slot_bytes=None, data_name="data",
                  label_name="softmax_label", force_python_decode=False,
                  **kwargs):
         super().__init__(batch_size)

@@ -74,9 +74,9 @@ class DataService:
     (data/iter.py) wraps this in the standard DataIter contract.
     """
 
-    def __init__(self, path_imgrec, data_shape, batch_size, num_workers=None,
+    def __init__(self, path_imgrec, data_shape, batch_size, num_workers=2,
                  label_width=1, shuffle=False, seed=0, host_index=None,
-                 num_hosts=None, ring_slots=None, slot_bytes=None,
+                 num_hosts=None, ring_slots=4, slot_bytes=None,
                  rand_crop=False, rand_mirror=False, mean_r=0.0, mean_g=0.0,
                  mean_b=0.0, scale=1.0, resize=0, preprocess_threads=1,
                  force_python_decode=False):
@@ -91,8 +91,7 @@ class DataService:
         self.data_shape = tuple(int(d) for d in data_shape)
         self.batch_size = int(batch_size)
         self.label_width = int(label_width)
-        self.num_workers = int(num_workers if num_workers is not None
-                               else config.get("MXTPU_DATA_WORKERS"))
+        self.num_workers = int(num_workers)
         if self.num_workers < 1:
             raise MXNetError("num_workers must be >= 1 (got %d)"
                              % self.num_workers)
@@ -100,8 +99,7 @@ class DataService:
                               else config.get("MXTPU_DATA_HOST_INDEX"))
         self.num_hosts = int(num_hosts if num_hosts is not None
                              else config.get("MXTPU_DATA_NUM_HOSTS"))
-        ring_slots = int(ring_slots if ring_slots is not None
-                         else config.get("MXTPU_DATA_RING_SLOTS"))
+        ring_slots = int(ring_slots)
         if ring_slots < 1:
             raise MXNetError("ring_slots must be >= 1 (got %d)" % ring_slots)
         need = slot_bytes_needed(self.batch_size, self.data_shape,

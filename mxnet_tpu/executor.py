@@ -1251,146 +1251,14 @@ class Executor:
         self._outputs_cache = None
         self._aux_applied = False
 
-    def _comm_mode(self):
-        """(psum_axes, bucket_bytes) when EXPLICIT bucketed hierarchical
-        gradient collectives are armed for the K-step block dispatch,
-        else None (the implicit path: XLA's SPMD partitioner inserts the
-        gradient all-reduce itself).
-
-        Armed by MXTPU_COMM_BUCKETED=1 — or automatically ('auto') on a
-        multi-process mesh, where controlling the collective layout is
-        the point: grads pack into MXTPU_COMM_BUCKET_MB buckets, each
-        reduced ICI-first then DCN (collectives.hierarchical_psum), and
-        each bucket's all-reduce depends only on its member grads so it
-        overlaps the rest of the backward structurally.  Only the pure
-        data-parallel regime qualifies: TP/EP param shardings, ctx_group
-        boundaries, mesh-needing ops, and batch-/valid-normalized losses
-        keep the implicit partitioner path (their collectives/shape
-        reads are the partitioner's job).
-
-        SEMANTICS NOTE: train-mode BatchNorm computes batch statistics
-        per SHARD on this path (the reference's per-device BN) while
-        the implicit partitioner computes global-batch statistics
-        (SyncBN-like); moving stats are pmean'd across shards each
-        step.  Valid data-parallel training either way, but not
-        bit-parity between the two modes for BN models — fine-tune
-        flows wanting exact parity use fit(frozen_bn=True)
-        (docs/distributed.md).
-
-        Cached per executor (like _fused_static): the answer is constant
-        for a bound graph, and this sits on the per-dispatch and
-        per-epoch host paths — toggling MXTPU_COMM_* mid-process takes
-        effect on the next bind."""
-        cached = getattr(self, "_comm_mode_cache", "unset")
-        if cached != "unset":
-            return cached
-        self._comm_mode_cache = self._comm_mode_impl()
-        return self._comm_mode_cache
-
-    def _comm_mode_impl(self):
-        if self._mesh is None:
-            return None
-        from .parallel.mesh import data_axes
-
-        axes = data_axes(self._mesh)
-        if not axes or set(axes) != set(self._mesh.axis_names):
-            return None
-        size = 1
-        for a in axes:
-            size *= self._mesh.shape[a]
-        if size <= 1:
-            return None
-        if self._node_groups or self._param_shardings:
-            return None
-        for node in self._order:
-            if node.op is None:
-                continue
-            if getattr(node.op, "need_mesh", False) \
-                    or getattr(node.op, "input_axes", None):
-                return None
-            # batch-/valid-normalized losses divide the gradient by a
-            # PER-SHARD count inside shard_map (ops/nn.py _softmax_bwd
-            # reads data.shape[0], which is local there) — psumming
-            # those local means would over-scale grads n_shards x.  The
-            # implicit partitioner sees the GLOBAL shape and stays
-            # correct, so such graphs keep it.
-            if node.attrs and str(node.attrs.get(
-                    "normalization", "null")) != "null":
-                return None
-        # every output must carry the batch on dim 0: a batch-REDUCED
-        # output (e.g. a Group'd mx.sym.sum head) has sum semantics the
-        # per-shard pmean cannot reproduce — those graphs keep the
-        # implicit partitioner, which reduces over the global array
-        flags = self._out_batch_flags()
-        if flags is None or not all(flags):
-            return None
-        from . import config
-
-        mode = str(config.get("MXTPU_COMM_BUCKETED")).strip().lower()
-        if mode in ("0", "off", "false", "no"):
-            return None
-        if mode in ("auto", "") and jax.process_count() <= 1:
-            return None
-        raw = config.get("MXTPU_COMM_BUCKET_MB")
-        self._comm_bucket_auto = (raw == "auto")
-        if self._comm_bucket_auto:
-            # 'auto': arm with the registered default until the first
-            # comm-mode block derives the real target from a measured
-            # probe (autotune_comm_bucket) and re-arms this cache
-            bucket_bytes = getattr(self, "_comm_auto_bytes", None) or max(
-                1, int(float(
-                    config.spec("MXTPU_COMM_BUCKET_MB").default) * 1e6))
-        else:
-            bucket_bytes = max(1, int(float(raw) * 1e6))
-        # ICI-first reduction order: the innermost data axis is the LAST
-        # in mesh order ('data_dcn' x 'data_ici' -> reduce ici, then dcn)
-        return tuple(reversed(axes)), bucket_bytes
-
-    def _out_batch_flags(self):
-        """Per-output flag: does the leading dim carry the batch (so a
-        comm-mode shard_map must tile it over the data axes) vs a
-        reduced/replicated output (pmean'd across shards).  Cached: the
-        full-graph infer_shape walk must not run per dispatch (arg
-        shapes are fixed at bind; reshape builds a fresh executor)."""
-        cached = getattr(self, "_out_batch_cache", "unset")
-        if cached != "unset":
-            return cached
-        shapes = {n: tuple(self.arg_dict[n].shape) for n in self._arg_names}
-        data_names = self._data_arg_names
-        batch = shapes[data_names[0]][0] if data_names and \
-            shapes[data_names[0]] else 0
-        try:
-            _, out_shapes, _ = self._symbol.infer_shape(**shapes)
-        except Exception:
-            out_shapes = None
-        if not out_shapes:
-            self._out_batch_cache = None
-        else:
-            self._out_batch_cache = [bool(s) and batch > 0
-                                     and s[0] == batch for s in out_shapes]
-        return self._out_batch_cache
-
-    def _build_block_fn(self, stream_idx, static_idx, comm,
-                        out_batch=None):
-        """The K-step scan over full fwd+bwd+update steps.  With `comm`
-        armed the returned fn is written for a PER-SHARD view (wrapped in
-        shard_map by the caller): the vjp gradients are local sums, so
-        they are packed into size-targeted buckets and hierarchical-
-        psum'd (ICI-first) right where backward produces them — inside
-        the scan body, so the overlap with remaining backward compute is
-        part of the HLO dependency structure; aux (BN stats) and
-        non-batch outputs are pmean'd back to replicated."""
+    def _build_block_fn(self, stream_idx, static_idx):
+        """The K-step scan over full fwd+bwd+update steps."""
         an = self._arg_names
         diff_names, diff_idx, nondiff_idx = self._fused_static
         opt = self._fused_updater.optimizer
         core = self._grad_core(diff_idx, nondiff_idx)
         stream_pos = {i: p for p, i in enumerate(stream_idx)}
         static_pos = {i: p for p, i in enumerate(static_idx)}
-        if comm is not None:
-            from .parallel.collectives import (bucketed_psum,
-                                               hierarchical_pmean)
-
-            axes, bucket_bytes = comm
 
         def block(diff_vals, static_vals, aux_vals, state_tuples,
                   stream_vals, seeds_arr, scalars_arr):
@@ -1403,14 +1271,6 @@ class Executor:
                     for i in nondiff_idx)
                 rng = jax.random.key(seed)
                 outs, aux_upd, grads = core(dv, nondiff, aux, rng, None)
-                if comm is not None:
-                    grads, _ = bucketed_psum(grads, axes, bucket_bytes)
-                    aux_upd = tuple(hierarchical_pmean(a, axes)
-                                    for a in aux_upd)
-                    if out_batch is not None:
-                        outs = tuple(
-                            o if is_b else hierarchical_pmean(o, axes)
-                            for o, is_b in zip(outs, out_batch))
                 new_params, new_states = [], []
                 for j, (w, g, st) in enumerate(zip(dv, grads, sts)):
                     nw, nst = opt._fused(w, g, st, scal[j, 0],
@@ -1428,44 +1288,11 @@ class Executor:
 
         return block
 
-    def _wrap_comm_block(self, fn, out_batch):
-        """shard_map the block over the mesh: params/state/aux/seeds
-        replicated, stacked inputs sharded over the data axes on dim 1,
-        batch-carrying outputs tiled back, everything else replicated
-        (provably so — grads ride psum, stats ride pmean)."""
-        from .parallel.collectives import shard_map_unchecked
-        from .parallel.mesh import P, batch_pspec
-
-        bspec = batch_pspec(self._mesh, lead_dims=1)
-        out_spec_outs = tuple(bspec if b else P() for b in out_batch)
-        return shard_map_unchecked(
-            fn, mesh=self._mesh,
-            in_specs=(P(), P(), P(), P(), bspec, P(), P()),
-            out_specs=(out_spec_outs, P(), P(), P()))
-
-    def _comm_plan_bytes(self, comm):
-        """Host-side mirror of the bucket plan bucketed_psum will trace:
-        per-bucket byte sizes for the armed diff params (telemetry +
-        the comm probe's algorithmic-byte accounting).  Cached per
-        bucket size — param shapes are fixed at bind, and this runs in
-        the per-dispatch telemetry block."""
-        cache = getattr(self, "_comm_plan_cache", None)
-        if cache is None:
-            cache = self._comm_plan_cache = {}
-        if comm[1] not in cache:
-            from .parallel.collectives import bucket_plan
-
-            diff_names, _, _ = self._fused_static
-            avals = [self.arg_dict[n].data for n in diff_names]
-            cache[comm[1]] = [nb for _, nb in bucket_plan(avals, comm[1])]
-        return cache[comm[1]]
-
     def fused_update_block(self):
         """Run the staged K-step block: one jitted lax.scan dispatch
         executing K full fwd+bwd+update steps (see stage_block).  On a
-        comm-mode mesh (_comm_mode) the gradient sync inside the scan is
-        explicit: bucketed, hierarchical (ICI-first), and overlapped
-        with backward by construction — docs/distributed.md."""
+        mesh the gradient all-reduce is the one XLA's partitioner puts
+        into the scan body — docs/distributed.md."""
         import numpy as _np
 
         from .optimizer import schedule_prefix
@@ -1490,37 +1317,14 @@ class Executor:
         static_idx = [i for i in nondiff_idx if an[i] not in named]
         sig = tuple((n, tuple(l.shape for l in leaves_by_name[n]))
                     for n in diff_names)
-        comm = self._comm_mode()
-        if comm is not None and getattr(self, "_comm_bucket_auto", False) \
-                and not getattr(self, "_comm_auto_done", False):
-            # MXTPU_COMM_BUCKET_MB=auto: derive the real target from a
-            # measured probe BEFORE the first block compiles, so the
-            # first program already carries the tuned bucket plan (a
-            # COLLECTIVE step — every rank reaches it at its first
-            # comm-mode block)
-            self.autotune_comm_bucket()
-            comm = self._comm_mode()
-        out_batch = None
-        if comm is not None:
-            # resolved ONCE and shared by the body and the shard_map
-            # out_specs — the two must never disagree.  The comm gate
-            # already required all-batch inferable outputs, so this is
-            # the cached list, never None
-            out_batch = self._out_batch_flags()
-            assert out_batch is not None and all(out_batch),                 "comm mode armed without all-batch outputs (gate bug)"
-        key = (k, tuple(an[i] for i in stream_idx), sig, comm)
+        key = (k, tuple(an[i] for i in stream_idx), sig)
         first_call = key not in self._jit_block
         self._note_compile_cache(not first_call,
                                  site="executor.fused_block", signature=key)
         if first_call:
-            fn = self._build_block_fn(stream_idx, static_idx, comm,
-                                      out_batch=out_batch)
-            if comm is not None:
-                fn = self._wrap_comm_block(fn, out_batch)
             self._jit_block[key] = self._mem_program(
-                fn, "executor.fused_block", key, donate_argnums=(0, 3))
-        self._last_block_key = key
-        self._last_block_streams = (tuple(stream_idx), tuple(static_idx))
+                self._build_block_fn(stream_idx, static_idx),
+                "executor.fused_block", key, donate_argnums=(0, 3))
         fn = self._jit_block[key]
         all_vals = self._place(self._gather_args())
         diff_vals = tuple(all_vals[i] for i in diff_idx)
@@ -1538,35 +1342,17 @@ class Executor:
                        + sum(l.nbytes for st in state_tuples for l in st))
             self._note_bytes("executor.donated_bytes", donated)
             self._note_bytes("mem.donated_retired_bytes", donated)
-            if comm is not None:
-                # bucket accounting is host-static (shapes + the plan
-                # bucketed_psum traces): bytes_reduced counts one full
-                # gradient sweep per scan step
-                plan = self._comm_plan_bytes(comm)
-                telemetry.inc("comm.dispatches")
-                telemetry.inc("comm.bytes_reduced", sum(plan) * k)
-                telemetry.set_gauge("comm.buckets", len(plan))
-                for nb in plan:
-                    telemetry.observe("comm.bucket_bytes", nb,
-                                      buckets=telemetry.BYTE_BUCKETS)
         # flight-recorder bracket (obs/recorder.py): seq is the dispatch
-        # counter, detail carries K and the comm bucket layout, bytes are
-        # the per-sweep reduced gradient bytes — the post-mortem's "which
-        # collective seq was in flight" answer.  The compile bracket
-        # suppresses the stall watchdog across a first XLA compile.
+        # counter, detail carries K — the post-mortem's "which dispatch
+        # was in flight" answer.  The compile bracket suppresses the
+        # stall watchdog across a first XLA compile.
         rec = recorder.enabled()
         seq = self._train_dispatches + 1
         if rec:
-            if comm is not None:
-                plan = self._comm_plan_bytes(comm)
-                detail = "block(K=%d,buckets=%d)" % (k, len(plan))
-                rec_bytes = sum(plan) * k
-            else:
-                detail, rec_bytes = "block(K=%d)" % k, 0
+            detail = "block(K=%d)" % k
             if first_call:
                 recorder.record("compile", "enter", seq, detail=detail)
-            recorder.record("dispatch", "enter", seq, detail=detail,
-                            nbytes=rec_bytes)
+            recorder.record("dispatch", "enter", seq, detail=detail)
         try:
             with profiler.span("fit.dispatch", cat="executor",
                                hist="executor.dispatch_seconds.block", k=k,
@@ -1594,255 +1380,6 @@ class Executor:
             self.arg_dict[n]._set_data(nw)
             for l, v in zip(leaves_by_name[n], nst):
                 l._set_data(v)
-
-    def _time_comm_only(self, axes, bucket_bytes, iters=2):
-        """Compile and time ONE bucketed hierarchical gradient sweep at
-        an arbitrary bucket size — zeros gradients on throwaway
-        buffers, params untouched.  The shared probe under
-        measure_comm's comm-only leg and autotune_comm_bucket's
-        two-point model fit.  Returns mean seconds per sweep."""
-        import numpy as _np
-
-        from . import profiler
-        from .parallel.collectives import bucketed_psum, shard_map_unchecked
-        from .parallel.mesh import P, global_put
-
-        diff_names, _, _ = self._fused_static
-        n_buckets = len(self._comm_plan_bytes((tuple(axes), bucket_bytes)))
-
-        def comm_only(gs):
-            red, _ = bucketed_psum(gs, axes, bucket_bytes)
-            return red
-
-        comm_fn = jax.jit(shard_map_unchecked(
-            comm_only, mesh=self._mesh, in_specs=(P(),), out_specs=P()))
-        gz = tuple(global_put(
-            _np.zeros(self.arg_dict[nm].shape,
-                      _np.dtype(self.arg_dict[nm].dtype)),
-            self._repl_sharding) for nm in diff_names)
-        jax.block_until_ready(comm_fn(gz))  # compile
-        with profiler.span("comm.allreduce", cat="comm",
-                           buckets=n_buckets) as sweeps:
-            for _ in range(iters):
-                jax.block_until_ready(comm_fn(gz))
-        return sweeps.seconds / iters
-
-    def autotune_comm_bucket(self, iters=2):
-        """MXTPU_COMM_BUCKET_MB=auto: derive the bucket target at fit
-        start from a MEASURED probe (docs/perf.md "Autotuning").
-
-        Times one full gradient sweep at the armed bucket size and at a
-        quarter of it, fits the per-collective fixed cost c0 and the
-        wire rate to the two points (tune.fit_comm_model), and adopts
-        the smallest bucket whose per-sweep fixed-cost share stays
-        under 10% of wire time (tune.derive_comm_bucket; clamped
-        [1, 64] MB, no-flapping keep-threshold 25%).  On a
-        multi-process mesh the derived target is allgathered and
-        AVERAGED so every rank arms the IDENTICAL bucket plan —
-        divergent plans would desync the collective schedule — and a
-        rank whose probe did not fit the model vetoes the change
-        everywhere.  The decision and its measured basis are booked as
-        tune.* telemetry and a flight-recorder tune bracket; on a
-        change the comm cache re-arms so the NEXT block program
-        compiles with the target (prior variants stay jit-cached).
-
-        A COLLECTIVE call — fused_update_block runs it at the first
-        comm-mode block when armed, every rank in step.  Returns the
-        decision record (also kept as _comm_auto_decision)."""
-        import numpy as _np
-
-        from . import telemetry, tune
-        from .obs import recorder
-
-        self._comm_auto_done = True
-        comm = self._comm_mode()
-        if comm is None:
-            return None
-        axes, cur_bytes = comm
-        rec = recorder.enabled()
-        if rec:
-            recorder.record("tune", "enter", detail="comm_bucket(auto)")
-        try:
-            plan_cur = self._comm_plan_bytes((axes, cur_bytes))
-            probe_bytes = max(1, cur_bytes // 4)
-            plan_probe = self._comm_plan_bytes((axes, probe_bytes))
-            t_cur = self._time_comm_only(axes, cur_bytes, iters=iters)
-            t_probe = self._time_comm_only(axes, probe_bytes, iters=iters)
-            n_dev = 1
-            for a in axes:
-                n_dev *= self._mesh.shape[a]
-            sweep_bytes = sum(plan_cur)
-            algo_bytes = 2.0 * (n_dev - 1) / n_dev * sweep_bytes
-            proposal = tune.derive_comm_bucket(
-                cur_bytes=cur_bytes, t_cur=t_cur, n_cur=len(plan_cur),
-                t_probe=t_probe, n_probe=len(plan_probe),
-                algo_bytes=algo_bytes, sweep_bytes=sweep_bytes)
-            target = float(proposal["target_bytes"]) if proposal else 0.0
-            if jax.process_count() > 1:
-                # consensus: one rank's no-fit (target 0) vetoes the
-                # change for everyone; otherwise the mean target arms
-                from jax.experimental import multihost_utils
-
-                gathered = _np.asarray(multihost_utils.process_allgather(
-                    _np.float64(target))).reshape(-1)
-                target = (0.0 if (gathered <= 0).any()
-                          else float(gathered.mean()))
-            decision = {
-                "mode": "auto",
-                "prev_bytes": int(cur_bytes),
-                "applied_bytes": (int(target) if target > 0
-                                  else int(cur_bytes)),
-                "changed": bool(target > 0),
-                "probe": {
-                    "t_cur_s": t_cur, "buckets_cur": len(plan_cur),
-                    "t_probe_s": t_probe,
-                    "buckets_probe": len(plan_probe),
-                    "probe_bytes": int(probe_bytes),
-                    "sweep_bytes": int(sweep_bytes),
-                    "algo_bytes": int(algo_bytes),
-                },
-                "model": (None if proposal is None else
-                          {"c0_us": proposal["c0_s"] * 1e6,
-                           "wire_gbps": proposal["wire_bps"] / 1e9}),
-            }
-            if target > 0:
-                self._comm_auto_bytes = int(target)
-                self._comm_mode_cache = "unset"  # re-arm with the target
-            self._comm_auto_decision = decision
-            if telemetry.enabled():
-                telemetry.inc("tune.decisions")
-                telemetry.inc("tune.comm_bucket_changed"
-                              if decision["changed"]
-                              else "tune.comm_bucket_kept")
-                telemetry.set_gauge("tune.comm_bucket_bytes",
-                                    decision["applied_bytes"])
-                if proposal is not None:
-                    telemetry.set_gauge("tune.comm_c0_us",
-                                        proposal["c0_s"] * 1e6)
-                    telemetry.set_gauge("tune.comm_wire_gbps",
-                                        proposal["wire_bps"] / 1e9)
-            return decision
-        finally:
-            if rec:
-                recorder.record("tune", "exit",
-                                detail="comm_bucket(auto)")
-
-    def measure_comm(self, iters=3):
-        """Measure the armed bucketed collectives against the compute
-        they hide under — the three-program probe (docs/distributed.md):
-
-          * comm-only — one bucketed hierarchical gradient sweep alone
-            -> measured collective GB/s (ring-algorithm bytes / time),
-          * compute-only — the SAME shard-mapped K-step block with the
-            psums elided -> t_nocomm,
-          * full — the real comm-mode block -> t_full.
-
-        ``overlap_frac = (t_nocomm + K*t_comm + - t_full) / (K*t_comm)``
-        clamped to [0, 1]: the fraction of collective time hidden under
-        backward compute.  Records comm.gbps / comm.overlap_frac gauges
-        (chrome counter lanes while profiling) plus comm.allreduce /
-        comm.overlap_probe spans beside fit.dispatch.
-
-        A COLLECTIVE probe: on a multi-process mesh every process must
-        call it at the same point.  Runs on
-        throwaway copies — params/optimizer state are not advanced.
-        Requires a prior comm-mode fused_update_block (the probe reuses
-        its shapes)."""
-        import time as _time
-
-        import numpy as _np
-
-        from . import profiler, telemetry
-        from .optimizer import schedule_prefix
-        from .parallel.mesh import global_put
-
-        comm = self._comm_mode()
-        key = getattr(self, "_last_block_key", None)
-        if comm is None or key is None or key[3] != comm:
-            raise MXNetError(
-                "measure_comm: run at least one comm-mode K-step block "
-                "dispatch first (fit on a >1-device data mesh with "
-                "MXTPU_COMM_BUCKETED armed)")
-        k = key[0]
-        stream_idx, static_idx = self._last_block_streams
-        axes, bucket_bytes = comm
-        plan = self._comm_plan_bytes(comm)
-        n = 1
-        for a in axes:
-            n *= self._mesh.shape[a]
-        diff_names, diff_idx, nondiff_idx = self._fused_static
-        leaves_by_name = self._ensure_fused_states(diff_names)
-        an = self._arg_names
-
-        def _fence(x):
-            jax.block_until_ready(x)
-
-        with profiler.span("comm.overlap_probe", cat="comm"):
-            # -- comm-only: one bucketed hierarchical sweep ------------
-            t_comm = self._time_comm_only(axes, bucket_bytes, iters=iters)
-            # -- compute-only vs full block on throwaway inputs --------
-            zeros_stream = tuple(global_put(
-                _np.zeros((k,) + tuple(self.arg_dict[an[i]].shape),
-                          _np.dtype(self.arg_dict[an[i]].dtype)),
-                self.block_input_sharding()) for i in stream_idx)
-            all_vals = self._place(self._gather_args())
-            diff_vals = tuple(all_vals[i] for i in diff_idx)
-            static_vals = tuple(all_vals[i] for i in static_idx)
-            aux_vals = self._place_repl(self._gather_aux())
-            state_tuples = tuple(self._place_repl(
-                tuple(l.data for l in leaves_by_name[nm]))
-                for nm in diff_names)
-            seeds = _np.zeros((k,), _np.uint32)
-            # schedule_prefix ADVANCES the optimizer's update counts (by
-            # design, for real blocks) — the probe must leave the LR
-            # schedule exactly where it found it
-            opt_probe = self._fused_updater.optimizer
-            saved_counts = (opt_probe.num_update,
-                            dict(opt_probe._index_update_count))
-            scalars = schedule_prefix(
-                opt_probe,
-                [self._fused_index_of_name[nm] for nm in diff_names], k)
-            opt_probe.num_update = saved_counts[0]
-            opt_probe._index_update_count = saved_counts[1]
-
-            def timed(fn):
-                outs = fn(diff_vals, static_vals, aux_vals, state_tuples,
-                          zeros_stream, seeds, scalars)
-                _fence(outs)  # compile + settle
-                t0 = _time.time()
-                for _ in range(iters):
-                    _fence(fn(diff_vals, static_vals, aux_vals,
-                              state_tuples, zeros_stream, seeds, scalars))
-                return (_time.time() - t0) / iters
-
-            # probe programs are built WITHOUT donation: the live param/
-            # state buffers must survive.  Both variants share one
-            # out_batch resolution with the real block
-            out_batch = self._out_batch_flags()
-            if out_batch is None:
-                raise MXNetError("measure_comm: cannot infer output "
-                                 "shapes for the bound symbol")
-            t_full = timed(jax.jit(self._wrap_comm_block(
-                self._build_block_fn(stream_idx, static_idx, comm,
-                                     out_batch=out_batch), out_batch)))
-            t_nocomm = timed(jax.jit(self._wrap_comm_block(
-                self._build_block_fn(stream_idx, static_idx, None,
-                                     out_batch=out_batch), out_batch)))
-        sweep_bytes = sum(plan)
-        algo_bytes = 2.0 * (n - 1) / n * sweep_bytes
-        gbps = algo_bytes / t_comm / 1e9 if t_comm > 0 else 0.0
-        overlap = 0.0
-        if t_comm > 0:
-            overlap = (t_nocomm + k * t_comm - t_full) / (k * t_comm)
-            overlap = max(0.0, min(1.0, overlap))
-        if telemetry.enabled():
-            telemetry.set_gauge("comm.gbps", gbps)
-            telemetry.set_gauge("comm.overlap_frac", overlap)
-        return {"buckets": len(plan), "bucket_bytes": plan,
-                "sweep_bytes": sweep_bytes, "devices": n,
-                "t_comm_s": t_comm, "t_nocomm_s": t_nocomm,
-                "t_full_s": t_full, "comm_gbps": gbps,
-                "overlap_frac": overlap}
 
     def backward(self, out_grads=None):
         """Fused forward+backward in one XLA executable; grads land per grad_req.

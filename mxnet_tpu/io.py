@@ -555,8 +555,8 @@ class DeviceStagedIter(DataIter):
     The fetch rides engine.ThreadedIter (one engine op per block on the
     shared worker pool, its iterator var declared as the op's write set,
     so SanitizerEngine sees a fully-declared pipeline and `mx.waitall()`
-    fences staging along with everything else).  ``MXTPU_STAGE_BUFFERS``
-    blocks are kept in flight (default 2 = classic double buffering).
+    fences staging along with everything else).  `buffers` blocks are
+    kept in flight (default 2 = classic double buffering).
     Each staging op records an ``io.stage`` profiler span, so overlap
     with the ``fit.dispatch`` lane is visible in the trace.
 
@@ -577,7 +577,7 @@ class DeviceStagedIter(DataIter):
     """
 
     def __init__(self, data_iter, steps_per_dispatch=None, place_fn=None,
-                 buffers=None, stack_fn=None):
+                 buffers=2, stack_fn=None):
         super().__init__()
         from . import config
 
@@ -594,8 +594,7 @@ class DeviceStagedIter(DataIter):
             lambda name, a: (_in_host_memory(a), _to_host(a)))
         self._stack_fn = stack_fn or (
             lambda name, steps: _np.stack([s for _, s in steps]))
-        self._buffers = max(1, int(buffers if buffers is not None
-                                   else config.get("MXTPU_STAGE_BUFFERS")))
+        self._buffers = max(1, int(buffers))
         self.batch_size = getattr(data_iter, "batch_size", 0)
         self._bg = None
         self._seq = 0  # blocks staged so far: the `block` of their spans
@@ -828,10 +827,10 @@ def ImageDetRecordIter(**kwargs):
 
 def ShardedImageRecordIter(**kwargs):
     """Multi-process sharded RecordIO image iterator (mxnet_tpu.data):
-    ``num_workers`` decode PROCESSES (default ``MXTPU_DATA_WORKERS``)
-    feed batches through shared-memory rings, with deterministic
-    ``(seed, epoch)`` coverage, per-host sharding composed on top
-    (``host_index``/``num_hosts``), and worker-crash detection.  Same
+    ``num_workers`` decode PROCESSES (default 2) feed batches through
+    shared-memory rings, with deterministic ``(seed, epoch)`` coverage,
+    per-host sharding composed on top (``host_index``/``num_hosts``),
+    and worker-crash detection.  Same
     decode/augment surface as ``ImageRecordIter``; plugs into
     ``DeviceStagedIter``/``Module.fit`` like any DataIter.  See
     docs/data.md."""

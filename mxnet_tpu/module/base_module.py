@@ -141,13 +141,6 @@ class BaseModule:
         requires the armed single-dispatch updater)."""
         return False
 
-    def _comm_armed(self):
-        """Whether the executor runs EXPLICIT bucketed hierarchical
-        gradient collectives (executor._comm_mode; Module overrides).
-        Armed runs route through the block dispatch path even at K=1 —
-        the bucketed sync lives in the fused scan."""
-        return False
-
     def _apply_frozen_bn(self, force_rebind=False):
         """Rewrite the bound symbol for frozen-BN fine-tuning (Module
         overrides; see fit(frozen_bn=))."""
@@ -216,17 +209,16 @@ class BaseModule:
 
             _ckpt_resume.fast_forward(train_data, epoch, skip)
         k = getattr(self, "_steps_per_dispatch", 1)
-        if k > 1 or self._comm_armed():
+        if k > 1:
             if monitor is None and self._block_ready():
                 return self._run_epoch_block(train_data, epoch, eval_metric,
                                              batch_end_callback, k,
                                              skip=skip)
-            if k > 1:
-                self.logger.warning(
-                    "steps_per_dispatch=%d requested but the fused K-step "
-                    "block path is unavailable (non-fused optimizer, "
-                    "kvstore-side update, inputs_need_grad, or a monitor is "
-                    "installed); falling back to one dispatch per step", k)
+            self.logger.warning(
+                "steps_per_dispatch=%d requested but the fused K-step "
+                "block path is unavailable (non-fused optimizer, "
+                "kvstore-side update, inputs_need_grad, or a monitor is "
+                "installed); falling back to one dispatch per step", k)
         from .. import profiler, telemetry
 
         tel = telemetry.enabled()
@@ -263,8 +255,8 @@ class BaseModule:
             initializer=Uniform(0.01), arg_params=None, aux_params=None,
             allow_missing=False, force_rebind=False, force_init=False,
             begin_epoch=0, num_epoch=None, validation_metric=None, monitor=None,
-            steps_per_dispatch=None, frozen_bn=None, resume_from=None,
-            checkpoint_dir=None, checkpoint_every_steps=None):
+            steps_per_dispatch=None, frozen_bn=False, resume_from=None,
+            checkpoint_dir=None, checkpoint_every_steps=0):
         """Full training loop (parity: base_module.py fit:375-530).
 
         `steps_per_dispatch` (default: ``MXTPU_STEPS_PER_DISPATCH``) sets
@@ -274,7 +266,7 @@ class BaseModule:
         (io.DeviceStagedIter) — see docs/perf.md.  K=1 keeps the classic
         one-dispatch-per-step loop.
 
-        `frozen_bn` (default: ``MXTPU_FROZEN_BN``) turns the run into a
+        `frozen_bn` turns the run into a
         frozen-BatchNorm fine-tune: every BatchNorm runs with
         ``use_global_stats`` (running stats carried bit-identical, never
         recomputed) and the BN gamma/beta parameters are excluded from
@@ -284,8 +276,8 @@ class BaseModule:
         normalizes with whatever statistics it is given.  See
         docs/perf.md "MFU sinks" (+17.9% measured on ResNet-50).
 
-        `checkpoint_dir`/`checkpoint_every_steps` (defaults:
-        ``MXTPU_CKPT_DIR``/``MXTPU_CKPT_EVERY_STEPS``) arm async
+        `checkpoint_dir` (default: ``MXTPU_CKPT_DIR``) with
+        `checkpoint_every_steps` > 0 arms async
         distributed checkpoints: every rank writes write-then-rename
         shard files overlapped with the next dispatches; rank 0 commits
         the mxtpu-ckpt-v1 manifest.  `resume_from` (default:
@@ -304,10 +296,6 @@ class BaseModule:
 
             steps_per_dispatch = config.get("MXTPU_STEPS_PER_DISPATCH")
         self._steps_per_dispatch = max(1, int(steps_per_dispatch))
-        if frozen_bn is None:
-            from .. import config
-
-            frozen_bn = bool(config.get("MXTPU_FROZEN_BN"))
         if frozen_bn:
             self._apply_frozen_bn(force_rebind)
         else:

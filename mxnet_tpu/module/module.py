@@ -424,14 +424,6 @@ class Module(BaseModule):
                 and getattr(self._exec_group.execs[0], "_fused_updater",
                             None) is not None)
 
-    def _comm_armed(self):
-        """Explicit bucketed hierarchical gradient collectives armed on
-        the bound executor (executor._comm_mode: multi-process mesh or
-        MXTPU_COMM_BUCKETED=1)."""
-        return (self.binded and self._exec_group is not None
-                and bool(self._exec_group.execs)
-                and self._exec_group.execs[0]._comm_mode() is not None)
-
     def _run_epoch_block(self, train_data, epoch, eval_metric,
                          batch_end_callback, k, skip=0):
         """Blocked epoch body: K steps per dispatch, inputs double-

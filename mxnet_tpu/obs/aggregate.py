@@ -9,7 +9,7 @@ transport the PS scheduler's heartbeat/dead-node machinery rides):
   * rank 0 runs an :class:`Aggregator` listening on ``MXTPU_OBS_PORT``
     (``tools/launch.py --local-spmd --obs`` exports a free one);
   * every rank runs a :class:`Reporter` thread that ships a small
-    snapshot — steps, mean/p50 step seconds, comm GB/s, flight-
+    snapshot — steps, mean/p50 step seconds, flight-
     recorder progress counters — every ``MXTPU_OBS_INTERVAL_SECONDS``;
   * the aggregator folds the latest per-rank snapshots into one
     cluster-level JSONL record (``MXTPU_OBS_CLUSTER_FILE``) carrying
@@ -132,8 +132,6 @@ def build_snapshot(rank=None):
         "step_count": count,
         "step_mean_s": (step_h.get("sum", 0.0) / count) if count else None,
         "step_p50_s": _hist_quantile(step_h, 0.5),
-        "comm_gbps": gauges.get("comm.gbps"),
-        "comm_bytes": counters.get("comm.bytes_reduced", 0),
         "mfu": gauges.get("module.mfu"),
         "recorder_progress": recorder.progress(),
         "clock_offset_s": _STATE["offset_s"],
@@ -206,7 +204,7 @@ class Aggregator:
 
     def cluster_record(self):
         """Fold the latest per-rank snapshots into ONE cluster record:
-        per-rank step/step-time/comm columns + the skew attribution."""
+        per-rank step/step-time columns + the skew attribution."""
         now = time.monotonic()
         with self._lock:
             latest = {r: (t, dict(snap)) for r, (t, snap)
@@ -218,7 +216,6 @@ class Aggregator:
                 "dispatches": snap.get("dispatches"),
                 "step_mean_s": snap.get("step_mean_s"),
                 "step_p50_s": snap.get("step_p50_s"),
-                "comm_gbps": snap.get("comm_gbps"),
                 "mfu": snap.get("mfu"),
                 "clock_offset_s": snap.get("clock_offset_s"),
                 "age_s": now - t,

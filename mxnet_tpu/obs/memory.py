@@ -129,12 +129,12 @@ _CENSUS_LOCK = threading.RLock()
 _LIVE = {}          # tag -> live bytes
 _LIVE_TOTAL = 0
 _PEAK = {"bytes": 0, "top": [], "wall_time": None}
-_CENSUS_ON = os.environ.get("MXTPU_MEM_CENSUS", "1") not in ("0", "")
+_CENSUS_ON = True
 
 
 def set_census(flag):
-    """Arm/disarm the census in-process (tests; ``MXTPU_MEM_CENSUS=0``
-    sets the import-time default).  Returns the previous state."""
+    """Arm/disarm the census in-process (tests; it starts armed).
+    Returns the previous state."""
     global _CENSUS_ON
     prev = _CENSUS_ON
     _CENSUS_ON = bool(flag)

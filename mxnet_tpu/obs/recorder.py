@@ -17,7 +17,7 @@ and whether its peers ever entered it.
 Cost discipline matches telemetry: every helper early-returns when
 disabled, and HOT call sites must guard the call itself behind
 :func:`enabled` so no formatting/timestamping happens when the
-recorder is off (``MXTPU_OBS_RECORDER=0``) — mxlint E004 enforces the
+recorder is off (:func:`set_enabled`) — mxlint E004 enforces the
 guard for ``recorder.record`` exactly as it does for
 ``telemetry.inc``.
 
@@ -43,17 +43,8 @@ __all__ = ["enabled", "set_enabled", "record", "events", "open_spans",
            "progress", "compiling", "last_compile_exit", "reset",
            "ring_slots", "own_rank", "set_schedule_hook"]
 
-_ENABLED = _os.environ.get("MXTPU_OBS_RECORDER", "1") not in ("0", "")
-_DEFAULT_SLOTS = 512
-
-
-def _env_slots():
-    try:
-        n = int(_os.environ.get("MXTPU_OBS_RING_SLOTS", "") or _DEFAULT_SLOTS)
-    except ValueError:
-        n = _DEFAULT_SLOTS
-    return max(8, n)
-
+_ENABLED = True
+_RING_SLOTS = 512
 
 _LOCK = locks.lock("obs.recorder")
 # collective-schedule hook (parallel/schedule_check.py installs it when
@@ -62,7 +53,7 @@ _LOCK = locks.lock("obs.recorder")
 # verifier folds the same stream the ring retains.  None when the
 # check is off — one predicate per record(), nothing else.
 _SCHED_HOOK = None
-_RING = [None] * _env_slots()  # fixed slots, preallocated — no growth
+_RING = [None] * _RING_SLOTS  # fixed slots, preallocated — no growth
 _NEXT = 0  # total events ever recorded; slot = _NEXT % len(_RING)
 _KIND_SEQ = {}  # kind -> last auto-assigned sequence number
 _OPEN = {}  # (kind, seq) -> (t_enter, detail, nbytes)

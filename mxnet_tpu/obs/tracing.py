@@ -35,7 +35,7 @@ behind :func:`enabled` (mxlint E004 covers ``tracing.record`` /
 
 Two sinks:
 
-  * a bounded in-process span buffer (``MXTPU_TRACE_BUFFER`` slots;
+  * a bounded in-process span buffer (``_CAP`` slots;
     :func:`spans` / :func:`reset`) — what tests and in-process
     consumers read;
   * the profiler chrome trace: while profiling is running every span
@@ -66,17 +66,8 @@ def _env_fraction():
     return min(1.0, max(0.0, f))
 
 
-def _env_cap():
-    raw = _os.environ.get("MXTPU_TRACE_BUFFER", "")
-    try:
-        n = int(raw) if raw else 4096
-    except ValueError:
-        n = 4096
-    return max(64, n)
-
-
 _SAMPLE = _env_fraction()
-_CAP = _env_cap()
+_CAP = 4096
 _LOCK = locks.lock("obs.tracing")
 _SPANS = []          # bounded: the oldest _CAP spans are kept, then drop
 _DROPPED = 0

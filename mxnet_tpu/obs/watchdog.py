@@ -221,7 +221,6 @@ class StallWatchdog(threading.Thread):
                 "module.steps": telemetry.counter_value("module.steps"),
                 "executor.train_dispatches":
                     telemetry.counter_value("executor.train_dispatches"),
-                "comm.dispatches": telemetry.counter_value("comm.dispatches"),
             },
         }
         os.makedirs(self.artifact_dir, exist_ok=True)

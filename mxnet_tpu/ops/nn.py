@@ -647,26 +647,9 @@ def batch_norm(
         # var costs a second full HBM sweep — measured ~25 ms/step on
         # ResNet-50 batch 512).  Cancellation is benign post-conv (mean~0)
         # and both accumulators are fp32.
-        stats_src = data
-        from ..config import get as _cfg_get
-
-        # ghost-batch statistics (opt-in, NOT default: changes training
-        # semantics the way ghost BN does): compute stats on the leading
-        # `sample` rows only, cutting the stats-pass HBM reads by
-        # batch/sample.  Gradients still flow through the sampled stats.
-        sample = int(_cfg_get("MXNET_BN_STATS_SAMPLE") or 0)
-        if sample > 0 and ax != 0 and data.shape[0] > sample:
-            stats_src = lax.slice_in_dim(data, 0, sample, axis=0)
-        mean = mean_sq = None
-        if ax == data.ndim - 1:
-            from .pallas_kernels import bn_stats, bn_stats_supported
-            if _cfg_get("MXNET_TPU_PALLAS_BN") and \
-                    bn_stats_supported(stats_src.shape, ax):
-                mean, mean_sq = bn_stats(stats_src, ax)
-        if mean is None:
-            mean = jnp.mean(stats_src, axis=reduce_axes, dtype=jnp.float32)
-            mean_sq = jnp.mean(jnp.square(stats_src), axis=reduce_axes,
-                               dtype=jnp.float32)
+        mean = jnp.mean(data, axis=reduce_axes, dtype=jnp.float32)
+        mean_sq = jnp.mean(jnp.square(data), axis=reduce_axes,
+                           dtype=jnp.float32)
         var = jnp.maximum(mean_sq - jnp.square(mean), 0.0)
         new_mm = moving_mean * momentum + lax.stop_gradient(mean).astype(moving_mean.dtype) * (1 - momentum)
         new_mv = moving_var * momentum + lax.stop_gradient(var).astype(moving_var.dtype) * (1 - momentum)

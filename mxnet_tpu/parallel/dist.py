@@ -69,7 +69,11 @@ BIGARRAY_BOUND = int(os.environ.get("MXNET_KVSTORE_BIGARRAY_BOUND", 1 << 20))
 HEARTBEAT_INTERVAL = float(os.environ.get("MXNET_KVSTORE_HEARTBEAT_INTERVAL", "2"))
 DEAD_NODE_TIMEOUT = float(os.environ.get("MXNET_KVSTORE_DEAD_TIMEOUT", "60"))
 BARRIER_TIMEOUT = float(os.environ.get("MXNET_KVSTORE_BARRIER_TIMEOUT", "300"))
-PULL_TIMEOUT = float(os.environ.get("MXNET_KVSTORE_PULL_TIMEOUT", "60"))
+# version-gated pull wait limit: past it a server replies with an error
+# instead of serving a stale value
+PULL_TIMEOUT = 60.0
+# scheduler wait limit for every role to register at start-up
+REGISTER_TIMEOUT = 600.0
 
 
 # ----------------------------------------------------------------------
@@ -245,9 +249,7 @@ class Scheduler:
         pending_recovery = []
         # a role that dies BEFORE registering would otherwise hang this
         # loop (and any launcher waiting on the scheduler) forever
-        reg_timeout = float(os.environ.get(
-            "MXNET_KVSTORE_REGISTER_TIMEOUT", "600"))
-        deadline = time.monotonic() + reg_timeout
+        deadline = time.monotonic() + REGISTER_TIMEOUT
         self.sock.settimeout(1.0)
         while len(conns) < self.num_workers + self.num_servers:
             try:
@@ -256,9 +258,9 @@ class Scheduler:
                 if time.monotonic() > deadline:
                     raise MXNetError(
                         "scheduler: only %d/%d nodes registered within "
-                        "%.0fs (MXNET_KVSTORE_REGISTER_TIMEOUT)"
+                        "%.0fs (dist.REGISTER_TIMEOUT)"
                         % (len(conns), self.num_workers + self.num_servers,
-                           reg_timeout))
+                           REGISTER_TIMEOUT))
                 continue
             cmd, meta, _ = _recv_frame(conn)
             assert cmd == _REGISTER
@@ -908,7 +910,10 @@ def run_scheduler():
         print("scheduler: %s" % e, file=_sys.stderr)
         return 1
     with sched._lock:
-        unclean = sched._book.unclean()
+        # a server never FINALIZEs: it is stopped once the workers have, and
+        # its socket may close before this thread looks (1 run in 8, PR 43)
+        unclean = {n for n in sched._book.unclean()
+                   if n.startswith("worker:")}
     return 1 if unclean else 0
 
 

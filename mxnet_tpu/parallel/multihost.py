@@ -105,9 +105,9 @@ def global_mesh(axes=None, hierarchical=False):
 
     ``hierarchical=True`` (with axes=None) names the topology instead of
     flattening it: {'data_dcn': process_count, 'data_ici': local_devices}
-    — the same device order, but collectives keyed off the axis split
-    (collectives.hierarchical_psum) reduce intra-host ICI first and move
-    ONE pre-reduced value per host across DCN.  Degenerates to a flat
+    — the same device order, with the two levels named so a sharding
+    (or a shard_map'd collective) can tell the intra-host ICI axis from
+    the cross-host DCN one.  Degenerates to a flat
     {'data': -1} mesh when only one of the two levels has size > 1."""
     from .mesh import make_mesh
 

@@ -2,18 +2,18 @@
 
 A multi-process SPMD job deadlocks the moment two ranks disagree about
 the SEQUENCE of collectives: rank 0 enters all-reduce #7 while rank 1
-— having taken a divergent bucket path, skipped a batch, or raced a
-rebind — is entering a different #7 (or none at all).  The stall
-watchdog (obs/watchdog.py) diagnoses that hang POST-MORTEM, after
-``MXTPU_OBS_STALL_SECONDS`` of silence; this module catches the
-divergence the moment it becomes observable, usually BEFORE the hang:
+— having skipped a batch or raced a rebind — is entering a different
+#7 (or none at all).  The stall watchdog (obs/watchdog.py) diagnoses
+that hang POST-MORTEM, after ``MXTPU_OBS_STALL_SECONDS`` of silence;
+this module catches the divergence the moment it becomes observable,
+usually BEFORE the hang:
 
   * every rank folds its flight-recorder stream of collective-ish
-    enter events — ``(kind, seq, nbytes, detail)``; detail carries the
-    bucket-plan fingerprint on the fused-dispatch path — into a
-    rolling structural hash (:class:`ScheduleLog`), keeping a bounded
-    ring of recent per-event prefix hashes so any common prefix length
-    within the window is comparable;
+    enter events — ``(kind, seq, nbytes, detail)``; detail carries K
+    on the fused-dispatch path — into a rolling structural hash
+    (:class:`ScheduleLog`), keeping a bounded ring of recent per-event
+    prefix hashes so any common prefix length within the window is
+    comparable;
   * the per-rank digest rides the EXISTING obs snapshot
     (obs/aggregate.py Reporter -> rank-0 Aggregator, every
     ``MXTPU_OBS_INTERVAL_SECONDS``) — no new control plane;
@@ -383,8 +383,8 @@ def maybe_start_from_env():
 
         warnings.warn(
             "MXTPU_COLLECTIVE_CHECK=1 requires the flight recorder "
-            "(MXTPU_OBS_RECORDER is 0/empty): the schedule verifier "
-            "will see no events and detect nothing")
+            "(recorder.set_enabled(False) turned it off): the schedule "
+            "verifier will see no events and detect nothing")
         return None
     _sync_recorder_hook()
     if not os.environ.get("MXTPU_OBS_PORT", ""):

@@ -108,38 +108,36 @@ def _channel_amax(act, spec):
     return a.max(axis=other) if other else a
 
 
-def calibrate(symbol, arg_params, aux_params, batches, ctx=None, mode=None,
-              percentile=None, hist_bins=None, max_batches=None):
+def calibrate(symbol, arg_params, aux_params, batches, ctx=None,
+              mode="minmax", percentile=99.99, hist_bins=2048,
+              max_batches=None):
     """Run `batches` through `symbol` bound with the given params and
     return a :class:`CalibTable` of per-channel activation ranges for
     every eligible conv/FC node.
 
     `batches` — iterable of ``{input_name: batched ndarray}`` (the
     representative set; a handful of real batches is the point, random
-    data calibrates random ranges).  `mode` — ``minmax`` (default,
-    ``MXTPU_QUANT_CALIB_MODE``) keeps the observed per-channel max;
+    data calibrates random ranges).  `mode` — ``minmax`` (default)
+    keeps the observed per-channel max;
     ``percentile`` additionally caps every channel at the
-    ``MXTPU_QUANT_PERCENTILE``-th percentile of the node's |x|
-    distribution (``MXTPU_QUANT_HIST_BINS``-bucket value-range
+    `percentile`-th percentile of the node's |x|
+    distribution (a `hist_bins`-bucket value-range
     histogram), recording the clipped mass as ``clip_pct``.
     Calibration runs in the executor's default f32; the bf16 serving
     executors see ranges within bf16 rounding of these."""
     from .. import ndarray as _nd
     from .. import telemetry
-    from ..config import get as _cfg_get
     from ..predict import Predictor
 
-    mode = str(mode if mode is not None else _cfg_get("MXTPU_QUANT_CALIB_MODE"))
+    mode = str(mode)
     if mode not in ("minmax", "percentile"):
         raise MXNetError("calibrate: mode must be 'minmax' or "
                          "'percentile', got %r" % mode)
-    pct = float(percentile if percentile is not None
-                else _cfg_get("MXTPU_QUANT_PERCENTILE"))
+    pct = float(percentile)
     if not 0.0 < pct <= 100.0:
         raise MXNetError("calibrate: percentile must be in (0, 100], "
                          "got %r" % pct)
-    bins = int(hist_bins if hist_bins is not None
-               else _cfg_get("MXTPU_QUANT_HIST_BINS"))
+    bins = int(hist_bins)
     nodes = eligible_nodes(symbol)
     if not nodes:
         raise MXNetError(

@@ -77,7 +77,7 @@ def eligible_nodes(symbol):
     return out
 
 
-def quantize_symbol(symbol, calib_table, skip_names=(), skip_first_last=None):
+def quantize_symbol(symbol, calib_table, skip_names=(), skip_first_last=True):
     """Rewrite `symbol`'s calibrated conv/FC nodes onto the int8 kernels.
 
     Returns ``(qsym, scale_args)``: a NEW symbol (the input is never
@@ -89,16 +89,13 @@ def quantize_symbol(symbol, calib_table, skip_names=(), skip_first_last=None):
     plain ``{node_name: amax_vector}`` mapping).  A node is LEFT IN
     FLOAT when it is ineligible (grouped/non-2-D conv), named in
     `skip_names`, excluded by the first/last policy
-    (``MXTPU_QUANT_SKIP_FIRST_LAST``, default on — the input stem and
+    (`skip_first_last`, default on — the input stem and
     the classifier head are the classic accuracy-critical layers), or
     missing from the table (a calibration coverage hole: it is counted,
     not fatal).  Quantizing NOTHING is fatal — an "int8" symbol with
     zero int8 nodes would silently serve float."""
     from .. import telemetry
-    from ..config import get as _cfg_get
 
-    if skip_first_last is None:
-        skip_first_last = bool(_cfg_get("MXTPU_QUANT_SKIP_FIRST_LAST"))
     qsym = load_json(symbol.tojson())
     arg_names = set(qsym.list_arguments())
     eligible = eligible_nodes(qsym)

@@ -11,7 +11,7 @@ surface — ``submit(tenant, inputs) -> Future``:
   replay to healthy peers from their submit-time snapshots, so no
   caller future is ever lost (router.py);
 * traffic-adaptive bucket ladders — the fill-ratio telemetry shipped
-  in health snapshots re-derives each replica's ``MXTPU_SERVE_BUCKETS``
+  in health snapshots re-derives each replica's bucket
   ladder and pushes a re-warm when the offered shape mix drifts.
 
 Fleets launch with ``tools/launch.py --serve-replicas N``; the wire

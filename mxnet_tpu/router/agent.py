@@ -53,7 +53,7 @@ def _serving_extract(tenants=()):
     if not telemetry.enabled():
         return {}
     # point reads, not snapshot(): the probe answers every
-    # MXTPU_ROUTER_POLL_MS per connected router, and a full-registry
+    # Router(poll_ms=) per connected router, and a full-registry
     # deep copy (every histogram ladder) on that cadence is real work
     lat_count, lat_sum = telemetry.histogram_moments(
         "serving.request_seconds")
@@ -137,8 +137,12 @@ class ReplicaAgent:
         # **add_generative_tenant kwargs}; re-registered on every server
         # (re)construction (the rebucket swap included)
         self._generative = {k: dict(v) for k, v in (generative or {}).items()}
-        self._server_kw = dict(max_batch=max_batch, timeout_ms=timeout_ms,
-                               max_queue=max_queue, wait_ms=wait_ms)
+        # None = ModelServer's own default
+        self._server_kw = {
+            k: v for k, v in dict(
+                max_batch=max_batch, timeout_ms=timeout_ms,
+                max_queue=max_queue, wait_ms=wait_ms).items()
+            if v is not None}
         self.replica_id = (int(replica_id) if replica_id is not None
                            else config.get("MXTPU_REPLICA_ID"))
         self.name = "replica:%d" % self.replica_id

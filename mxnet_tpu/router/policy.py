@@ -5,7 +5,7 @@ without a fleet: :func:`replica_usable` is the health gate (which
 replicas may take traffic NOW), :func:`pick_replica` the health-gated
 least-loaded dispatch, and :func:`derive_ladder` the traffic-adaptive
 bucket math that turns the fill-ratio telemetry shipped in health
-snapshots into a better ``MXTPU_SERVE_BUCKETS`` ladder.
+snapshots into a better bucket ladder.
 """
 from __future__ import annotations
 

@@ -8,7 +8,7 @@ client contract — ``submit(tenant, inputs) -> Future`` resolving to
 
 * **health-gated least-loaded dispatch** — a poll thread probes every
   replica's ``health()`` (queue depth / admission headroom / deadline
-  pressure) on the ``MXTPU_ROUTER_POLL_MS`` cadence; ``submit()``
+  pressure) on the ``poll_ms`` cadence; ``submit()``
   routes whole requests to the least-loaded replica that can take
   traffic (policy.py), never sharding one request across replicas —
   each replica runs a complete program (the pjit multi-device
@@ -25,7 +25,7 @@ client contract — ``submit(tenant, inputs) -> Future`` resolving to
   replay implies is safe.
 * **traffic-adaptive bucket ladders** — health replies carry the
   cumulative fill accounting (``serving.batch_slots_used`` /
-  ``_padded`` / ``dispatches``); every ``MXTPU_ROUTER_ADAPT_WINDOW_S``
+  ``_padded`` / ``dispatches``); every ``adapt_window_s``
   the router derives the mean fill per replica and, when the offered
   mix pads away more than a quarter of each bucket
   (policy.derive_ladder), pushes a WARMUP carrying a better ladder.
@@ -54,7 +54,7 @@ __all__ = ["Router", "ReplicaDead", "RouterClosed", "NoHealthyReplica"]
 
 class ReplicaDead(MXNetError):
     """The replica holding this request died and the re-dispatch budget
-    (MXTPU_ROUTER_REDISPATCH) ran out before a healthy peer answered."""
+    (Router(redispatch_cap=)) ran out before a healthy peer answered."""
 
 
 class RouterClosed(MXNetError):
@@ -161,9 +161,10 @@ class Router:
     until every replica answered its first health probe — a router
     that would route blind instead raises within `connect_timeout`."""
 
-    def __init__(self, replicas=None, poll_ms=None, redispatch_cap=None,
-                 adapt_window_s=None, connect_timeout=60.0):
+    def __init__(self, replicas=None, poll_ms=200.0, redispatch_cap=2,
+                 adapt_window_s=10.0, connect_timeout=60.0):
         from .. import config
+        from ..serving.server import DEFAULT_TIMEOUT_MS
 
         if replicas is None:
             spec = config.get("MXTPU_ROUTER_REPLICAS")
@@ -174,20 +175,14 @@ class Router:
                 "replicas=['host:port', ...] or export "
                 "MXTPU_ROUTER_REPLICAS — tools/launch.py "
                 "--serve-replicas prints the list)")
-        self._poll_s = (float(poll_ms) if poll_ms is not None
-                        else config.get("MXTPU_ROUTER_POLL_MS")) / 1e3
-        self._redispatch_cap = int(
-            redispatch_cap if redispatch_cap is not None
-            else config.get("MXTPU_ROUTER_REDISPATCH"))
-        self._adapt_window_s = float(
-            adapt_window_s if adapt_window_s is not None
-            else config.get("MXTPU_ROUTER_ADAPT_WINDOW_S"))
+        self._poll_s = float(poll_ms) / 1e3
+        self._redispatch_cap = int(redispatch_cap)
+        self._adapt_window_s = float(adapt_window_s)
         # resolved HERE, not left as None on the wire: a None deadline
         # would let each replay hop apply a fresh replica-side default,
         # multiplying the caller's effective deadline by the redispatch
         # count — the remaining-budget math needs a concrete number
-        self._default_timeout_ms = float(
-            config.get("MXTPU_SERVE_TIMEOUT_MS"))
+        self._default_timeout_ms = DEFAULT_TIMEOUT_MS
         # a replica is stale-dead after 5 silent poll intervals (floored
         # so a very tight test cadence doesn't flap on scheduler jitter)
         self._dead_after = max(5 * self._poll_s, 2.0)
@@ -960,7 +955,7 @@ class Router:
                 if flight.redispatches >= self._redispatch_cap:
                     flight.fail(ReplicaDead(
                         "request to tenant %r: replica %s died (%s) and "
-                        "the re-dispatch budget (MXTPU_ROUTER_REDISPATCH"
+                        "the re-dispatch budget (redispatch_cap"
                         "=%d) is spent" % (flight.tenant, rep.name, exc,
                                            self._redispatch_cap)))
                     if telemetry.enabled():

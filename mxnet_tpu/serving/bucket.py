@@ -21,10 +21,10 @@ __all__ = ["bucket_ladder", "choose_bucket", "pad_rows"]
 
 def bucket_ladder(max_batch, spec=""):
     """The sorted batch-bucket ladder: `spec` is the comma-separated
-    ``MXTPU_SERVE_BUCKETS`` override; empty means powers of two up to
-    (and always including) `max_batch`.  Buckets above `max_batch` are
-    rejected rather than clamped — a silent clamp would hide a config
-    contradiction."""
+    ladder ``ModelServer(buckets=)`` was given; empty means powers of
+    two up to (and always including) `max_batch`.  Buckets above
+    `max_batch` are rejected rather than clamped — a silent clamp would
+    hide a config contradiction."""
     max_batch = int(max_batch)
     if max_batch < 1:
         raise MXNetError("max_batch must be >= 1, got %d" % max_batch)
@@ -32,12 +32,12 @@ def bucket_ladder(max_batch, spec=""):
         try:
             buckets = sorted({int(tok) for tok in spec.split(",") if tok.strip()})
         except ValueError:
-            raise MXNetError("MXTPU_SERVE_BUCKETS=%r is not a comma-"
+            raise MXNetError("bucket ladder %r is not a comma-"
                              "separated int list" % spec)
         if not buckets or buckets[0] < 1:
             raise MXNetError("bucket ladder %r must be positive ints" % spec)
         if buckets[-1] > max_batch:
-            raise MXNetError("bucket %d exceeds MXTPU_SERVE_MAX_BATCH=%d"
+            raise MXNetError("bucket %d exceeds max_batch=%d"
                              % (buckets[-1], max_batch))
         if buckets[-1] != max_batch:
             buckets.append(max_batch)

@@ -276,28 +276,24 @@ class GenerativeSession:
     return ``[logits, entries..., last_token, token (B,),
     extra_outputs()...]``.
     `params` maps parameter name -> array (a training checkpoint's
-    arg+aux dicts merged).  Knob defaults come from the config
-    registry: ``MXTPU_SERVE_MAX_SESSIONS`` / ``_MAX_DECODE_TOKENS`` /
-    ``_KV_MAX_LEN`` (clamped to the model's positional table)."""
+    arg+aux dicts merged).  `max_sessions` is the number of cache
+    slots (the cap on concurrent sessions), `max_len` a slot's ring
+    length in tokens (clamped to the model's positional table),
+    `max_decode_tokens` the budget of a request that names none."""
 
     is_generative = True
 
-    def __init__(self, name, model, params, ctx=None, max_sessions=None,
-                 max_len=None, max_decode_tokens=None, eos_id=None,
+    def __init__(self, name, model, params, ctx=None, max_sessions=8,
+                 max_len=256, max_decode_tokens=64, eos_id=None,
                  seq_buckets=None):
-        from .. import config, telemetry
+        from .. import telemetry
         from ..predict import Predictor
 
         self.name = name
         self._model = model
-        self._slots = int(max_sessions if max_sessions is not None
-                          else config.get("MXTPU_SERVE_MAX_SESSIONS"))
-        ring_len = int(max_len if max_len is not None
-                       else config.get("MXTPU_SERVE_KV_MAX_LEN"))
-        self._max_len = min(ring_len, int(model.max_len))
-        self._budget_default = int(
-            max_decode_tokens if max_decode_tokens is not None
-            else config.get("MXTPU_SERVE_MAX_DECODE_TOKENS"))
+        self._slots = int(max_sessions)
+        self._max_len = min(int(max_len), int(model.max_len))
+        self._budget_default = int(max_decode_tokens)
         self._eos_default = None if eos_id is None else int(eos_id)
         # the model owns what is cached and in which shape; the +1 is the
         # scratch slot padded decode rows point at
@@ -440,7 +436,7 @@ class GenerativeSession:
             raise MXNetError(
                 "generate request for tenant %r needs %d prompt + %d "
                 "new tokens > the %d-token KV ring "
-                "(MXTPU_SERVE_KV_MAX_LEN, clamped to the model's "
+                "(the tenant's max_len, clamped to the model's "
                 "max_len) — shorten the prompt or the budget"
                 % (self.name, n, max_new_tokens, self._max_len))
 

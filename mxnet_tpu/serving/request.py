@@ -343,7 +343,7 @@ class RequestQueue:
                 telemetry.inc("serving.timeouts.%s" % tenant)
             req.fail(RequestTimeout(
                 "request to tenant %r spent %.1f ms queued, past its "
-                "%.1f ms deadline (MXTPU_SERVE_TIMEOUT_MS or the "
+                "%.1f ms deadline (ModelServer(timeout_ms=) or the "
                 "submit() override)" % (
                     tenant, (now - req.arrival) * 1e3,
                     (req.deadline - req.arrival) * 1e3)))

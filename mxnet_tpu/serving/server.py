@@ -36,6 +36,14 @@ from .. import locks
 
 __all__ = ["ModelServer"]
 
+# default per-request deadline (queue time); the router resolves a
+# request that names none to the same number before it goes on the wire
+DEFAULT_TIMEOUT_MS = 5000.0
+
+# token-level continuous-batching window: with decode sessions active the
+# batcher waits at most this long for admissions between two steps
+DECODE_WINDOW_MS = 2.0
+
 
 def _memory_section(tenants):
     """health()'s ``memory`` key — defensive: a census problem must
@@ -51,25 +59,25 @@ def _memory_section(tenants):
 class ModelServer:
     """Continuous-batching server over N Predictor-backed tenants.
 
-    Knob defaults come from the config registry (docs/how_to/env_var.md):
-    ``MXTPU_SERVE_MAX_BATCH`` / ``_BUCKETS`` / ``_TIMEOUT_MS`` /
-    ``_MAX_QUEUE`` / ``_WAIT_MS``; constructor arguments override."""
+    `max_batch` is the top of the bucket ladder, `buckets` the ladder
+    itself (a list, or a comma-separated string; None = powers of two
+    up to `max_batch`), `timeout_ms` the default per-request deadline.
+    `max_queue` / `wait_ms` default to ``MXTPU_SERVE_MAX_QUEUE`` /
+    ``MXTPU_SERVE_WAIT_MS`` (docs/how_to/env_var.md)."""
 
-    def __init__(self, tenants=None, max_batch=None, buckets=None,
-                 timeout_ms=None, max_queue=None, wait_ms=None):
+    def __init__(self, tenants=None, max_batch=32, buckets=None,
+                 timeout_ms=DEFAULT_TIMEOUT_MS, max_queue=None, wait_ms=None):
         from .. import config
 
-        self._max_batch = int(max_batch if max_batch is not None
-                              else config.get("MXTPU_SERVE_MAX_BATCH"))
-        spec = buckets if buckets is not None else config.get("MXTPU_SERVE_BUCKETS")
+        self._max_batch = int(max_batch)
+        spec = buckets or ""
         if isinstance(spec, (list, tuple)):
             spec = ",".join(str(int(b)) for b in spec)
         self.ladder = bucket_ladder(self._max_batch, spec)
-        self._timeout_s = float(timeout_ms if timeout_ms is not None
-                                else config.get("MXTPU_SERVE_TIMEOUT_MS")) / 1e3
+        self._timeout_s = float(timeout_ms) / 1e3
         self._wait_s = float(wait_ms if wait_ms is not None
                              else config.get("MXTPU_SERVE_WAIT_MS")) / 1e3
-        self._window_s = float(config.get("MXTPU_SERVE_DECODE_WINDOW_MS")) / 1e3
+        self._window_s = DECODE_WINDOW_MS / 1e3
         self._queue = RequestQueue(max_queue if max_queue is not None
                                    else config.get("MXTPU_SERVE_MAX_QUEUE"))
         self._slo = {}  # tenant -> (budget_s, target) declared at add_tenant
@@ -160,8 +168,8 @@ class ModelServer:
 
     def add_generative_tenant(self, name, model, params, ctx=None,
                               slo_ms=None, slo_target=0.999,
-                              max_sessions=None, max_len=None,
-                              max_decode_tokens=None, eos_id=None,
+                              max_sessions=8, max_len=256,
+                              max_decode_tokens=64, eos_id=None,
                               seq_buckets=None):
         """Register one autoregressive LM for token generation
         (docs/serving.md "Decode sessions & continuous batching").
@@ -171,9 +179,11 @@ class ModelServer:
         parameters by plain name.  Requests go through
         :meth:`submit_generate` — plain :meth:`submit` is rejected for
         generative tenants.  The tenant owns ``max_sessions`` KV-cache
-        slots (``MXTPU_SERVE_MAX_SESSIONS``); classic tenants on the
-        same server interleave with its decode steps under the usual
-        fairness policy."""
+        slots of ``max_len`` tokens (clamped to the model's positional
+        table) and a request that names no budget gets
+        ``max_decode_tokens``; classic tenants on the same server
+        interleave with its decode steps under the usual fairness
+        policy."""
         slo = None
         if slo_ms is not None:
             target = float(slo_target)
@@ -192,7 +202,6 @@ class ModelServer:
         # census has booked of them is in the bytes `admit` adds to the
         # prediction, so it is not predicted a second time — anything else
         # is placed by each predictor for itself
-        from .. import config
         from ..ndarray import NDArray
         from ..obs import memory
 
@@ -200,13 +209,10 @@ class ModelServer:
         param_bytes = sum(memory.nbytes_of(v) for v in params.values())
         shared = all(isinstance(v, NDArray) and v.context == ctx
                      for v in params.values())
-        slots = int(max_sessions if max_sessions is not None
-                    else config.get("MXTPU_SERVE_MAX_SESSIONS"))
-        ring_len = int(max_len if max_len is not None
-                       else config.get("MXTPU_SERVE_KV_MAX_LEN"))
-        ring_len = min(ring_len, int(model.max_len))
-        cache_bytes = sum(entry.nbytes for entry in
-                          model.cache_spec(slots + 1, ring_len).values())
+        ring_len = min(int(max_len), int(model.max_len))
+        cache_bytes = sum(
+            entry.nbytes for entry in
+            model.cache_spec(int(max_sessions) + 1, ring_len).values())
         live = sum(v._mem_booked for v in params.values()) if shared else 0
         memory.admit("generative tenant %r" % name,
                      (1 if shared else 2) * param_bytes - live + cache_bytes,
@@ -237,7 +243,7 @@ class ModelServer:
         to a :class:`~.decode.GenerateResult` (generated token ids +
         finish reason).  `tokens` is the 1-D int prompt;
         `max_new_tokens` / `eos_id` override the tenant defaults
-        (``MXTPU_SERVE_MAX_DECODE_TOKENS`` / ``add_generative_tenant``).
+        (``add_generative_tenant``'s ``max_decode_tokens`` / ``eos_id``).
         `on_token` — optional callable streamed each sampled token id
         from the batcher thread (must be cheap and never block; the
         router agent uses it to push TOKEN frames).  The deadline

@@ -72,7 +72,7 @@ def run(mx, np, k, tag, ckpt_dir=None, resume_from=None, kill_after=0):
             initializer=mx.init.Xavier(), eval_metric="mse",
             steps_per_dispatch=k, batch_end_callback=on_batch,
             checkpoint_dir=ckpt_dir,
-            checkpoint_every_steps=1 if ckpt_dir else None,
+            checkpoint_every_steps=1 if ckpt_dir else 0,
             resume_from=resume_from)
 
 

@@ -3,11 +3,12 @@
 
 Each process joins the jax.distributed mesh (multihost.initialize reads
 the launcher env), builds the hierarchical global mesh, and runs the
-REAL training stack — Module.fit -> DeviceStagedIter -> K-step fused
-dispatch with bucketed hierarchical gradient collectives — on a shared
-deterministic problem.  It prints per-dispatch loss values and a final
-parameter digest; the test asserts every rank agrees and matches the
-single-process answer.
+REAL training stack — Module.fit, per step or (--steps-per-dispatch K)
+DeviceStagedIter -> K-step fused dispatch, the gradient all-reduce the
+one XLA's partitioner inserts — on a shared deterministic problem.  It
+records per-dispatch loss values and a final parameter digest — in a
+file of its own under --record-dir, else on stdout; the test asserts
+every rank agrees and matches the single-process answer.
 
 With --kvstore-check (launcher run with PS roles, -s > 0) it ALSO runs
 a dist_sync push/pull parity pin through the SAME processes: the
@@ -15,6 +16,7 @@ reference-style parameter-server control plane and the SPMD mesh ride
 one launcher invocation.
 """
 import argparse
+import json
 import os
 import sys
 
@@ -57,9 +59,9 @@ def build_lm_problem(mx, np):
 
 
 def run_fit_transformer(mx, np, mesh, steps_per_dispatch):
-    """The transformer flavor of run_fit: the SAME fused-dispatch +
-    hierarchical-collective training stack, driven by the attention
-    graph instead of the MLP (the SPMD pin for the transformer rows)."""
+    """The transformer flavor of run_fit: the SAME training stack,
+    driven by the attention graph instead of the MLP (the SPMD pin for
+    the transformer rows)."""
     from mxnet_tpu.ops.random_ops import HOST_RNG
 
     mx.random.seed(0)
@@ -140,6 +142,11 @@ def main():
     parser.add_argument("--no-fit", action="store_true",
                         help="skip the training run (fast control-plane-"
                              "only checks)")
+    parser.add_argument("--record-dir", default="",
+                        help="write this rank's fit record to "
+                             "<dir>/spmd_fit.r<rank>.json instead of "
+                             "stdout, which the ranks, the launcher and "
+                             "Gloo's own messages share")
     parser.add_argument("--profile", default="",
                         help="profile the fit and dump a chrome trace to "
                              "this path (auto-suffixed .r<rank> per "
@@ -165,19 +172,22 @@ def main():
     if not args.no_fit:
         fit = run_fit_transformer if args.transformer else run_fit
         losses, digest = fit(mx, np, mesh, args.steps_per_dispatch)
-        # the ranks share the launcher's stdout pipe and a record (a
-        # full parameter digest) is far larger than PIPE_BUF, so two
-        # concurrent writes interleave mid-record: take turns, one rank
-        # per barrier
-        record = ("SPMDFIT rank=%d axes=%s losses=%s digest=%s\n"
-                  % (rank, ",".join(mesh.axis_names),
-                     ";".join("%.6f" % l for l in losses),
-                     ";".join("%.6f" % v for v in digest)))
-        for turn in range(jax.process_count()):
-            if turn == rank:
-                sys.stdout.write(record)
-                sys.stdout.flush()
-            multihost.sync_global_devices("spmd_fit_record_%d" % turn)
+        record = {"rank": rank, "axes": list(mesh.axis_names),
+                  "losses": ["%.6f" % l for l in losses],
+                  "digest": ["%.6f" % v for v in digest]}
+        if args.record_dir:
+            # a file a rank, renamed into place when whole: a record (a
+            # full parameter digest) is far larger than PIPE_BUF, and on
+            # the shared stdout pipe the other rank's lines and Gloo's
+            # "[Gloo] Rank ... connected" messages land inside it
+            path = os.path.join(args.record_dir,
+                                "spmd_fit.r%d.json" % rank)
+            with open(path + ".tmp", "w") as f:
+                json.dump(record, f)
+            os.replace(path + ".tmp", path)
+        else:
+            sys.stdout.write("SPMDFIT %s\n" % json.dumps(record))
+            sys.stdout.flush()
     else:
         sys.stdout.write("SPMDMESH rank=%d axes=%s devices=%d\n"
                          % (rank, ",".join(mesh.axis_names),

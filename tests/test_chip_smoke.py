@@ -59,7 +59,6 @@ TINY = {
                                 positions="rotary", rotary_dim=8,
                                 qk_norm="head", out_gate=True,
                                 bias=False)]},
-    "kernel": {"shapes": [(4, 4, 4, 64), (8, 2, 2, 256)], "seed": 3},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
 }
@@ -82,13 +81,11 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert len(lines) == 1 and "not a TPU" in lines[0], proc.stderr
 
 
-def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
+def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     """Every phase function, tiny, on mx.cpu(2) — NOT the default device,
     so the phases' own placement checks (parameters, optimizer state and
     Predictor outputs on the context's device) pin per-context placement
     for fit, serve and decode at once."""
-    from mxnet_tpu.ops import pallas_kernels as pk
-
     telemetry.set_enabled(True)
     # an earlier test file of this worker may have fallen back on purpose
     fallbacks = telemetry.counter_value("mem.program_fallbacks")
@@ -129,9 +126,6 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device(monkeypatch):
         ["16", "8"], ["8"], ["8"], ["8"], ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
-    monkeypatch.setattr(pk, "_INTERPRET", True)
-    chip_smoke.run_phase("kernel", chip_smoke.phase_kernel, TINY["kernel"],
-                         ctx, clock, report)
     chip_smoke.run_phase("four_chips", chip_smoke.phase_four_chips,
                          TINY["four_chips"], [mx.cpu(i) for i in range(4)],
                          clock, report)
@@ -195,7 +189,7 @@ def test_the_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     # main() holds its PROCESS to no AOT fallback; an earlier test file of
     # this worker may have fallen back on purpose (tests/test_lazy.py)
     telemetry.reset()
-    for name in ("fence", "train", "serve", "generate", "kv_ring", "kernel"):
+    for name in ("fence", "train", "serve", "generate", "kv_ring"):
         monkeypatch.setattr(chip_smoke, "phase_" + name, lambda s, c: {})
     monkeypatch.setattr(chip_smoke, "phase_four_chips",
                         lambda s, c: {"predictor_device": "x"})
@@ -212,7 +206,7 @@ def test_the_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert lines[-2].startswith("[chip_smoke] report ")
     report = json.loads(lines[-2][len("[chip_smoke] report "):])
     assert set(report["phases"]) == {"fence", "train", "serve", "generate",
-                                     "kv_ring", "kernel", "four_chips"}
+                                     "kv_ring", "four_chips"}
 
 
 # ----------------------------------------------------------------------
@@ -344,15 +338,3 @@ def test_launcher_gives_each_child_one_chip_or_refuses(monkeypatch):
     Args.local_devices = 2  # forced host devices: a CPU job by definition
     assert launch._local_spmd_env(Args, Parser()) == {
         "MXTPU_LOCAL_DEVICES": "2", "JAX_PLATFORMS": "cpu"}
-
-
-def test_pallas_bn_switch_says_so_once_off_the_tpu():
-    import warnings
-
-    from mxnet_tpu.ops import pallas_kernels as pk
-
-    with warnings.catch_warnings(record=True) as seen:
-        warnings.simplefilter("default")
-        for _ in range(3):  # e.g. three BN layers of one trace
-            assert not pk.bn_stats_supported((8, 4, 4, 128), -1)
-    assert len(seen) == 1 and "MXNET_TPU_PALLAS_BN" in str(seen[0].message)

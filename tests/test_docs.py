@@ -95,13 +95,14 @@ def test_documents_name_only_files_that_exist(doc):
 def test_importing_the_package_leaves_the_environment_alone(tmp_path):
     """`import mxnet_tpu` reads os.environ and never writes it — not
     even when a variable of the deleted offline tuner points at a
-    profile that names registered variables."""
+    profile that names registered variables, nor when a retired
+    variable is set: that one it names, once, on stderr."""
     profile = tmp_path / "profile.json"
     profile.write_text(json.dumps({
         "schema": "mxtpu-tuned-v1",
         "models": {"m": {"knobs": {"MXTPU_STEPS_PER_DISPATCH": "4"}}}}))
     env = dict(os.environ, MXTPU_TUNED_FILE=str(profile),
-               MXTPU_TUNED_MODEL="m")
+               MXTPU_TUNED_MODEL="m", MXTPU_FROZEN_BN="1")
     env.pop("MXTPU_STEPS_PER_DISPATCH", None)
     code = ("import os, json; before = dict(os.environ); "
             "import mxnet_tpu; after = dict(os.environ); "
@@ -111,3 +112,5 @@ def test_importing_the_package_leaves_the_environment_alone(tmp_path):
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr[-4000:]
     assert json.loads(r.stdout.strip().splitlines()[-1]) == []
+    assert r.stderr.count("MXTPU_FROZEN_BN is set and has no effect: "
+                          "Module.fit(frozen_bn=False)") == 1, r.stderr

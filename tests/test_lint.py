@@ -881,14 +881,12 @@ def test_w103_flags_only_undocumented_framework_vars(tmp_path):
 SINK_KNOB_READS = """
 import os
 a = os.environ.get("MXTPU_BF16_WGRAD")
-b = os.environ.get("MXTPU_FROZEN_BN")
 c = os.environ.get("MXNET_TPU_S2D_STEM")
 """
 
 SINK_KNOB_CONFIG = """
 EnvVar = None
 REGISTRY = [EnvVar("MXTPU_BF16_WGRAD", int, 0, "bf16 wgrad"),
-            EnvVar("MXTPU_FROZEN_BN", int, 0, "frozen-BN fit default"),
             EnvVar("MXNET_TPU_S2D_STEM", int, 0, "s2d stem fold")]
 ABSORBED = {}
 """
@@ -896,10 +894,9 @@ ABSORBED = {}
 
 def test_w103_sink_knobs_must_be_registered(tmp_path):
     findings, _, _ = _lint_src(tmp_path, SINK_KNOB_READS)
-    assert _ids(findings) == ["W103", "W103", "W103"]
+    assert _ids(findings) == ["W103", "W103"]
     hit = "\n".join(f.message for f in findings)
-    for name in ("MXTPU_BF16_WGRAD", "MXTPU_FROZEN_BN",
-                 "MXNET_TPU_S2D_STEM"):
+    for name in ("MXTPU_BF16_WGRAD", "MXNET_TPU_S2D_STEM"):
         assert name in hit
 
 
@@ -920,8 +917,7 @@ def test_sink_knobs_registered_in_real_config():
     names = {n.args[0].value for n in ast.walk(tree)
              if isinstance(n, ast.Call) and getattr(n.func, "id", "") == "EnvVar"
              and n.args and isinstance(n.args[0], ast.Constant)}
-    for knob in ("MXTPU_BF16_WGRAD", "MXTPU_FROZEN_BN",
-                 "MXNET_TPU_S2D_STEM"):
+    for knob in ("MXTPU_BF16_WGRAD", "MXNET_TPU_S2D_STEM"):
         assert knob in names, knob
 
 

@@ -13,7 +13,7 @@ import mxnet_tpu as mx
 @pytest.fixture
 def clean_knobs():
     """Snapshot/restore the sink env knobs around a test."""
-    names = ("MXNET_TPU_S2D_STEM", "MXTPU_BF16_WGRAD", "MXTPU_FROZEN_BN")
+    names = ("MXNET_TPU_S2D_STEM", "MXTPU_BF16_WGRAD")
     prior = {n: os.environ.get(n) for n in names}
     yield
     for n, v in prior.items():
@@ -472,20 +472,6 @@ def test_trainable_bn_updates_stats_by_default():
     assert not np.array_equal(auxs["bn1_moving_mean"].asnumpy(),
                               aux0["bn1_moving_mean"].asnumpy())
     assert telemetry.gauge_value("module.frozen_bn") == 0
-
-
-def test_frozen_bn_env_default(clean_knobs):
-    """MXTPU_FROZEN_BN=1 makes fit default to the frozen mode."""
-    os.environ["MXTPU_FROZEN_BN"] = "1"
-    it, aux0 = _bn_fit_inputs()
-    mod = mx.mod.Module(_bn_net(), context=mx.cpu())
-    mod.fit(it, num_epoch=1, optimizer="sgd",
-            optimizer_params={"learning_rate": 0.1},
-            aux_params={n: v.copy() for n, v in aux0.items()},
-            allow_missing=True)
-    _, auxs = mod.get_params()
-    for n, v in aux0.items():
-        np.testing.assert_array_equal(auxs[n].asnumpy(), v.asnumpy())
 
 
 def test_frozen_bn_already_bound_needs_force_rebind():

@@ -169,36 +169,3 @@ def test_bf16_keeps_bn_aux_fp32():
     assert str(new_mm.dtype) == "float32"
     # old bf16 round-trip collapsed 1+2^-12 to 1.0 (error ~2.2e-4)
     np.testing.assert_allclose(new_mm, 0.9 * mm, rtol=0, atol=1e-6)
-
-
-def test_bn_ghost_stats_sample(monkeypatch):
-    """MXNET_BN_STATS_SAMPLE=N: train-mode BN stats come from the leading
-    N rows (ghost batch norm semantics); default 0 keeps full-batch."""
-    import numpy as np
-
-    import mxnet_tpu as mx
-    from mxnet_tpu.ops.nn import batch_norm
-
-    rng = np.random.RandomState(0)
-    x = rng.randn(16, 6).astype(np.float32) * 2 + 1
-    g = np.ones(6, np.float32)
-    b = np.zeros(6, np.float32)
-    mm = np.zeros(6, np.float32)
-    mv = np.ones(6, np.float32)
-
-    def run(sample):
-        monkeypatch.setenv("MXNET_BN_STATS_SAMPLE", str(sample))
-        out, nmm, nmv = batch_norm(
-            mx.nd.array(x).data, mx.nd.array(g).data, mx.nd.array(b).data,
-            mx.nd.array(mm).data, mx.nd.array(mv).data, axis=1,
-            is_train=True, fix_gamma=False, momentum=0.0)
-        return np.asarray(out), np.asarray(nmm), np.asarray(nmv)
-
-    _, mm_full, mv_full = run(0)
-    np.testing.assert_allclose(mm_full, x.mean(0), rtol=1e-5)
-    out_s, mm_s, mv_s = run(4)
-    np.testing.assert_allclose(mm_s, x[:4].mean(0), rtol=1e-5)
-    np.testing.assert_allclose(mv_s, x[:4].var(0), rtol=1e-4, atol=1e-5)
-    # the WHOLE batch is normalized with the sampled stats
-    exp = (x - x[:4].mean(0)) / np.sqrt(x[:4].var(0) + 1e-3)
-    np.testing.assert_allclose(out_s, exp, rtol=1e-4, atol=1e-5)

@@ -43,8 +43,7 @@ _COLLECTIVE_NAMES = {
     "psum", "pmean", "pmax", "pmin", "all_gather", "psum_scatter",
     "all_to_all", "ppermute", "pshuffle",
     "allreduce", "allgather", "reduce_scatter", "alltoall",
-    "ring_permute", "hierarchical_psum", "hierarchical_pmean",
-    "bucketed_psum", "barrier", "mesh_allreduce",
+    "ring_permute", "barrier", "mesh_allreduce",
 }
 # rank sources: calls whose value differs per rank
 _RANK_CALL_NAMES = {"process_index", "axis_index", "own_rank",

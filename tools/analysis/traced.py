@@ -20,7 +20,7 @@ A function is traced when it flows into a trace entry point:
     builder's RETURNED closures are traced (the executor.py
     ``_build_fwd``/``_grad_core``/``_build_block_fn`` idiom), chased
     through local assignments (``fn = self._build_block_fn(...)``;
-    ``fn = self._wrap_comm_block(fn, ...)``; ``jax.jit(fn)``);
+    ``jax.jit(fn)``);
   * transitively — a call inside a traced body to a function this file
     can resolve (nested def, module-level def, ``self._method``, a
     closure variable bound from a builder call) traces that callee too.

@@ -10,7 +10,7 @@ sys.path.insert(0, REPO)
 
 
 def render():
-    from mxnet_tpu.config import ABSORBED, REGISTRY
+    from mxnet_tpu.config import ABSORBED, REGISTRY, RETIRED
 
     lines = [
         "# Environment variables",
@@ -41,6 +41,19 @@ def render():
     ]
     for k, why in sorted(ABSORBED.items()):
         lines.append("| `%s` | %s |" % (k, why))
+    lines += [
+        "",
+        "## Retired",
+        "",
+        "Variables an earlier release registered and no code reads any",
+        "more.  Setting one has no effect; importing the package with",
+        "one set warns once and names what holds the value now:",
+        "",
+        "| retired variable | what holds the value now |",
+        "|---|---|",
+    ]
+    for k, now in sorted(RETIRED.items()):
+        lines.append("| `%s` | %s |" % (k, now))
     lines.append("")
     return "\n".join(lines)
 

@@ -20,10 +20,7 @@ batch-fill %, request p99) when it recorded the ``serving`` namespace
 (docs/serving.md), and the data-service columns (``data_qdepth`` ring
 backlog, ``decode_mbps`` compressed MB/s through the worker decoders)
 when it recorded the ``data`` namespace (docs/data.md), and the
-distributed-comm columns (``comm_gbps`` measured collective bandwidth,
-``overlap_pct`` fraction of collective time hidden under backward
-compute) when it recorded the ``comm`` namespace
-(docs/distributed.md), and the trace-contract columns (``retraces``
+trace-contract columns (``retraces``
 compiled-signature churn from the retrace monitor, ``sched_div``
 cross-rank collective-schedule divergences from
 ``MXTPU_COLLECTIVE_CHECK=1``; docs/static_analysis.md), and the int8-
@@ -51,8 +48,8 @@ Older logs render '-' in columns they predate.
 With ``--cluster`` the input is the rank-0 CLUSTER JSONL
 (``MXTPU_OBS_CLUSTER_FILE``, written by the obs aggregator —
 mxnet_tpu/obs/aggregate.py): one row per record with per-rank steps
-and step times, the max/median step-time skew ratio with the slowest
-rank named (straggler attribution), and the per-rank comm GB/s spread.
+and step times and the max/median step-time skew ratio with the slowest
+rank named (straggler attribution).
 Plain single-rank telemetry records fed to --cluster render '-' in
 every cluster column.  See docs/observability.md.
 """
@@ -182,14 +179,6 @@ def parse_telemetry(lines):
             "data_qdepth": gauges.get("data.ring_occupancy"),
             "decode_mbps": (data_bytes / dec_h["sum"] / 1e6
                             if dec_h.get("sum") else None),
-            # distributed-comm columns (docs/distributed.md): measured
-            # collective GB/s and % of collective time hidden under
-            # backward compute (executor.measure_comm gauges) — '-' for
-            # logs that predate the multi-process runtime
-            "comm_gbps": gauges.get("comm.gbps"),
-            "overlap_pct": (100.0 * gauges["comm.overlap_frac"]
-                            if gauges.get("comm.overlap_frac") is not None
-                            else None),
             # trace-contract columns (ISSUE 12, docs/static_analysis.md):
             # compiled-signature churn per run (telemetry.note_retrace,
             # the runtime half of mxlint W104) and cross-rank collective-
@@ -324,8 +313,6 @@ def parse_cluster(lines):
                 vals.append("-" if v is None else "%.4g" % (v * scale))
             return ";".join("r%s:%s" % (r, v) for r, v in zip(_o, vals))
 
-        gbps = [ranks[r].get("comm_gbps") for r in order]
-        gbps = [g for g in gbps if g is not None]
         rows.append({
             "seq": idx,
             "nranks": rec.get("nranks", len(ranks)),
@@ -334,14 +321,11 @@ def parse_cluster(lines):
             "step_ms": col("step_mean_s", scale=1e3),
             "skew": skew.get("max_over_median"),
             "slowest": skew.get("slowest_rank"),
-            "gbps_min": min(gbps) if gbps else None,
-            "gbps_max": max(gbps) if gbps else None,
         })
     return rows
 
 
-_CLUSTER_COLS = ["seq", "nranks", "steps", "step_ms", "skew", "slowest",
-                 "gbps_min", "gbps_max"]
+_CLUSTER_COLS = ["seq", "nranks", "steps", "step_ms", "skew", "slowest"]
 
 
 _TELEMETRY_COLS = ["flush_seq", "step", "epoch", "step_p50", "step_max",
@@ -349,7 +333,7 @@ _TELEMETRY_COLS = ["flush_seq", "step", "epoch", "step_p50", "step_max",
                    "io_wait_p50", "h2d_bytes", "lazy_flushes", "chain_mean",
                    "fusion_hit_pct", "wgrad_bf16", "frozen_bn",
                    "serve_qdepth", "fill_pct", "req_p99", "data_qdepth",
-                   "decode_mbps", "comm_gbps", "overlap_pct", "retraces",
+                   "decode_mbps", "retraces",
                    "sched_div", "quant_clip_pct", "tenant_bits",
                    "replicas_healthy", "redispatches", "route_p99",
                    "trace_sampled", "slo_burn", "queue_p99", "service_p99",
