@@ -24,7 +24,7 @@ apart from the rest:
   generate  TransformerLM via add_generative_tenant + submit_generate;
             one session's prefill/decode logits against the
             full-recompute score_symbol forward
-  kv_ring   the decode programs of six TransformerLMs shaped like the
+  kv_ring   the decode programs of seven TransformerLMs shaped like the
             benchmark's decoders (32 heads of 64; 16 of 128; 32 query on
             8 K/V heads of 64 with rings of 2,304; a delta-rule layer of
             30 heads of 96 x 192 beside 30 heads of 128 with rings of
@@ -33,10 +33,13 @@ apart from the rest:
             128; a delta-rule layer of 16 q/k heads under 32 value heads
             of 128 x 128 beside a ring of 2 K/V heads of 256 — a head
             over two tiles of 128 lines — under 16 query heads, a quarter
-            of each head rotated; 8 sessions, the sixth 16) as XLA
-            compiled them: every cache_spec
+            of each head rotated; two latent-attention layers of 32 heads
+            over ONE ring of 320-wide rows and 6,144 positions each, the
+            query of rank 1,024; 8 sessions, the sixth and seventh 16) as
+            XLA compiled them: every cache_spec
             entry aliased to its output, no instruction that copies one,
-            ONE attention kernel call an attention layer, ONE step-kernel
+            ONE attention kernel call an attention layer (a latent layer's
+            is ops/latent_ring_kernel.py), ONE step-kernel
             call a delta-rule layer (ops/gdn_step_kernel.py) and nothing
             of rows x page size beside it, and no ring or
             recurrent state fatter on the device than cache_spec states;
@@ -119,7 +122,23 @@ FULL = {
                                 linear_key_dim=128, linear_value_dim=128,
                                 linear_neg_eigval=False, norm="rms",
                                 positions="rotary", rotary_dim=64,
-                                qk_norm="head", out_gate=True, bias=False)]},
+                                qk_norm="head", out_gate=True, bias=False),
+                           # latent attention: ONE ring a layer of
+                           # 256 + 64 lines a position for all 32 heads,
+                           # the decode step absorbed over it
+                           dict(d_model=4096, num_heads=32, max_len=6144,
+                                seq_buckets=[64, 2048], max_sessions=16,
+                                layer_types=["latent_attention"] * 2,
+                                latent_q_rank=1024, latent_kv_rank=256,
+                                latent_nope_dim=64, latent_rope_dim=64,
+                                latent_value_dim=128,
+                                rope_scaling=dict(
+                                    factor=128, beta_fast=32, beta_slow=1,
+                                    original_max_position_embeddings=8192,
+                                    mscale=1, mscale_all_dim=1),
+                                attention_multiplier=0.195,
+                                query_scale=(0.1, 8192), norm="rms",
+                                positions="none", bias=False)]},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
 }
@@ -638,9 +657,11 @@ def phase_kv_ring(sizes, ctx):
         # the model's rings, one shape a length (a window layer's is its
         # own): the first is read for its layout, the others beside it
         rings = list(dict.fromkeys(e.shape for e in spec.values()
-                                   if e.kind == "ring"))
+                                   if e.kind in ("ring", "latent")))
         ring = rings[0]
-        ring_layers = sum(e.kind == "ring" for e in spec.values()) // 2
+        # an attention layer has two rings, a latent layer ONE
+        ring_layers = (sum(e.kind == "ring" for e in spec.values()) // 2
+                       + sum(e.kind == "latent" for e in spec.values()))
         # judged: every entry of at least a hundredth of the set's bytes
         # (a conv window of three rows lies in tiles of four, and XLA may
         # fetch so small a buffer into fast memory ahead of its use)

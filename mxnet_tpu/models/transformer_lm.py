@@ -49,12 +49,17 @@ expert in this call — which the batcher books as the ``moe.*`` counters.
 the block before the FFN — ``"attention"`` (the default for every layer),
 ``"window_attention"`` (attention over a sliding window of
 `sliding_window` positions), ``"mamba"`` (a Mamba-2 state-space mixer,
-ops/ssm.py) or ``"linear_attention"`` (a Gated DeltaNet delta-rule mixer,
+ops/ssm.py), ``"linear_attention"`` (a Gated DeltaNet delta-rule mixer,
 ops/gdn.py: `linear_heads` value heads, one state each, over
 `linear_key_heads` heads of q and k — as many unless the spec says fewer,
-each then read by a group of value heads).  A kind is one class below
+each then read by a group of value heads) or ``"latent_attention"``
+(multi-head latent attention, ops/latent.py: a low-rank query, ONE cached
+row a position for all heads — the normed joint down-projection and a
+rotary key of its own projection — which the full-sequence forms
+up-project per head and the decode step absorbs into the query).  A kind
+is one class below
 (:class:`_Attention`, :class:`_WindowAttention`, :class:`_Mamba2`,
-:class:`_GatedDeltaNet`) that
+:class:`_GatedDeltaNet`, :class:`_LatentAttention`) that
 declares, in that one place, its parameters, its full-sequence forward,
 its prefill, its decode step, the device-resident state it keeps between
 calls and the counters a program call adds to; the four graph builders
@@ -90,6 +95,14 @@ softmax ``route_norm`` experts of `expert_d_ff` with a ``shared_gate`` on
 the shared expert and ``held_experts`` are Qwen3-Next's (`qwen3_next`);
 its norm gains are stored as they are applied, ``1 + w`` of the published
 ``w``.
+``layer_types`` all ``"latent_attention"`` with `latent_q_rank`,
+`latent_kv_rank`, `latent_nope_dim` + `latent_rope_dim` = `latent_value_dim`,
+``rope_scaling`` (YaRN's blended frequencies on the rotary part alone),
+``attention_multiplier`` (the softmax scale with YaRN's ``mscale`` square),
+``query_scale`` (the query grows with the logarithm of its position past
+the trained length), and softmax ``route_norm`` experts of `expert_d_ff`
+beside an ungated shared expert with ``held_experts`` are
+Mistral-Small-4's (`mistral4`).
 
 **Cache spec.**  :meth:`TransformerLM.cache_spec` is the ONE statement of
 what a serving session holds on the device between calls: an ordered
@@ -98,7 +111,10 @@ two KV rings (kind ``"ring"``, pages addressed by slot and masked by
 length, so stale contents are harmless; A RING'S LENGTH IS ITS KIND'S,
 the last axis of its shape: the session's ``max_len`` for a full layer,
 ``min(sliding_window, max_len)`` for a window layer, whose ring a longer
-session writes modulo) and a Mamba or Gated DeltaNet layer's
+session writes modulo), a latent-attention layer's ONE latent ring (kind
+``"latent"``, ``(slots, 1, latent_kv_rank + latent_rope_dim, max_len)``:
+addressed, masked and counted like the rings, with no second ring beside
+it and no per-head K or V anywhere) and a Mamba or Gated DeltaNet layer's
 conv window and recurrent state (kind ``"state"``, a fixed size a slot
 whatever the context, wholly rewritten by a prefill).  The serving graphs take and
 return exactly these names in this order; whoever allocates, sizes,
@@ -110,16 +126,19 @@ import math
 from typing import NamedTuple
 
 from .. import symbol as sym
-from ..ops import gdn as _gdn, ssm as _ssm
+from ..ops import attention as _attention, gdn as _gdn, ssm as _ssm
 
 __all__ = ["TransformerLM", "CacheEntry"]
 
 
 class CacheEntry(NamedTuple):
     """One device buffer a serving session threads through its calls:
-    `kind` ``"ring"`` (KV pages, masked by length; ``shape[3]`` is the
-    ring's own length in positions, which differs by layer kind) or
-    ``"state"`` (a recurrent layer's, overwritten whole by a prefill);
+    `kind` ``"ring"`` (a K or a V ring's pages, masked by length;
+    ``shape[3]`` is the ring's own length in positions, which differs by
+    layer kind), ``"latent"`` (a latent-attention layer's ONE ring, ``(slots,
+    1, width, positions)``: a ring in every respect, of one row a
+    position that all heads read as key and, its leading lines, as value)
+    or ``"state"`` (a recurrent layer's, overwritten whole by a prefill);
     `shape` as stored, float32."""
 
     kind: str
@@ -304,6 +323,157 @@ class _WindowAttention(_Attention):
                 "cache.window_bytes": pages * page}
 
 
+class _LatentAttention:
+    """The latent-attention mixer (MLA) of layer i (ops/latent.py has the
+    equations): a low-rank query ``c_q = norm(x W_qa)``, ``q = c_q W_qb``
+    of `num_heads` heads of ``[q_nope | q_rope]``; a joint down-projection
+    ``[c_kv | k_r] = x W_kva`` with a norm on ``c_kv`` alone and ONE
+    rotary key ``k_r`` for all heads; the per-head up-projection `W_kvb`
+    ``(H * (nope + value), kv_rank)``, which the full-sequence forms apply
+    and the decode step absorbs; rotary (the spec's `rope_scaling`: YaRN)
+    on ``q_rope`` and ``k_r`` only; softmax scale `attention_multiplier`;
+    output projection; no bias anywhere.  `W_qb`'s rows lie BY KIND, all
+    heads' ``q_nope`` then all heads' ``q_rope``, and a rotary part's
+    channels in the rotate-half order (a checkpoint that interleaves the
+    pairs is permuted once, on loading).  State: ONE latent ring a layer,
+    ``(slots, 1, kv_rank + rope, max_len)`` — a row a position for all
+    heads, no per-head K or V anywhere."""
+
+    KIND = "latent_attention"
+
+    def __init__(self, lm):
+        self.lm = lm
+        self.nope, self.rope = lm.latent_nope_dim, lm.latent_rope_dim
+        self.value, self.rank = lm.latent_value_dim, lm.latent_kv_rank
+        self.width = self.rank + self.rope
+        # an option appears on a node only when the spec sets it
+        self.rope_attrs = dict(theta=lm.rope_theta)
+        scaling = lm.rope_scaling
+        if scaling is not None:
+            self.rope_attrs["yarn"] = tuple(
+                float(scaling[k]) for k in (
+                    "factor", "original_max_position_embeddings",
+                    "beta_fast", "beta_slow"))
+            # YaRN's attention factor: the ratio of the two mscales
+            grow = 0.1 * math.log(float(scaling["factor"]))
+            factor = ((grow * float(scaling.get("mscale", 1.0)) + 1.0)
+                      / (grow * float(scaling.get("mscale_all_dim", 0.0))
+                         + 1.0))
+            if factor != 1.0:
+                self.rope_attrs["rope_scale"] = factor
+        self.attrs = dict(num_heads=lm.num_heads, rope_dim=self.rope,
+                          value_dim=self.value)
+        if lm.attention_multiplier is not None:
+            self.attrs["scale"] = lm.attention_multiplier
+        if lm.query_scale is not None:
+            self.attrs["query_scale"] = lm.query_scale
+
+    def params(self, i):
+        lm, v = self.lm, sym.Variable
+        d, h = lm.d_model, lm.num_heads
+        return {
+            "qa_weight": v("l%d_qa_weight" % i, shape=(lm.latent_q_rank, d)),
+            "qb_weight": v("l%d_qb_weight" % i,
+                           shape=(h * (self.nope + self.rope),
+                                  lm.latent_q_rank)),
+            "kva_weight": v("l%d_kva_weight" % i, shape=(self.width, d)),
+            "kvb_weight": v("l%d_kvb_weight" % i,
+                            shape=(h * (self.nope + self.value), self.rank)),
+            "out_weight": v("l%d_out_weight" % i,
+                            shape=(d, h * self.value))}
+
+    def cache_spec(self, i, slots, max_len):
+        """ONE entry: ``(slots, 1, kv_rank + rope, max_len)``, kind
+        ``"latent"`` — the positions on the minor axis like every ring,
+        one head of `width` lines that every query head reads."""
+        return [("latent_cache_%d" % i, CacheEntry(
+            "latent", (int(slots), 1, self.width, int(max_len))))]
+
+    def _fc(self, x, p, key, width, name):
+        return sym.FullyConnected(x, weight=p[key + "_weight"],
+                                  num_hidden=width, no_bias=True,
+                                  flatten=False, name=name)
+
+    def _project(self, x, p, i, index=None):
+        """``(q_nope, q_rope, latent)`` of the normed stream: the rotary
+        parts turned — each row's own `index` in a decode step, 0..T-1
+        without one — and ``latent = [norm(c_kv) | k_r]``, the row the
+        ring keeps."""
+        lm, h = self.lm, self.lm.num_heads
+        c_q = lm._norm(self._fc(x, p, "qa", lm.latent_q_rank, "l%d_qa" % i),
+                       "l%d_qa_norm" % i, width=lm.latent_q_rank)
+        q = self._fc(c_q, p, "qb", h * (self.nope + self.rope), "l%d_qb" % i)
+        kva = self._fc(x, p, "kva", self.width, "l%d_kva" % i)
+
+        def part(t, name, begin, end):
+            return sym.slice_axis(t, axis=2, begin=begin, end=end,
+                                  name="l%d_%s" % (i, name))
+
+        q_nope = part(q, "q_nope", 0, h * self.nope)
+        q_rope = part(q, "q_rope", h * self.nope, h * (self.nope + self.rope))
+        c = lm._norm(part(kva, "c_kv", 0, self.rank), "l%d_kva_norm" % i,
+                     width=self.rank)
+        k_r = part(kva, "k_r", self.rank, self.width)
+
+        def turn(t, n, heads):
+            attrs = dict(self.rope_attrs, num_heads=heads,
+                         name="l%d_%srope" % (i, n))
+            return (sym._rotary(t, **attrs) if index is None
+                    else sym._rotary_at(t, index, **attrs))
+
+        q_rope, k_r = turn(q_rope, "q", h), turn(k_r, "k", 1)
+        return q_nope, q_rope, sym.Concat(c, k_r, dim=2,
+                                          name="l%d_latent" % i)
+
+    def _out(self, ctx, p, i):
+        return self._fc(ctx, p, "out", self.lm.d_model, "l%d_proj" % i)
+
+    def _expanded(self, x, p, i):
+        q_nope, q_rope, latent = self._project(x, p, i)
+        ctx = sym._latent_attention(q_nope, q_rope, latent, p["kvb_weight"],
+                                    name="l%d_attn" % i, **self.attrs)
+        return self._out(ctx, p, i), latent
+
+    def full(self, x, p, i):
+        return self._expanded(x, p, i)[0]
+
+    def prefill(self, x, p, i, caches, slot, length):
+        y, latent = self._expanded(x, p, i)
+        return y, [sym._latent_cache_write(
+            caches["latent_cache_%d" % i], latent, slot,
+            name="l%d_latent_write" % i)]
+
+    def decode(self, x, p, i, caches, slot, length):
+        q_nope, q_rope, latent = self._project(x, p, i, index=length)
+        step = sym._latent_cached_attention(
+            q_nope, q_rope, latent, p["kvb_weight"],
+            caches["latent_cache_%d" % i], slot, length,
+            name="l%d_attn" % i, **self.attrs)
+        return self._out(step[0], p, i), [step[1]]
+
+    def counters(self, i, rows=0, lengths=(), computed=0, pages=0,
+                 max_len=None, platform=None, **call):
+        """What one decode step (a call of `computed` program rows) adds:
+        a latent layer-step, and one served by the TPU's kernel where a
+        program lowered for `platform` has it; the bytes of this layer's
+        pages the step reads — by the kernel's blocks, up to the one that
+        holds each row's `length`, or whole pages where the ``jax.numpy``
+        body runs; and the bytes of this layer's ring among the `pages`
+        pages bound."""
+        _, entry = self.cache_spec(
+            i, 1, self.lm.max_len if max_len is None else max_len)[0]
+        ring = entry.shape[3]
+        block = _attention.decode_block(entry.shape, platform, latent=True)
+        at_a_time = block or ring
+        read = sum(min((n // at_a_time + 1) * at_a_time, ring)
+                   for n in lengths)
+        step = int(computed > 0)
+        return {"mla.layer_steps": step,
+                "mla.kernel_steps": step * (block is not None),
+                "mla.ring_bytes": 4 * self.width * read,
+                "cache.latent_bytes": pages * entry.nbytes}
+
+
 class _Recurrent:
     """What the two recurrent kinds share: a fused input projection of
     `d_proj` rows, ONE op node a form (``OPS``: full sequence, prefill,
@@ -451,7 +621,8 @@ class _GatedDeltaNet(_Recurrent):
 
 
 _KINDS = {"attention": _Attention, "window_attention": _WindowAttention,
-          "mamba": _Mamba2, "linear_attention": _GatedDeltaNet}
+          "mamba": _Mamba2, "linear_attention": _GatedDeltaNet,
+          "latent_attention": _LatentAttention}
 
 
 class _DenseFFN:
@@ -595,7 +766,8 @@ class TransformerLM:
     the head its own ``head_weight (vocab, d_model)``.
 
     Further choices (defaults: as if absent): `layer_types` — one mixer
-    kind a layer, ``"attention"`` | ``"mamba"`` | ``"linear_attention"``
+    kind a layer, ``"attention"`` | ``"window_attention"`` | ``"mamba"`` |
+    ``"linear_attention"`` | ``"latent_attention"``
     (module docstring; default all attention); `block_norm` ``"input"``
     (``h + f(norm(h))``) | ``"output"`` (``h + norm(f(h))``), for both
     halves of every block; `num_kv_heads` K/V heads shared by groups of
@@ -631,7 +803,17 @@ class TransformerLM:
     `route_norm` renormalises the chosen scores, `route_scale` multiplies
     them; `held_experts` ``(first, count)`` — the experts whose matrices
     this model holds, one chip's share: the router stays `num_experts`
-    wide."""
+    wide; the latent-attention mixer's `latent_q_rank` (the query's
+    low-rank width, normed between its two projections), `latent_kv_rank`
+    (the cached ``c``'s width), `latent_nope_dim` / `latent_rope_dim` (a
+    head's unturned and rotary query channels; one rotary key of
+    `latent_rope_dim` serves all heads) and `latent_value_dim` (a head's
+    value width, equal to their sum), its rotary part turned whatever
+    `positions` says; `rope_scaling` — YaRN's ``{factor,
+    original_max_position_embeddings, beta_fast, beta_slow[, mscale,
+    mscale_all_dim]}`` for the latent kind's rotary part; `query_scale`
+    ``(beta, period)`` — a latent layer's query at position p times ``1 +
+    beta * ln(1 + floor(p / period))``."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
                  d_ff=None, max_len=64, dropout=0.0, norm="layer",
@@ -649,7 +831,9 @@ class TransformerLM:
                  expert_d_ff=None, shared_d_ff=0, router_score="softmax",
                  router_bias=False, route_norm=False, route_scale=1.0,
                  held_experts=None, linear_key_heads=None, rotary_dim=None,
-                 shared_gate=False):
+                 shared_gate=False, latent_q_rank=0, latent_kv_rank=0,
+                 latent_nope_dim=0, latent_rope_dim=0, latent_value_dim=0,
+                 rope_scaling=None, query_scale=None):
         if head_dim is None and d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
@@ -719,6 +903,33 @@ class TransformerLM:
             raise ValueError("linear_heads=%d not a multiple of "
                              "linear_key_heads=%d"
                              % (linear_heads, linear_key_heads))
+        if "latent_attention" in layer_types:
+            if min(latent_q_rank, latent_kv_rank, latent_nope_dim,
+                   latent_rope_dim, latent_value_dim) < 1 or latent_rope_dim % 2:
+                raise ValueError(
+                    "a 'latent_attention' layer needs latent_q_rank, "
+                    "latent_kv_rank, latent_nope_dim, latent_value_dim >= 1 "
+                    "and an even latent_rope_dim >= 2")
+            if latent_nope_dim + latent_rope_dim != latent_value_dim:
+                raise ValueError(
+                    "latent_nope_dim + latent_rope_dim = %d must equal "
+                    "latent_value_dim = %d: the up-projected form goes "
+                    "through _sdp_attention, whose heads have one width"
+                    % (latent_nope_dim + latent_rope_dim, latent_value_dim))
+        if rope_scaling is not None:
+            rope_scaling = dict(rope_scaling)
+            missing = {"factor", "original_max_position_embeddings",
+                       "beta_fast", "beta_slow"} - set(rope_scaling)
+            if missing or rope_scaling.get("rope_type", "yarn") != "yarn":
+                raise ValueError("rope_scaling must be YaRN's: factor, "
+                                 "original_max_position_embeddings, beta_fast "
+                                 "and beta_slow (mscale, mscale_all_dim), got "
+                                 "%r" % (rope_scaling,))
+        if query_scale is not None:
+            query_scale = tuple(float(v) for v in query_scale)
+            if len(query_scale) != 2 or query_scale[1] <= 0:
+                raise ValueError("query_scale must be (beta, period), got %r"
+                                 % (query_scale,))
         if shared_gate and not shared_d_ff:
             raise ValueError("shared_gate needs a shared expert "
                              "(shared_d_ff >= 1)")
@@ -784,6 +995,13 @@ class TransformerLM:
         self.linear_key_heads = linear_key_heads
         self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
         self.shared_gate = bool(shared_gate)
+        self.latent_q_rank = int(latent_q_rank)
+        self.latent_kv_rank = int(latent_kv_rank)
+        self.latent_nope_dim = int(latent_nope_dim)
+        self.latent_rope_dim = int(latent_rope_dim)
+        self.latent_value_dim = int(latent_value_dim)
+        self.rope_scaling = rope_scaling
+        self.query_scale = query_scale
         if self.rotary_dim is not None and (
                 self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.d_head):
             raise ValueError("rotary_dim=%d must be even and within the "

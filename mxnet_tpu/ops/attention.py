@@ -80,9 +80,11 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, nn as jnn
 
 from .registry import register
@@ -149,27 +151,64 @@ def rms_norm(data, gamma, eps=1e-5, num_heads=1, **kw):
 # ----------------------------------------------------------------------
 
 
-def _rotate(data, positions, num_heads, theta, rotary_dim=None):
+def _yarn_inv_freq(half, theta, yarn):
+    """YaRN's blended frequencies of a rotary part of ``2 * half``
+    channels (Peng et al. 2023, "NTK-by-parts"), `yarn` ``(factor,
+    original_max, beta_fast, beta_slow)``: pair j turns at ``theta^(-j /
+    half)`` where it completes more than `beta_fast` turns over the
+    `original_max` positions the model was trained on, at ``1 / factor``
+    of that where it completes fewer than `beta_slow`, and at a linear
+    blend between — ``f_j = (1 - m_j) * b_j / factor + m_j * b_j`` with
+    ``m_j = 1 - clip((j - lo) / (hi - lo), 0, 1)``, ``lo = floor(d(beta_
+    fast))``, ``hi = ceil(d(beta_slow))``, ``d(r) = half * ln(original_max /
+    (2 pi r)) / ln(theta)``, both clipped to the part's channels."""
+    factor, original, fast, slow = (float(v) for v in _lit(yarn))
+    dim = 2 * half
+
+    def turns_at(r):
+        return dim * math.log(original / (2 * math.pi * r)) / (
+            2 * math.log(theta))
+
+    lo = max(math.floor(turns_at(fast)), 0)
+    hi = min(math.ceil(turns_at(slow)), dim - 1)
+    j = np.arange(half, dtype=np.float64)
+    base = theta ** (-j / half)
+    keep = 1.0 - np.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return jnp.asarray((1.0 - keep) * base / factor + keep * base,
+                       jnp.float32)
+
+
+def _rotate(data, positions, num_heads, theta, rotary_dim=None, yarn=None,
+            rope_scale=None):
     """Rotate each head of ``data (N, T, d_model)`` by its row's
     ``positions (N, T)``: the rotate-half convention over the WHOLE head
     (pairs ``(i, i + d_head/2)``, angle ``pos * theta^(-2i/d_head)``),
     angles and products in float32.  With `rotary_dim` R the first R
     channels of each head turn so, as a head of R (pairs ``(i, i + R/2)``,
     angle ``pos * theta^(-2i/R)``), and the other ``d_head - R`` pass as
-    they are."""
+    they are.  With `yarn` the angles' frequencies are YaRN's
+    (``_yarn_inv_freq``), and `rope_scale` multiplies cos and sin (its
+    attention factor, where that is not 1)."""
     h = int(_lit(num_heads))
     n, t, d = data.shape
     r = d // h if rotary_dim is None else int(_lit(rotary_dim))
     if r != d // h:
         x = data.reshape(n, t, h, d // h)
-        turned = _rotate(x[..., :r].reshape(n, t, h * r), positions, h, theta)
+        turned = _rotate(x[..., :r].reshape(n, t, h * r), positions, h, theta,
+                         yarn=yarn, rope_scale=rope_scale)
         return jnp.concatenate([turned.reshape(n, t, h, r), x[..., r:]],
                                axis=-1).reshape(n, t, d)
     half = d // h // 2
-    inv_freq = float(_lit(theta)) ** (
-        -jnp.arange(half, dtype=jnp.float32) / half)
+    if yarn is None:
+        inv_freq = float(_lit(theta)) ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
+    else:
+        inv_freq = _yarn_inv_freq(half, float(_lit(theta)), yarn)
     ang = positions.astype(jnp.float32)[:, :, None, None] * inv_freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if rope_scale is not None:
+        factor = float(_lit(rope_scale))
+        cos, sin = cos * factor, sin * factor
     x = data.astype(jnp.float32).reshape(n, t, h, 2, half)
     x1, x2 = x[:, :, :, 0], x[:, :, :, 1]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=3)
@@ -181,25 +220,28 @@ def _infer_same(in_shapes, attrs):
 
 
 @register("_rotary", inputs=("data",), infer_shape=_infer_same)
-def rotary(data, num_heads=1, theta=10000.0, rotary_dim=None, **kw):
+def rotary(data, num_heads=1, theta=10000.0, rotary_dim=None, yarn=None,
+           rope_scale=None, **kw):
     """Rotary position embedding of a full sequence ``(N, T, d_model)``:
     row t sits at position t (training / prefill).  `rotary_dim`: the
-    leading channels of each head that turn (default: the whole head)."""
+    leading channels of each head that turn (default: the whole head);
+    `yarn` ``(factor, original_max, beta_fast, beta_slow)`` and
+    `rope_scale`: YaRN's frequencies and attention factor (``_rotate``)."""
     n, t, _ = data.shape
     pos = jnp.broadcast_to(jnp.arange(t)[None, :], (n, t))
-    return _rotate(data, pos, num_heads, theta, rotary_dim)
+    return _rotate(data, pos, num_heads, theta, rotary_dim, yarn, rope_scale)
 
 
 @register("_rotary_at", inputs=("data", "index"), infer_shape=_infer_same)
 def rotary_at(data, index, num_heads=1, theta=10000.0, rotary_dim=None,
-              **kw):
+              yarn=None, rope_scale=None, **kw):
     """Rotary position embedding where row b's first token sits at
     ``index[b]`` — the decode step's ``length``, a traced operand, so
-    one compiled program serves every position.  `rotary_dim` as
-    ``_rotary``'s."""
+    one compiled program serves every position.  `rotary_dim`, `yarn`
+    and `rope_scale` as ``_rotary``'s."""
     t = data.shape[1]
     pos = _as_index(index)[:, None] + jnp.arange(t)[None, :]
-    return _rotate(data, pos, num_heads, theta, rotary_dim)
+    return _rotate(data, pos, num_heads, theta, rotary_dim, yarn, rope_scale)
 
 
 # ----------------------------------------------------------------------
@@ -305,7 +347,7 @@ _LANES = 128
 _BLOCK_BYTES = 1 << 20
 
 
-def decode_heads(ring_shape, itemsize=4):
+def decode_heads(ring_shape, itemsize=4, latent=False):
     """K/V heads of a ring ``(slots, H_kv, d_head, max_len)`` that one
     block of the TPU kernel holds: ALL of them wherever 128 positions of
     all heads are within 1 MiB; for a wider ring the most heads that
@@ -314,8 +356,14 @@ def decode_heads(ring_shape, itemsize=4):
     None for a ring the kernel's tiling does not divide: a ``d_head``
     under 8, or that neither divides 128 (several heads a tile of 128
     lines) nor is a multiple of it (a head over several tiles), or no such
-    group of heads."""
+    group of heads.  A `latent` ring ``(slots, 1, width, ring_len)`` is
+    ONE page for all query heads (ops/latent_ring_kernel.py reads it by
+    two matrix products): 1 wherever its `width` is whole 8-line tiles
+    and 128 positions of it are within 1 MiB."""
     _, h_kv, d_head, _ = ring_shape
+    if latent:
+        fits = d_head % 8 == 0 and d_head * _LANES * itemsize <= _BLOCK_BYTES
+        return 1 if h_kv == 1 and fits else None
     if d_head < 8 or (_LANES % d_head and d_head % _LANES):
         return None
     return next((h for h in range(h_kv, 0, -1)
@@ -323,18 +371,19 @@ def decode_heads(ring_shape, itemsize=4):
                  and h * d_head * _LANES * itemsize <= _BLOCK_BYTES), None)
 
 
-def decode_block(ring_shape, platform, itemsize=4):
+def decode_block(ring_shape, platform, itemsize=4, latent=False):
     """Positions of a page that one step of the TPU kernel holds in fast
     memory, for a ring ``(slots, H_kv, d_head, max_len)``: the largest
     multiple of 128 that divides ``max_len`` and keeps a block
     ``(decode_heads, d_head, block)`` within 1 MiB.  None where
-    ``_cached_attention`` runs its ``jax.numpy`` body and reads whole
+    ``_cached_attention`` (for a `latent` ring ``_latent_cached_
+    attention``) runs its ``jax.numpy`` body and reads whole
     pages: off the TPU, or for a ring the kernel's tiling does not divide
     (``max_len`` not a multiple of 128; no ``decode_heads``).  The decode
     program reads ``length // block + 1`` blocks of a row's page —
     whoever counts what a step reads (serving/decode.py) asks here."""
     _, _, d_head, max_len = ring_shape
-    heads = decode_heads(ring_shape, itemsize)
+    heads = decode_heads(ring_shape, itemsize, latent)
     if platform != "tpu" or heads is None or max_len % _LANES:
         return None
     return max(blk for blk in range(_LANES, max_len + 1, _LANES)

@@ -28,7 +28,10 @@ spells out: per attention layer two KV RINGS (kind ``"ring"``:
 ``max_sessions + 1`` pages of as many positions as the layer's kind
 keeps — the session's ``max_len``, or a window layer's window, which a
 session outgrows and then writes modulo — every length read off the
-entry's own shape), per state-space
+entry's own shape), per latent-attention layer ONE latent ring (kind
+``"latent"``: a ring in every respect — pages of ``max_len`` positions,
+masked by length, counted with the rings — of one row a position that
+all heads share, with no second ring beside it), per state-space
 layer a conv window and a recurrent STATE (kind ``"state"``: a fixed size
 a slot, whatever the context).  Every entry is preallocated, threaded,
 donated, booked and charged the same way; the kinds differ in what may
@@ -270,7 +273,7 @@ class GenerativeSession:
     the zoo instance): attribute ``max_len`` and methods
     ``prefill_symbol()`` / ``decode_symbol()`` / ``cache_spec(slots,
     max_len)`` (ordered name -> entry with ``kind`` ``"ring"`` |
-    ``"state"``, ``shape`` and ``nbytes``: every buffer the session
+    ``"latent"`` | ``"state"``, ``shape`` and ``nbytes``: every buffer the session
     keeps on the device).  Both graphs take ``data``, ``slot``,
     ``length``, the spec's entries and ``last_token (slots + 1,)`` and
     return ``[logits, entries..., last_token, token (B,),
@@ -303,7 +306,11 @@ class GenerativeSession:
                                 if e.kind == "state")
         # every ring's own positions a page (a window layer's are fewer
         # than `max_len`); counters of positions are means over the rings
-        rings = [e.shape for e in self._spec.values() if e.kind == "ring"]
+        # (a latent layer's one ring counts as any other: a page of
+        # positions, read by blocks up to the one that holds `length`)
+        ring_entries = [e for e in self._spec.values()
+                        if e.kind in ("ring", "latent")]
+        rings = [e.shape for e in ring_entries]
         self._ring_lens = _np.asarray([r[3] for r in rings], _np.int64)
         self._has_ring = bool(rings)
         # a routed model's programs end with tokens per (layer, expert)
@@ -344,20 +351,25 @@ class GenerativeSession:
         # pages (the CPU)
         from ..ops.attention import decode_block
 
-        blocks = [decode_block(r, self._platform) for r in rings]
+        blocks = [decode_block(e.shape, self._platform, latent=True)
+                  if e.kind == "latent"
+                  else decode_block(e.shape, self._platform)
+                  for e in ring_entries]
         self._ring_blocks = _np.asarray(
             [b or r[3] for b, r in zip(blocks, rings)], _np.int64)
         # a page's positions in the rings the decode program reads
         # through the TPU's kernel (those with a block), summed
         self._kernel_positions = sum(
             r[3] for b, r in zip(blocks, rings) if b)
-        if any(blocks):
-            # the decode programs will lower the kernel: importing
-            # Pallas is over a second of Python, spent here beside
-            # the prefill programs' compiles instead of after them
+        # the decode programs will lower a kernel: importing Pallas is
+        # over a second of Python, spent here beside the prefill
+        # programs' compiles instead of after them
+        for module in {"latent_ring_kernel" if e.kind == "latent"
+                       else "kv_ring_kernel"
+                       for e, b in zip(ring_entries, blocks) if b}:
             threading.Thread(
                 target=importlib.import_module, daemon=True,
-                args=("mxnet_tpu.ops.kv_ring_kernel",)).start()
+                args=("mxnet_tpu.ops." + module,)).start()
         # the device-resident state, threaded through every call
         self._state = self._fresh_state()
         self._free = list(range(self._slots))  # LIFO slot pool

@@ -58,7 +58,18 @@ TINY = {
                                 linear_neg_eigval=False, norm="rms",
                                 positions="rotary", rotary_dim=8,
                                 qk_norm="head", out_gate=True,
-                                bias=False)]},
+                                bias=False),
+                           dict(num_heads=4, max_len=48,
+                                layer_types=["latent_attention"] * 2,
+                                latent_q_rank=16, latent_kv_rank=24,
+                                latent_nope_dim=8, latent_rope_dim=8,
+                                latent_value_dim=16,
+                                rope_scaling=dict(
+                                    factor=8, beta_fast=32, beta_slow=1,
+                                    original_max_position_embeddings=16),
+                                attention_multiplier=0.3,
+                                query_scale=(0.1, 16), norm="rms",
+                                positions="none", bias=False)]},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
 }
@@ -99,12 +110,14 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     # delta-rule layer's window and state beside one layer's rings, found
     # in the compiled decode programs, then a window layer's rings of 16
     # positions beside a full layer's of 48, then two key heads under four
-    # value heads beside one layer's rings of heads of 32; the CPU's
-    # programs hold no kernel call
-    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4
+    # value heads beside one layer's rings of heads of 32, then two latent
+    # layers' ONE ring each of 24 + 8 lines; the CPU's programs hold no
+    # kernel call
+    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
                                           [3, 2, 16, 48], [3, 2, 16, 16],
-                                          [3, 2, 16, 48], [3, 2, 32, 48]]
+                                          [3, 2, 16, 48], [3, 2, 32, 48],
+                                          [3, 1, 32, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
     # the third shape's longest prefill bucket, read for the delta rule:
     # one such layer, no kernel in a program lowered for the CPU
@@ -123,7 +136,7 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert all(step["ms"] > 0 for step in steps)
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
-        ["16", "8"], ["8"], ["8"], ["8"], ["8"]]
+        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
     chip_smoke.run_phase("four_chips", chip_smoke.phase_four_chips,

@@ -123,3 +123,49 @@ def test_ulysses_matches_ring():
     ring = np.asarray(ring_attention_sharded(mesh, q, k, v, causal=True))
     uly = np.asarray(ulysses_attention_sharded(mesh, q, k, v, causal=True))
     np.testing.assert_allclose(uly, ring, rtol=2e-4, atol=2e-5)
+
+
+# sha1 of the jaxpr — the Pallas call with the kernel's body inside — that
+# `ops.attention._decode_attention` traces for a per-head K and V ring, at
+# the parent of PR 44 (013aa37): (rows, query heads, K/V heads, d_head,
+# ring length, wraps)
+PARENT_RING_PROGRAMS = {
+    "olmoe": ((8, 16, 16, 128, 768, False),
+              "6abd5b35cac1d7a6e01b8e7a96f7fc8dd286a85f"),
+    "trinity_window": ((8, 32, 4, 128, 2048, True),
+                       "88f409619c128345275b8fb755fc883e5295c275"),
+}
+
+
+@pytest.mark.parametrize("which", sorted(PARENT_RING_PROGRAMS))
+def test_a_per_head_rings_kernel_program_is_the_parents(which):
+    """The latent ring (PR 44) has a kernel of its own beside
+    `kv_ring_kernel.py`, and `decode_block` / `decode_heads` answer for it
+    only when asked `latent=True`: for rings that are per-head K and V the
+    decode step traces the parent's program, op for op (PR 38's lesson:
+    there the kernel's source moved and `olmoe_offline` read 1% lower until
+    it emitted the parent's program for rings that do not wrap)."""
+    import hashlib
+
+    from mxnet_tpu.ops import attention
+
+    (rows, h_q, h_kv, d_head, ring_len, wraps), want = \
+        PARENT_RING_PROGRAMS[which]
+    ring = (9, h_kv, d_head, ring_len)
+    block = attention.decode_block(ring, "tpu")
+    heads = attention.decode_heads(ring)
+
+    def step(*operands):
+        return attention._decode_attention.__wrapped__(
+            *operands, block=block, heads=heads, scale=None,
+            interpret=False, wraps=wraps)
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    text = str(jax.make_jaxpr(step)(
+        arg((rows, h_q, d_head)), arg((rows, h_kv, d_head)),
+        arg((rows, h_kv, d_head)), arg(ring), arg(ring),
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32)))
+    assert "pallas_call" in text
+    assert hashlib.sha1(text.encode()).hexdigest() == want

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 import chip_smoke
-from mxnet_tpu.ops import attention, gdn
+from mxnet_tpu.ops import attention, gdn, latent
 
 SLOTS = 9
 # (rows, query heads, K/V heads, d_head, ring length, scale[, wraps]);
@@ -90,6 +90,47 @@ def test_the_decode_attention_compiles_for_a_v5e(name, one_chip):
     assert facts["copies"] == []
     assert facts["layouts"] == ["{3,2,1,0:T(8,128)}"]
     # nothing but the rings and the small operands: no ring-sized scratch
+    stats = compiled.memory_analysis()
+    assert stats.temp_size_in_bytes < np.prod(ring[1:]) * 4
+
+
+# (rows, ring length): Mistral-Small-4's cell and its stated fallback
+LATENT = {"mistral4": (16, 6144, 768), "mistral4_fallback": (16, 4096, 512),
+          "one_row": (1, 6144, 768)}
+
+
+@pytest.mark.parametrize("name", sorted(LATENT))
+def test_the_latent_decode_compiles_for_a_v5e(name, one_chip):
+    """Lowered for the TPU, `_latent_decode` is the latent kernel — 32
+    heads against ONE page of 320 lines by two matrix products, the
+    contraction 320 wide, not a multiple of 128 —: ONE `tpu_custom_call`,
+    the ring aliased to its output, no copy of it, and the ring in the
+    layout `cache_spec`'s shape has by default (a change of layout would
+    be a copy of 33 MB a row-step)."""
+    import jax
+    import jax.numpy as jnp
+
+    rows, ring_len, want = LATENT[name]
+    ring = (17, 1, 320, ring_len)
+    block = attention.decode_block(ring, "tpu", latent=True)
+    assert block == want
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(*operands):
+        return latent._latent_decode(*operands, rank=256, scale=0.195,
+                                     block=block, interpret=False)
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        arg((rows, 32, 320)), arg((rows, 320)), arg(ring),
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32)).compile()
+    facts = chip_smoke.ring_hlo_facts(compiled.as_text(), ring)
+    assert facts["kernel_calls"] == 1
+    assert facts["ring_params"] == facts["aliased"] == 1
+    assert facts["copies"] == []
+    assert facts["layouts"] == ["{3,2,1,0:T(8,128)}"]
+    # nothing but the ring and the small operands: no page-sized scratch
     stats = compiled.memory_analysis()
     assert stats.temp_size_in_bytes < np.prod(ring[1:]) * 4
 
@@ -233,6 +274,42 @@ def test_the_delta_rule_step_compiles_for_a_v5e(widths, one_chip):
     assert facts["kernel_calls"] == 2      # the ring's and the step's
     assert facts["ring_params"] == facts["aliased"] == 1
     assert facts["copies"] == []
+
+
+def test_the_latent_decode_program_compiles_for_a_v5e(one_chip):
+    """The whole 16-row decode program of `chip_smoke.py`'s seventh model
+    — two latent-attention layers at Mistral-Small-4's widths, 32 heads
+    over ONE ring of 320 x 6,144 each — lowered for the TPU: ONE kernel
+    call a layer, both latent rings aliased to their outputs in the layout
+    `cache_spec` states, no copy or change of layout of a ring, and no
+    per-head K or V of the page's length anywhere (`f32[...,32,...,6144]`
+    would be the absorbed form undone)."""
+    import re
+    import warnings
+
+    from mxnet_tpu.models import TransformerLM
+
+    sizes, rows = chip_smoke.FULL["kv_ring"], 16
+    shape = {k: v for k, v in sizes["shapes"][6].items()
+             if k not in ("seq_buckets", "max_sessions")}
+    lm = TransformerLM(**{**{k: sizes[k] for k in (
+        "vocab", "num_layers", "d_model", "d_ff")}, **shape})
+    spec = lm.cache_spec(rows + 1)
+    ring = spec["latent_cache_0"].shape
+    assert ring == (17, 1, 320, 6144) and len(spec) == 2
+    wire = dict(data=(rows, 1), slot=(rows,), length=(rows,),
+                last_token=(rows + 1,),
+                **{n: e.shape for n, e in spec.items()})
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        text = _serving_program(lm.decode_symbol(), wire,
+                                one_chip).as_text()
+    facts = chip_smoke.ring_hlo_facts(text, ring)
+    assert facts["kernel_calls"] == 2
+    assert facts["ring_params"] == facts["aliased"] == 2
+    assert facts["copies"] == []
+    assert facts["layouts"] == ["{3,2,1,0:T(8,128)}"]
+    assert not re.search(r"f32\[[\d,]*\b32,[\d,]*6144\]", text)
 
 
 OPT_BUCKETS = [64, 128, 256, 512]  # benchmarks/traffic/gen_closed_c16.json
