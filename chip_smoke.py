@@ -548,11 +548,16 @@ def delta_rule_hlo_facts(text):
     """What a compiled prefill program makes of the delta rule's chunks,
     read from its optimised HLO `text`: the triangular solves XLA left in
     it (the op, or the custom calls a TPU expands it to) and the Pallas
-    kernel calls."""
+    kernel calls that are the chunked rule's — a mixed step (PR 46) also
+    holds its riders' step and ring kernels, which keep their names."""
     return {"solves": len(re.findall(
                 r' triangular-solve\(|custom_call_target="[^"]*Triangular',
                 text)),
-            "kernel_calls": text.count('custom_call_target="tpu_custom_call"')}
+            "kernel_calls": sum(
+                'custom_call_target="tpu_custom_call"' in line
+                and "gdn_state_step" not in line
+                and "kv_ring_attention" not in line
+                for line in text.splitlines())}
 
 
 def delta_step_hlo_facts(text, rows, state_shape):
@@ -634,8 +639,9 @@ def phase_kv_ring(sizes, ctx):
     of the rings.  Only a device backend donates and only the TPU has
     the kernel, so only there are aliasing, copies, kernel calls and
     the rings' size on the device judged; on the CPU the phase still
-    compiles, runs and parses.  Each tenant's prefill buckets are timed
-    warm, and on a device backend held against their neighbours."""
+    compiles, runs and parses.  Each tenant's prefill buckets — the mixed
+    step of each, where the model has one — are read the same way and
+    timed warm, and on a device backend held against their neighbours."""
     import numpy as np
 
     import mxnet_tpu as mx
@@ -644,7 +650,7 @@ def phase_kv_ring(sizes, ctx):
     platform = ctx.jax_device().platform
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
              "layouts": [], "rings": [], "delta_rule": [], "delta_step": [],
-             "prefill_ms": []}
+             "prefill_ms": [], "mixed_steps": 0}
     for shape in sizes["shapes"]:
         shape = dict(shape)
         buckets = shape.pop("seq_buckets", sizes["seq_buckets"])
@@ -716,6 +722,28 @@ def phase_kv_ring(sizes, ctx):
                     kernel_layers=scanned * stepped["gdn.step_kernel_bytes"]
                     // stepped["gdn.state_bytes"],
                     ms=float("%.3g" % decode_step_ms(session, slots)))
+            # where the prefill bucket programs are the mixed step (PR
+            # 46): each bucket's, as the warm-up compiled it
+            mixed = []
+            for t in buckets if session._mixed else ():
+                _exe, pre = session._program(session._prefill_pred, 1, t,
+                                             True)
+                text = pre.hlo_text()
+                chunked = lm.call_counters(
+                    positions=t, platform=platform).get(
+                        "gdn.kernel_positions", 0) // t
+                seen = {"bucket": t, "ring_params": 0, "aliased": 0,
+                        "copies": [], "kernel_calls": text.count(
+                            'custom_call_target="tpu_custom_call"'),
+                        # the riders' ring kernel an attention layer, a
+                        # delta-rule layer's chunked and step kernels
+                        "kernels": ring_layers + chunked + (
+                            stepped_by["kernel_layers"] if scanned else 0)}
+                for held_shape in sorted({e.shape for e in judged.values()}):
+                    more = ring_hlo_facts(text, held_shape)
+                    for key in ("ring_params", "aliased", "copies"):
+                        seen[key] += more[key]
+                mixed.append(seen)
             prefill_ms = prefill_bucket_ms(session, buckets)
             # the live set as the warm-up's programs left it on the device
             held = [(n, e.nbytes, getattr(a, "on_device_size_in_bytes",
@@ -745,6 +773,28 @@ def phase_kv_ring(sizes, ctx):
             print("[chip_smoke] kv_ring: on-device bytes over cache_spec's: "
                   + ", ".join("%s %.3f" % (n, on / want)
                               for n, want, on in held), flush=True)
+        # a mixed step: every entry aliased and none copied (a rider's
+        # row is written inside the kernel), the kernels it should hold
+        for seen in mixed:
+            print("[chip_smoke] kv_ring: the %(bucket)d-bucket mixed step: "
+                  "%(ring_params)d cache parameters, %(aliased)d aliased, "
+                  "%(kernel_calls)d kernel calls" % seen, flush=True)
+            _check(seen["ring_params"] == len(judged),
+                   "found %d cache parameters of %d in the %d-bucket mixed "
+                   "step's HLO" % (seen["ring_params"], len(judged),
+                                   seen["bucket"]))
+            if platform != "cpu":
+                _check(seen["aliased"] == seen["ring_params"]
+                       and not seen["copies"],
+                       "the %d-bucket mixed step aliases %d of %d cache "
+                       "entries and copies: %s"
+                       % (seen["bucket"], seen["aliased"],
+                          seen["ring_params"], "; ".join(seen["copies"][:4])))
+            if platform == "tpu":
+                _check(seen["kernel_calls"] == seen["kernels"],
+                       "%(kernel_calls)d kernel calls in the %(bucket)d-"
+                       "bucket mixed step, %(kernels)d expected" % seen)
+        total["mixed_steps"] += len(mixed)
         print("[chip_smoke] kv_ring: prefill buckets, ms a warm program: "
               + ", ".join("%d: %.2f" % row for row in sorted(
                   prefill_ms.items())), flush=True)

@@ -2,7 +2,7 @@
 (ROADMAP item 2: "modern traffic" for the serving tier, and the first
 real SP-runtime consumer outside dryrun).
 
-One :class:`TransformerLM` spec builds FOUR graphs over ONE parameter
+One :class:`TransformerLM` spec builds FIVE graphs over ONE parameter
 set (shared names, so a training checkpoint serves directly):
 
 * :meth:`sym_gen` — the BucketingModule factory: full-sequence
@@ -24,6 +24,16 @@ set (shared names, so a training checkpoint serves directly):
   batch of sessions: slot + length ride as traced operands into
   ``_cached_attention``, so one compiled program per decode bucket
   serves any join/leave mix (serving/decode.py).
+* :meth:`mixed_symbol` — the two in ONE program (PR 46): one prompt's
+  prefill AND one decode step of the session's packed rows, the prompt's
+  ``T`` positions and the rows' tokens concatenated along the token axis
+  wherever a layer is a dense product (embedding, the mixers'
+  projections, the FFN and its router, final norm and head), so every
+  weight is read once; only the mixers' cores split, each kind's `mixed`
+  calling its prefill's and its decode's core around shared
+  projections.  A model with a kind that has none (Mamba-2, whose
+  bucket programs are compute-bound and the costliest to build; latent
+  attention) returns None and keeps the two programs.
 
 The serving graphs thread the KV rings functionally (caches in ->
 updated caches out); on TPU the serve program's donated-input tuple
@@ -40,7 +50,7 @@ LayerNorm, ReLU FFN, biases, tied head); ``norm="rms"``,
 ``positions="rotary"``, ``qk_norm=True``, ``num_experts=E`` with
 ``experts_per_token=k`` (a dropless routed SwiGLU layer of width
 `d_ff`, ``mx.sym.MoE``), ``bias=False`` and ``tied_head=False``
-together are OLMoE's.  The helper methods branch; the four graph
+together are OLMoE's.  The helper methods branch; the five graph
 builders are shared.  A routed model's serving graphs end with one
 more small output, ``moe_load (num_layers, num_experts)`` — tokens per
 expert in this call — which the batcher books as the ``moe.*`` counters.
@@ -61,8 +71,9 @@ is one class below
 (:class:`_Attention`, :class:`_WindowAttention`, :class:`_Mamba2`,
 :class:`_GatedDeltaNet`, :class:`_LatentAttention`) that
 declares, in that one place, its parameters, its full-sequence forward,
-its prefill, its decode step, the device-resident state it keeps between
-calls and the counters a program call adds to; the four graph builders
+its prefill, its decode step, the two in one (`mixed`: attention, window
+attention and Gated DeltaNet have one), the device-resident state it keeps between
+calls and the counters a program call adds to; the five graph builders
 walk the pattern and know no kind by name.  `ffn_types` names each
 layer's FFN — the other half — the same way: ``"dense"``
 (:class:`_DenseFFN`, of width `d_ff`) or ``"routed"``
@@ -149,6 +160,33 @@ class CacheEntry(NamedTuple):
         return 4 * math.prod(self.shape)
 
 
+class _Rows(NamedTuple):
+    """The packed decode rows that ride a mixed step: how many the program
+    has (`n`, static), and their `slot` and `length` operands ``(n,)``."""
+
+    n: int
+    slot: object
+    length: object
+
+
+def _split_rows(x, rows, name):
+    """A mixed step's tokens ``(1, T + rows, W)`` as the prompt's
+    positions ``(1, T, W)`` and the packed rows ``(rows, 1, W)``."""
+    prompt = sym.slice_axis(x, axis=1, begin=0, end=-rows,
+                            name=name + "_prompt")
+    tail = sym.slice_axis(x, axis=1, begin=-rows, end=None,
+                          name=name + "_tail")
+    return prompt, sym.SwapAxis(tail, dim1=0, dim2=1, name=name + "_rows")
+
+
+def _join_rows(prompt, rows, name):
+    """The inverse: ``(1, T, W)`` and ``(rows, 1, W)`` as one ``(1, T +
+    rows, W)``, the prompt's positions first."""
+    return sym.Concat(prompt, sym.SwapAxis(rows, dim1=0, dim2=1,
+                                           name=name + "_tail"),
+                      dim=1, name=name + "_tokens")
+
+
 class _Attention:
     """The attention mixer of layer i: fused QKV projection (and the
     output gate's, where the spec has one), QK-norm and rotary as the
@@ -216,12 +254,10 @@ class _Attention:
         return sym.RMSNorm(x, gamma=gamma, eps=lm.norm_eps, num_heads=heads,
                            name=name)
 
-    def _qkv(self, x, p, i, index=None):
-        """The projections of the normed stream, ready for the attention
-        op: QK-norm, then rotary positions where this kind has them —
-        each row's own `index` in a decode step, 0..T-1 without one — so
-        K reaches the ring already rotated.  Returns ``(q, k, v, gate)``,
-        `gate` the output gate's projection or None."""
+    def _project(self, x, p, i):
+        """The fused projection of the normed stream, split and QK-normed
+        — what every token takes alike, whatever its position: ``[q, k,
+        v, gate]``, `gate` the output gate's projection or None."""
         lm = self.lm
         q_width, kv_width = self.q_width, self.kv_width
         widths = [q_width, kv_width, kv_width] + [q_width] * lm.out_gate
@@ -234,24 +270,38 @@ class _Attention:
             parts = [sym.slice_axis(qkv, axis=2, begin=a, end=b,
                                     name="l%d_%s_split" % (i, n))
                      for n, a, b in zip("qkvg", edges, edges[1:])]
-        q, k, v = parts[:3]
         if lm.qk_norm:
-            q = self._head_norm(q, "l%d_qnorm" % i, lm.num_heads)
-            k = self._head_norm(k, "l%d_knorm" % i, lm.num_kv_heads)
-        if self.rope:
-            rope = dict(theta=lm.rope_theta)
-            if lm.rotary_dim is not None:
-                rope["rotary_dim"] = lm.rotary_dim
-            heads = (lm.num_heads, lm.num_kv_heads)
-            if index is None:
-                q, k = (sym._rotary(t, name="l%d_%srope" % (i, n),
-                                    num_heads=h, **rope)
-                        for t, n, h in zip((q, k), "qk", heads))
-            else:
-                q, k = (sym._rotary_at(t, index, name="l%d_%srope" % (i, n),
-                                       num_heads=h, **rope)
-                        for t, n, h in zip((q, k), "qk", heads))
-        return q, k, v, parts[3] if lm.out_gate else None
+            parts[0] = self._head_norm(parts[0], "l%d_qnorm" % i,
+                                       lm.num_heads)
+            parts[1] = self._head_norm(parts[1], "l%d_knorm" % i,
+                                       lm.num_kv_heads)
+        return parts + [None] * (not lm.out_gate)
+
+    def _turn(self, q, k, i, index=None, tag=""):
+        """Rotary positions on Q and K where this kind has them — each
+        row's own `index` in a decode step, 0..T-1 without one — so K
+        reaches the ring already rotated."""
+        lm = self.lm
+        if not self.rope:
+            return q, k
+        rope = dict(theta=lm.rope_theta)
+        if lm.rotary_dim is not None:
+            rope["rotary_dim"] = lm.rotary_dim
+        turned = []
+        for t, n, h in zip((q, k), "qk", (lm.num_heads, lm.num_kv_heads)):
+            name = "l%d_%s%srope" % (i, tag, n)
+            turned.append(
+                sym._rotary(t, name=name, num_heads=h, **rope)
+                if index is None else
+                sym._rotary_at(t, index, name=name, num_heads=h, **rope))
+        return turned
+
+    def _qkv(self, x, p, i, index=None):
+        """The projections of the normed stream, ready for the attention
+        op: ``(q, k, v, gate)``."""
+        q, k, v, gate = self._project(x, p, i)
+        q, k = self._turn(q, k, i, index)
+        return q, k, v, gate
 
     def _out(self, ctx, gate, p, i):
         if gate is not None:
@@ -260,31 +310,63 @@ class _Attention:
         return self.lm._linear(ctx, p, "out", self.lm.d_model,
                                "l%d_proj" % i)
 
-    def _attend(self, x, p, i):
-        q, k, v, gate = self._qkv(x, p, i)
+    def _attend(self, q, k, v, i):
         return sym._sdp_attention(q, k, v, name="l%d_attn" % i,
-                                  **self.sdp_attrs), gate
+                                  **self.sdp_attrs)
 
     def full(self, x, p, i):
-        attn, gate = self._attend(x, p, i)
-        return self._out(attn[0], gate, p, i)
+        q, k, v, gate = self._qkv(x, p, i)
+        return self._out(self._attend(q, k, v, i)[0], gate, p, i)
 
     def _ring_write(self, caches, i, attn, slot, length):
         return sym._kv_cache_write(
             caches["k_cache_%d" % i], caches["v_cache_%d" % i],
             attn[1], attn[2], slot, name="l%d_kv_write" % i)
 
-    def prefill(self, x, p, i, caches, slot, length):
-        attn, gate = self._attend(x, p, i)
+    def _fill(self, q, k, v, i, caches, slot, length):
+        """A prompt's positions the prefill way: causal attention among
+        themselves, their K/V written into the rings' page `slot`.
+        Returns (context, the two rings)."""
+        attn = self._attend(q, k, v, i)
         wrote = self._ring_write(caches, i, attn, slot, length)
-        return self._out(attn[0], gate, p, i), [wrote[0], wrote[1]]
+        return attn[0], [wrote[0], wrote[1]]
+
+    def _step(self, q, k, v, i, rings, slot, length, tag=""):
+        """Packed rows the decode way: one token each against its own
+        page of `rings` (K's, V's).  Returns (context, the two rings)."""
+        step = sym._cached_attention(
+            q, k, v, *rings, slot, length, name="l%d_%sattn" % (i, tag),
+            **self.attrs)
+        return step[0], [step[1], step[2]]
+
+    def prefill(self, x, p, i, caches, slot, length):
+        q, k, v, gate = self._qkv(x, p, i)
+        ctx, rings = self._fill(q, k, v, i, caches, slot, length)
+        return self._out(ctx, gate, p, i), rings
 
     def decode(self, x, p, i, caches, slot, length):
         q, k, v, gate = self._qkv(x, p, i, index=length)
-        step = sym._cached_attention(
-            q, k, v, caches["k_cache_%d" % i], caches["v_cache_%d" % i],
-            slot, length, name="l%d_attn" % i, **self.attrs)
-        return self._out(step[0], gate, p, i), [step[1], step[2]]
+        rings = [caches["k_cache_%d" % i], caches["v_cache_%d" % i]]
+        ctx, rings = self._step(q, k, v, i, rings, slot, length)
+        return self._out(ctx, gate, p, i), rings
+
+    def mixed(self, x, p, i, caches, slot, length, rows):
+        """The prefill of one prompt AND the decode step of `rows` packed
+        rows around ONE projection each way: `x` ``(1, T + rows.n, d)``
+        holds the prompt's positions, then the rows' tokens.  Only the
+        core splits — the prompt's positions fill page `slot`, then the
+        rows step against the rings so written."""
+        q, k, v, gate = self._project(x, p, i)
+        (q, q_r), (k, k_r), (v, v_r) = (
+            _split_rows(t, rows.n, "l%d_%s" % (i, n))
+            for t, n in zip((q, k, v), "qkv"))
+        q, k = self._turn(q, k, i)
+        q_r, k_r = self._turn(q_r, k_r, i, index=rows.length, tag="row_")
+        ctx, rings = self._fill(q, k, v, i, caches, slot, length)
+        ctx_r, rings = self._step(q_r, k_r, v_r, i, rings, rows.slot,
+                                  rows.length, tag="row_")
+        return self._out(_join_rows(ctx, ctx_r, "l%d_ctx" % i), gate, p,
+                         i), rings
 
 
 class _WindowAttention(_Attention):
@@ -520,17 +602,32 @@ class _Recurrent:
                                       **self.attrs)
         return self._out(y, p, i)
 
-    def prefill(self, x, p, i, caches, slot, length):
+    def _fill(self, operands, states, i, slot, length):
+        """A prompt's positions the prefill way: the scan from an empty
+        state, the slot's window and state written whole.  Returns (y,
+        the two states)."""
         y = getattr(sym, self.OPS[1])(
-            *self._in(x, p, i), *self._states(caches, i), slot, length,
+            *operands, *states, slot, length,
             name="l%d_%s" % (i, self.NODE), **self.attrs)
-        return self._out(y[0], p, i), [y[1], y[2]]
+        return y[0], [y[1], y[2]]
+
+    def _step(self, operands, states, i, slot, tag=""):
+        """Packed rows the decode way: one token each on its own slot's
+        window and state.  Returns (y, the two states)."""
+        y = getattr(sym, self.OPS[2])(
+            *operands, *states, slot,
+            name="l%d_%s%s" % (i, tag, self.NODE), **self.attrs)
+        return y[0], [y[1], y[2]]
+
+    def prefill(self, x, p, i, caches, slot, length):
+        y, states = self._fill(self._in(x, p, i), self._states(caches, i),
+                               i, slot, length)
+        return self._out(y, p, i), states
 
     def decode(self, x, p, i, caches, slot, length):
-        y = getattr(sym, self.OPS[2])(
-            *self._in(x, p, i), *self._states(caches, i), slot,
-            name="l%d_%s" % (i, self.NODE), **self.attrs)
-        return self._out(y[0], p, i), [y[1], y[2]]
+        y, states = self._step(self._in(x, p, i), self._states(caches, i),
+                               i, slot)
+        return self._out(y, p, i), states
 
 
 class _Mamba2(_Recurrent):
@@ -597,6 +694,20 @@ class _GatedDeltaNet(_Recurrent):
                           neg_eigval=lm.linear_neg_eigval, eps=lm.norm_eps)
         if hk != h:   # on a node only when the spec sets it
             self.attrs["num_key_heads"] = hk
+
+    def mixed(self, x, p, i, caches, slot, length, rows):
+        """The prefill of one prompt AND the decode step of `rows` packed
+        rows around ONE input and ONE output projection: `x` ``(1, T +
+        rows.n, d)`` holds the prompt's positions, then the rows' tokens.
+        Only the mixer's core splits — the prompt's scan writes slot
+        `slot` whole, then the rows step on the states so written."""
+        proj, *small = self._in(x, p, i)
+        proj, proj_r = _split_rows(proj, rows.n, "l%d_inproj" % i)
+        y, states = self._fill([proj] + small, self._states(caches, i), i,
+                               slot, length)
+        y_r, states = self._step([proj_r] + small, states, i, rows.slot,
+                                 tag="row_")
+        return self._out(_join_rows(y, y_r, "l%d_mixed" % i), p, i), states
 
     def counters(self, i, positions=0, rows=0, platform=None, **call):
         """What one program call adds: the bucket positions a prefill
@@ -1088,28 +1199,29 @@ class TransformerLM:
         h = self._join(h, a)
         return self._ffn(h, p, i, train)
 
-    def _embed(self, data, index=None):
+    def _embed(self, data, index=None, tables=None, tag=""):
         """Token embedding (plus the learned position table's rows: each
-        row's own `index` in a decode step, 0..T-1 without one).
-        Returns (hidden, embed_weight)."""
-        embed_w = self._embed_weight()
+        row's own `index` in a decode step, 0..T-1 without one).  A graph
+        that embeds twice hands the second call the `tables` the first
+        returned and a `tag` for its nodes' names.  Returns (hidden,
+        (embed_weight, pos_weight or None))."""
+        embed_w, pos_w = tables or (
+            self._embed_weight(),
+            self._pos_weight() if self.positions == "learned" else None)
         h = sym.Embedding(data, weight=embed_w, input_dim=self.vocab,
-                          output_dim=self.d_model, name="embed")
+                          output_dim=self.d_model, name=tag + "embed")
         if self.embedding_multiplier != 1.0:
             h = h * self.embedding_multiplier
-        if self.positions == "learned":
-            if index is None:
-                h = sym._add_positional(h, self._pos_weight(),
-                                        name="pos_add")
-            else:
-                h = sym._add_positional_at(h, self._pos_weight(), index,
-                                           name="pos_add")
-        return h, embed_w
+        if pos_w is not None and index is None:
+            h = sym._add_positional(h, pos_w, name=tag + "pos_add")
+        elif pos_w is not None:
+            h = sym._add_positional_at(h, pos_w, index, name=tag + "pos_add")
+        return h, (embed_w, pos_w)
 
     def _trunk(self, data, train):
         """Embedding + positions + the block stack + final norm; returns
         hidden states ``(N, T, d_model)``."""
-        h, embed_w = self._embed(data)
+        h, (embed_w, _) = self._embed(data)
         for i in range(self.num_layers):
             h = self._block_train(h, i, train)
         return self._norm(h, "ln_f"), embed_w
@@ -1224,6 +1336,21 @@ class TransformerLM:
     def _cache_vars(self):
         return {n: sym.Variable(n) for n in self.cache_spec(1)}
 
+    def _blocks(self, h, mix):
+        """The block stack of a serving graph on the stream `h`, layer
+        i's mixer called through ``mix(mixer, x, p, i)`` → (its output,
+        its cache entries).  Returns (the stream, every layer's entries
+        in `cache_spec`'s order, the routed layers' loads or None)."""
+        outs, loads = [], [] if self._routed() else None
+        for i, mixer in enumerate(self._mixers):
+            p = self._block_params(i)
+            x = self._branch_in(h, "l%d_ln1" % i)
+            y, state = mix(mixer, x, p, i)
+            outs += state
+            h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
+            h = self._ffn(h, p, i, train=False, loads=loads)
+        return h, outs, loads
+
     def prefill_symbol(self):
         """Prefill one prompt (batch 1, padded to a sequence bucket):
         outputs ``[next_logits (1, vocab), <cache_spec entries>'...,
@@ -1235,15 +1362,10 @@ class TransformerLM:
         length = sym.Variable("length")
         last_token = sym.Variable("last_token")
         caches = self._cache_vars()
-        h, embed_w = self._embed(data)
-        outs, loads = [], [] if self._routed() else None
-        for i, mixer in enumerate(self._mixers):
-            p = self._block_params(i)
-            x = self._branch_in(h, "l%d_ln1" % i)
-            y, state = mixer.prefill(x, p, i, caches, slot, length)
-            outs += state
-            h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
-            h = self._ffn(h, p, i, train=False, loads=loads)
+        h, (embed_w, _) = self._embed(data)
+        h, outs, loads = self._blocks(
+            h, lambda mixer, x, p, i: mixer.prefill(x, p, i, caches, slot,
+                                                    length))
         h = self._norm(h, "ln_f")
         # logits at the prompt's true tail, not the pad
         last = sym._take_step(h, length - 1, name="last_h")
@@ -1263,16 +1385,58 @@ class TransformerLM:
         last_token = sym.Variable("last_token")
         caches = self._cache_vars()
         data = sym._token_feed(data, last_token, slot, name="token_feed")
-        h, embed_w = self._embed(data, index=length)
-        outs, loads = [], [] if self._routed() else None
-        for i, mixer in enumerate(self._mixers):
-            p = self._block_params(i)
-            x = self._branch_in(h, "l%d_ln1" % i)
-            y, state = mixer.decode(x, p, i, caches, slot, length)
-            outs += state
-            h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
-            h = self._ffn(h, p, i, train=False, loads=loads)
+        h, (embed_w, _) = self._embed(data, index=length)
+        h, outs, loads = self._blocks(
+            h, lambda mixer, x, p, i: mixer.decode(x, p, i, caches, slot,
+                                                   length))
         h = self._norm(h, "ln_f")
         flat = sym.Reshape(h, shape=(-1, self.d_model), name="flat")
         logits = self._head(flat, embed_w, "next_logits")
         return self._serving_outputs(logits, outs, loads, last_token, slot)
+
+    def mixed_symbol(self, rows):
+        """A MIXED STEP: the prefill of one prompt (as `prefill_symbol`)
+        and one decode step of `rows` packed rows (as `decode_symbol`) in
+        ONE program that reads every weight once — the prompt's ``T``
+        positions and the rows' tokens ride every dense product
+        (embedding, the mixers' projections, the FFN and its router, the
+        final norm and the head) as ONE ``(1, T + rows, d)`` stream, the
+        prompt first; only the mixers' cores split (each kind's `mixed`).
+        Inputs: the prompt's ``data (1, T)``, ``slot (1,)``, ``length
+        (1,)``; the rows' ``row_data (rows, 1)``, ``row_slot (rows,)``,
+        ``row_length (rows,)`` (rows with none to serve point at the
+        scratch slot with length 0); the cache entries and ``last_token
+        (slots + 1,)``.  Outputs as the two graphs', the prompt's row
+        first: ``[logits (1 + rows, vocab), <cache_spec entries>'...,
+        last_token', token (1 + rows,)]``.  None for a model with a mixer
+        kind that has no `mixed` (it keeps the two programs)."""
+        if not all(hasattr(mixer, "mixed") for mixer in self._mixers):
+            return None
+        data = sym.Variable("data")
+        slot = sym.Variable("slot")
+        length = sym.Variable("length")
+        last_token = sym.Variable("last_token")
+        riders = _Rows(int(rows), sym.Variable("row_slot"),
+                       sym.Variable("row_length"))
+        caches = self._cache_vars()
+        fed = sym._token_feed(sym.Variable("row_data"), last_token,
+                              riders.slot, name="token_feed")
+        h, tables = self._embed(data)
+        h_r, _ = self._embed(fed, index=riders.length, tables=tables,
+                             tag="row_")
+        h, outs, loads = self._blocks(
+            _join_rows(h, h_r, "stream"),
+            lambda mixer, x, p, i: mixer.mixed(x, p, i, caches, slot,
+                                               length, riders))
+        # the prompt's true tail and the rows' tokens: 1 + rows rows of
+        # final norm and head
+        h, h_r = _split_rows(h, riders.n, "tail")
+        last = sym._take_step(h, length - 1, name="last_h")
+        flat = sym.Concat(last, sym.Reshape(h_r, shape=(-1, self.d_model),
+                                            name="row_flat"),
+                          dim=0, name="flat")
+        logits = self._head(self._norm(flat, "ln_f"), tables[0],
+                            "next_logits")
+        return self._serving_outputs(
+            logits, outs, loads, last_token,
+            sym.Concat(slot, riders.slot, dim=0, name="slots"))

@@ -87,6 +87,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax, nn as jnn
 
+from . import exported
 from .registry import register
 from .tensor import _bool, _lit
 
@@ -463,10 +464,13 @@ def _decode_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i, *,
         return body(*operands)
 
     def kernel(*operands):
-        from .kv_ring_kernel import ring_attention
-
-        return ring_attention(*operands, block=block, heads=heads,
-                              scale=scale, interpret=interpret, wraps=wraps)
+        # lowered once a shape for all programs and processes
+        # (ops/exported.py): a session's decode ladder and, since PR 46,
+        # every prefill bucket's mixed step hold this kernel
+        return exported.call(
+            "kv_ring_kernel", "ring_attention", operands,
+            interpret=interpret, block=block, heads=heads, scale=scale,
+            wraps=wraps)
     return lax.platform_dependent(*operands, tpu=kernel, default=body)
 
 

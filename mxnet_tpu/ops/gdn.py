@@ -43,10 +43,10 @@ what the state already answers for its key.
   is stored) wherever ``chunk_heads`` says the kernel tiles the shape;
   ``lax.platform_dependent`` chooses at lowering, and `_chunked` below
   is what runs everywhere else and the kernel's oracle.  The kernel is
-  lowered once a shape for all processes (`_exported_kernel`: a
-  ``jax.export`` kept beside JAX's compiled programs), so that a warm
-  start neither imports Pallas nor traces the kernel for its first
-  prefill.
+  lowered once a shape for all programs and processes
+  (``ops/exported.py``: a ``jax.export`` kept beside JAX's compiled
+  programs), so that a warm start neither imports Pallas nor traces the
+  kernel for its first prefill; the step's kernel likewise.
 * ``_gdn_step`` — one position for B packed decode rows, each row's page
   advanced where it lies in the donated buffer.  Both products with the
   old state — ``S k`` for the correction and ``S q`` for the output, ``o =
@@ -84,15 +84,13 @@ kernels have no backward and are not on its path).
 from __future__ import annotations
 
 import functools
-import hashlib
-import os
 
 import jax
 import jax.numpy as jnp
-import jaxlib
 from jax import lax, nn as jnn
 from jax.scipy.linalg import solve_triangular
 
+from . import exported
 from .attention import _LANES, _as_index
 from .ssm import _conv_full
 from .registry import register
@@ -289,51 +287,6 @@ def chunk_heads(shape, value_dim, chunk, platform):
 # interpret mode on the CPU
 _INTERPRET = False
 
-_EXPORTED = {}   # (operand shapes, chunk, heads) -> jax.export.Exported
-
-
-def _exported_kernel(shapes, chunk, heads):
-    """The TPU kernel for float32 operands of `shapes`, lowered ONCE a
-    shape for all processes: a ``jax.export.Exported`` kept beside JAX's
-    compiled programs (``jax_compilation_cache_dir``) under a name made of
-    the kernel's source, the JAX versions and the shapes.  A serving
-    process traces a prefill first; calling the exported kernel there
-    needs neither Pallas (1.3 s of import that nothing hides: PERF.md
-    section 6, PR 34) nor a trace and a lowering of the kernel a bucket
-    (0.2 s each), so a warm start pays for neither, as it pays for no
-    compile.  A file that is missing, stale or unreadable is made anew."""
-    key = (shapes, chunk, heads)
-    if key in _EXPORTED:
-        return _EXPORTED[key]
-    from jax import export
-
-    with open(os.path.join(os.path.dirname(__file__), "gdn_kernel.py"),
-              "rb") as f:
-        stamp = hashlib.sha1(f.read() + repr(
-            (key, jax.__version__, jaxlib.__version__)).encode()).hexdigest()
-    folder = jax.config.jax_compilation_cache_dir
-    path = folder and os.path.join(folder, "mx-gdn-kernel-%s.export" % stamp)
-    try:
-        with open(path, "rb") as f:
-            _EXPORTED[key] = export.deserialize(bytearray(f.read()))
-        return _EXPORTED[key]
-    except Exception:  # no cache, no file, or not a whole one
-        pass
-    from .gdn_kernel import chunked_delta_rule
-
-    exported = _EXPORTED[key] = export.export(
-        jax.jit(functools.partial(chunked_delta_rule, chunk=chunk,
-                                  heads=heads)), platforms=("tpu",))(
-        *(jax.ShapeDtypeStruct(shape, jnp.float32) for shape in shapes))
-    try:
-        os.makedirs(folder, exist_ok=True)
-        with open("%s.%d" % (path, os.getpid()), "wb") as f:
-            f.write(exported.serialize())
-        os.replace(f.name, path)
-    except (OSError, TypeError):  # no folder to keep it in
-        pass
-    return exported
-
 
 @functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
 def _delta_rule(q, k, v, beta, g, *, chunk, heads, interpret):
@@ -349,13 +302,12 @@ def _delta_rule(q, k, v, beta, g, *, chunk, heads, interpret):
         return body(*operands)
 
     def kernel(*operands):
-        if interpret:
-            from .gdn_kernel import chunked_delta_rule
-
-            return chunked_delta_rule(*operands, chunk=chunk, heads=heads,
-                                      interpret=True)
-        return tuple(_exported_kernel(
-            tuple(x.shape for x in operands), chunk, heads).call(*operands))
+        # lowered once a shape for all programs and processes: a serving
+        # process traces a prefill first, and the exported kernel needs
+        # neither Pallas (1.3 s of import that nothing hides: PERF.md
+        # section 6, PR 34) nor a lowering a bucket (ops/exported.py)
+        return exported.call("gdn_kernel", "chunked_delta_rule", operands,
+                             interpret=interpret, chunk=chunk, heads=heads)
     return lax.platform_dependent(*operands, tpu=kernel, default=body)
 
 
@@ -496,9 +448,8 @@ def _state_step(k, q, v, alpha, beta, state, slot, *, heads, interpret):
         return _step_body(*operands)
 
     def kernel(*operands):
-        from .gdn_step_kernel import state_step
-
-        return state_step(*operands, heads=heads, interpret=interpret)
+        return exported.call("gdn_step_kernel", "state_step", operands,
+                             interpret=interpret, heads=heads)
     return lax.platform_dependent(*operands, tpu=kernel, default=_step_body)
 
 

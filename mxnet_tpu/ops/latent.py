@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from jax import lax, nn as jnn
 
 from . import attention as _attn
+from . import exported
 from .registry import register
 from .tensor import _lit
 
@@ -167,10 +168,11 @@ def _latent_decode(q, new, cache, slot_i, len_i, *, rank, scale, block,
         return body(*operands)
 
     def kernel(*operands):
-        from .latent_ring_kernel import latent_ring_attention
-
-        return latent_ring_attention(*operands, rank=rank, block=block,
-                                     scale=scale, interpret=interpret)
+        # lowered once a shape for all programs and processes
+        # (ops/exported.py), as the per-head rings' kernel is
+        return exported.call(
+            "latent_ring_kernel", "latent_ring_attention", operands,
+            interpret=interpret, rank=rank, block=block, scale=scale)
     return lax.platform_dependent(*operands, tpu=kernel, default=body)
 
 

@@ -20,6 +20,24 @@ many decode iterations — and two program families:
   recompiling — the vLLM slot discipline composed with the Orca
   iteration-level re-pack the batcher already does for classic
   tenants.
+* **mixed step** (PR 46) — where every mixer kind of the model has a
+  mixed form (``model.mixed_symbol(slots)``), the two in ONE program: it
+  prefills one prompt into its slot as the prefill does AND advances
+  ``max_sessions`` packed rows one token as a decode step does, the
+  prompt's positions and the rows' tokens riding every dense product
+  together, so an admission reads the weights once and not twice.  It
+  takes the prefill programs' PLACE, bucket for bucket (no executor
+  more: each binds a cache set of its own); rows with no session point
+  at the scratch slot with length 0, and with none live it IS the
+  prefill.  :meth:`admit` then only takes the slot and leaves the prompt
+  PENDING; :meth:`decode_step` dispatches ONE program an iteration —
+  the oldest pending prompt's mixed step with every live row packed
+  into it, or, nothing pending, the decode bucket's step — so several
+  prompts ride consecutive iterations and the sessions admitted earlier
+  advance meanwhile.  A model with a mixer kind that has no mixed form
+  (Mamba-2, latent attention) keeps the two programs, its prefill dispatched at
+  admission as before: that is the only selection, made from what the
+  model offers.
 
 **State of more than one kind.**  What a session keeps on the device
 between calls is whatever the MODEL's ``cache_spec(slots, max_len)``
@@ -73,7 +91,12 @@ retirement by budget or ring-full is a count, so a session whose token
 in flight is its last is simply not packed), and only then fences on
 step n, reads it and emits — one ``on_token`` a session a step.  A
 prefill is dispatched by :meth:`admit` the same way and read after the
-step that was in flight before it.  So the host's pack, dispatch and
+step that was in flight before it (a mixed step is dispatched by
+:meth:`decode_step` in a plain step's place and read by the next call,
+under ``serve.prefill``: it is a prefill for every reading with prefill
+in its name — its bucket's positions, its whole device time, the host's
+wait for it — and a decode dispatch of the live rows it carried for the
+counters of steps, rows and tokens).  So the host's pack, dispatch and
 emit, and the completion's way back to the host, pass under the device's
 step instead of beside it.  EOS needs the value: a session that turns
 out to have hit EOS has one row in flight, whose token is dropped
@@ -112,9 +135,8 @@ still in flight then are read first, they are computed already.
 """
 from __future__ import annotations
 
-import importlib
+import collections
 import logging
-import threading
 import time
 
 import numpy as _np
@@ -247,23 +269,21 @@ class _Bucket:
 class _Flight:
     """One dispatched program call whose outputs the host has not read:
     `outs` are the device's ``token (B,)`` and the outputs after it,
-    `rows` the sessions of the packed rows in order, `prog` the
-    :class:`_Bucket` of its program, `seq` its number among the
-    session's flights and `enqueued_ns` the end of its
-    ``decode.dispatch`` span."""
+    `rows` the sessions of its tokens in order — a prefill's one session,
+    a step's packed rows, a mixed step's prompt and then its riders —,
+    `riders` how many of them are decode rows, `prog` the :class:`_Bucket`
+    of its program, `seq` its number among the session's flights and
+    `enqueued_ns` the end of its ``decode.dispatch`` span."""
 
-    __slots__ = ("outs", "rows", "prog", "seq", "enqueued_ns")
+    __slots__ = ("outs", "rows", "riders", "prog", "seq", "enqueued_ns")
 
-    def __init__(self, outs, rows, prog, seq, enqueued_ns):
+    def __init__(self, outs, rows, riders, prog, seq, enqueued_ns):
         self.outs = outs
         self.rows = rows
+        self.riders = riders
         self.prog = prog
         self.seq = seq
         self.enqueued_ns = enqueued_ns
-
-    @property
-    def prefill(self):
-        return self.prog.kind == "prefill"
 
 
 class GenerativeSession:
@@ -313,15 +333,45 @@ class GenerativeSession:
         rings = [e.shape for e in ring_entries]
         self._ring_lens = _np.asarray([r[3] for r in rings], _np.int64)
         self._has_ring = bool(rings)
+        # the platform the programs are lowered for: what the layer kinds'
+        # counters and the rings' blocks are asked with
+        from ..context import current_context
+        from ..ops.attention import decode_block
+
+        self._platform = (ctx or current_context()).jax_device().platform
+        # positions of each ring's page that one step of the decode
+        # program's attention reads at a time, where it stops at the
+        # block that holds `length`; the whole page where it reads whole
+        # pages (the CPU)
+        blocks = [decode_block(e.shape, self._platform, latent=True)
+                  if e.kind == "latent"
+                  else decode_block(e.shape, self._platform)
+                  for e in ring_entries]
+        self._ring_blocks = _np.asarray(
+            [b or r[3] for b, r in zip(blocks, rings)], _np.int64)
+        # a page's positions in the rings the decode program reads
+        # through the TPU's kernel (those with a block), summed
+        self._kernel_positions = sum(
+            r[3] for b, r in zip(blocks, rings) if b)
         # a routed model's programs end with tokens per (layer, expert)
         self._reports_moe_load = "moe_load" in tuple(
             getattr(model, "extra_outputs", tuple)())
         # counters the model's layer kinds declare for a program call
         self._call_counters = getattr(model, "call_counters", None)
-        # every call threads the cache entries, then each slot's last token
-        self._input_names = (["data", "slot", "length"] + list(self._spec)
+        # every call threads the cache entries, then each slot's last
+        # token; a mixed step's riders come between the prompt's operands
+        # and the entries
+        self._input_names = (["data", "slot", "length", "row_data",
+                              "row_slot", "row_length"] + list(self._spec)
                              + ["last_token"])
-        graphs = {True: model.prefill_symbol(), False: model.decode_symbol()}
+        # a model whose mixer kinds all have a mixed form prefills through
+        # the mixed step: that program takes the prefill's place
+        mixed = getattr(model, "mixed_symbol", None)
+        mixed = mixed(self._slots) if mixed is not None else None
+        self._mixed = mixed is not None
+        self._laddered = False  # `_build_ladder` has run
+        graphs = {True: mixed or model.prefill_symbol(),
+                  False: model.decode_symbol()}
         # a graph takes the inputs it uses: the decode step of a model
         # with neither a ring nor a position table has no use for `length`
         self._wire = {
@@ -342,37 +392,11 @@ class GenerativeSession:
             graphs[False], dict(params),
             self._shapes(self._decode_ladder[0], 1, prefill=False),
             ctx=ctx)
-        # the platform the programs are lowered for: what the layer kinds'
-        # counters and the rings' blocks are asked with
-        self._platform = self._decode_pred._ctx.jax_device().platform
-        # positions of each ring's page that one step of the decode
-        # program's attention reads at a time, where it stops at the
-        # block that holds `length`; the whole page where it reads whole
-        # pages (the CPU)
-        from ..ops.attention import decode_block
-
-        blocks = [decode_block(e.shape, self._platform, latent=True)
-                  if e.kind == "latent"
-                  else decode_block(e.shape, self._platform)
-                  for e in ring_entries]
-        self._ring_blocks = _np.asarray(
-            [b or r[3] for b, r in zip(blocks, rings)], _np.int64)
-        # a page's positions in the rings the decode program reads
-        # through the TPU's kernel (those with a block), summed
-        self._kernel_positions = sum(
-            r[3] for b, r in zip(blocks, rings) if b)
-        # the decode programs will lower a kernel: importing Pallas is
-        # over a second of Python, spent here beside the prefill
-        # programs' compiles instead of after them
-        for module in {"latent_ring_kernel" if e.kind == "latent"
-                       else "kv_ring_kernel"
-                       for e, b in zip(ring_entries, blocks) if b}:
-            threading.Thread(
-                target=importlib.import_module, daemon=True,
-                args=("mxnet_tpu.ops." + module,)).start()
         # the device-resident state, threaded through every call
         self._state = self._fresh_state()
         self._free = list(range(self._slots))  # LIFO slot pool
+        # admitted (slot held) and waiting to ride a mixed step, in order
+        self._pending = collections.deque()
         self._active = []
         self._flights = []  # dispatched and unread, oldest first
         self._seq = 0  # flights dispatched: a flight's `seq`
@@ -405,7 +429,8 @@ class GenerativeSession:
     # ------------------------------------------------------------------
     def _shapes(self, batch, seq, prefill):
         shp = {"data": (batch, seq), "slot": (batch,),
-               "length": (batch,)}
+               "length": (batch,), "row_data": (self._slots, 1),
+               "row_slot": (self._slots,), "row_length": (self._slots,)}
         shp.update({n: e.shape for n, e in self._spec.items()})
         shp["last_token"] = (self._slots + 1,)
         return {n: shp[n] for n in self._wire[bool(prefill)]}
@@ -457,9 +482,11 @@ class GenerativeSession:
 
     def active(self):
         """Sessions mid-generation — or, once the last of them has hit
-        EOS, the flight that still holds its dropped row: truthy while
+        EOS, the flight that still holds its dropped row; or prompts
+        admitted that wait for their step: truthy while
         :meth:`decode_step` has anything left to do."""
-        return len(self._active) or len(self._flights)
+        return (len(self._active) or len(self._flights)
+                or len(self._pending))
 
     def budget_for(self, max_new_tokens):
         return (self._budget_default if max_new_tokens is None
@@ -503,25 +530,34 @@ class GenerativeSession:
                 _np.ones((1,), _np.float32))
             n += 1
         for b in self._decode_ladder:
-            exe, fn = self._program(self._decode_pred, b, 1, False)
-            _, state, _ = self._call(
-                exe, fn, state, _np.zeros((b, 1), _np.float32),
-                _np.full((b,), self._slots, _np.float32),
-                _np.zeros((b,), _np.float32))
+            state = self._idle_step(b, state)
             n += 1
         return n
 
-    def _launch(self, exe, fn, state, data, slot, length, logits):
+    def _idle_step(self, bucket, state):
+        """The `bucket`-row decode program once on `state`, every row
+        idle (the scratch slot, length 0): the updated state."""
+        exe, fn = self._program(self._decode_pred, bucket, 1, False)
+        return self._call(exe, fn, state, *self._pack((), bucket))[1]
+
+    def _launch(self, exe, fn, state, data, slot, length, logits,
+                riders=None):
         """Queue one program call threading `state` through and ask for
         the copies of its small outputs behind it (a copy asked for only
         after a fence costs one more host round trip a call): returns
         (those outputs still on the device — the logits if `logits`,
         the tokens, a routed model's `moe_load` — and the updated
-        state).  The state passed in is donated on device backends —
-        the caller keeps only what comes back."""
+        state).  `riders`: a mixed step's ``(row_data, row_slot,
+        row_length)``; without them its rows idle.  The state passed in
+        is donated on device backends — the caller keeps only what comes
+        back."""
         names = [n for n in self._input_names if n in exe.arg_dict]
         other_vals, aux_vals = exe.serve_args(names)
-        wire = dict(zip(self._input_names, [data, slot, length] + list(state)))
+        if riders is None:  # a mixed step with none to serve: all idle
+            riders = self._pack((), self._slots) if "row_data" in names \
+                else (None,) * 3
+        wire = dict(zip(self._input_names,
+                        [data, slot, length, *riders, *state]))
         outs = fn(tuple(wire[n] for n in names), other_vals, aux_vals,
                   _np.uint32(0))
         n_state = len(state)
@@ -550,14 +586,19 @@ class GenerativeSession:
         with profiler.span("decode.d2h", cat="serving"):
             logits, _token, *extra = (_np.asarray(o) for o in small)
         self._last_fence = None
+        if "row_data" in exe.arg_dict:
+            # a mixed step with idle rows IS the prefill: the prompt's
+            # row comes first, the scratch rows' logits are nobody's
+            logits = logits[:1]
         return logits, state, extra
 
     def _run(self, exe, fn, data, slot, length):
         """One LIVE program call, start to end: the session's state goes
         in, the updated state replaces it; returns the host logits
-        ``(B, vocab)``.  The path of whoever needs logits and not tokens
-        (the benchmark's reference check, chip_smoke.py), for a batcher
-        that is idle."""
+        ``(B, vocab)`` — of a prefill bucket's program, mixed or not, the
+        prompt's ``(1, vocab)``.  The path of whoever needs logits and
+        not tokens (the benchmark's reference check, chip_smoke.py), for
+        a batcher that is idle."""
         logits, self._state, extra = self._call(
             exe, fn, self._state, data, slot, length)
         if self._reports_moe_load:
@@ -565,21 +606,36 @@ class GenerativeSession:
         return logits
 
     def _dispatch(self, exe, fn, data, slot, length, rows, prog,
-                  hist=None):
+                  hist=None, riders=None):
         """Queue one call of the bucket program `prog` on the live state
-        and leave it in flight: nothing here waits for the device."""
+        and leave it in flight: nothing here waits for the device.
+        `rows`: the sessions of its tokens, a prefill bucket's prompt
+        first; `riders`: a mixed step's ``(row_data, row_slot,
+        row_length)``."""
         from .. import profiler
 
         self._seq = seq = self._seq + 1
+        live = len(rows) - (prog.kind == "prefill")
         with profiler.span("decode.dispatch", cat="serving", hist=hist,
-                           seq=seq, program=prog.program) as sent:
+                           seq=seq, program=prog.program,
+                           kind=self._kind(prog), bucket=prog.bucket,
+                           rows=live) as sent:
             outs, self._state = self._launch(
-                exe, fn, self._state, data, slot, length, logits=False)
+                exe, fn, self._state, data, slot, length, logits=False,
+                riders=riders)
         if not prog.program:
             # read once, off the compiled object the first call has just
             # made: beside that compile, never in a warmed bucket's path
             prog.program = fn.module_name() or ""
-        self._flights.append(_Flight(outs, rows, prog, seq, sent.end_ns))
+        self._flights.append(
+            _Flight(outs, rows, live, prog, seq, sent.end_ns))
+
+    def _kind(self, prog):
+        """What a span says the bucket program `prog` is: ``"decode"``,
+        ``"prefill"``, or ``"mixed"`` where a prefill bucket's program
+        is the mixed step."""
+        return "mixed" if self._mixed and prog.kind == "prefill" \
+            else prog.kind
 
     def _land(self, flight, hists=_NO_HISTS, book=True):
         """Fence on one flight, read its tokens and emit them, one a
@@ -598,15 +654,20 @@ class GenerativeSession:
         if self._reports_moe_load:
             self._book_moe_load(extra[0])
         with profiler.span("decode.emit", cat="serving", hist=hists[2]):
-            live = [(sess, int(t)) for sess, t in zip(flight.rows, token)
+            live = [(i, sess, int(t))
+                    for i, (sess, t) in enumerate(zip(flight.rows, token))
                     if not sess.retired]
-            for sess, t in live:
+            for _, sess, t in live:
                 self._emit(sess, t)
         dropped = len(flight.rows) - len(live)
-        if not flight.prefill:  # a session's first token is the prefill's
-            self._tokens_done += len(live)
+        # a session's first token is its prefill's, not a decode token:
+        # the decode rows are the flight's last `riders`
+        first = len(flight.rows) - flight.riders
+        decoded = sum(1 for i, _, _ in live if i >= first)
+        if decoded:
+            self._tokens_done += decoded
             if telemetry.enabled():
-                telemetry.inc("serving.decode.tokens", len(live))
+                telemetry.inc("serving.decode.tokens", decoded)
         if dropped and telemetry.enabled():
             telemetry.inc("serving.decode.dropped_rows", dropped)
         # after the emit: whether a session is live through the gap to
@@ -685,54 +746,79 @@ class GenerativeSession:
     # admission: prefill newly-arrived prompts into free slots
     # ------------------------------------------------------------------
     def admit(self, reqs):
-        """Dispatch each request's prefill into a free slot; returns the
-        requests that found NO free slot (the server re-queues them at
-        the front — admission control, not failure).  The first token
-        is read by the next :meth:`decode_step`.  A prefill that cannot
-        be dispatched fails ITS request only."""
+        """Give each request a free slot; returns the requests that found
+        NONE (the server re-queues them at the front — admission control,
+        not failure).  Where the prefill program is the mixed step, the
+        prompt is left PENDING: it rides the next :meth:`decode_step`'s
+        dispatch, one prompt an iteration, and its first token is read by
+        the iteration after.  Where the model keeps two programs its
+        prefill is dispatched here, and one that cannot be fails ITS
+        request only."""
         leftovers = []
         for req in reqs:
-            if self._closed:
+            if self._closed or not self._free:
                 leftovers.append(req)
-            elif not self._free:
-                leftovers.append(req)
-            else:
-                try:
-                    self._prefill(req)
-                except BaseException as e:  # noqa: BLE001
-                    req.fail(e)
+                continue
+            n = req.inputs["data"].reshape(-1).shape[0]
+            sess = _Session(req, self._free.pop(), n)
+            if self._mixed:
+                self._pending.append(sess)
+                continue
+            try:
+                self._prefill(sess, ())
+            except BaseException as e:  # noqa: BLE001
+                req.fail(e)
+        self._note_occupancy()
         return leftovers
 
-    def _prefill(self, req):
+    def _prefill(self, sess, rows):
+        """Dispatch the prefill bucket program of `sess`'s prompt — with
+        `rows`, the live sessions that decode on, packed into it where it
+        is the mixed step — and book it.  A dispatch that fails gives
+        the slot back and raises."""
         from .. import profiler, telemetry
 
-        tokens = req.inputs["data"].reshape(-1)
-        n = tokens.shape[0]
+        req, n = sess.req, sess.prompt_len
         bucket = choose_bucket(self._seq_ladder, n)
+        riders, live = None, len(rows)
         with profiler.span("serve.prefill_dispatch", cat="serving",
                            bucket=bucket, prompt=n):
             req.service_at = time.monotonic()
-            exe, fn = self._program(self._prefill_pred, 1, bucket, True)
-            data = _np.zeros((1, bucket), _np.float32)
-            data[0, :n] = tokens
-            sess = _Session(req, self._free.pop(), n)
             try:
-                self._dispatch(exe, fn, data,
-                               _np.full((1,), sess.slot, _np.float32),
-                               _np.full((1,), n, _np.float32),
-                               [sess], self._buckets["prefill", bucket])
+                exe, fn = self._program(self._prefill_pred, 1, bucket, True)
+                data = _np.zeros((1, bucket), _np.float32)
+                data[0, :n] = req.inputs["data"].reshape(-1)
+                if self._mixed:
+                    riders = self._pack(rows, self._slots)
+                self._dispatch(
+                    exe, fn, data, _np.full((1,), sess.slot, _np.float32),
+                    _np.full((1,), n, _np.float32), [sess, *rows],
+                    self._buckets["prefill", bucket], riders=riders)
             except BaseException:
                 self._free.append(sess.slot)
                 raise
             self._active.append(sess)
+        for rider in rows:
+            rider.fed += 1
         if telemetry.enabled():
             telemetry.inc("serving.decode.sessions")
+            # how often the mixed step engages: prompts whose program
+            # carried a live row, and the rows carried (booked by 0 too,
+            # so that a window's ratio reads 0 and not nothing)
+            telemetry.inc("serving.prefill.mixed", int(live > 0))
+            telemetry.inc("serving.prefill.rider_rows", live)
             # positions the prefill program computed, and those of them
             # past the prompt's end: computed and thrown away
             telemetry.inc("serving.prefill.bucket_positions", bucket)
             telemetry.inc("serving.prefill.pad_positions", bucket - n)
-            self._book_call(positions=bucket)
-            self._note_occupancy()
+            if live:
+                # a decode dispatch of its live rows, in a program that
+                # computes all its rows beside the bucket's positions
+                self._book_step(riders, live, self._slots,
+                                positions=bucket)
+            else:
+                self._book_call(positions=bucket,
+                                computed=self._slots * self._mixed)
 
     def _note_occupancy(self):
         from .. import telemetry
@@ -755,31 +841,48 @@ class GenerativeSession:
                 and sess.prompt_len + sampled < self._max_len)
 
     def decode_step(self):
-        """One token-level iteration, a step ahead of the host: re-pack
-        every session that decodes on into the smallest decode bucket
-        and DISPATCH that step; then land what was in flight before it —
-        the previous step (fence, read, emit, retire) and the prefills
-        admitted since.  Returns the rows dispatched (0 when the call
-        only landed, or found nothing to do)."""
+        """One token-level iteration, a step ahead of the host: DISPATCH
+        one program — the mixed step of the oldest pending prompt's
+        bucket with every session that decodes on packed into it, or,
+        nothing pending, those sessions re-packed into the smallest
+        decode bucket — then land what was in flight before it: the
+        previous step (fence, read, emit, retire), mixed or not, and,
+        where the model keeps two programs, the prefills admitted since.
+        Returns the rows dispatched (0 when the call only landed, or
+        found nothing to do)."""
         from .. import profiler
 
         landing, self._flights = self._flights, []
         rows = [s for s in self._active if self._wants_row(s)]
-        # at most one step is in flight, and it is the oldest: this call
-        # lands all it finds and leaves the one it dispatches
-        step = landing.pop(0) if landing and not landing[0].prefill else None
+        prompt = self._pending.popleft() if self._pending else None
+        if prompt is not None and not self._laddered:
+            self._build_ladder()
+        # at most one decode step is in flight, and it is the oldest:
+        # this call lands all it finds and leaves the one it dispatches
+        step = (landing.pop(0)
+                if landing and landing[0].prog.kind == "decode" else None)
         n = len(rows)
-        bucket = choose_bucket(self._decode_ladder, n) if n else 0
-        if rows or step is not None:
+        if prompt is not None:
+            bucket = self._slots
+        else:
+            bucket = choose_bucket(self._decode_ladder, n) if n else 0
+        if prompt is not None or rows or step is not None:
             # `seq`: the flight this call dispatches, `landed`: the one
-            # it reads (0: none)
-            with profiler.span("serve.decode_step", cat="serving",
-                               hist="serving.decode.step_seconds", n=n,
-                               bucket=bucket,
-                               seq=self._seq + 1 if rows else 0,
-                               landed=step.seq if step is not None else 0):
-                if rows:
-                    self._dispatch_step(rows, bucket)
+            # it reads (0: none).  The histograms of the step and its
+            # legs are the period of PURE steps: a call that reads a
+            # mixed step feeds `serving.prefill_seconds` below instead
+            pure = not any(self._kind(f.prog) == "mixed" for f in landing)
+            with profiler.span(
+                    "serve.decode_step", cat="serving",
+                    hist="serving.decode.step_seconds" if pure else None,
+                    n=n, bucket=bucket, rows=n,
+                    program="decode" if prompt is None else "mixed",
+                    seq=self._seq + 1 if rows or prompt is not None else 0,
+                    landed=step.seq if step is not None else 0):
+                if prompt is not None:
+                    prompt = self._ride(prompt, rows)
+                if prompt is None and rows:
+                    self._dispatch_step(rows, bucket, timed=pure)
                 if step is not None:
                     self._land(step, _LAND_HISTS)
         for flight in landing:
@@ -791,70 +894,113 @@ class GenerativeSession:
         self._note_occupancy()
         return n
 
-    def _dispatch_step(self, rows, bucket):
-        """Pack `rows` into the `bucket`-row decode program and dispatch
-        it.  A row whose last token the host has not read yet says so
-        with a negative `data`, and the program takes the token from
-        ``last_token[slot]``."""
-        from .. import profiler, telemetry
+    def _build_ladder(self):
+        """Before the first mixed step: build every decode bucket program
+        not built yet and run it once, its rows idle, on the live state
+        (a step of garbage on the scratch slot; no second set of rings as
+        `warm` threads).  With two programs a burst of admissions prefills
+        first and then steps with every slot live, so a tenant's first
+        requests passed through every decode bucket from the top; with
+        mixed steps the earlier sessions advance while the later prompts
+        prefill and may have retired before the slots are ever full —
+        the bucket would compile, for seconds, under live sessions, the
+        first time they are."""
+        self._laddered = True
+        for b in self._decode_ladder:
+            if ("decode", b) not in self._programs:
+                self._state = self._idle_step(b, self._state)
 
-        with profiler.span("decode.pack", cat="serving",
-                           hist="serving.decode.pack_seconds"):
+    def _ride(self, prompt, rows):
+        """Dispatch the mixed step of `prompt` with `rows` riding it;
+        returns `prompt`, or None where that could not be dispatched:
+        it fails ITS request only, and the rows are owed a plain step."""
+        try:
+            self._prefill(prompt, rows)
+        except Exception as e:  # noqa: BLE001 — the request's failure
+            prompt.req.fail(e)
+            return None
+        return prompt
+
+    def _pack(self, rows, bucket):
+        """``(data, slot, length)`` of `rows` packed into `bucket` program
+        rows, the rest at the scratch slot with length 0.  A row whose
+        last token the host has not read yet says so with a negative
+        `data`, and the program takes the token from
+        ``last_token[slot]``."""
+        data = _np.zeros((bucket, 1), _np.float32)
+        slot = _np.full((bucket,), self._slots, _np.float32)  # scratch
+        length = _np.zeros((bucket,), _np.float32)
+        for i, sess in enumerate(rows):
+            unread = sess.sampled() > len(sess.generated)
+            data[i, 0] = -1.0 if unread else sess.generated[-1]
+            slot[i] = sess.slot
+            length[i] = sess.fed
+        return data, slot, length
+
+    def _dispatch_step(self, rows, bucket, timed=True):
+        """Pack `rows` into the `bucket`-row decode program and dispatch
+        it; `timed`: the two legs feed their histograms."""
+        from .. import profiler
+
+        with profiler.span(
+                "decode.pack", cat="serving",
+                hist="serving.decode.pack_seconds" if timed else None):
             exe, fn = self._program(self._decode_pred, bucket, 1, False)
-            data = _np.zeros((bucket, 1), _np.float32)
-            slot = _np.full((bucket,), self._slots, _np.float32)  # scratch
-            length = _np.zeros((bucket,), _np.float32)
-            ahead = 0
-            for i, sess in enumerate(rows):
-                unread = sess.sampled() > len(sess.generated)
-                ahead += unread
-                data[i, 0] = -1.0 if unread else sess.generated[-1]
-                slot[i] = sess.slot
-                length[i] = sess.fed
-        self._dispatch(exe, fn, data, slot, length, rows,
-                       self._buckets["decode", bucket],
-                       hist="serving.decode.dispatch_seconds")
+            packed = self._pack(rows, bucket)
+        self._dispatch(
+            exe, fn, *packed, rows, self._buckets["decode", bucket],
+            hist="serving.decode.dispatch_seconds" if timed else None)
         for sess in rows:
             sess.fed += 1
-        if telemetry.enabled():
-            n = len(rows)
-            telemetry.inc("serving.decode.dispatches")
-            if ahead:
-                telemetry.inc("serving.decode.runahead_steps")
-            # the cache sets bound on the device: the live one plus the
-            # zero-filled placeholder set each bucket program's executor
-            # binds.  All their bytes, and the part that is recurrent state
-            sets = 1 + len(self._programs)
-            telemetry.inc("cache.reserved_bytes", sets * self._cache_bytes)
-            telemetry.inc("cache.state_bytes", sets * self._state_bytes)
-            pages = sets * (self._slots + 1)
-            filled = length[:n].astype(_np.int64)
-            self._book_call(rows=n, lengths=filled.tolist(), computed=bucket,
-                            pages=pages, max_len=self._max_len)
-            if self._has_ring:
-                # position-steps, each the mean over the rings (whose
-                # lengths differ where the model has window layers): over
-                # a window their ratio is the mean reserved over used.
-                # Reserved are a ring's pages times its length, in every
-                # bound set; used are the positions the packed sessions
-                # had filled — at most a ring's own length — when the
-                # step was packed.  A model with no ring has neither
-                lens, blks = self._ring_lens[:, None], self._ring_blocks[:, None]
-                whole = int(lens.sum())
-                telemetry.inc("kv.reserved_positions",
-                              self._per_ring(pages * whole))
-                telemetry.inc("kv.used_positions", self._per_ring(
-                    _np.minimum(filled, lens).sum()))
-                # what the dispatched program's attention reads of the
-                # packed rows' pages: all of each, or the blocks up to
-                # the one that holds `length` — every block of a ring
-                # that has wrapped (ops/attention.py)
-                read = _np.minimum((filled // blks + 1) * blks, lens)
-                telemetry.inc("kv.page_positions", self._per_ring(n * whole))
-                telemetry.inc("kv.kernel_positions",
-                              self._per_ring(n * self._kernel_positions))
-                telemetry.inc("kv.skipped_positions",
-                              self._per_ring(n * whole - read.sum()))
+        self._book_step(packed, len(rows), bucket)
+
+    def _book_step(self, packed, n, computed, positions=0):
+        """The counters of one decode dispatch: `n` real rows `packed`
+        (``_pack``'s arrays) in a program of `computed` rows — and, a
+        mixed step, `positions` of a prompt's bucket beside them."""
+        from .. import telemetry
+
+        if not telemetry.enabled():
+            return
+        data, _, length = packed
+        telemetry.inc("serving.decode.dispatches")
+        if (data[:n] < 0).any():
+            telemetry.inc("serving.decode.runahead_steps")
+        # the cache sets bound on the device: the live one plus the
+        # zero-filled placeholder set each bucket program's executor
+        # binds.  All their bytes, and the part that is recurrent state
+        sets = 1 + len(self._programs)
+        telemetry.inc("cache.reserved_bytes", sets * self._cache_bytes)
+        telemetry.inc("cache.state_bytes", sets * self._state_bytes)
+        pages = sets * (self._slots + 1)
+        filled = length[:n].astype(_np.int64)
+        self._book_call(positions=positions, rows=n,
+                        lengths=filled.tolist(), computed=computed,
+                        pages=pages, max_len=self._max_len)
+        if self._has_ring:
+            # position-steps, each the mean over the rings (whose
+            # lengths differ where the model has window layers): over
+            # a window their ratio is the mean reserved over used.
+            # Reserved are a ring's pages times its length, in every
+            # bound set; used are the positions the packed sessions
+            # had filled — at most a ring's own length — when the
+            # step was packed.  A model with no ring has neither
+            lens, blks = self._ring_lens[:, None], self._ring_blocks[:, None]
+            whole = int(lens.sum())
+            telemetry.inc("kv.reserved_positions",
+                          self._per_ring(pages * whole))
+            telemetry.inc("kv.used_positions", self._per_ring(
+                _np.minimum(filled, lens).sum()))
+            # what the dispatched program's attention reads of the
+            # packed rows' pages: all of each, or the blocks up to
+            # the one that holds `length` — every block of a ring
+            # that has wrapped (ops/attention.py)
+            read = _np.minimum((filled // blks + 1) * blks, lens)
+            telemetry.inc("kv.page_positions", self._per_ring(n * whole))
+            telemetry.inc("kv.kernel_positions",
+                          self._per_ring(n * self._kernel_positions))
+            telemetry.inc("kv.skipped_positions",
+                          self._per_ring(n * whole - read.sum()))
 
     def _emit(self, sess, token):
         """Book one sampled token; retire on EOS / budget / ring-full."""
@@ -906,7 +1052,8 @@ class GenerativeSession:
                 "tenant %r: a program call in flight at shutdown could "
                 "not be read; its tokens are dropped", self.name,
                 exc_info=True)
-        for sess in list(self._active):
+        pending, self._pending = self._pending, collections.deque()
+        for sess in [*self._active, *pending]:
             self._retire(sess, reason)
 
     def fail_active(self, exc):
@@ -918,10 +1065,11 @@ class GenerativeSession:
 
         self._flights = []
         self._last_fence = None
-        for sess in list(self._active):
-            self._active.remove(sess)
+        pending, self._pending = self._pending, collections.deque()
+        for sess in [*self._active, *pending]:
             self._free.append(sess.slot)
             sess.req.fail(exc)
+        self._active = []
         if telemetry.enabled():
             self._note_occupancy()
 

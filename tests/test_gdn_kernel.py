@@ -202,37 +202,45 @@ def test_where_the_function_says_none_the_op_is_todays(bucket, chunk):
 
 
 def test_the_kernel_is_exported_once_a_shape_and_found_again(tmp_path):
-    """`_exported_kernel` keeps the lowered kernel beside JAX's compiled
+    """`ops.exported` keeps the lowered kernel beside JAX's compiled
     programs: made and written on the first call, and a later process —
     here the same one with its memory of it cleared and the kernel's
     module made unusable — reads it back instead of tracing the kernel;
     a file that is not a whole export is made anew."""
     import jax
 
+    from mxnet_tpu.ops import exported
+
     shapes = ((1, 2 * CHUNK, H, DK), (1, 2 * CHUNK, H, DK),
               (1, 2 * CHUNK, H, DV), (1, 2 * CHUNK, H), (1, 2 * CHUNK, H))
+    operands = [jax.ShapeDtypeStruct(s, np.float32) for s in shapes]
+
+    def made(heads):
+        key = ("gdn_kernel", "chunked_delta_rule",
+               tuple((s, "float32") for s in shapes),
+               (("chunk", CHUNK), ("heads", heads)))
+        return exported._exported(key, operands,
+                                  dict(chunk=CHUNK, heads=heads))
+
     was = jax.config.jax_compilation_cache_dir
     jax.config.update("jax_compilation_cache_dir", str(tmp_path))
     try:
-        gdn._EXPORTED.clear()
-        first = gdn._exported_kernel(shapes, CHUNK, 2)
+        first = made(2)
         kept, = tmp_path.iterdir()
+        assert kept.name.startswith("mx-gdn-kernel-")
         assert first.platforms == ("tpu",)
         assert [tuple(a.shape) for a in first.in_avals] == list(shapes)
-        gdn._EXPORTED.clear()
         with mock.patch.object(gdn_kernel, "chunked_delta_rule",
                                side_effect=AssertionError("traced again")):
-            again = gdn._exported_kernel(shapes, CHUNK, 2)
+            again = made(2)
             assert again.mlir_module_serialized == first.mlir_module_serialized
             # another shape is another kernel
             with pytest.raises(AssertionError, match="traced again"):
-                gdn._exported_kernel(shapes, CHUNK, 4)
+                made(4)
         kept.write_bytes(kept.read_bytes()[:100])
-        gdn._EXPORTED.clear()
-        mended = gdn._exported_kernel(shapes, CHUNK, 2)
+        mended = made(2)
         assert [tuple(a.shape) for a in mended.out_avals] == [
             (1, 2 * CHUNK, H, DV), (1, DK, H * DV)]
         assert len(kept.read_bytes()) > 100
     finally:
-        gdn._EXPORTED.clear()
         jax.config.update("jax_compilation_cache_dir", was)

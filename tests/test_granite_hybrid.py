@@ -625,6 +625,43 @@ def test_the_cache_and_prefill_counters(held):
     assert telemetry.snapshot()["gauges"]["kv.ring_bytes"] == cache + 4 * 3
 
 
+def test_a_mamba_model_keeps_its_two_programs(held):
+    """PR 46: `_Mamba2` has no mixed form, so Granite's model offers no
+    mixed graph and its session is the parent's: `admit` dispatches the
+    prefill itself and leaves nothing pending, the bound programs are the
+    prefill buckets' and the decode ladder's with no rider operand, no
+    decode bucket is built ahead of its first use, and the two counters of
+    the mixed step are booked by 0 with every admission."""
+    lm = family.model(CONFIG)
+    assert lm.mixed_symbol(3) is None
+    telemetry.set_enabled(True)
+    names = ("serving.prefill.mixed", "serving.prefill.rider_rows",
+             "serving.decode.sessions", "serving.decode.bucket_programs")
+    before = {n: telemetry.counter_value(n) for n in names}
+    gs = _session(held)
+    try:
+        assert not gs._mixed
+        reqs = [GenerateRequest("lm", list(range(1, 1 + n)), 60.0, 4)
+                for n in (5, 11)]
+        assert gs.admit(reqs) == [] and not gs._pending
+        assert [f.prog.kind for f in gs._flights] == ["prefill"] * 2
+        assert set(gs._programs) == {("prefill", 8), ("prefill", 32)}
+        _drive(gs, [])
+        assert set(gs._programs) == {("prefill", 8), ("prefill", 32),
+                                     ("decode", 2)}
+        assert not any("row_data" in exe.arg_dict
+                       for exe in gs._programs.values())
+    finally:
+        gs.close()
+    moved = {n: telemetry.counter_value(n) - before[n] for n in names}
+    assert moved == {"serving.prefill.mixed": 0,
+                     "serving.prefill.rider_rows": 0,
+                     "serving.decode.sessions": 2,
+                     "serving.decode.bucket_programs": 3}
+    for r in reqs:
+        assert len(r.future.result(timeout=5).tokens) == 4
+
+
 def test_a_model_with_no_ring_reads_no_kv_counter(params):
     """All-Mamba layers: the session holds state only, and the `kv.*`
     position counters stay where they were."""

@@ -282,8 +282,9 @@ def test_batcher_books_the_moe_counters(held):
              for k in ("moe.pairs", "moe.experts_hit", "moe.expert_slots",
                        "moe.max_load")}
     layers, k = CONFIG["num_hidden_layers"], CONFIG["num_experts_per_tok"]
-    # one prefill of 16 rows, two decode steps of one row
-    assert moved["moe.pairs"] == layers * k * (16 + 1 + 1)
+    # one mixed step of 16 positions and its two idle rows, two decode
+    # steps of one row
+    assert moved["moe.pairs"] == layers * k * (16 + 2 + 1 + 1)
     assert moved["moe.expert_slots"] == 3 * layers * CONFIG["num_experts"]
     assert 0 < moved["moe.experts_hit"] <= moved["moe.expert_slots"]
     assert moved["moe.max_load"] >= 3 * layers

@@ -442,13 +442,14 @@ def test_the_batcher_books_recurrent_state_experts_and_the_ring(held):
         telemetry.set_enabled(was)
     for r in reqs:
         assert len(r.future.result(timeout=5).tokens) == 6
-    steps = moved["serving.decode.dispatches"]
-    assert steps == 5
-    assert moved["kv.page_positions"] == steps * 2 * 128
+    # five rows a session: the first session's first rides the second
+    # prompt's mixed step, so its last leaves the second's alone
+    assert moved["serving.decode.dispatches"] == 6
+    assert moved["kv.page_positions"] == 5 * 2 * 128
     assert moved["kv.kernel_positions"] == 0
     assert moved["gdn.scan_positions"] == 3 * (8 + 32)
     assert 0 < moved["cache.state_bytes"] < moved["cache.reserved_bytes"]
-    assert moved["moe.routed_pairs"] == 4 * 4 * (8 + 32 + steps * 2)
+    assert moved["moe.routed_pairs"] == 4 * 4 * (8 + 2 + 32 + 2 + 4 * 2 + 1)
     assert 0 < moved["moe.pairs"] < moved["moe.routed_pairs"]
 
 

@@ -126,14 +126,17 @@ def test_ulysses_matches_ring():
 
 
 # sha1 of the jaxpr — the Pallas call with the kernel's body inside — that
-# `ops.attention._decode_attention` traces for a per-head K and V ring, at
-# the parent of PR 44 (013aa37): (rows, query heads, K/V heads, d_head,
-# ring length, wraps)
+# `ops.kv_ring_kernel.ring_attention` traces for a per-head K and V ring,
+# as the parent of PR 46 (335689c) traces it — the same file, to the byte,
+# that the parent of PR 44 had: (rows, query heads, K/V heads, d_head, ring
+# length, wraps).  (Until PR 46 the pin was on `_decode_attention`'s own
+# jaxpr, which held this call inline; it now calls the kernel EXPORTED,
+# lowered once a shape — `ops/exported.py` — and what is exported is this.)
 PARENT_RING_PROGRAMS = {
     "olmoe": ((8, 16, 16, 128, 768, False),
-              "6abd5b35cac1d7a6e01b8e7a96f7fc8dd286a85f"),
+              "31e07feaa7be48044af11f5847624ca7e2d8a535"),
     "trinity_window": ((8, 32, 4, 128, 2048, True),
-                       "88f409619c128345275b8fb755fc883e5295c275"),
+                       "fbe1114ee3134eac4d5c3bae9dcb75168d238005"),
 }
 
 
@@ -145,20 +148,19 @@ def test_a_per_head_rings_kernel_program_is_the_parents(which):
     decode step traces the parent's program, op for op (PR 38's lesson:
     there the kernel's source moved and `olmoe_offline` read 1% lower until
     it emitted the parent's program for rings that do not wrap)."""
+    import functools
     import hashlib
 
-    from mxnet_tpu.ops import attention
+    from mxnet_tpu.ops import attention, kv_ring_kernel
 
     (rows, h_q, h_kv, d_head, ring_len, wraps), want = \
         PARENT_RING_PROGRAMS[which]
     ring = (9, h_kv, d_head, ring_len)
     block = attention.decode_block(ring, "tpu")
     heads = attention.decode_heads(ring)
-
-    def step(*operands):
-        return attention._decode_attention.__wrapped__(
-            *operands, block=block, heads=heads, scale=None,
-            interpret=False, wraps=wraps)
+    step = functools.partial(kv_ring_kernel.ring_attention, block=block,
+                             heads=heads, scale=None, interpret=False,
+                             wraps=wraps)
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype)

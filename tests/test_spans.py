@@ -182,13 +182,10 @@ def fit_run(tmp_path_factory):
     return hists, events
 
 
-@pytest.fixture(scope="module")
-def served(tmp_path_factory):
-    """One generation through `ModelServer` on a tiny TransformerLM
-    tenant: (telemetry histograms, chrome events)."""
+def _served(tmp_path_factory, two_programs):
     prev = telemetry.set_enabled(True)
     telemetry.reset()
-    lm, params = _lm_and_params()
+    lm, params = _lm_and_params(two_programs=two_programs)
     server = mx.serving.ModelServer({}, wait_ms=1.0)
 
     def body():
@@ -207,6 +204,21 @@ def served(tmp_path_factory):
     return hists, events
 
 
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One generation through `ModelServer` on a tiny TransformerLM
+    tenant that keeps TWO programs (a prefill dispatched at admission,
+    then steps): (telemetry histograms, chrome events)."""
+    return _served(tmp_path_factory, two_programs=True)
+
+
+@pytest.fixture(scope="module")
+def served_mixed(tmp_path_factory):
+    """The same generation on the same model with its mixed step: the
+    prompt rides the first `decode_step`'s dispatch."""
+    return _served(tmp_path_factory, two_programs=False)
+
+
 @pytest.mark.parametrize("hist", [
     "io.h2d_stage_seconds", *STAGE_LEGS, "io.consumer_wait_seconds",
     "executor.dispatch_seconds.block", "module.device_wait_seconds",
@@ -219,8 +231,10 @@ def test_a_fit_block_feeds_every_histogram_of_the_table(fit_run, hist):
 @pytest.mark.parametrize("hist", [
     "serving.decode.step_seconds", *DECODE_LEGS, "serving.prefill_seconds",
     "serving.loop.wait_seconds"])
-def test_a_decode_step_feeds_every_histogram_of_the_table(served, hist):
-    hists, _ = served
+@pytest.mark.parametrize("run", ["served", "served_mixed"])
+def test_a_decode_step_feeds_every_histogram_of_the_table(request, run,
+                                                          hist):
+    hists, _ = request.getfixturevalue(run)
     assert hists[hist]["count"] > 0 and hists[hist]["sum"] > 0
 
 
@@ -229,6 +243,7 @@ def test_a_decode_step_feeds_every_histogram_of_the_table(served, hist):
     ("fit_run", "module.step_seconds",
      ["executor.dispatch_seconds.block", "module.device_wait_seconds"]),
     ("served", "serving.decode.step_seconds", DECODE_LEGS),
+    ("served_mixed", "serving.decode.step_seconds", DECODE_LEGS),
 ])
 def test_children_do_not_sum_past_their_parent(request, run, parent,
                                                children):
@@ -268,6 +283,47 @@ def test_the_legs_of_a_decode_step_belong_to_two_steps(served):
     assert sent["args"]["bucket"] == 8 and sent["args"]["prompt"] == 3
     assert sent["ts"] < steps[0]["ts"] < read["ts"] < steps[1]["ts"]
     assert by_id[read["args"]["id"]] is read
+
+
+def test_a_prompt_rides_the_first_step_of_a_mixed_model(served_mixed):
+    """The same request where the prefill bucket's program is the mixed
+    step: five calls — the first dispatches the prompt's mixed step
+    (nothing live rides it, nothing is in flight to read), the second
+    the first plain step and reads the mixed one under `serve.prefill`,
+    feeding `serving.prefill_seconds` and NOT the step's period, the
+    last reads alone.  The step's histograms count what they counted."""
+    hists, events = served_mixed
+    assert hists["serving.decode.step_seconds"]["count"] == 4
+    for leg in DECODE_LEGS[:2]:   # the call that read the mixed step: none
+        assert hists[leg]["count"] == 2
+    for leg in DECODE_LEGS[2:]:
+        assert hists[leg]["count"] == 3
+    assert hists["serving.prefill_seconds"]["count"] == 1
+    steps = sorted((e for e in events if e["name"] == "serve.decode_step"),
+                   key=lambda e: e["ts"])
+    legs = [sorted(e["name"] for e in events
+                   if e["args"].get("parent") == step["args"]["id"])
+            for step in steps]
+    ahead = ["decode.dispatch", "decode.pack"]
+    behind = ["decode.d2h", "decode.device_wait", "decode.emit"]
+    assert legs == [["serve.prefill_dispatch"], ahead,
+                    sorted(ahead + behind), sorted(ahead + behind), behind]
+    assert [(s["args"]["program"], s["args"]["rows"], s["args"]["bucket"])
+            for s in steps] == [("mixed", 0, 2), ("decode", 1, 1),
+                                ("decode", 1, 1), ("decode", 1, 1),
+                                ("decode", 0, 0)]
+    # flight 1 the mixed step, 2-4 the plain ones
+    assert [(s["args"]["seq"], s["args"]["landed"]) for s in steps] == [
+        (1, 0), (2, 0), (3, 2), (4, 3), (0, 4)]
+    (sent,) = [e for e in events if e["name"] == "serve.prefill_dispatch"]
+    (read,) = [e for e in events if e["name"] == "serve.prefill"]
+    assert sent["args"]["bucket"] == 8 and sent["args"]["prompt"] == 3
+    assert read["args"]["seq"] == 1 and read["args"]["bucket"] == 8
+    assert steps[1]["ts"] < read["ts"] < steps[2]["ts"]
+    sends = sorted((e for e in events if e["name"] == "decode.dispatch"
+                    and e["args"].get("seq")), key=lambda e: e["ts"])
+    assert [(e["args"]["kind"], e["args"]["rows"]) for e in sends] == [
+        ("mixed", 0), ("decode", 1), ("decode", 1), ("decode", 1)]
 
 
 @pytest.mark.parametrize("run,child,parent", [
@@ -406,14 +462,16 @@ def test_parent_stacks_are_per_thread():
 # ----------------------------------------------------------------------
 # (e) the run-ahead's own counter
 # ----------------------------------------------------------------------
+@pytest.mark.parametrize("two_programs", [True, False])
 def test_runahead_steps_count_the_steps_dispatched_before_a_read(
-        fresh_telemetry):
+        fresh_telemetry, two_programs):
     """`serving.decode.runahead_steps` grows when a step is dispatched
     with a row whose token the host has not read — never past
     `serving.decode.dispatches`, and with two live sessions by every
     step: the first follows the unread prefills, each later one the
-    unread step before it."""
-    lm, params = _lm_and_params()
+    unread step before it.  The second prompt's mixed step carries the
+    first session's row: one dispatch more, run ahead like the rest."""
+    lm, params = _lm_and_params(two_programs=two_programs)
     gs = GenerativeSession("lm", lm, params, max_sessions=2, max_len=16,
                            seq_buckets=[8])
     try:
@@ -426,7 +484,9 @@ def test_runahead_steps_count_the_steps_dispatched_before_a_read(
         steps = telemetry.counter_value("serving.decode.dispatches")
         assert 0 < ahead <= steps
         # budgets 5 and 6: the prefill's token, then 5 dispatched steps
-        assert (ahead, steps) == (5, 5)
+        assert (ahead, steps) == ((5, 5) if two_programs else (6, 6))
+        assert telemetry.counter_value("serving.prefill.rider_rows") == (
+            0 if two_programs else 1)
         assert telemetry.counter_value("serving.decode.tokens") == 4 + 5
         assert telemetry.counter_value("serving.decode.dropped_rows") == 0
     finally:
@@ -436,16 +496,22 @@ def test_runahead_steps_count_the_steps_dispatched_before_a_read(
 # ----------------------------------------------------------------------
 # (f) KV positions reserved and used
 # ----------------------------------------------------------------------
+@pytest.mark.parametrize("two_programs", [True, False])
 @pytest.mark.parametrize("sessions", [1, 2])
 def test_kv_position_counters_grow_with_every_decode_step(
-        fresh_telemetry, sessions):
-    lm, params = _lm_and_params()
+        fresh_telemetry, sessions, two_programs):
+    lm, params = _lm_and_params(two_programs=two_programs)
     gs = GenerativeSession("lm", lm, params, max_sessions=2, max_len=16,
                            seq_buckets=[8])
     try:
         reqs = [GenerateRequest("lm", [3, 4, 5][:2 + i], 60.0, 6)
                 for i in range(sessions)]
         assert gs.admit(reqs) == []
+        if not two_programs:
+            # the first prompt's mixed step carries no row: no decode
+            # dispatch, nothing reserved or used
+            gs.decode_step()
+            assert telemetry.counter_value("kv.reserved_positions") == 0
         reserved = used = 0
         for step in range(3):
             fed = sum(s.fed for s in gs._active)
@@ -491,8 +557,10 @@ class _FakeChip:
             time=real.time))
         launch = gs._launch
 
-        def fake_launch(exe, fn, state, data, slot, length, logits):
-            small, state = launch(exe, fn, state, data, slot, length, logits)
+        def fake_launch(exe, fn, state, data, slot, length, logits,
+                        **riders):
+            small, state = launch(exe, fn, state, data, slot, length, logits,
+                                  **riders)
             self.now += self.DISPATCH
             done = (max(self.free_at, self.now)
                     + (self.PREFILL if data.shape[1] > 1 else self.STEP))
@@ -522,12 +590,25 @@ class _FakeOut:
         return np.asarray(self.real)
 
 
-@pytest.fixture
-def chip_session(fresh_telemetry, monkeypatch):
-    lm, params = _lm_and_params()
+def _chip_session(monkeypatch, two_programs):
+    lm, params = _lm_and_params(two_programs=two_programs)
     gs = GenerativeSession("lm", lm, params, max_sessions=2, max_len=16,
                            seq_buckets=[8])
-    chip = _FakeChip(monkeypatch, gs)
+    return gs, _FakeChip(monkeypatch, gs)
+
+
+@pytest.fixture
+def chip_session(fresh_telemetry, monkeypatch):
+    """A session that keeps two programs on the made-up chip."""
+    gs, chip = _chip_session(monkeypatch, two_programs=True)
+    yield gs, chip
+    gs.close()
+
+
+@pytest.fixture
+def mixed_chip_session(fresh_telemetry, monkeypatch):
+    """A session with the mixed step on the made-up chip."""
+    gs, chip = _chip_session(monkeypatch, two_programs=False)
     yield gs, chip
     gs.close()
 
@@ -632,6 +713,41 @@ def test_a_prefill_between_two_steps_is_charged_to_the_prefill(chip_session):
     assert _device("decode_seconds") == (3, 12.0)
     assert telemetry.counter_value("serving.device.prefill_positions") == 16
     assert _flights() == (5, 5)
+
+
+def test_a_mixed_step_is_charged_to_the_prefill_whole(mixed_chip_session):
+    """(d') The same on a model with the mixed step: the second prompt's
+    program carries the first session's row, and ALL its 10 ms go to
+    `prefill_seconds` and `.8` — `decode_seconds` stays the plain steps'
+    — while its row counts as a decode dispatch, a rider and a token."""
+    gs, chip = mixed_chip_session
+    assert gs.admit([GenerateRequest("lm", [3, 4, 5], 60.0, 8)]) == []
+    assert not gs._flights and len(gs._pending) == 1
+    gs.decode_step()   # the mixed step out, alone: nothing rides, nothing read
+    assert [f.prog.kind for f in gs._flights] == ["prefill"]
+    gs.decode_step()   # step 1 out; the mixed step read
+    gs.decode_step()   # step 2 out; step 1 read
+    assert _device("prefill_seconds") == (1, 10.3)
+    assert _device("decode_seconds.1") == (1, 4.0)
+    assert telemetry.counter_value("serving.decode.dispatches") == 2
+    assert gs.admit([GenerateRequest("lm", [6, 7], 60.0, 8)]) == []
+    gs.decode_step()   # the second mixed step out, one rider; step 2 read
+    assert telemetry.counter_value("serving.decode.dispatches") == 3
+    assert telemetry.counter_value("serving.decode.tokens") == 2
+    gs.decode_step()   # step 3 (2 rows) out; the mixed step read
+    assert telemetry.counter_value("serving.decode.tokens") == 3
+    gs.decode_step()   # step 4 out; step 3 read
+    assert _device("prefill_seconds") == (2, 20.3)
+    assert _device("prefill_seconds.8") == (2, 20.3)
+    assert _device("decode_seconds.1") == (2, 8.0)
+    assert _device("decode_seconds.2") == (1, 4.0)
+    assert _device("decode_seconds") == (3, 12.0)
+    assert telemetry.counter_value("serving.device.prefill_positions") == 16
+    assert telemetry.counter_value("serving.device.decode_seen") == 3
+    assert _flights() == (5, 5)
+    assert [telemetry.counter_value("serving.prefill." + n)
+            for n in ("mixed", "rider_rows")] == [1, 1]
+    assert telemetry.counter_value("serving.decode.sessions") == 2
 
 
 @pytest.mark.parametrize("how", ["warm", "run", "drain", "finish_all"])
@@ -789,6 +905,33 @@ def test_the_tool_joins_a_flight_to_its_module_and_holds_the_estimate(
     assert report["prefill"]["estimate_minus_module_ms"]["mean"] == (
         pytest.approx(0.0004))
     assert gaps_s == 0.0
+
+
+def test_the_tool_takes_a_flights_kind_from_its_dispatch_span():
+    """A trace of this program: `mx:decode.dispatch` says what it sent —
+    `kind` (``mixed``: a prefill bucket's program that is the mixed step)
+    and `bucket` — so a mixed flight is a row of its own kind, whose
+    device ops `--ops mixed.8` sums, even where the `serve.prefill` span
+    that read it fell outside the trace."""
+    from tools import device_time_check as tool
+
+    spans, enqueues, modules = _synthetic_trace()
+    kinds = {1: ("mixed", 8), 2: ("decode", 1), 3: ("decode", 1),
+             4: ("decode", 1)}
+    spans["mx:decode.dispatch"] = [
+        sp + (kinds[sp[2]][0],) for sp in (
+            sp[:4] + (kinds[sp[2]][1],)
+            for sp in spans["mx:decode.dispatch"])]
+    spans["mx:serve.prefill"] = []
+    rows = tool.join(spans, enqueues, modules)
+    assert [(r["seq"], r["kind"], r["bucket"]) for r in rows] == [
+        (1, "mixed", 8), (2, "decode", 1), (3, "decode", 1),
+        (4, "decode", 1)]
+    report, _ = tool.compare(rows, floor_s=100e-9)
+    assert report["mixed"]["flights"] == 1 and "prefill" not in report
+    ops = [(1_000, 9_000, "%fusion.9 = f32[8]{0} fusion(%p.0)")]
+    assert tool.ops_by_name(rows, ops, "mixed", 8)["runs"] == 1
+    assert tool.ops_by_name(rows, ops, "prefill", 8)["runs"] == 0
 
 
 def test_the_tool_sums_a_programs_device_ops_by_name():

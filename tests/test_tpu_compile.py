@@ -205,7 +205,12 @@ def test_the_delta_rule_prefill_compiles_for_a_v5e(widths, bucket, one_chip):
 def _serving_program(graph, wire, one_chip):
     """The serving `graph` compiled for the described chip as a session
     compiles it: `wire` ({name: shape}: the call's inputs and the cache
-    entries) donated, every other argument a weight."""
+    entries) donated AS A TUPLE IN ITS ORDER — JAX gives a donated buffer
+    to the first output of its shape, so the entries have to come in the
+    order the graph returns them, as `GenerativeSession._launch` passes
+    them; a dict would be sorted by name, and of two layers' rings of one
+    shape each would be given the other's output and copied — every
+    other argument a weight."""
     import jax
     import jax.numpy as jnp
 
@@ -214,9 +219,10 @@ def _serving_program(graph, wire, one_chip):
 
     order = _topo_order(graph._entries)
     names = graph.list_arguments()
+    wired = [n for n in wire if n in names]
 
     def program(state, weights):
-        vals = {**state, **weights}
+        vals = {**dict(zip(wired, state)), **weights}
         outs, _ = _run_graph(graph._entries, order, names, [],
                              tuple(vals[n] for n in names), (), False,
                              jax.random.key(0))
@@ -226,8 +232,34 @@ def _serving_program(graph, wire, one_chip):
     args = {n: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
             for n, s in zip(names, shapes)}
     return jax.jit(program, donate_argnums=(0,)).lower(
-        {n: a for n, a in args.items() if n in wire},
+        tuple(args[n] for n in wired),
         {n: a for n, a in args.items() if n not in wire}).compile()
+
+
+def _wire(spec, rows, prompt=None):
+    """The inputs of a serving graph in a session's wire order: a decode
+    step of `rows` rows, or — `prompt` positions — the mixed step of that
+    bucket with `rows` riders."""
+    slots = next(iter(spec.values())).shape[0]
+    if prompt is None:
+        small = dict(data=(rows, 1), slot=(rows,), length=(rows,))
+    else:
+        small = dict(data=(1, prompt), slot=(1,), length=(1,),
+                     row_data=(rows, 1), row_slot=(rows,),
+                     row_length=(rows,))
+    return dict(small, **{n: e.shape for n, e in spec.items()},
+                last_token=(slots,))
+
+
+def _smoke_model(index):
+    """`chip_smoke.py`'s kv_ring model `index` at its full size."""
+    from mxnet_tpu.models import TransformerLM
+
+    sizes = chip_smoke.FULL["kv_ring"]
+    shape = {k: v for k, v in sizes["shapes"][index].items()
+             if k not in ("seq_buckets", "max_sessions")}
+    return TransformerLM(**{**{k: sizes[k] for k in (
+        "vocab", "num_layers", "d_model", "d_ff")}, **shape})
 
 
 # name -> (chip_smoke.py's kv_ring model, rows, heads a grid step)
@@ -249,24 +281,15 @@ def test_the_delta_rule_step_compiles_for_a_v5e(widths, one_chip):
     and back around the kernel."""
     import warnings
 
-    from mxnet_tpu.models import TransformerLM
-
     index, rows, heads = STEP_MODELS[widths]
-    sizes = chip_smoke.FULL["kv_ring"]
-    shape = {k: v for k, v in sizes["shapes"][index].items()
-             if k not in ("seq_buckets", "max_sessions")}
-    lm = TransformerLM(**{**{k: sizes[k] for k in (
-        "vocab", "num_layers", "d_model", "d_ff")}, **shape})
+    lm = _smoke_model(index)
     spec = lm.cache_spec(rows + 1)
     state = spec["gdn_state_0"].shape
     assert gdn.step_heads(state, lm.linear_value_dim, "tpu") == heads
-    wire = dict(data=(rows, 1), slot=(rows,), length=(rows,),
-                last_token=(rows + 1,),
-                **{n: e.shape for n, e in spec.items()})
     gdn._state_step.clear_cache()
     with warnings.catch_warnings():   # the small inputs are not donated
         warnings.simplefilter("ignore")
-        text = _serving_program(lm.decode_symbol(), wire,
+        text = _serving_program(lm.decode_symbol(), _wire(spec, rows),
                                 one_chip).as_text()
     assert chip_smoke.delta_step_hlo_facts(text, rows, state) == {
         "kernel_calls": 1, "row_pages": []}
@@ -287,22 +310,15 @@ def test_the_latent_decode_program_compiles_for_a_v5e(one_chip):
     import re
     import warnings
 
-    from mxnet_tpu.models import TransformerLM
-
-    sizes, rows = chip_smoke.FULL["kv_ring"], 16
-    shape = {k: v for k, v in sizes["shapes"][6].items()
-             if k not in ("seq_buckets", "max_sessions")}
-    lm = TransformerLM(**{**{k: sizes[k] for k in (
-        "vocab", "num_layers", "d_model", "d_ff")}, **shape})
+    rows = 16
+    lm = _smoke_model(6)
     spec = lm.cache_spec(rows + 1)
     ring = spec["latent_cache_0"].shape
     assert ring == (17, 1, 320, 6144) and len(spec) == 2
-    wire = dict(data=(rows, 1), slot=(rows,), length=(rows,),
-                last_token=(rows + 1,),
-                **{n: e.shape for n, e in spec.items()})
+    assert lm.mixed_symbol(rows) is None   # it keeps its two programs
     with warnings.catch_warnings():   # the small inputs are not donated
         warnings.simplefilter("ignore")
-        text = _serving_program(lm.decode_symbol(), wire,
+        text = _serving_program(lm.decode_symbol(), _wire(spec, rows),
                                 one_chip).as_text()
     facts = chip_smoke.ring_hlo_facts(text, ring)
     assert facts["kernel_calls"] == 2
@@ -312,18 +328,65 @@ def test_the_latent_decode_program_compiles_for_a_v5e(one_chip):
     assert not re.search(r"f32\[[\d,]*\b32,[\d,]*6144\]", text)
 
 
+# name -> (chip_smoke.py's kv_ring model, riders, the prompt's bucket, the
+# kernel calls a mixed step of it holds: the riders' ring kernel an
+# attention layer, and a delta-rule layer's chunked AND step kernel)
+MIXED_MODELS = {"opt": (0, 8, 64, 2), "olmo_hybrid": (3, 8, 2048, 1 + 2),
+                "trinity": (4, 8, 2048, 2), "qwen3_next": (5, 16, 2048, 1 + 2)}
+
+
+@pytest.mark.parametrize("name", sorted(MIXED_MODELS))
+def test_a_mixed_step_compiles_for_a_v5e(name, one_chip):
+    """The mixed step of `chip_smoke.py`'s models — a prompt's bucket AND
+    the slots' rows in one program — lowered for the TPU: the riders' ring
+    kernel once an attention layer and a delta-rule layer's two kernels,
+    every ring and state a parameter aliased to its output and never
+    copied, and of ring-shaped `dynamic-update-slice`s only the prompt's
+    block into K and into V (a rider's row is written inside the kernel)."""
+    import re
+    import warnings
+
+    index, rows, bucket, kernels = MIXED_MODELS[name]
+    lm = _smoke_model(index)
+    spec = lm.cache_spec(rows + 1)
+    gdn._delta_rule.clear_cache()
+    gdn._state_step.clear_cache()
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        text = _serving_program(lm.mixed_symbol(rows),
+                                _wire(spec, rows, prompt=bucket),
+                                one_chip).as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    floor = sum(e.nbytes for e in spec.values()) // 100
+    entry = text[text.index("ENTRY"):]
+    for shape in sorted({e.shape for e in spec.values()
+                         if e.nbytes >= floor}):
+        count = sum(e.shape == shape for e in spec.values())
+        facts = chip_smoke.ring_hlo_facts(text, shape)
+        assert facts["ring_params"] == facts["aliased"] == count, shape
+        assert facts["copies"] == [], shape
+        dims = re.escape(",".join(str(d) for d in shape))
+        writes = len(re.findall(
+            r"= f32\[%s\]\S* dynamic-update-slice\(" % dims, entry))
+        rings = sum(e.shape == shape and e.kind == "ring"
+                    for e in spec.values())
+        assert writes <= rings, (shape, writes)
+
+
 OPT_BUCKETS = [64, 128, 256, 512]  # benchmarks/traffic/gen_closed_c16.json
 
 
-@pytest.fixture(scope="module")
-def opt_prefill_cycles(one_chip):
-    """OPT-1.3B's prefill graph (the benchmark's configuration: twelve
-    layers at the published widths, nine pages of 768) jitted for the
+@pytest.fixture(scope="module", params=["prefill", "mixed"])
+def opt_prefill_cycles(request, one_chip):
+    """OPT-1.3B's prefill graph, and the mixed step that takes its place
+    in a session (the benchmark's configuration: twelve layers at the
+    published widths, nine pages of 768, eight riders) jitted for the
     described v5e at each of the cell's buckets: {bucket: (the sum of
     the compiler's own `estimated_cycles` over the program's ops, the
     largest single one)}."""
     import json
     import re
+    import warnings
 
     from benchmarks.families import opt
 
@@ -331,13 +394,15 @@ def opt_prefill_cycles(one_chip):
     with open(os.path.join(root, "benchmarks", "configs",
                            "opt-1.3b.json")) as f:
         lm = opt.model(json.load(f))
-    graph = lm.prefill_symbol()
+    mixed = request.param == "mixed"
+    graph = lm.mixed_symbol(SLOTS - 1) if mixed else lm.prefill_symbol()
     spec = lm.cache_spec(SLOTS, 768)
     cycles = {}
     for t in OPT_BUCKETS:
-        wire = dict(data=(1, t), slot=(1,), length=(1,), last_token=(SLOTS,),
-                    **{n: e.shape for n, e in spec.items()})
-        text = _serving_program(graph, wire, one_chip).as_text()
+        wire = _wire(spec, SLOTS - 1, prompt=t)
+        with warnings.catch_warnings():   # the small inputs are not donated
+            warnings.simplefilter("ignore")
+            text = _serving_program(graph, wire, one_chip).as_text()
         found = [int(c) for c in re.findall(
             r'"estimated_cycles":"(\d+)"', text[text.index("ENTRY"):])]
         cycles[t] = (sum(found), max(found))

@@ -31,13 +31,23 @@ from mxnet_tpu.models import TransformerLM
 from mxnet_tpu.serving import GenerateRequest, GenerativeSession, ServerClosed
 
 
+class TwoProgramLM(TransformerLM):
+    """A model that offers no mixed step (as one with a latent-attention
+    layer offers none): its session keeps a prefill program of its own
+    beside the decode ladder, dispatched at admission — the batcher every
+    model had before PR 46."""
+
+    mixed_symbol = None
+
+
 def _lm_and_params(vocab=24, num_layers=2, num_heads=2, d_model=16,
-                   max_len=32, seed=0):
+                   max_len=32, seed=0, two_programs=False):
     """A tiny TransformerLM plus a randomly-initialized checkpoint in
-    the plain-name form GenerativeSession consumes (arg+aux merged)."""
-    lm = TransformerLM(vocab=vocab, num_layers=num_layers,
-                       num_heads=num_heads, d_model=d_model,
-                       max_len=max_len)
+    the plain-name form GenerativeSession consumes (arg+aux merged).
+    `two_programs`: the same model without a mixed step."""
+    lm = (TwoProgramLM if two_programs else TransformerLM)(
+        vocab=vocab, num_layers=num_layers, num_heads=num_heads,
+        d_model=d_model, max_len=max_len)
     mx.random.seed(seed)
     mod = mx.mod.Module(lm.training_symbol(), data_names=("data",),
                         label_names=("softmax_label",), context=mx.cpu())
@@ -236,13 +246,16 @@ def test_runahead_matches_one_at_a_time_greedy_decode():
     assert_runahead_matches_one_at_a_time(lm, params)
 
 
+@pytest.mark.parametrize("two_programs", [True, False])
 @pytest.mark.parametrize("budgets", [[4], [3, 6, 2, 5]])
-def test_every_dispatched_flight_is_counted_once_when_it_lands(budgets):
+def test_every_dispatched_flight_is_counted_once_when_it_lands(
+        budgets, two_programs):
     """`serving.device.flights` is the prefills plus the decode steps
-    dispatched, whatever the fences saw of them: on a real (CPU) session
+    dispatched — a mixed step that carried a live row is ONE flight that
+    is both —, whatever the fences saw of them: on a real (CPU) session
     most return at once, so few flights are SEEN, never more than
     landed, and what the means divide by grows with every flight."""
-    lm, params = _lm_and_params(seed=5)
+    lm, params = _lm_and_params(seed=5, two_programs=two_programs)
     rng = np.random.RandomState(3)
     prompts = [rng.randint(0, lm.vocab, size=4).tolist() for _ in budgets]
     prev = telemetry.set_enabled(True)
@@ -254,7 +267,12 @@ def test_every_dispatched_flight_is_counted_once_when_it_lands(budgets):
                     for p, b in zip(prompts, budgets)])
         steps = telemetry.counter_value("serving.decode.dispatches")
         flights = telemetry.counter_value("serving.device.flights")
-        assert flights == len(budgets) + steps == gs._seq
+        mixed = telemetry.counter_value("serving.prefill.mixed")
+        # a later prompt finds the other slot's session live, unless
+        # that session's last row is in flight already
+        assert mixed == 0 if two_programs or len(budgets) == 1 \
+            else 0 < mixed < len(budgets)
+        assert flights == len(budgets) + steps - mixed == gs._seq
         assert steps >= max(budgets) - 1
         seen = telemetry.counter_value("serving.device.seen_flights")
         assert 0 <= seen <= flights
@@ -299,10 +317,17 @@ def test_eos_mid_run_drops_the_row_in_flight_and_the_slot_serves_on():
     assert second.finish_reason == "length"
 
 
-def test_finish_all_with_a_step_in_flight_keeps_the_tokens_computed():
+@pytest.mark.parametrize("two_programs,sampled", [
+    # two prefills at admission, then three steps of both rows
+    (True, (4, 4)),
+    # the first prompt alone, the second with the first's row riding,
+    # then one step of both rows
+    (False, (3, 2))])
+def test_finish_all_with_a_step_in_flight_keeps_the_tokens_computed(
+        two_programs, sampled):
     """close(drain=False) between a dispatch and its read: every future
     resolves 'closed', and with every token the device had sampled."""
-    lm, params = _lm_and_params(seed=4)
+    lm, params = _lm_and_params(seed=4, two_programs=two_programs)
     rng = np.random.RandomState(9)
     prompts = [rng.randint(0, lm.vocab, size=4).tolist() for _ in range(2)]
     gs = GenerativeSession("lm", lm, params, max_sessions=2,
@@ -312,14 +337,14 @@ def test_finish_all_with_a_step_in_flight_keeps_the_tokens_computed():
     for _ in range(3):
         gs.decode_step()
     (flight,) = gs._flights
-    assert not flight.prefill and len(flight.rows) == 2
+    assert flight.prog.kind == "decode" and len(flight.rows) == 2
     gs.close()
     assert not gs._flights and gs.free_slots() == 2
-    for p, r in zip(prompts, reqs):
+    for p, r, n in zip(prompts, reqs, sampled):
         out = r.future.result(timeout=0)
         assert out.finish_reason == "closed"
-        # the prefill's token and one a dispatched step
-        assert out.tokens.tolist() == _greedy_reference(lm, params, p, 4)
+        # the prefill's token and one a dispatched row
+        assert out.tokens.tolist() == _greedy_reference(lm, params, p, n)
 
 
 def test_a_failing_step_with_one_in_flight_loses_no_future():
@@ -338,13 +363,14 @@ def test_a_failing_step_with_one_in_flight_loses_no_future():
         server.warmup()
         launch, tripped = gs._launch, []
 
-        def flaky(exe, fn, state, data, slot, length, logits):
+        def flaky(exe, fn, state, data, slot, length, logits, **riders):
             # the first step that packs both sessions with both tokens
             # still on the device: their previous step is in flight
             if not tripped and data.shape == (2, 1) and (data < 0).all():
                 tripped.append(1)
                 raise RuntimeError("injected step failure")
-            return launch(exe, fn, state, data, slot, length, logits)
+            return launch(exe, fn, state, data, slot, length, logits,
+                          **riders)
 
         gs._launch = flaky
         futs = [server.submit_generate("lm", p, max_new_tokens=12)
@@ -969,8 +995,9 @@ def test_decode_program_touches_a_ring_only_by_row_updates(bucket):
         page_makers
     on_tpu = [eqn for eqn in _walk_eqns(jaxpr.jaxpr, "tpu")
               if any(tuple(out.aval.shape) == ring for out in eqn.outvars)]
+    # the kernel, lowered once a shape and called exported (ops/exported.py)
     assert [eqn.primitive.name for eqn in on_tpu] == \
-        ["pallas_call"] * lm.num_layers
+        ["call_exported"] * lm.num_layers
 
 
 @pytest.mark.parametrize("ring,platform,block,heads", [
@@ -1001,20 +1028,24 @@ def test_decode_block_is_read_off_the_rings_shape_and_the_platform(
     assert decode_heads(ring) == heads
 
 
+@pytest.mark.parametrize("two_programs", [True, False])
 @pytest.mark.parametrize("block", [None, 128])
-def test_page_and_skipped_position_counters(block, monkeypatch):
+def test_page_and_skipped_position_counters(block, two_programs,
+                                            monkeypatch):
     """Per decode step `kv.page_positions` grows by ``max_len`` a packed
     row and `kv.skipped_positions` by what the dispatched program's
     attention does not read of those pages: nothing where it reads whole
     pages (the CPU's program: `block` None), everything beyond the block
-    that holds `length` where it reads by blocks."""
+    that holds `length` where it reads by blocks.  A row that rides a
+    mixed step counts like any other: the same rows at the same lengths,
+    in one dispatch more."""
     from mxnet_tpu.ops import attention
 
     max_len = 256
     if block:  # what a session on a TPU is told; the counters are host side
         monkeypatch.setattr(attention, "decode_block",
                             lambda shape, platform, itemsize=4: block)
-    lm, params = _lm_and_params(max_len=max_len)
+    lm, params = _lm_and_params(max_len=max_len, two_programs=two_programs)
     telemetry.set_enabled(True)
     names = ("kv.page_positions", "kv.skipped_positions",
              "kv.used_positions", "serving.decode.dispatches")
@@ -1032,7 +1063,8 @@ def test_page_and_skipped_position_counters(block, monkeypatch):
         gs.close()
     moved = {n: telemetry.counter_value(n) - before[n] for n in names}
     lengths = [5 + i for i in range(5)] + [126 + i for i in range(5)]
-    assert moved["serving.decode.dispatches"] == 5
+    # the second prompt's mixed step carries the first session's row
+    assert moved["serving.decode.dispatches"] == 5 + (not two_programs)
     assert moved["kv.used_positions"] == sum(lengths)
     assert moved["kv.page_positions"] == len(lengths) * max_len
     read = sum((n // block + 1) * block for n in lengths) if block else \

@@ -412,21 +412,22 @@ def test_the_batcher_books_rings_of_two_lengths(held):
         telemetry.set_enabled(was)
     for r in reqs:
         assert len(r.future.result(timeout=5).tokens) == 6
-    steps = moved["serving.decode.dispatches"]
-    assert steps == 5
-    lengths = [[5 + s, 20 + s] for s in range(steps)]
+    # five rows a session: the first session's first rides the second
+    # prompt's mixed step, so its last leaves the second's alone
+    assert moved["serving.decode.dispatches"] == 6
+    lengths = [p + s for p in (5, 20) for s in range(5)]
     mean = lambda per_ring: sum(per_ring) / 10.0   # noqa: E731 (8 + 2 rings)
     assert moved["kv.used_positions"] == pytest.approx(sum(
-        mean([min(n, W)] * 8 + [n] * 2) for row in lengths for n in row))
+        mean([min(n, W)] * 8 + [n] * 2) for n in lengths))
     assert moved["kv.page_positions"] == pytest.approx(
-        steps * 2 * mean([W] * 8 + [64] * 2))
+        len(lengths) * mean([W] * 8 + [64] * 2))
     assert moved["kv.skipped_positions"] == 0   # the CPU reads whole pages
-    assert moved["kv.window_rows"] == steps * 2 * 4
-    assert moved["kv.wrapped_rows"] == 4 * sum(
-        n >= W for row in lengths for n in row)
+    assert moved["kv.window_rows"] == len(lengths) * 4
+    assert moved["kv.wrapped_rows"] == 4 * sum(n >= W for n in lengths)
     assert 0 < moved["cache.window_bytes"] < moved["cache.reserved_bytes"]
     assert moved["cache.window_bytes"] * (4 * W + 64) == (
         moved["cache.reserved_bytes"] * 4 * W)
-    # prefills of buckets 8 and 32, then five 2-row steps
-    assert moved["moe.routed_pairs"] == 4 * 2 * (8 + 32 + steps * 2)
+    # mixed steps of buckets 8 and 32 with their two rows each, then four
+    # 2-row steps and a 1-row one
+    assert moved["moe.routed_pairs"] == 4 * 2 * (8 + 2 + 32 + 2 + 4 * 2 + 1)
     assert 0 < moved["moe.pairs"] < moved["moe.routed_pairs"]

@@ -21,15 +21,18 @@ The join (docs/observability.md "Device time without a profiler"):
   attribute, the fingerprint is the runtime's own);
 * `mx:decode.device_wait{seq}` is the flight's fence: its end is
   `ready(k)`, the dispatch span's end `enqueued(k)`;
-* which program a flight ran: `mx:serve.decode_step{seq, bucket}` names
-  the step it dispatched, `mx:serve.prefill{seq, bucket}` the prefill it
-  read;
+* which program a flight ran: `mx:decode.dispatch{kind, bucket}` says it
+  (`decode` and its rows, `prefill` and its positions, or `mixed` — a
+  prefill bucket's program that is the mixed step, PR 46 — and its
+  positions); in a trace from before those attributes,
+  `mx:serve.decode_step{seq, bucket}` names the step it dispatched and
+  `mx:serve.prefill{seq, bucket}` the prefill it read;
 * where a trace has no `DoEnqueueProgram` (host level 0), the flight's
   run is the `XLA Modules` event of its program that ended last before
   the fence returned — right for every flight whose fence blocked.
 
-`--ops prefill.256` (repeatable) prints the device ops of that program
-summed by name — each run's `XLA Ops` events inside its `XLA Modules`
+`--ops prefill.256` / `--ops mixed.256` / `--ops decode.8` (repeatable)
+prints the device ops of that program summed by name — each run's `XLA Ops` events inside its `XLA Modules`
 interval, self times, as ms a run — and writes the program's optimised
 HLO beside the report (`device_time_<cell>.<kind>.<bucket>.hlo`): which
 op of a bucket's program takes its time, and what the compiler made of
@@ -64,7 +67,7 @@ OPS_SHOWN = 12  # lines of an --ops table on the terminal; the file has all
 
 def read_trace(path):
     """(spans, enqueues, modules, ops) of one xplane: `spans[name]` =
-    [(start_ns, end_ns, seq, program, bucket)], `enqueues` = [(start_ns,
+    [(start_ns, end_ns, seq, program, bucket, kind)], `enqueues` = [(start_ns,
     run_id)], `modules` = [(start_ns, end_ns, name, run_id)] of the
     first chip, `ops` = [(start_ns, end_ns, name)] of its `XLA Ops`, by
     start."""
@@ -91,7 +94,8 @@ def read_trace(path):
                     stats = dict(ev.stats)
                     spans[ev.name].append((start, end, int(stats["seq"]),
                                            str(stats.get("program", "")),
-                                           int(stats.get("bucket", 0))))
+                                           int(stats.get("bucket", 0)),
+                                           str(stats.get("kind", ""))))
                 elif ev.name == ENQUEUE:
                     enqueues.append((start, dict(ev.stats).get("run_id")))
     enqueues.sort(key=lambda e: e[0])
@@ -138,14 +142,16 @@ def join(spans, enqueues, modules):
     None}."""
     by_run = {m[3]: m for m in modules if m[3] is not None}
     launched = [at for at, _rid in enqueues]  # sorted, as `enqueues` is
-    fences = {seq: (s, e, prog) for s, e, seq, prog, _bucket
-              in spans["mx:decode.device_wait"]}
-    ran = {seq: ("decode", bucket) for _s, _e, seq, _p, bucket
-           in spans["mx:serve.decode_step"] if seq}
-    ran.update({seq: ("prefill", bucket) for _s, _e, seq, _p, bucket
-                in spans["mx:serve.prefill"]})
+    fences = {sp[2]: (sp[0], sp[1], sp[3])
+              for sp in spans["mx:decode.device_wait"]}
+    ran = {sp[2]: ("decode", sp[4])
+           for sp in spans["mx:serve.decode_step"] if sp[2]}
+    ran.update({sp[2]: ("prefill", sp[4]) for sp in spans["mx:serve.prefill"]})
+    # a dispatch span that says its program's kind says it best
+    ran.update({sp[2]: (sp[5], sp[4])
+                for sp in spans["mx:decode.dispatch"] if sp[5:] and sp[5]})
     rows = []
-    for start, end, seq, _prog, _bucket in sorted(
+    for start, end, seq, *_ in sorted(
             spans["mx:decode.dispatch"], key=lambda sp: sp[2]):
         if seq not in fences:
             continue
@@ -299,7 +305,9 @@ def main(argv=None):
 
     def remembered_program(self, pred, batch, seq, prefill):
         exe, fn = program_of(self, pred, batch, seq, prefill)
-        programs[("prefill", seq) if prefill else ("decode", batch)] = fn
+        kind = "decode" if not prefill else (
+            "mixed" if self._mixed else "prefill")
+        programs[kind, seq if prefill else batch] = fn
         return exe, fn
 
     decode.GenerativeSession._program = remembered_program
