@@ -47,7 +47,10 @@ apart from the rest:
             delta-rule models' decode step; and the 2,048-bucket
             prefill of the fourth and the sixth: ONE kernel call a
             delta-rule layer
-            (ops/gdn_kernel.py) and no triangular solve left in it; and
+            (ops/gdn_kernel.py) and no triangular solve left in it; no
+            prefill program of a bucket whose attention is the blockwise
+            kernel (ops/sdp_kernel.py: the 2,048 buckets) may hold an
+            array of bucket x bucket scores, beside the ring copies; and
             every tenant's prefill bucket programs timed warm: none may
             run longer than 1.5 times the next larger bucket's (the
             first shape has OPT-1.3B's FFN of 8,192 and its four buckets)
@@ -557,7 +560,24 @@ def delta_rule_hlo_facts(text):
                 'custom_call_target="tpu_custom_call"' in line
                 and "gdn_state_step" not in line
                 and "kv_ring_attention" not in line
+                and "sdp_causal_attention" not in line
                 for line in text.splitlines())}
+
+
+def score_arrays(text, bucket):
+    """Every array in a compiled prefill program's optimised HLO `text`
+    of SEVERAL matrices of `bucket` x `bucket` (its last two dimensions
+    both the bucket, the ones before them more than 1 together): a
+    prompt's scores, or their mask spread over the heads, made whole —
+    what the blockwise kernel (``ops/sdp_kernel.py``) never writes, so in
+    a program of a bucket ``ops.attention.prefill_block`` sends through
+    it any such array is a fault.  (One matrix of that shape may be a
+    weight, or the activations of a model as wide as the bucket.)"""
+    found = re.findall(r"\b(\w+)\[((?:\d+,)+)%d,%d\]" % (bucket, bucket),
+                       text)
+    return sorted({"%s[%s%d,%d]" % (dtype, heads, bucket, bucket)
+                   for dtype, heads in found
+                   if math.prod(int(d) for d in heads.split(",")[:-1]) > 1})
 
 
 def delta_step_hlo_facts(text, rows, state_shape):
@@ -650,7 +670,7 @@ def phase_kv_ring(sizes, ctx):
     platform = ctx.jax_device().platform
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
              "layouts": [], "rings": [], "delta_rule": [], "delta_step": [],
-             "prefill_ms": [], "mixed_steps": 0}
+             "prefill_ms": [], "mixed_steps": 0, "kernel_buckets": 0}
     for shape in sizes["shapes"]:
         shape = dict(shape)
         buckets = shape.pop("seq_buckets", sizes["seq_buckets"])
@@ -722,22 +742,29 @@ def phase_kv_ring(sizes, ctx):
                     kernel_layers=scanned * stepped["gdn.step_kernel_bytes"]
                     // stepped["gdn.state_bytes"],
                     ms=float("%.3g" % decode_step_ms(session, slots)))
-            # where the prefill bucket programs are the mixed step (PR
-            # 46): each bucket's, as the warm-up compiled it
-            mixed = []
-            for t in buckets if session._mixed else ():
+            # each prefill bucket's program as the warm-up compiled it —
+            # the mixed step where the model has one (PR 46) —: a bucket
+            # the shape rule sends through the blockwise attention kernel
+            # (PR 47) holds no array of bucket x bucket scores
+            mixed, scores = [], {}
+            for t in buckets:
                 _exe, pre = session._program(session._prefill_pred, 1, t,
                                              True)
                 text = pre.hlo_text()
-                chunked = lm.call_counters(
-                    positions=t, platform=platform).get(
-                        "gdn.kernel_positions", 0) // t
+                booked = lm.call_counters(positions=t, platform=platform)
+                chunked = booked.get("gdn.kernel_positions", 0) // t
+                tiled = booked.get("attn.kernel_positions", 0) // t
+                if tiled and tiled == booked["attn.prefill_positions"] // t:
+                    scores[t] = score_arrays(text, t)
+                if not session._mixed:
+                    continue
                 seen = {"bucket": t, "ring_params": 0, "aliased": 0,
                         "copies": [], "kernel_calls": text.count(
                             'custom_call_target="tpu_custom_call"'),
-                        # the riders' ring kernel an attention layer, a
+                        # the riders' ring kernel and the prompt's
+                        # blockwise kernel an attention layer, a
                         # delta-rule layer's chunked and step kernels
-                        "kernels": ring_layers + chunked + (
+                        "kernels": ring_layers + tiled + chunked + (
                             stepped_by["kernel_layers"] if scanned else 0)}
                 for held_shape in sorted({e.shape for e in judged.values()}):
                     more = ring_hlo_facts(text, held_shape)
@@ -795,6 +822,14 @@ def phase_kv_ring(sizes, ctx):
                        "%(kernel_calls)d kernel calls in the %(bucket)d-"
                        "bucket mixed step, %(kernels)d expected" % seen)
         total["mixed_steps"] += len(mixed)
+        for t, fat in sorted(scores.items()):
+            print("[chip_smoke] kv_ring: the %d-bucket prefill's attention "
+                  "is the blockwise kernel; arrays of bucket x bucket: %s"
+                  % (t, fat), flush=True)
+            _check(not fat, "the %d-bucket prefill program, whose attention "
+                   "is the blockwise kernel, still holds its scores whole: "
+                   "%s" % (t, fat))
+        total["kernel_buckets"] += len(scores)
         print("[chip_smoke] kv_ring: prefill buckets, ms a warm program: "
               + ", ".join("%d: %.2f" % row for row in sorted(
                   prefill_ms.items())), flush=True)

@@ -187,6 +187,19 @@ def _join_rows(prompt, rows, name):
                       dim=1, name=name + "_tokens")
 
 
+def _prefill_counters(positions, width, heads, kv_heads, platform):
+    """What a prefill (or a mixed step) of a bucket of `positions` adds
+    for ONE layer whose prompt goes through ``_sdp_attention`` with a
+    query `width` wide: the bucket's positions (the pad included), and
+    those of them that a program lowered for `platform` runs through the
+    TPU's blockwise kernel (all, or none where
+    ``ops.attention.prefill_block`` says the ``jax.numpy`` body runs)."""
+    tiled = _attention.prefill_block((1, positions, width), heads, kv_heads,
+                                     platform) is not None
+    return {"attn.prefill_positions": positions,
+            "attn.kernel_positions": positions * tiled}
+
+
 class _Attention:
     """The attention mixer of layer i: fused QKV projection (and the
     output gate's, where the spec has one), QK-norm and rotary as the
@@ -314,6 +327,13 @@ class _Attention:
         return sym._sdp_attention(q, k, v, name="l%d_attn" % i,
                                   **self.sdp_attrs)
 
+    def counters(self, i, positions=0, platform=None, **call):
+        """What one program call adds: the bucket positions a prefill (or
+        a mixed step) attends among themselves in this layer, and those
+        of them the TPU's blockwise kernel takes (`_prefill_counters`)."""
+        return _prefill_counters(positions, self.q_width, self.lm.num_heads,
+                                 self.lm.num_kv_heads, platform)
+
     def full(self, x, p, i):
         q, k, v, gate = self._qkv(x, p, i)
         return self._out(self._attend(q, k, v, i)[0], gate, p, i)
@@ -394,15 +414,17 @@ class _WindowAttention(_Attention):
 
     def counters(self, i, rows=0, lengths=(), pages=0, max_len=None,
                  **call):
-        """What one decode step adds: a window row for each real row of
-        this layer, those of them whose ring has wrapped (``length >=
-        W``: the row is written modulo and the whole ring is read), and
-        the bytes of this layer's rings among the `pages` pages bound."""
+        """What every attention layer adds, and of one decode step: a
+        window row for each real row of this layer, those of them whose
+        ring has wrapped (``length >= W``: the row is written modulo and
+        the whole ring is read), and the bytes of this layer's rings
+        among the `pages` pages bound."""
         page = sum(e.nbytes for _, e in self.cache_spec(
             i, 1, self.lm.max_len if max_len is None else max_len))
         wrapped = sum(1 for n in lengths if n >= self.lm.sliding_window)
-        return {"kv.window_rows": rows, "kv.wrapped_rows": wrapped,
-                "cache.window_bytes": pages * page}
+        return dict(super().counters(i, **call), **{
+            "kv.window_rows": rows, "kv.wrapped_rows": wrapped,
+            "cache.window_bytes": pages * page})
 
 
 class _LatentAttention:
@@ -533,9 +555,12 @@ class _LatentAttention:
             name="l%d_attn" % i, **self.attrs)
         return self._out(step[0], p, i), [step[1]]
 
-    def counters(self, i, rows=0, lengths=(), computed=0, pages=0,
-                 max_len=None, platform=None, **call):
-        """What one decode step (a call of `computed` program rows) adds:
+    def counters(self, i, positions=0, rows=0, lengths=(), computed=0,
+                 pages=0, max_len=None, platform=None, **call):
+        """What a prefill of a bucket of `positions` adds: those
+        positions, attended among themselves up-projected, and those of
+        them the TPU's blockwise kernel takes (`_prefill_counters`).
+        What one decode step (a call of `computed` program rows) adds:
         a latent layer-step, and one served by the TPU's kernel where a
         program lowered for `platform` has it; the bytes of this layer's
         pages the step reads — by the kernel's blocks, up to the one that
@@ -550,10 +575,14 @@ class _LatentAttention:
         read = sum(min((n // at_a_time + 1) * at_a_time, ring)
                    for n in lengths)
         step = int(computed > 0)
-        return {"mla.layer_steps": step,
-                "mla.kernel_steps": step * (block is not None),
-                "mla.ring_bytes": 4 * self.width * read,
-                "cache.latent_bytes": pages * entry.nbytes}
+        heads = self.lm.num_heads
+        return dict(
+            _prefill_counters(positions, heads * (self.nope + self.rope),
+                              heads, heads, platform),
+            **{"mla.layer_steps": step,
+               "mla.kernel_steps": step * (block is not None),
+               "mla.ring_bytes": 4 * self.width * read,
+               "cache.latent_bytes": pages * entry.nbytes})
 
 
 class _Recurrent:

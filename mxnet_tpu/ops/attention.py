@@ -284,6 +284,120 @@ def _infer_sdp(in_shapes, attrs):
     return [q, kv_in, kv_in], [q, heads, heads]
 
 
+_LANES = 128
+# the bytes of a sequence batch's float32 scores, all heads', up to which
+# XLA's own form is the shorter program: a v5e has 128 MiB of VMEM, XLA
+# keeps scores of 67 and 75 MB there from the first product to the second
+# (one fusion a layer at the matrix unit's pace), and scores of 134 MB and
+# more go to HBM and back about six times (PERF.md section 6, PR 47, has
+# the readings either side)
+_SCORES_ON_CHIP = 96 << 20
+_PREFILL_ROWS = 1024   # query rows a grid step, all heads of a group
+_PREFILL_KEYS = 1024   # positions a key block
+_PREFILL_VMEM = 24 << 20
+
+
+def prefill_block(q_shape, num_heads, kv_heads, platform, causal=True):
+    """``(rows, keys)`` — the query positions a grid step of the TPU's
+    prefill kernel holds (``ops/sdp_kernel.py``; of every query head of a
+    K/V head's group at once) and the positions of a key block — for a
+    `query` of `q_shape` ``(N, T, d_model)`` with `num_heads` query heads
+    over `kv_heads` K/V heads: the largest multiples of 128 that divide
+    ``T`` within 1,024 rows of all the group's heads a step and 1,024
+    positions a key block.  None where ``_sdp_attention`` runs its
+    ``jax.numpy`` body: off the TPU; without the `causal` mask; where the
+    float32 scores of all heads, ``4 N H T^2`` bytes, are within 96 MiB
+    (XLA keeps them on the chip between its two products, and its one
+    fusion a layer is the shorter program: for 30 or 32 heads a ``T`` under
+    896, for 16 under 1,280); for a ``T`` that is no multiple of 128 or a
+    ``d_head`` that is no multiple of 64; or for a head's whole K and V
+    (the pipeline's two buffers each), a step's blocks and its scores
+    beyond 24 MiB of the 32 MiB of VMEM the kernel asks for.  Whoever
+    counts what a prefill runs (``TransformerLM.call_counters``) asks
+    here."""
+    n, t, d = q_shape
+    d_head = d // num_heads
+    group = num_heads // kv_heads
+    if (platform != "tpu" or not causal or t % _LANES or d_head % 64
+            or 4 * n * num_heads * t * t <= _SCORES_ON_CHIP):
+        return None
+
+    def divisor(cap):
+        return max(b for b in range(_LANES, max(cap, _LANES) + 1, _LANES)
+                   if t % b == 0)
+
+    rows, keys = divisor(_PREFILL_ROWS // group), divisor(_PREFILL_KEYS)
+    wide = group * rows
+    # bfloat16 operands twice (the pipeline's buffers), the float32
+    # output twice, the accumulator, and a block's scores three times
+    # over (scores, probabilities, mask)
+    held = (2 * 2 * (2 * t + wide) * d_head + 3 * 4 * wide * d_head
+            + 3 * 4 * wide * keys)
+    return (rows, keys) if held <= _PREFILL_VMEM else None
+
+
+def _masked_attention(qg, kh, vh, *, scale, window, causal=True):
+    """Attention of ``qg (N, H_kv, r, T, d)`` — each K/V head's group of
+    query heads; K and V are never repeated — over ``kh`` / ``vh (N,
+    H_kv, T, d)`` in ``jax.numpy``, the scores of all positions at once:
+    what runs wherever the TPU's kernel does not, and the kernel's
+    oracle."""
+    t, dh = qg.shape[-2:]
+    scores = _scaled(jnp.einsum("ngrqd,ngkd->ngrqk", qg, kh), dh, scale)
+    if causal:
+        keep = jnp.tril(jnp.ones((t, t), dtype=bool))
+        if window is not None:
+            keep &= ~jnp.tril(keep, -window)
+        scores = jnp.where(keep, scores, _NEG)
+    return jnp.einsum("ngrqk,ngkd->ngrqd", jnn.softmax(scores, axis=-1), vh)
+
+
+# tests flip this to have `_cached_attention` and `_sdp_attention` run the
+# TPU's kernels in Pallas interpret mode on the CPU
+_INTERPRET = False
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "window", "block",
+                                             "interpret"))
+def _prefill_attention(qg, kh, vh, *, scale, window, block, interpret):
+    """A prompt's causal attention on whatever platform the program is
+    lowered for: the TPU's blockwise kernel (`block`: ``prefill_block``'s
+    rows and keys; `interpret` runs it in Pallas's interpreter, for
+    tests) or the ``jax.numpy`` body.  Jitted, so that the layers of a
+    program, whose attention is one and the same, trace and lower both
+    once.  The kernel has no derivative and needs none: under ``jax.grad``
+    (`Module.fit` of a decoder) the backward pass is the body's,
+    recomputed from the operands."""
+    body = functools.partial(_masked_attention, scale=scale, window=window)
+    if block is None:
+        return body(qg, kh, vh)
+    rows, keys = block
+
+    def kernel(*operands):
+        if not interpret:
+            # what one pass of the matrix unit makes of float32 operands,
+            # made once (the interpreter on the CPU multiplies in float32,
+            # as the CPU's body does)
+            operands = [x.astype(jnp.bfloat16) for x in operands]
+        # lowered once a shape for all programs and processes
+        # (ops/exported.py): every prefill and mixed program of a bucket
+        # the rule sends here holds this kernel
+        with jax.named_scope("mx:attn.prefill"):
+            ctx, = exported.call(
+                "sdp_kernel", "causal_attention", operands,
+                interpret=interpret, rows=rows, keys=keys, window=window,
+                scale=(qg.shape[-1] ** -0.5 if scale is None else scale))
+        return ctx
+
+    def chosen(*operands):
+        return lax.platform_dependent(*operands, tpu=kernel, default=body)
+
+    attend = jax.custom_vjp(chosen)
+    attend.defvjp(lambda *operands: (chosen(*operands), operands),
+                  lambda operands, g: jax.vjp(body, *operands)[1](g))
+    return attend(qg, kh, vh)
+
+
 @register("_sdp_attention", inputs=("query", "key", "value"),
           num_outputs=3, infer_shape=_infer_sdp)
 def sdp_attention(query, key, value, num_heads=1, causal=True, scale=None,
@@ -306,28 +420,40 @@ def sdp_attention(query, key, value, num_heads=1, causal=True, scale=None,
     Outputs 1/2 cost nothing (they are the reshapes the op computes
     anyway) and exist for the serving prefill graph, which writes them
     into the session's KV-cache slot (``_kv_cache_write``) so decode
-    steps never re-project the prompt."""
+    steps never re-project the prompt.
+
+    *On a TPU* a causal sequence long enough to pay (``prefill_block``)
+    goes through ONE blockwise kernel a layer (``ops/sdp_kernel.py``,
+    device scope ``mx:attn.prefill``): an online softmax over key blocks
+    that visits none above the diagonal or outside the window and writes
+    no score to HBM.  *Everywhere else* the scores of all positions are
+    made at once (``_masked_attention``)."""
     h = int(_lit(num_heads))
     n, t, d = query.shape
     dh = d // h
     kv = _kv_heads(kw, h)
+    causal = _bool(causal)
 
     def heads(x, count):
         return x.reshape(n, t, count, dh).transpose(0, 2, 1, 3)
 
     qh, kh, vh = heads(query, h), heads(key, kv), heads(value, kv)
     # query heads grouped over their K/V head (groups of one without
-    # `num_kv_heads`); K and V are never repeated
+    # `num_kv_heads`)
     qg = qh.reshape(n, kv, h // kv, t, dh)
+    scale = None if scale is None else float(_lit(scale))
+    window = None if window is None else int(_lit(window))
     with _window_scope(window):
-        scores = _scaled(jnp.einsum("ngrqd,ngkd->ngrqk", qg, kh), dh, scale)
-        if _bool(causal):
-            keep = jnp.tril(jnp.ones((t, t), dtype=bool))
-            if window is not None:
-                keep &= ~jnp.tril(keep, -int(_lit(window)))
-            scores = jnp.where(keep, scores, _NEG)
-        ctx = jnp.einsum("ngrqk,ngkd->ngrqd", jnn.softmax(scores, axis=-1),
-                         vh).reshape(n, h, t, dh)
+        if causal:
+            ctx = _prefill_attention(
+                qg, kh, vh, scale=scale, window=window, interpret=_INTERPRET,
+                # the tiling a lowering for the TPU would use; which
+                # platform the program is lowered for is not known here
+                block=prefill_block(query.shape, h, kv, "tpu"))
+        else:
+            ctx = _masked_attention(qg, kh, vh, scale=scale, window=None,
+                                    causal=False)
+    ctx = ctx.reshape(n, h, t, dh)
     return ctx.transpose(0, 2, 1, 3).reshape(n, t, d), kh, vh
 
 
@@ -344,7 +470,6 @@ def _infer_cached(in_shapes, attrs):
     return [q, kv_in, kv_in, kc, kc, slot, slot], [q, kc, kc]
 
 
-_LANES = 128
 _BLOCK_BYTES = 1 << 20
 
 
@@ -441,11 +566,6 @@ def _ring_attention(q, k_new, v_new, k_cache, v_cache, slot_i, len_i,
         [jnp.einsum("grk,gdk->grd", probs[i], _page(vc, slot_i[i]))
          for i in range(b)])
     return ctx.reshape(b, h, dh), kc, vc
-
-
-# tests flip this to have `_cached_attention` run the TPU's kernel in
-# Pallas interpret mode on the CPU
-_INTERPRET = False
 
 
 @functools.partial(jax.jit, static_argnames=("block", "heads", "scale",

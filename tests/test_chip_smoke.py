@@ -119,6 +119,8 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
                                           [3, 2, 16, 48], [3, 2, 32, 48],
                                           [3, 1, 32, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
+    # nor does the shape rule send a bucket through the blockwise kernel
+    assert report["kv_ring"]["kernel_buckets"] == 0
     # the third shape's longest prefill bucket, read for the delta rule:
     # one such layer, no kernel in a program lowered for the CPU
     assert report["kv_ring"]["delta_rule"] == 2 * [
@@ -177,6 +179,22 @@ def test_the_delta_rule_facts_count_solves_and_kernel_calls():
            'custom_call_target="InvertDiagBlocksLowerTriangular"\n')
     assert chip_smoke.delta_rule_hlo_facts(old + kernel) == {
         "solves": 2, "kernel_calls": 1}
+
+
+@pytest.mark.parametrize("why,line,found", [
+    ("the scores of all heads", "%s = f32[1,30,1,2048,2048]{4,3,2,1,0} "
+     "fusion(%q, %k)", ["f32[1,30,1,2048,2048]"]),
+    ("the probabilities, rounded", "%p = bf16[32,2048,2048]{2,1,0} convert("
+     "%s)", ["bf16[32,2048,2048]"]),
+    ("a weight of bucket x bucket is no score", "%w = f32[2048,2048]{1,0} "
+     "parameter(3)", []),
+    ("nor the activations of a model as wide", "%h = f32[1,2048,2048]{2,1,0}"
+     " fusion(%x)", []),
+    ("another bucket's", "%s = f32[1,30,1,1024,1024]{4,3,2,1,0} fusion(%q)",
+     []),
+    ("K of a head", "%k = f32[1,30,2048,128]{3,2,1,0} transpose(%x)", [])])
+def test_a_prompts_scores_made_whole_are_found(why, line, found):
+    assert chip_smoke.score_arrays(line + "\n", 2048) == found, why
 
 
 def test_a_failing_phase_propagates():

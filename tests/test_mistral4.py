@@ -422,6 +422,7 @@ def test_cache_spec_has_one_latent_entry_a_layer():
     assert {e.nbytes // (17 * 6144) for e in spec.values()} == {1280}
     assert 2 * 4 * 32 * 128 == 32768
     assert real.call_counters(positions=2048, platform="tpu") == {
+        "attn.prefill_positions": 4 * 2048, "attn.kernel_positions": 4 * 2048,
         "mla.layer_steps": 0, "mla.kernel_steps": 0, "mla.ring_bytes": 0,
         "cache.latent_bytes": 0, "moe.routed_pairs": 4 * 2048 * 4}
     counted = real.call_counters(rows=2, lengths=[767, 768], computed=2,

@@ -63,6 +63,10 @@ def test_the_rehearsal_reads_the_mixed_share(cell_name, mixed, tmp_path,
         assert 0 < share["value"] <= 100
     else:
         assert share["value"] == 0
+    # PR 47: the blockwise prefill kernel's share of the attention layers'
+    # bucket positions, read the same way — booked, and 0 off the TPU
+    assert result["metrics"]["attn.kernel_share_sat"]["value"] == 0
+    assert result["metrics"]["attn.kernel_share_sat"]["unit"] == "%"
 
 
 def test_the_definition_is_one_file_read_under_two_names():
@@ -80,3 +84,22 @@ def test_the_definition_is_one_file_read_under_two_names():
     sat = entries["batcher.mixed_share_sat"]
     assert sat["moves"] == "gen_tok_per_s" and len(sat["workloads"]) == 7
     assert {m["layer"] for m in entries.values()} == {"serving batcher"}
+
+
+def test_the_kernel_share_is_listed_where_the_mixed_share_is():
+    """PR 47: `attn.kernel_share_*` = 100 x `attn.kernel_positions` ÷
+    `attn.prefill_positions`, one definition file under two names, listed
+    for the same cells as `batcher.mixed_share_*` (the cell tests pin
+    differences of lists)."""
+    base = spec.metric_definition("attn.kernel_share")
+    assert base["reader"] == "ratio" and base["args"]["scale"] == 100.0
+    assert base["args"]["num"] == [{"counter": "attn.kernel_positions"}]
+    assert base["args"]["den"] == [{"counter": "attn.prefill_positions"}]
+    per_layer = {m["name"]: m for m in spec.load_benchmark()["per_layer"]}
+    for tail, moves in (("_open", "itl_p99_ms"), ("_sat", "gen_tok_per_s")):
+        entry = per_layer["attn.kernel_share" + tail]
+        assert spec.metric_definition(entry["name"]) == base
+        assert entry["workloads"] == per_layer[
+            "batcher.mixed_share" + tail]["workloads"]
+        assert (entry["layer"], entry["moves"], entry["source"]) == (
+            "device", moves, "program_counter")

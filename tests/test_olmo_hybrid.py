@@ -606,18 +606,21 @@ def test_the_delta_rule_counters(held):
     assert moved["gdn.step_kernel_bytes"] == 0      # the CPU's programs
     assert moved["cache.state_bytes"] > 0
     lm = family.model(CONFIG)
+    attn = {"attn.prefill_positions": 32, "attn.kernel_positions": 0}
     assert lm.call_counters(positions=32, platform="cpu") == {
-        "gdn.scan_positions": 96, "gdn.kernel_positions": 0,
+        **attn, "gdn.scan_positions": 96, "gdn.kernel_positions": 0,
         "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0}
     # a program lowered for the TPU runs the kernel in every bucket of
     # whole chunks (of 8 here), and the body in any other
     assert lm.call_counters(positions=32, platform="tpu") == {
-        "gdn.scan_positions": 96, "gdn.kernel_positions": 96,
+        **attn, "gdn.scan_positions": 96, "gdn.kernel_positions": 96,
         "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0}
     assert lm.call_counters(positions=36, platform="tpu") == {
+        "attn.prefill_positions": 36, "attn.kernel_positions": 0,
         "gdn.scan_positions": 108, "gdn.kernel_positions": 0,
         "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0}
     assert lm.call_counters(rows=2, platform="tpu") == {
+        "attn.prefill_positions": 0, "attn.kernel_positions": 0,
         "gdn.scan_positions": 0, "gdn.kernel_positions": 0,
         "gdn.state_bytes": 2 * 2 * 3 * page, "gdn.step_kernel_bytes": 0}
     # (four heads of 16 values fill no lane tile: `ops.gdn.step_heads`); at
@@ -630,5 +633,6 @@ def test_the_delta_rule_counters(held):
     assert stepped["gdn.step_kernel_bytes"] == stepped["gdn.state_bytes"] > 0
     assert real.call_counters(rows=8, platform="cpu")[
         "gdn.step_kernel_bytes"] == 0
-    # a model none of whose kinds declares a counter books none
-    assert TransformerLM(vocab=8).call_counters(positions=32, rows=4) == {}
+    # a plain decoder books its attention layers' positions and no other
+    assert TransformerLM(vocab=8).call_counters(positions=32, rows=4) == {
+        "attn.prefill_positions": 2 * 32, "attn.kernel_positions": 0}

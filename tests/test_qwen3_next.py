@@ -398,11 +398,13 @@ def test_cache_spec_and_counters_take_both_head_counts():
     assert spec["k_cache_3"].shape == (5, 2, 32, 128)
     page = 4 * (3 * conv_dim + 16 * 8 * 16)
     assert lm.call_counters(positions=32, platform="cpu") == {
+        "attn.prefill_positions": 32, "attn.kernel_positions": 0,
         "gdn.scan_positions": 3 * 32, "gdn.kernel_positions": 0,
         "gdn.state_bytes": 0, "gdn.step_kernel_bytes": 0,
         "moe.routed_pairs": 4 * 32 * 4}
     assert lm.call_counters(rows=3, lengths=[3, 8, 30], computed=4, pages=10,
                             max_len=128, platform="cpu") == {
+        "attn.prefill_positions": 0, "attn.kernel_positions": 0,
         "gdn.scan_positions": 0, "gdn.kernel_positions": 0,
         "gdn.state_bytes": 3 * 2 * 3 * page, "gdn.step_kernel_bytes": 0,
         "moe.routed_pairs": 4 * 4 * 4}

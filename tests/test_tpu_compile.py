@@ -1,5 +1,5 @@
-"""The decode-attention kernel and the delta rule's prefill and step
-kernels compiled for a TPU v5e that is described, not attached (the TPU's
+"""The decode-attention kernel, the prefill's blockwise attention kernel
+and the delta rule's prefill and step kernels compiled for a TPU v5e that is described, not attached (the TPU's
 compiler is installed where the tests run): what Pallas's interpreter
 cannot see — Mosaic refusing a slice, a layout or the fast memory a
 kernel asks for — at the real widths of the benchmark's decoders.
@@ -330,9 +330,10 @@ def test_the_latent_decode_program_compiles_for_a_v5e(one_chip):
 
 # name -> (chip_smoke.py's kv_ring model, riders, the prompt's bucket, the
 # kernel calls a mixed step of it holds: the riders' ring kernel an
-# attention layer, and a delta-rule layer's chunked AND step kernel)
-MIXED_MODELS = {"opt": (0, 8, 64, 2), "olmo_hybrid": (3, 8, 2048, 1 + 2),
-                "trinity": (4, 8, 2048, 2), "qwen3_next": (5, 16, 2048, 1 + 2)}
+# attention layer — and, at a bucket `prefill_block` takes, the prompt's
+# blockwise kernel —, and a delta-rule layer's chunked AND step kernel)
+MIXED_MODELS = {"opt": (0, 8, 64, 2), "olmo_hybrid": (3, 8, 2048, 2 + 2),
+                "trinity": (4, 8, 2048, 4), "qwen3_next": (5, 16, 2048, 2 + 2)}
 
 
 @pytest.mark.parametrize("name", sorted(MIXED_MODELS))
@@ -371,6 +372,92 @@ def test_a_mixed_step_compiles_for_a_v5e(name, one_chip):
         rings = sum(e.shape == shape and e.kind == "ring"
                     for e in spec.values())
         assert writes <= rings, (shape, writes)
+
+
+# (query heads, K/V heads, d_head, window, scale): every caller of
+# `_sdp_attention` in a cell
+PREFILL_SHAPES = {"opt": (32, 32, 64, None, None),
+                  "olmoe": (16, 16, 128, None, None),
+                  "olmo_hybrid": (30, 30, 128, None, None),
+                  "granite": (32, 8, 64, None, 1 / 64),
+                  "trinity_full": (32, 4, 128, None, None),
+                  "trinity_window": (32, 4, 128, 2048, None),
+                  "qwen3_next": (16, 2, 256, None, None),
+                  "mistral4_latent": (32, 32, 128, None, 0.195)}
+
+
+@pytest.mark.parametrize("name", sorted(PREFILL_SHAPES))
+def test_the_prefill_attention_compiles_for_a_v5e(name, one_chip):
+    """`_sdp_attention` of a 2,048-bucket lowered for the TPU is the
+    blockwise kernel: ONE `tpu_custom_call` under the scope
+    `mx:attn.prefill`, bfloat16 operands, and no array of bucket x bucket
+    anywhere — what Mosaic makes of a head of 64, of a group of eight
+    heads a step, of a head of 256 and of a window."""
+    import jax
+    import jax.numpy as jnp
+
+    h, kv, dh, window, scale = PREFILL_SHAPES[name]
+    t = 2048
+    attrs = dict(num_heads=h, scale=scale, window=window)
+    if kv != h:
+        attrs["num_kv_heads"] = kv
+    assert attention.prefill_block((1, t, h * dh), h, kv, "tpu") is not None
+
+    def arg(width):
+        return jax.ShapeDtypeStruct((1, t, width), jnp.float32,
+                                    sharding=one_chip)
+
+    attention._prefill_attention.clear_cache()
+    text = jax.jit(
+        lambda q, k, v: attention.sdp_attention(q, k, v, **attrs)).lower(
+            arg(h * dh), arg(kv * dh), arg(kv * dh)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "sdp_causal_attention" in calls[0]
+    assert "mx:attn.prefill" in calls[0]
+    assert "bf16[1,%d,%d,%d,%d]" % (kv, h // kv, t, dh) in calls[0]
+    assert chip_smoke.score_arrays(text, t) == []
+
+
+# name -> (chip_smoke.py's kv_ring model, riders, attention layers):
+# a delta-rule layer beside 30 heads of 128 (Olmo-Hybrid's), and two
+# layers of 32 query heads on 8 K/V heads of 64 (Granite's attention)
+PREFILL_MODELS = {"olmo_hybrid": (3, 8, 1), "granite": (2, 8, 2)}
+
+
+@pytest.mark.parametrize("form", ["prefill", "mixed"])
+@pytest.mark.parametrize("name", sorted(PREFILL_MODELS))
+def test_a_long_prefill_holds_no_scores(name, form, one_chip):
+    """The 2,048-bucket prefill program and the mixed step in its place,
+    lowered for the TPU: the blockwise kernel once an attention layer and
+    NO array whose last two dimensions are both the bucket — the `(H, T,
+    T)` scores, 503 MB a layer of 30 heads, that XLA's form writes and
+    reads about six times."""
+    import warnings
+
+    index, rows, layers = PREFILL_MODELS[name]
+    bucket = 2048
+    lm = _smoke_model(index)
+    spec = lm.cache_spec(rows + 1)
+    booked = lm.call_counters(positions=bucket, platform="tpu")
+    assert booked["attn.kernel_positions"] == layers * bucket
+    assert booked["attn.prefill_positions"] == layers * bucket
+    gdn._delta_rule.clear_cache()
+    gdn._state_step.clear_cache()
+    attention._prefill_attention.clear_cache()
+    wire = _wire(spec, rows, prompt=bucket)
+    if form == "mixed":
+        graph = lm.mixed_symbol(rows)
+    else:
+        graph = lm.prefill_symbol()
+        wire = {n: s for n, s in wire.items() if not n.startswith("row_")}
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        text = _serving_program(graph, wire, one_chip).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("sdp_causal_attention" in c for c in calls) == layers
+    assert chip_smoke.score_arrays(text, bucket) == []
 
 
 OPT_BUCKETS = [64, 128, 256, 512]  # benchmarks/traffic/gen_closed_c16.json
