@@ -114,6 +114,20 @@ its norm gains are stored as they are applied, ``1 + w`` of the published
 the trained length), and softmax ``route_norm`` experts of `expert_d_ff`
 beside an ungated shared expert with ``held_experts`` are
 Mistral-Small-4's (`mistral4`).
+``layer_types`` of ``"sparse_latent_attention"`` and three
+``"window_latent_attention"`` a period with `kind_specs` giving EACH KIND ITS
+OWN heads, ranks, widths (a key of ``nope_dim + rope_dim`` beside a value of
+`value_dim`), rotary base, `head_gate` and `lora_rescale` — and the full
+kind its indexer's `index_heads` x `index_dim` and `index_topk`, the window
+kind its `window` —, ``ffn_types`` of a leading ``"dense"`` layer before
+``"routed"`` ones, sigmoid scores with ``router_bias`` and ``held_experts``
+are dots3-note-prev's (`dots3`).
+
+**Per-kind sizes.**  The flat arguments above give a model ONE geometry a
+mechanism.  `kind_specs` ``{kind: {size: value}}`` is the seam for a model
+whose layer kinds differ in them (ROADMAP D13, begun with the two kinds
+that need it): a kind's class reads its own mapping and nothing else of
+the spec but `d_model`, the norm and the FFN.
 
 **Cache spec.**  :meth:`TransformerLM.cache_spec` is the ONE statement of
 what a serving session holds on the device between calls: an ordered
@@ -125,7 +139,12 @@ the last axis of its shape: the session's ``max_len`` for a full layer,
 session writes modulo), a latent-attention layer's ONE latent ring (kind
 ``"latent"``, ``(slots, 1, latent_kv_rank + latent_rope_dim, max_len)``:
 addressed, masked and counted like the rings, with no second ring beside
-it and no per-head K or V anywhere) and a Mamba or Gated DeltaNet layer's
+it and no per-head K or V anywhere; a window latent layer's is
+``min(window, max_len)`` long and written modulo), a sparse latent layer's
+INDEX KEYS beside its latent ring (kind ``"index"``, ``(slots, 1, index_dim,
+max_len)``: one key a position that the layer's indexer scores; whoever
+sizes, charges or zeroes session state takes it like any entry, and only
+the rings' position counters leave it out) and a Mamba or Gated DeltaNet layer's
 conv window and recurrent state (kind ``"state"``, a fixed size a slot
 whatever the context, wholly rewritten by a prefill).  The serving graphs take and
 return exactly these names in this order; whoever allocates, sizes,
@@ -148,8 +167,11 @@ class CacheEntry(NamedTuple):
     ``shape[3]`` is the ring's own length in positions, which differs by
     layer kind), ``"latent"`` (a latent-attention layer's ONE ring, ``(slots,
     1, width, positions)``: a ring in every respect, of one row a
-    position that all heads read as key and, its leading lines, as value)
-    or ``"state"`` (a recurrent layer's, overwritten whole by a prefill);
+    position that all heads read as key and, its leading lines, as value),
+    ``"index"`` (a sparse latent layer's index keys, ``(slots, 1, width,
+    positions)``: read by that layer's indexer alone, no ring to anyone
+    who counts rings) or ``"state"`` (a recurrent layer's, overwritten
+    whole by a prefill);
     `shape` as stored, float32."""
 
     kind: str
@@ -585,6 +607,339 @@ class _LatentAttention:
                "cache.latent_bytes": pages * entry.nbytes})
 
 
+class _KindLatent:
+    """What the two latent kinds that read their sizes from the spec's
+    `kind_specs` share (ops/sparse_latent.py has the equations): latent
+    attention as :class:`_LatentAttention` computes it, with — all of the
+    KIND'S OWN, ``kind_specs[KIND]`` — `num_heads` heads, a query of rank
+    `q_rank`, ONE cached row of ``kv_rank + rope_dim`` a position, a head's
+    key ``nope_dim + rope_dim`` wide beside a value of `value_dim` (the two
+    need not agree), rotary base `rope_theta`; `lora_rescale` multiplies
+    the normed latents by ``sqrt(d_model / rank)`` of their own rank;
+    `head_gate` multiplies each head's context by ``sigmoid(x w_h)``, ONE
+    scalar a head, ``l<i>_hgate_weight (num_heads, d_model)``, before the
+    output projection; the softmax scale is ``(nope_dim + rope_dim)^
+    -1/2``.  No bias anywhere; `W_qb`'s rows by kind, rotary channels in
+    rotate-half order, as :class:`_LatentAttention`'s.  The whole-sequence
+    forms hand the query LATENT to one op node that up-projects a group of
+    heads at a time; the decode step projects the queries and absorbs."""
+
+    SIZES = ("num_heads", "q_rank", "kv_rank", "nope_dim", "rope_dim",
+             "value_dim")
+    OPTIONS = {"rope_theta": 10000.0, "lora_rescale": False,
+               "head_gate": False}
+
+    def __init__(self, lm):
+        self.lm = lm
+        spec = dict(lm.kind_specs.get(self.KIND) or {})
+        unknown = set(spec) - set(self.SIZES) - set(self.OPTIONS)
+        sizes = [int(spec.get(k, 0)) for k in self.SIZES]
+        if unknown or min(sizes) < 1 or sizes[4] % 2:
+            raise ValueError(
+                "kind_specs[%r] needs %s >= 1 (rope_dim even) and may set "
+                "%s, got %r" % (self.KIND, ", ".join(self.SIZES),
+                                ", ".join(sorted(self.OPTIONS)), spec))
+        (self.heads, self.q_rank, self.rank, self.nope, self.rope,
+         self.value) = sizes[:6]
+        self.own = sizes[6:]      # a kind's further sizes, in its order
+        for k, default in self.OPTIONS.items():
+            setattr(self, k, spec.get(k, default))
+        self.width = self.rank + self.rope
+        # what every attention node of the kind takes
+        self.attrs = dict(num_heads=self.heads, rope_dim=self.rope,
+                          value_dim=self.value)
+
+    def params(self, i):
+        lm, v = self.lm, sym.Variable
+        d, h = lm.d_model, self.heads
+        p = {
+            "qa_weight": v("l%d_qa_weight" % i, shape=(self.q_rank, d)),
+            "qb_weight": v("l%d_qb_weight" % i,
+                           shape=(h * (self.nope + self.rope), self.q_rank)),
+            "kva_weight": v("l%d_kva_weight" % i, shape=(self.width, d)),
+            "kvb_weight": v("l%d_kvb_weight" % i,
+                            shape=(h * (self.nope + self.value), self.rank)),
+            "out_weight": v("l%d_out_weight" % i,
+                            shape=(d, h * self.value))}
+        if self.head_gate:
+            p["hgate_weight"] = v("l%d_hgate_weight" % i, shape=(h, d))
+        return p
+
+    def _fc(self, x, p, key, width, name):
+        return sym.FullyConnected(x, weight=p[key + "_weight"],
+                                  num_hidden=width, no_bias=True,
+                                  flatten=False, name=name)
+
+    def _turn(self, t, name, heads, index, **rope):
+        attrs = dict(rope, theta=float(self.rope_theta), num_heads=heads,
+                     name=name)
+        return (sym._rotary(t, **attrs) if index is None
+                else sym._rotary_at(t, index, **attrs))
+
+    def _rescaled(self, c, rank):
+        if not self.lora_rescale:
+            return c
+        return c * (self.lm.d_model / rank) ** 0.5
+
+    def _latents(self, x, p, i, index=None):
+        """``(c_q, latent)`` of the normed stream: the query latent
+        (normed, rescaled) and ``latent = [c | k_r]``, the row the ring
+        keeps — ``c`` normed and rescaled, the ONE rotary key turned (each
+        row's own `index` in a decode step, 0..T-1 without one)."""
+        lm = self.lm
+        c_q = self._rescaled(
+            lm._norm(self._fc(x, p, "qa", self.q_rank, "l%d_qa" % i),
+                     "l%d_qa_norm" % i, width=self.q_rank), self.q_rank)
+        kva = self._fc(x, p, "kva", self.width, "l%d_kva" % i)
+        c = self._rescaled(
+            lm._norm(sym.slice_axis(kva, axis=2, begin=0, end=self.rank,
+                                    name="l%d_c_kv" % i),
+                     "l%d_kva_norm" % i, width=self.rank), self.rank)
+        k_r = self._turn(sym.slice_axis(kva, axis=2, begin=self.rank,
+                                        end=self.width, name="l%d_k_r" % i),
+                         "l%d_krope" % i, 1, index)
+        return c_q, sym.Concat(c, k_r, dim=2, name="l%d_latent" % i)
+
+    def _queries(self, c_q, p, i, index):
+        """A decode step's ``(q_nope, q_rope)`` of the query latent."""
+        h = self.heads
+        q = self._fc(c_q, p, "qb", h * (self.nope + self.rope), "l%d_qb" % i)
+        q_nope = sym.slice_axis(q, axis=2, begin=0, end=h * self.nope,
+                                name="l%d_q_nope" % i)
+        q_rope = sym.slice_axis(q, axis=2, begin=h * self.nope, end=None,
+                                name="l%d_q_rope" % i)
+        return q_nope, self._turn(q_rope, "l%d_qrope" % i, h, index)
+
+    def _gate(self, x, p, i):
+        return sym.Activation(
+            self._fc(x, p, "hgate", self.heads, "l%d_hgate" % i),
+            act_type="sigmoid", name="l%d_head_gate" % i)
+
+    def _masked_operands(self, x, p, i):
+        """(operands of the whole-sequence node, its attributes, the
+        entries' rows a prefill writes)."""
+        c_q, latent = self._latents(x, p, i)
+        operands = [c_q, p["qb_weight"], latent, p["kvb_weight"]]
+        attrs = dict(self.attrs, nope_dim=self.nope,
+                     theta=float(self.rope_theta))
+        if self.head_gate:
+            operands.append(self._gate(x, p, i))
+            attrs["gated"] = True
+        return operands, attrs, [latent]
+
+    def _out(self, ctx, p, i):
+        return self._fc(ctx, p, "out", self.lm.d_model, "l%d_proj" % i)
+
+    def _gated_out(self, ctx, x, p, i):
+        """A decode step's context ``(B, 1, H * value)``, each head's times
+        its gate, projected."""
+        if self.head_gate:
+            gate = sym.Reshape(self._gate(x, p, i), shape=(0, 0, -1, 1),
+                               name="l%d_gate_heads" % i)
+            ctx = sym.Reshape(
+                sym.broadcast_mul(
+                    sym.Reshape(ctx, shape=(0, 0, self.heads, self.value),
+                                name="l%d_ctx_heads" % i), gate,
+                    name="l%d_ctx_gated" % i),
+                shape=(0, 0, -1), name="l%d_ctx_flat" % i)
+        return self._out(ctx, p, i)
+
+    def full(self, x, p, i):
+        return self._expanded(x, p, i)[0]
+
+    def _latent_counters(self, i, positions, computed, pages, max_len, read):
+        """What both kinds add: a bucket's positions (none through the
+        TPU's blockwise kernel: these kinds attend under a mask of their
+        own), a latent layer-step (none by the latent ring's kernel), the
+        bytes of the `read` positions of this layer's pages a step reads,
+        this layer's ring among the `pages` pages bound."""
+        entry = dict(self.cache_spec(
+            i, 1, self.lm.max_len if max_len is None else max_len))[
+                "latent_cache_%d" % i]
+        return {"attn.prefill_positions": positions,
+                "attn.kernel_positions": 0,
+                "mla.layer_steps": int(computed > 0), "mla.kernel_steps": 0,
+                "mla.ring_bytes": 4 * self.width * read,
+                "cache.latent_bytes": pages * entry.nbytes}
+
+
+class _SparseLatentAttention(_KindLatent):
+    """The latent-attention mixer under a LEARNED SELECTION (DeepSeek-
+    V3.2's indexer; ops/sparse_latent.py): `index_heads` query heads of
+    `index_dim` made from the query latent (``l<i>_iq_weight``), ONE key of
+    `index_dim` a position (``l<i>_ik_weight`` and a LayerNorm), weights of
+    the stream (``l<i>_iw_weight``, times ``index_heads^-1/2 index_dim^
+    -1/2``), rotary on the first `rope_dim` channels of both; row t attends
+    to the `index_topk` positions of largest ``sum_j w_j relu(q_j . k_s)``.
+    State: the latent ring ``(slots, 1, kv_rank + rope_dim, max_len)`` AND
+    the index keys, kind ``"index"``, ``(slots, 1, index_dim, max_len)``.
+    A whole sequence keeps the selection as a mask; a decode step scores
+    the cached keys, takes the exact top-k and attends, absorbed, to the
+    rows gathered there."""
+
+    KIND = "sparse_latent_attention"
+    SIZES = _KindLatent.SIZES + ("index_heads", "index_dim", "index_topk")
+
+    def __init__(self, lm):
+        super().__init__(lm)
+        self.index_heads, self.index_dim, self.topk = self.own
+        if self.rope > self.index_dim:
+            raise ValueError("index_dim=%d holds the rotary part of %d"
+                             % (self.index_dim, self.rope))
+        self.index_attrs = dict(index_heads=self.index_heads,
+                                top_k=self.topk)
+
+    def params(self, i):
+        v, d = sym.Variable, self.lm.d_model
+        p = super().params(i)
+        p["iq_weight"] = v("l%d_iq_weight" % i, shape=(
+            self.index_heads * self.index_dim, self.q_rank))
+        p["ik_weight"] = v("l%d_ik_weight" % i, shape=(self.index_dim, d))
+        p["iw_weight"] = v("l%d_iw_weight" % i, shape=(self.index_heads, d))
+        return p
+
+    def cache_spec(self, i, slots, max_len):
+        """The latent ring, kind ``"latent"``, and the index keys, kind
+        ``"index"``: both ``(slots, 1, lines, max_len)``, the positions on
+        the minor axis."""
+        return [("latent_cache_%d" % i, CacheEntry(
+                    "latent", (int(slots), 1, self.width, int(max_len)))),
+                ("index_cache_%d" % i, CacheEntry(
+                    "index", (int(slots), 1, self.index_dim, int(max_len))))]
+
+    def _index(self, x, c_q, p, i, index=None):
+        """``(index_q, index_k, index_w)``: the indexer's queries and key
+        turned over their first `rope_dim` channels, its weights scaled."""
+        wide = self.index_heads * self.index_dim
+        q = self._turn(self._fc(c_q, p, "iq", wide, "l%d_iq" % i),
+                       "l%d_iqrope" % i, self.index_heads, index,
+                       rotary_dim=self.rope)
+        k = sym.LayerNorm(
+            self._fc(x, p, "ik", self.index_dim, "l%d_ik" % i),
+            gamma=sym.Variable("l%d_ik_norm_gamma" % i,
+                               shape=(self.index_dim,)),
+            beta=sym.Variable("l%d_ik_norm_beta" % i,
+                              shape=(self.index_dim,)),
+            eps=self.lm.norm_eps, name="l%d_ik_norm" % i)
+        k = self._turn(k, "l%d_ikrope" % i, 1, index, rotary_dim=self.rope)
+        w = self._fc(x, p, "iw", self.index_heads, "l%d_iw" % i) * (
+            self.index_heads ** -0.5 * self.index_dim ** -0.5)
+        return q, k, w
+
+    def _expanded(self, x, p, i):
+        operands, attrs, rows = self._masked_operands(x, p, i)
+        index = self._index(x, operands[0], p, i)
+        ctx = sym._sparse_latent_attention(
+            *operands, *index, name="l%d_attn" % i, **attrs,
+            **self.index_attrs)
+        return self._out(ctx, p, i), rows + [index[1]]
+
+    def prefill(self, x, p, i, caches, slot, length):
+        y, (latent, index_k) = self._expanded(x, p, i)
+        return y, [
+            sym._latent_cache_write(caches["latent_cache_%d" % i], latent,
+                                    slot, name="l%d_latent_write" % i),
+            sym._latent_cache_write(caches["index_cache_%d" % i], index_k,
+                                    slot, name="l%d_index_write" % i)]
+
+    def decode(self, x, p, i, caches, slot, length):
+        c_q, latent = self._latents(x, p, i, index=length)
+        q_nope, q_rope = self._queries(c_q, p, i, length)
+        step = sym._sparse_latent_cached_attention(
+            q_nope, q_rope, latent, p["kvb_weight"],
+            *self._index(x, c_q, p, i, index=length),
+            caches["latent_cache_%d" % i], caches["index_cache_%d" % i],
+            slot, length, name="l%d_attn" % i, **self.attrs,
+            **self.index_attrs)
+        return self._gated_out(step[0], x, p, i), [step[1], step[2]]
+
+    def counters(self, i, positions=0, rows=0, lengths=(), computed=0,
+                 pages=0, max_len=None, **call):
+        """What a prefill of a bucket of `positions` adds: its causal
+        (query, key) pairs and those of them the selection keeps (row t
+        its ``min(t + 1, index_topk)`` best).  What one decode step adds:
+        a sparse layer-step, and one on the GATHERED form where the ring
+        is longer than `index_topk` (a shorter one is read whole); each
+        real row's cached positions and those of them it attends to; the
+        bytes of index keys the step scores (whole pages); of the `pages`
+        pages bound, this layer's index keys' bytes."""
+        ring = self.lm.max_len if max_len is None else int(max_len)
+        keys = dict(self.cache_spec(i, 1, ring))["index_cache_%d" % i]
+        cached = [n + 1 for n in lengths]
+        selected = sum(min(n, self.topk) for n in cached)
+        whole = min(positions, self.topk)
+        step = int(computed > 0)
+        return dict(
+            self._latent_counters(i, positions, computed, pages, max_len,
+                                  selected),
+            **{"sparse.prefill_pairs": positions * (positions + 1) // 2,
+               "sparse.prefill_kept": (whole * (whole + 1) // 2
+                                       + (positions - whole) * self.topk),
+               "sparse.layer_steps": step,
+               "sparse.kernel_steps": step * (ring > self.topk),
+               "sparse.context_positions": sum(cached),
+               "sparse.selected_positions": selected,
+               "sparse.index_bytes": rows * keys.nbytes,
+               "cache.index_bytes": pages * keys.nbytes})
+
+
+class _WindowLatentAttention(_KindLatent):
+    """The latent-attention mixer under a sliding window: row i attends to
+    ``j <= i`` with ``i - j < window`` (itself and the W - 1 before it).
+    State: ONE latent ring of ``min(window, max_len)`` positions whatever
+    the session's length — a prefill writes the prompt's last W rows and a
+    decode step writes at ``length mod W``, so a ring that is full holds
+    exactly the window."""
+
+    KIND = "window_latent_attention"
+    SIZES = _KindLatent.SIZES + ("window",)
+
+    def __init__(self, lm):
+        super().__init__(lm)
+        self.window, = self.own
+
+    def cache_spec(self, i, slots, max_len):
+        return [("latent_cache_%d" % i, CacheEntry("latent", (
+            int(slots), 1, self.width, min(self.window, int(max_len)))))]
+
+    def _expanded(self, x, p, i):
+        operands, attrs, rows = self._masked_operands(x, p, i)
+        ctx = sym._window_latent_attention(
+            *operands, name="l%d_attn" % i, window=self.window, **attrs)
+        return self._out(ctx, p, i), rows
+
+    def prefill(self, x, p, i, caches, slot, length):
+        y, (latent,) = self._expanded(x, p, i)
+        return y, [sym._latent_window_write(
+            caches["latent_cache_%d" % i], latent, slot, length,
+            name="l%d_latent_write" % i)]
+
+    def decode(self, x, p, i, caches, slot, length):
+        c_q, latent = self._latents(x, p, i, index=length)
+        q_nope, q_rope = self._queries(c_q, p, i, length)
+        step = sym._window_latent_cached_attention(
+            q_nope, q_rope, latent, p["kvb_weight"],
+            caches["latent_cache_%d" % i], slot, length,
+            name="l%d_attn" % i, **self.attrs)
+        return self._gated_out(step[0], x, p, i), [step[1]]
+
+    def counters(self, i, positions=0, rows=0, lengths=(), computed=0,
+                 pages=0, max_len=None, **call):
+        """What every latent layer adds — a step reads each real row's
+        whole ring —, and of one decode step: a window row for each real
+        row of this layer, those of them whose ring has wrapped (``length
+        >= W``), and the bytes of this layer's ring among the `pages`
+        pages bound."""
+        ring = min(self.window,
+                   self.lm.max_len if max_len is None else int(max_len))
+        counters = self._latent_counters(i, positions, computed, pages,
+                                         max_len, rows * ring)
+        return dict(counters, **{
+            "kv.window_rows": rows,
+            "kv.wrapped_rows": sum(1 for n in lengths if n >= self.window),
+            "cache.window_bytes": counters["cache.latent_bytes"]})
+
+
 class _Recurrent:
     """What the two recurrent kinds share: a fused input projection of
     `d_proj` rows, ONE op node a form (``OPS``: full sequence, prefill,
@@ -762,7 +1117,9 @@ class _GatedDeltaNet(_Recurrent):
 
 _KINDS = {"attention": _Attention, "window_attention": _WindowAttention,
           "mamba": _Mamba2, "linear_attention": _GatedDeltaNet,
-          "latent_attention": _LatentAttention}
+          "latent_attention": _LatentAttention,
+          "sparse_latent_attention": _SparseLatentAttention,
+          "window_latent_attention": _WindowLatentAttention}
 
 
 class _DenseFFN:
@@ -907,7 +1264,8 @@ class TransformerLM:
 
     Further choices (defaults: as if absent): `layer_types` — one mixer
     kind a layer, ``"attention"`` | ``"window_attention"`` | ``"mamba"`` |
-    ``"linear_attention"`` | ``"latent_attention"``
+    ``"linear_attention"`` | ``"latent_attention"`` |
+    ``"sparse_latent_attention"`` | ``"window_latent_attention"``
     (module docstring; default all attention); `block_norm` ``"input"``
     (``h + f(norm(h))``) | ``"output"`` (``h + norm(f(h))``), for both
     halves of every block; `num_kv_heads` K/V heads shared by groups of
@@ -953,7 +1311,12 @@ class TransformerLM:
     original_max_position_embeddings, beta_fast, beta_slow[, mscale,
     mscale_all_dim]}`` for the latent kind's rotary part; `query_scale`
     ``(beta, period)`` — a latent layer's query at position p times ``1 +
-    beta * ln(1 + floor(p / period))``."""
+    beta * ln(1 + floor(p / period))``; `kind_specs` ``{kind: {size:
+    value}}`` — the sizes of the kinds that read their own
+    (:class:`_KindLatent`: `num_heads`, `q_rank`, `kv_rank`, `nope_dim`,
+    `rope_dim`, `value_dim`, and `rope_theta`, `lora_rescale`, `head_gate`;
+    the sparse kind's `index_heads`, `index_dim`, `index_topk`; the window
+    kind's `window`), whatever the flat arguments say."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
                  d_ff=None, max_len=64, dropout=0.0, norm="layer",
@@ -973,7 +1336,7 @@ class TransformerLM:
                  held_experts=None, linear_key_heads=None, rotary_dim=None,
                  shared_gate=False, latent_q_rank=0, latent_kv_rank=0,
                  latent_nope_dim=0, latent_rope_dim=0, latent_value_dim=0,
-                 rope_scaling=None, query_scale=None):
+                 rope_scaling=None, query_scale=None, kind_specs=None):
         if head_dim is None and d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
@@ -1142,6 +1505,10 @@ class TransformerLM:
         self.latent_value_dim = int(latent_value_dim)
         self.rope_scaling = rope_scaling
         self.query_scale = query_scale
+        self.kind_specs = {k: dict(v) for k, v in (kind_specs or {}).items()}
+        if set(self.kind_specs) - set(_KINDS):
+            raise ValueError("kind_specs names kinds of %s, got %r"
+                             % (sorted(_KINDS), sorted(self.kind_specs)))
         if self.rotary_dim is not None and (
                 self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.d_head):
             raise ValueError("rotary_dim=%d must be even and within the "

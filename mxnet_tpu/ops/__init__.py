@@ -10,6 +10,7 @@ from . import attention  # noqa: F401
 from . import ssm  # noqa: F401
 from . import gdn  # noqa: F401
 from . import latent  # noqa: F401
+from . import sparse_latent  # noqa: F401
 from . import spatial  # noqa: F401
 from . import optim_ops  # noqa: F401
 from . import sharded_ops  # noqa: F401

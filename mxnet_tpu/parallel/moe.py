@@ -65,9 +65,42 @@ def expert_ffn(matmul, x, weights, biases, act, gated):
     return lin(h, 1)
 
 
+# the bytes of the T*k gathered pair rows a call sorts at once: a bucket of
+# 2,048 positions at eight experts a token and a hidden size of 4,096 is
+# within it, and a bucket of fifteen thousand (eight a token at 5,120: 2.5
+# GB a copy of the rows, five copies live) goes through in equal pieces of
+# its tokens, one after the other
+_PAIR_BYTES = 256 << 20
+
+
 def dropless_experts(x, logits, k, weights, biases=None, act="relu",
                      gated=False, normalize=True, score="softmax",
                      select_bias=None, scale=1.0, held=None):
+    """`_dropless` (below) of the fewest equal pieces of the tokens whose
+    pairs' rows are within `_PAIR_BYTES` each — all tokens at once where
+    theirs are — one piece after the other (a token's experts do not
+    depend on its neighbours): the same numbers, the loads summed."""
+    t_len, width = x.shape
+    pair = k * width * x.dtype.itemsize
+    if pair > _PAIR_BYTES:
+        raise ValueError("one token's %d pair rows of %d are %d bytes: no "
+                         "piece is within %d" % (k, width, pair, _PAIR_BYTES))
+    pieces = next(n for n in range(1, max(t_len, 1) + 1) if t_len % n == 0
+                  and t_len // n * pair <= _PAIR_BYTES)
+    if pieces == 1:
+        return _dropless(x, logits, k, weights, biases, act, gated,
+                         normalize, score, select_bias, scale, held)
+    out, load = lax.map(
+        lambda piece: _dropless(*piece, k, weights, biases, act, gated,
+                                normalize, score, select_bias, scale, held),
+        (x.reshape(pieces, -1, width),
+         logits.reshape(pieces, -1, logits.shape[-1])))
+    return out.reshape(t_len, -1), load.sum(0)
+
+
+def _dropless(x, logits, k, weights, biases=None, act="relu",
+              gated=False, normalize=True, score="softmax",
+              select_bias=None, scale=1.0, held=None):
     """Every token through its k best experts, none dropped.
 
     x [T, D]; logits [T, E] router scores (softmax here, float32);

@@ -764,6 +764,12 @@ COVERED_ELSEWHERE = {
     # write and the absorbed step against the benchmark's plain jax.numpy
     # reference, which has neither a cache nor the absorbed form)
     "_latent_attention", "_latent_cache_write", "_latent_cached_attention",
+    # test_dots3.py (ops/sparse_latent.py: the masked whole-sequence forms,
+    # the window ring's write and the two absorbed steps against the
+    # benchmark's plain jax.numpy reference, which has masks and no cache)
+    "_sparse_latent_attention", "_window_latent_attention",
+    "_latent_window_write", "_sparse_latent_cached_attention",
+    "_window_latent_cached_attention",
     # test_contrib_ops2.py
     "_contrib_fft", "_contrib_ifft", "_contrib_quantize",
     "_contrib_dequantize", "_contrib_count_sketch", "_contrib_Proposal",

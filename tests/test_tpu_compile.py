@@ -512,3 +512,60 @@ def test_opts_prefill_programs_grow_with_their_bucket(bucket, larger,
     total_larger, worst_larger = opt_prefill_cycles[larger]
     assert total <= 1.5 * total_larger, opt_prefill_cycles
     assert worst <= 1.5 * worst_larger, opt_prefill_cycles
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_dots3_programs_compile_for_a_v5e(program, one_chip):
+    """dots3-note-prev's two serving programs at the cell's sizes — the
+    published widths, five layers, eight held experts, four slots of
+    16,384 positions, the ONE bucket of 15,360 — lowered for the TPU:
+    every cache entry (latent rings, index keys, window rings) aliased to
+    its output and never copied; the decode step gathers 2,048 rows and
+    keeps no array of a page's positions by heads; the prefill's
+    temporaries fit beside the weights and five bound cache sets (its Q,
+    K and V exist a group of heads at a time, its experts' pairs a piece
+    of the tokens at a time, and no ``(heads, T, T)`` score at all)."""
+    import json
+    import re
+    import warnings
+
+    from benchmarks.families import dots3 as family
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "dots3-note-prev.json")) as f:
+        config = json.load(f)
+    lm = family.model(config)
+    rows, bucket = 4, 15360
+    spec = lm.cache_spec(rows + 1, 16384)
+    wire = _wire(spec, rows)
+    if program == "prefill":
+        wire = dict(wire, data=(1, bucket), slot=(1,), length=(1,))
+    graph = (lm.decode_symbol() if program == "decode"
+             else lm.prefill_symbol())
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        compiled = _serving_program(graph, wire, one_chip)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    for shape in sorted({e.shape for e in spec.values()}):
+        count = sum(e.shape == shape for e in spec.values())
+        facts = chip_smoke.ring_hlo_facts(text, shape)
+        assert facts["ring_params"] == facts["aliased"] == count, shape
+        assert facts["copies"] == [], shape
+    # no Pallas kernel of this repo: XLA's own ragged-dot calls alone
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == text.count('op_name="ragged-dot')
+    sets = sum(e.nbytes for e in spec.values())
+    assert stats.alias_size_in_bytes >= sets
+    weights = stats.argument_size_in_bytes - sets
+    assert 7.2e9 < weights < 7.4e9
+    if program == "decode":
+        assert stats.temp_size_in_bytes < 0.3e9
+        # the selected rows, not the page, meet the heads
+        assert re.search(r"f32\[4,576,2048\]", text)
+        assert not re.search(r"f32\[(4,)?128,16384\]", text)
+    else:
+        # a v5e's 16.9e9 bytes hold the weights, five sets, the program
+        assert weights + 5 * sets + stats.temp_size_in_bytes < 16.5e9
+        assert not re.search(r"f32\[(\d+,)?(128|64|16|8),15360,15360\]", text)
+        assert not re.search(r"f32\[122880,5120\]", text)
