@@ -24,7 +24,7 @@ apart from the rest:
   generate  TransformerLM via add_generative_tenant + submit_generate;
             one session's prefill/decode logits against the
             full-recompute score_symbol forward
-  kv_ring   the decode programs of seven TransformerLMs shaped like the
+  kv_ring   the decode programs of eight TransformerLMs shaped like the
             benchmark's decoders (32 heads of 64; 16 of 128; 32 query on
             8 K/V heads of 64 with rings of 2,304; a delta-rule layer of
             30 heads of 96 x 192 beside 30 heads of 128 with rings of
@@ -35,11 +35,15 @@ apart from the rest:
             over two tiles of 128 lines — under 16 query heads, a quarter
             of each head rotated; two latent-attention layers of 32 heads
             over ONE ring of 320-wide rows and 6,144 positions each, the
-            query of rank 1,024; 8 sessions, the sixth and seventh 16) as
-            XLA compiled them: every cache_spec
+            query of rank 1,024; a layer of 128 heads of 128 + 64 under a
+            learned selection of 2,048 over a latent ring of 576-wide rows
+            and 16,384 positions, with its index keys, beside a window
+            layer that is latent too; 8 sessions, the sixth and seventh
+            16, the eighth 4) as XLA compiled them: every cache_spec
             entry aliased to its output, no instruction that copies one,
             ONE attention kernel call an attention layer (a latent layer's
-            is ops/latent_ring_kernel.py), ONE step-kernel
+            is ops/latent_ring_kernel.py; the eighth's absorbed steps are
+            jax.numpy and hold none), ONE step-kernel
             call a delta-rule layer (ops/gdn_step_kernel.py) and nothing
             of rows x page size beside it, and no ring or
             recurrent state fatter on the device than cache_spec states;
@@ -50,7 +54,10 @@ apart from the rest:
             (ops/gdn_kernel.py) and no triangular solve left in it; no
             prefill program of a bucket whose attention is the blockwise
             kernel (ops/sdp_kernel.py: the 2,048 buckets) may hold an
-            array of bucket x bucket scores, beside the ring copies; and
+            array of bucket x bucket scores, beside the ring copies; the
+            eighth's 15,360-bucket prefill holds ONE masked kernel call
+            (ops/masked_latent_kernel.py) for its selected layer and no
+            array of a run's scores (heads x 512 x a run's keys); and
             every tenant's prefill bucket programs timed warm: none may
             run longer than 1.5 times the next larger bucket's (the
             first shape has OPT-1.3B's FFN of 8,192 and its four buckets)
@@ -141,7 +148,33 @@ FULL = {
                                     mscale=1, mscale_all_dim=1),
                                 attention_multiplier=0.195,
                                 query_scale=(0.1, 8192), norm="rms",
-                                positions="none", bias=False)]},
+                                positions="none", bias=False),
+                           # a learned selection over a latent ring of
+                           # 512 + 64 lines and 16,384 positions beside
+                           # a window layer that is latent too (its ring
+                           # of 512 positions whole tiles): 128 heads of
+                           # 128 + 64 beside a value of 128 that keep
+                           # 2,048 positions; the absorbed steps are
+                           # jax.numpy, the 15,360-bucket's masked
+                           # attention ONE blockwise kernel a layer
+                           dict(num_heads=128, max_len=16384,
+                                seq_buckets=[64, 15360], max_sessions=4,
+                                layer_types=["sparse_latent_attention",
+                                             "window_latent_attention"],
+                                kind_specs={
+                                    "sparse_latent_attention": dict(
+                                        num_heads=128, q_rank=1024,
+                                        kv_rank=512, nope_dim=128,
+                                        rope_dim=64, value_dim=128,
+                                        head_gate=True, index_heads=64,
+                                        index_dim=128, index_topk=2048),
+                                    "window_latent_attention": dict(
+                                        num_heads=64, q_rank=1024,
+                                        kv_rank=1024, nope_dim=192,
+                                        rope_dim=64, value_dim=128,
+                                        head_gate=True, window=512)},
+                                norm="rms", positions="none",
+                                bias=False)]},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
 }
@@ -547,6 +580,13 @@ def ring_hlo_facts(text, ring_shape):
             "kernel_calls": text.count('custom_call_target="tpu_custom_call"')}
 
 
+def named_kernel_calls(text, name):
+    """The calls of the Pallas kernel `name` (a kernel keeps its name) in
+    a compiled program's optimised HLO `text`."""
+    return sum('custom_call_target="tpu_custom_call"' in line and name in line
+               for line in text.splitlines())
+
+
 def delta_rule_hlo_facts(text):
     """What a compiled prefill program makes of the delta rule's chunks,
     read from its optimised HLO `text`: the triangular solves XLA left in
@@ -580,6 +620,26 @@ def score_arrays(text, bucket):
                    if math.prod(int(d) for d in heads.split(",")[:-1]) > 1})
 
 
+def run_score_arrays(text, bucket):
+    """Every float32 array in a compiled prefill program's optimised HLO
+    `text` of a GROUP OF HEADS' scores of a block of queries against the
+    keys of a run, ``(heads, 512, keys)`` (``ops/sparse_latent.py``: a
+    `bucket`'s query blocks go in runs, each against the keys up to its
+    own end): what the ``jax.numpy`` form of the masked attention wrote
+    to HBM and read back, and the masked kernel
+    (``ops/masked_latent_kernel.py``) never writes — so in a program of a
+    bucket ``ops.sparse_latent.masked_block`` sends through it any such
+    array is a fault."""
+    from mxnet_tpu.ops import sparse_latent
+
+    block = sparse_latent._block(bucket, sparse_latent._QUERY_BLOCK)
+    seen = {min(bucket, (first + count) * block)
+            for first, count in sparse_latent._runs(-(-bucket // block))}
+    found = re.findall(r"\bf32\[(\d+),%d,(\d+)\]" % block, text)
+    return sorted({"f32[%s,%d,%s]" % (heads, block, keys)
+                   for heads, keys in found if int(keys) in seen})
+
+
 def delta_step_hlo_facts(text, rows, state_shape):
     """What a compiled decode program of `rows` rows makes of the delta
     rule's step, read from its optimised HLO `text`: the calls of the
@@ -592,9 +652,7 @@ def delta_step_hlo_facts(text, rows, state_shape):
     fat = {"f32[%d,%s]" % (rows, dims)
            for dims in re.findall(r"f32\[%d,([\d,]+)\]" % rows, text)
            if math.prod(int(d) for d in dims.split(",")) == page}
-    return {"kernel_calls": sum(
-                'custom_call_target="tpu_custom_call"' in line
-                and "gdn_state_step" in line for line in text.splitlines()),
+    return {"kernel_calls": named_kernel_calls(text, "gdn_state_step"),
             "row_pages": sorted(fat)}
 
 
@@ -670,7 +728,8 @@ def phase_kv_ring(sizes, ctx):
     platform = ctx.jax_device().platform
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
              "layouts": [], "rings": [], "delta_rule": [], "delta_step": [],
-             "prefill_ms": [], "mixed_steps": 0, "kernel_buckets": 0}
+             "prefill_ms": [], "mixed_steps": 0, "kernel_buckets": 0,
+             "masked_buckets": 0}
     for shape in sizes["shapes"]:
         shape = dict(shape)
         buckets = shape.pop("seq_buckets", sizes["seq_buckets"])
@@ -685,9 +744,13 @@ def phase_kv_ring(sizes, ctx):
         rings = list(dict.fromkeys(e.shape for e in spec.values()
                                    if e.kind in ("ring", "latent")))
         ring = rings[0]
-        # an attention layer has two rings, a latent layer ONE
+        # an attention layer has two rings, a latent layer ONE: a kernel
+        # call each, but for a latent layer whose absorbed step is
+        # jax.numpy (a learned selection's, a window's: the kinds say)
         ring_layers = (sum(e.kind == "ring" for e in spec.values()) // 2
-                       + sum(e.kind == "latent" for e in spec.values()))
+                       + lm.call_counters(
+                           rows=slots, lengths=[0] * slots, computed=slots,
+                           platform=platform).get("mla.kernel_steps", 0))
         # judged: every entry of at least a hundredth of the set's bytes
         # (a conv window of three rows lies in tiles of four, and XLA may
         # fetch so small a buffer into fast memory ahead of its use)
@@ -746,7 +809,7 @@ def phase_kv_ring(sizes, ctx):
             # the mixed step where the model has one (PR 46) —: a bucket
             # the shape rule sends through the blockwise attention kernel
             # (PR 47) holds no array of bucket x bucket scores
-            mixed, scores = [], {}
+            mixed, scores, masked = [], {}, {}
             for t in buckets:
                 _exe, pre = session._program(session._prefill_pred, 1, t,
                                              True)
@@ -756,6 +819,15 @@ def phase_kv_ring(sizes, ctx):
                 tiled = booked.get("attn.kernel_positions", 0) // t
                 if tiled and tiled == booked["attn.prefill_positions"] // t:
                     scores[t] = score_arrays(text, t)
+                # a bucket whose layers under a learned selection go
+                # through the masked kernel (PR 49; a window layer beside
+                # them books no kernel): one call a layer, its site in the
+                # scan over the groups of heads, and no run's scores
+                if tiled and booked.get("sparse.prefill_pairs"):
+                    masked[t] = {
+                        "layers": tiled, "scores": run_score_arrays(text, t),
+                        "kernel_calls": named_kernel_calls(
+                            text, "masked_latent_attention")}
                 if not session._mixed:
                     continue
                 seen = {"bucket": t, "ring_params": 0, "aliased": 0,
@@ -830,6 +902,20 @@ def phase_kv_ring(sizes, ctx):
                    "is the blockwise kernel, still holds its scores whole: "
                    "%s" % (t, fat))
         total["kernel_buckets"] += len(scores)
+        for t, seen in sorted(masked.items()):
+            print("[chip_smoke] kv_ring: the %d-bucket prefill's selected "
+                  "attention: %d masked kernel call(s) for %d layer(s); "
+                  "arrays of a run's scores: %s"
+                  % (t, seen["kernel_calls"], seen["layers"],
+                     seen["scores"]), flush=True)
+            _check(seen["kernel_calls"] == seen["layers"]
+                   and not seen["scores"],
+                   "the %d-bucket prefill program, whose selected attention "
+                   "is the masked kernel, holds %d calls of it for %d "
+                   "layers and these scores of a run: %s"
+                   % (t, seen["kernel_calls"], seen["layers"],
+                      seen["scores"]))
+        total["masked_buckets"] += len(masked)
         print("[chip_smoke] kv_ring: prefill buckets, ms a warm program: "
               + ", ".join("%d: %.2f" % row for row in sorted(
                   prefill_ms.items())), flush=True)

@@ -156,7 +156,8 @@ import math
 from typing import NamedTuple
 
 from .. import symbol as sym
-from ..ops import attention as _attention, gdn as _gdn, ssm as _ssm
+from ..ops import (attention as _attention, gdn as _gdn,
+                   sparse_latent as _sparse_latent, ssm as _ssm)
 
 __all__ = ["TransformerLM", "CacheEntry"]
 
@@ -747,17 +748,19 @@ class _KindLatent:
     def full(self, x, p, i):
         return self._expanded(x, p, i)[0]
 
-    def _latent_counters(self, i, positions, computed, pages, max_len, read):
-        """What both kinds add: a bucket's positions (none through the
-        TPU's blockwise kernel: these kinds attend under a mask of their
-        own), a latent layer-step (none by the latent ring's kernel), the
-        bytes of the `read` positions of this layer's pages a step reads,
-        this layer's ring among the `pages` pages bound."""
+    def _latent_counters(self, i, positions, computed, pages, max_len, read,
+                         tiled=False):
+        """What both kinds add: a bucket's positions (and those of them a
+        blockwise kernel of the TPU takes: all where the kind's mask has
+        one and the program is `tiled`, else none), a latent layer-step
+        (none by the latent ring's kernel), the bytes of the `read`
+        positions of this layer's pages a step reads, this layer's ring
+        among the `pages` pages bound."""
         entry = dict(self.cache_spec(
             i, 1, self.lm.max_len if max_len is None else max_len))[
                 "latent_cache_%d" % i]
         return {"attn.prefill_positions": positions,
-                "attn.kernel_positions": 0,
+                "attn.kernel_positions": positions * tiled,
                 "mla.layer_steps": int(computed > 0), "mla.kernel_steps": 0,
                 "mla.ring_bytes": 4 * self.width * read,
                 "cache.latent_bytes": pages * entry.nbytes}
@@ -854,10 +857,12 @@ class _SparseLatentAttention(_KindLatent):
         return self._gated_out(step[0], x, p, i), [step[1], step[2]]
 
     def counters(self, i, positions=0, rows=0, lengths=(), computed=0,
-                 pages=0, max_len=None, **call):
-        """What a prefill of a bucket of `positions` adds: its causal
-        (query, key) pairs and those of them the selection keeps (row t
-        its ``min(t + 1, index_topk)`` best).  What one decode step adds:
+                 pages=0, max_len=None, platform=None, **call):
+        """What a prefill of a bucket of `positions` adds: its positions —
+        all of them through the TPU's masked kernel where a program lowered
+        for `platform` has it (``ops.sparse_latent.masked_block``) —, its
+        causal (query, key) pairs and those of them the selection keeps
+        (row t its ``min(t + 1, index_topk)`` best).  What one decode step adds:
         a sparse layer-step, and one on the GATHERED form where the ring
         is longer than `index_topk` (a shorter one is read whole); each
         real row's cached positions and those of them it attends to; the
@@ -869,9 +874,12 @@ class _SparseLatentAttention(_KindLatent):
         selected = sum(min(n, self.topk) for n in cached)
         whole = min(positions, self.topk)
         step = int(computed > 0)
+        tiled = _sparse_latent.masked_block(
+            positions, self.heads, self.nope + self.rope, self.value,
+            platform) is not None
         return dict(
             self._latent_counters(i, positions, computed, pages, max_len,
-                                  selected),
+                                  selected, tiled),
             **{"sparse.prefill_pairs": positions * (positions + 1) // 2,
                "sparse.prefill_kept": (whole * (whole + 1) // 2
                                        + (positions - whole) * self.topk),

@@ -53,12 +53,14 @@ TPU one bfloat16 pass with float32 accumulation.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax, nn as jnn
 
 from . import attention as _attn
+from . import exported
 from .registry import register
 from .tensor import _lit
 
@@ -68,6 +70,8 @@ _GROUP_SCORES = 64 << 20  # bytes of a step's scores: they stay on the chip
 _GROUP_ROWS = 512 << 20   # bytes of a group's Q, K and V of all positions
 _INDEX_HEADS = 8         # indexer heads a product (their scores' bytes)
 _RUNS = 4                # runs of query blocks, each against its own keys
+_KERNEL_KEYS = 1024      # positions a key block of the TPU's kernel
+_KERNEL_HEADS = 4        # heads a grid step of it: they share the mask's block
 
 
 def _block(t, most):
@@ -94,10 +98,41 @@ def _head_group(heads, block, keys, head_bytes):
     group's Q, K and V of all positions (`head_bytes` a head) within 512
     MiB.  A step reads its group's K and V whole, so the bytes a layer
     reads fall with the BLOCK, not with the group: 512 queries of 2 heads
-    read an eighth of what 64 queries of 16 heads do."""
-    fit = max(1, min(_GROUP_SCORES // (4 * block * keys),
-                     _GROUP_ROWS // head_bytes))
-    return max(g for g in range(1, heads + 1) if heads % g == 0 and g <= fit)
+    read an eighth of what 64 queries of 16 heads do.  Without `keys` no
+    score array exists (the TPU's kernel keeps a block's on the chip) and
+    the rows alone bound the group."""
+    fit = _GROUP_ROWS // head_bytes
+    if keys is not None:
+        fit = min(fit, _GROUP_SCORES // (4 * block * keys))
+    return max(g for g in range(1, heads + 1)
+               if heads % g == 0 and g <= max(1, fit))
+
+
+def masked_block(t, heads, key_dim, value_dim, platform):
+    """``(rows, keys)`` — the query positions a grid step of the TPU's
+    masked kernel holds (``ops/masked_latent_kernel.py``) and the positions
+    of a key block — for a sequence of `t` positions under a selection,
+    with `heads` heads whose key is `key_dim` wide (``nope + rope``) beside
+    a value of `value_dim`: the body's block of queries and the largest
+    multiple of 128 that divides `t` within 1,024 positions a key block
+    (on a v5e 512 x 1,024 reads 51.6% of the bfloat16 peak at 15,360
+    positions, key blocks of 512 36%: PERF.md section 6, PR 49).  None
+    where ``_sparse_latent_attention`` runs its ``jax.numpy`` body
+    (``_attend_masked``): off the TPU; where the float32 scores of all
+    heads, ``4 H t^2`` bytes, are within the 96 MiB up to which XLA keeps
+    them on the chip (``ops.attention._SCORES_ON_CHIP``: for 128 heads a
+    `t` under 512 — the tiny rehearsal size, a short scoring call); for a
+    `t` that is no multiple of 128, a key width that is no multiple of 64
+    or a value width that is no multiple of 128.  Whoever counts what a
+    prefill runs (``TransformerLM.call_counters``) asks here, as
+    ``ops.attention.prefill_block`` is asked for ``_sdp_attention``."""
+    if (platform != "tpu" or t % _attn._LANES or key_dim % 64
+            or value_dim % _attn._LANES
+            or 4 * heads * t * t <= _attn._SCORES_ON_CHIP):
+        return None
+    keys = max(b for b in range(_attn._LANES, _KERNEL_KEYS + 1, _attn._LANES)
+               if t % b == 0)
+    return _block(t, _QUERY_BLOCK), keys
 
 
 def _pad_rows(x, multiple):
@@ -208,6 +243,51 @@ def _attend_masked(q_n, q_r, k_n, k_r, v, keep, scale, block):
     return jnp.concatenate(ctx)
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "block", "tiled",
+                                             "interpret"))
+def _masked_read(q_n, q_r, k_n, k_r, v, keep, *, scale, block, tiled,
+                 interpret):
+    """``_attend_masked`` on whatever platform the program is lowered for:
+    the TPU's blockwise kernel (`tiled`: ``masked_block``'s rows and keys,
+    and the heads a step; `interpret` runs it in Pallas's interpreter, for
+    tests) or the ``jax.numpy`` body.  Jitted, so that the layers of a
+    program trace and lower both once.  The kernel has no derivative and needs
+    none: under ``jax.grad`` the backward pass is the body's, recomputed
+    from the operands."""
+    body = functools.partial(_attend_masked, scale=scale, block=block)
+    if tiled is None:
+        return body(q_n, q_r, k_n, k_r, v, keep)
+    rows, keys, heads = tiled
+
+    def kernel(q_n, q_r, k_n, k_r, v, keep):
+        # a head's key as wide as its query: the position's one rotary row
+        # beside every head's own part
+        q = jnp.concatenate([q_n, q_r], axis=-1)
+        k = jnp.concatenate([k_n, jnp.broadcast_to(
+            k_r[:, None], k_n.shape[:2] + k_r.shape[1:])], axis=-1)
+        operands = [x.swapaxes(0, 1) for x in (q, k, v)]
+        if not interpret:
+            # what one pass of the matrix unit makes of float32 operands,
+            # made once (the interpreter on the CPU multiplies in float32,
+            # as the CPU's body does)
+            operands = [x.astype(jnp.bfloat16) for x in operands]
+        # lowered once a shape for all programs and processes
+        # (ops/exported.py)
+        ctx, = exported.call(
+            "masked_latent_kernel", "masked_attention",
+            operands + [keep.astype(jnp.int8)], interpret=interpret,
+            rows=rows, keys=keys, heads=heads, scale=scale)
+        return ctx.swapaxes(0, 1)
+
+    def chosen(*operands):
+        return lax.platform_dependent(*operands, tpu=kernel, default=body)
+
+    attend = jax.custom_vjp(chosen)
+    attend.defvjp(lambda *operands: (chosen(*operands), operands),
+                  lambda operands, g: jax.vjp(body, *operands)[1](g))
+    return attend(q_n, q_r, k_n, k_r, v, keep)
+
+
 def _attend_window(q_n, q_r, k_n, k_r, v, window, scale, block):
     """The same under a sliding `window`: a block of queries reads the
     slab of ``block + window - 1`` positions that ends with it."""
@@ -253,8 +333,14 @@ def _grouped_attention(c_q, qb_weight, latent, kvb_weight, gate, *, heads,
     nope = qb_weight.shape[0] // heads - rope
     c, k_r = latent[:, :rank], latent[:, rank:]
     block = _block(t, _QUERY_BLOCK)
-    g = _head_group(heads, block, t if window is None else block + window,
-                    4 * t * (2 * nope + rope + value))
+    # the tiling a lowering for the TPU would use; which platform the
+    # program is lowered for is not known here
+    tiled = None if keep is None else masked_block(t, heads, nope + rope,
+                                                   value, "tpu")
+    keys = None if tiled else t if window is None else block + window
+    g = _head_group(heads, block, keys, 4 * t * (2 * nope + rope + value))
+    if tiled:   # up to four of the group's heads a grid step
+        tiled += (math.gcd(g, _KERNEL_HEADS),)
     groups = heads // g
     qb_n = qb_weight[:heads * nope].reshape(groups, g * nope, -1)
     qb_r = qb_weight[heads * nope:].reshape(groups, g * rope, -1)
@@ -277,7 +363,9 @@ def _grouped_attention(c_q, qb_weight, latent, kvb_weight, gate, *, heads,
             q_n, q_r = _pad_rows(q_n, block), _pad_rows(q_r, block)
         with jax.named_scope("mx:dsa.read" if window is None
                              else "mx:attn.window"):
-            ctx = (_attend_masked(q_n, q_r, k_n, k_r, v, keep, scale, block)
+            ctx = (_masked_read(q_n, q_r, k_n, k_r, v, keep, scale=scale,
+                                block=block, tiled=tiled,
+                                interpret=_attn._INTERPRET)
                    if window is None else
                    _attend_window(q_n, q_r, k_n, k_r, v, window, scale,
                                   block))

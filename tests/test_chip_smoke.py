@@ -69,7 +69,24 @@ TINY = {
                                     original_max_position_embeddings=16),
                                 attention_multiplier=0.3,
                                 query_scale=(0.1, 16), norm="rms",
-                                positions="none", bias=False)]},
+                                positions="none", bias=False),
+                           dict(num_heads=4, max_len=48,
+                                layer_types=["sparse_latent_attention",
+                                             "window_latent_attention"],
+                                kind_specs={
+                                    "sparse_latent_attention": dict(
+                                        num_heads=4, q_rank=16, kv_rank=24,
+                                        nope_dim=8, rope_dim=8,
+                                        value_dim=16, head_gate=True,
+                                        index_heads=2, index_dim=16,
+                                        index_topk=8),
+                                    "window_latent_attention": dict(
+                                        num_heads=2, q_rank=16, kv_rank=32,
+                                        nope_dim=16, rope_dim=8,
+                                        value_dim=16, head_gate=True,
+                                        window=16)},
+                                norm="rms", positions="none",
+                                bias=False)]},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
 }
@@ -111,16 +128,19 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     # in the compiled decode programs, then a window layer's rings of 16
     # positions beside a full layer's of 48, then two key heads under four
     # value heads beside one layer's rings of heads of 32, then two latent
-    # layers' ONE ring each of 24 + 8 lines; the CPU's programs hold no
-    # kernel call
-    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2
+    # layers' ONE ring each of 24 + 8 lines, then a selected layer's ring
+    # of 24 + 8 lines with its index keys beside a window layer's latent
+    # ring of 16 positions; the CPU's programs hold no kernel call
+    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2 + 3
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
                                           [3, 2, 16, 48], [3, 2, 16, 16],
                                           [3, 2, 16, 48], [3, 2, 32, 48],
-                                          [3, 1, 32, 48]]
+                                          [3, 1, 32, 48], [3, 1, 32, 48],
+                                          [3, 1, 40, 16]]
     assert report["kv_ring"]["kernel_calls"] == 0
-    # nor does the shape rule send a bucket through the blockwise kernel
+    # nor does a shape rule send a bucket through a blockwise kernel
     assert report["kv_ring"]["kernel_buckets"] == 0
+    assert report["kv_ring"]["masked_buckets"] == 0
     # the third shape's longest prefill bucket, read for the delta rule:
     # one such layer, no kernel in a program lowered for the CPU
     assert report["kv_ring"]["delta_rule"] == 2 * [
@@ -138,7 +158,7 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert all(step["ms"] > 0 for step in steps)
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
-        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
+        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
     chip_smoke.run_phase("four_chips", chip_smoke.phase_four_chips,
@@ -195,6 +215,27 @@ def test_the_delta_rule_facts_count_solves_and_kernel_calls():
     ("K of a head", "%k = f32[1,30,2048,128]{3,2,1,0} transpose(%x)", [])])
 def test_a_prompts_scores_made_whole_are_found(why, line, found):
     assert chip_smoke.score_arrays(line + "\n", 2048) == found, why
+
+
+@pytest.mark.parametrize("why,line,found", [
+    ("two heads' scores against the last run's keys", "%s = f32[2,512,15360]"
+     "{2,1,0} fusion(%q, %k)", ["f32[2,512,15360]"]),
+    ("sixteen heads' against the first run's", "%s = (f32[16,512]{1,0}, "
+     "f32[16,512,4096]{2,1,0}) fusion(%s)", ["f32[16,512,4096]"]),
+    ("keys that end no run", "%s = f32[2,512,2048]{2,1,0} fusion(%q)", []),
+    ("the probabilities, rounded, are another array's fault", "%p = "
+     "bf16[2,512,15360]{2,1,0} convert(%s)", []),
+    ("a block of the mask has no heads", "%m = pred[512,15360]{1,0} "
+     "slice(%keep)", []),
+    ("the kernel's context", "%c = f32[16,15360,128]{2,1,0} custom-call(%q)",
+     [])])
+def test_a_runs_scores_made_whole_are_found(why, line, found):
+    """A 15,360 bucket's thirty blocks of 512 queries go in runs that end
+    at 4,096, 8,192, 12,288 and 15,360 keys."""
+    assert chip_smoke.run_score_arrays(line + "\n", 15360) == found, why
+    assert chip_smoke.run_score_arrays(
+        "%s = f32[2,512,2560]{2,1,0} fusion(%q)\n", 2560) == [
+            "f32[2,512,2560]"]       # a short bucket is ONE run
 
 
 def test_a_failing_phase_propagates():

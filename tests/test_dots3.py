@@ -354,6 +354,29 @@ def test_the_layer_kinds_count_what_a_call_reads():
     assert short["sparse.kernel_steps"] == 0
 
 
+@pytest.mark.parametrize("why,positions,platform,kernel_layers", [
+    ("the cell's one bucket, lowered for the TPU", 15360, "tpu", 2),
+    ("the same bucket off the TPU", 15360, "cpu", 0),
+    ("a short bucket: its scores stay on the chip", 256, "tpu", 0),
+    ("a bucket that is no multiple of 128", 15400, "tpu", 0)])
+def test_a_prefill_books_the_masked_kernels_positions(why, positions,
+                                                      platform,
+                                                      kernel_layers):
+    """PR 49: the two full layers' positions go through the TPU's masked
+    kernel where `ops.sparse_latent.masked_block` says a program lowered
+    for `platform` has it; the three window layers keep 0."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "dots3-note-prev.json")) as f:
+        lm = family.model(json.load(f))
+    fill = lm.call_counters(positions=positions, platform=platform)
+    assert fill["attn.prefill_positions"] == 5 * positions, why
+    assert fill["attn.kernel_positions"] == kernel_layers * positions, why
+    for i, kernel in enumerate([kernel_layers > 0] * 2 + [False] * 3):
+        layer = lm._mixers[i].counters(i, positions=positions,
+                                       platform=platform)
+        assert layer["attn.kernel_positions"] == positions * kernel, (why, i)
+
+
 def test_the_tenant_charges_and_counts_the_new_entries(held):
     """`add_generative_tenant` charges every entry; two requests through
     the batcher move `kv.*` (the index keys are no ring), `cache.*`,

@@ -966,3 +966,42 @@ def test_the_tool_sums_a_programs_device_ops_by_name():
         ["f32[8]{0} fusion(%)", pytest.approx(0.008), 1.0]]
     assert tool.ops_by_name(rows, ops, "prefill", 64) == {
         "runs": 0, "module_ms": None, "ops": []}
+
+
+def test_the_tool_sums_a_programs_device_ops_by_scope():
+    """`--ops` also sums by the innermost ``mx:`` scope of each
+    instruction's `op_name` in the program's optimised HLO (PR 49: a
+    prefill's device time under `mx:dsa.read`, `mx:mla.expand`, …); an
+    instruction outside every scope, or one the HLO does not hold, is
+    "-"."""
+    from tools import device_time_check as tool
+
+    hlo = """
+  %fusion.1 = f32[4]{0} fusion(%p.1), kind=kLoop, calls=%f.1, metadata={op_name="jit(program)/l0_attn/while/body/mx:mla.expand/dot_general" stack_frame_id=3}
+  ROOT %fusion.2 = f32[4]{0} fusion(%p.2), kind=kLoop, calls=%f.2, metadata={op_name="jit(program)/l0_attn/mx:dsa.read/jit(_masked_read)/mx:inner.most/exp"}
+  %copy.3 = f32[4]{0} copy(%p.3), metadata={op_name="jit(program)/l0_ffn/add"}
+  %while.1 = (f32[4]{0}) while(%tuple.1), condition=%c, body=%b
+"""
+    key = tool.scope_of(hlo)
+    assert key("%fusion.1 = f32[4]{0:T(128)} fusion(%p.1)") == "mx:mla.expand"
+    assert key("%fusion.2 = f32[4]{0:T(128)} fusion(%p.2)") == "mx:inner.most"
+    assert key("%copy.3 = f32[4]{0} copy(%p.3)") == "-"
+    assert key("%while.1 = (f32[4]{0}) while(%tuple.1)") == "-"
+    assert key("%fusion.77 = f32[4]{0} fusion(%p.7)") == "-"
+    spans, enqueues, modules = _synthetic_trace()
+    rows = tool.join(spans, enqueues, modules)
+    ops = []
+    for start in (11_000, 15_000, 19_000):  # the three steps
+        ops += [(start, start + 3_000,
+                 "%while.1 = (f32[4]{0}) while(%tuple.1)"),
+                (start + 500, start + 1_500,
+                 "%fusion.1 = f32[4]{0:T(128)} fusion(%p.1)"),
+                (start + 1_500, start + 2_500,
+                 "%fusion.2 = f32[4]{0:T(128)} fusion(%p.2)"),
+                (start + 3_000, start + 4_000,
+                 "%copy.3 = f32[4]{0} copy(%p.3)")]
+    ops.sort()
+    table = tool.ops_by_name(rows, ops, "decode", 1, key=key)
+    assert sorted((name, round(ms, 6), n) for name, ms, n in table["ops"]) \
+        == [("-", 0.002, 2.0), ("mx:inner.most", 0.001, 1.0),
+            ("mx:mla.expand", 0.001, 1.0)]

@@ -524,7 +524,9 @@ def test_the_dots3_programs_compile_for_a_v5e(program, one_chip):
     keeps no array of a page's positions by heads; the prefill's
     temporaries fit beside the weights and five bound cache sets (its Q,
     K and V exist a group of heads at a time, its experts' pairs a piece
-    of the tokens at a time, and no ``(heads, T, T)`` score at all)."""
+    of the tokens at a time, and no ``(heads, T, T)`` score at all), and
+    its two full layers' masked attention is the blockwise kernel
+    (``ops/masked_latent_kernel.py``): no array of a run's scores."""
     import json
     import re
     import warnings
@@ -552,9 +554,13 @@ def test_the_dots3_programs_compile_for_a_v5e(program, one_chip):
         facts = chip_smoke.ring_hlo_facts(text, shape)
         assert facts["ring_params"] == facts["aliased"] == count, shape
         assert facts["copies"] == [], shape
-    # no Pallas kernel of this repo: XLA's own ragged-dot calls alone
+    # XLA's own ragged-dot calls and, in the prefill, ONE masked kernel a
+    # full layer (PR 49: its call sits in the scan over the groups of
+    # heads); no other Pallas kernel of this repo
+    masked = chip_smoke.named_kernel_calls(text, "masked_latent_attention")
+    assert masked == (2 if program == "prefill" else 0)
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == text.count('op_name="ragged-dot')
+        == text.count('op_name="ragged-dot') + masked
     sets = sum(e.nbytes for e in spec.values())
     assert stats.alias_size_in_bytes >= sets
     weights = stats.argument_size_in_bytes - sets
@@ -568,4 +574,7 @@ def test_the_dots3_programs_compile_for_a_v5e(program, one_chip):
         # a v5e's 16.9e9 bytes hold the weights, five sets, the program
         assert weights + 5 * sets + stats.temp_size_in_bytes < 16.5e9
         assert not re.search(r"f32\[(\d+,)?(128|64|16|8),15360,15360\]", text)
+        # nor a group of heads' scores of a block of queries against a
+        # run's keys, which XLA's form wrote to HBM and read back
+        assert chip_smoke.run_score_arrays(text, bucket) == []
         assert not re.search(r"f32\[122880,5120\]", text)

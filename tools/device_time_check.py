@@ -36,7 +36,9 @@ prints the device ops of that program summed by name — each run's `XLA Ops` ev
 interval, self times, as ms a run — and writes the program's optimised
 HLO beside the report (`device_time_<cell>.<kind>.<bucket>.hlo`): which
 op of a bucket's program takes its time, and what the compiler made of
-it, in one command.
+it, in one command.  Below it the same ops summed by the innermost ``mx:``
+scope (`jax.named_scope`) of each instruction's `op_name` in that HLO
+(`scope_of`): the device time under `mx:dsa.read`, `mx:mla.expand`, ….
 
 Per kind it reports the estimate (`serving.decode.device_interval` on
 the spans' times, the rule `GenerativeSession._book_device` books by)
@@ -48,6 +50,7 @@ import argparse
 import bisect
 import json
 import os
+import re
 import shutil
 import statistics
 import sys
@@ -104,14 +107,36 @@ def read_trace(path):
     return spans, enqueues, modules, ops
 
 
-def ops_by_name(rows, ops, kind, bucket):
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name=\"([^\"]*)\"", re.M)
+_SCOPE = re.compile(r"mx:[\w.]+")
+
+
+def scope_of(hlo_text):
+    """A key for `ops_by_name` that sums a program's device ops by the
+    innermost ``mx:`` scope (`jax.named_scope`) of each instruction's
+    `op_name` in the program's optimised HLO (a fusion carries its
+    root's); "-" for an instruction outside every scope or without
+    metadata (a `while`, a copy the compiler added)."""
+    scopes = {name: (_SCOPE.findall(op_name) or ["-"])[-1]
+              for name, op_name in _INSTRUCTION.findall(hlo_text)}
+
+    def key(event_name):
+        return scopes.get(event_name.partition(" = ")[0].lstrip("%"), "-")
+
+    return key
+
+
+def ops_by_name(rows, ops, kind, bucket, key=None):
     """The device ops of the `(kind, bucket)` program's runs in the
     trace, summed by what kind of op each is (`trace_reduce.op_kind`:
-    opcode, shapes, layouts and tiles, names dropped): {runs, module_ms,
-    ops: [[name, ms a run, events a run]]} by falling time, self times
-    (a `while` is charged what its body leaves)."""
+    opcode, shapes, layouts and tiles, names dropped; or by `key` of the
+    event's name, `scope_of`'s): {runs, module_ms, ops: [[name, ms a run,
+    events a run]]} by falling time, self times (a `while` is charged
+    what its body leaves)."""
     from benchmarks.harness import trace_reduce
 
+    key = key or trace_reduce.op_kind
     runs = [r["module"] for r in rows
             if r["module"] and (r["kind"], r["bucket"]) == (kind, bucket)]
     if not runs:
@@ -119,7 +144,7 @@ def ops_by_name(rows, ops, kind, bucket):
     starts = [op[0] for op in ops]  # sorted, as `ops` is
     seconds, counts = {}, {}
     for m_start, m_end, _name in runs:
-        inside = [(s, e, trace_reduce.op_kind(name)) for s, e, name in ops[
+        inside = [(s, e, key(name)) for s, e, name in ops[
             bisect.bisect_left(starts, m_start):
             bisect.bisect_right(starts, m_end)] if e <= m_end]
         for name, t in trace_reduce._self_times(inside).items():
@@ -364,7 +389,7 @@ def main(argv=None):
           flush=True)
     out = args.out or os.path.join(ROOT, "chiprun_out",
                                    "device_time_%s.json" % cell.name)
-    summary["ops_by_program"] = {}
+    summary["ops_by_program"], summary["scopes_by_program"] = {}, {}
     for kind, bucket in wanted:
         tag = "%s.%d" % (kind, bucket)
         table = summary["ops_by_program"][tag] = ops_by_name(
@@ -374,9 +399,15 @@ def main(argv=None):
         for name, ms, n in table["ops"][:OPS_SHOWN]:
             print("  %8.4f ms  x%-5.4g %s" % (ms, n, name))
         if (kind, bucket) in programs:
+            hlo = programs[kind, bucket].hlo_text() or ""
             with open("%s.%s.hlo" % (os.path.splitext(out)[0], tag),
                       "w") as f:
-                f.write(programs[kind, bucket].hlo_text() or "")
+                f.write(hlo)
+            scopes = summary["scopes_by_program"][tag] = ops_by_name(
+                rows, ops, kind, bucket, key=scope_of(hlo))["ops"]
+            print("[device_time] scopes of %s:" % tag)
+            for name, ms, n in scopes:
+                print("  %8.4f ms  x%-5.4g %s" % (ms, n, name))
     with open(out, "w") as f:
         json.dump(summary, f, indent=1)
     os.remove(kept)
