@@ -124,6 +124,54 @@ completion's way to the host.  Two subtractions and one ``observe`` a
 flight on clock readings the spans already took; no profiler session is
 needed (docs/observability.md "Device time without a profiler").
 
+**A pass and its legs.**  A PASS is one iteration of
+``ModelServer._loop``: its wait for work, at most one admission, one
+:meth:`decode_step` a live tenant.  Its LEGS are the leaf spans the
+batcher's thread opens on the way — ``serve.wait_work``, ``decode.pack``
+(a step's rows or a prompt's bucket), ``decode.dispatch``,
+``decode.device_wait``, ``decode.d2h``, ``decode.emit``, whichever
+flight they belong to: the step a call dispatches is one flight and the
+step or prefill it lands another — and ``rest``: the pass's duration
+less the leaves', the thread's time under NO span (admission, the
+counters' own booking, the parents' bookkeeping).  Each leaf adds its
+``seconds`` to the server's :class:`Pass` as it closes, and the pass ends
+where the next ``serve.wait_work`` began (that span's ``end_ns`` less its
+``seconds``): no clock is read for it.  Every pass books `rest`
+(``serving.loop.unspanned_seconds``, ``serving.loop.passes``).
+
+A leg is a STALL when it outlasts its limit: `_STALL_FLOOR_S` for a host
+leg (``wait_work`` only while a session is live, its wait bounded by the
+decode window), and for a fence the floor plus `_STALL_REF_X` times the
+program's own mean SEEN device time (``_Bucket.seen_n`` / ``seen_s``, fed
+where the histograms above are), once `_STALL_MIN_SEEN` of its flights
+were seen.  A pass that built or compiled a program — a bucket's first
+``_program`` or first flight, ``_build_ladder``'s idle steps (the two
+that compile are ``compile`` brackets of the flight recorder too, which
+hold the stall watchdog still), a classic fill — judges none of its
+host legs, and the synchronous ``_call``, a
+shutdown's landings and a session driven by hand (no pass) are never
+judged: a replica's warm-up runs through this same loop and logs
+nothing.  A stall books ``serving.stalls`` and
+``serving.stall_seconds[.<leg>]`` and leaves one RECORD (:meth:`_stall`):
+which leg of which pass, for how long against what limit; the flight —
+``seq``, program, kind, bucket, rows, enqueue to ready, the mean it was
+held to —; what the THREAD did up to the stall (CPU seconds, context
+switches of its own will and against it, major faults, over `sampled_s`
+seconds: sampled at a pass's start — the end of its ``serve.wait_work``
+— every `_SAMPLE_EVERY_NS`, not every pass, and once more at the stall);
+what the
+HOST and the device said then (load, pressure, a compile, the
+interpreter's collections, the allocator); and, filled at the NEXT
+landing, how long that flight's fence blocked and whether it was seen —
+step n+1 is enqueued before step n's fence, so a next fence that blocks
+its own device time says flight n itself ended late, and one that
+returns at once says the chip went on and the host learned late.  The
+finished record is kept for :meth:`stats`, logged as ONE line ``mx.stall
+{json}`` and put into the flight recorder (kind ``"stall"``), where every
+flight is an enter/exit pair too (kind ``"flight"``): the stall watchdog,
+armed, dumps every thread's stack while one stands open
+(docs/observability.md "Reading a stall record").
+
 Retirement (EOS, token budget, or ring-full) resolves the request's
 future with a :class:`GenerateResult` and frees the slot under
 admission control: prompts that arrive while all slots are busy wait
@@ -136,13 +184,19 @@ still in flight then are read first, they are computed already.
 from __future__ import annotations
 
 import collections
+import contextlib
+import gc
+import json
 import logging
+import os
+import resource
 import time
 
 import numpy as _np
 
 from ..base import MXNetError
-from .. import locks
+from .. import locks, profiler, telemetry
+from ..obs import recorder
 from .bucket import bucket_ladder, choose_bucket
 from .request import Request
 
@@ -170,6 +224,164 @@ _FENCE_FLOOR_S = 100e-6
 # something — a reader that takes a zero denominator for "no reading"
 # (the benchmark's `ratio`) then reads 0, not nothing
 _UNSEEN = 1e-6
+# a leg of the batcher's pass that outlasts this stood still (module
+# docstring, "A pass and its legs").  The slowest sound host leg on
+# record is a 3 ms dispatch and the longest sound program a 1.0 s prefill
+# (PERF.md section 7 (9)); the stalls it is there to name last 1.3-3.4 s
+_STALL_FLOOR_S = 0.25
+# a fence may wait its program's whole device time and no more (every
+# flight before it has been fenced): the floor plus this many times the
+# program's own mean SEEN time leaves a sound flight its scatter
+_STALL_REF_X = 2.0
+# a program is judged once this many of its flights were seen: a bucket's
+# first calls have no history to be held to
+_STALL_MIN_SEEN = 3
+# stall records a session keeps for `stats()`; the log line and the
+# flight recorder have every one
+_STALLS_KEPT = 16
+# the batcher's thread is sampled (`_thread_sample`) at the first pass
+# that begins this long after the last sample, not every pass: on the
+# v5e's host the three system calls cost 18 us together (0.4 us each on
+# an ordinary Linux; PERF.md section 6, PR 50), and a stall of a quarter
+# of a second or more is told as well from a baseline that old
+_SAMPLE_EVERY_NS = 250_000_000
+
+
+def _thread_sample():
+    """What the calling thread has used so far: CPU ns of the thread and
+    of the process, and the thread's `getrusage` (context switches,
+    faults): three system calls, no more."""
+    return (time.thread_time_ns(), time.process_time_ns(),
+            resource.getrusage(resource.RUSAGE_THREAD))
+
+
+def _thread_growth(old, new):
+    """The fields of a stall record that say what the thread did between
+    two `_thread_sample`s: on the CPU (`thread_cpu_s`), descheduled of
+    its own will (`nvcsw`: it blocked) or against it (`nivcsw`: the host
+    took the core), waiting for a page (`majflt`)."""
+    (cpu0, proc0, ru0), (cpu1, proc1, ru1) = old, new
+    return {"thread_cpu_s": (cpu1 - cpu0) * 1e-9,
+            "process_cpu_s": (proc1 - proc0) * 1e-9,
+            "nvcsw": ru1.ru_nvcsw - ru0.ru_nvcsw,
+            "nivcsw": ru1.ru_nivcsw - ru0.ru_nivcsw,
+            "majflt": ru1.ru_majflt - ru0.ru_majflt}
+
+
+def _pressure(resource_name):
+    """`some avg10` of ``/proc/pressure/<resource_name>``: the share of
+    the last ten seconds in which some task of the HOST waited for it;
+    None where the kernel keeps none."""
+    try:
+        with open("/proc/pressure/" + resource_name) as f:
+            for line in f:
+                if line.startswith("some"):
+                    return float(line.split("avg10=")[1].split()[0])
+    except (OSError, IndexError, ValueError):
+        pass
+    return None
+
+
+def _host_reading(device, leg_seconds):
+    """What is read of the host and the device AT a stall only: the load
+    and the pressure the whole host is under, whether a compile was open
+    or closed inside the leg, and what the device's allocator holds."""
+    try:
+        mem = device.memory_stats() or {}
+    except Exception:  # noqa: BLE001 — a backend without the statistics
+        mem = {}
+    return {"loadavg": list(os.getloadavg()),
+            "psi_cpu_some_avg10": _pressure("cpu"),
+            "psi_mem_some_avg10": _pressure("memory"),
+            "psi_io_some_avg10": _pressure("io"),
+            "compiling": bool(
+                recorder.compiling() or recorder.last_compile_exit()
+                > time.monotonic() - leg_seconds),
+            "bytes_in_use": mem.get("bytes_in_use"),
+            "largest_free_block_bytes": mem.get("largest_free_block_bytes"),
+            "num_allocs": mem.get("num_allocs")}
+
+
+class Pass:
+    """One iteration of ``ModelServer._loop`` as the sum of its legs
+    (module docstring, "A pass and its legs"): the server owns one and
+    hands it to every generative session it registers; a session driven
+    by hand has none and judges nothing.
+
+    `number`: passes begun; `on`: whether this pass is kept at all
+    (telemetry or the flight recorder is on); `start_ns`: when its
+    ``serve.wait_work`` began; `leaves`: seconds its leaf spans have added
+    so far; `unjudged`: it built or compiled a program, or ran a classic
+    fill (whose program is under no leg): none of its host legs is a
+    stall; `owner`: the session whose leg closed last, which takes a
+    stall of the loop's own two legs (``wait_work``, ``rest``);
+    `sample`, `sampled_ns`: the thread's last `_thread_sample` and when
+    it was taken — at a pass's start, at most `_SAMPLE_EVERY_NS` before
+    this one's."""
+
+    __slots__ = ("number", "on", "start_ns", "leaves", "unjudged", "owner",
+                 "sample", "sampled_ns")
+
+    def __init__(self):
+        self.number = 0
+        self.on = False
+        self.start_ns = 0
+        self.leaves = 0.0
+        self.unjudged = False
+        self.owner = None
+        self.sample = None
+        self.sampled_ns = 0
+
+    def growth(self, now_ns):
+        """What the thread did since the last sample (`_thread_growth`),
+        over how many seconds (`sampled_s`), sampled NOW, at `now_ns` on
+        the spans' clock: the sample is the new baseline."""
+        old, since = self.sample, self.sampled_ns
+        self.sample, self.sampled_ns = _thread_sample(), now_ns
+        grown = _thread_growth(old, self.sample)
+        grown["sampled_s"] = (now_ns - since) * 1e-9
+        return grown
+
+    def turn(self, wait, live):
+        """``serve.wait_work`` has closed: the pass before it ended where
+        this span began (no clock is read: `end_ns` less `seconds`), so
+        book what of it lay under no span, judge that and — `live`: a
+        session was mid-generation, the wait was bounded by the decode
+        window — the wait itself, and begin the next pass with the wait as
+        its first leg."""
+        counted = telemetry.enabled()
+        if not (counted or recorder.enabled()):
+            self.on = False
+            return
+        seconds, end_ns = wait.seconds, wait.end_ns
+        start_ns = end_ns - int(seconds * 1e9)
+        owner = self.owner
+        if self.on:
+            rest = (start_ns - self.start_ns) * 1e-9 - self.leaves
+            if counted:
+                telemetry.inc("serving.loop.passes")
+                telemetry.observe("serving.loop.unspanned_seconds", rest)
+            if owner is not None:
+                grown = None
+                if rest > _STALL_FLOOR_S and not self.unjudged:
+                    grown = self.growth(end_ns)
+                    owner._stall("rest", rest, _STALL_FLOOR_S, growth=grown)
+                if live and seconds > _STALL_FLOOR_S:
+                    # the wait lies between the two samples as the rest
+                    owner._stall("wait_work", seconds, _STALL_FLOOR_S,
+                                 growth=grown or self.growth(end_ns),
+                                 number=self.number + 1)
+                if owner._stalls_open and not owner._flights:
+                    owner._finish_stalls()  # nothing in flight to land next
+        else:
+            self.on = True
+            self.sampled_ns = end_ns - _SAMPLE_EVERY_NS
+        if end_ns - self.sampled_ns >= _SAMPLE_EVERY_NS:
+            self.sample, self.sampled_ns = _thread_sample(), end_ns
+        self.number += 1
+        self.start_ns = start_ns
+        self.leaves = seconds
+        self.unjudged = False
 
 
 def device_interval(last, sent_ns, ready_ns, blocked):
@@ -252,18 +464,32 @@ class _Bucket:
     """What a flight says of the bucket program it runs: `kind`
     (``"decode"`` | ``"prefill"``), `bucket` (rows | positions),
     `program` (the executable's name in a device trace's ``XLA
-    Modules`` line; ``""`` until its first flight has compiled it) and
-    `hists`, the two histograms its device time goes into.  Built once a
+    Modules`` line; ``""`` until its first flight has compiled it),
+    `hists`, the two histograms its device time goes into, `label`, what
+    the flight recorder says of its flights (``<tenant> <kind>.<bucket>``)
+    and `seen_n` / `seen_s`, how many of its flights were seen and their
+    device seconds: the mean a fence on it is held to.  Built once a
     bucket program: a call formats no name."""
 
-    __slots__ = ("kind", "bucket", "program", "hists")
+    __slots__ = ("kind", "bucket", "program", "hists", "label", "seen_n",
+                 "seen_s")
 
-    def __init__(self, kind, bucket):
+    def __init__(self, kind, bucket, tenant=""):
         self.kind = kind
         self.bucket = bucket
         self.program = ""
         hist = "serving.device.%s_seconds" % kind
         self.hists = (hist, "%s.%d" % (hist, bucket))
+        self.label = "%s %s.%d" % (tenant, kind, bucket)
+        self.seen_n = 0
+        self.seen_s = 0.0
+
+    def fence_limit(self):
+        """How long a fence on this program may block before it is a
+        stall; None while too few of its flights were seen to say."""
+        if self.seen_n < _STALL_MIN_SEEN:
+            return None
+        return _STALL_FLOOR_S + _STALL_REF_X * self.seen_s / self.seen_n
 
 
 class _Flight:
@@ -309,7 +535,6 @@ class GenerativeSession:
     def __init__(self, name, model, params, ctx=None, max_sessions=8,
                  max_len=256, max_decode_tokens=64, eos_id=None,
                  seq_buckets=None):
-        from .. import telemetry
         from ..predict import Predictor
 
         self.name = name
@@ -338,7 +563,8 @@ class GenerativeSession:
         from ..context import current_context
         from ..ops.attention import decode_block
 
-        self._platform = (ctx or current_context()).jax_device().platform
+        self._device = (ctx or current_context()).jax_device()
+        self._platform = self._device.platform
         # positions of each ring's page that one step of the decode
         # program's attention reads at a time, where it stops at the
         # block that holds `length`; the whole page where it reads whole
@@ -406,6 +632,18 @@ class GenerativeSession:
         # time can start from it (nothing landed, or a synchronous call
         # or a drain came between)
         self._last_fence = None
+        # the server's pass, whose legs this session's spans are (None: a
+        # session driven by hand); stall records that wait for the next
+        # landing, the last `_STALLS_KEPT` finished ones, and their count
+        self._pass = None
+        self._stalls_open = []
+        self._stalls = collections.deque(maxlen=_STALLS_KEPT)
+        self._stall_count = 0
+        # collections of the interpreter, by generation, at the last
+        # stall: a record says how many came since
+        self._gc_seen = [g["collections"] for g in gc.get_stats()]
+        # the two gauges `_note_occupancy` wrote last
+        self._occupancy = None
         self._prog_lock = locks.lock("serving.decode_progs")
         self._programs = {}
         self._buckets = {}  # (kind, bucket) -> _Bucket, beside _programs
@@ -499,15 +737,14 @@ class GenerativeSession:
         """(executor, fn) for one (prefill-T | decode-B) bucket; the
         session pins executors like TenantSession does, so
         compile-once-per-bucket survives predictor-cache eviction."""
-        from .. import telemetry
-
         key = ("prefill", seq) if prefill else ("decode", batch)
         with self._prog_lock:
             exe = self._programs.get(key)
             if exe is None:
                 exe = self._programs[key] = pred.executor_for(
                     self._shapes(batch, seq, prefill))
-                self._buckets[key] = _Bucket(*key)
+                self._buckets[key] = _Bucket(*key, tenant=self.name)
+                self._unjudged()  # this pass binds, and soon compiles
                 if telemetry.enabled():
                     telemetry.inc("serving.decode.bucket_programs")
             fn = exe.serve_program(self._wire[bool(prefill)])
@@ -574,8 +811,6 @@ class GenerativeSession:
         warm-up and `_run` use; the batcher's own calls stay in flight
         (`_dispatch` / `_land`).  It times no program, and no flight's
         device time starts from a fence before it."""
-        from .. import profiler
-
         with profiler.span("decode.dispatch", cat="serving"):
             small, state = self._launch(exe, fn, state, data, slot, length,
                                         logits=True)
@@ -605,30 +840,42 @@ class GenerativeSession:
             self._book_moe_load(extra[0])
         return logits
 
-    def _dispatch(self, exe, fn, data, slot, length, rows, prog,
+    def _dispatch(self, exe, fn, data, slot, length, rows, prog, pack,
                   hist=None, riders=None):
         """Queue one call of the bucket program `prog` on the live state
         and leave it in flight: nothing here waits for the device.
         `rows`: the sessions of its tokens, a prefill bucket's prompt
-        first; `riders`: a mixed step's ``(row_data, row_slot,
+        first; `pack`: the closed ``decode.pack`` span that made its
+        operands; `riders`: a mixed step's ``(row_data, row_slot,
         row_length)``."""
-        from .. import profiler
-
         self._seq = seq = self._seq + 1
         live = len(rows) - (prog.kind == "prefill")
-        with profiler.span("decode.dispatch", cat="serving", hist=hist,
-                           seq=seq, program=prog.program,
-                           kind=self._kind(prog), bucket=prog.bucket,
-                           rows=live) as sent:
-            outs, self._state = self._launch(
-                exe, fn, self._state, data, slot, length, logits=False,
-                riders=riders)
-        if not prog.program:
+        sent = profiler.span("decode.dispatch", cat="serving", hist=hist,
+                             seq=seq, program=prog.program,
+                             kind=self._kind(prog), bucket=prog.bucket,
+                             rows=live)
+        if prog.program:
+            with sent:
+                outs, self._state = self._launch(
+                    exe, fn, self._state, data, slot, length, logits=False,
+                    riders=riders)
+        else:
+            # a bucket's first flight compiles its program, or reads it
+            # back from the compile cache for a second
+            with self._building(prog.label), sent:
+                outs, self._state = self._launch(
+                    exe, fn, self._state, data, slot, length, logits=False,
+                    riders=riders)
             # read once, off the compiled object the first call has just
             # made: beside that compile, never in a warmed bucket's path
             prog.program = fn.module_name() or ""
-        self._flights.append(
-            _Flight(outs, rows, live, prog, seq, sent.end_ns))
+        flight = _Flight(outs, rows, live, prog, seq, sent.end_ns)
+        self._flights.append(flight)
+        if recorder.enabled():
+            # open until `_land` has fenced on it: what the stall
+            # watchdog's post-mortem names (obs/watchdog.py)
+            recorder.record("flight", "enter", seq, detail=prog.label)
+        self._sent(pack, sent, flight)
 
     def _kind(self, prog):
         """What a span says the bucket program `prog` is: ``"decode"``,
@@ -642,23 +889,33 @@ class GenerativeSession:
         row — but for a row whose session has retired since (it hit EOS
         while the row was in flight): that token is dropped.  Then, if
         `book`, book the flight's device time from the fence (module
-        docstring)."""
-        from .. import profiler, telemetry
-
-        with profiler.span("decode.device_wait", cat="serving",
-                           hist=hists[0], seq=flight.seq,
-                           program=flight.prog.program) as wait:
-            flight.outs[0].block_until_ready()
-        with profiler.span("decode.d2h", cat="serving", hist=hists[1]):
+        docstring); a shutdown's landing (not `book`) is no leg of a
+        pass either."""
+        try:
+            with profiler.span("decode.device_wait", cat="serving",
+                               hist=hists[0], seq=flight.seq,
+                               program=flight.prog.program) as wait:
+                flight.outs[0].block_until_ready()
+        finally:
+            if recorder.enabled():
+                recorder.record("flight", "exit", flight.seq)
+        with profiler.span("decode.d2h", cat="serving",
+                           hist=hists[1]) as read:
             token, *extra = (_np.asarray(o) for o in flight.outs)
         if self._reports_moe_load:
             self._book_moe_load(extra[0])
-        with profiler.span("decode.emit", cat="serving", hist=hists[2]):
+        with profiler.span("decode.emit", cat="serving",
+                           hist=hists[2]) as emit:
             live = [(i, sess, int(t))
                     for i, (sess, t) in enumerate(zip(flight.rows, token))
                     if not sess.retired]
             for _, sess, t in live:
                 self._emit(sess, t)
+        # the stall records this landing's fence finishes
+        waiting = None
+        if self._stalls_open:
+            waiting, self._stalls_open = self._stalls_open, []
+        stalled = book and self._landed(flight, wait, read, emit)
         dropped = len(flight.rows) - len(live)
         # a session's first token is its prefill's, not a decode token:
         # the decode rows are the flight's last `riders`
@@ -672,22 +929,27 @@ class GenerativeSession:
             telemetry.inc("serving.decode.dropped_rows", dropped)
         # after the emit: whether a session is live through the gap to
         # the next flight is known once this one's rows have retired
+        seen = None
         if book:
-            self._book_device(flight, wait.end_ns,
-                              wait.seconds > _FENCE_FLOOR_S)
+            seen = self._book_device(flight, wait.end_ns,
+                                     wait.seconds > _FENCE_FLOOR_S,
+                                     feed=not stalled)
         else:
             self._last_fence = None
+        if waiting:
+            self._finish_stalls(waiting, wait.seconds, seen)
 
-    def _book_device(self, flight, ready_ns, blocked):
+    def _book_device(self, flight, ready_ns, blocked, feed=True):
         """Flight k's time on the device from its fence and the one
         before it (module docstring): `ready_ns` the end of its
         ``decode.device_wait`` span, `blocked` whether that span
-        outlasted the floor."""
-        from .. import telemetry
-
+        outlasted the floor.  Returns whether the flight was seen (None
+        with telemetry off); `feed`: a seen flight also feeds the mean
+        its program's fences are held to — not one whose own fence was a
+        stall."""
         if not telemetry.enabled():
             self._last_fence = None
-            return
+            return None
         last = self._last_fence
         self._last_fence = (ready_ns, blocked, bool(self._active))
         start, seen, gap = device_interval(last, flight.enqueued_ns,
@@ -697,9 +959,13 @@ class GenerativeSession:
             telemetry.observe("serving.device.starved_seconds", gap * 1e-9)
         prog = flight.prog
         if seen:
+            seconds = (ready_ns - start) * 1e-9
             telemetry.inc("serving.device.seen_flights")
             for hist in prog.hists:
-                telemetry.observe(hist, (ready_ns - start) * 1e-9)
+                telemetry.observe(hist, seconds)
+            if feed:
+                prog.seen_n += 1
+                prog.seen_s += seconds
         # what the two means divide by: steps, and a prefill's positions
         # (so that microseconds a position divide like by like)
         weight = 1 if seen else _UNSEEN
@@ -708,6 +974,145 @@ class GenerativeSession:
                           weight * prog.bucket)
         else:
             telemetry.inc("serving.device.decode_seen", weight)
+        return seen
+
+    # ------------------------------------------------------------------
+    # a pass, its legs, and a leg that stands still
+    # ------------------------------------------------------------------
+    def _unjudged(self):
+        """This pass builds or compiles a program: none of its host legs
+        is a stall (the stall watchdog's own rule)."""
+        if self._pass is not None:
+            self._pass.unjudged = True
+
+    @contextlib.contextmanager
+    def _building(self, what):
+        """The batcher compiles `what` (a bucket's first flight, the
+        ladder's idle steps) with flights in the air: the pass is not
+        judged, and a ``compile`` bracket of the flight recorder holds
+        the stall watchdog still meanwhile and restarts the age of the
+        flights that stood open behind it."""
+        self._unjudged()
+        seq = (recorder.record("compile", "enter", detail=what)
+               if recorder.enabled() else None)
+        try:
+            yield
+        finally:
+            if seq is not None and recorder.enabled():
+                recorder.record("compile", "exit", seq)
+
+    def _sent(self, pack, sent, flight):
+        """The two legs in which `flight` was dispatched have closed, its
+        ``decode.pack`` and ``decode.dispatch``: add them to the pass,
+        and call one a stall if it outlasted the floor in a pass that
+        built nothing."""
+        pas = self._pass
+        if pas is None or not pas.on:
+            return
+        pas.leaves += pack.seconds + sent.seconds
+        pas.owner = self
+        if not pas.unjudged:
+            if pack.seconds > _STALL_FLOOR_S:
+                self._stall("pack", pack.seconds, _STALL_FLOOR_S, flight)
+            if sent.seconds > _STALL_FLOOR_S:
+                self._stall("dispatch", sent.seconds, _STALL_FLOOR_S, flight)
+
+    def _landed(self, flight, wait, read, emit):
+        """The same for the three legs in which `flight` was landed: its
+        ``decode.device_wait``, held to its program's own history
+        (`_Bucket.fence_limit`), and ``decode.d2h`` and ``decode.emit``.
+        Returns whether the fence stood still."""
+        pas = self._pass
+        if pas is None or not pas.on:
+            return False
+        pas.leaves += wait.seconds + read.seconds + emit.seconds
+        pas.owner = self
+        stalled = False
+        if wait.seconds > _STALL_FLOOR_S:  # no fence's limit is under it
+            limit = flight.prog.fence_limit()
+            stalled = limit is not None and wait.seconds > limit
+            if stalled:
+                self._stall("device_wait", wait.seconds, limit, flight, wait)
+        if not pas.unjudged:
+            if read.seconds > _STALL_FLOOR_S:
+                self._stall("d2h", read.seconds, _STALL_FLOOR_S, flight, wait)
+            if emit.seconds > _STALL_FLOOR_S:
+                self._stall("emit", emit.seconds, _STALL_FLOOR_S, flight,
+                            wait)
+        return stalled
+
+    def _stall(self, leg, seconds, limit, flight=None, fence=None,
+               growth=None, number=None):
+        """Book a leg that stood still and open its record (docs/
+        observability.md "Reading a stall record"): which leg of which
+        pass for how long against what limit, the flight it fenced on or
+        dispatched (`fence`: its closed ``decode.device_wait`` span), what
+        the thread did since its last sample (`growth`, or sampled now),
+        and what is read of the host and the device now.
+        The record is finished, logged and kept at the NEXT landing,
+        whose fence says whether the device or the host was late."""
+        if telemetry.enabled():
+            telemetry.inc("serving.stalls")
+            telemetry.observe("serving.stall_seconds", seconds)
+            telemetry.observe("serving.stall_seconds." + leg, seconds)
+        self._stall_count += 1
+        try:
+            self._stalls_open.append(self._stall_record(
+                leg, seconds, limit, flight, fence, growth, number))
+        except Exception:  # noqa: BLE001 — a record never fails a step
+            logging.getLogger(__name__).warning(
+                "tenant %r: no record of a %.3f s stall of leg %s",
+                self.name, seconds, leg, exc_info=True)
+
+    def _stall_record(self, leg, seconds, limit, flight, fence, growth,
+                      number):
+        """The record `_stall` opens."""
+        pas = self._pass
+        if growth is None:
+            growth = pas.growth(time.perf_counter_ns())
+        collections_now = [g["collections"] for g in gc.get_stats()]
+        rec = {"leg": leg, "seconds": seconds, "limit_s": limit,
+               "wall_time": time.time(), "tenant": self.name,
+               "pass": pas.number if number is None else number,
+               "seq": None, "program": None, "kind": None, "bucket": None,
+               "rows": None, "enqueued_to_ready_s": None,
+               "ref_device_s": None}
+        if flight is not None:
+            prog = flight.prog
+            rec.update(
+                seq=flight.seq, program=prog.program, kind=self._kind(prog),
+                bucket=prog.bucket, rows=flight.riders,
+                enqueued_to_ready_s=(
+                    None if fence is None
+                    else (fence.end_ns - flight.enqueued_ns) * 1e-9),
+                ref_device_s=(prog.seen_s / prog.seen_n if prog.seen_n
+                              else None))
+        rec.update(growth)
+        rec.update(next_wait_s=None, next_seen=None)
+        rec.update(_host_reading(self._device, seconds))
+        rec["gc_collections"] = [now - seen for now, seen in
+                                 zip(collections_now, self._gc_seen)]
+        self._gc_seen = collections_now
+        return rec
+
+    def _finish_stalls(self, records=None, next_wait_s=None,
+                       next_seen=None):
+        """Finish `records` (all that are open, by default) with the
+        fence of the flight landed after them — how long it blocked and
+        whether it was seen; None where nothing was left to land —, keep
+        them for `stats()`, log each as one ``mx.stall {json}`` line and
+        put it into the flight recorder."""
+        if records is None:
+            records, self._stalls_open = self._stalls_open, []
+        for rec in records:
+            rec["next_wait_s"] = next_wait_s
+            rec["next_seen"] = next_seen
+            line = json.dumps(rec, default=str)
+            self._stalls.append(rec)
+            logging.getLogger(__name__).warning("mx.stall %s", line)
+            if recorder.enabled():
+                # a point event: an exit that no enter opened
+                recorder.record("stall", "exit", rec["pass"], detail=line)
 
     def _per_ring(self, total):
         """`total`, a sum over the rings, as the mean a ring: a whole
@@ -720,8 +1125,6 @@ class GenerativeSession:
         (``call_counters(positions=...)`` of a prefill bucket, ``(rows=
         ..., lengths=..., ...)`` of a decode step), told the platform its
         programs are lowered for."""
-        from .. import telemetry
-
         if telemetry.enabled() and self._call_counters is not None:
             for name, n in self._call_counters(platform=self._platform,
                                                **call).items():
@@ -734,8 +1137,6 @@ class GenerativeSession:
         rows included — the device computed them), experts that got at
         least one token, expert slots offered, and the fullest expert's
         tokens, each summed over the layers."""
-        from .. import telemetry
-
         if telemetry.enabled():
             telemetry.inc("moe.pairs", int(load.sum()))
             telemetry.inc("moe.experts_hit", int((load > 0).sum()))
@@ -776,8 +1177,6 @@ class GenerativeSession:
         `rows`, the live sessions that decode on, packed into it where it
         is the mixed step — and book it.  A dispatch that fails gives
         the slot back and raises."""
-        from .. import profiler, telemetry
-
         req, n = sess.req, sess.prompt_len
         bucket = choose_bucket(self._seq_ladder, n)
         riders, live = None, len(rows)
@@ -785,15 +1184,18 @@ class GenerativeSession:
                            bucket=bucket, prompt=n):
             req.service_at = time.monotonic()
             try:
-                exe, fn = self._program(self._prefill_pred, 1, bucket, True)
-                data = _np.zeros((1, bucket), _np.float32)
-                data[0, :n] = req.inputs["data"].reshape(-1)
-                if self._mixed:
-                    riders = self._pack(rows, self._slots)
+                # the same work for a prompt as for a step's rows
+                with profiler.span("decode.pack", cat="serving") as pack:
+                    exe, fn = self._program(self._prefill_pred, 1, bucket,
+                                            True)
+                    data = _np.zeros((1, bucket), _np.float32)
+                    data[0, :n] = req.inputs["data"].reshape(-1)
+                    if self._mixed:
+                        riders = self._pack(rows, self._slots)
                 self._dispatch(
                     exe, fn, data, _np.full((1,), sess.slot, _np.float32),
                     _np.full((1,), n, _np.float32), [sess, *rows],
-                    self._buckets["prefill", bucket], riders=riders)
+                    self._buckets["prefill", bucket], pack, riders=riders)
             except BaseException:
                 self._free.append(sess.slot)
                 raise
@@ -821,14 +1223,15 @@ class GenerativeSession:
                                 computed=self._slots * self._mixed)
 
     def _note_occupancy(self):
-        from .. import telemetry
-
         if not telemetry.enabled():
             return
-        used = self._slots - len(self._free)
-        telemetry.set_gauge("kv.slot_occupancy", used / self._slots)
-        telemetry.set_gauge("serving.decode.active_sessions",
-                            len(self._active))
+        # the two change at an admission or a retirement, not a pass
+        now = (self._slots - len(self._free), len(self._active))
+        if now == self._occupancy:
+            return
+        self._occupancy = now
+        telemetry.set_gauge("kv.slot_occupancy", now[0] / self._slots)
+        telemetry.set_gauge("serving.decode.active_sessions", now[1])
 
     # ------------------------------------------------------------------
     # the decode iteration
@@ -850,8 +1253,6 @@ class GenerativeSession:
         where the model keeps two programs, the prefills admitted since.
         Returns the rows dispatched (0 when the call only landed, or
         found nothing to do)."""
-        from .. import profiler
-
         landing, self._flights = self._flights, []
         rows = [s for s in self._active if self._wants_row(s)]
         prompt = self._pending.popleft() if self._pending else None
@@ -875,7 +1276,7 @@ class GenerativeSession:
             with profiler.span(
                     "serve.decode_step", cat="serving",
                     hist="serving.decode.step_seconds" if pure else None,
-                    n=n, bucket=bucket, rows=n,
+                    bucket=bucket, rows=n,
                     program="decode" if prompt is None else "mixed",
                     seq=self._seq + 1 if rows or prompt is not None else 0,
                     landed=step.seq if step is not None else 0):
@@ -892,6 +1293,8 @@ class GenerativeSession:
                                program=flight.prog.program):
                 self._land(flight)
         self._note_occupancy()
+        if self._stalls_open and not self._flights:
+            self._finish_stalls()  # nothing is in flight to land next
         return n
 
     def _build_ladder(self):
@@ -906,9 +1309,11 @@ class GenerativeSession:
         the bucket would compile, for seconds, under live sessions, the
         first time they are."""
         self._laddered = True
-        for b in self._decode_ladder:
-            if ("decode", b) not in self._programs:
-                self._state = self._idle_step(b, self._state)
+        # its idle steps are under no leg of the pass
+        with self._building("%s decode ladder" % self.name):
+            for b in self._decode_ladder:
+                if ("decode", b) not in self._programs:
+                    self._state = self._idle_step(b, self._state)
 
     def _ride(self, prompt, rows):
         """Dispatch the mixed step of `prompt` with `rows` riding it;
@@ -940,15 +1345,14 @@ class GenerativeSession:
     def _dispatch_step(self, rows, bucket, timed=True):
         """Pack `rows` into the `bucket`-row decode program and dispatch
         it; `timed`: the two legs feed their histograms."""
-        from .. import profiler
-
         with profiler.span(
                 "decode.pack", cat="serving",
-                hist="serving.decode.pack_seconds" if timed else None):
+                hist="serving.decode.pack_seconds" if timed else None
+                ) as pack:
             exe, fn = self._program(self._decode_pred, bucket, 1, False)
             packed = self._pack(rows, bucket)
         self._dispatch(
-            exe, fn, *packed, rows, self._buckets["decode", bucket],
+            exe, fn, *packed, rows, self._buckets["decode", bucket], pack,
             hist="serving.decode.dispatch_seconds" if timed else None)
         for sess in rows:
             sess.fed += 1
@@ -958,8 +1362,6 @@ class GenerativeSession:
         """The counters of one decode dispatch: `n` real rows `packed`
         (``_pack``'s arrays) in a program of `computed` rows — and, a
         mixed step, `positions` of a prompt's bucket beside them."""
-        from .. import telemetry
-
         if not telemetry.enabled():
             return
         data, _, length = packed
@@ -1023,8 +1425,6 @@ class GenerativeSession:
         """Resolve the session's future and free its slot — mid-window
         retirement is the normal path (sessions leave between decode
         steps; the next step simply re-packs without them)."""
-        from .. import telemetry
-
         sess.retired = True
         if sess in self._active:
             self._active.remove(sess)
@@ -1044,14 +1444,17 @@ class GenerativeSession:
         flight that cannot be read is dropped."""
         landing, self._flights = self._flights, []
         try:
-            for flight in landing:
+            while landing:
                 # a shutdown's fences time no program
-                self._land(flight, book=False)
+                self._land(landing[0], book=False)
+                del landing[0]
         except Exception:  # noqa: BLE001 — the futures come first
             logging.getLogger(__name__).warning(
                 "tenant %r: a program call in flight at shutdown could "
                 "not be read; its tokens are dropped", self.name,
                 exc_info=True)
+            self._forget(landing[1:])
+        self._finish_stalls()
         pending, self._pending = self._pending, collections.deque()
         for sess in [*self._active, *pending]:
             self._retire(sess, reason)
@@ -1061,10 +1464,10 @@ class GenerativeSession:
         every active session, so all of them share the failure.  Fail
         their futures and free the slots — the tenant keeps accepting
         new prompts (a request-level error, not a server-level one)."""
-        from .. import telemetry
-
+        self._forget(self._flights)
         self._flights = []
         self._last_fence = None
+        self._finish_stalls()
         pending, self._pending = self._pending, collections.deque()
         for sess in [*self._active, *pending]:
             self._free.append(sess.slot)
@@ -1073,12 +1476,25 @@ class GenerativeSession:
         if telemetry.enabled():
             self._note_occupancy()
 
+    @staticmethod
+    def _forget(flights):
+        """Close the flight recorder's brackets of `flights` that will
+        never be landed: none may stand open for the stall watchdog to
+        find."""
+        if recorder.enabled():
+            for flight in flights:
+                recorder.record("flight", "exit", flight.seq)
+
     def stats(self):
+        """`stalls`: the last `_STALLS_KEPT` finished stall records,
+        oldest first; `stall_count`: every stall so far."""
         return {"active_sessions": len(self._active),
                 "free_slots": len(self._free),
                 "max_sessions": self._slots,
                 "max_len": self._max_len,
-                "tokens_decoded": self._tokens_done}
+                "tokens_decoded": self._tokens_done,
+                "stall_count": self._stall_count,
+                "stalls": list(self._stalls)}
 
     def drain(self):
         """The batcher thread lands its own flights (the decode loop IS

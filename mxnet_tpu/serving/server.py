@@ -29,7 +29,7 @@ import time
 from ..base import MXNetError
 from ..context import current_context
 from .bucket import bucket_ladder
-from .decode import GenerateRequest, GenerativeSession
+from .decode import GenerateRequest, GenerativeSession, Pass
 from .request import Request, RequestQueue, ServerClosed
 from .session import TenantSession
 from .. import locks
@@ -67,8 +67,12 @@ class ModelServer:
 
     def __init__(self, tenants=None, max_batch=32, buckets=None,
                  timeout_ms=DEFAULT_TIMEOUT_MS, max_queue=None, wait_ms=None):
-        from .. import config
+        from .. import config, obs
 
+        # arm what the environment asks for (idempotent, never raises):
+        # the stall watchdog then finds a decode flight that stands open
+        # on a serving replica too (docs/observability.md)
+        obs.bootstrap()
         self._max_batch = int(max_batch)
         spec = buckets or ""
         if isinstance(spec, (list, tuple)):
@@ -91,6 +95,8 @@ class ModelServer:
         # a host may run several servers)
         self._dispatches = 0
         self._dispatch_errors = 0
+        # the batcher's pass: each generative session adds its legs
+        self._pass = Pass()
         for name, pred in (tenants or {}).items():
             self.add_tenant(name, pred)
         self._thread = threading.Thread(target=self._loop,
@@ -223,6 +229,7 @@ class ModelServer:
             name, model, params, ctx=ctx, max_sessions=max_sessions,
             max_len=max_len, max_decode_tokens=max_decode_tokens,
             eos_id=eos_id, seq_buckets=seq_buckets)
+        session._pass = self._pass
         with self._lock:
             if self._closed:
                 raise ServerClosed("cannot add tenant %r: server is closed"
@@ -360,7 +367,10 @@ class ModelServer:
         ``oldest_deadline_in_s`` (seconds until the most pressed queued
         request times out; None when idle — negative means requests are
         already expiring), ``dispatches`` / ``dispatch_errors`` (this
-        server's fill counts), ``tenants``, ``ladder``, and ``memory``
+        server's fill counts), ``stalls`` (legs of the batcher's pass
+        that stood still so far, over the generative tenants; their
+        records are ``stats()["generative"][tenant]["stalls"]``),
+        ``tenants``, ``ladder``, and ``memory``
         — the live-byte census / budget headroom / per-tenant KV-ring
         bytes section from :func:`mxnet_tpu.obs.memory.health_section`
         (docs/observability.md "Memory observability")."""
@@ -371,6 +381,8 @@ class ModelServer:
         # consistent view
         with self._lock:
             tenants = list(self._sessions)
+            stalls = sum(getattr(s, "_stall_count", 0)
+                         for s in self._sessions.values())
             closed = self._closed
             dispatches = self._dispatches
             errors = self._dispatch_errors
@@ -390,6 +402,7 @@ class ModelServer:
                                      else oldest - time.monotonic()),
             "dispatches": dispatches,
             "dispatch_errors": errors,
+            "stalls": stalls,
             "tenants": sorted(tenants),
             "ladder": list(self.ladder),
             "memory": _memory_section(tenants),
@@ -453,15 +466,19 @@ class ModelServer:
         contract."""
         from .. import profiler, telemetry
 
+        pas = self._pass
         while True:
             gens = self._generative()
             ticking = any(s.active() for s in gens)
             until = (time.monotonic() + self._window_s) if ticking else None
             with profiler.span("serve.wait_work", cat="serving",
-                               hist="serving.loop.wait_seconds"):
+                               hist="serving.loop.wait_seconds") as wait:
                 tenant = self._queue.next_work(self._wait_s, self._max_batch,
                                                lambda: self._stopping,
                                                until=until)
+            # one PASS ends where this wait began and the next begins
+            # with it (serving/decode.py "A pass and its legs")
+            pas.turn(wait, ticking)
             if tenant is not None:
                 session = self._sessions[tenant]
                 if getattr(session, "is_generative", False):
@@ -521,6 +538,8 @@ class ModelServer:
         reqs = self._queue.take(tenant, self._max_batch)
         if not reqs:
             return
+        # a classic fill's program is under no leg of the pass
+        self._pass.unjudged = True
         try:
             session.dispatch(reqs)
             self._dispatches += 1

@@ -275,7 +275,7 @@ def test_the_legs_of_a_decode_step_belong_to_two_steps(served):
     behind = ["decode.d2h", "decode.device_wait", "decode.emit"]
     assert legs == [ahead, sorted(ahead + behind), sorted(ahead + behind),
                     behind]
-    assert [s["args"]["n"] for s in steps] == [1, 1, 1, 0]
+    assert [s["args"]["rows"] for s in steps] == [1, 1, 1, 0]
     # the prefill: dispatched at admission, read after the first step
     # was dispatched behind it
     (sent,) = [e for e in events if e["name"] == "serve.prefill_dispatch"]
@@ -333,7 +333,8 @@ def test_a_prompt_rides_the_first_step_of_a_mixed_model(served_mixed):
     ("fit_run", "io.stage.put", "io.stage"),
     ("fit_run", "fit.dispatch", "fit.block"),
     ("fit_run", "fit.device_wait", "fit.block"),
-    ("served", "decode.pack", "serve.decode_step"),
+    ("served", "decode.pack", ("serve.decode_step",
+                               "serve.prefill_dispatch")),
     ("served", "decode.dispatch", ("serve.decode_step",
                                    "serve.prefill_dispatch")),
     ("served", "decode.device_wait", ("serve.decode_step", "serve.prefill")),
@@ -403,7 +404,7 @@ def test_span_names_are_static_and_what_varies_is_in_args(fit_run, served):
             assert "(" not in e["name"] and "%" not in e["name"]
     _, events = served
     step = [e for e in events if e["name"] == "serve.decode_step"][0]
-    assert step["args"]["n"] == 1 and step["args"]["bucket"] == 1
+    assert step["args"]["rows"] == 1 and step["args"]["bucket"] == 1
     wait = [e for e in events if e["name"] == "serve.wait_work"][0]
     assert wait["args"]["parent"] == 0
 
@@ -812,7 +813,7 @@ def test_device_interval_is_the_rule(last, sent, ready, blocked, expect):
     ("decode.dispatch", {"seq", "program"}),
     ("decode.device_wait", {"seq", "program"}),
     ("serve.prefill", {"seq", "program", "bucket"}),
-    ("serve.decode_step", {"seq", "landed", "n", "bucket"}),
+    ("serve.decode_step", {"seq", "landed", "rows", "bucket"}),
 ])
 def test_the_spans_say_which_flight_and_program(served, name, attrs):
     """(f) `seq` numbers a session's flights; `program` is the
