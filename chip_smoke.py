@@ -51,7 +51,9 @@ apart from the rest:
             delta-rule models' decode step; and the 2,048-bucket
             prefill of the fourth and the sixth: ONE kernel call a
             delta-rule layer
-            (ops/gdn_kernel.py) and no triangular solve left in it; no
+            (ops/gdn_kernel.py), no triangular solve left in it and no
+            array of the scan split into heads beside it (XLA's L2 norms,
+            the repeat to value heads, the gated norm: the kernel's); no
             prefill program of a bucket whose attention is the blockwise
             kernel (ops/sdp_kernel.py: the 2,048 buckets) may hold an
             array of bucket x bucket scores, beside the ring copies; the
@@ -587,12 +589,21 @@ def named_kernel_calls(text, name):
                for line in text.splitlines())
 
 
-def delta_rule_hlo_facts(text):
+def delta_rule_hlo_facts(text, head_shapes=()):
     """What a compiled prefill program makes of the delta rule's chunks,
     read from its optimised HLO `text`: the triangular solves XLA left in
-    it (the op, or the custom calls a TPU expands it to) and the Pallas
+    it (the op, or the custom calls a TPU expands it to), the Pallas
     kernel calls that are the chunked rule's — a mixed step (PR 46) also
-    holds its riders' step and ring kernels, which keep their names."""
+    holds its riders' step and ring kernels, which keep their names — and
+    every array under the scan's scope (``mx:gdn.scan``; the riders' step
+    has its own) that is split into heads: positions, then one of
+    `head_shapes` (``(heads, width)`` pairs: the model's key and value
+    heads) — what XLA's L2 norms reduce over, what q and k are repeated
+    to and what the gated norm reduces over, none of which a program
+    keeps whose kernel does all three (PR 51)."""
+    split = [re.compile(r"\b\w+\[(?:\d+,)+%d,%d\]" % pair)
+             for pair in sorted(set(head_shapes))]
+    lines = text.splitlines()
     return {"solves": len(re.findall(
                 r' triangular-solve\(|custom_call_target="[^"]*Triangular',
                 text)),
@@ -601,7 +612,19 @@ def delta_rule_hlo_facts(text):
                 and "gdn_state_step" not in line
                 and "kv_ring_attention" not in line
                 and "sdp_causal_attention" not in line
-                for line in text.splitlines())}
+                for line in lines),
+            "head_arrays": sorted({
+                found for line in lines if "mx:gdn.scan" in line
+                for shape in split for found in shape.findall(line)})}
+
+
+def delta_head_shapes(lm):
+    """`delta_rule_hlo_facts`' `head_shapes` of a model: its delta-rule
+    layers' key heads and value heads of a key's width, and its value
+    heads of a value's."""
+    return [(lm.linear_key_heads, lm.linear_key_dim),
+            (lm.linear_heads, lm.linear_key_dim),
+            (lm.linear_heads, lm.linear_value_dim)]
 
 
 def score_arrays(text, bucket):
@@ -790,7 +813,8 @@ def phase_kv_ring(sizes, ctx):
             if scanned:
                 _exe, pre = session._program(session._prefill_pred, 1,
                                              longest, True)
-                rule = dict(delta_rule_hlo_facts(pre.hlo_text()),
+                rule = dict(delta_rule_hlo_facts(pre.hlo_text(),
+                                                 delta_head_shapes(lm)),
                             bucket=longest, layers=scanned,
                             kernel_layers=booked["gdn.kernel_positions"]
                             // longest)
@@ -946,6 +970,9 @@ def phase_kv_ring(sizes, ctx):
                    "%(kernel_layers)d" % rule)
             _check(not rule["solves"], "the prefill program still holds "
                    "%(solves)d triangular solve(s)" % rule)
+            _check(not rule["head_arrays"], "the prefill program still "
+                   "splits the scan's arrays into heads beside the kernel: "
+                   "%(head_arrays)s" % rule)
         if scanned:
             print("[chip_smoke] kv_ring: the %(rows)d-row decode step of "
                   "%(layers)d delta-rule layer(s): %(kernel_calls)d "

@@ -1114,7 +1114,8 @@ class _GatedDeltaNet(_Recurrent):
         lm = self.lm
         tiled = _gdn.chunk_heads(
             (1, positions, lm.linear_heads, lm.linear_key_dim),
-            lm.linear_value_dim, lm.linear_chunk, platform) is not None
+            lm.linear_value_dim, lm.linear_chunk, platform,
+            lm.linear_key_heads) is not None
         stepped = _gdn.step_heads((1,) + self.state_shapes[1],
                                   lm.linear_value_dim, platform) is not None
         return {"gdn.scan_positions": positions,

@@ -36,14 +36,21 @@ what the state already answers for its key.
   positions at and beyond ``length`` get ``alpha = 1, beta = 0``, so the
   state passes through them; the conv window (the last ``K - 1`` raw
   ``[q | k | v]`` rows before ``length``, zeros before the sequence) and
-  the final state are written WHOLE at ``slot``.  On a TPU its chunked
-  rule — everything between the L2 norms and the gated norm — is ONE
-  Pallas kernel a layer (``ops/gdn_kernel.py``: a chunk's solve and
-  products stay in VMEM, the state is carried there and comes out as it
+  the final state are written WHOLE at ``slot``.  On a TPU everything
+  between the conv's SiLU and the output projection — the L2 norms of q
+  and k, the repeat to value heads, the chunked rule and the gated norm
+  — is ONE Pallas kernel a layer (``ops/gdn_kernel.py``: it turns a
+  chunk head-leading in VMEM, where both norms are a reduction along a
+  row of lanes and the repeat is an index; the chunk's solve and
+  products stay there, the state is carried there and comes out as it
   is stored) wherever ``chunk_heads`` says the kernel tiles the shape;
-  ``lax.platform_dependent`` chooses at lowering, and `_chunked` below
-  is what runs everywhere else and the kernel's oracle.  The kernel is
-  lowered once a shape for all programs and processes
+  ``lax.platform_dependent`` chooses at lowering, and `_normed_rule`
+  below (`_heads`, `_chunked`, `_gated_norm`) is what runs everywhere
+  else and the kernel's oracle: there is no shape on which the kernel
+  runs with XLA's norms around it (they cost more than the kernel's own
+  work: the reductions over a head's 96 or 192 lanes of an array laid
+  out for neither made XLA turn it over twice, PERF.md section 6, PR
+  51).  The kernel is lowered once a shape for all programs and processes
   (``ops/exported.py``: a ``jax.export`` kept beside JAX's compiled
   programs), so that a warm start neither imports Pallas nor traces the
   kernel for its first prefill; the step's kernel likewise.
@@ -62,9 +69,11 @@ what the state already answers for its key.
   gathered, advanced in one expression and scattered back, nothing
   spread out to ``rows x page`` (XLA made that of the keys and queries,
   33.5 MB an operand a layer at 16 rows of 128 x 4,096, and read every
-  page three times: PERF.md section 6, PR 41).  The conv window, the
-  conv, the L2 norms, the gates and the gated norm are XLA's on every
-  platform.
+  page three times: PERF.md section 6, PR 41).  The step's L2 norms and
+  gated norm are XLA's (`_heads`, `_gated_norm`: B rows of them).
+
+The conv window, the conv and the gates are XLA's on every platform and
+in all three forms.
 
 Stored shapes belong to the model (``TransformerLM.cache_spec``): a conv
 window ``(slots, K - 1, 2 H_k d_k + H d_v)`` and a state ``(slots, d_k, H *
@@ -245,34 +254,56 @@ def _stored_chunked(q, k, v, beta, g, chunk):
     return o, _stored(final)
 
 
+def _normed_rule(qkv, z, beta, g, gamma, *, key_heads, eps, chunk):
+    """What lies between the conv's SiLU and the output projection, for
+    whole sequences from an empty state: ``qkv (N, T, 2 H_k d_k + H d_v)``
+    the conv's output, ``z (N, T, H d_v)`` the gate, ``beta`` / ``g (N, T,
+    H)``, ``gamma (d_v,)``.  q and k normed and repeated to the value
+    heads (`_heads`), the rule in chunks (`_stored_chunked`), the gated
+    norm (`_gated_norm`).  Returns ``(y (N, T, H d_v), final state (N, d_k,
+    H d_v))``.  The differentiable body of `_gdn_scan`, what `_gdn_prefill`
+    runs off the TPU, and the oracle of the TPU's kernel, which takes the
+    same operands (``ops/gdn_kernel.py``)."""
+    h, dv = beta.shape[-1], gamma.shape[0]
+    dk = (qkv.shape[-1] - h * dv) // (2 * key_heads)
+    q, k, v = _heads(qkv, key_heads, h, dk, dv)
+    o, final = _stored_chunked(q, k, v, beta, g, chunk)
+    y = _gated_norm(o, z.reshape(o.shape), gamma, eps)
+    return y.reshape(z.shape), final
+
+
 _SUBLANES = 8
 _WALK_BYTES = 4 << 20
 _VMEM_BYTES = 24 << 20
 
 
-def chunk_heads(shape, value_dim, chunk, platform):
-    """Heads that one step of the TPU kernel's walk over a chunk takes
-    (``ops/gdn_kernel.py``), for ``q`` / ``k`` of `shape` ``(N, T, H,
-    d_k)``, values of `value_dim` and chunks of `chunk` positions: the
-    most heads that divide ``H`` and whose operands and products of one
-    chunk are within 4 MiB — an even number where one fits (the kernel
-    solves two heads' systems side by side).  None where `_gdn_prefill`
-    runs its ``jax.numpy`` body: off the TPU, or for a shape the kernel's
-    tiling does not divide — ``T`` no whole number of chunks, a chunk that
-    is no whole number of 8-row tiles, or a chunk of all heads (the
-    pipeline's two buffers an operand, the head-leading copies and the
-    state) beyond 24 MiB of the 32 MiB of VMEM the kernel asks for (it asks
-    for no more: what a kernel may use, XLA may not keep activations in
-    across it).  Whoever counts what a prefill runs
-    (``TransformerLM.call_counters``) asks here."""
+def chunk_heads(shape, value_dim, chunk, platform, key_heads=None):
+    """Value heads that one step of the TPU kernel's walk over a chunk
+    takes (``ops/gdn_kernel.py``), for ``q`` / ``k`` of `shape` ``(N, T, H,
+    d_k)`` once repeated to the ``H`` value heads (they come `key_heads`
+    wide, ``H`` where not given), values of `value_dim` and chunks of
+    `chunk` positions: the most heads that divide ``H`` and whose operands
+    and products of one chunk are within 4 MiB — an even number where one
+    fits (the kernel solves two heads' systems side by side).  None where
+    `_gdn_prefill` runs its ``jax.numpy`` body: off the TPU, or for a
+    shape the kernel's tiling does not divide — ``T`` no whole number of
+    chunks, a chunk that is no whole number of 8-row tiles, or a chunk of
+    all heads (the pipeline's two buffers of ``[q | k]``, ``v``, the gate
+    ``z``, ``y``, the gates and the state; the head-leading copies of q,
+    k and v — ``o`` takes v's place — and the state) beyond 24 MiB of the
+    32 MiB of VMEM the kernel asks for (it asks for no more: what a kernel
+    may use, XLA may not keep activations in across it).  Whoever counts
+    what a prefill runs (``TransformerLM.call_counters``) asks here."""
     _, t, h, dk = shape
+    hk = h if key_heads is None else int(key_heads)
     size = min(int(chunk), t)
     if platform != "tpu" or not size or t % size or size % _SUBLANES:
         return None
     pad = lambda width: -(-width // _LANES) * _LANES
     dv = int(value_dim)
-    piped = 2 * size * (2 * h * dk + 2 * h * dv + 2 * pad(h)) + 2 * dk * h * dv
-    turned = (h * (2 * size * (pad(dk) + pad(dv)) + dk * pad(dv))
+    piped = (2 * size * (2 * hk * dk + 3 * h * dv + 2 * pad(h))
+             + 2 * dk * h * dv)
+    turned = (size * (2 * hk * pad(dk) + h * pad(dv)) + h * dk * pad(dv)
               + 2 * h * pad(size))
     if 4 * (piped + turned) > _VMEM_BYTES:
         return None
@@ -288,16 +319,18 @@ def chunk_heads(shape, value_dim, chunk, platform):
 _INTERPRET = False
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "heads", "interpret"))
-def _delta_rule(q, k, v, beta, g, *, chunk, heads, interpret):
-    """`_chunked` on whatever platform the program is lowered for — the
-    TPU's kernel (walking `heads` heads at a time; `interpret` runs it in
-    Pallas's interpreter, for tests) or the ``jax.numpy`` body — with the
-    final state as the session stores it.  Jitted, so that the delta-rule
-    layers of a prefill program, whose rule is one and the same, trace
-    and lower both once."""
-    operands = (q, k, v, beta, g)
-    body = functools.partial(_stored_chunked, chunk=chunk)
+@functools.partial(jax.jit, static_argnames=("key_heads", "eps", "chunk",
+                                             "heads", "interpret"))
+def _delta_rule(qkv, z, beta, g, gamma, *, key_heads, eps, chunk, heads,
+                interpret):
+    """`_normed_rule` on whatever platform the program is lowered for —
+    the TPU's kernel (walking `heads` heads at a time; `interpret` runs it
+    in Pallas's interpreter, for tests) or the ``jax.numpy`` composition.
+    Jitted, so that the delta-rule layers of a prefill program, whose rule
+    is one and the same, trace and lower both once."""
+    operands = (qkv, z, beta, g, gamma)
+    body = functools.partial(_normed_rule, key_heads=key_heads, eps=eps,
+                             chunk=chunk)
     if heads is None:
         return body(*operands)
 
@@ -306,37 +339,44 @@ def _delta_rule(q, k, v, beta, g, *, chunk, heads, interpret):
         # process traces a prefill first, and the exported kernel needs
         # neither Pallas (1.3 s of import that nothing hides: PERF.md
         # section 6, PR 34) nor a lowering a bucket (ops/exported.py)
-        return exported.call("gdn_kernel", "chunked_delta_rule", operands,
-                             interpret=interpret, chunk=chunk, heads=heads)
+        y, final = exported.call(
+            "gdn_kernel", "chunked_delta_rule", operands, interpret=interpret,
+            key_heads=key_heads, eps=eps, chunk=chunk, heads=heads)
+        # the state's write at `slot` stays an op of its own: fused into
+        # the kernel's call — which XLA does where it reckons the call
+        # within the default 16 MiB of scoped VMEM — the call is held to
+        # that default and not to what the kernel asks for, and a bucket
+        # of 768 fails to compile by 0.6 MiB
+        return y, lax.optimization_barrier(final)
     return lax.platform_dependent(*operands, tpu=kernel, default=body)
 
 
 def _mix(data, conv_weight, dt_bias, a_log, norm_gamma, attrs, length=None):
     """Conv, chunked rule and gated norm of whole sequences; positions at
     and beyond ``length (N,)`` leave the state untouched (the serving
-    prefill: its rule may run as the TPU's kernel, `_delta_rule`; without
-    `length`, training and scoring, it is the differentiable body).
-    Returns ``(y, raw [q | k | v], final state (N, d_k, H * d_v) as a
-    session stores it)``."""
+    prefill: everything after the conv may run as the TPU's kernel,
+    `_delta_rule`; without `length`, training and scoring, it is the
+    differentiable body).  Returns ``(y, raw [q | k | v], final state (N,
+    d_k, H * d_v) as a session stores it)``."""
     hk, h, dk, dv, _ = _sizes(attrs)
     raw, z, b, a = _split(data, hk, h, dk, dv)
-    q, k, v = _heads(_conv_full(raw, conv_weight, 0.0), hk, h, dk, dv)
+    qkv = _conv_full(raw, conv_weight, 0.0)
     beta, g = _gates(b, a, dt_bias, a_log, attrs)
-    chunk = int(_lit(attrs["chunk_size"]))
+    rule = dict(key_heads=hk, eps=float(_lit(attrs["eps"])),
+                chunk=int(_lit(attrs["chunk_size"])))
     if length is None:
-        o, final = _stored_chunked(q, k, v, beta, g, chunk)
+        y, final = _normed_rule(qkv, z, beta, g, norm_gamma, **rule)
     else:
         live = (jnp.arange(data.shape[1])[None, :] < length[:, None])[..., None]
         beta, g = jnp.where(live, beta, 0.0), jnp.where(live, g, 0.0)
         # the heads a lowering for the TPU would walk at a time; which
         # platform the program is lowered for is not known here
-        o, final = _delta_rule(
-            q, k, v, beta, g, chunk=chunk, interpret=_INTERPRET,
-            heads=chunk_heads(q.shape, dv, chunk, "tpu"))
-    y = _gated_norm(o, z.reshape(o.shape), norm_gamma,
-                    float(_lit(attrs["eps"])))
-    return (y.reshape(data.shape[:2] + (h * dv,)).astype(data.dtype), raw,
-            final)
+        y, final = _delta_rule(
+            qkv, z, beta, g, norm_gamma.astype(jnp.float32),
+            interpret=_INTERPRET, heads=chunk_heads(
+                data.shape[:2] + (h, dk), dv, rule["chunk"], "tpu", hk),
+            **rule)
+    return y.astype(data.dtype), raw, final
 
 
 @register("_gdn_scan", inputs=("data",) + PARAMS, infer_shape=_infer)

@@ -143,9 +143,14 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert report["kv_ring"]["masked_buckets"] == 0
     # the third shape's longest prefill bucket, read for the delta rule:
     # one such layer, no kernel in a program lowered for the CPU
-    assert report["kv_ring"]["delta_rule"] == 2 * [
+    # (whose ``jax.numpy`` composition splits the scan's arrays into heads
+    # for its norms: what a program lowered for the TPU may not)
+    rules = report["kv_ring"]["delta_rule"]
+    assert [{k: v for k, v in rule.items() if k != "head_arrays"}
+            for rule in rules] == 2 * [
         {"solves": 0, "kernel_calls": 0, "bucket": 8, "layers": 1,
          "kernel_layers": 0}]
+    assert all(rule["head_arrays"] for rule in rules)
     # and their decode programs, read for the step: one such layer, no
     # kernel in a program lowered for the CPU, whose body gathers the two
     # rows' pages (two heads of 8 x 16; four of 8 x 8), the step timed warm
@@ -193,12 +198,46 @@ def test_the_delta_rule_facts_count_solves_and_kernel_calls():
     kernel = ('%x = (f32[1,64,384]{2,1,0}, f32[1,16,384]{2,1,0}) custom-call('
               '%q), custom_call_target="tpu_custom_call"\n')
     assert chip_smoke.delta_rule_hlo_facts(3 * kernel) == {
-        "solves": 0, "kernel_calls": 3}
+        "solves": 0, "kernel_calls": 3, "head_arrays": []}
     old = ('%t = f32[8,8]{1,0} triangular-solve(%a, %b), left_side=true\n'
            '%i = f32[1,32,30,1,64,64]{5,4,3,2,1,0} custom-call(%a), '
            'custom_call_target="InvertDiagBlocksLowerTriangular"\n')
     assert chip_smoke.delta_rule_hlo_facts(old + kernel) == {
-        "solves": 2, "kernel_calls": 1}
+        "solves": 2, "kernel_calls": 1, "head_arrays": []}
+
+
+@pytest.mark.parametrize("why,line,found", [
+    ("XLA's L2 norm reduces over a head's keys",
+     '%r = f32[2048,30]{0,1} reduce(f32[2048,30,96]{0,2,1} %q, f32[] %c), '
+     'metadata={op_name="jit(f)/l0_gdn/mx:gdn.scan/reduce_sum"}',
+     ["f32[2048,30,96]"]),
+    ("q repeated to the value heads",
+     '%b = f32[1,2048,32,128]{3,2,1,0} broadcast(f32[1,2048,16,128]{3,2,1,0} '
+     '%q), metadata={op_name="jit(f)/mx:gdn.scan/broadcast_in_dim"}',
+     ["f32[1,2048,16,128]", "f32[1,2048,32,128]"]),
+    ("the gated norm's over a head's values",
+     '%m = f32[2048,30,192]{0,2,1} multiply(%o, %o), '
+     'metadata={op_name="jit(f)/mx:gdn.scan/mul"}', ["f32[2048,30,192]"]),
+    ("the riders' step norms its rows under its own scope",
+     '%r = f32[8,30]{1,0} reduce(f32[8,30,96]{2,1,0} %q, f32[] %c), '
+     'metadata={op_name="jit(f)/l0_gdn/mx:gdn.step/reduce_sum"}', []),
+    ("heads side by side on the lanes are what the kernel reads",
+     '%c = f32[1,2048,11520]{2,1,0} fusion(%raw), '
+     'metadata={op_name="jit(f)/mx:gdn.scan/mul"}', []),
+    ("the gates a head a position are no head's width",
+     '%g = f32[1,2048,30]{2,1,0} fusion(%a), '
+     'metadata={op_name="jit(f)/mx:gdn.scan/mul"}', []),
+    ("an attention layer's heads are another scope's",
+     '%k = f32[1,2048,30,96]{3,2,1,0} fusion(%x), '
+     'metadata={op_name="jit(f)/mx:attn.prefill/mul"}', [])])
+def test_the_delta_rule_facts_name_what_is_split_into_heads(why, line, found):
+    """`delta_rule_hlo_facts`' third fact: arrays of positions x heads x
+    a head's width under the scan's scope — XLA's norms and repeat, which
+    a program whose kernel does them (PR 51) may not hold."""
+    pairs = [(16, 128), (32, 128)] if "16,128" in line else [(30, 96),
+                                                             (30, 192)]
+    assert chip_smoke.delta_rule_hlo_facts(line, pairs)["head_arrays"] \
+        == found, why
 
 
 @pytest.mark.parametrize("why,line,found", [

@@ -166,11 +166,14 @@ GDN_WIDTHS = {"olmo_hybrid": (30, 30, 96, 192, 6),
 def test_the_delta_rule_prefill_compiles_for_a_v5e(widths, bucket, one_chip):
     """`_gdn_prefill` at Olmo-Hybrid's widths (30 heads of 96 x 192, chunks
     of 64, the cell's shortest and longest bucket) and at Qwen3-Next's (16
-    q/k heads under 32 value heads of 128 x 128: the kernel sees 32 heads)
+    q/k heads under 32 value heads of 128 x 128, q and k at their 16 heads)
     lowered for the TPU: ONE `tpu_custom_call` — the kernel, walking six
-    or eight heads at a time — no triangular solve, the window and the
-    state aliased to their outputs, and nothing the size of the operands
-    kept beside them."""
+    or eight heads at a time — no triangular solve, no array split into
+    heads between the conv and the kernel's ``y`` (XLA's L2 norms over
+    ``(…, H, 96)``, the repeat of q and k to ``(N, T, H, d_k)``, the gated
+    norm over ``(…, H, 192)``: the kernel's since PR 51), the window and
+    the state aliased to their outputs, and nothing the size of the
+    operands kept beside them."""
     import jax
     import jax.numpy as jnp
 
@@ -181,7 +184,7 @@ def test_the_delta_rule_prefill_compiles_for_a_v5e(widths, bucket, one_chip):
                  chunk_size=64, neg_eigval=hk == h, eps=1e-6)
     if hk != h:
         attrs["num_key_heads"] = hk
-    assert gdn.chunk_heads((1, bucket, h, dk), dv, 64, "tpu") == walk
+    assert gdn.chunk_heads((1, bucket, h, dk), dv, 64, "tpu", hk) == walk
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
@@ -195,8 +198,9 @@ def test_the_delta_rule_prefill_compiles_for_a_v5e(widths, bucket, one_chip):
         arg(h), arg(h), arg(dv), arg(slots, taps - 1, conv_dim),
         arg(slots, dk, h * dv), arg(1), arg(1)).compile()
     text = compiled.as_text()
-    assert chip_smoke.delta_rule_hlo_facts(text) == {"solves": 0,
-                                                     "kernel_calls": 1}
+    assert chip_smoke.delta_rule_hlo_facts(
+        text, [(hk, dk), (h, dk), (h, dv)]) == {
+            "solves": 0, "kernel_calls": 1, "head_arrays": []}
     state = chip_smoke.ring_hlo_facts(text, (slots, dk, h * dv))
     assert state["ring_params"] == state["aliased"] == 1
     assert state["copies"] == []
@@ -358,6 +362,11 @@ def test_a_mixed_step_compiles_for_a_v5e(name, one_chip):
                                 _wire(spec, rows, prompt=bucket),
                                 one_chip).as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == kernels
+    if "linear_attention" in lm.layer_types:
+        # nor anything of the prompt's scan split into heads: the norms
+        # and the repeat are the chunked kernel's (PR 51)
+        assert chip_smoke.delta_rule_hlo_facts(
+            text, chip_smoke.delta_head_shapes(lm))["head_arrays"] == []
     floor = sum(e.nbytes for e in spec.values()) // 100
     entry = text[text.index("ENTRY"):]
     for shape in sorted({e.shape for e in spec.values()
