@@ -115,8 +115,16 @@ def _run_graph(entries, order, arg_names, aux_names, arg_vals, aux_vals, is_trai
         # the whole step is one fused executable — the analog of the
         # reference profiler's per-op SetOprStart/End rows
         # (src/engine/profiler.cc:134-190).  Trace-time only; free at run.
+        # a node made under ``AttrScope(__scope__="mx:...")`` runs under
+        # that scope too: a stretch of plain nodes that is ONE thing to a
+        # reader of the trace (the draft module's block)
+        scope = node.attrs.get("__scope__")
         with jax.named_scope(node.name):
-            res = op.fn(*ins, **kwargs)
+            if scope is None:
+                res = op.fn(*ins, **kwargs)
+            else:
+                with jax.named_scope(scope):
+                    res = op.fn(*ins, **kwargs)
         if not isinstance(res, tuple):
             res = (res,)
         if op.num_aux_out:

@@ -123,6 +123,26 @@ kind its `window` —, ``ffn_types`` of a leading ``"dense"`` layer before
 ``"routed"`` ones, sigmoid scores with ``router_bias`` and ``held_experts``
 are dots3-note-prev's (`dots3`).
 
+**A draft module.**  `nextn` = 1 adds DeepSeek-V3's multi-token-prediction
+module behind the trunk (GLM-5's, `glm5`): two norms (``mtp_enorm``,
+``mtp_hnorm``), ``mtp_eh_weight (d_model, 2 d_model)``, ONE more block of
+the last layer's own kinds as layer ``num_layers`` (parameters
+``l<num_layers>_*``, cache entries of its own in :meth:`cache_spec`), a head
+norm ``mtp_ln_f``; the embedding and the head are the trunk's.  At
+position p it takes the trunk's last stream BEFORE ``ln_f`` and the token
+at p + 1 and predicts the token at p + 2.  The two serving graphs use it
+as the model's own DRAFT: a prefill runs it over the prompt (tokens
+shifted by one, the first sampled token at the tail) and leaves a draft
+beside the first token; a decode step runs TWO rows a session — the last
+verified token at position n and the draft at n + 1 —, keeps the draft
+where it is the trunk's own argmax, emits one or two tokens and drafts
+again (ops/attention.py ``_draft_*``).  ``last_token`` is then ``(3,
+slots + 1)`` — token, draft, position: :meth:`token_state` — the
+``token`` output ``(B, 3)`` = ``[count, first, second]``, and the
+``logits`` output stacks the trunk's rows over the module's
+(:meth:`decode_symbol`).  No mixed step; the training graph leaves the
+module out (there it is one more loss term).
+
 **Per-kind sizes.**  The flat arguments above give a model ONE geometry a
 mechanism.  `kind_specs` ``{kind: {size: value}}`` is the seam for a model
 whose layer kinds differ in them (ROADMAP D13, begun with the two kinds
@@ -152,10 +172,12 @@ charges or counts session state asks here.
 """
 from __future__ import annotations
 
+import ast
 import math
 from typing import NamedTuple
 
 from .. import symbol as sym
+from ..attribute import AttrScope
 from ..ops import (attention as _attention, gdn as _gdn,
                    sparse_latent as _sparse_latent, ssm as _ssm)
 
@@ -1248,6 +1270,8 @@ class _RoutedFFN:
 
 
 _FFNS = {"dense": _DenseFFN, "routed": _RoutedFFN}
+# a routed FFN's parameters that are stacked an expert
+_EXPERT_KEYS = ("gate_weight", "down_weight", "up_weight")
 
 
 class TransformerLM:
@@ -1325,7 +1349,9 @@ class TransformerLM:
     (:class:`_KindLatent`: `num_heads`, `q_rank`, `kv_rank`, `nope_dim`,
     `rope_dim`, `value_dim`, and `rope_theta`, `lora_rescale`, `head_gate`;
     the sparse kind's `index_heads`, `index_dim`, `index_topk`; the window
-    kind's `window`), whatever the flat arguments say."""
+    kind's `window`), whatever the flat arguments say; `nextn` 1 — a
+    multi-token-prediction module behind the trunk, the serving graphs'
+    draft (module docstring)."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
                  d_ff=None, max_len=64, dropout=0.0, norm="layer",
@@ -1345,7 +1371,11 @@ class TransformerLM:
                  held_experts=None, linear_key_heads=None, rotary_dim=None,
                  shared_gate=False, latent_q_rank=0, latent_kv_rank=0,
                  latent_nope_dim=0, latent_rope_dim=0, latent_value_dim=0,
-                 rope_scaling=None, query_scale=None, kind_specs=None):
+                 rope_scaling=None, query_scale=None, kind_specs=None,
+                 nextn=0):
+        if int(nextn) not in (0, 1):
+            raise ValueError("nextn must be 0 or 1 (ONE draft a step), got %r"
+                             % (nextn,))
         if head_dim is None and d_model % num_heads:
             raise ValueError("d_model=%d not divisible by num_heads=%d"
                              % (d_model, num_heads))
@@ -1523,10 +1553,17 @@ class TransformerLM:
             raise ValueError("rotary_dim=%d must be even and within the "
                              "head's %d channels"
                              % (self.rotary_dim, self.d_head))
+        self.nextn = int(nextn)
         kinds = {k: _KINDS[k](self) for k in set(layer_types)}
         self._mixers = [kinds[k] for k in layer_types]
         kinds = {k: _FFNS[k](self) for k in set(ffn_types)}
         self._ffns = [kinds[k] for k in ffn_types]
+        # the draft module's block, layer `num_layers`: the last layer's
+        # kinds (it is not among `_mixers` / `_ffns`: the trunk's loops
+        # pass it by)
+        self._draft = ([(self.num_layers, self._mixers[-1], self._ffns[-1])]
+                       if self.nextn else [])
+        self._weights = None   # `step_weight_bytes`' constants
 
     # ------------------------------------------------------------------
     # shared pieces
@@ -1558,10 +1595,14 @@ class TransformerLM:
                                   num_hidden=num_hidden, no_bias=True,
                                   flatten=False, name=name)
 
-    def _block_params(self, i):
+    def _layers(self):
+        """``(i, mixer, ffn)`` of the trunk's layers."""
+        return list(zip(range(self.num_layers), self._mixers, self._ffns))
+
+    def _block_params(self, i, mixer=None, ffn=None):
         """Layer i's parameter variables: its mixer's, then its FFN's."""
-        p = self._mixers[i].params(i)
-        p.update(self._ffns[i].params(i))
+        p = (mixer or self._mixers[i]).params(i)
+        p.update((ffn or self._ffns[i]).params(i))
         return p
 
     def _branch_in(self, h, name):
@@ -1583,13 +1624,13 @@ class TransformerLM:
             branch = branch * self.residual_multiplier
         return h + branch
 
-    def _ffn(self, h, p, i, train, loads=None):
+    def _ffn(self, h, p, i, train, loads=None, ffn=None):
         """The block's second half on the residual stream `h`: layer i's
         FFN kind between the block's norms.  A routed model's serving
         graphs pass `loads`, which collects each routed layer's
         tokens-per-expert output."""
         x = self._branch_in(h, "l%d_ln2" % i)
-        f = self._ffns[i].apply(x, p, i, loads)
+        f = (ffn or self._ffns[i]).apply(x, p, i, loads)
         f = self._branch_out(f, "l%d_ln2" % i)
         if train and self.dropout > 0:
             f = sym.Dropout(f, p=self.dropout, name="l%d_drop" % i)
@@ -1631,24 +1672,34 @@ class TransformerLM:
             h = self._block_train(h, i, train)
         return self._norm(h, "ln_f"), embed_w
 
-    def _head(self, h2d, embed_w, name):
+    def _head_weight(self):
+        """An untied head's own matrix (None for a tied one)."""
+        return None if self.tied_head else sym.Variable(
+            "head_weight", shape=(self.vocab, self.d_model))
+
+    def _head(self, h2d, embed_w, name, weight=None):
         """LM head over flattened positions: ``h @ W^T`` with W the
         embedding table (the tie halves head params and is the reference
-        transformer-LM convention) or the head's own matrix."""
-        w = embed_w if self.tied_head else sym.Variable(
-            "head_weight", shape=(self.vocab, self.d_model))
+        transformer-LM convention) or the head's own matrix (`weight`:
+        the variable a graph's first head made, for its second)."""
+        w = weight if weight is not None else (
+            embed_w if self.tied_head else self._head_weight())
         if self.logits_scaling == 1.0:
             return sym.dot(h2d, w, transpose_b=True, name=name)
         raw = sym.dot(h2d, w, transpose_b=True, name=name + "_unscaled")
         return sym._div_scalar(raw, scalar=self.logits_scaling, name=name)
 
-    def _serving_outputs(self, logits, rings, loads, last_token, slot):
+    def _serving_outputs(self, logits, rings, loads, last_token, slot,
+                         sampled=None):
         """``[logits, rings..., last_token, token, moe_load]``: what is
         threaded from call to call (the rings, then the last sampled
         token of every slot), then what the batcher reads — the greedy
-        token of each row and, for a routed model, tokens per (layer,
+        token of each row (`sampled`: a drafting graph's own ``(token,
+        last_token')``) and, for a routed model, tokens per (layer,
         expert) of this call."""
-        sampled = sym._greedy_token(logits, last_token, slot, name="token")
+        if sampled is None:
+            sampled = sym._greedy_token(logits, last_token, slot,
+                                        name="token")
         extra = []
         if loads:
             held = (self.held_experts or (0, self.num_experts))[1]
@@ -1715,9 +1766,14 @@ class TransformerLM:
         ask here, and the ops read and write exactly these shapes."""
         max_len = self.max_len if max_len is None else int(max_len)
         spec = {}
-        for i, mixer in enumerate(self._mixers):
+        for i, mixer, _ in self._layers() + self._draft:
             spec.update(mixer.cache_spec(i, slots, max_len))
         return spec
+
+    def token_state(self, slots):
+        """The shape of ``last_token`` for `slots` pages: one token a slot
+        or, for a model that drafts, token, draft and position."""
+        return (3, int(slots)) if self.nextn else (int(slots),)
 
     def call_counters(self, **call):
         """The telemetry counters that ONE serving program call adds to
@@ -1731,12 +1787,43 @@ class TransformerLM:
         `max_len`; either of a program lowered for `platform` (the
         session's device's).  The session books them at dispatch."""
         total = {}
-        for i, kinds in enumerate(zip(self._mixers, self._ffns)):
+        for i, *kinds in self._layers() + self._draft:
             for kind in kinds:
                 if hasattr(kind, "counters"):
                     for name, n in kind.counters(i, **call).items():
                         total[name] = total.get(name, 0) + n
         return total
+
+    def step_weight_bytes(self, load=None):
+        """``{"mtp.bytes", "mtp.step_bytes"}``: the float32 bytes of the
+        WEIGHTS one decode step of a drafting model reads for its draft
+        module (``mtp_eh_weight``, its block, the head a second time) and
+        for the whole step (every layer's matrices, the head twice; an
+        embedding's rows and the norms' gains are left out).  A routed
+        layer's experts count where they got a row: `load` ``(routed
+        layers, held)`` is the call's ``moe_load``, the module's layer
+        last; without it every held expert counts."""
+        if self._weights is None:
+            fixed, expert = [], []
+            for i, mixer, ffn in self._layers() + self._draft:
+                sizes = {k: math.prod(ast.literal_eval(v.attr("__shape__")))
+                         for k, v in self._block_params(i, mixer,
+                                                        ffn).items()}
+                routed = isinstance(ffn, _RoutedFFN)
+                mine = sum(sizes[k] for k in _EXPERT_KEYS) if routed else 0
+                fixed.append(4 * (sum(sizes.values()) - mine))
+                expert.append(4 * mine // ffn.held if routed else None)
+            self._weights = (fixed, expert,
+                             4 * self.vocab * self.d_model,
+                             4 * 2 * self.d_model * self.d_model)
+        fixed, expert, head, join = self._weights
+        hit = iter([] if load is None else (load > 0).sum(axis=-1))
+        layers = [f + (0 if e is None else
+                       e * int(next(hit, self._ffns[-1].held)))
+                  for f, e in zip(fixed, expert)]
+        module = layers[-1] + join + head
+        return {"mtp.bytes": module,
+                "mtp.step_bytes": sum(layers[:-1]) + head + module}
 
     def _cache_vars(self):
         return {n: sym.Variable(n) for n in self.cache_spec(1)}
@@ -1747,14 +1834,43 @@ class TransformerLM:
         its cache entries).  Returns (the stream, every layer's entries
         in `cache_spec`'s order, the routed layers' loads or None)."""
         outs, loads = [], [] if self._routed() else None
-        for i, mixer in enumerate(self._mixers):
-            p = self._block_params(i)
-            x = self._branch_in(h, "l%d_ln1" % i)
-            y, state = mix(mixer, x, p, i)
-            outs += state
-            h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
-            h = self._ffn(h, p, i, train=False, loads=loads)
+        for layer in self._layers():
+            h = self._block(h, layer, mix, outs, loads)
         return h, outs, loads
+
+    def _block(self, h, layer, mix, outs, loads):
+        """One block of a serving graph: `layer` ``(i, mixer, ffn)``; its
+        cache entries go to `outs`, a routed FFN's load to `loads`."""
+        i, mixer, ffn = layer
+        p = self._block_params(i, mixer, ffn)
+        x = self._branch_in(h, "l%d_ln1" % i)
+        y, state = mix(mixer, x, p, i)
+        outs += state
+        h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
+        return self._ffn(h, p, i, train=False, loads=loads, ffn=ffn)
+
+    def _drafted(self, stream, tokens, embed_w, mix, outs, loads):
+        """The draft module on the trunk's last `stream` (before ``ln_f``)
+        and the `tokens` that FOLLOW its positions: ``[RMS_e(Emb(token)) ;
+        RMS_h(stream)] W_eh`` through the module's block — `mix` as
+        `_blocks` takes it, its cache entries after the trunk's in `outs`
+        — and the head norm: the stream its logits are made of."""
+        with AttrScope(__scope__="mx:mtp.embed_join"):
+            e = sym.Embedding(tokens, weight=embed_w, input_dim=self.vocab,
+                              output_dim=self.d_model, name="mtp_embed")
+            if self.embedding_multiplier != 1.0:
+                e = e * self.embedding_multiplier
+            u = sym.FullyConnected(
+                sym.Concat(self._norm(e, "mtp_enorm"),
+                           self._norm(stream, "mtp_hnorm"), dim=2,
+                           name="mtp_joined"),
+                weight=sym.Variable("mtp_eh_weight", shape=(
+                    self.d_model, 2 * self.d_model)),
+                num_hidden=self.d_model, no_bias=True, flatten=False,
+                name="mtp_eh")
+        with AttrScope(__scope__="mx:mtp.block"):
+            z = self._block(u, self._draft[0], mix, outs, loads)
+            return self._norm(z, "mtp_ln_f")
 
     def prefill_symbol(self):
         """Prefill one prompt (batch 1, padded to a sequence bucket):
@@ -1771,11 +1887,79 @@ class TransformerLM:
         h, outs, loads = self._blocks(
             h, lambda mixer, x, p, i: mixer.prefill(x, p, i, caches, slot,
                                                     length))
+        if self.nextn:
+            return self._prefill_drafting(data, slot, length, last_token,
+                                          caches, h, embed_w, outs, loads)
         h = self._norm(h, "ln_f")
         # logits at the prompt's true tail, not the pad
         last = sym._take_step(h, length - 1, name="last_h")
         logits = self._head(last, embed_w, "next_logits")
         return self._serving_outputs(logits, outs, loads, last_token, slot)
+
+    def _prefill_drafting(self, data, slot, length, last_token, caches,
+                          stream, embed_w, outs, loads):
+        """The prefill's end for a model that drafts: the first token from
+        the prompt's tail, then the module over the whole prompt — its
+        tokens shifted by one, the first token at the tail —, whose own
+        cache entries the prompt fills, and the first draft from ITS tail.
+        ``logits (2, vocab)``: the trunk's, then the module's."""
+        head = self._head_weight()
+        last = sym._take_step(self._norm(stream, "ln_f"), length - 1,
+                              name="last_h")
+        logits = self._head(last, embed_w, "next_logits", head)
+        follows = sym._draft_shift(data, logits, length, name="mtp_tokens")
+        z = self._drafted(
+            stream, follows, embed_w,
+            lambda mixer, x, p, i: mixer.prefill(x, p, i, caches, slot,
+                                                 length), outs, loads)
+        draft_logits = self._head(
+            sym._take_step(z, length - 1, name="mtp_last_h"), embed_w,
+            "draft_logits", head)
+        sampled = sym._draft_start(logits, draft_logits, length, last_token,
+                                   slot, name="token")
+        return self._serving_outputs(
+            sym.Concat(logits, draft_logits, dim=0, name="all_logits"), outs,
+            loads, last_token, slot, sampled=sampled)
+
+    def _decode_drafting(self):
+        """`decode_symbol` of a model that drafts (module docstring):
+        ``data (B, 1)``, ``slot (B,)``, ``length (B,)`` as the plain
+        step's; ``last_token (3, slots + 1)``.  The trunk runs the 2B rows
+        ``_draft_feed`` makes (the B verified tokens at their positions,
+        then the B drafts one position on: a session's second row attends
+        the row its first has just written, every ring's rows being
+        written before any is read); ``_draft_verify`` compares; the
+        module runs the same 2B rows on the trunk's streams and the
+        tokens sampled after them; the next draft is the argmax at each
+        session's last valid row.  Outputs ``[logits (3B, vocab): the
+        trunk's first rows, its second rows, the module's chosen rows;
+        <cache_spec entries>'..., last_token', token (B, 3) = [count,
+        first, second]]``."""
+        slot = sym.Variable("slot")
+        last_token = sym.Variable("last_token")
+        caches = self._cache_vars()
+        fed = sym._draft_feed(sym.Variable("data"), sym.Variable("length"),
+                              last_token, slot, name="token_feed")
+        tokens, slots, lengths = fed[0], fed[1], fed[2]
+        h, (embed_w, _) = self._embed(tokens, index=lengths)
+
+        def mix(mixer, x, p, i):
+            return mixer.decode(x, p, i, caches, slots, lengths)
+
+        h, outs, loads = self._blocks(h, mix)
+        head = self._head_weight()
+        flat = sym.Reshape(self._norm(h, "ln_f"), shape=(-1, self.d_model),
+                           name="flat")
+        logits = self._head(flat, embed_w, "next_logits", head)
+        verdict = sym._draft_verify(logits, tokens, name="mtp_verify")
+        z = self._drafted(h, verdict[0], embed_w, mix, outs, loads)
+        chosen = sym._draft_select(z, verdict[1], name="mtp_last_h")
+        draft_logits = self._head(chosen, embed_w, "draft_logits", head)
+        sampled = sym._draft_commit(verdict[0], verdict[1], draft_logits,
+                                    lengths, last_token, slot, name="token")
+        return self._serving_outputs(
+            sym.Concat(logits, draft_logits, dim=0, name="all_logits"), outs,
+            loads, last_token, slot, sampled=sampled)
 
     def decode_symbol(self):
         """One decode step for a packed session batch: inputs ``data
@@ -1784,6 +1968,8 @@ class TransformerLM:
         (B,)`` (tokens already cached), plus the cache entries and
         ``last_token (slots + 1,)``; outputs ``[logits (B, vocab),
         <cache_spec entries>'..., last_token', token (B,)]``."""
+        if self.nextn:
+            return self._decode_drafting()
         data = sym.Variable("data")
         slot = sym.Variable("slot")
         length = sym.Variable("length")
@@ -1815,8 +2001,9 @@ class TransformerLM:
         first: ``[logits (1 + rows, vocab), <cache_spec entries>'...,
         last_token', token (1 + rows,)]``.  None for a model with a mixer
         kind that has no `mixed` (it keeps the two programs)."""
-        if not all(hasattr(mixer, "mixed") for mixer in self._mixers):
-            return None
+        if self.nextn or not all(hasattr(mixer, "mixed")
+                                 for mixer in self._mixers):
+            return None   # (a draft under a mixed step: ROADMAP R10)
         data = sym.Variable("data")
         slot = sym.Variable("slot")
         length = sym.Variable("length")

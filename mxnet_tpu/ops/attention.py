@@ -62,6 +62,14 @@ needs four graph-level primitives beyond the classic registry:
   row whose ``data`` is negative reads its token from there.  The host
   can so dispatch step n+1 before it has read step n
   (serving/decode.py).
+* ``_draft_feed`` / ``_draft_verify`` / ``_draft_select`` /
+  ``_draft_commit`` / ``_draft_start`` — the same for a model that DRAFTS
+  (a multi-token-prediction module, `TransformerLM`'s `nextn`): a decode
+  step runs two positions a session, the verified token and the draft of
+  the one after it, accepts the draft where it is the trunk's own argmax
+  and emits one or two tokens; token, draft and POSITION of every slot
+  stay on the device in ``last_token (3, slots + 1)`` (scope
+  ``mx:mtp.verify``; the end of this file).
 
 The block vocabulary of current open decoders rides beside them:
 ``RMSNorm`` and the rotary pair ``_rotary`` / ``_rotary_at`` (positions
@@ -783,3 +791,160 @@ def greedy_token(logits, last_token, slot, **kw):
         last_token = lax.dynamic_update_slice(
             last_token, token[b:b + 1], (slot_i[b],))
     return token, last_token
+
+
+# ----------------------------------------------------------------------
+# a DRAFT beside the sampled token: the model's own multi-token-
+# prediction module guesses the token after the one just sampled, and the
+# next step VERIFIES the guess — two positions a row, one or two tokens
+# ----------------------------------------------------------------------
+#
+# A drafting model threads ``last_token (3, slots + 1)`` where the others
+# thread ``(slots + 1,)``: row 0 each slot's last verified token (sampled,
+# not yet through the trunk), row 1 the draft of the token after it, row 2
+# the positions the slot has cached.  The row's POSITION is on the device
+# because it depends on whether the device accepted the last draft, which
+# the host, a step behind, does not know yet.
+
+
+def _infer_draft_feed(in_shapes, attrs):
+    data, length, last_token, slot = in_shapes
+    b = data[0]
+    return ([data, (b,), last_token, (b,)],
+            [(2 * b, 1), (2 * b,), (2 * b,)])
+
+
+@register("_draft_feed", inputs=("data", "length", "last_token", "slot"),
+          num_outputs=3, infer_shape=_infer_draft_feed)
+def draft_feed(data, length, last_token, slot, **kw):
+    """What a drafting decode step runs: each session's TWO rows.  ``data
+    (B, 1)`` and ``length (B,)`` are the host's — the last token and the
+    positions cached — or, where the host wrote a negative `data` because
+    the step before is still in flight, ``last_token[0 | 2, slot]``; the
+    draft is ``last_token[1, slot]`` always (the host never reads one).
+    Outputs ``tokens (2B, 1)``, ``slot (2B,)``, ``length (2B,)``: rows
+    ``0..B-1`` the sessions' verified tokens at their positions n, rows
+    ``B..2B-1`` their drafts at n + 1, in the wire's float32."""
+    slot_i = _as_index(slot)
+    unread = data[:, 0] < 0
+    token = jnp.where(unread, last_token[0, slot_i], data[:, 0])
+    n = jnp.where(unread, last_token[2, slot_i], length)
+    return (jnp.concatenate([token, last_token[1, slot_i]])[:, None],
+            jnp.concatenate([slot, slot]), jnp.concatenate([n, n + 1]))
+
+
+def _infer_draft_verify(in_shapes, attrs):
+    logits, fed = in_shapes
+    rows = logits[0]
+    return [logits, (rows, 1)], [(rows, 1), (rows // 2,)]
+
+
+@register("_draft_verify", inputs=("logits", "fed"), num_outputs=2,
+          infer_shape=_infer_draft_verify)
+def draft_verify(logits, fed, **kw):
+    """THE VERIFY RULE: ``logits (2B, vocab)`` of the rows ``_draft_feed``
+    made, `fed` the tokens they ran on.  ``a = argmax logits[:B]`` is the
+    trunk's token after each session's verified one; its draft ``fed[B:]``
+    is ACCEPTED where it equals `a`, and then ``b = argmax logits[B:]`` is
+    the trunk's token after the draft.  Outputs ``sampled (2B, 1)`` = ``[a;
+    b]`` (what the draft module embeds beside each row's stream) and
+    ``accept (B,)`` in {0, 1}.  Nothing here or after it overrides the
+    comparison: a session receives the trunk's own greedy tokens."""
+    with jax.named_scope("mx:mtp.verify"):
+        b = logits.shape[0] // 2
+        sampled = jnp.argmax(logits, axis=-1).astype(fed.dtype)
+        accept = (sampled[:b] == fed[b:, 0]).astype(fed.dtype)
+        return sampled[:, None], accept
+
+
+def _infer_draft_select(in_shapes, attrs):
+    z, accept = in_shapes
+    rows, _, d = z
+    return [z, (rows // 2,)], [(rows // 2, d)]
+
+
+@register("_draft_select", inputs=("data", "accept"),
+          infer_shape=_infer_draft_select)
+def draft_select(data, accept, **kw):
+    """Each session's LAST VALID row of the draft module's output ``data
+    (2B, 1, d)``: its second where the draft was accepted, else its
+    first."""
+    b = data.shape[0] // 2
+    return jnp.where(accept[:, None] > 0, data[b:, 0], data[:b, 0])
+
+
+def _infer_draft_commit(in_shapes, attrs):
+    sampled, accept, logits, length, last_token, slot = in_shapes
+    b = accept[0]
+    return ([(2 * b, 1), (b,), logits, (2 * b,), last_token, (b,)],
+            [(b, 3), last_token])
+
+
+@register("_draft_commit",
+          inputs=("sampled", "accept", "draft_logits", "length",
+                  "last_token", "slot"),
+          num_outputs=2, infer_shape=_infer_draft_commit)
+def draft_commit(sampled, accept, draft_logits, length, last_token, slot,
+                 **kw):
+    """What a drafting step leaves: ``token (B, 3)`` = ``[count, a, b]``
+    for the host (count 1 or 2 tokens emitted; `b` is the session's only
+    where count is 2) and ``last_token`` with, at each row's slot in row
+    order, the last verified token (`b` if accepted else `a`), the NEXT
+    draft (``argmax draft_logits``) and the position ``n + count``:
+    a rejected draft's row of every ring lies at ``n + 1``, where the next
+    step writes before it reads."""
+    with jax.named_scope("mx:mtp.verify"):
+        b = accept.shape[0]
+        first, second = sampled[:b, 0], sampled[b:, 0]
+        count = 1 + accept
+        state = jnp.stack([
+            jnp.where(accept > 0, second, first),
+            jnp.argmax(draft_logits, axis=-1).astype(last_token.dtype),
+            length[:b] + count]).astype(last_token.dtype)
+        slot_i = _as_index(slot)
+        for r in range(b):
+            last_token = lax.dynamic_update_slice(
+                last_token, state[:, r:r + 1], (0, slot_i[r]))
+        return jnp.stack([count, first, second], axis=1), last_token
+
+
+def _infer_draft_shift(in_shapes, attrs):
+    data, logits, length = in_shapes
+    return [data, logits, (data[0],)], [data]
+
+
+@register("_draft_shift", inputs=("data", "logits", "length"),
+          infer_shape=_infer_draft_shift)
+def draft_shift(data, logits, length, **kw):
+    """The tokens that FOLLOW a prompt's positions, what a prefill's draft
+    module embeds: ``data (1, T)`` shifted left by one, with the first
+    sampled token (``argmax logits (1, vocab)``) after the prompt's last
+    (position ``length - 1``); the pad's rows are nobody's."""
+    first = jnp.argmax(logits, axis=-1).astype(data.dtype)
+    at = jnp.arange(data.shape[1])[None, :]
+    return jnp.where(at == _as_index(length)[:, None] - 1, first[:, None],
+                     jnp.roll(data, -1, axis=1))
+
+
+def _infer_draft_start(in_shapes, attrs):
+    logits, draft_logits, length, last_token, slot = in_shapes
+    return ([logits, draft_logits, (1,), last_token, (1,)],
+            [(1, 3), last_token])
+
+
+@register("_draft_start",
+          inputs=("logits", "draft_logits", "length", "last_token", "slot"),
+          num_outputs=2, infer_shape=_infer_draft_start)
+def draft_start(logits, draft_logits, length, last_token, slot, **kw):
+    """A PREFILL's end for a drafting model: the first token (``argmax
+    logits (1, vocab)``), the first draft (``argmax draft_logits``) and
+    the prompt's `length` go to the slot's column of ``last_token``;
+    ``token (1, 3)`` = ``[1, first, first]``."""
+    with jax.named_scope("mx:mtp.verify"):
+        first = jnp.argmax(logits, axis=-1).astype(last_token.dtype)
+        draft = jnp.argmax(draft_logits, axis=-1).astype(last_token.dtype)
+        state = jnp.stack([first, draft, length.astype(last_token.dtype)])
+        last_token = lax.dynamic_update_slice(
+            last_token, state, (0, _as_index(slot)[0]))
+        return jnp.stack([jnp.ones_like(first), first, first], axis=1), \
+            last_token

@@ -105,6 +105,41 @@ already freed slot at a position the slot's next tenant overwrites
 before it attends that far.  A step with no predecessor (the first
 after idle) is dispatched and read by the next call.
 
+**A model that drafts** (``model.token_state`` gives ``last_token`` a
+second axis: `TransformerLM` with `nextn`, a multi-token-prediction module
+used as the model's own draft).  A decode step then runs TWO positions a
+row — the last verified token and the draft of the one after it — and
+yields ONE OR TWO tokens: the trunk's argmax after the verified token,
+and, where the draft was that very token, the trunk's argmax after the
+draft.  The tokens a request receives are the trunk's own greedy tokens
+whatever the draft; nothing here accepts or forces one.  What changes
+with it is what the host can know a step ahead.  A row's next POSITION
+depends on whether the device accepted, so token, draft and position of
+every slot live on the device (``last_token (3, slots + 1)``) and a row
+packed while its last step is in flight (negative ``data``) takes all
+three from there; ``_Session.fed`` is then a LOWER BOUND of the positions
+cached — one a step dispatched, and one more for each step that is READ
+and had accepted — exact once nothing of the session is in flight.
+:meth:`_wants_row` decides by that bound: a session that MAY still lack
+a token is packed, so one whose step in flight turns out to have emitted
+its last token has a row in flight that is dropped, exactly as after an
+EOS (its writes land in its own, freed slot at positions the next tenant's
+prefill and steps overwrite before they read that far; a step's second
+row may lie ONE position past ``prompt + budget``, which ``max_len``
+must leave room for or the write is clamped onto the row before it — of
+a session that has retired).  :meth:`_land` reads ``token (B, 3)`` =
+``[count, first, second]`` and emits `count` tokens, trimmed to the
+budget — a reply holds exactly its budget.  Counters tell rows, positions
+and tokens apart: ``serving.decode.row_steps`` (rows read that emitted),
+``serving.decode.tokens`` (tokens emitted by them),
+``serving.mtp.drafts`` (rows read that carried a draft),
+``serving.mtp.accepted`` (those whose second token was emitted),
+``serving.mtp.dropped_rows`` (second positions computed and thrown away:
+rejected, past a budget or the ring's end, or of a row dropped whole),
+``mtp.bytes`` / ``mtp.step_bytes`` (the weights the module's part of a
+step reads, and the whole step's: ``model.step_weight_bytes``).  There is
+no mixed step for such a model.
+
 **Device time, from the fences.**  The device runs the flights in the
 order they were enqueued and :meth:`_land` fences on every one.  When
 the host was BLOCKED at the fence of flight k-1 and of flight k (each
@@ -546,6 +581,15 @@ class GenerativeSession:
         # the model owns what is cached and in which shape; the +1 is the
         # scratch slot padded decode rows point at
         self._spec = dict(model.cache_spec(self._slots + 1, self._max_len))
+        # each slot's last token — or, of a model that DRAFTS, its last
+        # token, draft and position (module docstring)
+        token_state = getattr(model, "token_state", None)
+        self._token_shape = (tuple(token_state(self._slots + 1))
+                             if token_state is not None
+                             else (self._slots + 1,))
+        self._drafts = len(self._token_shape) == 2
+        self._step_bytes = (getattr(model, "step_weight_bytes", None)
+                            if self._drafts else None)
         self._cache_bytes = sum(e.nbytes for e in self._spec.values())
         self._state_bytes = sum(e.nbytes for e in self._spec.values()
                                 if e.kind == "state")
@@ -670,7 +714,7 @@ class GenerativeSession:
                "length": (batch,), "row_data": (self._slots, 1),
                "row_slot": (self._slots,), "row_length": (self._slots,)}
         shp.update({n: e.shape for n, e in self._spec.items()})
-        shp["last_token"] = (self._slots + 1,)
+        shp["last_token"] = self._token_shape
         return {n: shp[n] for n in self._wire[bool(prefill)]}
 
     def _fresh_state(self, on_device=False):
@@ -681,7 +725,7 @@ class GenerativeSession:
         warm-up's: gigabytes need not cross the host link to be
         zeros)."""
         shapes = [e.shape for e in self._spec.values()]
-        shapes.append((self._slots + 1,))
+        shapes.append(self._token_shape)
         if not on_device:
             return [_np.zeros(shape, _np.float32) for shape in shapes]
         import jax.numpy as jnp
@@ -886,7 +930,8 @@ class GenerativeSession:
 
     def _land(self, flight, hists=_NO_HISTS, book=True):
         """Fence on one flight, read its tokens and emit them, one a
-        row — but for a row whose session has retired since (it hit EOS
+        row (a drafting model's: one or two, and the count is returned) —
+        but for a row whose session has retired since (it hit EOS
         while the row was in flight): that token is dropped.  Then, if
         `book`, book the flight's device time from the fence (module
         docstring); a shutdown's landing (not `book`) is no leg of a
@@ -904,6 +949,9 @@ class GenerativeSession:
             token, *extra = (_np.asarray(o) for o in flight.outs)
         if self._reports_moe_load:
             self._book_moe_load(extra[0])
+        if self._drafts:
+            return self._land_drafted(flight, token, extra, wait, read,
+                                      hists[2], book)
         with profiler.span("decode.emit", cat="serving",
                            hist=hists[2]) as emit:
             live = [(i, sess, int(t))
@@ -911,16 +959,64 @@ class GenerativeSession:
                     if not sess.retired]
             for _, sess, t in live:
                 self._emit(sess, t)
+        self._close_landing(flight, wait, read, emit, book, len(live), sum(
+            1 for i, _, _ in live if i >= len(flight.rows) - flight.riders))
+
+    def _land_drafted(self, flight, token, extra, wait, read, hist, book):
+        """`_land`'s second half for a model that drafts: ``token (rows,
+        3)`` = ``[count, first, second]``.  Each live row emits its
+        `count` tokens as far as its budget goes (`_emit` retires at the
+        budget; what follows is dropped) and a step's row whose two were
+        both emitted has cached one position more than its dispatch
+        counted."""
+        step = flight.prog.kind == "decode"
+        live = decoded = accepted = 0
+        with profiler.span("decode.emit", cat="serving", hist=hist) as emit:
+            for sess, (count, *tokens) in zip(flight.rows, token):
+                if sess.retired:
+                    continue
+                live += 1
+                before = len(sess.generated)
+                for t in tokens[:int(count)]:
+                    self._emit(sess, int(t))
+                    if sess.retired:
+                        break
+                emitted = len(sess.generated) - before
+                decoded += emitted * step
+                if emitted > 1:   # the draft's position is cached too
+                    sess.fed += 1
+                    accepted += 1
+        if step and telemetry.enabled():
+            drafts = len(flight.rows)
+            telemetry.inc("serving.decode.row_steps", live)
+            telemetry.inc("serving.mtp.drafts", drafts)
+            telemetry.inc("serving.mtp.accepted", accepted)
+            telemetry.inc("serving.mtp.dropped_rows", drafts - accepted)
+            if self._step_bytes is not None:
+                for name, n in self._step_bytes(
+                        extra[0] if self._reports_moe_load else None).items():
+                    telemetry.inc(name, n)
+        if step and recorder.enabled():
+            # a point event beside the flight's bracket: what it emitted
+            recorder.record("emitted", "exit", flight.seq,
+                            detail="rows=%d live=%d accepted=%d tokens=%d"
+                            % (len(flight.rows), live, accepted, decoded))
+        self._close_landing(flight, wait, read, emit, book, live, decoded)
+        return decoded
+
+    def _close_landing(self, flight, wait, read, emit, book, live, decoded):
+        """What follows a landing's emit: the stall records its fence
+        finishes, the tokens and dropped rows it adds (`live` of its rows
+        were read, `decoded` tokens came from decode rows), its device
+        time."""
         # the stall records this landing's fence finishes
         waiting = None
         if self._stalls_open:
             waiting, self._stalls_open = self._stalls_open, []
         stalled = book and self._landed(flight, wait, read, emit)
-        dropped = len(flight.rows) - len(live)
-        # a session's first token is its prefill's, not a decode token:
-        # the decode rows are the flight's last `riders`
-        first = len(flight.rows) - flight.riders
-        decoded = sum(1 for i, _, _ in live if i >= first)
+        dropped = len(flight.rows) - live
+        # (a session's first token is its prefill's, not a decode token:
+        # the decode rows are the flight's last `riders`)
         if decoded:
             self._tokens_done += decoded
             if telemetry.enabled():
@@ -1256,7 +1352,10 @@ class GenerativeSession:
         landing, self._flights = self._flights, []
         rows = [s for s in self._active if self._wants_row(s)]
         prompt = self._pending.popleft() if self._pending else None
-        if prompt is not None and not self._laddered:
+        if (prompt is not None or self._drafts) and not self._laddered:
+            # (a drafting model too: its rows retire one OR two tokens a
+            # step, so a warm-up of staggered budgets need not pass
+            # through every count of live rows)
             self._build_ladder()
         # at most one decode step is in flight, and it is the oldest:
         # this call lands all it finds and leaves the one it dispatches
@@ -1273,19 +1372,25 @@ class GenerativeSession:
             # legs are the period of PURE steps: a call that reads a
             # mixed step feeds `serving.prefill_seconds` below instead
             pure = not any(self._kind(f.prog) == "mixed" for f in landing)
+            # a drafting model's step also says how many rows carry a
+            # draft and, once the step before is read, what that emitted
+            drafted = {"drafted": n, "emitted": 0} if self._drafts else {}
             with profiler.span(
                     "serve.decode_step", cat="serving",
                     hist="serving.decode.step_seconds" if pure else None,
                     bucket=bucket, rows=n,
                     program="decode" if prompt is None else "mixed",
                     seq=self._seq + 1 if rows or prompt is not None else 0,
-                    landed=step.seq if step is not None else 0):
+                    landed=step.seq if step is not None else 0,
+                    **drafted) as whole:
                 if prompt is not None:
                     prompt = self._ride(prompt, rows)
                 if prompt is None and rows:
                     self._dispatch_step(rows, bucket, timed=pure)
                 if step is not None:
-                    self._land(step, _LAND_HISTS)
+                    emitted = self._land(step, _LAND_HISTS)
+                    if self._drafts:
+                        whole.attrs["emitted"] = emitted
         for flight in landing:
             with profiler.span("serve.prefill", cat="serving",
                                hist="serving.prefill_seconds",
@@ -1298,7 +1403,8 @@ class GenerativeSession:
         return n
 
     def _build_ladder(self):
-        """Before the first mixed step: build every decode bucket program
+        """Before the first mixed step (and a drafting model's first
+        step): build every decode bucket program
         not built yet and run it once, its rows idle, on the live state
         (a step of garbage on the scratch slot; no second set of rings as
         `warm` threads).  With two programs a burst of admissions prefills
@@ -1376,9 +1482,14 @@ class GenerativeSession:
         telemetry.inc("cache.state_bytes", sets * self._state_bytes)
         pages = sets * (self._slots + 1)
         filled = length[:n].astype(_np.int64)
-        self._book_call(positions=positions, rows=n,
-                        lengths=filled.tolist(), computed=computed,
-                        pages=pages, max_len=self._max_len)
+        # a drafting step runs two positions a row, the second one on
+        # (`filled` is the host's lower bound where the step before is in
+        # flight)
+        each = 2 if self._drafts else 1
+        lengths = [int(f) + k for k in range(each) for f in filled]
+        self._book_call(positions=positions, rows=each * n, lengths=lengths,
+                        computed=each * computed, pages=pages,
+                        max_len=self._max_len)
         if self._has_ring:
             # position-steps, each the mean over the rings (whose
             # lengths differ where the model has window layers): over

@@ -770,6 +770,11 @@ COVERED_ELSEWHERE = {
     "_sparse_latent_attention", "_window_latent_attention",
     "_latent_window_write", "_sparse_latent_cached_attention",
     "_window_latent_cached_attention",
+    # test_glm5.py (ops/attention.py: a drafting model's verify rule and
+    # what it leaves on the device, against the benchmark's plain
+    # reference and the same trunk served with no draft module)
+    "_draft_feed", "_draft_verify", "_draft_select", "_draft_commit",
+    "_draft_shift", "_draft_start",
     # test_contrib_ops2.py
     "_contrib_fft", "_contrib_ifft", "_contrib_quantize",
     "_contrib_dequantize", "_contrib_count_sketch", "_contrib_Proposal",

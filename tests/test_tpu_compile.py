@@ -587,3 +587,52 @@ def test_the_dots3_programs_compile_for_a_v5e(program, one_chip):
         # run's keys, which XLA's form wrote to HBM and read back
         assert chip_smoke.run_score_arrays(text, bucket) == []
         assert not re.search(r"f32\[122880,5120\]", text)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_glm5_drafting_programs_compile_for_a_v5e(program, one_chip):
+    """GLM-5's two serving programs WITH ITS DRAFT MODULE at the cell's
+    sizes — the published widths, five layers and the MTP block, eight
+    held experts, four slots of 3,200 positions, the ONE bucket of 1,024 —
+    lowered for the TPU: the drafting decode step runs two positions a
+    session (eight rows), and every cache entry — the module's own latent
+    ring and index keys among them — is aliased to its output and never
+    copied, as is ``last_token (3, slots + 1)``; the weights are the
+    13.17 GB the configuration's `reduced_why` reckons, and weights, five
+    bound cache sets and the larger program's temporaries fit a v5e."""
+    import json
+    import warnings
+
+    from benchmarks.families import glm5 as family
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "glm-5.json")) as f:
+        config = json.load(f)
+    lm = family.model(config)
+    rows, bucket, max_len = 4, 1024, 3200
+    spec = lm.cache_spec(rows + 1, max_len)
+    assert len(spec) == 2 * (config["num_hidden_layers"] + 1)
+    wire = dict(_wire(spec, rows), last_token=lm.token_state(rows + 1))
+    if program == "prefill":
+        wire = dict(wire, data=(1, bucket), slot=(1,), length=(1,))
+    graph = (lm.decode_symbol() if program == "decode"
+             else lm.prefill_symbol())
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        compiled = _serving_program(graph, wire, one_chip)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    for shape in sorted({e.shape for e in spec.values()}):
+        count = sum(e.shape == shape for e in spec.values())
+        facts = chip_smoke.ring_hlo_facts(text, shape)
+        assert facts["ring_params"] == facts["aliased"] == count, shape
+        assert facts["copies"] == [], shape
+    sets = sum(e.nbytes for e in spec.values())
+    assert stats.alias_size_in_bytes >= sets
+    weights = stats.argument_size_in_bytes - sets
+    assert 13.1e9 < weights < 13.25e9
+    # a v5e's 16.9e9 bytes hold the weights, five sets, the program
+    assert weights + 5 * sets + stats.temp_size_in_bytes < 16.5e9, (
+        weights, sets, stats.temp_size_in_bytes)
+    if program == "decode":
+        assert stats.temp_size_in_bytes < 0.3e9
