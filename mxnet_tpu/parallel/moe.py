@@ -71,6 +71,30 @@ def expert_ffn(matmul, x, weights, biases, act, gated):
 # GB a copy of the rows, five copies live) goes through in equal pieces of
 # its tokens, one after the other
 _PAIR_BYTES = 256 << 20
+# How many rows `_dropless` gathers for its pairs.  The TPU's grouped matmul
+# (`lax.ragged_dot`) walks the sorted pair rows by the largest power-of-two
+# tile, up to `_ROW_TILE`, that divides their count, one (tile, expert) item
+# at a time: by the large tile an item multiplies 512 rows whatever the
+# expert's segment holds of them (~12.5 us at OLMoE's widths), by a small one
+# it re-reads the expert's weights for every tile the segment touches (~9.9
+# us a 64-row item).  So the large tile pays from about `_ROWS_AN_EXPERT`
+# pairs an expert scored (PERF.md section 6, PR 53, a dot in its program:
+# the small tile 10% shorter at 8 pairs an expert, the two level at 16 and
+# at 30, the large one 15% shorter at 32 and 46% at 65), and from there the
+# count is made a whole number of large tiles — a mixed step's (T + rows) x
+# k pairs are never one by themselves; fewer pairs are left as they are.
+# XLA documents none of this: both numbers were read off the chip under
+# jaxlib 0.9.0 / libtpu 0.0.34, and `tests/test_tpu_compile.py` reads the
+# tile the compiler picks back from the compiled dot.
+_ROW_TILE = 512
+_ROWS_AN_EXPERT = 24
+
+
+def _spare_rows(pairs, experts):
+    """The rows to gather beyond `pairs` pairs routed over `experts`."""
+    if pairs < max(_ROW_TILE, _ROWS_AN_EXPERT * experts):
+        return 0
+    return -pairs % _ROW_TILE
 
 
 def dropless_experts(x, logits, k, weights, biases=None, act="relu",
@@ -149,15 +173,23 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
     order = jnp.argsort(flat_e, stable=True)               # pair -> sorted
     sorted_e = flat_e[order]
     load = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1, mode="drop")
-    rows = x[order // k]                                   # [T*k, D]
+    # rows past the last pair lie beyond every segment: no expert multiplies
+    # them, nothing reads them, and a gradient that reaches them is cut off
+    # with them (`_spare_rows` says why there are any)
+    spare = _spare_rows(len(order), logits.shape[-1])
+
+    def spared(pair_rows):
+        return jnp.pad(pair_rows, ((0, spare), (0, 0))) if spare else pair_rows
 
     def matmul(r, w):
         return lax.ragged_dot(r, w.astype(r.dtype), load)
 
-    ys = expert_ffn(matmul, rows, weights,
+    ys = expert_ffn(matmul, spared(x[order // k]), weights,    # [T*k (+), D]
                     None if biases is None
-                    else [b.astype(x.dtype)[sorted_e] for b in biases],
+                    else [spared(b.astype(x.dtype)[sorted_e]) for b in biases],
                     act, gated)
+    if spare:
+        ys = ys[:len(order)]
     if held is not None:
         ys = jnp.where((sorted_e < n_exp)[:, None], ys, 0)
     pairs = ys[jnp.argsort(order)].reshape(t_len, k, -1)   # token order

@@ -172,6 +172,113 @@ def test_dropless_when_every_token_picks_the_same_experts():
     assert np.abs(out.asnumpy()[capacity:]).min() > 0.0
 
 
+# (pairs, experts scored) of the routed cells' programs -> rows spared: a
+# mixed step's (bucket + rows) x k pairs, a decode step's, a whole bucket's
+SPARED = {"olmoe 64": (72 * 8, 64, 0), "olmoe 128": (136 * 8, 64, 0),
+          "olmoe 256": (264 * 8, 64, 448), "olmoe 512": (520 * 8, 64, 448),
+          "olmoe step": (8 * 8, 64, 0), "olmoe prefill": (256 * 8, 64, 0),
+          "qwen3-next 1024": (1040 * 10, 512, 0),
+          "qwen3-next 2048": (2064 * 10, 512, 352),
+          "trinity 768": (776 * 8, 128, 448),
+          "few experts": (40 * 2, 2, 0)}
+
+
+@pytest.mark.parametrize("name", sorted(SPARED))
+def test_the_pair_rows_an_expert_layer_spares(name):
+    """Two dozen pairs an expert scored or more: up to whole 512-row
+    tiles; fewer, or less than one such tile: none."""
+    from mxnet_tpu.parallel import moe
+
+    pairs, experts, spare = SPARED[name]
+    assert moe._spare_rows(pairs, experts) == spare
+
+
+@pytest.mark.parametrize("tokens", [264, 520])
+@pytest.mark.parametrize("held", [None, (16, 32)])
+@pytest.mark.parametrize("biased", [False, True])
+def test_pairs_are_gathered_to_whole_row_tiles(tokens, held, biased,
+                                               monkeypatch):
+    """PR 53: the TPU's grouped matmul walks the pair rows by the largest
+    power-of-two tile, up to 512, that divides their count, so `_dropless`
+    gathers MORE rows than it has pairs where an expert gets two dozen or
+    more (264 and 520 positions at eight of 64 experts a token: a mixed
+    step's riders behind its 256 and 512 buckets).  The rows past the last
+    pair lie beyond every segment, where a TPU's segment matmul leaves
+    stale memory — NaN here, forward and backward: the same output, load
+    and gradient as the pairs alone, with biases, and with a held range of
+    the experts."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import moe
+
+    dot = jax.lax.ragged_dot
+
+    def beyond(rows, groups, fill):
+        live = jnp.arange(rows.shape[0]) < groups.sum()
+        return jnp.where(live[:, None], rows, fill)
+
+    @jax.custom_vjp
+    def stale(lhs, rhs, groups):
+        assert lhs.shape[0] == tokens * k + 448
+        return beyond(dot(beyond(lhs, groups, 0), rhs, groups), groups,
+                      jnp.nan)
+
+    def forward(lhs, rhs, groups):
+        return stale(lhs, rhs, groups), (lhs, rhs, groups)
+
+    def backward(saved, g):
+        lhs, rhs, groups = saved
+        d_lhs, d_rhs = jax.vjp(lambda a, b: dot(a, b, groups),
+                               beyond(lhs, groups, 0), rhs)[1](
+                                   beyond(g, groups, 0))
+        return beyond(d_lhs, groups, jnp.nan), d_rhs, None
+
+    stale.defvjp(forward, backward)
+    rng = np.random.default_rng(3)
+    D, H, E, k = 16, 8, 64, 8
+    assert moe._spare_rows(tokens * k, E) == 448
+    x = jnp.asarray(rng.normal(size=(tokens, D)).astype(np.float32))
+    logits = jnp.asarray(rng.normal(size=(tokens, E)).astype(np.float32))
+    first, count = held or (0, E)
+    weights = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                    for shape in ((count, D, H), (count, H, D),
+                                  (count, D, H)))
+    biases = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                   for shape in ((count, H), (count, D), (count, H))
+                   ) if biased else None
+
+    def layer(x, weights, biases):
+        return moe.dropless_experts(x, logits, k, weights, biases,
+                                    act="silu", gated=True, held=held)
+
+    def both():
+        return layer(x, weights, biases), jax.grad(
+            lambda *args: layer(*args)[0].sum(), (0, 1, 2))(
+                x, weights, biases)
+
+    plain_spare = moe._spare_rows
+    # (a held layer's pairs of experts held elsewhere lie beyond every
+    # segment too, and only the forward pass zeroes them: the parent's)
+    if held is None:
+        monkeypatch.setattr(jax.lax, "ragged_dot", stale)
+    gathered = both()
+    monkeypatch.setattr(moe, "_spare_rows", lambda pairs, experts: 0)
+    monkeypatch.setattr(jax.lax, "ragged_dot", dot)
+    plain = both()
+    assert plain_spare is not moe._spare_rows
+    assert float(jnp.abs(plain[0][0]).max()) > 1.0
+    assert 0 < float(plain[0][1].sum()) <= tokens * k
+    for a, b in zip(jax.tree_util.tree_leaves((gathered[0], gathered[1][0])),
+                    jax.tree_util.tree_leaves((plain[0], plain[1][0]))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # (a weight's gradient sums over the rows, in another order over more)
+    for a, b in zip(jax.tree_util.tree_leaves(gathered[1][1:]),
+                    jax.tree_util.tree_leaves(plain[1][1:])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
+                                   atol=1e-5)
+
+
 def test_training_loss_and_gradients_match_reference(params, held):
     """(d) the training graph's loss gradient for router, expert,
     attention and norm weights against `jax.grad` of the reference's

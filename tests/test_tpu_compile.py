@@ -156,6 +156,62 @@ def test_a_one_row_product_is_not_the_mxus(rows, on_the_mxu, one_chip):
     assert len(re.findall(r"= \S+ convolution\(", text)) == on_the_mxu
 
 
+# (tokens, k, experts scored, experts held, d_model, d_expert) -> the row
+# tile the grouped matmul should walk: a mixed step's bucket + rows tokens
+# at the cells' widths (OLMoE's four programs; Qwen3-Next's and Trinity's
+# longest, a quarter and a half of their experts held)
+PAIR_TILES = {"olmoe 64": ((72, 8, 64, 64, 2048, 1024), 64),
+              "olmoe 128": ((136, 8, 64, 64, 2048, 1024), 64),
+              "olmoe 256": ((264, 8, 64, 64, 2048, 1024), 512),
+              "olmoe 512": ((520, 8, 64, 64, 2048, 1024), 512),
+              "qwen3-next 2048": ((2064, 10, 512, 128, 2048, 512), 512),
+              "trinity 2048": ((2056, 8, 128, 64, 2048, 1024), 512)}
+
+
+@pytest.mark.parametrize("name", sorted(PAIR_TILES))
+def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
+        name, one_chip):
+    """`parallel/moe.py _spare_rows` leans on a choice XLA documents
+    nowhere: the TPU's `ragged-dot` walks its rows by the largest power of
+    two, up to 512, that divides their count.  Read back from the compiled
+    program — the dot's `ragged_dot_tiling` and its metadata operand of
+    tiles + groups - 1 entries — so that a compiler that chooses otherwise
+    fails here and not silently on the chip: two dozen pairs an expert or
+    more walk whole 512-row tiles, fewer keep the parent's count and its
+    small tile."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import moe
+
+    (tokens, k, scored, held, d_model, d_expert), tile = PAIR_TILES[name]
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def layer(x, logits, *weights):
+        return moe.dropless_experts(
+            x, logits, k, weights, act="silu", gated=True,
+            held=None if held == scored else (0, held))
+
+    text = jax.jit(layer).lower(
+        arg(tokens, d_model), arg(tokens, scored),
+        arg(held, d_model, d_expert), arg(held, d_expert, d_model),
+        arg(held, d_model, d_expert)).compile().as_text()
+    rows = tokens * k + moe._spare_rows(tokens * k, scored)
+    assert rows % tile == 0 and (tile == 512 or rows == tokens * k)
+    dots = re.findall(
+        r"= f32\[(\d+),\d+\]\S* custom-call\([^)]*\)[^\n]*?"
+        r'ragged_dot_tiling="(\d+),', text)
+    assert len(dots) == 3, name
+    assert set(dots) == {(str(rows), str(tile))}
+    entries = set(re.findall(r"%ragged-dot-metadata = \(s32\[\d+\]\S*, "
+                             r"s32\[(\d+)\]", text))
+    assert entries == {str(rows // tile + held - 1)}
+
+
 # (key heads, value heads, d_k, d_v, heads a step of the kernel's walk)
 GDN_WIDTHS = {"olmo_hybrid": (30, 30, 96, 192, 6),
               "qwen3_next": (16, 32, 128, 128, 8)}
