@@ -1010,6 +1010,10 @@ class _Recurrent:
         return [caches["conv_state_%d" % i],
                 caches["%s_%d" % (self.STATE, i)]]
 
+    def _page_bytes(self, i):
+        """One slot's window and state of layer i, in bytes."""
+        return sum(e.nbytes for _, e in self.cache_spec(i, 1, 0))
+
     def full(self, x, p, i):
         y = getattr(sym, self.OPS[0])(*self._in(x, p, i),
                                       name="l%d_%s" % (i, self.NODE),
@@ -1074,6 +1078,13 @@ class _Mamba2(_Recurrent):
                           conv_kernel=lm.mamba_conv,
                           chunk_size=lm.mamba_chunk, eps=lm.norm_eps)
 
+    def counters(self, i, positions=0, rows=0, **call):
+        """What one program call adds: the bucket positions a prefill
+        scans in this layer (the pad included), and the bytes of window
+        and state a decode step's `rows` rows read and write."""
+        return {"ssm.scan_positions": positions,
+                "ssm.state_bytes": 2 * rows * self._page_bytes(i)}
+
 
 class _GatedDeltaNet(_Recurrent):
     """The Gated DeltaNet mixer of layer i (ops/gdn.py has the equations):
@@ -1132,8 +1143,7 @@ class _GatedDeltaNet(_Recurrent):
         `rows` rows read and write, and those of them that such a
         program's step kernel moves (all, or none where
         ``ops.gdn.step_heads`` says the body runs)."""
-        page = sum(e.nbytes for _, e in self.cache_spec(i, 1, 0))
-        lm = self.lm
+        page, lm = self._page_bytes(i), self.lm
         tiled = _gdn.chunk_heads(
             (1, positions, lm.linear_heads, lm.linear_key_dim),
             lm.linear_value_dim, lm.linear_chunk, platform,

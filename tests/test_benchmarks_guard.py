@@ -5,6 +5,14 @@ Each file runs in a subprocess: the two suites force different virtual
 device counts in their `conftest.py` (8 here, 4 there) before jax is
 imported, so they cannot share an interpreter.  A case per file so that
 a break names its file.
+
+The files run one after the other, and all of them on one worker were
+the longest chain of tier-1 (twelve minutes alone, while the other
+workers had finished): this file keeps `test_rehearsal.py` — every cell
+twice —, the files that rehearse nothing and three cells' (`HERE`), `test_benchmarks_guard_more.py`
+runs the others through `run_file`.  Two traced rehearsals at once would
+share one trace directory; `tests/trace_dir_plugin.py` gives each
+subprocess its own.
 """
 import glob
 import os
@@ -15,20 +23,31 @@ import sys
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FILES = sorted(
+ALL = sorted(
     os.path.relpath(p, ROOT)
     for p in glob.glob(os.path.join(ROOT, "benchmarks", "tests", "test_*.py")))
-LIMIT_S = 300
+# the files of `test_benchmarks_guard_more.py`: a cell's own rehearsal, or
+# every cell's spans — but for three that stay here to level the two chains
+# (about nine minutes each, alone: `--durations`)
+HERE = ("olmo_hybrid", "trinity_mini", "glm5_mtp")
+MORE = [p for p in ALL if p.endswith(("_cell.py", "_spans.py"))
+        and not any(name in p for name in HERE)]
+FILES = [p for p in ALL if p not in MORE]
+# `test_rehearsal.py` runs every cell twice and grows by ~25 s a cell: 285 s
+# alone with twelve cells, more beside five other workers
+LIMIT_S = 420
 
 
 def test_the_glob_finds_the_benchmark_tests():
     """An empty list would parametrise the guard away in silence."""
-    assert FILES
+    assert FILES and MORE and sorted(FILES + MORE) == ALL
 
 
-@pytest.mark.parametrize("path", FILES, ids=[
-    os.path.splitext(os.path.basename(p))[0] for p in FILES])
-def test_benchmark_test_file_passes(path):
+def ids(files):
+    return [os.path.splitext(os.path.basename(p))[0] for p in files]
+
+
+def run_file(path):
     env = dict(os.environ)
     # this suite's conftest forced 8 devices; benchmarks/tests/conftest.py
     # appends its own count, and only one such flag may stand
@@ -38,7 +57,7 @@ def test_benchmark_test_file_passes(path):
     try:
         r = subprocess.run(
             [sys.executable, "-m", "pytest", path, "-q",
-             "-p", "no:cacheprovider"],
+             "-p", "no:cacheprovider", "-p", "tests.trace_dir_plugin"],
             cwd=ROOT, env=env, capture_output=True, text=True,
             timeout=LIMIT_S)
     except subprocess.TimeoutExpired as e:
@@ -50,3 +69,8 @@ def test_benchmark_test_file_passes(path):
     assert r.returncode == 0, \
         "%s: exit %d\n%s" % (path, r.returncode,
                              (r.stdout + r.stderr)[-6000:])
+
+
+@pytest.mark.parametrize("path", FILES, ids=ids(FILES))
+def test_benchmark_test_file_passes(path):
+    run_file(path)

@@ -692,3 +692,66 @@ def test_the_glm5_drafting_programs_compile_for_a_v5e(program, one_chip):
         weights, sets, stats.temp_size_in_bytes)
     if program == "decode":
         assert stats.temp_size_in_bytes < 0.3e9
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
+    """granite-4.0-h-small's two serving programs at the cell's sizes —
+    the published widths, ten layers (nine Mamba-2 of 128 heads x 64 with
+    128 states, one attention), nine held experts of 72 under every mixer,
+    eight slots of 1,536 positions, the 8-row step and the 1,024 bucket —
+    lowered for the TPU: every cache entry (conv windows, Mamba states,
+    the attention layer's two rings) is aliased to its output and none is
+    copied within HBM (the step stages some layers' state in the
+    compiler's fast memory, one write-out each); the weights
+    are the 8.22 GB the configuration's `reduced_why` reckons; weights,
+    the tenant's nine bound cache sets and the larger program's
+    temporaries fit a v5e."""
+    import json
+    import warnings
+
+    from benchmarks.families import granite_moe_hybrid as family
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "granite-4.0-h-small.json")) as f:
+        config = json.load(f)
+    lm = family.model(config)
+    assert lm.mixed_symbol(8) is None      # two programs: Mamba-2
+    rows, bucket, max_len = 8, 1024, 1536
+    spec = lm.cache_spec(rows + 1, max_len)
+    assert len(spec) == 2 * config["num_hidden_layers"]
+    wire = _wire(spec, rows)
+    if program == "prefill":
+        wire = dict(wire, data=(1, bucket), slot=(1,), length=(1,))
+    graph = (lm.decode_symbol() if program == "decode"
+             else lm.prefill_symbol())
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        compiled = _serving_program(graph, wire, one_chip)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    recurrent = {e.shape for e in spec.values() if e.kind == "state"}
+    for shape in sorted({e.shape for e in spec.values()}):
+        count = sum(e.shape == shape for e in spec.values())
+        facts = chip_smoke.ring_hlo_facts(text, shape)
+        assert facts["ring_params"] == facts["aliased"] == count, shape
+        if shape in recurrent and program == "decode":
+            # the step's compiler advances some layers' windows (0.9 MB)
+            # and states (37.7 MB: four of nine layers) in its fast
+            # memory, S(1), and writes the buffer out once — 75 MB a layer
+            # where the eight rows' pages read and written in place are 67
+            # (PERF.md section 6, PR 54); no buffer is copied within HBM
+            assert all("S(1)" in line for line in facts["copies"]), shape
+            assert len(facts["copies"]) <= count, shape
+        else:
+            assert facts["copies"] == [], shape
+    sets = sum(e.nbytes for e in spec.values())
+    assert 0.46e9 < sets < 0.4625e9        # nine pages of 51.2 MB
+    assert stats.alias_size_in_bytes >= sets
+    weights = stats.argument_size_in_bytes - sets
+    assert 8.21e9 < weights < 8.23e9
+    # a v5e's 16.9e9 bytes hold the weights, nine sets, the program
+    assert weights + 9 * sets + stats.temp_size_in_bytes < 16.5e9, (
+        weights, sets, stats.temp_size_in_bytes)
+    if program == "decode":
+        assert stats.temp_size_in_bytes < 0.3e9
