@@ -1804,6 +1804,21 @@ class TransformerLM:
                         total[name] = total.get(name, 0) + n
         return total
 
+    def expert_plan(self, tokens):
+        """``(pairs, pieces, rows)`` of each routed layer in a serving
+        program that computes `tokens` tokens (the pad included): the
+        (token, expert) pairs its router makes and how the layer goes
+        through them — `parallel.moe.pass_plan`'s pieces and the sorted
+        rows a pass gathers, 0 where it gathers every pair's row (no held
+        range, or few pairs).  The session keeps it a bucket program and
+        books `moe.pair_rows` / `moe.passes` from it and the call's
+        ``moe_load``."""
+        from ..parallel import moe
+
+        k = self.experts_per_token
+        return (tokens * k,) + moe.pass_plan(
+            tokens, k, 4 * self.d_model, self.held_experts, self.num_experts)
+
     def step_weight_bytes(self, load=None):
         """``{"mtp.bytes", "mtp.step_bytes"}``: the float32 bytes of the
         WEIGHTS one decode step of a drafting model reads for its draft

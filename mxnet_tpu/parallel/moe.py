@@ -14,7 +14,9 @@ Pieces:
     own contiguous segment (`lax.ragged_dot`: on the TPU a grouped
     matmul that visits only the tiles a non-empty segment touches, so a
     decode step reads the experts that were hit and a prefill runs one
-    matmul a segment); no [T, E, C] tensor exists.
+    matmul a segment); no [T, E, C] tensor exists.  A model that holds a
+    RANGE of the router's experts sorts every pair and walks the held
+    ones alone, in passes of a static row count (`_held_passes`).
   * top_k_gating(logits, k, capacity) — deterministic capacity-bounded
     router (Switch/GShard-style): per-expert position via a cumulative
     count, tokens over capacity dropped (combine weight 0).
@@ -29,6 +31,7 @@ whole layer jits into one XLA program — no data-dependent shapes.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -65,11 +68,13 @@ def expert_ffn(matmul, x, weights, biases, act, gated):
     return lin(h, 1)
 
 
-# the bytes of the T*k gathered pair rows a call sorts at once: a bucket of
-# 2,048 positions at eight experts a token and a hidden size of 4,096 is
-# within it, and a bucket of fifteen thousand (eight a token at 5,120: 2.5
-# GB a copy of the rows, five copies live) goes through in equal pieces of
-# its tokens, one after the other
+# the bytes of the pair rows a call GATHERS at once — every pair's T*k rows,
+# or, where a held range walks its held pairs alone (`_pass_rows`), one
+# pass's: a bucket of 2,048 positions at eight experts a token and a hidden
+# size of 4,096 is within it; a bucket of fifteen thousand (eight a token at
+# 5,120) whose every pair's row would be 2.5 GB a copy is too where eight of
+# its 256 experts are held (one pass of 6,144 rows, 126 MB); whatever is not
+# goes through in equal pieces of its tokens, one after the other
 _PAIR_BYTES = 256 << 20
 # How many rows `_dropless` gathers for its pairs.  The TPU's grouped matmul
 # (`lax.ragged_dot`) walks the sorted pair rows by the largest power-of-two
@@ -97,20 +102,121 @@ def _spare_rows(pairs, experts):
     return -pairs % _ROW_TILE
 
 
+# Under a held range only `count` of the router's `experts` experts are here,
+# and the sort puts their pairs first: the `width`-wide work — the gather of
+# the rows, the three grouped matmuls, the weights, the return to token order
+# — then runs over passes of a STATIC count of sorted rows, and as many
+# passes as the held pairs fill.  The count is the uniform expectation
+# `pairs * count / experts` and half as much again, in whole `_ROW_TILE`s
+# (the count is ours to choose, so the grouped matmul walks the large tile):
+# one pass for any routing near uniform, more under skew, every pair in
+# some pass.  It is taken where it saves whole tiles of a call that has a
+# few, from `_COMPACT_PAIRS` pairs: read off the chip (PERF.md section 6,
+# PR 55), Granite-H-Small's 128-position bucket — 1,280 pairs, a pass of
+# 512 — is level with gathering every pair's row, its 256 bucket a fifth
+# shorter and its 1,024 bucket 30%; a decode step's 32 to 320 pairs are
+# under it and compile to what they did.
+_PASS_SLACK = 1.5
+_COMPACT_PAIRS = 2 * _ROW_TILE
+
+
+def _pass_rows(pairs, held, experts):
+    """The sorted rows a pass of `_held_passes` takes of `pairs` pairs
+    routed over `experts` experts of which `held` ``(first, count)`` are
+    here; 0 where `_dropless` gathers every pair's row (no held range,
+    few pairs, or no whole tile saved)."""
+    if held is None or pairs < _COMPACT_PAIRS:
+        return 0
+    rows = _ROW_TILE * math.ceil(
+        _PASS_SLACK * pairs * held[1] / experts / _ROW_TILE)
+    return rows if rows < pairs else 0
+
+
+def pass_plan(tokens, k, row_bytes, held, experts):
+    """How `dropless_experts` goes through `tokens` tokens of `row_bytes`
+    a row: ``(pieces, rows)`` — in `pieces` equal pieces of the tokens,
+    the fewest whose gathered rows are within `_PAIR_BYTES` each, a piece
+    in passes of `rows` sorted rows (`_pass_rows`; 0: every pair's row at
+    once).  The serving path's counters read the same plan."""
+    if k * row_bytes > _PAIR_BYTES:
+        raise ValueError("one token's %d pair rows are %d bytes: no piece "
+                         "is within %d" % (k, k * row_bytes, _PAIR_BYTES))
+    for pieces in range(1, max(tokens, 1) + 1):
+        if tokens % pieces == 0:
+            pairs = tokens // pieces * k
+            rows = _pass_rows(pairs, held, experts)
+            if (rows or pairs) * row_bytes <= _PAIR_BYTES:
+                return pieces, rows
+
+
+def _held_passes(x, order, sorted_e, load, top_w, rows, weights, biases,
+                 act, gated):
+    """The held experts' part of `_dropless` over the held pairs alone:
+    `order [T*k]` the pairs sorted by held expert (the others behind
+    them), `load [count]` each held expert's pairs.  Pass p takes sorted
+    rows ``p * rows .. (p + 1) * rows``: it gathers their tokens' rows of
+    `x`, multiplies each expert's part of the window by its weights,
+    weighs the results by the router and adds them to their tokens' rows
+    of the result.  Passes run while held pairs are left — a `lax.scan`
+    over the most there can be whose empty passes a `lax.cond` skips, so
+    the layer stays reverse-differentiable — and consecutive passes meet
+    consecutive experts: the held weights are read about once.  Returns
+    out [T, D]."""
+    t_len, k = top_w.shape
+    held_pairs = load.sum()
+    ends = jnp.cumsum(load)
+    passes = -(-len(order) // rows)
+    pad = passes * rows - len(order)
+    order = jnp.pad(order, (0, pad))
+    flat_w = top_w.reshape(-1)
+    if biases is not None:
+        sorted_e = jnp.pad(sorted_e, (0, pad))
+        biases = [b.astype(x.dtype) for b in biases]
+
+    def one(out, first):
+        def run(out):
+            pair = lax.dynamic_slice(order, (first,), (rows,))
+            # rows past the last held pair belong to no segment: whatever
+            # the grouped matmul leaves there, forward or backward (zeros
+            # on the CPU, stale memory on a TPU), is set to 0 and comes
+            # from and goes to row T, nobody's
+            live = first + jnp.arange(rows) < held_pairs
+            token = jnp.where(live, pair // k, t_len)
+            window = (jnp.clip(ends, first, first + rows)
+                      - jnp.clip(ends - load, first, first + rows))
+
+            def matmul(r, w):
+                return lax.ragged_dot(r, w.astype(r.dtype), window)
+
+            of_rows = None
+            if biases is not None:
+                expert = lax.dynamic_slice(sorted_e, (first,), (rows,))
+                of_rows = [b[expert] for b in biases]
+            ys = expert_ffn(matmul, x.at[token].get(mode="fill",
+                                                    fill_value=0),
+                            weights, of_rows, act, gated)
+            # on the vector unit: a matmul would round the scores to bfloat16
+            ys = (jnp.where(live[:, None], ys, 0)
+                  * flat_w[pair].astype(ys.dtype)[:, None])
+            return out.at[token].add(ys, mode="drop")
+
+        return lax.cond(first < held_pairs, run, lambda out: out, out), None
+
+    out = jnp.zeros((t_len, weights[1].shape[-1]), x.dtype)
+    return lax.scan(one, out, jnp.arange(passes) * rows)[0]
+
+
 def dropless_experts(x, logits, k, weights, biases=None, act="relu",
                      gated=False, normalize=True, score="softmax",
                      select_bias=None, scale=1.0, held=None):
     """`_dropless` (below) of the fewest equal pieces of the tokens whose
-    pairs' rows are within `_PAIR_BYTES` each — all tokens at once where
-    theirs are — one piece after the other (a token's experts do not
+    gathered rows — every pair's, or one pass of the held pairs'
+    (`_pass_rows`) — are within `_PAIR_BYTES` each — all tokens at once
+    where theirs are — one piece after the other (a token's experts do not
     depend on its neighbours): the same numbers, the loads summed."""
     t_len, width = x.shape
-    pair = k * width * x.dtype.itemsize
-    if pair > _PAIR_BYTES:
-        raise ValueError("one token's %d pair rows of %d are %d bytes: no "
-                         "piece is within %d" % (k, width, pair, _PAIR_BYTES))
-    pieces = next(n for n in range(1, max(t_len, 1) + 1) if t_len % n == 0
-                  and t_len // n * pair <= _PAIR_BYTES)
+    pieces, _ = pass_plan(t_len, k, width * x.dtype.itemsize, held,
+                          logits.shape[-1])
     if pieces == 1:
         return _dropless(x, logits, k, weights, biases, act, gated,
                          normalize, score, select_bias, scale, held)
@@ -135,7 +241,11 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
     return to token order weighted by the router score (renormalised
     over the k kept when `normalize`).  Returns (out [T, D], load [E]
     — tokens per expert, float32).  Differentiable in x, probs and the
-    expert parameters.
+    expert parameters.  What is `width` wide — the gathered rows, the
+    three grouped matmuls' outputs, the return to token order — is over
+    all T*k pairs; but under `held`, from `_COMPACT_PAIRS` pairs, over
+    passes of the held pairs alone (`_pass_rows`, `_held_passes`): only
+    the router's top-k, the keys, their sort and `load` stay T*k long.
 
     `score` ``"sigmoid"`` scores each expert on its own (and renormalises
     with 1e-20 under the sum, as the models that route so do);
@@ -173,6 +283,11 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
     order = jnp.argsort(flat_e, stable=True)               # pair -> sorted
     sorted_e = flat_e[order]
     load = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1, mode="drop")
+    rows = _pass_rows(len(order), held, logits.shape[-1])
+    if rows:
+        out = _held_passes(x, order, sorted_e, load, top_w, rows, weights,
+                           biases, act, gated)
+        return out, load.astype(jnp.float32)
     # rows past the last pair lie beyond every segment: no expert multiplies
     # them, nothing reads them, and a gradient that reaches them is cut off
     # with them (`_spare_rows` says why there are any)

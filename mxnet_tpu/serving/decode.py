@@ -501,17 +501,19 @@ class _Bucket:
     `program` (the executable's name in a device trace's ``XLA
     Modules`` line; ``""`` until its first flight has compiled it),
     `hists`, the two histograms its device time goes into, `label`, what
-    the flight recorder says of its flights (``<tenant> <kind>.<bucket>``)
-    and `seen_n` / `seen_s`, how many of its flights were seen and their
-    device seconds: the mean a fence on it is held to.  Built once a
-    bucket program: a call formats no name."""
+    the flight recorder says of its flights (``<tenant> <kind>.<bucket>``),
+    `experts`, a routed model's ``expert_plan`` of the tokens a call
+    computes (None for any other model), and `seen_n` / `seen_s`, how many
+    of its flights were seen and their device seconds: the mean a fence on
+    it is held to.  Built once a bucket program: a call formats no name."""
 
-    __slots__ = ("kind", "bucket", "program", "hists", "label", "seen_n",
-                 "seen_s")
+    __slots__ = ("kind", "bucket", "program", "hists", "label", "experts",
+                 "seen_n", "seen_s")
 
-    def __init__(self, kind, bucket, tenant=""):
+    def __init__(self, kind, bucket, tenant="", experts=None):
         self.kind = kind
         self.bucket = bucket
+        self.experts = experts
         self.program = ""
         hist = "serving.device.%s_seconds" % kind
         self.hists = (hist, "%s.%d" % (hist, bucket))
@@ -787,12 +789,26 @@ class GenerativeSession:
             if exe is None:
                 exe = self._programs[key] = pred.executor_for(
                     self._shapes(batch, seq, prefill))
-                self._buckets[key] = _Bucket(*key, tenant=self.name)
+                self._buckets[key] = _Bucket(
+                    *key, tenant=self.name,
+                    experts=self._expert_plan(batch, seq, prefill))
                 self._unjudged()  # this pass binds, and soon compiles
                 if telemetry.enabled():
                     telemetry.inc("serving.decode.bucket_programs")
             fn = exe.serve_program(self._wire[bool(prefill)])
         return exe, fn
+
+    def _expert_plan(self, batch, seq, prefill):
+        """A routed model's ``expert_plan`` of the tokens ONE call of a
+        bucket program computes: a prefill bucket's positions and, where
+        its program is the mixed step, every slot's row beside them; a
+        decode bucket's rows — a drafting model's rows twice."""
+        if not self._reports_moe_load:
+            return None
+        each = 2 if self._drafts else 1
+        return self._model.expert_plan(
+            seq + each * self._slots * bool(self._mixed) if prefill
+            else each * batch)
 
     def warm(self, buckets=None):
         """Compile-and-run every prefill sequence bucket and decode
@@ -881,7 +897,9 @@ class GenerativeSession:
         logits, self._state, extra = self._call(
             exe, fn, self._state, data, slot, length)
         if self._reports_moe_load:
-            self._book_moe_load(extra[0])
+            with self._prog_lock:
+                key = next(k for k, e in self._programs.items() if e is exe)
+            self._book_moe_load(extra[0], self._buckets[key].experts)
         return logits
 
     def _dispatch(self, exe, fn, data, slot, length, rows, prog, pack,
@@ -948,7 +966,7 @@ class GenerativeSession:
                            hist=hists[1]) as read:
             token, *extra = (_np.asarray(o) for o in flight.outs)
         if self._reports_moe_load:
-            self._book_moe_load(extra[0])
+            self._book_moe_load(extra[0], flight.prog.experts)
         if self._drafts:
             return self._land_drafted(flight, token, extra, wait, read,
                                       hists[2], book)
@@ -1227,17 +1245,32 @@ class GenerativeSession:
                 telemetry.inc(name, n)
 
     @staticmethod
-    def _book_moe_load(load):
+    def _book_moe_load(load, plan):
         """The `moe.*` counters of one program call from its `moe_load
         (layers, experts)` output: token-expert pairs computed (padded
         rows included — the device computed them), experts that got at
         least one token, expert slots offered, and the fullest expert's
-        tokens, each summed over the layers."""
+        tokens, each summed over the layers.  And from the program's
+        `plan` (``expert_plan``: pairs a layer, pieces, a pass's rows) the
+        rows the expert layers gathered, `moe.pair_rows`: every pair's
+        row where a layer gathers them all; where it walks the held pairs
+        alone (`moe.compact_calls`, a layer) a pass's rows times the
+        passes the load filled (`moe.passes`) — a call in pieces as if
+        its held pairs lay evenly over them."""
         if telemetry.enabled():
             telemetry.inc("moe.pairs", int(load.sum()))
             telemetry.inc("moe.experts_hit", int((load > 0).sum()))
             telemetry.inc("moe.expert_slots", int(load.size))
             telemetry.inc("moe.max_load", int(load.max(axis=-1).sum()))
+            pairs, pieces, rows = plan
+            if rows:
+                passes = pieces * int(_np.ceil(
+                    load.sum(axis=-1) / (pieces * rows)).sum())
+                telemetry.inc("moe.compact_calls", len(load))
+                telemetry.inc("moe.passes", passes)
+                telemetry.inc("moe.pair_rows", passes * rows)
+            else:
+                telemetry.inc("moe.pair_rows", pairs * len(load))
 
     # ------------------------------------------------------------------
     # admission: prefill newly-arrived prompts into free slots

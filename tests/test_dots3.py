@@ -514,28 +514,40 @@ def test_many_pairs_go_through_the_experts_in_pieces(monkeypatch):
     assert (np.asarray(load) == np.asarray(load_pieces)).all()
 
 
-def test_every_other_cell_sorts_its_pairs_at_once():
-    """`_PAIR_BYTES` cuts this model's bucket and no other cell's: their
-    largest call's rows (a mixed step's: the longest bucket beside every
-    slot) are within it, so their programs stay what they were."""
+def test_every_cell_sorts_its_pairs_at_once():
+    """`_PAIR_BYTES` cuts no cell's bucket any more (PR 55): every pair's
+    rows of this model's 15,360-position bucket pass it — until PR 55 it
+    went through its experts in ten pieces of its tokens — but a held
+    range gathers a PASS of its held pairs' rows at a time
+    (`parallel.moe.pass_plan`), and that is within it; every other cell's
+    largest call (a mixed step's: the longest bucket beside every slot)
+    was within it as it was."""
     import importlib
 
     from benchmarks.harness import spec
     from mxnet_tpu.parallel import moe
 
     bench = spec.load_benchmark()
-    cut = []
+    cut, plans = [], {}
     for row in bench["workloads"]:
         cell = spec.Cell(bench, row["name"])
         if "tenant" not in cell.traffic:
             continue
         lm = importlib.import_module(
             "benchmarks.families." + cell.config["family"]).model(cell.config)
+        if not lm._routed():
+            continue
         tenant = cell.traffic["tenant"]
         rows = max(tenant["seq_buckets"]) + tenant["max_sessions"]
         if rows * lm.experts_per_token * lm.d_model * 4 > moe._PAIR_BYTES:
             cut.append(row["name"])
+        plans[row["name"]] = lm.expert_plan(rows)[1:]
     assert cut == ["dots3note_longdoc_c8"]
+    assert {pieces for pieces, _ in plans.values()} == {1}
+    # one pass of twelve 512-row tiles: 126 MB of rows, where every
+    # pair's were 2.5 GB
+    assert plans["dots3note_longdoc_c8"] == (1, 6144)
+    assert plans["olmoe_offline"] == (1, 0)
 
 
 # ----------------------------------------------------------------------

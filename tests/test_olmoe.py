@@ -259,7 +259,11 @@ def test_pairs_are_gathered_to_whole_row_tiles(tokens, held, biased,
 
     plain_spare = moe._spare_rows
     # (a held layer's pairs of experts held elsewhere lie beyond every
-    # segment too, and only the forward pass zeroes them: the parent's)
+    # segment too, and only the forward pass zeroes them: the parent's;
+    # since PR 55 this many pairs of a held range go through
+    # `_held_passes` — the next test — so the held case is kept on the
+    # path that gathers every pair's row)
+    monkeypatch.setattr(moe, "_COMPACT_PAIRS", 1 << 30)
     if held is None:
         monkeypatch.setattr(jax.lax, "ragged_dot", stale)
     gathered = both()
@@ -277,6 +281,139 @@ def test_pairs_are_gathered_to_whole_row_tiles(tokens, held, biased,
                     jax.tree_util.tree_leaves(plain[1][1:])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                    atol=1e-5)
+
+
+# The held pairs alone (PR 55), at a row tile of 8 and a threshold of 16 so
+# that the shapes stay tiny: 16 experts scored, experts 2-5 held, four a
+# token — a pass takes ceil(1.5 x pairs / 4 / 8) x 8 sorted rows.  A case:
+# (tokens, tokens whose four are ALL held, tokens with ONE held pair beside
+# them, passes the held pairs fill); None draws the routing from noise.
+HELD_PASSES = {"near uniform": (40, None, None, 1),
+               "with biases": (40, 16, 1, 2),
+               "every pair held": (40, 40, 0, 3),
+               "no pair held": (40, 0, 0, 0),
+               "exactly one pass": (40, 16, 0, 1),
+               "one pass and one pair": (40, 16, 1, 2),
+               "a mixed step's odd count": (37 + 3, 5, 7, 1),
+               "in pieces before": (48, None, None, 1)}
+
+
+@pytest.mark.parametrize("name", sorted(HELD_PASSES))
+def test_a_held_range_walks_the_pair_rows_it_holds(name, monkeypatch):
+    """PR 55: under a held range `_dropless` sorts all T x k pairs (the
+    narrow work) and gathers, multiplies and returns to token order the
+    HELD pairs alone, in passes of a static count of sorted rows, as many
+    as the load fills — none dropped, whatever the routing.  Against the
+    path that gathers every pair's row (forced by the threshold): the
+    same output to float32 rounding (a token's pairs are summed in
+    another order), the same load exactly, the same gradient in x, the
+    router's scores and the expert weights — with a grouped matmul that
+    leaves NaN beyond its segments, forward and backward, as a TPU's
+    leaves stale memory."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.parallel import moe
+
+    tokens, all_held, one_held, passes = HELD_PASSES[name]
+    D, H, E, k, held = 16, 8, 16, 4, (2, 4)
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    monkeypatch.setattr(moe, "_COMPACT_PAIRS", 16)
+    rows = moe._pass_rows(tokens * k, held, E)
+    assert rows == {40: 64, 48: 72}[tokens]
+    rng = np.random.default_rng(5)
+    logits = rng.normal(size=(tokens, E)).astype(np.float32)
+    if all_held is not None:
+        # the four largest: held experts for the first `all_held` tokens,
+        # one held and three others for the next `one_held`, others after
+        logits[:, 2:6] -= 20
+        logits[:all_held, 2:6] += 40
+        logits[all_held:all_held + one_held, 3] += 40
+    x = jnp.asarray(rng.normal(size=(tokens, D)).astype(np.float32))
+    logits = jnp.asarray(logits)
+    weights = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                    for shape in ((4, D, H), (4, H, D), (4, D, H)))
+    biases = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                   for shape in ((4, H), (4, D), (4, H))
+                   ) if name == "with biases" else None
+    dot = jax.lax.ragged_dot
+    calls = []
+
+    def beyond(r, groups, fill):
+        return jnp.where((jnp.arange(r.shape[0]) < groups.sum())[:, None],
+                         r, fill)
+
+    @jax.custom_vjp
+    def stale(lhs, rhs, groups):
+        calls.append(lhs.shape[0])
+        return beyond(dot(beyond(lhs, groups, 0), rhs, groups), groups,
+                      jnp.nan)
+
+    def backward(saved, g):
+        lhs, rhs, groups = saved
+        d_lhs, d_rhs = jax.vjp(lambda a, b: dot(a, b, groups),
+                               beyond(lhs, groups, 0), rhs)[1](
+                                   beyond(g, groups, 0))
+        return beyond(d_lhs, groups, jnp.nan), d_rhs, None
+
+    stale.defvjp(lambda *a: (stale(*a), a), backward)
+
+    def layer(x, logits, weights, biases):
+        return moe.dropless_experts(x, logits, k, weights, biases,
+                                    act="silu", gated=True, held=held)
+
+    def both():
+        return layer(x, logits, weights, biases), jax.grad(
+            lambda *args: (layer(*args)[0] ** 2).sum(), (0, 1, 2, 3))(
+                x, logits, weights, biases)
+
+    pieces = []
+    whole = moe._dropless
+    monkeypatch.setattr(moe, "_dropless", lambda x, *a: (
+        pieces.append(x.shape[0]), whole(x, *a))[1])
+    if name == "in pieces before":
+        # every pair's 192 rows pass it, a pass's 72 do not: two pieces of
+        # the tokens at the parent, one now
+        monkeypatch.setattr(moe, "_PAIR_BYTES", 100 * D * 4)
+    monkeypatch.setattr(jax.lax, "ragged_dot", stale)
+    (out, load), grads = both()
+    assert set(calls) == {rows} and pieces == [tokens, tokens]
+    monkeypatch.setattr(jax.lax, "ragged_dot", dot)
+    monkeypatch.setattr(moe, "_COMPACT_PAIRS", 1 << 30)
+    (want, want_load), want_grads = both()
+    if name == "in pieces before":
+        assert pieces[2:] == [24] * 2   # lax.map traces a piece once a call
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    assert -(-int(load.sum()) // rows) == passes
+    if all_held is not None:
+        assert int(load.sum()) == k * all_held + one_held
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    assert passes == 0 or float(jnp.abs(want).max()) > 0.1
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        scale = max(float(jnp.abs(b).max()), 1.0)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4,
+                                   atol=1e-5 * scale)
+
+
+def test_a_pass_past_the_pair_bytes_still_goes_in_pieces(monkeypatch):
+    """`dropless_experts` reckons what a call GATHERS: where one pass's
+    rows pass `_PAIR_BYTES` the tokens are cut again, each piece walking
+    its own held pairs."""
+    from mxnet_tpu.parallel import moe
+
+    monkeypatch.setattr(moe, "_ROW_TILE", 8)
+    monkeypatch.setattr(moe, "_COMPACT_PAIRS", 16)
+    row = 16 * 4
+    assert moe.pass_plan(48, 4, row, (2, 4), 16) == (1, 72)
+    monkeypatch.setattr(moe, "_PAIR_BYTES", 71 * row)
+    assert moe.pass_plan(48, 4, row, (2, 4), 16) == (2, 40)
+    # no held range, or few pairs: every pair's row, as before
+    assert moe.pass_plan(48, 4, row, None, 16) == (3, 0)   # 64 <= 71 rows
+    assert moe.pass_plan(3, 4, row, (2, 4), 16) == (1, 0)
+    with pytest.raises(ValueError, match="no piece"):
+        moe.pass_plan(48, 4, 18 * row, (2, 4), 16)
 
 
 def test_training_loss_and_gradients_match_reference(params, held):
@@ -387,11 +524,61 @@ def test_batcher_books_the_moe_counters(held):
     after = telemetry.snapshot()["counters"]
     moved = {k: after.get(k, 0) - before.get(k, 0)
              for k in ("moe.pairs", "moe.experts_hit", "moe.expert_slots",
-                       "moe.max_load")}
+                       "moe.max_load", "moe.pair_rows", "moe.passes",
+                       "moe.compact_calls")}
     layers, k = CONFIG["num_hidden_layers"], CONFIG["num_experts_per_tok"]
     # one mixed step of 16 positions and its two idle rows, two decode
     # steps of one row
     assert moved["moe.pairs"] == layers * k * (16 + 2 + 1 + 1)
+    # every expert is here: a layer gathers every pair's row, in no pass
+    assert moved["moe.pair_rows"] == moved["moe.pairs"]
+    assert moved["moe.passes"] == moved["moe.compact_calls"] == 0
     assert moved["moe.expert_slots"] == 3 * layers * CONFIG["num_experts"]
     assert 0 < moved["moe.experts_hit"] <= moved["moe.expert_slots"]
     assert moved["moe.max_load"] >= 3 * layers
+
+
+# (pairs a layer, pieces, a pass's rows) of a program, its `moe_load` summed
+# an expert-layer -> rows gathered, passes, layers that walked held pairs
+BOOKED = {"one pass a layer": ((5120, 1, 1024), [640, 1024], 2048, 2, 2),
+          "three passes": ((5120, 1, 1024), [2049, 0], 3072, 3, 2),
+          "every pair's row": ((80, 1, 0), [10, 7], 160, 0, 0),
+          "two pieces": ((122880, 2, 3072), [3840, 6145], 18432, 6, 2)}
+
+
+@pytest.mark.parametrize("name", sorted(BOOKED))
+def test_the_rows_an_expert_layer_gathered_are_booked_from_its_load(name):
+    """`moe.pair_rows` / `moe.passes` / `moe.compact_calls` (PR 55) come
+    from what the program returns anyway, its `moe_load`, and the static
+    plan of its bucket (`TransformerLM.expert_plan`): where a layer walks
+    the held pairs alone, the passes its load filled times a pass's rows
+    (no pass for a layer that held no pair; a call in pieces as if its
+    pairs lay evenly over them); every pair's row where it does not."""
+    plan, held_pairs, rows, passes, compact = BOOKED[name]
+    load = np.zeros((len(held_pairs), 9), np.int64)
+    load[:, 0] = [n // 2 for n in held_pairs]
+    load[:, 4] = [n - n // 2 for n in held_pairs]
+    telemetry.set_enabled(True)
+    names = ("moe.pairs", "moe.pair_rows", "moe.passes", "moe.compact_calls")
+    before = dict(telemetry.snapshot()["counters"])
+    GenerativeSession._book_moe_load(load, plan)
+    after = telemetry.snapshot()["counters"]
+    assert [after.get(k, 0) - before.get(k, 0) for k in names] == [
+        sum(held_pairs), rows, passes, compact]
+
+
+def test_a_programs_expert_plan_follows_its_tokens():
+    """Granite-H-Small's shape of the question — nine of 72 experts held,
+    ten a token: a 512 bucket walks passes of 1,024 sorted rows, a 128
+    bucket of 512, an 8-row decode step gathers its 80 pairs' rows; with
+    every expert held nothing is walked in passes."""
+    lm = TransformerLM(vocab=32, num_layers=1, num_heads=2, d_model=64,
+                       max_len=32, ffn_types=["routed"], num_experts=72,
+                       experts_per_token=10, expert_d_ff=16,
+                       held_experts=(0, 9))
+    assert lm.expert_plan(512) == (5120, 1, 1024)
+    assert lm.expert_plan(1024) == (10240, 1, 2048)
+    assert lm.expert_plan(128) == (1280, 1, 512)
+    assert lm.expert_plan(8) == (80, 1, 0)
+    assert family.model(CONFIG).expert_plan(520) == (1040, 1, 0)
+

@@ -159,13 +159,28 @@ def test_a_one_row_product_is_not_the_mxus(rows, on_the_mxu, one_chip):
 # (tokens, k, experts scored, experts held, d_model, d_expert) -> the row
 # tile the grouped matmul should walk: a mixed step's bucket + rows tokens
 # at the cells' widths (OLMoE's four programs; Qwen3-Next's and Trinity's
-# longest, a quarter and a half of their experts held)
+# longest, a quarter and a half of their experts held; Granite-H-Small's 512
+# bucket and its 8-row decode step, nine of 72 held)
 PAIR_TILES = {"olmoe 64": ((72, 8, 64, 64, 2048, 1024), 64),
               "olmoe 128": ((136, 8, 64, 64, 2048, 1024), 64),
               "olmoe 256": ((264, 8, 64, 64, 2048, 1024), 512),
               "olmoe 512": ((520, 8, 64, 64, 2048, 1024), 512),
               "qwen3-next 2048": ((2064, 10, 512, 128, 2048, 512), 512),
-              "trinity 2048": ((2056, 8, 128, 64, 2048, 1024), 512)}
+              "trinity 2048": ((2056, 8, 128, 64, 2048, 1024), 512),
+              "granite-h-small 512": ((512, 10, 72, 9, 4096, 768), 512),
+              "granite-h-small step": ((8, 10, 72, 9, 4096, 768), 16)}
+# the rows a pass of the held pairs walks (PR 55); 0: every pair's row
+PASS_ROWS = {"qwen3-next 2048": 8192, "trinity 2048": 12800,
+             "granite-h-small 512": 1024}
+
+
+def _ragged_dots(text):
+    """(rows, row tile) of every compiled `ragged-dot` in `text`."""
+    import re
+
+    return re.findall(
+        r"= f32\[(\d+),\d+\]\S* custom-call\([^)]*\)[^\n]*?"
+        r'ragged_dot_tiling="(\d+),', text)
 
 
 @pytest.mark.parametrize("name", sorted(PAIR_TILES))
@@ -178,7 +193,11 @@ def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
     tiles + groups - 1 entries — so that a compiler that chooses otherwise
     fails here and not silently on the chip: two dozen pairs an expert or
     more walk whole 512-row tiles, fewer keep the parent's count and its
-    small tile."""
+    small tile.  A held range's long call (PR 55) walks passes of its HELD
+    pairs — whole 512-row tiles of them, inside the one loop of
+    `_held_passes` — and no array of every pair's row is left; without a
+    held range, and in a decode step, the layer is the straight line it
+    was: no loop, no conditional."""
     import re
 
     import jax
@@ -187,29 +206,35 @@ def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
     from mxnet_tpu.parallel import moe
 
     (tokens, k, scored, held, d_model, d_expert), tile = PAIR_TILES[name]
+    held_range = None if held == scored else (0, held)
 
     def arg(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
 
     def layer(x, logits, *weights):
         return moe.dropless_experts(
-            x, logits, k, weights, act="silu", gated=True,
-            held=None if held == scored else (0, held))
+            x, logits, k, weights, act="silu", gated=True, held=held_range)
 
     text = jax.jit(layer).lower(
         arg(tokens, d_model), arg(tokens, scored),
         arg(held, d_model, d_expert), arg(held, d_expert, d_model),
         arg(held, d_model, d_expert)).compile().as_text()
-    rows = tokens * k + moe._spare_rows(tokens * k, scored)
+    passed = moe._pass_rows(tokens * k, held_range, scored)
+    assert passed == PASS_ROWS.get(name, 0)
+    rows = passed or tokens * k + moe._spare_rows(tokens * k, scored)
     assert rows % tile == 0 and (tile == 512 or rows == tokens * k)
-    dots = re.findall(
-        r"= f32\[(\d+),\d+\]\S* custom-call\([^)]*\)[^\n]*?"
-        r'ragged_dot_tiling="(\d+),', text)
+    dots = _ragged_dots(text)
     assert len(dots) == 3, name
     assert set(dots) == {(str(rows), str(tile))}
     entries = set(re.findall(r"%ragged-dot-metadata = \(s32\[\d+\]\S*, "
                              r"s32\[(\d+)\]", text))
     assert entries == {str(rows // tile + held - 1)}
+    loops = len(re.findall(r" (?:while|conditional)\(", text))
+    every_pair = re.findall(r"f32\[%d,%d\]" % (tokens * k, d_model), text)
+    if passed:
+        assert loops == 2 and every_pair == []
+    else:
+        assert loops == 0 and every_pair
 
 
 # (key heads, value heads, d_k, d_v, heads a step of the kernel's walk)
@@ -706,8 +731,12 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
     compiler's fast memory, one write-out each); the weights
     are the 8.22 GB the configuration's `reduced_why` reckons; weights,
     the tenant's nine bound cache sets and the larger program's
-    temporaries fit a v5e."""
+    temporaries fit a v5e.  The expert layers (PR 55): the step gathers
+    its 80 pairs' rows as it did; the 1,024 bucket walks ONE pass's 2,048
+    sorted rows of its held pairs by the 512-row tile and keeps no array
+    of all 10,240 pairs' rows of 4,096."""
     import json
+    import re
     import warnings
 
     from benchmarks.families import granite_moe_hybrid as family
@@ -753,5 +782,10 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
     # a v5e's 16.9e9 bytes hold the weights, nine sets, the program
     assert weights + 9 * sets + stats.temp_size_in_bytes < 16.5e9, (
         weights, sets, stats.temp_size_in_bytes)
+    dots = set(_ragged_dots(text))
     if program == "decode":
         assert stats.temp_size_in_bytes < 0.3e9
+        assert dots == {("80", "16")}
+    else:
+        assert dots == {("2048", "512")}
+        assert not re.search(r"f32\[10240,4096\]", text)
