@@ -622,9 +622,10 @@ def delta_head_shapes(lm):
     """`delta_rule_hlo_facts`' `head_shapes` of a model: its delta-rule
     layers' key heads and value heads of a key's width, and its value
     heads of a value's."""
-    return [(lm.linear_key_heads, lm.linear_key_dim),
-            (lm.linear_heads, lm.linear_key_dim),
-            (lm.linear_heads, lm.linear_value_dim)]
+    own = lm.kind_specs["linear_attention"]
+    heads, key_dim = own["heads"], own["key_dim"]
+    return [(own.get("key_heads") or heads, key_dim), (heads, key_dim),
+            (heads, own["value_dim"])]
 
 
 def score_arrays(text, bucket):

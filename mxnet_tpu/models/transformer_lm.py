@@ -60,9 +60,9 @@ the block before the FFN — ``"attention"`` (the default for every layer),
 ``"window_attention"`` (attention over a sliding window of
 `sliding_window` positions), ``"mamba"`` (a Mamba-2 state-space mixer,
 ops/ssm.py), ``"linear_attention"`` (a Gated DeltaNet delta-rule mixer,
-ops/gdn.py: `linear_heads` value heads, one state each, over
-`linear_key_heads` heads of q and k — as many unless the spec says fewer,
-each then read by a group of value heads) or ``"latent_attention"``
+ops/gdn.py: `heads` value heads, one state each, over `key_heads` heads of
+q and k — as many unless the spec says fewer, each then read by a group of
+value heads) or ``"latent_attention"``
 (multi-head latent attention, ops/latent.py: a low-rank query, ONE cached
 row a position for all heads — the normed joint down-projection and a
 rotary key of its own projection — which the full-sequence forms
@@ -70,12 +70,12 @@ up-project per head and the decode step absorbs into the query).  A kind
 is one class below
 (:class:`_Attention`, :class:`_WindowAttention`, :class:`_Mamba2`,
 :class:`_GatedDeltaNet`, :class:`_LatentAttention`) that
-declares, in that one place, its parameters, its full-sequence forward,
-its prefill, its decode step, the two in one (`mixed`: attention, window
-attention and Gated DeltaNet have one), the device-resident state it keeps between
-calls and the counters a program call adds to; the five graph builders
-walk the pattern and know no kind by name.  `ffn_types` names each
-layer's FFN — the other half — the same way: ``"dense"``
+declares, in that one place, its sizes, its parameters, its full-sequence
+forward, its prefill, its decode step, the two in one (`mixed`: attention,
+window attention and Gated DeltaNet have one), the device-resident state it
+keeps between calls and the counters a program call adds to; the five
+graph builders walk the pattern and know no kind by name.  `ffn_types`
+names each layer's FFN — the other half — the same way: ``"dense"``
 (:class:`_DenseFFN`, of width `d_ff`) or ``"routed"``
 (:class:`_RoutedFFN`: ``mx.sym.MoE`` experts of width `expert_d_ff`, a
 shared expert, a held range); by default every layer's is the one
@@ -99,16 +99,16 @@ one ``"attention"``, ``ffn_types`` of leading ``"dense"`` layers before
 Trinity's (`afmoe`), held as one chip's share of its experts.
 ``layer_types`` of three ``"linear_attention"`` to one ``"attention"`` with
 ``ffn_types`` all ``"routed"`` (a recurrent mixer and an expert FFN in ONE
-block), ``linear_key_heads`` under twice as many ``linear_heads`` with
-``linear_neg_eigval=False``, ``head_dim=256`` with ``qk_norm="head"``,
+block), the delta rule's `key_heads` under twice as many `heads` with
+``neg_eigval=False``, ``head_dim=256`` with ``qk_norm="head"``,
 ``out_gate`` and ``rotary_dim`` (the first quarter of each head turned),
 softmax ``route_norm`` experts of `expert_d_ff` with a ``shared_gate`` on
 the shared expert and ``held_experts`` are Qwen3-Next's (`qwen3_next`);
 its norm gains are stored as they are applied, ``1 + w`` of the published
 ``w``.
-``layer_types`` all ``"latent_attention"`` with `latent_q_rank`,
-`latent_kv_rank`, `latent_nope_dim` + `latent_rope_dim` = `latent_value_dim`,
-``rope_scaling`` (YaRN's blended frequencies on the rotary part alone),
+``layer_types`` all ``"latent_attention"`` with the kind's `q_rank`,
+`kv_rank`, `nope_dim` + `rope_dim` = `value_dim`, ``rope_scaling`` (YaRN's
+blended frequencies on the rotary part alone),
 ``attention_multiplier`` (the softmax scale with YaRN's ``mscale`` square),
 ``query_scale`` (the query grows with the logarithm of its position past
 the trained length), and softmax ``route_norm`` experts of `expert_d_ff`
@@ -143,11 +143,15 @@ slots + 1)`` — token, draft, position: :meth:`token_state` — the
 (:meth:`decode_symbol`).  No mixed step; the training graph leaves the
 module out (there it is one more loss term).
 
-**Per-kind sizes.**  The flat arguments above give a model ONE geometry a
-mechanism.  `kind_specs` ``{kind: {size: value}}`` is the seam for a model
-whose layer kinds differ in them (ROADMAP D13, begun with the two kinds
-that need it): a kind's class reads its own mapping and nothing else of
-the spec but `d_model`, the norm and the FFN.
+**Per-kind sizes.**  A mixer kind that has sizes of its own OWNS them:
+its class declares them (`SIZES`, `OPTIONS` with their defaults), reads
+``kind_specs[kind]`` ``{size: value}`` and validates where it is built
+(`_own_sizes`) — the constructor knows none of them.  The three kinds older
+than `kind_specs` are also spelt flat, ``<prefix><size>`` (``mamba_heads``,
+``linear_key_dim``, ``latent_q_rank``: `_FLAT`), which IS
+``kind_specs[kind][size]``.  The model-wide arguments above stay the
+model's; the latent kinds that differ in heads or rotary base say so in
+their own mapping.
 
 **Cache spec.**  :meth:`TransformerLM.cache_spec` is the ONE statement of
 what a serving session holds on the device between calls: an ordered
@@ -157,7 +161,7 @@ length, so stale contents are harmless; A RING'S LENGTH IS ITS KIND'S,
 the last axis of its shape: the session's ``max_len`` for a full layer,
 ``min(sliding_window, max_len)`` for a window layer, whose ring a longer
 session writes modulo), a latent-attention layer's ONE latent ring (kind
-``"latent"``, ``(slots, 1, latent_kv_rank + latent_rope_dim, max_len)``:
+``"latent"``, ``(slots, 1, kv_rank + rope_dim, max_len)``:
 addressed, masked and counted like the rings, with no second ring beside
 it and no per-head K or V anywhere; a window latent layer's is
 ``min(window, max_len)`` long and written modulo), a sparse latent layer's
@@ -243,6 +247,39 @@ def _prefill_counters(positions, width, heads, kv_heads, platform):
                                      platform) is not None
     return {"attn.prefill_positions": positions,
             "attn.kernel_positions": positions * tiled}
+
+
+# how callers from before `kind_specs` spell three kinds' sizes: a keyword
+# ``<prefix><size>`` of the constructor IS ``kind_specs[kind][size]``
+_FLAT = {"mamba": "mamba_", "linear_attention": "linear_",
+         "latent_attention": "latent_"}
+
+
+def _own_sizes(mixer, lm):
+    """What a kind with sizes reads of the spec, ``lm.kind_specs[KIND]``
+    and nothing else of them, as ``{name: value}``: each of the kind's
+    `SIZES` an int >= 1, its `OPTIONS` ``{name: default}`` as the mapping
+    sets them (a value of its default's type), None "not set", no
+    other key, and the kind's own `_fits` of them — `NEEDS` in words —
+    true.  ValueError otherwise, naming the kind and its sizes as its
+    callers spell them."""
+    spec = dict(lm.kind_specs.get(mixer.KIND) or {})
+    given = {k: v for k, v in spec.items() if v is not None}
+    own = {k: int(given.get(k, 0)) for k in mixer.SIZES}
+    for k, default in mixer.OPTIONS.items():
+        value = given.get(k, default)
+        own[k] = type(default)(value)
+    if (set(spec) - set(own) or min(own[k] for k in mixer.SIZES) < 1
+            or not mixer._fits(own)):
+        flat = _FLAT.get(mixer.KIND, "")
+        raise ValueError(
+            "a %r layer needs %s >= 1, %s; it may set %s; kind_specs[%r] is "
+            "%r" % (mixer.KIND, ", ".join(flat + k for k in mixer.SIZES),
+                    mixer.NEEDS,
+                    ", ".join(flat + k for k in sorted(mixer.OPTIONS))
+                    or "nothing else",
+                    mixer.KIND, spec))
+    return own
 
 
 class _Attention:
@@ -472,205 +509,53 @@ class _WindowAttention(_Attention):
             "cache.window_bytes": pages * page})
 
 
-class _LatentAttention:
-    """The latent-attention mixer (MLA) of layer i (ops/latent.py has the
-    equations): a low-rank query ``c_q = norm(x W_qa)``, ``q = c_q W_qb``
-    of `num_heads` heads of ``[q_nope | q_rope]``; a joint down-projection
-    ``[c_kv | k_r] = x W_kva`` with a norm on ``c_kv`` alone and ONE
-    rotary key ``k_r`` for all heads; the per-head up-projection `W_kvb`
-    ``(H * (nope + value), kv_rank)``, which the full-sequence forms apply
-    and the decode step absorbs; rotary (the spec's `rope_scaling`: YaRN)
-    on ``q_rope`` and ``k_r`` only; softmax scale `attention_multiplier`;
-    output projection; no bias anywhere.  `W_qb`'s rows lie BY KIND, all
-    heads' ``q_nope`` then all heads' ``q_rope``, and a rotary part's
-    channels in the rotate-half order (a checkpoint that interleaves the
-    pairs is permuted once, on loading).  State: ONE latent ring a layer,
-    ``(slots, 1, kv_rank + rope, max_len)`` — a row a position for all
-    heads, no per-head K or V anywhere."""
-
-    KIND = "latent_attention"
-
-    def __init__(self, lm):
-        self.lm = lm
-        self.nope, self.rope = lm.latent_nope_dim, lm.latent_rope_dim
-        self.value, self.rank = lm.latent_value_dim, lm.latent_kv_rank
-        self.width = self.rank + self.rope
-        # an option appears on a node only when the spec sets it
-        self.rope_attrs = dict(theta=lm.rope_theta)
-        scaling = lm.rope_scaling
-        if scaling is not None:
-            self.rope_attrs["yarn"] = tuple(
-                float(scaling[k]) for k in (
-                    "factor", "original_max_position_embeddings",
-                    "beta_fast", "beta_slow"))
-            # YaRN's attention factor: the ratio of the two mscales
-            grow = 0.1 * math.log(float(scaling["factor"]))
-            factor = ((grow * float(scaling.get("mscale", 1.0)) + 1.0)
-                      / (grow * float(scaling.get("mscale_all_dim", 0.0))
-                         + 1.0))
-            if factor != 1.0:
-                self.rope_attrs["rope_scale"] = factor
-        self.attrs = dict(num_heads=lm.num_heads, rope_dim=self.rope,
-                          value_dim=self.value)
-        if lm.attention_multiplier is not None:
-            self.attrs["scale"] = lm.attention_multiplier
-        if lm.query_scale is not None:
-            self.attrs["query_scale"] = lm.query_scale
-
-    def params(self, i):
-        lm, v = self.lm, sym.Variable
-        d, h = lm.d_model, lm.num_heads
-        return {
-            "qa_weight": v("l%d_qa_weight" % i, shape=(lm.latent_q_rank, d)),
-            "qb_weight": v("l%d_qb_weight" % i,
-                           shape=(h * (self.nope + self.rope),
-                                  lm.latent_q_rank)),
-            "kva_weight": v("l%d_kva_weight" % i, shape=(self.width, d)),
-            "kvb_weight": v("l%d_kvb_weight" % i,
-                            shape=(h * (self.nope + self.value), self.rank)),
-            "out_weight": v("l%d_out_weight" % i,
-                            shape=(d, h * self.value))}
-
-    def cache_spec(self, i, slots, max_len):
-        """ONE entry: ``(slots, 1, kv_rank + rope, max_len)``, kind
-        ``"latent"`` — the positions on the minor axis like every ring,
-        one head of `width` lines that every query head reads."""
-        return [("latent_cache_%d" % i, CacheEntry(
-            "latent", (int(slots), 1, self.width, int(max_len))))]
-
-    def _fc(self, x, p, key, width, name):
-        return sym.FullyConnected(x, weight=p[key + "_weight"],
-                                  num_hidden=width, no_bias=True,
-                                  flatten=False, name=name)
-
-    def _project(self, x, p, i, index=None):
-        """``(q_nope, q_rope, latent)`` of the normed stream: the rotary
-        parts turned — each row's own `index` in a decode step, 0..T-1
-        without one — and ``latent = [norm(c_kv) | k_r]``, the row the
-        ring keeps."""
-        lm, h = self.lm, self.lm.num_heads
-        c_q = lm._norm(self._fc(x, p, "qa", lm.latent_q_rank, "l%d_qa" % i),
-                       "l%d_qa_norm" % i, width=lm.latent_q_rank)
-        q = self._fc(c_q, p, "qb", h * (self.nope + self.rope), "l%d_qb" % i)
-        kva = self._fc(x, p, "kva", self.width, "l%d_kva" % i)
-
-        def part(t, name, begin, end):
-            return sym.slice_axis(t, axis=2, begin=begin, end=end,
-                                  name="l%d_%s" % (i, name))
-
-        q_nope = part(q, "q_nope", 0, h * self.nope)
-        q_rope = part(q, "q_rope", h * self.nope, h * (self.nope + self.rope))
-        c = lm._norm(part(kva, "c_kv", 0, self.rank), "l%d_kva_norm" % i,
-                     width=self.rank)
-        k_r = part(kva, "k_r", self.rank, self.width)
-
-        def turn(t, n, heads):
-            attrs = dict(self.rope_attrs, num_heads=heads,
-                         name="l%d_%srope" % (i, n))
-            return (sym._rotary(t, **attrs) if index is None
-                    else sym._rotary_at(t, index, **attrs))
-
-        q_rope, k_r = turn(q_rope, "q", h), turn(k_r, "k", 1)
-        return q_nope, q_rope, sym.Concat(c, k_r, dim=2,
-                                          name="l%d_latent" % i)
-
-    def _out(self, ctx, p, i):
-        return self._fc(ctx, p, "out", self.lm.d_model, "l%d_proj" % i)
-
-    def _expanded(self, x, p, i):
-        q_nope, q_rope, latent = self._project(x, p, i)
-        ctx = sym._latent_attention(q_nope, q_rope, latent, p["kvb_weight"],
-                                    name="l%d_attn" % i, **self.attrs)
-        return self._out(ctx, p, i), latent
-
-    def full(self, x, p, i):
-        return self._expanded(x, p, i)[0]
-
-    def prefill(self, x, p, i, caches, slot, length):
-        y, latent = self._expanded(x, p, i)
-        return y, [sym._latent_cache_write(
-            caches["latent_cache_%d" % i], latent, slot,
-            name="l%d_latent_write" % i)]
-
-    def decode(self, x, p, i, caches, slot, length):
-        q_nope, q_rope, latent = self._project(x, p, i, index=length)
-        step = sym._latent_cached_attention(
-            q_nope, q_rope, latent, p["kvb_weight"],
-            caches["latent_cache_%d" % i], slot, length,
-            name="l%d_attn" % i, **self.attrs)
-        return self._out(step[0], p, i), [step[1]]
-
-    def counters(self, i, positions=0, rows=0, lengths=(), computed=0,
-                 pages=0, max_len=None, platform=None, **call):
-        """What a prefill of a bucket of `positions` adds: those
-        positions, attended among themselves up-projected, and those of
-        them the TPU's blockwise kernel takes (`_prefill_counters`).
-        What one decode step (a call of `computed` program rows) adds:
-        a latent layer-step, and one served by the TPU's kernel where a
-        program lowered for `platform` has it; the bytes of this layer's
-        pages the step reads — by the kernel's blocks, up to the one that
-        holds each row's `length`, or whole pages where the ``jax.numpy``
-        body runs; and the bytes of this layer's ring among the `pages`
-        pages bound."""
-        _, entry = self.cache_spec(
-            i, 1, self.lm.max_len if max_len is None else max_len)[0]
-        ring = entry.shape[3]
-        block = _attention.decode_block(entry.shape, platform, latent=True)
-        at_a_time = block or ring
-        read = sum(min((n // at_a_time + 1) * at_a_time, ring)
-                   for n in lengths)
-        step = int(computed > 0)
-        heads = self.lm.num_heads
-        return dict(
-            _prefill_counters(positions, heads * (self.nope + self.rope),
-                              heads, heads, platform),
-            **{"mla.layer_steps": step,
-               "mla.kernel_steps": step * (block is not None),
-               "mla.ring_bytes": 4 * self.width * read,
-               "cache.latent_bytes": pages * entry.nbytes})
-
-
 class _KindLatent:
-    """What the two latent kinds that read their sizes from the spec's
-    `kind_specs` share (ops/sparse_latent.py has the equations): latent
-    attention as :class:`_LatentAttention` computes it, with — all of the
-    KIND'S OWN, ``kind_specs[KIND]`` — `num_heads` heads, a query of rank
-    `q_rank`, ONE cached row of ``kv_rank + rope_dim`` a position, a head's
-    key ``nope_dim + rope_dim`` wide beside a value of `value_dim` (the two
-    need not agree), rotary base `rope_theta`; `lora_rescale` multiplies
-    the normed latents by ``sqrt(d_model / rank)`` of their own rank;
-    `head_gate` multiplies each head's context by ``sigmoid(x w_h)``, ONE
-    scalar a head, ``l<i>_hgate_weight (num_heads, d_model)``, before the
-    output projection; the softmax scale is ``(nope_dim + rope_dim)^
-    -1/2``.  No bias anywhere; `W_qb`'s rows by kind, rotary channels in
-    rotate-half order, as :class:`_LatentAttention`'s.  The whole-sequence
-    forms hand the query LATENT to one op node that up-projects a group of
-    heads at a time; the decode step projects the queries and absorbs."""
+    """What the three latent kinds share (ops/latent.py and
+    ops/sparse_latent.py have the equations), all of the KIND'S OWN,
+    ``kind_specs[KIND]``: `num_heads` heads; a low-rank query ``c_q =
+    norm(x W_qa)`` of rank `q_rank`, ``q = c_q W_qb`` of heads of
+    ``[q_nope | q_rope]``; a joint down-projection ``[c_kv | k_r] = x
+    W_kva`` with a norm on ``c_kv`` alone and ONE rotary key ``k_r`` for
+    all heads — ONE cached row of ``kv_rank + rope_dim`` a position; the
+    per-head up-projection `W_kvb` ``(H * (nope_dim + value_dim),
+    kv_rank)``: a head's key ``nope_dim + rope_dim`` wide beside a value
+    of `value_dim` (the two need not agree), rotary base `rope_theta`;
+    `lora_rescale` multiplies the normed latents by ``sqrt(d_model /
+    rank)`` of their own rank; `head_gate` multiplies each head's context
+    by ``sigmoid(x w_h)``, ONE scalar a head, ``l<i>_hgate_weight
+    (num_heads, d_model)``, before the output projection; the softmax
+    scale is ``(nope_dim + rope_dim)^-1/2``.  No bias anywhere; `W_qb`'s
+    rows lie BY KIND, all heads' ``q_nope`` then all heads' ``q_rope``,
+    and a rotary part's channels in the rotate-half order (a checkpoint
+    that interleaves the pairs is permuted once, on loading).  State: ONE
+    latent ring a layer, ``(slots, 1, kv_rank + rope_dim, max_len)`` — a
+    row a position for all heads, no per-head K or V anywhere.  The two
+    kinds under a mask hand a whole sequence's query LATENT to one op node
+    that up-projects a group of heads at a time (`_masked_operands`);
+    every decode step projects the queries and absorbs."""
 
     SIZES = ("num_heads", "q_rank", "kv_rank", "nope_dim", "rope_dim",
              "value_dim")
     OPTIONS = {"rope_theta": 10000.0, "lora_rescale": False,
                "head_gate": False}
+    NEEDS = "rope_dim even"
 
     def __init__(self, lm):
         self.lm = lm
-        spec = dict(lm.kind_specs.get(self.KIND) or {})
-        unknown = set(spec) - set(self.SIZES) - set(self.OPTIONS)
-        sizes = [int(spec.get(k, 0)) for k in self.SIZES]
-        if unknown or min(sizes) < 1 or sizes[4] % 2:
-            raise ValueError(
-                "kind_specs[%r] needs %s >= 1 (rope_dim even) and may set "
-                "%s, got %r" % (self.KIND, ", ".join(self.SIZES),
-                                ", ".join(sorted(self.OPTIONS)), spec))
-        (self.heads, self.q_rank, self.rank, self.nope, self.rope,
-         self.value) = sizes[:6]
-        self.own = sizes[6:]      # a kind's further sizes, in its order
-        for k, default in self.OPTIONS.items():
-            setattr(self, k, spec.get(k, default))
+        self.sizes = own = _own_sizes(self, lm)
+        self.heads = own.get("num_heads") or lm.num_heads
+        (self.q_rank, self.rank, self.nope, self.rope,
+         self.value) = (own[k] for k in _KindLatent.SIZES[1:])
+        for k, default in _KindLatent.OPTIONS.items():
+            setattr(self, k, own.get(k, default))
         self.width = self.rank + self.rope
         # what every attention node of the kind takes
         self.attrs = dict(num_heads=self.heads, rope_dim=self.rope,
                           value_dim=self.value)
+        self.rope_attrs = {}   # and every rotary node, beyond the base
+
+    def _fits(self, own):
+        return own["rope_dim"] % 2 == 0
 
     def params(self, i):
         lm, v = self.lm, sym.Variable
@@ -688,14 +573,21 @@ class _KindLatent:
             p["hgate_weight"] = v("l%d_hgate_weight" % i, shape=(h, d))
         return p
 
+    def cache_spec(self, i, slots, max_len):
+        """ONE entry: ``(slots, 1, kv_rank + rope_dim, max_len)``, kind
+        ``"latent"`` — the positions on the minor axis like every ring,
+        one head of `width` lines that every query head reads."""
+        return [("latent_cache_%d" % i, CacheEntry(
+            "latent", (int(slots), 1, self.width, int(max_len))))]
+
     def _fc(self, x, p, key, width, name):
         return sym.FullyConnected(x, weight=p[key + "_weight"],
                                   num_hidden=width, no_bias=True,
                                   flatten=False, name=name)
 
     def _turn(self, t, name, heads, index, **rope):
-        attrs = dict(rope, theta=float(self.rope_theta), num_heads=heads,
-                     name=name)
+        attrs = dict(rope, theta=float(self.rope_theta), **self.rope_attrs,
+                     num_heads=heads, name=name)
         return (sym._rotary(t, **attrs) if index is None
                 else sym._rotary_at(t, index, **attrs))
 
@@ -723,13 +615,14 @@ class _KindLatent:
                          "l%d_krope" % i, 1, index)
         return c_q, sym.Concat(c, k_r, dim=2, name="l%d_latent" % i)
 
-    def _queries(self, c_q, p, i, index):
-        """A decode step's ``(q_nope, q_rope)`` of the query latent."""
+    def _queries(self, c_q, p, i, index, end=None):
+        """``(q_nope, q_rope)`` of the query latent (`end`: how a kind's
+        graphs spell where ``q_rope`` ends)."""
         h = self.heads
         q = self._fc(c_q, p, "qb", h * (self.nope + self.rope), "l%d_qb" % i)
         q_nope = sym.slice_axis(q, axis=2, begin=0, end=h * self.nope,
                                 name="l%d_q_nope" % i)
-        q_rope = sym.slice_axis(q, axis=2, begin=h * self.nope, end=None,
+        q_rope = sym.slice_axis(q, axis=2, begin=h * self.nope, end=end,
                                 name="l%d_q_rope" % i)
         return q_nope, self._turn(q_rope, "l%d_qrope" % i, h, index)
 
@@ -772,10 +665,11 @@ class _KindLatent:
 
     def _latent_counters(self, i, positions, computed, pages, max_len, read,
                          tiled=False):
-        """What both kinds add: a bucket's positions (and those of them a
-        blockwise kernel of the TPU takes: all where the kind's mask has
+        """What every latent kind adds: a bucket's positions (and those of
+        them a blockwise kernel of the TPU takes: all where the kind has
         one and the program is `tiled`, else none), a latent layer-step
-        (none by the latent ring's kernel), the bytes of the `read`
+        (none by the latent ring's kernel: the plain kind's own), the
+        bytes of the `read`
         positions of this layer's pages a step reads, this layer's ring
         among the `pages` pages bound."""
         entry = dict(self.cache_spec(
@@ -786,6 +680,102 @@ class _KindLatent:
                 "mla.layer_steps": int(computed > 0), "mla.kernel_steps": 0,
                 "mla.ring_bytes": 4 * self.width * read,
                 "cache.latent_bytes": pages * entry.nbytes}
+
+
+class _LatentAttention(_KindLatent):
+    """The latent-attention mixer (MLA) of layer i, unmasked, at the
+    MODEL'S heads and rotary base:
+    rotary with the spec's `rope_scaling` (YaRN) on ``q_rope`` and ``k_r``
+    only; softmax scale `attention_multiplier`; `query_scale`.  The
+    full-sequence forms up-project per head, so a head's key and value
+    have one width; the decode step absorbs `W_kvb` into the query."""
+
+    KIND = "latent_attention"
+    SIZES, OPTIONS = _KindLatent.SIZES[1:], {}
+    NEEDS = ("an even latent_rope_dim, and latent_nope_dim + latent_rope_dim"
+             " = latent_value_dim: the up-projected form goes through "
+             "_sdp_attention, whose heads have one width")
+
+    def __init__(self, lm):
+        super().__init__(lm)
+        self.rope_theta = lm.rope_theta
+        # an option appears on a node only when the spec sets it
+        scaling = lm.rope_scaling
+        if scaling is not None:
+            self.rope_attrs["yarn"] = tuple(
+                float(scaling[k]) for k in (
+                    "factor", "original_max_position_embeddings",
+                    "beta_fast", "beta_slow"))
+            # YaRN's attention factor: the ratio of the two mscales
+            grow = 0.1 * math.log(float(scaling["factor"]))
+            factor = ((grow * float(scaling.get("mscale", 1.0)) + 1.0)
+                      / (grow * float(scaling.get("mscale_all_dim", 0.0))
+                         + 1.0))
+            if factor != 1.0:
+                self.rope_attrs["rope_scale"] = factor
+        if lm.attention_multiplier is not None:
+            self.attrs["scale"] = lm.attention_multiplier
+        if lm.query_scale is not None:
+            self.attrs["query_scale"] = lm.query_scale
+
+    def _fits(self, own):
+        return super()._fits(own) and (
+            own["nope_dim"] + own["rope_dim"] == own["value_dim"])
+
+    def _project(self, x, p, i, index=None):
+        """``(q_nope, q_rope, latent)`` of the normed stream: the rotary
+        parts turned — each row's own `index` in a decode step, 0..T-1
+        without one — and ``latent = [norm(c_kv) | k_r]``, the row the
+        ring keeps."""
+        c_q, latent = self._latents(x, p, i, index)
+        q_nope, q_rope = self._queries(
+            c_q, p, i, index, end=self.heads * (self.nope + self.rope))
+        return q_nope, q_rope, latent
+
+    def _expanded(self, x, p, i):
+        q_nope, q_rope, latent = self._project(x, p, i)
+        ctx = sym._latent_attention(q_nope, q_rope, latent, p["kvb_weight"],
+                                    name="l%d_attn" % i, **self.attrs)
+        return self._out(ctx, p, i), latent
+
+    def prefill(self, x, p, i, caches, slot, length):
+        y, latent = self._expanded(x, p, i)
+        return y, [sym._latent_cache_write(
+            caches["latent_cache_%d" % i], latent, slot,
+            name="l%d_latent_write" % i)]
+
+    def decode(self, x, p, i, caches, slot, length):
+        q_nope, q_rope, latent = self._project(x, p, i, index=length)
+        step = sym._latent_cached_attention(
+            q_nope, q_rope, latent, p["kvb_weight"],
+            caches["latent_cache_%d" % i], slot, length,
+            name="l%d_attn" % i, **self.attrs)
+        return self._out(step[0], p, i), [step[1]]
+
+    def counters(self, i, positions=0, rows=0, lengths=(), computed=0,
+                 pages=0, max_len=None, platform=None, **call):
+        """What every latent layer adds, with — a prefill's positions
+        attend among themselves up-projected — those of them the TPU's
+        blockwise kernel takes (``ops.attention.prefill_block``), a decode
+        step served by the TPU's kernel where a program lowered for `platform`
+        has it, and the bytes of this layer's pages the step reads by the
+        kernel's blocks, up to the one that holds each row's `length`, or
+        whole pages where the ``jax.numpy`` body runs."""
+        _, entry = self.cache_spec(
+            i, 1, self.lm.max_len if max_len is None else max_len)[0]
+        ring = entry.shape[3]
+        block = _attention.decode_block(entry.shape, platform, latent=True)
+        at_a_time = block or ring
+        read = sum(min((n // at_a_time + 1) * at_a_time, ring)
+                   for n in lengths)
+        tiled = _attention.prefill_block(
+            (1, positions, self.heads * (self.nope + self.rope)), self.heads,
+            self.heads, platform) is not None
+        counters = self._latent_counters(i, positions, computed, pages,
+                                         max_len, read, tiled)
+        counters["mla.kernel_steps"] = (counters["mla.layer_steps"]
+                                        * (block is not None))
+        return counters
 
 
 class _SparseLatentAttention(_KindLatent):
@@ -804,15 +794,17 @@ class _SparseLatentAttention(_KindLatent):
 
     KIND = "sparse_latent_attention"
     SIZES = _KindLatent.SIZES + ("index_heads", "index_dim", "index_topk")
+    NEEDS = "rope_dim even, within index_dim"
 
     def __init__(self, lm):
         super().__init__(lm)
-        self.index_heads, self.index_dim, self.topk = self.own
-        if self.rope > self.index_dim:
-            raise ValueError("index_dim=%d holds the rotary part of %d"
-                             % (self.index_dim, self.rope))
+        self.index_heads, self.index_dim, self.topk = (
+            self.sizes[k] for k in self.SIZES[-3:])
         self.index_attrs = dict(index_heads=self.index_heads,
                                 top_k=self.topk)
+
+    def _fits(self, own):
+        return super()._fits(own) and own["rope_dim"] <= own["index_dim"]
 
     def params(self, i):
         v, d = sym.Variable, self.lm.d_model
@@ -827,10 +819,9 @@ class _SparseLatentAttention(_KindLatent):
         """The latent ring, kind ``"latent"``, and the index keys, kind
         ``"index"``: both ``(slots, 1, lines, max_len)``, the positions on
         the minor axis."""
-        return [("latent_cache_%d" % i, CacheEntry(
-                    "latent", (int(slots), 1, self.width, int(max_len)))),
-                ("index_cache_%d" % i, CacheEntry(
-                    "index", (int(slots), 1, self.index_dim, int(max_len))))]
+        return super().cache_spec(i, slots, max_len) + [
+            ("index_cache_%d" % i, CacheEntry(
+                "index", (int(slots), 1, self.index_dim, int(max_len))))]
 
     def _index(self, x, c_q, p, i, index=None):
         """``(index_q, index_k, index_w)``: the indexer's queries and key
@@ -926,7 +917,7 @@ class _WindowLatentAttention(_KindLatent):
 
     def __init__(self, lm):
         super().__init__(lm)
-        self.window, = self.own
+        self.window = self.sizes["window"]
 
     def cache_spec(self, i, slots, max_len):
         return [("latent_cache_%d" % i, CacheEntry("latent", (
@@ -1055,8 +1046,14 @@ class _Mamba2(_Recurrent):
     conv window ``(slots, d_conv - 1, conv_dim)`` — channels on the
     lanes; stored ``(conv_dim, d_conv - 1)`` a TPU tile would pad the 3
     taps to 128 — and the recurrent state ``(slots, heads, head_dim,
-    d_state)``."""
+    d_state)``.  Its own, ``kind_specs["mamba"]``: `heads` x `head_dim`
+    (its inner width), `state`, `groups`, `conv` taps and the prefill
+    scan's `chunk`."""
 
+    KIND = "mamba"
+    SIZES = ("heads", "head_dim", "state")
+    OPTIONS = {"groups": 1, "conv": 4, "chunk": 256}
+    NEEDS = "mamba_heads a multiple of mamba_groups >= 1, mamba_conv >= 2"
     OPS, NODE, STATE = ("_ssm_scan", "_ssm_prefill", "_ssm_step"), "ssm", \
         "ssm_state"
     # the mixer's own small parameters, in the ops' operand order
@@ -1065,18 +1062,22 @@ class _Mamba2(_Recurrent):
 
     def __init__(self, lm):
         self.lm = lm
-        sizes = (lm.mamba_heads, lm.mamba_head_dim, lm.mamba_state,
-                 lm.mamba_groups, lm.mamba_conv)
+        own = _own_sizes(self, lm)
+        heads, head_dim, state, groups, conv = sizes = tuple(
+            own[k] for k in ("heads", "head_dim", "state", "groups", "conv"))
         self.small_shapes = _ssm.param_shapes(*sizes)
-        self.d_inner = lm.mamba_heads * lm.mamba_head_dim
-        conv_dim = self.d_inner + 2 * lm.mamba_groups * lm.mamba_state
-        self.d_proj = self.d_inner + conv_dim + lm.mamba_heads
-        self.state_shapes = ((lm.mamba_conv - 1, conv_dim), sizes[:3])
-        self.attrs = dict(num_heads=lm.mamba_heads,
-                          head_dim=lm.mamba_head_dim,
-                          state_size=lm.mamba_state, n_groups=lm.mamba_groups,
-                          conv_kernel=lm.mamba_conv,
-                          chunk_size=lm.mamba_chunk, eps=lm.norm_eps)
+        self.d_inner = heads * head_dim
+        conv_dim = self.d_inner + 2 * groups * state
+        self.d_proj = self.d_inner + conv_dim + heads
+        self.state_shapes = ((conv - 1, conv_dim), sizes[:3])
+        self.attrs = dict(num_heads=heads, head_dim=head_dim,
+                          state_size=state, n_groups=groups,
+                          conv_kernel=conv, chunk_size=own["chunk"],
+                          eps=lm.norm_eps)
+
+    def _fits(self, own):
+        return (own["groups"] >= 1 and own["heads"] % own["groups"] == 0
+                and own["conv"] >= 2)
 
     def counters(self, i, positions=0, rows=0, **call):
         """What one program call adds: the bucket positions a prefill
@@ -1090,14 +1091,22 @@ class _GatedDeltaNet(_Recurrent):
     """The Gated DeltaNet mixer of layer i (ops/gdn.py has the equations):
     input projection ``[q | k | v | z | b | a]``, causal conv over ``[q | k
     | v]`` + delta rule + gated per-head RMSNorm in ONE op node a form,
-    output projection.  `linear_heads` are the VALUE heads (v, z, b, a, a
-    state each); q and k have `linear_key_heads` heads, as many unless the
-    spec says fewer.  State: the conv window ``(slots, taps - 1,
+    output projection.  Its own, ``kind_specs["linear_attention"]``:
+    `heads` VALUE heads (v, z, b, a, a state each) of `key_dim` x
+    `value_dim`; q and k have `key_heads` heads, as many unless the spec
+    says fewer, which they divide — each then read by a group of value
+    heads; `conv` taps, the `chunk` of the full-sequence form, `neg_eigval`
+    (``beta`` reaches 2).  State: the conv window ``(slots, taps - 1,
     conv_dim)`` and the delta-rule state ``(slots, key_dim, heads *
     value_dim)`` — the key axis leading, the heads' values side by side
     on the lanes (ops/gdn.py: for heads of 96 x 192 a TPU tile then pads
     nothing)."""
 
+    KIND = "linear_attention"
+    SIZES = ("heads", "key_dim", "value_dim")
+    OPTIONS = {"conv": 4, "chunk": 64, "neg_eigval": True, "key_heads": 0}
+    NEEDS = ("linear_heads a multiple of linear_key_heads >= 1, "
+             "linear_conv >= 2")
     OPS, NODE, STATE = ("_gdn_scan", "_gdn_prefill", "_gdn_step"), "gdn", \
         "gdn_state"
     # the mixer's own small parameters, in the ops' operand order
@@ -1105,20 +1114,25 @@ class _GatedDeltaNet(_Recurrent):
 
     def __init__(self, lm):
         self.lm = lm
-        h, dk, dv = lm.linear_heads, lm.linear_key_dim, lm.linear_value_dim
-        hk = lm.linear_key_heads
-        self.small_shapes = _gdn.param_shapes(h, dk, dv, lm.linear_conv, hk)
+        own = _own_sizes(self, lm)
+        h, dk, dv, hk = self.head_sizes = (
+            own["heads"], own["key_dim"], own["value_dim"],
+            own["key_heads"] or own["heads"])
+        self.small_shapes = _gdn.param_shapes(h, dk, dv, own["conv"], hk)
         self.d_inner = h * dv
         conv_dim = _gdn.conv_channels(h, dk, dv, hk)
         self.d_proj = conv_dim + self.d_inner + 2 * h
-        self.state_shapes = ((lm.linear_conv - 1, conv_dim),
-                             (dk, self.d_inner))
+        self.state_shapes = ((own["conv"] - 1, conv_dim), (dk, self.d_inner))
         self.attrs = dict(num_heads=h, key_dim=dk, value_dim=dv,
-                          conv_kernel=lm.linear_conv,
-                          chunk_size=lm.linear_chunk,
-                          neg_eigval=lm.linear_neg_eigval, eps=lm.norm_eps)
+                          conv_kernel=own["conv"], chunk_size=own["chunk"],
+                          neg_eigval=own["neg_eigval"], eps=lm.norm_eps)
         if hk != h:   # on a node only when the spec sets it
             self.attrs["num_key_heads"] = hk
+
+    def _fits(self, own):
+        key_heads = own["key_heads"] or own["heads"]
+        return (key_heads >= 1 and own["heads"] % key_heads == 0
+                and own["conv"] >= 2)
 
     def mixed(self, x, p, i, caches, slot, length, rows):
         """The prefill of one prompt AND the decode step of `rows` packed
@@ -1143,13 +1157,13 @@ class _GatedDeltaNet(_Recurrent):
         `rows` rows read and write, and those of them that such a
         program's step kernel moves (all, or none where
         ``ops.gdn.step_heads`` says the body runs)."""
-        page, lm = self._page_bytes(i), self.lm
-        tiled = _gdn.chunk_heads(
-            (1, positions, lm.linear_heads, lm.linear_key_dim),
-            lm.linear_value_dim, lm.linear_chunk, platform,
-            lm.linear_key_heads) is not None
-        stepped = _gdn.step_heads((1,) + self.state_shapes[1],
-                                  lm.linear_value_dim, platform) is not None
+        page = self._page_bytes(i)
+        h, dk, dv, hk = self.head_sizes
+        tiled = _gdn.chunk_heads((1, positions, h, dk), dv,
+                                 self.attrs["chunk_size"], platform,
+                                 hk) is not None
+        stepped = _gdn.step_heads((1,) + self.state_shapes[1], dv,
+                                  platform) is not None
         return {"gdn.scan_positions": positions,
                 "gdn.kernel_positions": positions * tiled,
                 "gdn.state_bytes": 2 * rows * page,
@@ -1317,19 +1331,12 @@ class TransformerLM:
     fused ``(2 d_ff, d_model)`` projection); `embedding_multiplier` scales
     the embedded tokens, `residual_multiplier` every branch before it
     joins the stream, `attention_multiplier` replaces ``1/sqrt(d_head)``,
-    `logits_scaling` divides the logits; the Mamba-2 mixer's sizes
-    `mamba_heads` x `mamba_head_dim` (its inner width), `mamba_state`,
-    `mamba_groups`, `mamba_conv` taps and the prefill scan's
-    `mamba_chunk`; the Gated DeltaNet mixer's `linear_heads` heads of
-    `linear_key_dim` x `linear_value_dim`, `linear_conv` taps, the chunk
-    `linear_chunk` of its full-sequence form, `linear_neg_eigval`
-    (``beta`` reaches 2) and `linear_key_heads` — heads of q and k where
-    they are fewer than the `linear_heads` value heads, which they
-    divide; `head_dim` — the width of a head where it is not
-    ``d_model / num_heads`` (the projections are then ``num_heads *
-    head_dim`` wide); `sliding_window` W of the ``"window_attention"``
-    kind (row i attends to ``j <= i`` with ``i - j < W``); `positions`
-    may be a dict by attention kind, ``{"window_attention": "rotary"}``
+    `logits_scaling` divides the logits; `head_dim` — the width of a head
+    where it is not ``d_model / num_heads`` (the projections are then
+    ``num_heads * head_dim`` wide); `sliding_window` W of the
+    ``"window_attention"`` kind (row i attends to ``j <= i`` with ``i - j <
+    W``); `positions` may be a dict by attention kind,
+    ``{"window_attention": "rotary"}``
     (kinds left out have none; no learned table then); `qk_norm`
     ``"head"`` norms each head of Q and K on its own with one ``(head_dim,)``
     gain; `out_gate` multiplies attention's context by ``sigmoid(x Wg)``,
@@ -1344,24 +1351,44 @@ class TransformerLM:
     `route_norm` renormalises the chosen scores, `route_scale` multiplies
     them; `held_experts` ``(first, count)`` — the experts whose matrices
     this model holds, one chip's share: the router stays `num_experts`
-    wide; the latent-attention mixer's `latent_q_rank` (the query's
-    low-rank width, normed between its two projections), `latent_kv_rank`
-    (the cached ``c``'s width), `latent_nope_dim` / `latent_rope_dim` (a
-    head's unturned and rotary query channels; one rotary key of
-    `latent_rope_dim` serves all heads) and `latent_value_dim` (a head's
-    value width, equal to their sum), its rotary part turned whatever
-    `positions` says; `rope_scaling` — YaRN's ``{factor,
+    wide; `rope_scaling` — YaRN's ``{factor,
     original_max_position_embeddings, beta_fast, beta_slow[, mscale,
-    mscale_all_dim]}`` for the latent kind's rotary part; `query_scale`
-    ``(beta, period)`` — a latent layer's query at position p times ``1 +
-    beta * ln(1 + floor(p / period))``; `kind_specs` ``{kind: {size:
-    value}}`` — the sizes of the kinds that read their own
-    (:class:`_KindLatent`: `num_heads`, `q_rank`, `kv_rank`, `nope_dim`,
-    `rope_dim`, `value_dim`, and `rope_theta`, `lora_rescale`, `head_gate`;
-    the sparse kind's `index_heads`, `index_dim`, `index_topk`; the window
-    kind's `window`), whatever the flat arguments say; `nextn` 1 — a
+    mscale_all_dim]}`` for the ``"latent_attention"`` kind's rotary part;
+    `query_scale` ``(beta, period)`` — that kind's query at position p
+    times ``1 + beta * ln(1 + floor(p / period))``; `nextn` 1 — a
     multi-token-prediction module behind the trunk, the serving graphs'
-    draft (module docstring)."""
+    draft (module docstring).
+
+    `kind_specs` ``{kind: {size: value}}`` — each mixer kind's OWN sizes
+    (every one an int >= 1) and options (with their defaults), checked
+    where the kind is built:
+
+    * ``"mamba"`` — `heads` x `head_dim` (its inner width), `state`;
+      `groups` 1 (which divide `heads`), `conv` 4 taps, the prefill
+      scan's `chunk` 256.  Flat: ``mamba_<size>``.
+    * ``"linear_attention"`` — `heads` value heads of `key_dim` x
+      `value_dim`; `conv` 4 taps, the `chunk` 64 of its full-sequence
+      form, `neg_eigval` True (``beta`` reaches 2), `key_heads` — heads of
+      q and k where they are fewer than `heads`, which they divide.
+      Flat: ``linear_<size>``.
+    * ``"latent_attention"`` — `q_rank` (the query's low-rank width,
+      normed between its two projections), `kv_rank` (the cached ``c``'s
+      width), `nope_dim` / `rope_dim` (a head's unturned and rotary query
+      channels; one rotary key of an even `rope_dim` serves all heads),
+      `value_dim` (a head's value width, equal to their sum), at the
+      model's `num_heads`; its rotary part turned whatever `positions` says.
+      Flat: ``latent_<size>``.
+    * ``"sparse_latent_attention"`` / ``"window_latent_attention"`` —
+      `num_heads`, `q_rank`, `kv_rank`, `nope_dim`, `rope_dim`,
+      `value_dim` (a head's key ``nope_dim + rope_dim`` wide, whatever its
+      value's width); `rope_theta` 10000, `lora_rescale`, `head_gate`;
+      the sparse kind's `index_heads`, `index_dim`, `index_topk`; the
+      window kind's `window`.
+
+    A keyword ``<prefix><size>`` of the three flat spellings IS
+    ``kind_specs[kind][size]`` (a kind is spelt ONE way: flat keywords
+    beside its mapping are a ValueError); any other unknown keyword is a
+    TypeError."""
 
     def __init__(self, vocab, num_layers=2, num_heads=2, d_model=32,
                  d_ff=None, max_len=64, dropout=0.0, norm="layer",
@@ -1370,19 +1397,13 @@ class TransformerLM:
                  bias=True, tied_head=True, layer_types=None,
                  num_kv_heads=None, ffn="relu", embedding_multiplier=1.0,
                  residual_multiplier=1.0, attention_multiplier=None,
-                 logits_scaling=1.0, mamba_heads=0, mamba_head_dim=0,
-                 mamba_state=0, mamba_groups=1, mamba_conv=4,
-                 mamba_chunk=256, block_norm="input", linear_heads=0,
-                 linear_key_dim=0, linear_value_dim=0, linear_conv=4,
-                 linear_chunk=64, linear_neg_eigval=True, head_dim=None,
+                 logits_scaling=1.0, block_norm="input", head_dim=None,
                  sliding_window=0, out_gate=False, ffn_types=None,
                  expert_d_ff=None, shared_d_ff=0, router_score="softmax",
                  router_bias=False, route_norm=False, route_scale=1.0,
-                 held_experts=None, linear_key_heads=None, rotary_dim=None,
-                 shared_gate=False, latent_q_rank=0, latent_kv_rank=0,
-                 latent_nope_dim=0, latent_rope_dim=0, latent_value_dim=0,
+                 held_experts=None, rotary_dim=None, shared_gate=False,
                  rope_scaling=None, query_scale=None, kind_specs=None,
-                 nextn=0):
+                 nextn=0, **flat):
         if int(nextn) not in (0, 1):
             raise ValueError("nextn must be 0 or 1 (ONE draft a step), got %r"
                              % (nextn,))
@@ -1437,37 +1458,6 @@ class TransformerLM:
             raise ValueError("layer_types must name num_layers=%d kinds of %s,"
                              " got %r" % (num_layers, sorted(_KINDS),
                                           layer_types))
-        if "mamba" in layer_types and (
-                min(mamba_heads, mamba_head_dim, mamba_state, mamba_groups) < 1
-                or mamba_heads % mamba_groups or mamba_conv < 2):
-            raise ValueError("a 'mamba' layer needs mamba_heads, "
-                             "mamba_head_dim, mamba_state >= 1, mamba_heads a "
-                             "multiple of mamba_groups and mamba_conv >= 2")
-        if "linear_attention" in layer_types and (
-                min(linear_heads, linear_key_dim, linear_value_dim) < 1
-                or linear_conv < 2):
-            raise ValueError("a 'linear_attention' layer needs linear_heads, "
-                             "linear_key_dim, linear_value_dim >= 1 and "
-                             "linear_conv >= 2")
-        linear_key_heads = int(linear_key_heads or linear_heads)
-        if "linear_attention" in layer_types and (
-                linear_key_heads < 1 or linear_heads % linear_key_heads):
-            raise ValueError("linear_heads=%d not a multiple of "
-                             "linear_key_heads=%d"
-                             % (linear_heads, linear_key_heads))
-        if "latent_attention" in layer_types:
-            if min(latent_q_rank, latent_kv_rank, latent_nope_dim,
-                   latent_rope_dim, latent_value_dim) < 1 or latent_rope_dim % 2:
-                raise ValueError(
-                    "a 'latent_attention' layer needs latent_q_rank, "
-                    "latent_kv_rank, latent_nope_dim, latent_value_dim >= 1 "
-                    "and an even latent_rope_dim >= 2")
-            if latent_nope_dim + latent_rope_dim != latent_value_dim:
-                raise ValueError(
-                    "latent_nope_dim + latent_rope_dim = %d must equal "
-                    "latent_value_dim = %d: the up-projected form goes "
-                    "through _sdp_attention, whose heads have one width"
-                    % (latent_nope_dim + latent_rope_dim, latent_value_dim))
         if rope_scaling is not None:
             rope_scaling = dict(rope_scaling)
             missing = {"factor", "original_max_position_embeddings",
@@ -1524,15 +1514,7 @@ class TransformerLM:
         self.attention_multiplier = (None if attention_multiplier is None
                                      else float(attention_multiplier))
         self.logits_scaling = float(logits_scaling)
-        self.mamba_heads, self.mamba_head_dim = int(mamba_heads), int(mamba_head_dim)
-        self.mamba_state, self.mamba_groups = int(mamba_state), int(mamba_groups)
-        self.mamba_conv, self.mamba_chunk = int(mamba_conv), int(mamba_chunk)
         self.block_norm = block_norm
-        self.linear_heads = int(linear_heads)
-        self.linear_key_dim = int(linear_key_dim)
-        self.linear_value_dim = int(linear_value_dim)
-        self.linear_conv, self.linear_chunk = int(linear_conv), int(linear_chunk)
-        self.linear_neg_eigval = bool(linear_neg_eigval)
         self.kind_positions = kind_positions
         self.sliding_window = int(sliding_window)
         self.out_gate = bool(out_gate)
@@ -1544,20 +1526,26 @@ class TransformerLM:
         self.route_norm = bool(route_norm)
         self.route_scale = float(route_scale)
         self.held_experts = held_experts
-        self.linear_key_heads = linear_key_heads
         self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
         self.shared_gate = bool(shared_gate)
-        self.latent_q_rank = int(latent_q_rank)
-        self.latent_kv_rank = int(latent_kv_rank)
-        self.latent_nope_dim = int(latent_nope_dim)
-        self.latent_rope_dim = int(latent_rope_dim)
-        self.latent_value_dim = int(latent_value_dim)
         self.rope_scaling = rope_scaling
         self.query_scale = query_scale
         self.kind_specs = {k: dict(v) for k, v in (kind_specs or {}).items()}
         if set(self.kind_specs) - set(_KINDS):
             raise ValueError("kind_specs names kinds of %s, got %r"
                              % (sorted(_KINDS), sorted(self.kind_specs)))
+        for name, value in flat.items():
+            kind = next((k for k, prefix in _FLAT.items()
+                         if name.startswith(prefix)), None)
+            size = name[len(_FLAT.get(kind, "")):]
+            if kind is None or size not in (*_KINDS[kind].SIZES,
+                                            *_KINDS[kind].OPTIONS):
+                raise TypeError("TransformerLM.__init__() got an unexpected "
+                                "keyword argument %r" % name)
+            if kind in (kind_specs or {}):
+                raise ValueError("%s beside kind_specs[%r]: a kind's sizes "
+                                 "are spelt ONE way" % (name, kind))
+            self.kind_specs.setdefault(kind, {})[size] = value
         if self.rotary_dim is not None and (
                 self.rotary_dim % 2 or not 0 < self.rotary_dim <= self.d_head):
             raise ValueError("rotary_dim=%d must be even and within the "

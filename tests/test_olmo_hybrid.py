@@ -311,12 +311,13 @@ def test_the_reference_is_sensitive_to_every_part(params, held, part,
 
 
 def _arguments(lm):
-    """The constructor arguments of a `TransformerLM`, read back."""
+    """The constructor arguments of a `TransformerLM`, read back (the
+    kinds' sizes are in `kind_specs`)."""
     import inspect
 
-    return {n: getattr(lm, n) for n in
-            inspect.signature(TransformerLM.__init__).parameters
-            if n != "self"}
+    return {n: getattr(lm, n) for n, p in
+            inspect.signature(TransformerLM.__init__).parameters.items()
+            if n != "self" and p.kind is not p.VAR_KEYWORD}
 
 
 def test_two_interleaved_sessions_match_one_full_forward(params, held):

@@ -14,7 +14,6 @@ are float32 rounding (measured 5e-6 of the largest logit); the bound 1e-4
 is far above that and a fortieth of what one bfloat16 pass leaves.  The
 file costs about 70 s.
 """
-import hashlib
 import json
 import os
 import sys
@@ -31,7 +30,6 @@ from mxnet_tpu.serving import GenerateRequest, GenerativeSession
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
-from benchmarks.families import afmoe, olmo_hybrid, olmoe  # noqa: E402
 from benchmarks.families import qwen3_next as family  # noqa: E402
 from benchmarks.reference import qwen3_next as reference  # noqa: E402
 
@@ -453,41 +451,3 @@ def test_the_batcher_books_recurrent_state_experts_and_the_ring(held):
     assert 0 < moved["cache.state_bytes"] < moved["cache.reserved_bytes"]
     assert moved["moe.routed_pairs"] == 4 * 4 * (8 + 2 + 32 + 2 + 4 * 2 + 1)
     assert 0 < moved["moe.pairs"] < moved["moe.routed_pairs"]
-
-
-# sha1 of `tojson()` of the three serving graphs of the configurations that
-# share this PR's code, built by their families from the benchmark's own
-# configuration files at the parent commit (84fd161), each graph under a
-# NameManager of its own
-PARENT_GRAPHS = {
-    ("olmo-hybrid-7b", "score_symbol"): "84e606c0357771a7689eeb07c8b75d1a1f209d4d",
-    ("olmo-hybrid-7b", "prefill_symbol"): "9ca47d0cadaa97c8a310cc78fea7fc68c70f9d46",
-    ("olmo-hybrid-7b", "decode_symbol"): "4e8abcd9794e4123f9c1bc32d8487b32035f75a1",
-    ("trinity-mini", "score_symbol"): "6e327883e6a050a6467c7bf2c6af51df3720ec59",
-    ("trinity-mini", "prefill_symbol"): "e0e80f515d3db50b41fbb1d1fbfc55ea23e66a72",
-    ("trinity-mini", "decode_symbol"): "55a5295c67dc63d92505d16e88b7c36646d8cc0e",
-    ("olmoe-1b-7b", "score_symbol"): "d5785914ed4170a138deea55fdc36ae073630e58",
-    ("olmoe-1b-7b", "prefill_symbol"): "bd4be60fb1b6685e0d4038f833136f8217e14663",
-    ("olmoe-1b-7b", "decode_symbol"): "c7557c24b3b581b54e765b9d0c084c5f4265ffa0",
-}
-FAMILIES = {"olmo-hybrid-7b": olmo_hybrid, "trinity-mini": afmoe,
-            "olmoe-1b-7b": olmoe}
-
-
-@pytest.mark.parametrize("which,graph", sorted(PARENT_GRAPHS))
-def test_the_other_models_graphs_are_the_parents_byte_for_byte(which, graph):
-    """Olmo-Hybrid's, Trinity's and OLMoE's graphs carry no new attribute:
-    `num_key_heads`, `rotary_dim` and `shared_gate` appear on a node only
-    where a spec sets them."""
-    with open(os.path.join(ROOT, "benchmarks", "configs",
-                           which + ".json")) as f:
-        config = json.load(f)
-    with mx.name.NameManager():
-        js = getattr(FAMILIES[which].model(config), graph)().tojson()
-    assert hashlib.sha1(js.encode()).hexdigest() == PARENT_GRAPHS[which, graph]
-    assert not {"num_key_heads", "rotary_dim", "shared_gate"} & set(
-        json.dumps(json.loads(js)).replace('"', " ").split())
-    # and this model's nodes do carry them
-    mine = family.model(CONFIG).decode_symbol().tojson()
-    assert all(n in mine for n in ("num_key_heads", "rotary_dim",
-                                   "shared_gate"))

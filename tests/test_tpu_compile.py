@@ -370,7 +370,8 @@ def test_the_delta_rule_step_compiles_for_a_v5e(widths, one_chip):
     lm = _smoke_model(index)
     spec = lm.cache_spec(rows + 1)
     state = spec["gdn_state_0"].shape
-    assert gdn.step_heads(state, lm.linear_value_dim, "tpu") == heads
+    assert gdn.step_heads(
+        state, lm.kind_specs["linear_attention"]["value_dim"], "tpu") == heads
     gdn._state_step.clear_cache()
     with warnings.catch_warnings():   # the small inputs are not donated
         warnings.simplefilter("ignore")

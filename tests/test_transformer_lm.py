@@ -17,6 +17,11 @@ The decode pins are the acceptance criteria of the KV-cache PR:
   submitted generation, active or queued.
 """
 import contextlib
+import hashlib
+import importlib
+import json
+import os
+import sys
 import threading
 import time
 from unittest import mock
@@ -29,6 +34,9 @@ from mxnet_tpu import telemetry
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models import TransformerLM
 from mxnet_tpu.serving import GenerateRequest, GenerativeSession, ServerClosed
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)   # benchmarks/: the families the cells build
 
 
 class TwoProgramLM(TransformerLM):
@@ -1072,3 +1080,227 @@ def test_page_and_skipped_position_counters(block, two_programs,
     assert moved["kv.skipped_positions"] == len(lengths) * max_len - read
     assert moved["kv.skipped_positions"] == (
         (5 * 128 + 2 * 128) if block else 0)
+
+
+# ----------------------------------------------------------------------
+# the graphs every configuration binds, and a kind's own sizes (PR 56)
+# ----------------------------------------------------------------------
+
+GRAPHS = ("training_symbol", "score_symbol", "prefill_symbol",
+          "decode_symbol", "mixed_symbol")
+# configuration: (family, the cell's slots, sha1 of `tojson()` of GRAPHS as
+# `benchmarks.families.<family>.model(config)` built them from the
+# benchmark's own configuration file AT THE PARENT COMMIT (bc80f38, before
+# the kinds owned their sizes) — graph construction only, each under a
+# NameManager of its own; None: the configuration has no mixed form
+FAMILY_GRAPHS = {
+    "opt-1.3b": ("opt", 8,
+        "6ad731022342e4b8f5583f0debb0ce00d757abfe",
+        "1036bf47d1f9ca438177e4807a7120749cea233b",
+        "2e76b7f6ab2fc05337340930cefa08923407e09e",
+        "393430e83d62cac24c30a4e0aed47f7f8e50650f",
+        "acefd4f82b59f145fa73b295d1f03f3da94f29ef"),
+    "olmoe-1b-7b": ("olmoe", 8,
+        "3875e31773ed0a8af8e1db9c5cc9d6e24a97bbbd",
+        "d5785914ed4170a138deea55fdc36ae073630e58",
+        "bd4be60fb1b6685e0d4038f833136f8217e14663",
+        "c7557c24b3b581b54e765b9d0c084c5f4265ffa0",
+        "34d648acb1941b12c75092fad146590b7c975862"),
+    "granite-4.0-h-micro": ("granite_hybrid", 8,
+        "087821df4244e18d542902f0c42eb9eaa2948bd2",
+        "679394e4132b8d5fd82797203f9b32a47ad03595",
+        "a2bf615c635a3f7f1ca0c15895fd1c779776240e",
+        "8bccda39c3fa55d5cf2f8ce87af40c84d38c199d",
+        None),
+    "olmo-hybrid-7b": ("olmo_hybrid", 8,
+        "7109e9032e3c97cb9db0983865b532c7d4b965d5",
+        "84e606c0357771a7689eeb07c8b75d1a1f209d4d",
+        "9ca47d0cadaa97c8a310cc78fea7fc68c70f9d46",
+        "4e8abcd9794e4123f9c1bc32d8487b32035f75a1",
+        "25f3d04c39004c98a53131a6bf197e7d3b0b77ff"),
+    "trinity-mini": ("afmoe", 8,
+        "d1b463c67efe0cddb0b3b11928906eb24db70339",
+        "6e327883e6a050a6467c7bf2c6af51df3720ec59",
+        "e0e80f515d3db50b41fbb1d1fbfc55ea23e66a72",
+        "55a5295c67dc63d92505d16e88b7c36646d8cc0e",
+        "1aaed8cff56aab3481481ef07fb097635d3e3368"),
+    "qwen3-next-80b-a3b": ("qwen3_next", 16,
+        "3208fa81c88a908d7d4cb284460b770d72e2dcd2",
+        "79d573c9b6865da27dc3487da4f200b8f2651c2f",
+        "85c6ef01e68bebb1adc657ffef0109752a9652f2",
+        "e83b7b1f379695bf0678614014d7103d144e3b08",
+        "1ebc119fc90648f427e25b6456e851a6e83f1f96"),
+    "mistral-small-4-119b": ("mistral4", 16,
+        "40ff36540e20e755a4381f74ccc56d86566e83d8",
+        "582b5ca27eb8b87d0dcc4bccc2875e6b72dd9ae8",
+        "c52917aa45c1af061dff3992bfdafc9222501a31",
+        "9ceeb654732c44badf180821e48c585704b00db8",
+        None),
+    "dots3-note-prev": ("dots3", 4,
+        "3522b62a3bcfd5130d44e9eea25797aa0e7a4ebc",
+        "43e9e7dbf37bdf274a8ce93a5d65fbd8424ea62e",
+        "8676552ed85408b2579560d845d5c8adbdeab41d",
+        "1bee16480cdc89e682cf08528bd3f22f327b7294",
+        None),
+    "glm-5": ("glm5", 4,
+        "314be826d7169938cfb8825939bb8ab7b3302c78",
+        "60b23443d211605f7338b6dfea25b25cd699df0a",
+        "924b713673d73f3badfdf2857f301922601e8ce7",
+        "cf7605ca41661e0c3a7664c8e141df0055e9e441",
+        None),
+    "granite-4.0-h-small": ("granite_moe_hybrid", 8,
+        "baa90f0a116d9adc20f7a1c9ebafe2f39d525d06",
+        "5455591ed5a4038604e68318c31bc3b2c6f3db80",
+        "58198bd18192884e74e96cce8ed7b9edb8191500",
+        "d1d3a3bb025bab538b2601b81a9bcfd9d263689f",
+        None),
+}
+# three layers of each — six of Granite's, so that both of its kinds are in
+# — at the published widths, hashed at older parents yet (OPT's and OLMoE's
+# at 2109c79, before the layer kinds; Granite's at 2ac94fd, before
+# `block_norm` and a third kind): GRAPHS' first four
+OLDER_GRAPHS = {
+    "opt": (dict(vocab=50272, num_layers=3, num_heads=32, d_model=2048,
+                 d_ff=8192, max_len=2048),
+            "c5ec39dad62315ec1bebce350b266fd623536162",
+            "1977b83bd3110ebd66b30ec94cf150b7f2303b14",
+            "48532b55682f2ad6f69d722de119d7756fa8ea0c",
+            "124e97c9ed89d78016b93f4d8b74cc7fd6319fc2"),
+    "olmoe": (dict(vocab=50304, num_layers=3, num_heads=16, d_model=2048,
+                   d_ff=1024, max_len=4096, norm="rms", norm_eps=1e-5,
+                   positions="rotary", rope_theta=10000.0, qk_norm=True,
+                   num_experts=64, experts_per_token=8, bias=False,
+                   tied_head=False),
+              "833cffed802b0f12737ec4fef09cb890e2c6483c",
+              "321af145a4a4a583067ccca139885bfd3d167f67",
+              "4d1b2ec2e4c78401528d2adddc81654106b81e2e",
+              "529499e280108dda9508fcadc203aed86013ca5c"),
+    "granite": (dict(vocab=100352, num_layers=6, num_heads=32, d_model=2048,
+                     d_ff=8192, max_len=131072, norm="rms", norm_eps=1e-5,
+                     positions="none", bias=False, tied_head=True,
+                     layer_types=["mamba"] * 5 + ["attention"],
+                     num_kv_heads=8, ffn="swiglu", embedding_multiplier=12,
+                     residual_multiplier=0.22, attention_multiplier=0.015625,
+                     logits_scaling=8, mamba_heads=64, mamba_head_dim=64,
+                     mamba_state=128, mamba_groups=1, mamba_conv=4,
+                     mamba_chunk=256),
+                "2b28d9946c4695960b7074ebda9ca33a339f8313",
+                "a0ec10075e3e19cc3b622dd27d27918653fc5d68",
+                "c2c586ac196572182bd0cbcf039cabbbec139fe7",
+                "e93f15ca90cefaf24c32446c38d3d1b603947dde"),
+}
+# what Qwen3-Next's options put on a node (PR 40), and the serving graphs
+# that must go on carrying none of it
+MARKS = ("num_key_heads", "rotary_dim", "shared_gate")
+UNMARKED = [(name, graph)
+            for name in ("olmo-hybrid-7b", "trinity-mini", "olmoe-1b-7b")
+            for graph in GRAPHS[1:4]]
+
+
+def _family_model(name):
+    """The model of configuration `name` as its family builds it from the
+    benchmark's own file."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    return importlib.import_module(
+        "benchmarks.families." + FAMILY_GRAPHS[name][0]).model(config)
+
+
+def _graph_json(build, graph, *args):
+    with mx.name.NameManager():  # auto-names count from 0, as in a new process
+        built = getattr(build(), graph)(*args)
+    return None if built is None else built.tojson()
+
+
+def _sha1(js):
+    return None if js is None else hashlib.sha1(js.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("family,graph", [
+    pytest.param(name, graph, id="%s-%s" % (name, graph))
+    for name in FAMILY_GRAPHS for graph in GRAPHS] + [
+    pytest.param(name, graph, id="three-layer-%s-%s" % (name, graph))
+    for name in OLDER_GRAPHS for graph in GRAPHS[:4]] + [
+    pytest.param(name, graph, id="%s-%s-unmarked" % (name, graph))
+    for name, graph in UNMARKED])
+def test_every_family_builds_the_parents_graphs(family, graph, request):
+    """Every graph every cell binds serialises to the parent's bytes: the
+    same programs, the same compile-cache keys.  The three-layer cases
+    hold OPT, OLMoE and Granite to parents older yet; the unmarked cases
+    hold three configurations' serving graphs to carrying no attribute a
+    later model's options brought, which that model's nodes do carry."""
+    if family in OLDER_GRAPHS:
+        args, *pins = OLDER_GRAPHS[family]
+        js = _graph_json(lambda: TransformerLM(**args), graph)
+    else:
+        _, slots, *pins = FAMILY_GRAPHS[family]
+        js = _graph_json(lambda: _family_model(family), graph,
+                         *[slots] * (graph == "mixed_symbol"))
+    assert _sha1(js) == pins[GRAPHS.index(graph)]
+    if request.node.callspec.id.endswith("unmarked"):
+        words = json.dumps(json.loads(js)).replace('"', " ").split()
+        assert not set(MARKS) & set(words)
+        mine = _family_model("qwen3-next-80b-a3b").decode_symbol().tojson()
+        assert all(n in mine for n in MARKS)
+
+
+# kind: (its flat prefix, sizes that build it, mappings over them that it
+# refuses)
+KIND_SIZES = {
+    "mamba": ("mamba_", dict(heads=4, head_dim=4, state=8, groups=2,
+                             chunk=8),
+              [dict(heads=3), dict(groups=0), dict(conv=1)]),
+    "linear_attention": ("linear_", dict(heads=4, key_dim=4, value_dim=8,
+                                         key_heads=2, neg_eigval=False),
+                         [dict(heads=3), dict(key_heads=-1), dict(conv=1)]),
+    "latent_attention": ("latent_", dict(q_rank=8, kv_rank=8, nope_dim=4,
+                                         rope_dim=4, value_dim=8),
+                         [dict(rope_dim=3, value_dim=7), dict(value_dim=16),
+                          # the model's heads and rotary base, no others
+                          dict(num_heads=2), dict(rope_theta=1e4)]),
+    "sparse_latent_attention": (None, dict(
+        num_heads=2, q_rank=8, kv_rank=8, nope_dim=4, rope_dim=4,
+        value_dim=6, index_heads=2, index_dim=8, index_topk=4,
+        head_gate=True), [dict(rope_dim=3), dict(index_dim=2)]),
+    "window_latent_attention": (None, dict(
+        num_heads=2, q_rank=8, kv_rank=8, nope_dim=4, rope_dim=4,
+        value_dim=6, window=5), [dict(rope_dim=3), dict(window=0)]),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(KIND_SIZES))
+def test_a_kind_owns_its_sizes(kind):
+    """A kind's sizes are `kind_specs[kind]`, checked where the kind is
+    built; the three older kinds' flat keywords are that mapping spelt
+    another way, and nothing else is a keyword."""
+    prefix, sizes, refused = KIND_SIZES[kind]
+    base = dict(vocab=8, num_layers=1, num_heads=2, d_model=16, norm="rms",
+                layer_types=[kind])
+
+    def shas(**args):
+        return [_sha1(_graph_json(lambda: TransformerLM(**base, **args), g))
+                for g in GRAPHS[:4]]
+
+    by_kind = shas(kind_specs={kind: sizes})
+    assert None not in by_kind
+    for fault in [{next(iter(sizes)): None}, {"bogus": 1}] + refused:
+        with pytest.raises(ValueError, match=repr(kind)):
+            TransformerLM(**base, kind_specs={kind: dict(sizes, **fault)})
+    with pytest.raises(ValueError, match=repr(kind)):
+        TransformerLM(**base)
+    with pytest.raises(TypeError, match="bogus"):
+        TransformerLM(**base, kind_specs={kind: sizes},
+                      **{(prefix or "sparse_") + "bogus": 1})
+    if prefix is None:
+        return
+    flat = {prefix + k: v for k, v in sizes.items()}
+    assert shas(**flat) == by_kind
+    # a kind is spelt one way: a size given both ways is refused
+    name, value = next(iter(flat.items()))
+    with pytest.raises(ValueError, match=name):
+        TransformerLM(**base, kind_specs={kind: sizes},
+                      **{name: value + 1})
+    lm = TransformerLM(**base, **flat)
+    assert lm.kind_specs[kind] == sizes
+    assert not [a for a in vars(lm) if a.startswith(prefix)]
