@@ -24,7 +24,7 @@ apart from the rest:
   generate  TransformerLM via add_generative_tenant + submit_generate;
             one session's prefill/decode logits against the
             full-recompute score_symbol forward
-  kv_ring   the decode programs of eight TransformerLMs shaped like the
+  kv_ring   the decode programs of ten TransformerLMs shaped like the
             benchmark's decoders (32 heads of 64; 16 of 128; 32 query on
             8 K/V heads of 64 with rings of 2,304; a delta-rule layer of
             30 heads of 96 x 192 beside 30 heads of 128 with rings of
@@ -44,11 +44,13 @@ apart from the rest:
             ONE attention kernel call an attention layer (a latent layer's
             is ops/latent_ring_kernel.py; the eighth's absorbed steps are
             jax.numpy and hold none), ONE step-kernel
-            call a delta-rule layer (ops/gdn_step_kernel.py) and nothing
-            of rows x page size beside it, and no ring or
-            recurrent state fatter on the device than cache_spec states;
+            call a delta-rule layer (ops/gdn_step_kernel.py) and ONE a
+            Mamba-2 layer (ops/ssm_step_kernel.py: the ninth and tenth,
+            128 and 64 heads of 64 x 128 states beside an attention
+            layer) and nothing of rows x page size beside it, and no ring
+            or recurrent state fatter on the device than cache_spec states;
             prints the rings' on-device layout and the warm ms of the
-            delta-rule models' decode step; and the 2,048-bucket
+            delta-rule and Mamba-2 models' decode step; and the 2,048-bucket
             prefill of the fourth and the sixth: ONE kernel call a
             delta-rule layer
             (ops/gdn_kernel.py), no triangular solve left in it and no
@@ -176,7 +178,23 @@ FULL = {
                                         rope_dim=64, value_dim=128,
                                         head_gate=True, window=512)},
                                 norm="rms", positions="none",
-                                bias=False)]},
+                                bias=False),
+                           # a Mamba-2 layer's state of 128 heads of 64 x
+                           # 128 (granite-4.0-h-small's: a page of 4.19
+                           # MB the step kernel takes 32 heads at a time)
+                           # beside a ring of 8 K/V heads of 128
+                           dict(d_model=4096, num_heads=32, num_kv_heads=8,
+                                max_len=1536,
+                                layer_types=["mamba", "attention"],
+                                mamba_heads=128, mamba_head_dim=64,
+                                mamba_state=128, norm="rms",
+                                positions="none", bias=False),
+                           # and of 64 heads (granite-4.0-h-micro's)
+                           dict(num_heads=32, num_kv_heads=8, max_len=2304,
+                                layer_types=["mamba", "attention"],
+                                mamba_heads=64, mamba_head_dim=64,
+                                mamba_state=128, norm="rms",
+                                positions="none", bias=False)]},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
 }
@@ -672,12 +690,31 @@ def delta_step_hlo_facts(text, rows, state_shape):
     leading — a key spread out to a page's size for all rows, or the
     rows' pages gathered — for a state of `state_shape` ``(slots, d_k, H
     d_v)``."""
-    page = state_shape[1] * state_shape[2]
-    fat = {"f32[%d,%s]" % (rows, dims)
-           for dims in re.findall(r"f32\[%d,([\d,]+)\]" % rows, text)
-           if math.prod(int(d) for d in dims.split(",")) == page}
     return {"kernel_calls": named_kernel_calls(text, "gdn_state_step"),
-            "row_pages": sorted(fat)}
+            "row_pages": _row_page_arrays(text, rows, state_shape)}
+
+
+def _row_page_arrays(text, rows, state_shape):
+    """Every float32 array in the HLO `text` of `rows` x a page of a state
+    stored `state_shape` ``(slots, ...)``, the rows leading, however the
+    page's elements are split into dimensions."""
+    page = math.prod(state_shape[1:])
+    return sorted({"f32[%d,%s]" % (rows, dims)
+                   for dims in re.findall(r"f32\[%d,([\d,]+)\]" % rows, text)
+                   if math.prod(int(d) for d in dims.split(",")) == page})
+
+
+def ssm_step_hlo_facts(text, rows, state_shape):
+    """What a compiled decode program of `rows` rows makes of the Mamba-2
+    step, read from its optimised HLO `text`: the calls of the step kernel
+    (``ops/ssm_step_kernel.py``; a Pallas kernel keeps its name), every
+    array of ``rows x H x P x S`` elements, the rows leading — the rows'
+    pages gathered — and every copy of an array of `state_shape` ``(slots,
+    H, P, S)``, within HBM or staged through the compiler's fast memory
+    (PERF.md section 6, PR 54: four of nine layers' whole buffers)."""
+    return {"kernel_calls": named_kernel_calls(text, "ssm_step_kernel"),
+            "row_pages": _row_page_arrays(text, rows, state_shape),
+            "copies": ring_hlo_facts(text, state_shape)["copies"]}
 
 
 def _best_ms(session, exe, fn, calls, *operands):
@@ -747,11 +784,13 @@ def phase_kv_ring(sizes, ctx):
     import numpy as np
 
     import mxnet_tpu as mx
+    from mxnet_tpu import telemetry
     from mxnet_tpu.models import TransformerLM
 
     platform = ctx.jax_device().platform
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
              "layouts": [], "rings": [], "delta_rule": [], "delta_step": [],
+             "ssm_step": [],
              "prefill_ms": [], "mixed_steps": 0, "kernel_buckets": 0,
              "masked_buckets": 0}
     for shape in sizes["shapes"]:
@@ -811,6 +850,10 @@ def phase_kv_ring(sizes, ctx):
             longest = max(buckets)
             booked = lm.call_counters(positions=longest, platform=platform)
             scanned = booked.get("gdn.scan_positions", 0) // longest
+            mamba = booked.get("ssm.scan_positions", 0) // longest
+            # what a decode step of all slots books: a recurrent kind
+            # says how much of its state the step kernel moves
+            stepped = lm.call_counters(rows=slots, platform=platform)
             if scanned:
                 _exe, pre = session._program(session._prefill_pred, 1,
                                              longest, True)
@@ -821,7 +864,6 @@ def phase_kv_ring(sizes, ctx):
                             // longest)
                 # its decode program: the step kernel where the shape
                 # function gives it one, and nothing of rows x page size
-                stepped = lm.call_counters(rows=slots, platform=platform)
                 state, = {e.shape for n, e in spec.items()
                           if n.startswith("gdn_state")}
                 stepped_by = dict(
@@ -829,6 +871,27 @@ def phase_kv_ring(sizes, ctx):
                     rows=slots, layers=scanned,
                     kernel_layers=scanned * stepped["gdn.step_kernel_bytes"]
                     // stepped["gdn.state_bytes"],
+                    ms=float("%.3g" % decode_step_ms(session, slots)))
+            # a model with Mamba-2 layers: its decode program holds the
+            # step kernel where the shape function gives it one, no copy
+            # of a state buffer and nothing of rows x page size
+            if mamba:
+                state, = {e.shape for n, e in spec.items()
+                          if n.startswith("ssm_state")}
+                # a few tokens through the batcher: what it books of the
+                # steps' state bytes, and of them for the step kernel
+                names = ("ssm.state_bytes", "ssm.step_kernel_bytes")
+                before = [telemetry.counter_value(n) for n in names]
+                server.submit_generate(
+                    "ring", [1, 2, 3], max_new_tokens=4,
+                    timeout_ms=600000).result(timeout=600)
+                ssm_by = dict(
+                    ssm_step_hlo_facts(fn.hlo_text(), slots, state),
+                    rows=slots, layers=mamba,
+                    kernel_layers=mamba * stepped["ssm.step_kernel_bytes"]
+                    // stepped["ssm.state_bytes"],
+                    booked=[int(telemetry.counter_value(n) - was)
+                            for n, was in zip(names, before)],
                     ms=float("%.3g" % decode_step_ms(session, slots)))
             # each prefill bucket's program as the warm-up compiled it —
             # the mixed step where the model has one (PR 46) —: a bucket
@@ -952,7 +1015,8 @@ def phase_kv_ring(sizes, ctx):
                    "times the next larger bucket's program: %s"
                    % (slow, BUCKET_RATIO, prefill_ms))
         if platform == "tpu":
-            others = stepped_by["kernel_calls"] if scanned else 0
+            others = ((stepped_by["kernel_calls"] if scanned else 0)
+                      + (ssm_by["kernel_calls"] if mamba else 0))
             _check(facts["kernel_calls"] - others == ring_layers,
                    "%d attention kernel calls in a decode program of %d "
                    "attention layers" % (facts["kernel_calls"] - others,
@@ -990,6 +1054,30 @@ def phase_kv_ring(sizes, ctx):
                    "%(kernel_layers)d" % stepped_by)
             _check(not stepped_by["row_pages"], "the decode program holds "
                    "arrays of rows x page size: %(row_pages)s" % stepped_by)
+        if mamba:
+            print("[chip_smoke] kv_ring: the %(rows)d-row decode step of "
+                  "%(layers)d Mamba-2 layer(s): %(kernel_calls)d "
+                  "step-kernel call(s), copies of a state buffer: "
+                  "%(copies)s, arrays of rows x page size: %(row_pages)s; "
+                  "state bytes booked and of them the kernel's: %(booked)s; "
+                  "%(ms)s ms a warm step" % ssm_by, flush=True)
+            total["ssm_step"].append(ssm_by)
+            state_bytes, kernel_bytes = ssm_by["booked"]
+            _check(state_bytes > 0 and kernel_bytes == state_bytes * (
+                       ssm_by["kernel_layers"] == mamba),
+                   "the batcher booked %d state bytes and %d of them for "
+                   "the step kernel on %s" % (state_bytes, kernel_bytes,
+                                              platform))
+        if mamba and platform == "tpu":
+            _check(ssm_by["kernel_layers"] == ssm_by["kernel_calls"]
+                   == ssm_by["layers"],
+                   "%(kernel_calls)d step-kernel calls in a decode program "
+                   "of %(layers)d Mamba-2 layers, of which the shape "
+                   "function gives the kernel %(kernel_layers)d" % ssm_by)
+            _check(not ssm_by["copies"] and not ssm_by["row_pages"],
+                   "the decode program copies a Mamba-2 state buffer "
+                   "(%(copies)s) or holds arrays of rows x page size: "
+                   "%(row_pages)s" % ssm_by)
         for key in ("ring_params", "aliased", "kernel_calls"):
             total[key] += facts[key]
         total["copies"] += len(facts["copies"])

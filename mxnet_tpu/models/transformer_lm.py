@@ -1079,12 +1079,19 @@ class _Mamba2(_Recurrent):
         return (own["groups"] >= 1 and own["heads"] % own["groups"] == 0
                 and own["conv"] >= 2)
 
-    def counters(self, i, positions=0, rows=0, **call):
+    def counters(self, i, positions=0, rows=0, platform=None, **call):
         """What one program call adds: the bucket positions a prefill
-        scans in this layer (the pad included), and the bytes of window
-        and state a decode step's `rows` rows read and write."""
+        scans in this layer (the pad included), the bytes of window and
+        state a decode step's `rows` rows read and write, and those of
+        them that a program lowered for `platform` moves with the step
+        kernel (all, or none where ``ops.ssm.step_heads`` says the
+        ``jax.numpy`` body runs)."""
+        page = self._page_bytes(i)
+        stepped = _ssm.step_heads((1,) + self.state_shapes[1], platform,
+                                  self.attrs["n_groups"]) is not None
         return {"ssm.scan_positions": positions,
-                "ssm.state_bytes": 2 * rows * self._page_bytes(i)}
+                "ssm.state_bytes": 2 * rows * page,
+                "ssm.step_kernel_bytes": 2 * rows * page * stepped}
 
 
 class _GatedDeltaNet(_Recurrent):

@@ -27,9 +27,23 @@ projection consumes:
   buffers — nothing of the slot's previous tenant survives a prefill.
 * ``_ssm_step`` — one position for B packed decode rows, each at its
   slot: read the slot's window and state, advance one step, write both
-  back with one ``dynamic_update_slice`` a row (in place under the serve
-  program's donation, as the KV ring's ``_write_rows``).  Padded rows
-  point at the scratch slot and dirty only it.
+  back where they lay (in place under the serve program's donation, as
+  the KV ring's ``_write_rows``).  Padded rows point at the scratch slot
+  and dirty only it.  The state's update — one multiply-add a state
+  element, one reduction over the states — is ONE Pallas kernel a layer
+  on a TPU (``ops/ssm_step_kernel.py``: grid ``(row, block of heads)``,
+  the block of ``slot[b]``'s page brought to VMEM by the pipeline,
+  advanced and sent back: a page is read once and written once, and the
+  buffer never leaves HBM whole) wherever ``step_heads`` says the kernel
+  tiles the state's shape; ``lax.platform_dependent`` chooses at
+  lowering, and `_step_body` — a ``dynamic_index``, the advance and a
+  ``dynamic_update_slice`` a row — is what runs everywhere else and the
+  kernel's oracle (XLA made some forty fusions a layer of it and staged
+  four of granite-4.0-h-small's nine 37.7 MB buffers whole through its
+  fast memory: 62% of the chip's bandwidth, PERF.md section 6, PR 58).
+  The kernel is lowered once a shape for all programs and processes
+  (``ops/exported.py``).  The window, the conv, ``dt`` and the gated norm
+  are XLA's on every platform.
 
 Stored shapes belong to the model (``TransformerLM.cache_spec``): a conv
 window is ``(slots, d_conv - 1, conv_dim)`` — the channels on the lanes —
@@ -39,16 +53,24 @@ Precision: everything after the projection — the conv, ``softplus``,
 ``exp``, the cumulative decays, the block products of the scan and the
 gated norm — is float32, and the scan's matrix products run at
 ``highest`` (they are a few percent of a layer's operations; at one
-bfloat16 pass the carried state would round like a bf16 recurrence).
-Pure ``jax.numpy`` / ``lax``, differentiable.
+bfloat16 pass the carried state would round like a bf16 recurrence); the
+step's products are multiply-adds on the vector unit, in the kernel as in
+the body.  ``_ssm_scan`` and ``_ssm_prefill`` are pure ``jax.numpy`` /
+``lax``, and so is ``_ssm_step`` off the TPU; all are differentiable
+(the kernel has no backward: a gradient through a step takes the
+body's, `_kernel_step_bwd`).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, nn as jnn
 
-from .attention import _as_index
+from . import exported
+from .attention import _LANES, _as_index
 from .registry import register
 from .tensor import _lit
 
@@ -230,17 +252,107 @@ def ssm_prefill(data, conv_weight, conv_bias, dt_bias, A_log, D, norm_gamma,
     return y, conv_state, ssm_state
 
 
+_STEP_BLOCK_BYTES = 1 << 20
+_SUBLANES = 8
+
+
+def step_heads(state_shape, platform, groups=1):
+    """Heads that one grid step of the TPU's decode kernel holds
+    (``ops/ssm_step_kernel.py``), for a state stored `state_shape`
+    ``(slots, H, P, S)`` of float32 whose heads share ``B`` and ``C`` in
+    `groups`: the most heads that divide ``H``, lie in one group or are
+    whole groups, and make a block ``(heads, P, S)`` of at most 1 MiB — the
+    pipeline holds two of them coming and two going, and a row of several
+    blocks has the next one on its way while one is advanced — or one head
+    where a head alone is larger.  granite-4.0-h-small's ``(9, 128, 64,
+    128)`` and granite-4.0-h-micro's ``(9, 64, 64, 128)``, heads of 32 KiB:
+    32, a quarter and a half of a page.  None where `_ssm_step` runs its
+    ``jax.numpy`` body: off the TPU, or for a state the kernel's tiling
+    does not divide — ``P`` no whole number of 8-row tiles, ``S`` no whole
+    number of 128-lane tiles, or a head beyond 4 MiB.  Whoever counts what
+    a decode step runs (``TransformerLM.call_counters``) asks here."""
+    _, h, p, s = state_shape
+    block = lambda n: 4 * n * p * s                   # bytes, float32
+    if (platform != "tpu" or p % _SUBLANES or s % _LANES
+            or block(1) > 4 * _STEP_BLOCK_BYTES):
+        return None
+    per_group = h // int(groups)
+    fit = [n for n in range(1, h + 1) if h % n == 0
+           and (n % per_group == 0 or per_group % n == 0)]
+    return max([n for n in fit if block(n) <= _STEP_BLOCK_BYTES] or fit[:1])
+
+
+def _step_body(dtx, decay, b, c, state, slot):
+    """One position of the recurrence for B rows: ``dtx (B, H, P)``,
+    ``decay (B, H)``, ``b`` / ``c (B, G, S)``, row i's page at ``slot[i]``
+    of ``state (slots, H, P, S)``.  The rows' pages are advanced in row
+    order, each read from its slot, stepped and written back with one
+    ``dynamic_update_slice`` — XLA keeps the three in one in-place fusion
+    on the donated buffer (padded rows all land on the scratch slot, one
+    after the other).  Returns ``(y (B, H, P)`` without the ``D`` term,
+    ``state')``."""
+    h = dtx.shape[1]
+    b, c = (jnp.repeat(v, h // v.shape[1], axis=1) for v in (b, c))
+    ys = []
+    for i in range(dtx.shape[0]):
+        page = lax.dynamic_index_in_dim(state, slot[i], 0, keepdims=False)
+        page = (page.astype(jnp.float32) * decay[i][:, None, None]
+                + dtx[i][..., None] * b[i][:, None, :])
+        ys.append((page * c[i][:, None, :]).sum(axis=-1))
+        state = lax.dynamic_update_slice(
+            state, page[None].astype(state.dtype), (slot[i], 0, 0, 0))
+    return jnp.stack(ys), state
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _kernel_step(heads, interpret, *operands):
+    def kernel(*operands):
+        return exported.call("ssm_step_kernel", "state_step", operands,
+                             interpret=interpret, heads=heads)
+    return lax.platform_dependent(*operands, tpu=kernel, default=_step_body)
+
+
+def _kernel_step_fwd(heads, interpret, *operands):
+    return _kernel_step(heads, interpret, *operands), operands
+
+
+def _kernel_step_bwd(heads, interpret, operands, cotangents):
+    # the kernel has no backward: a gradient takes the body's
+    *floats, slot = operands
+    _, vjp = jax.vjp(lambda *floats: _step_body(*floats, slot), *floats)
+    return vjp(cotangents) + (np.zeros(slot.shape, jax.dtypes.float0),)
+
+
+_kernel_step.defvjp(_kernel_step_fwd, _kernel_step_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "interpret"))
+def _state_step(dtx, decay, b, c, state, slot, *, heads, interpret):
+    """`_step_body` on whatever platform the program is lowered for: the
+    TPU's kernel (`heads` heads a grid step; `interpret` runs it in
+    Pallas's interpreter, for tests) or the ``jax.numpy`` body, which is
+    also every platform's backward.  Jitted, so that the Mamba-2 layers of
+    a decode program trace and lower both once."""
+    operands = (dtx, decay, b, c, state, slot)
+    if heads is None:
+        return _step_body(*operands)
+    return _kernel_step(heads, interpret, *operands)
+
+
+# Pallas's interpreter in place of the TPU's kernel (tests)
+_INTERPRET = False
+
+
 @register("_ssm_step",
           inputs=("data",) + PARAMS + ("conv_state", "ssm_state", "slot"),
           num_outputs=3, infer_shape=_infer_stateful)
 def ssm_step(data, conv_weight, conv_bias, dt_bias, A_log, D, norm_gamma,
              conv_state, ssm_state, slot, **kw):
     """One decode step of the mixer for B packed rows: ``data (B, 1,
-    d_proj)``, row b's window and state at ``slot[b]``.  The rows' states
-    are advanced in row order, each read from its slot, stepped and
-    written back with one ``dynamic_update_slice`` (padded rows all land
-    on the scratch slot, one after the other).  Outputs ``y (B, 1, d_inner)`` and the two updated
-    buffers."""
+    d_proj)``, row b's window and state at ``slot[b]``, each advanced by
+    one position and written back where it lay (the state by `_state_step`;
+    padded rows all land on the scratch slot).  Outputs ``y (B, 1,
+    d_inner)`` and the two updated buffers."""
     attrs = _attrs(kw)
     h, p, s, g, k = _sizes(attrs)
     rows = data.shape[0]
@@ -252,29 +364,26 @@ def ssm_step(data, conv_weight, conv_bias, dt_bias, A_log, D, norm_gamma,
                                                  keepdims=False)
                         for i in range(rows)]).astype(jnp.float32),
              xbc_raw[:, None]], axis=1)                   # (B, K, C)
+        for i in range(rows):
+            conv_state = lax.dynamic_update_slice(
+                conv_state, window[i, 1:][None].astype(conv_state.dtype),
+                (slot_i[i], 0, 0))
         x, b, c = _split_xbc(
             jnn.silu((window * conv_weight).sum(axis=1) + conv_bias),
             h, p, s, g)
         dt = jnn.softplus(dt + dt_bias)                   # (B, H)
         decay = jnp.exp(dt * -jnp.exp(A_log.astype(jnp.float32)))
-        b, c = (jnp.repeat(v, h // g, axis=1) for v in (b, c))  # (B, H, S)
-        dtx = dt[..., None] * x                           # (B, H, P)
-        ys = []
-        for i in range(rows):
-            # read, advance and write row i's page where it lies: XLA keeps
-            # the three in one in-place fusion on the donated buffer
-            page = lax.dynamic_index_in_dim(ssm_state, slot_i[i], 0,
-                                            keepdims=False)
-            page = (page.astype(jnp.float32) * decay[i][:, None, None]
-                    + dtx[i][..., None] * b[i][:, None, :])
-            ys.append((page * c[i][:, None, :]).sum(axis=-1))
-            ssm_state = lax.dynamic_update_slice(
-                ssm_state, page[None].astype(ssm_state.dtype),
-                (slot_i[i], 0, 0, 0))
-            conv_state = lax.dynamic_update_slice(
-                conv_state, window[i, 1:][None].astype(conv_state.dtype),
-                (slot_i[i], 0, 0))
-        y = jnp.stack(ys) + D[:, None] * x
-        y = _gated_norm(y.reshape(rows, h * p), z, norm_gamma,
-                        float(_lit(attrs["eps"])))
+        # the heads a lowering for the TPU would hold at a time; which
+        # platform the program is lowered for is not known here.  Called
+        # on arrays, not traced into a program (``mx.nd``'s eager path),
+        # the step is a program of its own that donates nothing: the body
+        # (the kernel's pinned state needs the donation a whole program's
+        # caller gives it: ops/ssm_step_kernel.py)
+        traced = isinstance(ssm_state, jax.core.Tracer)
+        y, ssm_state = _state_step(
+            dt[..., None] * x, decay, b, c, ssm_state, slot_i,
+            interpret=_INTERPRET,
+            heads=step_heads(ssm_state.shape, "tpu", g) if traced else None)
+        y = _gated_norm((y + D[:, None] * x).reshape(rows, h * p), z,
+                        norm_gamma, float(_lit(attrs["eps"])))
     return y[:, None].astype(data.dtype), conv_state, ssm_state

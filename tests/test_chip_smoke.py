@@ -86,7 +86,12 @@ TINY = {
                                         value_dim=16, head_gate=True,
                                         window=16)},
                                 norm="rms", positions="none",
-                                bias=False)]},
+                                bias=False),
+                           dict(num_heads=2, max_len=48,
+                                layer_types=["mamba", "attention"],
+                                mamba_heads=4, mamba_head_dim=8,
+                                mamba_state=16, norm="rms",
+                                positions="none", bias=False)]},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
 }
@@ -130,13 +135,14 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     # value heads beside one layer's rings of heads of 32, then two latent
     # layers' ONE ring each of 24 + 8 lines, then a selected layer's ring
     # of 24 + 8 lines with its index keys beside a window layer's latent
-    # ring of 16 positions; the CPU's programs hold no kernel call
-    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2 + 3
+    # ring of 16 positions, then a Mamba-2 layer's window and state beside
+    # one layer's rings; the CPU's programs hold no kernel call
+    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2 + 3 + 4
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
                                           [3, 2, 16, 48], [3, 2, 16, 16],
                                           [3, 2, 16, 48], [3, 2, 32, 48],
                                           [3, 1, 32, 48], [3, 1, 32, 48],
-                                          [3, 1, 40, 16]]
+                                          [3, 1, 40, 16], [3, 2, 16, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
     # nor does a shape rule send a bucket through a blockwise kernel
     assert report["kv_ring"]["kernel_buckets"] == 0
@@ -161,9 +167,24 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert "f32[2,8,2,16]" in steps[0]["row_pages"]
     assert "f32[2,8,4,8]" in steps[1]["row_pages"]
     assert all(step["ms"] > 0 for step in steps)
+    # the last shape's decode program, read for the Mamba-2 step: one such
+    # layer, no kernel in a program lowered for the CPU (nor on any
+    # platform for a state of 16 lanes), whose body advances the rows'
+    # pages one by one and gathers nothing (the CPU donates no buffer, so
+    # its program copies them: judged on a device only)
+    step, = report["kv_ring"]["ssm_step"]
+    assert {k: v for k, v in step.items()
+            if k not in ("ms", "copies", "booked")} == {
+        "kernel_calls": 0, "row_pages": [], "rows": 2, "layers": 1,
+        "kernel_layers": 0}
+    # three tokens after the first, one row a step: the batcher booked
+    # their window-and-state bytes, and none of them for the kernel
+    window, state = 3 * (4 * 8 + 2 * 16) * 4, 4 * 8 * 16 * 4
+    assert step["booked"] == [2 * 3 * (window + state), 0]
+    assert step["ms"] > 0
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
-        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
+        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
     chip_smoke.run_phase("four_chips", chip_smoke.phase_four_chips,
@@ -238,6 +259,45 @@ def test_the_delta_rule_facts_name_what_is_split_into_heads(why, line, found):
                                                              (30, 192)]
     assert chip_smoke.delta_rule_hlo_facts(line, pairs)["head_arrays"] \
         == found, why
+
+
+_MAMBA_ENTRY = ("ENTRY %main (p: f32[9,128,64,128]) -> f32[8,1] {\n"
+                "%p = f32[9,128,64,128]{3,2,1,0:T(8,128)} parameter(0)\n")
+
+
+@pytest.mark.parametrize("why,line,found", [
+    ("the step kernel, its state in HBM and aliased",
+     '%k = (f32[8,4,64,32]{3,2,1,0:T(8,128)S(1)}, f32[9,128,64,128]{3,2,1,0:'
+     'T(8,128)}) custom-call(%slot, %decay, %cols, %rows, %p), '
+     'custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/'
+     'l0_ssm/mx:ssm.step/ssm_step_kernel/pallas_call"}',
+     {"kernel_calls": 1, "row_pages": [], "copies": []}),
+    ("a state buffer staged whole through the compiler's fast memory",
+     '%c = (f32[9,128,64,128]{3,2,1,0:T(8,128)}, f32[9,128,64,128]{3,2,1,0:'
+     'T(8,128)S(1)}, u32[]{:S(2)}) copy-start(%g)',
+     {"kernel_calls": 0, "row_pages": [], "copies": [
+         "%c = (f32[9,128,64,128]{3,2,1,0:T(8,128)}, f32[9,128,64,128]"
+         "{3,2,1,0:T(8,128)S(1)}, u32[]{:S(2)}) copy-start(%g)"]}),
+    ("the rows' pages gathered",
+     '%g = f32[8,128,64,128]{3,2,1,0} gather(%p, %slot)',
+     {"kernel_calls": 0, "row_pages": ["f32[8,128,64,128]"], "copies": []}),
+    ("gathered with the heads' channels folded",
+     '%g = f32[8,8192,128]{2,1,0} fusion(%p, %slot)',
+     {"kernel_calls": 0, "row_pages": ["f32[8,8192,128]"], "copies": []}),
+    ("another kernel's call, a row's inputs and the delta rule's state",
+     '%k = (f32[8,1,4096]{2,1,0}, f32[9,128,4096]{2,1,0}) custom-call(%s), '
+     'custom_call_target="tpu_custom_call", metadata={op_name="jit(f)/'
+     'mx:gdn.step/gdn_state_step/pallas_call"}\n'
+     '%d = f32[8,128,64]{2,1,0} multiply(%dt, %x)',
+     {"kernel_calls": 0, "row_pages": [], "copies": []})])
+def test_the_mamba2_step_facts_name_copies_and_gathered_pages(why, line,
+                                                              found):
+    """`ssm_step_hlo_facts` on what an 8-row decode program may make of a
+    Mamba-2 state of 128 heads x 64 x 128: the step kernel's call, a copy
+    of the buffer (within HBM or into fast memory), the rows' pages
+    gathered."""
+    assert chip_smoke.ssm_step_hlo_facts(
+        _MAMBA_ENTRY + line + "\n}\n", 8, (9, 128, 64, 128)) == found, why
 
 
 @pytest.mark.parametrize("why,line,found", [

@@ -1,5 +1,6 @@
-"""The decode-attention kernel, the prefill's blockwise attention kernel
-and the delta rule's prefill and step kernels compiled for a TPU v5e that is described, not attached (the TPU's
+"""The decode-attention kernel, the prefill's blockwise attention kernel,
+the delta rule's prefill and step kernels and the Mamba-2 step kernel
+compiled for a TPU v5e that is described, not attached (the TPU's
 compiler is installed where the tests run): what Pallas's interpreter
 cannot see — Mosaic refusing a slice, a layout or the fast memory a
 kernel asks for — at the real widths of the benchmark's decoders.
@@ -12,7 +13,7 @@ import numpy as np
 import pytest
 
 import chip_smoke
-from mxnet_tpu.ops import attention, gdn, latent
+from mxnet_tpu.ops import attention, gdn, latent, ssm
 
 SLOTS = 9
 # (rows, query heads, K/V heads, d_head, ring length, scale[, wraps]);
@@ -385,6 +386,42 @@ def test_the_delta_rule_step_compiles_for_a_v5e(widths, one_chip):
     assert facts["copies"] == []
 
 
+# name -> (chip_smoke.py's kv_ring model, rows, heads a grid step)
+MAMBA_STEP_MODELS = {"granite_h_small": (8, 8, 32),
+                     "granite_h_micro": (9, 8, 32)}
+
+
+@pytest.mark.parametrize("widths", sorted(MAMBA_STEP_MODELS))
+def test_the_mamba2_step_compiles_for_a_v5e(widths, one_chip):
+    """The 8-row decode program of `chip_smoke.py`'s two Mamba-2 models —
+    a Mamba-2 layer at granite-4.0-h-small's state (128 heads of 64 x 128)
+    and at granite-4.0-h-micro's (64 heads), each beside an attention
+    layer — lowered for the TPU: ONE step-kernel call beside the ring's,
+    no array of ``rows x H x P x S`` elements (the rows' pages gathered),
+    and the state aliased to its output and never copied — neither within
+    HBM nor staged whole through the compiler's fast memory, as XLA did
+    with four of nine layers' buffers around the ``jax.numpy`` form (PR
+    54).  The whole program, not the op alone (PR 41: XLA stages a whole
+    state around a lone kernel)."""
+    import warnings
+
+    index, rows, heads = MAMBA_STEP_MODELS[widths]
+    lm = _smoke_model(index)
+    spec = lm.cache_spec(rows + 1)
+    state = spec["ssm_state_0"].shape
+    assert ssm.step_heads(state, "tpu") == heads
+    ssm._state_step.clear_cache()
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        text = _serving_program(lm.decode_symbol(), _wire(spec, rows),
+                                one_chip).as_text()
+    assert chip_smoke.ssm_step_hlo_facts(text, rows, state) == {
+        "kernel_calls": 1, "row_pages": [], "copies": []}
+    facts = chip_smoke.ring_hlo_facts(text, state)
+    assert facts["kernel_calls"] == 2      # the ring's and the step's
+    assert facts["ring_params"] == facts["aliased"] == 1
+
+
 def test_the_latent_decode_program_compiles_for_a_v5e(one_chip):
     """The whole 16-row decode program of `chip_smoke.py`'s seventh model
     — two latent-attention layers at Mistral-Small-4's widths, 32 heads
@@ -728,8 +765,12 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
     eight slots of 1,536 positions, the 8-row step and the 1,024 bucket —
     lowered for the TPU: every cache entry (conv windows, Mamba states,
     the attention layer's two rings) is aliased to its output and none is
-    copied within HBM (the step stages some layers' state in the
-    compiler's fast memory, one write-out each); the weights
+    copied within HBM; the step advances each Mamba-2 layer's state in ONE
+    kernel call (``ops/ssm_step_kernel.py``, PR 58) and stages no state
+    buffer in the compiler's fast memory (XLA did, four of nine layers'
+    37.7 MB around the ``jax.numpy`` form, one write-out each: 75 MB a
+    layer where the eight rows' pages read and written in place are 67,
+    PERF.md section 6, PR 54), at most the 0.9 MB windows; the weights
     are the 8.22 GB the configuration's `reduced_why` reckons; weights,
     the tenant's nine bound cache sets and the larger program's
     temporaries fit a v5e.  The expert layers (PR 55): the step gathers
@@ -760,21 +801,25 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
         warnings.simplefilter("ignore")
         compiled = _serving_program(graph, wire, one_chip)
     text, stats = compiled.as_text(), compiled.memory_analysis()
-    recurrent = {e.shape for e in spec.values() if e.kind == "state"}
+    windows = {e.shape for n, e in spec.items() if n.startswith("conv_")}
+    state, = {e.shape for n, e in spec.items() if n.startswith("ssm_state")}
+    assert len(windows) == 1
     for shape in sorted({e.shape for e in spec.values()}):
         count = sum(e.shape == shape for e in spec.values())
         facts = chip_smoke.ring_hlo_facts(text, shape)
         assert facts["ring_params"] == facts["aliased"] == count, shape
-        if shape in recurrent and program == "decode":
-            # the step's compiler advances some layers' windows (0.9 MB)
-            # and states (37.7 MB: four of nine layers) in its fast
-            # memory, S(1), and writes the buffer out once — 75 MB a layer
-            # where the eight rows' pages read and written in place are 67
-            # (PERF.md section 6, PR 54); no buffer is copied within HBM
+        if shape in windows and program == "decode":
+            # the step's compiler may advance a layer's window (0.9 MB,
+            # XLA's on every platform) in its fast memory, S(1), and write
+            # it out once; no buffer is copied within HBM
             assert all("S(1)" in line for line in facts["copies"]), shape
             assert len(facts["copies"]) <= count, shape
         else:
             assert facts["copies"] == [], shape
+    layers = config["layer_types"].count("mamba")
+    stepped = chip_smoke.ssm_step_hlo_facts(text, rows, state)
+    assert stepped == {"kernel_calls": layers * (program == "decode"),
+                       "row_pages": [], "copies": []}
     sets = sum(e.nbytes for e in spec.values())
     assert 0.46e9 < sets < 0.4625e9        # nine pages of 51.2 MB
     assert stats.alias_size_in_bytes >= sets
