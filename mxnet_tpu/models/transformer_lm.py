@@ -122,6 +122,12 @@ kind its indexer's `index_heads` x `index_dim` and `index_topk`, the window
 kind its `window` —, ``ffn_types`` of a leading ``"dense"`` layer before
 ``"routed"`` ones, sigmoid scores with ``router_bias`` and ``held_experts``
 are dots3-note-prev's (`dots3`).
+``layer_types`` of one ``"attention"`` WITHOUT a position signal to three
+``"window_attention"`` with rotary positions, 28 query heads over 4 K/V
+heads (groups of seven), every layer ``"routed"`` with ``router_input=
+"mixer"`` (the router scores what the block's mixer reads, before the
+mixer runs), ``expert_act="relu"`` (a ReLU gate), ``route_norm`` and no
+shared expert, an untied head: SmallThinker's (`smallthinker`).
 
 **A draft module.**  `nextn` = 1 adds DeepSeek-V3's multi-token-prediction
 module behind the trunk (GLM-5's, `glm5`): two norms (``mtp_enorm``,
@@ -494,19 +500,34 @@ class _WindowAttention(_Attention):
             attn[1], attn[2], slot, length, window=self.lm.sliding_window,
             name="l%d_kv_write" % i)
 
-    def counters(self, i, rows=0, lengths=(), pages=0, max_len=None,
-                 **call):
-        """What every attention layer adds, and of one decode step: a
-        window row for each real row of this layer, those of them whose
-        ring has wrapped (``length >= W``: the row is written modulo and
-        the whole ring is read), and the bytes of this layer's rings
-        among the `pages` pages bound."""
+    def counters(self, i, positions=0, platform=None, rows=0, lengths=(),
+                 pages=0, max_len=None, **call):
+        """What every attention layer adds; of a prefill, the key blocks
+        the TPU's blockwise kernel visits of one K/V head under the window
+        and those a causal prefill of the bucket would
+        (``ops.attention.prefill_visits``; one each where the ``jax.numpy``
+        body computes every score); and of one decode step: a window row
+        for each real row of this layer, those of them whose ring has
+        wrapped (``length >= W``: the row is written modulo and the whole
+        ring is read), and the bytes of this layer's rings among the
+        `pages` pages bound."""
+        lm = self.lm
         page = sum(e.nbytes for _, e in self.cache_spec(
-            i, 1, self.lm.max_len if max_len is None else max_len))
-        wrapped = sum(1 for n in lengths if n >= self.lm.sliding_window)
-        return dict(super().counters(i, **call), **{
-            "kv.window_rows": rows, "kv.wrapped_rows": wrapped,
-            "cache.window_bytes": pages * page})
+            i, 1, lm.max_len if max_len is None else max_len))
+        wrapped = sum(1 for n in lengths if n >= lm.sliding_window)
+        band = causal = 0
+        if positions:
+            # the ``jax.numpy`` body computes ONE block, the whole square
+            block = _attention.prefill_block(
+                (1, positions, self.q_width), lm.num_heads, lm.num_kv_heads,
+                platform) or (positions, positions)
+            band, causal = (_attention.prefill_visits(positions, block, w)
+                            for w in (lm.sliding_window, None))
+        return dict(
+            super().counters(i, positions=positions, platform=platform),
+            **{"attn.band_blocks": band, "attn.causal_blocks": causal,
+               "kv.window_rows": rows, "kv.wrapped_rows": wrapped,
+               "cache.window_bytes": pages * page})
 
 
 class _KindLatent:
@@ -1203,7 +1224,7 @@ class _DenseFFN:
             p["ffn2_bias"] = v("l%d_ffn2_bias" % i, shape=(d,))
         return p
 
-    def apply(self, x, p, i, loads):
+    def apply(self, x, p, i, loads, mixer_in=None):
         lm = self.lm
         if lm.ffn == "swiglu":
             a, b = sym.SliceChannel(
@@ -1219,8 +1240,10 @@ class _DenseFFN:
 
 class _RoutedFFN:
     """The routed FFN of layer i (``mx.sym.MoE``, dropless):
-    `experts_per_token` of `num_experts` SwiGLU experts of width
-    `expert_d_ff` by the router's scores, plus — `shared_d_ff` — one
+    `experts_per_token` of `num_experts` gated experts (`expert_act`:
+    SwiGLU, or a ReLU gate) of width `expert_d_ff` by the router's scores
+    — of the FFN's own normed input or, `router_input` ``"mixer"``, of what
+    the block's mixer read —, plus — `shared_d_ff` — one
     expert every token passes, times — `shared_gate` — the sigmoid of its
     own score ``x w_s``, ``w_s`` the ``(d_model, 1)``
     ``l<i>_shared_score_weight``.  `held_experts` ``(first, count)`` are the
@@ -1246,6 +1269,8 @@ class _RoutedFFN:
             self.attrs["shared_gate"] = True
         if held is not None:
             self.attrs.update(held_first=held[0], held_count=held[1])
+        if lm.router_input == "mixer":
+            self.attrs["router_input"] = True
 
     def params(self, i):
         lm, v = self.lm, sym.Variable
@@ -1270,7 +1295,7 @@ class _RoutedFFN:
                                          shape=(d, 1))
         return p
 
-    def apply(self, x, p, i, loads):
+    def apply(self, x, p, i, loads, mixer_in=None):
         lm = self.lm
         operands = [x, p["router_weight"]]
         operands += [p["router_bias"]] if lm.router_bias else []
@@ -1280,9 +1305,11 @@ class _RoutedFFN:
                          p["shared_up_weight"]]
         if lm.shared_gate:
             operands.append(p["shared_score_weight"])
+        if lm.router_input == "mixer":
+            operands.append(mixer_in)
         f = sym.MoE(*operands, num_experts=lm.num_experts,
                     hidden_size=lm.expert_d_ff, k=lm.experts_per_token,
-                    act_type="silu", gated=True, no_bias=True,
+                    act_type=lm.expert_act, gated=True, no_bias=True,
                     normalize=lm.route_norm,
                     return_load=loads is not None, name="l%d_moe" % i,
                     **self.attrs)
@@ -1358,7 +1385,11 @@ class TransformerLM:
     `route_norm` renormalises the chosen scores, `route_scale` multiplies
     them; `held_experts` ``(first, count)`` — the experts whose matrices
     this model holds, one chip's share: the router stays `num_experts`
-    wide; `rope_scaling` — YaRN's ``{factor,
+    wide; `router_input` ``"ffn"`` | ``"mixer"`` — what a routed FFN's
+    router scores: the FFN's own normed input, or the normed stream the
+    block's MIXER read (the choice of experts is then known before the
+    mixer has run); `expert_act` ``"silu"`` | ``"relu"`` — the routed
+    experts' gate activation; `rope_scaling` — YaRN's ``{factor,
     original_max_position_embeddings, beta_fast, beta_slow[, mscale,
     mscale_all_dim]}`` for the ``"latent_attention"`` kind's rotary part;
     `query_scale` ``(beta, period)`` — that kind's query at position p
@@ -1410,7 +1441,7 @@ class TransformerLM:
                  router_bias=False, route_norm=False, route_scale=1.0,
                  held_experts=None, rotary_dim=None, shared_gate=False,
                  rope_scaling=None, query_scale=None, kind_specs=None,
-                 nextn=0, **flat):
+                 nextn=0, router_input="ffn", expert_act="silu", **flat):
         if int(nextn) not in (0, 1):
             raise ValueError("nextn must be 0 or 1 (ONE draft a step), got %r"
                              % (nextn,))
@@ -1494,6 +1525,18 @@ class TransformerLM:
                                           ffn_types))
         if "routed" in ffn_types and not num_experts:
             raise ValueError("a 'routed' FFN needs num_experts >= 1")
+        if router_input not in ("ffn", "mixer"):
+            raise ValueError("router_input must be 'ffn' or 'mixer', got %r"
+                             % (router_input,))
+        if expert_act not in ("silu", "relu"):
+            raise ValueError("expert_act must be 'silu' or 'relu', got %r"
+                             % (expert_act,))
+        for name, value, default in (("router_input", router_input, "ffn"),
+                                     ("expert_act", expert_act, "silu")):
+            if value != default and "routed" not in ffn_types:
+                raise ValueError("%s=%r is a routed FFN's: no layer of "
+                                 "ffn_types %r is 'routed'"
+                                 % (name, value, ffn_types))
         self.vocab = int(vocab)
         self.num_layers = int(num_layers)
         self.num_heads = int(num_heads)
@@ -1533,6 +1576,8 @@ class TransformerLM:
         self.route_norm = bool(route_norm)
         self.route_scale = float(route_scale)
         self.held_experts = held_experts
+        self.router_input = router_input
+        self.expert_act = expert_act
         self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
         self.shared_gate = bool(shared_gate)
         self.rope_scaling = rope_scaling
@@ -1629,13 +1674,14 @@ class TransformerLM:
             branch = branch * self.residual_multiplier
         return h + branch
 
-    def _ffn(self, h, p, i, train, loads=None, ffn=None):
+    def _ffn(self, h, p, i, train, loads=None, ffn=None, mixer_in=None):
         """The block's second half on the residual stream `h`: layer i's
         FFN kind between the block's norms.  A routed model's serving
         graphs pass `loads`, which collects each routed layer's
-        tokens-per-expert output."""
+        tokens-per-expert output; `mixer_in` is what the block's mixer
+        read, for a router that reads it too (`router_input`)."""
         x = self._branch_in(h, "l%d_ln2" % i)
-        f = (ffn or self._ffns[i]).apply(x, p, i, loads)
+        f = (ffn or self._ffns[i]).apply(x, p, i, loads, mixer_in)
         f = self._branch_out(f, "l%d_ln2" % i)
         if train and self.dropout > 0:
             f = sym.Dropout(f, p=self.dropout, name="l%d_drop" % i)
@@ -1648,7 +1694,7 @@ class TransformerLM:
         if train and self.dropout > 0:
             a = sym.Dropout(a, p=self.dropout, name="l%d_adrop" % i)
         h = self._join(h, a)
-        return self._ffn(h, p, i, train)
+        return self._ffn(h, p, i, train, mixer_in=x)
 
     def _embed(self, data, index=None, tables=None, tag=""):
         """Token embedding (plus the learned position table's rows: each
@@ -1867,7 +1913,8 @@ class TransformerLM:
         y, state = mix(mixer, x, p, i)
         outs += state
         h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
-        return self._ffn(h, p, i, train=False, loads=loads, ffn=ffn)
+        return self._ffn(h, p, i, train=False, loads=loads, ffn=ffn,
+                         mixer_in=x)
 
     def _drafted(self, stream, tokens, embed_w, mix, outs, loads):
         """The draft module on the trunk's last `stream` (before ``ln_f``)

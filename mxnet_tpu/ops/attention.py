@@ -344,6 +344,19 @@ def prefill_block(q_shape, num_heads, kv_heads, platform, causal=True):
     return (rows, keys) if held <= _PREFILL_VMEM else None
 
 
+def prefill_visits(t, block, window=None):
+    """The key blocks the TPU's prefill kernel visits for ONE K/V head of
+    a sequence of `t` positions tiled by `block` (``prefill_block``'s rows
+    and keys), summed over its grid steps — by the kernel's own bounds,
+    ``ops/sdp_kernel.py visited``: what the kernel skips, this does not
+    count."""
+    from .sdp_kernel import visited
+
+    rows, keys = block
+    return sum(end - lo for lo, end in (
+        visited(first, rows, keys, window) for first in range(0, t, rows)))
+
+
 def _masked_attention(qg, kh, vh, *, scale, window, causal=True):
     """Attention of ``qg (N, H_kv, r, T, d)`` — each K/V head's group of
     query heads; K and V are never repeated — over ``kh`` / ``vh (N,

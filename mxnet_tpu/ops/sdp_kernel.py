@@ -42,10 +42,24 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["causal_attention"]
+__all__ = ["causal_attention", "visited"]
 
 _F32 = jnp.float32
 _NEG = -1e30          # ops/attention.py's mask value: finite
+
+
+def visited(first, rows, keys, window, maximum=max):
+    """The key blocks ``[lo, end)`` of `keys` positions that the grid step
+    of query rows ``first .. first + rows`` walks: up to its last row's
+    block and, under a `window`, from the block that holds the first row's
+    oldest visible key, ``first - window + 1``.  THE rule of what the
+    kernel skips: the kernel walks these bounds (`first` traced, `maximum`
+    ``jnp.maximum``) and ``ops.attention.prefill_visits`` counts them (plain
+    integers)."""
+    end = (first + rows - 1) // keys + 1
+    if window is None:
+        return 0, end
+    return maximum(first - window + 1, 0) // keys, end
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -93,12 +107,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         lax.fori_loop(lo, hi, body, 0)
 
     # blocks [lo, end) are visible; of them [clear, diagonal) wholly so
-    end = (first + rows - 1) // keys + 1
+    lo, end = visited(first, rows, keys, window, jnp.maximum)
     diagonal = (first + 1) // keys
     if window is None:
-        lo = clear = 0
+        clear = 0
     else:
-        lo = jnp.maximum(first - window + 1, 0) // keys
         clear = jnp.clip(
             jnp.maximum(first + rows - 1 - window + keys, 0) // keys, lo,
             diagonal)

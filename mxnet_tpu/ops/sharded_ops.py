@@ -35,9 +35,10 @@ sort-and-segment (parallel/moe.py dropless_experts), no [T, E, C].  That
 form also takes what later routers brought: `score_func="sigmoid"`, a
 `select_bias` operand that moves the choice and not the weights,
 `route_scale`, an always-on shared expert (`shared_size`) with a sigmoid
-gate of its own (`shared_gate`), and
+gate of its own (`shared_gate`),
 `held_first` / `held_count` — WHICH of the `num_experts` the expert
-operands are, one chip's share of an expert-parallel layer.
+operands are, one chip's share of an expert-parallel layer — and
+`router_input`, one more operand that the router scores in `data`'s place.
 """
 from __future__ import annotations
 
@@ -52,7 +53,8 @@ def _moe_inputs(attrs):
     """data, the router (and its selection bias), then expert matrix i
     (and its bias): 1 = in, 2 = out, 3 = the gated branch's second
     in-projection; then the shared expert's matrices in the same order,
-    and its gate's ``(D, 1)`` column."""
+    and its gate's ``(D, 1)`` column; last, where the node says `router_input`,
+    the rows the router scores in `data`'s place."""
     gated = _bool_attr(attrs.get("gated", False))
     no_bias = _bool_attr(attrs.get("no_bias", False))
     names = ["data", "gate_weight"]
@@ -67,6 +69,8 @@ def _moe_inputs(attrs):
                                                   else (1, 2))]
     if _bool_attr(attrs.get("shared_gate", False)):
         names.append("shared_gate_weight")
+    if _bool_attr(attrs.get("router_input", False)):
+        names.append("router_data")
     return names
 
 
@@ -97,7 +101,8 @@ def _infer_moe(in_shapes, attrs):
                "expert2_weight": (E, H, D), "expert2_bias": (E, D),
                "expert3_weight": (E, D, H), "expert3_bias": (E, H),
                "shared1_weight": (D, S), "shared2_weight": (S, D),
-               "shared3_weight": (D, S), "shared_gate_weight": (D, 1)}
+               "shared3_weight": (D, S), "shared_gate_weight": (D, 1),
+               "router_data": data}
     outs = [tuple(data)] + [(E,)] * (_moe_outputs(attrs) - 1)
     return [by_slot[n] for n in _moe_inputs(attrs)], outs
 
@@ -124,7 +129,7 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
         capacity_factor=None, act_type="relu", gated=False, no_bias=False,
         normalize=True, return_load=False, score_func="softmax",
         select_bias=False, route_scale=1.0, shared_size=0,
-        shared_gate=False, mesh=None, **kw):
+        shared_gate=False, router_input=False, mesh=None, **kw):
     """Top-k routed expert FFN: out[t] = sum_e gate[t,e] * FFN_e(x[t])
     over t's top-k experts, FFN_e = ``act(x w1 + b1) @ w2 + b2``, or with
     `gated` ``(act(x w1 + b1) * (x w3 + b3)) @ w2 + b2``; `no_bias`
@@ -164,6 +169,7 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
     normalize = _bool_attr(normalize)
     act = str(_lit(act_type))
     operands = list(operands)
+    routed_on = operands.pop() if _bool_attr(router_input) else data
     bias = operands.pop(0) if _bool_attr(select_bias) else None
     shared, shared_score = (), None
     if _bool_attr(shared_gate):
@@ -182,17 +188,19 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
                    held=None if held == (0, E) else held)
     if capacity_factor is not None and (
             shared or bias is not None or options["held"]
+            or routed_on is not data
             or (options["score"], options["scale"]) != ("softmax", 1.0)):
         raise ValueError("MoE: score_func, select_bias, route_scale, "
-                         "shared_size and held_count are the dropless "
-                         "form's (no capacity_factor)")
+                         "shared_size, held_count and router_input are the "
+                         "dropless form's (no capacity_factor)")
     lead = data.shape[:-1]
     d_model = data.shape[-1]
     x = data.reshape(-1, d_model)
     T = x.shape[0]
 
     with jax.named_scope("mx:moe.route"):
-        logits = _moe.router_logits(x, gate_weight)
+        logits = _moe.router_logits(routed_on.reshape(-1, d_model),
+                                    gate_weight)
     if capacity_factor is None:
         with jax.named_scope("mx:moe.experts"):
             out, load = _moe.dropless_experts(

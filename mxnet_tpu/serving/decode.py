@@ -692,6 +692,7 @@ class GenerativeSession:
         self._occupancy = None
         self._prog_lock = locks.lock("serving.decode_progs")
         self._programs = {}
+        self._placeholders = {}  # the one zero-filled set `_program` keeps
         self._buckets = {}  # (kind, bucket) -> _Bucket, beside _programs
         self._tokens_done = 0
         self._closed = False
@@ -722,8 +723,10 @@ class GenerativeSession:
     def _fresh_state(self, on_device=False):
         """Zeroed cache entries and last-token vector, in wire order: on
         the host (the live set: it reaches the device with the first
-        call that takes it, after the warm-up's set is gone — two sets
-        at once do not fit beside OLMoE's prefill), or `on_device` (the
+        call that takes it, after the warm-up's set is gone, so the
+        device never holds both beside the bucket programs' placeholder
+        set, `_program` — OLMoE's prefill had no room for the two before
+        the placeholders were one set), or `on_device` (the
         warm-up's: gigabytes need not cross the host link to be
         zeros)."""
         shapes = [e.shape for e in self._spec.values()]
@@ -789,6 +792,15 @@ class GenerativeSession:
             if exe is None:
                 exe = self._programs[key] = pred.executor_for(
                     self._shapes(batch, seq, prefill))
+                # an executor binds a zero-filled array for each of its
+                # inputs, the cache entries among them, which no call
+                # reads (the live set is a call operand): all bucket
+                # programs hold ONE such set between them, the first
+                # bound, not one each
+                for name in (*self._spec, "last_token"):
+                    if name in exe.arg_dict:
+                        exe.arg_dict[name] = self._placeholders.setdefault(
+                            name, exe.arg_dict[name])
                 self._buckets[key] = _Bucket(
                     *key, tenant=self.name,
                     experts=self._expert_plan(batch, seq, prefill))
@@ -865,7 +877,7 @@ class GenerativeSession:
             o.copy_to_host_async()
         return small, list(outs[1:1 + n_state])
 
-    def _call(self, exe, fn, state, data, slot, length):
+    def _call(self, exe, fn, state, data, slot, length, riders=None):
         """One SYNCHRONOUS program call on `state`: returns (host
         logits, updated state, host outputs after the token).  What the
         warm-up and `_run` use; the batcher's own calls stay in flight
@@ -873,7 +885,7 @@ class GenerativeSession:
         device time starts from a fence before it."""
         with profiler.span("decode.dispatch", cat="serving"):
             small, state = self._launch(exe, fn, state, data, slot, length,
-                                        logits=True)
+                                        logits=True, riders=riders)
         # the fence np.asarray would perform anyway, made explicit so
         # that waiting for the device and copying are two numbers
         with profiler.span("decode.device_wait", cat="serving"):
@@ -881,21 +893,23 @@ class GenerativeSession:
         with profiler.span("decode.d2h", cat="serving"):
             logits, _token, *extra = (_np.asarray(o) for o in small)
         self._last_fence = None
-        if "row_data" in exe.arg_dict:
+        if "row_data" in exe.arg_dict and riders is None:
             # a mixed step with idle rows IS the prefill: the prompt's
             # row comes first, the scratch rows' logits are nobody's
             logits = logits[:1]
         return logits, state, extra
 
-    def _run(self, exe, fn, data, slot, length):
+    def _run(self, exe, fn, data, slot, length, riders=None):
         """One LIVE program call, start to end: the session's state goes
         in, the updated state replaces it; returns the host logits
         ``(B, vocab)`` — of a prefill bucket's program, mixed or not, the
-        prompt's ``(1, vocab)``.  The path of whoever needs logits and
-        not tokens (the benchmark's reference check, chip_smoke.py), for
-        a batcher that is idle."""
+        prompt's ``(1, vocab)``; of a mixed step handed `riders`
+        (``(row_data, row_slot, row_length)``, as `_pack` makes them) the
+        prompt's and then every packed row's, ``(1 + slots, vocab)``.
+        The path of whoever needs logits and not tokens (the benchmark's
+        reference check, chip_smoke.py), for a batcher that is idle."""
         logits, self._state, extra = self._call(
-            exe, fn, self._state, data, slot, length)
+            exe, fn, self._state, data, slot, length, riders)
         if self._reports_moe_load:
             with self._prog_lock:
                 key = next(k for k, e in self._programs.items() if e is exe)
@@ -1507,10 +1521,11 @@ class GenerativeSession:
         telemetry.inc("serving.decode.dispatches")
         if (data[:n] < 0).any():
             telemetry.inc("serving.decode.runahead_steps")
-        # the cache sets bound on the device: the live one plus the
-        # zero-filled placeholder set each bucket program's executor
-        # binds.  All their bytes, and the part that is recurrent state
-        sets = 1 + len(self._programs)
+        # the cache sets bound on the device: the live one plus the ONE
+        # zero-filled placeholder set the bucket programs' executors
+        # share (`_program`).  All their bytes, and the part that is
+        # recurrent state
+        sets = 1 + bool(self._programs)
         telemetry.inc("cache.reserved_bytes", sets * self._cache_bytes)
         telemetry.inc("cache.state_bytes", sets * self._state_bytes)
         pages = sets * (self._slots + 1)
@@ -1650,6 +1665,7 @@ class GenerativeSession:
         self._closed = True
         self.finish_all("closed")
         self._programs.clear()
+        self._placeholders.clear()
         booked, self._mem_booked = getattr(self, "_mem_booked", 0), 0
         if booked:
             from ..obs import memory

@@ -542,7 +542,11 @@ def test_every_cell_sorts_its_pairs_at_once():
         if rows * lm.experts_per_token * lm.d_model * 4 > moe._PAIR_BYTES:
             cut.append(row["name"])
         plans[row["name"]] = lm.expert_plan(rows)[1:]
-    assert cut == ["dots3note_longdoc_c8"]
+    assert cut == ["dots3note_longdoc_c8", "smallthinker_longctx_c16"]
+    # no held range there (PR 59: 64 of 64 experts): the 61,488 pairs of
+    # its 10,240 bucket beside eight rows go in three pieces of 3,416
+    # tokens, 210 MB of rows each
+    assert plans.pop("smallthinker_longctx_c16") == (3, 0)
     assert {pieces for pieces, _ in plans.values()} == {1}
     # one pass of twelve 512-row tiles: 126 MB of rows, where every
     # pair's were 2.5 GB

@@ -519,11 +519,9 @@ def test_kv_position_counters_grow_with_every_decode_step(
             gs.decode_step()
             r = telemetry.counter_value("kv.reserved_positions")
             u = telemetry.counter_value("kv.used_positions")
-            # the live ring set and one placeholder set per program
-            # built so far, (slots + 1) rows of max_len each
-            programs = telemetry.counter_value(
-                "serving.decode.bucket_programs")
-            assert r - reserved == (1 + programs) * 3 * 16
+            # the live ring set and the ONE placeholder set the bucket
+            # programs share (PR 59), (slots + 1) rows of max_len each
+            assert r - reserved == 2 * 3 * 16
             assert u - used == fed > 0
             assert u <= r
             reserved, used = r, u

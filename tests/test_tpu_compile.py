@@ -835,3 +835,77 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
     else:
         assert dots == {("2048", "512")}
         assert not re.search(r"f32\[10240,4096\]", text)
+
+
+SMALLTHINKER_BUCKETS = (7168, 8192, 9216, 10240)
+
+
+@pytest.mark.parametrize("program", ["decode", "mixed"])
+def test_the_smallthinker_programs_compile_for_a_v5e(program, one_chip):
+    """SmallThinker-21BA3B's two serving programs at the cell's sizes — the
+    published widths, four layers (a full layer and three window layers of
+    4,096), 64 experts held of 64 under a router on the mixer's input,
+    eight slots of 10,752 positions, the 8-row step and the mixed step of
+    the 10,240 bucket (a prompt 2.5 windows long beside eight riders) —
+    lowered for the TPU: every ring — the full layer's of 10,752 positions,
+    the window layers' of 4,096, SHORTER than the bucket — is aliased to
+    its output and none is copied within HBM; the prompt's attention is one
+    blockwise kernel call a layer at groups of SEVEN query heads, and
+    `attn.kernel_positions` equals `attn.prefill_positions` at every bucket
+    of the cell (`prefill_block` takes them all); the weights are the
+    9.49 GB the configuration's `reduced_why` reckons; weights, the
+    tenant's two bound cache sets (the live one and the placeholder set its
+    bucket programs share since PR 59: with a set a program, nine, the
+    tenant died binding on the chip) and the larger program's temporaries
+    fit a v5e."""
+    import json
+    import warnings
+
+    from benchmarks.families import smallthinker as family
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "smallthinker-21b-a3b.json")) as f:
+        config = json.load(f)
+    lm = family.model(config)
+    rows, bucket, max_len = 8, SMALLTHINKER_BUCKETS[-1], 10752
+    spec = lm.cache_spec(rows + 1, max_len)
+    shapes = sorted({e.shape for e in spec.values()})
+    assert shapes == [(9, 4, 128, 4096), (9, 4, 128, 10752)]
+    for t in SMALLTHINKER_BUCKETS:
+        booked = lm.call_counters(positions=t, platform="tpu")
+        assert booked["attn.kernel_positions"] == booked[
+            "attn.prefill_positions"] == 4 * t
+    if program == "decode":
+        graph, wire = lm.decode_symbol(), _wire(spec, rows)
+    else:
+        graph, wire = lm.mixed_symbol(rows), _wire(spec, rows, bucket)
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        compiled = _serving_program(graph, wire, one_chip)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    for shape in shapes:
+        count = sum(e.shape == shape for e in spec.values())
+        facts = chip_smoke.ring_hlo_facts(text, shape)
+        assert facts["ring_params"] == facts["aliased"] == count, shape
+        assert facts["copies"] == [], shape
+    # the rows' ring kernel a layer and, in the mixed step, the prompt's
+    # blockwise kernel a layer
+    assert chip_smoke.named_kernel_calls(text, "kv_ring_attention") == 4
+    assert chip_smoke.named_kernel_calls(text, "sdp_causal_attention") == (
+        4 * (program == "mixed"))
+    sets = sum(e.nbytes for e in spec.values())
+    assert 0.849e9 < sets < 0.850e9        # nine pages of 94.4 MB
+    assert stats.alias_size_in_bytes >= sets
+    weights = stats.argument_size_in_bytes - sets
+    assert 9.48e9 < weights < 9.50e9
+    # a v5e's 16.9e9 bytes hold the weights, two sets, the program; the
+    # nine sets of a set a bucket program (four prefill buckets, four
+    # decode buckets, the live one) would not fit beside the weights alone
+    assert weights + 2 * sets + stats.temp_size_in_bytes < 13.5e9, (
+        weights, sets, stats.temp_size_in_bytes)
+    assert weights + 9 * sets > 16.9e9
+    if program == "decode":
+        assert stats.temp_size_in_bytes < 0.3e9
+    else:
+        assert not chip_smoke.score_arrays(text, bucket)

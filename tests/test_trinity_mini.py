@@ -380,11 +380,14 @@ def test_the_layer_kinds_declare_their_counters():
     page = 2 * 4 * 2 * 16 * W               # one slot's K and V window page
     assert lm.call_counters(positions=32, platform="cpu") == {
         "attn.prefill_positions": 5 * 32, "attn.kernel_positions": 0,
+        # the CPU's body computes ONE block a window layer, either mask
+        "attn.band_blocks": 4, "attn.causal_blocks": 4,
         "kv.window_rows": 0, "kv.wrapped_rows": 0, "cache.window_bytes": 0,
         "moe.routed_pairs": 4 * 32 * 2}
     assert lm.call_counters(rows=3, lengths=[3, 8, 30], computed=4, pages=10,
                             max_len=64, platform="cpu") == {
         "attn.prefill_positions": 0, "attn.kernel_positions": 0,
+        "attn.band_blocks": 0, "attn.causal_blocks": 0,
         "kv.window_rows": 4 * 3, "kv.wrapped_rows": 4 * 2,
         "cache.window_bytes": 4 * 10 * page, "moe.routed_pairs": 4 * 4 * 2}
     assert TransformerLM(vocab=8).call_counters(rows=4, lengths=[1] * 4) == {
