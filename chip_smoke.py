@@ -65,6 +65,12 @@ apart from the rest:
             every tenant's prefill bucket programs timed warm: none may
             run longer than 1.5 times the next larger bucket's (the
             first shape has OPT-1.3B's FFN of 8,192 and its four buckets)
+  grouped_matmul  a routed FFN's three segment matmuls at the eight
+            routed cells' prefill shapes, through lax.ragged_dot and
+            through the TPU's kernel (ops/grouped_matmul_kernel.py): the
+            same numbers, both timed warm — the table
+            parallel.moe._KERNEL_ROWS was read from; where the rule takes
+            the kernel it may not be the slower one
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
             Predictor bound to chip 3, in this same process
 
@@ -77,6 +83,7 @@ seconds, compile seconds and facts.  The phase functions take
 (sizes, ctx) so the tier-1 tests (tests/test_chip_smoke.py) call them
 tiny on mx.cpu(); main() has no switch that skips the device check.
 """
+import functools
 import json
 import math
 import re
@@ -197,6 +204,25 @@ FULL = {
                                 positions="none", bias=False)]},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
+    # the eight routed cells' expert layers at their prefill programs'
+    # shapes: (tokens a call, experts a token, scored experts, held,
+    # d_model, d_expert) as `tests/test_tpu_compile.py` PAIR_TILES has them
+    # — a mixed step's bucket and its slots' rows, or a prefill's bucket
+    "grouped_matmul": {"reps": 8, "seed": 6, "shapes": {
+        "olmoe 136": (136, 8, 64, 64, 2048, 1024),
+        "olmoe 264": (264, 8, 64, 64, 2048, 1024),
+        "olmoe 520": (520, 8, 64, 64, 2048, 1024),
+        "qwen3-next 2064": (2064, 10, 512, 128, 2048, 512),
+        "glm-5 1032": (1032, 8, 256, 8, 6144, 2048),
+        "granite-h-small 256": (256, 10, 72, 9, 4096, 768),
+        "granite-h-small 512": (512, 10, 72, 9, 4096, 768),
+        "granite-h-small 1024": (1024, 10, 72, 9, 4096, 768),
+        "mistral-small-4 2048": (2048, 4, 128, 16, 4096, 2048),
+        "trinity 2056": (2056, 8, 128, 64, 2048, 1024),
+        "dots3 15360": (15360, 8, 256, 8, 5120, 1536),
+        "smallthinker 9224": (9224, 6, 64, 64, 2560, 768),
+        "smallthinker 10248": (10248, 6, 64, 64, 2560, 768),
+        "smallthinker 8200": (8200, 6, 64, 64, 2560, 768)}},
 }
 
 # Tolerances.  On the TPU an f32 matmul/conv runs at default precision
@@ -1086,6 +1112,121 @@ def phase_kv_ring(sizes, ctx):
     return total
 
 
+def phase_grouped_matmul(sizes, ctx):
+    """A routed FFN's three segment matmuls (`parallel.moe.expert_ffn`,
+    SiLU-gated) over ONE call's sorted pair rows, at each shape of
+    `sizes["shapes"]`: through `lax.ragged_dot` over the rows
+    `parallel/moe.py` gathers for it (`_spare_rows`: whole 512-row tiles)
+    and through the TPU's kernel (`ops/grouped_matmul_kernel.py`, forced
+    at every shape; Pallas's interpreter off the TPU) over the rows there
+    are.  The routing is a uniform draw over the scored experts, of which
+    the call keeps the held ones' pairs — a held range's pass is filled to
+    two thirds, as `_pass_rows` means it to be.  The kernel is held to
+    `lax.ragged_dot` on the rows that lie in a segment, and both are timed
+    warm: `reps` layers in one program, each fed the last one's result
+    (the last layer's is what is compared), the best of three calls on
+    the host's clock over `reps`.  The table —
+    rows an expert against the two times — is where
+    `parallel.moe._KERNEL_ROWS` was read (PERF.md section 6, PR 60)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+
+    from mxnet_tpu.ops.grouped_matmul_kernel import grouped_matmul
+    from mxnet_tpu.parallel import moe
+
+    device = ctx.jax_device()
+    on_tpu = device.platform == "tpu"
+    rng = np.random.default_rng(sizes["seed"])
+    reps = sizes["reps"]
+
+    def layers(matmul, spare, x, weights, load):
+        def one(_, carry):
+            x, _ = carry
+            rows = jnp.pad(x, ((0, spare), (0, 0))) if spare else x
+            y = moe.expert_ffn(lambda r, w: matmul(r, w, load), rows,
+                               weights, None, "silu", True)[:len(x)]
+            y = jnp.where((jnp.arange(len(x)) < load.sum())[:, None], y, 0)
+            return x + 1e-3 * y, y
+        return lax.fori_loop(0, reps, one, (x, jnp.zeros_like(x)))[1]
+
+    def timed(fn, *operands):
+        out = jax.block_until_ready(fn(*operands))
+        took = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*operands))
+            took.append(time.perf_counter() - t0)
+        return out, 1e3 * min(took) / reps
+
+    table = []
+    for name, (tokens, k, scored, held, d_model, d_expert) in \
+            sizes["shapes"].items():
+        held_range = None if held == scored else (0, held)
+        pieces, passed = moe.pass_plan(tokens, k, 4 * d_model, held_range,
+                                       scored)
+        pairs = tokens // pieces * k
+        rows = passed or pairs
+        load = np.bincount(rng.integers(0, scored, pairs),
+                           minlength=scored)[:held]
+        while load.sum() > rows:          # a pass takes what it holds
+            load[load.argmax()] -= 1
+        taken = moe.kernel_tiles(rows, held, d_model, d_expert) is not None
+        # the tiles of these widths, whatever the rule says of the rows
+        enough = max(rows, moe._KERNEL_ROWS * held)
+        tm, tn = moe.kernel_tiles(enough, held, d_model, d_expert)
+        _, tn_down = moe.kernel_tiles(enough, held, d_expert, d_model)
+
+        def kernel(r, w, load):
+            out, = grouped_matmul(
+                r, w, load, tm=tm, interpret=not on_tpu,
+                tn=tn if w.shape[-1] == d_expert else tn_down)
+            return out
+
+        put = lambda a: jax.device_put(jnp.asarray(a), device)
+        x = put(rng.standard_normal((rows, d_model)).astype(np.float32))
+        weights = [put((rng.standard_normal(shape) / math.sqrt(shape[1]))
+                       .astype(np.float32))
+                   for shape in ((held, d_model, d_expert),
+                                 (held, d_expert, d_model),
+                                 (held, d_model, d_expert))]
+        sizes_ = put(load.astype(np.int32))
+        spare = 0 if passed else moe._spare_rows(pairs, scored)
+        ref, xla_ms = timed(jax.jit(functools.partial(
+            layers, moe._ragged_dot, spare)), x, weights, sizes_)
+        got, kernel_ms = timed(jax.jit(functools.partial(
+            layers, kernel, 0)), x, weights, sizes_)
+        err = _rel_err(got, np.asarray(ref, np.float64))
+        # one bfloat16 pass both on a TPU, in another order of the float32
+        # sums; off it `lax.ragged_dot` is a float32 product
+        _check(err < (1e-3 if on_tpu else 2e-2), "%s: the kernel's layer is "
+               "%.2e off lax.ragged_dot's" % (name, err))
+        # the three matrices of every expert with a row, once; the flops
+        # of the rows that lie in a segment
+        hit = int((load > 0).sum())
+        table.append({
+            "shape": name, "rows": rows, "experts": held,
+            "rows_an_expert": round(rows / held, 1),
+            "held_rows": int(load.sum()), "tiles": [tm, tn, tn_down],
+            "taken": taken,
+            "xla_ms": round(xla_ms, 3), "kernel_ms": round(kernel_ms, 3),
+            "weights_ms_at_819": round(
+                1e3 * 3 * hit * 4 * d_model * d_expert / 819e9, 3),
+            "flops_ms_at_197": round(
+                1e3 * 6 * int(load.sum()) * d_model * d_expert / 197e12, 3),
+            "err": err})
+        print("[chip_smoke] grouped_matmul %s" % json.dumps(table[-1]),
+              flush=True)
+    # the rule stands on these readings: where it sends a call to the
+    # kernel, the kernel may not be the slower one
+    slower = [row["shape"] for row in table
+              if on_tpu and row["taken"] and row["kernel_ms"] > row["xla_ms"]]
+    _check(not slower, "the kernel is slower than lax.ragged_dot where "
+           "kernel_tiles takes it: %s" % slower)
+    return {"table": table, "rows_an_expert_from": moe._KERNEL_ROWS}
+
+
 def _staged_block_facts(mx, exe, it, devices, sizes):
     """One K-step block staged from the NDArrayIter `it`, as fit's
     steps_per_dispatch > 1 stages it.  Beside an accelerator the batches
@@ -1247,6 +1388,8 @@ def main():
     run_phase("generate", phase_generate, FULL["generate"], ctx, clock,
               report)
     run_phase("kv_ring", phase_kv_ring, FULL["kv_ring"], ctx, clock, report)
+    run_phase("grouped_matmul", phase_grouped_matmul,
+              FULL["grouped_matmul"], ctx, clock, report)
     if jax.device_count() >= 4:
         run_phase("four_chips", phase_four_chips, FULL["four_chips"],
                   [mx.tpu(i) for i in range(4)], clock, report)

@@ -11,10 +11,12 @@ Pieces:
   * router_logits / dropless_experts — the dropless layer of ONE device
     (what today's open MoE decoders run): every token-expert pair is
     computed.  Pairs are sorted by expert and each expert multiplies its
-    own contiguous segment (`lax.ragged_dot`: on the TPU a grouped
-    matmul that visits only the tiles a non-empty segment touches, so a
-    decode step reads the experts that were hit and a prefill runs one
-    matmul a segment); no [T, E, C] tensor exists.  A model that holds a
+    own contiguous segment (`segment_matmul`: `lax.ragged_dot` — on the
+    TPU a grouped matmul that visits only the tiles a non-empty segment
+    touches, so a decode step reads the experts that were hit — or, from
+    `_KERNEL_ROWS` rows an expert on a TPU, the kernel of
+    ops/grouped_matmul_kernel.py, which walks each expert's own rows);
+    no [T, E, C] tensor exists.  A model that holds a
     RANGE of the router's experts sorts every pair and walks the held
     ones alone, in passes of a static row count (`_held_passes`).
   * top_k_gating(logits, k, capacity) — deterministic capacity-bounded
@@ -33,10 +35,13 @@ from __future__ import annotations
 import functools
 import math
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import exported
 from .collectives import axis_size, shard_map, shard_map_unchecked
 from .mesh import P
 
@@ -100,6 +105,94 @@ def _spare_rows(pairs, experts):
     if pairs < max(_ROW_TILE, _ROWS_AN_EXPERT * experts):
         return 0
     return -pairs % _ROW_TILE
+
+
+# From `_KERNEL_ROWS` sorted rows an expert whose matrix is an operand, a
+# call's segment matmul is OURS on a TPU (`ops/grouped_matmul_kernel.py`):
+# it walks each expert's own rows by a tile of `_KERNEL_TILE` and reads a
+# matrix once, where XLA's multiplies a whole 512-row tile for every expert
+# whose segment touches it — two to three times the rows there are at a few
+# hundred an expert — or re-reads the matrix a 64-row tile.  Read off the
+# chip at the eight routed cells' shapes, a routed FFN's three dots a call
+# (`chip_smoke.py` `grouped_matmul`, PERF.md section 6, PR 60; jaxlib 0.9.0
+# / libtpu 0.0.34), the kernel's time over XLA's: 0.99-1.06 at a decode
+# step's 1-4 rows an expert (both read the hit experts' matrices once),
+# 0.83-0.93 at 9, 0.82 at 17, 0.72-0.84 at 24-96, 0.62-0.69 at 114-228,
+# 0.44-0.48 at SmallThinker's 216-384.  16 is between the level readings
+# and the first clear one: every decode step of the cells (at most 8.9
+# rows an expert: 80 pairs over nine) stays XLA's, every prefill from 17
+# is ours.  `_KERNEL_BLOCK` bounds the float32 bytes of the part of a
+# matrix the kernel holds at a time, twice: the contraction whole, as many
+# of the columns as fit.
+_KERNEL_ROWS = 16
+_KERNEL_TILE = 128
+_KERNEL_BLOCK = 16 << 20
+_LANES = 128
+
+
+def kernel_tiles(rows, experts, k, n):
+    """``(tm, tn)``, the row tile and the columns a strip of
+    ``ops.grouped_matmul_kernel.grouped_matmul`` for a call's segment
+    matmul — `rows` sorted rows ``[rows, k]``, `experts` matrices ``[k,
+    n]`` as operands — or None where the call is `lax.ragged_dot`'s: fewer
+    than `_KERNEL_ROWS` rows an expert (every decode step), or widths
+    that are no whole 128-lane tiles.  Static shapes in, nothing else:
+    whoever counts what a program runs (`TransformerLM.expert_plan`) asks
+    here too."""
+    if rows < _KERNEL_ROWS * experts or k % _LANES or n % _LANES:
+        return None
+    tn = next((tn for tn in range(n, 0, -_LANES)
+               if n % tn == 0 and 4 * k * tn <= _KERNEL_BLOCK), None)
+    return tn and (_KERNEL_TILE, tn)
+
+
+def _ragged_dot(rows, w, sizes):
+    return lax.ragged_dot(rows, w.astype(rows.dtype), sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _kernel_matmul(tiles, interpret, rows, w, sizes):
+    def kernel(rows, w, sizes):
+        # lowered once a shape for all programs and processes
+        # (ops/exported.py)
+        out, = exported.call("grouped_matmul_kernel", "grouped_matmul",
+                             (rows, w, sizes), interpret=interpret,
+                             tm=tiles[0], tn=tiles[1])
+        return out
+    return lax.platform_dependent(rows, w, sizes, tpu=kernel,
+                                  default=_ragged_dot)
+
+
+def _kernel_matmul_fwd(tiles, interpret, rows, w, sizes):
+    return _kernel_matmul(tiles, interpret, rows, w, sizes), (rows, w, sizes)
+
+
+def _kernel_matmul_bwd(tiles, interpret, operands, cotangent):
+    # the kernel has no backward: a gradient takes `lax.ragged_dot`'s
+    rows, w, sizes = operands
+    _, vjp = jax.vjp(lambda rows, w: _ragged_dot(rows, w, sizes), rows, w)
+    return vjp(cotangent) + (np.zeros(sizes.shape, jax.dtypes.float0),)
+
+
+_kernel_matmul.defvjp(_kernel_matmul_fwd, _kernel_matmul_bwd)
+
+# Pallas's interpreter in place of the TPU's kernel (tests)
+_INTERPRET = False
+
+
+def segment_matmul(rows, w, sizes):
+    """``rows [M, K]`` sorted by expert times each expert's matrix of ``w
+    [E, K, N]``, ``sizes [E]`` rows an expert → ``[M, N]``: one contraction
+    with two implementations, chosen by the call's static shape
+    (`kernel_tiles`) and the platform the program is lowered for — the
+    TPU's kernel, or `lax.ragged_dot`, which is also every platform's
+    backward and what a call under the rule is traced as, with no trace of
+    the choice.  Rows past the last segment hold whatever either leaves
+    there: the callers set them to 0."""
+    tiles = kernel_tiles(rows.shape[0], *w.shape)
+    if tiles is None:
+        return _ragged_dot(rows, w, sizes)
+    return _kernel_matmul(tiles, _INTERPRET, rows, w, sizes)
 
 
 # Under a held range only `count` of the router's `experts` experts are here,
@@ -186,7 +279,7 @@ def _held_passes(x, order, sorted_e, load, top_w, rows, weights, biases,
                       - jnp.clip(ends - load, first, first + rows))
 
             def matmul(r, w):
-                return lax.ragged_dot(r, w.astype(r.dtype), window)
+                return segment_matmul(r, w, window)
 
             of_rows = None
             if biases is not None:
@@ -290,14 +383,16 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
         return out, load.astype(jnp.float32)
     # rows past the last pair lie beyond every segment: no expert multiplies
     # them, nothing reads them, and a gradient that reaches them is cut off
-    # with them (`_spare_rows` says why there are any)
-    spare = _spare_rows(len(order), logits.shape[-1])
+    # with them (`_spare_rows` says why there are any: to steer XLA's tile,
+    # so none where the kernel walks its own)
+    spare = 0 if kernel_tiles(len(order), *weights[0].shape) else \
+        _spare_rows(len(order), logits.shape[-1])
 
     def spared(pair_rows):
         return jnp.pad(pair_rows, ((0, spare), (0, 0))) if spare else pair_rows
 
     def matmul(r, w):
-        return lax.ragged_dot(r, w.astype(r.dtype), load)
+        return segment_matmul(r, w, load)
 
     ys = expert_ffn(matmul, spared(x[order // k]), weights,    # [T*k (+), D]
                     None if biases is None

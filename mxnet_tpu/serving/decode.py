@@ -820,7 +820,7 @@ class GenerativeSession:
         each = 2 if self._drafts else 1
         return self._model.expert_plan(
             seq + each * self._slots * bool(self._mixed) if prefill
-            else each * batch)
+            else each * batch, platform=self._platform)
 
     def warm(self, buckets=None):
         """Compile-and-run every prefill sequence bucket and decode
@@ -1265,26 +1265,31 @@ class GenerativeSession:
         rows included — the device computed them), experts that got at
         least one token, expert slots offered, and the fullest expert's
         tokens, each summed over the layers.  And from the program's
-        `plan` (``expert_plan``: pairs a layer, pieces, a pass's rows) the
-        rows the expert layers gathered, `moe.pair_rows`: every pair's
-        row where a layer gathers them all; where it walks the held pairs
-        alone (`moe.compact_calls`, a layer) a pass's rows times the
-        passes the load filled (`moe.passes`) — a call in pieces as if
-        its held pairs lay evenly over them."""
+        `plan` (``expert_plan``: pairs a layer, pieces, a pass's rows,
+        whether the kernel multiplies them) the rows the expert layers
+        gathered, `moe.pair_rows`: every pair's row where a layer gathers
+        them all; where it walks the held pairs alone
+        (`moe.compact_calls`, a layer) a pass's rows times the passes the
+        load filled (`moe.passes`) — a call in pieces as if its held pairs
+        lay evenly over them.  The same rows are `moe.kernel_rows` where
+        the program's segment matmuls are the TPU's kernel
+        (`parallel.moe.kernel_tiles`)."""
         if telemetry.enabled():
             telemetry.inc("moe.pairs", int(load.sum()))
             telemetry.inc("moe.experts_hit", int((load > 0).sum()))
             telemetry.inc("moe.expert_slots", int(load.size))
             telemetry.inc("moe.max_load", int(load.max(axis=-1).sum()))
-            pairs, pieces, rows = plan
+            pairs, pieces, rows, kernel = plan
+            gathered = pairs * len(load)
             if rows:
                 passes = pieces * int(_np.ceil(
                     load.sum(axis=-1) / (pieces * rows)).sum())
                 telemetry.inc("moe.compact_calls", len(load))
                 telemetry.inc("moe.passes", passes)
-                telemetry.inc("moe.pair_rows", passes * rows)
-            else:
-                telemetry.inc("moe.pair_rows", pairs * len(load))
+                gathered = passes * rows
+            telemetry.inc("moe.pair_rows", gathered)
+            if kernel:
+                telemetry.inc("moe.kernel_rows", gathered)
 
     # ------------------------------------------------------------------
     # admission: prefill newly-arrived prompts into free slots

@@ -94,6 +94,11 @@ TINY = {
                                 positions="none", bias=False)]},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
+    # four of eight experts held, every pair's row gathered; every expert
+    # held: widths of one and two lane tiles
+    "grouped_matmul": {"reps": 2, "seed": 6, "shapes": {
+        "a held range": (64, 2, 8, 4, 128, 256),
+        "every expert": (40, 2, 4, 4, 256, 128)}},
 }
 
 
@@ -125,9 +130,20 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     clock = chip_smoke.CompileClock()
     report = {}
     ctx = mx.cpu(2)
-    for name in ("fence", "train", "serve", "generate", "kv_ring"):
+    for name in ("fence", "train", "serve", "generate", "kv_ring",
+                 "grouped_matmul"):
         chip_smoke.run_phase(name, getattr(chip_smoke, "phase_" + name),
                              TINY[name], ctx, clock, report)
+    # the expert layer's kernel (Pallas's interpreter here) against
+    # `lax.ragged_dot` at both shapes, each timed: 32 and 20 rows an
+    # expert, the first over the rule's count and the second too
+    table = report["grouped_matmul"]["table"]
+    assert [(row["shape"], row["rows"], row["experts"], row["held_rows"] <=
+             row["rows"], row["taken"]) for row in table] == [
+        ("a held range", 128, 4, True, True),
+        ("every expert", 80, 4, True, True)]
+    assert all(row["xla_ms"] > 0 and row["kernel_ms"] > 0 and
+               row["err"] < 2e-2 for row in table)
     # two layers' K and V rings of both attention-only shapes, then a
     # delta-rule layer's window and state beside one layer's rings, found
     # in the compiled decode programs, then a window layer's rings of 16
@@ -360,7 +376,8 @@ def test_the_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     # main() holds its PROCESS to no AOT fallback; an earlier test file of
     # this worker may have fallen back on purpose (tests/test_lazy.py)
     telemetry.reset()
-    for name in ("fence", "train", "serve", "generate", "kv_ring"):
+    for name in ("fence", "train", "serve", "generate", "kv_ring",
+                 "grouped_matmul"):
         monkeypatch.setattr(chip_smoke, "phase_" + name, lambda s, c: {})
     monkeypatch.setattr(chip_smoke, "phase_four_chips",
                         lambda s, c: {"predictor_device": "x"})
@@ -377,7 +394,8 @@ def test_the_result_line_has_exactly_the_contract_keys(monkeypatch, capsys):
     assert lines[-2].startswith("[chip_smoke] report ")
     report = json.loads(lines[-2][len("[chip_smoke] report "):])
     assert set(report["phases"]) == {"fence", "train", "serve", "generate",
-                                     "kv_ring", "four_chips"}
+                                     "kv_ring", "grouped_matmul",
+                                     "four_chips"}
 
 
 # ----------------------------------------------------------------------

@@ -1,0 +1,259 @@
+"""A routed FFN's segment matmul as a Pallas TPU kernel
+(mxnet_tpu/ops/grouped_matmul_kernel.py), run by Pallas's interpreter on
+the CPU against `lax.ragged_dot`: alone at every way a call's segments can
+lie over the row tiles, and inside `parallel.moe.dropless_experts` with
+the kernel forced against the parent's form — outputs, `load` and the
+gradients — and through the shape function that says where the kernel
+runs (`parallel.moe.kernel_tiles`).  What Mosaic makes of the kernel at
+the benchmark's widths is in tests/test_tpu_compile.py.  The file costs
+about 40 s."""
+import contextlib
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from mxnet_tpu.ops.grouped_matmul_kernel import grouped_matmul, items
+from mxnet_tpu.parallel import moe
+
+# name -> (rows M, K, N, row tile, columns a strip, each expert's rows)
+CASES = {
+    # three segments over four tiles of 16, two edges inside a tile
+    "segments that cross row tiles": (64, 128, 128, 16, 128, [20, 25, 19]),
+    "segments that end on a tile's edge": (64, 128, 128, 16, 128,
+                                           [16, 32, 16]),
+    "several segments inside one tile": (32, 128, 128, 32, 128,
+                                         [3, 5, 0, 7, 17]),
+    "an expert with no rows": (48, 128, 128, 16, 128, [20, 0, 0, 28, 0]),
+    "the first experts with no rows": (48, 128, 128, 16, 128, [0, 0, 48]),
+    "a last tile that is partial": (44, 128, 128, 16, 128, [30, 14]),
+    "rows that are no whole 8-row tile": (39, 128, 128, 16, 128, [9, 30]),
+    "rows past the last segment": (64, 128, 128, 16, 128, [10, 11, 0]),
+    # a held range's pass: a window of a longer walk, filled to two thirds
+    "a held range's pass": (96, 128, 256, 32, 256, [0, 23, 41, 0]),
+    "no row in any segment": (32, 128, 128, 16, 128, [0, 0]),
+    "one row tile larger than the rows": (24, 128, 128, 128, 128, [10, 14]),
+    # SmallThinker's 2,560 x 768 and dots3's 5,120 x 1,536 (10 : 3), up
+    # and down, and OLMoE's 2,048 x 1,024, cut down to whole lane tiles
+    "smallthinker's widths cut down": (80, 1280, 384, 32, 384,
+                                       [31, 0, 40, 9]),
+    "smallthinker's down projection": (80, 384, 1280, 32, 1280,
+                                       [31, 0, 40, 9]),
+    "olmoe's widths cut down": (72, 512, 256, 16, 256, [9, 8, 30, 25]),
+    # a matrix walked in strips of its columns: the rows read once a strip
+    "dots3's widths in two strips": (80, 1280, 384, 32, 128, [50, 30]),
+    "dots3's down projection in strips": (80, 384, 1280, 32, 256,
+                                          [50, 0, 25]),
+}
+
+
+def _rounded(a):
+    return a.astype(jnp.bfloat16).astype(jnp.float32)
+
+
+@pytest.mark.parametrize("name", sorted(CASES))
+def test_the_kernel_multiplies_each_experts_rows_by_its_matrix(name):
+    """Both operands rounded to bfloat16, the products exact in float32:
+    the kernel against `lax.ragged_dot` of the rounded operands at
+    `highest` differs by the order of a float32 sum alone; a row past the
+    last segment is 0 inside a tile that holds a segment's rows and
+    untouched (the interpreter's NaN) in one that holds none; and the walk
+    visits no item of an expert without rows."""
+    m, k, n, tm, tn, sizes = CASES[name]
+    rng = np.random.default_rng(len(name))
+    x = jnp.asarray(rng.standard_normal((m, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((len(sizes), k, n)), jnp.float32)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    live = int(sizes.sum())
+    # rows past the last segment hold what a TPU leaves there
+    x = x.at[live:].set(jnp.nan)
+    got, = grouped_matmul(x, w, sizes, tm=tm, tn=tn, interpret=True)
+    assert got.shape == (m, n) and got.dtype == x.dtype
+    want = lax.ragged_dot(_rounded(x[:live]), _rounded(w), sizes,
+                          precision=lax.Precision.HIGHEST)
+    got = np.asarray(got)
+    scale = max(float(jnp.abs(want).max()), 1.0) if live else 1.0
+    assert np.abs(got[:live] - np.asarray(want)).max(initial=0) < 1e-5 * scale
+    # against the one bfloat16 pass of XLA's default on a TPU, not the
+    # CPU's float32 product: within a rounding of both operands
+    exact = lax.ragged_dot(x[:live], w, sizes,
+                           precision=lax.Precision.HIGHEST)
+    assert np.abs(got[:live] - np.asarray(exact)).max(initial=0) \
+        < 2e-2 * scale
+    visited = -(-live // tm) * tm if live else 0
+    assert not got[live:visited].any()
+    assert np.isnan(got[visited:]).all()
+    expert, tile, _, following, offsets, count = (
+        np.asarray(a) for a in items(sizes, m, tm))
+    count = int(count[0])
+    hit = [e for e, size in enumerate(np.asarray(sizes)) if size]
+    assert sorted(set(expert[:count])) == (hit if count else [])
+    assert list(expert[:count]) == sorted(expert[:count])
+    assert len(expert) == -(-m // tm) + len(sizes) - 1 >= count
+    # an item a tile a segment touches, no more
+    assert count == sum(
+        (offsets[e + 1] - 1) // tm - offsets[e] // tm + 1 for e in hit)
+    assert set(following[:count]) <= set(hit[1:]) | {-1}
+    # the items past the last repeat it: no block moves for them
+    assert count == 0 or (set(expert[count:]) <= {expert[count - 1]}
+                          and set(tile[count:]) <= {tile[count - 1]})
+
+
+@contextlib.contextmanager
+def _tpu_kernel_interpreted(rows=1, tile=16):
+    """Inside, `segment_matmul` takes the branch a lowering for the TPU
+    keeps — the Pallas kernel — run by Pallas's interpreter, from `rows`
+    rows an expert on and by a row tile of `tile`.  Yields the list of
+    kernel branches taken."""
+    calls = []
+
+    def take_tpu(*operands, tpu, default):
+        calls.append(tpu)
+        return tpu(*operands)
+
+    with mock.patch.object(moe.lax, "platform_dependent", take_tpu), \
+            mock.patch.object(moe, "_INTERPRET", True), \
+            mock.patch.object(moe, "_KERNEL_ROWS", rows), \
+            mock.patch.object(moe, "_KERNEL_TILE", tile):
+        yield calls
+
+
+# name -> (tokens, experts a token, experts scored, held range, what is
+# patched of `parallel.moe`)
+LAYERS = {
+    "every expert held": (48, 2, 4, None, {}),
+    "a held range, every pair's row gathered": (48, 2, 8, (2, 4), {}),
+    "a held range walked in passes": (
+        48, 4, 16, (2, 4), {"_ROW_TILE": 8, "_COMPACT_PAIRS": 16}),
+    "the tokens in pieces": (48, 2, 4, None, {"_PAIR_BYTES": 32 * 512}),
+}
+
+
+@pytest.mark.parametrize("biased", [False, True])
+@pytest.mark.parametrize("name", sorted(LAYERS))
+def test_the_layer_through_the_kernel_is_the_parents(name, biased):
+    """`dropless_experts` with its three segment matmuls in the kernel
+    (forced: the TPU's branch, interpreted, from one row an expert on)
+    against the same layer through `lax.ragged_dot`: `load` bit for bit,
+    the output and the gradients in `x`, the logits, the three matrices
+    and the biases within the one bfloat16 pass the kernel makes where
+    the CPU's dot is float32 — the backward IS `lax.ragged_dot`'s, at the
+    kernel's forward values."""
+    tokens, k, scored, held, patched = LAYERS[name]
+    rng = np.random.default_rng(7)
+    d_model, d_expert = 128, 256
+    count = scored if held is None else held[1]
+    x = jnp.asarray(rng.standard_normal((tokens, d_model)), jnp.float32)
+    logits = jnp.asarray(rng.standard_normal((tokens, scored)), jnp.float32)
+    weights = tuple(
+        jnp.asarray(rng.standard_normal(shape) / np.sqrt(shape[1]),
+                    jnp.float32)
+        for shape in ((count, d_model, d_expert), (count, d_expert, d_model),
+                      (count, d_model, d_expert)))
+    biases = tuple(
+        jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        for shape in ((count, d_expert), (count, d_model),
+                      (count, d_expert))) if biased else None
+
+    def layer(x, logits, weights, biases):
+        return moe.dropless_experts(x, logits, k, weights, biases,
+                                    act="silu", gated=True, held=held)
+
+    def both():
+        return layer(x, logits, weights, biases), jax.grad(
+            lambda *args: (layer(*args)[0] ** 2).sum(), (0, 1, 2, 3))(
+                x, logits, weights, biases)
+
+    with contextlib.ExitStack() as stack:
+        for attr, value in patched.items():
+            stack.enter_context(mock.patch.object(moe, attr, value))
+        parent = both()
+        with _tpu_kernel_interpreted() as calls:
+            kernel = both()
+    # a layer's three matmuls, forward and forward again under the
+    # gradient (a loop's body is traced more than once)
+    assert len(calls) >= 6 and len(calls) % 3 == 0
+    np.testing.assert_array_equal(np.asarray(kernel[0][1]),
+                                  np.asarray(parent[0][1]))
+    assert 0 < float(parent[0][1].sum()) <= tokens * k
+    for a, b in zip(jax.tree_util.tree_leaves((kernel[0][0], kernel[1])),
+                    jax.tree_util.tree_leaves((parent[0][0], parent[1]))):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all()
+        assert np.abs(a - b).max() < 2e-2 * np.abs(b).max()
+
+
+# name -> ((rows, experts, K, N), the tiles or None): the eight routed
+# cells' programs (benchmarks/traffic, `pass_plan`)
+TILES = {
+    "smallthinker 8200, a piece": ((24600, 64, 2560, 768), (128, 768)),
+    "smallthinker 9224, down": ((13836, 64, 768, 2560), (128, 2560)),
+    "smallthinker step": ((48, 64, 2560, 768), None),
+    "dots3 15360, a pass": ((6144, 8, 5120, 1536), (128, 768)),
+    "dots3 15360, down": ((6144, 8, 1536, 5120), (128, 2560)),
+    "trinity 2056, a pass": ((12800, 64, 2048, 1024), (128, 1024)),
+    "granite-h-small 1024, a pass": ((2048, 9, 4096, 768), (128, 768)),
+    "granite-h-small 512, a pass": ((1024, 9, 4096, 768), (128, 768)),
+    "granite-h-small 256, a pass": ((512, 9, 4096, 768), (128, 768)),
+    "granite-h-small step": ((80, 9, 4096, 768), None),
+    "mistral-small-4 2048, a pass": ((1536, 16, 4096, 2048), (128, 1024)),
+    "mistral-small-4 step": ((64, 16, 4096, 2048), None),
+    "qwen3-next 2064, a pass": ((8192, 128, 2048, 512), (128, 512)),
+    "glm-5 1032, a pass": ((512, 8, 6144, 2048), (128, 512)),
+    "glm-5 1032, down": ((512, 8, 2048, 6144), (128, 2048)),
+    "glm-5 step": ((64, 8, 6144, 2048), None),
+    "olmoe 520": ((4160, 64, 2048, 1024), (128, 1024)),
+    "olmoe 136": ((1088, 64, 2048, 1024), (128, 1024)),
+    "olmoe 72": ((576, 64, 2048, 1024), None),
+    "olmoe step": ((64, 64, 2048, 1024), None),
+    "widths that are no whole lane tile": ((4096, 4, 96, 128), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TILES))
+def test_the_rule_reads_a_calls_static_shape(name):
+    shape, tiles = TILES[name]
+    assert moe.kernel_tiles(*shape) == tiles
+
+
+def test_off_the_tpu_the_layer_is_ragged_dot_forward_and_backward():
+    """A call the rule takes, traced for the CPU: the platform's branch is
+    `lax.ragged_dot`, so output, load and gradients are the parent's bit
+    for bit — and a call under the rule is traced with no trace of the
+    choice."""
+    rng = np.random.default_rng(11)
+    tokens, k, experts, d_model, d_expert = 256, 2, 4, 128, 128
+    assert moe.kernel_tiles(tokens * k, experts, d_model, d_expert)
+    x = jnp.asarray(rng.standard_normal((tokens, d_model)), jnp.float32)
+    logits = jnp.asarray(rng.standard_normal((tokens, experts)), jnp.float32)
+    weights = tuple(
+        jnp.asarray(rng.standard_normal(shape) / np.sqrt(shape[1]),
+                    jnp.float32)
+        for shape in ((experts, d_model, d_expert),
+                      (experts, d_expert, d_model),
+                      (experts, d_model, d_expert)))
+
+    def layer(x, logits, weights):
+        return moe.dropless_experts(x, logits, k, weights, act="silu",
+                                    gated=True)
+
+    def both():
+        return layer(x, logits, weights), jax.grad(
+            lambda *args: (layer(*args)[0] ** 2).sum(), (0, 1, 2))(
+                x, logits, weights)
+
+    def traced():       # (a trace is kept by the function's identity)
+        return str(jax.make_jaxpr(lambda *args: layer(*args))(
+            x, logits, weights))
+
+    chosen = both()
+    assert "platform_index" in traced()
+    with mock.patch.object(moe, "_KERNEL_ROWS", 1 << 30):
+        parent = both()
+        assert "platform_index" not in traced()
+    for a, b in zip(jax.tree_util.tree_leaves(chosen),
+                    jax.tree_util.tree_leaves(parent)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -1,5 +1,6 @@
 """The decode-attention kernel, the prefill's blockwise attention kernel,
-the delta rule's prefill and step kernels and the Mamba-2 step kernel
+the delta rule's prefill and step kernels, the Mamba-2 step kernel and the
+expert layer's grouped matmul
 compiled for a TPU v5e that is described, not attached (the TPU's
 compiler is installed where the tests run): what Pallas's interpreter
 cannot see — Mosaic refusing a slice, a layout or the fast memory a
@@ -169,10 +170,16 @@ PAIR_TILES = {"olmoe 64": ((72, 8, 64, 64, 2048, 1024), 64),
               "qwen3-next 2048": ((2064, 10, 512, 128, 2048, 512), 512),
               "trinity 2048": ((2056, 8, 128, 64, 2048, 1024), 512),
               "granite-h-small 512": ((512, 10, 72, 9, 4096, 768), 512),
-              "granite-h-small step": ((8, 10, 72, 9, 4096, 768), 16)}
+              "granite-h-small step": ((8, 10, 72, 9, 4096, 768), 16),
+              "smallthinker 8192": ((8200, 6, 64, 64, 2560, 768), 512),
+              "smallthinker step": ((8, 6, 64, 64, 2560, 768), 16)}
 # the rows a pass of the held pairs walks (PR 55); 0: every pair's row
 PASS_ROWS = {"qwen3-next 2048": 8192, "trinity 2048": 12800,
              "granite-h-small 512": 1024}
+# the cases whose segment matmuls are the TPU's kernel (PR 60,
+# `moe.kernel_tiles`: from `_KERNEL_ROWS` sorted rows an expert held)
+KERNEL_CASES = {"olmoe 128", "olmoe 256", "olmoe 512", "qwen3-next 2048",
+                "trinity 2048", "granite-h-small 512", "smallthinker 8192"}
 
 
 def _ragged_dots(text):
@@ -187,23 +194,31 @@ def _ragged_dots(text):
 @pytest.mark.parametrize("name", sorted(PAIR_TILES))
 def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
         name, one_chip):
-    """`parallel/moe.py _spare_rows` leans on a choice XLA documents
+    """Under `moe._KERNEL_ROWS` rows an expert (PR 60) the layer is the
+    parent's: `parallel/moe.py _spare_rows` leans on a choice XLA documents
     nowhere: the TPU's `ragged-dot` walks its rows by the largest power of
     two, up to 512, that divides their count.  Read back from the compiled
     program — the dot's `ragged_dot_tiling` and its metadata operand of
     tiles + groups - 1 entries — so that a compiler that chooses otherwise
     fails here and not silently on the chip: two dozen pairs an expert or
     more walk whole 512-row tiles, fewer keep the parent's count and its
-    small tile.  A held range's long call (PR 55) walks passes of its HELD
+    small tile.  From `_KERNEL_ROWS` on the three segment matmuls of a
+    call are OUR kernel (`ops/grouped_matmul_kernel.py`: three
+    `tpu_custom_call`s, no `ragged-dot`, the rows there ARE — no pad to a
+    multiple of 512 — and the fast memory the kernel asks for granted).  A
+    held range's long call (PR 55) walks passes of its HELD
     pairs — whole 512-row tiles of them, inside the one loop of
     `_held_passes` — and no array of every pair's row is left; without a
     held range, and in a decode step, the layer is the straight line it
-    was: no loop, no conditional."""
+    was: no loop, no conditional — but the one loop over the pieces of a
+    bucket whose pair rows pass `_PAIR_BYTES` (SmallThinker's 8,192: two
+    pieces of 24,600 rows)."""
     import re
 
     import jax
     import jax.numpy as jnp
 
+    from mxnet_tpu.ops import grouped_matmul_kernel
     from mxnet_tpu.parallel import moe
 
     (tokens, k, scored, held, d_model, d_expert), tile = PAIR_TILES[name]
@@ -220,22 +235,40 @@ def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
         arg(tokens, d_model), arg(tokens, scored),
         arg(held, d_model, d_expert), arg(held, d_expert, d_model),
         arg(held, d_model, d_expert)).compile().as_text()
-    passed = moe._pass_rows(tokens * k, held_range, scored)
+    pieces, passed = moe.pass_plan(tokens, k, 4 * d_model, held_range,
+                                   scored)
     assert passed == PASS_ROWS.get(name, 0)
-    rows = passed or tokens * k + moe._spare_rows(tokens * k, scored)
-    assert rows % tile == 0 and (tile == 512 or rows == tokens * k)
+    pairs = tokens // pieces * k
+    loops = len(re.findall(r" (?:while|conditional)\(", text))
+    every_pair = re.findall(r"f32\[%d,%d\]" % (pairs, d_model), text)
+    if passed:
+        assert loops == 2 and every_pair == []
+    else:
+        assert loops == (pieces > 1) and every_pair
     dots = _ragged_dots(text)
+    tiles = moe.kernel_tiles(passed or pairs, held, d_model, d_expert)
+    assert (tiles is not None) == (name in KERNEL_CASES)
+    if tiles:
+        assert dots == [] and "ragged" not in text
+        assert chip_smoke.named_kernel_calls(
+            text, "grouped_matmul_kernel") == 3
+        # the rows there are, not a whole number of 512-row tiles
+        spare = moe._spare_rows(pairs, scored)
+        assert passed or not spare or not re.search(
+            r"f32\[%d,\d+\]" % (pairs + spare), text)
+        # both of a matrix's slots, its rounded copy and the pipeline's
+        # tiles within what a v5e's 128 MiB of fast memory can give
+        for n_in, n_out in ((d_model, d_expert), (d_expert, d_model)):
+            tm, tn = moe.kernel_tiles(passed or pairs, held, n_in, n_out)
+            assert grouped_matmul_kernel._vmem(tm, tn, n_in, 4) < 96 << 20
+        return
+    rows = passed or pairs + moe._spare_rows(pairs, scored)
+    assert rows % tile == 0 and (tile == 512 or rows == tokens * k)
     assert len(dots) == 3, name
     assert set(dots) == {(str(rows), str(tile))}
     entries = set(re.findall(r"%ragged-dot-metadata = \(s32\[\d+\]\S*, "
                              r"s32\[(\d+)\]", text))
     assert entries == {str(rows // tile + held - 1)}
-    loops = len(re.findall(r" (?:while|conditional)\(", text))
-    every_pair = re.findall(r"f32\[%d,%d\]" % (tokens * k, d_model), text)
-    if passed:
-        assert loops == 2 and every_pair == []
-    else:
-        assert loops == 0 and every_pair
 
 
 # (key heads, value heads, d_k, d_v, heads a step of the kernel's walk)
@@ -687,8 +720,14 @@ def test_the_dots3_programs_compile_for_a_v5e(program, one_chip):
     # heads); no other Pallas kernel of this repo
     masked = chip_smoke.named_kernel_calls(text, "masked_latent_attention")
     assert masked == (2 if program == "prefill" else 0)
+    # and, since PR 60, the prefill's segment matmuls (a pass of 6,144
+    # sorted rows over eight experts), three a routed layer
+    grouped = chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel")
+    assert grouped == (3 * sum(
+        kind == "routed" for kind in lm.ffn_types)
+        if program == "prefill" else 0)
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == text.count('op_name="ragged-dot') + masked
+        == text.count('op_name="ragged-dot') + masked + grouped
     sets = sum(e.nbytes for e in spec.values())
     assert stats.alias_size_in_bytes >= sets
     weights = stats.argument_size_in_bytes - sets
@@ -829,11 +868,14 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
     assert weights + 9 * sets + stats.temp_size_in_bytes < 16.5e9, (
         weights, sets, stats.temp_size_in_bytes)
     dots = set(_ragged_dots(text))
+    routed = config["num_hidden_layers"]
+    grouped = chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel")
     if program == "decode":
         assert stats.temp_size_in_bytes < 0.3e9
-        assert dots == {("80", "16")}
+        assert dots == {("80", "16")} and grouped == 0
     else:
-        assert dots == {("2048", "512")}
+        # a pass of 2,048 sorted rows over nine experts: ours (PR 60)
+        assert dots == set() and grouped == 3 * routed
         assert not re.search(r"f32\[10240,4096\]", text)
 
 
@@ -894,6 +936,12 @@ def test_the_smallthinker_programs_compile_for_a_v5e(program, one_chip):
     assert chip_smoke.named_kernel_calls(text, "kv_ring_attention") == 4
     assert chip_smoke.named_kernel_calls(text, "sdp_causal_attention") == (
         4 * (program == "mixed"))
+    # and the mixed step's expert layers are OUR grouped matmul (PR 60:
+    # three pieces of 20,496 sorted rows over 64 experts), the step's
+    # 48 pairs a layer `lax.ragged_dot`
+    assert chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel") == (
+        12 * (program == "mixed"))
+    assert ("ragged" in text) == (program == "decode")
     sets = sum(e.nbytes for e in spec.values())
     assert 0.849e9 < sets < 0.850e9        # nine pages of 94.4 MB
     assert stats.alias_size_in_bytes >= sets
