@@ -70,7 +70,11 @@ apart from the rest:
             through the TPU's kernel (ops/grouped_matmul_kernel.py): the
             same numbers, both timed warm — the table
             parallel.moe._KERNEL_ROWS was read from; where the rule takes
-            the kernel it may not be the slower one
+            the kernel it may not be the slower one.  Where every expert
+            is held, the whole layer-piece beside it: the two calls that
+            fetch and place their own rows against the three between a
+            gather and an un-sort (PR 61), neither the slower one where
+            parallel.moe.fused_tile takes them
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
             Predictor bound to chip 3, in this same process
 
@@ -628,9 +632,14 @@ def ring_hlo_facts(text, ring_shape):
 
 def named_kernel_calls(text, name):
     """The calls of the Pallas kernel `name` (a kernel keeps its name) in
-    a compiled program's optimised HLO `text`."""
-    return sum('custom_call_target="tpu_custom_call"' in line and name in line
-               for line in text.splitlines())
+    a compiled program's optimised HLO `text` — a call's own line less its
+    operands, which may be another kernel's result and carry ITS name."""
+    calls = 0
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            head, _, rest = line.partition(" custom-call(")
+            calls += name in head or name in rest.partition(")")[2]
+    return calls
 
 
 def delta_rule_hlo_facts(text, head_shapes=()):
@@ -1127,7 +1136,16 @@ def phase_grouped_matmul(sizes, ctx):
     (the last layer's is what is compared), the best of three calls on
     the host's clock over `reps`.  The table —
     rows an expert against the two times — is where
-    `parallel.moe._KERNEL_ROWS` was read (PERF.md section 6, PR 60)."""
+    `parallel.moe._KERNEL_ROWS` was read (PERF.md section 6, PR 60).
+
+    Where every expert is held and `parallel.moe.fused_tile` takes the
+    call (PR 61) the WHOLE layer-piece is timed two ways beside it, from
+    `x [T, D]` and a uniform routing to the tokens' weighted sums: the
+    three kernel calls between a gather of every pair's row, an un-sort
+    and a weighted sum over ``[T, k, D]`` (`moe._every_pair`:
+    `three_calls_ms`) and the two calls that fetch and place their own
+    rows (`moe._two_calls`: `two_calls_ms`), and the two results compared
+    (`fused_err`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1216,6 +1234,41 @@ def phase_grouped_matmul(sizes, ctx):
             "flops_ms_at_197": round(
                 1e3 * 6 * int(load.sum()) * d_model * d_expert / 197e12, 3),
             "err": err})
+        tile = held_range is None and moe.fused_tile(
+            pairs, held, d_model, d_expert, True)
+        if tile:
+            routed = rng.integers(0, held, pairs)
+            operands = [put(a) for a in (
+                rng.standard_normal((pairs // k, d_model)).astype(np.float32),
+                np.argsort(routed, kind="stable").astype(np.int32),
+                np.bincount(routed, minlength=held).astype(np.int32),
+                rng.random((pairs // k, k)).astype(np.float32))] + weights
+
+            def pieces(piece, x, *operands):
+                def one(_, carry):
+                    x, _ = carry
+                    y = piece(x, *operands)
+                    return x + 1e-3 * y, y
+                return lax.fori_loop(0, reps, one, (x, jnp.zeros_like(x)))[1]
+
+            def three_calls(x, order, load, top_w, *weights):
+                return moe._every_pair(kernel, x, order, None, load, top_w,
+                                       weights, None, "silu", True, 0, False)
+
+            ref, three_ms = timed(jax.jit(functools.partial(
+                pieces, three_calls)), *operands)
+            got, two_ms = timed(jax.jit(functools.partial(
+                pieces, functools.partial(
+                    moe._two_calls, (tile, "silu", True), not on_tpu))),
+                *operands)
+            err = _rel_err(got, np.asarray(ref, np.float64))
+            # the same products and the same roundings, `h` rounded to
+            # bfloat16 where `down` would; on a TPU the kernel's SiLU and
+            # XLA's may differ in a last bit before that rounding
+            _check(err < (2e-3 if on_tpu else 1e-5), "%s: the two calls' "
+                   "layer is %.2e off the three calls'" % (name, err))
+            table[-1].update(three_calls_ms=round(three_ms, 3),
+                             two_calls_ms=round(two_ms, 3), fused_err=err)
         print("[chip_smoke] grouped_matmul %s" % json.dumps(table[-1]),
               flush=True)
     # the rule stands on these readings: where it sends a call to the
@@ -1224,6 +1277,11 @@ def phase_grouped_matmul(sizes, ctx):
               if on_tpu and row["taken"] and row["kernel_ms"] > row["xla_ms"]]
     _check(not slower, "the kernel is slower than lax.ragged_dot where "
            "kernel_tiles takes it: %s" % slower)
+    slower = [row["shape"] for row in table if on_tpu and row.get(
+        "two_calls_ms", 0) > row.get("three_calls_ms", 0)]
+    _check(not slower, "the two calls that fetch and place their own rows "
+           "are slower than the three where fused_tile takes them: %s"
+           % slower)
     return {"table": table, "rows_an_expert_from": moe._KERNEL_ROWS}
 
 

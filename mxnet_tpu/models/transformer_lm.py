@@ -1846,27 +1846,32 @@ class TransformerLM:
         return total
 
     def expert_plan(self, tokens, platform="tpu"):
-        """``(pairs, pieces, rows, kernel)`` of each routed layer in a
-        serving program that computes `tokens` tokens (the pad included):
+        """``(pairs, pieces, rows, kernel, fused)`` of each routed layer in
+        a serving program that computes `tokens` tokens (the pad included):
         the (token, expert) pairs its router makes and how the layer goes
         through them — `parallel.moe.pass_plan`'s pieces and the sorted
         rows a pass gathers, 0 where it gathers every pair's row (no held
-        range, or few pairs) —, and whether a program lowered for
+        range, or few pairs) —, whether a program lowered for
         `platform` multiplies those rows in the TPU's grouped-matmul
         kernel (`parallel.moe.kernel_tiles` of a call's rows: the three
-        matmuls of a layer choose alike).  The session keeps it a bucket
-        program and books `moe.pair_rows` / `moe.passes` /
-        `moe.kernel_rows` from it and the call's ``moe_load``."""
+        matmuls of a layer choose alike), and whether the layer's calls
+        also fetch and place their own rows (`parallel.moe.fused_tile`:
+        every expert held — these gated FFNs have no bias).  The session
+        keeps it a bucket program and books `moe.pair_rows` /
+        `moe.passes` / `moe.kernel_rows` / `moe.fused_rows` from it and
+        the call's ``moe_load``."""
         from ..parallel import moe
 
         k = self.experts_per_token
         pieces, rows = moe.pass_plan(
             tokens, k, 4 * self.d_model, self.held_experts, self.num_experts)
         held = (self.held_experts or (0, self.num_experts))[1]
-        kernel = platform == "tpu" and moe.kernel_tiles(
-            rows or tokens // pieces * k, held, self.d_model,
-            self.expert_d_ff) is not None
-        return tokens * k, pieces, rows, kernel
+        call = (rows or tokens // pieces * k, held, self.d_model,
+                self.expert_d_ff)
+        kernel = platform == "tpu" and moe.kernel_tiles(*call) is not None
+        fused = (kernel and self.held_experts is None
+                 and moe.fused_tile(*call, True) is not None)
+        return tokens * k, pieces, rows, kernel, fused
 
     def step_weight_bytes(self, load=None):
         """``{"mtp.bytes", "mtp.step_bytes"}``: the float32 bytes of the

@@ -49,7 +49,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["grouped_matmul", "items"]
+__all__ = ["grouped_matmul", "gate_up", "down", "slab_sum", "items"]
 
 _BF16 = jnp.bfloat16
 
@@ -180,3 +180,345 @@ def _vmem(tm, tn, k, itemsize):
     the compiler stages."""
     return (2 * k * tn * (itemsize + 1) + tm * k * (2 * 4 + 2 + 4)
             + 3 * tm * tn * 4 + (4 << 20))
+
+
+# (The two calls and their account stand BELOW the first kernel, whose
+# lines keep their numbers: a Mosaic kernel's body holds its source
+# locations, and a program that calls `grouped_matmul` alone — every held
+# range's — stays the parent's byte for byte, compile cache and all.)
+#
+# Where every pair's row is walked and no expert has a bias
+# (``parallel/moe.py _dropless`` says where), the layer's three products are
+# TWO calls that also fetch and place their own rows (PR 61), so that no ``[T
+# k, D]`` copy of `x` is made before them and no un-sort, reshape or second
+# pass after them:
+#
+# * `gate_up` leaves `x` in HBM and reads ``token [M]`` — the sorted pairs'
+#   tokens — from SMEM: a row tile's `tm` rows arrive by ROW DMA, one a
+#   row, into one of two VMEM buffers, the next tile's while this one
+#   multiplies, and are rounded to bfloat16 once a tile.  An expert's
+#   ``w1`` and ``w3`` are copied and rounded together, and ``h = act(x w1) *
+#   (x w3)`` leaves as ``[M, H]`` bfloat16: `g` and `u` never leave VMEM, and
+#   `h` is stored as what `down` rounds it to first thing (the same bits
+#   either way);
+# * `down` multiplies a tile of `h` by the expert's ``w2``, scales each row
+#   by its router weight in float32 on the vector unit, and sends row `r` to
+#   ``out[dest[r]]`` by row DMA — only a segment's own rows of a tile that
+#   two segments share.  The caller's ``dest = slot * T + token`` makes `out`
+#   k slabs of ``[T, D]`` whose sum over k is the layer's result
+#   (`slab_sum`, a third, small call).  Every row of `out` whose `dest`
+#   names it is written once; no other row is written.
+#
+# Mosaic slices no ONE row out of an array tiled ``(8, 128)``, in HBM or in
+# VMEM: a row that a DMA moves is a row of ``[T, 1, D]`` (XLA lays it out
+# ``T(1,128)``: a row's `D` values lie one after the other) and of a ``(tm,
+# 1, D)`` buffer, which the kernel reads and writes as ``buf[:, 0, :]``.  So
+# `gate_up` takes `x` as ``[T, 1, D]`` and `down` returns ``[rows, 1, D]``:
+# the relayout in and out is XLA's, once a layer, where it can.
+#
+# Both walk the same items as `grouped_matmul` and hold an expert's matrices
+# whole (no strips: ``parallel.moe.fused_tile`` leaves a layer whose matrices
+# do not fit to the three calls).
+#
+# Measured on a TPU v5e (PERF.md section 6, PR 61).
+
+
+def _expert_opens(i, count, e, turn, expert_ref, following_ref, copies):
+    """Item `i`'s part of the matrices' relay, where it is the first of
+    expert `e`: wait for `e`'s copies (the first item of a call starts
+    them itself) and start the following expert's into the other slot.
+    ``copies(expert, slot)`` lists the copies of one expert's matrices."""
+    before = jnp.maximum(i - 1, 0)
+    opens = (i == 0) | (expert_ref[before] != e)
+
+    def relay(then):
+        @pl.when((i < count) & opens)
+        def _():
+            slot = turn % 2
+
+            @pl.when(i == 0)
+            def _():
+                for copy in copies(e, slot):
+                    copy.start()
+
+            for copy in copies(e, slot):
+                copy.wait()
+            following = following_ref[i]
+
+            @pl.when(following >= 0)
+            def _():
+                for copy in copies(following, 1 - slot):
+                    copy.start()
+
+            then(slot)
+    return relay
+
+
+def _gate_up_kernel(expert_ref, tile_ref, turn_ref, following_ref,
+                    offsets_ref, count_ref, token_ref, x_hbm, *refs, tm, act):
+    *w_hbm, o_ref, rows, tile_rows, slots, rounded, row_sems, w_sems = refs
+    i, (m,) = pl.program_id(0), token_ref.shape
+    count = count_ref[0]
+    e, turn, tile = expert_ref[i], turn_ref[i], tile_ref[i]
+    fresh = (i == 0) | (tile_ref[jnp.maximum(i - 1, 0)] != tile)
+    # the tiles that hold a segment's row lie one after the other from 0
+    tiles = pl.cdiv(offsets_ref[offsets_ref.shape[0] - 1], tm)
+
+    def fetch(tile):
+        """Start the copies of `tile`'s rows of `x`: a row a DMA (a row
+        past the last names the last: a copy more of a row that is there)."""
+        slot = tile % 2
+
+        def eight(r8, carry):
+            for r in range(8):           # (Mosaic unrolls whole or not)
+                r = r8 * 8 + r
+                at = token_ref[jnp.minimum(tile * tm + r, m - 1)]
+                pltpu.make_async_copy(x_hbm.at[at], rows.at[slot, r],
+                                      row_sems.at[slot]).start()
+            return carry
+        lax.fori_loop(0, tm // 8, eight, 0)
+
+    @pl.when((i == 0) & (count > 0))
+    def _():
+        fetch(tile)
+
+    def copies(expert, slot):
+        return [pltpu.make_async_copy(w.at[expert], slots.at[slot, n],
+                                      w_sems.at[slot, n])
+                for n, w in enumerate(w_hbm)]
+
+    @_expert_opens(i, count, e, turn, expert_ref, following_ref, copies)
+    def _(slot):
+        for n in range(len(w_hbm)):
+            rounded[n] = slots[slot, n].astype(_BF16)
+
+    @pl.when((i < count) & fresh)
+    def _():
+        slot = tile % 2
+        # the tile's `tm` copies signalled one semaphore: one wait for
+        # their bytes together (the wait needs the shapes only)
+        pltpu.make_async_copy(rows.at[slot], rows.at[slot],
+                              row_sems.at[slot]).wait()
+        # rounded, and from a sublane a row to whole tiles, once a tile:
+        # two segments that share it read the same copy
+        tile_rows[...] = rows[slot, :, 0, :].astype(_BF16)
+
+        @pl.when(tile + 1 < tiles)
+        def _():
+            fetch(tile + 1)
+
+    @pl.when(i < count)
+    def _():
+        x = tile_rows[...]
+        h = getattr(jax.nn, act)(
+            jnp.dot(x, rounded[0], preferred_element_type=jnp.float32))
+        if len(w_hbm) == 2:
+            h = h * jnp.dot(x, rounded[1],
+                            preferred_element_type=jnp.float32)
+        row = tile * tm + lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+        mine = (row >= offsets_ref[e]) & (row < offsets_ref[e + 1])
+        kept = jnp.where(fresh, 0, o_ref[...].astype(jnp.float32))
+        o_ref[...] = jnp.where(mine, h, kept).astype(o_ref.dtype)
+
+
+def gate_up(x, token, sizes, w1, w3=None, *, tm, act, interpret=False):
+    """``x [T, D]``, ``token [M]`` int32 — sorted row r is ``x[token[r]]``
+    —, ``sizes [E]`` (their sum at most `M`), ``w1`` and ``w3 [E, D, H]``
+    (no `w3`: an un-gated FFN) → ``[h [M, H]]`` bfloat16: each expert's
+    segment as ``act(rows w1) * (rows w3)``, one bfloat16 pass each
+    accumulated in float32, the product in float32, rounded once as it is
+    stored.  `act` names a function of `jax.nn`; `tm` rows a tile
+    (``parallel.moe.fused_tile``).  Rows past the last segment as
+    `grouped_matmul` leaves them."""
+    m, = token.shape
+    matrices = [w1] if w3 is None else [w1, w3]
+    _, k, n = w1.shape
+    walk = items(sizes, m, tm)
+    itemsize = w1.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(_gate_up_kernel, tm=tm, act=act),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(walk[0].shape[0],),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (1 + len(matrices)),
+            out_specs=[pl.BlockSpec((tm, n),
+                                    lambda i, expert, tile, *_: (tile[i], 0))],
+            scratch_shapes=[pltpu.VMEM((2, tm, 1, k), x.dtype),
+                            pltpu.VMEM((tm, k), _BF16),
+                            pltpu.VMEM((2, len(matrices), k, n), w1.dtype),
+                            pltpu.VMEM((len(matrices), k, n), _BF16),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2, len(matrices)))]),
+        out_shape=[jax.ShapeDtypeStruct((m, n), _BF16)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # the matrices' two slots and their rounded copies, the rows'
+            # two buffers and their rounded copy, the products, the
+            # result's two tiles, and what the compiler stages
+            vmem_limit_bytes=(len(matrices) * k * n * (2 * itemsize + 2)
+                              + tm * k * (2 * x.dtype.itemsize + 2)
+                              + tm * n * (3 * 4 + 2 * 2) + (4 << 20))),
+        # every matrix once — fewer where experts have no row —, a row of
+        # `x` a pair, the result once
+        cost_estimate=pl.CostEstimate(
+            flops=2 * len(matrices) * m * k * n,
+            transcendentals=m * n if act != "relu" else 0,
+            bytes_accessed=(len(matrices) * w1.size * itemsize
+                            + m * k * x.dtype.itemsize + m * n * 2)),
+        name="grouped_gate_up_kernel",
+        interpret=interpret,
+    )(*walk, token.astype(jnp.int32), x.reshape(-1, 1, k), *matrices)
+
+
+def _down_kernel(expert_ref, tile_ref, turn_ref, following_ref, offsets_ref,
+                 count_ref, dest_ref, h_ref, weight_ref, w_hbm, out_hbm,
+                 slots, rounded, sent, w_sems, row_sems, *, tm):
+    i, last = pl.program_id(0), pl.num_programs(0) - 1
+    count = count_ref[0]
+    e, turn = expert_ref[i], turn_ref[i]
+
+    def rows_of(item, wait):
+        """Item `item`'s own rows of its tile, each from the item's buffer
+        to its place in `out`: start the copies, or wait for them."""
+        expert, first = expert_ref[item], tile_ref[item] * tm
+        slot = item % 2
+
+        lo = jnp.maximum(first, offsets_ref[expert])
+        hi = jnp.minimum(first + tm, offsets_ref[expert + 1])
+
+        def row(r, carry):
+            copy = pltpu.make_async_copy(
+                sent.at[slot, 0 if wait else r - first],
+                out_hbm.at[0 if wait else dest_ref[r]], row_sems.at[slot])
+            copy.wait() if wait else copy.start()
+            return carry
+
+        if wait:
+            # a whole tile's copies signalled one semaphore `tm` rows'
+            # bytes: one wait for them together
+            @pl.when(hi - lo == tm)
+            def _():
+                pltpu.make_async_copy(sent.at[slot], sent.at[slot],
+                                      row_sems.at[slot]).wait()
+            lo = jnp.where(hi - lo == tm, hi, lo)
+        lax.fori_loop(lo, hi, row, 0)
+
+    # the buffer this item fills was sent from two items ago
+    @pl.when((i >= 2) & (i - 2 < count))
+    def _():
+        rows_of(i - 2, wait=True)
+
+    def copies(expert, slot):
+        return [pltpu.make_async_copy(w_hbm.at[expert], slots.at[slot],
+                                      w_sems.at[slot])]
+
+    @_expert_opens(i, count, e, turn, expert_ref, following_ref, copies)
+    def _(slot):
+        rounded[...] = slots[slot].astype(_BF16)
+
+    @pl.when(i < count)
+    def _():
+        y = jnp.dot(h_ref[...].astype(_BF16), rounded[...],
+                    preferred_element_type=jnp.float32)
+        # the tile's weights lie along the lanes: each to its row's
+        # sublane by a masked sum of one term (exact), and the rows scaled
+        # in float32 on the vector unit — a matmul would round the scores
+        # to bfloat16
+        across = lax.broadcasted_iota(jnp.int32, (tm, tm), 1)
+        down_ = lax.broadcasted_iota(jnp.int32, (tm, tm), 0)
+        weight = jnp.sum(jnp.where(across == down_, weight_ref[0], 0.0),
+                         axis=1, keepdims=True)
+        sent[i % 2, :, 0, :] = (y * weight).astype(sent.dtype)
+        rows_of(i, wait=False)
+
+    @pl.when(i == last)
+    def _():
+        for item in (i - 1, i):
+            @pl.when((item >= 0) & (item < count))
+            def _():
+                rows_of(item, wait=True)
+
+
+def down(h, w, sizes, dest, weight, *, tm, dtype, interpret=False):
+    """``h [M, H]`` sorted by expert, ``w [E, H, D]``, ``sizes [E]``,
+    ``dest [M]`` int32 (distinct, below `M`) and ``weight [M]`` float32 →
+    ``[out [M, 1, D]]`` of `dtype` (a name): ``out[dest[r]] = (h[r] @
+    w[e]) * weight[r]`` for every row r of a segment — one bfloat16 pass
+    accumulated in float32, the scaling in float32 —, sent there by row
+    DMA; a row of `out` that no `dest` names is not written."""
+    m, k = h.shape
+    n = w.shape[2]
+    dtype = jnp.dtype(dtype)
+    walk = items(sizes, m, tm)
+    tiles = pl.cdiv(m, tm)
+    weight = jnp.pad(weight.astype(jnp.float32),
+                     (0, tiles * tm - m)).reshape(tiles, 1, tm)
+    at_tile = lambda i, expert, tile, *_: (tile[i], 0)
+    return pl.pallas_call(
+        functools.partial(_down_kernel, tm=tm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(walk[0].shape[0],),
+            in_specs=[pl.BlockSpec((tm, k), at_tile),
+                      pl.BlockSpec((1, 1, tm),
+                                   lambda i, expert, tile, *_:
+                                   (tile[i], 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            scratch_shapes=[pltpu.VMEM((2, k, n), w.dtype),
+                            pltpu.VMEM((k, n), _BF16),
+                            pltpu.VMEM((2, tm, 1, n), dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((m, 1, n), dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # the matrix's two slots and its rounded copy, the pipeline's
+            # two tiles of `h`, the products, the two buffers sent from,
+            # and what the compiler stages
+            vmem_limit_bytes=(k * n * (2 * w.dtype.itemsize + 2)
+                              + 2 * tm * k * h.dtype.itemsize
+                              + tm * n * (2 * 4 + 2 * dtype.itemsize)
+                              + (4 << 20))),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(w.size * w.dtype.itemsize
+                            + m * k * h.dtype.itemsize
+                            + m * n * dtype.itemsize)),
+        name="grouped_down_kernel",
+        interpret=interpret,
+    )(*walk, dest.astype(jnp.int32), h, weight, w)
+
+
+def _slab_sum_kernel(s_ref, o_ref):
+    total = s_ref[0, :, 0, :]
+    for slot in range(1, s_ref.shape[0]):
+        total = total + s_ref[slot, :, 0, :]
+    o_ref[...] = total
+
+
+def slab_sum(slabs, *, k, tb=128, interpret=False):
+    """`down`'s ``slabs [k T, 1, D]`` → ``[out [T, D]]``: the k slabs added,
+    slot 0 first, `tb` tokens a step, and the sum stored as whole ``(8,
+    128)`` tiles.  XLA's own sum of the slabs keeps the rows of one
+    sublane, and the loop over a long bucket's pieces then stacks its
+    results in that layout: 1.2 ms a piece of SmallThinker's for the
+    update alone (PERF.md section 6, PR 61)."""
+    rows, _, d = slabs.shape
+    t = rows // k
+    itemsize = slabs.dtype.itemsize
+    return pl.pallas_call(
+        _slab_sum_kernel,
+        grid=(pl.cdiv(t, tb),),
+        in_specs=[pl.BlockSpec((k, tb, 1, d), lambda i: (0, i, 0, 0))],
+        out_specs=[pl.BlockSpec((tb, d), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((t, d), slabs.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=2 * (k + 2) * tb * d * itemsize + (4 << 20)),
+        cost_estimate=pl.CostEstimate(
+            flops=(k - 1) * t * d, transcendentals=0,
+            bytes_accessed=(k + 1) * t * d * itemsize),
+        name="grouped_slab_sum_kernel",
+        interpret=interpret,
+    )(slabs.reshape(k, t, 1, d))

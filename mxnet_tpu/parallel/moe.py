@@ -299,6 +299,118 @@ def _held_passes(x, order, sorted_e, load, top_w, rows, weights, biases,
     return lax.scan(one, out, jnp.arange(passes) * rows)[0]
 
 
+# (What PR 61 adds stands BELOW `_held_passes`: the exported kernel's body
+# holds the source lines of the calls that reach it, so a held range's
+# programs stay the parent's byte for byte while the lines above do.)
+def fused_tile(rows, experts, d, h, gated):
+    """The row tile of the TWO kernel calls a routed FFN is where its
+    calls fetch and place their own rows (``ops.grouped_matmul_kernel``
+    `gate_up` / `down`, PR 61) — `rows` sorted rows, `experts` FFNs of
+    ``[d, h]`` (`gated`: two such an expert) and ``[h, d]`` as operands —
+    or None: a shape the kernel does not take (`kernel_tiles`), matrices
+    an expert that `_KERNEL_BLOCK` does not hold WHOLE (the two calls walk
+    no strips: such a layer keeps the three calls), or more rows than
+    `_FUSED_ROWS`.  `_dropless` says which calls may ask."""
+    if (kernel_tiles(rows, experts, d, h) is None or rows > _FUSED_ROWS
+            or 4 * d * h * (1 + bool(gated)) > _KERNEL_BLOCK):
+        return None
+    return _KERNEL_TILE
+
+
+# the sorted rows' tokens and places are scalars the two calls read from
+# the TPU's scalar memory, whole (a v5e's is 1 MiB: compiled for a described
+# one, a call of 131,072 rows fits beside the walk and one of 262,144 does
+# not; jaxlib 0.9.0 / libtpu 0.0.34) — half of what was seen to fit
+_FUSED_ROWS = 1 << 16
+
+
+def _every_pair(matmul, x, order, sorted_e, load, top_w, weights, biases, act,
+                gated, spare, held):
+    """The experts' part of `_dropless` over every pair's row: gather the
+    sorted pairs' rows of `x` (and `spare` more: `_spare_rows`), multiply
+    each expert's segment by its weights through ``matmul(rows, w, load)``,
+    return the results to token order and sum a token's k, weighted by
+    the router.  `held`: pairs of experts held elsewhere lie behind every
+    segment and add nothing.  Returns out [T, D]."""
+    t_len, k = top_w.shape
+
+    def spared(pair_rows):
+        return jnp.pad(pair_rows, ((0, spare), (0, 0))) if spare else pair_rows
+
+    ys = expert_ffn(lambda r, w: matmul(r, w, load),
+                    spared(x[order // k]), weights,            # [T*k (+), D]
+                    None if biases is None
+                    else [spared(b.astype(x.dtype)[sorted_e]) for b in biases],
+                    act, gated)
+    if spare:
+        ys = ys[:len(order)]
+    if held:
+        ys = jnp.where((sorted_e < len(load))[:, None], ys, 0)
+    pairs = ys[jnp.argsort(order)].reshape(t_len, k, -1)   # token order
+    # on the vector unit: a matmul would round the scores to bfloat16
+    return (pairs * top_w.astype(pairs.dtype)[:, :, None]).sum(1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _fused_experts(static, interpret, x, order, load, top_w, *weights):
+    """`_every_pair` where a program lowered for a TPU runs it as the two
+    kernel calls that fetch and place their own rows (`_two_calls`,
+    `static` ``(tm, act, gated)`` from `fused_tile`); any other platform,
+    and every backward, is `_every_pair` through `lax.ragged_dot`."""
+    return lax.platform_dependent(
+        x, order, load, top_w, *weights,
+        tpu=functools.partial(_two_calls, static, interpret),
+        default=functools.partial(_plain_experts, *static[1:]))
+
+
+def _two_calls(static, interpret, x, order, load, top_w, *weights):
+    """`gate_up` reads row r of the sorted pairs at ``x[order[r] // k]``
+    and writes ``act(g) * u``; `down` weighs its row r by the router and
+    writes it to row ``slot * T + token`` of k slabs of ``[T, D]``, whose
+    sum over k is the result — no ``[T k, D]`` copy of `x`, no un-sort, no
+    ``[T, k, D]`` relayout.  With no held range every (token, slot) has
+    one sorted row: every row of the slabs is written once.  `slab_sum`
+    adds them slot 0 first, as `_every_pair` sums a token's k rows."""
+    tm, act, _ = static
+    t_len, k = top_w.shape
+    token = order // k
+    call = functools.partial(exported.call, "grouped_matmul_kernel",
+                             interpret=interpret)
+    h, = call("gate_up", (x, token, load, weights[0]) + weights[2:], tm=tm,
+              act=act)
+    slabs, = call("down", (h, weights[1], load, order % k * t_len + token,
+                           top_w.reshape(-1)[order]),
+                  tm=tm, dtype=str(x.dtype))
+    # slab on slab where they lie, rows of one sublane, into whole tiles:
+    # the kernel's, because XLA's own sum keeps the rows' layout and the
+    # loop over a long bucket's pieces then stacks its results in it
+    out, = call("slab_sum", (slabs,), k=k)
+    return out
+
+
+def _plain_experts(act, gated, x, order, load, top_w, *weights):
+    return _every_pair(_ragged_dot, x, order, None, load, top_w, weights,
+                       None, act, gated, 0, False)
+
+
+def _fused_experts_fwd(static, interpret, *operands):
+    return _fused_experts(static, interpret, *operands), operands
+
+
+def _fused_experts_bwd(static, interpret, operands, cotangent):
+    x, order, load, top_w, *weights = operands
+    _, vjp = jax.vjp(
+        lambda x, top_w, *weights: _plain_experts(
+            *static[1:], x, order, load, top_w, *weights),
+        x, top_w, *weights)
+    d_x, d_top_w, *d_weights = vjp(cotangent)
+    none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (d_x, none(order), none(load), d_top_w, *d_weights)
+
+
+_fused_experts.defvjp(_fused_experts_fwd, _fused_experts_bwd)
+
+
 def dropless_experts(x, logits, k, weights, biases=None, act="relu",
                      gated=False, normalize=True, score="softmax",
                      select_bias=None, scale=1.0, held=None):
@@ -381,30 +493,20 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
         out = _held_passes(x, order, sorted_e, load, top_w, rows, weights,
                            biases, act, gated)
         return out, load.astype(jnp.float32)
+    if held is None and biases is None and x.dtype == jnp.float32:
+        tm = fused_tile(len(order), *weights[0].shape, gated)
+        if tm:
+            out = _fused_experts((tm, act, gated), _INTERPRET, x, order, load,
+                                 top_w, *weights)
+            return out, load.astype(jnp.float32)
     # rows past the last pair lie beyond every segment: no expert multiplies
     # them, nothing reads them, and a gradient that reaches them is cut off
     # with them (`_spare_rows` says why there are any: to steer XLA's tile,
     # so none where the kernel walks its own)
     spare = 0 if kernel_tiles(len(order), *weights[0].shape) else \
         _spare_rows(len(order), logits.shape[-1])
-
-    def spared(pair_rows):
-        return jnp.pad(pair_rows, ((0, spare), (0, 0))) if spare else pair_rows
-
-    def matmul(r, w):
-        return segment_matmul(r, w, load)
-
-    ys = expert_ffn(matmul, spared(x[order // k]), weights,    # [T*k (+), D]
-                    None if biases is None
-                    else [spared(b.astype(x.dtype)[sorted_e]) for b in biases],
-                    act, gated)
-    if spare:
-        ys = ys[:len(order)]
-    if held is not None:
-        ys = jnp.where((sorted_e < n_exp)[:, None], ys, 0)
-    pairs = ys[jnp.argsort(order)].reshape(t_len, k, -1)   # token order
-    # on the vector unit: a matmul would round the scores to bfloat16
-    out = (pairs * top_w.astype(pairs.dtype)[:, :, None]).sum(1)
+    out = _every_pair(segment_matmul, x, order, sorted_e, load, top_w,
+                      weights, biases, act, gated, spare, held is not None)
     return out, load.astype(jnp.float32)
 
 

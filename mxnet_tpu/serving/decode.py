@@ -1273,13 +1273,15 @@ class GenerativeSession:
         load filled (`moe.passes`) — a call in pieces as if its held pairs
         lay evenly over them.  The same rows are `moe.kernel_rows` where
         the program's segment matmuls are the TPU's kernel
-        (`parallel.moe.kernel_tiles`)."""
+        (`parallel.moe.kernel_tiles`), and `moe.fused_rows` where the
+        kernel's calls fetch and place their own rows
+        (`parallel.moe.fused_tile`)."""
         if telemetry.enabled():
             telemetry.inc("moe.pairs", int(load.sum()))
             telemetry.inc("moe.experts_hit", int((load > 0).sum()))
             telemetry.inc("moe.expert_slots", int(load.size))
             telemetry.inc("moe.max_load", int(load.max(axis=-1).sum()))
-            pairs, pieces, rows, kernel = plan
+            pairs, pieces, rows, kernel, fused = plan
             gathered = pairs * len(load)
             if rows:
                 passes = pieces * int(_np.ceil(
@@ -1290,6 +1292,8 @@ class GenerativeSession:
             telemetry.inc("moe.pair_rows", gathered)
             if kernel:
                 telemetry.inc("moe.kernel_rows", gathered)
+            if fused:
+                telemetry.inc("moe.fused_rows", gathered)
 
     # ------------------------------------------------------------------
     # admission: prefill newly-arrived prompts into free slots

@@ -144,6 +144,12 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
         ("every expert", 80, 4, True, True)]
     assert all(row["xla_ms"] > 0 and row["kernel_ms"] > 0 and
                row["err"] < 2e-2 for row in table)
+    # with every expert held the whole layer-piece is timed as the two
+    # calls that fetch and place their own rows too (PR 61), and held to
+    # the three between a gather and an un-sort; a held range is not
+    assert "two_calls_ms" not in table[0]
+    assert table[1]["two_calls_ms"] > 0 and table[1]["three_calls_ms"] > 0
+    assert table[1]["fused_err"] < 1e-5
     # two layers' K and V rings of both attention-only shapes, then a
     # delta-rule layer's window and state beside one layer's rings, found
     # in the compiled decode programs, then a window layer's rings of 16

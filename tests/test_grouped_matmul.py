@@ -4,9 +4,13 @@ the CPU against `lax.ragged_dot`: alone at every way a call's segments can
 lie over the row tiles, and inside `parallel.moe.dropless_experts` with
 the kernel forced against the parent's form — outputs, `load` and the
 gradients — and through the shape function that says where the kernel
-runs (`parallel.moe.kernel_tiles`).  What Mosaic makes of the kernel at
-the benchmark's widths is in tests/test_tpu_compile.py.  The file costs
-about 40 s."""
+runs (`parallel.moe.kernel_tiles`).  The two calls that fetch and place
+their own rows and the sum of what they place (PR 61: `gate_up`, `down`,
+`slab_sum`, chosen by `parallel.moe.fused_tile`) against the gather,
+`lax.ragged_dot` and the un-sort they replace, alone and inside the
+layer.  What Mosaic makes of
+the kernels at the benchmark's widths is in tests/test_tpu_compile.py.
+The file costs about 70 s."""
 import contextlib
 from unittest import mock
 
@@ -16,7 +20,9 @@ import numpy as np
 import pytest
 from jax import lax
 
-from mxnet_tpu.ops.grouped_matmul_kernel import grouped_matmul, items
+from mxnet_tpu.ops.grouped_matmul_kernel import (down, gate_up,
+                                                  grouped_matmul, items,
+                                                  slab_sum)
 from mxnet_tpu.parallel import moe
 
 # name -> (rows M, K, N, row tile, columns a strip, each expert's rows)
@@ -102,6 +108,82 @@ def test_the_kernel_multiplies_each_experts_rows_by_its_matrix(name):
                           and set(tile[count:]) <= {tile[count - 1]})
 
 
+# name -> (tokens, experts a token, D, H, row tile, each expert's rows,
+# activation, gated)
+FUSED = {
+    # 39 rows over tiles of 16: row 9 an edge inside a tile, an expert
+    # with no row, a last tile of 7 rows
+    "an edge inside a tile, an expert with no row, a partial last tile": (
+        13, 3, 128, 256, 16, [9, 0, 30], "silu", True),
+    "un-gated, segments that cross tiles": (
+        11, 4, 256, 128, 16, [30, 14], "relu", False),
+    "several segments inside one tile larger than the rows": (
+        13, 3, 128, 128, 128, [3, 5, 0, 7, 17, 7], "relu", True),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FUSED))
+def test_the_two_calls_fetch_and_place_their_own_rows(name):
+    """Operands of small whole numbers, so that every product and every
+    float32 sum is exact whatever its order: `gate_up` — the rows of `x`
+    fetched by `token` — against gather + `lax.ragged_dot` x 2 + product
+    rounded to bfloat16, TO THE LAST BIT.  `down` against `lax.ragged_dot`
+    of that `h` (no whole numbers any more: the two differ by the order
+    of a float32 sum), each row times its float32 weight, at ``dest`` —
+    every row of the k slabs written; and `slab_sum` — slot 0 first, one
+    add a slab, to the last bit; 13 and 11 tokens over steps of 8 —
+    against the parent's ``(pairs * top_w).sum(1)`` — XLA's reduction
+    over k, in the order it chooses — within float32 rounding of a k-term
+    sum.  That `h` stored
+    bfloat16 changes no bit of `down`'s result is the layer test's: the
+    three-call form rounds the same float32 `h` in the same place."""
+    tokens, k, d, h, tm, sizes, act, gated = FUSED[name]
+    m = tokens * k
+    assert sum(sizes) == m
+    rng = np.random.default_rng(len(name))
+
+    def whole(*shape):
+        return jnp.asarray(rng.integers(-2, 3, shape), jnp.float32)
+
+    sizes = jnp.asarray(sizes, jnp.int32)
+    x, w1, w3 = whole(tokens, d), whole(len(sizes), d, h), whole(len(sizes), d, h)
+    w2 = whole(len(sizes), h, d)
+    order = jnp.asarray(rng.permutation(m), jnp.int32)   # sorted row -> pair
+    token = order // k
+    top_w = jnp.asarray(rng.random((tokens, k)), jnp.float32)
+    got, = gate_up(x, token, sizes, w1, *([w3] if gated else []), tm=tm,
+                   act=act, interpret=True)
+    assert got.shape == (m, h) and got.dtype == jnp.bfloat16
+
+    def dot(rows, w):
+        return lax.ragged_dot(rows, w, sizes, precision=lax.Precision.HIGHEST)
+
+    want = getattr(jax.nn, act)(dot(x[token], w1))
+    if gated:
+        want = want * dot(x[token], w3)
+    np.testing.assert_array_equal(
+        np.asarray(got.astype(jnp.float32)), np.asarray(_rounded(want)))
+    slabs, = down(got, w2, sizes, order % k * tokens + token,
+                  top_w.reshape(-1)[order], tm=tm, dtype="float32",
+                  interpret=True)
+    assert slabs.shape == (m, 1, d) and slabs.dtype == jnp.float32
+    pairs = dot(_rounded(want), w2)[jnp.argsort(order)].reshape(tokens, k, d)
+    weighed = pairs * top_w[:, :, None]
+    # (the interpreter hands out NaNs: a row nobody wrote would show)
+    scale = float(jnp.abs(weighed).max())
+    assert np.abs(np.asarray(slabs[:, 0]) - np.asarray(
+        weighed.transpose(1, 0, 2).reshape(m, d))).max() < 1e-6 * scale
+    out, = slab_sum(slabs, k=k, tb=8, interpret=True)
+    assert out.shape == (tokens, d) and out.dtype == jnp.float32
+    assert np.abs(np.asarray(out) - np.asarray(weighed.sum(1))).max() \
+        < k * 1e-6 * scale
+    by_slot = slabs.reshape(k, tokens, d)
+    want = by_slot[0]
+    for slot in range(1, k):
+        want = want + by_slot[slot]
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
 @contextlib.contextmanager
 def _tpu_kernel_interpreted(rows=1, tile=16):
     """Inside, `segment_matmul` takes the branch a lowering for the TPU
@@ -141,7 +223,12 @@ def test_the_layer_through_the_kernel_is_the_parents(name, biased):
     the output and the gradients in `x`, the logits, the three matrices
     and the biases within the one bfloat16 pass the kernel makes where
     the CPU's dot is float32 — the backward IS `lax.ragged_dot`'s, at the
-    kernel's forward values."""
+    kernel's forward values.  With no held range and no bias (PR 61) the
+    layer is the TWO calls that fetch and place their own rows, a piece:
+    one branch a trace, its backward `_every_pair`'s through
+    `lax.ragged_dot`, and within float32 rounding of the three-call
+    form it replaces (`h` rounded to bfloat16 where `down` would round
+    it; a token's k rows summed in the same order)."""
     tokens, k, scored, held, patched = LAYERS[name]
     rng = np.random.default_rng(7)
     d_model, d_expert = 128, 256
@@ -167,15 +254,27 @@ def test_the_layer_through_the_kernel_is_the_parents(name, biased):
             lambda *args: (layer(*args)[0] ** 2).sum(), (0, 1, 2, 3))(
                 x, logits, weights, biases)
 
+    fused = held is None and not biased
     with contextlib.ExitStack() as stack:
         for attr, value in patched.items():
             stack.enter_context(mock.patch.object(moe, attr, value))
         parent = both()
         with _tpu_kernel_interpreted() as calls:
             kernel = both()
-    # a layer's three matmuls, forward and forward again under the
-    # gradient (a loop's body is traced more than once)
-    assert len(calls) >= 6 and len(calls) % 3 == 0
+            if fused:
+                with mock.patch.object(moe, "_FUSED_ROWS", 0):
+                    three = layer(x, logits, weights, biases)[0]
+    if fused:
+        # the two calls are ONE branch a trace, forward and forward again
+        # under the gradient: no segment matmul of its own is left
+        assert len(calls) >= 2 and {
+            call.func for call in calls[:-3]} == {moe._two_calls}
+        assert np.abs(np.asarray(kernel[0][0] - three)).max() \
+            < 1e-6 * np.abs(np.asarray(three)).max()
+    else:
+        # a layer's three matmuls, forward and forward again under the
+        # gradient (a loop's body is traced more than once)
+        assert len(calls) >= 6 and len(calls) % 3 == 0
     np.testing.assert_array_equal(np.asarray(kernel[0][1]),
                                   np.asarray(parent[0][1]))
     assert 0 < float(parent[0][1].sum()) <= tokens * k
@@ -213,8 +312,32 @@ TILES = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(TILES))
+# name -> ((rows, experts, D, H, gated), the row tile or None): where the
+# layer's calls fetch and place their own rows — `_dropless` asks for a
+# call with no held range alone
+FUSED_TILES = {
+    "fused: smallthinker 8200, a piece": ((24600, 64, 2560, 768, True), 128),
+    "fused: smallthinker 10248, a piece": ((20496, 64, 2560, 768, True),
+                                           128),
+    "fused: smallthinker step": ((48, 64, 2560, 768, True), None),
+    "fused: olmoe 520": ((4160, 64, 2048, 1024, True), 128),
+    "fused: olmoe 136": ((1088, 64, 2048, 1024, True), 128),
+    "fused: olmoe 72": ((576, 64, 2048, 1024, True), None),
+    # both matrices of an expert whole, twice over, or the three calls
+    "fused: dots3's matrices, too large": ((6144, 8, 5120, 1536, True),
+                                           None),
+    "fused: dots3's matrices, un-gated": ((6144, 8, 5120, 768, False), 128),
+    "fused: more rows than the scalar memory holds": (
+        (1 << 17, 64, 128, 128, True), None),
+}
+
+
+@pytest.mark.parametrize("name", sorted({**TILES, **FUSED_TILES}))
 def test_the_rule_reads_a_calls_static_shape(name):
+    if name in FUSED_TILES:
+        shape, tile = FUSED_TILES[name]
+        assert moe.fused_tile(*shape) == tile
+        return
     shape, tiles = TILES[name]
     assert moe.kernel_tiles(*shape) == tiles
 
@@ -223,7 +346,9 @@ def test_off_the_tpu_the_layer_is_ragged_dot_forward_and_backward():
     """A call the rule takes, traced for the CPU: the platform's branch is
     `lax.ragged_dot`, so output, load and gradients are the parent's bit
     for bit — and a call under the rule is traced with no trace of the
-    choice."""
+    choice.  The call here is one `fused_tile` takes too (PR 61): what is
+    lowered for the CPU is the parent's program — the same operations the
+    same number of times, no kernel."""
     rng = np.random.default_rng(11)
     tokens, k, experts, d_model, d_expert = 256, 2, 4, 128, 128
     assert moe.kernel_tiles(tokens * k, experts, d_model, d_expert)
@@ -249,11 +374,27 @@ def test_off_the_tpu_the_layer_is_ragged_dot_forward_and_backward():
         return str(jax.make_jaxpr(lambda *args: layer(*args))(
             x, logits, weights))
 
-    chosen = both()
+    def lowered():
+        import collections
+        import re
+
+        text = jax.jit(lambda *args: layer(*args)).lower(
+            x, logits, weights).as_text()
+        assert "custom_call" not in text
+        # but for the choice itself: a `case` of one branch, functions
+        ops = collections.Counter(re.findall(r'= "?(\w+\.\w+)', text))
+        return {op: n for op, n in ops.items() if op not in (
+            "func.call", "stablehlo.case", "stablehlo.constant")}
+
+    assert moe.fused_tile(tokens * k, experts, d_model, d_expert, True)
+    chosen, program = both(), lowered()
     assert "platform_index" in traced()
+    assert program["stablehlo.dot_general"] == 3 \
+        and program["stablehlo.gather"] == 2
     with mock.patch.object(moe, "_KERNEL_ROWS", 1 << 30):
         parent = both()
         assert "platform_index" not in traced()
+        assert lowered() == program
     for a, b in zip(jax.tree_util.tree_leaves(chosen),
                     jax.tree_util.tree_leaves(parent)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

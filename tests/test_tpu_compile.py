@@ -180,6 +180,9 @@ PASS_ROWS = {"qwen3-next 2048": 8192, "trinity 2048": 12800,
 # `moe.kernel_tiles`: from `_KERNEL_ROWS` sorted rows an expert held)
 KERNEL_CASES = {"olmoe 128", "olmoe 256", "olmoe 512", "qwen3-next 2048",
                 "trinity 2048", "granite-h-small 512", "smallthinker 8192"}
+# of them, the cases whose kernel calls fetch and place their own rows (PR
+# 61, `moe.fused_tile`: no held range, an expert's matrices held whole)
+FUSED_CASES = {"olmoe 128", "olmoe 256", "olmoe 512", "smallthinker 8192"}
 
 
 def _ragged_dots(text):
@@ -212,7 +215,17 @@ def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
     held range, and in a decode step, the layer is the straight line it
     was: no loop, no conditional — but the one loop over the pieces of a
     bucket whose pair rows pass `_PAIR_BYTES` (SmallThinker's 8,192: two
-    pieces of 24,600 rows)."""
+    pieces of 24,600 rows).  With no held range (PR 61, `moe.fused_tile`)
+    the kernel's calls are TWO a piece — `grouped_gate_up_kernel`, which
+    fetches its rows of `x`, and `grouped_down_kernel`, which puts each
+    weighted row where its token's sum reads it — and no array of every
+    pair's row is left in the program at all: no gather, scatter or copy
+    of ``[pairs, D]``; the k slabs the second call writes are rows of ONE
+    sublane, ``[pairs, 1, D]``, which a third, small call
+    (`grouped_slab_sum_kernel`) adds slab on slab into whole tiles, so
+    that the loop over the pieces carries its result in the layout the
+    parent's does.  A decode step and a held range's call hold none of
+    the three."""
     import re
 
     import jax
@@ -241,17 +254,34 @@ def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
     pairs = tokens // pieces * k
     loops = len(re.findall(r" (?:while|conditional)\(", text))
     every_pair = re.findall(r"f32\[%d,%d\]" % (pairs, d_model), text)
-    if passed:
-        assert loops == 2 and every_pair == []
+    fused = held_range is None and moe.fused_tile(
+        pairs, held, d_model, d_expert, True) is not None
+    assert fused == (name in FUSED_CASES)
+    if passed or fused:
+        assert loops == (2 if passed else pieces > 1) and every_pair == []
     else:
         assert loops == (pieces > 1) and every_pair
     dots = _ragged_dots(text)
     tiles = moe.kernel_tiles(passed or pairs, held, d_model, d_expert)
     assert (tiles is not None) == (name in KERNEL_CASES)
+    calls = [chip_smoke.named_kernel_calls(text, kernel) for kernel in (
+        "grouped_matmul_kernel", "grouped_gate_up_kernel",
+        "grouped_down_kernel", "grouped_slab_sum_kernel")]
+    assert calls == ([0, 1, 1, 1] if fused else [3, 0, 0, 0] if tiles
+                     else [0, 0, 0, 0])
+    if fused:
+        assert dots == [] and "ragged" not in text
+        # the rows are fetched from `x` and written into the slabs where
+        # they lie: both one sublane a row
+        assert re.search(r"f32\[%d,1,%d\]\S* custom-call\(" % (pairs, d_model),
+                         text)
+        assert re.search(r"bf16\[%d,%d\]\S* custom-call\(" % (pairs, d_expert),
+                         text)
+        assert not re.search(r" (?:gather|scatter)\([^\n]*f32\[%d," % pairs,
+                             text)
+        return
     if tiles:
         assert dots == [] and "ragged" not in text
-        assert chip_smoke.named_kernel_calls(
-            text, "grouped_matmul_kernel") == 3
         # the rows there are, not a whole number of 512-row tiles
         spare = moe._spare_rows(pairs, scored)
         assert passed or not spare or not re.search(
@@ -901,6 +931,7 @@ def test_the_smallthinker_programs_compile_for_a_v5e(program, one_chip):
     tenant died binding on the chip) and the larger program's temporaries
     fit a v5e."""
     import json
+    import re
     import warnings
 
     from benchmarks.families import smallthinker as family
@@ -937,11 +968,16 @@ def test_the_smallthinker_programs_compile_for_a_v5e(program, one_chip):
     assert chip_smoke.named_kernel_calls(text, "sdp_causal_attention") == (
         4 * (program == "mixed"))
     # and the mixed step's expert layers are OUR grouped matmul (PR 60:
-    # three pieces of 20,496 sorted rows over 64 experts), the step's
-    # 48 pairs a layer `lax.ragged_dot`
-    assert chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel") == (
-        12 * (program == "mixed"))
+    # three pieces of 20,496 sorted rows over 64 experts), since PR 61 as
+    # the two calls that fetch and place their own rows and the slabs'
+    # sum — and no array of a piece's every pair's row, gathered,
+    # scattered or copied —, the step's 48 pairs a layer `lax.ragged_dot`
+    assert [chip_smoke.named_kernel_calls(text, kernel) for kernel in (
+        "grouped_matmul_kernel", "grouped_gate_up_kernel",
+        "grouped_down_kernel", "grouped_slab_sum_kernel")] == [
+            0, *[4 * (program == "mixed")] * 3]
     assert ("ragged" in text) == (program == "decode")
+    assert not re.search(r"f32\[20496,2560\]", text)
     sets = sum(e.nbytes for e in spec.values())
     assert 0.849e9 < sets < 0.850e9        # nine pages of 94.4 MB
     assert stats.alias_size_in_bytes >= sets
