@@ -47,7 +47,10 @@ apart from the rest:
             call a delta-rule layer (ops/gdn_step_kernel.py) and ONE a
             Mamba-2 layer (ops/ssm_step_kernel.py: the ninth and tenth,
             128 and 64 heads of 64 x 128 states beside an attention
-            layer) and nothing of rows x page size beside it, and no ring
+            layer; the eleventh, PR 62, is LongCat-Flash's published layer
+            as two — 8 heads whose value is narrower than their key, a
+            routed layer with zero-compute experts carried across the
+            second sublayer) and nothing of rows x page size beside it, and no ring
             or recurrent state fatter on the device than cache_spec states;
             prints the rings' on-device layout and the warm ms of the
             delta-rule and Mamba-2 models' decode step; and the 2,048-bucket
@@ -205,6 +208,26 @@ FULL = {
                                 layer_types=["mamba", "attention"],
                                 mamba_heads=64, mamba_head_dim=64,
                                 mamba_state=128, norm="rms",
+                                positions="none", bias=False),
+                           # LongCat-Flash's published layer as two: 8
+                           # held heads of 128 + 64 over a value of 128
+                           # (the 2,048 bucket's blockwise kernel carries
+                           # it at 192), rescaled latents, two rings of
+                           # 512 + 64 lines, a routed layer of 8 held of
+                           # 64 real experts and 32 zero-compute ones
+                           # carried across the second sublayer
+                           dict(num_heads=8, max_len=2304,
+                                seq_buckets=[64, 2048],
+                                layer_types=["latent_attention"] * 2,
+                                latent_q_rank=1536, latent_kv_rank=512,
+                                latent_nope_dim=128, latent_rope_dim=64,
+                                latent_value_dim=128,
+                                latent_lora_rescale=True, ffn="swiglu",
+                                ffn_types=["shortcut", "dense"],
+                                num_experts=64, zero_experts=32,
+                                experts_per_token=12, expert_d_ff=512,
+                                router_bias=True, route_scale=6.0,
+                                held_experts=(0, 8), norm="rms",
                                 positions="none", bias=False)]},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
@@ -1050,8 +1073,10 @@ def phase_kv_ring(sizes, ctx):
                    "times the next larger bucket's program: %s"
                    % (slow, BUCKET_RATIO, prefill_ms))
         if platform == "tpu":
+            # (a routed layer's segment matmuls are XLA's own custom calls)
             others = ((stepped_by["kernel_calls"] if scanned else 0)
-                      + (ssm_by["kernel_calls"] if mamba else 0))
+                      + (ssm_by["kernel_calls"] if mamba else 0)
+                      + fn.hlo_text().count('op_name="ragged-dot'))
             _check(facts["kernel_calls"] - others == ring_layers,
                    "%d attention kernel calls in a decode program of %d "
                    "attention layers" % (facts["kernel_calls"] - others,

@@ -76,10 +76,12 @@ window attention and Gated DeltaNet have one), the device-resident state it
 keeps between calls and the counters a program call adds to; the five
 graph builders walk the pattern and know no kind by name.  `ffn_types`
 names each layer's FFN — the other half — the same way: ``"dense"``
-(:class:`_DenseFFN`, of width `d_ff`) or ``"routed"``
+(:class:`_DenseFFN`, of width `d_ff`), ``"routed"``
 (:class:`_RoutedFFN`: ``mx.sym.MoE`` experts of width `expert_d_ff`, a
-shared expert, a held range); by default every layer's is the one
-`num_experts` implies.
+shared expert, a held range, zero-compute experts) or ``"shortcut"``
+(:class:`_ShortcutFFN`: a dense FFN now and, forked from the same normed
+input, a routed layer whose result joins the stream at the END OF THE NEXT
+layer); by default every layer's is the one `num_experts` implies.
 ``num_kv_heads`` (grouped-query attention), ``positions="none"``,
 ``ffn="swiglu"`` (a dense gated FFN), ``attention_multiplier`` and the
 three stream multipliers (``embedding_`` / ``residual_multiplier``,
@@ -107,7 +109,7 @@ the shared expert and ``held_experts`` are Qwen3-Next's (`qwen3_next`);
 its norm gains are stored as they are applied, ``1 + w`` of the published
 ``w``.
 ``layer_types`` all ``"latent_attention"`` with the kind's `q_rank`,
-`kv_rank`, `nope_dim` + `rope_dim` = `value_dim`, ``rope_scaling`` (YaRN's
+`kv_rank`, `nope_dim` + `rope_dim` >= `value_dim`, ``rope_scaling`` (YaRN's
 blended frequencies on the rotary part alone),
 ``attention_multiplier`` (the softmax scale with YaRN's ``mscale`` square),
 ``query_scale`` (the query grows with the logarithm of its position past
@@ -128,6 +130,14 @@ heads (groups of seven), every layer ``"routed"`` with ``router_input=
 "mixer"`` (the router scores what the block's mixer reads, before the
 mixer runs), ``expert_act="relu"`` (a ReLU gate), ``route_norm`` and no
 shared expert, an untied head: SmallThinker's (`smallthinker`).
+``layer_types`` all ``"latent_attention"`` with a value of 128 under a key
+of 128 + 64 and ``latent_lora_rescale``, ``ffn_types`` of ``("shortcut",
+"dense")`` a PUBLISHED layer — two latent-attention sublayers, two dense
+SwiGLUs of `d_ff` and one routed layer across them —, ``zero_experts`` 256
+behind 512 real ones in a softmax router used unnormalised times
+``route_scale`` with ``router_bias``, held as one chip's share of experts
+AND of heads (`num_heads` is then the share's count): LongCat-Flash's
+(`longcat_flash`).
 
 **A draft module.**  `nextn` = 1 adds DeepSeek-V3's multi-token-prediction
 module behind the trunk (GLM-5's, `glm5`): two norms (``mtp_enorm``,
@@ -708,14 +718,16 @@ class _LatentAttention(_KindLatent):
     MODEL'S heads and rotary base:
     rotary with the spec's `rope_scaling` (YaRN) on ``q_rope`` and ``k_r``
     only; softmax scale `attention_multiplier`; `query_scale`.  The
-    full-sequence forms up-project per head, so a head's key and value
-    have one width; the decode step absorbs `W_kvb` into the query."""
+    full-sequence forms up-project per head and carry a value narrower
+    than the key at the key's width (ops/latent.py); the decode step
+    absorbs `W_kvb` into the query.  `lora_rescale` as every latent
+    kind's."""
 
     KIND = "latent_attention"
-    SIZES, OPTIONS = _KindLatent.SIZES[1:], {}
-    NEEDS = ("an even latent_rope_dim, and latent_nope_dim + latent_rope_dim"
-             " = latent_value_dim: the up-projected form goes through "
-             "_sdp_attention, whose heads have one width")
+    SIZES, OPTIONS = _KindLatent.SIZES[1:], {"lora_rescale": False}
+    NEEDS = ("an even latent_rope_dim, and latent_value_dim within "
+             "latent_nope_dim + latent_rope_dim: the up-projected form goes "
+             "through _sdp_attention, whose heads have one width, the key's")
 
     def __init__(self, lm):
         super().__init__(lm)
@@ -741,7 +753,7 @@ class _LatentAttention(_KindLatent):
 
     def _fits(self, own):
         return super()._fits(own) and (
-            own["nope_dim"] + own["rope_dim"] == own["value_dim"])
+            own["value_dim"] <= own["nope_dim"] + own["rope_dim"])
 
     def _project(self, x, p, i, index=None):
         """``(q_nope, q_rope, latent)`` of the normed stream: the rotary
@@ -1248,12 +1260,15 @@ class _RoutedFFN:
     own score ``x w_s``, ``w_s`` the ``(d_model, 1)``
     ``l<i>_shared_score_weight``.  `held_experts` ``(first, count)`` are the
     experts whose matrices this model holds, one chip's share of the
-    layer: the router and the choice stay `num_experts` wide."""
+    layer: the router and the choice stay `num_experts` wide — and
+    `zero_experts` wider: zero-compute experts behind the real ones, which
+    add their weights' sum times the token itself."""
 
     def __init__(self, lm):
         self.lm = lm
         held = lm.held_experts
         self.held = lm.num_experts if held is None else held[1]
+        self.router_width = lm.num_experts + lm.zero_experts
         # beyond OLMoE's: an option appears on a node only when the
         # spec sets it
         self.attrs = {}
@@ -1271,15 +1286,17 @@ class _RoutedFFN:
             self.attrs.update(held_first=held[0], held_count=held[1])
         if lm.router_input == "mixer":
             self.attrs["router_input"] = True
+        if lm.zero_experts:
+            self.attrs["zero_experts"] = lm.zero_experts
 
     def params(self, i):
         lm, v = self.lm, sym.Variable
         d, ff, e, s = lm.d_model, lm.expert_d_ff, self.held, lm.shared_d_ff
         p = {"router_weight": v("l%d_router_weight" % i,
-                                shape=(d, lm.num_experts))}
+                                shape=(d, self.router_width))}
         if lm.router_bias:
             p["router_bias"] = v("l%d_router_bias" % i,
-                                 shape=(lm.num_experts,))
+                                 shape=(self.router_width,))
         p["gate_weight"] = v("l%d_gate_weight" % i, shape=(e, d, ff))
         p["down_weight"] = v("l%d_down_weight" % i, shape=(e, ff, d))
         p["up_weight"] = v("l%d_up_weight" % i, shape=(e, d, ff))
@@ -1327,7 +1344,33 @@ class _RoutedFFN:
                 (positions + computed) * self.lm.experts_per_token}
 
 
-_FFNS = {"dense": _DenseFFN, "routed": _RoutedFFN}
+class _ShortcutFFN(_RoutedFFN):
+    """Layer i's dense FFN and, FORKED from the same normed input, a
+    routed layer whose result is CARRIED past the next layer's mixer and
+    FFN and joins the stream at that layer's end (`TransformerLM._ffn`):
+    LongCat-Flash's shortcut-connected expert layer — a published layer is
+    two layers here, ``("shortcut", "dense")``.  Between fork and join the
+    branch depends on nothing the dense path computes.  Parameters: a
+    dense FFN's and a routed FFN's, side by side."""
+
+    def __init__(self, lm):
+        super().__init__(lm)
+        self.dense = _DenseFFN(lm)
+
+    def params(self, i):
+        return dict(self.dense.params(i), **super().params(i))
+
+    def apply(self, x, p, i, loads, mixer_in=None):
+        return self.dense.apply(x, p, i, loads)
+
+    def branch(self, x, p, i, loads, mixer_in=None):
+        """The routed layer of the input `apply` read (device scope
+        ``mx:moe.shortcut``): what the NEXT layer's end joins."""
+        with AttrScope(__scope__="mx:moe.shortcut"):
+            return super().apply(x, p, i, loads, mixer_in)
+
+
+_FFNS = {"dense": _DenseFFN, "routed": _RoutedFFN, "shortcut": _ShortcutFFN}
 # a routed FFN's parameters that are stacked an expert
 _EXPERT_KEYS = ("gate_weight", "down_weight", "up_weight")
 
@@ -1376,7 +1419,10 @@ class TransformerLM:
     gain; `out_gate` multiplies attention's context by ``sigmoid(x Wg)``,
     `Wg` fused behind ``[q | k | v]``; `block_norm` ``"both"`` norms a
     branch's input AND its output (``<name>`` and ``<name>_post``);
-    `ffn_types` — one FFN kind a layer, ``"dense"`` | ``"routed"``;
+    `ffn_types` — one FFN kind a layer, ``"dense"`` | ``"routed"`` |
+    ``"shortcut"`` (a dense FFN, and a routed layer of the same normed
+    input whose result joins at the end of the NEXT layer, which is
+    therefore never the last);
     `expert_d_ff` — a routed expert's width (default `d_ff`);
     `shared_d_ff` — the width of one expert every token passes,
     `shared_gate` multiplies what it adds by ``sigmoid(x w_s)``;
@@ -1389,7 +1435,11 @@ class TransformerLM:
     router scores: the FFN's own normed input, or the normed stream the
     block's MIXER read (the choice of experts is then known before the
     mixer has run); `expert_act` ``"silu"`` | ``"relu"`` — the routed
-    experts' gate activation; `rope_scaling` — YaRN's ``{factor,
+    experts' gate activation; `zero_experts` n — the router is
+    ``num_experts + n`` wide, its last n columns zero-compute experts that
+    add ``(sum of their weights) * x`` and hold no matrix (a held range is
+    over the real ones; ``moe_load`` gains one column, their pairs);
+    `rope_scaling` — YaRN's ``{factor,
     original_max_position_embeddings, beta_fast, beta_slow[, mscale,
     mscale_all_dim]}`` for the ``"latent_attention"`` kind's rotary part;
     `query_scale` ``(beta, period)`` — that kind's query at position p
@@ -1413,8 +1463,9 @@ class TransformerLM:
       normed between its two projections), `kv_rank` (the cached ``c``'s
       width), `nope_dim` / `rope_dim` (a head's unturned and rotary query
       channels; one rotary key of an even `rope_dim` serves all heads),
-      `value_dim` (a head's value width, equal to their sum), at the
-      model's `num_heads`; its rotary part turned whatever `positions` says.
+      `value_dim` (a head's value width, at most their sum), at the
+      model's `num_heads`; its rotary part turned whatever `positions` says;
+      `lora_rescale` (the normed latents times ``sqrt(d_model / rank)``).
       Flat: ``latent_<size>``.
     * ``"sparse_latent_attention"`` / ``"window_latent_attention"`` —
       `num_heads`, `q_rank`, `kv_rank`, `nope_dim`, `rope_dim`,
@@ -1441,7 +1492,8 @@ class TransformerLM:
                  router_bias=False, route_norm=False, route_scale=1.0,
                  held_experts=None, rotary_dim=None, shared_gate=False,
                  rope_scaling=None, query_scale=None, kind_specs=None,
-                 nextn=0, router_input="ffn", expert_act="silu", **flat):
+                 nextn=0, router_input="ffn", expert_act="silu",
+                 zero_experts=0, **flat):
         if int(nextn) not in (0, 1):
             raise ValueError("nextn must be 0 or 1 (ONE draft a step), got %r"
                              % (nextn,))
@@ -1523,8 +1575,16 @@ class TransformerLM:
             raise ValueError("ffn_types must name num_layers=%d kinds of %s,"
                              " got %r" % (num_layers, sorted(_FFNS),
                                           ffn_types))
-        if "routed" in ffn_types and not num_experts:
-            raise ValueError("a 'routed' FFN needs num_experts >= 1")
+        routed = bool({"routed", "shortcut"} & set(ffn_types))
+        if routed and not num_experts:
+            raise ValueError("a 'routed' or 'shortcut' FFN needs "
+                             "num_experts >= 1")
+        if ffn_types[-1:] == ("shortcut",):
+            raise ValueError("a 'shortcut' FFN's branch joins at the end of "
+                             "the NEXT layer: the last layer has none")
+        if int(zero_experts) < 0 or (zero_experts and not routed):
+            raise ValueError("zero_experts=%r are a routed FFN's, >= 0"
+                             % (zero_experts,))
         if router_input not in ("ffn", "mixer"):
             raise ValueError("router_input must be 'ffn' or 'mixer', got %r"
                              % (router_input,))
@@ -1533,7 +1593,7 @@ class TransformerLM:
                              % (expert_act,))
         for name, value, default in (("router_input", router_input, "ffn"),
                                      ("expert_act", expert_act, "silu")):
-            if value != default and "routed" not in ffn_types:
+            if value != default and not routed:
                 raise ValueError("%s=%r is a routed FFN's: no layer of "
                                  "ffn_types %r is 'routed'"
                                  % (name, value, ffn_types))
@@ -1578,6 +1638,7 @@ class TransformerLM:
         self.held_experts = held_experts
         self.router_input = router_input
         self.expert_act = expert_act
+        self.zero_experts = int(zero_experts)
         self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
         self.shared_gate = bool(shared_gate)
         self.rope_scaling = rope_scaling
@@ -1674,27 +1735,37 @@ class TransformerLM:
             branch = branch * self.residual_multiplier
         return h + branch
 
-    def _ffn(self, h, p, i, train, loads=None, ffn=None, mixer_in=None):
+    def _ffn(self, h, p, i, train, loads=None, ffn=None, mixer_in=None,
+             carried=None):
         """The block's second half on the residual stream `h`: layer i's
         FFN kind between the block's norms.  A routed model's serving
         graphs pass `loads`, which collects each routed layer's
         tokens-per-expert output; `mixer_in` is what the block's mixer
-        read, for a router that reads it too (`router_input`)."""
+        read, for a router that reads it too (`router_input`).  `carried`
+        is the graph's list of branches that wait for their join: the one
+        the layer before forked (a ``"shortcut"`` FFN's) joins here, as it
+        is, and this layer's own is left there for the next."""
+        ffn = ffn or self._ffns[i]
         x = self._branch_in(h, "l%d_ln2" % i)
-        f = (ffn or self._ffns[i]).apply(x, p, i, loads, mixer_in)
+        f = ffn.apply(x, p, i, loads, mixer_in)
         f = self._branch_out(f, "l%d_ln2" % i)
         if train and self.dropout > 0:
             f = sym.Dropout(f, p=self.dropout, name="l%d_drop" % i)
-        return self._join(h, f)
+        h = self._join(h, f)
+        if carried:
+            h = self._join(h, carried.pop())
+        if hasattr(ffn, "branch"):
+            carried.append(ffn.branch(x, p, i, loads, mixer_in))
+        return h
 
-    def _block_train(self, h, i, train):
+    def _block_train(self, h, i, train, carried):
         p = self._block_params(i)
         x = self._branch_in(h, "l%d_ln1" % i)
         a = self._branch_out(self._mixers[i].full(x, p, i), "l%d_ln1" % i)
         if train and self.dropout > 0:
             a = sym.Dropout(a, p=self.dropout, name="l%d_adrop" % i)
         h = self._join(h, a)
-        return self._ffn(h, p, i, train, mixer_in=x)
+        return self._ffn(h, p, i, train, mixer_in=x, carried=carried)
 
     def _embed(self, data, index=None, tables=None, tag=""):
         """Token embedding (plus the learned position table's rows: each
@@ -1719,8 +1790,9 @@ class TransformerLM:
         """Embedding + positions + the block stack + final norm; returns
         hidden states ``(N, T, d_model)``."""
         h, (embed_w, _) = self._embed(data)
+        carried = []
         for i in range(self.num_layers):
-            h = self._block_train(h, i, train)
+            h = self._block_train(h, i, train, carried)
         return self._norm(h, "ln_f"), embed_w
 
     def _head_weight(self):
@@ -1753,13 +1825,16 @@ class TransformerLM:
                                         name="token")
         extra = []
         if loads:
-            held = (self.held_experts or (0, self.num_experts))[1]
+            # each layer's held experts and, behind them, ONE entry for
+            # all its zero-compute experts
+            held = ((self.held_experts or (0, self.num_experts))[1]
+                    + bool(self.zero_experts))
             extra = [sym.Reshape(sym.Concat(*loads, dim=0),
                                  shape=(len(loads), held), name="moe_load")]
         return sym.Group([logits] + rings + [sampled[1], sampled[0]] + extra)
 
     def _routed(self):
-        return "routed" in self.ffn_types
+        return any(isinstance(ffn, _RoutedFFN) for ffn in self._ffns)
 
     def extra_outputs(self):
         """Names of the serving graphs' outputs after the token."""
@@ -1863,13 +1938,15 @@ class TransformerLM:
         from ..parallel import moe
 
         k = self.experts_per_token
-        pieces, rows = moe.pass_plan(
-            tokens, k, 4 * self.d_model, self.held_experts, self.num_experts)
-        held = (self.held_experts or (0, self.num_experts))[1]
-        call = (rows or tokens // pieces * k, held, self.d_model,
+        scored = self.num_experts + self.zero_experts
+        held = moe.held_range(self.held_experts, scored, self.zero_experts)
+        pieces, rows = moe.pass_plan(tokens, k, 4 * self.d_model, held,
+                                     scored)
+        call = (rows or tokens // pieces * k,
+                (held or (0, self.num_experts))[1], self.d_model,
                 self.expert_d_ff)
         kernel = platform == "tpu" and moe.kernel_tiles(*call) is not None
-        fused = (kernel and self.held_experts is None
+        fused = (kernel and held is None
                  and moe.fused_tile(*call, True) is not None)
         return tokens * k, pieces, rows, kernel, fused
 
@@ -1896,6 +1973,8 @@ class TransformerLM:
                              4 * self.vocab * self.d_model,
                              4 * 2 * self.d_model * self.d_model)
         fixed, expert, head, join = self._weights
+        if load is not None and self.zero_experts:
+            load = load[..., :-1]   # (the zero-compute experts' column)
         hit = iter([] if load is None else (load > 0).sum(axis=-1))
         layers = [f + (0 if e is None else
                        e * int(next(hit, self._ffns[-1].held)))
@@ -1913,13 +1992,15 @@ class TransformerLM:
         its cache entries).  Returns (the stream, every layer's entries
         in `cache_spec`'s order, the routed layers' loads or None)."""
         outs, loads = [], [] if self._routed() else None
+        carried = []
         for layer in self._layers():
-            h = self._block(h, layer, mix, outs, loads)
+            h = self._block(h, layer, mix, outs, loads, carried)
         return h, outs, loads
 
-    def _block(self, h, layer, mix, outs, loads):
+    def _block(self, h, layer, mix, outs, loads, carried=None):
         """One block of a serving graph: `layer` ``(i, mixer, ffn)``; its
-        cache entries go to `outs`, a routed FFN's load to `loads`."""
+        cache entries go to `outs`, a routed FFN's load to `loads`, a
+        branch it forks for the next block to `carried`."""
         i, mixer, ffn = layer
         p = self._block_params(i, mixer, ffn)
         x = self._branch_in(h, "l%d_ln1" % i)
@@ -1927,7 +2008,7 @@ class TransformerLM:
         outs += state
         h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
         return self._ffn(h, p, i, train=False, loads=loads, ffn=ffn,
-                         mixer_in=x)
+                         mixer_in=x, carried=carried)
 
     def _drafted(self, stream, tokens, embed_w, mix, outs, loads):
         """The draft module on the trunk's last `stream` (before ``ln_f``)

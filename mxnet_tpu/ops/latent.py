@@ -11,8 +11,10 @@ and its value ``c W_kvb,h[:, nope:]``, `W_kvb` the per-head up-projection
 
 * ``_latent_attention`` — the UP-PROJECTED form, for a whole sequence
   (training, scoring, prefill): K and V are made from the rows by `W_kvb`
-  and go through ``ops.attention.sdp_attention`` as they are (a head's key
-  and its value are both ``nope + rope`` wide; `scale` carries the
+  and go through ``ops.attention.sdp_attention`` (whose heads have ONE
+  width: where a head's value is narrower than its key of ``nope + rope``,
+  the value rides at the key's width, zeros behind it, and the context's
+  first `value` channels are kept — the same numbers; `scale` carries the
   model's softmax scale).  Device scope ``mx:mla.expand``.
 * ``_latent_cache_write`` — the prefill's rows into the latent ring
   ``(slots, 1, rank + rope, ring_len)``: positions on the minor axis like
@@ -89,8 +91,9 @@ def latent_attention(q_nope, q_rope, latent, kvb_weight, num_heads=1,
     ``latent (N, T, rank + rope)`` rows ``[c | k_r]`` (normed; rotated),
     ``kvb_weight (H * (nope + value), rank)`` → context ``(N, T, H *
     value)``.  Each head's key ``[c W_k,h | k_r]`` and value ``c W_v,h``
-    go through ``sdp_attention`` unchanged, so ``nope + rope`` must equal
-    `value_dim` (the models' graph builder checks it)."""
+    go through ``sdp_attention``, a `value_dim` under ``nope + rope``
+    padded to it with zeros that the context then drops (the models' graph
+    builder checks that it is not over)."""
     h, rope, value = (int(_lit(v)) for v in (num_heads, rope_dim, value_dim))
     n, t, _ = q_nope.shape
     rank = latent.shape[-1] - rope
@@ -106,10 +109,15 @@ def latent_attention(q_nope, q_rope, latent, kvb_weight, num_heads=1,
         if query_scale is not None:
             query = query * _query_factor(
                 jnp.arange(t), query_scale)[None, :, None, None]
-        return _attn.sdp_attention(
+        v, narrow = kv[..., nope:], nope + rope - value
+        if narrow:
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, narrow),))
+        ctx = _attn.sdp_attention(
             query.reshape(n, t, -1), key.reshape(n, t, -1),
-            kv[..., nope:].reshape(n, t, -1), num_heads=h, causal=True,
-            scale=scale)[0]
+            v.reshape(n, t, -1), num_heads=h, causal=True, scale=scale)[0]
+        if narrow:
+            ctx = ctx.reshape(n, t, h, -1)[..., :value].reshape(n, t, -1)
+        return ctx
 
 
 def _infer_latent_write(in_shapes, attrs):

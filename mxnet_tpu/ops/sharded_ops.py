@@ -90,7 +90,9 @@ def _moe_outputs(attrs):
 
 def _infer_moe(in_shapes, attrs):
     data = in_shapes[0]
-    total = int(_lit(attrs["num_experts"]))
+    zero = int(_lit(attrs.get("zero_experts", 0)))
+    # the router scores the zero-compute experts beside the real ones
+    total = int(_lit(attrs["num_experts"])) + zero
     E = _held(attrs)[1]
     H = int(_lit(attrs["hidden_size"]))
     S = int(_lit(attrs.get("shared_size", 0)))
@@ -103,7 +105,7 @@ def _infer_moe(in_shapes, attrs):
                "shared1_weight": (D, S), "shared2_weight": (S, D),
                "shared3_weight": (D, S), "shared_gate_weight": (D, 1),
                "router_data": data}
-    outs = [tuple(data)] + [(E,)] * (_moe_outputs(attrs) - 1)
+    outs = [tuple(data)] + [(E + bool(zero),)] * (_moe_outputs(attrs) - 1)
     return [by_slot[n] for n in _moe_inputs(attrs)], outs
 
 
@@ -129,7 +131,8 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
         capacity_factor=None, act_type="relu", gated=False, no_bias=False,
         normalize=True, return_load=False, score_func="softmax",
         select_bias=False, route_scale=1.0, shared_size=0,
-        shared_gate=False, router_input=False, mesh=None, **kw):
+        shared_gate=False, router_input=False, zero_experts=0, mesh=None,
+        **kw):
     """Top-k routed expert FFN: out[t] = sum_e gate[t,e] * FFN_e(x[t])
     over t's top-k experts, FFN_e = ``act(x w1 + b1) @ w2 + b2``, or with
     `gated` ``(act(x w1 + b1) * (x w3 + b3)) @ w2 + b2``; `no_bias`
@@ -159,7 +162,12 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
     expert operands are experts ``held_first .. held_first + held_count``
     of the `num_experts` the router scores — the choice and the weights
     stay over all of them, pairs of absent experts add nothing, and the
-    load is over the experts held."""
+    load is over the experts held; `zero_experts` n makes the router
+    ``num_experts + n`` wide, its last n columns zero-compute (identity)
+    experts: chosen and weighed like any, they add ``(sum of their
+    weights) * x`` and have no operand, a held range is over the
+    `num_experts` real ones, and the load gains one entry, the pairs that
+    chose one (``parallel.moe._dropless``)."""
     from ..parallel import moe as _moe
     from ..parallel.mesh import P
 
@@ -186,13 +194,18 @@ def moe(data, gate_weight, *operands, num_experts, hidden_size, k=2,
     options = dict(score=str(_lit(score_func)), select_bias=bias,
                    scale=float(_lit(route_scale)),
                    held=None if held == (0, E) else held)
+    zero = int(_lit(zero_experts))
+    if zero:
+        options["zero_experts"] = zero
     if capacity_factor is not None and (
             shared or bias is not None or options["held"]
+            or zero
             or routed_on is not data
             or (options["score"], options["scale"]) != ("softmax", 1.0)):
         raise ValueError("MoE: score_func, select_bias, route_scale, "
-                         "shared_size, held_count and router_input are the "
-                         "dropless form's (no capacity_factor)")
+                         "shared_size, held_count, zero_experts and "
+                         "router_input are the dropless form's (no "
+                         "capacity_factor)")
     lead = data.shape[:-1]
     d_model = data.shape[-1]
     x = data.reshape(-1, d_model)

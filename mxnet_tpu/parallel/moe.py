@@ -411,23 +411,36 @@ def _fused_experts_bwd(static, interpret, operands, cotangent):
 _fused_experts.defvjp(_fused_experts_fwd, _fused_experts_bwd)
 
 
+def held_range(held, experts, zero_experts=0):
+    """The range `_dropless` walks: `held` ``(first, count)`` or — no range
+    named, but `zero_experts` of the router's `experts` columns
+    zero-compute — all the real experts, a range of the router's columns
+    like any; None where every column is an expert held here."""
+    if held is None and zero_experts:
+        return 0, experts - zero_experts
+    return held
+
+
 def dropless_experts(x, logits, k, weights, biases=None, act="relu",
                      gated=False, normalize=True, score="softmax",
-                     select_bias=None, scale=1.0, held=None):
+                     select_bias=None, scale=1.0, held=None, zero_experts=0):
     """`_dropless` (below) of the fewest equal pieces of the tokens whose
     gathered rows — every pair's, or one pass of the held pairs'
     (`_pass_rows`) — are within `_PAIR_BYTES` each — all tokens at once
     where theirs are — one piece after the other (a token's experts do not
     depend on its neighbours): the same numbers, the loads summed."""
     t_len, width = x.shape
+    held = held_range(held, logits.shape[-1], zero_experts)
     pieces, _ = pass_plan(t_len, k, width * x.dtype.itemsize, held,
                           logits.shape[-1])
     if pieces == 1:
         return _dropless(x, logits, k, weights, biases, act, gated,
-                         normalize, score, select_bias, scale, held)
+                         normalize, score, select_bias, scale, held,
+                         zero_experts)
     out, load = lax.map(
         lambda piece: _dropless(*piece, k, weights, biases, act, gated,
-                                normalize, score, select_bias, scale, held),
+                                normalize, score, select_bias, scale, held,
+                                zero_experts),
         (x.reshape(pieces, -1, width),
          logits.reshape(pieces, -1, logits.shape[-1])))
     return out.reshape(t_len, -1), load.sum(0)
@@ -435,7 +448,7 @@ def dropless_experts(x, logits, k, weights, biases=None, act="relu",
 
 def _dropless(x, logits, k, weights, biases=None, act="relu",
               gated=False, normalize=True, score="softmax",
-              select_bias=None, scale=1.0, held=None):
+              select_bias=None, scale=1.0, held=None, zero_experts=0):
     """Every token through its k best experts, none dropped.
 
     x [T, D]; logits [T, E] router scores (softmax here, float32);
@@ -460,7 +473,14 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
     first + count`` of the E the router scores, one chip's share of an
     expert-parallel layer: the choice and the weights are over all E, the
     pairs whose expert lives elsewhere add nothing here, and `load` is
-    ``[count]``, over the experts held."""
+    ``[count]``, over the experts held.
+
+    `zero_experts` n: the LAST n of the router's E columns are zero-compute
+    experts (LongCat-Flash's identity experts) — chosen and weighed with
+    the others, they have no matrix and no pair row: a token's ``(sum of
+    their weights) * x`` is added where the token lives (scope
+    ``mx:moe.zero``), `held` ranges over the ``E - n`` real experts, and
+    `load` gains one entry, ``[count + 1]``: the pairs that chose one."""
     t_len, n_exp = logits.shape
     if score == "sigmoid":
         probs = jax.nn.sigmoid(logits.astype(jnp.float32))
@@ -477,6 +497,12 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
     if scale != 1.0:
         top_w = top_w * scale
     flat_e = top_e.reshape(-1)
+    if zero_experts:
+        with jax.named_scope("mx:moe.zero"):
+            is_zero = top_e >= n_exp - zero_experts
+            passed = x * jnp.where(is_zero, top_w, 0).sum(
+                -1, keepdims=True).astype(x.dtype)
+            zero_pairs = is_zero.sum(dtype=jnp.float32)[None]
     if held is not None:
         # pairs of experts held elsewhere sort behind every segment, at
         # index `count`: no expert multiplies them, and their rows of the
@@ -489,6 +515,18 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
     sorted_e = flat_e[order]
     load = jnp.zeros((n_exp,), jnp.int32).at[flat_e].add(1, mode="drop")
     rows = _pass_rows(len(order), held, logits.shape[-1])
+    out, load = _dropless_real(x, logits.shape[-1], order, sorted_e, load,
+                               top_w, rows, weights, biases, act, gated, held)
+    if zero_experts:
+        out, load = out + passed, jnp.concatenate([load, zero_pairs])
+    return out, load
+
+
+def _dropless_real(x, scored, order, sorted_e, load, top_w, rows, weights,
+                   biases, act, gated, held):
+    """`_dropless`'s matrices: the sorted pairs, of a router of `scored`
+    columns, through the experts that have any.  Returns (out [T, D], load
+    float32)."""
     if rows:
         out = _held_passes(x, order, sorted_e, load, top_w, rows, weights,
                            biases, act, gated)
@@ -504,7 +542,7 @@ def _dropless(x, logits, k, weights, biases=None, act="relu",
     # with them (`_spare_rows` says why there are any: to steer XLA's tile,
     # so none where the kernel walks its own)
     spare = 0 if kernel_tiles(len(order), *weights[0].shape) else \
-        _spare_rows(len(order), logits.shape[-1])
+        _spare_rows(len(order), scored)
     out = _every_pair(segment_matmul, x, order, sorted_e, load, top_w,
                       weights, biases, act, gated, spare, held is not None)
     return out, load.astype(jnp.float32)

@@ -628,6 +628,8 @@ class GenerativeSession:
         # a routed model's programs end with tokens per (layer, expert)
         self._reports_moe_load = "moe_load" in tuple(
             getattr(model, "extra_outputs", tuple)())
+        # whose last column is then the zero-compute experts' pairs
+        self._zero_experts = bool(getattr(model, "zero_experts", 0))
         # counters the model's layer kinds declare for a program call
         self._call_counters = getattr(model, "call_counters", None)
         # every call threads the cache entries, then each slot's last
@@ -913,7 +915,8 @@ class GenerativeSession:
         if self._reports_moe_load:
             with self._prog_lock:
                 key = next(k for k, e in self._programs.items() if e is exe)
-            self._book_moe_load(extra[0], self._buckets[key].experts)
+            self._book_moe_load(extra[0], self._buckets[key].experts,
+                                self._zero_experts)
         return logits
 
     def _dispatch(self, exe, fn, data, slot, length, rows, prog, pack,
@@ -980,7 +983,8 @@ class GenerativeSession:
                            hist=hists[1]) as read:
             token, *extra = (_np.asarray(o) for o in flight.outs)
         if self._reports_moe_load:
-            self._book_moe_load(extra[0], flight.prog.experts)
+            self._book_moe_load(extra[0], flight.prog.experts,
+                                self._zero_experts)
         if self._drafts:
             return self._land_drafted(flight, token, extra, wait, read,
                                       hists[2], book)
@@ -1259,9 +1263,13 @@ class GenerativeSession:
                 telemetry.inc(name, n)
 
     @staticmethod
-    def _book_moe_load(load, plan):
+    def _book_moe_load(load, plan, zero=False):
         """The `moe.*` counters of one program call from its `moe_load
-        (layers, experts)` output: token-expert pairs computed (padded
+        (layers, experts)` output — behind them, where the model has
+        zero-compute experts (`zero`), ONE column of the pairs that chose
+        one, `moe.zero_pairs` (padded rows included, as `moe.pairs` has
+        them), which no other counter takes for an expert —: token-expert
+        pairs computed (padded
         rows included — the device computed them), experts that got at
         least one token, expert slots offered, and the fullest expert's
         tokens, each summed over the layers.  And from the program's
@@ -1277,6 +1285,9 @@ class GenerativeSession:
         kernel's calls fetch and place their own rows
         (`parallel.moe.fused_tile`)."""
         if telemetry.enabled():
+            if zero:
+                telemetry.inc("moe.zero_pairs", int(load[..., -1].sum()))
+                load = load[..., :-1]
             telemetry.inc("moe.pairs", int(load.sum()))
             telemetry.inc("moe.experts_hit", int((load > 0).sum()))
             telemetry.inc("moe.expert_slots", int(load.size))

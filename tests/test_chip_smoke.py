@@ -91,6 +91,18 @@ TINY = {
                                 layer_types=["mamba", "attention"],
                                 mamba_heads=4, mamba_head_dim=8,
                                 mamba_state=16, norm="rms",
+                                positions="none", bias=False),
+                           dict(num_heads=2, max_len=48,
+                                layer_types=["latent_attention"] * 2,
+                                latent_q_rank=16, latent_kv_rank=24,
+                                latent_nope_dim=8, latent_rope_dim=8,
+                                latent_value_dim=8,
+                                latent_lora_rescale=True, ffn="swiglu",
+                                ffn_types=["shortcut", "dense"],
+                                num_experts=8, zero_experts=4,
+                                experts_per_token=3, expert_d_ff=16,
+                                router_bias=True, route_scale=6.0,
+                                held_experts=(0, 2), norm="rms",
                                 positions="none", bias=False)]},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
@@ -158,13 +170,16 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     # layers' ONE ring each of 24 + 8 lines, then a selected layer's ring
     # of 24 + 8 lines with its index keys beside a window layer's latent
     # ring of 16 positions, then a Mamba-2 layer's window and state beside
-    # one layer's rings; the CPU's programs hold no kernel call
-    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2 + 3 + 4
+    # one layer's rings, then (PR 62) the two latent rings of a published
+    # layer whose routed branch is carried; the CPU's programs hold no
+    # kernel call
+    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2 + 3 + 4 + 2
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
                                           [3, 2, 16, 48], [3, 2, 16, 16],
                                           [3, 2, 16, 48], [3, 2, 32, 48],
                                           [3, 1, 32, 48], [3, 1, 32, 48],
-                                          [3, 1, 40, 16], [3, 2, 16, 48]]
+                                          [3, 1, 40, 16], [3, 2, 16, 48],
+                                          [3, 1, 32, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
     # nor does a shape rule send a bucket through a blockwise kernel
     assert report["kv_ring"]["kernel_buckets"] == 0
@@ -206,7 +221,7 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert step["ms"] > 0
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
-        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
+        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
     chip_smoke.run_phase("four_chips", chip_smoke.phase_four_chips,

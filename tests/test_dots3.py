@@ -542,7 +542,13 @@ def test_every_cell_sorts_its_pairs_at_once():
         if rows * lm.experts_per_token * lm.d_model * 4 > moe._PAIR_BYTES:
             cut.append(row["name"])
         plans[row["name"]] = lm.expert_plan(rows)[1:3]
-    assert cut == ["dots3note_longdoc_c8", "smallthinker_longctx_c16"]
+    assert cut == ["dots3note_longdoc_c8", "smallthinker_longctx_c16",
+                   "longcatflash_turns_c16"]
+    # twelve experts a token of 6,144: the 24,672 pairs of its 2,048 bucket
+    # beside eight rows would be 606 MB of rows, but 8 of the router's 768
+    # columns are held (PR 62; the zero-compute experts' pairs gather no
+    # row): ONE pass of one 512-row tile
+    assert plans.pop("longcatflash_turns_c16") == (1, 512)
     # no held range there (PR 59: 64 of 64 experts): the 61,488 pairs of
     # its 10,240 bucket beside eight rows go in three pieces of 3,416
     # tokens, 210 MB of rows each

@@ -1004,3 +1004,53 @@ def test_the_tool_sums_a_programs_device_ops_by_scope():
     assert sorted((name, round(ms, 6), n) for name, ms, n in table["ops"]) \
         == [("-", 0.002, 2.0), ("mx:inner.most", 0.001, 1.0),
             ("mx:mla.expand", 0.001, 1.0)]
+
+
+def test_the_carried_branch_and_the_identity_term_have_scopes_of_their_own():
+    """PR 62: a ``"shortcut"`` FFN's routed branch runs under
+    ``mx:moe.shortcut`` and the zero-compute experts' term under
+    ``mx:moe.zero`` inside it — op metadata of the compiled program, so a
+    device trace can say what the fork costs — and a program call's
+    `moe_load` books `moe.zero_pairs` beside `moe.pairs`."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import _run_graph
+    from mxnet_tpu.models import TransformerLM
+    from mxnet_tpu.symbol import _topo_order
+
+    lm = TransformerLM(
+        vocab=32, num_layers=2, num_heads=2, d_model=16, d_ff=24, max_len=16,
+        norm="rms", positions="rotary", bias=False, ffn="swiglu",
+        ffn_types=["shortcut", "dense"], num_experts=4, zero_experts=4,
+        experts_per_token=2, expert_d_ff=8, route_scale=6.0)
+    graph = lm.score_symbol()
+    names = graph.list_arguments()
+    shapes, _, _ = graph.infer_shape(data=(1, 8))
+    order = _topo_order(graph._entries)
+
+    def program(*args):
+        return _run_graph(graph._entries, order, names, [], args, (), False,
+                          jax.random.key(0))[0]
+
+    text = jax.jit(program).lower(
+        *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes)
+    ).compile().as_text()
+    assert "mx:moe.shortcut/mx:moe.route" in text
+    assert "mx:moe.shortcut/mx:moe.experts/mx:moe.zero" in text
+    # the dense FFN of the same layer is outside the branch's scope
+    assert re.search(r'op_name="[^"]*l0_ffn1[^"]*"', text)
+    assert not re.search(r'op_name="[^"]*mx:moe.shortcut[^"]*l0_ffn1', text)
+    load = np.asarray([[3.0, 0.0, 2.0, 1.0, 10.0]])
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    try:
+        before = {n: telemetry.counter_value(n)
+                  for n in ("moe.zero_pairs", "moe.pairs", "moe.expert_slots")}
+        GenerativeSession._book_moe_load(load, (16, 1, 0, False, False), True)
+        moved = {n: telemetry.counter_value(n) - v for n, v in before.items()}
+    finally:
+        telemetry.set_enabled(was)
+    assert moved == {"moe.zero_pairs": 10, "moe.pairs": 6,
+                     "moe.expert_slots": 4}

@@ -993,3 +993,69 @@ def test_the_smallthinker_programs_compile_for_a_v5e(program, one_chip):
         assert stats.temp_size_in_bytes < 0.3e9
     else:
         assert not chip_smoke.score_arrays(text, bucket)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_longcat_flash_programs_compile_for_a_v5e(program, one_chip):
+    """LongCat-Flash-Omni's two serving programs at the cell's sizes — the
+    published widths, four published layers (eight here: two latent
+    sublayers, two dense FFNs of 12,288 and the carried routed layer a
+    published one), eight held heads and eight held experts of a 768-wide
+    router, eight slots of 2,304 positions, the 8-row step and the 2,048
+    bucket — lowered for the TPU: all eight latent rings (two a published
+    layer) are aliased to their outputs and none is copied; the step reads
+    each ring through ONE latent-ring kernel call; the 2,048 bucket — the
+    one `ops.attention.prefill_block` sends through the blockwise kernel,
+    a head 192 wide with the value carried at that width — holds ONE
+    kernel call a sublayer and no ``(heads, 2048, 2048)`` score; its
+    experts walk ONE pass of 512 sorted rows of the 24,576 pairs (the
+    zero-compute experts' pairs gather no row); the weights are the 13.69
+    GB the configuration's `reduced_why` reckons, and weights, two bound
+    cache sets and the larger program's temporaries fit a v5e."""
+    import json
+    import re
+    import warnings
+
+    from benchmarks.families import longcat_flash as family
+    from mxnet_tpu.ops import attention
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "longcat-flash-omni.json")) as f:
+        config = json.load(f)
+    lm = family.model(config)
+    rows, bucket, max_len = 8, 2048, 2304
+    spec = lm.cache_spec(rows + 1, max_len)
+    assert list(spec) == ["latent_cache_%d" % i for i in range(8)]
+    assert {e.shape for e in spec.values()} == {(9, 1, 576, 2304)}
+    assert attention.prefill_block((1, bucket, 8 * 192), 8, 8, "tpu")
+    assert lm.expert_plan(bucket)[1:4] == (1, 512, True)
+    assert lm.expert_plan(rows)[1:4] == (1, 0, False)
+    wire = _wire(spec, rows)
+    if program == "prefill":
+        wire = dict(wire, data=(1, bucket), slot=(1,), length=(1,))
+    graph = (lm.decode_symbol() if program == "decode"
+             else lm.prefill_symbol())
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        compiled = _serving_program(graph, wire, one_chip)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    facts = chip_smoke.ring_hlo_facts(text, (9, 1, 576, 2304))
+    assert facts["ring_params"] == facts["aliased"] == 8
+    assert facts["copies"] == []
+    ring = chip_smoke.named_kernel_calls(text, "latent_ring_attention")
+    sdp = chip_smoke.named_kernel_calls(text, "causal_attention")
+    grouped = chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel")
+    assert (ring, sdp) == ((8, 0) if program == "decode" else (0, 8))
+    assert grouped == (0 if program == "decode" else 3 * 4)
+    assert "mx:moe.shortcut" in text and "mx:moe.zero" in text
+    sets = sum(e.nbytes for e in spec.values())
+    assert stats.alias_size_in_bytes >= sets
+    weights = stats.argument_size_in_bytes - sets
+    assert 13.6e9 < weights < 13.8e9
+    assert weights + 2 * sets + stats.temp_size_in_bytes < 16.2e9, (
+        weights, sets, stats.temp_size_in_bytes)
+    if program == "decode":
+        assert stats.temp_size_in_bytes < 0.3e9
+    else:
+        assert not re.search(r"f32\[(1,)?8,(1,)?2048,2048\]", text)
