@@ -77,7 +77,11 @@ apart from the rest:
             is held, the whole layer-piece beside it: the two calls that
             fetch and place their own rows against the three between a
             gather and an un-sort (PR 61), neither the slower one where
-            parallel.moe.fused_tile takes them
+            parallel.moe.fused_tile takes them.  Where a held range walks
+            passes, a pass's return to token order beside it: XLA's
+            scatter-add against the kernel's row copies
+            (ops/row_return_kernel.py, PR 63), the same sums, ms a call
+            and us a row
   four_chips  (>= 4 devices) a 4-way data-parallel ResNet-50 fit and a
             Predictor bound to chip 3, in this same process
 
@@ -241,6 +245,7 @@ FULL = {
         "olmoe 520": (520, 8, 64, 64, 2048, 1024),
         "qwen3-next 2064": (2064, 10, 512, 128, 2048, 512),
         "glm-5 1032": (1032, 8, 256, 8, 6144, 2048),
+        "longcat-flash 2056": (2056, 12, 768, 8, 6144, 2048),
         "granite-h-small 256": (256, 10, 72, 9, 4096, 768),
         "granite-h-small 512": (512, 10, 72, 9, 4096, 768),
         "granite-h-small 1024": (1024, 10, 72, 9, 4096, 768),
@@ -1170,13 +1175,24 @@ def phase_grouped_matmul(sizes, ctx):
     and a weighted sum over ``[T, k, D]`` (`moe._every_pair`:
     `three_calls_ms`) and the two calls that fetch and place their own
     rows (`moe._two_calls`: `two_calls_ms`), and the two results compared
-    (`fused_err`)."""
+    (`fused_err`).
+
+    Where a held range walks passes (`pass_plan`'s rows: PR 63) the pass's
+    RETURN to token order is timed two ways beside it: `reps` passes' rows
+    — an expert's rows of distinct tokens in token order, the rows past
+    the held pairs zeros and token `T`'s — added into ``[T, D]`` by XLA's
+    scatter-add (`moe._scatter_add`: `xla_return_ms`, `xla_us_a_row`) and
+    by the kernel (`ops/row_return_kernel.py`, the tiles
+    `moe.return_tiles` gives: `kernel_return_ms`, `kernel_us_a_row`), and
+    the two sums compared (`return_err`: a token's rows are added in
+    another order)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax import lax
 
     from mxnet_tpu.ops.grouped_matmul_kernel import grouped_matmul
+    from mxnet_tpu.ops.row_return_kernel import row_return
     from mxnet_tpu.parallel import moe
 
     device = ctx.jax_device()
@@ -1259,6 +1275,43 @@ def phase_grouped_matmul(sizes, ctx):
             "flops_ms_at_197": round(
                 1e3 * 6 * int(load.sum()) * d_model * d_expert / 197e12, 3),
             "err": err})
+        tiles = passed and moe.return_tiles(tokens // pieces, rows, d_model,
+                                            jnp.float32)
+        if tiles:
+            t_len, live = tokens // pieces, int(load.sum())
+            # an expert's rows are of distinct tokens, in token order
+            token = np.full(rows, t_len, np.int32)
+            token[:live] = np.concatenate([
+                np.sort(rng.choice(t_len, n, replace=False)) for n in load])
+            ys = rng.standard_normal((rows, d_model)).astype(np.float32)
+            ys[live:] = 0
+            operands = [put(np.zeros((t_len, d_model), np.float32)),
+                        put(ys), put(token), put(np.int32(live))]
+
+            def passes(place, out, ys, token, count):
+                return lax.fori_loop(
+                    0, reps, lambda _, out: place(out, ys, token, count), out)
+
+            def placed(out, ys, token, count):
+                return row_return(out, ys, token, count, tb=tiles[0],
+                                  tm=tiles[1], interpret=not on_tpu)[0]
+
+            ref, xla_ms = timed(jax.jit(functools.partial(
+                passes, lambda out, ys, token, _: moe._scatter_add(
+                    out, ys, token))), *operands)
+            got, kernel_ms = timed(jax.jit(functools.partial(
+                passes, placed)), *operands)
+            err = _rel_err(got, np.asarray(ref, np.float64))
+            _check(err < 1e-6, "%s: the kernel's return is %.2e off the "
+                   "scatter-add's" % (name, err))
+            table[-1].update(
+                return_live=live, return_err=err,
+                xla_return_ms=round(xla_ms, 3),
+                kernel_return_ms=round(kernel_ms, 3),
+                xla_us_a_row=round(1e3 * xla_ms / live, 3),
+                kernel_us_a_row=round(1e3 * kernel_ms / live, 3),
+                return_ms_at_819=round(
+                    1e3 * 3 * live * 4 * d_model / 819e9, 3))
         tile = held_range is None and moe.fused_tile(
             pairs, held, d_model, d_expert, True)
         if tile:
@@ -1307,6 +1360,12 @@ def phase_grouped_matmul(sizes, ctx):
     _check(not slower, "the two calls that fetch and place their own rows "
            "are slower than the three where fused_tile takes them: %s"
            % slower)
+    # ONE form for every pass (`moe.return_tiles` has no threshold of rows
+    # or width): it stands on the kernel being the faster one at each
+    slower = [row["shape"] for row in table if on_tpu and row.get(
+        "kernel_return_ms", 0) > row.get("xla_return_ms", 0)]
+    _check(not slower, "the kernel's return to token order is slower than "
+           "XLA's scatter-add where return_tiles takes it: %s" % slower)
     return {"table": table, "rows_an_expert_from": moe._KERNEL_ROWS}
 
 

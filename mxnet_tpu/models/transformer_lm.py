@@ -1921,8 +1921,9 @@ class TransformerLM:
         return total
 
     def expert_plan(self, tokens, platform="tpu"):
-        """``(pairs, pieces, rows, kernel, fused)`` of each routed layer in
-        a serving program that computes `tokens` tokens (the pad included):
+        """``(pairs, pieces, rows, kernel, fused, placed)`` of each routed
+        layer in a serving program that computes `tokens` tokens (the pad
+        included):
         the (token, expert) pairs its router makes and how the layer goes
         through them — `parallel.moe.pass_plan`'s pieces and the sorted
         rows a pass gathers, 0 where it gathers every pair's row (no held
@@ -1931,10 +1932,13 @@ class TransformerLM:
         kernel (`parallel.moe.kernel_tiles` of a call's rows: the three
         matmuls of a layer choose alike), and whether the layer's calls
         also fetch and place their own rows (`parallel.moe.fused_tile`:
-        every expert held — these gated FFNs have no bias).  The session
-        keeps it a bucket program and books `moe.pair_rows` /
-        `moe.passes` / `moe.kernel_rows` / `moe.fused_rows` from it and
-        the call's ``moe_load``."""
+        every expert held — these gated FFNs have no bias), or whether
+        its passes return their rows to token order through the TPU's
+        kernel (`parallel.moe.return_tiles`: a pass of a held range whose
+        width the kernel's tiling holds).  The session keeps it a bucket
+        program and books `moe.pair_rows` / `moe.passes` /
+        `moe.kernel_rows` / `moe.fused_rows` / `moe.placed_rows` from it
+        and the call's ``moe_load``."""
         from ..parallel import moe
 
         k = self.experts_per_token
@@ -1948,7 +1952,9 @@ class TransformerLM:
         kernel = platform == "tpu" and moe.kernel_tiles(*call) is not None
         fused = (kernel and held is None
                  and moe.fused_tile(*call, True) is not None)
-        return tokens * k, pieces, rows, kernel, fused
+        placed = bool(platform == "tpu" and rows) and moe.return_tiles(
+            tokens // pieces, rows, self.d_model, "float32") is not None
+        return tokens * k, pieces, rows, kernel, fused, placed
 
     def step_weight_bytes(self, load=None):
         """``{"mtp.bytes", "mtp.step_bytes"}``: the float32 bytes of the

@@ -291,7 +291,7 @@ def _held_passes(x, order, sorted_e, load, top_w, rows, weights, biases,
             # on the vector unit: a matmul would round the scores to bfloat16
             ys = (jnp.where(live[:, None], ys, 0)
                   * flat_w[pair].astype(ys.dtype)[:, None])
-            return out.at[token].add(ys, mode="drop")
+            return _return_rows(out, ys, token, held_pairs - first)
 
         return lax.cond(first < held_pairs, run, lambda out: out, out), None
 
@@ -651,3 +651,80 @@ def moe_sharded(mesh, expert_fn, stacked_params, x, gate_w, k=1,
         in_specs=(param_spec, tok_spec, P()),
         out_specs=tok_spec,
     )(stacked_params, x, gate_w)
+
+
+# (What PR 63 adds stands at the END of the file, and `_held_passes` calls it
+# from the one line its scatter-add stood on: every line above keeps its
+# number, and with it the programs of the layers that hold no range.)
+#
+# A pass's return to token order is OURS on a TPU (`ops/row_return_kernel.py`)
+# wherever the kernel's tiling holds: XLA's scatter-add there is one
+# read-modify-write a row whose price goes by the row's WIDTH alone — us a
+# row, jaxlib 0.9.0 / libtpu 0.0.34: 4,096 floats 0.3-0.5, 5,120 floats
+# 3.7-4.8, 6,144 0.7-1.2, 7,168 1.7-2.0, 8,192 1.0-1.2 (PERF.md section 7,
+# builders, PR 57) — where the kernel's is the rows' bytes and one walk of
+# the tiles they name.  ONE form for every pass: a pass exists from 1,024
+# pairs (`_COMPACT_PAIRS`), and no rule by width, bytes or model chooses.
+def return_tiles(t_len, rows, d, dtype):
+    """``(tb, tm)``, the tokens a tile and the rows a chunk of
+    ``ops.row_return_kernel.row_return`` for a pass's return — `rows`
+    weighted rows ``[rows, d]`` of `dtype` added into ``[t_len, d]`` — or
+    None where the return is XLA's scatter-add: rows that are no float32,
+    a width that is no whole 128-lane tiles, more rows than the TPU's
+    scalar memory holds the tokens and the places of (`_FUSED_ROWS`), or
+    tiles that three times `_KERNEL_BLOCK` of VMEM do not hold (a width
+    past 13,000).  Static shapes in, nothing else:
+    `TransformerLM.expert_plan` asks here too."""
+    tile = _KERNEL_TILE
+    if (jnp.dtype(dtype) != jnp.float32 or d % _LANES or rows > _FUSED_ROWS
+            or 7 * tile * d * 4 > 3 * _KERNEL_BLOCK):
+        return None
+    return tile, tile
+
+
+def _scatter_add(out, ys, token):
+    return out.at[token].add(ys, mode="drop")
+
+
+def _return_rows(out, ys, token, count):
+    """`out [T, D]` with a pass's weighted rows `ys [rows, D]` added to
+    their tokens' rows: `token [rows]`, `T` for the rows from `count` on
+    (nobody's, and zeros).  One sum with two implementations, as
+    `segment_matmul` has: the TPU's kernel, which adds a token's rows in
+    the order they lie — ascending expert — after what `out` held, or
+    XLA's scatter-add, which is also every platform's backward."""
+    tiles = return_tiles(len(out), *ys.shape, ys.dtype)
+    if tiles is None or out.dtype != ys.dtype:
+        return _scatter_add(out, ys, token)
+    return _placed_rows(tiles, _INTERPRET, out, ys, token, count)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _placed_rows(tiles, interpret, out, ys, token, count):
+    def kernel(out, ys, token, count):
+        # lowered once a shape for all programs and processes
+        # (ops/exported.py); `out` is aliased to the result
+        out, = exported.call("row_return_kernel", "row_return",
+                             (out, ys, token, count), interpret=interpret,
+                             tb=tiles[0], tm=tiles[1])
+        return out
+    return lax.platform_dependent(
+        out, ys, token, count, tpu=kernel,
+        default=lambda out, ys, token, count: _scatter_add(out, ys, token))
+
+
+def _placed_rows_fwd(tiles, interpret, out, ys, token, count):
+    return _placed_rows(tiles, interpret, out, ys, token, count), (token,
+                                                                   count)
+
+
+def _placed_rows_bwd(tiles, interpret, kept, cotangent):
+    # the kernel has no backward: a gradient takes the scatter-add's —
+    # `out`'s as it comes, a row's from its token's row (a dead row's: 0)
+    token, count = kept
+    none = lambda a: np.zeros(a.shape, jax.dtypes.float0)
+    return (cotangent, cotangent.at[token].get(mode="fill", fill_value=0),
+            none(token), none(count))
+
+
+_placed_rows.defvjp(_placed_rows_fwd, _placed_rows_bwd)

@@ -1274,16 +1274,16 @@ class GenerativeSession:
         least one token, expert slots offered, and the fullest expert's
         tokens, each summed over the layers.  And from the program's
         `plan` (``expert_plan``: pairs a layer, pieces, a pass's rows,
-        whether the kernel multiplies them) the rows the expert layers
+        which kernels take them) the rows the expert layers
         gathered, `moe.pair_rows`: every pair's row where a layer gathers
         them all; where it walks the held pairs alone
         (`moe.compact_calls`, a layer) a pass's rows times the passes the
         load filled (`moe.passes`) — a call in pieces as if its held pairs
         lay evenly over them.  The same rows are `moe.kernel_rows` where
         the program's segment matmuls are the TPU's kernel
-        (`parallel.moe.kernel_tiles`), and `moe.fused_rows` where the
-        kernel's calls fetch and place their own rows
-        (`parallel.moe.fused_tile`)."""
+        (`parallel.moe.kernel_tiles`), `moe.fused_rows` where the
+        kernel's calls fetch and place their own rows (`.fused_tile`),
+        `moe.placed_rows` where a pass's return is ours (`.return_tiles`)."""
         if telemetry.enabled():
             if zero:
                 telemetry.inc("moe.zero_pairs", int(load[..., -1].sum()))
@@ -1292,7 +1292,7 @@ class GenerativeSession:
             telemetry.inc("moe.experts_hit", int((load > 0).sum()))
             telemetry.inc("moe.expert_slots", int(load.size))
             telemetry.inc("moe.max_load", int(load.max(axis=-1).sum()))
-            pairs, pieces, rows, kernel, fused = plan
+            pairs, pieces, rows = plan[:3]
             gathered = pairs * len(load)
             if rows:
                 passes = pieces * int(_np.ceil(
@@ -1301,10 +1301,10 @@ class GenerativeSession:
                 telemetry.inc("moe.passes", passes)
                 gathered = passes * rows
             telemetry.inc("moe.pair_rows", gathered)
-            if kernel:
-                telemetry.inc("moe.kernel_rows", gathered)
-            if fused:
-                telemetry.inc("moe.fused_rows", gathered)
+            # (`placed` only where the layer walks passes: their rows)
+            for name, taken in zip(("kernel", "fused", "placed"), plan[3:]):
+                if taken:
+                    telemetry.inc("moe.%s_rows" % name, gathered)
 
     # ------------------------------------------------------------------
     # admission: prefill newly-arrived prompts into free slots

@@ -110,7 +110,8 @@ TINY = {
     # held: widths of one and two lane tiles
     "grouped_matmul": {"reps": 2, "seed": 6, "shapes": {
         "a held range": (64, 2, 8, 4, 128, 256),
-        "every expert": (40, 2, 4, 4, 256, 128)}},
+        "every expert": (40, 2, 4, 4, 256, 128),
+        "a held range in passes": (512, 2, 8, 2, 128, 128)}},
 }
 
 
@@ -153,7 +154,8 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert [(row["shape"], row["rows"], row["experts"], row["held_rows"] <=
              row["rows"], row["taken"]) for row in table] == [
         ("a held range", 128, 4, True, True),
-        ("every expert", 80, 4, True, True)]
+        ("every expert", 80, 4, True, True),
+        ("a held range in passes", 512, 2, True, True)]
     assert all(row["xla_ms"] > 0 and row["kernel_ms"] > 0 and
                row["err"] < 2e-2 for row in table)
     # with every expert held the whole layer-piece is timed as the two
@@ -162,6 +164,14 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert "two_calls_ms" not in table[0]
     assert table[1]["two_calls_ms"] > 0 and table[1]["three_calls_ms"] > 0
     assert table[1]["fused_err"] < 1e-5
+    # from 1,024 pairs a held range walks passes of its held pairs (here
+    # ONE of 512 rows, filled to about half), and a pass's return to token
+    # order is timed as XLA's scatter-add and as the kernel's row copies
+    # (PR 63), the two sums compared; no other shape here has a pass
+    assert ["kernel_return_ms" in row for row in table] == [False, False,
+                                                            True]
+    assert 0 < table[2]["return_live"] <= 512
+    assert table[2]["xla_return_ms"] > 0 and table[2]["return_err"] < 1e-6
     # two layers' K and V rings of both attention-only shapes, then a
     # delta-rule layer's window and state beside one layer's rings, found
     # in the compiled decode programs, then a window layer's rings of 16

@@ -8,9 +8,13 @@ runs (`parallel.moe.kernel_tiles`).  The two calls that fetch and place
 their own rows and the sum of what they place (PR 61: `gate_up`, `down`,
 `slab_sum`, chosen by `parallel.moe.fused_tile`) against the gather,
 `lax.ragged_dot` and the un-sort they replace, alone and inside the
-layer.  What Mosaic makes of
+layer.  A held range's return to token order (PR 63:
+mxnet_tpu/ops/row_return_kernel.py, chosen by
+`parallel.moe.return_tiles`) against the scatter-add it replaces, pass
+by pass at the seven held cells' routings cut down, and inside the layer.
+What Mosaic makes of
 the kernels at the benchmark's widths is in tests/test_tpu_compile.py.
-The file costs about 70 s."""
+The file costs about 110 s."""
 import contextlib
 from unittest import mock
 
@@ -23,6 +27,7 @@ from jax import lax
 from mxnet_tpu.ops.grouped_matmul_kernel import (down, gate_up,
                                                   grouped_matmul, items,
                                                   slab_sum)
+from mxnet_tpu.ops.row_return_kernel import row_return
 from mxnet_tpu.parallel import moe
 
 # name -> (rows M, K, N, row tile, columns a strip, each expert's rows)
@@ -273,8 +278,14 @@ def test_the_layer_through_the_kernel_is_the_parents(name, biased):
             < 1e-6 * np.abs(np.asarray(three)).max()
     else:
         # a layer's three matmuls, forward and forward again under the
-        # gradient (a loop's body is traced more than once)
-        assert len(calls) >= 6 and len(calls) % 3 == 0
+        # gradient (a loop's body is traced more than once) — and, where
+        # a range is walked in passes, each pass's return to token order
+        # (PR 63: one more branch a pass's three)
+        returns = [call for call in calls
+                   if call.__qualname__.startswith("_placed_rows")]
+        matmuls = len(calls) - len(returns)
+        assert matmuls >= 6 and matmuls % 3 == 0
+        assert bool(returns) == ("passes" in name)
     np.testing.assert_array_equal(np.asarray(kernel[0][1]),
                                   np.asarray(parent[0][1]))
     assert 0 < float(parent[0][1].sum()) <= tokens * k
@@ -398,3 +409,205 @@ def test_off_the_tpu_the_layer_is_ragged_dot_forward_and_backward():
     for a, b in zip(jax.tree_util.tree_leaves(chosen),
                     jax.tree_util.tree_leaves(parent)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# A held range's return to token order (PR 63), at the seven held cells'
+# routings cut to what a CPU runs.  name -> (tokens T, experts a token k,
+# experts scored, experts held, D, a pass's rows, tokens a tile, rows a
+# chunk, held pairs): the router's draw is adjusted pair by pair until
+# exactly `held pairs` of its T x k pairs chose a held expert
+RETURNS = {
+    # dots3-note: 8 of 256 at 8 a token, 6,144 rows x 5,120 into 15,360;
+    # a quarter of the tokens have a live row, a tile of tokens a few
+    "dots3: a live row for a quarter of the tokens, D 5,120 cut to 640": (
+        240, 8, 64, 2, 640, 96, 16, 16, 61),
+    # LongCat-Flash: 8 of 768 at 12 a token, 512 rows x 6,144; the live
+    # rows end inside a chunk and inside a tile of tokens
+    "longcat-flash: live rows that end inside a chunk, D 6,144 cut to 768": (
+        96, 12, 96, 2, 768, 40, 16, 8, 27),
+    # GLM-5: 8 of 256 at 8 a token, 512 rows x 6,144; the second pass of
+    # two holds ONE live row
+    "glm-5: a second pass of one live row, D 6,144 cut to 384": (
+        64, 8, 32, 2, 384, 48, 16, 16, 49),
+    # Qwen3-Next: 128 of 512 at 10 a token, 7,680 rows x 2,048: a token
+    # holds two and three live rows of ONE pass
+    "qwen3-next: two and three live rows a token, D 2,048 cut to 256": (
+        64, 10, 64, 16, 256, 240, 16, 16, 170),
+    # Granite-H-Small: 9 of 72 at 10 a token, 512 rows x 4,096 into the
+    # 256 bucket: more rows than tokens, the pass filled to its last row
+    "granite-h-small: a pass filled to its last row, D 4,096 cut to 512": (
+        32, 10, 24, 3, 512, 64, 8, 16, 64),
+    # Trinity-Mini: 64 of 128 at 8 a token; the mixed step's 2,048 + 8
+    # tokens are no whole tile
+    "trinity-mini: T = 2,056, D 2,048 cut to 128": (
+        2056, 2, 64, 2, 128, 192, 128, 32, 150),
+    # Mistral-Small-4: 16 of 128 at 4 a token, 1,536 rows x 4,096; a
+    # skewed router fills a pass and a third of the next, which adds to
+    # tokens the first pass wrote, and rows past the held pairs
+    "mistral-small-4: two passes under a skewed router, D 4,096 cut to 512": (
+        96, 4, 32, 4, 512, 72, 16, 8, 96),
+}
+
+
+def _routing(rng, tokens, k, scored, held, held_pairs):
+    """``[T, k]`` distinct experts a token, exactly `held_pairs` of the
+    pairs on an expert below `held`."""
+    top_e = np.argsort(rng.random((tokens, scored)), axis=1)[:, :k]
+    while (top_e < held).sum() != held_pairs:
+        more = (top_e < held).sum() < held_pairs
+        t = rng.integers(tokens)
+        mine = top_e[t] < held
+        free = np.setdiff1d(np.arange(held) if more
+                            else np.arange(held, scored), top_e[t])
+        if len(free) and (~mine if more else mine).any():
+            top_e[t, rng.choice(np.flatnonzero(~mine if more else mine))] \
+                = rng.choice(free)
+    return top_e
+
+
+@pytest.mark.parametrize("name", sorted(RETURNS))
+def test_a_passs_rows_return_to_their_tokens(name):
+    """`row_return` under Pallas's interpreter, pass after pass as
+    `_held_passes` calls it — the sorted pairs of a routing, a window of
+    `rows` of them a pass, the rows past the held pairs token `T`'s —
+    against ``out.at[token].add(ys, mode="drop")``: within a float32
+    rounding of a token's few terms (XLA's order is its own), and TO THE
+    LAST BIT against the order the kernel states — a token's rows
+    ascending by sorted row, which is ascending expert, after what `out`
+    held.  `out` starts as noise, not zeros: a tile no row names must come
+    back as it went in.  The rows past the held pairs hold NaN here (zeros
+    in the layer): they are added nowhere."""
+    tokens, k, scored, held, d, rows, tb, tm, held_pairs = RETURNS[name]
+    rng = np.random.default_rng(len(name))
+    top_e = _routing(rng, tokens, k, scored, held, held_pairs)
+    flat_e = np.where(top_e < held, top_e, held).reshape(-1)
+    order = np.argsort(flat_e, kind="stable")
+    passes = -(-held_pairs // rows)
+    order = np.pad(order, (0, max(passes * rows - len(order), 0)))
+    out = rng.standard_normal((tokens, d)).astype(np.float32)
+    got, want, exact = jnp.asarray(out), jnp.asarray(out), out.copy()
+    seen = set()
+    for first in range(0, passes * rows, rows):
+        live = first + np.arange(rows) < held_pairs
+        token = np.where(live, order[first:first + rows] // k,
+                         tokens).astype(np.int32)
+        ys = rng.standard_normal((rows, d)).astype(np.float32)
+        want = want.at[token].add(np.where(live[:, None], ys, 0),
+                                  mode="drop")
+        for r in np.flatnonzero(live):
+            exact[token[r]] += ys[r]
+        ys[~live] = np.nan
+        got, = row_return(got, jnp.asarray(ys), jnp.asarray(token),
+                          jnp.int32(held_pairs - first), tb=tb, tm=tm,
+                          interpret=True)
+        counts = np.bincount(token[live], minlength=tokens)
+        seen |= set(counts)
+        # an expert's rows of a pass are of distinct tokens, in token order
+        expert = flat_e[order[first:first + rows]][live]
+        for e in set(expert):
+            mine = token[live][expert == e]
+            assert (np.diff(mine) > 0).all()
+        if "ends inside" in name:
+            assert live.sum() % tm and 0 < counts[-tb:].sum() < live.sum()
+    assert got.shape == out.shape and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(got), exact)
+    assert np.abs(np.asarray(got) - np.asarray(want)).max() < 1e-6 * max(
+        np.abs(exact).max(), 1.0)
+    # what the case says it holds
+    assert passes == (2 if "pass of" in name or "two passes" in name else 1)
+    if "one live row" in name:
+        assert held_pairs - rows == 1
+    if "two and three" in name:
+        assert {2, 3} <= seen
+    if "last row" in name:
+        assert held_pairs == rows > tokens
+    if "2,056" in name:
+        assert tokens % tb == 8
+    # tokens no pass named came back as they went in
+    named = np.zeros(tokens, bool)
+    named[order[:held_pairs] // k] = True
+    assert (~named).any()
+    if "a quarter" in name:
+        assert 0.15 < named.mean() < 0.35
+    np.testing.assert_array_equal(np.asarray(got)[~named], out[~named])
+
+
+@pytest.mark.parametrize("shape, tiles", [
+    ((15360, 6144, 5120, "float32"), (128, 128)),       # dots3
+    ((2056, 512, 6144, "float32"), (128, 128)),         # LongCat, GLM-5
+    ((256, 512, 4096, "float32"), (128, 128)),          # Granite-H-Small
+    ((2064, 8192, 2048, "float32"), (128, 128)),        # Qwen3-Next
+    ((2048, 1536, 4096, "bfloat16"), None),
+    ((2048, 1536, 4000, "float32"), None),              # no whole lane tile
+    ((64, 1 << 17, 128, "float32"), None),              # the scalar memory
+    ((2048, 1536, 16384, "float32"), None),             # the VMEM
+])
+def test_the_returns_rule_reads_a_passs_static_shape(shape, tiles):
+    assert moe.return_tiles(*shape) == tiles
+
+
+@pytest.mark.parametrize("biased", [False, True])
+def test_the_layer_through_the_return_kernel_is_the_parents(biased):
+    """`dropless_experts` over a held range in passes, a router skewed
+    toward the held experts so that a layer needs TWO passes, with each
+    pass's return in the kernel (forced: the TPU's branch of
+    `_placed_rows` alone, interpreted; the segment matmuls stay
+    `lax.ragged_dot`) against the parent's scatter-add: `load` bit for
+    bit; the output within a float32 rounding of a token's few terms (the
+    same rows, added ascending expert); the gradients in `x`, the logits,
+    the three matrices and the biases within that rounding carried
+    through — the backward IS the scatter-add's."""
+    tokens, k, scored, held = 48, 4, 16, (2, 4)
+    rng = np.random.default_rng(63)
+    d_model, d_expert = 128, 256
+    x = jnp.asarray(rng.standard_normal((tokens, d_model)), jnp.float32)
+    logits = rng.standard_normal((tokens, scored))
+    logits[:, held[0]:held[0] + held[1]] += 1.5
+    logits = jnp.asarray(logits, jnp.float32)
+    weights = tuple(
+        jnp.asarray(rng.standard_normal(shape) / np.sqrt(shape[1]),
+                    jnp.float32)
+        for shape in ((held[1], d_model, d_expert),
+                      (held[1], d_expert, d_model),
+                      (held[1], d_model, d_expert)))
+    biases = tuple(
+        jnp.asarray(rng.standard_normal(shape), jnp.float32)
+        for shape in ((held[1], d_expert), (held[1], d_model),
+                      (held[1], d_expert))) if biased else None
+
+    def layer(x, logits, weights, biases):
+        return moe.dropless_experts(x, logits, k, weights, biases,
+                                    act="silu", gated=True, held=held)
+
+    def both():
+        return layer(x, logits, weights, biases), jax.grad(
+            lambda *args: (layer(*args)[0] ** 2).sum(), (0, 1, 2, 3))(
+                x, logits, weights, biases)
+
+    calls = []
+
+    def the_returns_alone(*operands, tpu, default):
+        ours = tpu.__qualname__.startswith("_placed_rows")
+        calls.append(ours)
+        return (tpu if ours else default)(*operands)
+
+    with mock.patch.object(moe, "_ROW_TILE", 8), \
+            mock.patch.object(moe, "_COMPACT_PAIRS", 16):
+        rows = moe._pass_rows(tokens * k, held, scored)
+        parent = both()
+        with mock.patch.object(moe.lax, "platform_dependent",
+                               the_returns_alone), \
+                mock.patch.object(moe, "_INTERPRET", True), \
+                mock.patch.object(moe, "_KERNEL_ROWS", 1), \
+                mock.patch.object(moe, "_KERNEL_TILE", 16):
+            kernel = both()
+    assert any(calls) and not all(calls)
+    np.testing.assert_array_equal(np.asarray(kernel[0][1]),
+                                  np.asarray(parent[0][1]))
+    # two passes a layer: the second adds to what the first wrote
+    assert rows < float(parent[0][1].sum()) <= 2 * rows
+    for a, b in zip(jax.tree_util.tree_leaves((kernel[0][0], kernel[1])),
+                    jax.tree_util.tree_leaves((parent[0][0], parent[1]))):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all()
+        assert np.abs(a - b).max() < 1e-5 * np.abs(b).max()

@@ -539,23 +539,28 @@ def test_batcher_books_the_moe_counters(held):
 
 
 # (pairs a layer, pieces, a pass's rows, whether the TPU's kernel multiplies
-# them, whether its calls fetch and place their own rows) of a program, its
+# them, whether its calls fetch and place their own rows, whether a pass's
+# rows return to their tokens through the kernel) of a program, its
 # `moe_load` summed an expert-layer -> rows gathered, passes, layers that
 # walked held pairs
-BOOKED = {"one pass a layer": ((5120, 1, 1024, False, False), [640, 1024],
-                               2048, 2, 2),
-          "three passes": ((5120, 1, 1024, False, False), [2049, 0], 3072, 3,
-                           2),
-          "every pair's row": ((80, 1, 0, False, False), [10, 7], 160, 0, 0),
-          "two pieces": ((122880, 2, 3072, False, False), [3840, 6145], 18432,
-                         6, 2),
-          "passes through the kernel": ((10240, 1, 2048, True, False),
+BOOKED = {"one pass a layer": ((5120, 1, 1024, False, False, False),
+                               [640, 1024], 2048, 2, 2),
+          "three passes": ((5120, 1, 1024, False, False, False), [2049, 0],
+                           3072, 3, 2),
+          "every pair's row": ((80, 1, 0, False, False, False), [10, 7], 160,
+                               0, 0),
+          "two pieces": ((122880, 2, 3072, False, False, False), [3840, 6145],
+                         18432, 6, 2),
+          "passes through the kernel": ((10240, 1, 2048, True, False, False),
                                         [1280, 2049], 6144, 3, 2),
-          "every pair's row through the kernel": ((49200, 2, 0, True, False),
-                                                  [49200, 49200], 98400, 0,
-                                                  0),
+          "passes returned by the kernel": (
+              (10240, 1, 2048, True, False, True), [1280, 2049], 6144, 3, 2),
+          "passes returned by the kernel, XLA's matmuls": (
+              (1280, 1, 512, False, False, True), [500, 0], 512, 1, 2),
+          "every pair's row through the kernel": (
+              (49200, 2, 0, True, False, False), [49200, 49200], 98400, 0, 0),
           "every pair's row fetched by the kernel": (
-              (49200, 2, 0, True, True), [49200, 49200], 98400, 0, 0)}
+              (49200, 2, 0, True, True, False), [49200, 49200], 98400, 0, 0)}
 
 
 @pytest.mark.parametrize("name", sorted(BOOKED))
@@ -569,20 +574,21 @@ def test_the_rows_an_expert_layer_gathered_are_booked_from_its_load(name):
     `moe.kernel_rows` (PR 60) the same rows where the plan says the
     program's segment matmuls are the TPU's kernel, nothing where not, and
     `moe.fused_rows` (PR 61) where the kernel's calls fetch and place
-    their own rows."""
+    their own rows, `moe.placed_rows` (PR 63) where the passes' rows
+    return to their tokens through the kernel."""
     plan, held_pairs, rows, passes, compact = BOOKED[name]
     load = np.zeros((len(held_pairs), 9), np.int64)
     load[:, 0] = [n // 2 for n in held_pairs]
     load[:, 4] = [n - n // 2 for n in held_pairs]
     telemetry.set_enabled(True)
     names = ("moe.pairs", "moe.pair_rows", "moe.passes", "moe.compact_calls",
-             "moe.kernel_rows", "moe.fused_rows")
+             "moe.kernel_rows", "moe.fused_rows", "moe.placed_rows")
     before = dict(telemetry.snapshot()["counters"])
     GenerativeSession._book_moe_load(load, plan)
     after = telemetry.snapshot()["counters"]
     assert [after.get(k, 0) - before.get(k, 0) for k in names] == [
         sum(held_pairs), rows, passes, compact, rows if plan[3] else 0,
-        rows if plan[4] else 0]
+        rows if plan[4] else 0, rows if plan[5] else 0]
 
 
 def test_a_programs_expert_plan_follows_its_tokens():
@@ -594,30 +600,32 @@ def test_a_programs_expert_plan_follows_its_tokens():
                        max_len=32, ffn_types=["routed"], num_experts=72,
                        experts_per_token=10, expert_d_ff=16,
                        held_experts=(0, 9))
-    assert lm.expert_plan(512) == (5120, 1, 1024, False, False)
-    assert lm.expert_plan(1024) == (10240, 1, 2048, False, False)
-    assert lm.expert_plan(128) == (1280, 1, 512, False, False)
-    assert lm.expert_plan(8) == (80, 1, 0, False, False)
+    assert lm.expert_plan(512) == (5120, 1, 1024, False, False, False)
+    assert lm.expert_plan(1024) == (10240, 1, 2048, False, False, False)
+    assert lm.expert_plan(128) == (1280, 1, 512, False, False, False)
+    assert lm.expert_plan(8) == (80, 1, 0, False, False, False)
     assert family.model(CONFIG).expert_plan(520) == (1040, 1, 0, False,
-                                                      False)
+                                                      False, False)
     # the kernel (PR 60) is the TPU's, from `moe._KERNEL_ROWS` rows an
     # expert held and at widths of whole lane tiles: Granite-H-Small's own
     wide = TransformerLM(vocab=32, num_layers=1, num_heads=2, d_model=4096,
                          max_len=32, ffn_types=["routed"], num_experts=72,
                          experts_per_token=10, expert_d_ff=768,
                          held_experts=(0, 9))
-    assert wide.expert_plan(512) == (5120, 1, 1024, True, False)
+    # — and there (PR 63) a pass's rows return to their tokens through the
+    # TPU's kernel too: whole 128-lane tiles of float32, nothing else asked
+    assert wide.expert_plan(512) == (5120, 1, 1024, True, False, True)
     assert wide.expert_plan(512, platform="cpu") == (5120, 1, 1024, False,
-                                                     False)
-    assert wide.expert_plan(128) == (1280, 1, 512, True, False)
-    assert wide.expert_plan(8) == (80, 1, 0, False, False)
+                                                     False, False)
+    assert wide.expert_plan(128) == (1280, 1, 512, True, False, True)
+    assert wide.expert_plan(8) == (80, 1, 0, False, False, False)
 
     # with every expert held (PR 61) the kernel's calls also fetch and
     # place their own rows: SmallThinker's 8,192 mixed step in two pieces
     whole = TransformerLM(vocab=32, num_layers=1, num_heads=2, d_model=2560,
                           max_len=32, ffn_types=["routed"], num_experts=64,
                           experts_per_token=6, expert_d_ff=768)
-    assert whole.expert_plan(8200) == (49200, 2, 0, True, True)
+    assert whole.expert_plan(8200) == (49200, 2, 0, True, True, False)
     assert whole.expert_plan(8200, platform="cpu") == (49200, 2, 0, False,
-                                                       False)
-    assert whole.expert_plan(8) == (48, 1, 0, False, False)
+                                                       False, False)
+    assert whole.expert_plan(8) == (48, 1, 0, False, False, False)

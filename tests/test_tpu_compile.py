@@ -225,7 +225,10 @@ def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
     (`grouped_slab_sum_kernel`) adds slab on slab into whole tiles, so
     that the loop over the pieces carries its result in the layout the
     parent's does.  A decode step and a held range's call hold none of
-    the three."""
+    the three.  A held range's pass (PR 63, `moe.return_tiles`) returns
+    its rows to their tokens in ONE more call, `row_return_kernel`, inside
+    the loop, the result aliased to the loop's carry: no `scatter(` over
+    ``[T, D]`` is left, and no call without a pass holds the kernel."""
     import re
 
     import jax
@@ -269,6 +272,19 @@ def test_the_grouped_matmul_walks_the_tile_the_pairs_were_gathered_for(
         "grouped_down_kernel", "grouped_slab_sum_kernel")]
     assert calls == ([0, 1, 1, 1] if fused else [3, 0, 0, 0] if tiles
                      else [0, 0, 0, 0])
+    placed = bool(passed) and moe.return_tiles(
+        tokens // pieces, passed, d_model, "float32") is not None
+    assert placed == bool(passed)       # every pass of every cell's
+    assert chip_smoke.named_kernel_calls(text, "row_return_kernel") == placed
+    assert not re.search(r"f32\[%d,%d\]\S* scatter\(" % (
+        tokens // pieces, d_model), text) or not passed
+    if placed:
+        call, = [line for line in text.splitlines()
+                 if "row_return_kernel" in line and " custom-call(" in line]
+        assert "output_to_operand_aliasing={{}: (6, {})}" in call
+        # a row a DMA can slice: `ys` one sublane a row
+        assert re.search(r"f32\[%d,1,%d\]\{2,1,0:T\(1,128\)" % (
+            passed, d_model), text)
     if fused:
         assert dots == [] and "ragged" not in text
         # the rows are fetched from `x` and written into the slabs where
@@ -756,8 +772,14 @@ def test_the_dots3_programs_compile_for_a_v5e(program, one_chip):
     assert grouped == (3 * sum(
         kind == "routed" for kind in lm.ffn_types)
         if program == "prefill" else 0)
+    # and (PR 63) the ONE pass a routed layer returns its 6,144 rows to
+    # their tokens by the kernel's row copies: no scatter over the 315 MB
+    # ``[15360, 5120]``, XLA's 28 ms a layer
+    placed = chip_smoke.named_kernel_calls(text, "row_return_kernel")
+    assert placed == grouped // 3
+    assert not re.search(r"f32\[15360,5120\]\S* scatter\(", text)
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == text.count('op_name="ragged-dot') + masked + grouped
+        == text.count('op_name="ragged-dot') + masked + grouped + placed
     sets = sum(e.nbytes for e in spec.values())
     assert stats.alias_size_in_bytes >= sets
     weights = stats.argument_size_in_bytes - sets
@@ -787,8 +809,13 @@ def test_the_glm5_drafting_programs_compile_for_a_v5e(program, one_chip):
     ring and index keys among them — is aliased to its output and never
     copied, as is ``last_token (3, slots + 1)``; the weights are the
     13.17 GB the configuration's `reduced_why` reckons, and weights, five
-    bound cache sets and the larger program's temporaries fit a v5e."""
+    bound cache sets and the larger program's temporaries fit a v5e.  The
+    prefill's routed layers — the module's own among them — walk ONE pass
+    of 512 sorted rows each and return it by the kernel's row copies
+    (PR 63: the program whose split scatter-add hung the chip in PR 57
+    holds no scatter over ``f32[1024, 6144]``)."""
     import json
+    import re
     import warnings
 
     from benchmarks.families import glm5 as family
@@ -819,6 +846,11 @@ def test_the_glm5_drafting_programs_compile_for_a_v5e(program, one_chip):
     assert stats.alias_size_in_bytes >= sets
     weights = stats.argument_size_in_bytes - sets
     assert 13.1e9 < weights < 13.25e9
+    placed = chip_smoke.named_kernel_calls(text, "row_return_kernel")
+    grouped = chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel")
+    assert placed == grouped // 3 and bool(placed) == (program == "prefill")
+    assert not re.search(r"f32\[%d,6144\]\S* scatter\(" % bucket, text)
+    assert lm.expert_plan(bucket)[2:] == (512, True, False, True)
     # a v5e's 16.9e9 bytes hold the weights, five sets, the program
     assert weights + 5 * sets + stats.temp_size_in_bytes < 16.5e9, (
         weights, sets, stats.temp_size_in_bytes)
@@ -845,7 +877,9 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
     temporaries fit a v5e.  The expert layers (PR 55): the step gathers
     its 80 pairs' rows as it did; the 1,024 bucket walks ONE pass's 2,048
     sorted rows of its held pairs by the 512-row tile and keeps no array
-    of all 10,240 pairs' rows of 4,096."""
+    of all 10,240 pairs' rows of 4,096, and (PR 63) returns the pass's
+    rows to their tokens in ONE `row_return_kernel` call a routed layer:
+    no `scatter(` over ``f32[1024, 4096]``."""
     import json
     import re
     import warnings
@@ -907,6 +941,10 @@ def test_the_granite_h_small_programs_compile_for_a_v5e(program, one_chip):
         # a pass of 2,048 sorted rows over nine experts: ours (PR 60)
         assert dots == set() and grouped == 3 * routed
         assert not re.search(r"f32\[10240,4096\]", text)
+    placed = chip_smoke.named_kernel_calls(text, "row_return_kernel")
+    assert placed == routed * (program == "prefill")
+    assert not re.search(r"f32\[%d,4096\]\S* scatter\(" % bucket, text)
+    assert lm.expert_plan(bucket)[5] and not lm.expert_plan(rows)[5]
 
 
 SMALLTHINKER_BUCKETS = (7168, 8192, 9216, 10240)
@@ -976,6 +1014,11 @@ def test_the_smallthinker_programs_compile_for_a_v5e(program, one_chip):
         "grouped_matmul_kernel", "grouped_gate_up_kernel",
         "grouped_down_kernel", "grouped_slab_sum_kernel")] == [
             0, *[4 * (program == "mixed")] * 3]
+    # no range is held: no pass, and no call of the passes' return (PR 63)
+    assert chip_smoke.named_kernel_calls(text, "row_return_kernel") == 0
+    assert text.count('custom_call_target="tpu_custom_call"') == (
+        4 + 4 * 4 if program == "mixed"
+        else 4 + text.count('op_name="ragged-dot'))
     assert ("ragged" in text) == (program == "decode")
     assert not re.search(r"f32\[20496,2560\]", text)
     sets = sum(e.nbytes for e in spec.values())
@@ -1048,6 +1091,10 @@ def test_the_longcat_flash_programs_compile_for_a_v5e(program, one_chip):
     grouped = chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel")
     assert (ring, sdp) == ((8, 0) if program == "decode" else (0, 8))
     assert grouped == (0 if program == "decode" else 3 * 4)
+    # a pass of 512 rows a carried routed layer, returned by the kernel
+    assert chip_smoke.named_kernel_calls(text, "row_return_kernel") == (
+        0 if program == "decode" else 4)
+    assert lm.expert_plan(bucket)[5] and not lm.expert_plan(rows)[5]
     assert "mx:moe.shortcut" in text and "mx:moe.zero" in text
     sets = sum(e.nbytes for e in spec.values())
     assert stats.alias_size_in_bytes >= sets
