@@ -50,7 +50,11 @@ apart from the rest:
             layer; the eleventh, PR 62, is LongCat-Flash's published layer
             as two — 8 heads whose value is narrower than their key, a
             routed layer with zero-compute experts carried across the
-            second sublayer) and nothing of rows x page size beside it, and no ring
+            second sublayer; the twelfth, PR 64, Nemotron-H's three kinds
+            of layer of ONE sublayer each — a Mamba-2 mixer of eight
+            groups, ungated squared-ReLU experts whose 2,048 bucket holds
+            TWO grouped-matmul kernel calls a routed layer, NoPE attention
+            of 32 heads over 2) and nothing of rows x page size beside it, and no ring
             or recurrent state fatter on the device than cache_spec states;
             prints the rings' on-device layout and the warm ms of the
             delta-rule and Mamba-2 models' decode step; and the 2,048-bucket
@@ -232,7 +236,37 @@ FULL = {
                                 experts_per_token=12, expert_d_ff=512,
                                 router_bias=True, route_scale=6.0,
                                 held_experts=(0, 8), norm="rms",
-                                positions="none", bias=False)]},
+                                positions="none", bias=False),
+                           # Nemotron-H's layers of ONE sublayer (PR 64):
+                           # a Mamba-2 mixer of 64 heads in EIGHT groups
+                           # (the step kernel takes four whole groups a
+                           # grid step, the gated norm goes by group), 8
+                           # held of 32 UNGATED squared-ReLU experts of
+                           # the published 1,856, which the layer STORES
+                           # 1,920 wide, beside a shared 3,712 (two
+                           # kernel calls a routed layer in the 2,048
+                           # bucket, not three), NoPE attention of 32
+                           # heads over 2 (a key block of 512)
+                           # (rings of 4,608: the 0.66 MB conv window
+                           # is then under the hundredth of the set that
+                           # is judged — XLA may stage so small a buffer
+                           # through its fast memory, S(1))
+                           dict(num_layers=3, d_model=2688, num_heads=32,
+                                num_kv_heads=2, head_dim=128, max_len=4608,
+                                seq_buckets=[64, 2048],
+                                layer_types=["mamba", "none", "attention"],
+                                ffn_types=["none", "routed", "none"],
+                                kind_specs={"mamba": dict(
+                                    heads=64, head_dim=64, state=128,
+                                    groups=8, chunk=128)},
+                                num_experts=32, experts_per_token=6,
+                                expert_d_ff=1856, shared_d_ff=3712,
+                                router_score="sigmoid", router_bias=True,
+                                route_norm=True, route_scale=2.5,
+                                held_experts=(0, 8), expert_act="relu2",
+                                expert_gated=False, norm="rms",
+                                positions="none", bias=False,
+                                tied_head=False)]},
     "four_chips": {"depth": 50, "image": 224, "classes": 1000,
                    "batch": 256, "steps": 3, "seed": 4},
     # the eight routed cells' expert layers at their prefill programs'
@@ -853,7 +887,7 @@ def phase_kv_ring(sizes, ctx):
     platform = ctx.jax_device().platform
     total = {"ring_params": 0, "aliased": 0, "copies": 0, "kernel_calls": 0,
              "layouts": [], "rings": [], "delta_rule": [], "delta_step": [],
-             "ssm_step": [],
+             "ssm_step": [], "routed": [],
              "prefill_ms": [], "mixed_steps": 0, "kernel_buckets": 0,
              "masked_buckets": 0}
     for shape in sizes["shapes"]:
@@ -994,6 +1028,20 @@ def phase_kv_ring(sizes, ctx):
                     for key in ("ring_params", "aliased", "copies"):
                         seen[key] += more[key]
                 mixed.append(seen)
+            # a routed model's longest prefill: the segment matmuls of a
+            # layer whose call `expert_plan` gives the kernel — three of a
+            # gated expert, TWO of an ungated one (PR 64)
+            routed = None
+            if lm.extra_outputs():
+                _exe, pre = session._program(session._prefill_pred, 1,
+                                             longest, True)
+                routed = {"layers": sum(1 for f in lm.ffn_types
+                                        if f in ("routed", "shortcut")),
+                          "matrices": 3 if lm.expert_gated else 2,
+                          "kernel": lm.expert_plan(longest, platform)[3],
+                          "bucket": longest,
+                          "kernel_calls": named_kernel_calls(
+                              pre.hlo_text(), "grouped_matmul_kernel")}
             prefill_ms = prefill_bucket_ms(session, buckets)
             # the live set as the warm-up's programs left it on the device
             held = [(n, e.nbytes, getattr(a, "on_device_size_in_bytes",
@@ -1086,6 +1134,18 @@ def phase_kv_ring(sizes, ctx):
                    "%d attention kernel calls in a decode program of %d "
                    "attention layers" % (facts["kernel_calls"] - others,
                                          ring_layers))
+        if routed:
+            print("[chip_smoke] kv_ring: the %(bucket)d-bucket prefill of "
+                  "%(layers)d routed layer(s) of %(matrices)d matrices an "
+                  "expert: %(kernel_calls)d grouped-matmul kernel call(s)"
+                  % routed, flush=True)
+            total["routed"].append(routed)
+            _check(routed["kernel_calls"] == routed["kernel"]
+                   * routed["layers"] * routed["matrices"],
+                   "%(kernel_calls)d grouped-matmul kernel calls in the "
+                   "%(bucket)d-bucket prefill of %(layers)d routed layers "
+                   "of %(matrices)d matrices an expert (kernel: %(kernel)s)"
+                   % routed)
         if scanned:
             print("[chip_smoke] kv_ring: the %(bucket)d-bucket prefill of "
                   "%(layers)d delta-rule layer(s): %(kernel_calls)d kernel "

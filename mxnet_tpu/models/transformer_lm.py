@@ -74,7 +74,12 @@ declares, in that one place, its sizes, its parameters, its full-sequence
 forward, its prefill, its decode step, the two in one (`mixed`: attention,
 window attention and Gated DeltaNet have one), the device-resident state it
 keeps between calls and the counters a program call adds to; the five
-graph builders walk the pattern and know no kind by name.  `ffn_types`
+graph builders walk the pattern and know no kind by name.  **A layer is
+a mixer, an FFN or both, never neither:** ``"none"`` names the half a layer
+does not have (:class:`_Nothing`, in `layer_types` and in `ffn_types`
+alike) — no parameters, no norm, no cache entry, no counters, no join —
+so a stream of sublayers, each ``h <- h + F(norm(h))`` with ONE norm, is
+one layer a sublayer.  `ffn_types`
 names each layer's FFN — the other half — the same way: ``"dense"``
 (:class:`_DenseFFN`, of width `d_ff`), ``"routed"``
 (:class:`_RoutedFFN`: ``mx.sym.MoE`` experts of width `expert_d_ff`, a
@@ -138,6 +143,14 @@ behind 512 real ones in a softmax router used unnormalised times
 ``route_scale`` with ``router_bias``, held as one chip's share of experts
 AND of heads (`num_heads` is then the share's count): LongCat-Flash's
 (`longcat_flash`).
+``layer_types`` / ``ffn_types`` read off a pattern of SUBLAYERS, one layer
+a character — ``("mamba", "none")``, ``("attention", "none")``, ``("none",
+"routed")`` —, the Mamba-2 kind's `groups` 8 (of `B` / `C` and of the gated
+norm), NoPE attention of 32 heads over 2, ``expert_gated=False``
+with ``expert_act="relu2"`` (experts of TWO matrices, ``W2 relu(W1 x)^2``,
+the shared one too), sigmoid scores with ``router_bias``, ``route_norm``
+and ``route_scale``, ``held_experts``, an untied head: Nemotron-H's
+(`nemotron_h`).
 
 **A draft module.**  `nextn` = 1 adds DeepSeek-V3's multi-token-prediction
 module behind the trunk (GLM-5's, `glm5`): two norms (``mtp_enorm``,
@@ -1081,7 +1094,7 @@ class _Mamba2(_Recurrent):
     taps to 128 — and the recurrent state ``(slots, heads, head_dim,
     d_state)``.  Its own, ``kind_specs["mamba"]``: `heads` x `head_dim`
     (its inner width), `state`, `groups`, `conv` taps and the prefill
-    scan's `chunk`."""
+    scan's `chunk`; the gated norm goes by the same `groups` (``ops/ssm.py``)."""
 
     KIND = "mamba"
     SIZES = ("heads", "head_dim", "state")
@@ -1210,11 +1223,31 @@ class _GatedDeltaNet(_Recurrent):
                 "gdn.step_kernel_bytes": 2 * rows * page * stepped}
 
 
+class _Nothing:
+    """The half a layer does not have — kind ``"none"`` of `layer_types`
+    or of `ffn_types`: no parameters, no norm, no cache entry, no counters,
+    no join.  A layer of ONE sublayer (``h <- h + F(norm(h))`` with one
+    norm) names it for its other half; the graph builders pass a half
+    that is not there by (`TransformerLM._mixer_half`, `_ffn`)."""
+
+    KIND = "none"
+
+    def __init__(self, lm):
+        self.lm = lm
+
+    def params(self, i):
+        return {}
+
+    def cache_spec(self, i, slots, max_len):
+        return []
+
+
 _KINDS = {"attention": _Attention, "window_attention": _WindowAttention,
           "mamba": _Mamba2, "linear_attention": _GatedDeltaNet,
           "latent_attention": _LatentAttention,
           "sparse_latent_attention": _SparseLatentAttention,
-          "window_latent_attention": _WindowLatentAttention}
+          "window_latent_attention": _WindowLatentAttention,
+          "none": _Nothing}
 
 
 class _DenseFFN:
@@ -1250,10 +1283,34 @@ class _DenseFFN:
         return lm._linear(f, p, "ffn2", lm.d_model, "l%d_ffn2" % i)
 
 
+_LANES = 128
+
+
+def stored_width(width):
+    """The width a routed layer STORES its experts at: `width` rounded up
+    to whole 128-lane tiles, the pad ZERO (`_RoutedFFN.stored`) — the same
+    numbers exactly (every `expert_act` is 0 at 0, so a zero column of an
+    in-projection meets a zero row of `down_weight` with a zero; its
+    gradient is zero too, so training keeps it).  A TPU keeps a stack
+    ``(experts, d_model, width)`` whose width is no whole number of tiles
+    with `d_model` on the lanes instead; XLA's ragged-dot wants the width
+    there and COPIES the stack every step of every routed layer (read off
+    Nemotron-H's step compiled for a described v5e at 1,856: 639 MB a
+    layer, 0.68 GB of temporaries), and `parallel.moe.kernel_tiles` takes
+    no call of it.  A width within one tile (the tests' sizes) is left as
+    it is; every width of whole tiles — all the accepted decoders' — is
+    its own."""
+    return width if width <= _LANES else -(-width // _LANES) * _LANES
+
+
 class _RoutedFFN:
     """The routed FFN of layer i (``mx.sym.MoE``, dropless):
     `experts_per_token` of `num_experts` gated experts (`expert_act`:
-    SwiGLU, or a ReLU gate) of width `expert_d_ff` by the router's scores
+    SwiGLU, or a ReLU gate; with `expert_gated` false experts of TWO
+    matrices, ``W2 act(W1 x)``, the shared one too — `expert_act`
+    ``"relu2"`` squares the ReLU — under the device scope
+    ``mx:moe.ungated``) of width `expert_d_ff` (`stored_width`: what the
+    stacks are STORED at) by the router's scores
     — of the FFN's own normed input or, `router_input` ``"mixer"``, of what
     the block's mixer read —, plus — `shared_d_ff` — one
     expert every token passes, times — `shared_gate` — the sigmoid of its
@@ -1268,7 +1325,13 @@ class _RoutedFFN:
         self.lm = lm
         held = lm.held_experts
         self.held = lm.num_experts if held is None else held[1]
+        self.width = stored_width(lm.expert_d_ff)
         self.router_width = lm.num_experts + lm.zero_experts
+        # an expert's matrices (each stacked an expert), in the order
+        # ``mx.sym.MoE`` takes them: in, out and — gated — the second in
+        self.expert_keys = (("gate_weight", "down_weight", "up_weight")
+                            if lm.expert_gated else
+                            ("up_weight", "down_weight"))
         # beyond OLMoE's: an option appears on a node only when the
         # spec sets it
         self.attrs = {}
@@ -1291,22 +1354,18 @@ class _RoutedFFN:
 
     def params(self, i):
         lm, v = self.lm, sym.Variable
-        d, ff, e, s = lm.d_model, lm.expert_d_ff, self.held, lm.shared_d_ff
+        d, ff, e, s = lm.d_model, self.width, self.held, lm.shared_d_ff
         p = {"router_weight": v("l%d_router_weight" % i,
                                 shape=(d, self.router_width))}
         if lm.router_bias:
             p["router_bias"] = v("l%d_router_bias" % i,
                                  shape=(self.router_width,))
-        p["gate_weight"] = v("l%d_gate_weight" % i, shape=(e, d, ff))
-        p["down_weight"] = v("l%d_down_weight" % i, shape=(e, ff, d))
-        p["up_weight"] = v("l%d_up_weight" % i, shape=(e, d, ff))
-        if s:
-            p["shared_gate_weight"] = v("l%d_shared_gate_weight" % i,
-                                        shape=(d, s))
-            p["shared_down_weight"] = v("l%d_shared_down_weight" % i,
-                                        shape=(s, d))
-            p["shared_up_weight"] = v("l%d_shared_up_weight" % i,
-                                      shape=(d, s))
+        for key in self.expert_keys:
+            p[key] = v("l%d_%s" % (i, key), shape=(
+                (e, ff, d) if key == "down_weight" else (e, d, ff)))
+        for key in self.expert_keys if s else ():
+            p["shared_" + key] = v("l%d_shared_%s" % (i, key), shape=(
+                (s, d) if key == "down_weight" else (d, s)))
         if lm.shared_gate:
             p["shared_score_weight"] = v("l%d_shared_score_weight" % i,
                                          shape=(d, 1))
@@ -1316,24 +1375,45 @@ class _RoutedFFN:
         lm = self.lm
         operands = [x, p["router_weight"]]
         operands += [p["router_bias"]] if lm.router_bias else []
-        operands += [p["gate_weight"], p["down_weight"], p["up_weight"]]
+        operands += [p[key] for key in self.expert_keys]
         if lm.shared_d_ff:
-            operands += [p["shared_gate_weight"], p["shared_down_weight"],
-                         p["shared_up_weight"]]
+            operands += [p["shared_" + key] for key in self.expert_keys]
         if lm.shared_gate:
             operands.append(p["shared_score_weight"])
         if lm.router_input == "mixer":
             operands.append(mixer_in)
-        f = sym.MoE(*operands, num_experts=lm.num_experts,
-                    hidden_size=lm.expert_d_ff, k=lm.experts_per_token,
-                    act_type=lm.expert_act, gated=True, no_bias=True,
-                    normalize=lm.route_norm,
-                    return_load=loads is not None, name="l%d_moe" % i,
-                    **self.attrs)
+        # (a scope of the node only where the spec has two-matrix experts)
+        scope = {} if lm.expert_gated else {"__scope__": "mx:moe.ungated"}
+        with AttrScope(**scope):
+            f = sym.MoE(*operands, num_experts=lm.num_experts,
+                        hidden_size=self.width, k=lm.experts_per_token,
+                        act_type=lm.expert_act, gated=lm.expert_gated,
+                        no_bias=True, normalize=lm.route_norm,
+                        return_load=loads is not None, name="l%d_moe" % i,
+                        **self.attrs)
         if loads is None:
             return f
         loads.append(f[1])
         return f[0]
+
+    def stored(self, key, stack):
+        """The expert stack `key` (one of `expert_keys`) as the layer's
+        graphs take it: `stack` itself at the stored width, a stack of
+        the published `expert_d_ff` with ZEROS behind its columns (an
+        in-projection's) or rows (`down_weight`'s) up to `width`."""
+        axis = 1 if key == "down_weight" else 2
+        spare = self.width - stack.shape[axis]
+        if not spare:
+            return stack
+        if stack.shape[axis] != self.lm.expert_d_ff:
+            raise ValueError("%s is %d wide: neither expert_d_ff %d nor the "
+                             "stored %d" % (key, stack.shape[axis],
+                                            self.lm.expert_d_ff, self.width))
+        import jax.numpy as jnp
+
+        pad = [(0, 0)] * 3
+        pad[axis] = (0, spare)
+        return jnp.pad(getattr(stack, "_data", stack), pad)
 
     def counters(self, i, positions=0, computed=0, **call):
         """What one program call adds: the (token, expert) pairs the
@@ -1370,9 +1450,8 @@ class _ShortcutFFN(_RoutedFFN):
             return super().apply(x, p, i, loads, mixer_in)
 
 
-_FFNS = {"dense": _DenseFFN, "routed": _RoutedFFN, "shortcut": _ShortcutFFN}
-# a routed FFN's parameters that are stacked an expert
-_EXPERT_KEYS = ("gate_weight", "down_weight", "up_weight")
+_FFNS = {"dense": _DenseFFN, "routed": _RoutedFFN, "shortcut": _ShortcutFFN,
+         "none": _Nothing}
 
 
 class TransformerLM:
@@ -1399,7 +1478,9 @@ class TransformerLM:
     Further choices (defaults: as if absent): `layer_types` — one mixer
     kind a layer, ``"attention"`` | ``"window_attention"`` | ``"mamba"`` |
     ``"linear_attention"`` | ``"latent_attention"`` |
-    ``"sparse_latent_attention"`` | ``"window_latent_attention"``
+    ``"sparse_latent_attention"`` | ``"window_latent_attention"`` |
+    ``"none"`` (a layer that is an FFN alone: no mixer, no norm for one,
+    no cache entry)
     (module docstring; default all attention); `block_norm` ``"input"``
     (``h + f(norm(h))``) | ``"output"`` (``h + norm(f(h))``), for both
     halves of every block; `num_kv_heads` K/V heads shared by groups of
@@ -1422,8 +1503,11 @@ class TransformerLM:
     `ffn_types` — one FFN kind a layer, ``"dense"`` | ``"routed"`` |
     ``"shortcut"`` (a dense FFN, and a routed layer of the same normed
     input whose result joins at the end of the NEXT layer, which is
-    therefore never the last);
-    `expert_d_ff` — a routed expert's width (default `d_ff`);
+    therefore never the last) | ``"none"`` (a layer that is a mixer alone;
+    a layer with neither half is refused);
+    `expert_d_ff` — a routed expert's width (default `d_ff`; a width
+    over one 128-lane tile that is no whole number of them is STORED
+    rounded up, the pad zero: `stored_width`, `stored_params`);
     `shared_d_ff` — the width of one expert every token passes,
     `shared_gate` multiplies what it adds by ``sigmoid(x w_s)``;
     `router_score` ``"softmax"`` | ``"sigmoid"``; `router_bias` adds
@@ -1434,8 +1518,11 @@ class TransformerLM:
     wide; `router_input` ``"ffn"`` | ``"mixer"`` — what a routed FFN's
     router scores: the FFN's own normed input, or the normed stream the
     block's MIXER read (the choice of experts is then known before the
-    mixer has run); `expert_act` ``"silu"`` | ``"relu"`` — the routed
-    experts' gate activation; `zero_experts` n — the router is
+    mixer has run); `expert_act` ``"silu"`` | ``"relu"`` | ``"relu2"``
+    (``relu(.)^2``) — the routed experts' activation; `expert_gated`
+    (default true) — false: the experts and the shared expert are of TWO
+    matrices, ``W2 act(W1 x)``, ``l<i>_up_weight`` / ``l<i>_down_weight``
+    with no ``gate_weight``; `zero_experts` n — the router is
     ``num_experts + n`` wide, its last n columns zero-compute experts that
     add ``(sum of their weights) * x`` and hold no matrix (a held range is
     over the real ones; ``moe_load`` gains one column, their pairs);
@@ -1453,7 +1540,8 @@ class TransformerLM:
 
     * ``"mamba"`` — `heads` x `head_dim` (its inner width), `state`;
       `groups` 1 (which divide `heads`), `conv` 4 taps, the prefill
-      scan's `chunk` 256.  Flat: ``mamba_<size>``.
+      scan's `chunk` 256; the gated norm takes the `groups` runs of
+      channels each on its own mean square.  Flat: ``mamba_<size>``.
     * ``"linear_attention"`` — `heads` value heads of `key_dim` x
       `value_dim`; `conv` 4 taps, the `chunk` 64 of its full-sequence
       form, `neg_eigval` True (``beta`` reaches 2), `key_heads` — heads of
@@ -1493,7 +1581,7 @@ class TransformerLM:
                  held_experts=None, rotary_dim=None, shared_gate=False,
                  rope_scaling=None, query_scale=None, kind_specs=None,
                  nextn=0, router_input="ffn", expert_act="silu",
-                 zero_experts=0, **flat):
+                 zero_experts=0, expert_gated=True, **flat):
         if int(nextn) not in (0, 1):
             raise ValueError("nextn must be 0 or 1 (ONE draft a step), got %r"
                              % (nextn,))
@@ -1579,6 +1667,18 @@ class TransformerLM:
         if routed and not num_experts:
             raise ValueError("a 'routed' or 'shortcut' FFN needs "
                              "num_experts >= 1")
+        bare = [i for i, halves in enumerate(zip(layer_types, ffn_types))
+                if halves == ("none", "none")]
+        if bare:
+            raise ValueError("a layer has a mixer, an FFN or both: layers %r "
+                             "have neither ('none' in layer_types AND in "
+                             "ffn_types)" % (bare,))
+        if router_input == "mixer" and any(
+                m == "none" and f != "none"
+                for m, f in zip(layer_types, ffn_types)):
+            raise ValueError("router_input='mixer' scores what the block's "
+                             "mixer reads: every layer with an FFN needs a "
+                             "mixer, got layer_types %r" % (layer_types,))
         if ffn_types[-1:] == ("shortcut",):
             raise ValueError("a 'shortcut' FFN's branch joins at the end of "
                              "the NEXT layer: the last layer has none")
@@ -1588,11 +1688,13 @@ class TransformerLM:
         if router_input not in ("ffn", "mixer"):
             raise ValueError("router_input must be 'ffn' or 'mixer', got %r"
                              % (router_input,))
-        if expert_act not in ("silu", "relu"):
-            raise ValueError("expert_act must be 'silu' or 'relu', got %r"
-                             % (expert_act,))
+        if expert_act not in ("silu", "relu", "relu2"):
+            raise ValueError("expert_act must be 'silu', 'relu' or 'relu2', "
+                             "got %r" % (expert_act,))
         for name, value, default in (("router_input", router_input, "ffn"),
-                                     ("expert_act", expert_act, "silu")):
+                                     ("expert_act", expert_act, "silu"),
+                                     ("expert_gated", bool(expert_gated),
+                                      True)):
             if value != default and not routed:
                 raise ValueError("%s=%r is a routed FFN's: no layer of "
                                  "ffn_types %r is 'routed'"
@@ -1638,6 +1740,7 @@ class TransformerLM:
         self.held_experts = held_experts
         self.router_input = router_input
         self.expert_act = expert_act
+        self.expert_gated = bool(expert_gated)
         self.zero_experts = int(zero_experts)
         self.rotary_dim = None if rotary_dim is None else int(rotary_dim)
         self.shared_gate = bool(shared_gate)
@@ -1746,6 +1849,8 @@ class TransformerLM:
         the layer before forked (a ``"shortcut"`` FFN's) joins here, as it
         is, and this layer's own is left there for the next."""
         ffn = ffn or self._ffns[i]
+        if isinstance(ffn, _Nothing):
+            return self._join(h, carried.pop()) if carried else h
         x = self._branch_in(h, "l%d_ln2" % i)
         f = ffn.apply(x, p, i, loads, mixer_in)
         f = self._branch_out(f, "l%d_ln2" % i)
@@ -1758,13 +1863,26 @@ class TransformerLM:
             carried.append(ffn.branch(x, p, i, loads, mixer_in))
         return h
 
-    def _block_train(self, h, i, train, carried):
-        p = self._block_params(i)
+    def _mixer_half(self, h, mixer, i, mix, train=False):
+        """The block's first half on the residual stream `h`: layer i's
+        mixer between the block's norms, called through ``mix(x)`` → (its
+        output, its cache entries).  Returns (the stream, what the mixer
+        read, the entries) — `h` as it came, None and nothing for a layer
+        that has no mixer."""
+        if isinstance(mixer, _Nothing):
+            return h, None, []
         x = self._branch_in(h, "l%d_ln1" % i)
-        a = self._branch_out(self._mixers[i].full(x, p, i), "l%d_ln1" % i)
+        y, state = mix(x)
+        a = self._branch_out(y, "l%d_ln1" % i)
         if train and self.dropout > 0:
             a = sym.Dropout(a, p=self.dropout, name="l%d_adrop" % i)
-        h = self._join(h, a)
+        return self._join(h, a), x, state
+
+    def _block_train(self, h, i, train, carried):
+        p = self._block_params(i)
+        mixer = self._mixers[i]
+        h, x, _ = self._mixer_half(
+            h, mixer, i, lambda x: (mixer.full(x, p, i), []), train)
         return self._ffn(h, p, i, train, mixer_in=x, carried=carried)
 
     def _embed(self, data, index=None, tables=None, tag=""):
@@ -1932,7 +2050,7 @@ class TransformerLM:
         kernel (`parallel.moe.kernel_tiles` of a call's rows: the three
         matmuls of a layer choose alike), and whether the layer's calls
         also fetch and place their own rows (`parallel.moe.fused_tile`:
-        every expert held — these gated FFNs have no bias), or whether
+        every expert held — these FFNs have no bias), or whether
         its passes return their rows to token order through the TPU's
         kernel (`parallel.moe.return_tiles`: a pass of a held range whose
         width the kernel's tiling holds).  The session keeps it a bucket
@@ -1948,10 +2066,10 @@ class TransformerLM:
                                      scored)
         call = (rows or tokens // pieces * k,
                 (held or (0, self.num_experts))[1], self.d_model,
-                self.expert_d_ff)
+                stored_width(self.expert_d_ff))
         kernel = platform == "tpu" and moe.kernel_tiles(*call) is not None
         fused = (kernel and held is None
-                 and moe.fused_tile(*call, True) is not None)
+                 and moe.fused_tile(*call, self.expert_gated) is not None)
         placed = bool(platform == "tpu" and rows) and moe.return_tiles(
             tokens // pieces, rows, self.d_model, "float32") is not None
         return tokens * k, pieces, rows, kernel, fused, placed
@@ -1972,7 +2090,8 @@ class TransformerLM:
                          for k, v in self._block_params(i, mixer,
                                                         ffn).items()}
                 routed = isinstance(ffn, _RoutedFFN)
-                mine = sum(sizes[k] for k in _EXPERT_KEYS) if routed else 0
+                mine = (sum(sizes[k] for k in ffn.expert_keys) if routed
+                        else 0)
                 fixed.append(4 * (sum(sizes.values()) - mine))
                 expert.append(4 * mine // ffn.held if routed else None)
             self._weights = (fixed, expert,
@@ -1982,12 +2101,29 @@ class TransformerLM:
         if load is not None and self.zero_experts:
             load = load[..., :-1]   # (the zero-compute experts' column)
         hit = iter([] if load is None else (load > 0).sum(axis=-1))
-        layers = [f + (0 if e is None else
-                       e * int(next(hit, self._ffns[-1].held)))
+        held = (self.held_experts or (0, self.num_experts))[1]
+        layers = [f + (0 if e is None else e * int(next(hit, held)))
                   for f, e in zip(fixed, expert)]
         module = layers[-1] + join + head
         return {"mtp.bytes": module,
                 "mtp.step_bytes": sum(layers[:-1]) + head + module}
+
+    def stored_params(self, params):
+        """`params` (name -> array) as this model's graphs take them: a
+        routed layer's expert stacks of the published `expert_d_ff` padded
+        to the width the layer stores (`stored_width`), every other array
+        — a stack that is stored already among them — the object it was.
+        `GenerativeSession` passes what it is handed through here; what
+        trains or scores a checkpoint by `training_symbol` /
+        `score_symbol` does the same (an initializer that FILLS the pad
+        trains experts of the stored width)."""
+        out = dict(params)
+        for i, _, ffn in self._layers() + self._draft:
+            for key in getattr(ffn, "expert_keys", ()):
+                name = "l%d_%s" % (i, key)
+                if name in out:
+                    out[name] = ffn.stored(key, out[name])
+        return out
 
     def _cache_vars(self):
         return {n: sym.Variable(n) for n in self.cache_spec(1)}
@@ -2009,10 +2145,9 @@ class TransformerLM:
         branch it forks for the next block to `carried`."""
         i, mixer, ffn = layer
         p = self._block_params(i, mixer, ffn)
-        x = self._branch_in(h, "l%d_ln1" % i)
-        y, state = mix(mixer, x, p, i)
+        h, x, state = self._mixer_half(h, mixer, i,
+                                     lambda x: mix(mixer, x, p, i))
         outs += state
-        h = self._join(h, self._branch_out(y, "l%d_ln1" % i))
         return self._ffn(h, p, i, train=False, loads=loads, ffn=ffn,
                          mixer_in=x, carried=carried)
 
@@ -2169,7 +2304,8 @@ class TransformerLM:
         last_token', token (1 + rows,)]``.  None for a model with a mixer
         kind that has no `mixed` (it keeps the two programs)."""
         if self.nextn or not all(hasattr(mixer, "mixed")
-                                 for mixer in self._mixers):
+                                 for mixer in self._mixers
+                                 if not isinstance(mixer, _Nothing)):
             return None   # (a draft under a mixed step: ROADMAP R10)
         data = sym.Variable("data")
         slot = sym.Variable("slot")

@@ -312,7 +312,8 @@ def prefill_block(q_shape, num_heads, kv_heads, platform, causal=True):
     `query` of `q_shape` ``(N, T, d_model)`` with `num_heads` query heads
     over `kv_heads` K/V heads: the largest multiples of 128 that divide
     ``T`` within 1,024 rows of all the group's heads a step and 1,024
-    positions a key block.  None where ``_sdp_attention`` runs its
+    positions a key block (fewer where a group of sixteen heads' 128 rows
+    would not fit beside them).  None where ``_sdp_attention`` runs its
     ``jax.numpy`` body: off the TPU; without the `causal` mask; where the
     float32 scores of all heads, ``4 N H T^2`` bytes, are within 96 MiB
     (XLA keeps them on the chip between its two products, and its one
@@ -339,9 +340,13 @@ def prefill_block(q_shape, num_heads, kv_heads, platform, causal=True):
     # bfloat16 operands twice (the pipeline's buffers), the float32
     # output twice, the accumulator, and a block's scores three times
     # over (scores, probabilities, mask)
-    held = (2 * 2 * (2 * t + wide) * d_head + 3 * 4 * wide * d_head
-            + 3 * 4 * wide * keys)
-    return (rows, keys) if held <= _PREFILL_VMEM else None
+    held = lambda keys: (2 * 2 * (2 * t + wide) * d_head  # noqa: E731
+                         + 3 * 4 * wide * d_head + 3 * 4 * wide * keys)
+    # a group so large that 128 rows of all its heads pass `_PREFILL_ROWS`
+    # (16 query heads a K/V head: 2,048) takes a shorter key block
+    while held(keys) > _PREFILL_VMEM and keys > _LANES:
+        keys = divisor(keys - _LANES)
+    return (rows, keys) if held(keys) <= _PREFILL_VMEM else None
 
 
 def prefill_visits(t, block, window=None):

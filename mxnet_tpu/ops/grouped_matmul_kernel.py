@@ -310,7 +310,9 @@ def _gate_up_kernel(expert_ref, tile_ref, turn_ref, following_ref,
     @pl.when(i < count)
     def _():
         x = tile_rows[...]
-        h = getattr(jax.nn, act)(
+        from ..parallel.moe import ACTIVATIONS
+
+        h = ACTIVATIONS[act](
             jnp.dot(x, rounded[0], preferred_element_type=jnp.float32))
         if len(w_hbm) == 2:
             h = h * jnp.dot(x, rounded[1],
@@ -327,8 +329,8 @@ def gate_up(x, token, sizes, w1, w3=None, *, tm, act, interpret=False):
     (no `w3`: an un-gated FFN) → ``[h [M, H]]`` bfloat16: each expert's
     segment as ``act(rows w1) * (rows w3)``, one bfloat16 pass each
     accumulated in float32, the product in float32, rounded once as it is
-    stored.  `act` names a function of `jax.nn`; `tm` rows a tile
-    (``parallel.moe.fused_tile``).  Rows past the last segment as
+    stored.  `act` names one of ``parallel.moe.ACTIVATIONS``; `tm` rows
+    a tile (``parallel.moe.fused_tile``).  Rows past the last segment as
     `grouped_matmul` leaves them."""
     m, = token.shape
     matrices = [w1] if w3 is None else [w1, w3]
@@ -362,7 +364,7 @@ def gate_up(x, token, sizes, w1, w3=None, *, tm, act, interpret=False):
         # `x` a pair, the result once
         cost_estimate=pl.CostEstimate(
             flops=2 * len(matrices) * m * k * n,
-            transcendentals=m * n if act != "relu" else 0,
+            transcendentals=0 if act.startswith("relu") else m * n,
             bytes_accessed=(len(matrices) * w1.size * itemsize
                             + m * k * x.dtype.itemsize + m * n * 2)),
         name="grouped_gate_up_kernel",

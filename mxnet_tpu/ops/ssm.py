@@ -12,7 +12,9 @@ projection consumes:
     dt  = softplus(dt + dt_bias);  a = -exp(A_log)            per head
     S_t = exp(dt_t a) S_{t-1} + dt_t x_t (x) B_t              (H, P, S)
     y_t = S_t C_t + D x_t
-    y   = RMSNorm(y * silu(z)) * norm_gamma      (gate first, one group)
+    y   = RMSNorm_g(y * silu(z)) * norm_gamma    (gate first; the `n_groups`
+          equal runs of the d_inner channels — a group's heads — each times
+          the rsqrt of its OWN mean square; one group: all channels at once)
 
 * ``_ssm_scan`` — a whole sequence from an empty state: training and
   scoring.  The recurrence runs in its chunked block form (the paper's
@@ -135,9 +137,18 @@ def _conv_full(xbc, weight, bias):
     return jnn.silu(out)
 
 
-def _gated_norm(y, z, gamma, eps):
+def _gated_norm(y, z, gamma, attrs):
+    """``y * silu(z)``, then each of the node's `n_groups` runs of
+    channels times the rsqrt of its own mean square, times the gain."""
+    eps, groups = float(_lit(attrs["eps"])), int(_lit(attrs["n_groups"]))
     y = y * jnn.silu(z)
-    return y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps) * gamma
+    if groups == 1:
+        return y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
+                             + eps) * gamma
+    runs = y.reshape(y.shape[:-1] + (groups, -1))
+    runs = runs * lax.rsqrt(jnp.mean(runs * runs, axis=-1, keepdims=True)
+                            + eps)
+    return runs.reshape(y.shape) * gamma
 
 
 def _ssd(x, dt, a, b, c, chunk):
@@ -203,7 +214,7 @@ def _mix(data, conv_weight, conv_bias, dt_bias, a_log, d_skip, norm_gamma,
     y, final = _ssd(x, dt, -jnp.exp(a_log.astype(jnp.float32)), b, c,
                     _lit(attrs["chunk_size"]))
     y = (y + d_skip[:, None] * x).reshape(data.shape[:2] + (h * p,))
-    y = _gated_norm(y, z, norm_gamma, float(_lit(attrs["eps"])))
+    y = _gated_norm(y, z, norm_gamma, attrs)
     return y.astype(data.dtype), xbc_raw, final
 
 
@@ -385,5 +396,5 @@ def ssm_step(data, conv_weight, conv_bias, dt_bias, A_log, D, norm_gamma,
             interpret=_INTERPRET,
             heads=step_heads(ssm_state.shape, "tpu", g) if traced else None)
         y = _gated_norm((y + D[:, None] * x).reshape(rows, h * p), z,
-                        norm_gamma, float(_lit(attrs["eps"])))
+                        norm_gamma, attrs)
     return y[:, None].astype(data.dtype), conv_state, ssm_state

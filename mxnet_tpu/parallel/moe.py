@@ -728,3 +728,18 @@ def _placed_rows_bwd(tiles, interpret, kept, cotangent):
 
 
 _placed_rows.defvjp(_placed_rows_fwd, _placed_rows_bwd)
+
+
+# (What PR 64 adds stands here for the same reason.)  An activation an
+# UNGATED expert of two matrices takes — ``W2 relu(W1 x)^2``, Nemotron-H's
+# `mlp_hidden_act` — joins the two above; `expert_ffn` squares where a gated
+# expert multiplies by its second in-projection, and every route of a held
+# range and of a whole layer (`_held_passes`, `_every_pair`, `_two_calls`,
+# whose up call then reads one matrix an expert) takes `gated=False` as the
+# same code with one operand fewer.
+def relu2(x):
+    """``relu(x)^2``."""
+    return jnp.square(jax.nn.relu(x))
+
+
+ACTIVATIONS["relu2"] = relu2

@@ -562,7 +562,8 @@ class GenerativeSession:
     return ``[logits, entries..., last_token, token (B,),
     extra_outputs()...]``.
     `params` maps parameter name -> array (a training checkpoint's
-    arg+aux dicts merged).  `max_sessions` is the number of cache
+    arg+aux dicts merged; a model with ``stored_params(params)`` is asked
+    for them as its graphs take them).  `max_sessions` is the number of cache
     slots (the cap on concurrent sessions), `max_len` a slot's ring
     length in tokens (clamped to the model's positional table),
     `max_decode_tokens` the budget of a request that names none."""
@@ -630,6 +631,8 @@ class GenerativeSession:
             getattr(model, "extra_outputs", tuple)())
         # whose last column is then the zero-compute experts' pairs
         self._zero_experts = bool(getattr(model, "zero_experts", 0))
+        # and whose experts may be of two matrices (`moe.ungated_pairs`)
+        self._ungated = not getattr(model, "expert_gated", True)
         # counters the model's layer kinds declare for a program call
         self._call_counters = getattr(model, "call_counters", None)
         # every call threads the cache entries, then each slot's last
@@ -659,6 +662,10 @@ class GenerativeSession:
                             if seq_buckets else
                             bucket_ladder(self._max_len, ""))
         self._decode_ladder = bucket_ladder(self._slots, "")
+        # a model may STORE an array otherwise than a checkpoint has it (a
+        # routed layer's expert stacks in whole lane tiles); what is
+        # stored already passes as the object it is
+        params = getattr(model, "stored_params", dict)(params)
         self._prefill_pred = Predictor(
             graphs[True], dict(params),
             self._shapes(1, self._seq_ladder[0], prefill=True), ctx=ctx)
@@ -916,7 +923,7 @@ class GenerativeSession:
             with self._prog_lock:
                 key = next(k for k, e in self._programs.items() if e is exe)
             self._book_moe_load(extra[0], self._buckets[key].experts,
-                                self._zero_experts)
+                                self._zero_experts, self._ungated)
         return logits
 
     def _dispatch(self, exe, fn, data, slot, length, rows, prog, pack,
@@ -984,7 +991,7 @@ class GenerativeSession:
             token, *extra = (_np.asarray(o) for o in flight.outs)
         if self._reports_moe_load:
             self._book_moe_load(extra[0], flight.prog.experts,
-                                self._zero_experts)
+                                self._zero_experts, self._ungated)
         if self._drafts:
             return self._land_drafted(flight, token, extra, wait, read,
                                       hists[2], book)
@@ -1263,13 +1270,14 @@ class GenerativeSession:
                 telemetry.inc(name, n)
 
     @staticmethod
-    def _book_moe_load(load, plan, zero=False):
+    def _book_moe_load(load, plan, zero=False, ungated=False):
         """The `moe.*` counters of one program call from its `moe_load
         (layers, experts)` output — behind them, where the model has
         zero-compute experts (`zero`), ONE column of the pairs that chose
         one, `moe.zero_pairs` (padded rows included, as `moe.pairs` has
         them), which no other counter takes for an expert —: token-expert
-        pairs computed (padded
+        pairs computed (`moe.ungated_pairs` too where the model's experts
+        are of two matrices, `ungated`; padded
         rows included — the device computed them), experts that got at
         least one token, expert slots offered, and the fullest expert's
         tokens, each summed over the layers.  And from the program's
@@ -1289,6 +1297,8 @@ class GenerativeSession:
                 telemetry.inc("moe.zero_pairs", int(load[..., -1].sum()))
                 load = load[..., :-1]
             telemetry.inc("moe.pairs", int(load.sum()))
+            if ungated:
+                telemetry.inc("moe.ungated_pairs", int(load.sum()))
             telemetry.inc("moe.experts_hit", int((load > 0).sum()))
             telemetry.inc("moe.expert_slots", int(load.size))
             telemetry.inc("moe.max_load", int(load.max(axis=-1).sum()))

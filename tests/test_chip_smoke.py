@@ -103,7 +103,22 @@ TINY = {
                                 experts_per_token=3, expert_d_ff=16,
                                 router_bias=True, route_scale=6.0,
                                 held_experts=(0, 2), norm="rms",
-                                positions="none", bias=False)]},
+                                positions="none", bias=False),
+                           dict(num_layers=3, num_heads=4, num_kv_heads=2,
+                                max_len=48,
+                                layer_types=["mamba", "none", "attention"],
+                                ffn_types=["none", "routed", "none"],
+                                kind_specs={"mamba": dict(
+                                    heads=8, head_dim=4, state=16, groups=4,
+                                    chunk=8)},
+                                num_experts=8, experts_per_token=3,
+                                expert_d_ff=16, shared_d_ff=24,
+                                router_score="sigmoid", router_bias=True,
+                                route_norm=True, route_scale=2.5,
+                                held_experts=(0, 2), expert_act="relu2",
+                                expert_gated=False, norm="rms",
+                                positions="none", bias=False,
+                                tied_head=False)]},
     "four_chips": {"depth": 18, "image": 32, "classes": 10, "batch": 8,
                    "steps": 2, "seed": 4},
     # four of eight experts held, every pair's row gathered; every expert
@@ -183,13 +198,16 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     # one layer's rings, then (PR 62) the two latent rings of a published
     # layer whose routed branch is carried; the CPU's programs hold no
     # kernel call
-    assert report["kv_ring"]["ring_params"] == 8 + 4 + 4 + 4 + 2 + 3 + 4 + 2
+    # then (PR 64) a Mamba-2 layer's window and state and an attention
+    # layer's rings in a model whose layers have ONE sublayer each
+    assert report["kv_ring"]["ring_params"] == (8 + 4 + 4 + 4 + 2 + 3 + 4 + 2
+                                                + 4)
     assert report["kv_ring"]["rings"] == [[3, 2, 16, 48], [3, 2, 8, 48],
                                           [3, 2, 16, 48], [3, 2, 16, 16],
                                           [3, 2, 16, 48], [3, 2, 32, 48],
                                           [3, 1, 32, 48], [3, 1, 32, 48],
                                           [3, 1, 40, 16], [3, 2, 16, 48],
-                                          [3, 1, 32, 48]]
+                                          [3, 1, 32, 48], [3, 2, 8, 48]]
     assert report["kv_ring"]["kernel_calls"] == 0
     # nor does a shape rule send a bucket through a blockwise kernel
     assert report["kv_ring"]["kernel_buckets"] == 0
@@ -219,7 +237,14 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     # platform for a state of 16 lanes), whose body advances the rows'
     # pages one by one and gathers nothing (the CPU donates no buffer, so
     # its program copies them: judged on a device only)
-    step, = report["kv_ring"]["ssm_step"]
+    step, grouped = report["kv_ring"]["ssm_step"]
+    # (the twelfth model's Mamba-2 layer of four groups: as the tenth's)
+    assert {k: grouped[k] for k in ("kernel_calls", "rows", "layers")} == {
+        "kernel_calls": 0, "rows": 2, "layers": 1}
+    # the two routed models' longest prefill: no kernel call on the CPU,
+    # three matrices a gated expert and TWO an ungated one (PR 64)
+    assert [(r["matrices"], r["kernel_calls"])
+            for r in report["kv_ring"]["routed"]] == [(3, 0), (2, 0)]
     assert {k: v for k, v in step.items()
             if k not in ("ms", "copies", "booked")} == {
         "kernel_calls": 0, "row_pages": [], "rows": 2, "layers": 1,
@@ -231,7 +256,8 @@ def test_chip_smoke_phases_pass_tiny_on_a_cpu_device():
     assert step["ms"] > 0
     # every tenant's prefill buckets timed warm (judged on a device only)
     assert [sorted(ms) for ms in report["kv_ring"]["prefill_ms"]] == [
-        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"]]
+        ["16", "8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"], ["8"],
+        ["8"]]
     assert all(v > 0 for ms in report["kv_ring"]["prefill_ms"]
                for v in ms.values())
     chip_smoke.run_phase("four_chips", chip_smoke.phase_four_chips,

@@ -543,7 +543,11 @@ def test_every_cell_sorts_its_pairs_at_once():
             cut.append(row["name"])
         plans[row["name"]] = lm.expert_plan(rows)[1:3]
     assert cut == ["dots3note_longdoc_c8", "smallthinker_longctx_c16",
-                   "longcatflash_turns_c16"]
+                   "longcatflash_turns_c16", "nemotron3nano_agent_c16"]
+    # six experts a token of 2,688 (PR 64): the 49,200 pairs of its 8,192
+    # bucket beside eight rows would be 529 MB of rows, but 32 of 128
+    # experts are held: ONE pass of thirty-seven 512-row tiles, 204 MB
+    assert plans.pop("nemotron3nano_agent_c16") == (1, 18944)
     # twelve experts a token of 6,144: the 24,672 pairs of its 2,048 bucket
     # beside eight rows would be 606 MB of rows, but 8 of the router's 768
     # columns are held (PR 62; the zero-compute experts' pairs gather no

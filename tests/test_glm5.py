@@ -495,7 +495,8 @@ def test_every_other_cell_builds_the_plain_graphs():
     cell's model keeps the one-token contract — ``last_token (slots +
     1,)``, ``_token_feed`` in and ``_greedy_token`` out, no draft op, no
     node under a `__scope__` (but a carried routed branch's
-    ``mx:moe.shortcut``, PR 62's cell alone), no `mtp_` parameter — so its
+    ``mx:moe.shortcut``, PR 62's cell alone, and two-matrix experts'
+    ``mx:moe.ungated``, PR 64's), no `mtp_` parameter — so its
     programs stay what they were, to the compile cache's key (the graphs'
     JSON of all nine is the parent commit's, byte for byte: PERF.md
     section 6, PR 52)."""
@@ -524,7 +525,8 @@ def test_every_other_cell_builds_the_plain_graphs():
             text = graph.tojson()
             assert '"_draft' not in text and "mx:mtp" not in text
             if "__scope__" in text:
-                assert "mx:moe.shortcut" in text
+                assert ("mx:moe.shortcut" in text) != (
+                    "mx:moe.ungated" in text)
                 scoped.add(row["name"])
             assert '"_greedy_token"' in text
             args = graph.list_arguments()
@@ -537,7 +539,7 @@ def test_every_other_cell_builds_the_plain_graphs():
             assert len(outs) == 3 + len(spec_names) + len(
                 lm.extra_outputs())
     assert drafting == ["glm5_mtp_reason_c8"]
-    assert scoped == {"longcatflash_turns_c16"}
+    assert scoped == {"longcatflash_turns_c16", "nemotron3nano_agent_c16"}
 
 
 def test_a_step_says_what_it_drafted_and_a_flight_what_it_emitted(

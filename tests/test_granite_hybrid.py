@@ -132,8 +132,10 @@ def _np_mixer(data, small, conv=None, state=None):
         state = (np.exp(-dt * np.exp(a_log))[:, None, None] * state
                  + (dt[:, None] * x)[:, :, None] * b[:, None, :])
         y = (state * c[:, None, :]).sum(-1) + d_skip[:, None] * x
-        y = y.reshape(D_INNER) * _silu(z)
-        ys.append(y / np.sqrt((y * y).mean() + 1e-5) * gamma)
+        # gate first, then each GROUP's channels on their own mean square
+        y = (y.reshape(D_INNER) * _silu(z)).reshape(G, -1)
+        y = y / np.sqrt((y * y).mean(-1, keepdims=True) + 1e-5)
+        ys.append(y.reshape(D_INNER) * gamma)
     return np.stack(ys), conv, state
 
 

@@ -82,7 +82,7 @@ def test_the_definition_is_one_file_read_under_two_names():
         "opt1b3_chat_k80"]
     assert entries["batcher.mixed_share_open"]["moves"] == "itl_p99_ms"
     sat = entries["batcher.mixed_share_sat"]
-    assert sat["moves"] == "gen_tok_per_s" and len(sat["workloads"]) == 12
+    assert sat["moves"] == "gen_tok_per_s" and len(sat["workloads"]) == 13
     assert {m["layer"] for m in entries.values()} == {"serving batcher"}
 
 

@@ -68,6 +68,23 @@ def test_the_kernel_computes_the_bodys_context(name, t):
     _close(got, want)
 
 
+def test_a_group_of_sixteen_heads_goes_by_a_shorter_key_block():
+    """Nemotron-H's attention (PR 64): 32 query heads over 2 K/V heads of
+    128, the tiling `prefill_block` gives a group of sixteen — 128 rows,
+    512 keys at 2,048 positions — interpreted against the body."""
+    import jax
+
+    t = 2048
+    rows, keys = attention.prefill_block((1, t, 32 * 128), 32, 2, "tpu")
+    assert (rows, keys) == (128, 512)
+    q, k, v = _operands(1, t, 32, 2, 128, seed=64)
+    want = attention._masked_attention(q, k, v, scale=None, window=None)
+    got, = jax.jit(lambda *ops: sdp_kernel.causal_attention(
+        *ops, rows=rows, keys=keys, scale=128 ** -0.5, interpret=True))(
+            q, k, v)
+    _close(got, want)
+
+
 @pytest.mark.parametrize("why,window,rows,keys", [
     ("a window shorter than a key block", 100, 128, 256),
     ("a window that ends inside a block", 300, 256, 128),
@@ -188,10 +205,13 @@ def test_the_shape_function_says_where_the_body_runs(why, shape, heads,
 @pytest.mark.parametrize("t,group,want", [
     (1024, 1, (1024, 1024)), (1536, 1, (768, 768)), (2048, 1, (1024, 1024)),
     (1280, 4, (256, 640)), (2048, 4, (256, 1024)), (1536, 8, (128, 768)),
-    (2048, 8, (128, 1024)), (4096, 8, (128, 1024))])
+    (2048, 8, (128, 1024)), (4096, 8, (128, 1024)),
+    (2048, 16, (128, 512)), (5120, 16, (128, 640)), (8192, 16, (128, 512))])
 def test_the_shape_function_gives_the_rows_and_keys_of_a_step(t, group, want):
     """The largest multiples of 128 that divide T, within 1,024 rows of
-    all the heads of a group and 1,024 positions of a key block."""
+    all the heads of a group and 1,024 positions of a key block — a
+    shorter key block where sixteen heads' 128 rows would not fit beside
+    1,024 keys (PR 64: Nemotron-H's 32 query heads over 2)."""
     assert attention.prefill_block((1, t, 32 * 128), 32, 32 // group,
                                    "tpu") == want
 

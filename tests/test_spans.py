@@ -1054,3 +1054,65 @@ def test_the_carried_branch_and_the_identity_term_have_scopes_of_their_own():
         telemetry.set_enabled(was)
     assert moved == {"moe.zero_pairs": 10, "moe.pairs": 6,
                      "moe.expert_slots": 4}
+
+
+def test_two_matrix_experts_have_a_scope_and_a_counter_of_their_own():
+    """PR 64: a routed FFN whose experts are UNGATED (`expert_gated`
+    false: ``W2 act(W1 x)``, two matrices an expert and the shared one)
+    runs under ``mx:moe.ungated`` — op metadata of the compiled program —
+    and a program call's `moe_load` books `moe.ungated_pairs` beside
+    `moe.pairs`; a gated model's programs hold no such scope and book
+    nothing; a layer of ONE sublayer names no op of the half it lacks."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.executor import _run_graph
+    from mxnet_tpu.models import TransformerLM
+    from mxnet_tpu.symbol import _topo_order
+
+    def compiled(**spec):
+        lm = TransformerLM(
+            vocab=32, num_layers=2, num_heads=2, d_model=16, max_len=16,
+            norm="rms", positions="none", bias=False, num_experts=4,
+            experts_per_token=2, expert_d_ff=8, shared_d_ff=8,
+            layer_types=["attention", "none"], ffn_types=["none", "routed"],
+            **spec)
+        graph = lm.score_symbol()
+        names = graph.list_arguments()
+        shapes, _, _ = graph.infer_shape(data=(1, 8))
+        order = _topo_order(graph._entries)
+
+        def program(*args):
+            return _run_graph(graph._entries, order, names, [], args, (),
+                              False, jax.random.key(0))[0]
+
+        return names, jax.jit(program).lower(
+            *(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes)
+        ).compile().as_text()
+
+    names, text = compiled(expert_gated=False, expert_act="relu2")
+    assert "mx:moe.ungated/mx:moe.route" in text
+    assert "mx:moe.ungated/mx:moe.experts" in text
+    assert "mx:moe.ungated/mx:moe.shared" in text
+    assert "l1_up_weight" in names and "l1_gate_weight" not in names
+    # one sublayer a layer: no norm, projection or FFN of the missing half
+    assert not [n for n in names if n.startswith(("l0_ln2", "l0_ffn",
+                                                  "l1_ln1", "l1_qkv"))]
+    assert "l0_ffn" not in text and "l1_attn" not in text
+    names, text = compiled()
+    assert "mx:moe.ungated" not in text and "l1_gate_weight" in names
+    load = np.asarray([[3.0, 0.0, 2.0, 1.0]])
+    plan = (16, 1, 0, False, False, False)
+    was = telemetry.enabled()
+    telemetry.set_enabled(True)
+    try:
+        watched = ("moe.ungated_pairs", "moe.pairs", "moe.expert_slots")
+        before = {n: telemetry.counter_value(n) for n in watched}
+        GenerativeSession._book_moe_load(load, plan, False, True)
+        moved = {n: telemetry.counter_value(n) - v for n, v in before.items()}
+        assert moved == {"moe.ungated_pairs": 6, "moe.pairs": 6,
+                         "moe.expert_slots": 4}
+        GenerativeSession._book_moe_load(load, plan)
+        assert telemetry.counter_value("moe.ungated_pairs") == (
+            before["moe.ungated_pairs"] + 6)
+    finally:
+        telemetry.set_enabled(was)

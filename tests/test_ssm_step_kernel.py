@@ -30,6 +30,9 @@ SHAPES = {
     "granite_h_small": (128, 64, 128, 1, None, 32),
     # granite-4.0-h-micro's, 64 heads: two blocks a row
     "granite_h_micro": (64, 64, 128, 1, None, 32),
+    # Nemotron-H's (PR 64), 64 heads in EIGHT groups: two blocks a row of
+    # 32 heads, four whole groups each
+    "nemotron_h": (64, 64, 128, 8, None, 32),
     # a block of two whole groups of two heads
     "whole_groups_a_block": (8, 8, 128, 4, 1 << 14, 4),
     # two blocks inside each group of four heads
@@ -225,6 +228,8 @@ def test_a_gradient_through_a_step_the_kernel_tiles_is_the_bodys():
     ((3, 96, 64, 128), "tpu", 32, 24),
     ((3, 96, 64, 128), "tpu", 2, 24),
     ((3, 90, 64, 128), "tpu", 30, 30),
+    # Nemotron-H's eight groups of eight heads: four whole groups
+    ((9, 64, 64, 128), "tpu", 8, 32),
 ])
 def test_the_shape_function_says_where_the_kernel_runs(state, platform,
                                                        groups, heads):

@@ -1106,3 +1106,105 @@ def test_the_longcat_flash_programs_compile_for_a_v5e(program, one_chip):
         assert stats.temp_size_in_bytes < 0.3e9
     else:
         assert not re.search(r"f32\[(1,)?8,(1,)?2048,2048\]", text)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_nemotron_h_programs_compile_for_a_v5e(program, one_chip):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B's two serving programs at the cell's
+    sizes (PR 64) — the published widths, thirteen layers of ONE sublayer
+    each (`MEMEM*EMEMEM*`: six Mamba-2 of 64 heads x 64 with 128 states in
+    EIGHT groups, five of 32 held of 128 ungated squared-ReLU experts of
+    1,856 beside a shared 3,712, two NoPE attention layers of 32 heads over
+    2), eight slots of 8,704 positions, the 8-row step and the 8,192 bucket
+    — lowered for the TPU: a layer books a cache entry only for the half it
+    has (six windows, six states, four rings) and each is aliased to its
+    output, none copied within HBM; the step advances each Mamba-2 layer's
+    state in ONE kernel call at 32 heads — four whole groups — a grid step
+    (`ssm.step_heads`) and each attention layer's rings in one; the
+    two-matrix experts run under ``mx:moe.ungated``; the model is built at
+    the PUBLISHED 1,856 and the layer stores its stacks 1,920 wide
+    (`transformer_lm.stored_width`: as published the step COPIES each
+    layer's 639 MB `up` stack, 0.68 GB of temporaries — no copy of a stack
+    is in either program): TWO segment matmuls a routed layer, not
+    three — XLA's ragged-dot over the step's 48 pairs, `grouped_matmul_kernel`
+    over the bucket's ONE pass of 18,432 sorted rows, whose rows of 2,688
+    return through `row_return_kernel`; the weights are the 8.83 GB the
+    configuration's `reduced_why` reckons and fit a v5e with the tenant's
+    bound sets and the prefill's temporaries."""
+    import json
+    import re
+    import warnings
+
+    from benchmarks.families import nemotron_h as family
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "nemotron-3-nano-30b-a3b.json")) as f:
+        config = json.load(f)
+    lm = family.model(config)
+    assert lm.mixed_symbol(8) is None      # two programs: Mamba-2
+    pattern = config["hybrid_override_pattern"]
+    rows, bucket, max_len = 8, 8192, 8704
+    spec = lm.cache_spec(rows + 1, max_len)
+    assert len(spec) == 2 * (pattern.count("M") + pattern.count("*"))
+    wire = _wire(spec, rows)
+    if program == "prefill":
+        wire = dict(wire, data=(1, bucket), slot=(1,), length=(1,))
+    graph = (lm.decode_symbol() if program == "decode"
+             else lm.prefill_symbol())
+    ssm._state_step.clear_cache()
+    with warnings.catch_warnings():   # the small inputs are not donated
+        warnings.simplefilter("ignore")
+        compiled = _serving_program(graph, wire, one_chip)
+    text, stats = compiled.as_text(), compiled.memory_analysis()
+    windows = {e.shape for n, e in spec.items() if n.startswith("conv_")}
+    state, = {e.shape for n, e in spec.items() if n.startswith("ssm_state")}
+    assert state == (9, 64, 64, 128) and windows == {(9, 3, 6144)}
+    assert ssm.step_heads(state, "tpu", config["n_groups"]) == 32
+    for shape in sorted({e.shape for e in spec.values()}):
+        count = sum(e.shape == shape for e in spec.values())
+        facts = chip_smoke.ring_hlo_facts(text, shape)
+        assert facts["ring_params"] == facts["aliased"] == count, shape
+        if shape in windows and program == "decode":
+            assert all("S(1)" in line for line in facts["copies"]), shape
+        else:
+            assert facts["copies"] == [], shape
+    stepped = chip_smoke.ssm_step_hlo_facts(text, rows, state)
+    assert stepped == {
+        "kernel_calls": pattern.count("M") * (program == "decode"),
+        "row_pages": [], "copies": []}
+    assert "mx:moe.ungated/mx:moe.experts" in text
+    # built at the published width, stored in whole lane tiles: no program
+    # copies an expert stack (at 1,856 as it is the step copies `up`)
+    assert (lm.expert_d_ff, config["moe_intermediate_size"]) == (1856, 1856)
+    stacks = {s for n, s in zip(graph.list_arguments(), graph.infer_shape(
+        **wire)[0]) if n.endswith(("_up_weight", "_down_weight"))
+        and "shared" not in n}
+    assert stacks == {(32, 2688, 1920), (32, 1920, 2688)}
+    assert not re.search(r"f32\[32,(2688,\d+|\d+,2688)\]\S* copy\(", text)
+    sets = sum(e.nbytes for e in spec.values())
+    assert 0.437e9 < sets < 0.439e9        # nine pages of 48.7 MB
+    assert stats.alias_size_in_bytes >= sets
+    weights = stats.argument_size_in_bytes - sets
+    assert 8.82e9 < weights < 8.85e9
+    routed = pattern.count("E")
+    dots = _ragged_dots(text)
+    grouped = chip_smoke.named_kernel_calls(text, "grouped_matmul_kernel")
+    placed = chip_smoke.named_kernel_calls(text, "row_return_kernel")
+    assert lm.expert_plan(bucket) == (49152, 1, 18432, True, False, True)
+    if program == "decode":
+        assert stats.temp_size_in_bytes < 0.3e9
+        assert set(dots) == {("48", "16")} and len(dots) == 2 * routed
+        assert placed == grouped == 0
+    else:
+        assert dots == [] and grouped == 2 * routed
+        assert placed == routed
+        assert not re.search(r"f32\[49152,2688\]", text)
+        assert not re.search(r"f32\[%d,2688\]\S* scatter\(" % bucket, text)
+    print("nemotron_h %s: weights %.3f GB, a set %.3f GB, temporaries "
+          "%.3f GB" % (program, weights / 1e9, sets / 1e9,
+                       stats.temp_size_in_bytes / 1e9))
+    # a v5e's 16.9e9 bytes hold the weights, the tenant's bound sets (the
+    # live one and one a bucket program) and the program
+    assert weights + 6 * sets + stats.temp_size_in_bytes < 15.75e9, (
+        weights, sets, stats.temp_size_in_bytes)
